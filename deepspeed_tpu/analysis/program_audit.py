@@ -74,7 +74,7 @@ def _axis_names(params: Mapping[str, Any]) -> Tuple[str, ...]:
 
 
 def _subjaxprs(params: Mapping[str, Any]):
-    """Every sub-jaxpr held by an eqn's params (pjit/shard_map/scan/
+    """Every sub-jaxpr held by an eqn's params (jit/shard_map/scan/
     while/cond/custom_* all store them under different keys)."""
     from jax._src.core import ClosedJaxpr, Jaxpr
     for v in params.values():
@@ -101,12 +101,12 @@ class CollectiveSite:
         return f"{self.kind}[{','.join(self.axes)}]({self.dtype})"
 
 
-#: inner-jit (pjit eqn) name fragments that canonicalize the ppermute
+#: inner-jit (``jit`` eqn) name fragments that canonicalize the ppermute
 #: hops traced inside them: the decomposed TP collectives
 #: (``comm.ring_reduce_scatter`` / ``comm.ring_all_gather``) are built
 #: from ppermute rings, and counting those hops as raw ppermutes would
 #: make a reduce-scatter indistinguishable from pipeline p2p traffic.
-#: Any ppermute inside a region whose pjit name carries one of these
+#: Any ppermute inside a region whose jit name carries one of these
 #: fragments reports as the canonical decomposed kind — so a planted
 #: extra ring hop trips a reduce_scatter/all_gather budget diff.
 RING_REGION_KINDS: Mapping[str, str] = {
@@ -147,7 +147,7 @@ def _walk(jaxpr, counts: Dict[CollectiveSite, int], state: Dict[str, Any],
             # audited-collective share from exactly these two numbers)
             state["dot_generals"] += mult
         sub_ring = ring_kind
-        if prim == "pjit":
+        if prim == "jit":
             sub_ring = _ring_kind_for(eqn.params.get("name")) or ring_kind
         if prim == "scan":
             # a scan body executes `length` times: weight its collectives
@@ -242,21 +242,27 @@ class ProgramReport:
 # compiler:
 #   %arg7: tensor<...> {..., tf.aliasing_output = 0 : i32, ...}
 #   %arg0: tensor<...> {jax.buffer_donor = true, mhlo.sharding = ...}
-_ARG_ATTR_RE = re.compile(r"%arg(\d+):\s*[^\s{,)]+(?:\s*\{([^}]*)\})?")
+_ARG_RE = re.compile(r"%arg(\d+):")
 _DONOR_MARKS = ("tf.aliasing_output", "jax.buffer_donor")
 
 
 def donated_arg_indices(stablehlo_text: str) -> Tuple[int, ...]:
     """Flat input indices aliased/donated to outputs, parsed from the
     lowered module's ``@main`` signature. Lowering records donation on
-    every backend (the CPU compiler later drops it with a warning), so
-    the tier-1 CPU mesh can still verify a program *requests* donation."""
+    every backend, so the tier-1 CPU mesh can verify a program *requests*
+    donation. Each argument's attributes are everything up to the next
+    argument: an attribute dict may nest braces (a committed input carries
+    ``sdy.sharding = #sdy.sharding<@mesh, [{}, {}]>``), so it is not
+    matched as a ``{...}`` group."""
     for line in stablehlo_text.splitlines():
         if "@main(" not in line:
             continue
+        args = line.split(") -> ")[0]
+        found = list(_ARG_RE.finditer(args))
+        ends = [m.start() for m in found[1:]] + [len(args)]
         return tuple(sorted(
-            int(m.group(1)) for m in _ARG_ATTR_RE.finditer(line)
-            if m.group(2) and any(d in m.group(2) for d in _DONOR_MARKS)))
+            int(m.group(1)) for m, end in zip(found, ends)
+            if any(d in args[m.end():end] for d in _DONOR_MARKS)))
     return ()
 
 
@@ -491,30 +497,23 @@ def audit_serve_programs(engine, programs: Tuple[str, ...] = (
 # ------------------------------------------------------------------ #
 
 _COMPILES = {"n": 0}
-_LISTENING = {"on": False, "available": None}
+_LISTENING = {"on": False}
 
 
-def _ensure_compile_listener() -> bool:
+def _ensure_compile_listener() -> None:
     """Register (once) a jax monitoring listener counting XLA backend
-    compiles. Returns False when this jax build has no monitoring API."""
+    compiles. Raises if this jax has no such hook: a tripwire that cannot
+    count must not report zero."""
     if _LISTENING["on"]:
-        return True
-    if _LISTENING["available"] is False:
-        return False
-    try:
-        from jax._src import monitoring
+        return
+    from jax._src import monitoring
 
-        def _on_event(event, *a, **kw):
-            if "backend_compile" in event:
-                _COMPILES["n"] += 1
+    def _on_event(event, *a, **kw):
+        if "backend_compile" in event:
+            _COMPILES["n"] += 1
 
-        monitoring.register_event_duration_secs_listener(_on_event)
-    except Exception:                            # pragma: no cover
-        _LISTENING["available"] = False
-        return False
+    monitoring.register_event_duration_secs_listener(_on_event)
     _LISTENING["on"] = True
-    _LISTENING["available"] = True
-    return True
 
 
 class RecompileTripwire:
@@ -522,12 +521,14 @@ class RecompileTripwire:
 
     A warm serve-pipeline run must report ``fresh_compiles == 0``: a jit
     cache miss mid-serve means a shape/dtype/static-arg leak — a silent
-    latency cliff the tier-1 tests now catch. ``available`` is False on
-    jax builds without the monitoring API (the tripwire then reports 0).
+    latency cliff the tier-1 tests now catch. A jit cache miss that the
+    persistent compilation cache then serves still counts: the event fires
+    around the cache lookup. Construction raises on a jax without the
+    monitoring hook.
     """
 
     def __init__(self):
-        self.available = _ensure_compile_listener()
+        _ensure_compile_listener()
         self._start = 0
         self._stop: Optional[int] = None
 

@@ -150,9 +150,8 @@ def configure(config=None, enabled=None, prof_all=None, prof_ops=None,
 # --------------------------------------------------------------------------- #
 
 def _axis_size(axis_name) -> int:
-    from ..utils.jax_compat import axis_size
     try:
-        return axis_size(axis_name)
+        return jax.lax.axis_size(axis_name)
     except NameError:
         return 1
 

@@ -301,6 +301,18 @@ class InferenceEngineV2:
             # block-shard the pool at rest: per-chip KV bytes ∝ 1/seq as
             # CONTEXT grows — the capacity lever for long prompts
             self.kv_cache.shard_seq(self.runner.seqctx.mesh)
+        if self.runner.tp is None and self.runner.seqctx is None \
+                and self.runner.epctx is None:
+            # a one-device engine lives where its weights sit: commit
+            # them and the pool there, so a step dispatched from any
+            # thread runs on that device (weights spread over a mesh the
+            # engine does not own — the hybrid engine's — stay as given)
+            on = {d for leaf in jax.tree_util.tree_leaves(params)
+                  if isinstance(leaf, jax.Array) for d in leaf.devices()}
+            if len(on) == 1:
+                (dev,) = on
+                self.params = jax.device_put(params, dev)
+                self.kv_cache.pin(dev)
         self.state = StateManager(self.config, self.kv_cache)
         self._prefix = None
         if self.config.prefix_cache:

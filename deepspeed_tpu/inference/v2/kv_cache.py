@@ -23,6 +23,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, List, Optional
 
+import jax
 import jax.numpy as jnp
 
 from .blocked_allocator import BlockedAllocator
@@ -167,6 +168,17 @@ class BlockedKVCache:
                 (num_layers, 2, slots, kv_heads * head_dim), self.dtype)
             self.scales = None
 
+    def pin(self, device) -> None:
+        """COMMIT the pool to ``device`` (a one-device engine pins itself
+        to the device its weights sit on — ``InferenceEngineV2``). Arrays
+        merely created under a ``jax.default_device`` scope are
+        uncommitted: a step dispatched later from another thread (a
+        ``ReplicaPool`` worker) would move them, and the whole
+        computation, to that thread's default device."""
+        self.data = jax.device_put(self.data, device)
+        if self.scales is not None:
+            self.scales = jax.device_put(self.scales, device)
+
     @property
     def pool(self):
         """The threadable pool pytree: a KVPool when quantized (data +
@@ -190,7 +202,7 @@ class BlockedKVCache:
     def _warm_copy(self) -> None:
         """Compile the CoW row copy with a trash-block self-copy (writes
         only the trash block, whose content is never read) and thread the
-        result back — on TPU the program donates the pool buffers."""
+        result back — the program donates the pool buffers."""
         from .kv_quant import pool_parts
         warmed = self.copy_block(self.pool, self.cfg.num_blocks,
                                  self.cfg.num_blocks)
@@ -451,10 +463,8 @@ class BlockedKVCache:
             _copy = shard_map(_copy, mesh=self._mesh,
                               in_specs=(spec, P(), P()), out_specs=spec,
                               check_vma=False)
-        # pool donated on TPU like every other pool-threading program
-        # (CPU XLA implements no donation; () avoids the warning spam)
-        donate = (0,) if jax.default_backend() == "tpu" else ()
-        return jax.jit(_copy, donate_argnums=donate)
+        # pool donated like every other pool-threading program
+        return jax.jit(_copy, donate_argnums=(0,))
 
     def shard(self, mesh) -> None:
         """Head-shard the pool at rest over the TP ``model`` mesh axis:
@@ -575,8 +585,16 @@ class BlockedKVCache:
             raise ValueError(
                 f"restore: buffer holds {host_rows.shape[2]} slots, "
                 f"{idx.size} requested")
-        data = data.at[:, :, idx].set(jnp.asarray(host_rows, data.dtype))
+        def rows_for(pool_arr, rows):
+            # a handoff payload may still be device-resident on the
+            # SENDER's devices: a one-device pool takes it to its own
+            rows = jnp.asarray(rows, pool_arr.dtype)
+            if len(pool_arr.devices()) == 1:
+                rows = jax.device_put(rows, next(iter(pool_arr.devices())))
+            return rows
+
+        data = data.at[:, :, idx].set(rows_for(data, host_rows))
         if scales is not None:
             scales = scales.at[:, :, :, idx].set(
-                jnp.asarray(host_buf[1], scales.dtype))
+                rows_for(scales, host_buf[1]))
         return repack(kv_data, data, scales)

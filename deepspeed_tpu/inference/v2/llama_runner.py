@@ -73,8 +73,7 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
     if ep_axis_active():
         from ...moe.sharded_moe import (ep_serve_capacity,
                                         grouped_moe_ffn_ep_serve)
-        from ...utils.jax_compat import axis_size
-        ep = axis_size(EP_AXIS)
+        ep = jax.lax.axis_size(EP_AXIS)
         chunks = int(icfg.ep_comm_chunks) \
             if icfg is not None and icfg.ep_comm_overlap == "chunked" else 1
         factor = float(icfg.ep_capacity_factor) if icfg is not None else 2.0

@@ -49,7 +49,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ...utils.jax_compat import axis_size, manual_axes
+from ...utils.jax_compat import manual_axes
 from ...utils.logging import log_dist
 from .kv_quant import KVPool
 
@@ -117,7 +117,7 @@ def ring_all_gather(x, axis_name: str = SEQ_AXIS):
     quantized-collective shape, each visible to the program auditor under
     its own ``ppermute@dtype`` budget key. Registered DSL001 hot path
     (traced inside the warm prefill program)."""
-    sz = axis_size(axis_name)
+    sz = jax.lax.axis_size(axis_name)
     if sz == 1:
         return x[None]
     r = lax.axis_index(axis_name)

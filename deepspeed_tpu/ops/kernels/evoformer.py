@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ...utils.jax_compat import tpu_compiler_params as _compat_tpu_compiler_params
 
 _NEG_INF = float("-inf")
 
@@ -52,7 +51,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mb_ref, pb_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if mb_ref is not None:
-        s = s + mb_ref[...].astype(jnp.float32)        # [1, Tk] row bias
+        s = s + mb_ref[0].astype(jnp.float32)          # [1, Tk] row bias
     if pb_ref is not None:
         s = s + pb_ref[0, 0].astype(jnp.float32)       # [Tq, Tk] pair bias
     col = ki * block_k + jax.lax.broadcasted_iota(
@@ -110,9 +109,12 @@ def _evo_fwd_pallas(q4, k4, v4, mb2, pb4, *, n_rows, scale, block_q,
     ]
     args = [q4, k4, v4]
     if mb2 is not None:
+        # carried [BN, 1, Sk]: a (1, Tk) block of a 2-D [BN, Sk] array has
+        # a sublane dim that neither divides by 8 nor spans the array,
+        # which the Mosaic lowering refuses; a unit middle dim does span
         in_specs.append(
-            pl.BlockSpec((1, Tk), lambda bn, h, qi, ki: (bn, ki)))
-        args.append(mb2)
+            pl.BlockSpec((1, 1, Tk), lambda bn, h, qi, ki: (bn, 0, ki)))
+        args.append(mb2[:, None, :])
     if pb4 is not None:
         in_specs.append(pl.BlockSpec(
             (1, 1, Tq, Tk),
@@ -142,7 +144,7 @@ def _evo_fwd_pallas(q4, k4, v4, mb2, pb4, *, n_rows, scale, block_q,
         scratch_shapes=[pltpu.VMEM((Tq, 128), jnp.float32),
                         pltpu.VMEM((Tq, 128), jnp.float32),
                         pltpu.VMEM((Tq, D), jnp.float32)],
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

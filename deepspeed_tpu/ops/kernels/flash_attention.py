@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ...utils.jax_compat import tpu_compiler_params as _compat_tpu_compiler_params
 
 _NEG_INF = float("-inf")
 _LANES = 128
@@ -196,7 +195,7 @@ def _fwd_sparse(q, k, v, block_mask, sm_scale, block_q, block_k, kv_len,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, tq, d), q.dtype),
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -295,7 +294,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         out_shape=out_shape,
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -452,7 +451,7 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
                                lambda b, h, i, j: (b, h, i, 0)),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -488,7 +487,7 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ...utils.jax_compat import tpu_compiler_params as _compat_tpu_compiler_params
 
 _E, _M = 3, 2                      # e3m2
 _BIAS = 2 ** (_E - 1) - 1          # 3
@@ -143,7 +142,7 @@ def fp6_matmul(x: jnp.ndarray, fw: Fp6GemmWeight,
                                lambda mi, ji, ki: (mi, 0, ji)),
         out_shape=jax.ShapeDtypeStruct((M2, 4, J), x.dtype),
         scratch_shapes=[pltpu.VMEM((Mt, Jt), jnp.float32)] * 4,
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x2, fw.bytes3, fw.scale)

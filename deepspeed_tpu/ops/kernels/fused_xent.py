@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ...utils.jax_compat import tpu_compiler_params as _compat_tpu_compiler_params
 
 _NEG_INF = float("-inf")
 _LANES = 128
@@ -130,7 +129,7 @@ def _fwd(h2, emb, tgt2, *, Tb, Vb, eps, interpret):
         ],
         out_shape=[jax.ShapeDtypeStruct((N2, 1), jnp.float32)] * 3,
         scratch_shapes=[pltpu.VMEM((Tb, _LANES), jnp.float32)] * 4,
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(h2, e, tgt2[:, None])
@@ -291,7 +290,7 @@ def _xent_bwd_rule(N, Tb, Vb, ignore, z, eps, interpret, res, g):
         out_specs=pl.BlockSpec((1, Tb, C), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((Nt, Tb, C), h2.dtype),
         scratch_shapes=[pltpu.VMEM((Tb, C), jnp.float32)],
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(scale, h2, e, tgt2[:, None], lse[:, None]).reshape(N2, C)
@@ -310,7 +309,7 @@ def _xent_bwd_rule(N, Tb, Vb, ignore, z, eps, interpret, res, g):
         out_specs=pl.BlockSpec((1, Vb, C), lambda j, i: (j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((Vt, Vb, C), jnp.float32),
         scratch_shapes=[pltpu.VMEM((Vb, C), jnp.float32)],
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(scale, h2, e, tgt2[:, None], lse[:, None]).reshape(Vt * Vb, C)[:V]

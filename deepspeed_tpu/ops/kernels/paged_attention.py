@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ...utils.jax_compat import tpu_compiler_params as _compat_tpu_compiler_params
 
 _NEG_INF = float("-inf")
 _LANES = 128
@@ -616,7 +615,7 @@ def _flash_decode_grouped(qw, kp_flat, vp_flat, fetch, start_pos, seq_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, KVD), out_dtype),
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*prefetch, *operands)
@@ -817,10 +816,15 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
               * sel[None, :, None, :, None].astype(q.dtype))  # [S,H,C,KV,D]
         qw = qw.reshape(S, H, C, KVD).astype(compute_dt)
         row_lanes = KVD
-        if maxb_v == 1:
+        if maxb_v == 1 and (quant or KVD % _LANES == 0):
             # linear layout, whole context in one block: the grouped
             # kernel processes several sequences per grid step with manual
-            # async DMAs — the per-grid-step fixed cost was the decode wall
+            # async DMAs — the per-grid-step fixed cost was the decode wall.
+            # Its DMAs slice row windows out of the HBM pool, which Mosaic
+            # only takes at 128-lane rows: narrower bf16 rows (one 64-wide
+            # kv head per chip under tp) go through the BlockSpec path
+            # below, whose blocks span the whole row. int8 rows that narrow
+            # have no path at all and raise inside the grouped dispatch.
             out = _flash_decode_grouped(
                 qw.reshape(S, H, KVD), k_pool, v_pool, fetch[:, 0],
                 start_pos, seq_lens, bs=pbs, H=H, KV=KV, D=D,
@@ -939,7 +943,7 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qw.shape, q.dtype),
-        compiler_params=_compat_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(*prefetch, *operands)

@@ -32,8 +32,7 @@ NEG_INF = -1e30
 
 def _ring_attention_local(q, k, v, axis_name: str, causal: bool, sm_scale: float):
     """Runs inside shard_map. q/k/v: [B, T_loc, H, D] local shards."""
-    from ..utils.jax_compat import axis_size
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     B, T_loc, H, D = q.shape
 
@@ -103,8 +102,7 @@ def _ring_attention_local_kernel(q, k, v, axis_name: str, causal: bool,
     ring trains through jax.grad with kernel fwd+bwd."""
     from ..ops.kernels import flash_attention
 
-    from ..utils.jax_compat import axis_size
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 

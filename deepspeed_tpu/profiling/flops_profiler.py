@@ -20,22 +20,37 @@ import numpy as np
 from ..config.config import FlopsProfilerConfig
 from ..utils.logging import log_dist, logger
 
-# peak bf16 FLOPs for utilization estimates (per chip)
+# peak bf16 FLOP/s per chip, keyed by ``device_kind`` prefix (longest
+# prefix first). Source: Google Cloud TPU documentation, system
+# architecture pages of each generation.
 PEAK_FLOPS = {
     "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,   # v5e bf16
+    "TPU v5 lite": 197e12,   # v5e
     "TPU v5": 459e12,        # v5p
     "TPU v6 lite": 918e12,   # v6e
-    "cpu": 1e12,             # nominal, so utilization prints something sane
 }
 
 
 def device_peak_flops() -> float:
+    """Peak bf16 FLOP/s of device 0. A device that is not in the table is
+    an error, not a default: a ratio against a made-up peak is not a
+    utilization."""
     kind = jax.devices()[0].device_kind
     for name, flops in PEAK_FLOPS.items():
         if kind.lower().startswith(name.lower()):
             return flops
-    return PEAK_FLOPS["cpu"]
+    raise KeyError(
+        f"no peak FLOP/s on record for device_kind {kind!r}; add it to "
+        f"profiling.flops_profiler.PEAK_FLOPS with its source")
+
+
+def utilization(flops_per_s: float) -> Optional[float]:
+    """``flops_per_s`` over the device's peak, or None on a device with no
+    peak on record (the CPU test mesh)."""
+    try:
+        return flops_per_s / device_peak_flops()
+    except KeyError:
+        return None
 
 
 class FlopsProfiler:
@@ -72,7 +87,7 @@ class FlopsProfiler:
             "flops_per_step": flops,
             "tflops": flops / latency / 1e12 if latency > 0 else 0.0,
             "params": n_params,
-            "utilization": (flops / latency) / device_peak_flops() if latency > 0 else 0.0,
+            "utilization": utilization(flops / latency) if latency > 0 else None,
             "bytes_accessed": cost.get("bytes accessed", 0.0) if cost else 0.0,
         }
         self.results = result
@@ -118,8 +133,9 @@ class FlopsProfiler:
             f"params:               {r['params'] / 1e6:.2f} M\n"
             f"fwd+bwd+step latency: {r['latency_s'] * 1000:.2f} ms\n"
             f"FLOPs per step:       {r['flops_per_step'] / 1e9:.2f} G\n"
-            f"achieved:             {r['tflops']:.2f} TFLOPS "
-            f"({r['utilization'] * 100:.1f}% of peak)\n"
+            f"achieved:             {r['tflops']:.2f} TFLOPS ("
+            + ("no peak on record for this device" if r["utilization"] is None
+               else f"{r['utilization'] * 100:.1f}% of peak") + ")\n"
             f"bytes accessed:       {r['bytes_accessed'] / 1e9:.2f} GB\n"
             "---------------------------------------------------------------------")
 

@@ -43,8 +43,7 @@ Exit 0 only when every site both crashed and recovered. This is the CI
 guard (``bin/dstpu_faultdrill``) that keeps the recovery paths in
 ``checkpoint/``, ``runtime/engine.py`` and ``inference/v2/drain.py``
 honest; tier-1 runs subsets via ``tests/unit/test_resilience.py`` and
-``tests/unit/test_serve_drain.py``; ``tools/tpu_round11.sh`` runs both
-modes in CI.
+``tests/unit/test_serve_drain.py``.
 """
 
 from __future__ import annotations

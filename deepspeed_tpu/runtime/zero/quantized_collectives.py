@@ -41,8 +41,8 @@ from ...ops.kernels.quantization import (
     pack_int4, sym_quantize_rowwise, unpack_int4)
 
 
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-    """Version-tolerant shard_map with partial-manual axes."""
+def shard_map(f, mesh, in_specs, out_specs, axis_names=()):
+    """shard_map with partial-manual axes and no replication check."""
     from ...utils.jax_compat import shard_map as _sm
     return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                check_vma=False, axis_names=axis_names)

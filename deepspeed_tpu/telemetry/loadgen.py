@@ -477,7 +477,9 @@ class _OpenLoopDriver:
         self.poll_s = poll_s
         #: SamplingParams template applied to EVERY offered request
         #: (per-uid seeds derive from the uid when the template names
-        #: none — streams stay deterministic per request identity)
+        #: none — streams stay deterministic per request identity), or
+        #: a {uid: SamplingParams} map for a mixed pass (uids it does
+        #: not name stay greedy)
         self.sampling = sampling
         self.max_live = max(1, int(max_live)) \
             if max_live is not None else None
@@ -592,8 +594,11 @@ class _OpenLoopDriver:
             arrivals[r.uid] = self.t0 + t_arr
             if dl is not None:
                 deadlines[r.uid] = dl
-        sampling = {r.uid: self.sampling for r in due} \
-            if self.sampling is not None else None
+        sp = self.sampling
+        if isinstance(sp, dict):
+            sampling = {r.uid: sp[r.uid] for r in due if r.uid in sp}
+        else:
+            sampling = {r.uid: sp for r in due} if sp is not None else None
         res = self.engine.put([r.uid for r in due],
                               [r.prompt for r in due], _greedy=True,
                               arrivals=arrivals, deadlines=deadlines,
@@ -913,8 +918,9 @@ def run_open_loop(engine, requests: Sequence[Request],
     concurrency (further due requests wait at the door with their
     arrival stamp intact — their wait is measured, not hidden).
 
-    ``sampling`` (a SamplingParams template, or None for greedy)
-    attaches per-request sampling at admission — the engine then
+    ``sampling`` (a SamplingParams template for every request, a
+    ``{uid: SamplingParams}`` map for a mixed greedy/sampled pass, or
+    None for greedy) attaches per-request sampling at admission — the engine then
     selects tokens on-device per slot; speculative decoding (the
     engine's ``spec_decode`` knob) needs no driver support at all,
     because ``decode_pipelined`` routes greedy batches through it

@@ -1,10 +1,10 @@
-"""Version-tolerant wrappers over moving JAX APIs.
+"""The few spellings of the JAX surface the framework settles in one place.
 
-The framework targets the modern ``jax.shard_map`` surface
-(``check_vma`` / ``axis_names``); older installs (< 0.5) only ship
-``jax.experimental.shard_map.shard_map`` with the ``check_rep`` / ``auto``
-spelling. Every internal caller imports :func:`shard_map` from here so the
-translation lives in exactly one place.
+Written for the installed JAX (0.9): ``jax.shard_map`` with ``check_vma`` /
+``axis_names``, ``jax_num_cpu_devices``, the abstract mesh's
+``manual_axes``. Nothing here branches on a version. ``dslint`` DSL003
+keeps ``jax.experimental.shard_map`` out of the tree; every internal caller
+takes :func:`shard_map` from here.
 """
 
 from __future__ import annotations
@@ -13,87 +13,22 @@ import jax
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-              check_vma=None, axis_names=None):
-    """``jax.shard_map`` facade. ``axis_names`` is the MANUAL axis set (new
-    API); on the legacy API it is translated to ``auto`` (its complement
-    over the mesh axes), and ``check_vma`` to ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _legacy
-    kw = {}
-    if check_vma is not None:
-        kw["check_rep"] = bool(check_vma)
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        # size-1 auto axes are semantically manual no-ops; keeping them in
-        # ``auto`` routes the legacy implementation through its
-        # partial-auto transpose, which mis-specs scalar cotangents
-        # (_SpecError) — drop them so the common all-size-1 case takes the
-        # well-trodden full-manual path
-        auto = frozenset(a for a in auto if mesh.shape[a] > 1)
-        if auto:
-            kw["auto"] = auto
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   **kw)
-
-
-def axis_size(axis_name):
-    """``lax.axis_size`` facade (static size of a named mapped axis, usable
-    at trace time). Raises ``NameError`` when the axis is not bound, like
-    the modern primitive. Accepts an axis-name tuple (product of sizes)."""
-    if isinstance(axis_name, (tuple, list)):
-        n = 1
-        for a in axis_name:
-            n *= axis_size(a)
-        return n
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    from jax._src.core import axis_frame   # legacy: returns the int size
-    frame = axis_frame(axis_name)
-    return getattr(frame, "size", frame)
+              check_vma=True, axis_names=()):
+    """``jax.shard_map`` with the mesh accepted positionally.
+    ``axis_names`` is the MANUAL axis set (any iterable; empty = every mesh
+    axis is manual)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma,
+                         axis_names=frozenset(axis_names))
 
 
 def request_cpu_devices(n: int) -> None:
-    """Ask for ``n`` virtual CPU devices, whichever API this jax has. Must
-    run BEFORE the backend initializes (jax.config on modern jax; the
-    XLA_FLAGS env knob on older releases)."""
-    import os
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "--xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count={n}")
-
-
-def tpu_compiler_params(**kwargs):
-    """``pallas.tpu.CompilerParams`` facade (named ``TPUCompilerParams``
-    before jax 0.5)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+    """Ask for ``n`` virtual CPU devices. Must run BEFORE the backend
+    initializes."""
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 def manual_axes():
-    """Axis names currently mapped manually (i.e. we are tracing inside a
-    ``shard_map`` body). Modern: the abstract mesh's ``manual_axes``;
-    legacy: the nonempty axis environment."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        try:
-            return tuple(getattr(jax.sharding.get_abstract_mesh(),
-                                 "manual_axes", ()) or ())
-        except Exception:
-            return ()
-    # legacy: the nonempty axis env IS "inside a shard_map body" (the
-    # name lives on jax.core, NOT jax._src.core, on 0.4.x)
-    from jax.core import unsafe_get_axis_names_DO_NOT_USE
-    return tuple(unsafe_get_axis_names_DO_NOT_USE())
+    """Axis names currently mapped manually (non-empty exactly when we are
+    tracing inside a ``shard_map`` body)."""
+    return tuple(jax.sharding.get_abstract_mesh().manual_axes)

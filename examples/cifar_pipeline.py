@@ -18,11 +18,11 @@ import argparse
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
 
-if os.environ["JAX_PLATFORMS"] == "cpu":
-    # must precede the first backend touch (tests/conftest.py pattern)
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    # the 8-device virtual CPU mesh, when asked for by name; with nothing
+    # set, JAX picks the accelerator the host has
     jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
     from deepspeed_tpu.utils.jax_compat import request_cpu_devices

@@ -383,7 +383,7 @@ class TestSpikeGate:
                                     uid_base=4000),
                 admission=ctrl, retry_budget=2,
                 retry_base_s=0.05).report
-        fresh = tw.fresh_compiles if tw.available else 0
+        fresh = tw.fresh_compiles
         assert fresh == 0
         on_g = on["rates_rps"]["goodput"] or 0.0
         off_g = off["rates_rps"]["goodput"] or 0.0

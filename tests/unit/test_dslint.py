@@ -203,7 +203,7 @@ class TestDonationRule:
 
 
 class TestShardMapImportRule:
-    def test_flags_every_import_form_except_jax_compat(self, tmp_path):
+    def test_flags_every_import_form_everywhere(self, tmp_path):
         _write(str(tmp_path), "deepspeed_tpu/a.py", """
             from jax.experimental.shard_map import shard_map
         """)
@@ -223,7 +223,7 @@ class TestShardMapImportRule:
                                knob_rules=False)
         assert sorted(f.path for f in findings) == [
             "deepspeed_tpu/a.py", "deepspeed_tpu/b.py",
-            "deepspeed_tpu/c.py"]
+            "deepspeed_tpu/c.py", "deepspeed_tpu/utils/jax_compat.py"]
         assert {f.rule for f in findings} == {"DSL003"}
 
 

@@ -377,9 +377,6 @@ class TestEPWarmPath:
         rng = np.random.default_rng(0)
         prompts = [rng.integers(1, V, 6).tolist() for _ in range(2)]
         uids = [70, 71]
-        tw = RecompileTripwire()
-        if not tw.available:
-            pytest.skip("jax monitoring API unavailable")
         first = ep2.put(uids, prompts, _greedy=True)
         ep2.decode_pipelined(uids, [first[u] for u in uids], 4)
         with RecompileTripwire() as warm:

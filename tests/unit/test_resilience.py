@@ -577,7 +577,7 @@ class TestFaultDrill:
     def test_serve_drill_hard_crash_and_sigterm(self, tmp_path):
         # one hard-crash site (journal recovery) + the cooperative
         # SIGTERM drain (manifest recovery); bin/dstpu_faultdrill
-        # --mode serve runs every serve site in CI (tools/tpu_round11.sh)
+        # --mode serve runs every serve site
         from deepspeed_tpu.resilience.faultdrill import main
         rc = main(["--mode", "serve", "--sites", "mid_commit,sigterm",
                    "--workdir", str(tmp_path)])

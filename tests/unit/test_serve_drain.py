@@ -368,11 +368,21 @@ class TestAbort:
         eng = InferenceEngineV2(mcfg, params, _cfg(
             prefix=False, depth=3, block_size=1, num_blocks=64,
             max_blocks_per_seq=32, attention_impl="dense"))
-        prompt = list(np.random.default_rng(9).integers(1, 96, 10))
-        f = eng.put([0], [prompt], _greedy=True)
-        chain = eng.decode_pipelined([0], [int(f[0])], 8)[0]
-        eng.flush(0)
-        eos = chain[2]                     # EOS fires mid-ring at depth 3
+        # the tiny random model's greedy chains mostly repeat one token,
+        # and WHICH prompts do follows the jax.random stream the weights
+        # were drawn from (it changed under this test once already): take
+        # the first prompt whose third token is new, so that using it as
+        # EOS fires mid-ring at depth 3 and not at the first token
+        for seed in range(9, 40):
+            prompt = list(np.random.default_rng(seed).integers(1, 96, 10))
+            f = eng.put([0], [prompt], _greedy=True)
+            chain = eng.decode_pipelined([0], [int(f[0])], 8)[0]
+            eng.flush(0)
+            if chain[2] not in chain[:2]:
+                break
+        else:
+            pytest.fail("no prompt yields a chain with a fresh third token")
+        eos = chain[2]
         f = eng.put([1], [prompt], _greedy=True)
         orig, state = eng._pre_commit, {"done": False}
 
@@ -589,8 +599,7 @@ class TestServeDrainPrograms:
             out = surv.replay(m)
             short = sorted(int(s["uid"]) for s in m["sequences"])
             surv.decode_pipelined(short, [int(out[u]) for u in short], 3)
-        if tw.available:
-            assert tw.fresh_compiles == 0
+        assert tw.fresh_compiles == 0
         # drain-path device programs: zero collectives, zero callbacks
         reports = audit_serve_programs(surv)
         clean = CollectiveBudget(name="tp1 serve after drain/replay")

@@ -11,11 +11,12 @@ joiner). Heavier N and the subprocess SIGTERM drill ride the slow tier
 (``bin/dstpu_faultdrill --mode fleet`` is the CI gate).
 """
 
+import jax
 import pytest
 
 from deepspeed_tpu.serving import (NoServingReplicaError, ReplicaPool,
-                                   Router, fleet_prefix_stats,
-                                   single_stream_oracle)
+                                   Router, build_replica_engines,
+                                   fleet_prefix_stats, single_stream_oracle)
 from deepspeed_tpu.telemetry.loadgen import (UniformArrivals, WorkloadMix,
                                              _tiny_engine, build_requests,
                                              run_open_loop)
@@ -211,8 +212,16 @@ class TestMergeSourceScheme:
 
 
 def _mk_pool(n=2, policy="prefix_aware", seed=0):
-    built = [_tiny_engine() for _ in range(n)]
-    pool = ReplicaPool([e for e, _ in built], policy=policy, seed=seed)
+    # one device per replica; the factory commits nothing itself (its
+    # arrays are only CREATED under build_replica_engines' device scope)
+    built = []
+
+    def factory(i, dev):
+        built.append(_tiny_engine())
+        return built[-1][0]
+
+    pool = ReplicaPool(build_replica_engines(factory, n), policy=policy,
+                       seed=seed)
     return pool, built[0][1]
 
 
@@ -244,6 +253,13 @@ class TestPoolSmoke:
         assert not pool.state.sequences
         assert all(r.engine.free_blocks == r.engine.config.num_blocks
                    for r in pool.replicas())
+        # AFTER serving from the pool's worker threads each replica's
+        # weights and KV pool still sit on the device it was built on
+        # (uncommitted engine state used to drift to device 0)
+        for i, r in enumerate(pool.replicas()):
+            for tree in (r.engine.params, r.engine._kv_data):
+                assert {d.id for leaf in jax.tree_util.tree_leaves(tree)
+                        for d in leaf.devices()} == {jax.devices()[i].id}
         # fleet rollup: merged admitted counter covers every request,
         # gauges carry stable per-replica source labels
         snap = pool.fleet_snapshot()

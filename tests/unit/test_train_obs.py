@@ -111,8 +111,7 @@ class TestAttributionClosure:
         with tw:
             for b in bs[3:]:
                 eng.train_batch(b)
-        if tw.available:
-            assert tw.fresh_compiles == 0
+        assert tw.fresh_compiles == 0
 
 
 class TestObserverParity:

@@ -2,9 +2,8 @@
 
 Every round leaves a ``BENCH_*.json`` behind; until now they only
 *accumulated*. This tool diffs any two rounds per phase metric with
-tolerance bands and **exits non-zero on a regression**, so the round
-scripts (``tools/tpu_round17.sh`` onward) gate on the trajectory
-instead of hoping someone reads it.
+tolerance bands and **exits non-zero on a regression**, so a capture
+script can gate on the trajectory instead of hoping someone reads it.
 
 What counts as comparable: every numeric leaf under each phase of the
 round's ``detail`` dict (the orchestrator shape), or of the row itself
@@ -103,8 +102,8 @@ DIRECTIONS: Tuple[Tuple[str, str], ...] = (
     ("*drain_s*", "lower"),
     ("*dispatches_per_token*", "lower"),
     ("*fresh_compiles*", "lower"),
-    # repo lint capture (tools/tpu_round22.sh writes bin/dstpu_lint
-    # --json's count): any finding is a regression, zero slack below
+    # repo lint capture (bin/dstpu_lint --json's count): any finding is
+    # a regression, zero slack below
     ("*lint_findings*", "lower"),
     ("*_p99*", "lower"),
     ("*_p90*", "lower"),

@@ -16,9 +16,9 @@ cross-module analyses — consumes the same cached trees.
          ``donate_argnames`` under ``deepspeed_tpu/inference/v2/``
          (serving pools are large; an undonated jit silently doubles
          peak HBM). Suppress per-site with a justification.
-  DSL003 raw-shard-map-import direct ``jax.experimental.shard_map``
-         import anywhere but ``utils/jax_compat.py`` (the one place the
-         legacy/modern API translation lives).
+  DSL003 raw-shard-map-import ``jax.experimental.shard_map`` imported
+         anywhere: the tree targets ``jax.shard_map`` (through
+         ``utils/jax_compat.shard_map``) and carries no legacy spelling.
   DSL004 undocumented-knob    a ``DSTPU_*`` env knob read in code but
          absent from docs/CONFIG.md's generated knob table.
   DSL005 stale-knob-doc       a knob documented in docs/CONFIG.md that
@@ -75,8 +75,8 @@ RULES: Mapping[str, str] = {
     "DSL001": "blocking host sync inside a registered hot-path function",
     "DSL002": "jax.jit without donate_argnums/donate_argnames in "
               "inference/v2 (justify with # dslint: allow(DSL002): why)",
-    "DSL003": "direct jax.experimental.shard_map import outside "
-              "utils/jax_compat.py",
+    "DSL003": "jax.experimental.shard_map import (use "
+              "utils/jax_compat.shard_map, i.e. jax.shard_map)",
     "DSL004": "DSTPU_* env knob read in code but not documented in "
               "docs/CONFIG.md (re-run tools/gen_config_doc.py)",
     "DSL005": "DSTPU_* knob documented in docs/CONFIG.md but read "

@@ -251,27 +251,26 @@ def file_findings(fi: FileIndex,
                         "with # dslint: allow(DSL002): why)"),
                         _node_lines(node)))
 
-    # DSL003 — raw shard_map imports
-    if not relpath.endswith("utils/jax_compat.py"):
-        for node in ast.walk(fi.tree):
-            hit = None
-            if isinstance(node, ast.Import):
-                if any(a.name.startswith("jax.experimental.shard_map")
-                       for a in node.names):
-                    hit = "import jax.experimental.shard_map"
-            elif isinstance(node, ast.ImportFrom) and node.level == 0:
-                if node.module \
-                        and node.module.startswith(
-                            "jax.experimental.shard_map"):
-                    hit = f"from {node.module} import ..."
-                elif node.module == "jax.experimental" \
-                        and any(a.name == "shard_map" for a in node.names):
-                    hit = "from jax.experimental import shard_map"
-            if hit:
-                raw.append((Finding(
-                    "DSL003", relpath, node.lineno,
-                    f"{hit} bypasses utils/jax_compat (the one place the "
-                    f"legacy/modern shard_map translation lives)"),
-                    _node_lines(node)))
+    # DSL003 — the legacy jax.experimental.shard_map, anywhere
+    for node in ast.walk(fi.tree):
+        hit = None
+        if isinstance(node, ast.Import):
+            if any(a.name.startswith("jax.experimental.shard_map")
+                   for a in node.names):
+                hit = "import jax.experimental.shard_map"
+        elif isinstance(node, ast.ImportFrom) and node.level == 0:
+            if node.module \
+                    and node.module.startswith(
+                        "jax.experimental.shard_map"):
+                hit = f"from {node.module} import ..."
+            elif node.module == "jax.experimental" \
+                    and any(a.name == "shard_map" for a in node.names):
+                hit = "from jax.experimental import shard_map"
+        if hit:
+            raw.append((Finding(
+                "DSL003", relpath, node.lineno,
+                f"{hit}: the tree targets jax.shard_map (take shard_map "
+                f"from utils/jax_compat)"),
+                _node_lines(node)))
 
     return [f for f, lines in raw if not fi.suppressed(lines, f.rule)]

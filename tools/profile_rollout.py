@@ -25,6 +25,8 @@ def main():
 
     import deepspeed_tpu as dstpu
     from deepspeed_tpu.models.gpt2 import GPT2Config, make_model
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     gen = int(os.environ.get("DSTPU_ROLLOUT_GEN", "256"))
     cfg = GPT2Config(

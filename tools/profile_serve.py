@@ -1,8 +1,10 @@
 """Capture + summarize a device trace of the fused decode loop.
 
 Usage:
-    python tools/profile_serve.py capture   # runs on the TPU (exclusive!)
+    python tools/profile_serve.py capture   # on the TPU, one process per chip
     python tools/profile_serve.py report    # parses the newest trace
+
+The trace lands in chiprun_out/profile_serve_trace/.
 """
 
 import collections
@@ -13,7 +15,7 @@ import os
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TDIR = os.path.join(REPO, "profiles", "serve_trace")
+TDIR = os.path.join(REPO, "chiprun_out", "profile_serve_trace")
 
 
 def capture():
@@ -25,6 +27,8 @@ def capture():
     from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                             RaggedInferenceConfig)
     from deepspeed_tpu.models.llama import Llama, LlamaConfig
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     mcfg = LlamaConfig(vocab_size=32000, max_seq_len=2048, num_layers=22,
                        num_heads=32, num_kv_heads=4, hidden_size=2048,
@@ -40,8 +44,7 @@ def capture():
                                 num_blocks=S + 4, max_blocks_per_seq=1,
                                 decode_loop_steps=NL, dtype="bfloat16",
                                 attention_impl="paged_flash",
-                                # uncapped: keep the measured r4 single-
-                                # forward-prefill configuration comparable
+                                # uncapped: single-forward prefill
                                 prefill_chunk_cap=int(os.environ.get(
                                     "DSTPU_PROF_CHUNK_CAP", "0")),
                                 kv_cache_dtype=os.environ.get(
@@ -58,8 +61,7 @@ def capture():
     os.makedirs(TDIR, exist_ok=True)
     import jax.profiler
     jax.profiler.start_trace(TDIR)
-    outs = eng.decode_greedy(uids, last, NL)
-    float(jnp.asarray(outs[0][-1]))
+    eng.decode_greedy(uids, last, NL)     # returns after the readback
     jax.profiler.stop_trace()
     print("trace captured")
 

@@ -1,578 +1,572 @@
-"""On-chip Pallas kernel compile/parity smoke test.
+"""On-chip kernel-by-kernel compile and parity sweep.
 
-Runs every Pallas kernel COMPILED on the real TPU (not interpret mode) and
-checks parity against the jnp references — the evidence VERDICT r1 asked for
-that Mosaic lowering succeeds on hardware (tiling errors only surface when
-lowering for a real chip; the CPU test mesh runs interpret mode). Appends a
-result line per kernel; run as `python tools/tpu_smoke.py` on a TPU host.
+Every ``pallas_call`` family in ``deepspeed_tpu/ops/kernels`` is compiled by
+Mosaic (``interpret=False``) on the TPU and compared with its ``jax.numpy``
+reference; then the engine-level paths that read the donated KV pool
+(pipelined decode, prefix cache, host tier, speculation, attribution) are
+checked for token parity on the chip. A row that raises is reported with
+the compiler's message and the sweep goes on, so one refusal does not hide
+the others; the exit code is non-zero unless every row is OK.
+
+    python tools/tpu_smoke.py          # on a TPU host; prints one row each
+    python tools/tpu_smoke.py tp_ kv   # only rows whose name holds a word
+
+``chip_smoke.py`` is the pass/fail proof of the two main paths; this is the
+wider table behind it (``CHANGES.md`` records the last run).
 """
 
+from __future__ import annotations
+
+import dataclasses
+import os
 import sys
+import time
+from typing import Any, Callable, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-sys.path.insert(0, ".")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def check(name, got, want, atol=3e-2):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    err = float(np.max(np.abs(got - want)))
-    ok = err < atol
-    print(f"{'OK ' if ok else 'FAIL'} {name}: max_err={err:.2e}", flush=True)
-    return ok
+@dataclasses.dataclass
+class KernelCase:
+    """One kernel family at one shape: ``fn(*args)`` runs the compiled
+    kernel, ``want(*args)`` its reference; both return an array pytree."""
+
+    name: str
+    fn: Callable
+    args: Tuple[Any, ...]
+    want: Callable
+    atol: float = 3e-2
 
 
-def main():
-    assert jax.default_backend() == "tpu", "run on a TPU host"
-    from deepspeed_tpu.ops.kernels import (flash_attention,
-                                           flash_attention_sparse,
-                                           flash_paged_attention,
-                                           fused_layer_norm, fused_rms_norm)
+def _keys(seed: int, n: int):
+    return jax.random.split(jax.random.PRNGKey(seed), n)
+
+
+def kernel_cases() -> List[KernelCase]:
+    from deepspeed_tpu.inference.v2.kv_quant import quantize_rows
+    from deepspeed_tpu.models._lm_utils import chunked_lm_xent
+    from deepspeed_tpu.ops.evoformer_attn import DS4Sci_EvoformerAttention
+    from deepspeed_tpu.ops.kernels import (
+        flash_attention, flash_attention_sparse, flash_paged_attention,
+        fp6_gemm_pack, fp6_gemm_unpack, fp6_matmul, fused_adamw_update,
+        fused_layer_norm, fused_lm_xent, fused_rms_norm, quantize_blockwise)
     from deepspeed_tpu.ops.kernels.flash_attention import attention_reference
+    from deepspeed_tpu.ops.kernels.fused_optimizer import adamw_reference
+    from deepspeed_tpu.ops.kernels.quantization import dequantize_blockwise
 
-    ok = True
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    # flash fwd+bwd, bf16, multi-block
+    cases: List[KernelCase] = []
+    f32 = jnp.float32
+    ks = _keys(0, 3)
+
+    # flash attention, bf16, multi-block: forward, backward, GQA
     q, k, v = (jax.random.normal(x, (2, 1024, 8, 64), jnp.bfloat16)
                for x in ks)
-    o = jax.jit(lambda a, b, c: flash_attention(a, b, c, causal=True,
-                                                interpret=False))(q, k, v)
-    ok &= check("flash_fwd_bf16", o, attention_reference(q, k, v, causal=True))
-    g = jax.jit(jax.grad(lambda a: jnp.sum(
-        flash_attention(a, k, v, causal=True, interpret=False)
-        .astype(jnp.float32))))(q)
-    gr = jax.grad(lambda a: jnp.sum(
-        attention_reference(a, k, v, causal=True).astype(jnp.float32)))(q)
-    ok &= check("flash_bwd_bf16", g, gr, atol=8e-2)
 
-    # GQA
-    kg, vg = k[:, :, :2], v[:, :, :2]
-    o = jax.jit(lambda a, b, c: flash_attention(a, b, c, causal=True,
-                                                interpret=False))(q, kg, vg)
-    ok &= check("flash_gqa", o, attention_reference(q, kg, vg, causal=True))
+    def flash(a, b, c, **kw):
+        return flash_attention(a, b, c, causal=True, interpret=False, **kw)
 
-    # paged decode kernel: 4 seqs, bs=64, mixed lengths, C=4 chunk
+    def ref_attn(a, b, c, **_):
+        return attention_reference(a, b, c, causal=True)
+
+    def grad_q(attn, **kw):
+        return lambda a, b, c: jax.grad(
+            lambda x: jnp.sum(attn(x, b, c, **kw).astype(f32)))(a)
+
+    cases += [
+        KernelCase("flash_fwd_bf16", flash, (q, k, v), ref_attn),
+        KernelCase("flash_bwd_bf16", grad_q(flash), (q, k, v),
+                   grad_q(ref_attn), atol=8e-2),
+        KernelCase("flash_gqa", flash, (q, k[:, :, :2], v[:, :, :2]),
+                   ref_attn),
+    ]
+    # the 1.3B training shape: head_dim 128, seq 2048, 1024x1024 tiles
+    qb, kb, vb = (jax.random.normal(x, (1, 2048, 4, 128), jnp.bfloat16)
+                  for x in ks)
+    big = dict(block_q=1024, block_k=1024)
+    cases += [
+        KernelCase("flash_fwd_1024tile", lambda a, b, c: flash(a, b, c, **big),
+                   (qb, kb, vb), ref_attn),
+        KernelCase("flash_bwd_1024tile", grad_q(flash, **big), (qb, kb, vb),
+                   grad_q(ref_attn), atol=8e-2),
+    ]
+
+    # paged attention over a multi-block bf16 pool (BlockSpec path)
     bs, nb, KV, D, H, C, S = 64, 32, 4, 64, 8, 4, 4
     pool_k = jax.random.normal(ks[0], ((nb + 1) * bs, KV, D), jnp.bfloat16)
     pool_v = jax.random.normal(ks[1], ((nb + 1) * bs, KV, D), jnp.bfloat16)
-    tables = jnp.asarray(
-        np.random.RandomState(0).permutation(nb)[:S * 8].reshape(S, 8),
-        jnp.int32)
+    tables = jnp.asarray(np.random.RandomState(0).permutation(nb)[:S * 8]
+                         .reshape(S, 8), jnp.int32)
     start = jnp.asarray([0, 37, 130, 400], jnp.int32)
-    lens = start + C
     qd = jax.random.normal(ks[2], (S, C, H, D), jnp.bfloat16)
-    od = jax.jit(lambda a: flash_paged_attention(
-        a, pool_k, pool_v, tables, start, lens, block_size=bs,
-        interpret=False))(qd)
-    oi = flash_paged_attention(qd, pool_k, pool_v, tables, start, lens,
-                               block_size=bs, interpret=True)
-    ok &= check("paged_decode", od, oi)
 
-    # sliding window variant
-    od = jax.jit(lambda a: flash_paged_attention(
-        a, pool_k, pool_v, tables, start, lens, block_size=bs,
-        sliding_window=128, interpret=False))(qd)
-    oi = flash_paged_attention(qd, pool_k, pool_v, tables, start, lens,
-                               block_size=bs, sliding_window=128,
-                               interpret=True)
-    ok &= check("paged_decode_window", od, oi)
+    def paged(interpret, **kw):
+        return lambda a: flash_paged_attention(
+            a, pool_k, pool_v, tables, start, start + C, block_size=bs,
+            interpret=interpret, **kw)
 
-    # block-sparse (block-GRANULAR semantics: an allowed block attends whole,
-    # there is no intra-block causal mask — match the layout, not tril)
-    bm = np.tril(np.ones((8, 8), np.int32))[None].repeat(8, 0)
-    o = jax.jit(lambda a, b, c: flash_attention_sparse(
-        a, b, c, bm, block_q=128, block_k=128, interpret=False))(q, k, v)
-    qb, kb, vb = (jnp.swapaxes(x, 1, 2).astype(jnp.float32)
-                  for x in (q, k, v))
-    s = jnp.einsum("bhqd,bhkd->bhqk", qb, kb) / np.sqrt(64)
-    blk_mask = jnp.repeat(jnp.repeat(jnp.asarray(bm, bool), 128, 1), 128, 2)
-    s = jnp.where(blk_mask[None], s, -jnp.inf)
-    ref_sp = jnp.swapaxes(
-        jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vb), 1, 2)
-    ok &= check("flash_sparse", o, ref_sp, atol=8e-2)
+    cases += [
+        KernelCase("paged_decode", paged(False), (qd,), paged(True)),
+        KernelCase("paged_decode_window", paged(False, sliding_window=128),
+                   (qd,), paged(True, sliding_window=128)),
+    ]
 
-    # norms
-    x = jax.random.normal(ks[0], (256, 1024), jnp.bfloat16)
-    gamma = jnp.ones((1024,), jnp.float32)
-    beta = jnp.zeros((1024,), jnp.float32)
-    xf = x.astype(jnp.float32)
-    ref_ln = (xf - xf.mean(-1, keepdims=True)) / jnp.sqrt(
-        xf.var(-1, keepdims=True) + 1e-5)
-    ok &= check("fused_layer_norm",
-                jax.jit(lambda a: fused_layer_norm(a, gamma, beta,
-                                                   interpret=False))(x),
-                ref_ln)
-    ref_rms = xf * jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + 1e-6)
-    ok &= check("fused_rms_norm",
-                jax.jit(lambda a: fused_rms_norm(a, gamma,
-                                                 interpret=False))(x),
-                ref_rms)
-
-    # int8 paged decode (grouped path: linear layout, 128-aligned blocks)
-    from deepspeed_tpu.inference.v2.kv_quant import quantize_rows
+    # linear layout (one block per sequence), C=1: the grouped manual-DMA
+    # kernel at 128-aligned rows, int8 and bf16; the BlockSpec path at the
+    # 64-wide rows one kv head per chip leaves under tp=4
     S8, H8, KV8, D8, bs8 = 8, 8, 4, 128, 256
-    KVD8 = KV8 * D8
     slots8 = (S8 + 1) * bs8
-    kf = jax.random.normal(ks[0], (slots8, KVD8), jnp.float32)
-    vf = jax.random.normal(ks[1], (slots8, KVD8), jnp.float32)
+    kf = jax.random.normal(ks[0], (slots8, KV8 * D8), f32)
+    vf = jax.random.normal(ks[1], (slots8, KV8 * D8), f32)
     qk8, sk8 = quantize_rows(kf, KV8)
     qv8, sv8 = quantize_rows(vf, KV8)
     t8 = jnp.arange(S8, dtype=jnp.int32)[:, None]
     l8 = jnp.asarray([256, 100, 17, 256, 64, 0, 128, 200], jnp.int32)
     q8 = jax.random.normal(ks[2], (S8, 1, H8, D8), jnp.bfloat16)
-    o8 = jax.jit(lambda a: flash_paged_attention(
-        a, qk8, qv8, t8, l8, l8, block_size=bs8, num_kv_heads=KV8,
-        k_scales=sk8, v_scales=sv8, interpret=False))(q8)
-    ofp = flash_paged_attention(
-        q8, kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16), t8, l8, l8,
-        block_size=bs8, num_kv_heads=KV8, interpret=True)
-    ok &= check("paged_decode_int8", o8, ofp, atol=6e-2)
+    kbf, vbf = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16)
 
-    # int8 prefill path (BlockSpec, multi-block): same pool viewed as
-    # 2x blocks of half size (+1 trash block of the halved size)
+    def linear_ref(a):
+        return flash_paged_attention(a, kbf, vbf, t8, l8, l8, block_size=bs8,
+                                     num_kv_heads=KV8, interpret=True)
+
+    cases += [
+        KernelCase("paged_decode_grouped_bf16", lambda a: flash_paged_attention(
+            a, kbf, vbf, t8, l8, l8, block_size=bs8, num_kv_heads=KV8,
+            interpret=False), (q8,), linear_ref),
+        KernelCase("paged_decode_grouped_int8", lambda a: flash_paged_attention(
+            a, qk8, qv8, t8, l8, l8, block_size=bs8, num_kv_heads=KV8,
+            k_scales=sk8, v_scales=sv8, interpret=False), (q8,), linear_ref,
+            atol=6e-2),
+    ]
+    kn, vn = kbf[:, :64], vbf[:, :64]
+    qn = jax.random.normal(ks[2], (S8, 1, 8, 64), jnp.bfloat16)
+
+    def narrow(interpret):
+        return lambda a: flash_paged_attention(
+            a, kn, vn, t8, l8, l8, block_size=bs8, num_kv_heads=1,
+            interpret=interpret)
+
+    cases.append(KernelCase("paged_decode_linear_64wide", narrow(False),
+                            (qn,), narrow(True)))
+    # int8 prefill (BlockSpec, multi-block): the same pool viewed as twice
+    # the blocks of half the size
     slots_p = (S8 * 2 + 1) * (bs8 // 2)
     qp = jax.random.normal(ks[2], (S8, 8, H8, D8), jnp.bfloat16)
     tb = jnp.asarray(np.random.RandomState(1).permutation(S8 * 2)
                      .reshape(S8, 2), jnp.int32)
     st = jnp.maximum(l8 - 8, 0)
-    o8p = jax.jit(lambda a: flash_paged_attention(
-        a, qk8[:slots_p], qv8[:slots_p],
-        tb, st, l8, block_size=bs8 // 2, num_kv_heads=KV8,
-        k_scales=sk8[:, :slots_p], v_scales=sv8[:, :slots_p],
-        interpret=False))(qp)
-    ofpp = flash_paged_attention(
-        qp, kf.astype(jnp.bfloat16)[:slots_p],
-        vf.astype(jnp.bfloat16)[:slots_p],
-        tb, st, l8, block_size=bs8 // 2, num_kv_heads=KV8, interpret=True)
-    ok &= check("paged_prefill_int8", o8p, ofpp, atol=6e-2)
+    cases.append(KernelCase(
+        "paged_prefill_int8", lambda a: flash_paged_attention(
+            a, qk8[:slots_p], qv8[:slots_p], tb, st, l8,
+            block_size=bs8 // 2, num_kv_heads=KV8,
+            k_scales=sk8[:, :slots_p], v_scales=sv8[:, :slots_p],
+            interpret=False), (qp,),
+        lambda a: flash_paged_attention(
+            a, kbf[:slots_p], vbf[:slots_p], tb, st, l8,
+            block_size=bs8 // 2, num_kv_heads=KV8, interpret=True),
+        atol=6e-2))
 
-    # streaming fused LM-head xent: loss + grads vs the chunked reference.
-    # N = 1536 tokens at C = 512 -> Tb = 512, THREE token tiles: the
-    # multi-tile grid is what exercises the [N, 1] scalar-operand layout
-    # (a single-tile shape compiles even under layouts that fail at Nt>1)
-    from deepspeed_tpu.models._lm_utils import chunked_lm_xent
-    from deepspeed_tpu.ops.kernels import fused_lm_xent
+    # block-sparse flash (block-granular: an allowed block attends whole)
+    bm = np.tril(np.ones((8, 8), np.int32))[None].repeat(8, 0)
+
+    def sparse_ref(a, b, c):
+        a, b, c = (jnp.swapaxes(x, 1, 2).astype(f32) for x in (a, b, c))
+        s = jnp.einsum("bhqd,bhkd->bhqk", a, b) / np.sqrt(64)
+        allowed = jnp.repeat(jnp.repeat(jnp.asarray(bm, bool), 128, 1),
+                             128, 2)
+        s = jnp.where(allowed[None], s, -jnp.inf)
+        return jnp.swapaxes(
+            jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), c), 1, 2)
+
+    cases.append(KernelCase(
+        "flash_sparse", lambda a, b, c: flash_attention_sparse(
+            a, b, c, bm, block_q=128, block_k=128, interpret=False),
+        (q, k, v), sparse_ref, atol=8e-2))
+
+    # fused norms
+    x = jax.random.normal(ks[0], (256, 1024), jnp.bfloat16)
+    gamma, beta = jnp.ones((1024,), f32), jnp.zeros((1024,), f32)
+
+    def ln_ref(a):
+        a = a.astype(f32)
+        return (a - a.mean(-1, keepdims=True)) / jnp.sqrt(
+            a.var(-1, keepdims=True) + 1e-5)
+
+    def rms_ref(a):
+        a = a.astype(f32)
+        return a * jax.lax.rsqrt((a * a).mean(-1, keepdims=True) + 1e-6)
+
+    cases += [
+        KernelCase("fused_layer_norm", lambda a: fused_layer_norm(
+            a, gamma, beta, interpret=False), (x,), ln_ref),
+        KernelCase("fused_rms_norm", lambda a: fused_rms_norm(
+            a, gamma, interpret=False), (x,), rms_ref),
+    ]
+
+    # streaming fused LM-head xent, several token AND vocab tiles: loss and
+    # both gradients against the chunked reference
     hx = jax.random.normal(ks[0], (4, 384, 512), jnp.bfloat16) * 0.5
     ex = jax.random.normal(ks[1], (4000, 512), jnp.bfloat16) * 0.2
     tx = jax.random.randint(ks[2], (4, 384), 0, 4000)
-    lf = jax.jit(lambda a, b: fused_lm_xent(a, b, tx, interpret=False))
-    lr = float(chunked_lm_xent(hx, ex, tx, num_chunks=4))
-    ok &= check("fused_xent_fwd", lf(hx, ex), lr, atol=2e-2)
-    gf = jax.jit(jax.grad(lambda a, b: fused_lm_xent(
-        a, b, tx, interpret=False), argnums=(0, 1)))(hx, ex)
-    gr2 = jax.grad(lambda a, b: chunked_lm_xent(
-        a, b, tx, 4), argnums=(0, 1))(hx, ex)
-    ok &= check("fused_xent_dh", gf[0].astype(jnp.float32),
-                gr2[0].astype(jnp.float32), atol=2e-3)
-    ok &= check("fused_xent_dE", gf[1].astype(jnp.float32),
-                gr2[1].astype(jnp.float32), atol=2e-3)
+    cases += [
+        KernelCase("fused_xent_multitile_fwd", lambda a, b: fused_lm_xent(
+            a, b, tx, interpret=False), (hx, ex),
+            lambda a, b: chunked_lm_xent(a, b, tx, num_chunks=4), atol=2e-2),
+        KernelCase("fused_xent_multitile_grads", jax.grad(
+            lambda a, b: fused_lm_xent(a, b, tx, interpret=False),
+            argnums=(0, 1)), (hx, ex), jax.grad(
+            lambda a, b: chunked_lm_xent(a, b, tx, 4), argnums=(0, 1)),
+            atol=2e-3),
+    ]
 
-    # evoformer flash (ops/kernels/evoformer.py): fused bias-added
-    # attention vs the chunked jnp path, canonical mask + pair biases
-    from deepspeed_tpu.ops.evoformer_attn import DS4Sci_EvoformerAttention
+    # evoformer flash: fused bias-added attention vs the chunked jnp path
     Be, Ne, Se, He, De = 1, 4, 256, 4, 64
-    kse = jax.random.split(jax.random.PRNGKey(7), 5)
-    qe = jax.random.normal(kse[0], (Be, Ne, Se, He, De), jnp.bfloat16)
-    ke = jax.random.normal(kse[1], (Be, Ne, Se, He, De), jnp.bfloat16)
-    ve = jax.random.normal(kse[2], (Be, Ne, Se, He, De), jnp.bfloat16)
+    kse = _keys(7, 5)
+    qe, ke, ve = (jax.random.normal(kse[i], (Be, Ne, Se, He, De),
+                                    jnp.bfloat16) for i in range(3))
     mbe = jnp.where(jax.random.uniform(kse[3], (Be, Ne, 1, 1, Se)) < 0.2,
                     -1e9, 0.0)
-    pbe = jax.random.normal(kse[4], (Be, 1, He, Se, Se), jnp.float32)
-    oe = jax.jit(lambda a, b, c: DS4Sci_EvoformerAttention(
-        a, b, c, [mbe, pbe], use_kernel=True))(qe, ke, ve)
-    oer = DS4Sci_EvoformerAttention(qe, ke, ve, [mbe, pbe],
-                                    use_kernel=False)
-    ok &= check("evoformer_flash", oe, oer, atol=4e-2)
+    pbe = jax.random.normal(kse[4], (Be, 1, He, Se, Se), f32)
+    cases.append(KernelCase(
+        "evoformer_flash", lambda a, b, c: DS4Sci_EvoformerAttention(
+            a, b, c, [mbe, pbe], use_kernel=True), (qe, ke, ve),
+        lambda a, b, c: DS4Sci_EvoformerAttention(
+            a, b, c, [mbe, pbe], use_kernel=False), atol=4e-2))
 
-    # fused FP6 weight-only GEMM (ops/kernels/fp6_gemm.py)
-    from deepspeed_tpu.ops.kernels import (fp6_gemm_pack, fp6_gemm_unpack,
-                                           fp6_matmul)
-    w6 = jax.random.normal(jax.random.PRNGKey(8), (512, 2048),
-                           jnp.float32) * 0.1
-    fw6 = fp6_gemm_pack(w6)
+    # fused FP6 weight-only GEMM
+    fw6 = fp6_gemm_pack(jax.random.normal(jax.random.PRNGKey(8),
+                                          (512, 2048), f32) * 0.1)
     x6 = jax.random.normal(jax.random.PRNGKey(9), (64, 512), jnp.bfloat16)
-    o6 = jax.jit(lambda a: fp6_matmul(a, fw6, interpret=False))(x6)
-    o6r = x6.astype(jnp.float32) @ fp6_gemm_unpack(fw6)
-    ok &= check("fp6_gemm", o6, o6r, atol=6e-2)
+    cases.append(KernelCase(
+        "fp6_gemm", lambda a: fp6_matmul(a, fw6, interpret=False), (x6,),
+        lambda a: a.astype(f32) @ fp6_gemm_unpack(fw6), atol=6e-2))
 
-    # TP paged decode (ISSUE 2): the head-sharded ragged engine — fused
-    # decode loop + paged-flash kernel COMPILED inside the model-axis
-    # shard_map — must be token-identical to single-chip, on chip. First
-    # TPU contact evidence that Mosaic lowering composes with manual
-    # sharding; tools/tpu_round6.sh captures tok/s at tp=4 via
-    # DSTPU_BENCH_TP=4 bench rows.
-    import time as _time
+    # fused AdamW over flat f32 buffers
+    ka = _keys(11, 4)
+    n = 8 * 128 * 37
+    p0, g0 = (jax.random.normal(ka[i], (n,), f32) for i in range(2))
+    m0 = jax.random.normal(ka[2], (n,), f32) * 0.1
+    v0 = jnp.abs(jax.random.normal(ka[3], (n,), f32)) * 0.01
+    hyp = dict(lr=1e-3, weight_decay=0.01)
+    cases.append(KernelCase(
+        "fused_adamw", lambda *a: fused_adamw_update(
+            *a, jnp.int32(3), interpret=False, **hyp), (p0, g0, m0, v0),
+        lambda *a: adamw_reference(*a, jnp.int32(3), **hyp), atol=1e-5))
 
+    # block quantization: every value must come back within half a
+    # quantization step of its group (int4 goes through the nibble packing)
+    xq = jax.random.normal(ks[1], (512, 1024), f32)
+
+    def step_err(**kw):
+        def fn(a):
+            qt = quantize_blockwise(a, interpret=False, **kw)
+            err = jnp.abs(dequantize_blockwise(qt) - a)
+            return err.reshape(-1, qt.group_size) / qt.scale
+        return fn
+
+    for name, kw in (("int8_sym", dict(bits=8)),
+                     ("int8_asym", dict(bits=8, symmetric=False)),
+                     ("int4_sym", dict(bits=4))):
+        cases.append(KernelCase(
+            f"quantize_blockwise_{name}", step_err(**kw), (xq,),
+            lambda a: jnp.zeros((a.size // 256, 256), f32), atol=0.501))
+    return cases
+
+
+def run_kernel_case(case: KernelCase) -> Tuple[bool, str]:
+    got = jax.jit(case.fn)(*case.args)
+    want = case.want(*case.args)
+    err = max(float(np.max(np.abs(np.asarray(g, np.float32)
+                                  - np.asarray(w, np.float32))))
+              for g, w in zip(jax.tree_util.tree_leaves(got),
+                              jax.tree_util.tree_leaves(want)))
+    return err < case.atol, f"max_err={err:.2e}"
+
+
+# ---------------------------------------------------------------------- #
+# engine-level rows: the readers of the donated KV pool, on the chip
+# ---------------------------------------------------------------------- #
+
+
+def _gpt2(seed: int):
+    from deepspeed_tpu.models.gpt2 import GPT2, GPT2Config
+    mcfg = GPT2Config(vocab_size=512, max_seq_len=512, num_layers=2,
+                      num_heads=8, hidden_size=512, dtype=jnp.bfloat16)
+    params = GPT2(mcfg).init(jax.random.PRNGKey(seed),
+                             jnp.zeros((1, 8), jnp.int32))["params"]
+    return mcfg, params
+
+
+def _engine(mcfg, params, **kw):
     from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                             RaggedInferenceConfig)
-    from deepspeed_tpu.models.gpt2 import GPT2, GPT2Config
-    n_dev = len(jax.devices())
-    tp = 4 if n_dev >= 4 else (2 if n_dev >= 2 else 1)
-    if tp > 1:
-        mcfg_tp = GPT2Config(vocab_size=512, max_seq_len=512, num_layers=2,
-                             num_heads=8, hidden_size=512,
-                             dtype=jnp.bfloat16)
-        model_tp = GPT2(mcfg_tp)
-        params_tp = model_tp.init(jax.random.PRNGKey(3),
-                                  jnp.zeros((1, 8), jnp.int32))["params"]
-        base_tp = dict(max_seqs=4, chunk_size=32, block_size=128,
-                       num_blocks=8, max_blocks_per_seq=2,
-                       dtype="bfloat16", attention_impl="paged_flash",
-                       decode_loop_steps=8)
-        rng_tp = np.random.RandomState(5)
-        prompts_tp = [rng_tp.randint(1, 512, size=17).tolist()
-                      for _ in range(4)]
-        ref_tp = InferenceEngineV2(
-            mcfg_tp, params_tp, RaggedInferenceConfig(**base_tp)).generate(
-                prompts_tp, max_new_tokens=16)
-        eng_tp = InferenceEngineV2(
-            mcfg_tp, params_tp,
-            RaggedInferenceConfig(**base_tp, tp_size=tp))
-        t0 = _time.perf_counter()
-        got_tp = eng_tp.generate(prompts_tp, max_new_tokens=16)
-        dt = _time.perf_counter() - t0
-        parity = got_tp == ref_tp
-        rep = eng_tp.state.kv_memory_report()
-        kv_ok = rep["kv_pool_bytes_per_chip"] * tp \
-            == rep["kv_pool_bytes_total"]
-        ok &= parity and kv_ok
-        print(f"{'OK ' if parity and kv_ok else 'FAIL'} tp_paged_decode: "
-              f"tp={tp} token_parity={parity} kv_per_chip_1/tp={kv_ok} "
-              f"({4 * 16 / dt:.0f} tok/s incl. compile)", flush=True)
-    else:
-        print("SKIP tp_paged_decode (single chip)", flush=True)
+    base = dict(max_seqs=4, chunk_size=32, block_size=128, num_blocks=8,
+                max_blocks_per_seq=2, dtype="bfloat16",
+                attention_impl="paged_flash", decode_loop_steps=0)
+    base.update(kw)
+    return InferenceEngineV2(mcfg, params, RaggedInferenceConfig(**base))
 
-    # async parity (ISSUE 3): the overlapped serving pipeline — depth-2
-    # plan/dispatch/commit with device token feedback (step_greedy_fb
-    # COMPILED on chip, KV-pool donation active on TPU) — must be
-    # token-identical to the synchronous depth-0 oracle, on chip.
-    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                            RaggedInferenceConfig)
-    from deepspeed_tpu.models.gpt2 import GPT2, GPT2Config
-    mcfg_a = GPT2Config(vocab_size=512, max_seq_len=512, num_layers=2,
-                        num_heads=8, hidden_size=512, dtype=jnp.bfloat16)
-    params_a = GPT2(mcfg_a).init(jax.random.PRNGKey(11),
-                                 jnp.zeros((1, 8), jnp.int32))["params"]
-    base_a = dict(max_seqs=4, chunk_size=32, block_size=128, num_blocks=8,
-                  max_blocks_per_seq=2, dtype="bfloat16",
-                  attention_impl="paged_flash", decode_loop_steps=0)
-    rng_a = np.random.RandomState(13)   # one RNG: DISTINCT prompts per
-    prompts_a = [rng_a.randint(1, 512, size=17).tolist()  # slot, so a
-                 for _ in range(4)]     # feed_idx permutation bug cannot
-                                        # hide behind identical sequences
-    ref_a = InferenceEngineV2(
-        mcfg_a, params_a,
-        RaggedInferenceConfig(**base_a, serve_pipeline_depth=0)).generate(
-            prompts_a, max_new_tokens=16)
-    eng_a = InferenceEngineV2(
-        mcfg_a, params_a,
-        RaggedInferenceConfig(**base_a, serve_pipeline_depth=2))
-    got_a = eng_a.generate(prompts_a, max_new_tokens=16)
-    par_a = got_a == ref_a
-    fed_a = eng_a.pipeline_stats["fed_steps"]
-    ok &= par_a and fed_a > 0
-    print(f"{'OK ' if par_a and fed_a > 0 else 'FAIL'} async_parity: "
-          f"depth2 token_parity={par_a} device_fed_steps={fed_a}",
-          flush=True)
 
-    # program audit (ISSUE 4): the structural claims verified ON CHIP.
-    # Donation is only real where the backend implements it
-    # (jax.default_backend() == "tpu" gates the step programs' donate),
-    # so the buffer-donor check here is the hardware evidence the CPU
-    # tier-1 mesh cannot give; collective budgets re-checked with the
-    # Pallas kernels compiled for real Mosaic lowering.
+def _prompts(seed: int, n: int, length: int):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(1, 512, size=length).tolist() for _ in range(n)]
+
+
+def _tp_degree() -> int:
+    n = len(jax.devices())
+    return 4 if n >= 4 else (2 if n >= 2 else 1)
+
+
+def row_tp_paged_decode():
+    """Fused decode loop + paged-flash kernel inside the model-axis
+    shard_map: token parity with one chip, pool bytes per chip = 1/tp."""
+    tp = _tp_degree()
+    if tp == 1:
+        return None, "single chip"
+    mcfg, params = _gpt2(3)
+    prompts = _prompts(5, 4, 17)
+    ref = _engine(mcfg, params, decode_loop_steps=8).generate(
+        prompts, max_new_tokens=16)
+    eng = _engine(mcfg, params, decode_loop_steps=8, tp_size=tp)
+    got = eng.generate(prompts, max_new_tokens=16)
+    rep = eng.state.kv_memory_report()
+    kv_ok = rep["kv_pool_bytes_per_chip"] * tp == rep["kv_pool_bytes_total"]
+    return got == ref and kv_ok, \
+        f"tp={tp} token_parity={got == ref} kv_per_chip_1/tp={kv_ok}"
+
+
+def row_async_parity():
+    """Depth-2 plan/dispatch/commit with device token feedback and the
+    pool donated: token parity with the synchronous depth-0 oracle."""
+    mcfg, params = _gpt2(11)
+    prompts = _prompts(13, 4, 17)       # distinct per slot: a feed_idx
+    ref = _engine(mcfg, params,         # permutation bug cannot hide
+                  serve_pipeline_depth=0).generate(prompts,
+                                                   max_new_tokens=16)
+    eng = _engine(mcfg, params, serve_pipeline_depth=2)
+    got = eng.generate(prompts, max_new_tokens=16)
+    fed = eng.pipeline_stats["fed_steps"]
+    return got == ref and fed > 0, \
+        f"token_parity={got == ref} device_fed_steps={fed}"
+
+
+def row_program_audit():
+    """Donation and collective budgets of the programs as lowered for the
+    chip (Mosaic kernels included)."""
     from deepspeed_tpu.analysis import (CollectiveBudget, assert_budget,
                                         audit_serve_programs)
-    aud_ok = True
-    try:
-        reps = audit_serve_programs(eng_a)
-        for name in ("step", "step_greedy", "step_greedy_fb",
-                     "decode_loop", "flush_ring"):
-            # the budget's max_host_callbacks=0 default also fails on
-            # any host callback riding the decode path
-            assert_budget(reps[name],
-                          CollectiveBudget(f"tp1-{name}", num_layers=2))
-        assert reps["step_greedy_fb"].donates, \
-            "KV pool not donated into the feedback step on TPU"
-        assert reps["flush_ring"].donates, \
-            "KV pool not donated into the ring flush on TPU"
-        if tp > 1:
-            tp_reps = audit_serve_programs(eng_tp, programs=("step_greedy",))
-            assert_budget(tp_reps["step_greedy"], CollectiveBudget(
-                "tp-step", num_layers=2, per_layer={"all_reduce": 2}))
-    except AssertionError as e:
-        aud_ok = False
-        print(str(e), flush=True)
-    ok &= aud_ok
-    print(f"{'OK ' if aud_ok else 'FAIL'} program_audit: on-chip "
-          f"donation+collective budgets (tp={tp})", flush=True)
-
-    # prefix cache (ISSUE 5): refcounted KV-block reuse ON CHIP — three
-    # sequential requests sharing a 130-token preamble; cache-on must be
-    # token-identical to cache-off while skipping most prefill chunks
-    # (the matched blocks are read by the compiled paged-flash kernel,
-    # and the CoW block copy gets its first Mosaic-adjacent compile here)
-    mcfg_p = GPT2Config(vocab_size=512, max_seq_len=512, num_layers=2,
-                        num_heads=8, hidden_size=512, dtype=jnp.bfloat16)
-    params_p = GPT2(mcfg_p).init(jax.random.PRNGKey(17),
-                                 jnp.zeros((1, 8), jnp.int32))["params"]
-    base_p = dict(max_seqs=4, chunk_size=32, block_size=128, num_blocks=16,
-                  max_blocks_per_seq=3, dtype="bfloat16",
-                  attention_impl="paged_flash", decode_loop_steps=0)
-    rng_p = np.random.RandomState(19)
-    shared_p = rng_p.randint(1, 512, size=130).tolist()
-    prompts_p = [shared_p + rng_p.randint(1, 512, size=30).tolist()
-                 for _ in range(3)]
-    ref_eng = InferenceEngineV2(mcfg_p, params_p,
-                                RaggedInferenceConfig(**base_p))
-    ref_p = [ref_eng.generate([p], max_new_tokens=8)[0] for p in prompts_p]
-    eng_p = InferenceEngineV2(
-        mcfg_p, params_p,
-        RaggedInferenceConfig(**base_p, prefix_cache=True))
-    got_p = [eng_p.generate([p], max_new_tokens=8)[0] for p in prompts_p]
-    par_p = got_p == ref_p
-    frac_p = eng_p.prefix_stats["prefill_chunks_skipped_frac"]
-    hit_p = eng_p.prefix_stats["matched_blocks"] > 0
-    ok &= par_p and hit_p
-    print(f"{'OK ' if par_p and hit_p else 'FAIL'} prefix_cache: "
-          f"token_parity={par_p} skipped_chunk_frac={frac_p:.3f} "
-          f"matched_blocks={eng_p.prefix_stats['matched_blocks']} "
-          f"cow_copies={eng_p.prefix_stats['cow_copies']}", flush=True)
-
-    # TP overlap (ISSUE 6): the decomposed collective schedule ON CHIP —
-    # rs_ag_chunked must be token-identical to the psum oracle (got_tp
-    # above) with the audited per-layer schedule exactly k ring RS + k
-    # ring AG hops (k = chunks*(tp-1)) and zero residual psum; first
-    # evidence the ppermute rings lower through Mosaic/ICI and actually
-    # land next to the GEMMs they should hide under.
+    mcfg, params = _gpt2(11)
+    reps = audit_serve_programs(_engine(mcfg, params))
+    for name in ("step", "step_greedy", "step_greedy_fb", "decode_loop",
+                 "flush_ring"):
+        assert_budget(reps[name], CollectiveBudget(f"tp1-{name}",
+                                                   num_layers=2))
+    donated = reps["step_greedy_fb"].donates and reps["flush_ring"].donates
+    tp = _tp_degree()
     if tp > 1:
-        ov_chunks = 2
-        eng_ov = InferenceEngineV2(
-            mcfg_tp, params_tp,
-            RaggedInferenceConfig(**base_tp, tp_size=tp,
-                                  tp_comm_overlap="rs_ag_chunked",
-                                  tp_comm_chunks=ov_chunks))
-        t0 = _time.perf_counter()
-        got_ov = eng_ov.generate(prompts_tp, max_new_tokens=16)
-        dt_ov = _time.perf_counter() - t0
-        # the ring is BITWISE psum-equal only at tp=2 (one commutative
-        # add); beyond that it reassociates, so a within-ulp logit tie
-        # can legitimately flip an argmax — report parity at tp>2 but
-        # only hard-gate the unattended run on it at tp=2
-        par_ov = got_ov == got_tp
-        gate_par = par_ov or tp > 2
-        k_hops = 2 * ov_chunks * (tp - 1)   # 2 sites/layer, k hops each
-        sched_ov = True
-        try:
-            ov_reps = audit_serve_programs(eng_ov,
-                                           programs=("step_greedy",))
-            assert_budget(ov_reps["step_greedy"], CollectiveBudget(
-                "tp-overlap-step", num_layers=2,
-                per_layer={"reduce_scatter": k_hops,
-                           "all_gather": k_hops}))
-        except AssertionError as e:
-            sched_ov = False
-            print(str(e), flush=True)
-        ok &= gate_par and sched_ov
-        print(f"{'OK ' if gate_par and sched_ov else 'FAIL'} tp_overlap: "
-              f"tp={tp} rs_ag_chunked x{ov_chunks} token_parity={par_ov}"
-              f"{'' if tp == 2 else ' (informational at tp>2)'} "
-              f"audited_schedule_k={k_hops}/layer/phase ok={sched_ov} "
-              f"({4 * 16 / dt_ov:.0f} tok/s incl. compile)", flush=True)
-    else:
-        print("SKIP tp_overlap (single chip)", flush=True)
+        tp_reps = audit_serve_programs(_engine(mcfg, params, tp_size=tp),
+                                       programs=("step_greedy",))
+        assert_budget(tp_reps["step_greedy"], CollectiveBudget(
+            "tp-step", num_layers=2, per_layer={"all_reduce": 2}))
+    return donated, f"pool_donated={donated} budgets ok (tp={tp})"
 
-    # hierarchical KV (ISSUE 13): the host-RAM prefix-cache tier ON
-    # CHIP — a 4-group preamble working set over a pool that holds ~1
-    # group: revisits demote-then-promote through the real device
-    # gather/scatter paths (first Mosaic-adjacent compiles for both),
-    # and the streams must be token-identical to the tier-off engine
-    # while a meaningful fraction of hits comes off the host tier
-    rng_h = np.random.RandomState(29)
-    G_h = 4
-    pres_h = [rng_h.randint(1, 512, size=130).tolist() for _ in range(G_h)]
-    reqs_h = [pres_h[j % G_h] + rng_h.randint(1, 512, size=30).tolist()
-              for j in range(2 * G_h)]
-    base_h = dict(max_seqs=4, chunk_size=32, block_size=128, num_blocks=5,
-                  max_blocks_per_seq=3, dtype="bfloat16",
-                  attention_impl="paged_flash", decode_loop_steps=0)
-    eng_h0 = InferenceEngineV2(
-        mcfg_p, params_p,
-        RaggedInferenceConfig(**base_h, prefix_cache=True))
-    ref_h = [eng_h0.generate([p], max_new_tokens=8)[0] for p in reqs_h]
-    eng_h = InferenceEngineV2(
-        mcfg_p, params_p,
-        RaggedInferenceConfig(**base_h, prefix_cache=True,
-                              prefix_cache_host_blocks=16))
-    got_h = [eng_h.generate([p], max_new_tokens=8)[0] for p in reqs_h]
-    st_h = eng_h.prefix_stats
-    par_h = got_h == ref_h
-    hit_h = st_h["promoted"] > 0 and st_h["host_hit_frac"] > 0
-    ok &= par_h and hit_h
-    print(f"{'OK ' if par_h and hit_h else 'FAIL'} hier_kv: "
-          f"tier on/off token_parity={par_h} "
-          f"host_hit_frac={st_h['host_hit_frac']:.3f} "
-          f"demoted={st_h['demoted']} promoted={st_h['promoted']} "
-          f"skipped_frac={st_h['prefill_chunks_skipped_frac']:.3f}",
-          flush=True)
 
-    # speculative decode (ISSUE 12): the draft-fed verify program ON
-    # CHIP — ngram self-drafting over the fused decode_loop (feed=
-    # "given" compiled through Mosaic, rollback trims live) must be
-    # token-identical to plain greedy decode_pipelined, and the sampled
-    # feedback step's temperature->0 path must reproduce greedy too.
-    rng_s = np.random.RandomState(23)
-    pat_s = rng_s.randint(1, 512, size=12).tolist()
-    prompts_s = [(pat_s * 3)[:30] for _ in range(3)]       # repetitive:
-    uids_s = [0, 1, 2]                                     # ngram food
-    eng_g = InferenceEngineV2(mcfg_a, params_a,
-                              RaggedInferenceConfig(**base_a))
-    f_g = eng_g.put(uids_s, prompts_s, _greedy=True)
-    ref_s = eng_g.decode_pipelined(uids_s, [f_g[u] for u in uids_s], 12)
-    eng_s = InferenceEngineV2(
-        mcfg_a, params_a,
-        RaggedInferenceConfig(**base_a, spec_decode="ngram", spec_k=4))
-    f_s = eng_s.put(uids_s, prompts_s, _greedy=True)
-    got_s = eng_s.decode_pipelined(uids_s, [f_s[u] for u in uids_s], 12)
-    par_s = got_s == ref_s and f_s == f_g
-    slo_s = eng_s.slo_report()
-    acc_s = slo_s.get("spec_accept_rate")
+def row_prefix_cache():
+    """Refcounted block reuse: three requests sharing a 130-token preamble;
+    cache on must equal cache off while skipping prefill chunks (the CoW
+    block copy donates the pool)."""
+    mcfg, params = _gpt2(17)
+    rng = np.random.RandomState(19)
+    shared = rng.randint(1, 512, size=130).tolist()
+    prompts = [shared + rng.randint(1, 512, size=30).tolist()
+               for _ in range(3)]
+    kw = dict(num_blocks=16, max_blocks_per_seq=3)
+    ref_eng = _engine(mcfg, params, **kw)
+    ref = [ref_eng.generate([p], max_new_tokens=8)[0] for p in prompts]
+    eng = _engine(mcfg, params, prefix_cache=True, **kw)
+    got = [eng.generate([p], max_new_tokens=8)[0] for p in prompts]
+    st = eng.prefix_stats
+    return got == ref and st["matched_blocks"] > 0, \
+        (f"token_parity={got == ref} matched_blocks={st['matched_blocks']} "
+         f"skipped_chunk_frac={st['prefill_chunks_skipped_frac']:.3f} "
+         f"cow_copies={st['cow_copies']}")
+
+
+def row_tp_overlap():
+    """Decomposed TP collectives: rs_ag_chunked against the psum oracle and
+    its audited ring schedule."""
+    from deepspeed_tpu.analysis import (CollectiveBudget, assert_budget,
+                                        audit_serve_programs)
+    tp = _tp_degree()
+    if tp == 1:
+        return None, "single chip"
+    mcfg, params = _gpt2(3)
+    prompts = _prompts(5, 4, 17)
+    ref = _engine(mcfg, params, decode_loop_steps=8, tp_size=tp).generate(
+        prompts, max_new_tokens=16)
+    chunks = 2
+    eng = _engine(mcfg, params, decode_loop_steps=8, tp_size=tp,
+                  tp_comm_overlap="rs_ag_chunked", tp_comm_chunks=chunks)
+    got = eng.generate(prompts, max_new_tokens=16)
+    hops = 2 * chunks * (tp - 1)        # 2 sites/layer, k hops each
+    assert_budget(
+        audit_serve_programs(eng, programs=("step_greedy",))["step_greedy"],
+        CollectiveBudget("tp-overlap-step", num_layers=2,
+                         per_layer={"reduce_scatter": hops,
+                                    "all_gather": hops}))
+    # the ring is bitwise psum-equal only at tp=2 (one commutative add);
+    # beyond that it reassociates and a near-tie may flip an argmax
+    return got == ref or tp > 2, \
+        f"tp={tp} token_parity={got == ref} ring_hops={hops}/layer/phase"
+
+
+def row_hier_kv():
+    """Host-RAM prefix tier: demote then promote through the device
+    gather/scatter against the donated pool; tier on must equal tier off."""
+    mcfg, params = _gpt2(17)
+    rng = np.random.RandomState(29)
+    # six preambles cycled over a five-block pool: each revisit finds its
+    # block demoted (four preambles fit and never press the pool)
+    pres = [rng.randint(1, 512, size=130).tolist() for _ in range(6)]
+    reqs = [pres[j % 6] + rng.randint(1, 512, size=30).tolist()
+            for j in range(12)]
+    kw = dict(num_blocks=5, max_blocks_per_seq=3, prefix_cache=True)
+    off = _engine(mcfg, params, **kw)
+    ref = [off.generate([p], max_new_tokens=8)[0] for p in reqs]
+    eng = _engine(mcfg, params, prefix_cache_host_blocks=16, **kw)
+    got = [eng.generate([p], max_new_tokens=8)[0] for p in reqs]
+    st = eng.prefix_stats
+    return got == ref and st["promoted"] > 0, \
+        (f"token_parity={got == ref} demoted={st['demoted']} "
+         f"promoted={st['promoted']} "
+         f"host_hit_frac={st['host_hit_frac']:.3f}")
+
+
+def row_spec_decode():
+    """ngram speculation through the draft-fed verify loop, and the sampled
+    feedback step at temperature 0: both must equal plain greedy."""
     from deepspeed_tpu.inference.v2 import SamplingParams
-    eng_t0 = InferenceEngineV2(mcfg_a, params_a,
-                               RaggedInferenceConfig(**base_a))
-    sp0 = {u: SamplingParams(temperature=0.0) for u in uids_s}
-    f_t0 = eng_t0.put(uids_s, prompts_s, _greedy=True, sampling=sp0)
-    got_t0 = eng_t0.decode_pipelined(uids_s, [f_t0[u] for u in uids_s],
-                                     12)
-    par_t0 = got_t0 == ref_s and f_t0 == f_g
-    ok &= par_s and par_t0
-    print(f"{'OK ' if par_s and par_t0 else 'FAIL'} spec_decode: "
-          f"ngram token_parity={par_s} temp0_parity={par_t0} "
-          f"accept_rate={acc_s if acc_s is None else round(acc_s, 3)} "
-          f"rounds={slo_s.get('spec', {}).get('rounds')}", flush=True)
+    mcfg, params = _gpt2(11)
+    pat = np.random.RandomState(23).randint(1, 512, size=12).tolist()
+    prompts = [(pat * 3)[:30] for _ in range(3)]      # repetitive: ngram food
+    uids = [0, 1, 2]
 
-    # step-time attribution (ISSUE 14): ON CHIP, attribution on/off must
-    # be token-identical (the record path never touches a program) and
-    # the component sums must close against an externally measured
-    # pipelined decode window — the CPU harness proves the math, this
-    # row proves it against real async dispatch/readback timing.
-    import os as _os
-    import time as _time
+    def run(**kw):
+        sampling = kw.pop("sampling", None)
+        eng = _engine(mcfg, params, **kw)
+        first = eng.put(uids, prompts, _greedy=True, sampling=sampling)
+        return first, eng.decode_pipelined(
+            uids, [first[u] for u in uids], 12), eng
 
-    from deepspeed_tpu.telemetry.attribution import (
-        STEP_WALL_COMPONENTS, component_totals)
-    rng_at = np.random.RandomState(29)
-    prompts_at = [rng_at.randint(1, 512, size=24).tolist()
-                  for _ in range(3)]
-    uids_at = [0, 1, 2]
-    # pin the knob for each engine and RESTORE the operator's value
-    # after (an exported DSTPU_ATTRIB=0 must not silently fail the row)
-    prior_at = _os.environ.get("DSTPU_ATTRIB")
-    try:
-        _os.environ["DSTPU_ATTRIB"] = "1"
-        eng_a1 = InferenceEngineV2(mcfg_a, params_a,
-                                   RaggedInferenceConfig(**base_a))
-        f_a1 = eng_a1.put(uids_at, prompts_at, _greedy=True)
-        warm_a = eng_a1.decode_pipelined(uids_at,
-                                         [f_a1[u] for u in uids_at], 4)
-        snap_a0 = eng_a1.metrics.snapshot()
-        t_a0 = _time.perf_counter()
-        got_a1 = eng_a1.decode_pipelined(
-            uids_at, [warm_a[u][-1] for u in uids_at], 16)
-        wall_a = _time.perf_counter() - t_a0
-        comps_a = component_totals(eng_a1.metrics.snapshot(), snap_a0)
-        sum_a = sum(comps_a[c] for c in STEP_WALL_COMPONENTS)
-        close_a = abs(wall_a - sum_a) / wall_a if wall_a > 0 else 1.0
-        _os.environ["DSTPU_ATTRIB"] = "0"
-        eng_a0 = InferenceEngineV2(mcfg_a, params_a,
-                                   RaggedInferenceConfig(**base_a))
-        f_a0 = eng_a0.put(uids_at, prompts_at, _greedy=True)
-        warm_a0 = eng_a0.decode_pipelined(uids_at,
-                                          [f_a0[u] for u in uids_at], 4)
-        got_a0 = eng_a0.decode_pipelined(
-            uids_at, [warm_a0[u][-1] for u in uids_at], 16)
-    finally:
-        if prior_at is None:
-            _os.environ.pop("DSTPU_ATTRIB", None)
-        else:
-            _os.environ["DSTPU_ATTRIB"] = prior_at
-    par_a = got_a1 == got_a0 and f_a1 == f_a0 and warm_a == warm_a0
-    sum_ok = close_a <= 0.25
-    ok &= par_a and sum_ok
-    print(f"{'OK ' if par_a and sum_ok else 'FAIL'} attribution: "
-          f"on/off token_parity={par_a} closure_err={close_a:.3f} "
-          f"dominant="
-          f"{max(STEP_WALL_COMPONENTS, key=lambda c: comps_a[c])} "
-          f"wall={wall_a:.3f}s sum={sum_a:.3f}s", flush=True)
+    f_ref, ref, _ = run()
+    f_s, got_s, eng_s = run(spec_decode="ngram", spec_k=4)
+    f_0, got_0, _ = run(sampling={u: SamplingParams(temperature=0.0)
+                                  for u in uids})
+    par_s = got_s == ref and f_s == f_ref
+    par_0 = got_0 == ref and f_0 == f_ref
+    acc = eng_s.slo_report().get("spec_accept_rate")
+    return par_s and par_0, \
+        f"ngram_parity={par_s} temp0_parity={par_0} accept_rate={acc}"
 
-    # TRAIN attribution (ISSUE 15): ON CHIP, the train observer on/off
-    # must be loss-identical over the same batch stream and the six
-    # train components must close against an externally measured window
-    # — against REAL async dispatch (device_execute is only non-zero
-    # here; the CPU harness folds it into dispatch).
+
+def row_serve_attribution():
+    """Step-time attribution: its components must close against an
+    externally timed pipelined decode window on real async dispatch."""
+    from deepspeed_tpu.telemetry.attribution import (STEP_WALL_COMPONENTS,
+                                                     component_totals)
+    mcfg, params = _gpt2(11)
+    prompts = _prompts(29, 3, 24)
+    uids = [0, 1, 2]
+    eng = _engine(mcfg, params)
+    first = eng.put(uids, prompts, _greedy=True)
+    warm = eng.decode_pipelined(uids, [first[u] for u in uids], 4)
+    snap = eng.metrics.snapshot()
+    t0 = time.perf_counter()
+    eng.decode_pipelined(uids, [warm[u][-1] for u in uids], 16)
+    wall = time.perf_counter() - t0
+    comps = component_totals(eng.metrics.snapshot(), snap)
+    total = sum(comps[c] for c in STEP_WALL_COMPONENTS)
+    close = abs(wall - total) / wall
+    return close <= 0.25, \
+        (f"closure_err={close:.3f} wall={wall:.3f}s sum={total:.3f}s "
+         f"dominant={max(STEP_WALL_COMPONENTS, key=lambda c: comps[c])}")
+
+
+def row_train_attribution():
+    """Train observer: its six components must close against an externally
+    timed window (device_execute is only non-zero on a real device)."""
     import deepspeed_tpu as dstpu
-    from deepspeed_tpu.models.gpt2 import GPT2Config as _TGC
-    from deepspeed_tpu.models.gpt2 import make_model as _make_model
+    from deepspeed_tpu.models.gpt2 import GPT2Config, make_model
     from deepspeed_tpu.telemetry.attribution import (
-        TRAIN_ATTRIBUTION_COMPONENTS, TRAIN_STEP_WALL_COMPONENTS)
-    from deepspeed_tpu.telemetry.attribution import \
-        component_totals as _ct
+        TRAIN_ATTRIBUTION_COMPONENTS, TRAIN_STEP_WALL_COMPONENTS,
+        component_totals)
+    tcfg = GPT2Config(vocab_size=512, max_seq_len=64, num_layers=4,
+                      num_heads=4, hidden_size=128, dtype=jnp.bfloat16)
+    _, init_fn, loss_fn = make_model(tcfg)
+    rng = np.random.RandomState(31)
+    batches = [{"tokens": jnp.asarray(rng.randint(0, 512, size=(4, 34)),
+                                      jnp.int32)} for _ in range(16)]
+    eng, _, _, _ = dstpu.initialize(
+        loss_fn=loss_fn,
+        params=init_fn(jax.random.PRNGKey(0), batch_size=4, seq_len=33),
+        config={"train_micro_batch_size_per_gpu": 4,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+                "steps_per_print": 100000},
+        topology=dstpu.build_mesh(devices=jax.devices()[:1]))
+    for b in batches[:4]:
+        loss = eng.train_batch(b)
+    eng._train_obs.reset_anchor()
+    snap = eng._train_obs.registry.snapshot()
+    t0 = time.perf_counter()
+    for b in batches[4:]:
+        loss = eng.train_batch(b)
+    jax.block_until_ready(loss)
+    wall = time.perf_counter() - t0
+    comps = component_totals(eng._train_obs.registry.snapshot(), snap,
+                             components=TRAIN_ATTRIBUTION_COMPONENTS)
+    total = sum(comps[c] for c in TRAIN_STEP_WALL_COMPONENTS)
+    close = abs(wall - total) / wall
+    return close <= 0.25, \
+        (f"closure_err={close:.3f} wall={wall:.3f}s sum={total:.3f}s "
+         f"dominant="
+         f"{max(TRAIN_STEP_WALL_COMPONENTS, key=lambda c: comps[c])}")
 
-    tcfg = _TGC(vocab_size=512, max_seq_len=64, num_layers=4,
-                num_heads=4, hidden_size=128, dtype=jnp.bfloat16)
-    _, t_init, t_loss = _make_model(tcfg)
-    rng_t = np.random.RandomState(31)
-    t_batches = [{"tokens": jnp.asarray(
-        rng_t.randint(0, 512, size=(4, 34)), jnp.int32)}
-        for _ in range(16)]
 
-    def _t_engine(obs_on):
-        _os.environ["DSTPU_TRAIN_OBS"] = "1" if obs_on else "0"
-        eng, _, _, _ = dstpu.initialize(
-            loss_fn=t_loss,
-            params=t_init(jax.random.PRNGKey(0), batch_size=4,
-                          seq_len=33),
-            config={"train_micro_batch_size_per_gpu": 4,
-                    "optimizer": {"type": "AdamW",
-                                  "params": {"lr": 1e-3}},
-                    "steps_per_print": 100000})
-        return eng
+ENGINE_ROWS = (row_tp_paged_decode, row_async_parity, row_program_audit,
+               row_prefix_cache, row_tp_overlap, row_hier_kv,
+               row_spec_decode, row_serve_attribution, row_train_attribution)
 
-    prior_t = _os.environ.get("DSTPU_TRAIN_OBS")
-    try:
-        eng_t1 = _t_engine(True)
-        eng_t0 = _t_engine(False)
-        l1 = [float(eng_t1.train_batch(b)) for b in t_batches[:4]]
-        l0 = [float(eng_t0.train_batch(b)) for b in t_batches[:4]]
-        eng_t1._train_obs.reset_anchor()
-        snap_t0 = eng_t1._train_obs.registry.snapshot()
-        t_t0 = _time.perf_counter()
-        for b in t_batches[4:]:
-            tl = eng_t1.train_batch(b)
-        jax.block_until_ready(tl)
-        wall_t = _time.perf_counter() - t_t0
-        comps_t = _ct(eng_t1._train_obs.registry.snapshot(), snap_t0,
-                      components=TRAIN_ATTRIBUTION_COMPONENTS)
-    finally:
-        if prior_t is None:
-            _os.environ.pop("DSTPU_TRAIN_OBS", None)
-        else:
-            _os.environ["DSTPU_TRAIN_OBS"] = prior_t
-    sum_t = sum(comps_t[c] for c in TRAIN_STEP_WALL_COMPONENTS)
-    close_t = abs(wall_t - sum_t) / wall_t if wall_t > 0 else 1.0
-    par_t = l1 == l0 and eng_t0._train_obs is None
-    tsum_ok = close_t <= 0.25
-    ok &= par_t and tsum_ok
-    print(f"{'OK ' if par_t and tsum_ok else 'FAIL'} train_attrib: "
-          f"obs on/off loss_parity={par_t} closure_err={close_t:.3f} "
-          f"dominant="
-          f"{max(TRAIN_STEP_WALL_COMPONENTS, key=lambda c: comps_t[c])}"
-          f" wall={wall_t:.3f}s sum={sum_t:.3f}s", flush=True)
 
-    print("TPU_SMOKE " + ("PASS" if ok else "FAIL"), flush=True)
-    return 0 if ok else 1
+def main() -> int:
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"tpu_smoke: needs a TPU, found platform {dev.platform!r}",
+              file=sys.stderr)
+        return 2
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    print(f"# tools/tpu_smoke.py on {dev.device_kind} x{len(jax.devices())}, "
+          f"jax {jax.__version__}", flush=True)
+    rows = [(c.name, lambda c=c: run_kernel_case(c)) for c in kernel_cases()]
+    rows += [(fn.__name__[4:], fn) for fn in ENGINE_ROWS]
+    if sys.argv[1:]:
+        rows = [r for r in rows if any(w in r[0] for w in sys.argv[1:])]
+    bad = 0
+    for name, fn in rows:
+        try:
+            ok, detail = fn()
+        except Exception as e:  # the sweep must reach the remaining rows
+            ok, detail = False, "RAISED " + " ".join(
+                f"{type(e).__name__}: {e}".split())[:600]
+        status = "SKIP" if ok is None else ("OK  " if ok else "FAIL")
+        bad += ok is False
+        print(f"{status} {name}: {detail}", flush=True)
+    print("TPU_SMOKE " + ("PASS" if not bad else f"FAIL ({bad} row(s))"),
+          flush=True)
+    return 1 if bad else 0
 
 
 if __name__ == "__main__":
