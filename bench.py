@@ -1197,10 +1197,7 @@ def bench_serve_obs():
       - achieved decode TFLOPS comes from the shared
         ``telemetry.record_phase_tflops`` roofline helper (model-shape
         FLOPs estimate), read back from the gauge — not phase-local
-        arithmetic.
-
-    Set ``DSTPU_TRACE_DIR`` to additionally capture a jax.profiler trace
-    of the telemetry-on measured window."""
+        arithmetic."""
     import os
 
     import jax
@@ -1258,7 +1255,7 @@ def bench_serve_obs():
 
     def window(eng, last, stream, tw, label):
         t0 = time.perf_counter()
-        with tw, telemetry.maybe_trace(label):
+        with tw:
             outs = eng.decode_pipelined(eng_uids, last, GEN)
         dt = time.perf_counter() - t0
         for u in eng_uids:

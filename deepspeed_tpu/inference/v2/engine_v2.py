@@ -33,6 +33,7 @@ import numpy as np
 
 from ...resilience.fault_injection import get_fault_injector
 from ...telemetry.serve import serve_observer
+from ...telemetry.trace import SpanSet
 from ...utils.dtypes import resolve_dtype
 from ...utils.logging import log_dist, logger
 from .blocked_allocator import OutOfBlocksError
@@ -358,9 +359,20 @@ class InferenceEngineV2:
         # [S] last-token buffer and each uid's slot in it
         self._feed_src = None
         self._feed_slot: Dict[int, int] = {}
-        self.pipeline_stats = {"steps": 0, "fed_steps": 0, "plan_s": 0.0,
-                               "dispatch_s": 0.0, "commit_block_s": 0.0,
-                               "retries": 0}
+        #: the engine's own totals, filled where the work happens: the
+        #: ``*_s`` seconds by the brackets (telemetry/trace.py), the
+        #: counts by _plan_step / _dispatch_step / decode_batch. Per step
+        #: that carries a multi-token chunk: the scheduled chunk lengths
+        #: (real) against S x T of the program it runs (planned); per
+        #: pure-decode step: live sequences against the slot bucket
+        self.pipeline_stats = {
+            "steps": 0, "fed_steps": 0, "retries": 0, "plan_s": 0.0,
+            "dispatch_s": 0.0, "commit_block_s": 0.0, "commit_apply_s": 0.0,
+            "fused_dispatch_s": 0.0, "fused_apply_s": 0.0,
+            "prefill_tokens_real": 0,
+            "prefill_tokens_planned": 0, "decode_slots_live": 0,
+            "decode_slots_planned": 0}
+        self._spans = SpanSet(self.pipeline_stats, lambda: self._obs)
         # ---- serve-side resilience (drain.py, docs/resilience.md) ---- #
         # env knobs are read with LITERAL names so the dslint knob scan
         # (DSL004/5) and gen_config_doc keep seeing them
@@ -418,7 +430,7 @@ class InferenceEngineV2:
         self._watchdog = None
         if os.environ.get("DSTPU_SERVE_WATCHDOG") == "1":
             from ...resilience.watchdog import StepWatchdog
-            self._watchdog = StepWatchdog(action="log")
+            self.attach_watchdog(StepWatchdog(action="log"))
         self._drain_requested = False
         self._drained = False
         self._live_ring: Optional[deque] = None
@@ -498,92 +510,93 @@ class InferenceEngineV2:
         and carried through the drain manifest so a replayed request's
         survivor spans join the same logical track
         (docs/observability.md "Distributed tracing")."""
-        admitted: List[int] = []
-        bs = self.config.block_size
-        for uid, toks in zip(batch_uids, batch_tokens):
-            seq0 = self.state.get(uid)
-            fresh = seq0 is None or (seq0.seen_tokens == 0
-                                     and not seq0.kv_blocks)
-            if self._draining():
-                # a FRESH request is refused outright — the client must
-                # retry on another replica. A continuation of a LIVE
-                # sequence is simply not fed: that sequence rides the
-                # drain manifest (a rejection record here would
-                # double-route the same request — replayed by the
-                # survivor AND retried by the client)
-                if fresh:
-                    self._reject(uid, "draining",
-                                 detail="engine is draining for preemption")
-                continue
-            if fresh and self.serve_shed:
-                # load shedding at the door: a prompt whose KV (plus one
-                # generated token) exceeds the WHOLE pool can never be
-                # served, eviction or not — shed it before it poisons
-                # the scheduler (serve_shed=False keeps the legacy hard
-                # starvation RuntimeError instead)
-                need = -(-(len(toks) + 1) // bs)
-                if need > self.config.num_blocks:
-                    self._reject(
-                        uid, "kv_pool_exhausted",
-                        needed_blocks=need,
-                        num_blocks=self.config.num_blocks,
-                        detail="prompt exceeds the whole KV pool")
+        with self._spans.span("serve/put", requests=len(batch_uids)):
+            admitted: List[int] = []
+            bs = self.config.block_size
+            for uid, toks in zip(batch_uids, batch_tokens):
+                seq0 = self.state.get(uid)
+                fresh = seq0 is None or (seq0.seen_tokens == 0
+                                         and not seq0.kv_blocks)
+                if self._draining():
+                    # a FRESH request is refused outright — the client must
+                    # retry on another replica. A continuation of a LIVE
+                    # sequence is simply not fed: that sequence rides the
+                    # drain manifest (a rejection record here would
+                    # double-route the same request — replayed by the
+                    # survivor AND retried by the client)
+                    if fresh:
+                        self._reject(uid, "draining",
+                                     detail="engine is draining for "
+                                     "preemption")
                     continue
-            seq = self.state.put_tokens(uid, toks)
-            admitted.append(uid)
-            # a reused uid sheds its STALE rejection record — generate()
-            # and the serving layer treat a present record as "this
-            # request failed", which must only ever mean THIS admission
-            self.rejections.pop(uid, None)
-            if fresh:
-                sp = sampling.get(uid) if sampling else None
-                if sp is not None:
-                    seq.sampling = sp
-                tid = traces.get(uid) if traces else None
-                if tid is not None:
-                    # set BEFORE on_admit: the admit span must already
-                    # carry the trace context
-                    seq.trace_id = tid
-                arrived = arrivals.get(uid) if arrivals else None
-                if self._obs is not None:
-                    self._obs.on_admit(
-                        seq, arrived if arrived is not None
-                        else time.monotonic())
-                dl = deadlines.get(uid) if deadlines else None
-                if dl is None and self.request_deadline_s > 0:
-                    dl = self.request_deadline_s
-                if dl is not None and dl > 0 and seq.deadline_at is None:
-                    seq.deadline_at = dl + (
-                        arrived if arrived is not None
-                        else time.monotonic())
-                    seq.deadline_s = dl
-                    self._has_deadlines = True
-                if self.journal is not None \
-                        and seq.seen_tokens == 0 and not seq.kv_blocks:
-                    # prompt still building: (re-)journal the full chain
-                    # (+ sampling identity, so a hard-crash replay keeps
-                    # the stream deterministic)
-                    self.journal.admit(uid, seq.prompt_log,
-                                       sampling=seq.sampling.to_dict()
-                                       if seq.sampling is not None
-                                       else None,
-                                       trace=seq.trace_id)
+                if fresh and self.serve_shed:
+                    # load shedding at the door: a prompt whose KV (plus one
+                    # generated token) exceeds the WHOLE pool can never be
+                    # served, eviction or not — shed it before it poisons
+                    # the scheduler (serve_shed=False keeps the legacy hard
+                    # starvation RuntimeError instead)
+                    need = -(-(len(toks) + 1) // bs)
+                    if need > self.config.num_blocks:
+                        self._reject(
+                            uid, "kv_pool_exhausted",
+                            needed_blocks=need,
+                            num_blocks=self.config.num_blocks,
+                            detail="prompt exceeds the whole KV pool")
+                        continue
+                seq = self.state.put_tokens(uid, toks)
+                admitted.append(uid)
+                # a reused uid sheds its STALE rejection record — generate()
+                # and the serving layer treat a present record as "this
+                # request failed", which must only ever mean THIS admission
+                self.rejections.pop(uid, None)
+                if fresh:
+                    sp = sampling.get(uid) if sampling else None
+                    if sp is not None:
+                        seq.sampling = sp
+                    tid = traces.get(uid) if traces else None
+                    if tid is not None:
+                        # set BEFORE on_admit: the admit span must already
+                        # carry the trace context
+                        seq.trace_id = tid
+                    arrived = arrivals.get(uid) if arrivals else None
+                    seq.put_at = time.monotonic()
+                    if arrived is None:
+                        arrived = seq.put_at
+                    if self._obs is not None:
+                        self._obs.on_admit(seq, arrived)
+                    dl = deadlines.get(uid) if deadlines else None
+                    if dl is None and self.request_deadline_s > 0:
+                        dl = self.request_deadline_s
+                    if dl is not None and dl > 0 and seq.deadline_at is None:
+                        seq.deadline_at = dl + arrived
+                        seq.deadline_s = dl
+                        self._has_deadlines = True
+                    if self.journal is not None \
+                            and seq.seen_tokens == 0 and not seq.kv_blocks:
+                        # prompt still building: (re-)journal the full chain
+                        # (+ sampling identity, so a hard-crash replay keeps
+                        # the stream deterministic)
+                        self.journal.admit(uid, seq.prompt_log,
+                                           sampling=seq.sampling.to_dict()
+                                           if seq.sampling is not None
+                                           else None,
+                                           trace=seq.trace_id)
+                if self._prefix is not None:
+                    self._match_prefix(seq)
+            done: Dict[int, np.ndarray] = {}
+
+            def work_left():
+                return any(s.in_flight for s in self.state.sequences.values())
+
+            def commit_one(ring):
+                _, step_done = self._commit_step(ring.popleft())
+                done.update(step_done)
+
+            self._drive_pipeline(
+                work_left, lambda: self._plan_step(greedy=_greedy), commit_one)
             if self._prefix is not None:
-                self._match_prefix(seq)
-        done: Dict[int, np.ndarray] = {}
-
-        def work_left():
-            return any(s.in_flight for s in self.state.sequences.values())
-
-        def commit_one(ring):
-            _, step_done = self._commit_step(ring.popleft())
-            done.update(step_done)
-
-        self._drive_pipeline(
-            work_left, lambda: self._plan_step(greedy=_greedy), commit_one)
-        if self._prefix is not None:
-            self._register_prefix(admitted)
-        return done
+                self._register_prefix(admitted)
+            return done
 
     def _match_prefix(self, seq) -> None:
         """Prefix-cache hit path: point a fresh prompt's table at the
@@ -668,7 +681,9 @@ class InferenceEngineV2:
         step is COMMITTED (its rollbacks and deferred aborts applied),
         and the loop exits with host state token-consistent, ready for
         :meth:`drain` to snapshot. The watchdog (attach_watchdog) brackets
-        each iteration so a stalled dispatch or commit is *named*."""
+        each iteration, and the plan/dispatch/commit brackets
+        (telemetry/trace.py) tell it their phase, so a stalled dispatch
+        or commit is *named*."""
         depth = max(1, self.pipeline_depth)
         ring: deque = deque()
         wd = self._watchdog
@@ -687,17 +702,9 @@ class InferenceEngineV2:
                             and work_left():
                         self._expire_deadlines()
                         self._try_resume()
-                        if wd is not None:
-                            wd.phase("plan")
-                        if self._obs is not None:
-                            self._obs.phase("plan", self._step_counter)
                         plan = make_plan()
                         if plan is None:
                             break
-                        if wd is not None:
-                            wd.phase("dispatch")
-                        if self._obs is not None:
-                            self._obs.phase("dispatch", self._step_counter)
                         fl = self._dispatch_with_retry(plan)
                         ring.append(fl)
                         if on_dispatch is not None:
@@ -729,10 +736,6 @@ class InferenceEngineV2:
                 finally:
                     if wd is not None:
                         wd.step_end(self._step_counter)
-                    if self._obs is not None:
-                        # close the open flight-recorder span; the ring
-                        # then cleanly ends at the iteration boundary
-                        self._obs.phase("idle")
         finally:
             self._live_ring = None
             if self._obs is not None:
@@ -756,6 +759,7 @@ class InferenceEngineV2:
         iteration is bracketed and the plan/dispatch/commit phases are
         named, so a stalled step's diagnosis says WHERE it hung."""
         self._watchdog = wd
+        self._spans.watchdog = wd
 
     def request_drain(self) -> None:
         """Put the engine into draining mode (idempotent): no new
@@ -1051,12 +1055,9 @@ class InferenceEngineV2:
 
     def _pre_commit(self, fl: _InFlightStep) -> None:
         """Shared entry of both commit paths, ahead of the blocking
-        readback: names the watchdog phase and carries the ``mid_commit``
-        fault site. Registered DSL001 hot path — pure host work."""
-        if self._watchdog is not None:
-            self._watchdog.phase("commit")
-        if self._obs is not None:
-            self._obs.phase("commit", self._step_counter)
+        readback (whose ``serve/commit_block`` bracket names the
+        watchdog phase): carries the ``mid_commit`` fault site.
+        Registered DSL001 hot path — pure host work."""
         get_fault_injector().maybe_fire("mid_commit")
 
     def _finish_commit(self, fl: _InFlightStep) -> None:
@@ -1453,77 +1454,90 @@ class InferenceEngineV2:
         if not hasattr(self.runner, "decode_loop"):
             raise NotImplementedError(
                 f"{type(self.runner).__name__} has no decode_loop")
-        cfg = self.config
-        if len(batch_uids) > cfg.max_seqs:
-            raise ValueError(f"{len(batch_uids)} uids > max_seqs "
-                             f"{cfg.max_seqs}")
-        if len(batch_uids) != len(first_tokens):
-            raise ValueError(
-                f"{len(batch_uids)} uids but {len(first_tokens)} "
-                f"first_tokens")
-        seqs = []
-        for uid in batch_uids:
-            seq = self.state.get(uid)
-            if seq is None or seq.status is SequenceStatus.PAUSED:
-                raise ValueError(f"sequence {uid} missing or paused")
-            if seq.in_flight:
-                raise ValueError(f"sequence {uid} has pending tokens; "
-                                 f"drain with put() first")
-            seqs.append(seq)
-        # reserve atomically: check the WHOLE batch's demand first so a
-        # mid-batch failure doesn't leave earlier sequences holding
-        # allocate-ahead blocks that deepen the pool pressure the caller is
-        # about to fall back from
-        bsz = self.config.block_size
-        need = 0
-        for s_ in seqs:
-            nb = s_.blocks_needed(n, bsz)
-            if len(s_.kv_blocks) + nb > cfg.max_blocks_per_seq:
+        with self._spans.span("serve/decode_batch", steps=n,
+                              seqs=len(batch_uids)):
+            cfg = self.config
+            if len(batch_uids) > cfg.max_seqs:
+                raise ValueError(f"{len(batch_uids)} uids > max_seqs "
+                                 f"{cfg.max_seqs}")
+            if len(batch_uids) != len(first_tokens):
+                raise ValueError(
+                    f"{len(batch_uids)} uids but {len(first_tokens)} "
+                    f"first_tokens")
+            seqs = []
+            for uid in batch_uids:
+                seq = self.state.get(uid)
+                if seq is None or seq.status is SequenceStatus.PAUSED:
+                    raise ValueError(f"sequence {uid} missing or paused")
+                if seq.in_flight:
+                    raise ValueError(f"sequence {uid} has pending tokens; "
+                                     f"drain with put() first")
+                seqs.append(seq)
+            # reserve atomically: check the WHOLE batch's demand first so a
+            # mid-batch failure doesn't leave earlier sequences holding
+            # allocate-ahead blocks that deepen the pool pressure the caller is
+            # about to fall back from
+            bsz = self.config.block_size
+            need = 0
+            for s_ in seqs:
+                nb = s_.blocks_needed(n, bsz)
+                if len(s_.kv_blocks) + nb > cfg.max_blocks_per_seq:
+                    raise OutOfBlocksError(
+                        f"sequence {s_.uid} would exceed max_blocks_per_seq")
+                need += nb
+            if need > self.kv_cache.free_blocks:
                 raise OutOfBlocksError(
-                    f"sequence {s_.uid} would exceed max_blocks_per_seq")
-            need += nb
-        if need > self.kv_cache.free_blocks:
-            raise OutOfBlocksError(
-                f"decode_greedy needs {need} blocks, "
-                f"{self.kv_cache.free_blocks} free")
-        for seq in seqs:
-            self.state.ensure_blocks(seq, n)       # covers pos seen..seen+n-1
+                    f"decode_greedy needs {need} blocks, "
+                    f"{self.kv_cache.free_blocks} free")
+            for seq in seqs:
+                # covers positions seen .. seen + n - 1
+                self.state.ensure_blocks(seq, n)
 
-        S, MAXB = cfg.max_seqs, cfg.max_blocks_per_seq
-        tok0 = np.zeros((S,), np.int32)
-        start = np.zeros((S,), np.int32)
-        active = np.zeros((S,), np.int32)
-        tables = np.zeros((S, MAXB), np.int32)
-        for i, (seq, t0) in enumerate(zip(seqs, first_tokens)):
-            tok0[i] = t0
-            start[i] = seq.seen_tokens
-            active[i] = 1
-            tables[i, :len(seq.kv_blocks)] = seq.kv_blocks
-        samp = self._stage_loop_sampling(seqs, S, sampling)
+            S, MAXB = cfg.max_seqs, cfg.max_blocks_per_seq
+            tok0 = np.zeros((S,), np.int32)
+            start = np.zeros((S,), np.int32)
+            active = np.zeros((S,), np.int32)
+            tables = np.zeros((S, MAXB), np.int32)
+            for i, (seq, t0) in enumerate(zip(seqs, first_tokens)):
+                tok0[i] = t0
+                start[i] = seq.seen_tokens
+                active[i] = 1
+                tables[i, :len(seq.kv_blocks)] = seq.kv_blocks
+            samp = self._stage_loop_sampling(seqs, S, sampling)
+            obs = self._obs
+            if obs is not None:
+                # attribution window for the fused path: one dispatch + one
+                # blocking readback cover n steps; the bookkeeping after is
+                # the commit apply, anything else in the window is host gap
+                obs.on_loop_enter()
+            spans, live = self._spans, len(seqs)
+            with spans.span("serve/fused_dispatch", steps=n, seqs=live):
+                toks, lps, self._kv_data, consumed = self.runner.decode_loop(
+                    self.params, self._kv_data, jax.numpy.asarray(tok0),
+                    jax.numpy.asarray(start), jax.numpy.asarray(active),
+                    jax.numpy.asarray(tables), n,
+                    eos_id=-1 if eos_token_id is None else int(eos_token_id),
+                    **samp)
+            with spans.span("serve/fused_readback", steps=n, seqs=live):
+                toks = np.asarray(toks)
+                lps = np.asarray(lps) if lps is not None else None
+                # consumed is None when EOS is disabled: every slot fed all n
+                consumed = np.asarray(consumed) if consumed is not None \
+                    else None
+            with spans.span("serve/fused_apply", steps=n, seqs=live):
+                out = self._apply_fused(batch_uids, seqs, first_tokens, n,
+                                        toks, lps, consumed)
+            if obs is not None:
+                obs.after_commit(self._step_counter)
+                obs.on_loop_exit()
+            return out
+
+    def _apply_fused(self, batch_uids, seqs, first_tokens, n, toks, lps,
+                     consumed) -> Dict[int, List[int]]:
+        """The fused loop's commit apply: token bookkeeping, replay
+        history and journal for the ``n`` steps one readback covered."""
         obs = self._obs
-        if obs is not None:
-            # attribution window for the fused path: one dispatch + one
-            # blocking readback cover n steps; the bookkeeping after is
-            # the commit apply, anything else in the window is host gap
-            obs.on_loop_enter()
-        t_d = time.perf_counter()
-        toks, lps, self._kv_data, consumed = self.runner.decode_loop(
-            self.params, self._kv_data, jax.numpy.asarray(tok0),
-            jax.numpy.asarray(start), jax.numpy.asarray(active),
-            jax.numpy.asarray(tables), n,
-            eos_id=-1 if eos_token_id is None else int(eos_token_id),
-            **samp)
-        if obs is not None:
-            obs.on_fused_dispatch(time.perf_counter() - t_d)
-        t_r = time.perf_counter()
-        toks = np.asarray(toks)
-        lps = np.asarray(lps) if lps is not None else None
-        # consumed is None when EOS is disabled: every slot fed all n
-        consumed = np.asarray(consumed) if consumed is not None else None
-        if obs is not None:
-            obs.on_commit_block(time.perf_counter() - t_r)
-        t_apply = time.perf_counter() if obs is not None else 0.0
-        self.kv_cache.finalize_demotions()   # readback above proved them
+        self.kv_cache.finalize_demotions()   # the readback proved them
         self._step_counter += n
         out: Dict[int, List[int]] = {}
         journal_toks: Dict[int, List[int]] = {}
@@ -1560,10 +1574,6 @@ class InferenceEngineV2:
                 obs.on_token_commit(seq, now, n=used)
         if self.journal is not None:
             self.journal.tokens(journal_toks)
-        if obs is not None:
-            obs.on_commit_apply(time.perf_counter() - t_apply)
-            obs.after_commit(self._step_counter)
-            obs.on_loop_exit()
         return out
 
     # ------------------------------------------------------------------ #
@@ -1607,89 +1617,93 @@ class InferenceEngineV2:
                    eligible=None) -> Optional[_PlannedStep]:
         """PLAN: run the scheduler and stage the step's host arrays.
         Pure host work — runs ahead of the device in the pipelined loop."""
-        t0 = time.perf_counter()
-        sched = self.scheduler.schedule(eligible)
-        if not sched:
-            return None
-        self._step_counter += 1
-        self.state.step += 1
-        for item in sched:
-            item.seq.last_step = self._step_counter
-            item.seq.last_sched = self.state.step
-        if self._obs is not None:
-            # first-schedule stamps -> queue-wait histogram (pure host)
-            self._obs.on_sched(sched, time.monotonic())
-        cfg = self.config
-        # shape bucketing: a pure-decode step (every scheduled slot carries
-        # one token) runs the [S, 1] program instead of padding every slot
-        # to chunk_size — chunk_size× fewer wasted positions in the steady
-        # decode state. The SLOT dim buckets too (powers of two up to
-        # max_seqs): with the SplitFuse token budget a prefill step carries
-        # ~budget/chunk_size sequences, and padding it to max_seqs slots
-        # made prefill activation memory scale with max_seqs (OOM at
-        # max_seqs >= 384). A handful of compiled programs total (jit
-        # caches by shape); the reference gets the same effect by
-        # flattening tokens into one ragged array (ragged_wrapper.py),
-        # which XLA's static shapes forbid.
-        C = 1 if all(len(item.tokens) == 1 for item in sched) \
-            else cfg.effective_chunk
-        S = cfg.max_seqs
-        for b in (16, 32, 64, 128, 256, 512):
-            if b >= len(sched) and b <= cfg.max_seqs:
-                S = b
-                break
-        (tokens, start, ntok, tables, feed_mask, feed_idx,
-         seeds, spos, temps, topks, topps) = self._staging_bufs(S, C)
-        use_greedy = greedy and hasattr(self.runner, "step_greedy")
-        # sampled batch? then the per-slot sampler program selects the
-        # last-chunk token for EVERY slot (greedy slots stage temperature
-        # 0 -> in-program argmax, token-identical to step_greedy). The
-        # pure-greedy common case keeps its exact original program. A
-        # logprobs=True request forces the sampler program too — its
-        # output must not depend on what else happens to share the batch
-        use_sample = use_greedy \
-            and hasattr(self.runner, "step_sample_fb") \
-            and any(item.seq.sampling is not None
-                    and (not item.seq.sampling.greedy
-                         or item.seq.sampling.logprobs)
-                    for item in sched)
-        has_feed = False
-        for i, item in enumerate(sched):
-            seq = item.seq
-            if seq.spec_pending and item.tokens == [_SPEC_TOKEN]:
-                # speculative placeholder: its value is the in-flight
-                # latest step's device-side output for this sequence —
-                # the step program substitutes it (no host round-trip)
-                seq.spec_pending -= 1
-                feed_mask[i] = 1
-                feed_idx[i] = self._feed_slot[seq.uid]
-                has_feed = True
+        with self._spans.span("serve/plan") as span:
+            sched = self.scheduler.schedule(eligible)
+            if not sched:
+                span.void()
+                return None
+            self._step_counter += 1
+            self.state.step += 1
+            for item in sched:
+                item.seq.last_step = self._step_counter
+                item.seq.last_sched = self.state.step
+            if self._obs is not None:
+                # first-schedule stamps -> queue-wait histogram (pure host)
+                self._obs.on_sched(sched, time.monotonic())
+            cfg = self.config
+            # shape bucketing: a pure-decode step (every scheduled slot carries
+            # one token) runs the [S, 1] program instead of padding every slot
+            # to chunk_size — chunk_size× fewer wasted positions in the steady
+            # decode state. The SLOT dim buckets too (powers of two up to
+            # max_seqs): with the SplitFuse token budget a prefill step carries
+            # ~budget/chunk_size sequences, and padding it to max_seqs slots
+            # made prefill activation memory scale with max_seqs (OOM at
+            # max_seqs >= 384). A handful of compiled programs total (jit
+            # caches by shape); the reference gets the same effect by
+            # flattening tokens into one ragged array (ragged_wrapper.py),
+            # which XLA's static shapes forbid.
+            C = 1 if all(len(item.tokens) == 1 for item in sched) \
+                else cfg.effective_chunk
+            S = cfg.max_seqs
+            for b in (16, 32, 64, 128, 256, 512):
+                if b >= len(sched) and b <= cfg.max_seqs:
+                    S = b
+                    break
+            (tokens, start, ntok, tables, feed_mask, feed_idx,
+             seeds, spos, temps, topks, topps) = self._staging_bufs(S, C)
+            use_greedy = greedy and hasattr(self.runner, "step_greedy")
+            # sampled batch? then the per-slot sampler program selects the
+            # last-chunk token for EVERY slot (greedy slots stage temperature
+            # 0 -> in-program argmax, token-identical to step_greedy). The
+            # pure-greedy common case keeps its exact original program. A
+            # logprobs=True request forces the sampler program too — its
+            # output must not depend on what else happens to share the batch
+            use_sample = use_greedy \
+                and hasattr(self.runner, "step_sample_fb") \
+                and any(item.seq.sampling is not None
+                        and (not item.seq.sampling.greedy
+                             or item.seq.sampling.logprobs)
+                        for item in sched)
+            has_feed = False
+            for i, item in enumerate(sched):
+                seq = item.seq
+                if seq.spec_pending and item.tokens == [_SPEC_TOKEN]:
+                    # speculative placeholder: its value is the in-flight
+                    # latest step's device-side output for this sequence —
+                    # the step program substitutes it (no host round-trip)
+                    seq.spec_pending -= 1
+                    feed_mask[i] = 1
+                    feed_idx[i] = self._feed_slot[seq.uid]
+                    has_feed = True
+                else:
+                    tokens[i, :len(item.tokens)] = item.tokens
+                start[i] = item.start_pos
+                ntok[i] = len(item.tokens)
+                tables[i, :len(seq.kv_blocks)] = seq.kv_blocks
+                if use_sample:
+                    # the fold_in operand: the absolute position the
+                    # selected token will occupy (= seen after this step) —
+                    # invariant to chunking/pipeline depth/restart, which
+                    # is the whole determinism contract (sampling.py)
+                    stage_slot((seeds, spos, temps, topks, topps), i, seq,
+                               item.start_pos + len(item.tokens))
+            real = sum(len(item.tokens) for item in sched)
+            span.set(step=self._step_counter, seqs=len(sched), S=S, T=C,
+                     real=real)
+            if C > 1:
+                span.count(prefill_tokens_real=real,
+                           prefill_tokens_planned=S * C)
+                # serve fault site: a replica dying with a freshly planned
+                # multi-token prefill chunk (tokens consumed host-side, step
+                # never dispatched)
+                get_fault_injector().maybe_fire("during_prefill_chunk")
             else:
-                tokens[i, :len(item.tokens)] = item.tokens
-            start[i] = item.start_pos
-            ntok[i] = len(item.tokens)
-            tables[i, :len(seq.kv_blocks)] = seq.kv_blocks
-            if use_sample:
-                # the fold_in operand: the absolute position the
-                # selected token will occupy (= seen after this step) —
-                # invariant to chunking/pipeline depth/restart, which
-                # is the whole determinism contract (sampling.py)
-                stage_slot((seeds, spos, temps, topks, topps), i, seq,
-                           item.start_pos + len(item.tokens))
-        if any(n > 1 for n in ntok[:len(sched)]):
-            # serve fault site: a replica dying with a freshly planned
-            # multi-token prefill chunk (tokens consumed host-side, step
-            # never dispatched)
-            get_fault_injector().maybe_fire("during_prefill_chunk")
-        dt = time.perf_counter() - t0
-        self.pipeline_stats["plan_s"] += dt
-        if self._obs is not None:
-            self._obs.on_plan(dt)
-        return _PlannedStep(sched, tokens, start, ntok, tables,
-                            feed_mask if has_feed else None, feed_idx,
-                            use_greedy,
-                            sample=(seeds, spos, temps, topks, topps)
-                            if use_sample else None)
+                span.count(decode_slots_live=real, decode_slots_planned=S)
+            return _PlannedStep(sched, tokens, start, ntok, tables,
+                                feed_mask if has_feed else None, feed_idx,
+                                use_greedy,
+                                sample=(seeds, spos, temps, topks, topps)
+                                if use_sample else None)
 
     def _dispatch_step(self, plan: _PlannedStep) -> _InFlightStep:
         """DISPATCH: enqueue the compiled step without blocking — the
@@ -1699,57 +1713,56 @@ class InferenceEngineV2:
         # serve fault site: planned but not yet enqueued — with mode
         # 'ioerror' this is the transient _dispatch_with_retry absorbs
         get_fault_injector().maybe_fire("pre_dispatch")
-        t0 = time.perf_counter()
-        jnp = jax.numpy
-        batch = RaggedBatch(
-            tokens=jnp.asarray(plan.tokens),
-            start_pos=jnp.asarray(plan.start),
-            n_tokens=jnp.asarray(plan.ntok),
-            block_tables=jnp.asarray(plan.tables))
-        logprobs = None
-        if plan.sample is not None:
-            # per-slot on-device sampler (greedy slots ride along at
-            # temperature 0). One program covers fed and unfed steps:
-            # an unfed step passes an all-zero mask and a cached [1]
-            # dummy feed source (clipped gather, never read).
-            seeds, spos, temps, topks, topps = plan.sample
-            if plan.feed_mask is not None:
-                prev, mask = self._feed_src, plan.feed_mask
-                self.pipeline_stats["fed_steps"] += 1
+        fed = plan.feed_mask is not None
+        program = "step_sample_fb" if plan.sample is not None \
+            else "step_greedy_fb" if fed \
+            else "step_greedy" if plan.use_greedy else "step"
+        with self._spans.span("serve/dispatch", step=self._step_counter,
+                              fed=int(fed), program=program) as span:
+            jnp = jax.numpy
+            batch = RaggedBatch(
+                tokens=jnp.asarray(plan.tokens),
+                start_pos=jnp.asarray(plan.start),
+                n_tokens=jnp.asarray(plan.ntok),
+                block_tables=jnp.asarray(plan.tables))
+            logprobs = None
+            if plan.sample is not None:
+                # per-slot on-device sampler (greedy slots ride along at
+                # temperature 0). One program covers fed and unfed steps:
+                # an unfed step passes an all-zero mask and a cached [1]
+                # dummy feed source (clipped gather, never read).
+                seeds, spos, temps, topks, topps = plan.sample
+                if plan.feed_mask is not None:
+                    prev, mask = self._feed_src, plan.feed_mask
+                else:
+                    if not hasattr(self, "_dummy_feed"):
+                        self._dummy_feed = (jnp.zeros((1,), jnp.int32),
+                                            np.zeros((1,), np.int32))
+                    prev, _ = self._dummy_feed
+                    mask = np.zeros_like(plan.feed_idx)
+                (result, logprobs), self._kv_data = self.runner.step_sample_fb(
+                    self.params, self._kv_data, batch, prev,
+                    jnp.asarray(mask), jnp.asarray(plan.feed_idx),
+                    jnp.asarray(seeds), jnp.asarray(spos),
+                    jnp.asarray(temps), jnp.asarray(topks),
+                    jnp.asarray(topps))
+            elif plan.feed_mask is not None:
+                result, self._kv_data = self.runner.step_greedy_fb(
+                    self.params, self._kv_data, batch, self._feed_src,
+                    jnp.asarray(plan.feed_mask), jnp.asarray(plan.feed_idx))
+            elif plan.use_greedy:
+                result, self._kv_data = self.runner.step_greedy(
+                    self.params, self._kv_data, batch)
             else:
-                if not hasattr(self, "_dummy_feed"):
-                    self._dummy_feed = (jnp.zeros((1,), jnp.int32),
-                                        np.zeros((1,), np.int32))
-                prev, _ = self._dummy_feed
-                mask = np.zeros_like(plan.feed_idx)
-            (result, logprobs), self._kv_data = self.runner.step_sample_fb(
-                self.params, self._kv_data, batch, prev,
-                jnp.asarray(mask), jnp.asarray(plan.feed_idx),
-                jnp.asarray(seeds), jnp.asarray(spos),
-                jnp.asarray(temps), jnp.asarray(topks),
-                jnp.asarray(topps))
-        elif plan.feed_mask is not None:
-            result, self._kv_data = self.runner.step_greedy_fb(
-                self.params, self._kv_data, batch, self._feed_src,
-                jnp.asarray(plan.feed_mask), jnp.asarray(plan.feed_idx))
-            self.pipeline_stats["fed_steps"] += 1
-        elif plan.use_greedy:
-            result, self._kv_data = self.runner.step_greedy(
-                self.params, self._kv_data, batch)
-        else:
-            result, self._kv_data = self.runner.step(self.params,
-                                                     self._kv_data, batch)
-        if plan.use_greedy:
-            self._feed_src = result
-            self._feed_slot = {item.seq.uid: i
-                               for i, item in enumerate(plan.sched)}
-        self.pipeline_stats["steps"] += 1
-        dt = time.perf_counter() - t0
-        self.pipeline_stats["dispatch_s"] += dt
-        if self._obs is not None:
-            self._obs.on_dispatch(dt, plan.feed_mask is not None)
-        return _InFlightStep(plan.sched, result, plan.use_greedy,
-                             logprobs=logprobs)
+                result, self._kv_data = self.runner.step(self.params,
+                                                         self._kv_data, batch)
+            if plan.use_greedy:
+                self._feed_src = result
+                self._feed_slot = {item.seq.uid: i
+                                   for i, item in enumerate(plan.sched)}
+            span.count(steps=1, fed_steps=int(fed))
+            return _InFlightStep(plan.sched, result, plan.use_greedy,
+                                 logprobs=logprobs)
 
     def _commit_step(self, fl: _InFlightStep) -> Tuple[int, Dict[int, Any]]:
         """COMMIT: apply a step's host readback — in the pipelined loop
@@ -1761,44 +1774,42 @@ class InferenceEngineV2:
         committed stream: they extend each sequence's replay ``gen_log``
         and land in the write-ahead journal."""
         self._pre_commit(fl)
-        t0 = time.perf_counter()
-        result = np.asarray(fl.result)
-        lps = np.asarray(fl.logprobs) if fl.logprobs is not None else None
-        dt = time.perf_counter() - t0
-        self.pipeline_stats["commit_block_s"] += dt
+        step = self._step_counter
+        with self._spans.span("serve/commit_block", step=step):
+            result = np.asarray(fl.result)
+            lps = np.asarray(fl.logprobs) if fl.logprobs is not None \
+                else None
         obs = self._obs
         now = time.monotonic() if obs is not None else 0.0
-        if obs is not None:
-            obs.on_commit_block(dt)
-        t_apply = time.perf_counter() if obs is not None else 0.0
         out: Dict[int, Any] = {}
         journal_toks: Dict[int, List[int]] = {}
-        for i, item in enumerate(fl.sched):
-            if i in fl.dead:
-                continue
-            if item.is_last_chunk:
-                if fl.use_greedy:
-                    tok = int(result[i])
-                    out[item.seq.uid] = tok
-                    item.seq.gen_log.append(tok)
-                    if lps is not None \
-                            and item.seq.sampling is not None \
-                            and item.seq.sampling.logprobs:
-                        item.seq.logprob_log.append(float(lps[i]))
-                    if self.journal is not None:
-                        journal_toks[item.seq.uid] = [tok]
-                else:
-                    out[item.seq.uid] = result[i]
-                if obs is not None:
-                    # the last chunk's output (token or logits) is this
-                    # request's first host-visible result -> TTFT/TPOT
-                    obs.on_token_commit(item.seq, now)
-                item.seq.status = SequenceStatus.WAITING
-        if self.journal is not None:
-            self.journal.tokens(journal_toks)
-        self._finish_commit(fl)
+        with self._spans.span("serve/commit_apply", step=step):
+            for i, item in enumerate(fl.sched):
+                if i in fl.dead:
+                    continue
+                if item.is_last_chunk:
+                    if fl.use_greedy:
+                        tok = int(result[i])
+                        out[item.seq.uid] = tok
+                        item.seq.gen_log.append(tok)
+                        if lps is not None \
+                                and item.seq.sampling is not None \
+                                and item.seq.sampling.logprobs:
+                            item.seq.logprob_log.append(float(lps[i]))
+                        if self.journal is not None:
+                            journal_toks[item.seq.uid] = [tok]
+                    else:
+                        out[item.seq.uid] = result[i]
+                    if obs is not None:
+                        # the last chunk's output (token or logits) is
+                        # this request's first host-visible result ->
+                        # TTFT/TPOT
+                        obs.on_token_commit(item.seq, now)
+                    item.seq.status = SequenceStatus.WAITING
+            if self.journal is not None:
+                self.journal.tokens(journal_toks)
+            self._finish_commit(fl)
         if obs is not None:
-            obs.on_commit_apply(time.perf_counter() - t_apply)
             obs.after_commit(self._step_counter)
         return len(fl.sched), out
 
@@ -1835,6 +1846,12 @@ class InferenceEngineV2:
         Sequences must have no pending tokens (drain with put() first);
         returns {uid: emitted tokens}, ending with eos when it fired.
         The token stream is identical to the synchronous per-step path."""
+        # speculative fast path (greedy batches only — sampled
+        # sequences need lossless rejection sampling, and a logprobs
+        # request needs the sampler program's per-token logprob output,
+        # which the verify pass does not produce); token-identical to
+        # the pipelined path by the verify construction
+        impl = self._decode_pipelined_impl
         if self.spec_mode != "off" and batch_uids \
                 and hasattr(self.runner, "decode_loop") \
                 and all((s := self.state.get(u)) is not None
@@ -1842,15 +1859,11 @@ class InferenceEngineV2:
                              or (s.sampling.greedy
                                  and not s.sampling.logprobs))
                         and not s.in_flight for u in batch_uids):
-            # speculative fast path (greedy batches only — sampled
-            # sequences need lossless rejection sampling, and a
-            # logprobs request needs the sampler program's per-token
-            # logprob output, which the verify pass does not produce);
-            # token-identical to this method by the verify construction
-            return self.decode_spec(batch_uids, first_tokens, n,
-                                    eos_token_id=eos_token_id)
-        return self._decode_pipelined_impl(batch_uids, first_tokens, n,
-                                           eos_token_id=eos_token_id)
+            impl = self.decode_spec
+        with self._spans.span("serve/decode_pipelined",
+                              seqs=len(batch_uids)):
+            return impl(batch_uids, first_tokens, n,
+                        eos_token_id=eos_token_id)
 
     def _decode_pipelined_impl(self, batch_uids: Sequence[int],
                                first_tokens: Sequence[int], n,
@@ -1905,17 +1918,19 @@ class InferenceEngineV2:
         def commit_one(ring):
             fl = ring.popleft()
             self._pre_commit(fl)
-            t0 = time.perf_counter()
-            toks = np.asarray(fl.result)
-            lps = np.asarray(fl.logprobs) if fl.logprobs is not None \
-                else None
-            dt = time.perf_counter() - t0
-            self.pipeline_stats["commit_block_s"] += dt
+            step = self._step_counter
+            with self._spans.span("serve/commit_block", step=step):
+                toks = np.asarray(fl.result)
+                lps = np.asarray(fl.logprobs) \
+                    if fl.logprobs is not None else None
             obs = self._obs
             now = time.monotonic() if obs is not None else 0.0
+            with self._spans.span("serve/commit_apply", step=step):
+                apply_commit(ring, fl, toks, lps, obs, now)
             if obs is not None:
-                obs.on_commit_block(dt)
-            t_apply = time.perf_counter() if obs is not None else 0.0
+                obs.after_commit(self._step_counter)
+
+        def apply_commit(ring, fl, toks, lps, obs, now):
             journal_toks: Dict[int, List[int]] = {}
             for i, item in enumerate(fl.sched):
                 seq = item.seq
@@ -1976,9 +1991,6 @@ class InferenceEngineV2:
             if self.journal is not None:
                 self.journal.tokens(journal_toks)
             self._finish_commit(fl)
-            if obs is not None:
-                obs.on_commit_apply(time.perf_counter() - t_apply)
-                obs.after_commit(self._step_counter)
 
         def speculate(plan, fl):
             # speculate the next step: every live sequence scheduled in
@@ -2113,7 +2125,7 @@ class InferenceEngineV2:
         K = self.spec_k
         S, MAXB = cfg.max_seqs, cfg.max_blocks_per_seq
         bs = cfg.block_size
-        obs = self._obs
+        obs, spans = self._obs, self._spans
         jnp = jax.numpy
         # per-CALL staging (decode_spec is synchronous — the verify
         # readback completes before the next round reuses these, so
@@ -2198,88 +2210,83 @@ class InferenceEngineV2:
                 draft_arr[i, 0] = last[u]
                 if n_draft:
                     draft_arr[i, 1:] = row
-            t_d = time.perf_counter()
-            toks, _, self._kv_data, _ = self.runner.decode_loop(
-                self.params, self._kv_data, jnp.asarray(tok0),
-                jnp.asarray(start), jnp.asarray(active),
-                jnp.asarray(tables), L,
-                draft_toks=jnp.asarray(draft_arr), eos_id=-1)
-            if obs is not None:
-                obs.on_fused_dispatch(time.perf_counter() - t_d)
-            t_r = time.perf_counter()
-            toks = np.asarray(toks)
-            if obs is not None:
-                obs.on_commit_block(time.perf_counter() - t_r)
-            t_apply = time.perf_counter() if obs is not None else 0.0
-            self.kv_cache.finalize_demotions()
-            self._step_counter += L
-            now = time.monotonic() if obs is not None else 0.0
-            journal_toks: Dict[int, List[int]] = {}
-            round_prop = 0
-            round_acc = 0
-            for i, u in enumerate(ready):
-                seq = seqs[u]
-                emitted = [int(t) for t in toks[i]]
-                d_row = [int(t) for t in draft_arr[i, 1:]]
-                j = accept_length(d_row, emitted)
-                acc = emitted[:j + 1]
-                if len(acc) > rem[u]:
-                    acc = acc[:rem[u]]
-                if eos_token_id is not None and eos_token_id in acc:
-                    acc = acc[:acc.index(eos_token_id) + 1]
-                a = len(acc)
-                seen0 = seq.seen_tokens
-                # acceptance accounting + the multi-token rollback:
-                # consumed inputs == committed tokens == a; the
-                # remaining L - a appended positions are retracted and
-                # their over-allocated blocks freed (deferred-free
-                # semantics are unnecessary here — the verify readback
-                # is already committed, nothing is in flight)
-                seq.seen_tokens = seen0 + a
-                self.state.trim_blocks(seq)
-                seq.last_step = self._step_counter
-                seq.status = SequenceStatus.WAITING
-                # replay history (drain.py): the fed first token joins
-                # gen_log unless it is one of our own committed outputs
-                # being fed back — the decode_batch discipline
-                hist = []
-                if len(seq.prompt_log) + len(seq.gen_log) <= seen0:
-                    hist.append(int(draft_arr[i, 0]))
-                hist.extend(acc)
-                seq.gen_log.extend(hist)
-                out[u].extend(acc)
-                last[u] = acc[-1]
-                # acceptance accounting over the COMMITTABLE window:
-                # the numerator is drafts actually kept (consumed
-                # inputs are lt + d_1..d_{a-1} -> a-1 drafts; a
-                # rolled-back verified draft must not inflate the rate
-                # the bench gates on), and the denominator excludes
-                # the budget-capped tail (only rem-1 drafts could
-                # ever commit this round — the rest is the pinned-L
-                # over-verification padding, not a proposer miss), so
-                # a perfect proposer reads 1.0
-                prop_eff = min(n_draft, rem[u] - 1)
-                acc_drafts = min(j, a - 1)
-                seq.spec_proposed += prop_eff
-                seq.spec_accepted += acc_drafts
-                round_prop += prop_eff
-                round_acc += acc_drafts
-                proposer.observe_commit(seq, seen0, acc, d_row)
+            with spans.span("serve/fused_dispatch", steps=L, seqs=len(ready)):
+                toks, _, self._kv_data, _ = self.runner.decode_loop(
+                    self.params, self._kv_data, jnp.asarray(tok0),
+                    jnp.asarray(start), jnp.asarray(active),
+                    jnp.asarray(tables), L,
+                    draft_toks=jnp.asarray(draft_arr), eos_id=-1)
+            with spans.span("serve/fused_readback", steps=L, seqs=len(ready)):
+                toks = np.asarray(toks)
+            with spans.span("serve/fused_apply", steps=L, seqs=len(ready)):
+                self.kv_cache.finalize_demotions()
+                self._step_counter += L
+                now = time.monotonic() if obs is not None else 0.0
+                journal_toks: Dict[int, List[int]] = {}
+                round_prop = 0
+                round_acc = 0
+                for i, u in enumerate(ready):
+                    seq = seqs[u]
+                    emitted = [int(t) for t in toks[i]]
+                    d_row = [int(t) for t in draft_arr[i, 1:]]
+                    j = accept_length(d_row, emitted)
+                    acc = emitted[:j + 1]
+                    if len(acc) > rem[u]:
+                        acc = acc[:rem[u]]
+                    if eos_token_id is not None and eos_token_id in acc:
+                        acc = acc[:acc.index(eos_token_id) + 1]
+                    a = len(acc)
+                    seen0 = seq.seen_tokens
+                    # acceptance accounting + the multi-token rollback:
+                    # consumed inputs == committed tokens == a; the
+                    # remaining L - a appended positions are retracted and
+                    # their over-allocated blocks freed (deferred-free
+                    # semantics are unnecessary here — the verify readback
+                    # is already committed, nothing is in flight)
+                    seq.seen_tokens = seen0 + a
+                    self.state.trim_blocks(seq)
+                    seq.last_step = self._step_counter
+                    seq.status = SequenceStatus.WAITING
+                    # replay history (drain.py): the fed first token joins
+                    # gen_log unless it is one of our own committed outputs
+                    # being fed back — the decode_batch discipline
+                    hist = []
+                    if len(seq.prompt_log) + len(seq.gen_log) <= seen0:
+                        hist.append(int(draft_arr[i, 0]))
+                    hist.extend(acc)
+                    seq.gen_log.extend(hist)
+                    out[u].extend(acc)
+                    last[u] = acc[-1]
+                    # acceptance accounting over the COMMITTABLE window:
+                    # the numerator is drafts actually kept (consumed
+                    # inputs are lt + d_1..d_{a-1} -> a-1 drafts; a
+                    # rolled-back verified draft must not inflate the rate
+                    # the bench gates on), and the denominator excludes
+                    # the budget-capped tail (only rem-1 drafts could
+                    # ever commit this round — the rest is the pinned-L
+                    # over-verification padding, not a proposer miss), so
+                    # a perfect proposer reads 1.0
+                    prop_eff = min(n_draft, rem[u] - 1)
+                    acc_drafts = min(j, a - 1)
+                    seq.spec_proposed += prop_eff
+                    seq.spec_accepted += acc_drafts
+                    round_prop += prop_eff
+                    round_acc += acc_drafts
+                    proposer.observe_commit(seq, seen0, acc, d_row)
+                    if self.journal is not None:
+                        journal_toks[u] = hist
+                    if obs is not None and a:
+                        obs.on_token_commit(seq, now, n=a)
+                        # traced requests get a spec-round mark on their
+                        # fleet track (no-op for untraced sequences)
+                        obs.on_spec_commit(seq, acc_drafts, prop_eff)
+                    if len(out[u]) >= budgets[u] or (
+                            eos_token_id is not None
+                            and acc[-1] == eos_token_id):
+                        live.discard(u)
                 if self.journal is not None:
-                    journal_toks[u] = hist
-                if obs is not None and a:
-                    obs.on_token_commit(seq, now, n=a)
-                    # traced requests get a spec-round mark on their
-                    # fleet track (no-op for untraced sequences)
-                    obs.on_spec_commit(seq, acc_drafts, prop_eff)
-                if len(out[u]) >= budgets[u] or (
-                        eos_token_id is not None
-                        and acc[-1] == eos_token_id):
-                    live.discard(u)
-            if self.journal is not None:
-                self.journal.tokens(journal_toks)
+                    self.journal.tokens(journal_toks)
             if obs is not None:
-                obs.on_commit_apply(time.perf_counter() - t_apply)
                 obs.on_spec(round_prop, round_acc)
                 obs.after_commit(self._step_counter)
         if obs is not None:

@@ -98,12 +98,16 @@ class SequenceDescriptor:
     deadline_at: Optional[float] = None
     deadline_s: Optional[float] = None
     # telemetry lifecycle stamps (time.monotonic; None until reached /
-    # when DSTPU_TELEMETRY=0): admission, first scheduled chunk, first
-    # and latest COMMITTED output token. Per-request SLO invariants
-    # (TTFT >= queue wait, monotone token times) are checkable straight
-    # off these; the registry histograms aggregate them
+    # when DSTPU_TELEMETRY=0): admission (the DUE instant when the
+    # caller passes ``arrivals``), first scheduled chunk, first and
+    # latest COMMITTED output token. ``put_at`` is when put() received
+    # the request (set by the engine itself), so the first-token time
+    # splits into door wait (put_at - admitted_at), scheduler wait
+    # (first_sched_at - put_at) and prefill (first_token_at -
+    # first_sched_at). The registry histograms aggregate them
     # (telemetry/serve.py, docs/observability.md).
     admitted_at: Optional[float] = None
+    put_at: Optional[float] = None
     first_sched_at: Optional[float] = None
     first_token_at: Optional[float] = None
     last_token_at: Optional[float] = None

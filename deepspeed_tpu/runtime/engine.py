@@ -188,9 +188,16 @@ class Engine:
         # training observatory (telemetry/train.py, docs/observability.md
         # "Training observatory"): step-time attribution + goodput ledger
         # + anomaly sentinel at the existing host boundaries below.
-        # DSTPU_TRAIN_OBS=0 (or DSTPU_TELEMETRY=0) leaves this None and
-        # train_batch on its exact pre-observer path.
+        # DSTPU_TRAIN_OBS=0 (or DSTPU_TELEMETRY=0) leaves this None:
+        # train_batch then waits for nothing and feeds no registry.
+        from ..telemetry.trace import SpanSet
         from ..telemetry.train import train_observer
+        #: train_batch's own totals (seconds by bracket, steps), readable
+        #: without the registry; filled by the brackets of
+        #: telemetry/trace.py
+        self.step_stats = {"steps": 0, "stage_s": 0.0, "dispatch_s": 0.0,
+                           "commit_apply_s": 0.0}
+        self._spans = SpanSet(self.step_stats, lambda: self._train_obs)
         self._train_obs = train_observer(self)
 
         # ZeRO-Offload mode: the optimizer STEP runs on the host CPU — fp32
@@ -802,134 +809,113 @@ class Engine:
         """Run one full global step (micro_batch × GAS samples) and return the
         mean loss. The one-call equivalent of forward+backward+step.
 
-        With the training observatory attached (``self._train_obs``,
-        DSTPU_TRAIN_OBS) the step's wall clock decomposes at the
-        EXISTING host boundaries below into data_wait / stage /
-        dispatch / device_execute / commit_apply / host_gap
-        (docs/observability.md "Training observatory"); the kill switch
-        restores this exact path minus the observer calls."""
+        Four brackets (``telemetry/trace.py``) split the call at its
+        host boundaries: ``train/stage``, ``train/dispatch``,
+        ``train/device_wait`` and ``train/commit_apply`` (and
+        ``train/step_exit`` around the observer closing its books). They
+        fill ``self.step_stats`` and, with the training observatory attached
+        (``self._train_obs``, DSTPU_TRAIN_OBS), its data_wait / stage /
+        dispatch / device_execute / commit_apply / host_gap attribution
+        (docs/observability.md "Training observatory"). The step just
+        dispatched is never waited for here: the device bracket waits
+        for the step BEFORE it, so the device has this one queued."""
         obs = self._train_obs
+        spans = self._spans
+        step = self.global_steps
         if obs is not None:
             obs.on_step_enter()
         try:
-            self.tput_timer.start()
-            self.timers(TRAIN_BATCH_TIMER).start()
-            expected = self.config.train_batch_size
-            lead = jax.tree_util.tree_leaves(batch)[0].shape[0]
-            if lead != expected:
-                raise ConfigError(
-                    f"train_batch expects leading dim == train_batch_size ({expected}), got {lead}")
+            with spans.span("train/stage", step=step):
+                self.tput_timer.start()
+                self.timers(TRAIN_BATCH_TIMER).start()
+                expected = self.config.train_batch_size
+                lead = jax.tree_util.tree_leaves(batch)[0].shape[0]
+                if lead != expected:
+                    raise ConfigError(
+                        f"train_batch expects leading dim == train_batch_size ({expected}), got {lead}")
 
-            from ..resilience.fault_injection import get_fault_injector
-            get_fault_injector().maybe_fire("step", step=self.global_steps)
-            if self._watchdog is not None:
-                self._watchdog.step_start(self.global_steps)
+                from ..resilience.fault_injection import get_fault_injector
+                get_fault_injector().maybe_fire("step", step=step)
+                if self._watchdog is not None:
+                    self._watchdog.step_start(step)
 
-            if self.flops_profiler is not None:
-                self.flops_profiler.maybe_start(self.global_steps, batch)
-            self._ensure_opt_state_resident()
-            self._ensure_params_resident()
-            if self._watchdog is not None:
-                self._watchdog.phase("compiled_step")
+                if self.flops_profiler is not None:
+                    self.flops_profiler.maybe_start(step, batch)
+                self._ensure_opt_state_resident()
+                self._ensure_params_resident()
+                if self._watchdog is not None:
+                    self._watchdog.phase("compiled_step")
+            with spans.span("train/dispatch", step=step):
+                self.state, metrics = self._train_step(self.state, batch)
+            # the exposed device wait, with one step queued behind it:
+            # the PREVIOUS step's metrics, which the observer's sentinel
+            # then reads as ready values (nothing to wait for without
+            # an observer, or before the second step)
+            prev_loss = obs.previous_loss() if obs is not None else None
+            with spans.span("train/device_wait", step=step):
+                if prev_loss is not None:
+                    # dslint: allow(DSL001): the device_execute bracket
+                    # is the deliberate readback the attribution layer
+                    # measures; a deferred XLA error of the previous
+                    # step surfaces here
+                    jax.block_until_ready(prev_loss)
+            with spans.span("train/commit_apply", step=step) as span:
+                if self._stream_params:
+                    # re-park streamed leaves in pinned_host (inferred out
+                    # placements land them on device after the update)
+                    self.state = self._place_state(self.state)
+                self._evict_opt_state()
+                self._last_metrics = metrics
+
+                self.global_steps += 1
+                self.global_samples += expected
+                if self.compression_scheduler is not None and \
+                        self.compression_scheduler.pending():
+                    # state.step is the gate the compiled transform sees, but
+                    # reading it would block on the device every step (and a
+                    # technique whose offset is never reached would keep that
+                    # sync alive for the whole run). global_steps is its
+                    # host-side upper bound — they differ only by
+                    # overflow-skipped steps (rare, fp16 warmup), so the
+                    # announcement log may fire a few steps early; the
+                    # compiled gating itself is unaffected.
+                    self.compression_scheduler.check(self.global_steps)
+                self.timers(TRAIN_BATCH_TIMER).stop(barrier_value=metrics.loss)
+                self.tput_timer.stop(global_step=True, report_speed=True)
+                self._maybe_log(metrics)
+                if self.flops_profiler is not None:
+                    # before param eviction: the profiler counts param elements
+                    self.flops_profiler.maybe_stop(self.global_steps, metrics)
+                self._evict_params()
+                if self._watchdog is not None:
+                    # step_end blocks on the loss so the recorded duration is
+                    # the TRUE step time, not async dispatch time (and a hung
+                    # step parks us here — exactly where the watchdog is
+                    # watching)
+                    # dslint: allow(DSL001): the watchdog's sanctioned
+                    # blocking site
+                    jax.block_until_ready(metrics.loss)
+                    self._watchdog.step_end(self.global_steps)
+                span.count(steps=1)
         except BaseException:
-            # a pre-dispatch failure (validation, injector fire, swap-in
-            # error) aborts the observed step too: a leaked anchor would
-            # file the caller's whole recovery as the next step's
-            # data_wait — and could read as a bogus stall
-            if obs is not None:
-                obs.on_step_abort()
-            raise
-        if obs is not None:
-            obs.on_staged()
-        try:
-            self.state, metrics = self._train_step(self.state, batch)
-        except BaseException:
-            # a dead step must not read as an eternal stall (with
-            # action='abort' a stale in-flight marker would kill the
-            # process after the caller recovered)
+            # a failure anywhere in the step — validation, injector fire,
+            # swap-in error, a dead dispatch, a deferred XLA error at a
+            # blocking read, monitor IO — must not read as an eternal
+            # stall (with action='abort' a stale in-flight marker would
+            # kill the process after the caller recovered), nor leak the
+            # observer's anchors: they would file the caller's whole
+            # recovery as the next step's data_wait
             if self._watchdog is not None:
                 self._watchdog.step_abort()
             if obs is not None:
                 obs.on_step_abort()
             raise
         if obs is not None:
-            obs.on_dispatched()
-            if obs.sync:
-                try:
-                    # the observer's ONE sanctioned blocking site: the
-                    # exposed device wait IS the device_execute
-                    # component (it subsumes the sync the watchdog/
-                    # _maybe_log pay below — their later blocks then
-                    # cost ~0). DSTPU_TRAIN_OBS_SYNC=0 skips it for
-                    # TPU loops that rely on dispatch-ahead overlap
-                    # (device_execute then reads ~0; the sentinel lags
-                    # one step)
-                    # dslint: allow(DSL001): the device_execute bracket
-                    # is the deliberate readback the attribution layer
-                    # measures
-                    jax.block_until_ready(metrics.loss)
-                except BaseException:
-                    if self._watchdog is not None:
-                        self._watchdog.step_abort()  # deferred XLA error
-                    obs.on_step_abort()
-                    raise
-            obs.on_device_done()
-        try:
-            if self._stream_params:
-                # re-park streamed leaves in pinned_host (inferred out
-                # placements land them on device after the update)
-                self.state = self._place_state(self.state)
-            self._evict_opt_state()
-            self._last_metrics = metrics
-
-            self.global_steps += 1
-            self.global_samples += expected
-            if self.compression_scheduler is not None and \
-                    self.compression_scheduler.pending():
-                # state.step is the gate the compiled transform sees, but
-                # reading it would block on the device every step (and a
-                # technique whose offset is never reached would keep that
-                # sync alive for the whole run). global_steps is its
-                # host-side upper bound — they differ only by
-                # overflow-skipped steps (rare, fp16 warmup), so the
-                # announcement log may fire a few steps early; the
-                # compiled gating itself is unaffected.
-                self.compression_scheduler.check(self.global_steps)
-            self.timers(TRAIN_BATCH_TIMER).stop(barrier_value=metrics.loss)
-            self.tput_timer.stop(global_step=True, report_speed=True)
-            self._maybe_log(metrics)
-            if self.flops_profiler is not None:
-                # before param eviction: the profiler counts param elements
-                self.flops_profiler.maybe_stop(self.global_steps, metrics)
-            self._evict_params()
-            if self._watchdog is not None:
-                # step_end blocks on the loss so the recorded duration is
-                # the TRUE step time, not async dispatch time (and a hung
-                # step parks us here — exactly where the watchdog is
-                # watching)
-                try:
-                    # dslint: allow(DSL001): the watchdog's sanctioned
-                    # blocking site (free when the observer already
-                    # blocked)
-                    jax.block_until_ready(metrics.loss)
-                except BaseException:
-                    self._watchdog.step_abort()   # deferred XLA error
-                    raise
-                self._watchdog.step_end(self.global_steps)
-        except BaseException:
-            # commit-apply failures — a deferred XLA error surfacing at
-            # the blocking timer/watchdog/log reads (the FIRST blocking
-            # point when DSTPU_TRAIN_OBS_SYNC=0), monitor IO — abort
-            # the observed step too: same leaked-anchor rule as the
-            # pre-dispatch handler above
-            if obs is not None:
-                obs.on_step_abort()
-            raise
-        if obs is not None:
-            # closes the books: commit_apply tail + host_gap closure +
-            # the anomaly sentinel's readbacks (values ready)
-            obs.on_step_exit(self.global_steps, metrics,
-                             samples=expected)
+            # closes the books: the host_gap closure, and the anomaly
+            # sentinel's readbacks of the previous step's ready values
+            with spans.span("train/step_exit", step=step):
+                obs.on_step_exit(self.global_steps, metrics,
+                                 samples=expected)
         self._maybe_handle_preemption()
         return metrics.loss
 

@@ -6,7 +6,7 @@ and log-bucketed streaming histograms with Prometheus/JSON export
 spans dumped as Chrome-trace JSON on watchdog fire / fault-drill crash /
 drain (:mod:`.flight_recorder`), per-request SLO instrumentation for the
 v2 serve engine (:mod:`.serve`), a MonitorMaster bridge
-(:mod:`.monitor_bridge`), optional ``jax.profiler`` capture
+(:mod:`.monitor_bridge`), the engines' brackets on the profiler's clock
 (:mod:`.trace`) and the ``bin/dstpu_top`` renderer (:mod:`.top`).
 
 Kill switch: ``DSTPU_TELEMETRY=0`` — every registry call becomes a
@@ -32,7 +32,7 @@ from .registry import (COMM_CANONICAL_KINDS, REGISTERED_METRICS, Counter,
                        new_registry, record_phase_tflops, set_registry,
                        telemetry_enabled)
 from .serve import ServeObserver, serve_observer
-from .trace import annotate, maybe_trace, trace_dir
+from .trace import SPANS, SpanSet
 from .train import (TrainObserver, train_comm_share, train_observer,
                     train_skew_report)
 
@@ -40,16 +40,16 @@ __all__ = [
     "ATTRIBUTION_COMPONENTS", "COMM_CANONICAL_KINDS", "Counter",
     "FlightRecorder", "Gauge", "Histogram", "LoadResult",
     "MetricsRegistry", "MonitorBridge", "NullRegistry",
-    "PoissonArrivals", "REGISTERED_METRICS", "Request", "ServeObserver",
-    "TRAIN_ATTRIBUTION_COMPONENTS", "TraceArrivals", "TrainObserver",
-    "UniformArrivals", "WorkloadMix", "annotate",
+    "PoissonArrivals", "REGISTERED_METRICS", "Request", "SPANS",
+    "ServeObserver", "SpanSet", "TRAIN_ATTRIBUTION_COMPONENTS",
+    "TraceArrivals", "TrainObserver", "UniformArrivals", "WorkloadMix",
     "attach_monitor", "attribution_report", "auto_dump",
     "build_requests", "comm_counter", "comm_share", "component_totals",
     "flight_dir", "get_registry", "goodput_from_ledgers",
-    "goodput_report", "load_ledger_events", "maybe_trace",
-    "merge_chrome_traces", "merge_snapshots", "new_registry",
+    "goodput_report", "load_ledger_events", "merge_chrome_traces",
+    "merge_snapshots", "new_registry",
     "record_phase_tflops", "register_recorder", "request_tracks",
     "run_open_loop", "serve_observer", "set_registry", "sweep_capacity",
-    "telemetry_enabled", "trace_dir", "train_attribution_report",
+    "telemetry_enabled", "train_attribution_report",
     "train_comm_share", "train_observer", "train_skew_report",
 ]

@@ -66,6 +66,9 @@ REGISTERED_METRICS = {
     "serve_ttft_s": "admission -> first committed token",
     "serve_tpot_s": "per-token gap between committed tokens",
     "serve_queue_wait_s": "admission -> first scheduled chunk",
+    "serve_door_wait_s": "admission (due) stamp -> put() received it",
+    "serve_sched_wait_s": "put() received it -> first scheduled chunk",
+    "serve_prefill_s": "first scheduled chunk -> first committed token",
     "serve_plan_s": "per-step plan (scheduler + staging) time",
     "serve_dispatch_s": "per-step dispatch (enqueue) time",
     "serve_commit_block_s": "per-commit blocking readback time",
@@ -117,7 +120,8 @@ REGISTERED_METRICS = {
     "train_data_wait_s": "between-step span (caller's data fetch)",
     "train_stage_s": "per-step staging (validation, arming, swap-in)",
     "train_dispatch_s": "per-step compiled-step dispatch time",
-    "train_device_execute_s": "per-step exposed device wait at readback",
+    "train_device_execute_s":
+        "per-step exposed device wait (for the step before, this one queued)",
     "train_commit_apply_s": "per-step host bookkeeping after readback",
     "train_host_gap_s": "per-step residual host time between brackets",
     "train_step_wall_s": "per-committed-step wall between exit boundaries",

@@ -6,15 +6,17 @@ guard): it binds the hot metric handles once at engine build and turns
 the engine's EXISTING host-side boundaries into SLO numbers —
 
   * admission (``put``)          -> ``serve_requests_admitted`` +
-    ``seq.admitted_at`` stamp;
-  * first schedule (plan)        -> ``serve_queue_wait_s``;
-  * token commit (commit/fused)  -> ``serve_ttft_s`` on the first
-    committed token, ``serve_tpot_s`` on every later one,
-    ``serve_tokens_committed``;
+    ``seq.admitted_at`` stamp, ``serve_door_wait_s``;
+  * first schedule (plan)        -> ``serve_queue_wait_s``,
+    ``serve_sched_wait_s``;
+  * token commit (commit/fused)  -> ``serve_ttft_s`` and
+    ``serve_prefill_s`` on the first committed token, ``serve_tpot_s``
+    on every later one, ``serve_tokens_committed``;
   * rejection / abort / flush    -> the outcome counters goodput is
     computed from;
-  * plan/dispatch/commit phases  -> flight-recorder spans (the same
-    phase names the watchdog brackets carry).
+  * the engine's brackets (``telemetry/trace.py``, ``on_span``) ->
+    flight-recorder spans (the phase names the watchdog carries) and
+    the plan/dispatch/commit histograms.
 
 Everything is pure host work (floats, dict lookups on pre-bound
 handles) on paths that already run at those boundaries — no device
@@ -23,8 +25,9 @@ are bit-identical with telemetry on or off (tier-1 asserts 0 host
 callbacks and 0 fresh compiles on the warm path either way). The
 per-request timestamps additionally live on the SequenceDescriptor
 (``admitted_at``/``first_sched_at``/``first_token_at``/
-``last_token_at``), so TTFT >= queue-wait is checkable per request, not
-just in aggregate.
+``last_token_at``, and ``put_at`` set by the engine itself), so the
+first-token time splits per request into door wait, scheduler wait and
+prefill, not just in aggregate.
 
 Export: every ``DSTPU_TELEMETRY_EXPORT_EVERY`` committed steps the
 registry snapshot is atomically published to ``DSTPU_TELEMETRY_EXPORT``
@@ -109,12 +112,22 @@ class ServeObserver:
         self.h_ttft = r.histogram("serve_ttft_s")
         self.h_tpot = r.histogram("serve_tpot_s")
         self.h_queue = r.histogram("serve_queue_wait_s")
+        # the first-token wait split from inside (sequence.py stamps):
+        # due -> put() received it -> first schedule -> first token
+        self.h_door = r.histogram("serve_door_wait_s")
+        self.h_sched = r.histogram("serve_sched_wait_s")
+        self.h_prefill = r.histogram("serve_prefill_s")
         self.h_plan = r.histogram("serve_plan_s")
         self.h_dispatch = r.histogram("serve_dispatch_s")
         self.h_commit = r.histogram("serve_commit_block_s")
         self.h_apply = r.histogram("serve_commit_apply_s")
         self.h_gap = r.histogram("serve_host_gap_s")
         self.h_wall = r.histogram("serve_step_wall_s")
+        #: the brackets' histograms, by the name trace.SPANS gives
+        self._span_hist = {"serve_plan_s": self.h_plan,
+                           "serve_dispatch_s": self.h_dispatch,
+                           "serve_commit_block_s": self.h_commit,
+                           "serve_commit_apply_s": self.h_apply}
         self.h_promote = r.histogram("prefix_promote_wait_s")
         self.c_promoted = r.counter("prefix_promoted_blocks")
         self.c_flight_dropped = r.counter("flight_spans_dropped")
@@ -166,6 +179,8 @@ class ServeObserver:
         put() call time."""
         seq.admitted_at = now
         self.c_admitted.inc()
+        if seq.put_at is not None:
+            self.h_door.observe(seq.put_at - now)
         if self.req_spans:
             # anchored at the (possibly past) admission stamp so the
             # uid track reads admit -> queue -> ttft in order even when
@@ -182,6 +197,8 @@ class ServeObserver:
             seq = item.seq
             if seq.first_sched_at is None:
                 seq.first_sched_at = now
+                if seq.put_at is not None:
+                    self.h_sched.observe(now - seq.put_at)
                 if seq.admitted_at is not None:
                     self.h_queue.observe(now - seq.admitted_at)
                     if req:
@@ -202,6 +219,8 @@ class ServeObserver:
         self.c_tokens.inc(n)
         if seq.first_token_at is None:
             seq.first_token_at = now
+            if seq.first_sched_at is not None:
+                self.h_prefill.observe(now - seq.first_sched_at)
             if seq.admitted_at is not None:
                 self.h_ttft.observe(now - seq.admitted_at)
                 if self.req_spans:
@@ -213,36 +232,23 @@ class ServeObserver:
                 self.h_tpot.observe((now - last) / n, n=n)
         seq.last_token_at = now
 
-    def on_plan(self, dt):
-        self.h_plan.observe(dt)
+    def on_span(self, span, t0, t1):
+        """One of the engine's brackets closed (``telemetry/trace.py``):
+        the same duration goes to the flight ring, under the phase name
+        the watchdog carries, to the bracket's histogram and to the
+        attribution accumulator. ``serve_steps`` counts pipelined
+        dispatches only (a fused round is one dispatch of n steps,
+        visible as spec_rounds / token commits). Registered DSL001 hot
+        path — a ring append, one observe and two adds."""
+        spec = span.spec
+        dt = t1 - t0
+        self.flight.record(spec.phase, t0, t1, span.args.get("step"))
+        self._span_hist[spec.hist].observe(dt)
         self._acc += dt
-
-    def on_dispatch(self, dt, fed):
-        self.c_steps.inc()
-        if fed:
-            self.c_fed.inc()
-        self.h_dispatch.observe(dt)
-        self._acc += dt
-
-    def on_fused_dispatch(self, dt):
-        """One fused decode_batch / speculative-verify enqueue (n steps
-        in one dispatch): same dispatch histogram, no per-step counter
-        (``serve_steps`` counts pipelined dispatches; fused rounds are
-        already visible as spec_rounds / token commits). Registered
-        DSL001 hot path — one observe + one add."""
-        self.h_dispatch.observe(dt)
-        self._acc += dt
-
-    def on_commit_block(self, dt):
-        self.h_commit.observe(dt)
-        self._acc += dt
-
-    def on_commit_apply(self, dt):
-        """Host-side commit application — token bookkeeping, journal
-        appends, rollbacks and deferred flushes between the blocking
-        readback and the commit boundary. Registered DSL001 hot path."""
-        self.h_apply.observe(dt)
-        self._acc += dt
+        if span.name == "serve/dispatch":
+            self.c_steps.inc()
+            if span.args.get("fed"):
+                self.c_fed.inc()
 
     # ---------------- step-time attribution boundaries ----------------- #
 
@@ -383,9 +389,6 @@ class ServeObserver:
             self._req_event("req_finish", seq.uid, seq.trace_id,
                             outcome=outcome)
 
-    def phase(self, name, step=None):
-        self.flight.phase(name, step)
-
     # --------------------- boundaries / exports ----------------------- #
 
     def after_commit(self, step: int) -> None:
@@ -476,10 +479,11 @@ class ServeObserver:
     # ---------------------------- reports ----------------------------- #
 
     def slo_report(self) -> Dict[str, Any]:
-        """The serving-layer summary: TTFT/TPOT/queue-wait percentiles,
-        outcome counts and the goodput fraction (completed / terminal
-        outcomes; drained requests are in flight to a survivor, not an
-        outcome)."""
+        """The serving-layer summary: TTFT/TPOT/queue-wait percentiles
+        (and the first-token wait's three parts: door, scheduler,
+        prefill), outcome counts and the goodput fraction (completed /
+        terminal outcomes; drained requests are in flight to a survivor,
+        not an outcome)."""
         self.sync_gauges()
         return slo_report_from_registry(self.registry)
 
@@ -514,6 +518,9 @@ def slo_report_from_registry(registry) -> Dict[str, Any]:
         "ttft_s": r.histogram("serve_ttft_s").summary(),
         "tpot_s": r.histogram("serve_tpot_s").summary(),
         "queue_wait_s": r.histogram("serve_queue_wait_s").summary(),
+        "door_wait_s": r.histogram("serve_door_wait_s").summary(),
+        "sched_wait_s": r.histogram("serve_sched_wait_s").summary(),
+        "prefill_s": r.histogram("serve_prefill_s").summary(),
         "tokens_committed": c("serve_tokens_committed"),
         "requests": {
             "admitted": c("serve_requests_admitted"),

@@ -1,46 +1,138 @@
-"""Optional ``jax.profiler`` hooks, gated on ``DSTPU_TRACE_DIR``.
+"""Program spans on the profiler's clock: one bracket per engine boundary.
 
-The flight recorder answers "what was the HOST doing"; a real device
-timeline needs the XLA profiler. These helpers make that a zero-code
-knob: set ``DSTPU_TRACE_DIR`` and the bench phases (and any caller of
-:func:`maybe_trace`) capture a TensorBoard-loadable trace of their
-measured window; unset, both helpers are inert nullcontexts — no jax
-import, no overhead.
+An engine owns a :class:`SpanSet` whether or not ``DSTPU_TELEMETRY`` is
+on. ``with spans.span("serve/dispatch", step=n, fed=1):`` opens a
+``jax.profiler.TraceAnnotation("dstpu:serve/dispatch", ...)`` (a ``TraceMe``
+is inert while no profiler session is live, so there is no gate) and, on
+leaving, takes one ``perf_counter`` pair and hands the duration to every
+consumer that used to time the boundary itself:
+
+  * the engine's own totals (``InferenceEngineV2.pipeline_stats``,
+    ``Engine.step_stats``), under the span's ``total`` key;
+  * the watchdog, which is told the span's ``phase`` on entry;
+  * the attached observer (``on_span``), which files it in the flight
+    ring under ``phase`` and in a registry histogram (``hist`` for the
+    serve engine; the train observer's own, by phase, at step exit).
+
+:data:`SPANS` is the whole vocabulary: a span that is not in the table
+cannot be opened. Names carry no dots (the benchmark's readers split
+keys on dots). The annotation's arguments are small host integers read
+after planning; they never reach a jit signature.
 """
 
 from __future__ import annotations
 
-import os
-from contextlib import contextmanager, nullcontext
-from typing import Optional
+import time
+from typing import Any, Callable, Dict, NamedTuple, Optional
+
+import jax
+
+PREFIX = "dstpu:"
 
 
-def trace_dir() -> Optional[str]:
-    return os.environ.get("DSTPU_TRACE_DIR") or None
+class SpanSpec(NamedTuple):
+    total: Optional[str]   # key in the engine's totals dict
+    phase: Optional[str]   # flight-ring / watchdog phase name
+    hist: Optional[str]    # registry histogram the serve observer files it in
 
 
-@contextmanager
-def maybe_trace(label: str = "dstpu"):
-    """``jax.profiler.trace`` around the body when DSTPU_TRACE_DIR is
-    set (trace lands in ``<dir>/<label>``); yields whether tracing is
-    active."""
-    d = trace_dir()
-    if not d:
-        yield False
-        return
-    import jax
-    jax.profiler.start_trace(os.path.join(d, label))
-    try:
-        yield True
-    finally:
-        jax.profiler.stop_trace()
+SPANS: Dict[str, SpanSpec] = {
+    # the serve engine's public entry points
+    "serve/put": SpanSpec(None, None, None),
+    "serve/decode_pipelined": SpanSpec(None, None, None),
+    "serve/decode_batch": SpanSpec(None, None, None),
+    # the pipelined step: plan -> dispatch -> commit (readback, apply)
+    "serve/plan": SpanSpec("plan_s", "plan", "serve_plan_s"),
+    "serve/dispatch": SpanSpec("dispatch_s", "dispatch", "serve_dispatch_s"),
+    "serve/commit_block": SpanSpec("commit_block_s", "commit",
+                                   "serve_commit_block_s"),
+    "serve/commit_apply": SpanSpec("commit_apply_s", "commit_apply",
+                                   "serve_commit_apply_s"),
+    # the fused decode loop: one dispatch and one readback cover n steps
+    "serve/fused_dispatch": SpanSpec("fused_dispatch_s", "dispatch",
+                                     "serve_dispatch_s"),
+    "serve/fused_readback": SpanSpec(None, "commit", "serve_commit_block_s"),
+    "serve/fused_apply": SpanSpec("fused_apply_s", "commit_apply",
+                                  "serve_commit_apply_s"),
+    # the train step. Its observer files each phase's seconds itself, at
+    # step exit, under train_<phase>_s (the wait keeps the registry's and
+    # the ring's name, device_execute)
+    "train/stage": SpanSpec("stage_s", "stage", None),
+    "train/dispatch": SpanSpec("dispatch_s", "dispatch", None),
+    "train/device_wait": SpanSpec(None, "device_execute", None),
+    "train/commit_apply": SpanSpec("commit_apply_s", "commit_apply", None),
+    # the observer closing its books (the sentinel's scalar reads of the
+    # previous step, sampling, export): profiler only
+    "train/step_exit": SpanSpec(None, None, None),
+}
 
 
-def annotate(name: str):
-    """A ``jax.profiler.TraceAnnotation`` context when tracing is
-    enabled (names host spans inside the captured device timeline),
-    else a free nullcontext."""
-    if not trace_dir():
-        return nullcontext()
-    import jax
-    return jax.profiler.TraceAnnotation(name)
+class SpanSet:
+    """The brackets of one engine. ``totals`` is the engine's own dict
+    of accumulated seconds and counts; ``observer()`` gives the engine's
+    observer as it is now (anything with ``on_span``, or None: benches
+    switch it on and off on a live engine); ``watchdog`` (anything with
+    ``phase``) is attached by the engine and may be None."""
+
+    def __init__(self, totals: Dict[str, Any],
+                 observer: Callable[[], Any] = lambda: None):
+        self.totals = totals
+        self.observer = observer
+        self.watchdog = None
+
+    def span(self, name: str, **args: int) -> "Span":
+        return Span(self, name, SPANS[name], args)
+
+
+class Span:
+    """One open bracket. ``args`` (``step`` among them) go on the
+    annotation; ``set`` adds those known only once the work is done
+    (what a plan scheduled), ``count`` adds to the engine's totals,
+    ``void`` withdraws the bracket.
+    Registered DSL001 hot path: two clock reads, a dict add and the
+    observer's fan-out."""
+
+    __slots__ = ("_set", "name", "spec", "args", "_ann", "_t0")
+
+    def __init__(self, span_set: SpanSet, name: str, spec: SpanSpec,
+                 args: Dict[str, int]):
+        self._set, self.name, self.spec, self.args = \
+            span_set, name, spec, args
+
+    def set(self, **args: int) -> None:
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def count(self, **counts: int) -> None:
+        totals = self._set.totals
+        for key, n in counts.items():
+            totals[key] = totals.get(key, 0) + n
+
+    def void(self) -> None:
+        """Nothing was done under this bracket (a plan that scheduled
+        nothing): it closes without reaching totals, ring or histogram,
+        as the boundary it replaced returned before its clock read."""
+        self._t0 = None
+
+    def __enter__(self) -> "Span":
+        wd = self._set.watchdog
+        if wd is not None and self.spec.phase is not None:
+            wd.phase(self.spec.phase)
+        self._ann = jax.profiler.TraceAnnotation(PREFIX + self.name,
+                                                 **self.args)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
+        if self._t0 is None:
+            return
+        total = self.spec.total
+        if total is not None:
+            totals = self._set.totals
+            totals[total] = totals.get(total, 0.0) + (t1 - self._t0)
+        obs = self._set.observer()
+        if obs is not None and self.spec.phase is not None:
+            obs.on_span(self, self._t0, t1)

@@ -13,13 +13,15 @@ host-side boundaries:
     decomposes into ``data_wait`` (the between-step span: the caller's
     data fetch) / ``stage`` (validation, watchdog/profiler arming,
     offload swap-in) / ``dispatch`` (the compiled-step call) /
-    ``device_execute`` (the one sanctioned blocking readback) /
-    ``commit_apply`` (metrics readback, loss-scale + monitor +
-    checkpoint bookkeeping) / ``host_gap`` (the CLOSURE of the sum:
-    wall between step-exit boundaries minus every bracket), so the six
-    components ≡ measured wall by construction — the same closure
-    discipline ``serve_attrib`` gates, gated here by
-    ``bench.py train_obs``;
+    ``device_execute`` (the exposed device wait: the engine waits for
+    the PREVIOUS step's metrics with this step already queued behind
+    it) / ``commit_apply`` (loss-scale + monitor + checkpoint
+    bookkeeping) / ``host_gap`` (the CLOSURE of the sum: wall between
+    step-exit boundaries minus every bracket), so the six components ≡
+    measured wall by construction — the same closure discipline
+    ``serve_attrib`` gates, gated here by ``bench.py train_obs``. The
+    four bracketed components arrive from the engine's own brackets
+    (``telemetry/trace.py``, ``on_span``);
   * **goodput** — checkpoint saves, resumes and step progress land as
     stamped events in a :class:`~..resilience.ledger.RestartLedger`
     (``DSTPU_TRAIN_LEDGER``); at export boundaries the observer
@@ -33,18 +35,19 @@ host-side boundaries:
     :func:`train_skew_report` names the laggard;
   * **anomaly sentinel** — the compiled step reduces a non-finite
     loss/grad-norm flag into ``StepMetrics.nonfinite`` IN-PROGRAM (no
-    new callbacks — audited), the observer reads it after the
-    sanctioned block plus keeps a windowed z-score on the loss series;
-    either tripping increments a counter, records a ``train_anomaly``
-    flight event and auto-dumps the ring — a NaN'd or spiking run
-    leaves forensics behind.
+    new callbacks — audited), the observer reads it ONE STEP LATE, when
+    the value is ready without stalling the step just dispatched, plus
+    keeps a windowed z-score on the loss series; either tripping
+    increments a counter, records a ``train_anomaly`` flight event and
+    auto-dumps the ring — a NaN'd or spiking run leaves forensics
+    behind (the last step's entry is flushed at checkpoint saves).
 
 Everything on the record path is pre-bound counter/histogram arithmetic
-over host floats (dslint DSL001-registered); the ONE device sync the
-observer adds is the explicit ``block_until_ready`` that defines the
-``device_execute`` bracket — it subsumes the sync ``_maybe_log`` /
-the watchdog pay anyway, and ``bench.py train_obs`` gates the whole
-record path at ≤3% overhead with 0 fresh warm-path compiles.
+over host floats (dslint DSL001-registered). The observer never blocks
+on the step just dispatched: the engine's ``train/device_wait`` bracket
+waits for the step before it, so the device always has one step queued,
+and ``bench.py train_obs`` gates the whole record path at ≤3% overhead
+with 0 fresh warm-path compiles.
 """
 
 from __future__ import annotations
@@ -109,16 +112,7 @@ class TrainObserver:
         self.progress_every = int(
             os.environ.get("DSTPU_TRAIN_OBS_PROGRESS_EVERY", "25")
             or "25")
-        # DSTPU_TRAIN_OBS_SYNC=0: drop the per-step block_until_ready.
-        # The device_execute bracket then reads ~0 (device time hides
-        # under later host work or queue back-pressure — the closure
-        # still holds, wall is wall) and the sentinel reads the
-        # PREVIOUS step's metrics, which are ready by then without
-        # forcing a sync — the knob for TPU loops that rely on
-        # dispatch-ahead overlap between steps (the default keeps the
-        # exact attribution; the bench gates run with it on).
-        self.sync = os.environ.get("DSTPU_TRAIN_OBS_SYNC", "1") \
-            not in ("0", "false", "off")
+        #: the newest step's (step, metrics), examined one step late
         self._pending_sentinel: Optional[Tuple[int, Any]] = None
         self._last_progress: Optional[Dict[str, Any]] = None
         # the observer's own event ledger (goodput source); in-memory
@@ -146,7 +140,6 @@ class TrainObserver:
 
         # attribution state (pure perf_counter arithmetic)
         self._t_enter = 0.0
-        self._t_mark = 0.0
         self._acc: Dict[str, float] = {}
         self._last_exit: Optional[float] = None
         self._between_apply = 0.0    # checkpoint/eval work between steps
@@ -187,7 +180,6 @@ class TrainObserver:
         train loop, the data fetch."""
         now = time.perf_counter()
         self._t_enter = now
-        self._t_mark = now
         if self._wall_anchor is None:
             # first observed step: the wall ledger opens here, so the
             # closure covers [first enter -> last exit] exactly
@@ -213,32 +205,27 @@ class TrainObserver:
             self._between_this = 0.0
         self._between_apply = 0.0
         self._acc = acc
-        self.flight.phase("stage")
 
-    def on_staged(self):
-        """Stage done (validation, watchdog/profiler arming, offload
-        swap-in): the compiled step dispatches next."""
-        now = time.perf_counter()
-        self._acc["stage"] += now - self._t_mark
-        self._t_mark = now
-        self.flight.phase("dispatch")
+    def on_span(self, span, t0, t1):
+        """One of ``train_batch``'s brackets closed (``telemetry/
+        trace.py``): stage (validation, watchdog/profiler arming,
+        offload swap-in), dispatch (the compiled-step call: an enqueue
+        on TPU; the CPU harness executes synchronously, the caveat
+        serve_attrib documents), device_execute (the wait for the
+        previous step) or commit_apply. The duration goes to the flight
+        ring and to this step's component, which ``on_step_exit``
+        observes."""
+        phase = span.spec.phase
+        self.flight.record(phase, t0, t1, span.args.get("step"))
+        if self._acc:
+            self._acc[phase] += t1 - t0
 
-    def on_dispatched(self):
-        """The compiled step call returned (enqueue on TPU; on the CPU
-        harness eager dispatch executes synchronously — the same
-        measurement caveat serve_attrib documents)."""
-        now = time.perf_counter()
-        self._acc["dispatch"] += now - self._t_mark
-        self._t_mark = now
-        self.flight.phase("device_execute")
-
-    def on_device_done(self):
-        """The sanctioned blocking readback finished: the exposed device
-        wait is the bracket between on_dispatched and here."""
-        now = time.perf_counter()
-        self._acc["device_execute"] += now - self._t_mark
-        self._t_mark = now
-        self.flight.phase("commit_apply")
+    def previous_loss(self):
+        """The loss of the step before the one just dispatched (None
+        before the first): what ``train/device_wait`` blocks on, so the
+        sentinel below reads a ready value."""
+        prev = self._pending_sentinel
+        return None if prev is None else prev[1].loss
 
     def on_step_abort(self):
         """A dead step must not leak its anchors into the next window:
@@ -252,14 +239,13 @@ class TrainObserver:
         self._wall_anchor = None
         self._between_apply = 0.0
         self._pending_sentinel = None
-        self.flight.phase("idle")
 
     def flush(self):
-        """Process the deferred (DSTPU_TRAIN_OBS_SYNC=0) sentinel entry
-        — the final step of a run would otherwise end the process with
-        its metrics stashed and never examined, leaving no forensics
-        for a last-step NaN. Called at every checkpoint save (the
-        normal and urgent-preemption end-of-run paths) and public for
+        """Process the deferred sentinel entry — the final step of a run
+        would otherwise end the process with its metrics stashed and
+        never examined, leaving no forensics for a last-step NaN. Called
+        at every checkpoint save (the normal and urgent-preemption
+        end-of-run paths) and public for
         explicit teardown; blocks on the metrics if still in flight
         (teardown semantics, not the hot path)."""
         prev = self._pending_sentinel
@@ -277,23 +263,22 @@ class TrainObserver:
 
     def on_step_exit(self, step: int, metrics: Any, samples: int = 0):
         """Close the books on one committed step: the closure residual
-        is host_gap, per-component histograms observe, the sentinel
-        reads the in-program non-finite flag (ready — the device bracket
-        already blocked on this step's outputs) and the windowed loss
-        z-score, then periodic sampling/export. The scalar readbacks
-        here are transfers of READY values, not device syncs.
+        is host_gap, per-component histograms observe, and the sentinel
+        examines the PREVIOUS step's metrics (ready: the device bracket
+        blocked on them with this step queued behind) for the in-program
+        non-finite flag and the windowed loss z-score, stashing this
+        step's; then periodic sampling/export. The scalar readbacks are
+        transfers of READY values, not device syncs.
         """
         now = time.perf_counter()
         acc = self._acc
         if not acc or self._wall_anchor is None:
             return
-        acc["commit_apply"] += now - self._t_mark
         wall = now - (self._last_exit if self._last_exit is not None
                       else self._t_enter)
         gap = wall - sum(acc.values())
         self._last_exit = now
         self._acc = {}
-        self.flight.phase("idle")
 
         self.c_steps.inc()
         if samples:
@@ -306,26 +291,19 @@ class TrainObserver:
         self.h_gap.observe(gap if gap > 0.0 else 0.0)
         self.h_wall.observe(wall)
 
-        if self.sync:
-            # values ready: the device_execute bracket blocked on them
-            self._sentinel(step, metrics)
-        else:
-            # overlap-preserving mode: process the PREVIOUS step's
-            # metrics (at most one step behind the device, so the
-            # transfer is ready or nearly so) and stash this step's
-            prev = self._pending_sentinel
-            self._pending_sentinel = (step, metrics)
-            if prev is not None:
-                self._sentinel(*prev)
+        prev = self._pending_sentinel
+        self._pending_sentinel = (step, metrics)
+        if prev is not None:
+            self._sentinel(*prev)
         self._finish_step(step, wall)
 
     def _sentinel(self, step: int, metrics: Any):
         """The anomaly sentinel's readbacks for ONE step's metrics —
-        ready values when called (sync mode blocks in the device
-        bracket; deferred mode lags one step). Registered DSL001 hot
-        path — scalar transfers + pre-bound counter arithmetic."""
+        ready values when called (the device bracket waited for them
+        one step later). Registered DSL001 hot path — scalar transfers
+        + pre-bound counter arithmetic."""
         # dslint: allow(DSL001): scalar transfers of READY values — the
-        # device_execute bracket (or the one-step lag) proved them
+        # device_execute bracket proved them
         loss = float(metrics.loss)
         # dslint: allow(DSL001): ready-value transfer (see above)
         gnorm = float(metrics.grad_norm)
@@ -430,9 +408,9 @@ class TrainObserver:
         """One checkpoint save published: a stamped ledger interval (the
         goodput ledger's checkpoint_save bucket) + between-step
         accounting so the save rides commit_apply, not data_wait. Also
-        flushes a deferred sentinel entry — a run that ends (or is
+        flushes the deferred sentinel entry — a run that ends (or is
         preempted) right after its final save leaves complete
-        forensics even in SYNC=0 mode."""
+        forensics."""
         self.flush()
         self.ledger.record("checkpoint_save", t_start=t0, t_end=t1,
                            step=step, dir=save_dir)

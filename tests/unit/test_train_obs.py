@@ -63,8 +63,11 @@ class TestAttributionClosure:
             if i:
                 time.sleep(0.005)        # a little "data fetch"
             loss = eng.train_batch(b)
-        jax.block_until_ready(loss)
+        # the books close at the last step's exit; that step may still
+        # be running (train_batch waits for the step BEFORE the one it
+        # dispatched), so the external clock stops here too
         wall = time.perf_counter() - t0
+        jax.block_until_ready(loss)
         comps = component_totals(obs.registry.snapshot(), snap0,
                                  components=TRAIN_ATTRIBUTION_COMPONENTS)
         csum = sum(comps[c] for c in TRAIN_STEP_WALL_COMPONENTS)
@@ -96,6 +99,11 @@ class TestAttributionClosure:
         inj = component_totals(snap2, snap1,
                                components=TRAIN_ATTRIBUTION_COMPONENTS)
         deltas = {c: inj[c] - base[c] for c in TRAIN_STEP_WALL_COMPONENTS}
+        # the stall lets the step in flight finish, and the CPU backend
+        # then runs the next one inline in its dispatch: a step's compute
+        # moves from device_execute (the wait for it, one call later) to
+        # dispatch, and their sum stays
+        deltas["dispatch"] += deltas.pop("device_execute")
         assert max(deltas, key=deltas.get) == "data_wait", deltas
         # 4 of the 5 sleeps are between observed steps (the first lands
         # before the window's first enter re-anchor)
@@ -383,6 +391,10 @@ class TestAnomalySentinel:
         eng.train_batch(self._x(eng, 0.1))
         assert obs.c_nonfinite.value == 0
         eng.train_batch(self._x(eng, float("nan")))     # the planted batch
+        # the sentinel reads a step's metrics one step late (it never
+        # blocks on the step just dispatched): the next step trips it
+        assert obs.c_nonfinite.value == 0
+        eng.train_batch(self._x(eng, 0.1))
         assert obs.c_nonfinite.value == 1
         assert obs.c_anomalies.value >= 1
         dumps = [f for f in os.listdir(tmp_path)
@@ -412,6 +424,8 @@ class TestAnomalySentinel:
             eng.train_batch(self._x(eng, float(rng.normal(0.0, 0.01))))
         assert obs.c_anomalies.value == 0
         eng.train_batch(self._x(eng, 1000.0))           # the spike
+        assert obs.c_anomalies.value == 0               # one step late
+        obs.flush()                  # what a checkpoint save does
         assert obs.c_anomalies.value == 1
         assert obs.c_nonfinite.value == 0
 
@@ -586,10 +600,9 @@ class TestReviewHardening:
 
     def test_sync0_final_step_sentinel_flushed_at_checkpoint(
             self, monkeypatch, tmp_path):
-        """Regression (review catch): in SYNC=0 mode the LAST step's
-        stashed metrics flush at the end-of-run checkpoint save, so a
-        final-step NaN still leaves forensics."""
-        monkeypatch.setenv("DSTPU_TRAIN_OBS_SYNC", "0")
+        """Regression (review catch): the LAST step's stashed metrics
+        flush at the end-of-run checkpoint save, so a final-step NaN
+        still leaves forensics."""
 
         def loss_fn(params, batch, rng):
             return jnp.sum(params["w"] ** 2) + jnp.mean(batch["x"])
@@ -611,10 +624,9 @@ class TestReviewHardening:
 
     def test_overlap_mode_defers_sentinel_one_step(self, monkeypatch,
                                                    tmp_path):
-        """Regression (review catch): DSTPU_TRAIN_OBS_SYNC=0 drops the
-        per-step block (TPU dispatch-ahead overlap survives); the
-        sentinel then lags exactly one step but still trips."""
-        monkeypatch.setenv("DSTPU_TRAIN_OBS_SYNC", "0")
+        """Regression (review catch): no per-step block on the step just
+        dispatched (TPU dispatch-ahead overlap survives); the sentinel
+        lags exactly one step but still trips."""
         monkeypatch.setenv("DSTPU_FLIGHT_DIR", str(tmp_path))
 
         def loss_fn(params, batch, rng):
@@ -627,7 +639,6 @@ class TestReviewHardening:
                                   "params": {"lr": 1e-3}},
                     "steps_per_print": 100000})
         obs = eng._train_obs
-        assert obs.sync is False
         B = eng.config.train_batch_size
         eng.train_batch({"x": jnp.full((B, 4), 0.1, jnp.float32)})
         eng.train_batch({"x": jnp.full((B, 4), float("nan"),
@@ -635,8 +646,34 @@ class TestReviewHardening:
         assert obs.c_nonfinite.value == 0        # one step behind
         eng.train_batch({"x": jnp.full((B, 4), 0.1, jnp.float32)})
         assert obs.c_nonfinite.value == 1        # the lagged trip
-        # attribution still closes (wall is wall; device_execute ~0)
+        # attribution still closes (wall is wall)
         assert obs.h_wall.count == 3
+
+    def test_train_batch_never_blocks_on_the_step_it_dispatched(
+            self, monkeypatch):
+        """The device bracket waits for the step BEFORE the one just
+        dispatched, so the device always has a step queued: no blocking
+        call inside train_batch may receive that call's own loss."""
+        import deepspeed_tpu.runtime.engine as engine_mod
+        eng = _engine()
+        waited = []
+        real = jax.block_until_ready
+
+        def spy(x):
+            waited.append(x)
+            return real(x)
+
+        monkeypatch.setattr(engine_mod.jax, "block_until_ready", spy)
+        losses = []
+        for b in _batches(4, eng, seed=16):
+            before = len(waited)
+            loss = eng.train_batch(b)
+            assert all(w is not loss for w in waited[before:])
+            losses.append(loss)
+        # it did wait, each time for the step before: steps 2..4
+        assert len(waited) == 3
+        assert all(w is prev for w, prev in zip(waited, losses))
+        assert eng.step_stats["steps"] == 4
 
     def test_pre_dispatch_error_aborts_observed_step(self):
         """Regression (review catch): a validation error between

@@ -105,29 +105,32 @@ HOT_PATHS: Mapping[str, Tuple[str, ...]] = {
     # appends only — a device sync here would inflate the very host-gap
     # component the layer exists to measure
     "deepspeed_tpu/telemetry/serve.py":
-        ("on_admit", "on_sched", "on_token_commit", "on_plan",
-         "on_dispatch", "on_fused_dispatch", "on_commit_block",
-         "on_commit_apply", "on_loop_enter", "on_loop_exit",
-         "_close_step", "on_retry",
+        ("on_admit", "on_sched", "on_token_commit", "on_span",
+         "on_loop_enter", "on_loop_exit", "_close_step", "on_retry",
          "on_reject", "on_abort", "on_flush", "on_spec",
          "on_spec_commit", "on_promote", "on_handoff_out",
-         "on_handoff_in", "on_handoff_replay", "phase", "_req_span",
+         "on_handoff_in", "on_handoff_replay", "_req_span",
          "_req_event"),
+    # the engines' brackets (one per plan/dispatch/commit boundary and
+    # per train_batch phase) wrap the hot paths above: a TraceMe, two
+    # clock reads, a dict add and the observer's on_span
+    "deepspeed_tpu/telemetry/trace.py":
+        ("span", "set", "count", "void", "__enter__", "__exit__"),
     # the TRAIN observer's step brackets run inside every train_batch
     # (ISSUE 15): perf_counter reads, attribute stores and pre-bound
     # histogram observes only — a device sync here would inflate the
     # very components the attribution layer measures. The sanctioned
     # readbacks (the device_execute bracket in engine.train_batch, the
-    # post-block scalar reads in on_step_exit) carry explicit allow
-    # comments naming why they are deliberate.
+    # sentinel's scalar reads of the previous step's ready values)
+    # carry explicit allow comments naming why they are deliberate.
     "deepspeed_tpu/telemetry/train.py":
-        ("on_step_enter", "on_staged", "on_dispatched",
-         "on_device_done", "on_step_abort", "on_between",
-         "on_step_exit", "_sentinel", "_finish_step"),
+        ("on_step_enter", "on_span", "previous_loss", "on_step_abort",
+         "on_between", "on_step_exit", "_sentinel", "_finish_step"),
     # train_batch itself is the engine bracket site: the two
-    # block_until_ready calls (observer device_execute bracket,
-    # watchdog step_end) are the sanctioned blocking sites and carry
-    # allow comments; everything else must stay pure host work
+    # block_until_ready calls (the device_execute bracket, which waits
+    # for the step BEFORE the one just dispatched, and the watchdog's
+    # step_end) are the sanctioned blocking sites and carry allow
+    # comments; everything else must stay pure host work
     "deepspeed_tpu/runtime/engine.py": ("train_batch",),
     "deepspeed_tpu/telemetry/registry.py":
         ("inc", "set", "observe", "quantile", "sample",
