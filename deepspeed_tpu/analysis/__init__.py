@@ -11,10 +11,11 @@ from .budgets import HOP_BUDGETS, SITE_BUDGETS, budget_args
 from .program_audit import (CollectiveBudget, CollectiveSite, ProgramReport,
                             RecompileTripwire, assert_budget,
                             audit_fn, audit_serve_programs,
-                            donated_arg_indices)
+                            donated_arg_indices, serve_program_calls)
 
 __all__ = [
     "CollectiveBudget", "CollectiveSite", "HOP_BUDGETS", "ProgramReport",
     "RecompileTripwire", "SITE_BUDGETS", "assert_budget", "audit_fn",
     "audit_serve_programs", "budget_args", "donated_arg_indices",
+    "serve_program_calls",
 ]

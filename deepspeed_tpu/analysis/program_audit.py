@@ -416,16 +416,17 @@ def assert_budget(report: ProgramReport, budget: CollectiveBudget) -> None:
 # ------------------------------------------------------------------ #
 
 
-def audit_serve_programs(engine, programs: Tuple[str, ...] = (
-        "step", "step_greedy", "step_greedy_fb", "step_sample_fb",
-        "decode_loop", "decode_verify", "flush_ring")
-        ) -> Dict[str, ProgramReport]:
-    """Audit the v2 ragged engine's jitted runner programs against
-    representative decode-shaped inputs (S = max_seqs slots, one token
-    each). Returns {program name: ProgramReport}. The sampled feedback
-    step and the speculative verify loop are audited alongside the
-    greedy programs: sampling/verification must add ZERO collectives
-    and zero host callbacks over their greedy siblings."""
+_SERVE_PROGRAMS = ("step", "step_greedy", "step_greedy_fb",
+                   "step_sample_fb", "decode_loop", "decode_verify",
+                   "flush_ring")
+
+
+def serve_program_calls(engine, programs: Tuple[str, ...] = _SERVE_PROGRAMS
+                        ) -> Dict[str, Tuple[Callable, tuple, dict]]:
+    """{program name: (jitted fn, args, static kwargs)}: the v2 ragged
+    engine's runner programs with representative decode-shaped inputs
+    (S = max_seqs slots, one token each) — what ``audit_serve_programs``
+    audits, for any other walk over the same traced programs."""
     import jax.numpy as jnp
 
     from ..inference.v2.kv_quant import pool_parts
@@ -442,54 +443,56 @@ def audit_serve_programs(engine, programs: Tuple[str, ...] = (
     zeros_s = jnp.zeros((S,), jnp.int32)
     ones_s = jnp.ones((S,), jnp.int32)
     ones_f = jnp.ones((S,), jnp.float32)
-
-    reports: Dict[str, ProgramReport] = {}
-    if "step" in programs:
-        reports["step"] = audit_fn(r._step, params, kv, batch, name="step")
-    if "step_greedy" in programs:
-        reports["step_greedy"] = audit_fn(r._step_greedy, params, kv,
-                                          batch, name="step_greedy")
-    if "step_greedy_fb" in programs:
-        reports["step_greedy_fb"] = audit_fn(
-            r._step_greedy_fb, params, kv, batch, zeros_s, ones_s, zeros_s,
-            name="step_greedy_fb")
-    if "step_sample_fb" in programs and hasattr(r, "_step_sample_fb"):
-        reports["step_sample_fb"] = audit_fn(
-            r._step_sample_fb, params, kv, batch, zeros_s, ones_s, zeros_s,
-            zeros_s, zeros_s, ones_f, zeros_s, ones_f,
-            name="step_sample_fb")
     n = max(2, int(cfg.decode_loop_steps) or 2)
     n = min(n, cfg.block_size)     # linear-layout flush bound (R <= bs)
     samp_dummies = (jnp.zeros((1,), jnp.int32),
                     jnp.zeros((1,), jnp.float32),
                     jnp.zeros((1,), jnp.int32),
                     jnp.ones((1,), jnp.float32))
-    if "decode_loop" in programs:
-        reports["decode_loop"] = audit_fn(
-            r._decode_loop_ring, params, kv, zeros_s, zeros_s, ones_s,
-            batch.block_tables, *samp_dummies,
-            jnp.zeros((1, 1), jnp.int32),
-            static_kwargs=dict(n=n, mode="greedy", cand=1, eos_id=-1,
-                               feed="self"),
-            name="decode_loop")
-    if "decode_verify" in programs:
+    loop_static = dict(n=n, mode="greedy", cand=1, eos_id=-1)
+    pool_arr, pool_scales = pool_parts(kv)
+    ring = jnp.zeros(
+        (n, r.num_layers, 2, S, r.kv_heads * r.head_dim),
+        pool_arr.dtype if pool_scales is None else r.compute_dtype)
+
+    calls = {
+        "step": (r._step, (params, kv, batch), {}),
+        "step_greedy": (r._step_greedy, (params, kv, batch), {}),
+        "step_greedy_fb": (r._step_greedy_fb,
+                           (params, kv, batch, zeros_s, ones_s, zeros_s), {}),
+        "decode_loop": (r._decode_loop_ring,
+                        (params, kv, zeros_s, zeros_s, ones_s,
+                         batch.block_tables, *samp_dummies,
+                         jnp.zeros((1, 1), jnp.int32)),
+                        dict(loop_static, feed="self")),
         # the speculative verify program: identical scan, draft-fed
-        reports["decode_verify"] = audit_fn(
-            r._decode_loop_ring, params, kv, zeros_s, zeros_s, ones_s,
-            batch.block_tables, *samp_dummies,
-            jnp.zeros((S, n), jnp.int32),
-            static_kwargs=dict(n=n, mode="greedy", cand=1, eos_id=-1,
-                               feed="given"),
-            name="decode_verify")
-    if "flush_ring" in programs:
-        pool_arr, pool_scales = pool_parts(kv)
-        ring = jnp.zeros(
-            (n, r.num_layers, 2, S, r.kv_heads * r.head_dim),
-            pool_arr.dtype if pool_scales is None else r.compute_dtype)
-        reports["flush_ring"] = audit_fn(
-            r._flush_ring, kv, ring, batch.block_tables, zeros_s, ones_s,
-            name="flush_ring")
-    return reports
+        "decode_verify": (r._decode_loop_ring,
+                          (params, kv, zeros_s, zeros_s, ones_s,
+                           batch.block_tables, *samp_dummies,
+                           jnp.zeros((S, n), jnp.int32)),
+                          dict(loop_static, feed="given")),
+        "flush_ring": (r._flush_ring,
+                       (kv, ring, batch.block_tables, zeros_s, ones_s), {}),
+    }
+    if hasattr(r, "_step_sample_fb"):
+        calls["step_sample_fb"] = (
+            r._step_sample_fb,
+            (params, kv, batch, zeros_s, ones_s, zeros_s, zeros_s, zeros_s,
+             ones_f, zeros_s, ones_f), {})
+    return {name: calls[name] for name in programs if name in calls}
+
+
+def audit_serve_programs(engine, programs: Tuple[str, ...] = _SERVE_PROGRAMS
+                         ) -> Dict[str, ProgramReport]:
+    """Audit the v2 ragged engine's jitted runner programs against
+    ``serve_program_calls``'s inputs. Returns {program name:
+    ProgramReport}. The sampled feedback step and the speculative verify
+    loop are audited alongside the greedy programs: sampling/verification
+    must add ZERO collectives and zero host callbacks over their greedy
+    siblings."""
+    return {name: audit_fn(fn, *args, static_kwargs=static, name=name)
+            for name, (fn, args, static)
+            in serve_program_calls(engine, programs).items()}
 
 
 # ------------------------------------------------------------------ #

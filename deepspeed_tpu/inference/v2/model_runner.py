@@ -535,12 +535,15 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
                 batch.block_tables, batch.start_pos, settled_lens,
                 block_size=bs, sm_scale=scale, alibi_slopes=alibi_slopes,
                 sliding_window=sliding_window, num_kv_heads=KV,
-                # the WHOLE ring and pool ride through: the kernel selects
-                # (layer, k/v) itself — per-layer pool[li, x] slices
-                # materialized full-layer pool copies for the Pallas
-                # operands (the device trace measured them at ~45% of the
-                # decode step), and ring[:, li, x].swapaxes added 44
-                # strided 17 MB transposes
+                # the WHOLE ring and pool ride through: both kernels select
+                # (layer, k/v) themselves, so data[li, x] above only gives
+                # shape and dtype and is dead under jit. As operands those
+                # slices made XLA copy every layer's K and V plane out of
+                # the pool each step (the device trace measured them at
+                # ~45% of the decode step), and ring[:, li, x].swapaxes
+                # added 44 strided 17 MB transposes. On the paged layout
+                # (several blocks a sequence) the kernel still builds its
+                # per-layer ring planes from ring_full: small, R x S rows
                 ring_full=ring, ring_layer=li,
                 pool_full=data, pool_layer=li,
                 scales_full=scales,
@@ -596,8 +599,10 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
         # q joins the pool's storage dtype so the kernel's matmuls stay
         # single-dtype (f32 accumulation inside); the pool itself is NEVER
         # cast or copied — that would re-introduce the full-pool traffic
-        # this kernel exists to avoid. pool_full lets the grouped decode
-        # path skip even the per-layer slice (dead code when unused).
+        # this kernel exists to avoid. The kernel's K and V operand is
+        # pool_full, decode and prefill alike: data[li, x] gives shape and
+        # dtype only (dead under jit), so no plane is sliced out of the
+        # pool that the scatter above just updated in place.
         # Over an int8 pool q stays in the compute dtype; the kernel
         # scales scores/probabilities by the side-array scales.
         y = flash_paged_attention(

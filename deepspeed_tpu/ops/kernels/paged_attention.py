@@ -492,17 +492,8 @@ def _flash_decode_grouped(qw, kp_flat, vp_flat, fetch, start_pos, seq_lens,
         R = None
         ring5d = False
 
+    # shape and layer range were checked by flash_paged_attention
     use_pool_full = pool_full is not None and pool_layer is not None
-    if use_pool_full:
-        if pool_full.ndim != 4 or pool_full.shape[1] != 2 \
-                or pool_full.shape[3] != KVD:
-            raise ValueError(
-                f"pool_full must be [L, 2, slots, {KVD}], got "
-                f"{pool_full.shape}")
-        if not 0 <= int(pool_layer) < pool_full.shape[0]:
-            raise ValueError(
-                f"pool_layer {pool_layer} out of range for L = "
-                f"{pool_full.shape[0]}")
     if ring5d:
         if ring_full.ndim != 5 or ring_full.shape[2] != 2:
             raise ValueError(
@@ -662,14 +653,18 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         layer index; the grouped decode path selects the layer/kv planes
         in its BlockSpec, so no per-layer slice/transpose materializes.
         Must share the pool's dtype (never cast).
-      pool_full/pool_layer: the PREFERRED pool form for decode — the
-        un-sliced [L, 2, slots, KV*D] pool plus the layer index; the
-        grouped path indexes the layer inside its DMA source (a
-        model-level pool[layer, 0/1] slice materializes a full per-layer
-        pool copy for the Pallas operand). When both full forms are given
-        the two layer indices must match. k_pool/v_pool remain required
-        (shape probing + the multi-block fallback path; dead code under
-        jit when the grouped path runs).
+      pool_full/pool_layer: the PREFERRED pool form, decode and prefill
+        — the un-sliced [L, 2, slots, KV*D] pool plus the (static) layer
+        index. Both kernels then take the WHOLE pool as their operand and
+        pick (layer, k/v) themselves: the grouped path inside its DMA
+        source, the BlockSpec path in its index map. A Pallas operand is
+        a whole buffer, so a model-level pool[layer, 0/1] slice makes XLA
+        copy that plane out of the pool before every call (2 L planes a
+        step = the pool read and written once). When both full forms are
+        given the two layer indices must match. k_pool/v_pool remain
+        required but then give only shape and dtype (dead code under
+        jit); alone, they are the operands — the form for a caller that
+        holds one layer's planes.
       alibi_slopes: optional [H] f32 — in-kernel ALiBi bias (falcon/bloom).
       scales_full / k_scales+v_scales: int8-pool dequantization scales
         (kv_quant.py layout): ``scales_full`` [L, 2, KV, slots] rides whole
@@ -709,6 +704,17 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
     g = H // KV
+    use_pool_full = pool_full is not None and pool_layer is not None
+    if use_pool_full:
+        if pool_full.ndim != 4 or pool_full.shape[1:] != (2, slots, KVD) \
+                or pool_full.dtype != k_pool.dtype:
+            raise ValueError(
+                f"pool_full must be {k_pool.dtype}[L, 2, {slots}, {KVD}], "
+                f"got {pool_full.dtype}{list(pool_full.shape)}")
+        if not 0 <= int(pool_layer) < pool_full.shape[0]:
+            raise ValueError(
+                f"pool_layer {pool_layer} out of range for L = "
+                f"{pool_full.shape[0]}")
 
     # int8 pool: scales required; normalize to the per-layer [KV, slots]
     # form for the BlockSpec (prefill) path — the grouped decode path
@@ -753,9 +759,6 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     factor = bs // pbs
     maxb_v = maxb * factor
     nb_pool = slots // pbs
-
-    kp = k_pool.reshape(nb_pool, pbs, KVD)
-    vp = v_pool.reshape(nb_pool, pbs, KVD)
 
     # query-chunk tiling: scratch rows are H*Cb, so bound Cb to keep the
     # online-softmax state (m/l at 128 lanes + f32 acc over KV*D) plus the
@@ -872,10 +875,7 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         jc = jnp.clip(j, lo_ref[sq], jnp.maximum(hi_ref[sq] - 1, 0))
         return fetch_ref[s * maxb_v + jc]
 
-    def kv_index(s, qc, j, *pref):
-        return (_kv_block(s, qc, j, *pref), 0, 0)
-
-    def sc_index(s, qc, j, *pref):
+    def block_index(s, qc, j, *pref):
         return (_kv_block(s, qc, j, *pref), 0, 0)
 
     # q rows for chunk qc must be one contiguous [H*Cb] row block: reorder
@@ -894,12 +894,28 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     o_spec = pl.BlockSpec((1, H * Cb, row_lanes),
                           lambda s, qc, j, *_: (s, qc, 0))
 
-    in_specs = [
-        q_spec,
-        pl.BlockSpec((1, pbs, KVD), kv_index),
-        pl.BlockSpec((1, pbs, KVD), kv_index),
-    ]
-    operands = [qw, kp, vp]
+    if use_pool_full:
+        # the WHOLE pool is the K and the V operand, viewed
+        # [L, 2, nb, pbs, KVD] (the same split of the slots axis, no data
+        # moves); the index map picks (layer, k/v, block) and the squeezed
+        # leading axes leave the kernel its [1, pbs, KVD] refs. A Pallas
+        # operand is a whole buffer, so handing it pool[layer, x] made XLA
+        # copy that plane out of the pool first: 2 L planes = the whole
+        # pool read and written once per step
+        li = int(pool_layer)
+        pool5 = pool_full.reshape(pool_full.shape[0], 2, nb_pool, pbs, KVD)
+        in_specs = [q_spec] + [
+            pl.BlockSpec(
+                (None, None, 1, pbs, KVD),
+                lambda s, qc, j, *pref, x=x:
+                    (li, x, _kv_block(s, qc, j, *pref), 0, 0))
+            for x in (0, 1)]
+        operands = [qw, pool5, pool5]
+    else:
+        # direct callers that hold one layer's planes only
+        in_specs = [q_spec] + [pl.BlockSpec((1, pbs, KVD), block_index)] * 2
+        operands = [qw, k_pool.reshape(nb_pool, pbs, KVD),
+                    v_pool.reshape(nb_pool, pbs, KVD)]
     if quant:
         # per-layer [KV, slots] scales re-laid [nb, KV, pbs] so a block's
         # minor dims are (KV, pbs) proper tiles; the same clamped block
@@ -908,7 +924,7 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             KV, nb_pool, pbs).swapaxes(0, 1)
         vsb = v_scales.astype(jnp.float32).reshape(
             KV, nb_pool, pbs).swapaxes(0, 1)
-        in_specs += [pl.BlockSpec((1, KV, pbs), sc_index)] * 2
+        in_specs += [pl.BlockSpec((1, KV, pbs), block_index)] * 2
         operands += [ksb, vsb]
     grid = (S, nCb, maxb_v + 1 if has_ring else maxb_v)
     if has_ring:

@@ -595,6 +595,79 @@ class TestPagedFlashKernel:
             np.testing.assert_allclose(np.asarray(out)[s], np.asarray(ref),
                                        atol=2e-5, rtol=1e-4)
 
+    @pytest.mark.parametrize("mode,kv_dtype,window,ring", [
+        (mode, kv_dtype, window, ring)
+        for mode in ("decode", "prefill")
+        for kv_dtype in ("bf16", "int8")
+        for window in (None, 12)
+        for ring in ((False, True) if mode == "decode" else (False,))])
+    def test_whole_pool_operand_parity(self, mode, kv_dtype, window, ring):
+        """BlockSpec path (maxb > 1): handing the whole [L, 2, slots, KVD]
+        pool + pool_layer must equal the per-plane call BIT FOR BIT. Every
+        plane holds different rows and pool_layer is the last of three, so
+        a wrong layer or K/V index in the index map cannot pass."""
+        from deepspeed_tpu.ops.kernels import flash_paged_attention
+        rng = np.random.default_rng(26)
+        L, li = 3, 2
+        bs, nb, maxb, KV, H, D, S = 8, 16, 4, 2, 4, 8, 4
+        KVD, slots = KV * D, (nb + 1) * bs
+        C = 1 if mode == "decode" else 4
+        quant = kv_dtype == "int8"
+        if quant:
+            pool = jnp.asarray(
+                rng.integers(-127, 128, (L, 2, slots, KVD)), jnp.int8)
+            scales = jnp.asarray(
+                rng.uniform(0.005, 0.02, (L, 2, KV, slots)), jnp.float32)
+            plane_kw = dict(k_scales=scales[li, 0], v_scales=scales[li, 1])
+            full_kw = dict(scales_full=scales)
+        else:
+            pool = jnp.asarray(
+                rng.normal(size=(L, 2, slots, KVD)), jnp.bfloat16)
+            plane_kw, full_kw = {}, {}
+        tables = jnp.asarray(
+            rng.permutation(nb)[:S * maxb].reshape(S, maxb), jnp.int32)
+        lens = jnp.asarray([29, 9, 17, 0], jnp.int32)   # slot 3 idle
+        q = jnp.asarray(rng.normal(size=(S, C, H, D)), jnp.bfloat16)
+        common = dict(block_size=bs, num_kv_heads=KV, sliding_window=window,
+                      interpret=True)
+        if ring:
+            # the fused loop's form: the pool holds the settled rows, the
+            # loop's own tokens sit in the (never quantized) ring
+            rcount = jnp.asarray(3, jnp.int32)
+            common.update(
+                ring_full=jnp.asarray(
+                    rng.normal(size=(4, L, 2, S, KVD)), jnp.bfloat16),
+                ring_layer=li, ring_count=rcount)
+            start, settled = lens + rcount - 1, lens
+        else:
+            start, settled = jnp.maximum(lens - C, 0), lens
+        per_plane = flash_paged_attention(
+            q, pool[li, 0], pool[li, 1], tables, start, settled,
+            **common, **plane_kw)
+        whole = flash_paged_attention(
+            q, pool[li, 0], pool[li, 1], tables, start, settled,
+            pool_full=pool, pool_layer=li, **common, **full_kw)
+        assert bool(jnp.any(per_plane[:3] != 0))
+        assert bool(jnp.all(per_plane[3] == 0))         # idle slot
+        np.testing.assert_array_equal(
+            np.asarray(whole, np.float32), np.asarray(per_plane, np.float32))
+
+    def test_whole_pool_operand_is_checked(self):
+        # a pool of another geometry or dtype than the planes, or a layer
+        # outside it, is refused before any kernel is built
+        from deepspeed_tpu.ops.kernels import flash_paged_attention
+        bs, slots, KV, D = 8, 24, 2, 8
+        pool = jnp.zeros((3, 2, slots, KV * D), jnp.bfloat16)
+        args = (jnp.zeros((2, 1, 4, D), jnp.bfloat16), pool[0, 0],
+                pool[0, 1], jnp.zeros((2, 2), jnp.int32),
+                jnp.zeros((2,), jnp.int32), jnp.ones((2,), jnp.int32))
+        kw = dict(block_size=bs, num_kv_heads=KV, interpret=True)
+        for bad, layer in ((pool[:, :, :16], 0),
+                           (pool.astype(jnp.float32), 0), (pool, 3)):
+            with pytest.raises(ValueError, match="pool_full|pool_layer"):
+                flash_paged_attention(*args, pool_full=bad,
+                                      pool_layer=layer, **kw)
+
 
 class TestKVOffloadRestore:
     """engine.pause/resume — reference BlockedKVCache.offload/restore

@@ -17,7 +17,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.analysis import (CollectiveBudget, RecompileTripwire,
                                     assert_budget, audit_fn,
-                                    audit_serve_programs)
+                                    audit_serve_programs,
+                                    serve_program_calls)
+from deepspeed_tpu.analysis.program_audit import _subjaxprs
 from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                         RaggedInferenceConfig)
 from deepspeed_tpu.models.gpt2 import GPT2, GPT2Config
@@ -391,6 +393,63 @@ class TestAuditorCore:
         rep = audit_fn(jax.jit(f), jnp.ones((8,), jnp.float32))
         with pytest.raises(AssertionError, match="unbudgeted axis"):
             assert_budget(rep, CollectiveBudget("model-only"))
+
+
+def _live_equations(jaxpr):
+    """Every equation XLA will see, sub-jaxprs included: dead code (the
+    per-plane slices a caller makes only for their shapes) is dropped
+    first, as jit drops it."""
+    from jax._src.interpreters import partial_eval as pe
+    live, _ = pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))
+    for eqn in live.eqns:
+        yield eqn
+        for sub in _subjaxprs(eqn.params):
+            yield from _live_equations(sub)
+
+
+class TestPagedPoolIsReadInPlace:
+    """ISSUE 26: on the paged layout (several blocks a sequence) the Pallas
+    kernel's K and V operands are the WHOLE pool, and no step program
+    builds anything of a pool plane's size except the pool's own in-place
+    updates. A per-plane operand makes XLA copy that plane out of the pool
+    first (2 L planes = the pool read and written once a step: 26-44 % of
+    serve device time on the chip, PERF.md PR 26), and every token-parity
+    test still passes, so the structure is held here."""
+
+    #: what may yield a plane or more: the scatter that writes this step's
+    #: rows into the donated pool, free views of it, and the containers
+    #: that carry it through (their bodies are walked on their own)
+    ALLOWED = {"scatter", "reshape", "jit", "scan", "while", "cond"}
+
+    @pytest.fixture(scope="class")
+    def engine(self):
+        # a pool plane (257 blocks x 8 tokens x 64 lanes) larger than any
+        # weight or activation of the tiny model, so "a plane or more"
+        # singles the pool out
+        return _gpt2_engine(attention_impl="paged_flash", num_blocks=256,
+                            max_blocks_per_seq=4)
+
+    @pytest.mark.parametrize(
+        "program", ["step_greedy", "step_greedy_fb", "decode_loop"])
+    def test_kernel_operands_are_the_pool(self, engine, program):
+        import functools
+        assert engine.config.max_blocks_per_seq > 1
+        pool = engine._kv_data
+        plane = pool.shape[2] * pool.shape[3]
+        fn, args, static = serve_program_calls(engine, (program,))[program]
+        eqns = list(_live_equations(
+            jax.make_jaxpr(functools.partial(fn, **static))(*args).jaxpr))
+        kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert len(kernels) == L
+        for e in kernels:
+            sizes = [v.aval.size for v in e.invars]
+            assert sizes.count(pool.size) == 2, sizes   # K and V
+            assert plane not in sizes, sizes
+        big = sorted({(e.primitive.name, v.aval.shape)
+                      for e in eqns for v in e.outvars
+                      if v.aval.size >= plane
+                      and e.primitive.name not in self.ALLOWED})
+        assert not big, f"{program} builds plane-sized values: {big}"
 
 
 class TestRecompileTripwire:

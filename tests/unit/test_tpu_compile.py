@@ -1,0 +1,86 @@
+"""The serve step programs, compiled for the TPU v5e in this process with
+no chip attached (the TPU compiler, Mosaic included, ships with libtpu).
+
+What only the chip's compiler can say about a structure: whether XLA
+materializes anything of a KV-pool plane's size around the paged-attention
+kernel. A compile that passes is not a chip run; no time comes from here.
+
+The topology is described inside a fixture, never at import: one process
+at a time may load libtpu, and xdist workers import every test file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.analysis import serve_program_calls
+from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                        RaggedInferenceConfig)
+
+#: the serve-chat-steady pool's geometry (benchmark/cells): 388 + 1 blocks
+#: of 256 tokens, rows of 2 kv heads x 128
+BLOCK, BLOCKS, KV_HEADS, HEAD_DIM = 256, 388, 2, 128
+SLOTS = (BLOCKS + 1) * BLOCK
+PLANE = f"bf16[1,1,{SLOTS},{KV_HEADS * HEAD_DIM}]"
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """Two llama layers at the cell's KV row width, on the paged layout.
+    Built on the CPU over a pool of a few blocks; the programs are lowered
+    below against the cell's pool shape."""
+    from deepspeed_tpu.models.llama import Llama, LlamaConfig
+    mcfg = LlamaConfig.tiny(
+        hidden_size=512, num_heads=4, num_kv_heads=KV_HEADS,
+        intermediate_size=1024, max_seq_len=2048, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, attention_impl="xla")
+    assert mcfg.head_dim == HEAD_DIM
+    params = Llama(mcfg).init(jax.random.PRNGKey(0),
+                              jnp.zeros((1, 8), jnp.int32))["params"]
+    return InferenceEngineV2(mcfg, params, RaggedInferenceConfig(
+        max_seqs=16, chunk_size=256, block_size=BLOCK, num_blocks=12,
+        max_blocks_per_seq=6, dtype="bfloat16", decode_loop_steps=8,
+        attention_impl="paged_flash"))
+
+
+@pytest.mark.parametrize(
+    "program", ["step_greedy", "step_greedy_fb", "decode_loop"])
+def test_no_pool_plane_is_materialized(one_chip, engine, program,
+                                       monkeypatch):
+    import deepspeed_tpu.ops.kernels as kernels
+    # the kernels ask the default backend (the CPU here) whether to
+    # interpret: steer that from the test, the program has no option for it
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    fn, args, static = serve_program_calls(engine, (program,))[program]
+    pool = engine._kv_data
+
+    def spec(x):
+        shape = x.shape
+        if x is pool:
+            shape = shape[:2] + (SLOTS,) + shape[3:]
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=one_chip)
+
+    args = jax.tree_util.tree_map(spec, args)
+    exe = fn.trace(*args, **static).lower(
+        lowering_platforms=("tpu",)).compile()
+    hlo = exe.as_text()
+    assert hlo.count("tpu_custom_call") >= engine.runner.num_layers
+    assert PLANE not in hlo, \
+        f"{program}: XLA slices a K or V plane out of the pool"
+    # and nothing else of that size is kept beside the (aliased) pool
+    plane_bytes = SLOTS * KV_HEADS * HEAD_DIM * 2
+    assert exe.memory_analysis().temp_size_in_bytes < plane_bytes
