@@ -1,0 +1,48 @@
+"""Operations and bytes a grouped expert feed-forward needs, from its
+shapes alone (the algorithm's needs, as ``kernel_cost.py`` counts
+attention's): what the three grouped matmuls of one sparse layer
+(``moe/sharded_moe.grouped_moe_ffn``: gate, up and down projections of
+SwiGLU experts over rows sorted by expert) must compute and move.
+
+``readers.r_roofline`` resolves cost functions in ``kernel_cost`` only, so
+no reader file names this one yet (PERF.md section 7 lists the one-line
+edit); ``roofline_share`` below is what the builder's reduction of a
+traced run uses meanwhile.
+"""
+
+from __future__ import annotations
+
+from typing import Dict
+
+from .kernel_cost import roofline_seconds
+
+
+def grouped_moe_ffn_cost(rows: float, experts_hit: float, hidden: int,
+                         width: int, itemsize: int = 2) -> Dict[str, float]:
+    """One sparse layer's expert feed-forward over ``rows`` routed rows
+    (tokens x experts per token) that reach ``experts_hit`` distinct
+    experts of width ``width``.
+
+    FLOPs: every routed row goes through three [hidden x width] matmuls,
+    2 x hidden x width each. Bytes: the three matrices of every expert
+    that is hit are read once, and every routed row is read once at the
+    hidden width and written once at it (the [rows, width] intermediates
+    between the matmuls need not leave the chip's fast memory and are not
+    counted)."""
+    return {"flops": 6.0 * rows * hidden * width,
+            "bytes": float(3 * experts_hit * hidden * width * itemsize
+                           + 2 * rows * hidden * itemsize)}
+
+
+def expected_experts_hit(rows: float, experts: int) -> float:
+    """Distinct experts that ``rows`` uniformly routed rows reach."""
+    return experts * (1.0 - (1.0 - 1.0 / experts) ** rows)
+
+
+def roofline_share(seconds: float, calls: int, peak: Dict[str, float],
+                   **shape) -> Dict[str, float]:
+    """Share (%) of its roofline that ``calls`` sparse layers of one shape
+    reached in ``seconds`` of device time, and which limit bounds it."""
+    least = roofline_seconds(grouped_moe_ffn_cost(**shape), peak)
+    return {"share": 100.0 * calls * least["seconds"] / seconds,
+            "bound": least["bound"]}

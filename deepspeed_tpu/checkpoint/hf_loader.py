@@ -471,6 +471,7 @@ SPECIAL_HANDLERS = {
     "gpt_neox": _split_neox_fused,
     "mixtral": _mixtral_experts,
     "qwen2_moe": _qwen2_moe_experts,
+    "olmoe": _qwen2_moe_experts,     # the same per-expert names
 }
 
 _MOE_STACKED_RULES = [
@@ -492,8 +493,16 @@ _QWEN2_MOE_MAP = _LLAMA_MAP + _MOE_STACKED_RULES + [
      "layer_{0}/shared_expert_gate/kernel", "linear"),
 ]
 
+_OLMOE_MAP = _LLAMA_MAP + _MOE_STACKED_RULES + [
+    (r"model\.layers\.(\d+)\.mlp\.gate\.weight",
+     "layer_{0}/moe/gate", "linear"),
+    (r"model\.layers\.(\d+)\.self_attn\.(q|k)_norm\.weight",
+     "layer_{0}/attn/{1}_norm/scale", "vector"),
+]
+
 ARCH_MAPS["mixtral"] = _MIXTRAL_MAP
 ARCH_MAPS["qwen2_moe"] = _QWEN2_MOE_MAP
+ARCH_MAPS["olmoe"] = _OLMOE_MAP
 
 
 def _fw_path(template: str, groups: Tuple[str, ...]) -> str:

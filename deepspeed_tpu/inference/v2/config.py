@@ -372,6 +372,13 @@ class RaggedInferenceConfig(ConfigModel):
                 f"expert axis: set ep_size > 1 (ep×tp mesh — attention "
                 f"shards over tp, experts over ep) or serve at "
                 f"tp_size=1")
+        if getattr(model_cfg, "qk_norm", False) and self.tp_size > 1:
+            # the norm runs over the whole q / k projection, which tp
+            # shards by heads: a chip would normalise by its own heads only
+            raise ValueError(
+                f"qk_norm models normalise q and k over the whole "
+                f"projection; tp_size={self.tp_size} shards it by heads "
+                f"(serve at tp_size=1, or over the expert axis alone)")
         if self.ep_size > 1:
             if not is_moe:
                 raise ValueError(

@@ -364,14 +364,16 @@ class InferenceEngineV2:
         #: counts by _plan_step / _dispatch_step / decode_batch. Per step
         #: that carries a multi-token chunk: the scheduled chunk lengths
         #: (real) against S x T of the program it runs (planned); per
-        #: pure-decode step: live sequences against the slot bucket
+        #: pure-decode step: live sequences against the slot bucket; per
+        #: fused loop of a model with routed experts: the rows they took
         self.pipeline_stats = {
             "steps": 0, "fed_steps": 0, "retries": 0, "plan_s": 0.0,
             "dispatch_s": 0.0, "commit_block_s": 0.0, "commit_apply_s": 0.0,
             "fused_dispatch_s": 0.0, "fused_apply_s": 0.0,
             "prefill_tokens_real": 0,
             "prefill_tokens_planned": 0, "decode_slots_live": 0,
-            "decode_slots_planned": 0}
+            "decode_slots_planned": 0,
+            "moe_rows_routed": 0, "moe_rows_hottest": 0}
         self._spans = SpanSet(self.pipeline_stats, lambda: self._obs)
         # ---- serve-side resilience (drain.py, docs/resilience.md) ---- #
         # env knobs are read with LITERAL names so the dslint knob scan
@@ -1512,18 +1514,28 @@ class InferenceEngineV2:
                 obs.on_loop_enter()
             spans, live = self._spans, len(seqs)
             with spans.span("serve/fused_dispatch", steps=n, seqs=live):
-                toks, lps, self._kv_data, consumed = self.runner.decode_loop(
-                    self.params, self._kv_data, jax.numpy.asarray(tok0),
-                    jax.numpy.asarray(start), jax.numpy.asarray(active),
-                    jax.numpy.asarray(tables), n,
-                    eos_id=-1 if eos_token_id is None else int(eos_token_id),
-                    **samp)
+                toks, lps, self._kv_data, consumed, moe_rows = \
+                    self.runner.decode_loop(
+                        self.params, self._kv_data, jax.numpy.asarray(tok0),
+                        jax.numpy.asarray(start), jax.numpy.asarray(active),
+                        jax.numpy.asarray(tables), n,
+                        eos_id=-1 if eos_token_id is None
+                        else int(eos_token_id), **samp)
             with spans.span("serve/fused_readback", steps=n, seqs=live):
-                toks = np.asarray(toks)
-                lps = np.asarray(lps) if lps is not None else None
-                # consumed is None when EOS is disabled: every slot fed all n
-                consumed = np.asarray(consumed) if consumed is not None \
-                    else None
+                # one wait for all of it. lps is None for a greedy loop,
+                # consumed when EOS is disabled (every slot fed all n),
+                # moe_rows for a model without experts
+                toks, lps, consumed, moe_rows = jax.device_get(
+                    (toks, lps, consumed, moe_rows))
+            if moe_rows is not None:
+                # per call: rows the experts took, and what they would
+                # have taken had every expert been as busy as the busiest
+                # (hottest / routed = the imbalance a straggling
+                # expert-parallel chip would feel; 1.0 = even)
+                stats = self.pipeline_stats
+                stats["moe_rows_routed"] += int(moe_rows.sum())
+                stats["moe_rows_hottest"] += \
+                    int(moe_rows.max()) * len(moe_rows)
             with spans.span("serve/fused_apply", steps=n, seqs=live):
                 out = self._apply_fused(batch_uids, seqs, first_tokens, n,
                                         toks, lps, consumed)
@@ -2211,7 +2223,7 @@ class InferenceEngineV2:
                 if n_draft:
                     draft_arr[i, 1:] = row
             with spans.span("serve/fused_dispatch", steps=L, seqs=len(ready)):
-                toks, _, self._kv_data, _ = self.runner.decode_loop(
+                toks, _, self._kv_data, _, _ = self.runner.decode_loop(
                     self.params, self._kv_data, jnp.asarray(tok0),
                     jnp.asarray(start), jnp.asarray(active),
                     jnp.asarray(tables), L,

@@ -43,11 +43,14 @@ class KVPool(NamedTuple):
 class RingKV(NamedTuple):
     """Fused-decode-loop KV state threaded through the runners: the pool is
     READ-ONLY; this step's K/V goes into the [R, L, 2, S, KV*D] ring at
-    index ``t`` (see RaggedRunnerBase._decode_loop)."""
+    index ``t`` (see RaggedRunnerBase._decode_loop). ``moe_rows`` [E]
+    rides along for models with routed experts: the loop's running count
+    of real rows routed to each expert, which the sparse layers add to."""
     pool: Any           # KVPool or raw pool array
     ring: Any
     t: Any
     rcount: Any
+    moe_rows: Any = None
 
 
 def pool_parts(kv) -> Tuple[Any, Optional[Any]]:

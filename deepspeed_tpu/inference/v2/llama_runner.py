@@ -38,7 +38,7 @@ class LlamaRaggedRunner(RaggedRunnerBase):
 
 
 def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
-             icfg: RaggedInferenceConfig = None):
+             icfg: RaggedInferenceConfig = None, valid=None):
     """Grouped-GEMM MoE for the ragged path: tokens sort by their routed
     expert and each expert multiplies only its rows via
     ``jax.lax.ragged_dot`` (sharded_moe.grouped_moe_ffn) — E/k x fewer
@@ -54,7 +54,12 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
     per layer (chunked over ``icfg.ep_comm_chunks`` slices when
     ``ep_comm_overlap='chunked'`` so chunk k's expert GEMMs run under
     chunk k+1's exchange). ``p_moe`` then holds this chip's [E/ep, ...]
-    expert stacks while the gate stays full-width."""
+    expert stacks while the gate stays full-width.
+
+    Returns (y [S, C, M], rows): with ``valid`` [S, C] given, ``rows``
+    [E] int32 counts the routed rows of VALID positions per expert
+    (padding positions are computed like the others and left out of the
+    count only); None without it and on the expert-parallel path."""
     from ...moe.sharded_moe import grouped_moe_ffn
     from ...ops.kernels.fp6_gemm import Fp6GemmWeight, fp6_gemm_unpack
     from .expert_parallel import EP_AXIS, ep_axis_active
@@ -83,11 +88,17 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
             h.reshape(S * C, M), logits, cfg.experts_top_k, weights,
             jax.nn.silu, dtype, EP_AXIS, cfg.num_experts, cap,
             normalize_weights=norm, chunks=chunks)
-        return y.reshape(S, C, M)
+        return y.reshape(S, C, M), None
     y, _ = grouped_moe_ffn(
         h.reshape(S * C, M), logits, cfg.experts_top_k, weights,
         jax.nn.silu, dtype, normalize_weights=norm)
-    return y.reshape(S, C, M)
+    rows = None
+    if valid is not None:
+        # the same top-k the grouped path takes of the same logits
+        _, top_idx = jax.lax.top_k(logits, cfg.experts_top_k)
+        rows = jnp.zeros((cfg.num_experts,), jnp.int32).at[top_idx].add(
+            valid.reshape(S * C, 1).astype(jnp.int32))
+    return y.reshape(S, C, M), rows
 
 
 def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
@@ -117,6 +128,9 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
             q = q + pa["q_proj"]["bias"].astype(dtype)
             k = k + pa["k_proj"]["bias"].astype(dtype)
             v = v + pa["v_proj"]["bias"].astype(dtype)
+        if model_cfg.qk_norm:
+            q = _rms(q, pa["q_norm"]["scale"], model_cfg.rms_eps).astype(dtype)
+            k = _rms(k, pa["k_norm"]["scale"], model_cfg.rms_eps).astype(dtype)
         q = q.reshape(S, C, H, D)
         k = k.reshape(S, C, KV, D)
         v = v.reshape(S, C, KV, D)
@@ -133,7 +147,12 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
         h = _rms(x, p["post_attn_norm"]["scale"],
                  model_cfg.rms_eps).astype(dtype)
         if is_moe:
-            y = _moe_mlp(p["moe"], h, model_cfg, dtype, cfg)
+            # the fused decode loop's kv carries a count of routed rows
+            counted = getattr(kv, "moe_rows", None) is not None
+            y, rows = _moe_mlp(p["moe"], h, model_cfg, dtype, cfg,
+                               valid=valid_q if counted else None)
+            if rows is not None:
+                kv = kv._replace(moe_rows=kv.moe_rows + rows)
             if getattr(model_cfg, "shared_expert_size", 0):
                 # qwen2-moe always-on shared expert (sigmoid scalar gate)
                 gate = woq_mm(h, p["shared_gate_proj"]["kernel"], dtype)

@@ -513,7 +513,7 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
 
     ring_mode = isinstance(kv, RingKV)
     if ring_mode:
-        pool, ring, t, rcount = kv
+        pool, ring, t, rcount = kv[:4]
         data, scales = pool_parts(pool)
         # ring[t, li, 0/1] <- this step's K/V: the ring is R-LEADING so the
         # per-step write is a leading-index dynamic-update-slice (in-place
@@ -524,7 +524,7 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
             k.reshape(S, KV * D).astype(ring.dtype))
         ring = ring.at[t, li, 1].set(
             v.reshape(S, KV * D).astype(ring.dtype))
-        kv = RingKV(pool, ring, t, rcount)
+        kv = kv._replace(ring=ring)
         settled_lens = jnp.where(batch.n_tokens > 0,
                                  batch.start_pos - t, 0)
         if impl == "paged_flash":
@@ -749,6 +749,8 @@ class RaggedRunnerBase:
         mapped = tp is not None or seqc is not None or epc is not None
         mcfg_l = tp.localize_model_cfg(model_cfg) if tp else model_cfg
         vocab = getattr(model_cfg, "vocab_size", -1)
+        moe_experts = getattr(model_cfg, "num_experts", 0) \
+            if epc is None else 0
         quantized_pool = cfg.kv_cache_dtype == "int8"
         if epc is not None:
             # expert (or expert×model) mesh: specs merged by the EP
@@ -910,9 +912,13 @@ class RaggedRunnerBase:
                              else dtype)
             use_eos = eos_id >= 0
             done0 = jnp.zeros((S,), jnp.bool_)
+            # real rows routed to each expert, summed over the sparse
+            # layers and the loop's steps (the single-chip expert path
+            # adds to it; [0] for a model without experts and under ep)
+            moe0 = jnp.zeros((moe_experts,), jnp.int32)
 
             def body(carry, t):
-                ring, tok, pos, done = carry
+                ring, tok, pos, done, moe = carry
                 if use_eos:
                     # per-slot EOS freeze: finished slots stop appending KV
                     # (n_tokens 0 -> trash writes) and keep emitting eos_id
@@ -934,9 +940,9 @@ class RaggedRunnerBase:
                 batch = RaggedBatch(tokens=tok[:, None], start_pos=pos,
                                     n_tokens=alive, block_tables=tables)
                 logits, kv_out = type(self).step_fn(
-                    params, RingKV(kv_data, ring, t, t + 1), batch,
+                    params, RingKV(kv_data, ring, t, t + 1, moe), batch,
                     model_cfg=mcfg_l, cfg=cfg, dtype=dtype)
-                ring = kv_out.ring
+                ring, moe = kv_out.ring, kv_out.moe_rows
                 # the one pre-sampling collective: every chip then selects
                 # the SAME next token from identical full-width logits
                 logits = tp_gather_logits(logits, vocab)
@@ -957,15 +963,15 @@ class RaggedRunnerBase:
                     done = jnp.logical_or(done, nxt == eos_id)
                 else:
                     new_pos = pos + 1
-                return (ring, nxt, new_pos, done), (nxt, lp)
+                return (ring, nxt, new_pos, done, moe), (nxt, lp)
 
-            (ring, _, pos_f, _), (toks, lps) = jax.lax.scan(
-                body, (ring, tok0, start, done0),
+            (ring, _, pos_f, _, moe), (toks, lps) = jax.lax.scan(
+                body, (ring, tok0, start, done0, moe0),
                 jnp.arange(n, dtype=jnp.int32))
             # consumed is shard_map-shape-stable: always an array; the
             # decode_loop wrapper drops it when EOS is disabled
             return jnp.transpose(toks), jnp.transpose(lps), ring, \
-                pos_f - start
+                pos_f - start, moe
 
         def _decode_loop_ring(params, kv_data, tok0, start, active, tables,
                               seeds, temps, top_ks, top_ps, drafts,
@@ -982,7 +988,7 @@ class RaggedRunnerBase:
                     impl,
                     (pspecs, pool_spec, P(), P(), P(), P(), P(), P(),
                      P(), P(), P()),
-                    (P(), P(), ring_spec, P()))
+                    (P(), P(), ring_spec, P(), P()))
             return impl(params, kv_data, tok0, start, active, tables,
                         seeds, temps, top_ks, top_ps, drafts)
 
@@ -1121,8 +1127,10 @@ class RaggedRunnerBase:
         so one program scores the model's choice after every draft
         prefix. Returns (tokens [S, n] int32, logprobs [S, n] f32 or
         None, new kv_data, consumed [S] int32 or None — KV positions
-        each slot appended, None when EOS is off). Slots must have KV
-        blocks covering start_pos..start_pos+n-1.
+        each slot appended, None when EOS is off — and moe_rows [E]
+        int32 or None: real rows routed to each expert over the loop's
+        steps and the sparse layers, None for a model without experts).
+        Slots must have KV blocks covering start_pos..start_pos+n-1.
         """
         jnp_ = jax.numpy
         mode = "greedy" if temps is None else "sample"
@@ -1142,14 +1150,15 @@ class RaggedRunnerBase:
             draft_toks = self._dummy_draft
         cand = min(candidates, getattr(self.model_cfg, "vocab_size",
                                        1 << 30))
-        toks, lps, ring, consumed = self._decode_loop_ring(
+        toks, lps, ring, consumed, moe_rows = self._decode_loop_ring(
             params, kv_data, tok0, start_pos, active, block_tables,
             seeds, temps, top_ks, top_ps, draft_toks,
             n=n, mode=mode, cand=int(cand), eos_id=int(eos_id), feed=feed)
         kv_data = self._flush_ring(kv_data, ring, block_tables, start_pos,
                                    active)
         return toks, (lps if mode == "sample" else None), kv_data, \
-            (consumed if int(eos_id) >= 0 else None)
+            (consumed if int(eos_id) >= 0 else None), \
+            (moe_rows if moe_rows.shape[0] else None)
 
 
 class GPT2RaggedRunner(RaggedRunnerBase):

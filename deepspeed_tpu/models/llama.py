@@ -4,7 +4,8 @@ Covers the reference's v2 inference model zoo members that share this block
 structure — llama_v2, llama_v3, mistral, qwen2 (``inference/v2/
 model_implementations/{llama_v2,mistral,qwen_v2}/``) — via config:
 RMSNorm, RoPE, GQA attention, SwiGLU MLP, optional sliding-window mask
-(mistral), optional qkv bias (qwen2), untied LM head.
+(mistral), optional qkv bias (qwen2), optional projection-wide QK-norm
+(olmoe), untied LM head.
 
 TPU-first: bf16 compute / f32 params, MXU-shaped projections, optional remat
 per block; stable param names so TP rules and the ragged runner can address
@@ -34,6 +35,10 @@ class LlamaConfig:
     rms_eps: float = 1e-5
     sliding_window: Optional[int] = None   # mistral local attention
     qkv_bias: bool = False                 # qwen2
+    # olmoe: RMSNorm over the WHOLE q and k projections (one learned scale
+    # of the projection's width, not per head), before the split into
+    # heads and before RoPE
+    qk_norm: bool = False
     tie_embeddings: bool = False
     # LM-head cross-entropy knobs (models/_lm_utils.lm_head_xent):
     # "chunked" scan or the streaming "fused" Pallas kernel
@@ -126,8 +131,13 @@ class LlamaAttention(nn.Module):
         dense = lambda feats, name: nn.Dense(
             feats, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             use_bias=cfg.qkv_bias, name=name)
-        q = dense(H * D, "q_proj")(x).reshape(B, T, H, D)
-        k = dense(KV * D, "k_proj")(x).reshape(B, T, KV, D)
+        q = dense(H * D, "q_proj")(x)
+        k = dense(KV * D, "k_proj")(x)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
+            k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
+        q = q.reshape(B, T, H, D)
+        k = k.reshape(B, T, KV, D)
         v = dense(KV * D, "v_proj")(x).reshape(B, T, KV, D)
         pos = jnp.arange(T)[None, :]
         q = apply_rope(q, pos, cfg.rope_theta)

@@ -323,6 +323,23 @@ def _entry_qwen2_moe(d):
         router_aux_loss_coef=d.get("router_aux_loss_coef", 0.001)))
 
 
+def _entry_olmoe(d):
+    """OLMoE (allenai/OLMoE-1B-7B): every layer sparse, SwiGLU experts of
+    width ``intermediate_size``, softmax over all experts with the top-k
+    kept as they are, RMSNorm over the whole q and k projections.
+    ``clip_qkv`` is not implemented: a config that sets it is refused."""
+    if d.get("clip_qkv") is not None:
+        raise ValueError("olmoe configs with clip_qkv set are not "
+                         "supported (the published ones leave it null)")
+    return MixtralConfig(**_hf_llama(
+        d,
+        qk_norm=True,
+        num_experts=d.get("num_experts", 64),
+        experts_top_k=d.get("num_experts_per_tok", 8),
+        norm_topk_prob=d.get("norm_topk_prob", False),
+        router_aux_loss_coef=d.get("router_aux_loss_coef", 0.01)))
+
+
 ARCHITECTURES: Dict[str, ArchEntry] = {
     "gpt2": ArchEntry(GPT2Config, GPT2, make_gpt2, _entry_gpt2),
     "llama": ArchEntry(LlamaConfig, Llama, make_llama, _entry_llama),
@@ -343,6 +360,7 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
     "phi3": ArchEntry(LlamaConfig, Llama, make_llama, _entry_phi3),
     "qwen2_moe": ArchEntry(MixtralConfig, Mixtral, make_mixtral,
                            _entry_qwen2_moe),
+    "olmoe": ArchEntry(MixtralConfig, Mixtral, make_mixtral, _entry_olmoe),
     "gpt_neo": ArchEntry(GPTNeoConfig, GPTNeo, make_gpt_neo,
                          _entry_gpt_neo),
     "internlm": ArchEntry(LlamaConfig, Llama, make_llama, _entry_internlm),

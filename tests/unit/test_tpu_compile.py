@@ -84,3 +84,61 @@ def test_no_pool_plane_is_materialized(one_chip, engine, program,
     # and nothing else of that size is kept beside the (aliased) pool
     plane_bytes = SLOTS * KV_HEADS * HEAD_DIM * 2
     assert exe.memory_analysis().temp_size_in_bytes < plane_bytes
+
+
+def test_olmoe_decode_loop_compiles_without_an_expert_by_rows_temporary(
+        one_chip, monkeypatch):
+    """The fused decode loop of ``serve-olmoe-rollout`` (32 slots, KV rows
+    of 16 heads x 128 = 2048 lanes, two 640-token blocks a sequence, 64
+    experts of width 1024 top-8) at the published widths and two layers,
+    from shapes alone: the paged kernel takes the 2048-lane tiles, the
+    grouped matmuls stay XLA's ragged-dot calls over the 256 routed rows,
+    and nothing of [experts, rows, width] shape is built around them."""
+    import re
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.models.mixtral import Mixtral, MixtralConfig
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    slots, block, layers, experts, top_k = 32, 640, 2, 64, 8
+    mcfg = MixtralConfig(
+        vocab_size=50304, max_seq_len=4096, num_layers=layers, num_heads=16,
+        num_kv_heads=16, hidden_size=2048, intermediate_size=1024,
+        num_experts=experts, experts_top_k=top_k, norm_topk_prob=False,
+        qk_norm=True, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    icfg = RaggedInferenceConfig(
+        max_seqs=slots, chunk_size=512, block_size=block, num_blocks=68,
+        max_blocks_per_seq=2, decode_loop_steps=64, dtype="bfloat16",
+        attention_impl="paged_flash")
+    runner = LlamaRaggedRunner(mcfg, icfg)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, jnp.bfloat16), jax.eval_shape(
+            lambda k: Mixtral(mcfg).init(
+                {"params": k, "gating": k},
+                jnp.zeros((1, 8), jnp.int32))["params"],
+            jax.random.PRNGKey(0)))
+    kv_row = mcfg.num_kv_heads * mcfg.head_dim
+    pool = spec((layers, 2, 69 * block, kv_row), jnp.bfloat16)
+    i32 = functools.partial(spec, dtype=jnp.int32)
+    f32 = functools.partial(spec, dtype=jnp.float32)
+    exe = runner._decode_loop_ring.trace(
+        params, pool, i32((slots,)), i32((slots,)), i32((slots,)),
+        i32((slots, 2)), i32((1,)), f32((1,)), i32((1,)), f32((1,)),
+        i32((1, 1)), n=64, mode="greedy", cand=1, eos_id=-1,
+        feed="self").lower(lowering_platforms=("tpu",)).compile()
+    hlo = exe.as_text()
+    rows = slots * top_k
+    assert len(re.findall(r"%ragged-dot[\w\-.]* = bf16\[" + str(rows),
+                          hlo)) == 3 * layers
+    # one paged-attention kernel a layer beside them
+    assert hlo.count("tpu_custom_call") >= 4 * layers
+    wide = re.findall(r"(?:bf16|f32)\[%d,%d,(?:2048|1024)\]"
+                      % (experts, rows), hlo)
+    assert not wide, f"an [experts, rows, width] temporary: {set(wide)}"
+    # and the loop's temporaries stay far under one expert stack
+    assert exe.memory_analysis().temp_size_in_bytes \
+        < experts * 2048 * 1024 * 2
