@@ -414,6 +414,14 @@ class RaggedInferenceConfig(ConfigModel):
         return c
 
     @property
+    def prefill_rows(self) -> int:
+        """Rows longer than one token a step may carry, and the slot
+        dimension of the prefill program: a step of ~512 tokens already
+        passes the v5e's ridge (240 FLOP a byte on bf16 weights), so more
+        rows add latency and no throughput. Follows the ROUNDED chunk."""
+        return min(max(2, self.effective_chunk // 128), self.max_seqs)
+
+    @property
     def token_budget(self) -> int:
         if self.max_batch_tokens and self.max_batch_tokens > 0:
             return min(self.max_batch_tokens,

@@ -54,6 +54,10 @@ from .state_manager import StateManager
 #: is patched in at commit if the placeholder is still queued)
 _SPEC_TOKEN = -1
 
+#: slot dimensions a step program is compiled for (capped at ``max_seqs``);
+#: a step that carries prefill chunks and nothing more takes ``prefill_rows``
+_SLOT_BUCKETS = (16, 32, 64, 128, 256, 512)
+
 
 class _PlannedStep:
     """Host half of one step (the plan phase): the schedule plus its
@@ -363,7 +367,8 @@ class InferenceEngineV2:
         #: ``*_s`` seconds by the brackets (telemetry/trace.py), the
         #: counts by _plan_step / _dispatch_step / decode_batch. Per step
         #: that carries a multi-token chunk: the scheduled chunk lengths
-        #: (real) against S x T of the program it runs (planned); per
+        #: (real) against S x T of the program it runs (planned), and the
+        #: rows that carried more than one token (prefill_rows); per
         #: pure-decode step: live sequences against the slot bucket; per
         #: fused loop of a model with routed experts: the rows they took
         self.pipeline_stats = {
@@ -371,7 +376,8 @@ class InferenceEngineV2:
             "dispatch_s": 0.0, "commit_block_s": 0.0, "commit_apply_s": 0.0,
             "fused_dispatch_s": 0.0, "fused_apply_s": 0.0,
             "prefill_tokens_real": 0,
-            "prefill_tokens_planned": 0, "decode_slots_live": 0,
+            "prefill_tokens_planned": 0, "prefill_steps": 0,
+            "prefill_rows": 0, "decode_slots_live": 0,
             "decode_slots_planned": 0,
             "moe_rows_routed": 0, "moe_rows_hottest": 0}
         self._spans = SpanSet(self.pipeline_stats, lambda: self._obs)
@@ -1643,24 +1649,25 @@ class InferenceEngineV2:
                 # first-schedule stamps -> queue-wait histogram (pure host)
                 self._obs.on_sched(sched, time.monotonic())
             cfg = self.config
-            # shape bucketing: a pure-decode step (every scheduled slot carries
-            # one token) runs the [S, 1] program instead of padding every slot
-            # to chunk_size — chunk_size× fewer wasted positions in the steady
-            # decode state. The SLOT dim buckets too (powers of two up to
-            # max_seqs): with the SplitFuse token budget a prefill step carries
-            # ~budget/chunk_size sequences, and padding it to max_seqs slots
-            # made prefill activation memory scale with max_seqs (OOM at
-            # max_seqs >= 384). A handful of compiled programs total (jit
-            # caches by shape); the reference gets the same effect by
-            # flattening tokens into one ragged array (ragged_wrapper.py),
-            # which XLA's static shapes forbid.
+            # shape bucketing (jit caches by shape, so a handful of compiled
+            # programs total; the reference flattens tokens into one ragged
+            # array instead, ragged_wrapper.py, which XLA's static shapes
+            # forbid). A pure-decode step (every scheduled slot carries one
+            # token) runs an [S, 1] program, S the smallest power of two from
+            # 16 up to max_seqs that holds the rows. A step that carries a
+            # prefill chunk runs at T = effective_chunk, and the scheduler
+            # caps its chunk rows at prefill_rows, so a pure-prefill step
+            # (every put of a fresh prompt) is always the one
+            # [prefill_rows, T] program: as wide as the prompts it holds,
+            # not padded to 16 slots. Only a step that mixes more decode
+            # rows than that with a chunk falls back to the decode buckets.
             C = 1 if all(len(item.tokens) == 1 for item in sched) \
                 else cfg.effective_chunk
-            S = cfg.max_seqs
-            for b in (16, 32, 64, 128, 256, 512):
-                if b >= len(sched) and b <= cfg.max_seqs:
-                    S = b
-                    break
+            if C > 1 and len(sched) <= cfg.prefill_rows:
+                S = cfg.prefill_rows
+            else:
+                S = next((b for b in _SLOT_BUCKETS
+                          if len(sched) <= b <= cfg.max_seqs), cfg.max_seqs)
             (tokens, start, ntok, tables, feed_mask, feed_idx,
              seeds, spos, temps, topks, topps) = self._staging_bufs(S, C)
             use_greedy = greedy and hasattr(self.runner, "step_greedy")
@@ -1704,7 +1711,9 @@ class InferenceEngineV2:
                      real=real)
             if C > 1:
                 span.count(prefill_tokens_real=real,
-                           prefill_tokens_planned=S * C)
+                           prefill_tokens_planned=S * C, prefill_steps=1,
+                           prefill_rows=sum(len(item.tokens) > 1
+                                            for item in sched))
                 # serve fault site: a replica dying with a freshly planned
                 # multi-token prefill chunk (tokens consumed host-side, step
                 # never dispatched)

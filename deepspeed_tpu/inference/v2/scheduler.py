@@ -3,13 +3,19 @@
 Analogue of the reference's FastGen scheduling (``put``/``query``/
 ``can_schedule``, ``inference/v2/engine_v2.py:107-184`` + the Dynamic
 SplitFuse policy from the FastGen blog): long prompts are split into fixed
-chunks and fused with decode tokens so every forward consumes a near-constant
-token budget. Here the budget is *exactly* constant — ``max_seqs`` slots of
-up to ``chunk_size`` tokens, padded — which is what keeps one compiled
-program serving all traffic (static shapes; SURVEY.md §7 hard part 3).
+chunks and fused with decode tokens so no forward exceeds a token budget.
+Shapes are static (SURVEY.md §7 hard part 3), so a step runs one of a few
+compiled ``[slots, tokens]`` programs and pads to it; the scheduler keeps
+that padding small by making a prefill step as wide as the prompts it
+holds: at most ``prefill_rows`` rows (2 at chunks of 256, 4 at 512) carry
+more than one token, and the engine runs them in the one
+``[prefill_rows, effective_chunk]`` program (``engine_v2._plan_step``).
+``token_budget`` stays an upper limit on a step's prefill tokens. The
+single token that chunking may leave last (``mid_prefill``) is a prefill
+row too, so a ``put`` never mixes it with chunks beyond the cap.
 
-Decode sequences (1 pending token) are scheduled first — they bound
-per-token latency; remaining slots are filled with prefill chunks.
+Decode sequences (1 pending token) are scheduled first and are exempt from
+both limits — they bound per-token latency; prefill chunks follow.
 """
 
 from __future__ import annotations
@@ -86,26 +92,29 @@ class SplitFuseScheduler:
         # fresh pool, which stays longest-first (they need the most
         # chunks, start them early)
         now = self.state.step
-        decode = [s for s in pending if s.in_flight == 1]
+        decode = [s for s in pending
+                  if s.in_flight == 1 and not s.mid_prefill]
 
         def prefill_key(s):
             if now - s.last_sched >= PREFILL_AGING_STEPS:
                 return (0, s.last_sched, -s.in_flight)
             return (1, -s.in_flight, s.last_sched)
 
-        prefill = sorted((s for s in pending if s.in_flight > 1),
+        prefill = sorted((s for s in pending
+                          if s.in_flight > 1 or s.mid_prefill),
                          key=prefill_key)
         out: List[ScheduledSeq] = []
         # Dynamic-SplitFuse forward budget: decode rows always fit (1 token
         # each, latency-bound); prefill chunks fill — and SPLIT mid-chunk —
-        # up to the remaining budget, keeping every forward's token count
-        # (and its activation memory) near-constant regardless of how many
-        # slots hold fresh prompts
+        # up to the remaining budget and at most ``prefill_rows`` rows,
+        # bounding every forward's token count (and its activation memory)
+        # regardless of how many slots hold fresh prompts
         budget = cfg.token_budget
         used = 0
+        rows_left = cfg.prefill_rows
         for seq in decode + prefill:
-            if len(out) == cfg.max_seqs:
-                break
+            if len(out) == cfg.max_seqs or rows_left == 0:
+                break                      # prefills come last: none fits
             if seq.promote_defer and seq.in_flight > 1 and out:
                 # hierarchical-KV promote-ahead: this sequence's prefix
                 # match just dispatched host->device promotion scatters;
@@ -118,7 +127,8 @@ class SplitFuseScheduler:
                 # bounded, never starving, token-stream-invariant.
                 seq.promote_defer -= 1
                 continue
-            if seq.in_flight == 1:
+            is_prefill = seq.in_flight > 1 or seq.mid_prefill
+            if not is_prefill:
                 n = 1                          # decode rows are budget-EXEMPT
             else:
                 # effective_chunk = min(chunk_size, prefill_chunk_cap):
@@ -146,6 +156,8 @@ class SplitFuseScheduler:
             seq.seen_tokens += n
             seq.status = SequenceStatus.RUNNING
             seq.promote_defer = 0     # first chunk ran: head start over
-            if n > 1:
+            if is_prefill:
                 used += n
+                rows_left -= 1
+                seq.mid_prefill = seq.in_flight > 0
         return out

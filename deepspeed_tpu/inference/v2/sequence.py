@@ -35,6 +35,10 @@ class SequenceDescriptor:
     # last_step, whose engine-step clock jumps by n per fused decode_batch
     # call): what prefill AGING measures waiting time against
     last_sched: int = 0
+    # set by the scheduler while a multi-token feed is only partly
+    # scheduled: the single token its chunking may leave last is still a
+    # prefill row (ordered and capped with the chunks), not a decode row
+    mid_prefill: bool = False
     # prefix caching (engine prefix_cache=True): block ids in kv_blocks
     # that are CACHE-SHARED — co-owned by the prefix cache (and possibly
     # other sequences). Release paths (flush / trim_blocks rollback /

@@ -216,16 +216,20 @@ class TestServeBrackets:
 
     def test_prefill_and_decode_counters_on_a_two_prompt_put(self, served):
         _, _, after_put, after_decode, _ = served
-        # 13 + 5 prompt tokens in chunks of 8: two [S, 8] steps
+        # 13 + 5 prompt tokens in chunks of 8: two steps of the
+        # [prefill_rows, 8] = [2, 8] program, two rows then one
         assert after_put["prefill_tokens_real"] == 18
         assert after_put["prefill_tokens_real"] \
             <= after_put["prefill_tokens_planned"]
-        assert after_put["prefill_tokens_planned"] == 2 * 4 * 8
+        assert after_put["prefill_tokens_planned"] == 2 * 2 * 8
+        assert after_put["prefill_steps"] == 2
+        assert after_put["prefill_rows"] == 3
         assert after_put["decode_slots_live"] == 0
         # three pure-decode steps of two live sequences in four slots
         assert after_decode["decode_slots_live"] == 6
         assert after_decode["decode_slots_planned"] == 12
-        assert after_decode["prefill_tokens_planned"] == 2 * 4 * 8
+        assert after_decode["prefill_tokens_planned"] == 2 * 2 * 8
+        assert after_decode["prefill_steps"] == 2
 
     def test_a_plan_that_schedules_nothing_is_not_a_step(self, served):
         eng = served[0]
@@ -313,7 +317,7 @@ class TestServeBrackets:
                 "dstpu:serve/dispatch", "dstpu:serve/commit_block",
                 "dstpu:serve/commit_apply"} <= set(found)
         plan = found["dstpu:serve/plan"]
-        assert plan["S"] == 4 and plan["T"] == 8 and plan["seqs"] == 2
+        assert plan["S"] == 2 and plan["T"] == 8 and plan["seqs"] == 2
         assert found["dstpu:serve/dispatch"]["program"] == "step_greedy"
         assert found["dstpu:serve/put"]["requests"] == 2
 
