@@ -2,8 +2,8 @@
 the seq/TP hop budgets (ISSUE 18) and the static collective-site map.
 
 This module is deliberately jax-free and the two registries are PURE
-LITERALS: the runtime (bench.py ``serve_longctx`` asserts, the
-``test_seq_parallel.py`` budget tests) imports them through
+LITERALS: the runtime (the ``test_seq_parallel.py`` and
+``test_moe_serving.py`` budget tests) imports them through
 :func:`budget_args`, while ``tools/dslint`` (rule DSL008)
 ``ast.literal_eval``s the same assignments without importing the
 package — a budget edited in only one place is impossible, and lint

@@ -49,8 +49,7 @@ class RaggedInferenceConfig(ConfigModel):
     # per-chip KV shards); decode broadcasts q and combines per-chip
     # partial flash-softmax stats with one small all-gather per layer.
     # Weights replicate over the axis. seq_size=1 traces the exact
-    # pre-seq programs; the env knob DSTPU_SEQ_PARALLEL overrides at
-    # engine construction (0 = killswitch, N>1 = force the axis open).
+    # pre-seq programs (the parity oracle).
     # Mutually exclusive with tp_size > 1 for now; requires the dense
     # attention path and num_blocks / max_blocks_per_seq divisible by
     # seq_size.
@@ -70,9 +69,7 @@ class RaggedInferenceConfig(ConfigModel):
     # decode loop. Composes with tp_size > 1 (ep×tp mesh: attention
     # shards over 'model', experts over 'expert'); mutually exclusive
     # with seq_size > 1. num_experts must divide by ep_size. ep_size=1
-    # traces the exact pre-ep single-chip programs; the env knob
-    # DSTPU_EP_SIZE overrides at engine construction (0 = killswitch,
-    # N>1 = force the axis open).
+    # traces the exact pre-ep single-chip programs (the parity oracle).
     ep_size: int = 1
     # Overlapped expert dispatch/combine (the PR 6 decomposed-collective
     # shape): "chunked" splits each a2a's capacity slots into
@@ -80,11 +77,9 @@ class RaggedInferenceConfig(ConfigModel):
     # under chunk k+1's dispatch a2a. "off" is the single-a2a parity
     # oracle — token streams are identical either way (per-row GEMM
     # results and the slot-ordered combine don't depend on chunking).
-    # Env: DSTPU_EP_OVERLAP (off|chunked[:k]).
     ep_comm_overlap: str = "off"
     # Chunk count for ep_comm_overlap="chunked" (capacity slots per
-    # destination are rounded up to a multiple of this). Env:
-    # DSTPU_EP_OVERLAP_CHUNKS.
+    # destination are rounded up to a multiple of this).
     ep_comm_chunks: int = 2
     # Dispatch capacity slack: each chip reserves
     # ceil(rows * ep_capacity_factor / ep_size) slots per destination
@@ -93,7 +88,7 @@ class RaggedInferenceConfig(ConfigModel):
     # standard fixed-capacity MoE trade; factor >= ep_size is provably
     # dropless (every destination can absorb every row) — the default
     # 2.0 makes the flagship ep=2 geometry exact, which the ep=1 vs
-    # ep=2 parity oracle relies on. Env: DSTPU_EP_CAPACITY.
+    # ep=2 parity oracle relies on.
     ep_capacity_factor: float = 2.0
     # Route the TP all-reduces through int8 quantized comm (EQuARX-class
     # for bandwidth-bound decode). With tp_comm_overlap off this is the
@@ -150,8 +145,7 @@ class RaggedInferenceConfig(ConfigModel):
     # dispatched ahead of the sequence's remaining prefill chunks — a
     # demoted hit is still a hit, just a slower one. Content is only
     # lost past this cap (its own LRU/FIFO, prefix_cache_policy order).
-    # Token streams are identical tier on/off. Env override at engine
-    # construction: DSTPU_PREFIX_HOST_BLOCKS.
+    # Token streams are identical tier on/off.
     prefix_cache_host_blocks: int = 0
     # Overlapped serving pipeline depth: how many scheduled steps may be
     # in flight on the device at once. The serve loop splits into plan
@@ -163,8 +157,7 @@ class RaggedInferenceConfig(ConfigModel):
     # next step's token slots from a device-resident last-token buffer
     # (no host round-trip in the steady pure-decode state); EOS is
     # reconciled on the delayed readback with explicit rollback.
-    # 0 = fully synchronous (the parity oracle); the env knob
-    # DSTPU_SERVE_ASYNC overrides this at engine construction.
+    # 0 = fully synchronous (the parity oracle).
     serve_pipeline_depth: int = 2
     # ---- serve-side resilience (drain.py, docs/resilience.md) ---------
     # Per-request wall-clock deadline in seconds, stamped at admission
@@ -172,14 +165,12 @@ class RaggedInferenceConfig(ConfigModel):
     # a structured rejection (engine.rejections) instead of being served
     # late — its KV blocks and prefix-cache refcounts are released
     # exactly, deferred past any in-flight step that still writes them.
-    # Env override at engine construction: DSTPU_SERVE_DEADLINE_S.
     request_deadline_s: float = 0.0
     # Bounded retry for a serve-step dispatch that fails with a
     # TRANSIENT (I/O-class) error: retries with exponential backoff from
     # serve_retry_backoff_s, then raises ServeStepError. The plan phase's
     # host state is untouched by a failed dispatch, so redispatching the
-    # same planned step is always safe. Env: DSTPU_SERVE_RETRY /
-    # DSTPU_SERVE_RETRY_BACKOFF_S.
+    # same planned step is always safe.
     serve_step_retries: int = 2
     serve_retry_backoff_s: float = 0.05
     # Graceful load-shedding: when the scheduler starves with the KV pool
@@ -187,7 +178,6 @@ class RaggedInferenceConfig(ConfigModel):
     # holder, abort the cheapest-to-redo victim (not-yet-started first,
     # then largest demand) with a structured rejection instead of
     # crashing the serve loop. False restores the hard RuntimeError.
-    # Env: DSTPU_SERVE_SHED=0|1.
     serve_shed: bool = True
     # Write-ahead replay journal path ("" = off): one JSONL record per
     # admission / committed step / flush, flushed to the OS per record —
@@ -210,14 +200,13 @@ class RaggedInferenceConfig(ConfigModel):
     #             sequence's own history (prompt lookup decoding);
     #   "draft" — a config-paired small draft model (attach via
     #             engine.attach_draft; e.g. gpt2 drafting for llama).
-    # Env override at engine construction: DSTPU_SPEC_MODE; sampled
-    # (temperature > 0) sequences bypass speculation.
+    # Sampled (temperature > 0) sequences bypass speculation.
     spec_decode: str = "off"
     # Draft tokens proposed per sequence per round (the verify program
-    # scores spec_k + 1 positions). Env: DSTPU_SPEC_K.
+    # scores spec_k + 1 positions).
     spec_k: int = 4
     # n-gram width the "ngram" proposer matches against the sequence's
-    # own history (falls back n, n-1, .., 1). Env: DSTPU_SPEC_NGRAM.
+    # own history (falls back n, n-1, .., 1).
     spec_ngram: int = 3
 
     # sampling defaults for the built-in generate loop

@@ -150,10 +150,9 @@ class InferenceEngineV2:
         (serving/pool.py ``build_replica_engines``)."""
         self.config = config or RaggedInferenceConfig()
         # decomposed-collective env override (the operational kill-switch /
-        # force-on, like DSTPU_SERVE_ASYNC below): DSTPU_TP_OVERLAP =
-        # off|rs_ag|rs_ag_chunked[:k], DSTPU_TP_OVERLAP_CHUNKS = k.
-        # Applied BEFORE the runner builds so the traced step functions
-        # close over the final schedule.
+        # force-on): DSTPU_TP_OVERLAP = off|rs_ag|rs_ag_chunked[:k],
+        # DSTPU_TP_OVERLAP_CHUNKS = k. Applied BEFORE the runner builds so
+        # the traced step functions close over the final schedule.
         if os.environ.get("DSTPU_TP_OVERLAP") \
                 or os.environ.get("DSTPU_TP_OVERLAP_CHUNKS"):
             import dataclasses as _dc
@@ -168,54 +167,6 @@ class InferenceEngineV2:
                 self.config, tp_comm_overlap=mode,
                 **({"tp_comm_chunks": chunks}
                    if mode == "rs_ag_chunked" else {}))
-        # sequence-parallel env override (the long-context kill-switch):
-        # DSTPU_SEQ_PARALLEL=0 forces seq_size=1 — exact pre-seq programs,
-        # the parity oracle for live traffic — and =N forces the axis on.
-        # Applied BEFORE the runner builds, like DSTPU_TP_OVERLAP above.
-        env_seq = os.environ.get("DSTPU_SEQ_PARALLEL")
-        if env_seq not in (None, ""):
-            import dataclasses as _dc
-            sz = int(env_seq)
-            if sz < 0:
-                raise ValueError(
-                    f"DSTPU_SEQ_PARALLEL must be >= 0, got {sz}")
-            # replace, never mutate (same contract as the TP overlap knob);
-            # 0 means "off" -> the single-chip layout, seq_size=1
-            self.config = _dc.replace(self.config, seq_size=max(1, sz))
-        # expert-parallel env overrides (the MoE-serving kill-switch /
-        # force-on, same replace-never-mutate contract): DSTPU_EP_SIZE=0
-        # forces ep_size=1 — exact pre-EP single-chip programs, the
-        # parity oracle — and =N forces the expert axis on;
-        # DSTPU_EP_OVERLAP = off|chunked[:k] and DSTPU_EP_OVERLAP_CHUNKS
-        # pick the dispatch/combine a2a schedule; DSTPU_EP_CAPACITY sets
-        # the per-destination slot factor. Applied BEFORE the runner
-        # builds so the traced step functions close over the final knobs.
-        env_ep = os.environ.get("DSTPU_EP_SIZE")
-        if env_ep not in (None, ""):
-            import dataclasses as _dc
-            epz = int(env_ep)
-            if epz < 0:
-                raise ValueError(
-                    f"DSTPU_EP_SIZE must be >= 0, got {epz}")
-            self.config = _dc.replace(self.config, ep_size=max(1, epz))
-        env_epo = os.environ.get("DSTPU_EP_OVERLAP")
-        if env_epo not in (None, ""):
-            import dataclasses as _dc
-            head, _, kpart = env_epo.partition(":")
-            rep = {"ep_comm_overlap": head}
-            if kpart:
-                rep["ep_comm_chunks"] = int(kpart)
-            self.config = _dc.replace(self.config, **rep)
-        env_epc = os.environ.get("DSTPU_EP_OVERLAP_CHUNKS")
-        if env_epc not in (None, ""):
-            import dataclasses as _dc
-            self.config = _dc.replace(self.config,
-                                      ep_comm_chunks=int(env_epc))
-        env_cap = os.environ.get("DSTPU_EP_CAPACITY")
-        if env_cap not in (None, ""):
-            import dataclasses as _dc
-            self.config = _dc.replace(self.config,
-                                      ep_capacity_factor=float(env_cap))
         # config × model validation at CONSTRUCTION (satellite of ISSUE
         # 20): unsupported combos (MoE×tp without ep, ep on a dense
         # model, ep not dividing num_experts) fail here with the knob
@@ -326,21 +277,11 @@ class InferenceEngineV2:
             # pressure-driven eviction inside reserve) and on the state
             # manager (match/register/decref); put() drives it below
             from .prefix_cache import PrefixCache
-            # hierarchical KV: the host-RAM tier size, env-overridable
-            # with a LITERAL knob name (dslint DSL004/5). The env bypass
-            # skips the config validation — re-check the resolved value
-            host_blocks = int(
-                os.environ.get("DSTPU_PREFIX_HOST_BLOCKS")
-                or self.config.prefix_cache_host_blocks)
-            if host_blocks < 0:
-                raise ValueError(
-                    f"DSTPU_PREFIX_HOST_BLOCKS must be >= 0, got "
-                    f"{host_blocks}")
             self._prefix = PrefixCache(
                 self.config.block_size,
                 max_blocks=self.config.prefix_cache_max_blocks,
                 policy=self.config.prefix_cache_policy,
-                host_blocks=host_blocks)
+                host_blocks=self.config.prefix_cache_host_blocks)
             self.kv_cache.attach_prefix_cache(self._prefix)
             self.state.prefix = self._prefix
         self.scheduler = SplitFuseScheduler(self.config, self.state)
@@ -350,12 +291,9 @@ class InferenceEngineV2:
         # hand the kv cache a live view, not a snapshot
         self.kv_cache.attach_pool_source(lambda: self._kv_data)
         self._step_counter = 0
-        # overlapped serving pipeline: max in-flight steps. The env knob
-        # DSTPU_SERVE_ASYNC overrides the config (0 = force synchronous —
-        # the operational kill-switch for parity debugging on live traffic)
-        env_depth = os.environ.get("DSTPU_SERVE_ASYNC")
-        self.pipeline_depth = int(env_depth) if env_depth not in (None, "") \
-            else self.config.serve_pipeline_depth
+        # overlapped serving pipeline: max in-flight steps (0 = the
+        # synchronous parity oracle)
+        self.pipeline_depth = self.config.serve_pipeline_depth
         # reused per-(S, C) staging buffers (host alloc churn is on the
         # overlap-critical path) — see _staging_bufs
         self._staging: Dict[Tuple[int, int], Dict[str, Any]] = {}
@@ -382,25 +320,19 @@ class InferenceEngineV2:
             "moe_rows_routed": 0, "moe_rows_hottest": 0}
         self._spans = SpanSet(self.pipeline_stats, lambda: self._obs)
         # ---- serve-side resilience (drain.py, docs/resilience.md) ---- #
-        # env knobs are read with LITERAL names so the dslint knob scan
-        # (DSL004/5) and gen_config_doc keep seeing them
         cfg = self.config
-        self.request_deadline_s = float(
-            os.environ.get("DSTPU_SERVE_DEADLINE_S")
-            or cfg.request_deadline_s)
+        self.request_deadline_s = cfg.request_deadline_s
         #: True once ANY sequence carries a deadline (engine-level knob
         #: or a per-request ``put(..., deadlines=...)`` entry) — the
         #: deadline sweep's cheap skip must not assume the engine knob
         #: is the only deadline source
         self._has_deadlines = self.request_deadline_s > 0
-        self.serve_step_retries = int(
-            os.environ.get("DSTPU_SERVE_RETRY") or cfg.serve_step_retries)
-        self.serve_retry_backoff_s = float(
-            os.environ.get("DSTPU_SERVE_RETRY_BACKOFF_S")
-            or cfg.serve_retry_backoff_s)
-        shed = os.environ.get("DSTPU_SERVE_SHED")
-        self.serve_shed = cfg.serve_shed if shed in (None, "") \
-            else shed not in ("0", "false", "off")
+        self.serve_step_retries = cfg.serve_step_retries
+        self.serve_retry_backoff_s = cfg.serve_retry_backoff_s
+        self.serve_shed = cfg.serve_shed
+        # paths are deployment settings: the environment may place them,
+        # read with LITERAL names so the dslint knob scan (DSL004/5) and
+        # gen_config_doc keep seeing them
         jpath = os.environ.get("DSTPU_SERVE_JOURNAL") or cfg.serve_journal
         self.journal = ReplayJournal(
             jpath,
@@ -409,26 +341,11 @@ class InferenceEngineV2:
         self._manifest_path = \
             os.environ.get("DSTPU_SERVE_DRAIN_MANIFEST") or None
         # ---- speculative decoding (speculative.py, docs/serving.md) -- #
-        # env knobs with LITERAL names (dslint DSL004/5): DSTPU_SPEC_MODE
-        # is the operational on/off switch, DSTPU_SPEC_K / _NGRAM size
-        # the proposals (DSTPU_SPEC_NOISE calibrates bench acceptance,
-        # read inside speculative.build_proposer)
-        self.spec_mode = os.environ.get("DSTPU_SPEC_MODE") \
-            or cfg.spec_decode
-        self.spec_k = int(os.environ.get("DSTPU_SPEC_K")
-                          or cfg.spec_k)
-        self.spec_ngram = int(os.environ.get("DSTPU_SPEC_NGRAM")
-                              or cfg.spec_ngram)
-        if self.spec_mode not in ("off", "ngram", "draft"):
-            raise ValueError(
-                f"DSTPU_SPEC_MODE must be off|ngram|draft, got "
-                f"{self.spec_mode!r}")
-        if self.spec_k < 1 or self.spec_ngram < 1:
-            # the env overrides bypass the config's __post_init__
-            # validation — re-check the RESOLVED values
-            raise ValueError(
-                f"DSTPU_SPEC_K/DSTPU_SPEC_NGRAM must be >= 1, got "
-                f"k={self.spec_k} ngram={self.spec_ngram}")
+        # attributes, not config reads: the admission controller's
+        # brownout ladder lowers spec_mode / spec_k on a live engine
+        self.spec_mode = cfg.spec_decode
+        self.spec_k = cfg.spec_k
+        self.spec_ngram = cfg.spec_ngram
         #: paired draft engine (attach_draft) for spec_mode='draft'
         self._draft_engine = None
         #: lazy proposer instance (speculative.build_proposer)
@@ -667,7 +584,7 @@ class InferenceEngineV2:
         st["prefill_chunks_skipped_frac"] = (
             hit / (hit + ran) if hit + ran else 0.0)
         # hierarchical KV: the fraction of matched tokens the HOST tier
-        # served (the serve_hier bench's honest hit attribution)
+        # served
         st["host_hit_frac"] = (
             st["host_matched_tokens"] / hit if hit else 0.0)
         return st
@@ -1840,7 +1757,7 @@ class InferenceEngineV2:
                          ) -> Dict[int, List[int]]:
         """Decode up to ``n`` tokens per uid (int, or a per-uid sequence
         of budgets) through the overlapped pipeline — or, when
-        speculative decoding is armed (``spec_decode``/``DSTPU_SPEC_MODE``
+        speculative decoding is armed (``spec_decode``
         and every sequence in the batch is greedy), through
         :meth:`decode_spec`, token-identically. Single-engine drivers
         (the open-loop loadgen, the replica pool) call this one surface
@@ -2281,8 +2198,8 @@ class InferenceEngineV2:
                     # acceptance accounting over the COMMITTABLE window:
                     # the numerator is drafts actually kept (consumed
                     # inputs are lt + d_1..d_{a-1} -> a-1 drafts; a
-                    # rolled-back verified draft must not inflate the rate
-                    # the bench gates on), and the denominator excludes
+                    # rolled-back verified draft must not inflate the
+                    # rate), and the denominator excludes
                     # the budget-capped tail (only rem-1 drafts could
                     # ever commit this round — the rest is the pinned-L
                     # over-verification padding, not a proposer miss), so

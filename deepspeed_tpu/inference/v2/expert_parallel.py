@@ -215,7 +215,7 @@ def build_ep_context(cfg, runner, params,
 
 def expert_memory_report(engine) -> dict:
     """Per-chip vs total expert-stack bytes, read from the LIVE device
-    shardings (the bench gauge: at ep=2 per-chip must be total/2).
+    shardings (at ep=2 per-chip must be total/2).
     Counts every leaf the EP planner marked ``"ep"``; on an unsharded
     engine every MoE stack counts as fully chip-resident."""
     epc = getattr(engine.runner, "epctx", None)

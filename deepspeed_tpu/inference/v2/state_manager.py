@@ -70,7 +70,7 @@ class StateManager:
         #: stretches it so promotions yield ticks to decode chunks —
         #: token-stream-invariant, it changes only WHEN a chunk runs
         self.promote_defer_ticks: int = 1
-        #: skipped-vs-run prefill accounting for the serve_prefix bench /
+        #: skipped-vs-run prefill accounting for the prefix tests and
         #: smoke rows: matched_tokens never ran a prefill chunk,
         #: prefill_tokens did (scheduler-counted, prompt positions only)
         self.prefix_stats = {"matched_tokens": 0, "matched_blocks": 0,
@@ -79,12 +79,12 @@ class StateManager:
                              # multi-token trims (speculative rollback /
                              # pipelined EOS retraction) and the blocks
                              # they returned — the rollback-pressure
-                             # signal the serve_spec bench reads
+                             # signal
                              "trims": 0, "trimmed_blocks": 0,
                              # hierarchical KV: tokens matched out of the
                              # HOST tier (full promoted blocks + host CoW
                              # spans) — the "demoted hit is still a hit"
-                             # numerator the serve_hier bench reads
+                             # numerator
                              "host_matched_tokens": 0}
 
     # ------------------------------------------------------------------ #

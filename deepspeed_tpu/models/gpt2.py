@@ -44,9 +44,8 @@ class GPT2Config:
     # "flash" / "xla" force one path.
     attention_impl: str = "auto"
     # flash kernel tile geometry (ops/kernels/flash_attention.py):
-    # 512/512 measured best at seq 512; 1024/1024 measured +3.3 TFLOPS at
-    # seq 2048 (profiles/r04_results.jsonl big_bqk1024) — the bench sets
-    # it per shape
+    # 512/512 suits seq 512; the benchmark's 2048-token configuration
+    # sets 1024/1024 (benchmark/configs/gpt-1p3b.json)
     flash_block_q: int = 512
     flash_block_k: int = 512
     # fused LM-head xent chunking (models/_lm_utils.chunked_lm_xent):

@@ -28,7 +28,8 @@ This kernel never writes logits to HBM at all:
 Cost accounting vs the chunked path: 5 MXU passes of N*V*C MACs
 (fwd, 2x recompute, dh, dE) vs the chunked path's 4 plus ~8*N*V bytes of
 fp32 chunk HBM traffic plus scan serialization. Bandwidth-bound shapes
-win; the crossover is measured, not assumed (tools/profile_train.py).
+win; the crossover has not been measured on the benchmark's cells, which
+run the chunked path (``benchmark/configs/gpt-1p3b.json``).
 """
 
 from __future__ import annotations

@@ -1059,7 +1059,8 @@ class ReplicaPool:
 def fleet_prefix_stats(pool: ReplicaPool) -> Dict[str, Any]:
     """Summed host-side prefix-cache counters across live replicas plus
     the fleet-wide skipped-prefill fraction — the number the routing
-    bench gates on (prefix-aware must beat random here)."""
+    test gates on (``test_serving_fleet.py``: prefix-aware must beat
+    random here)."""
     keys = ("matched_tokens", "prefill_tokens", "cow_tokens",
             "matched_blocks", "cow_copies")
     out: Dict[str, Any] = {k: 0 for k in keys}

@@ -5,7 +5,7 @@ by a pluggable policy (``ReplicaPool(policy=...)`` /
 ``DSTPU_FLEET_POLICY``):
 
   * ``random``       — seeded uniform choice over available replicas
-    (the control the fleet bench compares against);
+    (the control the routing test compares against);
   * ``round_robin``  — cycle over available replicas in id order;
   * ``prefix_aware`` — score every available replica and take the max.
 

@@ -30,7 +30,8 @@ By construction ``plan + dispatch + device_execute + commit_apply +
 host_gap`` equals the serve loop's wall clock (each step's wall is the
 interval between commit boundaries; the loop exit closes the tail), so
 the components sum to externally measured step wall-clock within
-tolerance — ``bench.py serve_attrib`` gates exactly that. Everything is
+tolerance — ``tests/unit/test_attribution.py`` gates exactly that.
+Everything is
 host-side ``perf_counter`` arithmetic at existing boundaries: traced
 programs gain 0 host callbacks and the warm path 0 fresh compiles with
 attribution on (same gates as the PR 8 observer).
@@ -135,8 +136,8 @@ def attribution_report(snap: Mapping[str, Any],
     """The attribution summary over a snapshot (or a window between two
     snapshots): per-component seconds and fractions of the step wall,
     the dominant component, and the closure error
-    (``|wall − Σ components| / wall`` — the quantity the serve_attrib /
-    train_obs benches gate; a large residual means a new unbracketed
+    (``|wall − Σ components| / wall`` — the quantity test_attribution /
+    test_train_obs gate; a large residual means a new unbracketed
     code path crept into the loop). Defaults cover the serve partition;
     pass the TRAIN_* tables for the train observer."""
     comps = component_totals(snap, prev, components=components)

@@ -34,7 +34,7 @@ Pieces:
     and the shed/deadline-miss breakdown.
   * :func:`sweep_capacity` — offered-QPS sweep locating the knee: the
     highest offered rate whose goodput fraction still meets the SLO
-    threshold (``bench.py serve_capacity`` / ``bin/dstpu_loadgen``).
+    threshold (``bin/dstpu_loadgen --sweep``).
 
 The driver's per-iteration work (:meth:`_OpenLoopDriver._admit_due`,
 :meth:`_OpenLoopDriver._decode_burst`) is dslint DSL001-registered: it
@@ -250,7 +250,7 @@ class WorkloadMix:
     #: a tier whose whole point is surviving between revisits (uniform
     #: assignment revisits hot groups too soon and cold ones maybe
     #: never). Size it >= 3x the engine's device pool to measure the
-    #: host tier (bench.py serve_hier's workload).
+    #: host tier.
     prefix_working_set_blocks: int = 0
     #: tokens per KV block the working-set sizing assumes (the target
     #: engine's block_size; the CLI's tiny engine uses 16)
@@ -965,8 +965,7 @@ def sweep_capacity(engine, rates: Sequence[float], n_per_rate: int,
     whose goodput fraction still meets ``goodput_slo_frac``. Each rate
     runs an independent seeded pass (disjoint uid ranges; the engine's
     compiled programs stay warm across passes). Returns the
-    goodput-vs-offered-load curve plus the located knee — the
-    ``bench.py serve_capacity`` payload."""
+    goodput-vs-offered-load curve plus the located knee."""
     if process not in ("poisson", "uniform"):
         # a recorded trace pins its own rate — sweeping offered rates
         # over it has no meaning, and silently substituting Poisson
@@ -1025,8 +1024,8 @@ def _ms(v: Optional[float]) -> Optional[float]:
 def disagg_report(pool) -> Dict[str, Any]:
     """The ``disagg`` report section for a phase-specialist fleet
     (docs/serving.md "Disaggregated serving"): handoff volume (source-
-    counted), adoptions, fallback replays, the exposed-wait tail the
-    serve_disagg bench gates on, and per-role utilization rolled up
+    counted), adoptions, fallback replays, the exposed-wait tail, and
+    per-role utilization rolled up
     from the per-replica registries (``serve_tokens_committed`` /
     ``serve_steps`` attribute each role's share of the work)."""
     roles: Dict[str, Dict[str, Any]] = {}
@@ -1081,8 +1080,8 @@ def _tiny_engine(max_seqs: int = 8, num_blocks: int = 96,
                  host_blocks: int = 0, seq_size: int = 1):
     """CPU-harness GPT-2 engine for the CLI's self-contained mode and
     the tier-1 capacity smoke — small enough that a decode step is a
-    few ms. ``spec`` arms speculative decoding (``--spec`` /
-    ``DSTPU_SPEC_MODE``); ``host_blocks`` arms the hierarchical-KV
+    few ms. ``spec`` arms speculative decoding (``--spec``);
+    ``host_blocks`` arms the hierarchical-KV
     host-RAM tier (``--host-blocks``) so the working-set workload has a
     second tier to hit; ``seq_size`` opens the sequence-parallel axis
     (``--seq``, docs/serving.md "Long-context serving") — the caller
@@ -1314,8 +1313,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             # per-replica host devices BEFORE the backend initializes —
             # without them every tiny engine lands on ONE device and
             # the pool's replica threads serialize, so the fleet
-            # numbers would not scale with --replicas (the same shim
-            # bench.py serve_fleet uses)
+            # numbers would not scale with --replicas
             from ..utils.jax_compat import request_cpu_devices
             request_cpu_devices(max(2, args.replicas))
         mcfg_box = []

@@ -22,8 +22,9 @@ Design constraints (why this is not just a dict of floats):
     sample buffer.
   * **No-op when off.** ``DSTPU_TELEMETRY=0`` routes every caller to the
     :class:`NullRegistry`, whose metric handles are shared do-nothing
-    singletons — the zero-overhead kill switch (``bench.py serve_obs``
-    measures the on-path against it).
+    singletons — the zero-overhead kill switch
+    (``test_telemetry.py::TestServeTelemetry`` holds the on-path's token
+    streams against it).
 
 Metric names live in :data:`REGISTERED_METRICS`; the dslint DSL006 rule
 keeps that table and the docs/observability.md catalog from drifting in

@@ -317,7 +317,7 @@ class ServeObserver:
         ``blocks`` host-tier blocks scattered back on device, paying
         ``wait_s`` of host-side dispatch time on the plan path (the
         transfers themselves overlap under subsequent compute — this
-        histogram IS the exposed cost the serve_hier bench gates on).
+        histogram IS the exposed cost).
         Registered DSL001 hot path: a counter add + one observe."""
         self.c_promoted.inc(blocks)
         self.h_promote.observe(wait_s)
@@ -337,8 +337,7 @@ class ServeObserver:
         (``blocks`` KV blocks scattered in). ``exposed_s`` is the
         caller-measured NON-overlapped transfer wall — the part of the
         gather→materialize→scatter chain that did not hide under
-        neighboring compute; the serve_disagg bench gates on its share
-        of prefill time. Registered DSL001 hot path."""
+        neighboring compute. Registered DSL001 hot path."""
         self.c_handoff_in.inc(seqs)
         self.h_handoff_exposed.observe(exposed_s)
         del blocks  # volume counted once, at the source

@@ -19,7 +19,7 @@ host-side boundaries:
     bookkeeping) / ``host_gap`` (the CLOSURE of the sum: wall between
     step-exit boundaries minus every bracket), so the six components ≡
     measured wall by construction — the same closure discipline
-    ``serve_attrib`` gates, gated here by ``bench.py train_obs``. The
+    serve attribution keeps, gated by ``tests/unit/test_train_obs.py``. The
     four bracketed components arrive from the engine's own brackets
     (``telemetry/trace.py``, ``on_span``);
   * **goodput** — checkpoint saves, resumes and step progress land as
@@ -46,8 +46,8 @@ Everything on the record path is pre-bound counter/histogram arithmetic
 over host floats (dslint DSL001-registered). The observer never blocks
 on the step just dispatched: the engine's ``train/device_wait`` bracket
 waits for the step before it, so the device always has one step queued,
-and ``bench.py train_obs`` gates the whole record path at ≤3% overhead
-with 0 fresh warm-path compiles.
+and ``tests/unit/test_train_obs.py`` holds the record path to 0 fresh
+warm-path compiles and bit-identical state on or off.
 """
 
 from __future__ import annotations

@@ -318,6 +318,41 @@ class TestRetryDiscipline:
                              retry_budget=0).report
         assert rep2["requests"]["completed"] == 4
 
+    def test_armed_door_is_invisible_at_steady_load(self, tiny_engine):
+        """Well under capacity with a healthy controller armed: token
+        streams are those of the unarmed door (``admission=None``, the
+        DSTPU_ADMISSION=0 path), nothing is refused, the brownout ladder
+        never moves, and the armed pass compiles nothing."""
+        from deepspeed_tpu.analysis import RecompileTripwire
+        from deepspeed_tpu.telemetry.loadgen import (PoissonArrivals,
+                                                     WorkloadMix,
+                                                     build_requests,
+                                                     run_open_loop)
+        eng, mcfg = tiny_engine
+        mix = WorkloadMix(prompt_lens=(8,), prompt_probs=(1.0,),
+                          gen_lens=(4,), gen_probs=(1.0,),
+                          vocab_size=mcfg.vocab_size)
+
+        def reqs():
+            return build_requests(PoissonArrivals(20.0, seed=6), mix, 12,
+                                  seed=6, uid_base=600)
+
+        off = run_open_loop(eng, reqs())          # also the warm-up
+        ctrl = AdmissionController(eng, window_s=0.5, qw_slo_s=30.0,
+                                   tick_s=0.05)
+        ctrl.prime()
+        tw = RecompileTripwire()
+        with tw:
+            on = run_open_loop(eng, reqs(), admission=ctrl,
+                               retry_budget=2, retry_base_s=0.01)
+        assert on.streams == off.streams and all(on.streams.values())
+        rep = on.report
+        assert rep["requests"]["completed"] == 12
+        assert rep["requests"]["rejected_admission"] == 0
+        assert rep["requests"]["balance_ok"]
+        assert rep["admission"]["transitions"] == 0 and ctrl.level == 0
+        assert tw.fresh_compiles == 0
+
 
 # ------------------------------------------------------------------ #
 # the in-process spike gate + parity + compile discipline

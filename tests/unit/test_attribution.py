@@ -7,16 +7,13 @@ token, a synthetic host-side stall inside the serve loop is LOCALIZED
 to the host-gap component, one request's trace context follows it
 through router scoring → replica execution → SIGTERM drain → survivor
 replay as ONE gapless ordered track in the merged fleet Chrome trace,
-same-numbered uids from different replicas no longer collide after a
-multi-file merge (the tid-namespacing regression), and the
-``bench_compare`` regression sentinel exits non-zero on planted
-regressions / missing phases and zero on improvements.
+and same-numbered uids from different replicas no longer collide
+after a multi-file merge (the tid-namespacing regression).
 """
 
 import json
 import os
 import signal
-import sys
 import time
 
 import numpy as np
@@ -30,12 +27,6 @@ from deepspeed_tpu.telemetry.attribution import (ATTRIBUTION_COMPONENTS,
 from deepspeed_tpu.telemetry.flight_recorder import (FlightRecorder,
                                                      merge_chrome_traces,
                                                      request_tracks)
-
-REPO = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.join(REPO, "tools"))
-
-import bench_compare  # noqa: E402
 
 
 def _gpt2():
@@ -195,12 +186,17 @@ class TestStepAttribution:
         assert share["host_callbacks"] == 0
 
     def test_audited_programs_clean_with_attrib_on(self):
+        from deepspeed_tpu.analysis import RecompileTripwire
         from deepspeed_tpu.analysis.program_audit import \
             audit_serve_programs
         eng = _engine()
         uids = [0]
         first = eng.put(uids, _prompts(1, seed=9), _greedy=True)
-        eng.decode_pipelined(uids, [first[0]], 4)
+        toks = eng.decode_pipelined(uids, [first[0]], 4)
+        tw = RecompileTripwire()
+        with tw:                 # the warm path, attribution recording
+            eng.decode_pipelined(uids, [toks[0][-1]], 4)
+        assert tw.fresh_compiles == 0
         reports = audit_serve_programs(
             eng, programs=("step_greedy", "step_greedy_fb"))
         assert sum(r.host_callbacks for r in reports.values()) == 0
@@ -351,77 +347,3 @@ class TestFleetTraceReconstruction:
         assert args["chosen"] in ("r0", "r1")
         assert args["trace"].startswith("fleet/0#")
         pool.flush(0)
-
-
-# ------------------------------------------------------------------ #
-# bench_compare golden diffs
-# ------------------------------------------------------------------ #
-
-
-class TestBenchCompare:
-    OLD = {"metric": "x", "value": 10.0, "detail": {
-        "serve": {"decode_tokens_per_sec": 100.0, "token_parity": True,
-                  "fresh_compiles_measured": 0},
-        "serve_obs": {"overhead_frac": 0.01},
-        "serve_attrib": {"closure_err_frac": 0.01,
-                         "decode_steps_per_sec": 50.0}}}
-
-    def test_improvement_passes(self):
-        new = {"metric": "x", "value": 11.0, "detail": {
-            "serve": {"decode_tokens_per_sec": 130.0,
-                      "token_parity": True,
-                      "fresh_compiles_measured": 0},
-            "serve_obs": {"overhead_frac": 0.005},
-            "serve_attrib": {"closure_err_frac": 0.008,
-                             "decode_steps_per_sec": 60.0}}}
-        res = bench_compare.compare_rounds(self.OLD, new)
-        assert res["ok"] and not res["regressions"]
-        assert any(r["metric"] == "serve.decode_tokens_per_sec"
-                   for r in res["improvements"])
-
-    def test_planted_regression_fails(self):
-        new = {"metric": "x", "value": 9.9, "detail": {
-            "serve": {"decode_tokens_per_sec": 60.0,
-                      "token_parity": False,
-                      "fresh_compiles_measured": 1},
-            "serve_obs": {"overhead_frac": 0.01},
-            "serve_attrib": {"closure_err_frac": 0.01,
-                             "decode_steps_per_sec": 50.0}}}
-        res = bench_compare.compare_rounds(self.OLD, new)
-        assert not res["ok"]
-        metrics = {r["metric"] for r in res["regressions"]}
-        assert "serve.decode_tokens_per_sec" in metrics
-        assert "serve.token_parity" in metrics        # gate flip
-        assert "serve.fresh_compiles_measured" in metrics   # 0-band
-        # within-band drift never gates
-        assert "serve_attrib.decode_steps_per_sec" not in metrics
-
-    def test_missing_phase_fails_unless_allowed(self):
-        new = {"metric": "x", "value": 10.2, "detail": {
-            "serve": {"decode_tokens_per_sec": 101.0,
-                      "token_parity": True,
-                      "fresh_compiles_measured": 0},
-            "serve_obs": {"overhead_frac": 0.01}}}
-        res = bench_compare.compare_rounds(self.OLD, new)
-        assert not res["ok"]
-        assert res["missing_phases"] == ["serve_attrib"]
-        res2 = bench_compare.compare_rounds(self.OLD, new,
-                                            allow_missing=True)
-        assert res2["ok"]
-
-    def test_cli_exit_codes_and_wrapper_shape(self, tmp_path):
-        old_p = tmp_path / "old.json"
-        new_p = tmp_path / "new.json"
-        old_p.write_text(json.dumps(self.OLD))
-        # the driver-wrapper shape: bench row embedded in stdout tail
-        bad = dict(self.OLD)
-        bad = json.loads(json.dumps(self.OLD))
-        bad["detail"]["serve"]["decode_tokens_per_sec"] = 10.0
-        new_p.write_text(json.dumps(
-            {"n": 17, "rc": 0,
-             "tail": "noise\n" + json.dumps(bad) + "\n"}))
-        assert bench_compare.main([str(old_p), str(new_p)]) == 1
-        good = json.loads(json.dumps(self.OLD))
-        new_p.write_text(json.dumps(good))
-        assert bench_compare.main([str(old_p), str(new_p)]) == 0
-        assert bench_compare.main([str(old_p), "/nonexistent.json"]) == 2

@@ -74,9 +74,9 @@ class TestRepoClean:
     def test_knob_scan_finds_known_knobs(self, repo_knob_reads):
         names = {r.name for r in repo_knob_reads}
         # spot-check knobs of three different subsystems
-        assert "DSTPU_SERVE_ASYNC" in names
+        assert "DSTPU_SERVE_JOURNAL" in names
         assert "DSTPU_FAULT_SITE" in names
-        assert "DSTPU_BENCH_TP" in names
+        assert "DSTPU_LOADGEN_RATE" in names
         assert len(names) >= 60
 
 

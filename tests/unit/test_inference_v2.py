@@ -2,6 +2,9 @@
 ``tests/unit/inference/v2/`` (ragged ops, KV cache, scheduling) plus the
 model-parity checks of ``test_inference.py``."""
 
+import dataclasses
+import operator
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -54,6 +57,50 @@ def _tiny_setup(block_size=4, num_blocks=64, max_seqs=4, chunk=8,
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
     return cfg, mcfg, model, params
+
+
+#: engine-construction variables the serve engine no longer reads: each
+#: shadowed a RaggedInferenceConfig field (a legal non-default value, the
+#: field, and the engine attribute that keeps the resolved value where
+#: it is not ``config.<field>`` itself)
+_RETIRED_ENV = [
+    ("DSTPU_SEQ_PARALLEL", "2", "seq_size", None),
+    ("DSTPU_EP_SIZE", "2", "ep_size", None),
+    ("DSTPU_EP_OVERLAP", "chunked", "ep_comm_overlap", None),
+    ("DSTPU_EP_OVERLAP_CHUNKS", "4", "ep_comm_chunks", None),
+    ("DSTPU_EP_CAPACITY", "1.5", "ep_capacity_factor", None),
+    ("DSTPU_PREFIX_HOST_BLOCKS", "32", "prefix_cache_host_blocks",
+     "_prefix.host_blocks"),
+    ("DSTPU_SERVE_ASYNC", "0", "serve_pipeline_depth", "pipeline_depth"),
+    ("DSTPU_SERVE_DEADLINE_S", "1.5", "request_deadline_s",
+     "request_deadline_s"),
+    ("DSTPU_SERVE_RETRY", "5", "serve_step_retries", "serve_step_retries"),
+    ("DSTPU_SERVE_RETRY_BACKOFF_S", "0.5", "serve_retry_backoff_s",
+     "serve_retry_backoff_s"),
+    ("DSTPU_SERVE_SHED", "0", "serve_shed", "serve_shed"),
+    ("DSTPU_SPEC_MODE", "ngram", "spec_decode", "spec_mode"),
+    ("DSTPU_SPEC_K", "7", "spec_k", "spec_k"),
+    ("DSTPU_SPEC_NGRAM", "5", "spec_ngram", "spec_ngram"),
+]
+
+
+@pytest.mark.parametrize("name,value,field,attr", _RETIRED_ENV,
+                         ids=[c[0] for c in _RETIRED_ENV])
+def test_engine_config_is_its_config_object(monkeypatch, name, value,
+                                            field, attr):
+    """The environment is not a second source of the engine's
+    configuration: with the variable set, an engine built from default
+    fields still runs the defaults."""
+    cfg, mcfg, _, params = _tiny_setup()
+    cfg = dataclasses.replace(cfg, prefix_cache=True,
+                              attention_impl="dense")
+    monkeypatch.setenv(name, value)
+    eng = InferenceEngineV2(mcfg, params, cfg)
+    assert eng.config == cfg
+    default = getattr(RaggedInferenceConfig(), field)
+    assert getattr(eng.config, field) == default
+    if attr:
+        assert operator.attrgetter(attr)(eng) == default
 
 
 class TestStateManager:

@@ -11,7 +11,7 @@ per MoE layer per step, 2*chunks under the chunked overlap, zero
 anything-else); ``overlap='chunked'`` is numerics-preserving; ep
 composes with tp on the 2-D (expert, model) mesh; drain/handoff
 manifests cross ep geometries; the warm path stays compile-free; and
-``DSTPU_EP_SIZE=0`` restores the exact single-chip programs (zero
+``ep_size=1`` is the exact single-chip engine (zero
 collectives under the auditor).
 
 Tier-1 wall discipline: every Mixtral engine build compiles real XLA
@@ -259,16 +259,13 @@ class TestEPParity:
         assert eng.runner.epctx.mesh.shape == {EP_AXIS: 2, "model": 2}
         assert eng.generate(prompts, max_new_tokens=6) == ref
 
-    def test_killswitch_restores_single_chip_engine(self, base_pair,
-                                                    oracle, monkeypatch):
-        # DSTPU_EP_SIZE=0 must yield the exact pre-EP engine: ep_size
-        # resolves to 1, programs carry ZERO collectives, tokens match
+    def test_ep1_is_the_single_chip_engine(self, base_pair, oracle):
+        # ep_size=1 is the exact pre-EP engine: programs carry ZERO
+        # collectives, tokens match
         mcfg, params, base = base_pair
-        monkeypatch.setenv("DSTPU_EP_SIZE", "0")
         eng = InferenceEngineV2(mcfg, params, RaggedInferenceConfig(
-            **base, ep_size=2))
-        assert eng.config.ep_size == 1
-        monkeypatch.delenv("DSTPU_EP_SIZE")
+            **base, ep_size=1))
+        assert eng.runner.epctx is None
         for name, rep in audit_serve_programs(eng).items():
             assert rep.total_collectives == 0, (name, rep.summary())
         prompts = _prompts(seed=17)
@@ -346,7 +343,7 @@ class TestEPHopBudget:
         # per MoE layer: dispatch + combine, nothing per-program (the
         # batch replicates, logits need no gather) — the spec lives in
         # the shared registry (analysis/budgets.py "ep-step"), the same
-        # one bench.py serve_moe asserts and dslint DSL008 cross-checks
+        # one dslint DSL008 cross-checks
         budget = CollectiveBudget(**budget_args(
             "ep-step", num_layers=L, label="ep2-step"))
         for name in ("step", "step_greedy", "step_greedy_fb",

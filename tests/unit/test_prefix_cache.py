@@ -846,8 +846,8 @@ class TestHierKVServing:
         reqs = self._workload()
         off = self._run(self._engine(mcfg, params, prefix_cache=False),
                         reqs)
-        dev = self._run(self._engine(mcfg, params, prefix_cache=True),
-                        reqs)
+        dev_eng = self._engine(mcfg, params, prefix_cache=True)
+        dev = self._run(dev_eng, reqs)
         hier_eng = self._engine(mcfg, params, prefix_cache=True,
                                 prefix_cache_host_blocks=64)
         hier = self._run(hier_eng, reqs)
@@ -861,7 +861,32 @@ class TestHierKVServing:
         assert st["host_hit_blocks"] > 0
         assert st["host_matched_tokens"] > 0
         assert st["prefill_chunks_skipped_frac"] > 0.3
+        assert st["prefill_chunks_skipped_frac"] >= 1.3 * \
+            dev_eng.prefix_stats["prefill_chunks_skipped_frac"]
         assert st["evicted_pressure"] == 0      # nothing destroyed
+
+    def test_tier_warm_path_zero_fresh_compiles(self):
+        # demotion gathers and promotion scatters are shape-bucketed: a
+        # second lap over the working set, demoting and promoting all
+        # the way, compiles nothing
+        from deepspeed_tpu.analysis import RecompileTripwire
+        mcfg, params = self._model()
+        eng = self._engine(mcfg, params, prefix_cache=True,
+                           prefix_cache_host_blocks=64)
+        self._run(eng, self._workload(rounds=2))
+        st0 = dict(eng.prefix_stats)
+        tw = RecompileTripwire()
+        with tw:
+            # fresh preambles push the old ones down, a revisit pulls
+            # them back up
+            self._run(eng, [(100 + u, p) for u, p in
+                            self._workload(rounds=1, seed=1)])
+            self._run(eng, [(200 + u, p) for u, p in
+                            self._workload(rounds=1)])
+        st = eng.prefix_stats
+        assert st["demoted"] > st0["demoted"]
+        assert st["promoted"] > st0["promoted"]
+        assert tw.fresh_compiles == 0
 
     def test_tier_parity_with_spec_decode(self):
         mcfg, params = self._model()

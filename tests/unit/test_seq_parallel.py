@@ -9,7 +9,7 @@ is exactly budgeted (ring hops = seq-1 ppermutes + 1 fresh-KV all-gather
 per layer in prefill, 1 stat-combine all-gather per layer per fused
 decode step, 1 owner psum per step program); drain/handoff manifests
 cross seq geometries; the warm path stays compile-free; and
-``DSTPU_SEQ_PARALLEL=0`` restores the exact pre-seq programs (zero
+``seq_size=1`` is the exact pre-seq engine (zero
 collectives under the auditor).
 
 Tier-1 wall discipline: params init and every engine build compile real
@@ -247,18 +247,15 @@ class TestSeqParity:
         assert eng.config.effective_chunk == 8
         assert eng.generate(prompts, max_new_tokens=6) == ref
 
-    def test_killswitch_restores_single_chip_engine(self, base_pair,
-                                                    oracle, monkeypatch):
-        # DSTPU_SEQ_PARALLEL=0 must yield the exact pre-seq engine:
-        # seq_size resolves to 1, programs carry ZERO collectives (the
-        # auditor sees no diff vs the single-chip baseline), tokens match
+    def test_seq1_is_the_single_chip_engine(self, base_pair, oracle):
+        # seq_size=1 is the exact pre-seq engine: programs carry ZERO
+        # collectives (the auditor sees no diff vs the single-chip
+        # baseline), tokens match
         mcfg, params, base = base_pair
-        monkeypatch.setenv("DSTPU_SEQ_PARALLEL", "0")
         eng = InferenceEngineV2(mcfg, params, RaggedInferenceConfig(
-            **base, seq_size=2))
-        assert eng.config.seq_size == 1
+            **base, seq_size=1))
+        assert eng.runner.seqctx is None
         prompts = _prompts(seed=17)
-        monkeypatch.delenv("DSTPU_SEQ_PARALLEL")
         ref = oracle.generate(prompts, max_new_tokens=5)
         assert eng.generate(prompts, max_new_tokens=5) == ref
         for name, rep in audit_serve_programs(eng).items():
@@ -351,8 +348,8 @@ class TestSeqHopBudget:
         # per layer: 1 fresh-KV all-gather + (seq-1)=1 ring ppermute;
         # per program: 1 owner-logits psum (GPT-2's tied unembed adds
         # no logits gather) — the spec lives in the shared registry
-        # (analysis/budgets.py "seq-step"), the same one bench.py's
-        # serve_longctx asserts and dslint DSL008 cross-checks
+        # (analysis/budgets.py "seq-step"), the same one dslint DSL008
+        # cross-checks
         budget = CollectiveBudget(**budget_args(
             "seq-step", num_layers=L, seq=2, label="seq2-step"))
         for name in ("step", "step_greedy", "step_greedy_fb"):

@@ -146,6 +146,21 @@ class TestDisaggParity:
         toks, _ = _drive(_colocated_pool(2), PROMPTS)
         assert toks == greedy_oracle
 
+    def test_warm_handoff_pass_zero_fresh_compiles(self, greedy_oracle):
+        # the batched KV gather and restore scatter are shape-bucketed:
+        # a second wave of the same shapes migrates and decodes without
+        # compiling anything
+        from deepspeed_tpu.analysis import RecompileTripwire
+        pool = _disagg_pool()
+        _drive(pool, PROMPTS)
+        again = {10 + u: p for u, p in PROMPTS.items()}
+        tw = RecompileTripwire()
+        with tw:
+            toks, owners = _drive(pool, again)
+        assert {u - 10: t for u, t in toks.items()} == greedy_oracle
+        assert set(owners.values()) == {"dec"}
+        assert tw.fresh_compiles == 0
+
     def test_sampled_seeded_parity(self):
         sp = {u: SamplingParams(temperature=0.8, top_k=12, seed=70 + u)
               for u in PROMPTS}

@@ -297,6 +297,54 @@ class TestPoolSmoke:
             pool.flush(r.uid)
 
 
+class TestRoutingEarnsItsKeep:
+    def test_prefix_aware_beats_random_on_fleet_hit_fraction(self):
+        """More preamble groups than ONE replica's prefix-cache cap
+        holds, the same requests one after another: affinity keeps each
+        replica's group subset resident where random placement thrashes
+        both caps. Tokens do not depend on placement."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                                RaggedInferenceConfig)
+        from deepspeed_tpu.models.gpt2 import GPT2, GPT2Config
+        mcfg = GPT2Config(vocab_size=96, max_seq_len=256, num_layers=2,
+                          num_heads=2, hidden_size=32, dtype=jnp.float32)
+        params = GPT2(mcfg).init(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))["params"]
+
+        def factory(i, dev):
+            # the cap holds two of the four 2-block preambles
+            return InferenceEngineV2(
+                mcfg, jax.device_put(params, dev), RaggedInferenceConfig(
+                    max_seqs=4, chunk_size=16, block_size=16,
+                    num_blocks=48, max_blocks_per_seq=8, dtype="float32",
+                    attention_impl="dense", decode_loop_steps=0,
+                    prefix_cache=True, prefix_cache_max_blocks=4))
+
+        rng = np.random.default_rng(3)
+        pres = [rng.integers(1, 96, 32).tolist() for _ in range(4)]
+        reqs = [(lap * 4 + g, pres[g] + rng.integers(1, 96, 6).tolist())
+                for lap in range(5) for g in range(4)]
+        hit, streams = {}, {}
+        for policy in ("prefix_aware", "random"):
+            pool = ReplicaPool(build_replica_engines(factory, 2),
+                               policy=policy, seed=0)
+            out = {}
+            for uid, prompt in reqs:
+                first = pool.put([uid], [prompt], _greedy=True)
+                toks = pool.decode_pipelined([uid], [first[uid]], 2)
+                out[uid] = [int(first[uid])] + toks[uid]
+                pool.flush(uid)
+            st = fleet_prefix_stats(pool)
+            hit[policy] = st["matched_tokens"] / (
+                st["matched_tokens"] + st["prefill_tokens"])
+            streams[policy] = out
+        assert hit["prefix_aware"] > 2 * hit["random"] > 0
+        assert streams["prefix_aware"] == streams["random"]
+
+
 class TestElasticMembership:
     def _drive(self, pool, prompts, gen, drain_at=None, joiner=None):
         toks = {}

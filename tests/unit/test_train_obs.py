@@ -478,55 +478,6 @@ class TestExportAndTop:
         assert "no ledger events" not in out
         assert "loss         0.0000" not in out
 
-    def test_bench_compare_train_directions(self):
-        """The direction catalog gates the train_obs metrics: a rising
-        data_wait or a falling goodput is a regression; parity gates
-        never flip false silently."""
-        import sys
-        repo = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        sys.path.insert(0, os.path.join(repo, "tools"))
-        from bench_compare import compare_rounds
-        old = {"steps_per_sec": 100.0, "overhead_frac": 0.01,
-               "closure_err_frac": 0.05,
-               "goodput_drill": {"train_goodput_frac": 0.9},
-               "loss_state_parity": True,
-               "injected": {"component_deltas_s": {"data_wait": 0.1}}}
-        new = json.loads(json.dumps(old))
-        new["goodput_drill"]["train_goodput_frac"] = 0.4
-        new["loss_state_parity"] = False
-        res = compare_rounds(old, new)
-        metrics = {r["metric"] for r in res["regressions"]}
-        assert not res["ok"]
-        assert any("train_goodput_frac" in m for m in metrics)
-        assert any("loss_state_parity" in m for m in metrics)
-
-    def test_bench_compare_bucket_directions_beat_goodput_glob(self):
-        """Regression (review catch): goodput_drill.buckets.* seconds
-        are LOWER-is-better even though their dotted path matches the
-        generic *goodput* higher rule — order matters."""
-        import sys
-        repo = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        sys.path.insert(0, os.path.join(repo, "tools"))
-        from bench_compare import _direction
-        assert _direction("goodput_drill.buckets.restart_lost") == "lower"
-        assert _direction("goodput_drill.buckets.replay_catchup") == "lower"
-        assert _direction("goodput_drill.buckets.stall") == "lower"
-        assert _direction(
-            "goodput_drill.train_goodput_frac") == "higher"
-        # review catch: the injection experiments' per-component
-        # diagnostic breakdown scales with the injection knob — it must
-        # never gate (the localized_to_* booleans still do)
-        from bench_compare import compare_rounds
-        old = {"injected": {"component_deltas_s": {"data_wait": 0.1},
-                            "localized_to_data_wait": True}}
-        new = {"injected": {"component_deltas_s": {"data_wait": 0.4},
-                            "localized_to_data_wait": True}}
-        assert compare_rounds(old, new)["ok"]
-        new["injected"]["localized_to_data_wait"] = False
-        assert not compare_rounds(old, new)["ok"]
-
 
 class TestReviewHardening:
     def test_pre_window_between_work_never_breaks_closure(self):

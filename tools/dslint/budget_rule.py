@@ -1,7 +1,7 @@
 """dslint DSL008 — static collective-budget auditor.
 
 The declarative registry lives in ``deepspeed_tpu/analysis/budgets.py``
-as PURE LITERALS: the runtime (bench asserts, budget tests) imports it,
+as PURE LITERALS: the runtime (the budget tests) imports it,
 while this rule ``ast.literal_eval``s the same assignments — one source
 of truth, checked without ever importing the package (no jax needed at
 lint time).

@@ -140,8 +140,9 @@ HOT_PATHS: Mapping[str, Tuple[str, ...]] = {
     # the open-loop loadgen's per-iteration driver brackets the engine's
     # overlapped pipeline (admit due arrivals, run a short decode
     # burst): a blocking host sync here would serialize the very hot
-    # path whose capacity the bench is measuring, and stall the arrival
-    # clock the open-loop invariant protects
+    # path whose capacity the run is measuring (bin/dstpu_loadgen;
+    # benchmark/jobs/open_loop.py drives the same loop shape), and stall
+    # the arrival clock the open-loop invariant protects
     "deepspeed_tpu/telemetry/loadgen.py":
         ("_admit_due", "_decode_burst", "_door_reject"),
     # the admission controller's poll/door/reject hooks run per driver

@@ -14,7 +14,7 @@ from .core import REPO, Finding, RepoIndex, _dotted, _py_files
 
 #: roots scanned for DSTPU_* env reads (knob rules + gen_config_doc) —
 #: everything an operator can set, test-only knobs excluded
-ENV_SCAN_ROOTS = ("deepspeed_tpu", "bench.py", "tools", "bin", "examples")
+ENV_SCAN_ROOTS = ("deepspeed_tpu", "tools", "bin", "examples")
 
 _KNOB_DOC_ROW_RE = re.compile(r"^\|\s*`(DSTPU_[A-Z0-9_]+)`")
 _ENV_METHODS = ("get", "pop", "setdefault")

@@ -83,8 +83,7 @@ Keys of `deepspeed_tpu.inference.v2.RaggedInferenceConfig` — the v2
 ragged engine's constructor config (`InferenceEngineV2` /
 `build_hf_engine(engine_config=...)`), the analogue of the reference's
 `RaggedInferenceEngineConfig`. See docs/serving.md for the serving guide
-(tensor-parallel sharding map, comm accounting, per-chip KV formula,
-bench flags).
+(tensor-parallel sharding map, comm accounting, per-chip KV formula).
 
 """
 
@@ -95,14 +94,13 @@ ENV_HEADER = """
 
 Every `DSTPU_*` environment variable the code reads — name, default and
 reading site — generated from an AST scan of `deepspeed_tpu/`,
-`bench.py`, `tools/`, `bin/` and `examples/`
+`tools/`, `bin/` and `examples/`
 (`tools/dslint scan_env_knobs`). `bin/dstpu_lint`'s DSL004/DSL005
 rules fail CI when this table and the code drift, so re-run
 `python tools/gen_config_doc.py` after adding or removing a knob.
 "(required)" means the knob is read with no default
 (`os.environ[...]` or a presence test); "(dynamic)" means the default
-is computed at the read site. Bench/profiling knobs are further
-described in [serving.md](serving.md#bench-flags).
+is computed at the read site.
 
 """
 
