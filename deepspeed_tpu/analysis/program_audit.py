@@ -452,8 +452,17 @@ def serve_program_calls(engine, programs: Tuple[str, ...] = _SERVE_PROGRAMS
     loop_static = dict(n=n, mode="greedy", cand=1, eos_id=-1)
     pool_arr, pool_scales = pool_parts(kv)
     ring = jnp.zeros(
-        (n, r.num_layers, 2, S, r.kv_heads * r.head_dim),
+        (n, r.kv_layers, 2, S, r.kv_heads * r.head_dim),
         pool_arr.dtype if pool_scales is None else r.compute_dtype)
+    # a model with recurrent layers: every row names its state row, and
+    # the fused loop takes the state apart from the paged planes
+    lin = sslots = None
+    planes = kv
+    if r.state_spec is not None:
+        sslots = jnp.arange(S, dtype=jnp.int32)
+        batch = batch._replace(state_slots=sslots)
+        lin = (kv.state, kv.conv)
+        planes = kv._replace(state=None, conv=None)
 
     calls = {
         "step": (r._step, (params, kv, batch), {}),
@@ -461,18 +470,21 @@ def serve_program_calls(engine, programs: Tuple[str, ...] = _SERVE_PROGRAMS
         "step_greedy_fb": (r._step_greedy_fb,
                            (params, kv, batch, zeros_s, ones_s, zeros_s), {}),
         "decode_loop": (r._decode_loop_ring,
-                        (params, kv, zeros_s, zeros_s, ones_s,
+                        (params, planes, lin, sslots, zeros_s, zeros_s,
+                         ones_s,
                          batch.block_tables, *samp_dummies,
                          jnp.zeros((1, 1), jnp.int32)),
                         dict(loop_static, feed="self")),
         # the speculative verify program: identical scan, draft-fed
         "decode_verify": (r._decode_loop_ring,
-                          (params, kv, zeros_s, zeros_s, ones_s,
+                          (params, planes, lin, sslots, zeros_s, zeros_s,
+                           ones_s,
                            batch.block_tables, *samp_dummies,
                            jnp.zeros((S, n), jnp.int32)),
                           dict(loop_static, feed="given")),
         "flush_ring": (r._flush_ring,
-                       (kv, ring, batch.block_tables, zeros_s, ones_s), {}),
+                       (planes, ring, batch.block_tables, zeros_s, ones_s),
+                       {}),
     }
     if hasattr(r, "_step_sample_fb"):
         calls["step_sample_fb"] = (

@@ -351,6 +351,19 @@ class RaggedInferenceConfig(ConfigModel):
             return
         from ...models.mixtral import MixtralConfig
         is_moe = isinstance(model_cfg, MixtralConfig)
+        if any(k != "attn" for k in getattr(model_cfg, "layer_kinds", ())):
+            # a model with recurrent layers keeps per-sequence state that
+            # cannot be rewound, copied or sharded yet: what would need a
+            # state snapshot refuses here, by name
+            for on, feature in (
+                    (self.prefix_cache, "prefix_cache"),
+                    (self.spec_decode != "off", "spec_decode"),
+                    (self.kv_cache_dtype == "int8", "kv_cache_dtype='int8'"),
+                    (self.tp_size > 1, "tp_size > 1"),
+                    (self.seq_size > 1, "seq_size > 1"),
+                    (self.ep_size > 1, "ep_size > 1")):
+                if on:
+                    raise ValueError(stateful_refusal(feature))
         if is_moe and self.tp_size > 1 and self.ep_size == 1:
             # tp alone would replicate the full expert set on every chip
             # AND trip the dense-branch all-reduce accounting — for MoE
@@ -416,3 +429,12 @@ class RaggedInferenceConfig(ConfigModel):
             return min(self.max_batch_tokens,
                        self.max_seqs * self.chunk_size)
         return self.max_seqs * self.chunk_size
+
+
+def stateful_refusal(feature: str, kind: str = "kda") -> str:
+    """The one wording of every refusal a model with recurrent layers
+    makes: the feature, and the layer kind that stands in its way."""
+    return (f"{feature} is not supported for a model with recurrent "
+            f"({kind!r}) layers: it needs a snapshot, a rewind or a shard "
+            f"of the per-sequence recurrent state, which the state pool "
+            f"cannot give yet")

@@ -67,10 +67,11 @@ class _PlannedStep:
     any scheduled sequence samples (None = the pure-greedy program)."""
 
     __slots__ = ("sched", "tokens", "start", "ntok", "tables",
-                 "feed_mask", "feed_idx", "use_greedy", "sample")
+                 "feed_mask", "feed_idx", "use_greedy", "sample", "sslots")
 
     def __init__(self, sched, tokens, start, ntok, tables, feed_mask,
-                 feed_idx, use_greedy, sample=None):
+                 feed_idx, use_greedy, sample=None, sslots=None):
+        self.sslots = sslots                # [S] state-pool rows, or None
         self.sched = sched
         self.tokens = tokens
         self.start = start
@@ -239,8 +240,12 @@ class InferenceEngineV2:
                     f"multiple of 128 (int8 DMA tiling); round block_size "
                     f"up, or use attention_impl='dense' or the bf16 pool")
         self.kv_cache = BlockedKVCache(
-            self.config, self.runner.num_layers, self.runner.kv_heads,
-            self.runner.head_dim, dtype=resolve_dtype(self.config.dtype))
+            self.config, self.runner.kv_layers, self.runner.kv_heads,
+            self.runner.head_dim, dtype=resolve_dtype(self.config.dtype),
+            state_spec=self.runner.state_spec)
+        #: layer kind of the model's recurrent layers (None: it has none);
+        #: what needs a state snapshot refuses by this name
+        self._stateful = (self.runner.state_spec or {}).get("kind")
         if self.config.ep_size > 1:
             if self.runner.tp is not None:
                 # composed ep×tp: the pool head-shards over 'model' on
@@ -323,7 +328,17 @@ class InferenceEngineV2:
             "prefill_rows": 0, "decode_slots_live": 0,
             "decode_slots_planned": 0,
             "decode_kv_rows_live": 0, "decode_kv_rows_fetched": 0,
-            "moe_rows_routed": 0, "moe_rows_hottest": 0}
+            "moe_rows_routed": 0, "moe_rows_hottest": 0,
+            # routed rows whose expert another chip holds (a model told
+            # it holds a share of each layer's experts), from the fused
+            # loop's per-expert carry like the two above
+            "moe_rows_elsewhere": 0,
+            # recurrent models: state rows with a live tenant and their
+            # bytes, per decode step (sampled where decode_slots_live
+            # is, and per step of a fused loop), and the real positions
+            # that went through the chunked delta rule
+            "state_slots_live": 0, "state_bytes_live": 0,
+            "linear_attn_prefill_tokens": 0}
         from ...ops.kernels import decode_rows_fetched, decode_tile_rows
         self._kv_rows_fetched = functools.partial(
             decode_rows_fetched,
@@ -858,6 +873,7 @@ class InferenceEngineV2:
         the interrupted engine call returned; the pipeline itself unwinds
         on the drain flag. Returns the manifest dict (``pool`` carries
         the full-recovery verdict the drills assert on)."""
+        self._refuse_stateful("drain")
         if self._live_ring is not None:
             raise ServeDrainError(
                 "drain() called with steps in flight — request_drain() "
@@ -924,6 +940,7 @@ class InferenceEngineV2:
         next. The sequences stay live for continued decoding, with
         prompt/generated split restored so a LATER drain of this engine
         emits cumulative manifests."""
+        self._refuse_stateful("replay")
         if self._draining():
             raise EngineDrainingError(
                 "replay() on a draining engine — replay belongs on the "
@@ -1081,6 +1098,15 @@ class InferenceEngineV2:
     def flush(self, uid: int) -> None:
         self._flush_uid(uid)
 
+    def _refuse_stateful(self, feature: str) -> None:
+        """What would need a snapshot of the recurrent state refuses, by
+        the feature's name and the layer kind (config.stateful_refusal;
+        carrying state through these is later work)."""
+        if self._stateful:
+            from .config import stateful_refusal
+            raise NotImplementedError(
+                stateful_refusal(feature, self._stateful))
+
     def pause(self, uid: int) -> None:
         """Evict a sequence's KV blocks to host memory and free them — the
         pool can then be oversubscribed by other sequences. Reference:
@@ -1088,6 +1114,7 @@ class InferenceEngineV2:
         Queued (pending) tokens are allowed: KV is complete up to
         ``seen_tokens`` after every step, so the pending tokens simply wait
         in the queue until the sequence is resumed."""
+        self._refuse_stateful("pause")
         seq = self.state.get(uid)
         if seq is None:
             raise KeyError(f"unknown sequence {uid}")
@@ -1110,6 +1137,7 @@ class InferenceEngineV2:
         """Re-allocate blocks for a paused sequence and restore its KV from
         host memory, exactly as it was (reference ``restore``,
         kv_cache.py:176). Block ids may differ — tables are per-sequence."""
+        self._refuse_stateful("resume")
         seq = self.state.get(uid)
         if seq is None:
             raise KeyError(f"unknown sequence {uid}")
@@ -1153,6 +1181,7 @@ class InferenceEngineV2:
         device slices; the caller materializes them (one batched
         device_get) where the wait can hide under other replicas'
         compute. Registered DSL001 hot path — dispatch only."""
+        self._refuse_stateful("handoff_out")
         recs: List[Dict[str, Any]] = []
         blocks_moved = 0
         bytes_moved = 0
@@ -1229,6 +1258,7 @@ class InferenceEngineV2:
         measured non-overlapped transfer wall, observed into
         ``serve_handoff_exposed_s``. Registered DSL001 hot path —
         dispatch only."""
+        self._refuse_stateful("handoff_in")
         if self._draining():
             raise EngineDrainingError(
                 "handoff_in() on a draining engine — migrate to a "
@@ -1438,11 +1468,15 @@ class InferenceEngineV2:
             start = np.zeros((S,), np.int32)
             active = np.zeros((S,), np.int32)
             tables = np.zeros((S, MAXB), np.int32)
+            # idle rows point at the state pool's idle row
+            sslots = np.full((S,), S, np.int32) if self._stateful else None
             for i, (seq, t0) in enumerate(zip(seqs, first_tokens)):
                 tok0[i] = t0
                 start[i] = seq.seen_tokens
                 active[i] = 1
                 tables[i, :len(seq.kv_blocks)] = seq.kv_blocks
+                if sslots is not None:
+                    sslots[i] = seq.state_slot
             samp = self._stage_loop_sampling(seqs, S, sampling)
             obs = self._obs
             if obs is not None:
@@ -1458,7 +1492,9 @@ class InferenceEngineV2:
                         jax.numpy.asarray(start), jax.numpy.asarray(active),
                         jax.numpy.asarray(tables), n,
                         eos_id=-1 if eos_token_id is None
-                        else int(eos_token_id), **samp)
+                        else int(eos_token_id),
+                        state_slots=None if sslots is None
+                        else jax.numpy.asarray(sslots), **samp)
             with spans.span("serve/fused_readback", steps=n, seqs=live):
                 # one wait for all of it. lps is None for a greedy loop,
                 # consumed when EOS is disabled (every slot fed all n),
@@ -1473,14 +1509,27 @@ class InferenceEngineV2:
                 stats["decode_kv_rows_live"] += ran * seq.seen_tokens
                 stats["decode_kv_rows_fetched"] += \
                     ran * self._kv_rows_fetched(seq.seen_tokens)
+            if self._stateful:
+                ran = n * len(seqs) if consumed is None \
+                    else int(consumed[:len(seqs)].sum())
+                stats["state_slots_live"] += ran
+                stats["state_bytes_live"] += \
+                    ran * self.kv_cache.state_bytes_per_slot()
             if moe_rows is not None:
                 # per call: rows the experts took, and what they would
                 # have taken had every expert been as busy as the busiest
                 # (hottest / routed = the imbalance a straggling
-                # expert-parallel chip would feel; 1.0 = even)
-                stats["moe_rows_routed"] += int(moe_rows.sum())
-                stats["moe_rows_hottest"] += \
-                    int(moe_rows.max()) * len(moe_rows)
+                # expert-parallel chip would feel; 1.0 = even). A model
+                # that holds a share of the experts counts its own here
+                # and the rows it sent to the others apart
+                mc = self.runner.model_cfg
+                first = getattr(mc, "experts_first", 0)
+                mine = moe_rows[first:first + getattr(mc, "held",
+                                                      len(moe_rows))]
+                stats["moe_rows_routed"] += int(mine.sum())
+                stats["moe_rows_hottest"] += int(mine.max()) * len(mine)
+                stats["moe_rows_elsewhere"] += \
+                    int(moe_rows.sum()) - int(mine.sum())
             with spans.span("serve/fused_apply", steps=n, seqs=live):
                 out = self._apply_fused(batch_uids, seqs, first_tokens, n,
                                         toks, lps, consumed)
@@ -1623,8 +1672,12 @@ class InferenceEngineV2:
                              or item.seq.sampling.logprobs)
                         for item in sched)
             has_feed = False
+            sslots = np.full((S,), cfg.max_seqs, np.int32) \
+                if self._stateful else None
             for i, item in enumerate(sched):
                 seq = item.seq
+                if sslots is not None:
+                    sslots[i] = seq.state_slot
                 if seq.spec_pending and item.tokens == [_SPEC_TOKEN]:
                     # speculative placeholder: its value is the in-flight
                     # latest step's device-side output for this sequence —
@@ -1653,6 +1706,8 @@ class InferenceEngineV2:
                            prefill_tokens_planned=S * C, prefill_steps=1,
                            prefill_rows=sum(len(item.tokens) > 1
                                             for item in sched))
+                if sslots is not None:
+                    span.count(linear_attn_prefill_tokens=real)
                 # serve fault site: a replica dying with a freshly planned
                 # multi-token prefill chunk (tokens consumed host-side, step
                 # never dispatched)
@@ -1664,11 +1719,15 @@ class InferenceEngineV2:
                            decode_kv_rows_live=sum(lens),
                            decode_kv_rows_fetched=sum(
                                map(self._kv_rows_fetched, lens)))
+                if sslots is not None:
+                    span.count(state_slots_live=real,
+                               state_bytes_live=real
+                               * self.kv_cache.state_bytes_per_slot())
             return _PlannedStep(sched, tokens, start, ntok, tables,
                                 feed_mask if has_feed else None, feed_idx,
                                 use_greedy,
                                 sample=(seeds, spos, temps, topks, topps)
-                                if use_sample else None)
+                                if use_sample else None, sslots=sslots)
 
     def _dispatch_step(self, plan: _PlannedStep) -> _InFlightStep:
         """DISPATCH: enqueue the compiled step without blocking — the
@@ -1689,7 +1748,9 @@ class InferenceEngineV2:
                 tokens=jnp.asarray(plan.tokens),
                 start_pos=jnp.asarray(plan.start),
                 n_tokens=jnp.asarray(plan.ntok),
-                block_tables=jnp.asarray(plan.tables))
+                block_tables=jnp.asarray(plan.tables),
+                state_slots=None if plan.sslots is None
+                else jnp.asarray(plan.sslots))
             logprobs = None
             if plan.sample is not None:
                 # per-slot on-device sampler (greedy slots ride along at
@@ -1997,6 +2058,7 @@ class InferenceEngineV2:
         tokenizer). Its journal and telemetry are disabled — draft
         tokens are proposals, never served output. Returns the draft
         engine (callers may size ``draft_config`` themselves)."""
+        self._refuse_stateful("attach_draft")
         tv = getattr(self.runner.model_cfg, "vocab_size", None)
         dv = getattr(draft_model_cfg, "vocab_size", None)
         if tv != dv:
@@ -2064,6 +2126,7 @@ class InferenceEngineV2:
         sequences must have no pending tokens. Under KV pressure it
         evicts-then-retries and finally falls back to the incremental
         pipelined path, which can shed."""
+        self._refuse_stateful("decode_spec")
         from .speculative import accept_length
         cfg = self.config
         if len(batch_uids) != len(first_tokens):

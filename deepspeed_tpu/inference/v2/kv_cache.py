@@ -121,7 +121,12 @@ class _HostRef:
 
 class BlockedKVCache:
     def __init__(self, cfg: RaggedInferenceConfig, num_layers: int,
-                 kv_heads: int, head_dim: int, dtype: Any = None):
+                 kv_heads: int, head_dim: int, dtype: Any = None,
+                 state_spec: Optional[dict] = None):
+        """``num_layers`` counts the layers that keep K/V (the softmax
+        layers of a hybrid model, every layer otherwise). ``state_spec``
+        (``RaggedRunnerBase.state_spec``) asks for the per-sequence state
+        pool of a model with recurrent layers beside the paged planes."""
         self.cfg = cfg
         self.num_layers = num_layers
         self.kv_heads = kv_heads
@@ -167,6 +172,20 @@ class BlockedKVCache:
             self.data = jnp.zeros(
                 (num_layers, 2, slots, kv_heads * head_dim), self.dtype)
             self.scales = None
+        # per-sequence recurrent state: one row a sequence slot (the state
+        # manager hands the slots out) + the idle row padding points at
+        self.state = self.conv = None
+        if state_spec is not None:
+            rows = cfg.max_seqs + 1
+            hd = state_spec["head_dim"]
+            # one array a layer: the chip stalled on XLA's gather / scatter
+            # of 4 MB rows past 2^30 bytes of ONE array (PERF.md, PR 32)
+            self.state = tuple(
+                jnp.zeros((rows, state_spec["heads"], hd, hd), jnp.float32)
+                for _ in range(state_spec["layers"]))
+            self.conv = jnp.zeros((state_spec["layers"], rows,
+                                   state_spec["taps"] - 1,
+                                   state_spec["conv_width"]), self.dtype)
 
     def pin(self, device) -> None:
         """COMMIT the pool to ``device`` (a one-device engine pins itself
@@ -178,15 +197,20 @@ class BlockedKVCache:
         self.data = jax.device_put(self.data, device)
         if self.scales is not None:
             self.scales = jax.device_put(self.scales, device)
+        if self.state is not None:
+            self.state = jax.device_put(self.state, device)
+            self.conv = jax.device_put(self.conv, device)
 
     @property
     def pool(self):
         """The threadable pool pytree: a KVPool when quantized (data +
-        scales travel together through the jitted steps), else the raw
-        data array (byte-identical to the pre-int8 path)."""
-        if self.quantized:
+        scales travel together through the jitted steps) or when the
+        model has recurrent layers (the state pool travels with the
+        planes), else the raw data array (byte-identical to the pre-int8
+        path)."""
+        if self.quantized or self.state is not None:
             from .kv_quant import KVPool
-            return KVPool(self.data, self.scales)
+            return KVPool(self.data, self.scales, self.state, self.conv)
         return self.data
 
     def attach_prefix_cache(self, prefix: PrefixCache) -> None:
@@ -525,7 +549,16 @@ class BlockedKVCache:
         n = self.data.size * self.data.dtype.itemsize
         if self.scales is not None:
             n += self.scales.size * self.scales.dtype.itemsize
-        return n
+        return n + self.state_bytes_per_slot() * (self.cfg.max_seqs + 1)
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state and convolution inputs one sequence
+        slot holds over all recurrent layers (0 without any)."""
+        if self.state is None:
+            return 0
+        return sum(a.size * a.dtype.itemsize // a.shape[0]
+                   for a in self.state) \
+            + self.conv.size * self.conv.dtype.itemsize // self.conv.shape[1]
 
     def memory_bytes_per_chip(self) -> int:
         """Bytes one chip actually holds, read from the device sharding
@@ -542,7 +575,8 @@ class BlockedKVCache:
         n = per_chip(self.data)
         if self.scales is not None:
             n += per_chip(self.scales)
-        return n
+        # the state pool is never sharded (recurrent models refuse meshes)
+        return n + self.state_bytes_per_slot() * (self.cfg.max_seqs + 1)
 
     # ------------------- host offload / restore ----------------------- #
     # Reference parity: BlockedKVCache.offload/restore

@@ -34,10 +34,19 @@ import jax.numpy as jnp
 
 
 class KVPool(NamedTuple):
-    """KV pool pytree: ``data`` [L, 2, slots, KV*D]; ``scales`` is None for
-    an unquantized pool, else [L, 2, KV, slots] f32 per-row scales."""
+    """The cache value every program threads. ``data`` [L, 2, slots, KV*D]
+    holds the paged planes of the softmax-attention layers; ``scales`` is
+    None for an unquantized pool, else [L, 2, KV, slots] f32 per-row
+    scales. A model with recurrent layers (``layer_kinds`` with "kda")
+    also carries ``state``, a tuple of one [max_seqs + 1, H, dv, dk] f32
+    array a recurrent layer (a transposed delta-rule state a sequence
+    slot), and ``conv`` [Ls, max_seqs + 1, K - 1, width], the short
+    convolution's last inputs; the last row of both is the idle row that
+    padding rows of a batch point at."""
     data: Any
     scales: Optional[Any] = None
+    state: Optional[Any] = None
+    conv: Optional[Any] = None
 
 
 class RingKV(NamedTuple):
@@ -45,12 +54,16 @@ class RingKV(NamedTuple):
     READ-ONLY; this step's K/V goes into the [R, L, 2, S, KV*D] ring at
     index ``t`` (see RaggedRunnerBase._decode_loop). ``moe_rows`` [E]
     rides along for models with routed experts: the loop's running count
-    of real rows routed to each expert, which the sparse layers add to."""
+    of real rows routed to each expert, which the sparse layers add to.
+    ``lin`` is the ``(state, conv)`` pair of a model with recurrent
+    layers: unlike the pool it cannot stay read-only, so it is a carry of
+    the loop and the recurrent layers update it in place."""
     pool: Any           # KVPool or raw pool array
     ring: Any
     t: Any
     rcount: Any
     moe_rows: Any = None
+    lin: Any = None
 
 
 def pool_parts(kv) -> Tuple[Any, Optional[Any]]:
@@ -63,7 +76,7 @@ def pool_parts(kv) -> Tuple[Any, Optional[Any]]:
 def repack(kv, data, scales):
     """Rebuild the caller's pool type from updated parts."""
     if isinstance(kv, KVPool):
-        return KVPool(data, scales)
+        return kv._replace(data=data, scales=scales)
     return data
 
 
@@ -94,3 +107,20 @@ def dequantize_rows(q: jnp.ndarray, scales_t: jnp.ndarray,
     d = kvd // kv
     r = q.reshape(n, kv, d).astype(jnp.float32) * scales_t.T[:, :, None]
     return r.reshape(n, kvd).astype(dtype)
+
+
+def lin_parts(kv):
+    """(state, conv) of a cache value or of the fused loop's RingKV; a
+    pair of None for a model without recurrent layers."""
+    if isinstance(kv, RingKV):
+        return kv.lin if kv.lin is not None else (None, None)
+    if isinstance(kv, KVPool):
+        return kv.state, kv.conv
+    return None, None
+
+
+def with_lin(kv, state, conv):
+    """``kv`` with its recurrent state replaced."""
+    if isinstance(kv, RingKV):
+        return kv._replace(lin=(state, conv))
+    return kv._replace(state=state, conv=conv)

@@ -1,4 +1,5 @@
-"""Ragged paged-KV runner for the Llama family (and Mixtral MoE).
+"""Ragged paged-KV runner for the Llama family (Mixtral-style MoE, and the
+hybrid Solar-Open2 family whose layers follow a per-layer list of mixers).
 
 Analogue of the reference's llama_v2 / mistral / mixtral v2 containers
 (``inference/v2/model_implementations/{llama_v2,mistral,mixtral}/``): RoPE
@@ -17,6 +18,7 @@ import jax.numpy as jnp
 from ...models.llama import LlamaConfig, apply_rope
 from ...models.mixtral import MixtralConfig
 from .config import RaggedInferenceConfig
+from .kv_quant import lin_parts, with_lin
 from .model_runner import (RaggedBatch, RaggedRunnerBase, paged_attention,
                            tp_all_reduce, woq_mm)
 
@@ -60,7 +62,7 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
     [E] int32 counts the routed rows of VALID positions per expert
     (padding positions are computed like the others and left out of the
     count only); None without it and on the expert-parallel path."""
-    from ...moe.sharded_moe import grouped_moe_ffn
+    from ...moe.sharded_moe import grouped_moe_ffn, route_topk
     from ...ops.kernels.fp6_gemm import Fp6GemmWeight, fp6_gemm_unpack
     from .expert_parallel import EP_AXIS, ep_axis_active
     S, C, M = h.shape
@@ -75,6 +77,14 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
     else:
         weights = (p_moe["wi"], p_moe["wo"])
     norm = getattr(cfg, "norm_topk_prob", True)
+    # the router's form and the experts this chip holds, from the model
+    # config (softmax over all, no bias, every expert: the defaults)
+    router = dict(
+        score=getattr(cfg, "router_score", "softmax"),
+        select_bias=p_moe["sel_bias"]
+        if getattr(cfg, "router_bias", False) else None,
+        weight_scale=float(getattr(cfg, "routed_scaling", 1.0)))
+    held = (cfg.experts_first, cfg.held) if hasattr(cfg, "held") else None
     if ep_axis_active():
         from ...moe.sharded_moe import (ep_serve_capacity,
                                         grouped_moe_ffn_ep_serve)
@@ -91,58 +101,144 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
         return y.reshape(S, C, M), None
     y, _ = grouped_moe_ffn(
         h.reshape(S * C, M), logits, cfg.experts_top_k, weights,
-        jax.nn.silu, dtype, normalize_weights=norm)
+        jax.nn.silu, dtype, normalize_weights=norm, held=held, **router)
     rows = None
     if valid is not None:
-        # the same top-k the grouped path takes of the same logits
-        _, top_idx = jax.lax.top_k(logits, cfg.experts_top_k)
+        # the same choice the grouped path makes of the same logits
+        top_idx = route_topk(logits, cfg.experts_top_k, score=router["score"],
+                             bias=router["select_bias"])[0]
         rows = jnp.zeros((cfg.num_experts,), jnp.int32).at[top_idx].add(
             valid.reshape(S * C, 1).astype(jnp.int32))
     return y.reshape(S, C, M), rows
+
+
+@jax.jit
+def kda_chunked_prefill(q, k, v, g, beta, S0):
+    """The chunked delta rule (chunks of 64) as one named program of a
+    prefill step."""
+    from ...ops.kernels.delta_rule import kda_chunked
+    return kda_chunked(q, k, v, g, beta, S0)
+
+
+def _kda_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
+               dtype):
+    """One gated delta-rule layer over the state pool. ``si`` is the
+    layer's index among the recurrent layers (its plane of the pool);
+    every row reads and writes the pool row ``batch.state_slots`` names.
+    A row whose chunk starts at position 0 starts from zero state and
+    zero convolution inputs, whatever the slot's last tenant left there;
+    padded positions and idle rows (``n_tokens`` 0) leave their row as it
+    was. One token a row goes through the decode update (in place, one
+    read and one write of each state), more through the chunked form.
+    Returns (kv, y [S, C, M])."""
+    from ...models.solar_open2 import kda_inputs, kda_output
+    from ...ops.kernels.delta_rule import kda_decode_update
+    state, conv = lin_parts(kv)
+    st = state[si]              # this layer's states [rows, H, dv, dk]
+    S, C, _ = h.shape
+    slots = batch.state_slots
+    K = model_cfg.kda_conv
+    fresh = batch.start_pos == 0
+    live = batch.n_tokens > 0
+    prev0 = conv[si, slots]                               # [S, K-1, W]
+    prev = jnp.where(fresh[:, None, None], 0, prev0)
+    q, k, v, g, beta, padded = kda_inputs(p, h, model_cfg, prev, dtype)
+    g = jnp.where(valid_q[..., None, None], g, 0.0)
+    beta = jnp.where(valid_q[..., None], beta, 0.0)
+    # the next call's K-1 inputs end at the row's last real position
+    rows = batch.n_tokens[:, None] + jnp.arange(K - 1, dtype=jnp.int32)
+    nxt = jnp.take_along_axis(padded, rows[..., None], axis=1)
+    conv = conv.at[si, slots].set(
+        jnp.where(live[:, None, None], nxt.astype(conv.dtype), prev0))
+    if C == 1:
+        # exp(-inf) = 0 wipes what the slot held: a fresh row's zero state
+        g1 = jnp.where((fresh & live)[:, None, None], -jnp.inf, g[:, 0])
+        o, st = kda_decode_update(st, slots, q[:, 0], k[:, 0], v[:, 0], g1,
+                                  beta[:, 0])
+        o = o[:, None]
+    else:
+        St0 = st[slots]                                   # [S, H, dv, dk]
+        S0 = jnp.where(fresh[:, None, None, None], 0.0,
+                       jnp.swapaxes(St0, -1, -2))
+        o, Sn = kda_chunked_prefill(q, k, v, g, beta, S0)
+        st = st.at[slots].set(
+            jnp.where(live[:, None, None, None],
+                      jnp.swapaxes(Sn, -1, -2), St0))
+    state = state[:si] + (st,) + state[si + 1:]
+    return with_lin(kv, state, conv), kda_output(p, o, h, model_cfg, dtype)
+
+
+def _attn_mixer(pa, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
+                pos, valid_q, dtype):
+    """One softmax-attention layer over plane ``plane`` of the paged
+    cache: projections (qkv bias, whole-projection QK-norm), rotary
+    positions unless the family has none (``use_rope`` false: no position
+    code at all), paged attention, the family's elementwise sigmoid
+    output gate if it has one, the output projection. Returns (kv, y)."""
+    S, C, _ = h.shape
+    H, KV, D = model_cfg.num_heads, model_cfg.num_kv_heads, model_cfg.head_dim
+    q = woq_mm(h, pa["q_proj"]["kernel"], dtype)
+    k = woq_mm(h, pa["k_proj"]["kernel"], dtype)
+    v = woq_mm(h, pa["v_proj"]["kernel"], dtype)
+    if model_cfg.qkv_bias:
+        q = q + pa["q_proj"]["bias"].astype(dtype)
+        k = k + pa["k_proj"]["bias"].astype(dtype)
+        v = v + pa["v_proj"]["bias"].astype(dtype)
+    if model_cfg.qk_norm:
+        q = _rms(q, pa["q_norm"]["scale"], model_cfg.rms_eps).astype(dtype)
+        k = _rms(k, pa["k_norm"]["scale"], model_cfg.rms_eps).astype(dtype)
+    q = q.reshape(S, C, H, D)
+    k = k.reshape(S, C, KV, D)
+    v = v.reshape(S, C, KV, D)
+    if getattr(model_cfg, "use_rope", True):
+        q = apply_rope(q, pos, model_cfg.rope_theta)
+        k = apply_rope(k, pos, model_cfg.rope_theta)
+
+    kv, y = paged_attention(kv, plane, q, k, v, batch, cfg, pos, valid_q,
+                            1.0 / (D ** 0.5), dtype,
+                            sliding_window=model_cfg.sliding_window)
+    if getattr(model_cfg, "attn_gate", False):
+        gate = woq_mm(h, pa["g_proj"]["kernel"], dtype)
+        y = (y.astype(jnp.float32)
+             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype)
+    y = woq_mm(y, pa["o_proj"]["kernel"], dtype)
+    return kv, tp_all_reduce(y, cfg)        # TP collective 1 (row-parallel)
 
 
 def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
                        model_cfg: LlamaConfig, cfg: RaggedInferenceConfig,
                        dtype):
     S, C = batch.tokens.shape
-    H = model_cfg.num_heads
-    KV = model_cfg.num_kv_heads
-    D = model_cfg.head_dim
-    scale = 1.0 / (D ** 0.5)
     is_moe = isinstance(model_cfg, MixtralConfig)
 
     pos = batch.start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     valid_q = jnp.arange(C, dtype=jnp.int32)[None, :] < batch.n_tokens[:, None]
 
-    x = params["embed"]["embedding"][batch.tokens].astype(dtype)
+    # the residual stream's dtype: the compute dtype, unless the family
+    # asks for more (solar_open2: float32; its norms then read an
+    # unrounded stream, and only matmul operands are rounded to ``dtype``)
+    rdtype = getattr(model_cfg, "residual_dtype", None) or dtype
+    x = params["embed"]["embedding"][batch.tokens].astype(rdtype)
 
-    for li in range(model_cfg.num_layers):
+    # one step function for every family: the layer list says which mixer
+    # a layer runs; softmax layers take the cache's planes in order and
+    # recurrent layers the state pool's
+    kinds = getattr(model_cfg, "layer_kinds", None) \
+        or ("attn",) * model_cfg.num_layers
+    plane = si = 0
+    for li, kind in enumerate(kinds):
         p = params[f"layer_{li}"]
         h = _rms(x, p["input_norm"]["scale"],
                  model_cfg.rms_eps).astype(dtype)
-        pa = p["attn"]
-        q = woq_mm(h, pa["q_proj"]["kernel"], dtype)
-        k = woq_mm(h, pa["k_proj"]["kernel"], dtype)
-        v = woq_mm(h, pa["v_proj"]["kernel"], dtype)
-        if model_cfg.qkv_bias:
-            q = q + pa["q_proj"]["bias"].astype(dtype)
-            k = k + pa["k_proj"]["bias"].astype(dtype)
-            v = v + pa["v_proj"]["bias"].astype(dtype)
-        if model_cfg.qk_norm:
-            q = _rms(q, pa["q_norm"]["scale"], model_cfg.rms_eps).astype(dtype)
-            k = _rms(k, pa["k_norm"]["scale"], model_cfg.rms_eps).astype(dtype)
-        q = q.reshape(S, C, H, D)
-        k = k.reshape(S, C, KV, D)
-        v = v.reshape(S, C, KV, D)
-        q = apply_rope(q, pos, model_cfg.rope_theta)
-        k = apply_rope(k, pos, model_cfg.rope_theta)
-
-        kv, y = paged_attention(kv, li, q, k, v, batch, cfg, pos, valid_q,
-                                scale, dtype,
-                                sliding_window=model_cfg.sliding_window)
-        y = woq_mm(y, pa["o_proj"]["kernel"], dtype)
-        y = tp_all_reduce(y, cfg)           # TP collective 1 (row-parallel)
-        x = x + y
+        if kind == "kda":
+            kv, y = _kda_mixer(p["kda"], h, kv, si, batch, model_cfg,
+                               valid_q, dtype)
+            si += 1
+        else:
+            kv, y = _attn_mixer(p["attn"], h, kv, plane, batch, model_cfg,
+                                cfg, pos, valid_q, dtype)
+            plane += 1
+        x = x + y.astype(rdtype)
 
         h = _rms(x, p["post_attn_norm"]["scale"],
                  model_cfg.rms_eps).astype(dtype)
@@ -154,23 +250,26 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
             if rows is not None:
                 kv = kv._replace(moe_rows=kv.moe_rows + rows)
             if getattr(model_cfg, "shared_expert_size", 0):
-                # qwen2-moe always-on shared expert (sigmoid scalar gate)
+                # always-on shared expert: behind a sigmoid scalar gate
+                # (qwen2-moe) or ungated (solar_open2)
                 gate = woq_mm(h, p["shared_gate_proj"]["kernel"], dtype)
                 up = woq_mm(h, p["shared_up_proj"]["kernel"], dtype)
                 shared = woq_mm(jax.nn.silu(gate) * up,
                                 p["shared_down_proj"]["kernel"], dtype)
-                sg = jax.nn.sigmoid(
-                    (h @ p["shared_expert_gate"]["kernel"].astype(dtype)
-                     ).astype(jnp.float32))
-                y = y + shared * sg.astype(dtype)
-            x = x + y
+                if getattr(model_cfg, "shared_expert_gated", True):
+                    sg = jax.nn.sigmoid(
+                        (h @ p["shared_expert_gate"]["kernel"].astype(dtype)
+                         ).astype(jnp.float32))
+                    shared = shared * sg.astype(dtype)
+                y = y + shared
+            x = x + y.astype(rdtype)
         else:
             pm = p["mlp"]
             gate = woq_mm(h, pm["gate_proj"]["kernel"], dtype)
             up = woq_mm(h, pm["up_proj"]["kernel"], dtype)
             m = jax.nn.silu(gate) * up
             m = woq_mm(m, pm["down_proj"]["kernel"], dtype)
-            x = x + tp_all_reduce(m, cfg)   # TP collective 2 (row-parallel)
+            x = x + tp_all_reduce(m, cfg).astype(rdtype)   # TP collective 2
 
     x = _rms(x, params["final_norm"]["scale"], model_cfg.rms_eps)
     last = jnp.maximum(batch.n_tokens - 1, 0)

@@ -91,6 +91,9 @@ class RaggedBatch(NamedTuple):
     start_pos: jnp.ndarray     # [S] int32 — absolute pos of tokens[s, 0]
     n_tokens: jnp.ndarray      # [S] int32 — valid tokens this step (0 = idle)
     block_tables: jnp.ndarray  # [S, MAXB] int32 (padded with 0)
+    # [S] int32 — each row's row of the recurrent state pool (idle rows:
+    # the pool's last, idle row); None for a model without recurrent layers
+    state_slots: Any = None
 
 
 # --------------------------------------------------------------------- #
@@ -674,6 +677,17 @@ class RaggedRunnerBase:
         self.head_dim = getattr(
             model_cfg, "head_dim",
             model_cfg.hidden_size // model_cfg.num_heads)
+        # the cache follows the layer list: a paged K/V plane for each
+        # softmax layer, a state row a sequence for each recurrent one
+        kinds = getattr(model_cfg, "layer_kinds", None) \
+            or ("attn",) * self.num_layers
+        self.kv_layers = sum(k == "attn" for k in kinds)
+        #: what the state pool must hold (None: no recurrent layer)
+        self.state_spec = None if self.kv_layers == len(kinds) else {
+            "kind": "kda", "layers": len(kinds) - self.kv_layers,
+            "heads": model_cfg.kda_heads, "head_dim": model_cfg.kda_head_dim,
+            "taps": model_cfg.kda_conv,
+            "conv_width": 3 * model_cfg.kda_heads * model_cfg.kda_head_dim}
         self.tp = None            # TPContext once init_tp runs
         self.seqctx = None        # SeqContext once init_seq runs
         self.epctx = None         # EPContext once init_ep runs
@@ -896,9 +910,9 @@ class RaggedRunnerBase:
         # per-step pool scatter (TPU scatter slow path) AND the 1-GB pool
         # carry out of the scan entirely — the ring is flushed once per
         # loop (_flush_ring).
-        def _decode_loop_impl(params, kv_data, tok0, start, active, tables,
-                              seeds, temps, top_ks, top_ps, drafts,
-                              *, n, mode, cand, eos_id, feed):
+        def _decode_loop_impl(params, kv_data, lin, sslots, tok0, start,
+                              active, tables, seeds, temps, top_ks, top_ps,
+                              drafts, *, n, mode, cand, eos_id, feed):
             params = self._local_params(params)
             S = cfg.max_seqs
             pool_arr, pool_scales = pool_parts(kv_data)
@@ -906,7 +920,7 @@ class RaggedRunnerBase:
             # rows are the loop's freshest tokens, rewritten every step,
             # and are quantized once at flush time. Under TP the ring —
             # like the pool — is head-sharded: local_kv_heads rows.
-            ring = jnp.zeros((n, self.num_layers, 2, S,
+            ring = jnp.zeros((n, self.kv_layers, 2, S,
                               self.local_kv_heads * self.head_dim),
                              pool_arr.dtype if pool_scales is None
                              else dtype)
@@ -918,7 +932,7 @@ class RaggedRunnerBase:
             moe0 = jnp.zeros((moe_experts,), jnp.int32)
 
             def body(carry, t):
-                ring, tok, pos, done, moe = carry
+                ring, tok, pos, done, moe, lin = carry
                 if use_eos:
                     # per-slot EOS freeze: finished slots stop appending KV
                     # (n_tokens 0 -> trash writes) and keep emitting eos_id
@@ -938,11 +952,12 @@ class RaggedRunnerBase:
                     # prefix and rolls the rest back
                     tok = drafts[:, t]
                 batch = RaggedBatch(tokens=tok[:, None], start_pos=pos,
-                                    n_tokens=alive, block_tables=tables)
+                                    n_tokens=alive, block_tables=tables,
+                                    state_slots=sslots)
                 logits, kv_out = type(self).step_fn(
-                    params, RingKV(kv_data, ring, t, t + 1, moe), batch,
+                    params, RingKV(kv_data, ring, t, t + 1, moe, lin), batch,
                     model_cfg=mcfg_l, cfg=cfg, dtype=dtype)
-                ring, moe = kv_out.ring, kv_out.moe_rows
+                ring, moe, lin = kv_out.ring, kv_out.moe_rows, kv_out.lin
                 # the one pre-sampling collective: every chip then selects
                 # the SAME next token from identical full-width logits
                 logits = tp_gather_logits(logits, vocab)
@@ -963,19 +978,19 @@ class RaggedRunnerBase:
                     done = jnp.logical_or(done, nxt == eos_id)
                 else:
                     new_pos = pos + 1
-                return (ring, nxt, new_pos, done, moe), (nxt, lp)
+                return (ring, nxt, new_pos, done, moe, lin), (nxt, lp)
 
-            (ring, _, pos_f, _, moe), (toks, lps) = jax.lax.scan(
-                body, (ring, tok0, start, done0, moe0),
+            (ring, _, pos_f, _, moe, lin), (toks, lps) = jax.lax.scan(
+                body, (ring, tok0, start, done0, moe0, lin),
                 jnp.arange(n, dtype=jnp.int32))
             # consumed is shard_map-shape-stable: always an array; the
             # decode_loop wrapper drops it when EOS is disabled
             return jnp.transpose(toks), jnp.transpose(lps), ring, \
-                pos_f - start, moe
+                pos_f - start, moe, lin
 
-        def _decode_loop_ring(params, kv_data, tok0, start, active, tables,
-                              seeds, temps, top_ks, top_ps, drafts,
-                              *, n, mode, cand, eos_id, feed):
+        def _decode_loop_ring(params, kv_data, lin, sslots, tok0, start,
+                              active, tables, seeds, temps, top_ks, top_ps,
+                              drafts, *, n, mode, cand, eos_id, feed):
             # n/mode/cand/eos_id/feed are STATIC: they change rarely (per
             # tokenizer / per sampling profile) and shape the program;
             # per-slot sampling params ride as [S] device arrays so one
@@ -984,19 +999,24 @@ class RaggedRunnerBase:
                 _decode_loop_impl, n=n, mode=mode, cand=cand,
                 eos_id=eos_id, feed=feed)
             if mapped:
+                # lin / sslots are None on every mesh (a model with
+                # recurrent layers refuses tp, seq and ep)
                 impl = self._wrap(
                     impl,
-                    (pspecs, pool_spec, P(), P(), P(), P(), P(), P(),
-                     P(), P(), P()),
-                    (P(), P(), ring_spec, P(), P()))
-            return impl(params, kv_data, tok0, start, active, tables,
-                        seeds, temps, top_ks, top_ps, drafts)
+                    (pspecs, pool_spec, None, None, P(), P(), P(), P(),
+                     P(), P(), P(), P(), P()),
+                    (P(), P(), ring_spec, P(), P(), None))
+            return impl(params, kv_data, lin, sslots, tok0, start, active,
+                        tables, seeds, temps, top_ks, top_ps, drafts)
 
         # dslint: allow(DSL002): the pool is strictly READ-ONLY inside
         # the fused loop (fresh K/V rides the small ring carry);
-        # _flush_ring consumes — and donates — the pool right after
+        # _flush_ring consumes — and donates — the pool right after.
+        # The recurrent state cannot stay read-only: it enters as its own
+        # argument, donated, is the scan's carry and comes back updated
+        # in place (None, and no operand at all, without recurrent layers)
         self._decode_loop_ring = jax.jit(
-            _decode_loop_ring,
+            _decode_loop_ring, donate_argnames=("lin",),
             static_argnames=("n", "mode", "cand", "eos_id", "feed"))
 
         # flush: write the loop's ring rows into the pool. Linear layout
@@ -1111,7 +1131,8 @@ class RaggedRunnerBase:
     def decode_loop(self, params, kv_data, tok0, start_pos, active,
                     block_tables, n: int, *, seeds=None, temps=None,
                     top_ks=None, top_ps=None, eos_id: int = -1,
-                    draft_toks=None, candidates: int = SAMPLE_CANDIDATES):
+                    draft_toks=None, candidates: int = SAMPLE_CANDIDATES,
+                    state_slots=None):
         """Decode ``n`` tokens per active slot on-device (greedy when
         ``temps`` is None, else per-slot temperature/top-k/top-p
         categorical — the whole sampler lives inside the scan, keys
@@ -1150,12 +1171,21 @@ class RaggedRunnerBase:
             draft_toks = self._dummy_draft
         cand = min(candidates, getattr(self.model_cfg, "vocab_size",
                                        1 << 30))
-        toks, lps, ring, consumed, moe_rows = self._decode_loop_ring(
-            params, kv_data, tok0, start_pos, active, block_tables,
-            seeds, temps, top_ks, top_ps, draft_toks,
+        # the recurrent state leaves the cache value for the loop (its
+        # carry, donated) and rejoins it after the flush, which takes the
+        # paged planes alone
+        lin = None
+        if self.state_spec is not None:
+            lin = (kv_data.state, kv_data.conv)
+            kv_data = kv_data._replace(state=None, conv=None)
+        toks, lps, ring, consumed, moe_rows, lin = self._decode_loop_ring(
+            params, kv_data, lin, state_slots, tok0, start_pos, active,
+            block_tables, seeds, temps, top_ks, top_ps, draft_toks,
             n=n, mode=mode, cand=int(cand), eos_id=int(eos_id), feed=feed)
         kv_data = self._flush_ring(kv_data, ring, block_tables, start_pos,
                                    active)
+        if lin is not None:
+            kv_data = kv_data._replace(state=lin[0], conv=lin[1])
         return toks, (lps if mode == "sample" else None), kv_data, \
             (consumed if int(eos_id) >= 0 else None), \
             (moe_rows if moe_rows.shape[0] else None)

@@ -28,6 +28,9 @@ class SequenceDescriptor:
     kv_blocks: List[int] = field(default_factory=list)
     status: SequenceStatus = SequenceStatus.WAITING
     generated: List[int] = field(default_factory=list)
+    # row of the recurrent state pool this sequence owns (models with
+    # recurrent layers; taken with its first blocks, given back at flush)
+    state_slot: Optional[int] = None
     host_kv: object = None                # offloaded KV (engine.pause)
     paused_blocks: int = 0                # block count captured at pause()
     last_step: int = 0                    # engine step last scheduled (LRU)

@@ -54,6 +54,13 @@ class StateManager:
         self.cfg = cfg
         self.kv_cache = kv_cache
         self._seqs: Dict[int, SequenceDescriptor] = {}
+        #: free rows of the recurrent state pool (None: the model has no
+        #: recurrent layer). A sequence takes one with its first blocks
+        #: and returns it at flush; the row's last tenant's state is
+        #: wiped by the program that runs the new tenant's position 0
+        self.state_slots_free: Optional[List[int]] = \
+            list(range(cfg.max_seqs - 1, -1, -1)) \
+            if kv_cache.state is not None else None
         # scheduler clock: ONE tick per scheduler invocation (bumped by
         # the engine's plan phase — deliberately NOT the engine step
         # counter, which decode_batch advances by n per fused call and
@@ -353,6 +360,9 @@ class StateManager:
         seq = self.get_or_create(uid)
         if seq.status is SequenceStatus.PAUSED:
             return False
+        if self.state_slots_free is not None and seq.state_slot is None \
+                and not self.state_slots_free:
+            return False                   # every state row has a tenant
         need = seq.blocks_needed(n_tokens, self.cfg.block_size)
         if not (need <= self.kv_cache.free_blocks
                 and len(seq.kv_blocks) + need
@@ -374,6 +384,12 @@ class StateManager:
         return True
 
     def ensure_blocks(self, seq: SequenceDescriptor, n_tokens: int) -> None:
+        if self.state_slots_free is not None and seq.state_slot is None:
+            if not self.state_slots_free:
+                raise OutOfBlocksError(
+                    f"sequence {seq.uid}: all {self.cfg.max_seqs} recurrent "
+                    f"state rows are taken")
+            seq.state_slot = self.state_slots_free.pop()
         need = seq.blocks_needed(n_tokens, self.cfg.block_size)
         if need:
             if len(seq.kv_blocks) + need > self.cfg.max_blocks_per_seq:
@@ -426,6 +442,9 @@ class StateManager:
         seq = self._seqs.pop(uid, None)
         if seq is not None and seq.kv_blocks:
             self.release_blocks(seq, seq.kv_blocks)
+        if seq is not None and seq.state_slot is not None:
+            self.state_slots_free.append(seq.state_slot)
+            seq.state_slot = None
 
     def flush_all(self) -> None:
         for uid in list(self._seqs):

@@ -32,6 +32,8 @@ from .opt import OPT, OPTConfig
 from .opt import make_model as make_opt
 from .phi import Phi, PhiConfig
 from .phi import make_model as make_phi
+from .solar_open2 import SolarOpen2, SolarOpen2Config
+from .solar_open2 import make_model as make_solar_open2
 
 
 class ArchEntry(NamedTuple):
@@ -340,6 +342,52 @@ def _entry_olmoe(d):
         router_aux_loss_coef=d.get("router_aux_loss_coef", 0.01)))
 
 
+def _entry_solar_open2(d):
+    """Solar-Open2 (upstage/Solar-Open2-250B): ``gqa_layers`` are softmax
+    GQA layers without rotary positions and with an output gate, the
+    others gated delta-rule (KDA) layers; every layer sparse, sigmoid
+    router, one ungated shared expert. What the published config leaves
+    open is refused rather than guessed at: leading dense layers
+    (``first_k_dense_replace``), the full-rank decay projection
+    (``kda_use_full_proj``) and grouped key/value heads in the linear
+    layers (``linear_attn_config.num_kv_heads``)."""
+    la = d.get("linear_attn_config") or {}
+    if int(d.get("first_k_dense_replace", 0)) != 0:
+        raise ValueError("solar_open2 configs with leading dense layers "
+                         "(first_k_dense_replace != 0) are not supported")
+    if d.get("kda_use_full_proj", False):
+        raise ValueError("solar_open2 configs with kda_use_full_proj set "
+                         "are not supported (the published one is low-rank)")
+    if la.get("num_kv_heads") not in (None, la.get("num_heads")):
+        raise ValueError("solar_open2 linear-attention layers with grouped "
+                         "key/value heads are not supported")
+    n = d.get("num_hidden_layers", 48)
+    gqa = d.get("gqa_layers")
+    if gqa is None:
+        gqa = range(0, n, int(d.get("gqa_interval", 3)) + 1)
+    gqa = set(gqa)
+    width = d.get("moe_intermediate_size", 1280)
+    base = _hf_llama(d, intermediate_size=width)
+    return SolarOpen2Config(
+        **base,
+        attn_head_dim=d.get("head_dim",
+                            base["hidden_size"] // base["num_heads"]),
+        layer_kinds=tuple("attn" if i in gqa else "kda" for i in range(n)),
+        use_rope=bool(d.get("use_rope", False)),
+        attn_gate=bool(d.get("use_gqa_gate", True)),
+        kda_heads=la.get("num_heads", 64),
+        kda_head_dim=la.get("head_dim", 128),
+        kda_conv=la.get("short_conv_kernel_size", 4),
+        kda_rank=la.get("head_dim", 128),
+        kda_neg_eigval=bool(d.get("kda_allow_neg_eigval", True)),
+        num_experts=d.get("n_routed_experts", 320),
+        experts_top_k=d.get("num_experts_per_tok", 8),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        routed_scaling=float(d.get("routed_scaling_factor", 1.0)),
+        shared_expert_size=int(d.get("n_shared_experts", 1)) * width,
+        router_aux_loss_coef=0.0)
+
+
 ARCHITECTURES: Dict[str, ArchEntry] = {
     "gpt2": ArchEntry(GPT2Config, GPT2, make_gpt2, _entry_gpt2),
     "llama": ArchEntry(LlamaConfig, Llama, make_llama, _entry_llama),
@@ -361,6 +409,8 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
     "qwen2_moe": ArchEntry(MixtralConfig, Mixtral, make_mixtral,
                            _entry_qwen2_moe),
     "olmoe": ArchEntry(MixtralConfig, Mixtral, make_mixtral, _entry_olmoe),
+    "solar_open2": ArchEntry(SolarOpen2Config, SolarOpen2,
+                             make_solar_open2, _entry_solar_open2),
     "gpt_neo": ArchEntry(GPTNeoConfig, GPTNeo, make_gpt_neo,
                          _entry_gpt_neo),
     "internlm": ArchEntry(LlamaConfig, Llama, make_llama, _entry_internlm),

@@ -186,9 +186,53 @@ def gate(logits: jnp.ndarray, k: int = 1, **kwargs):
     return topkgating(logits, k, **kwargs)
 
 
+def route_topk(logits: jnp.ndarray, k: int, *, score: str = "softmax",
+               bias=None, normalize: bool = True, scale: float = 1.0):
+    """The router's choice for every row: (top_idx [S, k] int32,
+    weights [S, k] float32, scores [S, E] float32).
+
+    ``score="softmax"`` (mixtral, qwen2-moe, olmoe): the k largest
+    logits; their weights are the softmax over the chosen logits
+    (``normalize``) or their share of the softmax over all experts.
+    ``score="sigmoid"`` (the DeepSeek-V3 convention solar_open2's keys
+    follow): scores ``sigmoid(logits)``; the k largest of ``score +
+    bias`` are chosen (the bias takes part in the SELECTION only), and
+    their weights are the scores themselves, renormalised over the chosen
+    (``normalize``) and multiplied by ``scale``."""
+    lf = logits.astype(jnp.float32)
+    if score == "softmax":
+        gates = jax.nn.softmax(lf, axis=-1)
+        top_vals, top_idx = jax.lax.top_k(lf, k)
+        if normalize:
+            # renormalize over the selected experts (HF norm_topk_prob /
+            # the top2gating g/(g1+g2)); at k == 1 this is a constant 1.0
+            # — exactly HF's renormalized top-1. Training top-1 wants the
+            # raw softmax prob instead (top1gating semantics, and the
+            # router's gradient path): the MoE layer passes
+            # normalize_weights=False for k == 1.
+            w_sel = jax.nn.softmax(top_vals, axis=-1)      # [S, k]
+        else:
+            w_sel = jnp.take_along_axis(gates, top_idx, axis=-1)
+        return top_idx, w_sel, gates
+    if score != "sigmoid":
+        raise ValueError(f"router score must be 'softmax' or 'sigmoid', "
+                         f"got {score!r}")
+    gates = jax.nn.sigmoid(lf)
+    _, top_idx = jax.lax.top_k(
+        gates if bias is None else gates + bias.astype(jnp.float32), k)
+    w_sel = jnp.take_along_axis(gates, top_idx, axis=-1)
+    if normalize:
+        w_sel = w_sel / (jnp.sum(w_sel, axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        w_sel = w_sel * scale
+    return top_idx, w_sel, gates
+
+
 def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
                     weights, activation, dtype,
-                    normalize_weights: bool = True,
+                    normalize_weights: bool = True, *,
+                    score: str = "softmax", select_bias=None,
+                    weight_scale: float = 1.0, held=None,
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dropless top-k MoE via grouped expert matmuls (``jax.lax.ragged_dot``).
 
@@ -205,21 +249,27 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     (wi_gate, wi_up, wo) stacked [E, ...]. normalize_weights=True
     renormalizes over the selected experts (mixtral); False keeps
     full-softmax weights (qwen2-moe). Returns (out [S, M], l_aux).
+
+    ``score`` / ``select_bias`` / ``weight_scale``: the router's form
+    (:func:`route_topk`). ``held = (first, count)``: the stacked weights
+    hold experts ``first .. first + count`` of the ``E`` the router scores
+    (one chip's share of a layer divided over chips). Routing runs over
+    all ``E``; rows whose expert is elsewhere sort behind the last held
+    group, lie in no group of the grouped matmuls (which leave rows past
+    their groups alone) and add nothing to the output.
     """
     S, E = logits.shape
-    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_vals, top_idx = jax.lax.top_k(logits.astype(jnp.float32), k)
-    if normalize_weights:
-        # renormalize over the selected experts (HF norm_topk_prob / the
-        # top2gating g/(g1+g2)); at k == 1 this is a constant 1.0 — exactly
-        # HF's renormalized top-1. Training top-1 wants the raw softmax
-        # prob instead (top1gating semantics, and the router's gradient
-        # path): the MoE layer passes normalize_weights=False for k == 1.
-        w_sel = jax.nn.softmax(top_vals, axis=-1)          # [S, k]
-    else:
-        w_sel = jnp.take_along_axis(gates, top_idx, axis=-1)
+    top_idx, w_sel, gates = route_topk(
+        logits, k, score=score, bias=select_bias,
+        normalize=normalize_weights, scale=weight_scale)
 
     eid = top_idx.reshape(-1)                              # [S*k]
+    here = None
+    if held is not None and tuple(held) != (0, E):
+        first, count = held
+        here = (eid >= first) & (eid < first + count)
+        eid = jnp.where(here, eid - first, count)          # elsewhere: last
+        E = count
     order = jnp.argsort(eid, stable=True)
     tok_of = order // k                                    # source token
     xs = jnp.take(tokens, tok_of, axis=0).astype(dtype)    # sorted by expert
@@ -236,6 +286,9 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     ys = jax.lax.ragged_dot(h, wo.astype(dtype), group_sizes)  # [S*k, M]
 
     ws = jnp.take(w_sel.reshape(-1), order).astype(dtype)
+    if here is not None:
+        # a row past the groups carries whatever the matmul left there
+        ys = jnp.where(jnp.take(here, order)[:, None], ys, 0)
     out = jnp.zeros_like(tokens, dtype).at[tok_of].add(ys * ws[:, None])
 
     # load-balance loss — same statistic the capacity path this call
@@ -243,6 +296,8 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     # only (mask1.mean), topkgating averages all k choices. Matching per-k
     # keeps the router regularizer identical when the dropless path
     # auto-replaces the capacity path in MoE.__call__.
+    if here is not None:
+        return out, jnp.float32(0.0)       # serving share: no aux loss
     me = gates.mean(axis=0)
     if k <= 2:
         first = jnp.bincount(top_idx[:, 0], length=E).astype(jnp.float32)

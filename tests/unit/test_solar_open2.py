@@ -1,0 +1,380 @@
+"""Solar-Open2 (three gated delta-rule layers to one NoPE GQA layer, every
+layer sparse) through the normal engine, at a small size on the CPU:
+hidden 64, 4 heads of 16, 8 experts of which each of 2 shares holds 4,
+top-2, 4 layers in the 1 : 3 pattern. Logits against the plain reference
+(``benchmark/reference/solar_open2.py``), the chunked delta rule against
+the token-by-token recurrence, the share rule of the model-configs guide,
+the state pool's hygiene, and every refusal a stateful model makes."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.model_types import solar_open2 as mt
+from benchmark.reference import solar_open2 as reference
+from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                        RaggedInferenceConfig)
+from deepspeed_tpu.models.registry import config_from_hf
+from deepspeed_tpu.models.solar_open2 import SolarOpen2Config, param_counts
+from deepspeed_tpu.ops.kernels import delta_rule as dr
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+#: float32 engine against a float32 reference at highest precision: what
+#: is left is the order of the sums (chunked against token by token, the
+#: grouped matmul against the dense mask), a few 1e-6 on logits of size 3
+TOL = 2e-4
+
+
+def tiny(**kw):
+    return SolarOpen2Config.tiny(experts_held=4, dtype=jnp.float32,
+                                 param_dtype=jnp.float32, **kw)
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = tiny()
+    return cfg, mt.init_params(cfg, 3)
+
+
+def engine(cfg, params, chunk=64, **kw):
+    kw.setdefault("max_seqs", 8)
+    return InferenceEngineV2(cfg, params, RaggedInferenceConfig(
+        chunk_size=chunk, block_size=16, num_blocks=40,
+        max_blocks_per_seq=8, decode_loop_steps=4, dtype="float32", **kw))
+
+
+def ref_logits(cfg, params, tokens, at):
+    out = mt.reference_logits(cfg)(params, jnp.asarray([tokens]),
+                                   jnp.asarray([at]))
+    return np.asarray(out)[0]
+
+
+def prompt_of(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 512, n).tolist()
+
+
+# ------------------------- (a) engine vs reference ------------------------ #
+
+
+@pytest.mark.parametrize("chunk", [64, 16], ids=["one-chunk", "three-chunks"])
+@pytest.mark.parametrize("decode", ["fused", "pipelined"])
+def test_engine_logits_match_the_reference(model, chunk, decode):
+    """A 37-token prompt prefilled in one chunk or in three (the state
+    rides from chunk to chunk), 8 tokens decoded through the fused loop
+    or step by step, then one more position's logits: each against the
+    reference's forward pass over the whole sequence."""
+    cfg, params = model
+    prompt = prompt_of(37)
+    eng = engine(cfg, params, chunk)
+    lg = np.asarray(eng.put([7], [prompt])[7])
+    want = ref_logits(cfg, params, prompt, [len(prompt) - 1])[0]
+    assert np.abs(lg - want).max() < TOL
+    tok = int(np.argmax(lg))
+    if decode == "fused":
+        toks = eng.decode_batch([7], [tok], 8)[7]
+    else:
+        toks = eng.decode_pipelined([7], [tok], 8)[7]
+    seq = prompt + [tok] + list(toks)
+    at = list(range(len(prompt), len(seq)))
+    want = ref_logits(cfg, params, seq, at)
+    assert [int(t) for t in toks] == np.argmax(want[:-1], -1).tolist()
+    # the state the decode left behind: the next position's logits
+    lg = np.asarray(eng.put([7], [[int(toks[-1])]])[7])
+    assert np.abs(lg - want[-1]).max() < TOL
+    stats = eng.pipeline_stats
+    assert stats["linear_attn_prefill_tokens"] == len(prompt)
+    assert stats["state_slots_live"] >= 8 and stats["state_bytes_live"] == \
+        stats["state_slots_live"] * eng.kv_cache.state_bytes_per_slot()
+    if decode == "fused":
+        # 8 steps x 4 layers x top-2, split between this share and the other
+        assert stats["moe_rows_routed"] + stats["moe_rows_elsewhere"] == 64
+        assert stats["moe_rows_elsewhere"] > 0
+
+
+def test_generate_serves_the_model(model):
+    cfg, params = model
+    eng = engine(cfg, params)
+    prompt = prompt_of(20, seed=5)
+    out = eng.generate([prompt], max_new_tokens=6)[0]
+    seq = prompt + [int(t) for t in out]
+    want = ref_logits(cfg, params, seq,
+                      list(range(len(prompt) - 1, len(seq) - 1)))
+    assert [int(t) for t in out] == np.argmax(want, -1).tolist()
+
+
+# ------------------- (b) chunked against token by token ------------------- #
+
+
+def _kda_case(key, T, gscale, beta_shift, B=2, H=3, dk=16, dv=8):
+    ks = jax.random.split(key, 6)
+    q = jax.random.normal(ks[0], (B, T, H, dk))
+    k = jax.random.normal(ks[1], (B, T, H, dk))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (B, T, H, dv))
+    g = -jnp.exp(2.0 * jax.random.normal(ks[3], (B, T, H, dk))) * gscale
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H))
+                              + beta_shift)
+    return q, k, v, g, beta, jax.random.normal(ks[5], (B, H, dk, dv))
+
+
+@pytest.mark.parametrize("gscale, beta_shift", [
+    (1.0, 0.0),        # decays spread over (0, 1)
+    (20.0, 0.0),       # decays near 0: exp(G) underflows inside a chunk
+    (1e-3, 0.0),       # decays near 1: the state never forgets
+    (1e-3, 6.0),       # beta near 2: transitions with eigenvalues near -1
+], ids=["spread", "decay-near-0", "decay-near-1", "beta-near-2"])
+@pytest.mark.parametrize("chunk, sub", [(64, 16), (32, 32)])
+def test_chunked_delta_rule_is_the_recurrence(gscale, beta_shift, chunk, sub):
+    args = _kda_case(jax.random.PRNGKey(1), 100, gscale, beta_shift)
+    with jax.default_matmul_precision("highest"):
+        o1, S1 = dr.kda_recurrent(*args)
+        o2, S2 = dr.kda_chunked(*args, chunk=chunk, sub=sub)
+    assert np.isfinite(np.asarray(o2)).all()
+    scale = max(1.0, float(jnp.abs(S1).max()))
+    assert float(jnp.abs(o1 - o2).max()) < 1e-4 * scale
+    assert float(jnp.abs(S1 - S2).max()) < 1e-4 * scale
+
+
+def test_masked_positions_leave_the_state_as_it_was():
+    q, k, v, g, beta, S0 = _kda_case(jax.random.PRNGKey(2), 24, 1.0, 0.0)
+    keep = jnp.arange(24) < 17
+    g = jnp.where(keep[None, :, None, None], g, 0.0)
+    beta = jnp.where(keep[None, :, None], beta, 0.0)
+    _, S_all = dr.kda_chunked(q, k, v, g, beta, S0, chunk=8, sub=4)
+    _, S_17 = dr.kda_chunked(q[:, :17], k[:, :17], v[:, :17], g[:, :17],
+                             beta[:, :17], S0, chunk=8, sub=4)
+    assert float(jnp.abs(S_all - S_17).max()) < 1e-5
+
+
+def test_decode_kernel_updates_the_pool_in_place_by_slot():
+    """The Pallas decode update (interpreted here) against gather,
+    ``kda_step``, scatter: two rows on one slot apart, an idle-style row
+    (beta 0, g 0) writes back what it read."""
+    q, k, v, g, beta, _ = _kda_case(jax.random.PRNGKey(3), 5, 1.0, 0.0,
+                                    B=1, H=8, dk=128, dv=128)
+    state = jax.random.normal(jax.random.PRNGKey(4), (7, 8, 128, 128))
+    slots = jnp.array([3, 0, 6, 5, 2], jnp.int32)
+    beta = beta.at[0, 3].set(0.0)
+    g = g.at[0, 3].set(0.0)
+    args = (state, slots, q[0], k[0], v[0], g[0], beta[0])
+    o_x, st_x = dr.kda_decode_update(*args, impl="xla")
+    o_i, st_i = dr.kda_decode_update(*args, impl="interpret")
+    assert float(jnp.abs(o_x - o_i).max()) < 1e-5
+    assert float(jnp.abs(st_x - st_i).max()) < 1e-5
+    assert np.array_equal(np.asarray(st_i[5]), np.asarray(state[5]))
+    assert np.array_equal(np.asarray(st_i[1]), np.asarray(state[1]))
+    assert not np.array_equal(np.asarray(st_i[3]), np.asarray(state[3]))
+
+
+# ------------------------------ (c) shares ------------------------------- #
+
+
+def test_shares_routed_parts_add_up_to_the_uncut_layer():
+    """Guide section 4: the routed parts of all shares plus the shared
+    expert once equal the uncut layer, in the engine's sparse block and
+    in the reference alike."""
+    from deepspeed_tpu.inference.v2.llama_runner import _moe_mlp
+    whole_cfg = SolarOpen2Config.tiny(dtype=jnp.float32,
+                                      param_dtype=jnp.float32)
+    whole = mt.init_params(whole_cfg, 11)["layer_1"]
+    h = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 64))
+
+    def share(first, held):
+        cfg = dataclasses.replace(whole_cfg, experts_first=first,
+                                  experts_held=held)
+        p = dict(whole["moe"], **{n: whole["moe"][n][first:first + held]
+                                  for n in ("wi_gate", "wi_up", "wo")})
+        return cfg, p
+
+    with jax.default_matmul_precision("highest"):
+        uncut, _ = _moe_mlp(whole["moe"], h, whole_cfg, jnp.float32)
+        parts, refs = [], []
+        for first in (0, 4):
+            cfg, p = share(first, 4)
+            parts.append(_moe_mlp(p, h, cfg, jnp.float32)[0])
+            refs.append(reference._sparse_mlp(p, h, top_k=2, first=first,
+                                              scaling=1.0))
+        ref_uncut = reference._sparse_mlp(whole["moe"], h, top_k=2, first=0,
+                                          scaling=1.0)
+    assert float(jnp.abs(parts[0]).max()) > 1e-3      # each share does work
+    assert float(jnp.abs(parts[1]).max()) > 1e-3
+    assert float(jnp.abs(parts[0] + parts[1] - uncut).max()) < 1e-5
+    assert float(jnp.abs(refs[0] + refs[1] - ref_uncut).max()) < 1e-5
+    assert float(jnp.abs(uncut - ref_uncut).max()) < 1e-5
+    for a, b in zip(parts, refs):
+        assert float(jnp.abs(a - b).max()) < 1e-5
+
+
+def test_softmax_router_without_a_share_is_the_program_it_was():
+    """``grouped_moe_ffn`` at softmax scores, no bias and every expert
+    held lowers to the same program with and without the new arguments."""
+    from deepspeed_tpu.moe.sharded_moe import grouped_moe_ffn
+    x = jnp.ones((6, 16))
+    logits = jnp.arange(48.0).reshape(6, 8)
+    w = tuple(jnp.ones(s) for s in ((8, 16, 4), (8, 16, 4), (8, 4, 16)))
+
+    def program(**new):
+        def fn(x, logits):
+            return grouped_moe_ffn(x, logits, 2, w, jax.nn.silu, jnp.float32,
+                                   normalize_weights=False, **new)
+        return jax.jit(fn).lower(x, logits).as_text()
+
+    assert program() == program(score="softmax", select_bias=None,
+                                held=(0, 8))
+
+
+# ----------------------------- (d) state pool ---------------------------- #
+
+
+def test_a_slot_reused_after_flush_starts_from_zero(model):
+    cfg, params = model
+    a, b = prompt_of(30, seed=1), prompt_of(21, seed=2)
+    eng = engine(cfg, params, max_seqs=1)
+    eng.put([1], [a])
+    eng.decode_batch([1], [5], 4)
+    slot = eng.state.get(1).state_slot
+    eng.flush(1)
+    got = np.asarray(eng.put([2], [b])[2])
+    assert eng.state.get(2).state_slot == slot
+    fresh = np.asarray(engine(cfg, params, max_seqs=1).put([2], [b])[2])
+    assert np.array_equal(got, fresh)
+
+
+def test_padding_and_idle_rows_leave_every_other_state_bit_identical(model):
+    cfg, params = model
+    eng = engine(cfg, params)
+    eng.put([1, 2], [prompt_of(19, seed=3), prompt_of(33, seed=4)])
+    s1, s2 = (eng.state.get(u).state_slot for u in (1, 2))
+    before = jax.device_get((eng._kv_data.state, eng._kv_data.conv))
+    # sequence 2 alone: a padded prefill chunk, a fused loop whose other
+    # rows are idle, a per-step decode in a 16-row bucket
+    eng.put([2], [prompt_of(5, seed=5)])
+    eng.decode_batch([2], [9], 4)
+    eng.decode_pipelined([2], [11], 3)
+    after = jax.device_get((eng._kv_data.state, eng._kv_data.conv))
+    # rows lead a layer's states and follow the layer in the conv inputs
+    pairs = [(w, n) for w, n in zip(before[0], after[0])] \
+        + [(np.moveaxis(before[1], 1, 0), np.moveaxis(after[1], 1, 0))]
+    assert len(pairs) == 4
+    for was, now in pairs:
+        others = [r for r in range(was.shape[0] - 1) if r != s2]
+        assert s1 in others
+        assert np.array_equal(was[others], now[others])
+        assert not np.array_equal(was[s2], now[s2])
+
+
+def test_two_sequences_in_eight_slots_decode_as_they_do_alone(model):
+    cfg, params = model
+    prompts = {1: prompt_of(23, seed=6), 2: prompt_of(40, seed=7)}
+    alone = {}
+    for uid, p in prompts.items():
+        eng = engine(cfg, params)
+        first = eng.put([uid], [p], _greedy=True)[uid]
+        alone[uid] = [first] + list(eng.decode_batch([uid], [first], 8)[uid])
+    eng = engine(cfg, params)
+    first = eng.put([1, 2], [prompts[1], prompts[2]], _greedy=True)
+    outs = eng.decode_batch([1, 2], [first[1], first[2]], 8)
+    for uid in (1, 2):
+        assert [first[uid]] + list(outs[uid]) == alone[uid]
+
+
+# ------------------------------ (e) refusals ----------------------------- #
+
+
+@pytest.mark.parametrize("feature, kw", [
+    ("prefix_cache", dict(prefix_cache=True)),
+    ("spec_decode", dict(spec_decode="ngram")),
+    ("kv_cache_dtype='int8'", dict(kv_cache_dtype="int8")),
+    ("tp_size > 1", dict(tp_size=2, max_seqs=2)),
+    ("seq_size > 1", dict(seq_size=2, max_seqs=2)),
+    ("ep_size > 1", dict(ep_size=2, max_seqs=2)),
+])
+def test_construction_refuses_what_needs_a_state_snapshot(model, feature, kw):
+    cfg, params = model
+    with pytest.raises(ValueError) as err:
+        engine(cfg, params, **kw)
+    assert feature in str(err.value) and "'kda'" in str(err.value)
+
+
+@pytest.mark.parametrize("call", [
+    "pause", "resume", "handoff_out", "handoff_in", "drain", "replay",
+    "attach_draft", "decode_spec"])
+def test_calls_refuse_what_needs_a_state_snapshot(model, call):
+    cfg, params = model
+    eng = engine(cfg, params)
+    eng.put([1], [prompt_of(9)])
+    args = {"pause": (1,), "resume": (1,), "handoff_out": ([1],),
+            "handoff_in": ({},), "drain": (), "replay": ({},),
+            "attach_draft": (cfg, params), "decode_spec": ([1], [3], 2)}
+    with pytest.raises(NotImplementedError) as err:
+        getattr(eng, call)(*args[call])
+    assert call in str(err.value) and "'kda'" in str(err.value)
+
+
+# ------------------------------ (f) registry ----------------------------- #
+
+
+def _published():
+    """The catalog's ``config`` as the configuration file carries it, the
+    three reduced keys back at their published values."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "solar-open2-250b.json")) as f:
+        d = json.load(f)
+    for key in ("num_hidden_layers", "n_routed_experts", "vocab_size"):
+        d[key] = d[key + "_published"]
+    return d
+
+
+def test_config_from_hf_layer_list_and_parameter_counts():
+    arch, cfg = config_from_hf(_published())
+    assert arch == "solar_open2" and isinstance(cfg, SolarOpen2Config)
+    assert len(cfg.layer_kinds) == 48
+    assert [i for i, k in enumerate(cfg.layer_kinds) if k == "attn"] \
+        == list(range(0, 48, 4))
+    assert set(cfg.layer_kinds) == {"attn", "kda"}
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim) == (64, 8, 128)
+    assert (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv) == (64, 128, 4)
+    assert (cfg.num_experts, cfg.held, cfg.experts_top_k) == (320, 320, 8)
+    assert not cfg.use_rope and cfg.attn_gate and cfg.kda_neg_eigval
+    total, active = param_counts(cfg)
+    assert abs(total / 250e9 - 1) < 0.01           # the published 250B
+    assert abs(active / 15e9 - 1) < 0.03           # ... -A15B
+
+
+def test_the_benchmarks_cut_is_a_share_of_the_published_model():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "solar-open2-250b.json")) as f:
+        d = json.load(f)
+    cfg = mt.model_config(d)
+    assert cfg.layer_kinds == ("attn", "kda", "kda", "kda")
+    assert (cfg.num_experts, cfg.held, cfg.vocab_size) == (320, 40, 24576)
+    total, _ = param_counts(cfg)
+    assert abs(total / 3.308e9 - 1) < 0.005        # 6.62 GB in bfloat16
+    assert mt.kv_bytes_per_token(cfg) == 4096      # one softmax layer
+
+
+@pytest.mark.parametrize("key, value", [
+    ("first_k_dense_replace", 1), ("kda_use_full_proj", True)])
+def test_config_from_hf_refuses_what_it_does_not_implement(key, value):
+    with pytest.raises(ValueError, match=key):
+        config_from_hf(dict(_published(), **{key: value}))
+
+
+def test_flax_model_and_runner_read_one_tree(model):
+    """The flax module's forward (token-by-token recurrence, every held
+    expert densely) gives the reference's logits on the served tree."""
+    from deepspeed_tpu.models.solar_open2 import SolarOpen2
+    cfg, params = model
+    prompt = prompt_of(12, seed=8)
+    with jax.default_matmul_precision("highest"):
+        got = SolarOpen2(cfg).apply({"params": params},
+                                    jnp.asarray([prompt]))[0]
+    want = ref_logits(cfg, params, prompt, list(range(len(prompt))))
+    assert float(np.abs(np.asarray(got) - want).max()) < TOL

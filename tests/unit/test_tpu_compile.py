@@ -126,8 +126,9 @@ def test_olmoe_decode_loop_compiles_without_an_expert_by_rows_temporary(
     i32 = functools.partial(spec, dtype=jnp.int32)
     f32 = functools.partial(spec, dtype=jnp.float32)
     exe = runner._decode_loop_ring.trace(
-        params, pool, i32((slots,)), i32((slots,)), i32((slots,)),
-        i32((slots, 2)), i32((1,)), f32((1,)), i32((1,)), f32((1,)),
+        params, pool, None, None, i32((slots,)), i32((slots,)),
+        i32((slots,)), i32((slots, 2)), i32((1,)), f32((1,)), i32((1,)),
+        f32((1,)),
         i32((1, 1)), n=64, mode="greedy", cand=1, eos_id=-1,
         feed="self").lower(lowering_platforms=("tpu",)).compile()
     hlo = exe.as_text()
@@ -142,3 +143,66 @@ def test_olmoe_decode_loop_compiles_without_an_expert_by_rows_temporary(
     # and the loop's temporaries stay far under one expert stack
     assert exe.memory_analysis().temp_size_in_bytes \
         < experts * 2048 * 1024 * 2
+
+
+def test_solar2_decode_loop_keeps_one_copy_of_the_state(one_chip,
+                                                        monkeypatch):
+    """The fused decode loop of ``serve-solar2-rollout`` at the published
+    widths (one period of four layers, 128 slots), from shapes alone: the
+    recurrent state enters donated and comes back aliased, the three KDA
+    layers update it through the in-place Mosaic call, no operation
+    copies a state-shaped value and the loop's temporaries stay under one
+    layer's plane of the state."""
+    import json
+    import os
+    import re
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from benchmark.model_types import solar_open2 as mt
+    from deepspeed_tpu.inference.v2.kv_quant import KVPool
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "solar-open2-250b.json")) as f:
+        mcfg = mt.model_config(json.load(f))
+    slots, block = 128, 640
+    runner = LlamaRaggedRunner(mcfg, RaggedInferenceConfig(
+        max_seqs=slots, chunk_size=512, block_size=block, num_blocks=260,
+        max_blocks_per_seq=2, decode_loop_steps=64, dtype="bfloat16",
+        attention_impl="paged_flash"))
+    assert (runner.kv_layers, runner.state_spec["layers"]) == (1, 3)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: mt.init_params(mcfg, 0)))
+    planes = spec((1, 2, 261 * block, 8 * 128), jnp.bfloat16)
+    state = tuple(spec((slots + 1, 64, 128, 128), jnp.float32)
+                  for _ in range(3))
+    conv = spec((3, slots + 1, 3, 3 * 64 * 128), jnp.bfloat16)
+    i32 = functools.partial(spec, dtype=jnp.int32)
+    f32 = functools.partial(spec, dtype=jnp.float32)
+    exe = runner._decode_loop_ring.trace(
+        params, KVPool(planes, None, None, None), (state, conv),
+        i32((slots,)), i32((slots,)), i32((slots,)), i32((slots,)),
+        i32((slots, 2)), i32((1,)), f32((1,)), i32((1,)), f32((1,)),
+        i32((1, 1)), n=64, mode="greedy", cand=1, eos_id=-1,
+        feed="self").lower(lowering_platforms=("tpu",)).compile()
+    hlo = exe.as_text()
+    mem = exe.memory_analysis()
+    state_bytes = 3 * (slots + 1) * 64 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 3
+    shaped = r"f32\[%d,64,128,128\]" % (slots + 1)
+    made = re.findall(r"= %s\S* ([\w\-]+)\(" % shaped, hlo)
+    assert set(made) <= {"parameter", "get-tuple-element"}, set(made)
+    # the decode kernel of the softmax layer + one state update a KDA layer
+    assert hlo.count("tpu_custom_call") >= 4
+    rows = slots * mcfg.experts_top_k
+    assert len(re.findall(r"%ragged-dot[\w\-.]* = bf16\[" + str(rows),
+                          hlo)) == 3 * 4
