@@ -333,6 +333,10 @@ class InferenceEngineV2:
             # it holds a share of each layer's experts), from the fused
             # loop's per-expert carry like the two above
             "moe_rows_elsewhere": 0,
+            # the grouped expert kernel's work in the fused loop: held
+            # experts with at least one row, and visits to them (times an
+            # expert's matrices were streamed), over layers and steps
+            "moe_experts_hit": 0, "moe_expert_reads": 0,
             # recurrent models: state rows with a live tenant and their
             # bytes, per decode step (sampled where decode_slots_live
             # is, and per step of a fused loop), and the real positions
@@ -1523,6 +1527,13 @@ class InferenceEngineV2:
                 # that holds a share of the experts counts its own here
                 # and the rows it sent to the others apart
                 mc = self.runner.model_cfg
+                # the grouped kernel's own two counts ride behind the
+                # experts': held experts with a row, and visits to them
+                # (how often an expert's matrices were streamed); both 0
+                # from a program on the ragged_dot path
+                moe_rows, (hit, reads) = moe_rows[:-2], moe_rows[-2:]
+                stats["moe_experts_hit"] += int(hit)
+                stats["moe_expert_reads"] += int(reads)
                 first = getattr(mc, "experts_first", 0)
                 mine = moe_rows[first:first + getattr(mc, "held",
                                                       len(moe_rows))]

@@ -52,9 +52,10 @@ class KVPool(NamedTuple):
 class RingKV(NamedTuple):
     """Fused-decode-loop KV state threaded through the runners: the pool is
     READ-ONLY; this step's K/V goes into the [R, L, 2, S, KV*D] ring at
-    index ``t`` (see RaggedRunnerBase._decode_loop). ``moe_rows`` [E]
+    index ``t`` (see RaggedRunnerBase._decode_loop). ``moe_rows`` [E + 2]
     rides along for models with routed experts: the loop's running count
-    of real rows routed to each expert, which the sparse layers add to.
+    of real rows routed to each expert, then of the experts the grouped
+    kernel found hit and the visits it made, which the sparse layers add to.
     ``lin`` is the ``(state, conv)`` pair of a model with recurrent
     layers: unlike the pool it cannot stay read-only, so it is a carry of
     the loop and the recurrent layers update it in place."""

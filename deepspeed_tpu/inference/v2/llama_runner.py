@@ -42,11 +42,21 @@ class LlamaRaggedRunner(RaggedRunnerBase):
 def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
              icfg: RaggedInferenceConfig = None, valid=None):
     """Grouped-GEMM MoE for the ragged path: tokens sort by their routed
-    expert and each expert multiplies only its rows via
-    ``jax.lax.ragged_dot`` (sharded_moe.grouped_moe_ffn) — E/k x fewer
-    FLOPs than the round-2 dense-every-expert path. Matches the
-    reference's CUTLASS grouped GEMM
+    expert and each expert multiplies only its rows
+    (sharded_moe.grouped_moe_ffn) — E/k x fewer FLOPs than the round-2
+    dense-every-expert path. Matches the reference's CUTLASS grouped GEMM
     (inference/v2/kernels/cutlass_ops/moe_gemm/).
+
+    Which grouped matmul, from what this call can see
+    (``grouped_ffn.kernel_impl``): on a TPU backend, over plain floating
+    expert stacks, a step whose routed rows are a weight stream (``S x C
+    x k <= 128 x E``, under the chip's ridge of ~240 rows an expert)
+    takes the Pallas grouped kernel, each hit expert's matrices streamed
+    once with gate, up and down in one pass, at the row tile that holds
+    an expert's expected rows: every decode step (2-4 rows an expert) at
+    16, Solar's [4, 512] refill step (51) at 64. A step at the ridge
+    (OLMoE's refill, 256 rows an expert), quantised stacks and every
+    other backend take three ``jax.lax.ragged_dot`` calls.
 
     Inside an ``expert``-axis shard_map (``cfg.ep_size > 1`` engines)
     the routed rows instead travel the dispatch→grouped-GEMM→combine
@@ -59,10 +69,13 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
     expert stacks while the gate stays full-width.
 
     Returns (y [S, C, M], rows): with ``valid`` [S, C] given, ``rows``
-    [E] int32 counts the routed rows of VALID positions per expert
+    [E + 2] int32 counts the routed rows of VALID positions per expert
     (padding positions are computed like the others and left out of the
-    count only); None without it and on the expert-parallel path."""
+    count only), then the held experts the kernel found with at least one
+    row and the visits it made to them (both 0 on the ``ragged_dot``
+    path); None without it and on the expert-parallel path."""
     from ...moe.sharded_moe import grouped_moe_ffn, route_topk
+    from ...ops.kernels import grouped_ffn
     from ...ops.kernels.fp6_gemm import Fp6GemmWeight, fp6_gemm_unpack
     from .expert_parallel import EP_AXIS, ep_axis_active
     S, C, M = h.shape
@@ -99,16 +112,30 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
             jax.nn.silu, dtype, EP_AXIS, cfg.num_experts, cap,
             normalize_weights=norm, chunks=chunks)
         return y.reshape(S, C, M), None
+    impl = grouped_ffn.kernel_impl(S * C * cfg.experts_top_k,
+                                   cfg.num_experts, weights, dtype)
     y, _ = grouped_moe_ffn(
         h.reshape(S * C, M), logits, cfg.experts_top_k, weights,
-        jax.nn.silu, dtype, normalize_weights=norm, held=held, **router)
+        jax.nn.silu, dtype, normalize_weights=norm, held=held, impl=impl,
+        **router)
     rows = None
     if valid is not None:
         # the same choice the grouped path makes of the same logits
         top_idx = route_topk(logits, cfg.experts_top_k, score=router["score"],
                              bias=router["select_bias"])[0]
-        rows = jnp.zeros((cfg.num_experts,), jnp.int32).at[top_idx].add(
+        E = cfg.num_experts
+        rows = jnp.zeros((E,), jnp.int32).at[top_idx].add(
             valid.reshape(S * C, 1).astype(jnp.int32))
+        hit = reads = jnp.int32(0)
+        if impl is not None:
+            # what the kernel walked: every row of the step, padding too
+            first, count = held or (0, E)
+            mine = jnp.zeros((E,), jnp.int32).at[top_idx].add(
+                1)[first:first + count]
+            hit = jnp.sum(mine > 0, dtype=jnp.int32)
+            tile = grouped_ffn.row_tile(S * C * cfg.experts_top_k, E)
+            reads = jnp.sum(-(-mine // tile), dtype=jnp.int32)
+        rows = jnp.concatenate([rows, jnp.stack([hit, reads])])
     return y.reshape(S, C, M), rows
 
 

@@ -928,8 +928,11 @@ class RaggedRunnerBase:
             done0 = jnp.zeros((S,), jnp.bool_)
             # real rows routed to each expert, summed over the sparse
             # layers and the loop's steps (the single-chip expert path
-            # adds to it; [0] for a model without experts and under ep)
-            moe0 = jnp.zeros((moe_experts,), jnp.int32)
+            # adds to it; [0] for a model without experts and under ep),
+            # then the experts the grouped kernel found hit and the visits
+            # it made to them
+            moe0 = jnp.zeros((moe_experts + 2 if moe_experts else 0,),
+                             jnp.int32)
 
             def body(carry, t):
                 ring, tok, pos, done, moe, lin = carry
@@ -1148,9 +1151,11 @@ class RaggedRunnerBase:
         so one program scores the model's choice after every draft
         prefix. Returns (tokens [S, n] int32, logprobs [S, n] f32 or
         None, new kv_data, consumed [S] int32 or None — KV positions
-        each slot appended, None when EOS is off — and moe_rows [E]
+        each slot appended, None when EOS is off — and moe_rows [E + 2]
         int32 or None: real rows routed to each expert over the loop's
-        steps and the sparse layers, None for a model without experts).
+        steps and the sparse layers, then ``llama_runner._moe_mlp``'s two
+        counts of the grouped kernel's work; None for a model without
+        experts).
         Slots must have KV blocks covering start_pos..start_pos+n-1.
         """
         jnp_ = jax.numpy

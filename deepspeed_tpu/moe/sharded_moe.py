@@ -233,6 +233,7 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
                     normalize_weights: bool = True, *,
                     score: str = "softmax", select_bias=None,
                     weight_scale: float = 1.0, held=None,
+                    impl: Optional[str] = None,
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dropless top-k MoE via grouped expert matmuls (``jax.lax.ragged_dot``).
 
@@ -257,6 +258,19 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     all ``E``; rows whose expert is elsewhere sort behind the last held
     group, lie in no group of the grouped matmuls (which leave rows past
     their groups alone) and add nothing to the output.
+
+    ``impl``: None is the path above, three ``ragged_dot`` calls over all
+    S*k rows: what training takes (hundreds of rows an expert, and the
+    gradient flows through it) and what serving takes at the chip's ridge
+    (a prefill step at 256 rows an expert). "pallas" (or "interpret", the
+    same kernel interpreted) is the serving path where the work is a
+    weight stream (``S x k <= 128 x E``: a decode step's 2-4 rows an
+    expert at a 16-row tile, a refill step's 51 at a 64-row tile): one
+    grouped kernel of our own (``ops/kernels/grouped_ffn.py``) walks the
+    held groups that have rows, reads each one's matrices once and does
+    gate, up and down in one pass; it has no gradient and returns no aux
+    loss. The caller chooses (``inference/v2/llama_runner._moe_mlp``, by
+    ``grouped_ffn.kernel_impl``).
     """
     S, E = logits.shape
     top_idx, w_sel, gates = route_topk(
@@ -270,6 +284,17 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
         here = (eid >= first) & (eid < first + count)
         eid = jnp.where(here, eid - first, count)          # elsewhere: last
         E = count
+    if impl is not None:
+        from ..ops.kernels.grouped_ffn import layout_and_run, row_tile
+        # row r of ys is token r // k's j-th choice: no sort to undo
+        ys = layout_and_run(
+            tokens, eid.astype(jnp.int32),
+            tuple(w.astype(dtype) for w in weights), activation, dtype,
+            tile=row_tile(S * k, logits.shape[1]),
+            interpret=impl == "interpret")
+        out = jnp.sum(ys.reshape(S, k, -1).astype(jnp.float32)
+                      * w_sel[..., None], axis=1).astype(dtype)
+        return out, jnp.float32(0.0)
     order = jnp.argsort(eid, stable=True)
     tok_of = order // k                                    # source token
     xs = jnp.take(tokens, tok_of, axis=0).astype(dtype)    # sorted by expert

@@ -387,8 +387,10 @@ def test_moe_mlp_counts_valid_positions_per_expert():
     valid = jnp.asarray([[1, 1, 1, 0], [1, 0, 0, 0], [0, 0, 0, 0]], bool)
     y, rows = _moe_mlp(p_moe, h, cfg, jnp.float32, valid=valid)
     y_all, none = _moe_mlp(p_moe, h, cfg, jnp.float32)
-    assert none is None and rows.shape == (cfg.num_experts,)
-    assert int(rows.sum()) == 4 * cfg.experts_top_k
+    # behind the experts' counts, the grouped kernel's two: 0 on this path
+    assert none is None and rows.shape == (cfg.num_experts + 2,)
+    rows, kernel = rows[:-2], rows[-2:]
+    assert int(rows.sum()) == 4 * cfg.experts_top_k and not kernel.any()
     np.testing.assert_array_equal(np.asarray(y), np.asarray(y_all))
     logits = h.reshape(12, -1) @ p_moe["gate"]
     want = np.zeros(cfg.num_experts, np.int64)

@@ -92,14 +92,18 @@ def test_olmoe_decode_loop_compiles_without_an_expert_by_rows_temporary(
     of 16 heads x 128 = 2048 lanes, two 640-token blocks a sequence, 64
     experts of width 1024 top-8) at the published widths and two layers,
     from shapes alone: the paged kernel takes the 2048-lane tiles, the
-    grouped matmuls stay XLA's ragged-dot calls over the 256 routed rows,
-    and nothing of [experts, rows, width] shape is built around them."""
+    routed experts' feed-forward is ONE Mosaic call a layer (the grouped
+    kernel over 76 row tiles of 16: no ragged-dot is left at the decode
+    shape), and nothing of [experts, rows, width] shape is built around
+    it."""
     import re
 
     import deepspeed_tpu.ops.kernels as kernels
     from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
     from deepspeed_tpu.models.mixtral import Mixtral, MixtralConfig
+    from deepspeed_tpu.ops.kernels.grouped_ffn import ROW_TILE, visits_bound
     monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     slots, block, layers, experts, top_k = 32, 640, 2, 64, 8
     mcfg = MixtralConfig(
         vocab_size=50304, max_seq_len=4096, num_layers=layers, num_heads=16,
@@ -133,10 +137,13 @@ def test_olmoe_decode_loop_compiles_without_an_expert_by_rows_temporary(
         feed="self").lower(lowering_platforms=("tpu",)).compile()
     hlo = exe.as_text()
     rows = slots * top_k
-    assert len(re.findall(r"%ragged-dot[\w\-.]* = bf16\[" + str(rows),
-                          hlo)) == 3 * layers
+    assert "ragged-dot" not in hlo
+    padded = visits_bound(rows, experts) * ROW_TILE
+    assert len(re.findall(
+        r"%%grouped_ffn_decode[\w\-.]* = bf16\[%d,2048\]" % padded,
+        hlo)) >= layers
     # one paged-attention kernel a layer beside them
-    assert hlo.count("tpu_custom_call") >= 4 * layers
+    assert hlo.count("tpu_custom_call") >= 2 * layers
     wide = re.findall(r"(?:bf16|f32)\[%d,%d,(?:2048|1024)\]"
                       % (experts, rows), hlo)
     assert not wide, f"an [experts, rows, width] temporary: {set(wide)}"
@@ -203,6 +210,12 @@ def test_solar2_decode_loop_keeps_one_copy_of_the_state(one_chip,
     assert set(made) <= {"parameter", "get-tuple-element"}, set(made)
     # the decode kernel of the softmax layer + one state update a KDA layer
     assert hlo.count("tpu_custom_call") >= 4
+    # and the grouped expert kernel once a layer, over 101 row tiles of
+    # 16 where ragged-dot was handed all 1,024 routed rows three times
+    from deepspeed_tpu.ops.kernels.grouped_ffn import ROW_TILE, visits_bound
     rows = slots * mcfg.experts_top_k
-    assert len(re.findall(r"%ragged-dot[\w\-.]* = bf16\[" + str(rows),
-                          hlo)) == 3 * 4
+    assert "ragged-dot" not in hlo
+    padded = visits_bound(rows, mcfg.held) * ROW_TILE
+    assert len(re.findall(
+        r"%%grouped_ffn_decode[\w\-.]* = bf16\[%d,4096\]" % padded,
+        hlo)) >= 4
