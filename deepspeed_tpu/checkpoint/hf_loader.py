@@ -464,7 +464,24 @@ def _split_neox_fused(state, hf_cfg):
     return _split_headwise_qkv(state, hf_cfg, "query_key_value")
 
 
+def _pangu_ultra_moe_names(state, hf_cfg):
+    """pangu_ultra_moe: per-expert stacking as qwen2-moe names them, and
+    the multi-token-prediction modules, which the checkpoint stores as
+    layers ``num_hidden_layers ..`` (the DeepSeek-V3 convention), renamed
+    ``model.mtp.<i>`` so that ``_PANGU_ULTRA_MOE_MAP`` can tell them from
+    the decoder's layers."""
+    n = int(hf_cfg.get("num_hidden_layers", 61))
+    out = {}
+    for name, arr in _qwen2_moe_experts(state, hf_cfg).items():
+        m = re.match(r"model\.layers\.(\d+)\.(.*)", name)
+        if m and int(m.group(1)) >= n:
+            name = f"model.mtp.{int(m.group(1)) - n}.{m.group(2)}"
+        out[name] = arr
+    return out
+
+
 SPECIAL_HANDLERS = {
+    "pangu_ultra_moe": _pangu_ultra_moe_names,
     "phi3": _split_phi3_fused,
     "qwen": _split_qwen_fused,
     "bloom": _split_bloom_fused,
@@ -500,6 +517,50 @@ _OLMOE_MAP = _LLAMA_MAP + _MOE_STACKED_RULES + [
      "layer_{0}/attn/{1}_norm/scale", "vector"),
 ]
 
+def _pangu_layer_rules(hf: str, fw: str):
+    """One decoder block's names under the checkpoint prefix ``hf`` and
+    the tree prefix ``fw`` (a layer, or an MTP module's block). The
+    sandwich norms: ``post_attention_layernorm`` norms the attention
+    branch's OUTPUT and ``pre_mlp_layernorm`` is the norm before the
+    feed-forward, which this tree calls ``post_attn_norm`` as every
+    Llama-family tree does."""
+    return [
+        (hf + r"\.input_layernorm\.weight", fw + "/input_norm/scale",
+         "vector"),
+        (hf + r"\.post_attention_layernorm\.weight",
+         fw + "/attn_branch_norm/scale", "vector"),
+        (hf + r"\.pre_mlp_layernorm\.weight", fw + "/post_attn_norm/scale",
+         "vector"),
+        (hf + r"\.post_mlp_layernorm\.weight",
+         fw + "/mlp_branch_norm/scale", "vector"),
+        (hf + r"\.self_attn\.(q_a|q_b|kv_b|o)_proj\.weight",
+         fw + "/attn/{1}_proj/kernel", "linear"),
+        (hf + r"\.self_attn\.kv_a_proj_with_mqa\.weight",
+         fw + "/attn/kv_a_proj/kernel", "linear"),
+        (hf + r"\.self_attn\.(q_a|kv_a)_layernorm\.weight",
+         fw + "/attn/{1}_norm/scale", "vector"),
+        (hf + r"\.mlp\.(gate|up|down)_proj\.weight",
+         fw + "/mlp/{1}_proj/kernel", "linear"),
+        (hf + r"\.mlp\.gate\.weight", fw + "/moe/gate", "linear"),
+        (hf + r"\.mlp\.shared_experts\.(gate|up|down)_proj\.weight",
+         fw + "/shared_{1}_proj/kernel", "linear"),
+        (hf + r"\.moe_stacked\.(wi_gate|wi_up|wo)", fw + "/moe/{1}",
+         "stacked"),
+    ]
+
+
+_PANGU_ULTRA_MOE_MAP = _LLAMA_MAP[:3] \
+    + _pangu_layer_rules(r"model\.layers\.(\d+)", "layer_{0}") \
+    + _pangu_layer_rules(r"model\.mtp\.(\d+)", "mtp_{0}/block") + [
+        (r"model\.mtp\.(\d+)\.(enorm|hnorm)\.weight", "mtp_{0}/{1}/scale",
+         "vector"),
+        (r"model\.mtp\.(\d+)\.eh_proj\.weight", "mtp_{0}/eh_proj/kernel",
+         "linear"),
+        (r"model\.mtp\.(\d+)\.shared_head\.norm\.weight",
+         "mtp_{0}/final_norm/scale", "vector"),
+    ]
+
+ARCH_MAPS["pangu_ultra_moe"] = _PANGU_ULTRA_MOE_MAP
 ARCH_MAPS["mixtral"] = _MIXTRAL_MAP
 ARCH_MAPS["qwen2_moe"] = _QWEN2_MOE_MAP
 ARCH_MAPS["olmoe"] = _OLMOE_MAP

@@ -351,7 +351,8 @@ class RaggedInferenceConfig(ConfigModel):
             return
         from ...models.mixtral import MixtralConfig
         is_moe = isinstance(model_cfg, MixtralConfig)
-        if any(k != "attn" for k in getattr(model_cfg, "layer_kinds", ())):
+        if any(k not in ("attn", "mla")
+               for k in getattr(model_cfg, "layer_kinds", ())):
             # a model with recurrent layers keeps per-sequence state that
             # cannot be rewound, copied or sharded yet: what would need a
             # state snapshot refuses here, by name
@@ -364,6 +365,20 @@ class RaggedInferenceConfig(ConfigModel):
                     (self.ep_size > 1, "ep_size > 1")):
                 if on:
                     raise ValueError(stateful_refusal(feature))
+        if "mla" in getattr(model_cfg, "layer_kinds", ()):
+            # a latent-attention model keeps ONE plane a layer, a row that
+            # is key and value at once: what reads the cache as K and V
+            # planes, shards it by kv heads or scales it a head refuses
+            # here, by name
+            for on, feature in (
+                    (self.prefix_cache, "prefix_cache"),
+                    (self.spec_decode != "off", "spec_decode"),
+                    (self.kv_cache_dtype == "int8", "kv_cache_dtype='int8'"),
+                    (self.tp_size > 1, "tp_size > 1"),
+                    (self.seq_size > 1, "seq_size > 1"),
+                    (self.ep_size > 1, "ep_size > 1")):
+                if on:
+                    raise ValueError(latent_refusal(feature))
         if is_moe and self.tp_size > 1 and self.ep_size == 1:
             # tp alone would replicate the full expert set on every chip
             # AND trip the dense-branch all-reduce accounting — for MoE
@@ -438,3 +453,12 @@ def stateful_refusal(feature: str, kind: str = "kda") -> str:
             f"({kind!r}) layers: it needs a snapshot, a rewind or a shard "
             f"of the per-sequence recurrent state, which the state pool "
             f"cannot give yet")
+
+
+def latent_refusal(feature: str) -> str:
+    """The one wording of every refusal a latent-attention model makes:
+    the feature that cannot run over its one-plane cache yet."""
+    return (f"{feature} is not supported for a model with latent "
+            f"('mla') attention layers: its cache keeps one plane a layer "
+            f"(the latent row is key and value at once), and this path "
+            f"has not been carried over that plane yet")

@@ -242,10 +242,14 @@ class InferenceEngineV2:
         self.kv_cache = BlockedKVCache(
             self.config, self.runner.kv_layers, self.runner.kv_heads,
             self.runner.head_dim, dtype=resolve_dtype(self.config.dtype),
-            state_spec=self.runner.state_spec)
+            state_spec=self.runner.state_spec,
+            planes=self.runner.kv_planes)
         #: layer kind of the model's recurrent layers (None: it has none);
         #: what needs a state snapshot refuses by this name
         self._stateful = (self.runner.state_spec or {}).get("kind")
+        #: a latent-attention model's cache has one plane a layer; what
+        #: has not been carried over it refuses by name
+        self._latent = self.runner.kv_planes == 1
         if self.config.ep_size > 1:
             if self.runner.tp is not None:
                 # composed ep×tp: the pool head-shards over 'model' on
@@ -342,16 +346,39 @@ class InferenceEngineV2:
             # is, and per step of a fused loop), and the real positions
             # that went through the chunked delta rule
             "state_slots_live": 0, "state_bytes_live": 0,
-            "linear_attn_prefill_tokens": 0}
-        from ...ops.kernels import decode_rows_fetched, decode_tile_rows
-        self._kv_rows_fetched = functools.partial(
-            decode_rows_fetched,
-            tile_rows=decode_tile_rows(
-                self.config.block_size,
-                self.runner.local_kv_heads * self.runner.head_dim,
-                1 if self.config.kv_cache_dtype == "int8"
-                else np.dtype(resolve_dtype(self.config.dtype)).itemsize),
-            window=getattr(model_cfg, "sliding_window", None))
+            "linear_attn_prefill_tokens": 0,
+            # latent-attention models, a layer's worth each: settled
+            # latent rows of the live sequences per pure-decode step (and
+            # per step of a fused loop), the rows the decode kernel
+            # streams for them in whole copy tiles (each ONCE: the row is
+            # key and value), the bytes the live rows are over all layers;
+            # per prefill step the real positions through the absorbed
+            # prefill. These stand IN PLACE of decode_kv_rows_*: such a
+            # model has no K/V rows and never runs that kernel
+            "latent_rows_live": 0, "latent_rows_fetched": 0,
+            "latent_bytes_live": 0, "mla_prefill_tokens": 0}
+        #: rows the decode kernel this model runs streams for a sequence
+        #: of so many settled tokens (each kernel module's own arithmetic)
+        if self._latent:
+            from ...ops.kernels.mla_attention import decode_rows_fetched
+            self._kv_rows_fetched = functools.partial(
+                decode_rows_fetched, block_size=self.config.block_size)
+        else:
+            from ...ops.kernels import decode_rows_fetched, decode_tile_rows
+            self._kv_rows_fetched = functools.partial(
+                decode_rows_fetched,
+                tile_rows=decode_tile_rows(
+                    self.config.block_size,
+                    self.runner.local_kv_heads * self.runner.head_dim,
+                    1 if self.config.kv_cache_dtype == "int8"
+                    else np.dtype(resolve_dtype(self.config.dtype)).itemsize),
+                window=getattr(model_cfg, "sliding_window", None))
+        #: bytes of one token's latent rows over all layers, as the model
+        #: states them (the stored row's zero tail left out)
+        self._latent_token_bytes = self.runner.kv_layers \
+            * getattr(model_cfg, "head_dim", 0) \
+            * np.dtype(resolve_dtype(self.config.dtype)).itemsize \
+            if self._latent else 0
         self._spans = SpanSet(self.pipeline_stats, lambda: self._obs)
         # ---- serve-side resilience (drain.py, docs/resilience.md) ---- #
         cfg = self.config
@@ -877,7 +904,7 @@ class InferenceEngineV2:
         the interrupted engine call returned; the pipeline itself unwinds
         on the drain flag. Returns the manifest dict (``pool`` carries
         the full-recovery verdict the drills assert on)."""
-        self._refuse_stateful("drain")
+        self._refuse_stateful("drain", latent_too=True)
         if self._live_ring is not None:
             raise ServeDrainError(
                 "drain() called with steps in flight — request_drain() "
@@ -944,7 +971,7 @@ class InferenceEngineV2:
         next. The sequences stay live for continued decoding, with
         prompt/generated split restored so a LATER drain of this engine
         emits cumulative manifests."""
-        self._refuse_stateful("replay")
+        self._refuse_stateful("replay", latent_too=True)
         if self._draining():
             raise EngineDrainingError(
                 "replay() on a draining engine — replay belongs on the "
@@ -1102,14 +1129,34 @@ class InferenceEngineV2:
     def flush(self, uid: int) -> None:
         self._flush_uid(uid)
 
-    def _refuse_stateful(self, feature: str) -> None:
+    def _refuse_stateful(self, feature: str,
+                         latent_too: bool = False) -> None:
         """What would need a snapshot of the recurrent state refuses, by
         the feature's name and the layer kind (config.stateful_refusal;
-        carrying state through these is later work)."""
+        carrying state through these is later work); with ``latent_too``
+        also what has not been carried over a latent-attention model's
+        one-plane cache (config.latent_refusal)."""
         if self._stateful:
             from .config import stateful_refusal
             raise NotImplementedError(
                 stateful_refusal(feature, self._stateful))
+        if latent_too and self._latent:
+            from .config import latent_refusal
+            raise NotImplementedError(latent_refusal(feature))
+
+    def _decode_row_counts(self, runs) -> Dict[str, int]:
+        """The decode kernel's row counters for ``runs``, (steps a
+        sequence ran, its settled rows) pairs: ``decode_kv_rows_*`` over
+        K/V planes, ``latent_rows_*`` and their bytes over a latent
+        plane (a layer's worth each; one pair a model, never both)."""
+        live = sum(ran * rows for ran, rows in runs)
+        fetched = sum(ran * self._kv_rows_fetched(rows)
+                      for ran, rows in runs)
+        if not self._latent:
+            return {"decode_kv_rows_live": live,
+                    "decode_kv_rows_fetched": fetched}
+        return {"latent_rows_live": live, "latent_rows_fetched": fetched,
+                "latent_bytes_live": live * self._latent_token_bytes}
 
     def pause(self, uid: int) -> None:
         """Evict a sequence's KV blocks to host memory and free them — the
@@ -1185,7 +1232,7 @@ class InferenceEngineV2:
         device slices; the caller materializes them (one batched
         device_get) where the wait can hide under other replicas'
         compute. Registered DSL001 hot path — dispatch only."""
-        self._refuse_stateful("handoff_out")
+        self._refuse_stateful("handoff_out", latent_too=True)
         recs: List[Dict[str, Any]] = []
         blocks_moved = 0
         bytes_moved = 0
@@ -1262,7 +1309,7 @@ class InferenceEngineV2:
         measured non-overlapped transfer wall, observed into
         ``serve_handoff_exposed_s``. Registered DSL001 hot path —
         dispatch only."""
-        self._refuse_stateful("handoff_in")
+        self._refuse_stateful("handoff_in", latent_too=True)
         if self._draining():
             raise EngineDrainingError(
                 "handoff_in() on a draining engine — migrate to a "
@@ -1508,11 +1555,10 @@ class InferenceEngineV2:
             # the loop's own tokens ride its ring: every step a slot was
             # alive the kernel read the rows settled at entry
             stats = self.pipeline_stats
-            for i, seq in enumerate(seqs):
-                ran = int(consumed[i]) if consumed is not None else n
-                stats["decode_kv_rows_live"] += ran * seq.seen_tokens
-                stats["decode_kv_rows_fetched"] += \
-                    ran * self._kv_rows_fetched(seq.seen_tokens)
+            for key, val in self._decode_row_counts([
+                    (int(consumed[i]) if consumed is not None else n,
+                     seq.seen_tokens) for i, seq in enumerate(seqs)]).items():
+                stats[key] += val
             if self._stateful:
                 ran = n * len(seqs) if consumed is None \
                     else int(consumed[:len(seqs)].sum())
@@ -1719,6 +1765,8 @@ class InferenceEngineV2:
                                             for item in sched))
                 if sslots is not None:
                     span.count(linear_attn_prefill_tokens=real)
+                if self._latent:
+                    span.count(mla_prefill_tokens=real)
                 # serve fault site: a replica dying with a freshly planned
                 # multi-token prefill chunk (tokens consumed host-side, step
                 # never dispatched)
@@ -1727,9 +1775,7 @@ class InferenceEngineV2:
                 # this step's token is in the pool before the kernel runs
                 lens = [item.start_pos + 1 for item in sched]
                 span.count(decode_slots_live=real, decode_slots_planned=S,
-                           decode_kv_rows_live=sum(lens),
-                           decode_kv_rows_fetched=sum(
-                               map(self._kv_rows_fetched, lens)))
+                           **self._decode_row_counts([(1, n) for n in lens]))
                 if sslots is not None:
                     span.count(state_slots_live=real,
                                state_bytes_live=real
@@ -2069,7 +2115,7 @@ class InferenceEngineV2:
         tokenizer). Its journal and telemetry are disabled — draft
         tokens are proposals, never served output. Returns the draft
         engine (callers may size ``draft_config`` themselves)."""
-        self._refuse_stateful("attach_draft")
+        self._refuse_stateful("attach_draft", latent_too=True)
         tv = getattr(self.runner.model_cfg, "vocab_size", None)
         dv = getattr(draft_model_cfg, "vocab_size", None)
         if tv != dv:
@@ -2137,7 +2183,7 @@ class InferenceEngineV2:
         sequences must have no pending tokens. Under KV pressure it
         evicts-then-retries and finally falls back to the incremental
         pipelined path, which can shed."""
-        self._refuse_stateful("decode_spec")
+        self._refuse_stateful("decode_spec", latent_too=True)
         from .speculative import accept_length
         cfg = self.config
         if len(batch_uids) != len(first_tokens):

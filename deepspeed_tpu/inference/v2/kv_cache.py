@@ -122,12 +122,16 @@ class _HostRef:
 class BlockedKVCache:
     def __init__(self, cfg: RaggedInferenceConfig, num_layers: int,
                  kv_heads: int, head_dim: int, dtype: Any = None,
-                 state_spec: Optional[dict] = None):
+                 state_spec: Optional[dict] = None, planes: int = 2):
         """``num_layers`` counts the layers that keep K/V (the softmax
         layers of a hybrid model, every layer otherwise). ``state_spec``
         (``RaggedRunnerBase.state_spec``) asks for the per-sequence state
-        pool of a model with recurrent layers beside the paged planes."""
+        pool of a model with recurrent layers beside the paged planes.
+        ``planes`` is how many planes a layer keeps: K and V, or the ONE
+        plane of a latent-attention layer, whose row (``kv_heads`` 1,
+        ``head_dim`` the stored row) is key and value at once."""
         self.cfg = cfg
+        self.planes = planes
         self.num_layers = num_layers
         self.kv_heads = kv_heads
         self.head_dim = head_dim
@@ -165,12 +169,13 @@ class BlockedKVCache:
             # int8 rows + per-(token, kv-head) f32 scales TRANSPOSED so a
             # context window's scales DMA as KV contiguous runs (kv_quant)
             self.data = jnp.zeros(
-                (num_layers, 2, slots, kv_heads * head_dim), jnp.int8)
-            self.scales = jnp.zeros((num_layers, 2, kv_heads, slots),
+                (num_layers, planes, slots, kv_heads * head_dim), jnp.int8)
+            self.scales = jnp.zeros((num_layers, planes, kv_heads, slots),
                                     jnp.float32)
         else:
             self.data = jnp.zeros(
-                (num_layers, 2, slots, kv_heads * head_dim), self.dtype)
+                (num_layers, planes, slots, kv_heads * head_dim),
+                self.dtype)
             self.scales = None
         # per-sequence recurrent state: one row a sequence slot (the state
         # manager hands the slots out) + the idle row padding points at
@@ -550,6 +555,12 @@ class BlockedKVCache:
         if self.scales is not None:
             n += self.scales.size * self.scales.dtype.itemsize
         return n + self.state_bytes_per_slot() * (self.cfg.max_seqs + 1)
+
+    def kv_bytes_per_token(self) -> int:
+        """Bytes one token holds in the paged planes as stored, over all
+        layers (a latent row's zero tail included; scales left out)."""
+        L, planes, _, row = self.data.shape
+        return L * planes * row * self.data.dtype.itemsize
 
     def state_bytes_per_slot(self) -> int:
         """Bytes of recurrent state and convolution inputs one sequence

@@ -1,5 +1,7 @@
-"""Ragged paged-KV runner for the Llama family (Mixtral-style MoE, and the
-hybrid Solar-Open2 family whose layers follow a per-layer list of mixers).
+"""Ragged paged-KV runner for the Llama family (Mixtral-style MoE, the
+hybrid Solar-Open2 family whose layers follow a per-layer list of mixers,
+and the latent-attention openPangu-Ultra-MoE family, whose feed-forward
+kind follows a per-layer list too).
 
 Analogue of the reference's llama_v2 / mistral / mixtral v2 containers
 (``inference/v2/model_implementations/{llama_v2,mistral,mixtral}/``): RoPE
@@ -19,8 +21,8 @@ from ...models.llama import LlamaConfig, apply_rope
 from ...models.mixtral import MixtralConfig
 from .config import RaggedInferenceConfig
 from .kv_quant import lin_parts, with_lin
-from .model_runner import (RaggedBatch, RaggedRunnerBase, paged_attention,
-                           tp_all_reduce, woq_mm)
+from .model_runner import (RaggedBatch, RaggedRunnerBase, latent_attention,
+                           paged_attention, tp_all_reduce, woq_mm)
 
 
 def _rms(x, scale, eps):
@@ -195,6 +197,43 @@ def _kda_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
     return with_lin(kv, state, conv), kda_output(p, o, h, model_cfg, dtype)
 
 
+def _mla_mixer(p, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
+               pos, valid_q, dtype):
+    """One latent-attention (MLA) layer over plane ``plane`` of the
+    one-plane cache, in the ABSORBED form: the cache keeps ``[c_kv ; k_r]``
+    a token, ``W_UK`` (the key half of ``kv_b_proj``) is multiplied into
+    the query, ``q_lat = q_nope W_UK^T``, and ``W_UV`` (its value half)
+    into the output, so no cached row is ever expanded to per-head keys
+    and values: scores ``q_lat . c_kv + q_rope . k_r``, ``o_lat = sum p
+    c_kv``, ``o = o_lat W_UV``. Decode steps and prefill chunks alike.
+    Returns (kv, y)."""
+    S, C, _ = h.shape
+    H, r = model_cfg.num_heads, model_cfg.kv_lora_rank
+    dn, dr, dv = (model_cfg.qk_nope_head_dim, model_cfg.qk_rope_head_dim,
+                  model_cfg.v_head_dim)
+    eps, W = model_cfg.rms_eps, model_cfg.latent_row
+    cq = _rms(woq_mm(h, p["q_a_proj"]["kernel"], dtype),
+              p["q_a_norm"]["scale"], eps).astype(dtype)
+    q = woq_mm(cq, p["q_b_proj"]["kernel"], dtype).reshape(S, C, H, dn + dr)
+    ckv = woq_mm(h, p["kv_a_proj"]["kernel"], dtype)       # [S, C, r + dr]
+    c = _rms(ckv[..., :r], p["kv_a_norm"]["scale"], eps).astype(dtype)
+    k_r = apply_rope(ckv[..., None, r:], pos, model_cfg.rope_theta)[:, :, 0]
+    q_r = apply_rope(q[..., dn:], pos, model_cfg.rope_theta)
+    w_kvb = p["kv_b_proj"]["kernel"].astype(dtype).reshape(r, H, dn + dv)
+    q_lat = jnp.einsum("schd,rhd->schr", q[..., :dn], w_kvb[..., :dn])
+    # the stored row and the query against it, whole 128-lane groups
+    pad = W - r - dr
+    row = jnp.concatenate(
+        [c, k_r] + [jnp.zeros((S, C, pad), dtype)] * (pad > 0), axis=-1)
+    qa = jnp.concatenate(
+        [q_lat, q_r] + [jnp.zeros((S, C, H, pad), dtype)] * (pad > 0),
+        axis=-1)
+    kv, o_lat = latent_attention(kv, plane, qa, row, batch, cfg, pos,
+                                 valid_q, (dn + dr) ** -0.5, dtype, r)
+    o = jnp.einsum("schr,rhd->schd", o_lat, w_kvb[..., dn:])
+    return kv, woq_mm(o.reshape(S, C, H * dv), p["o_proj"]["kernel"], dtype)
+
+
 def _attn_mixer(pa, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
                 pos, valid_q, dtype):
     """One softmax-attention layer over plane ``plane`` of the paged
@@ -247,11 +286,15 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
     rdtype = getattr(model_cfg, "residual_dtype", None) or dtype
     x = params["embed"]["embedding"][batch.tokens].astype(rdtype)
 
-    # one step function for every family: the layer list says which mixer
-    # a layer runs; softmax layers take the cache's planes in order and
-    # recurrent layers the state pool's
+    # one step function for every family: the layer lists say which mixer
+    # and which feed-forward a layer runs; softmax and latent layers take
+    # the cache's planes in order and recurrent layers the state pool's
     kinds = getattr(model_cfg, "layer_kinds", None) \
         or ("attn",) * model_cfg.num_layers
+    ffn_kinds = getattr(model_cfg, "ffn_kinds", None) \
+        or ("moe" if is_moe else "dense",) * len(kinds)
+    # a norm on each branch's OUTPUT too, before the residual add
+    sandwich = getattr(model_cfg, "sandwich_norm", False)
     plane = si = 0
     for li, kind in enumerate(kinds):
         p = params[f"layer_{li}"]
@@ -261,15 +304,21 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
             kv, y = _kda_mixer(p["kda"], h, kv, si, batch, model_cfg,
                                valid_q, dtype)
             si += 1
+        elif kind == "mla":
+            kv, y = _mla_mixer(p["attn"], h, kv, plane, batch, model_cfg,
+                               cfg, pos, valid_q, dtype)
+            plane += 1
         else:
             kv, y = _attn_mixer(p["attn"], h, kv, plane, batch, model_cfg,
                                 cfg, pos, valid_q, dtype)
             plane += 1
+        if sandwich:
+            y = _rms(y, p["attn_branch_norm"]["scale"], model_cfg.rms_eps)
         x = x + y.astype(rdtype)
 
         h = _rms(x, p["post_attn_norm"]["scale"],
                  model_cfg.rms_eps).astype(dtype)
-        if is_moe:
+        if ffn_kinds[li] == "moe":
             # the fused decode loop's kv carries a count of routed rows
             counted = getattr(kv, "moe_rows", None) is not None
             y, rows = _moe_mlp(p["moe"], h, model_cfg, dtype, cfg,
@@ -278,7 +327,7 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
                 kv = kv._replace(moe_rows=kv.moe_rows + rows)
             if getattr(model_cfg, "shared_expert_size", 0):
                 # always-on shared expert: behind a sigmoid scalar gate
-                # (qwen2-moe) or ungated (solar_open2)
+                # (qwen2-moe) or ungated (solar_open2, pangu_ultra_moe)
                 gate = woq_mm(h, p["shared_gate_proj"]["kernel"], dtype)
                 up = woq_mm(h, p["shared_up_proj"]["kernel"], dtype)
                 shared = woq_mm(jax.nn.silu(gate) * up,
@@ -289,14 +338,16 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
                          ).astype(jnp.float32))
                     shared = shared * sg.astype(dtype)
                 y = y + shared
-            x = x + y.astype(rdtype)
         else:
             pm = p["mlp"]
             gate = woq_mm(h, pm["gate_proj"]["kernel"], dtype)
             up = woq_mm(h, pm["up_proj"]["kernel"], dtype)
             m = jax.nn.silu(gate) * up
             m = woq_mm(m, pm["down_proj"]["kernel"], dtype)
-            x = x + tp_all_reduce(m, cfg).astype(rdtype)   # TP collective 2
+            y = tp_all_reduce(m, cfg)                     # TP collective 2
+        if sandwich:
+            y = _rms(y, p["mlp_branch_norm"]["scale"], model_cfg.rms_eps)
+        x = x + y.astype(rdtype)
 
     x = _rms(x, params["final_norm"]["scale"], model_cfg.rms_eps)
     last = jnp.maximum(batch.n_tokens - 1, 0)

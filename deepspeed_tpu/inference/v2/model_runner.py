@@ -632,6 +632,92 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
     return kv, y
 
 
+def latent_attention(kv, li, q, row, batch: "RaggedBatch",
+                     cfg: RaggedInferenceConfig, pos, valid_q, scale, dtype,
+                     latent: int):
+    """``paged_attention``'s sibling for a latent-attention layer: append
+    this step's ONE row a token to plane ``li`` of the one-plane cache,
+    then attend in the absorbed form.
+
+    q [S, C, H, W]: each head's absorbed query ``[q_nope W_UK^T ; q_rope ;
+    0]``; row [S, C, W]: ``[c_kv ; k_r ; 0]``, the stored row, key and
+    value at once (``latent`` lanes of it are the value). The same three
+    shapes of ``kv`` as ``paged_attention``: the pool, or the fused loop's
+    ``RingKV`` (the row then goes to ``ring[li, 0, :, t]`` and the pool is
+    read-only). On a TPU a pure-decode step runs
+    ``ops/kernels/mla_attention.py`` (each live tile fetched once for
+    both products) and a prefill chunk the BlockSpec paged kernel with
+    the one plane as its K and its V operand (the absorbed form at the
+    row's width: no cached row is expanded through ``W_kvb``); elsewhere
+    the context is gathered and masked. Returns (kv, o_lat [S, C, H,
+    latent] in ``dtype``): ``W_UV`` is the caller's."""
+    from ...ops.kernels import default_interpret
+    from ...ops.kernels.mla_attention import (mla_attention_reference,
+                                              mla_decode_attention)
+    S, C, H, W = q.shape
+    bs = cfg.block_size
+    impl = cfg.attention_impl
+    if impl == "auto":
+        impl = "paged_flash" if jax.default_backend() == "tpu" else "dense"
+    if impl not in ("paged_flash", "dense"):
+        raise ValueError(
+            f"attention_impl must be 'auto', 'paged_flash' or 'dense', "
+            f"got {cfg.attention_impl!r}")
+    ring_mode = isinstance(kv, RingKV)
+    if ring_mode:
+        ring, t, rcount = kv.ring, kv.t, kv.rcount
+        data, _ = pool_parts(kv.pool)
+        # the latent ring is SEQUENCE-major, [L, 1, S, R, W]: the decode
+        # kernel then takes a [R, W] slab a sequence
+        ring = ring.at[li, 0, :, t].set(row.reshape(S, W).astype(ring.dtype))
+        kv = kv._replace(ring=ring)
+        lens = jnp.where(batch.n_tokens > 0, batch.start_pos - t, 0)
+    else:
+        data, _ = pool_parts(kv)
+        trash = data.shape[2] - 1
+        blk = jnp.take_along_axis(
+            batch.block_tables,
+            jnp.minimum(pos // bs, cfg.max_blocks_per_seq - 1), axis=1)
+        widx = jnp.where(valid_q, blk * bs + pos % bs, trash).reshape(-1)
+        data = data.at[li, 0, widx].set(
+            row.reshape(S * C, W).astype(data.dtype))
+        kv = repack(kv, data, None)
+        ring = rcount = None
+        lens = jnp.where(batch.n_tokens > 0,
+                         batch.start_pos + batch.n_tokens, 0)
+
+    if impl == "paged_flash" and C == 1:
+        y = mla_decode_attention(
+            q[:, 0].astype(data.dtype), data, ring, batch.block_tables, lens,
+            rcount if ring_mode else jnp.zeros((), jnp.int32),
+            jnp.asarray([li, li], jnp.int32), block_size=bs, latent=latent,
+            sm_scale=float(scale), interpret=default_interpret())[:, None]
+        return kv, y.astype(dtype)
+    if impl == "paged_flash":
+        from ...ops.kernels import flash_paged_attention
+        y = flash_paged_attention(
+            q.astype(data.dtype), data[li, 0], data[li, 0],
+            batch.block_tables, batch.start_pos, lens, block_size=bs,
+            sm_scale=scale, num_kv_heads=1, pool_full=data, pool_layer=li)
+        return kv, y[..., :latent].astype(dtype)
+
+    T = cfg.max_context
+    j = jnp.arange(T, dtype=jnp.int32)
+    rows = data[li, 0][batch.block_tables[:, j // bs] * bs + j % bs]
+    mask = (j[None, None, :] <= pos[:, :, None]) \
+        & (j[None, None, :] < lens[:, None, None])
+    if ring_mode:
+        # columns [T, T + R): the loop's rows, live below rcount
+        R = ring.shape[3]
+        rows = jnp.concatenate([rows, ring[li, 0]], axis=1)
+        live = (jnp.arange(R, dtype=jnp.int32) < rcount)[None, None, :] \
+            & (batch.n_tokens > 0)[:, None, None]
+        mask = jnp.concatenate(
+            [mask, jnp.broadcast_to(live, (S, C, R))], axis=2)
+    return kv, mla_attention_reference(q.astype(dtype), rows.astype(dtype),
+                                       mask, latent, scale)
+
+
 def woq_mm(h, w, dtype):
     """``h @ w`` with WOQ-aware dispatch: a dense array multiplies
     directly; an ``Fp6GemmWeight`` goes through the fused Pallas GEMM
@@ -681,7 +767,18 @@ class RaggedRunnerBase:
         # softmax layer, a state row a sequence for each recurrent one
         kinds = getattr(model_cfg, "layer_kinds", None) \
             or ("attn",) * self.num_layers
-        self.kv_layers = sum(k == "attn" for k in kinds)
+        self.kv_layers = sum(k in ("attn", "mla") for k in kinds)
+        #: planes a layer keeps in the paged cache: K and V, or the ONE
+        #: plane of a latent-attention model, whose stored row
+        #: (``latent_row`` lanes; one "kv head") is key and value at once
+        self.kv_planes = 2
+        if "mla" in kinds:
+            if any(k != "mla" for k in kinds):
+                raise ValueError(
+                    "latent ('mla') layers do not mix with other layer "
+                    "kinds in one model: the cache has one kind of plane")
+            self.kv_planes = 1
+            self.kv_heads, self.head_dim = 1, model_cfg.latent_row
         #: what the state pool must hold (None: no recurrent layer)
         self.state_spec = None if self.kv_layers == len(kinds) else {
             "kind": "kda", "layers": len(kinds) - self.kv_layers,
@@ -920,10 +1017,14 @@ class RaggedRunnerBase:
             # rows are the loop's freshest tokens, rewritten every step,
             # and are quantized once at flush time. Under TP the ring —
             # like the pool — is head-sharded: local_kv_heads rows.
-            ring = jnp.zeros((n, self.kv_layers, 2, S,
-                              self.local_kv_heads * self.head_dim),
-                             pool_arr.dtype if pool_scales is None
-                             else dtype)
+            ring_shape = (n, self.kv_layers, 2, S,
+                          self.local_kv_heads * self.head_dim)
+            if self.kv_planes == 1:
+                # a latent cache's ring: one plane, sequence-major
+                # (latent_attention)
+                ring_shape = (self.kv_layers, 1, S, n, self.head_dim)
+            ring = jnp.zeros(ring_shape, pool_arr.dtype
+                             if pool_scales is None else dtype)
             use_eos = eos_id >= 0
             done0 = jnp.zeros((S,), jnp.bool_)
             # real rows routed to each expert, summed over the sparse
@@ -1026,7 +1127,28 @@ class RaggedRunnerBase:
         # (one block per sequence) gets per-sequence dynamic-update-slices
         # (contiguous runs, no scatter); general blocked layout falls back
         # to one scatter over all layers at once.
+        def _flush_latent(kv_data, ring, tables, start0, active):
+            """The latent pool's flush (ring [L, 1, S, R, W]): one scatter
+            a layer, each on a leading index like a step's own row write,
+            so the donated pool is updated in place. The scatter over all
+            layers at once that the K/V pools keep goes through two
+            whole-pool copies (PERF.md), and a latent pool is sized to
+            what the chip has left."""
+            L, _, S, R, W = ring.shape
+            bs = cfg.block_size
+            data, _ = pool_parts(kv_data)
+            pos = start0[:, None] + jnp.arange(R, dtype=jnp.int32)[None, :]
+            blk = jnp.take_along_axis(
+                tables, jnp.minimum(pos // bs, tables.shape[1] - 1), axis=1)
+            idx = jnp.where(active[:, None] > 0, blk * bs + pos % bs,
+                            data.shape[2] - 1).reshape(-1)
+            for l in range(L):
+                data = data.at[l, 0, idx].set(ring[l, 0].reshape(S * R, W))
+            return repack(kv_data, data, None)
+
         def _flush_ring(kv_data, ring, tables, start0, active):
+            if self.kv_planes == 1:
+                return _flush_latent(kv_data, ring, tables, start0, active)
             R, L, _, S, KVD = ring.shape
             bs = cfg.block_size
             data, scales = pool_parts(kv_data)

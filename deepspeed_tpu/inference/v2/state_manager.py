@@ -433,6 +433,7 @@ class StateManager:
         return {
             "kv_pool_bytes_total": self.kv_cache.memory_bytes(),
             "kv_pool_bytes_per_chip": self.kv_cache.memory_bytes_per_chip(),
+            "kv_bytes_per_token": self.kv_cache.kv_bytes_per_token(),
             "tp_size": max(1, int(getattr(self.cfg, "tp_size", 1))),
             "seq_size": max(1, int(getattr(self.cfg, "seq_size", 1))),
         }

@@ -34,6 +34,8 @@ from .phi import Phi, PhiConfig
 from .phi import make_model as make_phi
 from .solar_open2 import SolarOpen2, SolarOpen2Config
 from .solar_open2 import make_model as make_solar_open2
+from .pangu_ultra_moe import PanguUltraMoE, PanguUltraMoEConfig
+from .pangu_ultra_moe import make_model as make_pangu_ultra_moe
 
 
 class ArchEntry(NamedTuple):
@@ -388,6 +390,45 @@ def _entry_solar_open2(d):
         router_aux_loss_coef=0.0)
 
 
+def _entry_pangu_ultra_moe(d):
+    """openPangu-Ultra-MoE (FreedomIntelligence/openPangu-Ultra-MoE-718B):
+    latent attention in every layer, the first ``first_k_dense_replace``
+    layers dense and the others sparse (sigmoid router, one ungated shared
+    expert), sandwich norms, ``num_nextn_predict_layers`` MTP modules. The
+    cache keeps one latent row a token (``num_kv_heads`` 1, whatever
+    ``num_key_value_heads`` says of the expanded heads). Attention bias
+    and rope scaling are refused rather than guessed at."""
+    if d.get("attention_bias", False):
+        raise ValueError("pangu_ultra_moe configs with attention_bias set "
+                         "are not supported (the published one has none)")
+    if d.get("rope_scaling") is not None:
+        raise ValueError("pangu_ultra_moe configs with rope_scaling set are "
+                         "not supported (the published one has none)")
+    n = d.get("num_hidden_layers", 61)
+    k_dense = int(d.get("first_k_dense_replace", 3))
+    width = d.get("moe_intermediate_size", 2048)
+    base = _hf_llama(d, intermediate_size=width, num_kv_heads=1)
+    return PanguUltraMoEConfig(
+        **base,
+        q_lora_rank=d.get("q_lora_rank", 1536),
+        kv_lora_rank=d.get("kv_lora_rank", 512),
+        qk_nope_head_dim=d.get("qk_nope_head_dim", 128),
+        qk_rope_head_dim=d.get("qk_rope_head_dim", 64),
+        v_head_dim=d.get("v_head_dim", 128),
+        layer_kinds=("mla",) * n,
+        ffn_kinds=tuple("dense" if i < k_dense else "moe"
+                        for i in range(n)),
+        dense_intermediate_size=d.get("intermediate_size", 18432),
+        sandwich_norm=bool(d.get("sandwich_norm", True)),
+        num_experts=d.get("n_routed_experts", 256),
+        experts_top_k=d.get("num_experts_per_tok", 8),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        routed_scaling=float(d.get("routed_scaling_factor", 2.5)),
+        shared_expert_size=int(d.get("n_shared_experts", 1)) * width,
+        nextn_layers=int(d.get("num_nextn_predict_layers", 0)),
+        router_aux_loss_coef=0.0)
+
+
 ARCHITECTURES: Dict[str, ArchEntry] = {
     "gpt2": ArchEntry(GPT2Config, GPT2, make_gpt2, _entry_gpt2),
     "llama": ArchEntry(LlamaConfig, Llama, make_llama, _entry_llama),
@@ -411,6 +452,9 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
     "olmoe": ArchEntry(MixtralConfig, Mixtral, make_mixtral, _entry_olmoe),
     "solar_open2": ArchEntry(SolarOpen2Config, SolarOpen2,
                              make_solar_open2, _entry_solar_open2),
+    "pangu_ultra_moe": ArchEntry(PanguUltraMoEConfig, PanguUltraMoE,
+                                 make_pangu_ultra_moe,
+                                 _entry_pangu_ultra_moe),
     "gpt_neo": ArchEntry(GPTNeoConfig, GPTNeo, make_gpt_neo,
                          _entry_gpt_neo),
     "internlm": ArchEntry(LlamaConfig, Llama, make_llama, _entry_internlm),

@@ -724,7 +724,10 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     g = H // KV
     use_pool_full = pool_full is not None and pool_layer is not None
     if use_pool_full:
-        if pool_full.ndim != 4 or pool_full.shape[1:] != (2, slots, KVD) \
+        # one plane a layer: a latent cache, whose row is key and value
+        # at once (both operands then read plane 0)
+        if pool_full.ndim != 4 or pool_full.shape[1] not in (1, 2) \
+                or pool_full.shape[2:] != (slots, KVD) \
                 or pool_full.dtype != k_pool.dtype:
             raise ValueError(
                 f"pool_full must be {k_pool.dtype}[L, 2, {slots}, {KVD}], "
@@ -940,13 +943,15 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         # copy that plane out of the pool first: 2 L planes = the whole
         # pool read and written once per step
         li = int(pool_layer)
-        pool5 = pool_full.reshape(pool_full.shape[0], 2, nb_pool, pbs, KVD)
+        planes = pool_full.shape[1]
+        pool5 = pool_full.reshape(pool_full.shape[0], planes, nb_pool, pbs,
+                                  KVD)
         in_specs = [q_spec] + [
             pl.BlockSpec(
                 (None, None, 1, pbs, KVD),
                 lambda s, qc, j, *pref, x=x:
                     (li, x, _kv_block(s, qc, j, *pref), 0, 0))
-            for x in (0, 1)]
+            for x in (0, planes - 1)]
         operands = [qw, pool5, pool5]
     else:
         # direct callers that hold one layer's planes only

@@ -1,7 +1,7 @@
 """Placement of JAX's persistent compilation cache.
 
 Every entry point that compiles for the chip (``benchmark/run.py``,
-``chip_smoke.py``, ``tools/tpu_smoke.py``, ``tools/olmoe_chip_parity.py``)
+``chip_smoke.py``, ``tools/tpu_smoke.py``, ``tools/chip_parity.py``)
 calls :func:`enable_compile_cache` before its first jit. The cache directory is part of nothing the program decides at run time:
 ``JAX_COMPILATION_CACHE_DIR`` places it from outside (JAX reads the variable
 itself, so nothing is set here); without it the cache lives at one fixed

@@ -6,7 +6,7 @@ Tiny, on the CPU, float32 on both sides at ``highest`` precision: the
 flax model, the ragged engine (prefill in chunks, then the pipelined and
 the fused decode paths through the paged cache), the registry entry, the
 HF name map and the routed-row counter. The chip run at the published
-widths is ``tools/olmoe_chip_parity.py``.
+widths is ``tools/chip_parity.py --config olmoe-1b-7b``.
 """
 
 import dataclasses

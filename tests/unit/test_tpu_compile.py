@@ -219,3 +219,72 @@ def test_solar2_decode_loop_keeps_one_copy_of_the_state(one_chip,
     assert len(re.findall(
         r"%%grouped_ffn_decode[\w\-.]* = bf16\[%d,4096\]" % padded,
         hlo)) >= 4
+
+
+def test_pangu_decode_loop_and_flush_compile_over_the_latent_plane(
+        one_chip, monkeypatch):
+    """The fused decode loop and the flush of ``serve-pangu-rollout-long``
+    (128 slots, one 640-lane latent plane a layer, 256-token blocks, 128
+    steps a loop) at the published widths and one dense + one sparse
+    layer, from shapes alone: one latent decode kernel a layer (a Mosaic
+    call named ``mla_decode_attention``: Mosaic takes its DMAs and its
+    VMEM), one grouped expert kernel for the sparse layer, and a flush
+    that updates the donated one-plane pool IN PLACE: its temporaries stay
+    under a tenth of the pool (the scatter over all layers at once, which
+    the K/V pools keep, holds the pool twice more: 5.2 GB at this cell's
+    3.15 GB pool, PERF.md PR 34)."""
+    import dataclasses
+    import re
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.models.pangu_ultra_moe import (PanguUltraMoE,
+                                                      PanguUltraMoEConfig)
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, block, maxb, blocks, steps = 128, 256, 24, 1920, 128
+    mcfg = PanguUltraMoEConfig(
+        vocab_size=19200, max_seq_len=131072, num_layers=2, num_heads=128,
+        num_kv_heads=1, hidden_size=7680, intermediate_size=2048,
+        shared_expert_size=2048, num_experts=256, experts_top_k=8,
+        experts_held=8, layer_kinds=("mla", "mla"),
+        ffn_kinds=("dense", "moe"), rope_theta=25.6e6,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    icfg = RaggedInferenceConfig(
+        max_seqs=slots, chunk_size=512, block_size=block, num_blocks=blocks,
+        max_blocks_per_seq=maxb, decode_loop_steps=steps, dtype="bfloat16",
+        attention_impl="paged_flash")
+    runner = LlamaRaggedRunner(mcfg, icfg)
+    assert (runner.kv_planes, runner.kv_heads, runner.head_dim) == (1, 1, 640)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype), jax.eval_shape(
+            lambda k: PanguUltraMoE(mcfg).init(
+                k, jnp.zeros((1, 8), jnp.int32))["params"],
+            jax.random.PRNGKey(0)))
+    pool = spec((2, 1, (blocks + 1) * block, 640), jnp.bfloat16)
+    i32 = functools.partial(spec, dtype=jnp.int32)
+    f32 = functools.partial(spec, dtype=jnp.float32)
+    exe = runner._decode_loop_ring.trace(
+        params, pool, None, None, i32((slots,)), i32((slots,)),
+        i32((slots,)), i32((slots, maxb)), i32((1,)), f32((1,)), i32((1,)),
+        f32((1,)), i32((1, 1)), n=steps, mode="greedy", cand=1, eos_id=-1,
+        feed="self").lower(lowering_platforms=("tpu",)).compile()
+    hlo = exe.as_text()
+    assert len(re.findall(
+        r"%mla_decode_attention[\w\-.]* = bf16\[128,128,512\]", hlo)) >= 2
+    assert len(re.findall(
+        r"%grouped_ffn_decode[\w\-.]* = bf16\[1136,7680\]", hlo)) >= 1
+    # nothing of the pool's size is built beside it in the loop
+    pool_bytes = 2 * (blocks + 1) * block * 640 * 2
+    assert exe.memory_analysis().temp_size_in_bytes < pool_bytes // 2
+    ring = spec((2, 1, slots, steps, 640), jnp.bfloat16)
+    flush = runner._flush_ring.trace(
+        pool, ring, i32((slots, maxb)), i32((slots,)),
+        i32((slots,))).lower(lowering_platforms=("tpu",)).compile()
+    mem = flush.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 10

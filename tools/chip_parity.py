@@ -1,0 +1,359 @@
+"""A configuration of the benchmark at its published widths on the chip,
+against its plain reference; and what each limit of its cell's ``correct``
+catches.
+
+    chiprun -- python tools/chip_parity.py --config openpangu-ultra-moe-718b
+    python tools/chip_parity.py --config solar-open2-250b --rehearse   # CPU, toy
+
+Outside any timed window. The model type's hooks come from
+``benchmark/model_types/<model_type>.py`` (``model_config``,
+``init_params``, ``reference_logits``), the cache geometry from the
+configuration's cell. Three parts, each a JSON line (and all of them in
+``chiprun_out/chip_parity.<config>.json``):
+
+* ``engine``: the configuration served through ``InferenceEngineV2`` on
+  the cell's geometry: 4 seeded prompts whose contexts cross a block
+  boundary while decoding (``put``, several prefill chunks), 22
+  single-token steps through the cache, 8 steps of the fused
+  ``decode_batch`` loop, then single-token steps that read the rows it
+  flushed: 32 positions a sequence. The engine's LOGITS against the
+  reference's float32 forward over the whole sequence, in deviations of the
+  reference's row, and the served tokens by the cell's own rule.
+* ``hidden`` (``--hidden``): the residual stream after the first SPARSE
+  layer (leading dense layers included), where a wrong expert still
+  shows: an engine of those layers whose final norm has scale one and
+  whose head is the identity serves the RMS-normalised stream as its
+  "logits"; against the reference's, and against the reference with its
+  smallest expert left out (``top_k - 1``). The positions that control
+  moves beyond the tolerance are the ones where the comparison sees an
+  expert (all of them where a chip holds every expert, few where it holds
+  a share): there must be some, and the engine must agree with the full
+  reference AT them, or the part fails. (Where the reference's router
+  holds two experts within a rounding of each other a bfloat16 engine
+  picks the other one and the position reads tens of percent: PERF.md
+  section 6, PR 27.)
+* ``variants``: the reference against itself with ONE thing wrong at a
+  time (``VARIANTS``, by model type; always every weight matrix rounded to
+  float8 e4m3, the nearest precision below the bfloat16 the configurations
+  state), over as many sequences and positions as the cell's ``correct``
+  compares. Each wrong model "serves" its own best token at the compared
+  positions; reported is the cell's own pair of numbers. A wrong model must
+  fail one of the cell's limits.
+
+Tolerances for ``ok``: logits within LOGIT_TOL = 0.06 deviations of the
+row in the median position and 0.3, the figure the cells allow the served
+token, in the worst; the served tokens correct by the cell's rule and the
+float8 reference not; with ``--hidden``, HIDDEN_TOL = 3 % of the stream's
+length in the median position (bfloat16 rounds at 0.2 % a value).
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import functools
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+HIDDEN_TOL = 0.03
+LOGIT_TOL = 0.06
+LOGIT_TOL_WORST = 0.3
+POSITIONS = 32
+SINGLE_BEFORE, FUSED = 22, 8
+
+#: the reference's own keyword for each wrong model, by model type
+VARIANTS = {
+    "olmoe": {"one_expert_left_out": {"top_k": 7}},
+    "solar_open2": {
+        "bf16_state": {"state_dtype": "bfloat16"},
+        "no_l2_norm": {"l2_norm": False},
+        "beta_without_its_2": {"beta_scale": 1.0},
+        "softmax_router": {"router": "softmax"},
+        "rotary_left_on": {"rope_theta": 10000.0}},
+    "pangu_ultra_moe": {
+        "rope_left_off_the_shared_key": {"rope_on_key": False},
+        "rope_theta_1e4": {"rope_theta": 10000.0},
+        "scale_128": {"scale": 128 ** -0.5},
+        "latent_norm_left_out": {"latent_norm": False},
+        "branch_norms_left_out": {"sandwich": False},
+        "routed_scaling_1": {"routed_scaling": 1.0}},
+}
+
+
+def serve_rows(engine, prompts, vocab_rows=None):
+    """Per sequence: the [POSITIONS, width] rows ``put`` returned (the
+    last prompt position, then single-token steps before and after one
+    fused loop) and the tokens fed, teacher-forced on the served argmax."""
+    import numpy as np
+    uids = list(range(len(prompts)))
+    rows = {u: [] for u in uids}
+    streams = {u: list(p) for u, p in enumerate(prompts)}
+
+    def pick(u, row):
+        rows[u].append(np.asarray(row, np.float32))
+        return int(np.argmax(row[:vocab_rows]))
+
+    def single():
+        out = engine.put(uids, [[nxt[u]] for u in uids])
+        for u in uids:
+            streams[u].append(nxt[u])
+            nxt[u] = pick(u, out[u])
+
+    out = engine.put(uids, prompts)
+    nxt = {u: pick(u, out[u]) for u in uids}
+    for _ in range(SINGLE_BEFORE):
+        single()
+    fused = engine.decode_batch(uids, [nxt[u] for u in uids], FUSED)
+    for u in uids:
+        streams[u] += [nxt[u]] + [int(t) for t in fused[u][:-1]]
+        nxt[u] = int(fused[u][-1])
+    while len(rows[uids[0]]) < POSITIONS:
+        single()
+    return rows, streams
+
+
+def row_positions(prompt_len):
+    """Positions of ``serve_rows``'s rows in the served stream."""
+    first = [prompt_len - 1 + i for i in range(1 + SINGLE_BEFORE)]
+    start = first[-1] + FUSED + 1
+    return first + [start + i for i in range(POSITIONS - len(first))]
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", required=True,
+                    help="a configuration of BENCHMARK.json that a serve "
+                         "cell runs")
+    ap.add_argument("--seed", type=int, default=3400000731)
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--hidden", action="store_true")
+    ap.add_argument("--parts", default="engine,variants")
+    args = ap.parse_args(argv)
+    if args.rehearse:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    if args.rehearse:
+        jax.config.update("jax_platforms", "cpu")
+    elif jax.devices()[0].platform != "tpu":
+        print(f"needs a TPU; JAX found {jax.devices()}", file=sys.stderr)
+        return 3
+    from benchmark.common import load_json, load_manifest
+    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                            RaggedInferenceConfig)
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    if not args.rehearse:
+        enable_compile_cache()
+    # the configuration's serve cell (its last, where it has several) and
+    # the whole blocks of context that cell's shortest prompt fills: the
+    # prompts here cross the next boundary while decoding
+    entry = [w for w in load_manifest()["workloads"]
+             if w["config"] == args.config][-1]
+    cell_name = entry["name"]
+    dims = load_json("configs", args.config + ".json")
+    if args.rehearse:
+        dims.update(dims["rehearse"])
+    mt = importlib.import_module(
+        f"benchmark.model_types.{dims['model_type']}")
+    cfg = mt.model_config(dims)
+    if args.rehearse:
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+    params = mt.init_params(cfg, args.seed)
+    cell = load_json("cells", cell_name + ".json")
+    spec = cell["correct"]
+    block = cell["engine"]["block_size"]
+    whole_blocks = max(1, min(load_json(
+        "traffic", entry["traffic"] + ".json")["prompt_lens"]) // block)
+    if args.rehearse:
+        block = 64
+    edge = whole_blocks * block
+    lens = [edge - 30, edge - 20, edge - 10, edge + 5]
+    icfg = RaggedInferenceConfig(**dict(
+        cell["engine"], max_seqs=8, block_size=block,
+        max_blocks_per_seq=whole_blocks + 1,
+        num_blocks=4 * (whole_blocks + 1) + 2,
+        chunk_size=512 if not args.rehearse else 48, max_batch_tokens=0,
+        decode_loop_steps=FUSED,
+        dtype="bfloat16" if not args.rehearse else "float32"))
+    rs = np.random.RandomState(args.seed % (2 ** 31))
+    prompts = [list(map(int, rs.randint(1, cfg.vocab_size, n)))
+               for n in lens]
+    # the model type's reference with its fixed dimensions, open to one
+    # more keyword: a wrong model
+    inner = mt.reference_logits(cfg).__wrapped__
+    reference = importlib.import_module(inner.func.__module__)
+
+    def logits_fn(**wrong):
+        return jax.jit(functools.partial(
+            inner.func, **{**inner.keywords, **wrong}))
+
+    result = {"config": args.config, "cell": cell_name, "seed": args.seed,
+              "device": jax.devices()[0].device_kind, "prompt_lens": lens}
+    parts = set(args.parts.split(","))
+    ok = True
+
+    def padded(streams):
+        T = max(len(s) for s in streams.values())
+        toks = np.zeros((len(streams), T), np.int32)
+        for u, s in streams.items():
+            toks[u, :len(s)] = s        # right padding: causal, unseen
+        at = np.stack([row_positions(n) for n in lens]).astype(np.int32)
+        return jnp.asarray(toks), jnp.asarray(at)
+
+    def cell_rule(ref, tokens_served):
+        """The cell's ``correct`` rule on served tokens [B, n]."""
+        got = np.take_along_axis(ref, tokens_served[..., None], -1)[..., 0]
+        g = (ref.max(-1) - got) / ref.std(-1)
+        return {"worst_gap_sigma": float(g.max()),
+                "same_top1_share": float((g == 0).mean()),
+                "correct": bool(
+                    g.max() <= spec["tolerance_sigma"]
+                    and (g == 0).mean() >= spec["min_same_top1_share"])}
+
+    def float8(tree):
+        return jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.float8_e4m3fn).astype(x.dtype)
+            if x.ndim >= 2 else x, tree)
+
+    # ---- the hidden state after the first sparse layer ---- #
+    if args.hidden:
+        C = cfg.hidden_size
+        kinds = list(getattr(cfg, "ffn_kinds", None) or ["moe"])
+        n_lay = kinds.index("moe") + 1
+        one = dataclasses.replace(cfg, num_layers=n_lay, **{
+            k: getattr(cfg, k)[:n_lay] for k in ("layer_kinds", "ffn_kinds")
+            if getattr(cfg, k, None)})
+        p_one = {"embed": params["embed"],
+                 **{f"layer_{i}": params[f"layer_{i}"]
+                    for i in range(n_lay)},
+                 "final_norm": {"scale": jnp.ones((C,), jnp.float32)},
+                 "lm_head": {"kernel": jnp.eye(C, dtype=cfg.param_dtype)}}
+        eng = InferenceEngineV2(one, p_one, icfg)
+        rows, streams = serve_rows(eng, prompts, vocab_rows=C)
+        del eng
+        toks, at = padded(streams)
+        dims_kw = {k: v for k, v in inner.keywords.items()}
+
+        def normed_hidden(**wrong):
+            @jax.jit
+            def f(p, toks, at):
+                x = reference.hidden_states(p, toks, layers=n_lay,
+                                            **{**dims_kw, **wrong})
+                x = jnp.take_along_axis(x, at[..., None], axis=1)
+                return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True)
+                                         + cfg.rms_eps)
+            return np.asarray(f(params, toks, at), np.float32)
+
+        ref = normed_hidden()
+        less = normed_hidden(top_k=cfg.experts_top_k - 1) \
+            if "top_k" in dims_kw else None
+        served = np.stack([np.stack(rows[u]) for u in sorted(rows)])
+
+        def rel(a, b):
+            return np.linalg.norm(a - b, axis=-1) \
+                / np.linalg.norm(b, axis=-1)
+
+        err = rel(served, ref)
+        result["hidden"] = {
+            "layers": n_lay, "positions": int(err.size),
+            "tolerance": HIDDEN_TOL,
+            "served_rel_err_median": float(np.median(err)),
+            "served_rel_err_p90": float(np.percentile(err, 90)),
+            "served_rel_err_max": float(err.max()),
+            "positions_over_tolerance": int((err > HIDDEN_TOL).sum())}
+        ok_hidden = np.median(err) <= HIDDEN_TOL
+        if less is not None:
+            # where leaving an expert out shows, the engine must not
+            miss = rel(less, ref)
+            seen = miss > HIDDEN_TOL
+            result["hidden"].update(
+                one_expert_left_out_rel_err_median=float(np.median(miss)),
+                positions_where_it_shows=int(seen.sum()),
+                left_out_rel_err_median_there=float(
+                    np.median(miss[seen])) if seen.any() else None,
+                served_rel_err_median_there=float(
+                    np.median(err[seen])) if seen.any() else None)
+            ok_hidden = ok_hidden and seen.any() \
+                and np.median(err[seen]) <= HIDDEN_TOL
+        print(json.dumps({"hidden": result["hidden"]}), flush=True)
+        ok = ok and bool(ok_hidden)
+
+    # ---- the engine's logits against the reference's ---- #
+    if "engine" in parts:
+        eng = InferenceEngineV2(cfg, params, icfg)
+        rows, streams = serve_rows(eng, prompts)
+        stats = {k: v for k, v in eng.pipeline_stats.items()
+                 if k.startswith(("latent_", "mla_", "decode_kv_rows"))}
+        del eng
+        toks, at = padded(streams)
+        ref = np.asarray(logits_fn()(params, toks, at), np.float32)
+        served = np.stack([np.stack(rows[u]) for u in sorted(rows)])
+        sigma = ref.std(-1)
+        err = np.abs(served - ref).max(-1) / sigma
+        low = np.asarray(logits_fn()(float8(params), toks, at), np.float32)
+        result["engine"] = {
+            "positions": int(err.size),
+            "logit_err_sigma_median": float(np.median(err)),
+            "logit_err_sigma_worst": float(err.max()),
+            "logit_err_sigma_by_row": [float(np.median(err[:, t]))
+                                       for t in (0, 1, 22, 23, 31)],
+            "same_top1_share": float(
+                (served.argmax(-1) == ref.argmax(-1)).mean()),
+            "served_by_the_cells_rule": cell_rule(ref, served.argmax(-1)),
+            "float8_reference_by_the_cells_rule":
+                cell_rule(ref, low.argmax(-1)),
+            "counters": stats,
+            "tolerance_sigma_median": LOGIT_TOL,
+            "tolerance_sigma_worst": LOGIT_TOL_WORST}
+        print(json.dumps({"engine": result["engine"]}), flush=True)
+        e = result["engine"]
+        ok = ok and bool(
+            np.median(err) <= LOGIT_TOL and err.max() <= LOGIT_TOL_WORST
+            and e["served_by_the_cells_rule"]["correct"]
+            and not e["float8_reference_by_the_cells_rule"]["correct"])
+
+    # ---- the reference with one thing wrong ---- #
+    if "variants" in parts:
+        n, B = int(spec["tokens"]), int(spec["sequences"])
+        if args.rehearse:
+            n = min(n, POSITIONS)
+        T = (edge if whole_blocks > 1 else 128) + n
+        toks = jnp.asarray(rs.randint(0, cfg.vocab_size, (B, T)), jnp.int32)
+        at = jnp.asarray(np.tile(np.arange(T - n, T), (B, 1)), jnp.int32)
+        base = np.asarray(logits_fn()(params, toks, at), np.float32)
+        result["variants_positions"] = [B, n]
+        result["variants"] = {}
+        wrongs = dict(VARIANTS.get(dims["model_type"], {}),
+                      float8_weights={"weights": "float8_e4m3fn"})
+        for name, wrong in wrongs.items():
+            wrong, tree = dict(wrong), params
+            if wrong.pop("weights", None):
+                tree = float8(params)
+            if "state_dtype" in wrong:
+                wrong["state_dtype"] = jnp.bfloat16
+            lg = np.asarray(logits_fn(**wrong)(tree, toks, at), np.float32)
+            result["variants"][name] = dict(
+                cell_rule(base, lg.argmax(-1)),
+                logit_err_sigma_median=float(np.median(
+                    np.abs(lg - base).max(-1) / base.std(-1))))
+            print(json.dumps({"variant": name,
+                              **result["variants"][name]}), flush=True)
+    result["ok"] = bool(ok)
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, f"chip_parity.{args.config}.json"),
+              "w") as f:
+        json.dump(result, f, indent=1)
+    print(json.dumps({"ok": result["ok"]}), flush=True)
+    return 0 if result["ok"] or args.rehearse else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
