@@ -444,7 +444,6 @@ def serve_program_calls(engine, programs: Tuple[str, ...] = _SERVE_PROGRAMS
     ones_s = jnp.ones((S,), jnp.int32)
     ones_f = jnp.ones((S,), jnp.float32)
     n = max(2, int(cfg.decode_loop_steps) or 2)
-    n = min(n, cfg.block_size)     # linear-layout flush bound (R <= bs)
     samp_dummies = (jnp.zeros((1,), jnp.int32),
                     jnp.zeros((1,), jnp.float32),
                     jnp.zeros((1,), jnp.int32),

@@ -43,6 +43,7 @@ from .config import RaggedInferenceConfig
 from .drain import (EngineDrainingError, ReplayJournal, ServeDrainError,
                     ServeStepError, build_manifest, write_manifest)
 from .kv_cache import BlockedKVCache
+from .kv_write import runs_issued
 from .model_runner import GPT2RaggedRunner, RaggedBatch
 from .sampling import SamplingParams, stage_slot
 from .scheduler import SplitFuseScheduler
@@ -332,6 +333,12 @@ class InferenceEngineV2:
             "prefill_rows": 0, "decode_slots_live": 0,
             "decode_slots_planned": 0,
             "decode_kv_rows_live": 0, "decode_kv_rows_fetched": 0,
+            # rows stored into the paged pool, a layer's worth (real
+            # positions of every step that writes it, ring rows of every
+            # flush), and the contiguous windows the writer issued for
+            # them (kv_write.runs_issued; trash windows not counted):
+            # rows / runs is what a run carries, 1.0 for one-token steps
+            "kv_write_rows": 0, "kv_write_runs": 0,
             "moe_rows_routed": 0, "moe_rows_hottest": 0,
             # routed rows whose expert another chip holds (a model told
             # it holds a share of each layer's experts), from the fused
@@ -1158,6 +1165,14 @@ class InferenceEngineV2:
         return {"latent_rows_live": live, "latent_rows_fetched": fetched,
                 "latent_bytes_live": live * self._latent_token_bytes}
 
+    def _kv_write_counts(self, stores, n: int) -> Dict[str, int]:
+        """The pool writer's counters for ``stores``, (first position, real
+        rows) pairs of a program that holds ``n`` positions a sequence."""
+        return {"kv_write_rows": sum(count for _, count in stores),
+                "kv_write_runs": sum(
+                    runs_issued(start, count, n, self.config.block_size)
+                    for start, count in stores)}
+
     def pause(self, uid: int) -> None:
         """Evict a sequence's KV blocks to host memory and free them — the
         pool can then be oversubscribed by other sequences. Reference:
@@ -1546,6 +1561,11 @@ class InferenceEngineV2:
                         else int(eos_token_id),
                         state_slots=None if sslots is None
                         else jax.numpy.asarray(sslots), **samp)
+            # the flush stores all n ring rows of every live slot: counted
+            # while the loop runs, not between its readback and the next call
+            for key, val in self._kv_write_counts(
+                    [(seq.seen_tokens, n) for seq in seqs], n).items():
+                self.pipeline_stats[key] += val
             with spans.span("serve/fused_readback", steps=n, seqs=live):
                 # one wait for all of it. lps is None for a greedy loop,
                 # consumed when EOS is disabled (every slot fed all n),
@@ -1758,6 +1778,8 @@ class InferenceEngineV2:
             real = sum(len(item.tokens) for item in sched)
             span.set(step=self._step_counter, seqs=len(sched), S=S, T=C,
                      real=real)
+            span.count(**self._kv_write_counts(
+                [(item.start_pos, len(item.tokens)) for item in sched], C))
             if C > 1:
                 span.count(prefill_tokens_real=real,
                            prefill_tokens_planned=S * C, prefill_steps=1,
@@ -2296,14 +2318,23 @@ class InferenceEngineV2:
                 if n_draft:
                     draft_arr[i, 1:] = row
             with spans.span("serve/fused_dispatch", steps=L, seqs=len(ready)):
+                # the ring's flush runs on after the readback below has
+                # returned and reads start / active / tables when it
+                # does: on the CPU backend jnp.asarray may alias numpy
+                # memory (zero-copy, by the buffer's alignment), and the
+                # next round refills these buffers, so the flush would
+                # store to blocks of that round. It gets copies.
                 toks, _, self._kv_data, _, _ = self.runner.decode_loop(
                     self.params, self._kv_data, jnp.asarray(tok0),
-                    jnp.asarray(start), jnp.asarray(active),
-                    jnp.asarray(tables), L,
+                    jnp.asarray(start.copy()), jnp.asarray(active.copy()),
+                    jnp.asarray(tables.copy()), L,
                     draft_toks=jnp.asarray(draft_arr), eos_id=-1)
             with spans.span("serve/fused_readback", steps=L, seqs=len(ready)):
                 toks = np.asarray(toks)
-            with spans.span("serve/fused_apply", steps=L, seqs=len(ready)):
+            with spans.span("serve/fused_apply", steps=L, seqs=len(ready)) \
+                    as span:
+                span.count(**self._kv_write_counts(
+                    [(seqs[u].seen_tokens, L) for u in ready], L))
                 self.kv_cache.finalize_demotions()
                 self._step_counter += L
                 now = time.monotonic() if obs is not None else 0.0

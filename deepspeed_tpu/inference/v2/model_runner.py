@@ -10,7 +10,7 @@ logits for each slot's last scheduled token only (the reference's
 
 Shapes are compile-time constant: ``[max_seqs, chunk_size]`` queries against
 ``[max_seqs, max_context]`` gathered KV. Padded query positions scatter into
-a dedicated trash slot (the last cache row) so they can never corrupt live
+the trash block (the pool's last) so they can never corrupt live
 sequences' KV.
 """
 
@@ -27,7 +27,8 @@ from ...models.gpt2 import GPT2Config
 from ...parallel.tp_rules import MODEL_AXIS
 from ...utils.jax_compat import manual_axes, shard_map
 from .config import RaggedInferenceConfig
-from .kv_quant import KVPool, RingKV, pool_parts, quantize_rows, repack
+from .kv_quant import KVPool, RingKV, pool_parts
+from .kv_write import store_rows, write_plan
 from .sampling import SAMPLE_CANDIDATES
 from .seq_parallel import (SEQ_AXIS, combine_decode_stats, ring_all_gather,
                            seq_axis_active)
@@ -94,6 +95,21 @@ class RaggedBatch(NamedTuple):
     # [S] int32 — each row's row of the recurrent state pool (idle rows:
     # the pool's last, idle row); None for a model without recurrent layers
     state_slots: Any = None
+    # where this step's rows go in the pool (kv_write.WritePlan): the step
+    # programs make it once for all layers; None (a mixer called with a
+    # bare batch, as the parity tests do) = made at each store
+    write_plan: Any = None
+
+
+def _store_step_rows(kv, li, rows, batch, cfg, kv_heads=1, shard=None):
+    """This step's fresh ``rows`` [P, S, C, W] into layer ``li`` of the
+    pool: the one writer (kv_write.py), at the batch's plan."""
+    plan = batch.write_plan
+    if plan is None:
+        plan = write_plan(batch.start_pos, batch.n_tokens,
+                          batch.block_tables, rows.shape[2], cfg.block_size,
+                          pool_parts(kv)[0].shape, shard)
+    return store_rows(kv, li, rows, plan, kv_heads)
 
 
 # --------------------------------------------------------------------- #
@@ -305,10 +321,11 @@ def _seq_paged_attention(kv, li, q, k, v, batch, cfg, pos, scale, dtype,
 
       1. fresh-KV exchange — ONE packed all-gather of ``[k|v]`` in the
          compute dtype reassembles the whole chunk's K/V on every chip;
-         each chip then scatters ONLY the rows it owns (``blk % sz ==
-         r``) into its local shard, everything else to its local trash
-         row. Over an int8 pool every chip quantizes the full chunk
-         identically, so pool bytes are bit-identical to seq=1's.
+         each chip then stores ONLY the windows whose block it owns
+         (``blk % sz == r``) into its local shard, everything else to its
+         local trash block (kv_write.py). Over an int8 pool every chip
+         quantizes the full chunk identically, so pool bytes are
+         bit-identical to seq=1's.
       2. full-context reconstruction — each chip gathers its local slab
          and a ring of ``sz - 1`` ppermute hops (two per hop over int8:
          data + scale planes) stacks every shard by origin; a static
@@ -326,40 +343,20 @@ def _seq_paged_attention(kv, li, q, k, v, batch, cfg, pos, scale, dtype,
     sz = jax.lax.axis_size(SEQ_AXIS)
     r = jax.lax.axis_index(SEQ_AXIS)
     C = C_loc * sz
-    data, scales = pool_parts(kv)
     # the step wrapper shifted start/n by r*C_loc; undo for global views
     n_g = batch.n_tokens + r * C_loc
     start_g = batch.start_pos - r * C_loc
-    # ---- 1. fresh-KV exchange + ownership-masked scatter ----
+    # ---- 1. fresh-KV exchange + ownership-masked store ----
     fresh = jnp.concatenate([k.reshape(S, C_loc, KV * D),
                              v.reshape(S, C_loc, KV * D)], axis=-1)
     allf = jax.lax.all_gather(fresh, SEQ_AXIS)     # [sz, S, C_loc, 2KVD]
     allf = jnp.moveaxis(allf, 0, 1).reshape(S, C, 2 * KV * D)
     k_all = allf[..., :KV * D]
     v_all = allf[..., KV * D:]
-    jc = jnp.arange(C, dtype=jnp.int32)
-    pos_all = start_g[:, None] + jc[None, :]
-    valid_all = jc[None, :] < n_g[:, None]
-    blk = jnp.take_along_axis(
-        batch.block_tables,
-        jnp.minimum(pos_all // bs, cfg.max_blocks_per_seq - 1), axis=1)
-    own = (blk % sz) == r
-    trash = data.shape[2] - 1                      # LOCAL trash row
-    widx = jnp.where(valid_all & own, (blk // sz) * bs + pos_all % bs,
-                     trash).reshape(-1)
-    if scales is None:
-        data = data.at[li, 0, widx].set(
-            k_all.reshape(S * C, KV * D).astype(data.dtype))
-        data = data.at[li, 1, widx].set(
-            v_all.reshape(S * C, KV * D).astype(data.dtype))
-    else:
-        qk, sk = quantize_rows(k_all.reshape(S * C, KV * D), KV)
-        qv, sv = quantize_rows(v_all.reshape(S * C, KV * D), KV)
-        data = data.at[li, 0, widx].set(qk)
-        data = data.at[li, 1, widx].set(qv)
-        scales = scales.at[li, 0, :, widx].set(sk.T)
-        scales = scales.at[li, 1, :, widx].set(sv.T)
-    kv = repack(kv, data, scales)
+    kv = _store_step_rows(
+        kv, li, jnp.stack([k_all, v_all]),
+        batch._replace(start_pos=start_g, n_tokens=n_g), cfg, KV, (sz, r))
+    data, scales = pool_parts(kv)
     # ---- 2. ring reconstruction of the full context ----
     nb_loc = cfg.max_blocks_per_seq // sz
     T = nb_loc * sz * bs
@@ -571,29 +568,10 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
                                     scale, dtype, alibi_slopes,
                                     sliding_window)
 
+    kv = _store_step_rows(
+        kv, li, jnp.stack([k.reshape(S, C, KV * D), v.reshape(S, C, KV * D)]),
+        batch, cfg, KV)
     data, scales = pool_parts(kv)
-    trash = data.shape[2] - 1
-    blk = jnp.take_along_axis(
-        batch.block_tables,
-        jnp.minimum(pos // bs, cfg.max_blocks_per_seq - 1), axis=1)
-    write_idx = jnp.where(valid_q, blk * bs + pos % bs, trash)
-    widx = write_idx.reshape(-1)
-    if scales is None:
-        data = data.at[li, 0, widx].set(
-            k.reshape(S * C, KV * D).astype(data.dtype))
-        data = data.at[li, 1, widx].set(
-            v.reshape(S * C, KV * D).astype(data.dtype))
-    else:
-        qk, sk = quantize_rows(k.reshape(S * C, KV * D), KV)
-        qv, sv = quantize_rows(v.reshape(S * C, KV * D), KV)
-        data = data.at[li, 0, widx].set(qk)
-        data = data.at[li, 1, widx].set(qv)
-        # NumPy advanced-indexing: the (li, 0, widx) advanced indices are
-        # separated by the ':' slice, so the indexed dims move FIRST —
-        # the update value is [N, KV], i.e. the scales untransposed
-        scales = scales.at[li, 0, :, widx].set(sk.T)
-        scales = scales.at[li, 1, :, widx].set(sv.T)
-    kv = repack(kv, data, scales)
 
     if impl == "paged_flash":
         from ...ops.kernels import flash_paged_attention
@@ -605,7 +583,7 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
         # this kernel exists to avoid. The kernel's K and V operand is
         # pool_full, decode and prefill alike: data[li, x] gives shape and
         # dtype only (dead under jit), so no plane is sliced out of the
-        # pool that the scatter above just updated in place.
+        # pool that the store above just updated in place.
         # Over an int8 pool q stays in the compute dtype; the kernel
         # scales scores/probabilities by the side-array scales.
         y = flash_paged_attention(
@@ -673,15 +651,8 @@ def latent_attention(kv, li, q, row, batch: "RaggedBatch",
         kv = kv._replace(ring=ring)
         lens = jnp.where(batch.n_tokens > 0, batch.start_pos - t, 0)
     else:
+        kv = _store_step_rows(kv, li, row.reshape(1, S, C, W), batch, cfg)
         data, _ = pool_parts(kv)
-        trash = data.shape[2] - 1
-        blk = jnp.take_along_axis(
-            batch.block_tables,
-            jnp.minimum(pos // bs, cfg.max_blocks_per_seq - 1), axis=1)
-        widx = jnp.where(valid_q, blk * bs + pos % bs, trash).reshape(-1)
-        data = data.at[li, 0, widx].set(
-            row.reshape(S * C, W).astype(data.dtype))
-        kv = repack(kv, data, None)
         ring = rcount = None
         lens = jnp.where(batch.n_tokens > 0,
                          batch.start_pos + batch.n_tokens, 0)
@@ -883,6 +854,7 @@ class RaggedRunnerBase:
             batch_spec = RaggedBatch(P(), P(), P(), P())
 
         def _step(params, kv_data, batch):
+            shard = None
             if seqc is not None:
                 # context-parallel prefill: chip r takes query slice
                 # [r*C/sz, (r+1)*C/sz) — start/n shift so the slice's
@@ -903,6 +875,13 @@ class RaggedRunnerBase:
                 r = jax.lax.axis_index(SEQ_AXIS)
                 c_loc = batch.tokens.shape[1] // seqc.seq_size
                 gbatch = batch
+                shard = (seqc.seq_size, r)
+            # where the step's rows go in the pool: once, for every layer
+            batch = batch._replace(write_plan=write_plan(
+                batch.start_pos, batch.n_tokens, batch.block_tables,
+                batch.tokens.shape[1], cfg.block_size,
+                pool_parts(kv_data)[0].shape, shard))
+            if seqc is not None:
                 batch = batch._replace(
                     tokens=jax.lax.dynamic_slice_in_dim(
                         batch.tokens, r * c_loc, c_loc, 1),
@@ -1123,99 +1102,37 @@ class RaggedRunnerBase:
             _decode_loop_ring, donate_argnames=("lin",),
             static_argnames=("n", "mode", "cand", "eos_id", "feed"))
 
-        # flush: write the loop's ring rows into the pool. Linear layout
-        # (one block per sequence) gets per-sequence dynamic-update-slices
-        # (contiguous runs, no scatter); general blocked layout falls back
-        # to one scatter over all layers at once.
-        def _flush_latent(kv_data, ring, tables, start0, active):
-            """The latent pool's flush (ring [L, 1, S, R, W]): one scatter
-            a layer, each on a leading index like a step's own row write,
-            so the donated pool is updated in place. The scatter over all
-            layers at once that the K/V pools keep goes through two
-            whole-pool copies (PERF.md), and a latent pool is sized to
-            what the chip has left."""
-            L, _, S, R, W = ring.shape
-            bs = cfg.block_size
-            data, _ = pool_parts(kv_data)
-            pos = start0[:, None] + jnp.arange(R, dtype=jnp.int32)[None, :]
-            blk = jnp.take_along_axis(
-                tables, jnp.minimum(pos // bs, tables.shape[1] - 1), axis=1)
-            idx = jnp.where(active[:, None] > 0, blk * bs + pos % bs,
-                            data.shape[2] - 1).reshape(-1)
-            for l in range(L):
-                data = data.at[l, 0, idx].set(ring[l, 0].reshape(S * R, W))
-            return repack(kv_data, data, None)
-
         def _flush_ring(kv_data, ring, tables, start0, active):
-            if self.kv_planes == 1:
-                return _flush_latent(kv_data, ring, tables, start0, active)
-            R, L, _, S, KVD = ring.shape
-            bs = cfg.block_size
+            """The loop's ring rows into the pool, through the one writer
+            (kv_write.py): a sequence's R rows are R consecutive positions
+            from ``start0``, whole windows whatever the layout (one block a
+            sequence or many), stored in place a layer at a time; over an
+            int8 pool they are quantized here, once (the ring itself runs
+            unquantized). Under ``seq`` every chip holds the SAME ring rows
+            (the loop is replicated) and stores the windows whose block it
+            owns: zero collectives, pool bytes as at seq=1."""
             data, scales = pool_parts(kv_data)
-            slots = data.shape[2]
-            trash_off = slots - bs                     # trash block start
-            ring_sl = jnp.moveaxis(ring, 0, 3)         # [L, 2, S, R, KVD]
-            if scales is not None:
-                # quantize the loop's rows once, at flush (the ring itself
-                # runs unquantized): per-(token, kv-head) symmetric int8
-                KV = scales.shape[2]
-                q_rows, sc_kv = quantize_rows(
-                    ring_sl.reshape(L * 2 * S * R, KVD), KV)
-                ring_rows = q_rows.reshape(L, 2, S, R, KVD)
-                # scales come back transposed [KV, N]; re-lay to the
-                # pool's [L, 2, KV, <slots window>] ordering
-                sc_t = sc_kv.T.reshape(L, 2, S, R, KV)
-                sc_t = jnp.moveaxis(sc_t, 4, 2)        # [L, 2, KV, S, R]
-            else:
-                ring_rows = ring_sl
-                sc_t = None
-            if cfg.max_blocks_per_seq == 1:
-                # the inactive-slot path parks rows at slots - bs; with
-                # R > bs the DUS start would clamp and overwrite the tail
-                # of the last real block (currently only reachable for an
-                # all-inactive batch, but nothing upstream enforces it)
-                assert R <= bs, (
-                    f"decode_loop_steps ({R}) must be <= block_size ({bs}) "
-                    f"on the linear (one-block-per-seq) layout")
-                for i in range(S):
-                    off = jnp.where(active[i] > 0,
-                                    tables[i, 0] * bs + start0[i],
-                                    trash_off)
-                    data = jax.lax.dynamic_update_slice(
-                        data, ring_rows[:, :, i], (0, 0, off, 0))
-                    if sc_t is not None:
-                        scales = jax.lax.dynamic_update_slice(
-                            scales, sc_t[:, :, :, i], (0, 0, 0, off))
-                return repack(kv_data, data, scales)
-            pos = start0[:, None] + jnp.arange(R, dtype=jnp.int32)[None, :]
-            blk = jnp.take_along_axis(
-                tables, jnp.minimum(pos // bs, tables.shape[1] - 1), axis=1)
-            if seqc is not None:
-                # seq-sharded flush: every chip quantized/laid out the
-                # SAME ring rows (the loop is replicated); each scatters
-                # only the rows whose block it owns, the rest to its
-                # local trash row — zero collectives, pool bytes
-                # bit-identical to the seq=1 scatter
-                r_ax = jax.lax.axis_index(SEQ_AXIS)
-                szz = seqc.seq_size
-                ok = (active[:, None] > 0) & ((blk % szz) == r_ax)
-                idx = jnp.where(ok, (blk // szz) * bs + pos % bs,
-                                slots - 1)
-            else:
-                idx = jnp.where(active[:, None] > 0, blk * bs + pos % bs,
-                                slots - 1)
-            data = data.at[:, :, idx.reshape(-1)].set(
-                ring_rows.reshape(L, 2, S * R, KVD))
-            if sc_t is not None:
-                scales = scales.at[:, :, :, idx.reshape(-1)].set(
-                    sc_t.reshape(L, 2, KV, S * R))
-            return repack(kv_data, data, scales)
+            latent = self.kv_planes == 1       # ring [L, 1, S, R, W]
+            R = ring.shape[3 if latent else 0]     # else [R, L, 2, S, W]
+            plan = write_plan(
+                start0, jnp.where(active > 0, R, 0), tables, R,
+                cfg.block_size, data.shape, None if seqc is None else
+                (seqc.seq_size, jax.lax.axis_index(SEQ_AXIS)))
+            kv_heads = 1 if scales is None else scales.shape[2]
+
+            def layer(l, kv):
+                rows = jax.lax.dynamic_index_in_dim(
+                    ring, l, 0 if latent else 1, keepdims=False)
+                if not latent:
+                    rows = jnp.transpose(rows, (1, 2, 0, 3))   # [2, S, R, W]
+                return store_rows(kv, l, rows, plan, kv_heads)
+
+            return jax.lax.fori_loop(0, data.shape[0], layer, kv_data)
 
         if mapped:
             # all flush work is chip-local (quantize_rows is per-kv-head,
-            # scatter indices live on the slots dim; under seq the
-            # ownership mask keeps foreign blocks in the trash row):
-            # zero collectives
+            # the windows live on the slots dim; under seq the ownership
+            # mask keeps foreign blocks in the trash block)
             _flush_ring = self._wrap(_flush_ring,
                                      (pool_spec, ring_spec, P(), P(), P()),
                                      pool_spec)

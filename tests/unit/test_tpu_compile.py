@@ -288,3 +288,90 @@ def test_pangu_decode_loop_and_flush_compile_over_the_latent_plane(
     mem = flush.memory_analysis()
     assert mem.alias_size_in_bytes == pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 10
+
+
+#: serve-offline-rollout's pool (benchmark/cells): 260 + 1 blocks of 640
+#: tokens, two a sequence, rows of 2 kv heads x 128; 128 clients, a
+#: 64-step ring
+ROLLOUT_BLOCK, ROLLOUT_ROW = 640, 256
+
+
+def _rollout_runner(layers, clients):
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.models.llama import LlamaConfig
+    mcfg = LlamaConfig.tiny(
+        hidden_size=512, num_heads=4, num_kv_heads=2, num_layers=layers,
+        intermediate_size=1024, max_seq_len=2048, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, attention_impl="xla")
+    return mcfg, LlamaRaggedRunner(mcfg, RaggedInferenceConfig(
+        max_seqs=clients, chunk_size=512, block_size=ROLLOUT_BLOCK,
+        num_blocks=2 * clients + 4, max_blocks_per_seq=2, dtype="bfloat16",
+        decode_loop_steps=64, attention_impl="paged_flash"))
+
+
+def _flush_at(runner, layers, clients, one_chip):
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    slots = (2 * clients + 5) * ROLLOUT_BLOCK
+    pool = spec((layers, 2, slots, ROLLOUT_ROW), jnp.bfloat16)
+    ring = spec((64, layers, 2, clients, ROLLOUT_ROW), jnp.bfloat16)
+    exe = runner._flush_ring.trace(
+        pool, ring, spec((clients, 2)), spec((clients,)),
+        spec((clients,))).lower(lowering_platforms=("tpu",)).compile()
+    return exe, layers * 2 * slots * ROLLOUT_ROW * 2
+
+
+def test_rollout_prefill_step_and_flush_store_rows_in_place(one_chip,
+                                                            monkeypatch):
+    """A [4, 512] prefill step and the ring's flush at
+    ``serve-offline-rollout``'s pool geometry (two layers), from shapes
+    alone: the donated pool comes back aliased, nothing of a pool plane's
+    size is kept beside it and no operation copies the pool (the flush's
+    scatter over all layers at once held it twice more: 0.0287 s a round
+    and the refusal at 256 clients, PERF.md PR 36)."""
+    import re
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
+    from deepspeed_tpu.models.llama import Llama
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layers, clients = 2, 128
+    mcfg, runner = _rollout_runner(layers, clients)
+    flush, pool_bytes = _flush_at(runner, layers, clients, one_chip)
+    plane_bytes = pool_bytes // (layers * 2)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype), jax.eval_shape(
+            lambda k: Llama(mcfg).init(
+                k, jnp.zeros((1, 8), jnp.int32))["params"],
+            jax.random.PRNGKey(0)))
+    pool = spec((layers, 2, pool_bytes // (layers * 2 * ROLLOUT_ROW * 2),
+                 ROLLOUT_ROW), jnp.bfloat16)
+    step = runner._step_greedy.trace(params, pool, RaggedBatch(
+        spec((4, 512)), spec((4,)), spec((4,)), spec((4, 2)))).lower(
+            lowering_platforms=("tpu",)).compile()
+    for name, exe in (("flush", flush), ("prefill step", step)):
+        mem = exe.memory_analysis()
+        assert mem.alias_size_in_bytes >= pool_bytes, name
+        assert mem.temp_size_in_bytes < plane_bytes, name
+        copies = re.findall(r"= bf16\[%d,2,\d+,(?:\d+,)?%d\]\S* copy\("
+                            % (layers, ROLLOUT_ROW), exe.as_text())
+        assert not copies, f"{name}: the pool is copied: {copies[:2]}"
+
+
+def test_flush_compiles_at_256_clients(one_chip):
+    """The flush ISSUE 24's 256-client cell was halved for: a 9.4 GB pool
+    (28 layers) and its 0.47 GB ring. The all-layers scatter was refused
+    there ("Used 18.55G of 15.75G hbm"); the writer's flush compiles, the
+    pool aliased and no temporary of a plane's size."""
+    layers, clients = 28, 256
+    _, runner = _rollout_runner(layers, clients)
+    flush, pool_bytes = _flush_at(runner, layers, clients, one_chip)
+    assert pool_bytes > 9.4e9
+    mem = flush.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // (layers * 2)
