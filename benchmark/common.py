@@ -140,7 +140,12 @@ class Ctx:
 
     @contextlib.contextmanager
     def traced_window(self) -> Iterator[None]:
-        """Profile what runs inside, under the span ``window``."""
+        """Profile what runs inside, under the span ``window`` (a
+        rehearsal walks it with no profiler: the CPU has no device
+        plane to read)."""
+        if self.rehearse:
+            yield
+            return
         import jax
         shutil.rmtree(self.trace_dir, ignore_errors=True)
         jax.profiler.start_trace(self.trace_dir)
@@ -158,6 +163,15 @@ class Ctx:
             stats = d.memory_stats() or {}
             peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
         self.memory_peak_bytes = max(self.memory_peak_bytes, peak)
+
+
+def counters_delta(now: Dict[str, Any], then: Dict[str, Any]
+                   ) -> Dict[str, float]:
+    """What an engine's own totals (``pipeline_stats``, ``step_stats``)
+    grew by between two copies: EVERY number of the dict, so a counter a
+    later PR adds reaches a new reader file with no edit here."""
+    return {k: now[k] - then.get(k, 0) for k in now
+            if isinstance(now[k], (int, float))}
 
 
 def percentile(values, q: float) -> float:

@@ -18,7 +18,7 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, Iterator, List
 
-from ..common import Ctx, say
+from ..common import Ctx, counters_delta, say
 from ..traffic import Request, closed_loop_requests, first_wave
 from . import serve_common
 
@@ -66,32 +66,42 @@ class ClosedLoop:
             outs = self.engine.decode_batch(
                 uids, [self.live[u]["last"] for u in uids], n)
         decode_s = time.perf_counter() - t0
-        done: List[int] = []
-        for u in uids:
-            st, got = self.live[u], outs[u]
-            self.streams[u].extend(int(t) for t in got)
-            st["last"] = int(got[-1])
-            st["remaining"] -= len(got)
-            if st["remaining"] <= 0:
-                done.append(u)
-        for u in done:
-            self.finished.append(self.live.pop(u)["req"])
-            self.engine.flush(u)
-        refill_s = self._admit([next(self.requests) for _ in done])
+        # the job's own code between the loop's return and the first
+        # refill ``put``: a device gap here is the harness's, by name
+        with self.ctx.span("harvest"):
+            done: List[int] = []
+            for u in uids:
+                st, got = self.live[u], outs[u]
+                self.streams[u].extend(int(t) for t in got)
+                st["last"] = int(got[-1])
+                st["remaining"] -= len(got)
+                if st["remaining"] <= 0:
+                    done.append(u)
+            for u in done:
+                self.finished.append(self.live.pop(u)["req"])
+                self.engine.flush(u)
+            refills = [next(self.requests) for _ in done]
+        refill_s = self._admit(refills)
         self.rounds.append({"live": len(uids), "tokens": len(uids) * n,
                             "decode_s": decode_s, "refill_s": refill_s,
                             "refills": len(done),
                             "context_tokens": ctx_tokens})
 
-    def run_rounds(self, until) -> Dict[str, float]:
+    def run_rounds(self, until) -> Dict[str, Any]:
+        """Whole rounds until ``until(rounds, elapsed)``; beside them the
+        delta of every number the engine counts (``pipeline_stats``),
+        copied outside the seconds they took."""
         first = len(self.rounds)
+        stats0 = dict(self.engine.pipeline_stats)
         t0 = time.perf_counter()
         while True:
             self.round()
             if until(len(self.rounds) - first, time.perf_counter() - t0):
                 break
-        return {"elapsed_s": time.perf_counter() - t0,
-                "rounds": self.rounds[first:]}
+        elapsed = time.perf_counter() - t0
+        return {"elapsed_s": elapsed, "rounds": self.rounds[first:],
+                "pipeline": counters_delta(
+                    dict(self.engine.pipeline_stats), stats0)}
 
 
 def _cycle(reqs: List[Request]) -> Iterator[Request]:
@@ -135,6 +145,7 @@ def run(ctx: Ctx) -> Dict[str, Any]:
         "slot_steps_live": sum(r["live"] for r in rounds) * quantum,
         "slot_steps": steps * engine.config.max_seqs,
         "decode_context_tokens": sum(r["context_tokens"] for r in rounds),
+        "pipeline": win["pipeline"],
     }
     say("window", dict(obs, serve_tok_s=tok_s,
                        compiles_in_window=trip.fresh_compiles))
@@ -145,7 +156,8 @@ def run(ctx: Ctx) -> Dict[str, Any]:
         obs["traced"] = {
             "decode_context_tokens": sum(r["context_tokens"]
                                          for r in tr["rounds"]),
-            "decode_steps": len(tr["rounds"]) * quantum}
+            "decode_steps": len(tr["rounds"]) * quantum,
+            "rounds": len(tr["rounds"]), "pipeline": tr["pipeline"]}
         obs["attention"] = {"q_heads": model_cfg.num_heads,
                             "kv_heads": model_cfg.num_kv_heads,
                             "head_dim": model_cfg.head_dim,
@@ -164,4 +176,5 @@ def run(ctx: Ctx) -> Dict[str, Any]:
               "served_tokens_match_reference": check["ok"],
               "every_slot_live": obs["slot_steps_live"] == steps * clients}
     return {"attempted": len(served), "failed": 0, "checks": checks,
-            "obs": obs, "end_to_end": {"serve_tok_s": tok_s}}
+            "compared": serve_common.compared(check), "obs": obs,
+            "end_to_end": {"serve_tok_s": tok_s}}

@@ -23,7 +23,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from ..common import Ctx, percentile, say
+from ..common import Ctx, counters_delta, percentile, say
 from ..traffic import Request, arrivals_schedule
 from . import serve_common
 
@@ -44,6 +44,9 @@ class OpenLoop:
         self.t_first: Dict[int, float] = {}
         self.t_last: Dict[int, float] = {}
         self.queue_wait: Dict[int, float] = {}
+        # the engine's own stamps of a request (sequence.py), read when
+        # its ``put`` returns: (put_at, first_sched_at, first_token_at)
+        self.stamps: Dict[int, tuple] = {}
         self.refused: set = set()
         self.streams: Dict[int, List[int]] = {}
         self.live: Dict[int, Dict[str, int]] = {}
@@ -95,6 +98,9 @@ class OpenLoop:
             if seq is not None and seq.first_sched_at is not None:
                 self.queue_wait[r.uid] = seq.first_sched_at \
                     - (self.t0 + r.due_s)
+            if seq is not None:
+                self.stamps[r.uid] = (seq.put_at, seq.first_sched_at,
+                                      seq.first_token_at)
             if r.gen_len <= 1:
                 self._finish(r.uid, now)
             else:
@@ -164,6 +170,13 @@ class OpenLoop:
         """Times of every request due in ``segment``, finished or not."""
         now = time.monotonic()
         ttft, tpot, late, qwait, met = [], [], [], [], []
+        # a request's wait by stage, each where both of its stamps exist:
+        # at the door (due -> put), in the scheduler (put -> first
+        # scheduled), in prefill (-> first token committed) and for the
+        # rest of its put group (-> first token host-visible)
+        stages: Dict[str, List[float]] = {
+            "door_wait_s": [], "sched_wait_s": [], "prefill_s": [],
+            "group_wait_s": []}
         failed = 0
         limits = self.ctx.param("limits")
         for r in self.schedule:
@@ -185,12 +198,20 @@ class OpenLoop:
                 late.append(self.offered[r.uid] - due)
             if r.uid in self.queue_wait:
                 qwait.append(self.queue_wait[r.uid])
+            put_at, sched_at, token_at = self.stamps.get(
+                r.uid, (None, None, None))
+            for key, end, start in (("door_wait_s", put_at, due),
+                                    ("sched_wait_s", sched_at, put_at),
+                                    ("prefill_s", token_at, sched_at),
+                                    ("group_wait_s", first, token_at)):
+                if end is not None and start is not None:
+                    stages[key].append(end - start)
             met.append(done and t_ttft <= limits["ttft_s"]
                        + limits["ttft_s_per_prompt_token"] * len(r.prompt)
                        and t_tpot <= limits["tpot_s"])
-        return {"n": len(ttft), "failed": failed, "ttft_s": ttft,
-                "tpot_s": tpot, "gen_late_s": late, "queue_wait_s": qwait,
-                "met_limits": met}
+        return dict(stages, n=len(ttft), failed=failed, ttft_s=ttft,
+                    tpot_s=tpot, gen_late_s=late, queue_wait_s=qwait,
+                    met_limits=met)
 
 
 def warm_up(ctx: Ctx, engine, vocab: int) -> None:
@@ -232,7 +253,7 @@ def summarize(loop: OpenLoop, stats0, stats1) -> Dict[str, Any]:
     s = loop.sample()
     steps = sum(b[0] for b in loop.bursts)
     obs = dict(s)
-    obs["pipeline"] = serve_common.pipeline_delta(stats1, stats0)
+    obs["pipeline"] = counters_delta(stats1, stats0)
     obs["decode_steps"] = steps
     obs["slot_steps_live"] = sum(b[0] * b[1] for b in loop.bursts)
     obs["slot_steps"] = steps * loop.max_seqs
@@ -271,12 +292,15 @@ def run(ctx: Ctx) -> Dict[str, Any]:
         traced: Optional[Dict[str, Any]] = None
         if ctx.trace:
             loop.bursts = []
+            traced_stats0 = dict(engine.pipeline_stats)
             with ctx.traced_window():
                 loop.recording = True
                 loop.serve_until(time.monotonic() - loop.t0 + trace_s)
                 loop.recording = False
             traced = {"decode_context_tokens": sum(b[3] for b in loop.bursts),
-                      "decode_steps": sum(b[0] for b in loop.bursts)}
+                      "decode_steps": sum(b[0] for b in loop.bursts),
+                      "pipeline": counters_delta(
+                          dict(engine.pipeline_stats), traced_stats0)}
             loop.bursts = win_bursts
             t_end = time.monotonic() - loop.t0
         window_uids = [r.uid for r in schedule if r.segment == "window"]
@@ -296,7 +320,10 @@ def run(ctx: Ctx) -> Dict[str, Any]:
     say("sample", {"due_in_window": obs["n"], "failed": obs["failed"],
                    "gen_late_p99_ms": 1e3 * percentile(obs["gen_late_s"], 99),
                    "ttft_p50_ms": 1e3 * percentile(obs["ttft_s"], 50),
+                   "ttft_p75_ms": 1e3 * percentile(obs["ttft_s"], 75),
                    "ttft_p90_ms": 1e3 * percentile(obs["ttft_s"], 90),
+                   "ttft_mean_ms": 1e3 * sum(obs["ttft_s"]) / obs["n"],
+                   "tpot_p50_ms": 1e3 * percentile(obs["tpot_s"], 50),
                    "tpot_p90_ms": 1e3 * percentile(obs["tpot_s"], 90),
                    "compiles_in_window": trip.fresh_compiles,
                    "compiles_while_serving": serving.fresh_compiles,
@@ -310,7 +337,7 @@ def run(ctx: Ctx) -> Dict[str, Any]:
               "served_tokens_match_reference": check["ok"],
               "sample_holds_requests": obs["n"] > 0}
     return {"attempted": obs["n"], "failed": obs["failed"], "checks": checks,
-            "obs": obs,
+            "compared": serve_common.compared(check), "obs": obs,
             "end_to_end": {
                 "ttft_p90_ms": 1e3 * percentile(obs["ttft_s"], 90),
                 "tpot_p90_ms": 1e3 * percentile(obs["tpot_s"], 90)}}

@@ -80,7 +80,13 @@ def check_streams(ctx: Ctx, mt, model_cfg, params,
     return out
 
 
-def pipeline_delta(now: Dict[str, float], then: Dict[str, float]
-                   ) -> Dict[str, float]:
-    return {k: now[k] - then.get(k, 0) for k in now
-            if isinstance(now[k], (int, float))}
+def compared(check: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
+    """The numbers :func:`check_streams` compared, each beside its limit
+    (an upper one for the gap, a lower one for the share)."""
+    if "of" not in check:
+        return {}
+    return {"worst_gap_sigma": {"value": check["worst_gap_sigma"],
+                                "limit": check["tolerance_sigma"]},
+            "same_top1_share": {"value": check["same_top1"] / check["of"],
+                                "limit": check["min_same_top1_share"]}}
+

@@ -16,14 +16,16 @@ import importlib
 import time
 from typing import Any, Dict, List
 
-from ..common import Ctx, say
+from ..common import Ctx, counters_delta, say
 
 
 def _run_steps(ctx: Ctx, engine, batches: List[Any], until) -> Dict[str, Any]:
-    """Whole steps until ``until(steps, elapsed)``; returns steps, seconds
-    and the losses (read after the clock stops)."""
+    """Whole steps until ``until(steps, elapsed)``; returns steps, seconds,
+    the losses (read after the clock stops) and the delta of every number
+    the engine counts in ``step_stats`` (copied outside the seconds)."""
     import jax
     losses, prev, steps = [], None, 0
+    stats0 = dict(engine.step_stats)
     t0 = time.perf_counter()
     while True:
         with ctx.span("train_batch"):
@@ -37,6 +39,7 @@ def _run_steps(ctx: Ctx, engine, batches: List[Any], until) -> Dict[str, Any]:
     jax.block_until_ready(prev)
     elapsed = time.perf_counter() - t0
     return {"steps": steps, "elapsed_s": elapsed,
+            "step_stats": counters_delta(dict(engine.step_stats), stats0),
             "losses": [float(v) for v in losses]}
 
 
@@ -114,6 +117,7 @@ def run(ctx: Ctx) -> Dict[str, Any]:
         "tokens": tokens_done, "n_params": n_params, "chips": chips,
         "train_tok_s_chip": tok_s_chip,
         "step_ms": 1e3 * win["elapsed_s"] / win["steps"],
+        "step_stats": win["step_stats"],
         "model_flops_per_s_chip": 6.0 * n_params * tok_s_chip,
         "attention": {"batch": B // chips, "heads": model_cfg.num_heads,
                       "seq": seq,
@@ -126,4 +130,6 @@ def run(ctx: Ctx) -> Dict[str, Any]:
                             lambda s, _t: s >= int(job["trace_steps"]))
         obs["traced_steps"] = tr["steps"]
     return {"attempted": win["steps"], "failed": 0, "checks": checks,
+            "compared": {"first_loss_gap": {
+                "value": abs(first_loss - ref_loss), "limit": tol}},
             "obs": obs, "end_to_end": {"train_tok_s": tok_s_chip}}

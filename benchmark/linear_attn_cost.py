@@ -3,10 +3,11 @@
 ``moe_cost.py`` the grouped matmuls'): what one KDA layer of
 ``ops/kernels/delta_rule.py`` must compute and move, in its two forms.
 
-``readers.r_roofline`` resolves cost functions in ``kernel_cost`` only, so
-no reader file names these yet (PERF.md section 7 lists the one-line
-edit); ``roofline_share`` below is what the builder's reduction of a
-traced run uses meanwhile.
+``layer_metrics/linear_attn_roofline.solar2.json`` names
+``kda_decode_cost`` as ``linear_attn_cost.kda_decode_cost``
+(``readers.cost_function``); no reader names the prefill form yet;
+``roofline_share`` below is the same share for a builder's own reduction
+of a traced run.
 """
 
 from __future__ import annotations

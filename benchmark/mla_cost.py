@@ -1,9 +1,11 @@
 """Operations and bytes latent attention (MLA) needs, from its shapes alone.
 
-The true counts of the ABSORBED form, where ``kernel_cost.
-paged_decode_attention_cost`` (what ``readers.r_roofline`` can resolve
-today) counts a row's bytes right at ``kv_heads`` 1, ``head_dim`` 288 and
-its FLOPs 1.9 x short. As in ``kernel_cost.py`` these are the algorithm's
+The true counts of the ABSORBED form, which
+``layer_metrics/mla_attn_roofline.pangu.json`` names as
+``mla_cost.mla_decode_attention_cost`` (``kernel_cost.
+paged_decode_attention_cost``, its stand-in until PR 37, counts a row's
+bytes right at ``kv_heads`` 1, ``head_dim`` 288 and its FLOPs 1.9 x
+short). As in ``kernel_cost.py`` these are the algorithm's
 needs: a kernel that reads a row for the scores and again for the values,
 or multiplies a padded row, does more, and its roofline share shows it.
 """

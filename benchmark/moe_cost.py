@@ -4,10 +4,10 @@ attention's): what the three grouped matmuls of one sparse layer
 (``moe/sharded_moe.grouped_moe_ffn``: gate, up and down projections of
 SwiGLU experts over rows sorted by expert) must compute and move.
 
-``readers.r_roofline`` resolves cost functions in ``kernel_cost`` only, so
-no reader file names this one yet (PERF.md section 7 lists the one-line
-edit); ``roofline_share`` below is what the builder's reduction of a
-traced run uses meanwhile.
+``layer_metrics/grouped_moe_roofline.*.json`` name ``grouped_moe_ffn_cost``
+as ``moe_cost.grouped_moe_ffn_cost`` (``readers.cost_function``);
+``roofline_share`` below is the same share for a builder's own reduction
+of a traced run.
 """
 
 from __future__ import annotations

@@ -13,6 +13,13 @@ host was doing while the device waited. It also gives:
   where no program span covers), with a 0.0 for every program span
   that appears in the trace, so a ratio over an idle-free phase reads 0
   and a trace without program spans gives nothing to read;
+* ``idle_named_share``: the share of the idle seconds that lie under a
+  program span, under no benchmark span at all (the harness's loop), or
+  under a benchmark span that brackets the harness's own code (one no
+  program span ever opens under: ``harvest``, a sleep). What is left is
+  inside a call into the program but outside every bracket of its own:
+  the program's blind spot. A trace without any program span (a parent
+  of PR 25) counts only the harness's loop as named;
 * ``device_programs``: device seconds and runs per program, from the
   device plane's ``XLA Modules`` line (one event per program run, named
   by its jit function);
@@ -30,9 +37,12 @@ A gap that crosses span boundaries is split at them, each piece named
 on its own; a single gap in ``idle_gaps`` carries the name that covers
 most of it.
 
-``run.py`` does not call this module yet (a PR that changes the program
-may not edit the harness). A traced run leaves its profile under
-``.bench_trace/<cell>``; read it with
+``run.py`` reads the profile once (``reduce_trace.load``) and hands it
+to ``reduce_trace.reduce`` and, through :func:`from_trace`, to
+:func:`name_gaps`: the keys above reach the readers under
+``obs["trace"]`` and the printed ``breakdown.idle_gaps`` are this
+module's. A traced run leaves its profile under ``.bench_trace/<cell>``;
+to read a saved one again:
 
     python3 -m benchmark.program_spans .bench_trace/serve-chat-steady
 """
@@ -47,57 +57,24 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from . import reduce_trace as rt
 
-PROGRAM_PREFIX = "dstpu:"
-MODULE_LINE = "XLA Modules"
-LAUNCH_EVENT = "DoEnqueueProgram"
-
 Span = Tuple[float, float, str]
 
 
+def from_trace(trace: Dict[str, Any]) -> Dict[str, Any]:
+    """What :func:`name_gaps` reads, out of ``reduce_trace.load``'s one
+    reading of the profile: {"bench": [...], "program": [(start_s, end_s,
+    name)], "ops": {plane: [(start_s, end_s)]}, "modules": {plane:
+    [(start_s, end_s, name, run_id)]}, "launches": {(device ordinal,
+    run_id): start_s}}."""
+    return {"bench": trace["spans"], "program": trace.get("program", []),
+            "ops": {plane: [(s, e) for s, e, _ in events]
+                    for plane, events in trace["devices"].items()},
+            "modules": trace.get("modules", {}),
+            "launches": trace.get("launches", {})}
+
+
 def load(path: str) -> Dict[str, Any]:
-    """{"bench": [...], "program": [(start_s, end_s, name)], "ops":
-    {plane: [(start_s, end_s)]}, "modules": {plane: [(start_s, end_s,
-    name, run_id)]}, "launches": {(device ordinal, run_id): start_s}}."""
-    import jax
-    data = jax.profiler.ProfileData.from_file(path)
-    out: Dict[str, Any] = {"bench": [], "program": [], "ops": {},
-                           "modules": {}, "launches": {}}
-    for plane in data.planes:
-        if rt.DEVICE_PLANE.match(plane.name):
-            for line in plane.lines:
-                if line.name == rt.OP_LINE:
-                    out["ops"][plane.name] = [
-                        (e.start_ns * 1e-9,
-                         (e.start_ns + e.duration_ns) * 1e-9)
-                        for e in line.events]
-                elif line.name == MODULE_LINE:
-                    out["modules"][plane.name] = [
-                        (e.start_ns * 1e-9,
-                         (e.start_ns + e.duration_ns) * 1e-9, e.name,
-                         dict(e.stats).get("run_id"))
-                        for e in line.events]
-        elif plane.name.startswith("/host:CPU"):
-            for line in plane.lines:
-                for e in line.events:
-                    name = e.name
-                    for prefix, key in ((rt.SPAN_PREFIX, "bench"),
-                                        (PROGRAM_PREFIX, "program")):
-                        if name.startswith(prefix):
-                            out[key].append((
-                                e.start_ns * 1e-9,
-                                (e.start_ns + e.duration_ns) * 1e-9,
-                                name[len(prefix):]))
-                    if name == LAUNCH_EVENT:
-                        st = dict(e.stats)
-                        key = (int(st.get("device_ordinal", 0)),
-                               st.get("run_id"))
-                        t = e.start_ns * 1e-9
-                        if key[1] is not None \
-                                and t < out["launches"].get(key, t + 1):
-                            out["launches"][key] = t
-    out["bench"].sort()
-    out["program"].sort()
-    return out
+    return from_trace(rt.load(path))
 
 
 def clock_offset(modules: Dict[str, List[Tuple]],
@@ -170,9 +147,16 @@ def name_gaps(extra: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
 
     b_bounds, b_names = _innermost([s for s in bench if s[2] != "window"])
     p_bounds, p_names = _innermost(extra["program"])
+    # a benchmark span that no program span ever opens under is the
+    # harness's own code (``harvest``, a sleep), not a call into the
+    # program: idle under it has its whole name already
+    starts = [s[0] for s in extra["program"]]
+    calls = {name for s, e, name in bench
+             if bisect.bisect_left(starts, e) > bisect.bisect_left(starts, s)}
     cuts = sorted(set(b_bounds) | set(p_bounds))
     by_name: Dict[str, float] = {}
     by_phase: Dict[str, float] = {s[2]: 0.0 for s in extra["program"]}
+    named = 0.0
     singles: List[Tuple[str, float]] = []
     for gs, ge in gaps:
         gs, ge = gs + shift, ge + shift
@@ -187,6 +171,9 @@ def name_gaps(extra: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
             parts[name] = parts.get(name, 0.0) + (b - a)
             by_phase[phase or "none"] = \
                 by_phase.get(phase or "none", 0.0) + (b - a)
+            if phase or bench_name is None or (starts and bench_name
+                                               not in calls):
+                named += b - a
         for name, dt in parts.items():
             by_name[name] = by_name.get(name, 0.0) + dt
         singles.append((max(parts, key=parts.get), ge - gs))
@@ -202,8 +189,6 @@ def name_gaps(extra: Dict[str, Any], top: int = 10) -> Dict[str, Any]:
                 row = programs.setdefault(_program_name(name), [0.0, 0])
                 row[0] += min(e, hi) - max(s, lo)
                 row[1] += 1
-    named = sum(v for k, v in by_phase.items() if k != "none") \
-        + by_name.get("none", 0.0)
     total = sum(by_phase.values())
     shared = {
         "idle_named_share": named / total if total else None,

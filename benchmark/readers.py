@@ -10,11 +10,16 @@ clock, ``memory_peak_bytes`` and ``peak.*`` from ``peaks.json``).
 
 from __future__ import annotations
 
+import importlib
 import re
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Optional
 
 from . import kernel_cost, reduce_trace
 from .common import percentile
+
+_COST_MODULE = re.compile(r"^[a-z0-9_]*_cost$")
+# a kernel's pattern may name sizes of the run: {attention.q_heads}
+_PLACEHOLDER = re.compile(r"\{([\w.]+)\}")
 
 
 def lookup(obs: Dict[str, Any], key: str) -> Any:
@@ -57,37 +62,73 @@ def r_ratio(spec, obs):
     return spec.get("scale", 1.0) * num / den
 
 
+def cost_function(name: str) -> Callable[..., Dict[str, float]]:
+    """The cost function a reader file names: ``<function>`` of
+    ``kernel_cost``, or ``<module>.<function>`` of any
+    ``benchmark/<module>.py`` whose name ends in ``_cost``, so a kernel's
+    yardstick comes with its own file. A name that is not there raises:
+    a roofline with no yardstick is a fault of the reader file, not a
+    metric with nothing to read."""
+    module, _, function = name.rpartition(".")
+    if module and not _COST_MODULE.match(module):
+        raise KeyError(f"cost {name!r}: {module!r} is not a *_cost module "
+                       f"of benchmark/")
+    try:
+        mod = importlib.import_module(
+            f"{__package__}.{module or 'kernel_cost'}")
+        return getattr(mod, function)
+    except (ImportError, AttributeError) as e:
+        raise KeyError(f"no cost function {name!r} under benchmark/: {e}")
+
+
 def r_roofline(spec, obs):
     """Least time the chip could take for the work of the named kernels,
     over the device time they took in the trace. Each kernel entry has a
     ``pattern`` over the trace's stable operation names and one or more
-    ``costs``: a function of ``kernel_cost`` with its arguments taken from
-    the observations, counted ``calls_share`` times per call seen in the
-    trace (per device), or a number of times taken from the observations
-    (``per``)."""
+    ``costs``: a function found by :func:`cost_function` with its
+    arguments taken from the observations, counted ``calls_share`` times
+    per call seen in the trace (per device), or ``per`` times: a number,
+    or a key of the observations (a cost that is linear in its arguments
+    is then ONE evaluation over the traced stretch's own totals, ``per``
+    1)."""
     trace, peak = obs.get("trace"), obs.get("peak")
     if not trace or not peak:
         return None
     least, took = 0.0, 0.0
     for k in spec["kernels"]:
-        # a pattern may name sizes of the run: {attention.q_heads}
-        pattern = re.sub(r"\{([\w.]+)\}",
-                         lambda m: str(lookup(obs, m.group(1))), k["pattern"])
+        pattern = _PLACEHOLDER.sub(lambda m: str(lookup(obs, m.group(1))),
+                                   k["pattern"])
         seconds, calls = reduce_trace.kernel_seconds(trace, pattern)
         if not calls:
             return None
         took += seconds / trace["n_devices"]
         for c in k["costs"]:
             args = {a: lookup(obs, key) for a, key in c["args"].items()}
-            times = lookup(obs, c["per"]) if "per" in c \
-                else c["calls_share"] * calls / trace["n_devices"]
+            per = c.get("per")
+            if per is None:
+                times = c["calls_share"] * calls / trace["n_devices"]
+            else:
+                times = lookup(obs, per) if isinstance(per, str) else per
             if times is None or any(v is None for v in args.values()):
                 return None
-            cost = getattr(kernel_cost, c["cost"])(**args,
-                                                   **c.get("fixed", {}))
+            cost = cost_function(c["cost"])(**args, **c.get("fixed", {}))
             least += times * kernel_cost.roofline_seconds(cost,
                                                           peak)["seconds"]
-    return 100.0 * least / took if took else None
+    return 100.0 * least / took if took and least else None
+
+
+def keys_of(spec: Dict[str, Any]) -> list:
+    """Every key of the observations a reader file names: what a job has
+    to export for the reader to find something to read."""
+    keys = [spec[k] for k in ("key", "series") if k in spec]
+    keys += list(spec.get("num", ())) + list(spec.get("den", ()))
+    for k in spec.get("kernels", ()):
+        keys += _PLACEHOLDER.findall(k["pattern"])
+        for c in k["costs"]:
+            keys += list(c["args"].values())
+            if isinstance(c.get("per"), str):
+                keys.append(c["per"])
+    return keys
 
 
 REDUCERS = {"value": r_value, "percentile": r_percentile,

@@ -15,6 +15,11 @@ Reads the trace with ``jax.profiler.ProfileData`` alone. What it gives:
   the ``Async XLA Ops`` line), and the part of it during which no other
   operation ran on that device, averaged over devices.
 
+``load`` is the one reading of the profile: beside what ``reduce`` needs
+it keeps the program's own spans, the program runs and their launches,
+which ``program_spans`` names the idle gaps by (``run.py`` hands the one
+reading to both).
+
 The device's operation line nests (a ``while`` or a fusion's parent spans
 its children), so busy time is a union, never a sum, and ``ops`` counts
 only events that contain no other event of their line (leaves).
@@ -28,9 +33,12 @@ import re
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 SPAN_PREFIX = "bench:"
+PROGRAM_PREFIX = "dstpu:"
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OP_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
+MODULE_LINE = "XLA Modules"
+LAUNCH_EVENT = "DoEnqueueProgram"
 COLLECTIVE = re.compile(
     r"^%?(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute|"
     r"collective-broadcast)")
@@ -111,12 +119,21 @@ def stable_name(name: str) -> str:
 
 
 def load(path: str) -> Dict[str, Any]:
-    """{"devices": {plane: [(start_s, end_s, name)]}, "spans": [...]}."""
+    """The one reading of a profile: {"devices": {plane: [(start_s, end_s,
+    name)]}, "async": the same of the asynchronous line, "spans": the
+    benchmark's spans} for :func:`reduce`, and beside them what
+    ``program_spans`` names the idle gaps by: "program" (the program's own
+    spans), "modules" ({plane: [(start_s, end_s, name, run_id)]}, one
+    event a program run) and "launches" ({(device ordinal, run_id):
+    start_s} of the host's enqueues)."""
     import jax
     data = jax.profiler.ProfileData.from_file(path)
     devices: Dict[str, List[Tuple[float, float, str]]] = {}
     overlapped: Dict[str, List[Tuple[float, float, str]]] = {}
+    modules: Dict[str, List[Tuple[float, float, str, Any]]] = {}
     spans: List[Tuple[float, float, str]] = []
+    program: List[Tuple[float, float, str]] = []
+    launches: Dict[Tuple[int, Any], float] = {}
     for plane in data.planes:
         if DEVICE_PLANE.match(plane.name):
             for line in plane.lines:
@@ -127,14 +144,33 @@ def load(path: str) -> Dict[str, Any]:
                         (e.start_ns * 1e-9,
                          (e.start_ns + e.duration_ns) * 1e-9, e.name)
                         for e in line.events]
+                elif line.name == MODULE_LINE:
+                    modules[plane.name] = [
+                        (e.start_ns * 1e-9,
+                         (e.start_ns + e.duration_ns) * 1e-9, e.name,
+                         dict(e.stats).get("run_id"))
+                        for e in line.events]
         elif plane.name.startswith("/host:CPU"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
-                        spans.append((e.start_ns * 1e-9,
-                                      (e.start_ns + e.duration_ns) * 1e-9,
-                                      e.name[len(SPAN_PREFIX):]))
-    return {"devices": devices, "async": overlapped, "spans": sorted(spans)}
+                    name = e.name
+                    for prefix, into in ((SPAN_PREFIX, spans),
+                                         (PROGRAM_PREFIX, program)):
+                        if name.startswith(prefix):
+                            into.append((e.start_ns * 1e-9,
+                                         (e.start_ns + e.duration_ns) * 1e-9,
+                                         name[len(prefix):]))
+                    if name == LAUNCH_EVENT:
+                        st = dict(e.stats)
+                        key = (int(st.get("device_ordinal", 0)),
+                               st.get("run_id"))
+                        t = e.start_ns * 1e-9
+                        if key[1] is not None \
+                                and t < launches.get(key, t + 1):
+                            launches[key] = t
+    return {"devices": devices, "async": overlapped, "spans": sorted(spans),
+            "program": sorted(program), "modules": modules,
+            "launches": launches}
 
 
 def reduce(trace: Dict[str, Any], top: int = 10,

@@ -11,7 +11,9 @@ window, and prints the result as the last line of standard output.
 
 ``--rehearse`` runs the same control flow on the CPU at the tiny sizes in
 the files' ``rehearse`` blocks (four virtual devices): the line then says
-``platform: cpu`` and carries no time, rate or share, only counts.
+``platform: cpu`` and carries no time, rate or share, only counts. With
+``--trace 1`` a rehearsal also walks the traced stretch, with no profiler
+under it, so that the job fills every observation a reader names.
 
 The harness holds no per-cell code: a cell is ``cells/<name>.json`` naming
 a job kind (a module of ``benchmark/jobs``), a configuration
@@ -43,7 +45,26 @@ def _metrics_of(manifest, section: str, cell: str):
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def main(argv=None) -> int:
+def read_trace(path: str):
+    """One reading of a profile (``.xplane.pb``): ``reduce_trace``'s sums
+    (window, busy, operations, collectives: computed as without the
+    program's spans) with the idle gaps named by what the host was doing,
+    ``<bench span>/<innermost program span>`` (``program_spans``). Returns
+    ``obs["trace"]`` and the printed ``breakdown``."""
+    from benchmark import program_spans, reduce_trace
+    trace = reduce_trace.load(path)
+    reduced = reduce_trace.reduce(trace)
+    reduced["idle_s"] = reduced["window_s"] - reduced["busy_s"]
+    named = program_spans.name_gaps(program_spans.from_trace(trace))
+    reduced.update(named["trace"])
+    return reduced, {
+        "device_ops": reduced["device_ops"],
+        "idle_gaps": named["breakdown"]["idle_gaps"]}
+
+
+def run_cell(argv=None):
+    """Run one cell as the command line says; returns the result line and
+    the job's observations (what the per-layer readers read)."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
@@ -80,8 +101,8 @@ def main(argv=None) -> int:
         else float(manifest["run_seconds"])
     ctx = Ctx(cell_name=entry["name"], cell=cell, config=config,
               traffic=traffic, seed=args.seed, seconds=seconds,
-              trace=bool(args.trace) and not args.rehearse,
-              rehearse=args.rehearse, t_process=T_PROCESS)
+              trace=bool(args.trace), rehearse=args.rehearse,
+              t_process=T_PROCESS)
     if args.rehearse:
         ctx.seconds = float(cell.get("rehearse", {}).get("seconds", 1.0))
     ctx.compiles = CompileClock()
@@ -103,35 +124,45 @@ def main(argv=None) -> int:
             "phase_s": ctx.phase_s}
     say("phase_s", ctx.phase_s)
 
-    if ctx.trace:
+    if args.rehearse:
+        # a rehearsal: counts only, never under a device metric's name
+        line["rehearsal"] = {"programs": obs["setup"]["programs"],
+                             "attempted": result["attempted"]}
+    elif ctx.trace:
         from benchmark import kernel_cost
         obs["peak"] = kernel_cost.peaks(device["kind"])
-        reduced = reduce_trace.reduce(
-            reduce_trace.load(reduce_trace.find_xplane(ctx.trace_dir)))
-        reduced["idle_s"] = reduced["window_s"] - reduced["busy_s"]
+        reduced, line["breakdown"] = read_trace(
+            reduce_trace.find_xplane(ctx.trace_dir))
         obs["trace"] = reduced
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
-        line["breakdown"] = {"device_ops": reduced["device_ops"],
-                             "idle_gaps": reduced["idle_gaps"]}
+        say("trace", {k: reduced.get(k) for k in (
+            "idle_named_share", "clock_offset_s", "device_programs",
+            "idle_by_phase")})
         if reduced["busy_s"] <= 0:
             line["correct"] = False
-    if args.trace and not args.rehearse:
         for m in _metrics_of(manifest, "per_layer", entry["name"]):
             spec = load_json("layer_metrics", m["name"] + ".json")
             value = readers.read(spec, obs)
             if value is not None:
                 line["metrics"][m["name"]] = {"value": value,
                                               "unit": m["unit"]}
-    elif not args.rehearse:
+    else:
         values = dict(result["end_to_end"], setup_s=ctx.setup_s)
         for m in _metrics_of(manifest, "end_to_end", entry["name"]):
             line["metrics"][m["name"]] = {"value": values[m["name"]],
                                           "unit": m["unit"]}
-    else:
-        # a rehearsal: counts only, never under a device metric's name
-        line["rehearsal"] = {"programs": obs["setup"]["programs"],
-                             "attempted": result["attempted"]}
+    # each number ``correct`` compared beside its limit: the line's last
+    # key and the last lines of standard error
+    line["compared"] = result.get("compared", {})
+    for name, pair in line["compared"].items():
+        print(f"[benchmark] compared {name}: {pair['value']!r} "
+              f"limit {pair['limit']!r}", file=sys.stderr, flush=True)
+    return line, obs
+
+
+def main(argv=None) -> int:
+    line, _obs = run_cell(argv)
     print(json.dumps(line), flush=True)
     return 0
 

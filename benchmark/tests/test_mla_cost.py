@@ -53,7 +53,14 @@ def test_prefill_cost_absorbed_and_expanded(ctx, absorbed, expanded):
 def test_mla_roofline_reader_counts_one_layers_rows_a_call():
     # one traced round of 128 steps over 5 layers: 640 calls
     ctx_tokens = 128 * 415_000
-    least = 5 * ctx_tokens * 1152 / PEAK["hbm_bytes_per_s"]
+    # the reader stands on the absorbed form's true count (PR 37): at 128
+    # heads its FLOPs bound a row by a hair, 0.5 % over reading it once
+    row = kernel_cost.roofline_seconds(
+        mla_cost.mla_decode_attention_cost(1.0, 128, 512, 64), PEAK)
+    assert row["bound"] == "compute"
+    assert row["seconds"] == pytest.approx(
+        1.005 * 1152 / PEAK["hbm_bytes_per_s"], rel=1e-3)
+    least = 5 * ctx_tokens * row["seconds"]
     name = "mla_decode_attention-bf16_128_128_512"
     obs = {"peak": PEAK, "attention": {"q_heads": 128, "kv_heads": 1,
                                        "head_dim": 576, "kv_row": 576,

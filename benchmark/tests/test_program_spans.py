@@ -75,6 +75,25 @@ def test_a_gap_under_no_span_and_a_trace_without_program_spans():
     assert out["trace"]["idle_named_share"] == pytest.approx(2.0 / 3.0)
 
 
+def test_idle_under_the_harnesss_own_span_is_named_and_inside_a_call_not():
+    # window 0-10, device busy 1-2 and 8-9. decode_batch 0.5-3 holds
+    # serve/fused_apply 2-2.5; harvest 3-5 holds no program span; put 5-9
+    # holds serve/plan 6-7. Idle: 0-0.5 none, 0.5-1 decode_batch (inside
+    # the call, no bracket: unnamed), 2-2.5 fused_apply, 2.5-3
+    # decode_batch (unnamed), 3-5 harvest, 5-6 put (unnamed), 6-7 plan,
+    # 7-8 put (unnamed), 9-10 none
+    extra = _extra(
+        ops=[(1.0, 2.0), (8.0, 9.0)],
+        bench=[(0.0, 10.0, "window"), (0.5, 3.0, "decode_batch"),
+               (3.0, 5.0, "harvest"), (5.0, 9.0, "put")],
+        program=[(2.0, 2.5, "serve/fused_apply"), (6.0, 7.0, "serve/plan")])
+    out = ps.name_gaps(extra)["trace"]
+    assert out["idle_by_name"]["harvest"] == pytest.approx(2.0)
+    assert out["idle_by_name"]["put"] == pytest.approx(2.0)
+    assert sum(out["idle_by_name"].values()) == pytest.approx(8.0)
+    assert out["idle_named_share"] == pytest.approx((8.0 - 3.0) / 8.0)
+
+
 def test_a_phase_without_idle_reads_zero_not_nothing():
     extra = _extra(ops=[(0.0, 4.0)], bench=[(0.0, 4.0, "window")],
                    program=[(1.0, 2.0, "serve/plan")])
@@ -215,7 +234,13 @@ def test_the_recorded_v5e_serve_trace():
     assert printed["idle_gaps"][0] == [
         "all_gaps_under_decode_pipelined/serve/dispatch",
         pytest.approx(0.014453, abs=2e-6)]
-    assert trace["idle_named_share"] == pytest.approx(0.8135, abs=1e-3)
+    # unnamed is only what idles inside a call but outside every bracket
+    # of the program's own (3 us in put, 20 us in decode_pipelined): the
+    # sleep is the harness's own code (no program span opens under it)
+    # and counts as named since PR 37; without it the share read 0.8135
+    assert trace["idle_named_share"] == pytest.approx(0.99919, abs=1e-4)
+    assert (1 - trace["idle_named_share"]) * idle_s == pytest.approx(
+        names["put"] + names["decode_pipelined"], rel=1e-6)
 
     # programs, from the module line: 2 prefill + 2 unfed decode steps
     # run _step_greedy, the 6 fed steps _step_greedy_fb
