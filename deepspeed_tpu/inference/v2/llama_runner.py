@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from ...models.llama import LlamaConfig, apply_rope
 from ...models.mixtral import MixtralConfig
+from ...telemetry.trace import region
 from .config import RaggedInferenceConfig
 from .kv_quant import lin_parts, with_lin
 from .model_runner import (RaggedBatch, RaggedRunnerBase, latent_attention,
@@ -86,7 +87,8 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
         # the router weight [hidden, E] is fused-packable (E % 4 == 0)
         # but tiny — unpack rather than kernel-dispatch the [*, E] GEMV
         gate_w = fp6_gemm_unpack(gate_w)
-    logits = h.astype(jnp.float32).reshape(S * C, M) @ gate_w
+    with region("moe_route"):
+        logits = h.astype(jnp.float32).reshape(S * C, M) @ gate_w
     if "wi_gate" in p_moe:                                    # SwiGLU experts
         weights = (p_moe["wi_gate"], p_moe["wi_up"], p_moe["wo"])
     else:
@@ -109,35 +111,42 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
         factor = float(icfg.ep_capacity_factor) if icfg is not None else 2.0
         cap = ep_serve_capacity(S * C, cfg.experts_top_k, ep, factor,
                                 chunks)
-        y, _ = grouped_moe_ffn_ep_serve(
-            h.reshape(S * C, M), logits, cfg.experts_top_k, weights,
-            jax.nn.silu, dtype, EP_AXIS, cfg.num_experts, cap,
-            normalize_weights=norm, chunks=chunks)
+        with region("moe_experts"):
+            y, _ = grouped_moe_ffn_ep_serve(
+                h.reshape(S * C, M), logits, cfg.experts_top_k, weights,
+                jax.nn.silu, dtype, EP_AXIS, cfg.num_experts, cap,
+                normalize_weights=norm, chunks=chunks)
         return y.reshape(S, C, M), None
     impl = grouped_ffn.kernel_impl(S * C * cfg.experts_top_k,
                                    cfg.num_experts, weights, dtype)
-    y, _ = grouped_moe_ffn(
-        h.reshape(S * C, M), logits, cfg.experts_top_k, weights,
-        jax.nn.silu, dtype, normalize_weights=norm, held=held, impl=impl,
-        **router)
+    # routing, layout and the weighted sum; the grouped matmuls open
+    # ``moe_experts`` inside
+    with region("moe_route"):
+        y, _ = grouped_moe_ffn(
+            h.reshape(S * C, M), logits, cfg.experts_top_k, weights,
+            jax.nn.silu, dtype, normalize_weights=norm, held=held,
+            impl=impl, **router)
     rows = None
     if valid is not None:
-        # the same choice the grouped path makes of the same logits
-        top_idx = route_topk(logits, cfg.experts_top_k, score=router["score"],
-                             bias=router["select_bias"])[0]
-        E = cfg.num_experts
-        rows = jnp.zeros((E,), jnp.int32).at[top_idx].add(
-            valid.reshape(S * C, 1).astype(jnp.int32))
-        hit = reads = jnp.int32(0)
-        if impl is not None:
-            # what the kernel walked: every row of the step, padding too
-            first, count = held or (0, E)
-            mine = jnp.zeros((E,), jnp.int32).at[top_idx].add(
-                1)[first:first + count]
-            hit = jnp.sum(mine > 0, dtype=jnp.int32)
-            tile = grouped_ffn.row_tile(S * C * cfg.experts_top_k, E)
-            reads = jnp.sum(-(-mine // tile), dtype=jnp.int32)
-        rows = jnp.concatenate([rows, jnp.stack([hit, reads])])
+        # the loop's counters: the same choice the grouped path makes of
+        # the same logits
+        with region("loop_carry"):
+            top_idx = route_topk(logits, cfg.experts_top_k,
+                                 score=router["score"],
+                                 bias=router["select_bias"])[0]
+            E = cfg.num_experts
+            rows = jnp.zeros((E,), jnp.int32).at[top_idx].add(
+                valid.reshape(S * C, 1).astype(jnp.int32))
+            hit = reads = jnp.int32(0)
+            if impl is not None:
+                # what the kernel walked: every row of the step, padding too
+                first, count = held or (0, E)
+                mine = jnp.zeros((E,), jnp.int32).at[top_idx].add(
+                    1)[first:first + count]
+                hit = jnp.sum(mine > 0, dtype=jnp.int32)
+                tile = grouped_ffn.row_tile(S * C * cfg.experts_top_k, E)
+                reads = jnp.sum(-(-mine // tile), dtype=jnp.int32)
+            rows = jnp.concatenate([rows, jnp.stack([hit, reads])])
     return y.reshape(S, C, M), rows
 
 
@@ -228,8 +237,9 @@ def _mla_mixer(p, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
     qa = jnp.concatenate(
         [q_lat, q_r] + [jnp.zeros((S, C, H, pad), dtype)] * (pad > 0),
         axis=-1)
-    kv, o_lat = latent_attention(kv, plane, qa, row, batch, cfg, pos,
-                                 valid_q, (dn + dr) ** -0.5, dtype, r)
+    with region("mla_core"):
+        kv, o_lat = latent_attention(kv, plane, qa, row, batch, cfg, pos,
+                                     valid_q, (dn + dr) ** -0.5, dtype, r)
     o = jnp.einsum("schr,rhd->schd", o_lat, w_kvb[..., dn:])
     return kv, woq_mm(o.reshape(S, C, H * dv), p["o_proj"]["kernel"], dtype)
 
@@ -260,9 +270,10 @@ def _attn_mixer(pa, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
         q = apply_rope(q, pos, model_cfg.rope_theta)
         k = apply_rope(k, pos, model_cfg.rope_theta)
 
-    kv, y = paged_attention(kv, plane, q, k, v, batch, cfg, pos, valid_q,
-                            1.0 / (D ** 0.5), dtype,
-                            sliding_window=model_cfg.sliding_window)
+    with region("attn_core"):
+        kv, y = paged_attention(kv, plane, q, k, v, batch, cfg, pos, valid_q,
+                                1.0 / (D ** 0.5), dtype,
+                                sliding_window=model_cfg.sliding_window)
     if getattr(model_cfg, "attn_gate", False):
         gate = woq_mm(h, pa["g_proj"]["kernel"], dtype)
         y = (y.astype(jnp.float32)
@@ -277,14 +288,21 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
     S, C = batch.tokens.shape
     is_moe = isinstance(model_cfg, MixtralConfig)
 
-    pos = batch.start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
-    valid_q = jnp.arange(C, dtype=jnp.int32)[None, :] < batch.n_tokens[:, None]
+    # device time is read by region (telemetry/trace.py): each ``with``
+    # names what is traced under it; a mixer's call site names its
+    # projections and the mixer opens the attention call's own inside
+    with region("embed"):
+        pos = batch.start_pos[:, None] \
+            + jnp.arange(C, dtype=jnp.int32)[None, :]
+        valid_q = jnp.arange(C, dtype=jnp.int32)[None, :] \
+            < batch.n_tokens[:, None]
 
-    # the residual stream's dtype: the compute dtype, unless the family
-    # asks for more (solar_open2: float32; its norms then read an
-    # unrounded stream, and only matmul operands are rounded to ``dtype``)
-    rdtype = getattr(model_cfg, "residual_dtype", None) or dtype
-    x = params["embed"]["embedding"][batch.tokens].astype(rdtype)
+        # the residual stream's dtype: the compute dtype, unless the family
+        # asks for more (solar_open2: float32; its norms then read an
+        # unrounded stream, and only matmul operands are rounded to
+        # ``dtype``)
+        rdtype = getattr(model_cfg, "residual_dtype", None) or dtype
+        x = params["embed"]["embedding"][batch.tokens].astype(rdtype)
 
     # one step function for every family: the layer lists say which mixer
     # and which feed-forward a layer runs; softmax and latent layers take
@@ -298,71 +316,85 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
     plane = si = 0
     for li, kind in enumerate(kinds):
         p = params[f"layer_{li}"]
-        h = _rms(x, p["input_norm"]["scale"],
-                 model_cfg.rms_eps).astype(dtype)
+        with region("norm"):
+            h = _rms(x, p["input_norm"]["scale"],
+                     model_cfg.rms_eps).astype(dtype)
         if kind == "kda":
-            kv, y = _kda_mixer(p["kda"], h, kv, si, batch, model_cfg,
-                               valid_q, dtype)
+            with region("linear_attn"):
+                kv, y = _kda_mixer(p["kda"], h, kv, si, batch, model_cfg,
+                                   valid_q, dtype)
             si += 1
         elif kind == "mla":
-            kv, y = _mla_mixer(p["attn"], h, kv, plane, batch, model_cfg,
-                               cfg, pos, valid_q, dtype)
+            with region("mla_proj"):
+                kv, y = _mla_mixer(p["attn"], h, kv, plane, batch,
+                                   model_cfg, cfg, pos, valid_q, dtype)
             plane += 1
         else:
-            kv, y = _attn_mixer(p["attn"], h, kv, plane, batch, model_cfg,
-                                cfg, pos, valid_q, dtype)
+            with region("attn_proj"):
+                kv, y = _attn_mixer(p["attn"], h, kv, plane, batch,
+                                    model_cfg, cfg, pos, valid_q, dtype)
             plane += 1
         if sandwich:
-            y = _rms(y, p["attn_branch_norm"]["scale"], model_cfg.rms_eps)
-        x = x + y.astype(rdtype)
+            with region("norm"):
+                y = _rms(y, p["attn_branch_norm"]["scale"],
+                         model_cfg.rms_eps)
+        with region("residual"):
+            x = x + y.astype(rdtype)
 
-        h = _rms(x, p["post_attn_norm"]["scale"],
-                 model_cfg.rms_eps).astype(dtype)
+        with region("norm"):
+            h = _rms(x, p["post_attn_norm"]["scale"],
+                     model_cfg.rms_eps).astype(dtype)
         if ffn_kinds[li] == "moe":
             # the fused decode loop's kv carries a count of routed rows
             counted = getattr(kv, "moe_rows", None) is not None
             y, rows = _moe_mlp(p["moe"], h, model_cfg, dtype, cfg,
                                valid=valid_q if counted else None)
             if rows is not None:
-                kv = kv._replace(moe_rows=kv.moe_rows + rows)
+                with region("loop_carry"):
+                    kv = kv._replace(moe_rows=kv.moe_rows + rows)
             if getattr(model_cfg, "shared_expert_size", 0):
                 # always-on shared expert: behind a sigmoid scalar gate
                 # (qwen2-moe) or ungated (solar_open2, pangu_ultra_moe)
-                gate = woq_mm(h, p["shared_gate_proj"]["kernel"], dtype)
-                up = woq_mm(h, p["shared_up_proj"]["kernel"], dtype)
-                shared = woq_mm(jax.nn.silu(gate) * up,
-                                p["shared_down_proj"]["kernel"], dtype)
-                if getattr(model_cfg, "shared_expert_gated", True):
-                    sg = jax.nn.sigmoid(
-                        (h @ p["shared_expert_gate"]["kernel"].astype(dtype)
-                         ).astype(jnp.float32))
-                    shared = shared * sg.astype(dtype)
-                y = y + shared
+                with region("moe_shared"):
+                    gate = woq_mm(h, p["shared_gate_proj"]["kernel"], dtype)
+                    up = woq_mm(h, p["shared_up_proj"]["kernel"], dtype)
+                    shared = woq_mm(jax.nn.silu(gate) * up,
+                                    p["shared_down_proj"]["kernel"], dtype)
+                    if getattr(model_cfg, "shared_expert_gated", True):
+                        sg = jax.nn.sigmoid(
+                            (h @ p["shared_expert_gate"]["kernel"].astype(
+                                dtype)).astype(jnp.float32))
+                        shared = shared * sg.astype(dtype)
+                    y = y + shared
         else:
             pm = p["mlp"]
-            gate = woq_mm(h, pm["gate_proj"]["kernel"], dtype)
-            up = woq_mm(h, pm["up_proj"]["kernel"], dtype)
-            m = jax.nn.silu(gate) * up
-            m = woq_mm(m, pm["down_proj"]["kernel"], dtype)
-            y = tp_all_reduce(m, cfg)                     # TP collective 2
+            with region("ffn_dense"):
+                gate = woq_mm(h, pm["gate_proj"]["kernel"], dtype)
+                up = woq_mm(h, pm["up_proj"]["kernel"], dtype)
+                m = jax.nn.silu(gate) * up
+                m = woq_mm(m, pm["down_proj"]["kernel"], dtype)
+                y = tp_all_reduce(m, cfg)                 # TP collective 2
         if sandwich:
-            y = _rms(y, p["mlp_branch_norm"]["scale"], model_cfg.rms_eps)
-        x = x + y.astype(rdtype)
+            with region("norm"):
+                y = _rms(y, p["mlp_branch_norm"]["scale"], model_cfg.rms_eps)
+        with region("residual"):
+            x = x + y.astype(rdtype)
 
-    x = _rms(x, params["final_norm"]["scale"], model_cfg.rms_eps)
-    last = jnp.maximum(batch.n_tokens - 1, 0)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     from ...ops.kernels.fp6_gemm import Fp6GemmWeight
-    if model_cfg.tie_embeddings:
-        # embedding tables are never fused-packed (the quantizer's
-        # structural exclusion — the token gather needs a dense array)
-        w_out = params["embed"]["embedding"].T
-    else:
-        w_out = params["lm_head"]["kernel"]
-        if isinstance(w_out, Fp6GemmWeight):
-            return woq_mm(x_last.astype(jnp.float32), w_out,
-                          jnp.float32), kv
-    logits = x_last.astype(jnp.float32) @ w_out.astype(jnp.float32)
+    with region("head"):
+        x = _rms(x, params["final_norm"]["scale"], model_cfg.rms_eps)
+        last = jnp.maximum(batch.n_tokens - 1, 0)
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        if model_cfg.tie_embeddings:
+            # embedding tables are never fused-packed (the quantizer's
+            # structural exclusion — the token gather needs a dense array)
+            w_out = params["embed"]["embedding"].T
+        else:
+            w_out = params["lm_head"]["kernel"]
+            if isinstance(w_out, Fp6GemmWeight):
+                return woq_mm(x_last.astype(jnp.float32), w_out,
+                              jnp.float32), kv
+        logits = x_last.astype(jnp.float32) @ w_out.astype(jnp.float32)
     return logits, kv
 
 

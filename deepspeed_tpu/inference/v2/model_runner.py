@@ -25,6 +25,7 @@ from jax.sharding import PartitionSpec as P
 
 from ...models.gpt2 import GPT2Config
 from ...parallel.tp_rules import MODEL_AXIS
+from ...telemetry.trace import region
 from ...utils.jax_compat import manual_axes, shard_map
 from .config import RaggedInferenceConfig
 from .kv_quant import KVPool, RingKV, pool_parts
@@ -520,10 +521,11 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
         # in the scan carry; a trailing index forced a ring copy per layer).
         # The ring stays UNQUANTIZED (compute dtype) even over an int8
         # pool — its rows are rewritten every loop and quantized at flush.
-        ring = ring.at[t, li, 0].set(
-            k.reshape(S, KV * D).astype(ring.dtype))
-        ring = ring.at[t, li, 1].set(
-            v.reshape(S, KV * D).astype(ring.dtype))
+        with region("kv_write"):
+            ring = ring.at[t, li, 0].set(
+                k.reshape(S, KV * D).astype(ring.dtype))
+            ring = ring.at[t, li, 1].set(
+                v.reshape(S, KV * D).astype(ring.dtype))
         kv = kv._replace(ring=ring)
         settled_lens = jnp.where(batch.n_tokens > 0,
                                  batch.start_pos - t, 0)
@@ -568,9 +570,11 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
                                     scale, dtype, alibi_slopes,
                                     sliding_window)
 
-    kv = _store_step_rows(
-        kv, li, jnp.stack([k.reshape(S, C, KV * D), v.reshape(S, C, KV * D)]),
-        batch, cfg, KV)
+    with region("kv_write"):
+        kv = _store_step_rows(
+            kv, li,
+            jnp.stack([k.reshape(S, C, KV * D), v.reshape(S, C, KV * D)]),
+            batch, cfg, KV)
     data, scales = pool_parts(kv)
 
     if impl == "paged_flash":
@@ -647,11 +651,15 @@ def latent_attention(kv, li, q, row, batch: "RaggedBatch",
         data, _ = pool_parts(kv.pool)
         # the latent ring is SEQUENCE-major, [L, 1, S, R, W]: the decode
         # kernel then takes a [R, W] slab a sequence
-        ring = ring.at[li, 0, :, t].set(row.reshape(S, W).astype(ring.dtype))
+        with region("kv_write"):
+            ring = ring.at[li, 0, :, t].set(
+                row.reshape(S, W).astype(ring.dtype))
         kv = kv._replace(ring=ring)
         lens = jnp.where(batch.n_tokens > 0, batch.start_pos - t, 0)
     else:
-        kv = _store_step_rows(kv, li, row.reshape(1, S, C, W), batch, cfg)
+        with region("kv_write"):
+            kv = _store_step_rows(kv, li, row.reshape(1, S, C, W), batch,
+                                  cfg)
         data, _ = pool_parts(kv)
         ring = rcount = None
         lens = jnp.where(batch.n_tokens > 0,
@@ -877,10 +885,11 @@ class RaggedRunnerBase:
                 gbatch = batch
                 shard = (seqc.seq_size, r)
             # where the step's rows go in the pool: once, for every layer
-            batch = batch._replace(write_plan=write_plan(
-                batch.start_pos, batch.n_tokens, batch.block_tables,
-                batch.tokens.shape[1], cfg.block_size,
-                pool_parts(kv_data)[0].shape, shard))
+            with region("kv_write"):
+                batch = batch._replace(write_plan=write_plan(
+                    batch.start_pos, batch.n_tokens, batch.block_tables,
+                    batch.tokens.shape[1], cfg.block_size,
+                    pool_parts(kv_data)[0].shape, shard))
             if seqc is not None:
                 batch = batch._replace(
                     tokens=jax.lax.dynamic_slice_in_dim(
@@ -894,7 +903,8 @@ class RaggedRunnerBase:
                 model_cfg=mcfg_l, cfg=cfg, dtype=dtype)
             # vocab-sharded unembed -> ONE all-gather to full logits
             # (identity for tied/replicated unembeds and at tp_size 1)
-            logits = tp_gather_logits(logits, vocab)
+            with region("head"):
+                logits = tp_gather_logits(logits, vocab)
             if seqc is not None:
                 # each slot's true last token lives on ONE chip's query
                 # slice; a single masked psum hands its logits to all —
@@ -924,7 +934,9 @@ class RaggedRunnerBase:
         # to the host (the reference's host-side sampler reads full logits)
         def _step_greedy(params, kv_data, batch):
             logits, kv_out = _step(params, kv_data, batch)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), kv_out
+            with region("sample"):
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return tok, kv_out
 
         self._step_greedy = jax.jit(_step_greedy, donate_argnums=donate)
 
@@ -941,9 +953,11 @@ class RaggedRunnerBase:
         # values after the next step dispatches.
         def _step_greedy_fb(params, kv_data, batch, prev_tok, feed_mask,
                             feed_idx):
-            fed = prev_tok[jnp.clip(feed_idx, 0, prev_tok.shape[0] - 1)]
-            tok0 = jnp.where(feed_mask > 0, fed, batch.tokens[:, 0])
-            batch = batch._replace(tokens=batch.tokens.at[:, 0].set(tok0))
+            with region("loop_carry"):
+                fed = prev_tok[jnp.clip(feed_idx, 0, prev_tok.shape[0] - 1)]
+                tok0 = jnp.where(feed_mask > 0, fed, batch.tokens[:, 0])
+                batch = batch._replace(
+                    tokens=batch.tokens.at[:, 0].set(tok0))
             return _step_greedy(params, kv_data, batch)
 
         self._step_greedy_fb = jax.jit(_step_greedy_fb,
@@ -962,15 +976,19 @@ class RaggedRunnerBase:
         # produces; logprobs ride to the host at commit.
         def _step_sample_fb(params, kv_data, batch, prev_tok, feed_mask,
                             feed_idx, seeds, spos, temps, top_ks, top_ps):
-            fed = prev_tok[jnp.clip(feed_idx, 0, prev_tok.shape[0] - 1)]
-            tok0 = jnp.where(feed_mask > 0, fed, batch.tokens[:, 0])
-            batch = batch._replace(tokens=batch.tokens.at[:, 0].set(tok0))
+            with region("loop_carry"):
+                fed = prev_tok[jnp.clip(feed_idx, 0, prev_tok.shape[0] - 1)]
+                tok0 = jnp.where(feed_mask > 0, fed, batch.tokens[:, 0])
+                batch = batch._replace(
+                    tokens=batch.tokens.at[:, 0].set(tok0))
             logits, kv_out = _step(params, kv_data, batch)
-            keys = _sample_keys(seeds, spos)
-            cand = min(SAMPLE_CANDIDATES, logits.shape[-1])
-            tok = _select_tokens(logits, keys, temps, top_ks, top_ps,
-                                 cand=cand)
-            return (tok, _chosen_logprob(logits, tok)), kv_out
+            with region("sample"):
+                keys = _sample_keys(seeds, spos)
+                cand = min(SAMPLE_CANDIDATES, logits.shape[-1])
+                tok = _select_tokens(logits, keys, temps, top_ks, top_ps,
+                                     cand=cand)
+                lp = _chosen_logprob(logits, tok)
+            return (tok, lp), kv_out
 
         self._step_sample_fb = jax.jit(_step_sample_fb,
                                        donate_argnums=donate)
@@ -1043,18 +1061,21 @@ class RaggedRunnerBase:
                 ring, moe, lin = kv_out.ring, kv_out.moe_rows, kv_out.lin
                 # the one pre-sampling collective: every chip then selects
                 # the SAME next token from identical full-width logits
-                logits = tp_gather_logits(logits, vocab)
-                if mode == "greedy":
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    lp = jnp.zeros((S,), jnp.float32)
-                else:
-                    # keys are a pure function of (seed, the position the
-                    # selected token will occupy) — deterministic across
-                    # fused/per-step paths and restarts (sampling.py)
-                    keys = _sample_keys(seeds, pos + 1)
-                    nxt = _select_tokens(logits, keys, temps, top_ks,
-                                         top_ps, cand=cand)
-                    lp = _chosen_logprob(logits, nxt)
+                with region("head"):
+                    logits = tp_gather_logits(logits, vocab)
+                with region("sample"):
+                    if mode == "greedy":
+                        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        lp = jnp.zeros((S,), jnp.float32)
+                    else:
+                        # keys are a pure function of (seed, the position
+                        # the selected token will occupy) — deterministic
+                        # across fused/per-step paths and restarts
+                        # (sampling.py)
+                        keys = _sample_keys(seeds, pos + 1)
+                        nxt = _select_tokens(logits, keys, temps, top_ks,
+                                             top_ps, cand=cand)
+                        lp = _chosen_logprob(logits, nxt)
                 if use_eos:
                     nxt = jnp.where(done, jnp.int32(eos_id), nxt)
                     new_pos = pos + (1 - done.astype(jnp.int32))
@@ -1089,8 +1110,12 @@ class RaggedRunnerBase:
                     (pspecs, pool_spec, None, None, P(), P(), P(), P(),
                      P(), P(), P(), P(), P()),
                     (P(), P(), ring_spec, P(), P(), None))
-            return impl(params, kv_data, lin, sslots, tok0, start, active,
-                        tables, seeds, temps, top_ks, top_ps, drafts)
+            # everything of the loop that no inner region claims: the
+            # ring, the feed, positions, counters, the stacked outputs
+            with region("loop_carry"):
+                return impl(params, kv_data, lin, sslots, tok0, start,
+                            active, tables, seeds, temps, top_ks, top_ps,
+                            drafts)
 
         # dslint: allow(DSL002): the pool is strictly READ-ONLY inside
         # the fused loop (fresh K/V rides the small ring carry);
@@ -1114,10 +1139,6 @@ class RaggedRunnerBase:
             data, scales = pool_parts(kv_data)
             latent = self.kv_planes == 1       # ring [L, 1, S, R, W]
             R = ring.shape[3 if latent else 0]     # else [R, L, 2, S, W]
-            plan = write_plan(
-                start0, jnp.where(active > 0, R, 0), tables, R,
-                cfg.block_size, data.shape, None if seqc is None else
-                (seqc.seq_size, jax.lax.axis_index(SEQ_AXIS)))
             kv_heads = 1 if scales is None else scales.shape[2]
 
             def layer(l, kv):
@@ -1127,7 +1148,12 @@ class RaggedRunnerBase:
                     rows = jnp.transpose(rows, (1, 2, 0, 3))   # [2, S, R, W]
                 return store_rows(kv, l, rows, plan, kv_heads)
 
-            return jax.lax.fori_loop(0, data.shape[0], layer, kv_data)
+            with region("kv_write"):
+                plan = write_plan(
+                    start0, jnp.where(active > 0, R, 0), tables, R,
+                    cfg.block_size, data.shape, None if seqc is None else
+                    (seqc.seq_size, jax.lax.axis_index(SEQ_AXIS)))
+                return jax.lax.fori_loop(0, data.shape[0], layer, kv_data)
 
         if mapped:
             # all flush work is chip-local (quantize_rows is per-kv-head,

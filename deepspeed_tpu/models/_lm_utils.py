@@ -7,6 +7,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.trace import region
+
 
 def constrain_activations(x: jnp.ndarray) -> jnp.ndarray:
     """Pin [B, T, C] activations to the framework's natural layout (batch
@@ -78,62 +80,63 @@ def lm_head_xent(hidden: jnp.ndarray, head: jnp.ndarray,
         raise ValueError(f"head_layout must be 'vc' or 'cv', "
                          f"got {head_layout!r}")
 
-    impl = getattr(cfg, "xent_impl", "chunked")
-    if impl not in ("chunked", "fused"):
-        raise ValueError(
-            f"xent_impl must be 'chunked' or 'fused', got {impl!r}")
-    chunks = getattr(cfg, "xent_chunks", 8)
-    remat = getattr(cfg, "xent_remat", True)
-    ignore = getattr(cfg, "xent_ignore_index", None)
+    with region("loss"):
+        impl = getattr(cfg, "xent_impl", "chunked")
+        if impl not in ("chunked", "fused"):
+            raise ValueError(
+                f"xent_impl must be 'chunked' or 'fused', got {impl!r}")
+        chunks = getattr(cfg, "xent_chunks", 8)
+        remat = getattr(cfg, "xent_remat", True)
+        ignore = getattr(cfg, "xent_ignore_index", None)
 
-    def _chunked():
-        return chunked_lm_xent(hidden, head, targets, num_chunks=chunks,
-                               remat=remat, ignore_index=ignore,
-                               head_layout=head_layout)
+        def _chunked():
+            return chunked_lm_xent(hidden, head, targets, num_chunks=chunks,
+                                   remat=remat, ignore_index=ignore,
+                                   head_layout=head_layout)
 
-    if impl == "fused":
-        from ..ops.kernels import fused_lm_xent
-        from ..ops.kernels.fused_xent import sharded_fused_lm_xent
-        from ..parallel import topology as _topo
-        if head_layout == "cv":
-            head = head.T
-        from ..utils.jax_compat import manual_axes
-        manual = manual_axes()
-        if manual:
-            # already inside an engine manual seam (ZeRO++/1-bit
-            # shard_map): hidden is per-rank local and the seam pmeans
-            # the loss — run the kernel plainly on the shard
-            return fused_lm_xent(hidden, head, targets,
-                                 ignore_index=ignore)
-        if jax.device_count() > 1:
-            if not _topo.has_topology():
-                # plain GSPMD data-parallel jit with no framework mesh:
-                # the Pallas custom call carries no sharding rules, so XLA
-                # would silently all-gather the full [B, T, C] hidden
-                # states around it — the exact traffic the shard_map
-                # wrapper exists to avoid. The chunked einsum shards
-                # naturally under GSPMD instead.
-                import warnings
-                warnings.warn(
-                    "xent_impl='fused' with multiple devices but no "
-                    "deepspeed_tpu topology registered: falling back to "
-                    "the chunked path (the fused kernel would all-gather "
-                    "hidden states). Build a mesh via dstpu.initialize / "
-                    "parallel.topology to use the fused kernel here.")
-                return _chunked()
-            mesh = _topo.get_topology().mesh
-            if mesh.shape.get("seq", 1) > 1:
-                # SP meshes: hidden arrives seq-sharded; the row-sharding
-                # wrapper would all-gather T (the chunked einsum shards
-                # naturally under GSPMD instead)
-                return _chunked()
-            # Pallas custom calls carry no GSPMD rules — without the
-            # shard_map wrapping a multi-device jit would all-gather the
-            # [B, T, C] hidden states around the kernel
-            return sharded_fused_lm_xent(hidden, head, targets, mesh,
-                                         ignore_index=ignore)
-        return fused_lm_xent(hidden, head, targets, ignore_index=ignore)
-    return _chunked()
+        if impl == "fused":
+            from ..ops.kernels import fused_lm_xent
+            from ..ops.kernels.fused_xent import sharded_fused_lm_xent
+            from ..parallel import topology as _topo
+            if head_layout == "cv":
+                head = head.T
+            from ..utils.jax_compat import manual_axes
+            manual = manual_axes()
+            if manual:
+                # already inside an engine manual seam (ZeRO++/1-bit
+                # shard_map): hidden is per-rank local and the seam pmeans
+                # the loss — run the kernel plainly on the shard
+                return fused_lm_xent(hidden, head, targets,
+                                     ignore_index=ignore)
+            if jax.device_count() > 1:
+                if not _topo.has_topology():
+                    # plain GSPMD data-parallel jit with no framework mesh:
+                    # the Pallas custom call carries no sharding rules, so XLA
+                    # would silently all-gather the full [B, T, C] hidden
+                    # states around it — the exact traffic the shard_map
+                    # wrapper exists to avoid. The chunked einsum shards
+                    # naturally under GSPMD instead.
+                    import warnings
+                    warnings.warn(
+                        "xent_impl='fused' with multiple devices but no "
+                        "deepspeed_tpu topology registered: falling back to "
+                        "the chunked path (the fused kernel would all-gather "
+                        "hidden states). Build a mesh via dstpu.initialize / "
+                        "parallel.topology to use the fused kernel here.")
+                    return _chunked()
+                mesh = _topo.get_topology().mesh
+                if mesh.shape.get("seq", 1) > 1:
+                    # SP meshes: hidden arrives seq-sharded; the row-sharding
+                    # wrapper would all-gather T (the chunked einsum shards
+                    # naturally under GSPMD instead)
+                    return _chunked()
+                # Pallas custom calls carry no GSPMD rules — without the
+                # shard_map wrapping a multi-device jit would all-gather the
+                # [B, T, C] hidden states around the kernel
+                return sharded_fused_lm_xent(hidden, head, targets, mesh,
+                                             ignore_index=ignore)
+            return fused_lm_xent(hidden, head, targets, ignore_index=ignore)
+        return _chunked()
 
 
 def chunked_lm_xent(hidden: jnp.ndarray, embedding: jnp.ndarray,

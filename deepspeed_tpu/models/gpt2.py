@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..telemetry.trace import region
+
 
 @dataclasses.dataclass(frozen=True)
 class GPT2Config:
@@ -113,25 +115,30 @@ class CausalSelfAttention(nn.Module):
                 # silently defeating SP — those meshes go through
                 # ulysses/ring attention (parallel/) or plain XLA here
                 impl = "xla"
-        if impl == "flash":
-            from deepspeed_tpu.ops.kernels import flash_attention
-            y = flash_attention(q, k, v, causal=True, layout="BTHD",
-                                block_q=cfg.flash_block_q,
-                                block_k=cfg.flash_block_k)
-        elif impl == "flash_sharded":
-            from deepspeed_tpu.ops.kernels import sharded_flash_attention
-            from deepspeed_tpu.parallel.topology import get_topology
-            y = sharded_flash_attention(q, k, v, get_topology().mesh,
-                                        causal=True, layout="BTHD",
-                                        block_q=cfg.flash_block_q,
-                                        block_k=cfg.flash_block_k)
-        elif impl == "xla":
-            # jax.nn.dot_product_attention lowers to a fused attention on TPU
-            y = jax.nn.dot_product_attention(q, k, v, is_causal=True)
-        else:
-            raise ValueError(
-                f"attention_impl must be 'auto', 'flash', 'flash_sharded' "
-                f"or 'xla', got {cfg.attention_impl!r}")
+        # an unnamed Pallas call takes its trace name from the innermost
+        # scope: the module's own name goes back there, so the flash
+        # kernels stay ``attn-*`` in a profile (flash_attn_roofline.train)
+        with region("attn_core"), jax.named_scope(self.name or "attn"):
+            if impl == "flash":
+                from deepspeed_tpu.ops.kernels import flash_attention
+                y = flash_attention(q, k, v, causal=True, layout="BTHD",
+                                    block_q=cfg.flash_block_q,
+                                    block_k=cfg.flash_block_k)
+            elif impl == "flash_sharded":
+                from deepspeed_tpu.ops.kernels import sharded_flash_attention
+                from deepspeed_tpu.parallel.topology import get_topology
+                y = sharded_flash_attention(q, k, v, get_topology().mesh,
+                                            causal=True, layout="BTHD",
+                                            block_q=cfg.flash_block_q,
+                                            block_k=cfg.flash_block_k)
+            elif impl == "xla":
+                # jax.nn.dot_product_attention lowers to a fused attention
+                # on TPU
+                y = jax.nn.dot_product_attention(q, k, v, is_causal=True)
+            else:
+                raise ValueError(
+                    f"attention_impl must be 'auto', 'flash', 'flash_sharded' "
+                    f"or 'xla', got {cfg.attention_impl!r}")
         y = y.reshape(B, T, C)
         y = nn.Dense(C, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                      use_bias=cfg.use_bias, name="c_proj")(y)
@@ -169,10 +176,22 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
         cfg = self.cfg
-        x = x + CausalSelfAttention(cfg, name="attn")(
-            nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name="ln_1")(x), deterministic)
-        x = x + MLP(cfg, name="mlp")(
-            nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name="ln_2")(x), deterministic)
+        # device time is read by region (telemetry/trace.py), under the
+        # names the serve step uses; flax puts the modules' names around
+        with region("norm"):
+            h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                             name="ln_1")(x)
+        with region("attn_proj"):       # attn_core opens inside
+            h = CausalSelfAttention(cfg, name="attn")(h, deterministic)
+        with region("residual"):
+            x = x + h
+        with region("norm"):
+            h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                             name="ln_2")(x)
+        with region("ffn_dense"):
+            h = MLP(cfg, name="mlp")(h, deterministic)
+        with region("residual"):
+            x = x + h
         return x
 
 
@@ -188,11 +207,12 @@ class GPT2(nn.Module):
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wte")
         wpe = nn.Embed(cfg.max_seq_len, cfg.hidden_size,
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wpe")
-        x = wte(tokens) + wpe(jnp.arange(T)[None, :])
-        # pin the embedding output to the natural activation layout
-        # (shared helper — see _lm_utils.constrain_activations for why)
         from ._lm_utils import constrain_activations
-        x = constrain_activations(x)
+        with region("embed"):
+            x = wte(tokens) + wpe(jnp.arange(T)[None, :])
+            # pin the embedding output to the natural activation layout
+            # (shared helper — see _lm_utils.constrain_activations for why)
+            x = constrain_activations(x)
         block_cls = Block
         if cfg.remat:
             policy = None
@@ -230,14 +250,16 @@ class GPT2(nn.Module):
             block_cls = nn.remat(Block, static_argnums=(2,), policy=policy)
         for i in range(cfg.num_layers):
             x = block_cls(cfg, name=f"h_{i}")(x, deterministic)
-        x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name="ln_f")(x)
-        if return_hidden:
-            # post-ln_f activations in the compute dtype: the training loss
-            # consumes these via the chunked fused cross-entropy
-            # (models/_lm_utils.chunked_lm_xent) instead of full logits
-            return x
-        # tied embedding unembed (GPT-2 ties wte)
-        logits = wte.attend(x.astype(jnp.float32))
+        with region("head"):
+            x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                             name="ln_f")(x)
+            if return_hidden:
+                # post-ln_f activations in the compute dtype: the training
+                # loss consumes these via the chunked fused cross-entropy
+                # (models/_lm_utils.chunked_lm_xent) instead of full logits
+                return x
+            # tied embedding unembed (GPT-2 ties wte)
+            logits = wte.attend(x.astype(jnp.float32))
         return logits
 
 
@@ -254,8 +276,9 @@ def make_model(cfg: GPT2Config):
 
     def loss_fn(params, batch, rng):
         from ._lm_utils import lm_head_xent
-        tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
+        with region("embed"):
+            tokens = batch["tokens"]
+            inputs, targets = tokens[:, :-1], tokens[:, 1:]
         hidden = model.apply({"params": params}, inputs,
                              deterministic=cfg.dropout == 0,
                              return_hidden=True,

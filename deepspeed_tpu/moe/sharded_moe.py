@@ -19,6 +19,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.trace import region
+
 
 def capacity(num_tokens: int, num_experts: int, capacity_factor: float,
              min_capacity: int) -> int:
@@ -300,15 +302,17 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     xs = jnp.take(tokens, tok_of, axis=0).astype(dtype)    # sorted by expert
     group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
 
-    if len(weights) == 3:
-        wi_gate, wi_up, wo = weights
-        g = jax.lax.ragged_dot(xs, wi_gate.astype(dtype), group_sizes)
-        u = jax.lax.ragged_dot(xs, wi_up.astype(dtype), group_sizes)
-        h = activation(g) * u
-    else:
-        wi, wo = weights
-        h = activation(jax.lax.ragged_dot(xs, wi.astype(dtype), group_sizes))
-    ys = jax.lax.ragged_dot(h, wo.astype(dtype), group_sizes)  # [S*k, M]
+    with region("moe_experts"):
+        if len(weights) == 3:
+            wi_gate, wi_up, wo = weights
+            g = jax.lax.ragged_dot(xs, wi_gate.astype(dtype), group_sizes)
+            u = jax.lax.ragged_dot(xs, wi_up.astype(dtype), group_sizes)
+            h = activation(g) * u
+        else:
+            wi, wo = weights
+            h = activation(
+                jax.lax.ragged_dot(xs, wi.astype(dtype), group_sizes))
+        ys = jax.lax.ragged_dot(h, wo.astype(dtype), group_sizes)  # [S*k, M]
 
     ws = jnp.take(w_sel.reshape(-1), order).astype(dtype)
     if here is not None:

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...telemetry.trace import region
+
 #: rows a visit at decode shapes: one packed bfloat16 tile (two float32)
 ROW_TILE = 16
 #: the row tiles a visit may take; past the last the work is no weight
@@ -261,6 +263,7 @@ def layout_and_run(tokens, eid, weights, activation, dtype, *,
         jnp.arange(R, dtype=jnp.int32) // k, mode="drop")
     xs = jnp.take(tokens.astype(dtype), src, axis=0, mode="fill",
                   fill_value=0)
-    ys = grouped_ffn_decode(xs, gid, nvis, tuple(weights),
-                            activation=activation, interpret=interpret)
+    with region("moe_experts"):
+        ys = grouped_ffn_decode(xs, gid, nvis, tuple(weights),
+                                activation=activation, interpret=interpret)
     return jnp.take(ys, dest, axis=0, mode="fill", fill_value=0)

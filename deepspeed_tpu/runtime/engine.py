@@ -33,6 +33,7 @@ from ..config.config import Config, ConfigError
 from ..ops.optimizers import build_optimizer
 from ..parallel.topology import (
     DATA_INNER_AXIS, Topology, build_mesh, set_topology)
+from ..telemetry.trace import region
 from ..utils.logging import log_dist, logger, see_memory_usage
 from ..utils.dtypes import cast_floating, resolve_dtype
 from ..utils.timer import (
@@ -468,13 +469,18 @@ class Engine:
             dev_twins = jax.tree_util.tree_map(
                 device_sharding, self._state_shardings.params)
 
+        # device time is read by region (telemetry/trace.py): the model
+        # and the loss open their own inside ``scaled_loss``; what the
+        # step does around them is ``grad_clip`` and ``optimizer``
         def micro_grads(params, micro_batch, rng, scale_state, step):
-            if host_mask is None:
-                cparams = cast_floating(params, compute_dtype)
-            else:
-                cparams = jax.tree_util.tree_map(
-                    lambda p, is_host: p if is_host
-                    else cast_floating(p, compute_dtype), params, host_mask)
+            with region("optimizer"):       # the compute-dtype copy
+                if host_mask is None:
+                    cparams = cast_floating(params, compute_dtype)
+                else:
+                    cparams = jax.tree_util.tree_map(
+                        lambda p, is_host: p if is_host
+                        else cast_floating(p, compute_dtype), params,
+                        host_mask)
 
             def scaled_loss(cp):
                 loss, _aux = self._loss_and_aux(cp, micro_batch, rng, step)
@@ -482,13 +488,15 @@ class Engine:
 
             grad_fn = jax.value_and_grad(scaled_loss, has_aux=True)
             (_scaled, loss), grads = grad_fn(cparams)
-            grads = jax.tree_util.tree_map(lambda g: g.astype(accum_dtype), grads)
-            if host_mask is not None:
-                # cotangents of pinned_host params land in HOST space;
-                # normalize to device for accumulation/clip/update
+            with region("grad_clip"):
                 grads = jax.tree_util.tree_map(
-                    lambda g, is_host, s: jax.device_put(g, s)
-                    if is_host else g, grads, host_mask, dev_twins)
+                    lambda g: g.astype(accum_dtype), grads)
+                if host_mask is not None:
+                    # cotangents of pinned_host params land in HOST space;
+                    # normalize to device for accumulation/clip/update
+                    grads = jax.tree_util.tree_map(
+                        lambda g, is_host, s: jax.device_put(g, s)
+                        if is_host else g, grads, host_mask, dev_twins)
             return loss, grads
 
         micro_grads = self._maybe_manual_micro_grads(micro_grads)
@@ -516,8 +524,9 @@ class Engine:
                 micro_batches = jax.tree_util.tree_map(to_micro, batch)
             params_c = state.params
 
-            rngs = jax.random.split(state.rng, gas + 1)
-            new_rng, micro_rngs = rngs[0], rngs[1:]
+            with region("optimizer"):       # the next step's key
+                rngs = jax.random.split(state.rng, gas + 1)
+                new_rng, micro_rngs = rngs[0], rngs[1:]
 
             zeros = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, accum_dtype), state.params)
@@ -527,10 +536,13 @@ class Engine:
                 mb, r = xs
                 loss, grads = micro_grads(params_c, mb, r,
                                           state.scale_state, state.step)
-                grad_acc = jax.tree_util.tree_map(jnp.add, grad_acc, grads)
-                if plan.stage >= 2:
-                    grad_acc = plan.constrain_grads(grad_acc, params_c)
-                return (grad_acc, loss_acc + loss), None
+                with region("grad_clip"):
+                    grad_acc = jax.tree_util.tree_map(jnp.add, grad_acc,
+                                                      grads)
+                    if plan.stage >= 2:
+                        grad_acc = plan.constrain_grads(grad_acc, params_c)
+                    loss_acc = loss_acc + loss
+                return (grad_acc, loss_acc), None
 
             new_comm = state.comm_state
             if onebit_grads is not None:
@@ -548,65 +560,75 @@ class Engine:
                 (grads, loss_sum), _ = jax.lax.scan(
                     scan_body, (zeros, jnp.zeros((), jnp.float32)),
                     (micro_batches, micro_rngs))
-            mean_loss = (loss_sum / gas).astype(jnp.float32)
+            with region("grad_clip"):
+                mean_loss = (loss_sum / gas).astype(jnp.float32)
 
-            # unscale + mean over gas
-            grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32) / gas, grads)
-            if fp16:
-                grads = ls.unscale_grads(grads, state.scale_state)
-            if plan.stage >= 2:
-                grads = plan.constrain_grads(grads, params_c)
+                # unscale + mean over gas
+                grads = jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32) / gas, grads)
+                if fp16:
+                    grads = ls.unscale_grads(grads, state.scale_state)
+                if plan.stage >= 2:
+                    grads = plan.constrain_grads(grads, params_c)
 
-            finite = ls.grads_finite(grads) if fp16 else jnp.asarray(True)
+                finite = ls.grads_finite(grads) if fp16 \
+                    else jnp.asarray(True)
 
-            # global grad norm + clip (reference engine clip_grad_norm path)
-            leaves = jax.tree_util.tree_leaves(grads)
-            grad_norm = jnp.sqrt(sum(jnp.vdot(g, g).real for g in leaves)).astype(jnp.float32)
-            if clip > 0.0:
-                factor = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
-                grads = jax.tree_util.tree_map(lambda g: g * factor, grads)
+                # global grad norm + clip (reference engine clip_grad_norm
+                # path)
+                leaves = jax.tree_util.tree_leaves(grads)
+                grad_norm = jnp.sqrt(sum(
+                    jnp.vdot(g, g).real for g in leaves)).astype(jnp.float32)
+                if clip > 0.0:
+                    factor = jnp.minimum(1.0, clip / (grad_norm + 1e-6))
+                    grads = jax.tree_util.tree_map(lambda g: g * factor,
+                                                   grads)
 
             # streamed (pinned_host) leaves: the elementwise update runs in
             # device space on a transient copy; out_shardings park the new
             # params back in host memory. (For models beyond HBM pair
             # streaming with offload_optimizer=cpu — the update then never
             # touches the device at all.)
-            params_u = params_c
-            if host_mask is not None:
-                params_u = jax.tree_util.tree_map(
-                    lambda p, is_host, s: jax.device_put(p, s)
-                    if is_host else p, params_c, host_mask, dev_twins)
-            updates, new_opt_state = self.optimizer.update(
-                grads, state.opt_state, params_u)
-            new_params = jax.tree_util.tree_map(
-                lambda p, u: p + u.astype(p.dtype), params_u, updates)
+            with region("optimizer"):
+                params_u = params_c
+                if host_mask is not None:
+                    params_u = jax.tree_util.tree_map(
+                        lambda p, is_host, s: jax.device_put(p, s)
+                        if is_host else p, params_c, host_mask, dev_twins)
+                updates, new_opt_state = self.optimizer.update(
+                    grads, state.opt_state, params_u)
+                new_params = jax.tree_util.tree_map(
+                    lambda p, u: p + u.astype(p.dtype), params_u, updates)
 
-            # overflow gate: keep old params/opt-state on non-finite grads
-            # (params_c == state.params numerically; with param offload it
-            # is the in-step device copy, keeping memory spaces uniform —
-            # out_shardings land new_params back in host memory)
-            def select(new, old):
-                return jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(finite, n, o), new, old)
-            new_params = select(new_params, params_u)
-            new_opt_state = select(new_opt_state, state.opt_state)
-            if new_comm is not state.comm_state:
-                new_comm = select(new_comm, state.comm_state)
+                # overflow gate: keep old params/opt-state on non-finite
+                # grads (params_c == state.params numerically; with param
+                # offload it is the in-step device copy, keeping memory
+                # spaces uniform — out_shardings land new_params back in
+                # host memory)
+                def select(new, old):
+                    return jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(finite, n, o), new, old)
+                new_params = select(new_params, params_u)
+                new_opt_state = select(new_opt_state, state.opt_state)
+                if new_comm is not state.comm_state:
+                    new_comm = select(new_comm, state.comm_state)
 
-            new_scale = ls.update_state(state.scale_state, finite, cfg.fp16)
-            new_step = state.step + jnp.where(finite, 1, 0).astype(jnp.int32)
+                new_scale = ls.update_state(state.scale_state, finite,
+                                            cfg.fp16)
+                new_step = state.step \
+                    + jnp.where(finite, 1, 0).astype(jnp.int32)
 
-            lr = jnp.asarray(self.lr_schedule(state.step), jnp.float32)
-            metrics = StepMetrics(
-                loss=mean_loss, grad_norm=grad_norm, lr=lr,
-                loss_scale=state.scale_state.scale,
-                skipped=jnp.logical_not(finite),
-                nonfinite=jnp.logical_not(
-                    jnp.isfinite(mean_loss) & jnp.isfinite(grad_norm)))
-            new_state = TrainState(step=new_step, params=new_params,
-                                   opt_state=new_opt_state,
-                                   scale_state=new_scale, rng=new_rng,
-                                   comm_state=new_comm)
+                lr = jnp.asarray(self.lr_schedule(state.step), jnp.float32)
+                metrics = StepMetrics(
+                    loss=mean_loss, grad_norm=grad_norm, lr=lr,
+                    loss_scale=state.scale_state.scale,
+                    skipped=jnp.logical_not(finite),
+                    nonfinite=jnp.logical_not(
+                        jnp.isfinite(mean_loss) & jnp.isfinite(grad_norm)))
+                new_state = TrainState(step=new_step, params=new_params,
+                                       opt_state=new_opt_state,
+                                       scale_state=new_scale, rng=new_rng,
+                                       comm_state=new_comm)
             return new_state, metrics
 
         if not cfg.compile:

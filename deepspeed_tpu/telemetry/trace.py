@@ -18,6 +18,19 @@ consumer that used to time the boundary itself:
 cannot be opened. Names carry no dots (the benchmark's readers split
 keys on dots). The annotation's arguments are small host integers read
 after planning; they never reach a jit signature.
+
+The device side has the same kind of table. ``with region("norm"):``
+opens ``jax.named_scope("rg.norm")`` around a part of a step program as
+it is traced, so every operation traced inside carries ``rg.norm`` in its
+``op_name``; the profiler writes that path beside each device operation
+(``tf_op``), and ``benchmark/regions.py`` sums device seconds by the
+INNERMOST marked component. A scope changes an instruction's metadata and
+never the instruction: the compiled program is the unscoped one.
+:data:`REGIONS` is that vocabulary, closed like :data:`SPANS`; the names
+mean the same in serving and in training, carry no dot and no slash
+(readers split keys on dots, ``op_name`` on slashes), and say nothing of
+the pass: forward, backward and recompute are read from the path
+(``transpose(jvp``, ``rematted_computation``).
 """
 
 from __future__ import annotations
@@ -65,6 +78,40 @@ SPANS: Dict[str, SpanSpec] = {
     # previous step, sampling, export): profiler only
     "train/step_exit": SpanSpec(None, None, None),
 }
+
+
+REGION_MARK = "rg."
+
+REGIONS = (
+    "embed",        # token gather, positions, validity, position code
+    "norm",         # RMSNorm / LayerNorm of the stream and of a branch
+    "attn_proj",    # q/k/v/out projections, rope, QK-norm, output gate
+    "attn_core",    # the paged / flash / dense attention call, its masks
+    "kv_write",     # rows into the pool or the loop's ring; the flush
+    "linear_attn",  # a delta-rule layer: convolution, decay, state update
+    "mla_proj",     # latent attention's low-rank projections, absorption
+    "mla_core",     # the latent attention call over the one-plane pool
+    "ffn_dense",    # a dense feed-forward
+    "moe_route",    # router, top-k, layout, gathers, the weighted sum
+    "moe_experts",  # the grouped expert matmuls
+    "moe_shared",   # the always-on shared expert
+    "residual",     # the residual adds
+    "head",         # final norm, last-row gather, unembedding
+    "sample",       # argmax, sampling, the chosen token's log-probability
+    "loop_carry",   # token feed, positions and counters between steps
+    "loss",         # the (chunked) cross-entropy
+    "grad_clip",    # mean over micro-batches, unscale, global norm, clip
+    "optimizer",    # the update, the overflow gate, the new state
+)
+
+
+def region(name: str):
+    """``with region("norm"):`` around a part of a step program: a
+    ``jax.named_scope`` under :data:`REGION_MARK`. A name outside
+    :data:`REGIONS` raises when the program is traced."""
+    if name not in REGIONS:
+        raise KeyError(f"{name!r} is not in telemetry.trace.REGIONS")
+    return jax.named_scope(REGION_MARK + name)
 
 
 class SpanSet:

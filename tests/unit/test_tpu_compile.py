@@ -57,6 +57,42 @@ def engine():
         attention_impl="paged_flash"))
 
 
+def _mosaic_call_names(hlo):
+    """Names of the compiled text's Mosaic calls, XLA's numbering cut."""
+    import re
+    return [re.sub(r"\.\d+$", "", name) for name in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)]
+
+
+def test_the_flash_kernels_keep_the_name_their_roofline_reader_matches(
+        one_chip, monkeypatch):
+    """``flash_attn_roofline.train`` matches ``attn-bf16_<B>_<H>_<T>_<D>``:
+    the flash calls are unnamed and take the flax module's name, which
+    ``CausalSelfAttention`` puts back innermost under its ``attn_core``
+    region. Forward, backward and the recompute, from shapes alone."""
+    import deepspeed_tpu.ops.kernels as kernels
+    from deepspeed_tpu.models.gpt2 import CausalSelfAttention, GPT2Config
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    cfg = GPT2Config(num_heads=4, hidden_size=512, attention_impl="flash",
+                     dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    attn = CausalSelfAttention(cfg, name="attn")
+    x = jnp.zeros((2, 1024, 512), jnp.bfloat16)
+    params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)
+
+    def loss(params, x):
+        y = jax.checkpoint(lambda p, x: attn.apply(p, x))(params, x)
+        return y.astype(jnp.float32).sum()
+
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, x))
+    hlo = jax.jit(jax.grad(loss)).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    names = _mosaic_call_names(hlo)
+    assert len(names) >= 3 and set(names) == {"attn"}, names
+    assert "rg.attn_core" in hlo        # and the region is on its path
+
+
 @pytest.mark.parametrize(
     "program", ["step_greedy", "step_greedy_fb", "decode_loop"])
 def test_no_pool_plane_is_materialized(one_chip, engine, program,
@@ -79,6 +115,10 @@ def test_no_pool_plane_is_materialized(one_chip, engine, program,
         lowering_platforms=("tpu",)).compile()
     hlo = exe.as_text()
     assert hlo.count("tpu_custom_call") >= engine.runner.num_layers
+    # an unnamed Pallas call is named in a profile after its innermost
+    # scope: the decode kernel keeps the name ``paged_attn_roofline.*``
+    # match, whatever regions (telemetry/trace.py) are opened around it
+    assert set(_mosaic_call_names(hlo)) == {"closed_call"}
     assert PLANE not in hlo, \
         f"{program}: XLA slices a K or V plane out of the pool"
     # and nothing else of that size is kept beside the (aliased) pool
