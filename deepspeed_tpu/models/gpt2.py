@@ -45,9 +45,12 @@ class GPT2Config:
     # "auto": Pallas flash attention on TPU, XLA fused attention elsewhere;
     # "flash" / "xla" force one path.
     attention_impl: str = "auto"
-    # flash kernel tile geometry (ops/kernels/flash_attention.py):
-    # 512/512 suits seq 512; the benchmark's 2048-token configuration
-    # sets 1024/1024 (benchmark/configs/gpt-1p3b.json)
+    # flash kernel tile geometry (ops/kernels/flash_attention.py): the
+    # DMA tile, not the grain of the causal mask. 512/512 suits seq 512;
+    # the benchmark's 2048-token configuration sets 1024/1024
+    # (benchmark/configs/gpt-1p3b.json), a 2 x 2 grid whose two diagonal
+    # blocks the kernels cut into sub-tiles and compute only on and below
+    # the diagonal, so a large tile no longer costs its masked half
     flash_block_q: int = 512
     flash_block_k: int = 512
     # fused LM-head xent chunking (models/_lm_utils.chunked_lm_xent):
@@ -115,10 +118,9 @@ class CausalSelfAttention(nn.Module):
                 # silently defeating SP — those meshes go through
                 # ulysses/ring attention (parallel/) or plain XLA here
                 impl = "xla"
-        # an unnamed Pallas call takes its trace name from the innermost
-        # scope: the module's own name goes back there, so the flash
-        # kernels stay ``attn-*`` in a profile (flash_attn_roofline.train)
-        with region("attn_core"), jax.named_scope(self.name or "attn"):
+        # the flash kernels name themselves ``attn`` in a profile
+        # (flash_attn_roofline.train), whatever scope calls them
+        with region("attn_core"):
             if impl == "flash":
                 from deepspeed_tpu.ops.kernels import flash_attention
                 y = flash_attention(q, k, v, causal=True, layout="BTHD",

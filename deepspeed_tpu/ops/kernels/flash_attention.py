@@ -12,7 +12,48 @@ multiples (masked), and broadcasts GQA KV heads.
 
 Backward follows the standard FlashAttention-2 recipe: forward additionally
 emits logsumexp; dq is accumulated over KV blocks, dk/dv over Q blocks, with
-delta = rowsum(dO * O) precomputed outside the kernels.
+delta = rowsum(dO * O) precomputed outside the kernels. The dk/dv kernel
+holds its tiles TRANSPOSED (``[key cols, query rows]``: scores as ``k q^T``),
+so ``dv = p^T do`` and ``dk = ds^T q`` are plain matmuls with no transpose
+of a score-sized tile, and lse / delta reach it as lane-dense ``(1,
+block_q)`` rows (4 KB a block where the ``(block_q, 1)`` column the dq
+kernel reads is padded to 512 KB in HBM).
+
+Causal work inside a block. The block is the DMA tile (1024 x 1024 in the
+benchmark's train cells, a 2 x 2 grid over T = 2048) and NOT the grain of
+the causal mask: a grid step knows from its position which of three kinds
+its block is (``_block_kinds``) and each kind has its own body in the three
+dense kernels.
+
+* interior: the diagonal never touches it. No iota, no compare, no select:
+  scores go straight to the online softmax (backward: to ``exp(s - lse)``).
+* aligned diagonal: a square block the diagonal enters at its top-left
+  corner. It is cut into ``n x n`` sub-tiles (``_sub_tiles``: halves in the
+  forward, quarters in the backward, never under 128 rows) and walked one
+  STRIP a sub-tile: forward and dq take query sub-tile ``i`` against the key
+  columns up to its own, dk/dv takes key sub-tile ``i`` against the query
+  rows from its own down (per-row state makes a slice independent). The
+  sub-tiles above the diagonal are never computed (no matmul, no exp); the
+  strip is masked as one.
+* general: everything else (non-causal, a padded last key block, ``tq !=
+  tk`` or a ring hop whose diagonal meets no block corner): the whole block
+  under its ``[block_q, block_k]`` mask.
+
+A block wholly above the diagonal runs nothing, and its grid step fetches
+nothing either: the index maps hold the last block the row (the column, in
+dk/dv) does need (``_last_key_block``, ``_first_query_block``).
+
+So a large tile no longer pays for the masked half of its diagonal blocks:
+at T = 2048 and 1024-blocks the forward computes 0.625 of the square and dq
+and dk/dv 0.5625, where a whole-block skip alone computes 0.75 (the mask
+needs 0.5). ``causal_plan`` counts the kinds and the score elements of one
+call from its static shapes, and ``flash_attention`` notes it for every
+causal call it traces (``take_causal_plans``: the train engine's
+``flash_score_elems_*``).
+
+Three bodies a kernel cost three times the tracing and lowering, so the
+launchers ``_fwd`` / ``_bwd`` are inner jits: a model's unrolled layers
+share one traced and lowered copy of each call, named ``attn`` in a profile.
 """
 
 from __future__ import annotations
@@ -36,12 +77,212 @@ def _round_up(x: int, m: int) -> int:
 
 
 # ---------------------------------------------------------------------------
+# causal work inside a block: the three kinds, the sub-tile rule, the plan
+# ---------------------------------------------------------------------------
+
+#: sub-tiles a side of an aligned diagonal block, at most, by kernel. On
+#: v5e at [2, 16, 2048, 128] and 1024-blocks (PERF.md, PR 39): every strip
+#: of the forward is one online-softmax update whose matmul, softmax and
+#: matmul run one after the other, so halves beat quarters (0.392 against
+#: 0.415 ms a call; eighths 0.458); the backward kernels only accumulate,
+#: and quarters beat halves (dq 0.412 / 0.445, dk/dv 0.508 / 0.553 ms).
+_MAX_SUB_TILES = {"fwd": 2, "dq": 4, "dkv": 4}
+
+
+def _sub_tiles(block: int, kernel: str) -> int:
+    """Sub-tiles a side of an aligned diagonal block in ``kernel``: the
+    most (up to ``_MAX_SUB_TILES``) that keep a sub-tile a whole number of
+    128-lane tiles. Backward: 1024 -> 4 x 256, 512 -> 4 x 128, 256 -> 2 x
+    128; a block of 128, or one that does not divide, is its own sub-tile."""
+    for n in range(_MAX_SUB_TILES[kernel], 1, -1):
+        if block % (n * _LANES) == 0:
+            return n
+    return 1
+
+
+def _block_kinds(qi, ki, *, causal, block_q, block_k, kv_len, causal_offset):
+    """(interior, diagonal, general) for the block at grid position
+    ``(qi, ki)``: at most one holds, none for a causal block wholly above
+    the diagonal. Works on traced ``program_id``s and, for ``_grid_kinds``,
+    on plain ints."""
+    if not causal:
+        return False, False, True
+    row0 = qi * block_q + causal_offset       # diagonal column of row 0
+    col0 = ki * block_k
+    # blocks strictly above the (bottom-right-aligned) diagonal run nothing
+    run = col0 <= row0 + (block_q - 1)
+    crossed = col0 + (block_k - 1) > row0     # the diagonal cuts the block
+    padded = col0 + block_k > kv_len          # it holds padded key columns
+    interior = (col0 + (block_k - 1) <= row0) & (col0 + block_k <= kv_len)
+    if block_q != block_k or causal_offset % block_q:
+        return interior, False, run & (crossed | padded)
+    diagonal = (row0 == col0) & (col0 + block_k <= kv_len)
+    return interior, diagonal, run & ((crossed & (row0 != col0)) | padded)
+
+
+def _grid_kinds(nq: int, nk: int, **geom):
+    """How many blocks of an ``nq`` x ``nk`` grid are (interior, diagonal,
+    general). Static: a kernel traces no body for a kind its grid lacks
+    (the train cells' grid has no general block), and ``causal_plan``
+    reports the counts."""
+    counts = [0, 0, 0]
+    for qi in range(nq):
+        for ki in range(nk):
+            for i, kind in enumerate(_block_kinds(qi, ki, **geom)):
+                counts[i] += bool(kind)
+    return tuple(counts)
+
+
+def _causal_mask(rows: int, cols: int, diag, transposed: bool):
+    """``row + diag >= col`` over a ``rows`` x ``cols`` tile (local
+    indices; ``diag`` is the tile's first row's diagonal column less its
+    first column). ``transposed``: laid out ``[cols, rows]``, as the dk/dv
+    kernel holds its scores."""
+    shape = (cols, rows) if transposed else (rows, cols)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transposed else 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if transposed else 1)
+    return row + diag >= col
+
+
+def _general_mask(qi, ki, lse=None, *, transposed=False, causal, block_q,
+                  block_k, kv_len, causal_offset):
+    """The whole-block mask of the general kind: key padding, the causal
+    diagonal wherever it runs and, in the backward, query rows no key is
+    visible to (``lse == -inf``; ``exp(s - lse)`` would be inf there)."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    col = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0 if transposed else 1)
+    mask = col < kv_len
+    if causal:
+        mask = jnp.logical_and(mask, _causal_mask(
+            block_q, block_k,
+            qi * block_q + causal_offset - ki * block_k, transposed))
+    if lse is not None:
+        mask = jnp.logical_and(mask, jnp.isfinite(lse))
+    return mask
+
+
+def _update_by_kind(qi, ki, update, *, kernel, present, lse=None, **geom):
+    """Run ``update(mask, rows, cols)`` over the block at ``(qi, ki)`` as
+    its kind asks: once and unmasked (interior), once a sub-tile strip on
+    and below the diagonal (aligned diagonal), or once under the
+    whole-block mask (general). The strips follow the axis whose slices
+    are independent in ``kernel``: per-query-row state (``"fwd"``,
+    ``"dq"``) takes query sub-tile ``i`` against the key columns up to its
+    own; per-key-row state (``"dkv"``) takes key sub-tile ``i`` against
+    the query rows from its own down, masks laid out ``[cols, rows]`` as
+    that kernel holds its tiles. One strip is one update: a strip cut
+    again into its unmasked part and the sub-tile on the diagonal paid
+    more for the second update than the narrower mask saved. ``lse`` is a
+    thunk for the block's logsumexp where the general mask needs it.
+    ``present`` (``_grid_kinds``) drops the bodies of kinds this grid
+    lacks."""
+    interior, diagonal, general = _block_kinds(qi, ki, **geom)
+    block_q, block_k = geom["block_q"], geom["block_k"]
+    whole_q, whole_k = pl.ds(0, block_q), pl.ds(0, block_k)
+    transposed = kernel == "dkv"
+
+    if present[0]:
+        pl.when(interior)(lambda: update(None, whole_q, whole_k))
+
+    if present[1]:
+        @pl.when(diagonal)
+        def _sub_tiled():
+            n = _sub_tiles(block_q, kernel)
+            sub = block_q // n
+            for i in range(n):
+                if transposed:
+                    rows, cols, diag = ((i * sub, block_q - i * sub),
+                                        (i * sub, sub), 0)
+                else:
+                    rows, cols, diag = ((i * sub, sub), (0, (i + 1) * sub),
+                                        i * sub)
+                update(_causal_mask(rows[1], cols[1], diag, transposed),
+                       pl.ds(*rows), pl.ds(*cols))
+
+    if present[2]:
+        @pl.when(general)
+        def _whole_block():
+            update(_general_mask(qi, ki, None if lse is None else lse(),
+                                 transposed=transposed, **geom),
+                   whole_q, whole_k)
+
+
+def _last_key_block(qi, ki, nk, *, causal, block_q, block_k, causal_offset,
+                    **_):
+    """The key block to hold at grid step ``(qi, ki)``: ``ki`` itself
+    while the query block sees it, else the last one it does see. A step
+    above the diagonal computes nothing, and a block index that repeats
+    the step before costs no DMA (nor the re-fetch of block 0 when the
+    next query block starts)."""
+    if not causal:
+        return ki
+    last = (qi * block_q + (block_q - 1) + causal_offset) // block_k
+    return jnp.minimum(ki, jnp.clip(last, 0, nk - 1))
+
+
+def _first_query_block(qi, ki, nq, *, causal, block_q, block_k, causal_offset,
+                       **_):
+    """The dk/dv walk's counterpart: the query block to hold at step
+    ``(ki, qi)`` is ``qi`` once it sees the key block, else the first one
+    that does."""
+    if not causal:
+        return qi
+    first = (ki * block_k - causal_offset) // block_q
+    return jnp.maximum(qi, jnp.clip(first, 0, nq - 1))
+
+
+def causal_plan(tq: int, tk: int, block_q: int, block_k: int, kv_len: int,
+                causal_offset: int) -> dict:
+    """What the three kernels of ONE causal call over padded lengths ``tq``
+    x ``tk`` compute for one (batch, head), from its static shapes alone:
+    how many blocks of each kind the grid holds (the kernels share it),
+    the side of a sub-tile in each kernel, the score elements the forward,
+    dq and dk/dv compute together, and the elements the mask needs of the
+    three (``row + causal_offset >= col``, ``col < kv_len``).
+    ``score_area_share`` is computed / needed: 1.0 would be kernels that
+    compute no masked score."""
+    nq, nk = tq // block_q, tk // block_k
+    interior, diagonal, general = _grid_kinds(
+        nq, nk, causal=True, block_q=block_q, block_k=block_k, kv_len=kv_len,
+        causal_offset=causal_offset)
+    plan = {"interior": interior, "sub_tiled": diagonal, "general": general,
+            "skipped": nq * nk - interior - diagonal - general, "sub": {}}
+    computed = 0
+    for kernel in _MAX_SUB_TILES:
+        n = _sub_tiles(block_q, kernel)
+        plan["sub"][kernel] = sub = block_q // n
+        computed += ((interior + general) * block_q * block_k
+                     + diagonal * (n * (n + 1) // 2) * sub * sub)
+    visible = np.clip(np.arange(tq) + causal_offset + 1, 0, kv_len)
+    plan["score_elems_computed"] = computed
+    plan["score_elems_needed"] = len(_MAX_SUB_TILES) * int(visible.sum())
+    plan["score_area_share"] = computed / max(plan["score_elems_needed"], 1)
+    return plan
+
+
+#: (batch, heads, plan) of every causal ``flash_attention`` call traced
+#: since the last ``take_causal_plans``
+_CAUSAL_PLANS: list = []
+
+
+def take_causal_plans() -> list:
+    """The plans noted since the last call, as ``(batch, heads, plan)``;
+    the record is cleared. A caller that traces a program (the train
+    engine around its step function) reads what that program will compute
+    a run; calls are noted when TRACED, so a cached program notes none."""
+    plans = list(_CAUSAL_PLANS)
+    _CAUSAL_PLANS.clear()
+    return plans
+
+
+# ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale, causal, block_q, block_k, kv_len, causal_offset):
+                *, sm_scale, present, **geom):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -52,22 +293,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros(l_scr.shape, l_scr.dtype)
         acc_scr[:] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
 
-    run = True
-    if causal:
-        # skip blocks strictly above the (bottom-right-aligned) diagonal
-        run = ki * block_k <= qi * block_q + (block_q - 1) + causal_offset
-
-    @pl.when(run)
-    def _compute():
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, row + causal_offset >= col)
+    def update(mask, rows, cols):
         _online_softmax_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                              mask, sm_scale)
+                              mask, sm_scale, rows, cols)
+
+    _update_by_kind(qi, ki, update, kernel="fwd", present=present, **geom)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -89,28 +319,34 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def _online_softmax_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                          s_mask, sm_scale):
+                          s_mask, sm_scale, rows=None, cols=None):
     """One flash block update (shared by the dense and sparse kernels):
-    scores for the current (q, k) tile, ``s_mask`` applied, online-softmax
-    accumulators advanced. Matmul operands stay in their storage dtype
-    (bf16 runs the MXU at full rate) with fp32 accumulation."""
-    q = q_ref[0, 0]
-    k = k_ref[0, 0]
+    scores for the current (q, k) tile, ``s_mask`` applied (None: every
+    score counts), online-softmax accumulators advanced. ``rows`` /
+    ``cols`` (``pl.ds``) narrow the update to a sub-tile of the block:
+    the accumulators are per query row, so a row slice is independent.
+    Matmul operands stay in their storage dtype (bf16 runs the MXU at
+    full rate) with fp32 accumulation."""
+    rows = slice(None) if rows is None else rows
+    cols = slice(None) if cols is None else cols
+    q = q_ref[0, 0, rows, :]
+    k = k_ref[0, 0, cols, :]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
-    s = jnp.where(s_mask, s, _NEG_INF)
-    m_prev = m_scr[:]
-    l_prev = l_scr[:]
+    if s_mask is not None:
+        s = jnp.where(s_mask, s, _NEG_INF)
+    m_prev = m_scr[rows, :]
+    l_prev = l_scr[rows, :]
     m_cur = jnp.max(s, axis=1, keepdims=True)
     m_next = jnp.maximum(m_prev, m_cur)
     alpha = jnp.exp(m_prev - m_next)
     p = jnp.exp(s - m_next[:, :1])
-    l_scr[:] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    m_scr[:] = m_next
-    v = v_ref[0, 0]
+    l_scr[rows, :] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    m_scr[rows, :] = m_next
+    v = v_ref[0, 0, cols, :]
     pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    acc_scr[:] = acc_scr[:] * alpha[:, :1] + pv
+    acc_scr[rows, :] = acc_scr[rows, :] * alpha[:, :1] + pv
 
 
 def _fwd_sparse_kernel(mask_ref, fetch_ref, q_ref, k_ref, v_ref, o_ref,
@@ -258,31 +494,42 @@ def flash_attention_sparse(q, k, v, block_mask, *, sm_scale=None,
     return o
 
 
+# ``_fwd`` and ``_bwd`` are jitted: a model calls them once a layer with the
+# same shapes, and an inner jit traces the kernels' bodies and lowers them to
+# Mosaic ONCE a program where 24 unrolled layers did it 24 times (the three
+# bodies a kernel make that 8 s of a warm start at 1.3B; PERF.md, PR 39).
+# The calls name themselves ``attn``: without a name a Mosaic call takes the
+# caller's innermost scope, which under the inner jit would be ``_fwd``.
+_KERNEL_NAME = "attn"
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
          interpret, group=1):
     b, h, tq, d = q.shape
     tk = k.shape[2]
     nq, nk = tq // block_q, tk // block_k
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, kv_len=kv_len,
-        causal_offset=causal_offset)
+    geom = dict(causal=causal, block_q=block_q, block_k=block_k,
+                kv_len=kv_len, causal_offset=causal_offset)
+    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
+                               present=_grid_kinds(nq, nk, **geom), **geom)
     grid = (b, h, nq, nk)
     out_shape = [
         jax.ShapeDtypeStruct((b, h, tq, d), q.dtype),
         jax.ShapeDtypeStruct((b, h, tq, 1), jnp.float32),
     ]
+    # GQA: K/V stay (b, h//group, t, d); the index map broadcasts a KV
+    # head across its q-head group — no materialized repeat
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, d),
+        lambda b, h, i, j: (b, h // group, _last_key_block(i, j, nk, **geom),
+                            0))
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
-            # GQA: K/V stay (b, h//group, t, d); the index map broadcasts a
-            # KV head across its q-head group — no materialized repeat
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, i, j: (b, h // group, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, i, j: (b, h // group, j, 0)),
+            kv_spec, kv_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
@@ -298,6 +545,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=_KERNEL_NAME,
     )(q, k, v)
     return o, lse
 
@@ -307,9 +555,30 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
 # ---------------------------------------------------------------------------
 
 
+def _bwd_tile(q, k, v, do, lse, delta, mask, sm_scale, transposed):
+    """Probabilities ``p`` and score cotangents ``ds`` (float32) of one
+    tile from its operands. ``mask`` None: every score of the tile counts.
+    ``transposed``: the tile is held ``[cols, rows]`` (scores as ``k q^T``,
+    ``lse`` / ``delta`` as ``(1, rows)`` rows): what the dk/dv kernel
+    contracts over query rows then needs no transpose. Matmul operands stay
+    in storage dtype (bf16 MXU) with f32 accumulation."""
+    nt = (((1,), (1,)), ((), ()))
+    a, b = (k, q) if transposed else (q, k)
+    s = jax.lax.dot_general(a, b, nt,
+                            preferred_element_type=jnp.float32) * sm_scale
+    if mask is None:
+        # a query row no key is visible to (lse == -inf) lies in no
+        # unmasked tile; the guard stays, on the column (or row) alone
+        p = jnp.exp(s - jnp.where(jnp.isfinite(lse), lse, jnp.inf))
+    else:
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+    a, b = (v, do) if transposed else (do, v)
+    dp = jax.lax.dot_general(a, b, nt, preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * sm_scale
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, sm_scale, causal, block_q, block_k, kv_len,
-                   causal_offset):
+                   dq_scr, *, sm_scale, present, **geom):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -318,37 +587,17 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[:] = jnp.zeros(dq_scr.shape, dq_scr.dtype)
 
-    run = True
-    if causal:
-        run = ki * block_k <= qi * block_q + (block_q - 1) + causal_offset
+    def update(mask, rows, cols):
+        k = k_ref[0, 0, cols, :]
+        _, ds = _bwd_tile(q_ref[0, 0, rows, :], k, v_ref[0, 0, cols, :],
+                          do_ref[0, 0, rows, :], lse_ref[0, 0, rows, :],
+                          delta_ref[0, 0, rows, :], mask, sm_scale, False)
+        dq_scr[rows, :] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _compute():
-        # matmul operands stay in storage dtype (bf16 MXU) w/ f32 accumulation
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                                   # (bq, 1)
-        delta = delta_ref[0, 0]                               # (bq, 1)
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, row + causal_offset >= col)
-        # padded q rows have lse == -inf; exp(s - lse) would be inf there
-        mask = jnp.logical_and(mask, jnp.isfinite(lse))
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
-        dq_scr[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+    _update_by_kind(qi, ki, update, kernel="dq", present=present,
+                    lse=lambda: lse_ref[0, 0], **geom)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -357,13 +606,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, sm_scale, causal, block_q, block_k, kv_len,
-                    causal_offset, nq):
+                    *, sm_scale, present, nq, **geom):
     # GQA grouped accumulation: the grid's innermost dim fuses (q-head in
     # group, q block) as gq = qh * nq + qi, so ONE kv head's dk/dv
     # accumulates over every q head it serves before the block is written
-    # (init at the first step, finish at the last). group == 1 reduces to
-    # the ungrouped order exactly.
+    # — K/V never get materialized per q-head and the cotangent comes out
+    # already (b, hk, t, d)
     ki = pl.program_id(2)
     gq = pl.program_id(3)
     ng = pl.num_programs(3)
@@ -374,39 +622,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros(dk_scr.shape, dk_scr.dtype)
         dv_scr[:] = jnp.zeros(dv_scr.shape, dv_scr.dtype)
 
-    run = True
-    if causal:
-        run = qi * block_q + (block_q - 1) + causal_offset >= ki * block_k
+    # tiles are held [cols, rows]: dv = p^T do and dk = ds^T q contract
+    # over query rows, which in this layout is a plain matmul; lse and
+    # delta come as lane-dense (1, block_q) rows
+    def update(mask, rows, cols):
+        q = q_ref[0, 0, rows, :]
+        do = do_ref[0, 0, rows, :]
+        p, ds = _bwd_tile(q, k_ref[0, 0, cols, :], v_ref[0, 0, cols, :], do,
+                          lse_ref[0, 0, :, rows], delta_ref[0, 0, :, rows],
+                          mask, sm_scale, True)
+        nn = (((1,), (0,)), ((), ()))
+        dv_scr[cols, :] += jax.lax.dot_general(
+            p.astype(do.dtype), do, nn, preferred_element_type=jnp.float32)
+        dk_scr[cols, :] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, nn, preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _compute():
-        # matmul operands stay in storage dtype (bf16 MXU) w/ f32 accumulation
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                                   # (bq, 1)
-        delta = delta_ref[0, 0]                               # (bq, 1)
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, row + causal_offset >= col)
-        mask = jnp.logical_and(mask, jnp.isfinite(lse))
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)            # (bq, bk)
-        dv_scr[:] += jax.lax.dot_general(p.astype(do.dtype), do,
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)    # (bq, bk)
-        dk_scr[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+    _update_by_kind(qi, ki, update, kernel="dkv", present=present,
+                    lse=lambda: lse_ref[0, 0], **geom)
 
     @pl.when(gq == ng - 1)
     def _finish():
@@ -414,6 +646,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6),
+                   static_argnames=("group",))
 def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
          res, g, dlse=None, group=1):
     q, k, v, o, lse = res
@@ -422,6 +656,9 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
     hk = k.shape[1]
     tk = k.shape[2]
     nq, nk = tq // block_q, tk // block_k
+    geom = dict(causal=causal, block_q=block_q, block_k=block_k,
+                kv_len=kv_len, causal_offset=causal_offset)
+    present = _grid_kinds(nq, nk, **geom)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)                    # (b, h, tq, 1)
@@ -432,17 +669,17 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
         # same kernels run with delta' = delta - dlse.
         delta = delta - dlse.astype(jnp.float32)
 
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, d),
+        lambda b, h, i, j: (b, h // group, _last_key_block(i, j, nk, **geom),
+                            0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, kv_len=kv_len,
-                          causal_offset=causal_offset),
+        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, present=present,
+                          **geom),
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, i, j: (b, h // group, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, i, j: (b, h // group, j, 0)),
+            kv_spec, kv_spec,
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
@@ -455,24 +692,29 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=_KERNEL_NAME,
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid walks KV heads (hk = h // group); the innermost dim fuses
     # (q-head in group, q block) so each kv head's cotangent sums its whole
     # q-head group in-scratch — the index maps pick the q-side head as
     # hh * group + gq // nq and the q block as gq % nq.
+    # lse and delta go in as lane-dense (b, h, 1, tq) rows: the kernel
+    # holds its tiles [cols, rows].
+    def q_block(i, gq):
+        return _first_query_block(gq % nq, i, nq, **geom)
+
     q_spec = pl.BlockSpec(
         (1, 1, block_q, d),
-        lambda b, hh, i, gq: (b, hh * group + gq // nq, gq % nq, 0))
+        lambda b, hh, i, gq: (b, hh * group + gq // nq, q_block(i, gq), 0))
     kv_spec = pl.BlockSpec((1, 1, block_k, d),
                            lambda b, hh, i, gq: (b, hh, i, 0))
     row_spec = pl.BlockSpec(
-        (1, 1, block_q, 1),
-        lambda b, hh, i, gq: (b, hh * group + gq // nq, gq % nq, 0))
+        (1, 1, 1, block_q),
+        lambda b, hh, i, gq: (b, hh * group + gq // nq, 0, q_block(i, gq)))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, kv_len=kv_len,
-                          causal_offset=causal_offset, nq=nq),
+        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, present=present,
+                          nq=nq, **geom),
         grid=(b, hk, nk, group * nq),
         in_specs=[
             q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
@@ -491,7 +733,8 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+        name=_KERNEL_NAME,
+    )(q, k, v, do, jnp.swapaxes(lse, 2, 3), jnp.swapaxes(delta, 2, 3))
     return dq, dk, dv
 
 
@@ -562,7 +805,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
       k, v: same layout; KV head count may divide H (GQA — heads broadcast).
       causal: lower-triangular mask.
       sm_scale: softmax scale, default 1/sqrt(D).
-      block_q/block_k: tile sizes (clamped to the padded sequence). 512/512
+      block_q/block_k: tile sizes (clamped to the padded sequence; block_q
+        a multiple of 128 outside interpret mode). 512/512
         measured ~1.25x faster than XLA fused attention at T=512 and ~1.9x
         at T=2048 on v5e (fwd+bwd); 128/128 is ~2x SLOWER — small tiles
         leave the MXU idle between grid steps.
@@ -594,6 +838,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     block_q = min(block_q, _round_up(tq, _LANES))
     block_k = min(block_k, _round_up(tk, _LANES))
+    if block_q % _LANES and not interpret:
+        # the dk/dv kernel reads lse and delta as (1, block_q) lane rows
+        raise ValueError(
+            f"block_q must be a multiple of {_LANES} on the chip, got "
+            f"{block_q}")
     tq_p, tk_p = _round_up(tq, block_q), _round_up(tk, block_k)
     pad_q, pad_k = tq_p - tq, tk_p - tk
     if pad_q:
@@ -607,6 +856,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     # whole prefix.
     args = (q, k, v, causal, float(sm_scale), block_q, block_k, tk,
             tk - tq, interpret, group)
+    if causal:
+        _CAUSAL_PLANS.append((b, h, causal_plan(tq_p, tk_p, block_q, block_k,
+                                                tk, tk - tq)))
     if return_lse:
         o, lse = _flash_lse(*args)
         lse = lse[..., 0]                                  # (b, h, tq_p)

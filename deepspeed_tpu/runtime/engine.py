@@ -21,8 +21,9 @@ API parity (reference engine.py):
 from __future__ import annotations
 
 import os
+import sys
 import time
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,14 @@ from ..utils.timer import (
 from . import loss_scaler as ls
 from .lr_schedules import build_schedule
 from .zero.sharding import ZeroShardingPlan
+
+
+def _take_flash_plans() -> list:
+    """The causal plans ``ops/kernels/flash_attention.py`` noted since the
+    last call. The module is looked up, not imported: a model that never
+    called the kernel never loaded Pallas, and noted nothing."""
+    mod = sys.modules.get("deepspeed_tpu.ops.kernels.flash_attention")
+    return mod.take_causal_plans() if mod is not None else []
 
 
 class TrainState(NamedTuple):
@@ -197,7 +206,13 @@ class Engine:
         #: without the registry; filled by the brackets of
         #: telemetry/trace.py
         self.step_stats = {"steps": 0, "stage_s": 0.0, "dispatch_s": 0.0,
-                           "commit_apply_s": 0.0}
+                           "commit_apply_s": 0.0,
+                           "flash_score_elems_computed": 0,
+                           "flash_score_elems_needed": 0}
+        #: what ONE step's causal flash calls compute / need, from the
+        #: plans noted while the step function was traced
+        self._flash_elems = {"flash_score_elems_computed": 0,
+                             "flash_score_elems_needed": 0}
         self._spans = SpanSet(self.step_stats, lambda: self._train_obs)
         self._train_obs = train_observer(self)
 
@@ -827,6 +842,22 @@ class Engine:
     def mesh(self):
         return self.topology.mesh
 
+    def _flash_score_elems(self) -> Dict[str, int]:
+        """One step's ``flash_score_elems_computed`` / ``_needed``: score
+        elements the causal flash kernels compute, and those the mask
+        needs, summed over the calls noted while this step's program was
+        traced (``flash_attention.causal_plan``; a call in a scanned body
+        is noted once, under ``shard_map`` with its shard's batch and
+        heads). Their ratio is the kernels' ``score_area_share``; 0 and 0
+        for a model without the kernel."""
+        plans = _take_flash_plans()
+        if plans:       # this dispatch traced the step function
+            self._flash_elems = {
+                "flash_score_elems_" + key: sum(
+                    b * h * plan["score_elems_" + key] for b, h, plan in plans)
+                for key in ("computed", "needed")}
+        return self._flash_elems
+
     def train_batch(self, batch: Any) -> jnp.ndarray:
         """Run one full global step (micro_batch × GAS samples) and return the
         mean loss. The one-call equivalent of forward+backward+step.
@@ -867,8 +898,10 @@ class Engine:
                 self._ensure_params_resident()
                 if self._watchdog is not None:
                     self._watchdog.phase("compiled_step")
-            with spans.span("train/dispatch", step=step):
+            with spans.span("train/dispatch", step=step) as span:
+                _take_flash_plans()     # another program's, traced since
                 self.state, metrics = self._train_step(self.state, batch)
+                span.count(**self._flash_score_elems())
             # the exposed device wait, with one step queued behind it:
             # the PREVIOUS step's metrics, which the observer's sentinel
             # then reads as ready values (nothing to wait for without

@@ -129,6 +129,105 @@ class TestFlashAttention:
         with pytest.raises(ValueError, match="attention_impl"):
             model.init(jax.random.PRNGKey(0), toks)
 
+    # (tq, tk, q heads, kv heads, block, kv padding) -> blocks of each kind a
+    # (batch, head): interior / sub-tiled / general
+    BLOCK_KIND_CASES = {
+        "three_kinds": ((700, 700, 2, 2, 256), (3, 2, 1)),
+        "sub64_of_256": ((512, 512, 2, 2, 256), (1, 2, 0)),
+        "cell_blocks_1024": ((2048, 2048, 1, 1, 1024), (1, 2, 0)),
+        "gqa_dkv_walk": ((768, 768, 4, 2, 256), (3, 3, 0)),
+        "one_block_512": ((512, 512, 2, 1, 512), (0, 1, 0)),
+        "block_128_is_its_own_sub_tile": ((256, 256, 2, 2, 128), (1, 2, 0)),
+        "tq_lt_tk_aligned": ((256, 512, 2, 2, 256), (1, 1, 0)),
+        "tq_lt_tk_unaligned": ((200, 456, 2, 2, 256), (1, 0, 1)),
+        "padded_kv_len": ((200, 200, 2, 2, 256), (0, 0, 1)),
+    }
+
+    @pytest.mark.parametrize("case", sorted(BLOCK_KIND_CASES))
+    def test_block_kinds_parity(self, case):
+        """Forward and gradients against the reference for grids that hold
+        interior, sub-tiled (aligned diagonal) and general blocks: an
+        aligned diagonal block computes only the strips on and below the
+        diagonal, an interior block builds no mask, and everything else
+        (padding, an offset that meets no block corner) keeps the
+        whole-block mask and its old answer."""
+        from deepspeed_tpu.ops.kernels.flash_attention import \
+            take_causal_plans
+        (tq, tk, h, hk, block), kinds = self.BLOCK_KIND_CASES[case]
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(tq + tk + h), 3)
+        q = _rand(k1, (1, tq, h, 16))
+        k = _rand(k2, (1, tk, hk, 16))
+        v = _rand(k3, (1, tk, hk, 16))
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=True,
+                                   block_q=block, block_k=block)
+
+        take_causal_plans()
+        out = flash(q, k, v)
+        (b_, h_, plan), = take_causal_plans()
+        assert (b_, h_) == (1, h)
+        assert (plan["interior"], plan["sub_tiled"], plan["general"]) == kinds
+        ref = attention_reference(q, k, v, causal=True)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+        g1 = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))),
+                      argnums=(0, 1, 2))(q, k, v)
+        g2 = jax.grad(lambda *a: jnp.sum(jnp.sin(
+            attention_reference(*a, causal=True))), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+    @pytest.mark.parametrize("geom,want", [
+        # the train cells: T 2048 at 1024-blocks, offset 0
+        ((2048, 2048, 1024, 1024, 2048, 0),
+         dict(interior=1, sub_tiled=2, general=0, skipped=1,
+              sub={"fwd": 512, "dq": 256, "dkv": 256},
+              computed=1024 * 1024 * 3 + 2 * (3 * 512 ** 2 + 2 * 10 * 256 ** 2),
+              needed=3 * (2048 * 2049 // 2))),
+        # the defaults over the same T: 4 x 4 blocks of 512
+        ((2048, 2048, 512, 512, 2048, 0),
+         dict(interior=6, sub_tiled=4, general=0, skipped=6,
+              sub={"fwd": 256, "dq": 128, "dkv": 128},
+              computed=6 * 512 ** 2 * 3 + 4 * (3 * 256 ** 2 + 2 * 10 * 128 ** 2),
+              needed=3 * (2048 * 2049 // 2))),
+        # ring attention's diagonal hop: a shard's T / sp against itself
+        ((512, 512, 512, 512, 512, 0),
+         dict(interior=0, sub_tiled=1, general=0, skipped=0,
+              sub={"fwd": 256, "dq": 128, "dkv": 128},
+              computed=3 * 256 ** 2 + 2 * 10 * 128 ** 2,
+              needed=3 * (512 * 513 // 2))),
+        # a query shard one whole block below its keys: offset = a block
+        ((512, 1024, 512, 512, 1024, 512),
+         dict(interior=1, sub_tiled=1, general=0, skipped=0,
+              sub={"fwd": 256, "dq": 128, "dkv": 128},
+              computed=3 * 512 ** 2 + 3 * 256 ** 2 + 2 * 10 * 128 ** 2,
+              needed=3 * (512 * 512 + 512 * 513 // 2))),
+        # an offset that meets no block corner: every crossed block general
+        ((512, 1024, 512, 512, 900, 388),
+         dict(interior=0, sub_tiled=0, general=2, skipped=0,
+              sub={"fwd": 256, "dq": 128, "dkv": 128},
+              computed=3 * 2 * 512 ** 2,
+              needed=3 * sum(min(r + 389, 900) for r in range(512)))),
+    ])
+    def test_causal_plan(self, geom, want):
+        from deepspeed_tpu.ops.kernels.flash_attention import causal_plan
+        plan = causal_plan(*geom)
+        for key in ("interior", "sub_tiled", "general", "skipped", "sub"):
+            assert plan[key] == want[key], key
+        assert plan["score_elems_computed"] == want["computed"]
+        assert plan["score_elems_needed"] == want["needed"]
+        assert plan["score_area_share"] == pytest.approx(
+            want["computed"] / want["needed"])
+        assert plan["score_area_share"] >= 1.0
+
+    def test_block_q_must_fill_lanes_on_the_chip(self):
+        """The dk/dv kernel reads lse and delta as (1, block_q) lane rows."""
+        x = jnp.zeros((1, 128, 1, 16))
+        with pytest.raises(ValueError, match="multiple of 128"):
+            flash_attention(x, x, x, block_q=64, interpret=False)
+
     def test_multi_block(self):
         """Sequence spanning several KV blocks (online-softmax accumulation)."""
         k1, k2, k3 = jax.random.split(jax.random.PRNGKey(4), 3)

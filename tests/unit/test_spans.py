@@ -32,13 +32,14 @@ def _engine(**kw):
     return InferenceEngineV2(mcfg, params, RaggedInferenceConfig(**base))
 
 
-def _train_engine():
+def _train_engine(**model_kw):
     import jax
     import jax.numpy as jnp
 
     import deepspeed_tpu as dstpu
     from deepspeed_tpu.models.gpt2 import GPT2Config, make_model
-    _, init_fn, loss_fn = make_model(GPT2Config.tiny(dtype=jnp.float32))
+    _, init_fn, loss_fn = make_model(GPT2Config.tiny(dtype=jnp.float32,
+                                                     **model_kw))
     params = init_fn(jax.random.PRNGKey(0), batch_size=2, seq_len=17)
     engine, _, _, _ = dstpu.initialize(
         loss_fn=loss_fn, params=params,
@@ -329,9 +330,10 @@ class TestTrainBrackets:
             eng.train_batch(b)
         st = eng.step_stats
         assert st["steps"] == 3
+        flash = {"flash_score_elems_computed", "flash_score_elems_needed"}
         assert set(st) == {"steps", "stage_s", "dispatch_s",
-                           "commit_apply_s"}
-        assert all(st[k] > 0.0 for k in st)
+                           "commit_apply_s"} | flash
+        assert all(st[k] > 0.0 for k in set(st) - flash)
         names = [s[0] for s in eng._train_obs.flight.spans]
         assert names == ["stage", "dispatch", "device_execute",
                          "commit_apply"] * 3
@@ -340,6 +342,31 @@ class TestTrainBrackets:
         assert hist["train_dispatch_s"]["sum"] \
             == pytest.approx(st["dispatch_s"], rel=1e-9)
         assert hist["train_device_execute_s"]["count"] == 3
+
+    @pytest.mark.parametrize("impl", ["xla", "flash"])
+    def test_flash_score_elems_grow_with_the_steps(self, impl):
+        """What the causal flash kernels compute and what the mask needs,
+        from the plans noted while the step function was traced: the same
+        two numbers every step, their ratio the plan's share; 0 and 0 for
+        a program without the kernel."""
+        from deepspeed_tpu.ops.kernels.flash_attention import causal_plan
+        eng, batches = _train_engine(attention_impl=impl)
+        seen = []
+        for b in batches:
+            eng.train_batch(b)
+            seen.append((eng.step_stats["flash_score_elems_computed"],
+                         eng.step_stats["flash_score_elems_needed"]))
+        if impl == "xla":
+            assert seen == [(0, 0)] * 3
+            return
+        # T = 17 under one 128-block: a general block a (batch, head)
+        plan = causal_plan(128, 128, 128, 128, 17, 0)
+        assert plan["general"] == 1
+        computed, needed = seen[0]
+        assert computed > 0 and computed % plan["score_elems_computed"] == 0
+        assert computed * plan["score_elems_needed"] \
+            == needed * plan["score_elems_computed"]
+        assert seen == [(computed * n, needed * n) for n in (1, 2, 3)]
 
     def test_step_stats_fill_with_the_observer_off(self, monkeypatch):
         monkeypatch.setenv("DSTPU_TRAIN_OBS", "0")

@@ -67,9 +67,10 @@ def _mosaic_call_names(hlo):
 def test_the_flash_kernels_keep_the_name_their_roofline_reader_matches(
         one_chip, monkeypatch):
     """``flash_attn_roofline.train`` matches ``attn-bf16_<B>_<H>_<T>_<D>``:
-    the flash calls are unnamed and take the flax module's name, which
-    ``CausalSelfAttention`` puts back innermost under its ``attn_core``
-    region. Forward, backward and the recompute, from shapes alone."""
+    the flash calls name themselves ``attn`` (their launchers are inner
+    jits, whose own names an unnamed call would take) under the
+    ``attn_core`` region. Forward, backward and the recompute, from shapes
+    alone."""
     import deepspeed_tpu.ops.kernels as kernels
     from deepspeed_tpu.models.gpt2 import CausalSelfAttention, GPT2Config
     monkeypatch.setattr(kernels, "default_interpret", lambda: False)
@@ -91,6 +92,64 @@ def test_the_flash_kernels_keep_the_name_their_roofline_reader_matches(
     names = _mosaic_call_names(hlo)
     assert len(names) >= 3 and set(names) == {"attn"}, names
     assert "rg.attn_core" in hlo        # and the region is on its path
+
+
+def test_the_flash_kernels_compile_at_the_train_cells_shape(one_chip,
+                                                           monkeypatch):
+    """``[2, 16, 2048, 128]`` at 1024-blocks, as both train cells run it
+    (benchmark/configs/gpt-1p3b.json): each kernel carries an interior body
+    and a diagonal body unrolled over its strips, and Mosaic still fits
+    them in VMEM. Under ``jax.checkpoint`` a layer has exactly four calls,
+    all named ``attn`` (forward, the forward again in the backward, dq,
+    dk/dv): ``flash_attn_roofline.train`` divides by that count."""
+    import deepspeed_tpu.ops.kernels as kernels
+    from deepspeed_tpu.models.gpt2 import CausalSelfAttention, GPT2Config
+    from deepspeed_tpu.ops.kernels.flash_attention import take_causal_plans
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    cfg = GPT2Config(num_heads=16, hidden_size=2048, attention_impl="flash",
+                     flash_block_q=1024, flash_block_k=1024,
+                     dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    attn = CausalSelfAttention(cfg, name="attn")
+    x = jnp.zeros((2, 2048, 2048), jnp.bfloat16)
+    params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), x)
+
+    def loss(params, x):
+        y = jax.checkpoint(lambda p, x: attn.apply(p, x))(params, x)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, x))
+    take_causal_plans()
+    hlo = jax.jit(jax.value_and_grad(loss)).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert _mosaic_call_names(hlo) == ["attn"] * 4
+    (b, h, plan), = take_causal_plans()
+    assert (b, h) == (2, 16)
+    assert (plan["interior"], plan["sub_tiled"], plan["general"]) == (1, 2, 0)
+
+
+def test_unrolled_layers_lower_the_flash_kernels_once(one_chip):
+    """The kernels' launchers are inner jits: a model's unrolled layers
+    call them with the same shapes, so one program holds ONE lowered copy
+    of each kernel call (the forward, its recompute under
+    ``jax.checkpoint``, dq, dk/dv) whatever its depth, where every layer
+    used to trace and lower its own four (8 s of a warm start at 24 layers
+    with three bodies a kernel). Lowering only: no compile."""
+    from deepspeed_tpu.ops.kernels.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        for _ in range(3):
+            q = jax.checkpoint(functools.partial(
+                flash_attention, causal=True, interpret=False,
+                block_q=256, block_k=256))(q, k, v)
+        return q.astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(x, x, x).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 4
 
 
 @pytest.mark.parametrize(
