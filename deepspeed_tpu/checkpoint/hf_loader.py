@@ -480,8 +480,32 @@ def _pangu_ultra_moe_names(state, hf_cfg):
     return out
 
 
+def _kimi_linear_names(state, hf_cfg):
+    """kimi_linear: per-expert stacking as mixtral names them (``w1`` the
+    gate, ``w3`` the up, ``w2`` the down projection), and the recurrent
+    layers' mixer, which the checkpoint keeps under ``self_attn`` like the
+    latent layers', renamed ``model.layers.<i>.kda`` for the layers
+    ``linear_attn_config.kda_layers`` names (1-based), with its tensors
+    brought to this tree's shapes: a convolution ``[C, 1, K]`` to
+    ``[K, C]``, ``A_log`` ``[1, 1, H, 1]`` to ``[H]``."""
+    kda = {int(i) - 1 for i in
+           (hf_cfg.get("linear_attn_config") or {}).get("kda_layers", ())}
+    out = {}
+    for name, arr in _mixtral_experts(state, hf_cfg).items():
+        m = re.match(r"model\.layers\.(\d+)\.self_attn\.(.*)", name)
+        if m and int(m.group(1)) in kda:
+            name = f"model.layers.{m.group(1)}.kda.{m.group(2)}"
+            if "_conv1d." in name:
+                arr = arr.reshape(arr.shape[0], arr.shape[-1]).T
+            elif name.endswith(".A_log"):
+                arr = arr.reshape(-1)
+        out[name] = arr
+    return out
+
+
 SPECIAL_HANDLERS = {
     "pangu_ultra_moe": _pangu_ultra_moe_names,
+    "kimi_linear": _kimi_linear_names,
     "phi3": _split_phi3_fused,
     "qwen": _split_qwen_fused,
     "bloom": _split_bloom_fused,
@@ -560,7 +584,39 @@ _PANGU_ULTRA_MOE_MAP = _LLAMA_MAP[:3] \
          "mtp_{0}/final_norm/scale", "vector"),
     ]
 
+#: the recurrent layers' tensors are bare arrays in the tree (a ``kda``
+#: dict of ``models/solar_open2.py::KDAMixer``'s names); the latent layers'
+#: are flax Dense kernels under ``attn``
+_KIMI_LINEAR_MAP = _LLAMA_MAP[:5] + _MOE_STACKED_RULES + [
+    (r"model\.layers\.(\d+)\.kda\.(q|k|v|o|b)_proj\.weight",
+     "layer_{0}/kda/{1}_proj", "linear"),
+    (r"model\.layers\.(\d+)\.kda\.(f|g)_(a|b)_proj\.weight",
+     "layer_{0}/kda/{1}_{2}", "linear"),
+    (r"model\.layers\.(\d+)\.kda\.(q|k|v)_conv1d\.weight",
+     "layer_{0}/kda/{1}_conv", "vector"),
+    (r"model\.layers\.(\d+)\.kda\.(A_log|dt_bias)",
+     "layer_{0}/kda/{1}", "vector"),
+    (r"model\.layers\.(\d+)\.kda\.o_norm\.weight",
+     "layer_{0}/kda/o_norm", "vector"),
+    (r"model\.layers\.(\d+)\.self_attn\.(q|kv_b|o)_proj\.weight",
+     "layer_{0}/attn/{1}_proj/kernel", "linear"),
+    (r"model\.layers\.(\d+)\.self_attn\.kv_a_proj_with_mqa\.weight",
+     "layer_{0}/attn/kv_a_proj/kernel", "linear"),
+    (r"model\.layers\.(\d+)\.self_attn\.kv_a_layernorm\.weight",
+     "layer_{0}/attn/kv_a_norm/scale", "vector"),
+    (r"model\.layers\.(\d+)\.mlp\.(gate|up|down)_proj\.weight",
+     "layer_{0}/mlp/{1}_proj/kernel", "linear"),
+    (r"model\.layers\.(\d+)\.block_sparse_moe\.gate\.weight",
+     "layer_{0}/moe/gate", "linear"),
+    (r"model\.layers\.(\d+)\.block_sparse_moe\.gate"
+     r"\.e_score_correction_bias", "layer_{0}/moe/sel_bias", "vector"),
+    (r"model\.layers\.(\d+)\.block_sparse_moe\.shared_experts"
+     r"\.(gate|up|down)_proj\.weight",
+     "layer_{0}/shared_{1}_proj/kernel", "linear"),
+]
+
 ARCH_MAPS["pangu_ultra_moe"] = _PANGU_ULTRA_MOE_MAP
+ARCH_MAPS["kimi_linear"] = _KIMI_LINEAR_MAP
 ARCH_MAPS["mixtral"] = _MIXTRAL_MAP
 ARCH_MAPS["qwen2_moe"] = _QWEN2_MOE_MAP
 ARCH_MAPS["olmoe"] = _OLMOE_MAP

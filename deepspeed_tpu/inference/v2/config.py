@@ -351,34 +351,28 @@ class RaggedInferenceConfig(ConfigModel):
             return
         from ...models.mixtral import MixtralConfig
         is_moe = isinstance(model_cfg, MixtralConfig)
-        if any(k not in ("attn", "mla")
-               for k in getattr(model_cfg, "layer_kinds", ())):
-            # a model with recurrent layers keeps per-sequence state that
-            # cannot be rewound, copied or sharded yet: what would need a
-            # state snapshot refuses here, by name
-            for on, feature in (
-                    (self.prefix_cache, "prefix_cache"),
-                    (self.spec_decode != "off", "spec_decode"),
-                    (self.kv_cache_dtype == "int8", "kv_cache_dtype='int8'"),
-                    (self.tp_size > 1, "tp_size > 1"),
-                    (self.seq_size > 1, "seq_size > 1"),
-                    (self.ep_size > 1, "ep_size > 1")):
-                if on:
-                    raise ValueError(stateful_refusal(feature))
-        if "mla" in getattr(model_cfg, "layer_kinds", ()):
-            # a latent-attention model keeps ONE plane a layer, a row that
-            # is key and value at once: what reads the cache as K and V
-            # planes, shards it by kv heads or scales it a head refuses
-            # here, by name
-            for on, feature in (
-                    (self.prefix_cache, "prefix_cache"),
-                    (self.spec_decode != "off", "spec_decode"),
-                    (self.kv_cache_dtype == "int8", "kv_cache_dtype='int8'"),
-                    (self.tp_size > 1, "tp_size > 1"),
-                    (self.seq_size > 1, "seq_size > 1"),
-                    (self.ep_size > 1, "ep_size > 1")):
-                if on:
-                    raise ValueError(latent_refusal(feature))
+        kinds = getattr(model_cfg, "layer_kinds", ())
+        # a model with recurrent layers keeps per-sequence state that
+        # cannot be rewound, copied or sharded yet; a latent-attention
+        # model keeps ONE plane a layer, a row that is key and value at
+        # once, which nothing that reads K and V planes, shards by kv
+        # heads or scales a head has been carried over. What would need
+        # either refuses here, by name; a model with both kinds of layer
+        # gives both reasons
+        why = []
+        if any(k not in ("attn", "mla") for k in kinds):
+            why.append(stateful_refusal)
+        if "mla" in kinds:
+            why.append(latent_refusal)
+        for on, feature in (
+                (self.prefix_cache, "prefix_cache"),
+                (self.spec_decode != "off", "spec_decode"),
+                (self.kv_cache_dtype == "int8", "kv_cache_dtype='int8'"),
+                (self.tp_size > 1, "tp_size > 1"),
+                (self.seq_size > 1, "seq_size > 1"),
+                (self.ep_size > 1, "ep_size > 1")):
+            if on and why:
+                raise ValueError("; ".join(r(feature) for r in why))
         if is_moe and self.tp_size > 1 and self.ep_size == 1:
             # tp alone would replicate the full expert set on every chip
             # AND trip the dense-branch all-reduce accounting — for MoE

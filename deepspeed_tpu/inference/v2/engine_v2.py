@@ -361,7 +361,8 @@ class InferenceEngineV2:
             # key and value), the bytes the live rows are over all layers;
             # per prefill step the real positions through the absorbed
             # prefill. These stand IN PLACE of decode_kv_rows_*: such a
-            # model has no K/V rows and never runs that kernel
+            # model has no K/V rows and never runs that kernel (beside
+            # recurrent layers the state_* counters fill in the same run)
             "latent_rows_live": 0, "latent_rows_fetched": 0,
             "latent_bytes_live": 0, "mla_prefill_tokens": 0}
         #: rows the decode kernel this model runs streams for a sequence
@@ -1142,14 +1143,16 @@ class InferenceEngineV2:
         the feature's name and the layer kind (config.stateful_refusal;
         carrying state through these is later work); with ``latent_too``
         also what has not been carried over a latent-attention model's
-        one-plane cache (config.latent_refusal)."""
+        one-plane cache (config.latent_refusal); a model with both kinds
+        of layer gives both reasons."""
+        from .config import latent_refusal, stateful_refusal
+        why = []
         if self._stateful:
-            from .config import stateful_refusal
-            raise NotImplementedError(
-                stateful_refusal(feature, self._stateful))
+            why.append(stateful_refusal(feature, self._stateful))
         if latent_too and self._latent:
-            from .config import latent_refusal
-            raise NotImplementedError(latent_refusal(feature))
+            why.append(latent_refusal(feature))
+        if why:
+            raise NotImplementedError("; ".join(why))
 
     def _decode_row_counts(self, runs) -> Dict[str, int]:
         """The decode kernel's row counters for ``runs``, (steps a

@@ -1,7 +1,8 @@
 """Ragged paged-KV runner for the Llama family (Mixtral-style MoE, the
 hybrid Solar-Open2 family whose layers follow a per-layer list of mixers,
-and the latent-attention openPangu-Ultra-MoE family, whose feed-forward
-kind follows a per-layer list too).
+the latent-attention openPangu-Ultra-MoE family, whose feed-forward
+kind follows a per-layer list too, and Kimi-Linear, whose layer list holds
+recurrent AND latent layers).
 
 Analogue of the reference's llama_v2 / mistral / mixtral v2 containers
 (``inference/v2/model_implementations/{llama_v2,mistral,mixtral}/``): RoPE
@@ -209,25 +210,36 @@ def _kda_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
 def _mla_mixer(p, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
                pos, valid_q, dtype):
     """One latent-attention (MLA) layer over plane ``plane`` of the
-    one-plane cache, in the ABSORBED form: the cache keeps ``[c_kv ; k_r]``
-    a token, ``W_UK`` (the key half of ``kv_b_proj``) is multiplied into
-    the query, ``q_lat = q_nope W_UK^T``, and ``W_UV`` (its value half)
-    into the output, so no cached row is ever expanded to per-head keys
-    and values: scores ``q_lat . c_kv + q_rope . k_r``, ``o_lat = sum p
-    c_kv``, ``o = o_lat W_UV``. Decode steps and prefill chunks alike.
-    Returns (kv, y)."""
+    one-plane cache (``plane`` counts the latent layers alone in a model
+    that also has recurrent ones), in the ABSORBED form: the cache keeps
+    ``[c_kv ; k_r]`` a token, ``W_UK`` (the key half of ``kv_b_proj``) is
+    multiplied into the query, ``q_lat = q_nope W_UK^T``, and ``W_UV`` (its
+    value half) into the output, so no cached row is ever expanded to
+    per-head keys and values: scores ``q_lat . c_kv + q_rope . k_r``,
+    ``o_lat = sum p c_kv``, ``o = o_lat W_UV``. Decode steps and prefill
+    chunks alike. A family's config says whether the query is low rank
+    (``q_lora_rank``) and whether the shared lanes carry rotary positions
+    (``use_rope``). Returns (kv, y)."""
     S, C, _ = h.shape
     H, r = model_cfg.num_heads, model_cfg.kv_lora_rank
     dn, dr, dv = (model_cfg.qk_nope_head_dim, model_cfg.qk_rope_head_dim,
                   model_cfg.v_head_dim)
     eps, W = model_cfg.rms_eps, model_cfg.latent_row
-    cq = _rms(woq_mm(h, p["q_a_proj"]["kernel"], dtype),
-              p["q_a_norm"]["scale"], eps).astype(dtype)
-    q = woq_mm(cq, p["q_b_proj"]["kernel"], dtype).reshape(S, C, H, dn + dr)
+    if model_cfg.q_lora_rank:
+        cq = _rms(woq_mm(h, p["q_a_proj"]["kernel"], dtype),
+                  p["q_a_norm"]["scale"], eps).astype(dtype)
+        q = woq_mm(cq, p["q_b_proj"]["kernel"], dtype)
+    else:                           # a full-rank query, no query norm
+        q = woq_mm(h, p["q_proj"]["kernel"], dtype)
+    q = q.reshape(S, C, H, dn + dr)
     ckv = woq_mm(h, p["kv_a_proj"]["kernel"], dtype)       # [S, C, r + dr]
     c = _rms(ckv[..., :r], p["kv_a_norm"]["scale"], eps).astype(dtype)
-    k_r = apply_rope(ckv[..., None, r:], pos, model_cfg.rope_theta)[:, :, 0]
-    q_r = apply_rope(q[..., dn:], pos, model_cfg.rope_theta)
+    k_r, q_r = ckv[..., r:], q[..., dn:]
+    if getattr(model_cfg, "use_rope", True):
+        # else the shared lanes carry no position (kimi_linear: NoPE)
+        k_r = apply_rope(k_r[..., None, :], pos,
+                         model_cfg.rope_theta)[:, :, 0]
+        q_r = apply_rope(q_r, pos, model_cfg.rope_theta)
     w_kvb = p["kv_b_proj"]["kernel"].astype(dtype).reshape(r, H, dn + dv)
     q_lat = jnp.einsum("schd,rhd->schr", q[..., :dn], w_kvb[..., :dn])
     # the stored row and the query against it, whole 128-lane groups

@@ -742,8 +742,8 @@ class RaggedRunnerBase:
         self.head_dim = getattr(
             model_cfg, "head_dim",
             model_cfg.hidden_size // model_cfg.num_heads)
-        # the cache follows the layer list: a paged K/V plane for each
-        # softmax layer, a state row a sequence for each recurrent one
+        # the cache follows the layer list: paged planes for each softmax
+        # or latent layer, a state row a sequence for each recurrent one
         kinds = getattr(model_cfg, "layer_kinds", None) \
             or ("attn",) * self.num_layers
         self.kv_layers = sum(k in ("attn", "mla") for k in kinds)
@@ -752,10 +752,13 @@ class RaggedRunnerBase:
         #: (``latent_row`` lanes; one "kv head") is key and value at once
         self.kv_planes = 2
         if "mla" in kinds:
-            if any(k != "mla" for k in kinds):
+            # beside recurrent layers they may stand (those keep their
+            # state in the pool's other part, not in a plane)
+            if "attn" in kinds:
                 raise ValueError(
-                    "latent ('mla') layers do not mix with other layer "
-                    "kinds in one model: the cache has one kind of plane")
+                    "latent ('mla') layers do not mix with softmax "
+                    "('attn') layers in one model: the cache has one "
+                    "kind of plane")
             self.kv_planes = 1
             self.kv_heads, self.head_dim = 1, model_cfg.latent_row
         #: what the state pool must hold (None: no recurrent layer)
@@ -1018,7 +1021,8 @@ class RaggedRunnerBase:
                           self.local_kv_heads * self.head_dim)
             if self.kv_planes == 1:
                 # a latent cache's ring: one plane, sequence-major
-                # (latent_attention)
+                # (latent_attention), over the latent layers alone where
+                # recurrent ones stand beside them (their state is ``lin``)
                 ring_shape = (self.kv_layers, 1, S, n, self.head_dim)
             ring = jnp.zeros(ring_shape, pool_arr.dtype
                              if pool_scales is None else dtype)

@@ -48,7 +48,7 @@ from .solar_open2 import SolarSparseBlock
 
 @dataclasses.dataclass(frozen=True)
 class PanguUltraMoEConfig(MixtralConfig):
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536    # None: a full-rank query
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -141,7 +141,10 @@ def _dense(cfg, f, name):
 
 
 class LatentAttention(nn.Module):
-    """MLA in its expanded form: per-head keys and values from W_kvb."""
+    """MLA in its expanded form: per-head keys and values from W_kvb.
+    ``q_lora_rank`` None is a full-rank query (one ``q_proj``, no query
+    norm) and ``use_rope`` false leaves the ``qk_rope_head_dim`` lanes
+    without a position code (``models/kimi_linear.py`` is both)."""
     cfg: PanguUltraMoEConfig
 
     @nn.compact
@@ -151,15 +154,20 @@ class LatentAttention(nn.Module):
         H, r = cfg.num_heads, cfg.kv_lora_rank
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
-        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        cq = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_a_norm")(
-            _dense(cfg, cfg.q_lora_rank, "q_a_proj")(h))
-        q = _dense(cfg, H * (dn + dr), "q_b_proj")(cq).reshape(
-            B, T, H, dn + dr)
+        if cfg.q_lora_rank:
+            cq = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_a_norm")(
+                _dense(cfg, cfg.q_lora_rank, "q_a_proj")(h))
+            q = _dense(cfg, H * (dn + dr), "q_b_proj")(cq)
+        else:
+            q = _dense(cfg, H * (dn + dr), "q_proj")(h)
+        q = q.reshape(B, T, H, dn + dr)
         ckv = _dense(cfg, r + dr, "kv_a_proj")(h)
         c = RMSNorm(cfg.rms_eps, cfg.dtype, name="kv_a_norm")(ckv[..., :r])
-        k_r = apply_rope(ckv[..., None, r:], pos, cfg.rope_theta)
-        q_r = apply_rope(q[..., dn:], pos, cfg.rope_theta)
+        k_r, q_r = ckv[..., None, r:], q[..., dn:]
+        if getattr(cfg, "use_rope", True):
+            pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+            k_r = apply_rope(k_r, pos, cfg.rope_theta)
+            q_r = apply_rope(q_r, pos, cfg.rope_theta)
         kv = _dense(cfg, H * (dn + dv), "kv_b_proj")(c).reshape(
             B, T, H, dn + dv)
         qf = jnp.concatenate([q[..., :dn], q_r], -1)

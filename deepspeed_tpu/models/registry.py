@@ -36,6 +36,8 @@ from .solar_open2 import SolarOpen2, SolarOpen2Config
 from .solar_open2 import make_model as make_solar_open2
 from .pangu_ultra_moe import PanguUltraMoE, PanguUltraMoEConfig
 from .pangu_ultra_moe import make_model as make_pangu_ultra_moe
+from .kimi_linear import KimiLinear, KimiLinearConfig
+from .kimi_linear import make_model as make_kimi_linear
 
 
 class ArchEntry(NamedTuple):
@@ -429,6 +431,62 @@ def _entry_pangu_ultra_moe(d):
         router_aux_loss_coef=0.0)
 
 
+def _entry_kimi_linear(d):
+    """Kimi-Linear (moonshotai/Kimi-Linear-48B-A3B-Instruct):
+    ``linear_attn_config.kda_layers`` are gated delta-rule (KDA) layers and
+    ``full_attn_layers`` latent-attention (MLA) layers, both lists 1-BASED;
+    the first ``first_k_dense_replace`` layers dense and the others sparse
+    (sigmoid router with a selection bias, one group, one ungated shared
+    expert). The latent layers have a full-rank query and no position
+    code. The cache keeps one latent row a token and latent layer
+    (``num_kv_heads`` 1). What the published config does not set is
+    refused by the key's name rather than guessed at."""
+    la = d.get("linear_attn_config") or {}
+    n = d.get("num_hidden_layers", 27)
+    for key, want in (("q_lora_rank", None), ("rope_scaling", None),
+                      ("mla_use_nope", True), ("num_expert_group", 1),
+                      ("topk_group", 1), ("moe_layer_freq", 1),
+                      ("num_nextn_predict_layers", 0),
+                      ("hidden_act", "silu"),
+                      ("moe_router_activation_func", "sigmoid")):
+        if d.get(key, want) != want:
+            raise ValueError(
+                f"kimi_linear configs with {key}={d[key]!r} are not "
+                f"supported (the published one has {want!r})")
+    kda = {int(i) for i in la.get("kda_layers", ())}
+    full = {int(i) for i in la.get("full_attn_layers", ())}
+    if kda & full or kda | full != set(range(1, n + 1)):
+        raise ValueError(
+            "kimi_linear linear_attn_config: kda_layers and "
+            "full_attn_layers must name each of the layers 1 .. "
+            f"num_hidden_layers ({n}) once")
+    k_dense = int(d.get("first_k_dense_replace", 1))
+    width = d.get("moe_intermediate_size", 1024)
+    base = _hf_llama(d, intermediate_size=width, num_kv_heads=1,
+                     max_seq_len=d.get("model_max_length", 1048576))
+    return KimiLinearConfig(
+        **base,
+        kv_lora_rank=d.get("kv_lora_rank", 512),
+        qk_nope_head_dim=d.get("qk_nope_head_dim", 128),
+        qk_rope_head_dim=d.get("qk_rope_head_dim", 64),
+        v_head_dim=d.get("v_head_dim", 128),
+        layer_kinds=tuple("mla" if i + 1 in full else "kda"
+                          for i in range(n)),
+        ffn_kinds=tuple("dense" if i < k_dense else "moe"
+                        for i in range(n)),
+        dense_intermediate_size=d.get("intermediate_size", 9216),
+        kda_heads=la.get("num_heads", 32),
+        kda_head_dim=la.get("head_dim", 128),
+        kda_conv=la.get("short_conv_kernel_size", 4),
+        kda_rank=la.get("head_dim", 128),
+        num_experts=d.get("num_experts", 256),
+        experts_top_k=d.get("num_experts_per_token", 8),
+        norm_topk_prob=bool(d.get("moe_renormalize", True)),
+        routed_scaling=float(d.get("routed_scaling_factor", 2.446)),
+        shared_expert_size=int(d.get("num_shared_experts", 1)) * width,
+        router_aux_loss_coef=0.0)
+
+
 ARCHITECTURES: Dict[str, ArchEntry] = {
     "gpt2": ArchEntry(GPT2Config, GPT2, make_gpt2, _entry_gpt2),
     "llama": ArchEntry(LlamaConfig, Llama, make_llama, _entry_llama),
@@ -455,6 +513,8 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
     "pangu_ultra_moe": ArchEntry(PanguUltraMoEConfig, PanguUltraMoE,
                                  make_pangu_ultra_moe,
                                  _entry_pangu_ultra_moe),
+    "kimi_linear": ArchEntry(KimiLinearConfig, KimiLinear,
+                             make_kimi_linear, _entry_kimi_linear),
     "gpt_neo": ArchEntry(GPTNeoConfig, GPTNeo, make_gpt_neo,
                          _entry_gpt_neo),
     "internlm": ArchEntry(LlamaConfig, Llama, make_llama, _entry_internlm),

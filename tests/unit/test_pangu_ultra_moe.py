@@ -350,9 +350,13 @@ def test_calls_refuse_what_the_latent_plane_cannot_do(model, call):
 
 
 def test_latent_layers_do_not_mix_with_other_kinds(model):
+    """``"mla"`` beside ``"attn"``: planes of two kinds in one pool array.
+    Beside ``"kda"`` latent layers do stand (one plane and a state pool:
+    ``test_kimi_linear.py::test_one_cache_value_holds_a_latent_plane_and_
+    a_state_pool``)."""
     cfg, params = model
     mixed = dataclasses.replace(cfg, layer_kinds=("mla", "attn", "mla"))
-    with pytest.raises(ValueError, match="do not mix"):
+    with pytest.raises(ValueError, match="do not mix with softmax"):
         engine(mixed, params)
 
 
