@@ -32,6 +32,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from ...ops.kernels.delta_rule import kda_prefill_uses_kernel
 from ...resilience.fault_injection import get_fault_injector
 from ...telemetry.serve import serve_observer
 from ...telemetry.trace import SpanSet
@@ -351,9 +352,12 @@ class InferenceEngineV2:
             # recurrent models: state rows with a live tenant and their
             # bytes, per decode step (sampled where decode_slots_live
             # is, and per step of a fused loop), and the real positions
-            # that went through the chunked delta rule
+            # that went through the chunked delta rule; of those, the
+            # ones of steps whose shape took the Pallas chunk kernel
+            # (delta_rule.kda_prefill_uses_kernel, as the mixer asks it)
             "state_slots_live": 0, "state_bytes_live": 0,
             "linear_attn_prefill_tokens": 0,
+            "linear_attn_prefill_kernel_tokens": 0,
             # latent-attention models, a layer's worth each: settled
             # latent rows of the live sequences per pure-decode step (and
             # per step of a fused loop), the rows the decode kernel
@@ -1789,7 +1793,13 @@ class InferenceEngineV2:
                            prefill_rows=sum(len(item.tokens) > 1
                                             for item in sched))
                 if sslots is not None:
-                    span.count(linear_attn_prefill_tokens=real)
+                    spec = self.runner.state_spec
+                    span.count(
+                        linear_attn_prefill_tokens=real,
+                        linear_attn_prefill_kernel_tokens=real
+                        * kda_prefill_uses_kernel(
+                            C, spec["heads"], spec["head_dim"],
+                            spec["head_dim"]))
                 if self._latent:
                     span.count(mla_prefill_tokens=real)
                 # serve fault site: a replica dying with a freshly planned

@@ -152,11 +152,13 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
 
 
 @jax.jit
-def kda_chunked_prefill(q, k, v, g, beta, S0):
+def kda_chunked_prefill(q, k, v, g, beta, St0, n_tokens):
     """The chunked delta rule (chunks of 64) as one named program of a
-    prefill step."""
-    from ...ops.kernels.delta_rule import kda_chunked
-    return kda_chunked(q, k, v, g, beta, S0)
+    prefill step, on states transposed as the pool holds them: traced
+    and lowered once for all the recurrent layers of a step. Positions
+    from ``n_tokens`` on are the step's padding (beta 0, g 0)."""
+    from ...ops.kernels.delta_rule import kda_prefill
+    return kda_prefill(q, k, v, g, beta, St0, n_tokens)
 
 
 def _kda_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
@@ -197,12 +199,12 @@ def _kda_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
         o = o[:, None]
     else:
         St0 = st[slots]                                   # [S, H, dv, dk]
-        S0 = jnp.where(fresh[:, None, None, None], 0.0,
-                       jnp.swapaxes(St0, -1, -2))
-        o, Sn = kda_chunked_prefill(q, k, v, g, beta, S0)
+        o, Sn = kda_chunked_prefill(
+            q, k, v, g, beta,
+            jnp.where(fresh[:, None, None, None], 0.0, St0),
+            batch.n_tokens)
         st = st.at[slots].set(
-            jnp.where(live[:, None, None, None],
-                      jnp.swapaxes(Sn, -1, -2), St0))
+            jnp.where(live[:, None, None, None], Sn, St0))
     state = state[:si] + (st,) + state[si + 1:]
     return with_lin(kv, state, conv), kda_output(p, o, h, model_cfg, dtype)
 

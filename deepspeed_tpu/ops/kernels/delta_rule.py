@@ -29,6 +29,12 @@ the callers mask padding.
   sub-blocks both factors are taken against the row's sub-block start,
   so a decay near 0 underflows to the right answer instead of dividing
   by zero. It is an algorithm, not a different model.
+* :func:`kda_prefill` / :func:`kda_chunk_prefill` — the chunked form as
+  one Pallas kernel a layer, on the pool's transposed states: the state
+  stays in VMEM across a row's chunks, the decay tables, the triangular
+  solve and the state's update never leave it. ``kda_chunked`` is its
+  jnp twin (the CPU path, and every shape off the kernel's grain:
+  :func:`kda_prefill_uses_kernel`).
 
 The serving state pool is one array a layer, ``[rows, H, dv, dk]``: XLA's
 gather and scatter of 4 MB rows stalled the chip at offsets past 2^30
@@ -146,6 +152,229 @@ def kda_chunked(q, k, v, g, beta, S0, *, chunk: int = 64, sub: int = 16):
                         S0.astype(F32), tuple(xs))
     o = jnp.moveaxis(o, 0, 2).reshape(B, H, T + pad, -1)[:, :, :T]
     return jnp.moveaxis(o, 1, 2), S
+
+
+# --------------------------------------------------------------------- #
+# prefill: one Pallas kernel a layer, the state carried in VMEM
+# --------------------------------------------------------------------- #
+
+_CHUNK, _SUB = 64, 16
+_PREFILL_HEADS = 8     # heads a grid step: one sublane tile a position
+BF16 = jnp.bfloat16
+
+
+def kda_prefill_uses_kernel(T: int, H: int, dk: int, dv: int,
+                            backend: Optional[str] = None) -> bool:
+    """Whether :func:`kda_prefill` runs the Pallas chunk kernel: on the
+    TPU, at ``T`` a whole number of 64-position chunks, ``dk`` and ``dv``
+    whole 128-lane groups and the heads a whole number of head blocks.
+    The mixer dispatches on it and the engine counts by it."""
+    backend = backend or jax.default_backend()
+    return (backend == "tpu" and T >= _CHUNK and T % _CHUNK == 0
+            and dk % 128 == 0 and dv % 128 == 0
+            and H % _PREFILL_HEADS == 0)
+
+
+def _dot(a, b, dims=((1,), (0,))):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
+                               preferred_element_type=F32)
+
+
+_NT = ((1,), (1,))          # a @ b^T
+_TN = ((0,), (0,))          # a^T @ b
+
+
+def _own(x, i):
+    """Row ``i`` of every sub-block, over its sub-block's rows."""
+    x4 = x.reshape((x.shape[0] // _SUB, _SUB) + x.shape[1:])
+    return jnp.broadcast_to(x4[:, i:i + 1], x4.shape).reshape(x.shape)
+
+
+def _chunk_consts():
+    """What the heads of a grid step share: the 0 / 1 rows that sum ``g``
+    over a span of positions (bfloat16, exact; three times along the
+    contraction for ``g``'s three parts), and the tables' masks."""
+    L, sub = _CHUNK, _SUB
+    t = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    t0 = t // sub * sub
+    # up to the row; the same from its sub-block's start; after the row;
+    # and for each later sub-block what lies between the row and that
+    # sub-block's start
+    spans = [j <= t, (j <= t) & (j >= t0), j > t] + [
+        ((j > t) & (j < n))[:n] for n in range(sub, L, sub)]
+    spans = jnp.concatenate([x.astype(BF16) for x in spans])
+    return {"spans": jnp.concatenate([spans] * 3, axis=1),
+            "eye": (j == t).astype(F32),
+            "col": j - t0, "row": (t - t0)[:, :1]}
+
+
+def _chunk_head(q, k, v, g, beta, St, spans, c):
+    """One chunk of one head inside the kernel: :func:`_chunk` with the
+    same reference points, the state transposed. q, k, g [L, dk];
+    v [L, dv]; beta [L, 1]; St [dv, dk]; ``spans`` and ``c`` of
+    :func:`_chunk_consts`.
+    Every exponent is the sum of the ``g`` it spans, by one exact matmul
+    against 0 / 1 rows (``g`` in three bfloat16 parts), never a
+    difference of two running sums. Returns (o [L, dv], St)."""
+    L, dv = v.shape
+    sub = _SUB
+    hi = g.astype(BF16)
+    mid = (g - hi.astype(F32)).astype(BF16)
+    lo = (g - hi.astype(F32) - mid.astype(F32)).astype(BF16)
+    sums = jax.lax.dot_general(spans, jnp.concatenate([hi, mid, lo]),
+                               ((((1,), (0,))), ((), ())),
+                               preferred_element_type=F32)
+    # the running sum, the same from the row's sub-block start (<= 0:
+    # against the sub-block's reference point), what follows the row
+    G, Gl, Gaft = sums[:L], sums[L:2 * L], sums[2 * L:3 * L]
+    eG = jnp.exp(G)
+    row = jnp.exp(Gl)
+    kb = k * beta                         # Diag(beta) A, not A, below
+    kr, qr = kb * row, q * row
+    # earlier sub-blocks' columns against this sub-block's reference
+    n_x, qk_x = [jnp.zeros((sub, L), F32)], [jnp.zeros((sub, L), F32)]
+    at = 3 * L
+    for n in range(sub, L, sub):
+        kcol = jnp.concatenate([k[:n] * jnp.exp(sums[at:at + n]),
+                                jnp.zeros((L - n, k.shape[1]), F32)])
+        at += n
+        x = _dot(jnp.concatenate([kr[n:n + sub], qr[n:n + sub]]), kcol, _NT)
+        n_x.append(x[:sub])
+        qk_x.append(x[sub:])
+    N_x, QK_x = jnp.concatenate(n_x), jnp.concatenate(qk_x)
+    # inside a sub-block the pairwise ratios, a column of every
+    # sub-block at a time; the same pass inverts the diagonal blocks of
+    # I + Diag(beta) A by forward substitution, row i final at step i
+    Ti, QKd = c["eye"], jnp.zeros((L, L), F32)
+    for i in range(sub):
+        kD = _own(k, i) * jnp.exp(Gl - _own(Gl, i))
+        n_i = jnp.sum(kb * kD, axis=-1, keepdims=True)
+        qk_i = jnp.sum(q * kD, axis=-1, keepdims=True)
+        QKd = jnp.where(c["col"] == i, qk_i, QKd)
+        Ti = Ti - jnp.where(c["row"] > i, n_i, 0.0) * _own(Ti, i)
+    QK = QK_x + jnp.where((c["col"] >= 0) & (c["col"] <= c["row"]), QKd, 0.0)
+    # (I + Diag(beta) A) U = Diag(beta) (V - (K exp G) S0), sub-block by
+    # sub-block: Ti holds the inverses of the diagonal blocks
+    xs = _dot(jnp.concatenate([k * eG, q * eG]), St, _NT)       # [2L, dv]
+    YN = _dot(Ti, jnp.concatenate([beta * v - beta * xs[:L], N_x], axis=1))
+    Y, Nb = YN[:, :dv], YN[:, dv:]
+    U = [Y[:sub]]
+    for n in range(sub, L, sub):
+        done = jnp.concatenate(U + [jnp.zeros((L - n, dv), F32)])
+        U.append(Y[n:n + sub] - _dot(Nb[n:n + sub], done))
+    U = jnp.concatenate(U)
+    o = xs[L:] + _dot(QK, U)
+    St = St * eG[L - 1:] + _dot(U, k * jnp.exp(Gaft), _TN)
+    return o, St
+
+
+def _prefill_kernel(n_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
+                    o_ref, s_ref):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    # a chunk wholly past the row's positions leaves the state alone
+    live = pl.program_id(2) * _CHUNK < n_ref[pl.program_id(0)]
+
+    @pl.when(live)
+    def _():
+        # the block's heads side by side (one trace, batched): a head's
+        # chunk is a chain of small dependent matmuls, and the chip fills
+        # one chain's waits with another's work (6.6 ms a layer one head
+        # at a time, 2.5 all eight: PERF.md, PR 42). A head's rows lie a
+        # sublane tile apart: strided loads and stores
+        hs = range(s_ref.shape[0])
+        each = lambda ref: jnp.stack([ref[:, h, :] for h in hs])  # noqa: E731
+        b = b_ref[...]
+        c = _chunk_consts()
+        # the span rows batched with the heads (a copy a head): their
+        # matmul then gives each head's sums as the rest takes them;
+        # shared, the heads come out as a middle axis and every use of
+        # a sum is a relayout
+        spans = c.pop("spans")
+        o, St = jax.vmap(lambda *x: _chunk_head(*x, c))(
+            each(q_ref), each(k_ref), each(v_ref), each(g_ref),
+            jnp.stack([b[:, h:h + 1] for h in hs]), s_ref[...],
+            jnp.broadcast_to(spans, (len(hs),) + spans.shape))
+        for h in hs:
+            o_ref[:, h, :] = o[h]
+        s_ref[...] = St
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+# jitted under its own name, as the decode update below is: the device
+# trace then names the Mosaic call after it
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_chunk_prefill(q, k, v, g, beta, St0, lengths, *, interpret=False):
+    """The chunked form as one Pallas call. q, k, g [B, T, H, dk],
+    v [B, T, H, dv], beta [B, T, H] (float32), St0 [B, H, dv, dk] the
+    TRANSPOSED states, as the pool holds them; ``lengths`` [B] int32:
+    row b's positions from ``lengths[b]`` on change nothing (beta 0,
+    g 0 there), and a chunk that holds only such is neither fetched nor
+    computed (its outputs are zeros). The grid is (row, block of heads,
+    chunk), the chunks innermost and in order: a row's states stay in
+    VMEM from its first chunk to its last. The operands are taken as
+    they lie (a block is 64 positions of 8 heads, one sublane tile a
+    position): no copy to a per-head layout on either side. Returns
+    (o [B, T, H, dv], St [B, H, dv, dk])."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    hb, L = _PREFILL_HEADS, _CHUNK
+    # the last chunk with a position of row b: later steps name it
+    # again, and a block named twice in a row is fetched once
+    at = lambda b, c, n: jnp.minimum(                     # noqa: E731
+        c, jnp.maximum((n[b] + L - 1) // L - 1, 0))
+    seq = lambda d: pl.BlockSpec(                         # noqa: E731
+        (None, L, hb, d), lambda b, j, c, n: (b, at(b, c, n), j, 0))
+    st = pl.BlockSpec((None, hb, dv, dk), lambda b, j, c, n: (b, j, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(B, H // hb, T // L),
+        in_specs=[seq(dk), seq(dk), seq(dv), seq(dk),
+                  pl.BlockSpec((None, None, L, hb),
+                               lambda b, j, c, n: (b, j, at(b, c, n), 0)),
+                  st],
+        out_specs=[pl.BlockSpec((None, L, hb, dv),
+                                lambda b, j, c, n: (b, c, j, 0)), st])
+    return pl.pallas_call(
+        _prefill_kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, T, H, dv), F32),
+                   jax.ShapeDtypeStruct(St0.shape, F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(lengths, q, k, v, g,
+      # the step sizes by block of heads: [B, H / hb, T, hb]
+      jnp.moveaxis(beta.reshape(B, T, H // hb, hb), 2, 1), St0)
+
+
+def kda_prefill(q, k, v, g, beta, St0, lengths=None, *,
+                impl: Optional[str] = None):
+    """A prefill chunk of every row on TRANSPOSED states St0
+    [B, H, dv, dk] (the pool's layout): the Pallas kernel where
+    :func:`kda_prefill_uses_kernel` says so, :func:`kda_chunked`
+    elsewhere. ``lengths`` [B] (None: every position counts) promises
+    that row b's positions from ``lengths[b]`` on have ``beta = 0`` and
+    ``g = 0`` and that nobody reads their outputs. Returns
+    (o [B, T, H, dv] float32, St). ``impl`` ("pallas", "interpret",
+    "xla") is for the tests."""
+    B, T, H, dk = q.shape
+    if impl is None:
+        impl = "pallas" if kda_prefill_uses_kernel(
+            T, H, dk, v.shape[-1]) else "xla"
+    if impl == "xla":
+        o, S = kda_chunked(q, k, v, g, beta, jnp.swapaxes(St0, -1, -2))
+        return o, jnp.swapaxes(S, -1, -2)
+    q, k, v, g, beta = (x.astype(F32) for x in (q, k, v, g, beta))
+    if lengths is None:
+        lengths = jnp.full((B,), T, jnp.int32)
+    return kda_chunk_prefill(q, k, v, g, beta, St0.astype(F32),
+                             lengths.astype(jnp.int32),
+                             interpret=impl == "interpret")
 
 
 # --------------------------------------------------------------------- #

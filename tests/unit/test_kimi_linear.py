@@ -110,6 +110,7 @@ def test_engine_logits_match_the_reference(model, chunk, decode):
     stats = eng.pipeline_stats
     assert stats["mla_prefill_tokens"] == len(prompt)
     assert stats["linear_attn_prefill_tokens"] == len(prompt)
+    assert stats["linear_attn_prefill_kernel_tokens"] == 0
     live = (sum(range(38, 46)) if decode == "pipelined" else 8 * 37) + 46
     assert stats["latent_rows_live"] == live
     assert stats["latent_rows_fetched"] >= live

@@ -89,6 +89,8 @@ def test_engine_logits_match_the_reference(model, chunk, decode):
     assert np.abs(lg - want[-1]).max() < TOL
     stats = eng.pipeline_stats
     assert stats["linear_attn_prefill_tokens"] == len(prompt)
+    # the chunk kernel is the chip's: none of them went through it here
+    assert stats["linear_attn_prefill_kernel_tokens"] == 0
     assert stats["state_slots_live"] >= 8 and stats["state_bytes_live"] == \
         stats["state_slots_live"] * eng.kv_cache.state_bytes_per_slot()
     if decode == "fused":
@@ -124,12 +126,15 @@ def _kda_case(key, T, gscale, beta_shift, B=2, H=3, dk=16, dv=8):
     return q, k, v, g, beta, jax.random.normal(ks[5], (B, H, dk, dv))
 
 
-@pytest.mark.parametrize("gscale, beta_shift", [
+_REGIMES = pytest.mark.parametrize("gscale, beta_shift", [
     (1.0, 0.0),        # decays spread over (0, 1)
     (20.0, 0.0),       # decays near 0: exp(G) underflows inside a chunk
     (1e-3, 0.0),       # decays near 1: the state never forgets
     (1e-3, 6.0),       # beta near 2: transitions with eigenvalues near -1
 ], ids=["spread", "decay-near-0", "decay-near-1", "beta-near-2"])
+
+
+@_REGIMES
 @pytest.mark.parametrize("chunk, sub", [(64, 16), (32, 32)])
 def test_chunked_delta_rule_is_the_recurrence(gscale, beta_shift, chunk, sub):
     args = _kda_case(jax.random.PRNGKey(1), 100, gscale, beta_shift)
@@ -140,6 +145,78 @@ def test_chunked_delta_rule_is_the_recurrence(gscale, beta_shift, chunk, sub):
     scale = max(1.0, float(jnp.abs(S1).max()))
     assert float(jnp.abs(o1 - o2).max()) < 1e-4 * scale
     assert float(jnp.abs(S1 - S2).max()) < 1e-4 * scale
+
+
+@_REGIMES
+@pytest.mark.parametrize("H, T", [
+    (8, 128), (2 * dr._PREFILL_HEADS, 512)], ids=["8-heads", "2-blocks"])
+def test_chunk_kernel_is_the_recurrence(gscale, beta_shift, H, T):
+    """The Pallas chunk kernel (interpreted here) at lane-true shapes,
+    from a nonzero state, against the token-by-token definition."""
+    q, k, v, g, beta, S0 = _kda_case(jax.random.PRNGKey(5), T, gscale,
+                                     beta_shift, B=1, H=H, dk=128, dv=128)
+    with jax.default_matmul_precision("highest"):
+        o1, S1 = dr.kda_recurrent(q, k, v, g, beta, S0)
+    o2, St2 = dr.kda_prefill(q, k, v, g, beta, jnp.swapaxes(S0, -1, -2),
+                             impl="interpret")
+    assert np.isfinite(np.asarray(o2)).all()
+    scale = max(1.0, float(jnp.abs(S1).max()))
+    assert float(jnp.abs(o1 - o2).max()) < 1e-4 * scale
+    assert float(jnp.abs(S1 - jnp.swapaxes(St2, -1, -2)).max()) \
+        < 1e-4 * scale
+
+
+@pytest.mark.parametrize("keep", [100, 64, 0],
+                         ids=["masked-tail", "masked-chunk", "masked-row"])
+def test_chunk_kernel_leaves_masked_positions_alone(keep):
+    """Row 1 keeps ``keep`` of its 128 positions (beta 0, g 0 after
+    them): its state is, to the bit, what its whole chunks alone give
+    whatever the masked positions hold, and a wholly masked row's state
+    is what it was."""
+    q, k, v, g, beta, S0 = _kda_case(jax.random.PRNGKey(6), 128, 1.0, 0.0,
+                                     B=2, H=dr._PREFILL_HEADS, dk=128,
+                                     dv=128)
+    on = (jnp.arange(128) < keep)[None] | (jnp.arange(2) == 0)[:, None]
+    g = jnp.where(on[..., None, None], g, 0.0)
+    beta = jnp.where(on[..., None], beta, 0.0)
+    St0 = jnp.swapaxes(S0, -1, -2)
+    o, St = dr.kda_prefill(q, k, v, g, beta, St0, impl="interpret")
+    # told the rows' lengths, the kernel skips the chunks past them: the
+    # same states, and the same outputs at the real positions
+    o_n, St_n = dr.kda_prefill(q, k, v, g, beta, St0,
+                               jnp.array([128, keep]), impl="interpret")
+    assert np.array_equal(np.asarray(St_n), np.asarray(St))
+    assert np.array_equal(np.asarray(o_n[1, :keep]), np.asarray(o[1, :keep]))
+    assert np.array_equal(np.asarray(o_n[0]), np.asarray(o[0]))
+    if keep:
+        n = -(-keep // 64) * 64        # whole chunks: the kernel's grain
+        # other values under the mask, and no chunk past the last real one
+        cut = lambda x: jnp.where(                       # noqa: E731
+            on[1:, :n].reshape((1, n) + (1,) * (x.ndim - 2)),
+            x[1:, :n], 3.0 - x[1:, :n])
+        _, want = dr.kda_prefill(cut(q), cut(k), cut(v), g[1:, :n],
+                                 beta[1:, :n], St0[1:], impl="interpret")
+        assert np.array_equal(np.asarray(St[1]), np.asarray(want[0]))
+        _, real = dr.kda_recurrent(q[1:, :keep], k[1:, :keep], v[1:, :keep],
+                                   g[1:, :keep], beta[1:, :keep], S0[1:])
+        assert float(jnp.abs(jnp.swapaxes(St[1:], -1, -2) - real).max()) \
+            < 1e-4
+    else:
+        assert np.array_equal(np.asarray(St[1]), np.asarray(St0[1]))
+    assert not np.array_equal(np.asarray(St[0]), np.asarray(St0[0]))
+
+
+@pytest.mark.parametrize("backend, T, H, dk, want", [
+    ("tpu", 512, 64, 128, True), ("tpu", 512, 32, 128, True),
+    ("tpu", 64, dr._PREFILL_HEADS, 128, True),
+    ("cpu", 512, 64, 128, False), ("tpu", 100, 64, 128, False),
+    ("tpu", 512, 64, 16, False), ("tpu", 512, 3, 128, False),
+], ids=["solar", "kimi", "one-chunk", "cpu", "T-100", "dk-16", "3-heads"])
+def test_chunk_kernel_is_taken_on_the_chip_at_its_grain(backend, T, H, dk,
+                                                        want):
+    assert dr.kda_prefill_uses_kernel(T, H, dk, dk, backend=backend) is want
+    # and here, on the CPU, whatever the shape: the jnp form
+    assert not dr.kda_prefill_uses_kernel(T, H, dk, dk)
 
 
 def test_masked_positions_leave_the_state_as_it_was():

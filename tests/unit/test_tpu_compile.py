@@ -395,6 +395,74 @@ def test_pangu_decode_loop_and_flush_compile_over_the_latent_plane(
 ROLLOUT_BLOCK, ROLLOUT_ROW = 640, 256
 
 
+@pytest.mark.parametrize(
+    "name, family, layers, heads, planes, row, block, blocks, maxb", [
+        ("solar-open2-250b", "solar_open2", 3, 64, (1, 2), 1024, 640, 260,
+         2),
+        ("kimi-linear-48b-a3b", "kimi_linear", 6, 32, (2, 1), 640, 256,
+         1920, 24),
+    ], ids=["solar2", "kimi"])
+def test_refill_step_runs_the_chunk_kernel_of_the_delta_rule(
+        one_chip, monkeypatch, name, family, layers, heads, planes, row,
+        block, blocks, maxb):
+    """The [4, 512] prefill step of the two cells with recurrent layers,
+    at their cut, from shapes alone: every KDA layer runs the Pallas chunk
+    kernel under its own name (which the decode update's readers do not
+    match), traced and lowered ONCE for all of them, and XLA's batched
+    triangular solve is gone from the program."""
+    import importlib
+    import json
+    import os
+    import re
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from deepspeed_tpu.inference.v2.kv_quant import KVPool
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
+    mt = importlib.import_module(f"benchmark.model_types.{family}")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           name + ".json")) as f:
+        mcfg = mt.model_config(json.load(f))
+    slots = 128
+    runner = LlamaRaggedRunner(mcfg, RaggedInferenceConfig(
+        max_seqs=slots, chunk_size=512, block_size=block,
+        num_blocks=blocks, max_blocks_per_seq=maxb, decode_loop_steps=64,
+        dtype="bfloat16", attention_impl="paged_flash"))
+    assert (runner.state_spec["layers"], runner.state_spec["heads"]) \
+        == (layers, heads)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: mt.init_params(mcfg, 0)))
+    state = tuple(spec((slots + 1, heads, 128, 128), jnp.float32)
+                  for _ in range(layers))
+    conv = spec((layers, slots + 1, 3, 3 * heads * 128), jnp.bfloat16)
+    lowered = runner._step_greedy.trace(
+        params, KVPool(spec(planes + ((blocks + 1) * block, row),
+                            jnp.bfloat16), None, state, conv),
+        RaggedBatch(spec((4, 512)), spec((4,)), spec((4,)), spec((4, maxb)),
+                    spec((4,)))).lower(lowering_platforms=("tpu",))
+    # one lowering a KIND of kernel in the step, whatever the depth: the
+    # chunk kernel's launcher is ONE function all the KDA layers call
+    text = lowered.as_text()
+    assert len(re.findall(r"func\.func private @kda_chunk_prefill\b",
+                          text)) == 1
+    assert text.count("stablehlo.custom_call @tpu_custom_call") <= 4
+    assert "triangular_solve" not in text
+    hlo = lowered.compile().as_text()
+    names = _mosaic_call_names(hlo)
+    assert names.count("kda_chunk_prefill") == layers, names
+    assert not any(re.match(r"^kda_decode_state_update", n) for n in names)
+    assert "riangular" not in hlo
+
+
 def _rollout_runner(layers, clients):
     from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
     from deepspeed_tpu.models.llama import LlamaConfig
