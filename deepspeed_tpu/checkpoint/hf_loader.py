@@ -503,8 +503,54 @@ def _kimi_linear_names(state, hf_cfg):
     return out
 
 
+def _nemotron_h_names(state, hf_cfg):
+    """nemotron_h: every block is ``backbone.layers.<i>.{norm, mixer}``
+    whatever its kind; the pattern's letter says which branch the norm
+    stands before (``input_layernorm`` before a mixer,
+    ``post_attention_layernorm`` before a feed-forward) and what ``mixer``
+    is: attention (``self_attn``), Mamba-2 (``mamba``, its convolution
+    ``[C, 1, K]`` brought to ``[K, C]``) or the sparse feed-forward, whose
+    two matrices an expert are stacked and stored at the width rounded up
+    to whole 128-lane groups (zeros; ``models/nemotron_h.py`` says why)."""
+    from ..models.nemotron_h import pad_experts
+    pattern = hf_cfg["hybrid_override_pattern"]
+    renamed = {}
+    for name, arr in state.items():
+        name = re.sub(r"^backbone\.embeddings\.", "model.embed_tokens.", name)
+        name = re.sub(r"^backbone\.norm_f\.", "model.norm.", name)
+        m = re.match(r"backbone\.layers\.(\d+)\.(norm|mixer)\.(.*)", name)
+        if m:
+            i, part, rest = int(m.group(1)), m.group(2), m.group(3)
+            letter = pattern[i]
+            if part == "norm":
+                rest = ("post_attention_layernorm." if letter == "E"
+                        else "input_layernorm.") + rest
+            elif letter == "*":
+                rest = "self_attn." + rest
+            elif letter == "M":
+                if rest == "conv1d.weight":
+                    arr = arr.reshape(arr.shape[0], arr.shape[-1]).T
+                rest = "mamba." + rest
+            else:
+                rest = "moe." + rest
+            name = f"model.layers.{i}.{rest}"
+        renamed[name] = arr
+    out = _stack_moe_experts(
+        renamed, hf_cfg,
+        r"model\.layers\.(\d+)\.moe\.experts\.(\d+)\."
+        r"(up_proj|down_proj)\.weight$",
+        gate_name="", up_name="up_proj", down_name="down_proj",
+        prefix_out="model.layers")
+    for name in [n for n in out if n.endswith("moe_stacked.wi_up")]:
+        base = name[:-len("wi_up")]
+        out[base + "wi"], out[base + "wo"] = pad_experts(
+            out.pop(name), out.pop(base + "wo"))
+    return out
+
+
 SPECIAL_HANDLERS = {
     "pangu_ultra_moe": _pangu_ultra_moe_names,
+    "nemotron_h": _nemotron_h_names,
     "kimi_linear": _kimi_linear_names,
     "phi3": _split_phi3_fused,
     "qwen": _split_qwen_fused,
@@ -615,7 +661,32 @@ _KIMI_LINEAR_MAP = _LLAMA_MAP[:5] + _MOE_STACKED_RULES + [
      "layer_{0}/shared_{1}_proj/kernel", "linear"),
 ]
 
+#: a state-space layer's tensors are bare arrays in the tree (a ``mamba``
+#: dict of ``models/nemotron_h.py::Mamba2Mixer``'s names); the names are
+#: :func:`_nemotron_h_names`'
+_NEMOTRON_H_MAP = _LLAMA_MAP[:6] + [
+    (r"model\.layers\.(\d+)\.mamba\.(in|out)_proj\.weight",
+     "layer_{0}/mamba/{1}_proj", "linear"),
+    (r"model\.layers\.(\d+)\.mamba\.conv1d\.weight",
+     "layer_{0}/mamba/conv_w", "vector"),
+    (r"model\.layers\.(\d+)\.mamba\.conv1d\.bias",
+     "layer_{0}/mamba/conv_b", "vector"),
+    (r"model\.layers\.(\d+)\.mamba\.(A_log|D|dt_bias)",
+     "layer_{0}/mamba/{1}", "vector"),
+    (r"model\.layers\.(\d+)\.mamba\.norm\.weight",
+     "layer_{0}/mamba/norm", "vector"),
+    (r"model\.layers\.(\d+)\.moe\.gate\.weight",
+     "layer_{0}/moe/gate", "linear"),
+    (r"model\.layers\.(\d+)\.moe\.gate\.e_score_correction_bias",
+     "layer_{0}/moe/sel_bias", "vector"),
+    (r"model\.layers\.(\d+)\.moe_stacked\.(wi|wo)",
+     "layer_{0}/moe/{1}", "stacked"),
+    (r"model\.layers\.(\d+)\.moe\.shared_experts\.(up|down)_proj\.weight",
+     "layer_{0}/shared_{1}_proj/kernel", "linear"),
+]
+
 ARCH_MAPS["pangu_ultra_moe"] = _PANGU_ULTRA_MOE_MAP
+ARCH_MAPS["nemotron_h"] = _NEMOTRON_H_MAP
 ARCH_MAPS["kimi_linear"] = _KIMI_LINEAR_MAP
 ARCH_MAPS["mixtral"] = _MIXTRAL_MAP
 ARCH_MAPS["qwen2_moe"] = _QWEN2_MOE_MAP

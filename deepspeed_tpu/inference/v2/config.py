@@ -8,6 +8,7 @@ are compile-time constants — one XLA program serves every step.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 from ...config.config_utils import ConfigModel
@@ -360,8 +361,10 @@ class RaggedInferenceConfig(ConfigModel):
         # either refuses here, by name; a model with both kinds of layer
         # gives both reasons
         why = []
-        if any(k not in ("attn", "mla") for k in kinds):
-            why.append(stateful_refusal)
+        recurrent = [k for k in kinds if k not in ("attn", "mla", None)]
+        if recurrent:
+            why.append(functools.partial(stateful_refusal,
+                                         kind=recurrent[0]))
         if "mla" in kinds:
             why.append(latent_refusal)
         for on, feature in (

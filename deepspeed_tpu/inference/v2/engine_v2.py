@@ -334,6 +334,10 @@ class InferenceEngineV2:
             "prefill_rows": 0, "decode_slots_live": 0,
             "decode_slots_planned": 0,
             "decode_kv_rows_live": 0, "decode_kv_rows_fetched": 0,
+            # bytes of settled K/V rows the live sequences hold over all
+            # the layers that keep K/V, counted where decode_kv_rows_live
+            # is (the paged twin of latent_bytes_live)
+            "kv_bytes_live": 0,
             # rows stored into the paged pool, a layer's worth (real
             # positions of every step that writes it, ring rows of every
             # flush), and the contiguous windows the writer issued for
@@ -354,7 +358,9 @@ class InferenceEngineV2:
             # is, and per step of a fused loop), and the real positions
             # that went through the chunked delta rule; of those, the
             # ones of steps whose shape took the Pallas chunk kernel
-            # (delta_rule.kda_prefill_uses_kernel, as the mixer asks it)
+            # (delta_rule.kda_prefill_uses_kernel, as the mixer asks it;
+            # none of a state-space layer's, whose chunked form has no
+            # kernel yet)
             "state_slots_live": 0, "state_bytes_live": 0,
             "linear_attn_prefill_tokens": 0,
             "linear_attn_prefill_kernel_tokens": 0,
@@ -1160,15 +1166,17 @@ class InferenceEngineV2:
 
     def _decode_row_counts(self, runs) -> Dict[str, int]:
         """The decode kernel's row counters for ``runs``, (steps a
-        sequence ran, its settled rows) pairs: ``decode_kv_rows_*`` over
-        K/V planes, ``latent_rows_*`` and their bytes over a latent
+        sequence ran, its settled rows) pairs: ``decode_kv_rows_*`` and
+        their bytes over K/V planes, ``latent_rows_*`` and theirs over a latent
         plane (a layer's worth each; one pair a model, never both)."""
         live = sum(ran * rows for ran, rows in runs)
         fetched = sum(ran * self._kv_rows_fetched(rows)
                       for ran, rows in runs)
         if not self._latent:
             return {"decode_kv_rows_live": live,
-                    "decode_kv_rows_fetched": fetched}
+                    "decode_kv_rows_fetched": fetched,
+                    "kv_bytes_live": live
+                    * self.kv_cache.kv_bytes_per_token()}
         return {"latent_rows_live": live, "latent_rows_fetched": fetched,
                 "latent_bytes_live": live * self._latent_token_bytes}
 
@@ -1797,9 +1805,9 @@ class InferenceEngineV2:
                     span.count(
                         linear_attn_prefill_tokens=real,
                         linear_attn_prefill_kernel_tokens=real
-                        * kda_prefill_uses_kernel(
-                            C, spec["heads"], spec["head_dim"],
-                            spec["head_dim"]))
+                        * (spec["kind"] == "kda"
+                           and kda_prefill_uses_kernel(
+                               C, spec["heads"], spec["d_k"], spec["d_v"])))
                 if self._latent:
                     span.count(mla_prefill_tokens=real)
                 # serve fault site: a replica dying with a freshly planned

@@ -182,11 +182,13 @@ class BlockedKVCache:
         self.state = self.conv = None
         if state_spec is not None:
             rows = cfg.max_seqs + 1
-            hd = state_spec["head_dim"]
             # one array a layer: the chip stalled on XLA's gather / scatter
-            # of 4 MB rows past 2^30 bytes of ONE array (PERF.md, PR 32)
+            # of 4 MB rows past 2^30 bytes of ONE array (PERF.md, PR 32).
+            # A state is [d_v, d_k], the two sizes apart: square for the
+            # delta rule, [head_dim, state] for a state-space layer
             self.state = tuple(
-                jnp.zeros((rows, state_spec["heads"], hd, hd), jnp.float32)
+                jnp.zeros((rows, state_spec["heads"], state_spec["d_v"],
+                           state_spec["d_k"]), jnp.float32)
                 for _ in range(state_spec["layers"]))
             self.conv = jnp.zeros((state_spec["layers"], rows,
                                    state_spec["taps"] - 1,

@@ -1,8 +1,9 @@
 """Ragged paged-KV runner for the Llama family (Mixtral-style MoE, the
 hybrid Solar-Open2 family whose layers follow a per-layer list of mixers,
 the latent-attention openPangu-Ultra-MoE family, whose feed-forward
-kind follows a per-layer list too, and Kimi-Linear, whose layer list holds
-recurrent AND latent layers).
+kind follows a per-layer list too, Kimi-Linear, whose layer list holds
+recurrent AND latent layers, and Nemotron-H, whose layers are a mixer ALONE
+or a feed-forward ALONE and whose recurrent layers are state-space ones).
 
 Analogue of the reference's llama_v2 / mistral / mixtral v2 containers
 (``inference/v2/model_implementations/{llama_v2,mistral,mixtral}/``): RoPE
@@ -25,6 +26,15 @@ from .config import RaggedInferenceConfig
 from .kv_quant import lin_parts, with_lin
 from .model_runner import (RaggedBatch, RaggedRunnerBase, latent_attention,
                            paged_attention, tp_all_reduce, woq_mm)
+
+
+def _mlp_act(model_cfg):
+    """The feed-forward's activation, by the name the model config gives
+    it (SiLU where it gives none); a name this runner has no function for
+    raises."""
+    from ...models.nemotron_h import relu2
+    return {"silu": jax.nn.silu,
+            "relu2": relu2}[getattr(model_cfg, "mlp_act", "silu")]
 
 
 def _rms(x, scale, eps):
@@ -95,6 +105,7 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
     else:
         weights = (p_moe["wi"], p_moe["wo"])
     norm = getattr(cfg, "norm_topk_prob", True)
+    act = _mlp_act(cfg)
     # the router's form and the experts this chip holds, from the model
     # config (softmax over all, no bias, every expert: the defaults)
     router = dict(
@@ -115,7 +126,7 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
         with region("moe_experts"):
             y, _ = grouped_moe_ffn_ep_serve(
                 h.reshape(S * C, M), logits, cfg.experts_top_k, weights,
-                jax.nn.silu, dtype, EP_AXIS, cfg.num_experts, cap,
+                act, dtype, EP_AXIS, cfg.num_experts, cap,
                 normalize_weights=norm, chunks=chunks)
         return y.reshape(S, C, M), None
     impl = grouped_ffn.kernel_impl(S * C * cfg.experts_top_k,
@@ -125,7 +136,7 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
     with region("moe_route"):
         y, _ = grouped_moe_ffn(
             h.reshape(S * C, M), logits, cfg.experts_top_k, weights,
-            jax.nn.silu, dtype, normalize_weights=norm, held=held,
+            act, dtype, normalize_weights=norm, held=held,
             impl=impl, **router)
     rows = None
     if valid is not None:
@@ -161,36 +172,55 @@ def kda_chunked_prefill(q, k, v, g, beta, St0, n_tokens):
     return kda_prefill(q, k, v, g, beta, St0, n_tokens)
 
 
-def _kda_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
-               dtype):
-    """One gated delta-rule layer over the state pool. ``si`` is the
-    layer's index among the recurrent layers (its plane of the pool);
-    every row reads and writes the pool row ``batch.state_slots`` names.
-    A row whose chunk starts at position 0 starts from zero state and
-    zero convolution inputs, whatever the slot's last tenant left there;
-    padded positions and idle rows (``n_tokens`` 0) leave their row as it
-    was. One token a row goes through the decode update (in place, one
-    read and one write of each state), more through the chunked form.
-    Returns (kv, y [S, C, M])."""
-    from ...models.solar_open2 import kda_inputs, kda_output
-    from ...ops.kernels.delta_rule import kda_decode_update
+def _state_rows(kv, si: int, batch: RaggedBatch):
+    """What the recurrent mixers share of the state pool: layer ``si``'s
+    states, and for every row the slot ``batch.state_slots`` names. A row
+    whose chunk starts at position 0 is ``fresh``: it starts from zero
+    state and zero convolution inputs, whatever the slot's last tenant
+    left there; a row with ``n_tokens`` 0 is idle (not ``live``) and
+    leaves its pool row as it was. Returns (state, conv, st, slots, fresh,
+    live, prev0 the slot's carried inputs [S, K-1, W], prev those with a
+    fresh row's zeroed)."""
     state, conv = lin_parts(kv)
-    st = state[si]              # this layer's states [rows, H, dv, dk]
-    S, C, _ = h.shape
     slots = batch.state_slots
-    K = model_cfg.kda_conv
     fresh = batch.start_pos == 0
     live = batch.n_tokens > 0
     prev0 = conv[si, slots]                               # [S, K-1, W]
     prev = jnp.where(fresh[:, None, None], 0, prev0)
+    return state, conv, state[si], slots, fresh, live, prev0, prev
+
+
+def _carry_conv(conv, si: int, slots, padded, n_tokens, live, prev0):
+    """The next call's K-1 convolution inputs end at the row's last real
+    position; an idle row keeps what it had."""
+    rows = n_tokens[:, None] + jnp.arange(prev0.shape[1], dtype=jnp.int32)
+    nxt = jnp.take_along_axis(padded, rows[..., None], axis=1)
+    return conv.at[si, slots].set(
+        jnp.where(live[:, None, None], nxt.astype(conv.dtype), prev0))
+
+
+def _with_layer_state(kv, state, conv, si: int, st):
+    """``kv`` with layer ``si``'s states replaced by ``st``."""
+    return with_lin(kv, state[:si] + (st,) + state[si + 1:], conv)
+
+
+def _kda_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
+               dtype):
+    """One gated delta-rule layer over the state pool. ``si`` is the
+    layer's index among the recurrent layers (its plane of the pool);
+    the rows and their slots are :func:`_state_rows`'. Padded positions
+    leave the state as it was (beta 0, g 0). One token a row goes through
+    the decode update (in place, one read and one write of each state),
+    more through the chunked form. Returns (kv, y [S, C, M])."""
+    from ...models.solar_open2 import kda_inputs, kda_output
+    from ...ops.kernels.delta_rule import kda_decode_update
+    state, conv, st, slots, fresh, live, prev0, prev = _state_rows(
+        kv, si, batch)
+    S, C, _ = h.shape
     q, k, v, g, beta, padded = kda_inputs(p, h, model_cfg, prev, dtype)
     g = jnp.where(valid_q[..., None, None], g, 0.0)
     beta = jnp.where(valid_q[..., None], beta, 0.0)
-    # the next call's K-1 inputs end at the row's last real position
-    rows = batch.n_tokens[:, None] + jnp.arange(K - 1, dtype=jnp.int32)
-    nxt = jnp.take_along_axis(padded, rows[..., None], axis=1)
-    conv = conv.at[si, slots].set(
-        jnp.where(live[:, None, None], nxt.astype(conv.dtype), prev0))
+    conv = _carry_conv(conv, si, slots, padded, batch.n_tokens, live, prev0)
     if C == 1:
         # exp(-inf) = 0 wipes what the slot held: a fresh row's zero state
         g1 = jnp.where((fresh & live)[:, None, None], -jnp.inf, g[:, 0])
@@ -205,8 +235,44 @@ def _kda_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
             batch.n_tokens)
         st = st.at[slots].set(
             jnp.where(live[:, None, None, None], Sn, St0))
-    state = state[:si] + (st,) + state[si + 1:]
-    return with_lin(kv, state, conv), kda_output(p, o, h, model_cfg, dtype)
+    return _with_layer_state(kv, state, conv, si, st), \
+        kda_output(p, o, h, model_cfg, dtype)
+
+
+def _mamba2_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
+                  dtype):
+    """One state-space (Mamba-2) layer over the state pool, as
+    :func:`_kda_mixer` runs a delta-rule one: the same rows and slots
+    (:func:`_state_rows`), a state ``[H, P, N]`` a row. Padded positions
+    take a zero step (``dt`` 0: decay 1, nothing added). One token a row
+    goes through the decode update kernel in place (``ops/kernels/ssd``),
+    more through the chunked SSD form. Returns (kv, y [S, C, M])."""
+    from ...models.nemotron_h import mamba2_inputs, mamba2_output
+    from ...ops.kernels.ssd import mamba2_decode_update, mamba2_prefill
+    state, conv, st, slots, fresh, live, prev0, prev = _state_rows(
+        kv, si, batch)
+    S, C, _ = h.shape
+    z, x, Bm, Cm, dt, padded = mamba2_inputs(p, h, model_cfg, prev, dtype)
+    dt = jnp.where(valid_q[..., None], dt, 0.0)
+    conv = _carry_conv(conv, si, slots, padded, batch.n_tokens, live, prev0)
+    a = -jnp.exp(p["A_log"].astype(jnp.float32))
+    D = p["D"].astype(jnp.float32)
+    if C == 1:
+        y, st = mamba2_decode_update(
+            st, slots, x[:, 0], dt[:, 0], a, Bm[:, 0], Cm[:, 0], D,
+            wipe=fresh & live)
+        y = y[:, None]
+    else:
+        St0 = st[slots]                                   # [S, H, P, N]
+        y, Sn = mamba2_prefill(
+            x, dt, a, Bm, Cm,
+            jnp.where(fresh[:, None, None, None], 0.0, St0),
+            chunk=model_cfg.mamba_chunk)
+        y = y + D[:, None] * x
+        st = st.at[slots].set(
+            jnp.where(live[:, None, None, None], Sn, St0))
+    return _with_layer_state(kv, state, conv, si, st), \
+        mamba2_output(p, y, z, model_cfg, dtype)
 
 
 def _mla_mixer(p, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
@@ -319,46 +385,57 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
         x = params["embed"]["embedding"][batch.tokens].astype(rdtype)
 
     # one step function for every family: the layer lists say which mixer
-    # and which feed-forward a layer runs; softmax and latent layers take
-    # the cache's planes in order and recurrent layers the state pool's
+    # and which feed-forward a layer runs, or that it has none (a layer of
+    # one branch has that branch's norm and one residual add); softmax and
+    # latent layers take the cache's planes in order and recurrent layers
+    # the state pool's
     kinds = getattr(model_cfg, "layer_kinds", None) \
         or ("attn",) * model_cfg.num_layers
     ffn_kinds = getattr(model_cfg, "ffn_kinds", None) \
         or ("moe" if is_moe else "dense",) * len(kinds)
     # a norm on each branch's OUTPUT too, before the residual add
     sandwich = getattr(model_cfg, "sandwich_norm", False)
+    act = _mlp_act(model_cfg)
     plane = si = 0
-    for li, kind in enumerate(kinds):
+    for li, (kind, ffn) in enumerate(zip(kinds, ffn_kinds)):
         p = params[f"layer_{li}"]
-        with region("norm"):
-            h = _rms(x, p["input_norm"]["scale"],
-                     model_cfg.rms_eps).astype(dtype)
-        if kind == "kda":
-            with region("linear_attn"):
-                kv, y = _kda_mixer(p["kda"], h, kv, si, batch, model_cfg,
-                                   valid_q, dtype)
-            si += 1
-        elif kind == "mla":
-            with region("mla_proj"):
-                kv, y = _mla_mixer(p["attn"], h, kv, plane, batch,
-                                   model_cfg, cfg, pos, valid_q, dtype)
-            plane += 1
-        else:
-            with region("attn_proj"):
-                kv, y = _attn_mixer(p["attn"], h, kv, plane, batch,
-                                    model_cfg, cfg, pos, valid_q, dtype)
-            plane += 1
-        if sandwich:
+        if kind is not None:
             with region("norm"):
-                y = _rms(y, p["attn_branch_norm"]["scale"],
-                         model_cfg.rms_eps)
-        with region("residual"):
-            x = x + y.astype(rdtype)
+                h = _rms(x, p["input_norm"]["scale"],
+                         model_cfg.rms_eps).astype(dtype)
+            if kind == "kda":
+                with region("linear_attn"):
+                    kv, y = _kda_mixer(p["kda"], h, kv, si, batch,
+                                       model_cfg, valid_q, dtype)
+                si += 1
+            elif kind == "mamba2":
+                with region("ssm"):
+                    kv, y = _mamba2_mixer(p["mamba"], h, kv, si, batch,
+                                          model_cfg, valid_q, dtype)
+                si += 1
+            elif kind == "mla":
+                with region("mla_proj"):
+                    kv, y = _mla_mixer(p["attn"], h, kv, plane, batch,
+                                       model_cfg, cfg, pos, valid_q, dtype)
+                plane += 1
+            else:
+                with region("attn_proj"):
+                    kv, y = _attn_mixer(p["attn"], h, kv, plane, batch,
+                                        model_cfg, cfg, pos, valid_q, dtype)
+                plane += 1
+            if sandwich:
+                with region("norm"):
+                    y = _rms(y, p["attn_branch_norm"]["scale"],
+                             model_cfg.rms_eps)
+            with region("residual"):
+                x = x + y.astype(rdtype)
+        if ffn is None:
+            continue
 
         with region("norm"):
             h = _rms(x, p["post_attn_norm"]["scale"],
                      model_cfg.rms_eps).astype(dtype)
-        if ffn_kinds[li] == "moe":
+        if ffn == "moe":
             # the fused decode loop's kv carries a count of routed rows
             counted = getattr(kv, "moe_rows", None) is not None
             y, rows = _moe_mlp(p["moe"], h, model_cfg, dtype, cfg,
@@ -367,13 +444,20 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
                 with region("loop_carry"):
                     kv = kv._replace(moe_rows=kv.moe_rows + rows)
             if getattr(model_cfg, "shared_expert_size", 0):
-                # always-on shared expert: behind a sigmoid scalar gate
-                # (qwen2-moe) or ungated (solar_open2, pangu_ultra_moe)
+                # always-on shared expert, of the routed experts' form
+                # (SwiGLU, or ungated where they are): behind a sigmoid
+                # scalar gate (qwen2-moe) or not (the others)
                 with region("moe_shared"):
-                    gate = woq_mm(h, p["shared_gate_proj"]["kernel"], dtype)
-                    up = woq_mm(h, p["shared_up_proj"]["kernel"], dtype)
-                    shared = woq_mm(jax.nn.silu(gate) * up,
-                                    p["shared_down_proj"]["kernel"], dtype)
+                    if getattr(model_cfg, "gated_experts", True):
+                        gate = woq_mm(h, p["shared_gate_proj"]["kernel"],
+                                      dtype)
+                        up = woq_mm(h, p["shared_up_proj"]["kernel"], dtype)
+                        m = act(gate) * up
+                    else:
+                        m = act(woq_mm(h, p["shared_up_proj"]["kernel"],
+                                       dtype))
+                    shared = woq_mm(m, p["shared_down_proj"]["kernel"],
+                                    dtype)
                     if getattr(model_cfg, "shared_expert_gated", True):
                         sg = jax.nn.sigmoid(
                             (h @ p["shared_expert_gate"]["kernel"].astype(

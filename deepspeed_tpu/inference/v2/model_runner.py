@@ -761,12 +761,30 @@ class RaggedRunnerBase:
                     "kind of plane")
             self.kv_planes = 1
             self.kv_heads, self.head_dim = 1, model_cfg.latent_row
-        #: what the state pool must hold (None: no recurrent layer)
-        self.state_spec = None if self.kv_layers == len(kinds) else {
-            "kind": "kda", "layers": len(kinds) - self.kv_layers,
-            "heads": model_cfg.kda_heads, "head_dim": model_cfg.kda_head_dim,
-            "taps": model_cfg.kda_conv,
-            "conv_width": 3 * model_cfg.kda_heads * model_cfg.kda_head_dim}
+        #: what the state pool must hold (None: no recurrent layer): one
+        #: state ``[heads, d_v, d_k]`` a recurrent layer and the last
+        #: ``taps - 1`` inputs of its convolution, ``conv_width`` wide
+        self.state_spec = None
+        recurrent = [k for k in kinds if k in ("kda", "mamba2")]
+        if len(set(recurrent)) > 1:
+            raise ValueError(
+                "recurrent layers of two kinds ('kda' and 'mamba2') in "
+                "one model: the state pool holds one shape of state")
+        if recurrent and recurrent[0] == "kda":
+            d = model_cfg.kda_head_dim
+            self.state_spec = {
+                "kind": "kda", "layers": len(recurrent),
+                "heads": model_cfg.kda_heads, "d_v": d, "d_k": d,
+                "taps": model_cfg.kda_conv,
+                "conv_width": 3 * model_cfg.kda_heads * d}
+        elif recurrent:
+            self.state_spec = {
+                "kind": "mamba2", "layers": len(recurrent),
+                "heads": model_cfg.mamba_heads,
+                "d_v": model_cfg.mamba_head_dim,
+                "d_k": model_cfg.mamba_state,
+                "taps": model_cfg.mamba_conv,
+                "conv_width": model_cfg.mamba_conv_width}
         self.tp = None            # TPContext once init_tp runs
         self.seqctx = None        # SeqContext once init_seq runs
         self.epctx = None         # EPContext once init_ep runs

@@ -38,6 +38,8 @@ from .pangu_ultra_moe import PanguUltraMoE, PanguUltraMoEConfig
 from .pangu_ultra_moe import make_model as make_pangu_ultra_moe
 from .kimi_linear import KimiLinear, KimiLinearConfig
 from .kimi_linear import make_model as make_kimi_linear
+from .nemotron_h import NemotronH, NemotronHConfig, kinds_from_pattern
+from .nemotron_h import make_model as make_nemotron_h
 
 
 class ArchEntry(NamedTuple):
@@ -487,6 +489,53 @@ def _entry_kimi_linear(d):
         router_aux_loss_coef=0.0)
 
 
+def _entry_nemotron_h(d):
+    """Nemotron-H (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16):
+    ``hybrid_override_pattern`` read letter by letter, a layer a mixer
+    alone (``M`` Mamba-2, ``*`` attention without a position code) or a
+    feed-forward alone (``E``: sigmoid router with a selection bias, one
+    group, ungated relu2 experts, one shared expert of the same form).
+    ``expand`` is not read: the state-space layers' inner width is
+    ``mamba_num_heads x mamba_head_dim``. What the published config does
+    not set is refused by the key's name rather than guessed at."""
+    pattern = d["hybrid_override_pattern"]
+    for key, want in (("n_group", 1), ("topk_group", 1),
+                      ("mlp_hidden_act", "relu2"),
+                      ("mamba_hidden_act", "silu"),
+                      ("attention_bias", False), ("mlp_bias", False),
+                      ("mamba_proj_bias", False), ("use_bias", False),
+                      ("use_conv_bias", True), ("n_shared_experts", 1),
+                      ("sliding_window", None),
+                      ("num_hidden_layers", len(pattern))):
+        if d.get(key, want) != want:
+            raise ValueError(
+                f"nemotron_h configs with {key}={d[key]!r} are not "
+                f"supported (the published one has {want!r})")
+    kinds, ffn_kinds = kinds_from_pattern(pattern)
+    base = _hf_llama(d, num_layers=len(pattern),
+                     intermediate_size=d.get("moe_intermediate_size", 1856),
+                     max_seq_len=d.get("max_position_embeddings", 262144),
+                     rms_eps=d.get("norm_eps",
+                                   d.get("layer_norm_epsilon", 1e-5)))
+    return NemotronHConfig(
+        **base,
+        attn_head_dim=d.get("head_dim", 128),
+        layer_kinds=kinds, ffn_kinds=ffn_kinds,
+        mamba_heads=d.get("mamba_num_heads", 64),
+        mamba_head_dim=d.get("mamba_head_dim", 64),
+        mamba_groups=d.get("n_groups", 8),
+        mamba_state=d.get("ssm_state_size", 128),
+        mamba_conv=d.get("conv_kernel", 4),
+        mamba_chunk=d.get("chunk_size", 128),
+        num_experts=d.get("n_routed_experts", 128),
+        experts_top_k=d.get("num_experts_per_tok", 6),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        routed_scaling=float(d.get("routed_scaling_factor", 2.5)),
+        shared_expert_size=d.get("moe_shared_expert_intermediate_size",
+                                 3712),
+        router_aux_loss_coef=0.0)
+
+
 ARCHITECTURES: Dict[str, ArchEntry] = {
     "gpt2": ArchEntry(GPT2Config, GPT2, make_gpt2, _entry_gpt2),
     "llama": ArchEntry(LlamaConfig, Llama, make_llama, _entry_llama),
@@ -515,6 +564,8 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
                                  _entry_pangu_ultra_moe),
     "kimi_linear": ArchEntry(KimiLinearConfig, KimiLinear,
                              make_kimi_linear, _entry_kimi_linear),
+    "nemotron_h": ArchEntry(NemotronHConfig, NemotronH, make_nemotron_h,
+                            _entry_nemotron_h),
     "gpt_neo": ArchEntry(GPTNeoConfig, GPTNeo, make_gpt_neo,
                          _entry_gpt_neo),
     "internlm": ArchEntry(LlamaConfig, Llama, make_llama, _entry_internlm),

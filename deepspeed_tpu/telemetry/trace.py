@@ -89,6 +89,7 @@ REGIONS = (
     "attn_core",    # the paged / flash / dense attention call, its masks
     "kv_write",     # rows into the pool or the loop's ring; the flush
     "linear_attn",  # a delta-rule layer: convolution, decay, state update
+    "ssm",          # a state-space layer: projection, convolution, scan, gate
     "mla_proj",     # latent attention's low-rank projections, absorption
     "mla_core",     # the latent attention call over the one-plane pool
     "ffn_dense",    # a dense feed-forward

@@ -348,7 +348,8 @@ def test_one_cache_value_holds_a_latent_plane_and_a_state_pool(model):
     assert (r.kv_planes, r.kv_layers, r.kv_heads, r.head_dim) \
         == (1, 1, 1, 256)
     assert r.state_spec == {"kind": "kda", "layers": 3, "heads": 4,
-                            "head_dim": 16, "taps": 4, "conv_width": 192}
+                            "d_v": 16, "d_k": 16, "taps": 4,
+                            "conv_width": 192}
     # 1 latent layer, ONE plane, 24 blocks + the trash block, 128 + 8
     # lanes stored in 256; 3 state arrays of 4 slots + the idle row
     assert cache.data.shape == (1, 1, 25 * 16, 256)

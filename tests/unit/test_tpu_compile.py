@@ -542,3 +542,94 @@ def test_flush_compiles_at_256_clients(one_chip):
     mem = flush.memory_analysis()
     assert mem.alias_size_in_bytes == pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // (layers * 2)
+
+
+def test_nemotron_loop_and_refill_compile_at_256_clients(one_chip,
+                                                         monkeypatch):
+    """The fused 128-step decode loop and the [4, 512] refill step of
+    ``serve-nemotron3-nano-rollout-long`` at the published widths and the
+    cell's 256-client pool, from shapes alone: every Mamba-2 layer updates
+    its OBLONG state through the in-place Mosaic call (whose name and
+    output shape ``ssm_roofline.nemotron`` matches), the ungated experts
+    of width 1856 (stored 1920) run in the grouped kernel and not in
+    ``ragged-dot``, the softmax layers in the paged decode kernel at 16
+    queries a kv head, the state enters donated and comes back aliased,
+    and the refill step's SSD form is plain XLA."""
+    import json
+    import os
+    import re
+    from collections import Counter
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from benchmark.model_types import nemotron_h as mt
+    from deepspeed_tpu.inference.v2.kv_quant import KVPool
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron-3-nano-30b-a3b.json")) as f:
+        mcfg = mt.model_config(json.load(f))
+    with open(os.path.join(root, "benchmark", "cells",
+                           "serve-nemotron3-nano-rollout-long.json")) as f:
+        eng = json.load(f)["engine"]
+    runner = LlamaRaggedRunner(mcfg, RaggedInferenceConfig(**eng))
+    slots, block, blocks, maxb = (eng["max_seqs"], eng["block_size"],
+                                  eng["num_blocks"],
+                                  eng["max_blocks_per_seq"])
+    assert (slots, blocks) == (256, 3840)
+    assert runner.state_spec == {
+        "kind": "mamba2", "layers": 6, "heads": 64, "d_v": 64, "d_k": 128,
+        "taps": 4, "conv_width": 6144}
+    assert (runner.kv_layers, runner.kv_heads, runner.head_dim) \
+        == (2, 2, 128)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: mt.init_params(mcfg, 0)))
+    assert params["layer_1"]["moe"]["wi"].shape == (64, 2688, 1920)
+    state = tuple(spec((slots + 1, 64, 64, 128), jnp.float32)
+                  for _ in range(6))
+    conv = spec((6, slots + 1, 3, 6144), jnp.bfloat16)
+    planes = spec((2, 2, (blocks + 1) * block, 256), jnp.bfloat16)
+    f32 = functools.partial(spec, dtype=jnp.float32)
+    exe = runner._decode_loop_ring.trace(
+        params, KVPool(planes, None, None, None), (state, conv),
+        spec((slots,)), spec((slots,)), spec((slots,)), spec((slots,)),
+        spec((slots, maxb)), spec((1,)), f32((1,)), spec((1,)), f32((1,)),
+        spec((1, 1)), n=128, mode="greedy", cand=1, eos_id=-1,
+        feed="self").lower(lowering_platforms=("tpu",)).compile()
+    hlo = exe.as_text()
+    assert Counter(_mosaic_call_names(hlo)) == {
+        "mamba2_decode_state_update": 6, "grouped_ffn_decode": 5,
+        "closed_call": 2}
+    assert "ragged-dot" not in hlo
+    # the names and shapes the .nemotron readers match
+    assert len(re.findall(
+        r"%mamba2_decode_state_update[\w\-.]* = \(f32\[257,64,64,128\]",
+        hlo)) == 6
+    assert len(re.findall(
+        r"%grouped_ffn_decode[\w\-.]* = bf16\[2496,2688\]", hlo)) == 5
+    assert len(re.findall(
+        r"%closed_call[\w\-.]* = bf16\[256,32,256\]", hlo)) == 2
+    mem = exe.memory_analysis()
+    state_bytes = 6 * (slots + 1) * 64 * 64 * 128 * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 6
+    made = re.findall(r"= f32\[257,64,64,128\]\S* ([\w\-]+)\(", hlo)
+    assert set(made) <= {"parameter", "get-tuple-element"}, set(made)
+    # the refill step: experts at a 128-row tile in the same kernel, the
+    # chunked SSD form without a kernel of its own
+    hlo = runner._step_greedy.trace(
+        params, KVPool(planes, None, state, conv),
+        RaggedBatch(spec((4, 512)), spec((4,)), spec((4,)), spec((4, maxb)),
+                    spec((4,)))).lower(
+                        lowering_platforms=("tpu",)).compile().as_text()
+    names = Counter(_mosaic_call_names(hlo))
+    assert names["grouped_ffn_decode"] == 5 and "ragged-dot" not in hlo
+    assert not any(n.startswith("mamba2") for n in names), names
