@@ -86,8 +86,10 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
     [E + 2] int32 counts the routed rows of VALID positions per expert
     (padding positions are computed like the others and left out of the
     count only), then the held experts the kernel found with at least one
-    row and the visits it made to them (both 0 on the ``ragged_dot``
-    path); None without it and on the expert-parallel path."""
+    row and the times it streamed an expert's matrices (one a visit: once
+    a hit expert unless its rows pass the 128 of a visit's span; both 0 on
+    the ``ragged_dot`` path); None without it and on the expert-parallel
+    path."""
     from ...moe.sharded_moe import grouped_moe_ffn, route_topk
     from ...ops.kernels import grouped_ffn
     from ...ops.kernels.fp6_gemm import Fp6GemmWeight, fp6_gemm_unpack
@@ -157,7 +159,8 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
                     1)[first:first + count]
                 hit = jnp.sum(mine > 0, dtype=jnp.int32)
                 tile = grouped_ffn.row_tile(S * C * cfg.experts_top_k, E)
-                reads = jnp.sum(-(-mine // tile), dtype=jnp.int32)
+                reads = jnp.sum(grouped_ffn.streams(mine, tile),
+                                dtype=jnp.int32)
             rows = jnp.concatenate([rows, jnp.stack([hit, reads])])
     return y.reshape(S, C, M), rows
 

@@ -6,18 +6,30 @@ read. ``jax.lax.ragged_dot`` (three calls a layer, XLA's own grouped
 matmul) walks the ROWS; this kernel walks the GROUPS:
 
 * rows arrive sorted by expert with every group laid out at a multiple
-  of the row tile (:func:`group_layout`), so a *visit* (one grid step)
-  owns one row tile of one expert and its output rows;
+  of the row tile (:func:`group_layout`), so a group's row tiles lie one
+  behind the other; a *visit* (one grid step) owns ONE expert and a span
+  of its row tiles, every tile of the group while they are within the
+  span cap (the last of ``_ROW_TILES``, 128 rows);
 * an expert's matrices come by manual DMA in contiguous row chunks, two
   scratch slots a matrix: the next chunk (and the next visit's first) is
-  in flight while this one multiplies;
+  in flight while this one multiplies. A chunk, once in VMEM, multiplies
+  every row of the span as ONE operand, of the smallest height of
+  ``_ROW_TILES`` that holds the span (the matrices are the MXU's
+  stationary operand: two products of 16 rows cost twice one of 32), so
+  the matrices of a hit expert are streamed once however many row tiles
+  it has;
+* the kernel brings a visit's rows itself, tile by tile (the next
+  visit's while this one's down projection runs), and writes back only
+  the visit's own tiles: a neighbouring group's rows inside the operand's
+  height are computed and dropped;
 * gate, up and down in one pass: ``g`` and ``u`` accumulate in float32
   over the hidden chunks, ``h = act(g) * u`` is rounded once to the
   operand type, and the down projection accumulates in float32 over the
   width chunks. Nothing of [rows, width] reaches HBM;
-* a group with no row has no visit and costs no read; a group with more
-  rows than one tile takes more visits (its matrices streamed again);
-  rows in no held group are never laid out, so never read.
+* a group with no row has no visit and costs no read; only a group with
+  more rows than the span cap takes more visits (its matrices streamed
+  again: :func:`streams` counts them); rows in no held group are never
+  laid out, so never read.
 """
 
 from __future__ import annotations
@@ -34,8 +46,9 @@ from ...telemetry.trace import region
 
 #: rows a visit at decode shapes: one packed bfloat16 tile (two float32)
 ROW_TILE = 16
-#: the row tiles a visit may take; past the last the work is no weight
-#: stream any more (the chip's ridge is ~240 rows an expert)
+#: the row tiles a layout may take and the heights of a visit's operand;
+#: past the last (the span cap) the work is no weight stream any more
+#: (the chip's ridge is ~240 rows an expert)
 _ROW_TILES = (16, 32, 64, 128)
 #: bytes of one weight chunk in flight (a slot); two slots a matrix
 _CHUNK_BYTES = 1 << 20
@@ -47,20 +60,37 @@ def visits_bound(rows: int, groups: int, tile: int = ROW_TILE) -> int:
     return max(1, (rows + groups * (tile - 1)) // tile)
 
 
+def _span_tiles(tile: int) -> int:
+    """Row tiles a visit holds at most: the span cap over the tile (one
+    of ``_ROW_TILES``)."""
+    return _ROW_TILES[-1] // tile
+
+
+def streams(sizes, tile: int = ROW_TILE):
+    """Times each group's matrices are streamed, [groups] int32 from the
+    groups' row counts: once for a group with a row, once more for every
+    span cap of rows (in whole tiles) beyond the first. The visits of
+    :func:`group_layout`, and what ``moe_expert_reads`` sums."""
+    tiles = (sizes + tile - 1) // tile
+    per = _span_tiles(tile)
+    return ((tiles + per - 1) // per).astype(jnp.int32)
+
+
 def group_layout(eid, groups: int, tile: int = ROW_TILE):
     """Where each routed row goes when every group starts at a multiple of
     ``tile``. ``eid`` [R] int32: the (local) group of each routed row,
     ``groups`` for a row in no held group. Returns (dest [R] int32: the
-    row's place in the padded layout, ``visits x tile`` (out of range) for
-    a row in no group; gid [visits] int32: the group each visit serves,
-    the last real visit's group repeated behind it; nvis [1] int32; sizes
+    row's place in the padded layout, ``V x tile`` (out of range) for a
+    row in no group, ``V = visits_bound(R, groups, tile)``; visits, three
+    [V] int32 lists: the group each visit serves, its first row tile and
+    how many of the group's tiles it spans (:func:`streams` visits a
+    group), the last real visit repeated behind it; nvis [1] int32; sizes
     [groups] int32)."""
     R = eid.shape[0]
     V = visits_bound(R, groups, tile)
     sizes = jnp.bincount(eid, length=groups + 1).astype(jnp.int32)[:groups]
     tiles = (sizes + tile - 1) // tile
     tile_end = jnp.cumsum(tiles)
-    nvis = tile_end[-1]
     first_row = jnp.cumsum(sizes) - sizes           # in the sorted order
     order = jnp.argsort(eid, stable=True)
     e_sorted = jnp.take(eid, order)
@@ -70,11 +100,19 @@ def group_layout(eid, groups: int, tile: int = ROW_TILE):
     dest_sorted = jnp.where(
         held, (jnp.take(tile_end - tiles, e_safe)) * tile + rank, V * tile)
     dest = jnp.zeros((R,), jnp.int32).at[order].set(dest_sorted)
-    # visit v serves the first group whose tiles end beyond v
+    # visit v serves the first group whose visits end beyond v, from the
+    # tile its earlier visits of that group stopped at
+    per = _span_tiles(tile)
+    visits = streams(sizes, tile)
+    vis_end = jnp.cumsum(visits)
+    nvis = vis_end[-1]
     v = jnp.minimum(jnp.arange(V, dtype=jnp.int32), jnp.maximum(nvis - 1, 0))
-    gid = jnp.minimum(jnp.searchsorted(tile_end, v, side="right"),
+    gid = jnp.minimum(jnp.searchsorted(vis_end, v, side="right"),
                       groups - 1).astype(jnp.int32)
-    return dest, gid, nvis.reshape(1).astype(jnp.int32), sizes
+    done = (v - jnp.take(vis_end - visits, gid)) * per
+    first = (jnp.take(tile_end - tiles, gid) + done).astype(jnp.int32)
+    ntile = jnp.clip(jnp.take(tiles, gid) - done, 0, per).astype(jnp.int32)
+    return dest, (gid, first, ntile), nvis.reshape(1).astype(jnp.int32), sizes
 
 
 def _chunk_rows(rows: int, cols: int, itemsize: int) -> int:
@@ -90,13 +128,19 @@ def _chunk_rows(rows: int, cols: int, itemsize: int) -> int:
     return best
 
 
-def _kernel(gid_ref, nvis_ref, x_ref, *rest, K1, K2, tm, tf, gated,
-            activation):
-    if gated:
-        wg_hbm, wu_hbm, wo_hbm, o_ref, gbuf, ubuf, obuf, sems = rest
-    else:
-        wg_hbm, wo_hbm, o_ref, gbuf, obuf, sems = rest
-        wu_hbm = ubuf = None
+def _kernel(gid_ref, first_ref, ntile_ref, nvis_ref, x_hbm, *rest, K1, K2,
+            tm, tf, T, heights, gated, activation):
+    rest = list(rest)
+    wg_hbm = rest.pop(0)
+    wu_hbm = rest.pop(0) if gated else None
+    wo_hbm, y_hbm, xbuf, ybuf, gbuf = (rest.pop(0) for _ in range(5))
+    ubuf = rest.pop(0) if gated else None
+    obuf, sems, rsems = (rest.pop(0) for _ in range(3))
+    if rest:
+        # a taller span's float32 sums and its h, in scratch of their own
+        gacc = rest.pop(0)
+        uacc = rest.pop(0) if gated else None
+        hbuf, yacc = rest
     v = pl.program_id(0)
     nvis = nvis_ref[0]
 
@@ -121,14 +165,106 @@ def _kernel(gid_ref, nvis_ref, x_ref, *rest, K1, K2, tm, tf, gated,
         for cp in cps:
             cp.wait()
 
-    @pl.when(v < nvis)
-    def _visit():
+    def tile(j, first=0):
+        return pl.ds(pl.multiple_of((first + j) * T, T), T)
+
+    def rows_in(w, j):
+        """Row tile j of visit w: [T, M] at a multiple of T in HBM, to
+        the same place of the span in VMEM."""
+        return pltpu.make_async_copy(x_hbm.at[tile(j, first_ref[w])],
+                                     xbuf.at[tile(j)], rsems.at[0])
+
+    def rows_out(w, j):
+        return pltpu.make_async_copy(ybuf.at[tile(j)],
+                                     y_hbm.at[tile(j, first_ref[w])],
+                                     rsems.at[1])
+
+    def each_tile(w, do):
+        """``do(w, j)`` for every row tile j of visit w."""
+        def one(j, carry):
+            do(w, j)
+            return carry
+        jax.lax.fori_loop(0, ntile_ref[w], one, 0)
+
+    def next_rows():
+        # this visit's rows are read: the next visit's come in under the
+        # down projection
+        @pl.when(v + 1 < nvis)
+        def _next_rows():
+            each_tile(v + 1, lambda w, j: rows_in(w, j).start())
+
+    def next_visit():
+        @pl.when(v + 1 < nvis)
+        def _next():
+            start(up_copies(gid_ref[v + 1], 0, 0))
+
+    def rows_landed():
+        # the last visit's rows went out under this visit's stream: they
+        # have long landed when this one's take their place in ``ybuf``
+        @pl.when(v > 0)
+        def _last_out():
+            each_tile(v - 1, lambda w, j: rows_out(w, j).wait())
+
+    def tall_span(H):
+        """A span of more than one tile: the same pass over the expert's
+        chunks in the same order, as LOOPS over the chunks with the sums
+        in scratch, so the code of one chunk step a height and not of
+        K1 + K2. Three more unrolled bodies beside the one-tile span's
+        cost the kernel a third of its speed at Solar's and Pangu's
+        widths with not one of them taken (PERF.md section 6, PR 45)."""
         e = gid_ref[v]
 
-        @pl.when(v == 0)
-        def _first():
-            start(up_copies(e, 0, 0))
+        def stream(K, copies, turn, multiply):
+            """Chunk c of K multiplies with c + 1 in flight; ``turn``
+            starts what follows the last."""
+            def step(c, carry):
+                slot = c % 2
+                pl.when(c + 1 < K)(lambda: start(copies(e, c + 1, 1 - slot)))
+                pl.when(c + 1 == K)(turn)
+                wait(copies(e, c, slot))
+                multiply(c, slot)
+                return carry
+            jax.lax.fori_loop(0, K, step, 0)
 
+        def add(acc, x, w):
+            # the product on the LEFT: the compiler then carries the running
+            # sum through the MXU's 128-deep passes, one chain over all of
+            # the contraction as a plain matmul sums it; ``acc + dot`` sums
+            # each chunk apart first and reads a little further from the
+            # plain reference (PERF.md section 6, PR 45)
+            acc[:H] = jnp.dot(x, w, preferred_element_type=jnp.float32) \
+                + acc[:H]
+
+        def lanes(c, n):
+            return pl.ds(pl.multiple_of(c * n, n), n)
+
+        def up(c, slot):
+            xc = xbuf[:H, lanes(c, tm)]
+            add(gacc, xc, gbuf[slot])
+            if gated:
+                add(uacc, xc, ubuf[slot])
+
+        def down(c, slot):
+            add(yacc, hbuf[:H, lanes(c, tf)], obuf[slot])
+
+        for acc in (gacc, uacc, yacc):
+            if acc is not None:
+                acc[:H] = jnp.zeros((H, acc.shape[1]), jnp.float32)
+        stream(K1, up_copies, lambda: start(down_copy(e, 0, 0)), up)
+        h = activation(gacc[:H]) * uacc[:H] if gated \
+            else activation(gacc[:H])
+        hbuf[:H] = h.astype(hbuf.dtype)
+        next_rows()
+        stream(K2, down_copy, next_visit, down)
+        rows_landed()
+        ybuf[:H] = yacc[:H].astype(ybuf.dtype)
+
+    def span(H):
+        """The visit's rows as one operand of H rows through gate, up
+        and down: one pass over the expert's chunks."""
+        if H > heights[0]:
+            return tall_span(H)
+        e = gid_ref[v]
         g = u = None
         for c in range(K1):
             # one chunk ahead, always: the stream never waits for us
@@ -137,7 +273,7 @@ def _kernel(gid_ref, nvis_ref, x_ref, *rest, K1, K2, tm, tf, gated,
             else:
                 start(down_copy(e, 0, 0))
             wait(up_copies(e, c, c % 2))
-            xc = x_ref[:, c * tm:(c + 1) * tm]
+            xc = xbuf[:H, c * tm:(c + 1) * tm]
             gp = jnp.dot(xc, gbuf[c % 2], preferred_element_type=jnp.float32)
             g = gp if g is None else g + gp
             if gated:
@@ -145,35 +281,79 @@ def _kernel(gid_ref, nvis_ref, x_ref, *rest, K1, K2, tm, tf, gated,
                              preferred_element_type=jnp.float32)
                 u = up if u is None else u + up
         h = activation(g) * u if gated else activation(g)
-        h = h.astype(x_ref.dtype)
+        h = h.astype(xbuf.dtype)
+        next_rows()
         acc = None
         for c in range(K2):
             if c + 1 < K2:
                 start(down_copy(e, c + 1, (c + 1) % 2))
             else:
-                @pl.when(v + 1 < nvis)
-                def _next():
-                    start(up_copies(gid_ref[v + 1], 0, 0))
+                next_visit()
             wait(down_copy(e, c, c % 2))
             part = jnp.dot(h[:, c * tf:(c + 1) * tf], obuf[c % 2],
                            preferred_element_type=jnp.float32)
             acc = part if acc is None else acc + part
-        o_ref[...] = acc.astype(o_ref.dtype)
+        rows_landed()
+        ybuf[:H] = acc.astype(ybuf.dtype)
+
+    @pl.when(v < nvis)
+    def _visit():
+        @pl.when(v == 0)
+        def _first():
+            start(up_copies(gid_ref[v], 0, 0))
+            each_tile(v, lambda w, j: rows_in(w, j).start())
+
+        each_tile(v, lambda w, j: rows_in(w, j).wait())
+        rows = ntile_ref[v] * T
+        below = 0
+        for H in heights:
+            # the smallest height that holds the span; one tile is the
+            # first branch, what a step at 3-4 rows an expert always takes
+            pl.when((rows > below) & (rows <= H))(
+                functools.partial(span, H))
+            below = H
+        each_tile(v, lambda w, j: rows_out(w, j).start())
+
+        @pl.when(v == nvis - 1)
+        def _drain():
+            each_tile(v, lambda w, j: rows_out(w, j).wait())
+
+
+def _heights(T: int, V: int):
+    """The operand heights a call's visits choose from: ``_ROW_TILES``
+    from the layout's tile up, no further than holds the whole layout."""
+    hs = [h for h in _ROW_TILES if h >= T]
+    enough = next((i for i, h in enumerate(hs) if h >= V * T), len(hs) - 1)
+    return tuple(hs[:enough + 1])
+
+
+def vmem_need(T: int, V: int, M: int, F: int, itemsize: int,
+              gated: bool) -> int:
+    """Bytes of VMEM a call asks for (its ``vmem_limit_bytes``): the two
+    chunk slots a matrix, the rows in and out of the tallest span, that
+    span's float32 intermediates and 4 MB. What the shapes need and no
+    round number: the rest of VMEM is where XLA prefetches the dense
+    weights of the operations around the call."""
+    tm, tf = _chunk_rows(M, F, itemsize), _chunk_rows(F, M, itemsize)
+    H = _heights(T, V)[-1]
+    need = sum(2 * a * b * itemsize for a, b in
+               [(tm, F)] * (2 if gated else 1) + [(tf, M)])
+    return need + 2 * H * M * itemsize + H * (2 * F + 2 * M) * 4 + (4 << 20)
 
 
 # jitted under its own name: the device trace names a Mosaic call after
 # the function that encloses it, and a program whose layers share shapes
 # traces this body once
 @functools.partial(jax.jit, static_argnames=("activation", "interpret"))
-def grouped_ffn_decode(xs, gid, nvis, weights, *, activation,
+def grouped_ffn_decode(xs, visits, nvis, weights, *, activation,
                        interpret=False):
     """xs [V * T, M] rows in :func:`group_layout`'s order at row tile T;
-    gid [V], nvis [1]; weights (wi, wo) or (wi_gate, wi_up, wo) stacked
-    [G, ...] in xs's dtype. Returns ys [V * T, M]: every laid-out row
-    through its group's feed-forward (rows of visits past ``nvis`` are
-    left as they were allocated)."""
+    visits (gid, first, ntile) [V] each, nvis [1]; weights (wi, wo) or
+    (wi_gate, wi_up, wo) stacked [G, ...] in xs's dtype. Returns ys
+    [V * T, M]: every laid-out row through its group's feed-forward (rows
+    of no visit's tiles are left as they were allocated)."""
     P, M = xs.shape
-    V = gid.shape[0]
+    V = visits[0].shape[0]
     T = P // V
     gated = len(weights) == 3
     wo = weights[-1]
@@ -181,44 +361,43 @@ def grouped_ffn_decode(xs, gid, nvis, weights, *, activation,
     isz = xs.dtype.itemsize
     tm, tf = _chunk_rows(M, F, isz), _chunk_rows(F, M, isz)
     K1, K2 = M // tm, F // tf
-
-    def row(i, gid, nvis):
-        # visits past the last real one stay on its tile: nothing moves
-        return (jnp.minimum(i, jnp.maximum(nvis[0] - 1, 0)), 0)
+    heights = _heights(T, V)
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    scratch = [pltpu.VMEM((2, tm, F), xs.dtype)]
-    if gated:
-        scratch.append(pltpu.VMEM((2, tm, F), xs.dtype))
-    scratch += [pltpu.VMEM((2, tf, M), xs.dtype),
-                pltpu.SemaphoreType.DMA((3, 2))]
-    need = sum(2 * a * b * isz for a, b in
-               [(tm, F)] * (2 if gated else 1) + [(tf, M)])
-    # what the shapes need and no round number: the rest of VMEM is where
-    # XLA prefetches the dense weights of the operations around the call
-    need += 4 * T * M * isz + T * (2 * F + 2 * M) * 4 + (4 << 20)
+    H = heights[-1]
+    scratch = [pltpu.VMEM((H, M), xs.dtype)] * 2 \
+        + [pltpu.VMEM((2, tm, F), xs.dtype)] * (2 if gated else 1) \
+        + [pltpu.VMEM((2, tf, M), xs.dtype),
+           pltpu.SemaphoreType.DMA((3, 2)), pltpu.SemaphoreType.DMA((2,))]
+    if len(heights) > 1:
+        scratch += [pltpu.VMEM((H, F), jnp.float32)] * (2 if gated else 1) \
+            + [pltpu.VMEM((H, F), xs.dtype), pltpu.VMEM((H, M), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(V,),
-        in_specs=[pl.BlockSpec((T, M), row)] + [any_spec] * len(weights),
-        out_specs=pl.BlockSpec((T, M), row),
+        num_scalar_prefetch=4, grid=(V,),
+        in_specs=[any_spec] * (1 + len(weights)),
+        out_specs=any_spec,
         scratch_shapes=scratch)
     return pl.pallas_call(
-        functools.partial(_kernel, K1=K1, K2=K2, tm=tm, tf=tf, gated=gated,
+        functools.partial(_kernel, K1=K1, K2=K2, tm=tm, tf=tf, T=T,
+                          heights=heights, gated=gated,
                           activation=activation),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, M), xs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",), vmem_limit_bytes=need),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_need(T, V, M, F, isz, gated)),
         interpret=interpret,
-    )(gid, nvis, xs, *weights)
+    )(*visits, nvis, xs, *weights)
 
 
 def row_tile(rows: int, experts: int) -> int:
-    """Rows a visit for ``rows`` routed rows over ``experts`` experts: the
-    smallest tile that holds the rows an expert expects, so that an
-    expert's matrices are streamed about once (decode steps, 2-4 rows an
-    expert: 16; a refill step at 51: 64), the largest when none does
-    (:func:`fits` keeps such a step off the kernel)."""
+    """The layout's row tile for ``rows`` routed rows over ``experts``
+    experts: the smallest that holds the rows an expert expects, so that
+    the padding a group brings stays under its rows (decode steps, 2-12
+    rows an expert: 16; a refill step at 51: 64), the largest when none
+    does (:func:`fits` keeps such a step off the kernel). A visit spans
+    all of a group's tiles up to 128 rows, so a group over its tile
+    costs rows of padding and no second stream."""
     return next((t for t in _ROW_TILES if rows <= t * experts),
                 _ROW_TILES[-1])
 
@@ -257,13 +436,13 @@ def layout_and_run(tokens, eid, weights, activation, dtype, *,
     R = eid.shape[0]
     k = R // tokens.shape[0]
     G = weights[0].shape[0]
-    dest, gid, nvis, _ = group_layout(eid, G, tile)
-    P = gid.shape[0] * tile
+    dest, visits, nvis, _ = group_layout(eid, G, tile)
+    P = visits[0].shape[0] * tile
     src = jnp.full((P,), tokens.shape[0], jnp.int32).at[dest].set(
         jnp.arange(R, dtype=jnp.int32) // k, mode="drop")
     xs = jnp.take(tokens.astype(dtype), src, axis=0, mode="fill",
                   fill_value=0)
     with region("moe_experts"):
-        ys = grouped_ffn_decode(xs, gid, nvis, tuple(weights),
+        ys = grouped_ffn_decode(xs, visits, nvis, tuple(weights),
                                 activation=activation, interpret=interpret)
     return jnp.take(ys, dest, axis=0, mode="fill", fill_value=0)

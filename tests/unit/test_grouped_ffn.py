@@ -67,17 +67,39 @@ CASES = {
     # the share holds experts 4..8 and every row goes to 0..4
     "no-held-row-at-all": dict(k=2, held=(4, 4), router={}, normalize=True,
                                hot=(0, 2)),
+    # groups of 17, 32, 33, 64, 129 and 0 rows (and 1, 0 to fill the eight)
+    # in ONE call at the 16-row tile: visits of two, two, three (a 64-row
+    # operand over a neighbour's tile), four and eight tiles, and a group
+    # split at the 128-row cap whose second visit is one tile
+    "spans-of-many-tiles": dict(k=1, held=None, router={}, normalize=False,
+                                rows=(17, 32, 33, 64, 129, 0, 1, 0), tile=16),
+    "spans-of-many-tiles-ungated": dict(
+        k=1, held=None, router={}, normalize=False, gated=False,
+        rows=(0, 129, 64, 33, 32, 17, 0, 1), tile=16),
+    # the same under a share's offset: the held half takes 17 + 33 + 129
+    # rows, the other half's 97 rows are never laid out
+    "spans-in-a-share": dict(k=1, held=(4, 4), router={}, normalize=False,
+                             rows=(64, 0, 32, 1, 17, 0, 33, 129), tile=16),
+    # a refill step's 64-row tile: groups of 65, 128, 129 and 200 rows are
+    # visits of two tiles (once 128 rows) or split at the cap
+    "spans-at-the-64-row-tile": dict(
+        k=1, held=None, router={}, normalize=False,
+        rows=(65, 128, 129, 200, 0, 64, 3, 0), tile=64),
 }
 
 
 def _case(name, dtype):
     c = CASES[name]
     rng = np.random.default_rng(sorted(CASES).index(name))
-    rows = c.get("S", S)
+    rows = sum(c["rows"]) if "rows" in c else c.get("S", S)
     tokens = jnp.asarray(rng.normal(size=(rows, M)), dtype)
     logits = rng.normal(size=(rows, E))
     for e in c.get("hot", ()):
         logits[:, e] += 50.0
+    if "rows" in c:
+        # token t's one expert, by count, shuffled over the tokens
+        mine = rng.permutation(np.repeat(np.arange(E), c["rows"]))
+        logits[np.arange(rows), mine] += 50.0
     router = dict(c["router"])
     if router.pop("bias", False):
         router["select_bias"] = jnp.asarray(rng.normal(size=E) * 0.3,
@@ -90,10 +112,22 @@ def _case(name, dtype):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_kernel_is_ragged_dot_and_the_dense_reference(name, dtype):
+def test_kernel_is_ragged_dot_and_the_dense_reference(name, dtype,
+                                                      monkeypatch):
     c, tokens, logits, router, weights = _case(name, dtype)
-    assert gf.row_tile(tokens.shape[0] * c["k"], E) \
-        == (64 if name == "forty-rows-an-expert" else 16)
+    if "tile" in c:
+        # the tile is the caller's: a decode step's 16 under the spread of
+        # a cell that expects 12 rows an expert, whatever this draw expects
+        monkeypatch.setattr(gf, "row_tile", lambda rows, experts: c["tile"])
+        sizes = np.asarray(c["rows"])
+        first, count = c["held"] or (0, E)
+        streams = np.asarray(gf.streams(sizes[first:first + count],
+                                        c["tile"]))
+        assert streams.max() == 2 and (streams[sizes[first:first + count]
+                                               <= 128] <= 1).all()
+    else:
+        assert gf.row_tile(tokens.shape[0] * c["k"], E) \
+            == (64 if name == "forty-rows-an-expert" else 16)
     call = dict(normalize_weights=c["normalize"], held=c["held"], **router)
     want, _ = grouped_moe_ffn(tokens, logits, c["k"], weights, jax.nn.silu,
                               dtype, **call)
@@ -147,26 +181,41 @@ def test_the_shares_halves_sum_to_the_whole():
     assert float(jnp.abs(halves[0] + halves[1] - uncut).max()) < 1e-5
 
 
+@pytest.mark.parametrize("tile", [16, 64])
 @pytest.mark.parametrize("sizes", [(0, 0, 0, 0), (1, 0, 17, 0), (0, 64, 0, 0),
-                                   (16, 16, 16, 16), (3, 5, 2, 7)])
-def test_layout_puts_every_group_at_a_tile_and_within_its_bound(sizes):
-    G, T = len(sizes), gf.ROW_TILE
+                                   (16, 16, 16, 16), (3, 5, 2, 7),
+                                   (17, 32, 33, 64, 129, 0), (300, 0, 5, 0),
+                                   (128, 129, 0, 257)])
+def test_layout_puts_every_group_at_a_tile_and_within_its_bound(sizes, tile):
+    G, T = len(sizes), tile
     elsewhere = 9
     eid = np.concatenate([np.full(n, g) for g, n in enumerate(sizes)]
                          + [np.full(elsewhere, G)]).astype(np.int32)
     np.random.default_rng(0).shuffle(eid)
-    dest, gid, nvis, got_sizes = jax.device_get(
-        gf.group_layout(jnp.asarray(eid), G))
-    V = gf.visits_bound(len(eid), G)
+    dest, (gid, first, ntile), nvis, got_sizes = jax.device_get(
+        gf.group_layout(jnp.asarray(eid), G, T))
+    V = gf.visits_bound(len(eid), G, T)
     tiles = [-(-n // T) for n in sizes]
-    assert gid.shape == (V,) and int(nvis[0]) == sum(tiles) <= V
     assert tuple(got_sizes) == sizes
-    want_gid = [g for g, t in enumerate(tiles) for _ in range(t)]
-    assert list(gid[:len(want_gid)]) == want_gid
-    # behind the last visit its group repeats (with no visit at all
-    # nothing reads the list)
-    if want_gid:
-        assert set(gid[len(want_gid):]) <= {want_gid[-1]}
+    # the visit table: ONE visit a group with a row while its tiles are
+    # within the 128-row span, one more for every 128 rows beyond
+    per = 128 // T
+    want = [(g, t0 + k, min(per, t - k))
+            for g, (t, t0) in enumerate(zip(tiles, np.cumsum([0] + tiles)))
+            for k in range(0, t, per)]
+    assert gid.shape == first.shape == ntile.shape == (V,)
+    assert int(nvis[0]) == len(want) <= sum(tiles) <= V
+    assert list(zip(gid, first, ntile))[:len(want)] == want
+    streams = np.asarray(gf.streams(jnp.asarray(sizes, jnp.int32), T))
+    assert list(streams) == [sum(g == w[0] for w in want) for g in range(G)]
+    assert all(streams[g] == (n > 0) for g, n in enumerate(sizes)
+               if -(-n // T) * T <= 128)
+    # behind the last visit it repeats (with no visit at all nothing
+    # reads the lists)
+    if want:
+        assert set(list(zip(gid, first, ntile))[len(want):]) <= {want[-1]}
+    # the rows' places and the padded size: what they were when a visit
+    # was a row tile
     held = eid < G
     assert (dest[~held] == V * T).all()
     assert len(set(dest[held])) == held.sum()            # no two rows share
@@ -174,6 +223,33 @@ def test_layout_puts_every_group_at_a_tile_and_within_its_bound(sizes):
     for g, n in enumerate(sizes):
         mine = np.sort(dest[eid == g])
         assert list(mine) == list(range(start[g], start[g] + n))
+
+
+def test_a_visit_writes_back_its_own_tiles_alone():
+    """A group of 33 rows is one visit of three tiles under a 64-row
+    operand; the fourth tile of that operand is the next group's. Stopped
+    after the first visit, the call has written three tiles and left the
+    neighbour's as allocated (the interpreter allocates NaN); run to the
+    end, both groups are their experts' feed-forward."""
+    rng = np.random.default_rng(5)
+    sizes = (33, 64)
+    eid = jnp.asarray(np.repeat(np.arange(2), sizes), jnp.int32)
+    dest, visits, nvis, _ = gf.group_layout(eid, 2)
+    assert [int(v[0]) for v in visits] == [0, 0, 3] and int(nvis[0]) == 2
+    weights = _weights(rng, 2, gated=False)
+    P = visits[0].shape[0] * gf.ROW_TILE
+    xs = jnp.asarray(rng.normal(size=(P, M)), jnp.float32)
+    run = lambda n: np.asarray(gf.grouped_ffn_decode(
+        xs, visits, jnp.full((1,), n, jnp.int32), weights,
+        activation=jax.nn.silu, interpret=True))
+    none, one, both = run(0), run(1), run(2)
+    assert np.isnan(none).all()
+    assert not np.isnan(one[:48]).any() and np.isnan(one[48:]).all()
+    want = [jax.nn.silu(xs[a:b] @ weights[0][g]) @ weights[1][g]
+            for g, (a, b) in enumerate(((0, 48), (48, 112)))]
+    np.testing.assert_allclose(one[:48], want[0], atol=1e-5)
+    np.testing.assert_allclose(both[:112], np.concatenate(want), atol=1e-5)
+    assert np.isnan(both[112:]).all()
 
 
 def test_who_takes_which_path(monkeypatch):
@@ -237,7 +313,8 @@ def test_training_layer_keeps_ragged_dot_and_its_gradient():
 def _closed_form(per_step_sizes):
     """(hit, reads) over a list of per-(step, layer) held group sizes."""
     hit = sum(int((s > 0).sum()) for s in per_step_sizes)
-    reads = sum(int((-(-s // gf.ROW_TILE)).sum()) for s in per_step_sizes)
+    reads = sum(int(gf.streams(jnp.asarray(s), gf.ROW_TILE).sum())
+                for s in per_step_sizes)
     return hit, reads
 
 
@@ -262,6 +339,23 @@ def _olmoe_engine():
     return make_engine(cfg, tiny_params(cfg)), 64
 
 
+def _olmoe_hot_engine():
+    """Twenty sequences whose router is silent (a zero gate: every row
+    ties, and the top-k of a tie is the first k experts), so each step
+    routes its 20+ rows to the same two experts: groups of two row tiles."""
+    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                            RaggedInferenceConfig)
+    from tests.unit.test_olmoe import tiny_cfg, tiny_params
+    cfg = tiny_cfg(2)
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: jnp.zeros_like(leaf)
+        if "'gate'" in jax.tree_util.keystr(path) else leaf,
+        tiny_params(cfg))
+    return InferenceEngineV2(cfg, params, RaggedInferenceConfig(
+        max_seqs=24, chunk_size=8, block_size=8, num_blocks=96,
+        max_blocks_per_seq=4, decode_loop_steps=4, dtype="float32")), 64
+
+
 def _solar_engine():
     from benchmark.model_types import solar_open2 as mt
     from tests.unit.test_solar_open2 import engine, tiny
@@ -281,21 +375,26 @@ def _dense_engine():
         max_blocks_per_seq=8, decode_loop_steps=4, dtype="float32")), 64
 
 
-@pytest.mark.parametrize("family", ["olmoe", "solar_open2", "dense"])
+@pytest.mark.parametrize("family", ["olmoe", "solar_open2", "dense",
+                                    "olmoe-one-hot-pair"])
 def test_fused_loop_counts_experts_hit_and_reads(family, monkeypatch):
     """After a ``decode_batch`` through the (interpreted) kernel,
     ``moe_experts_hit`` and ``moe_expert_reads`` are the closed form over
-    the routing the program computed: held groups with a row, and row
-    tiles visited, summed over sparse layers and steps. A model with no
-    routed expert leaves both at 0, and so does the ``ragged_dot`` path."""
+    the routing the program computed: held groups with a row, and the
+    kernel's visits to them (streams of an expert's matrices: one a group
+    within 128 rows, however many row tiles), summed over sparse layers
+    and steps. A model with no routed expert leaves both at 0, and so
+    does the ``ragged_dot`` path."""
     build = {"olmoe": _olmoe_engine, "solar_open2": _solar_engine,
-             "dense": _dense_engine}[family]
+             "dense": _dense_engine,
+             "olmoe-one-hot-pair": _olmoe_hot_engine}[family]
     eng, vocab = build()
     rng = np.random.default_rng(2)
-    prompts = [rng.integers(1, vocab, 5 + i).tolist() for i in range(3)]
-    first = eng.put([0, 1, 2], prompts, _greedy=True)
+    uids = list(range(20 if family == "olmoe-one-hot-pair" else 3))
+    prompts = [rng.integers(1, vocab, 5 + i % 3).tolist() for i in uids]
+    first = eng.put(uids, prompts, _greedy=True)
     # the CPU default is ragged_dot: a loop on it counts nothing
-    eng.decode_batch([0, 1, 2], [first[u] for u in (0, 1, 2)], 2)
+    eng.decode_batch(uids, [first[u] for u in uids], 2)
     stats = eng.pipeline_stats
     assert stats["moe_experts_hit"] == stats["moe_expert_reads"] == 0
     if family == "dense":
@@ -309,9 +408,9 @@ def test_fused_loop_counts_experts_hit_and_reads(family, monkeypatch):
     seen = _spy_on_layouts(monkeypatch)
     jax.clear_caches()
     eng2, _ = build()
-    first = eng2.put([0, 1, 2], prompts, _greedy=True)
+    first = eng2.put(uids, prompts, _greedy=True)
     seen.clear()                       # the prefill steps are not counted
-    toks = eng2.decode_batch([0, 1, 2], [first[u] for u in (0, 1, 2)], 4)
+    toks = eng2.decode_batch(uids, [first[u] for u in uids], 4)
     jax.effects_barrier()
     stats = eng2.pipeline_stats
     layers = eng2.runner.model_cfg.num_layers
@@ -319,11 +418,18 @@ def test_fused_loop_counts_experts_hit_and_reads(family, monkeypatch):
     assert (stats["moe_experts_hit"], stats["moe_expert_reads"]) \
         == _closed_form(seen)
     assert 0 < stats["moe_experts_hit"] <= stats["moe_expert_reads"]
+    if family == "olmoe-one-hot-pair":
+        # 20+ rows on each of two experts, two row tiles a group: one
+        # stream each all the same
+        assert all(sorted(s)[-2:] == [max(s)] * 2 and max(s) > gf.ROW_TILE
+                   and sum(s) == 2 * max(s) for s in map(list, seen))
+        assert stats["moe_expert_reads"] == stats["moe_experts_hit"] \
+            == 2 * len(seen)
     # the same tokens as the ragged_dot loop decodes
     eng3, _ = build()
     monkeypatch.undo()
     jax.clear_caches()
-    f3 = eng3.put([0, 1, 2], prompts, _greedy=True)
-    want = eng3.decode_batch([0, 1, 2], [f3[u] for u in (0, 1, 2)], 4)
+    f3 = eng3.put(uids, prompts, _greedy=True)
+    want = eng3.decode_batch(uids, [f3[u] for u in uids], 4)
     assert {u: list(map(int, t)) for u, t in toks.items()} \
         == {u: list(map(int, t)) for u, t in want.items()}

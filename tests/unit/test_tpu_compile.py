@@ -64,6 +64,19 @@ def _mosaic_call_names(hlo):
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)]
 
 
+def _scoped_vmem(hlo, name):
+    """(asked, used) bytes of scoped VMEM of every Mosaic call ``name`` in
+    the compiled text: the call's ``vmem_limit_bytes`` and what Mosaic
+    laid out under it."""
+    import re
+    size = r'scoped_memory_configs":\[\{[^\]]*?"size":"(\d+)"'
+    calls = [line for line in hlo.splitlines() if re.match(
+        r"\s*(ROOT )?%%%s[\w\-.]* = [^\n]*\"tpu_custom_call\"" % name, line)]
+    return [(int(re.search('"' + size, line).group(1)),
+             int(re.search('"used_' + size, line).group(1)))
+            for line in calls]
+
+
 def test_the_flash_kernels_keep_the_name_their_roofline_reader_matches(
         one_chip, monkeypatch):
     """``flash_attn_roofline.train`` matches ``attn-bf16_<B>_<H>_<T>_<D>``:
@@ -396,20 +409,23 @@ ROLLOUT_BLOCK, ROLLOUT_ROW = 640, 256
 
 
 @pytest.mark.parametrize(
-    "name, family, layers, heads, planes, row, block, blocks, maxb", [
+    "name, family, layers, heads, planes, row, block, blocks, maxb, "
+    "experts", [
         ("solar-open2-250b", "solar_open2", 3, 64, (1, 2), 1024, 640, 260,
-         2),
+         2, (18880, 4096, 1280)),
         ("kimi-linear-48b-a3b", "kimi_linear", 6, 32, (2, 1), 640, 256,
-         1920, 24),
+         1920, 24, (20416, 2304, 1024)),
     ], ids=["solar2", "kimi"])
 def test_refill_step_runs_the_chunk_kernel_of_the_delta_rule(
         one_chip, monkeypatch, name, family, layers, heads, planes, row,
-        block, blocks, maxb):
+        block, blocks, maxb, experts):
     """The [4, 512] prefill step of the two cells with recurrent layers,
     at their cut, from shapes alone: every KDA layer runs the Pallas chunk
     kernel under its own name (which the decode update's readers do not
     match), traced and lowered ONCE for all of them, and XLA's batched
-    triangular solve is gone from the program."""
+    triangular solve is gone from the program. Its routed experts run the
+    grouped kernel at a 64-row tile, under the name and padded row count
+    the cells' refill lines are read by."""
     import importlib
     import json
     import os
@@ -461,6 +477,14 @@ def test_refill_step_runs_the_chunk_kernel_of_the_delta_rule(
     assert names.count("kda_chunk_prefill") == layers, names
     assert not any(re.match(r"^kda_decode_state_update", n) for n in names)
     assert "riangular" not in hlo
+    from deepspeed_tpu.ops.kernels import grouped_ffn
+    padded, width, inner = experts
+    assert re.search(r"%%grouped_ffn_decode[\w\-.]* = bf16\[%d,%d\]"
+                     % (padded, width), hlo) and "ragged-dot" not in hlo
+    # at the refill's 64-row tile the call asks VMEM for a 128-row span
+    tile = 64
+    assert {a for a, _ in _scoped_vmem(hlo, "grouped_ffn_decode")} == {
+        grouped_ffn.vmem_need(tile, padded // tile, width, inner, 2, True)}
 
 
 def _rollout_runner(layers, clients):
@@ -617,14 +641,36 @@ def test_nemotron_loop_and_refill_compile_at_256_clients(one_chip,
         r"%grouped_ffn_decode[\w\-.]* = bf16\[2496,2688\]", hlo)) == 5
     assert len(re.findall(
         r"%closed_call[\w\-.]* = bf16\[256,32,256\]", hlo)) == 2
+    # the grouped kernel with its span branches (operands of 16 to 128
+    # rows) lies inside the VMEM its call asks for, and the asking counts
+    # the tallest span's rows, not one tile's
+    from deepspeed_tpu.ops.kernels import grouped_ffn
+    # (the TPU compiler refuses a Mosaic call whose scratch and stack pass
+    # its limit, so the loop's compile above is the first half of this;
+    # inside a program XLA adds its own operand prefetches to the call's
+    # "used" figure, so Mosaic's own is read from the call compiled alone)
+    asked = grouped_ffn.vmem_need(16, 2496 // 16, 2688, 1920, 2, False)
+    assert [a for a, _ in _scoped_vmem(hlo, "grouped_ffn_decode")] \
+        == [asked] * 5
+    assert asked - grouped_ffn.vmem_need(16, 1, 2688, 1920, 2, False) \
+        == (128 - 16) * (2 * 2688 * 2 + (2 * 1920 + 2 * 2688) * 4)
+    bf16 = functools.partial(spec, dtype=jnp.bfloat16)
+    alone = jax.jit(functools.partial(
+        grouped_ffn.grouped_ffn_decode, activation=jax.nn.relu)).trace(
+            bf16((2496, 2688)), (spec((156,)),) * 3, spec((1,)),
+            (bf16((64, 2688, 1920)), bf16((64, 1920, 2688)))).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+    (asked_alone, used), = _scoped_vmem(alone, "grouped_ffn_decode")
+    assert asked_alone == asked and 0 < used <= asked
     mem = exe.memory_analysis()
     state_bytes = 6 * (slots + 1) * 64 * 64 * 128 * 4
     assert mem.alias_size_in_bytes >= state_bytes
     assert mem.temp_size_in_bytes < state_bytes // 6
     made = re.findall(r"= f32\[257,64,64,128\]\S* ([\w\-]+)\(", hlo)
     assert set(made) <= {"parameter", "get-tuple-element"}, set(made)
-    # the refill step: experts at a 128-row tile in the same kernel, the
-    # chunked SSD form without a kernel of its own
+    # the refill step: experts at a 128-row tile in the same kernel (the
+    # span cap: a visit is one tile there), the chunked SSD form without a
+    # kernel of its own
     hlo = runner._step_greedy.trace(
         params, KVPool(planes, None, state, conv),
         RaggedBatch(spec((4, 512)), spec((4,)), spec((4,)), spec((4, maxb)),
