@@ -33,6 +33,7 @@ import jax
 import numpy as np
 
 from ...ops.kernels.delta_rule import kda_prefill_uses_kernel
+from ...ops.kernels import short_conv
 from ...resilience.fault_injection import get_fault_injector
 from ...telemetry.serve import serve_observer
 from ...telemetry.trace import SpanSet
@@ -364,6 +365,11 @@ class InferenceEngineV2:
             "state_slots_live": 0, "state_bytes_live": 0,
             "linear_attn_prefill_tokens": 0,
             "linear_attn_prefill_kernel_tokens": 0,
+            # layer-steps of decode whose short convolution ran the
+            # in-place kernel on the pool of carried inputs
+            # (short_conv.decode_uses_kernel, as the mixers ask it):
+            # recurrent layers x decode steps where it does, else 0
+            "conv_steps_in_place": 0,
             # latent-attention models, a layer's worth each: settled
             # latent rows of the live sequences per pure-decode step (and
             # per step of a fused loop), the rows the decode kernel
@@ -1147,6 +1153,13 @@ class InferenceEngineV2:
     def flush(self, uid: int) -> None:
         self._flush_uid(uid)
 
+    def _conv_in_place(self, S: int) -> int:
+        """Recurrent layers whose short convolution takes the in-place
+        kernel at a decode step of ``S`` rows (all of them or none)."""
+        spec = self.runner.state_spec
+        return spec["layers"] * short_conv.decode_uses_kernel(
+            S, spec["conv_width"], self.kv_cache.conv.dtype)
+
     def _refuse_stateful(self, feature: str,
                          latent_too: bool = False) -> None:
         """What would need a snapshot of the recurrent state refuses, by
@@ -1600,6 +1613,7 @@ class InferenceEngineV2:
                 stats["state_slots_live"] += ran
                 stats["state_bytes_live"] += \
                     ran * self.kv_cache.state_bytes_per_slot()
+                stats["conv_steps_in_place"] += n * self._conv_in_place(S)
             if moe_rows is not None:
                 # per call: rows the experts took, and what they would
                 # have taken had every expert been as busy as the busiest
@@ -1822,7 +1836,8 @@ class InferenceEngineV2:
                 if sslots is not None:
                     span.count(state_slots_live=real,
                                state_bytes_live=real
-                               * self.kv_cache.state_bytes_per_slot())
+                               * self.kv_cache.state_bytes_per_slot(),
+                               conv_steps_in_place=self._conv_in_place(S))
             return _PlannedStep(sched, tokens, start, ntok, tables,
                                 feed_mask if has_feed else None, feed_idx,
                                 use_greedy,

@@ -190,9 +190,12 @@ class BlockedKVCache:
                 jnp.zeros((rows, state_spec["heads"], state_spec["d_v"],
                            state_spec["d_k"]), jnp.float32)
                 for _ in range(state_spec["layers"]))
-            self.conv = jnp.zeros((state_spec["layers"], rows,
-                                   state_spec["taps"] - 1,
-                                   state_spec["conv_width"]), self.dtype)
+            # a slot's carried convolution inputs [taps - 1, width], laid
+            # out in whole tiles as the decode step's kernel takes them
+            from ...ops.kernels.short_conv import pool_shape
+            self.conv = jnp.zeros(
+                pool_shape(state_spec["layers"], rows, state_spec["taps"],
+                           state_spec["conv_width"]), self.dtype)
 
     def pin(self, device) -> None:
         """COMMIT the pool to ``device`` (a one-device engine pins itself

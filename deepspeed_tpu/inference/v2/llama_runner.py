@@ -182,24 +182,42 @@ def _state_rows(kv, si: int, batch: RaggedBatch):
     state and zero convolution inputs, whatever the slot's last tenant
     left there; a row with ``n_tokens`` 0 is idle (not ``live``) and
     leaves its pool row as it was. Returns (state, conv, st, slots, fresh,
-    live, prev0 the slot's carried inputs [S, K-1, W], prev those with a
-    fresh row's zeroed)."""
+    live)."""
     state, conv = lin_parts(kv)
-    slots = batch.state_slots
     fresh = batch.start_pos == 0
     live = batch.n_tokens > 0
-    prev0 = conv[si, slots]                               # [S, K-1, W]
-    prev = jnp.where(fresh[:, None, None], 0, prev0)
-    return state, conv, state[si], slots, fresh, live, prev0, prev
+    return state, conv, state[si], batch.state_slots, fresh, live
 
 
-def _carry_conv(conv, si: int, slots, padded, n_tokens, live, prev0):
-    """The next call's K-1 convolution inputs end at the row's last real
-    position; an idle row keeps what it had."""
-    rows = n_tokens[:, None] + jnp.arange(prev0.shape[1], dtype=jnp.int32)
+def _short_conv(conv, si: int, batch: RaggedBatch, fresh, live, pre, w,
+                bias=None):
+    """The activated short convolution of recurrent layer ``si`` over its
+    rows' carried inputs, and the pool with the inputs the next call
+    carries: the last K-1 up to each row's last real position; an idle
+    row keeps what it had. pre [S, C, W] float32, w [K, W], bias [W] or
+    None. A decode step on the TPU is one in-place Pallas call
+    (``ops/kernels/short_conv``: platform and shape decide, nothing a
+    user sets); a prefill chunk and every other backend gather the
+    slots' rows, run ``conv_silu`` and scatter. Returns (conv, y
+    [S, C, W] float32)."""
+    from ...models.solar_open2 import conv_silu
+    from ...ops.kernels import default_interpret, short_conv
+    S, C, W = pre.shape
+    slots = batch.state_slots
+    if C == 1 and short_conv.decode_uses_kernel(S, W, conv.dtype):
+        conv, y = short_conv.short_conv_decode_step(
+            conv, si, slots, pre[:, 0], w, bias, fresh, live,
+            interpret=default_interpret())
+        return conv, y[:, None]
+    taps = w.shape[0] - 1
+    prev0 = conv[si, slots]                 # [S, (K-1) W / lanes, lanes]
+    prev = jnp.where(fresh[:, None, None], 0, prev0).reshape(S, taps, W)
+    y, padded = conv_silu(pre, w, prev, bias)
+    rows = batch.n_tokens[:, None] + jnp.arange(taps, dtype=jnp.int32)
     nxt = jnp.take_along_axis(padded, rows[..., None], axis=1)
-    return conv.at[si, slots].set(
-        jnp.where(live[:, None, None], nxt.astype(conv.dtype), prev0))
+    return conv.at[si, slots].set(jnp.where(
+        live[:, None, None], nxt.astype(conv.dtype).reshape(prev0.shape),
+        prev0)), y
 
 
 def _with_layer_state(kv, state, conv, si: int, st):
@@ -215,15 +233,16 @@ def _kda_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
     leave the state as it was (beta 0, g 0). One token a row goes through
     the decode update (in place, one read and one write of each state),
     more through the chunked form. Returns (kv, y [S, C, M])."""
-    from ...models.solar_open2 import kda_inputs, kda_output
+    from ...models.solar_open2 import (kda_conv_inputs, kda_output,
+                                       kda_recurrence_inputs)
     from ...ops.kernels.delta_rule import kda_decode_update
-    state, conv, st, slots, fresh, live, prev0, prev = _state_rows(
-        kv, si, batch)
+    state, conv, st, slots, fresh, live = _state_rows(kv, si, batch)
     S, C, _ = h.shape
-    q, k, v, g, beta, padded = kda_inputs(p, h, model_cfg, prev, dtype)
+    pre, w = kda_conv_inputs(p, h, dtype)
+    conv, y = _short_conv(conv, si, batch, fresh, live, pre, w)
+    q, k, v, g, beta = kda_recurrence_inputs(p, h, y, model_cfg, dtype)
     g = jnp.where(valid_q[..., None, None], g, 0.0)
     beta = jnp.where(valid_q[..., None], beta, 0.0)
-    conv = _carry_conv(conv, si, slots, padded, batch.n_tokens, live, prev0)
     if C == 1:
         # exp(-inf) = 0 wipes what the slot held: a fresh row's zero state
         g1 = jnp.where((fresh & live)[:, None, None], -jnp.inf, g[:, 0])
@@ -250,14 +269,17 @@ def _mamba2_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
     take a zero step (``dt`` 0: decay 1, nothing added). One token a row
     goes through the decode update kernel in place (``ops/kernels/ssd``),
     more through the chunked SSD form. Returns (kv, y [S, C, M])."""
-    from ...models.nemotron_h import mamba2_inputs, mamba2_output
+    from ...models.nemotron_h import (mamba2_conv_inputs, mamba2_output,
+                                      mamba2_recurrence_inputs)
     from ...ops.kernels.ssd import mamba2_decode_update, mamba2_prefill
-    state, conv, st, slots, fresh, live, prev0, prev = _state_rows(
-        kv, si, batch)
+    state, conv, st, slots, fresh, live = _state_rows(kv, si, batch)
     S, C, _ = h.shape
-    z, x, Bm, Cm, dt, padded = mamba2_inputs(p, h, model_cfg, prev, dtype)
+    f32 = jnp.float32
+    z, xbc, dt = mamba2_conv_inputs(p, h, model_cfg, dtype)
+    conv, xbc = _short_conv(conv, si, batch, fresh, live, xbc,
+                            p["conv_w"].astype(f32), p["conv_b"].astype(f32))
+    x, Bm, Cm, dt = mamba2_recurrence_inputs(p, xbc, dt, model_cfg)
     dt = jnp.where(valid_q[..., None], dt, 0.0)
-    conv = _carry_conv(conv, si, slots, padded, batch.n_tokens, live, prev0)
     a = -jnp.exp(p["A_log"].astype(jnp.float32))
     D = p["D"].astype(jnp.float32)
     if C == 1:

@@ -52,7 +52,7 @@ import numpy as np
 from ._lm_utils import make_causal_lm
 from .llama import RMSNorm
 from .mixtral import MixtralConfig
-from .solar_open2 import GatedNoPEAttention, short_conv
+from .solar_open2 import GatedNoPEAttention, conv_silu
 
 #: the pattern's letters: (mixer kind, feed-forward kind) of a layer
 PATTERN = {"M": ("mamba2", None), "*": ("attn", None), "E": (None, "moe")}
@@ -190,32 +190,45 @@ def param_counts(cfg: NemotronHConfig) -> Tuple[int, int]:
             fixed + n_moe * cfg.experts_top_k * expert)
 
 
-def mamba2_inputs(p, h, cfg: NemotronHConfig, conv_prev, dtype):
-    """From the normed residual h [B, T, M] to the recurrence's inputs:
-    (z [B, T, d_in] the gate's pre-activation, x [B, T, H, P], B and C
-    [B, T, H, N] a HEAD (each group's repeated over its heads), dt
-    [B, T, H] after the softplus, all float32; the padded conv inputs
-    [B, K-1+T, d_in + 2 G N]). ``conv_prev`` [B, K-1, ..] holds the last
-    inputs of x | B | C.
+def mamba2_conv_inputs(p, h, cfg: NemotronHConfig, dtype):
+    """The one input projection of a Mamba-2 layer, split: (z [B, T, d_in]
+    the gate's pre-activation, xbc [B, T, d_in + 2 G N] what the short
+    convolution takes, dt [B, T, H] before its bias), all float32.
 
     The matmul takes ``dtype`` operands and gives float32: what feeds the
     recurrence is not rounded to ``dtype`` on the way, because a rounding
     of the step compounds over every later position of the sequence."""
-    Bsz, T, _ = h.shape
+    d_in = cfg.mamba_inner
+    zxbcdt = jnp.matmul(h, p["in_proj"].astype(dtype),
+                        preferred_element_type=jnp.float32)
+    return jnp.split(zxbcdt, [d_in, d_in + cfg.mamba_conv_width], -1)
+
+
+def mamba2_recurrence_inputs(p, xbc, dt, cfg: NemotronHConfig):
+    """From the activated convolution xbc [B, T, d_in + 2 G N] and the
+    step's pre-activation dt [B, T, H] to the recurrence's inputs:
+    (x [B, T, H, P], B and C [B, T, H, N] a HEAD (each group's repeated
+    over its heads), dt [B, T, H] after the softplus), all float32."""
+    Bsz, T, _ = xbc.shape
     H, P = cfg.mamba_heads, cfg.mamba_head_dim
     G, N, d_in = cfg.mamba_groups, cfg.mamba_state, cfg.mamba_inner
-    f32 = jnp.float32
-    zxbcdt = jnp.matmul(h, p["in_proj"].astype(dtype),
-                        preferred_element_type=f32)
-    z, xbc, dt = jnp.split(zxbcdt, [d_in, d_in + cfg.mamba_conv_width], -1)
-    y, padded = short_conv(xbc, p["conv_w"].astype(f32),
-                           conv_prev.astype(f32))
-    xbc = jax.nn.silu(y + p["conv_b"].astype(f32))
     x, Bm, Cm = jnp.split(xbc, [d_in, d_in + G * N], -1)
     heads = lambda t: jnp.repeat(                          # noqa: E731
         t.reshape(Bsz, T, G, N), H // G, axis=2)
-    dt = jax.nn.softplus(dt + p["dt_bias"].astype(f32))
-    return z, x.reshape(Bsz, T, H, P), heads(Bm), heads(Cm), dt, padded
+    dt = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
+    return x.reshape(Bsz, T, H, P), heads(Bm), heads(Cm), dt
+
+
+def mamba2_inputs(p, h, cfg: NemotronHConfig, conv_prev, dtype):
+    """From the normed residual h [B, T, M] to (z, the recurrence's inputs
+    (:func:`mamba2_recurrence_inputs`), the padded conv inputs
+    [B, K-1+T, d_in + 2 G N]). ``conv_prev`` [B, K-1, ..] holds the last
+    inputs of x | B | C."""
+    f32 = jnp.float32
+    z, xbc, dt = mamba2_conv_inputs(p, h, cfg, dtype)
+    xbc, padded = conv_silu(xbc, p["conv_w"].astype(f32), conv_prev,
+                            p["conv_b"].astype(f32))
+    return (z,) + mamba2_recurrence_inputs(p, xbc, dt, cfg) + (padded,)
 
 
 def mamba2_output(p, y, z, cfg: NemotronHConfig, dtype):

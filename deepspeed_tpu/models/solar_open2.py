@@ -136,26 +136,43 @@ def short_conv(x, w, prev):
     return y, xp
 
 
-def kda_inputs(p, h, cfg: SolarOpen2Config, conv_prev, dtype):
-    """From the normed residual h [B, T, M] to the recurrence's inputs:
-    (q, k [B, T, H, dk], v [B, T, H, dv], g [B, T, H, dk] float32,
-    beta [B, T, H] float32, the padded conv inputs [B, K-1+T, 3*H*dk]).
-    ``conv_prev`` [B, K-1, 3*H*dk] holds the last inputs of q | k | v.
+def conv_silu(pre, w, prev, bias=None):
+    """The activated short convolution of a recurrent layer: pre
+    [B, T, C] float32, w [K, C], prev [B, K-1, C] (:func:`short_conv`),
+    bias [C] or None. Returns (silu(conv + bias) [B, T, C], the padded
+    inputs)."""
+    y, padded = short_conv(pre, w, prev.astype(jnp.float32))
+    if bias is not None:
+        y = y + bias
+    return jax.nn.silu(y), padded
+
+
+def kda_conv_inputs(p, h, dtype):
+    """What the short convolution of a KDA layer takes: (pre
+    [B, T, 3*H*dk] float32 the projections of q | k | v, w [K, 3*H*dk]
+    float32 their taps).
 
     The matmuls take ``dtype`` operands and give float32: what feeds the
     recurrence (the convolution, the decay's pre-activation, the step
     size) is not rounded to ``dtype`` on the way, because a rounding of
     the decay compounds over every later position of the sequence."""
+    f32 = jnp.float32
+    pre = jnp.concatenate(
+        [jnp.matmul(h, p[n].astype(dtype), preferred_element_type=f32)
+         for n in ("q_proj", "k_proj", "v_proj")], -1)
+    w = jnp.concatenate([p["q_conv"], p["k_conv"], p["v_conv"]], -1)
+    return pre, w.astype(f32)
+
+
+def kda_recurrence_inputs(p, h, y, cfg: SolarOpen2Config, dtype):
+    """From the activated convolution y [B, T, 3*H*dk] float32 and the
+    normed residual h to the recurrence's inputs: (q, k [B, T, H, dk],
+    v [B, T, H, dv], g [B, T, H, dk] float32, beta [B, T, H] float32)."""
     B, T, _ = h.shape
     H, d = cfg.kda_heads, cfg.kda_head_dim
     f32 = jnp.float32
     mm = lambda x, w: jnp.matmul(x, w.astype(dtype),     # noqa: E731
                                  preferred_element_type=f32)
-    pre = jnp.concatenate([mm(h, p[n])
-                           for n in ("q_proj", "k_proj", "v_proj")], -1)
-    w = jnp.concatenate([p["q_conv"], p["k_conv"], p["v_conv"]], -1)
-    y, padded = short_conv(pre, w.astype(f32), conv_prev.astype(f32))
-    y = jax.nn.silu(y)
     q, k, v = (t.reshape(B, T, H, d) for t in jnp.split(y, 3, axis=-1))
     l2 = lambda t: t * jax.lax.rsqrt(                    # noqa: E731
         jnp.sum(t * t, -1, keepdims=True) + 1e-6)
@@ -168,7 +185,17 @@ def kda_inputs(p, h, cfg: SolarOpen2Config, conv_prev, dtype):
     beta = jax.nn.sigmoid(mm(h, p["b_proj"]))
     if cfg.kda_neg_eigval:
         beta = 2.0 * beta
-    return q, k, v, g, beta, padded
+    return q, k, v, g, beta
+
+
+def kda_inputs(p, h, cfg: SolarOpen2Config, conv_prev, dtype):
+    """From the normed residual h [B, T, M] to the recurrence's inputs
+    (:func:`kda_recurrence_inputs`) and the padded conv inputs
+    [B, K-1+T, 3*H*dk]. ``conv_prev`` [B, K-1, 3*H*dk] holds the last
+    inputs of q | k | v."""
+    pre, w = kda_conv_inputs(p, h, dtype)
+    y, padded = conv_silu(pre, w, conv_prev)
+    return kda_recurrence_inputs(p, h, y, cfg, dtype) + (padded,)
 
 
 def kda_output(p, o, h, cfg: SolarOpen2Config, dtype):

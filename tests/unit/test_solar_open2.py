@@ -110,6 +110,47 @@ def test_generate_serves_the_model(model):
     assert [int(t) for t in out] == np.argmax(want, -1).tolist()
 
 
+def test_decode_through_the_conv_kernel_serves_the_jnp_paths_tokens(
+        model, monkeypatch):
+    """The decode steps' short convolution through the in-place Pallas
+    call (forced and interpreted here; on the chip platform and shape
+    pick it) after a chunked prefill: 4 steps of the fused loop and 5
+    step by step give the jnp path's tokens and leave its pool, states
+    and carried inputs alike, and the engine counts the layer-steps.
+    (Alike to float32 rounding: inside a step program XLA's CPU backend
+    contracts the taps' multiply-adds where it fuses them and not in the
+    interpreted body; ``test_short_conv.py`` holds the call alone to the
+    jnp path bit for bit, and so did the chip, PERF.md PR 46.)"""
+    from deepspeed_tpu.ops.kernels import short_conv
+    cfg, params = model
+    prompts = {5: prompt_of(21, seed=4), 6: prompt_of(9, seed=5)}
+
+    def serve():
+        eng = engine(cfg, params, 16)
+        first = {u: int(np.argmax(np.asarray(lg)))
+                 for u, lg in eng.put(list(prompts),
+                                      list(prompts.values())).items()}
+        out = eng.decode_batch([5, 6], [first[5], first[6]], 4)
+        toks = {u: [first[u]] + [int(t) for t in out[u]] for u in prompts}
+        # one sequence alone: the other rows of its bucket are idle
+        toks[6] += [int(t) for t in
+                    eng.decode_pipelined([6], [toks[6][-1]], 5)[6]]
+        return toks, jax.device_get((eng._kv_data.state, eng._kv_data.conv)), \
+            eng.pipeline_stats["conv_steps_in_place"]
+
+    want_toks, want_pool, counted = serve()
+    assert counted == 0                  # the CPU path: gather and scatter
+    monkeypatch.setattr(short_conv, "decode_uses_kernel",
+                        lambda *a, **k: True)
+    toks, pool, counted = serve()
+    assert toks == want_toks
+    for got, want in zip(jax.tree_util.tree_leaves(pool),
+                         jax.tree_util.tree_leaves(want_pool)):
+        assert np.allclose(got, want, rtol=1e-4, atol=1e-5)
+    layers = sum(k in ("kda", "mamba2") for k in cfg.layer_kinds)
+    assert layers and counted == (4 + 5) * layers
+
+
 # ------------------- (b) chunked against token by token ------------------- #
 
 

@@ -77,6 +77,17 @@ def _scoped_vmem(hlo, name):
             for line in calls]
 
 
+def _conv_pool_moves(hlo, pool_rows):
+    """XLA's gathers and scatters (and copies) of the pool of carried
+    convolution inputs, whose slots are ``pool_rows`` rows of 128 lanes, in
+    the compiled text."""
+    import re
+    shaped = r"bf16\[\d+,\d+,%d,128\]" % pool_rows
+    return [line.strip()[:120] for line in hlo.splitlines()
+            if re.search(shaped, line)
+            and re.search(r" (gather|scatter|copy)\(", line)]
+
+
 def test_the_flash_kernels_keep_the_name_their_roofline_reader_matches(
         one_chip, monkeypatch):
     """``flash_attn_roofline.train`` matches ``attn-bf16_<B>_<H>_<T>_<D>``:
@@ -280,6 +291,7 @@ def test_solar2_decode_loop_keeps_one_copy_of_the_state(one_chip,
     from benchmark.model_types import solar_open2 as mt
     from deepspeed_tpu.inference.v2.kv_quant import KVPool
     from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.ops.kernels import short_conv
     monkeypatch.setattr(kernels, "default_interpret", lambda: False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     root = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -303,7 +315,9 @@ def test_solar2_decode_loop_keeps_one_copy_of_the_state(one_chip,
     planes = spec((1, 2, 261 * block, 8 * 128), jnp.bfloat16)
     state = tuple(spec((slots + 1, 64, 128, 128), jnp.float32)
                   for _ in range(3))
-    conv = spec((3, slots + 1, 3, 3 * 64 * 128), jnp.bfloat16)
+    # a slot's [3, 24576] carried inputs as 576 rows of 128 lanes
+    conv = spec((3, slots + 1, 576, 128), jnp.bfloat16)
+    assert conv.shape == short_conv.pool_shape(3, slots + 1, 4, 3 * 64 * 128)
     i32 = functools.partial(spec, dtype=jnp.int32)
     f32 = functools.partial(spec, dtype=jnp.float32)
     exe = runner._decode_loop_ring.trace(
@@ -320,8 +334,17 @@ def test_solar2_decode_loop_keeps_one_copy_of_the_state(one_chip,
     shaped = r"f32\[%d,64,128,128\]" % (slots + 1)
     made = re.findall(r"= %s\S* ([\w\-]+)\(" % shaped, hlo)
     assert set(made) <= {"parameter", "get-tuple-element"}, set(made)
-    # the decode kernel of the softmax layer + one state update a KDA layer
-    assert hlo.count("tpu_custom_call") >= 4
+    # the decode kernel of the softmax layer; a KDA layer's short
+    # convolution in place on the pool of carried inputs, which no gather
+    # or scatter of XLA's touches any more, and its state update
+    from collections import Counter
+    assert Counter(_mosaic_call_names(hlo)) == {
+        "closed_call": 1, "short_conv_decode_step": 3,
+        "kda_decode_state_update": 3, "grouped_ffn_decode": 4}
+    assert len(re.findall(
+        r"%short_conv_decode_step[\w\-.]* = \(bf16\[3,129,576,128\]",
+        hlo)) == 3
+    assert not _conv_pool_moves(hlo, 576)
     # and the grouped expert kernel once a layer, over 101 row tiles of
     # 16 where ragged-dot was handed all 1,024 routed rows three times
     from deepspeed_tpu.ops.kernels.grouped_ffn import ROW_TILE, visits_bound
@@ -331,6 +354,103 @@ def test_solar2_decode_loop_keeps_one_copy_of_the_state(one_chip,
     assert len(re.findall(
         r"%%grouped_ffn_decode[\w\-.]* = bf16\[%d,4096\]" % padded,
         hlo)) >= 4
+
+
+@pytest.mark.parametrize("S, W, dtype, bias", [
+    (16, 24576, jnp.bfloat16, False),     # a per-step bucket: ONE grid step
+    (24, 6144, jnp.bfloat16, True),       # rows no multiple of 16: 8 a step
+    (512, 12288, jnp.bfloat16, False),    # the largest slot bucket
+    (16, 1024, jnp.float32, False),       # a float32 pool: taps of 8 rows
+], ids=["one-step", "eight-rows", "bucket-512", "float32-pool"])
+def test_short_conv_decode_step_compiles_off_the_cells_shapes(
+        one_chip, S, W, dtype, bias):
+    """The in-place short convolution alone, at the shapes a per-step
+    decode (``decode_pipelined``: slot buckets of 16 to 512 rows) hands it
+    and the cells' fused loops do not: every shape ``decode_uses_kernel``
+    admits has to compile for the v5e, with the pool aliased."""
+    from deepspeed_tpu.ops.kernels import short_conv
+    assert short_conv.decode_uses_kernel(S, W, dtype, backend="tpu")
+
+    def spec(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = spec(short_conv.pool_shape(2, S + 1, 4, W), dtype)
+    exe = jax.jit(short_conv.short_conv_decode_step, donate_argnums=0).trace(
+        pool, spec(()), spec((S,)), spec((S, W), jnp.float32),
+        spec((4, W), jnp.float32),
+        spec((W,), jnp.float32) if bias else None, spec((S,), jnp.bool_),
+        spec((S,), jnp.bool_)).lower(lowering_platforms=("tpu",)).compile()
+    assert _mosaic_call_names(exe.as_text()) == ["short_conv_decode_step"]
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * (S + 1) * 3 * W \
+        * jnp.dtype(dtype).itemsize
+    assert mem.temp_size_in_bytes < S * W * 4
+
+
+def test_kimi_decode_loop_runs_the_short_conv_in_place(one_chip,
+                                                        monkeypatch):
+    """The fused 128-step decode loop of ``serve-kimi-linear-rollout-long``
+    at the published widths and the cell's pool, from shapes alone: six
+    KDA layers, each its short convolution and its state update in place
+    (the names and shapes the ``.kimi`` readers match unchanged beside
+    the new call), two latent layers in the latent decode kernel, and no
+    gather, scatter or copy of XLA's on the pool of carried inputs."""
+    import json
+    import os
+    import re
+    from collections import Counter
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from benchmark.model_types import kimi_linear as mt
+    from deepspeed_tpu.inference.v2.kv_quant import KVPool
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        mcfg = mt.model_config(json.load(f))
+    with open(os.path.join(root, "benchmark", "cells",
+                           "serve-kimi-linear-rollout-long.json")) as f:
+        eng = json.load(f)["engine"]
+    runner = LlamaRaggedRunner(mcfg, RaggedInferenceConfig(**eng))
+    slots, block, blocks, maxb = (eng["max_seqs"], eng["block_size"],
+                                  eng["num_blocks"],
+                                  eng["max_blocks_per_seq"])
+    assert runner.state_spec == {
+        "kind": "kda", "layers": 6, "heads": 32, "d_v": 128, "d_k": 128,
+        "taps": 4, "conv_width": 12288}
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: mt.init_params(mcfg, 0)))
+    state = tuple(spec((slots + 1, 32, 128, 128), jnp.float32)
+                  for _ in range(6))
+    conv = spec((6, slots + 1, 288, 128), jnp.bfloat16)
+    planes = spec((runner.kv_layers, runner.kv_planes, (blocks + 1) * block,
+                   runner.kv_heads * runner.head_dim), jnp.bfloat16)
+    f32 = functools.partial(spec, dtype=jnp.float32)
+    hlo = runner._decode_loop_ring.trace(
+        params, KVPool(planes, None, None, None), (state, conv),
+        spec((slots,)), spec((slots,)), spec((slots,)), spec((slots,)),
+        spec((slots, maxb)), spec((1,)), f32((1,)), spec((1,)), f32((1,)),
+        spec((1, 1)), n=eng["decode_loop_steps"], mode="greedy", cand=1,
+        eos_id=-1, feed="self").lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert Counter(_mosaic_call_names(hlo)) == {
+        "short_conv_decode_step": 6, "kda_decode_state_update": 6,
+        "grouped_ffn_decode": 7, "mla_decode_attention": 2}
+    assert len(re.findall(
+        r"%%short_conv_decode_step[\w\-.]* = \(bf16\[6,%d,288,128\]"
+        % (slots + 1), hlo)) == 6
+    assert len(re.findall(
+        r"%%kda_decode_state_update[\w\-.]* = \(f32\[%d,32,128,128\]"
+        % (slots + 1), hlo)) == 6
+    assert not _conv_pool_moves(hlo, 288)
 
 
 def test_pangu_decode_loop_and_flush_compile_over_the_latent_plane(
@@ -459,7 +579,7 @@ def test_refill_step_runs_the_chunk_kernel_of_the_delta_rule(
         jax.eval_shape(lambda: mt.init_params(mcfg, 0)))
     state = tuple(spec((slots + 1, heads, 128, 128), jnp.float32)
                   for _ in range(layers))
-    conv = spec((layers, slots + 1, 3, 3 * heads * 128), jnp.bfloat16)
+    conv = spec((layers, slots + 1, 9 * heads, 128), jnp.bfloat16)
     lowered = runner._step_greedy.trace(
         params, KVPool(spec(planes + ((blocks + 1) * block, row),
                             jnp.bfloat16), None, state, conv),
@@ -475,7 +595,9 @@ def test_refill_step_runs_the_chunk_kernel_of_the_delta_rule(
     hlo = lowered.compile().as_text()
     names = _mosaic_call_names(hlo)
     assert names.count("kda_chunk_prefill") == layers, names
-    assert not any(re.match(r"^kda_decode_state_update", n) for n in names)
+    # a prefill chunk keeps the decode step's two kernels off its path
+    assert not any(re.match(r"^(kda_decode_state_update|short_conv)", n)
+                   for n in names)
     assert "riangular" not in hlo
     from deepspeed_tpu.ops.kernels import grouped_ffn
     padded, width, inner = experts
@@ -619,7 +741,7 @@ def test_nemotron_loop_and_refill_compile_at_256_clients(one_chip,
     assert params["layer_1"]["moe"]["wi"].shape == (64, 2688, 1920)
     state = tuple(spec((slots + 1, 64, 64, 128), jnp.float32)
                   for _ in range(6))
-    conv = spec((6, slots + 1, 3, 6144), jnp.bfloat16)
+    conv = spec((6, slots + 1, 144, 128), jnp.bfloat16)
     planes = spec((2, 2, (blocks + 1) * block, 256), jnp.bfloat16)
     f32 = functools.partial(spec, dtype=jnp.float32)
     exe = runner._decode_loop_ring.trace(
@@ -630,9 +752,15 @@ def test_nemotron_loop_and_refill_compile_at_256_clients(one_chip,
         feed="self").lower(lowering_platforms=("tpu",)).compile()
     hlo = exe.as_text()
     assert Counter(_mosaic_call_names(hlo)) == {
-        "mamba2_decode_state_update": 6, "grouped_ffn_decode": 5,
-        "closed_call": 2}
+        "short_conv_decode_step": 6, "mamba2_decode_state_update": 6,
+        "grouped_ffn_decode": 5, "closed_call": 2}
     assert "ragged-dot" not in hlo
+    # the short convolution in place: XLA neither gathers nor scatters
+    # (nor copies) the pool of carried inputs
+    assert len(re.findall(
+        r"%short_conv_decode_step[\w\-.]* = \(bf16\[6,257,144,128\]",
+        hlo)) == 6
+    assert not _conv_pool_moves(hlo, 144)
     # the names and shapes the .nemotron readers match
     assert len(re.findall(
         r"%mamba2_decode_state_update[\w\-.]* = \(f32\[257,64,64,128\]",
@@ -678,4 +806,5 @@ def test_nemotron_loop_and_refill_compile_at_256_clients(one_chip,
                         lowering_platforms=("tpu",)).compile().as_text()
     names = Counter(_mosaic_call_names(hlo))
     assert names["grouped_ffn_decode"] == 5 and "ragged-dot" not in hlo
-    assert not any(n.startswith("mamba2") for n in names), names
+    assert not any(n.startswith(("mamba2", "short_conv")) for n in names), \
+        names
