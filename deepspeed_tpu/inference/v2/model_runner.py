@@ -473,6 +473,20 @@ def _seq_dense_ring_attention(pool, ring, li, q, batch, cfg, settled_lens,
     return jnp.moveaxis(y, 3, 1).reshape(S, C, H * D).astype(dtype)
 
 
+def _attention_impl(cfg: RaggedInferenceConfig) -> str:
+    """``cfg.attention_impl`` resolved: "auto" is "paged_flash" on a TPU
+    and "dense" elsewhere (interpret-mode Pallas off-TPU would run a
+    Python-loop interpreter per layer/step)."""
+    impl = cfg.attention_impl
+    if impl == "auto":
+        impl = "paged_flash" if jax.default_backend() == "tpu" else "dense"
+    if impl not in ("paged_flash", "dense"):
+        raise ValueError(
+            f"attention_impl must be 'auto', 'paged_flash' or 'dense', "
+            f"got {cfg.attention_impl!r}")
+    return impl
+
+
 def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
                     cfg: RaggedInferenceConfig, pos, valid_q, scale, dtype,
                     alibi_slopes=None, sliding_window=None):
@@ -481,8 +495,7 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
     Shared by every ragged runner. q: [S, C, H, D]; k/v: [S, C, KV, D]
     (KV may divide H — GQA). Dispatches on ``cfg.attention_impl``:
 
-      "auto" — "paged_flash" on TPU, "dense" elsewhere (interpret-mode
-        Pallas off-TPU would run a Python-loop interpreter per layer/step).
+      "auto" — "paged_flash" on TPU, "dense" elsewhere.
       "paged_flash" — Pallas flash kernel reading K/V straight through the
         block tables (ops/kernels/paged_attention.py): per-step HBM traffic
         is the LIVE blocks only, no ``max_context`` wall. (Reference:
@@ -502,9 +515,7 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
     S, C, H, D = q.shape
     KV = k.shape[2]
     bs = cfg.block_size
-    impl = cfg.attention_impl
-    if impl == "auto":
-        impl = "paged_flash" if jax.default_backend() == "tpu" else "dense"
+    impl = _attention_impl(cfg)
     seq_on = seq_axis_active()
     if seq_on:
         # the Pallas kernel indexes a single-chip pool layout; under the
@@ -531,38 +542,26 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
                                  batch.start_pos - t, 0)
         if impl == "paged_flash":
             from ...ops.kernels import flash_paged_attention
+            # the WHOLE pool and ring ride through: both kernels select
+            # (layer, k/v) themselves. As operands, data[li, x] slices
+            # made XLA copy every layer's K and V plane out of the pool
+            # each step (the device trace measured them at ~45% of the
+            # decode step), and ring[:, li, x].swapaxes added 44 strided
+            # 17 MB transposes
             y = flash_paged_attention(
                 q.astype(data.dtype if scales is None else dtype),
-                data[li, 0], data[li, 1],
-                batch.block_tables, batch.start_pos, settled_lens,
-                block_size=bs, sm_scale=scale, alibi_slopes=alibi_slopes,
-                sliding_window=sliding_window, num_kv_heads=KV,
-                # the WHOLE ring and pool ride through: both kernels select
-                # (layer, k/v) themselves, so data[li, x] above only gives
-                # shape and dtype and is dead under jit. As operands those
-                # slices made XLA copy every layer's K and V plane out of
-                # the pool each step (the device trace measured them at
-                # ~45% of the decode step), and ring[:, li, x].swapaxes
-                # added 44 strided 17 MB transposes. On the paged layout
-                # (several blocks a sequence) the kernel still builds its
-                # per-layer ring planes from ring_full: small, R x S rows
-                ring_full=ring, ring_layer=li,
-                pool_full=data, pool_layer=li,
-                scales_full=scales,
-                ring_count=rcount)
-        elif impl == "dense":
-            if seq_on:
-                y = _seq_dense_ring_attention(
-                    pool, ring, li, q, batch, cfg, settled_lens, rcount,
-                    scale, dtype, alibi_slopes, sliding_window)
-            else:
-                y = _dense_ring_attention(
-                    pool, ring, li, q, batch, cfg, settled_lens, rcount,
-                    scale, dtype, alibi_slopes, sliding_window)
+                data, li, batch.block_tables, batch.start_pos, settled_lens,
+                block_size=bs, num_kv_heads=KV, sm_scale=scale,
+                alibi_slopes=alibi_slopes, sliding_window=sliding_window,
+                scales=scales, ring=ring, ring_count=rcount)
+        elif seq_on:
+            y = _seq_dense_ring_attention(
+                pool, ring, li, q, batch, cfg, settled_lens, rcount,
+                scale, dtype, alibi_slopes, sliding_window)
         else:
-            raise ValueError(
-                f"attention_impl must be 'auto', 'paged_flash' or 'dense', "
-                f"got {cfg.attention_impl!r}")
+            y = _dense_ring_attention(
+                pool, ring, li, q, batch, cfg, settled_lens, rcount,
+                scale, dtype, alibi_slopes, sliding_window)
         return kv, y.reshape(S, C, H * D).astype(dtype)
 
     if seq_on:
@@ -583,25 +582,18 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
                              batch.start_pos + batch.n_tokens, 0)
         # q joins the pool's storage dtype so the kernel's matmuls stay
         # single-dtype (f32 accumulation inside); the pool itself is NEVER
-        # cast or copied — that would re-introduce the full-pool traffic
-        # this kernel exists to avoid. The kernel's K and V operand is
-        # pool_full, decode and prefill alike: data[li, x] gives shape and
-        # dtype only (dead under jit), so no plane is sliced out of the
-        # pool that the store above just updated in place.
+        # cast, copied or sliced — that would re-introduce the full-pool
+        # traffic this kernel exists to avoid, on the pool the store above
+        # just updated in place.
         # Over an int8 pool q stays in the compute dtype; the kernel
         # scales scores/probabilities by the side-array scales.
         y = flash_paged_attention(
             q.astype(data.dtype if scales is None else dtype),
-            data[li, 0], data[li, 1],
-            batch.block_tables, batch.start_pos, seq_lens,
-            block_size=bs, sm_scale=scale, alibi_slopes=alibi_slopes,
-            sliding_window=sliding_window, num_kv_heads=KV,
-            pool_full=data, pool_layer=li, scales_full=scales)
+            data, li, batch.block_tables, batch.start_pos, seq_lens,
+            block_size=bs, num_kv_heads=KV, sm_scale=scale,
+            alibi_slopes=alibi_slopes, sliding_window=sliding_window,
+            scales=scales)
         return kv, y.reshape(S, C, H * D).astype(dtype)
-    if impl != "dense":
-        raise ValueError(
-            f"attention_impl must be 'auto', 'paged_flash' or 'dense', "
-            f"got {cfg.attention_impl!r}")
 
     k_ctx, v_ctx = _gather_ctx(kv, li, batch, cfg, S, KV, D, dtype)
     j = jnp.arange(cfg.max_context, dtype=jnp.int32)
@@ -638,13 +630,7 @@ def latent_attention(kv, li, q, row, batch: "RaggedBatch",
                                               mla_decode_attention)
     S, C, H, W = q.shape
     bs = cfg.block_size
-    impl = cfg.attention_impl
-    if impl == "auto":
-        impl = "paged_flash" if jax.default_backend() == "tpu" else "dense"
-    if impl not in ("paged_flash", "dense"):
-        raise ValueError(
-            f"attention_impl must be 'auto', 'paged_flash' or 'dense', "
-            f"got {cfg.attention_impl!r}")
+    impl = _attention_impl(cfg)
     ring_mode = isinstance(kv, RingKV)
     if ring_mode:
         ring, t, rcount = kv.ring, kv.t, kv.rcount
@@ -675,9 +661,9 @@ def latent_attention(kv, li, q, row, batch: "RaggedBatch",
     if impl == "paged_flash":
         from ...ops.kernels import flash_paged_attention
         y = flash_paged_attention(
-            q.astype(data.dtype), data[li, 0], data[li, 0],
-            batch.block_tables, batch.start_pos, lens, block_size=bs,
-            sm_scale=scale, num_kv_heads=1, pool_full=data, pool_layer=li)
+            q.astype(data.dtype), data, li, batch.block_tables,
+            batch.start_pos, lens, block_size=bs, num_kv_heads=1,
+            sm_scale=scale)
         return kv, y[..., :latent].astype(dtype)
 
     T = cfg.max_context

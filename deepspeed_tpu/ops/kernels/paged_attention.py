@@ -20,12 +20,14 @@ call's shapes alone:
   and dead grid steps (past a sequence's live block count) repeat the
   previous block index — a revisited block costs no DMA.
 
-Layout contract (matches BlockedKVCache): the flat KV pool is
-``[slots, KV_heads * D]`` with ``slots = (num_blocks + 1) * block_size`` —
-one LANE-ALIGNED row per token. The earlier ``[slots, KV, D]`` layout let
-XLA pad the trailing ``(4, 64)`` dims to the (8, 128) tile — 4x the HBM
-footprint AND 4x the DMA traffic on the serving hot path. A 3-D pool is
-still accepted and viewed flat (same bytes, contiguous reshape).
+Layout contract: the pool is the cache's data array as ``BlockedKVCache``
+stores it, ``[L, planes, slots, KV_heads * D]`` with ``slots = (num_blocks
++ 1) * block_size`` — one LANE-ALIGNED row per token. A ``[slots, KV, D]``
+plane would let XLA pad the trailing ``(4, 64)`` dims to the (8, 128) tile
+— 4x the HBM footprint AND 4x the DMA traffic on the serving hot path.
+The pool is the operand WHOLE, with a layer index: a Pallas operand is a
+whole buffer, so a ``pool[layer, x]`` slice would make XLA copy that plane
+out of the pool before every call.
 
 GQA is handled by LANE WINDOWING instead of a per-kv-head matmul unroll:
 the caller expands q so the row for head h carries its values in lane
@@ -276,7 +278,7 @@ def _decode_plan(S: int, ctx_rows: int, ts: int, row_bytes: int):
 def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
                    slopes_ref, q_ref, kp_hbm, vp_hbm, *rest, G, CR, NCH, ts,
                    bs, maxb, H, Hp, KV, D, sm_scale, use_alibi, window, R,
-                   use_pool_full, quant):
+                   quant):
     """Pure-decode attention over paged KV: grid step (i, c) serves the G
     sequences of group i over context rows [c*CR, (c+1)*CR).
 
@@ -320,15 +322,13 @@ def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
     TPC = CR // ts                     # tiles a chunk
     M = G * Hp
 
-    if use_pool_full:
-        # the WHOLE [L, 2, slots, KVD] pool is the operand and the layer
-        # lands here, in the DMA source, from scalar prefetch: no plane is
-        # sliced out of the pool, and all layers share one Mosaic binary
-        def kv_src(x, off):
-            return kp_hbm.at[layer_ref[0], x, pl.ds(off, ts)]
-    else:
-        def kv_src(x, off):
-            return (kp_hbm, vp_hbm)[x].at[pl.ds(off, ts)]
+    # the WHOLE [L, 2, slots, KVD] pool is the operand (listed twice, as the
+    # K and as the V source: both are the one buffer, read through the
+    # first) and the layer lands here, in the DMA source, from scalar
+    # prefetch: no plane is sliced out of the pool, and all layers share
+    # one Mosaic binary
+    def kv_src(x, off):
+        return kp_hbm.at[layer_ref[0], x, pl.ds(off, ts)]
 
     def copies(gi, ci, sl, wait):
         """Start (or wait for) the live tiles of group gi, chunk ci."""
@@ -558,7 +558,7 @@ def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
 
 def closed_call(qw, kp, vp, ring, scales, tables, start_pos, seq_lens,
                 ring_count, layers, slopes, *, bs, H, KV, D, sm_scale,
-                use_alibi, window, use_pool_full, out_dtype, interpret):
+                use_alibi, window, out_dtype, interpret):
     """The decode kernel's one Mosaic call (``_decode_call`` below is its
     jitted form, and says why it carries this name)."""
     S, Hp, KVD = qw.shape
@@ -572,7 +572,7 @@ def closed_call(qw, kp, vp, ring, scales, tables, start_pos, seq_lens,
     kernel = functools.partial(
         _decode_kernel, G=G, CR=CR, NCH=NCH, ts=ts, bs=bs, maxb=maxb, H=H,
         Hp=Hp, KV=KV, D=D, sm_scale=sm_scale, use_alibi=use_alibi,
-        window=window, R=R, use_pool_full=use_pool_full, quant=quant)
+        window=window, R=R, quant=quant)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((G, Hp, KVD), lambda i, c, *_: (i, 0, 0)),
@@ -627,71 +627,55 @@ def closed_call(qw, kp, vp, ring, scales, tables, start_pos, seq_lens,
 # loop's scan body gave this kernel the name ``closed_call`` and the
 # benchmark's roofline readers find it by that name, so the name stays.
 _decode_call = jax.jit(closed_call, static_argnames=(
-    "bs", "H", "KV", "D", "sm_scale", "use_alibi", "window",
-    "use_pool_full", "out_dtype", "interpret"))
+    "bs", "H", "KV", "D", "sm_scale", "use_alibi", "window", "out_dtype",
+    "interpret"))
 
 
-def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
-                          v_pool: jnp.ndarray, block_tables: jnp.ndarray,
-                          start_pos: jnp.ndarray, seq_lens: jnp.ndarray,
-                          *, block_size: int,
+def flash_paged_attention(q: jnp.ndarray, pool: jnp.ndarray, layer: int,
+                          block_tables: jnp.ndarray, start_pos: jnp.ndarray,
+                          seq_lens: jnp.ndarray, *, block_size: int,
+                          num_kv_heads: int,
                           sm_scale: Optional[float] = None,
                           alibi_slopes: Optional[jnp.ndarray] = None,
                           sliding_window: Optional[int] = None,
-                          ring_k: Optional[jnp.ndarray] = None,
-                          ring_v: Optional[jnp.ndarray] = None,
+                          scales: Optional[jnp.ndarray] = None,
+                          ring: Optional[jnp.ndarray] = None,
                           ring_count: Optional[jnp.ndarray] = None,
-                          ring_full: Optional[jnp.ndarray] = None,
-                          ring_layer: int = 0,
-                          pool_full: Optional[jnp.ndarray] = None,
-                          pool_layer: Optional[int] = None,
-                          scales_full: Optional[jnp.ndarray] = None,
-                          k_scales: Optional[jnp.ndarray] = None,
-                          v_scales: Optional[jnp.ndarray] = None,
-                          num_kv_heads: Optional[int] = None,
                           interpret: Optional[bool] = None) -> jnp.ndarray:
     """Flash attention over paged KV.
+
+    The cache rides in as it is stored: the whole pool, its scales and
+    the fused loop's ring, with ONE (static) ``layer`` that indexes all
+    three. Both kernels pick (layer, K or V) themselves, the decode kernel
+    inside its DMA source and the BlockSpec kernel in its index map, so
+    no plane of the pool is ever sliced out in HBM.
 
     Args:
       q: [S, C, H, D] — C query tokens per slot (1 for pure decode;
         SplitFuse prefill chunks are larger). The step's K/V must ALREADY
         be in the pool (causal masking handles the chunk interior), except
-        in ring mode where the loop's tokens live in ring_k/ring_v.
-      k_pool/v_pool: [slots, KV*D] flat token rows (or [slots, KV, D],
-        viewed flat) with slots = (num_blocks + 1) * block_size (trailing
-        trash block).
+        in ring mode where the loop's tokens live in ``ring``.
+      pool: [L, planes, slots, KV*D] flat token rows with slots =
+        (num_blocks + 1) * block_size (trailing trash block). ``planes``
+        is 2 (K, V) or 1: a latent cache, whose row is key and value at
+        once (both operands then read plane 0).
+      layer: which of the pool's (and the scales' and the ring's) L layers
+        this call attends over.
       block_tables: [S, MAXB] int32 — pool block id per sequence block.
       start_pos: [S] int32 — absolute position of q[s, 0].
       seq_lens: [S] int32 — settled context length (0 marks an idle slot,
         which emits zeros). In ring mode this EXCLUDES the ring tokens.
-      ring_k/ring_v: optional [S, R, KV*D] decode-loop ring buffers;
-        ring_count: tokens valid in the ring.
-      ring_full/ring_layer: the PREFERRED ring form — the full
-        [R, L, 2, S, KV*D] decode-loop carry plus this call's (static)
-        layer index; the decode kernel selects the layer/kv planes in
-        its BlockSpec, so no per-layer slice/transpose materializes.
-        Must share the pool's dtype (never cast).
-      pool_full/pool_layer: the PREFERRED pool form, decode and prefill
-        — the un-sliced [L, 2, slots, KV*D] pool plus the (static) layer
-        index. Both kernels then take the WHOLE pool as their operand and
-        pick (layer, k/v) themselves: the decode kernel inside its DMA
-        source, the BlockSpec path in its index map. A Pallas operand is
-        a whole buffer, so a model-level pool[layer, 0/1] slice makes XLA
-        copy that plane out of the pool before every call (2 L planes a
-        step = the pool read and written once). When both full forms are
-        given the two layer indices must match. k_pool/v_pool remain
-        required but then give only shape and dtype (dead code under
-        jit); alone, they are the operands — the form for a caller that
-        holds one layer's planes.
       alibi_slopes: optional [H] f32 — in-kernel ALiBi bias (falcon/bloom).
-      scales_full / k_scales+v_scales: int8-pool dequantization scales
-        (kv_quant.py layout): ``scales_full`` [L, 2, KV, slots] rides whole
-        with the layer picked in-kernel; ``k_scales``/``v_scales``
-        [KV, slots] are the per-layer form for direct callers. Scales are
-        per (token-row, kv-head); the kernel multiplies SCORE columns by
-        the K scale and probability columns by the V scale — exact, and no
+      scales: [L, 2, KV, slots] float32 dequantization scales of an int8
+        pool (kv_quant.py layout), required exactly then. Scales are per
+        (token-row, kv-head); the kernel multiplies SCORE columns by the K
+        scale and probability columns by the V scale — exact, and no
         dequantized K/V tile ever materializes. q then stays in its own
-        (compute) dtype, and the decode-ring stays unquantized.
+        (compute) dtype.
+      ring, ring_count: the fused decode loop's carry [R, L, 2, S, KV*D]
+        and the tokens valid in it (pure decode only). It shares the
+        pool's dtype, or q's over an int8 pool (the ring is never
+        quantized), and is never cast.
 
     Returns [S, C, H, D] attention outputs in q.dtype. HBM traffic per
     step is O(sum of live blocks) of UNPADDED rows.
@@ -700,96 +684,67 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         from . import default_interpret
         interpret = default_interpret()
     S, C, H, D = q.shape
-    if k_pool.ndim == 3:
-        KV = k_pool.shape[1]
-        k_pool = k_pool.reshape(k_pool.shape[0], -1)
-        v_pool = v_pool.reshape(v_pool.shape[0], -1)
-    else:
-        if num_kv_heads is None:
-            raise ValueError("num_kv_heads required with a flat 2-D pool")
-        KV = num_kv_heads
-    slots, KVD = k_pool.shape
+    KV = num_kv_heads
+    bs = block_size
+    if pool.ndim != 4 or pool.shape[1] not in (1, 2):
+        raise ValueError(
+            f"pool must be [L, 1 or 2, slots, KV*D], got "
+            f"{pool.dtype}{list(pool.shape)}")
+    L, planes, slots, KVD = pool.shape
     if KVD != KV * D:
         raise ValueError(f"pool rows {KVD} != KV*D = {KV * D}")
-    bs = block_size
     if H % KV:
         raise ValueError(f"GQA requires H % KV == 0 ({H}/{KV})")
     if slots % bs:
         raise ValueError(
             f"pool slots ({slots}) must be a multiple of block_size ({bs}); "
             f"allocate (num_blocks+1)*block_size with a trailing trash block")
+    layer = int(layer)
+    if not 0 <= layer < L:
+        raise ValueError(f"layer {layer} out of range for L = {L}")
     maxb = block_tables.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
     g = H // KV
-    use_pool_full = pool_full is not None and pool_layer is not None
-    if use_pool_full:
-        # one plane a layer: a latent cache, whose row is key and value
-        # at once (both operands then read plane 0)
-        if pool_full.ndim != 4 or pool_full.shape[1] not in (1, 2) \
-                or pool_full.shape[2:] != (slots, KVD) \
-                or pool_full.dtype != k_pool.dtype:
-            raise ValueError(
-                f"pool_full must be {k_pool.dtype}[L, 2, {slots}, {KVD}], "
-                f"got {pool_full.dtype}{list(pool_full.shape)}")
-        if not 0 <= int(pool_layer) < pool_full.shape[0]:
-            raise ValueError(
-                f"pool_layer {pool_layer} out of range for L = "
-                f"{pool_full.shape[0]}")
 
-    # int8 pool: scales required; normalize to the per-layer [KV, slots]
-    # form for the BlockSpec (prefill) path — the decode kernel prefers
-    # scales_full (layer picked inside the DMA source)
-    quant = k_pool.dtype == jnp.int8
+    quant = pool.dtype == jnp.int8
     if quant:
-        if scales_full is not None:
-            if scales_full.ndim != 4 or scales_full.shape[1] != 2 \
-                    or scales_full.shape[2] != KV \
-                    or scales_full.shape[3] != slots:
-                raise ValueError(
-                    f"scales_full must be [L, 2, {KV}, {slots}], got "
-                    f"{scales_full.shape}")
-            li = int(pool_layer) if pool_layer is not None else 0
-            if k_scales is None:
-                k_scales = scales_full[li, 0]
-                v_scales = scales_full[li, 1]
-        if k_scales is None or v_scales is None:
+        if scales is None:
             raise ValueError(
-                "an int8 k_pool needs scales (scales_full or "
-                "k_scales+v_scales, see kv_quant.py)")
-        if k_scales.shape != (KV, slots):
+                "an int8 pool needs its scales (see kv_quant.py)")
+        if scales.shape != (L, 2, KV, slots):
             raise ValueError(
-                f"k_scales must be [{KV}, {slots}], got {k_scales.shape}")
+                f"scales must be [{L}, 2, {KV}, {slots}], got "
+                f"{list(scales.shape)}")
+        scales = scales.astype(jnp.float32)
         compute_dt = q.dtype if q.dtype != jnp.int8 else jnp.bfloat16
-    elif scales_full is not None or k_scales is not None:
+    elif scales is not None:
         raise ValueError("KV scales passed but the pool is not int8")
     else:
-        compute_dt = k_pool.dtype
+        compute_dt = pool.dtype
 
     use_alibi = alibi_slopes is not None
     slopes = (jnp.asarray(alibi_slopes, jnp.float32) if use_alibi
               else jnp.zeros((H,), jnp.float32))
     window = int(sliding_window) if sliding_window is not None else None
 
-    # ring_full [R, L, 2, S, KVD] + ring_layer is the fused loop's form;
-    # ring_k/ring_v [S, R, KVD] is a direct caller's
-    has_ring = ring_k is not None or ring_full is not None
-    if has_ring and C != 1:
-        raise ValueError("ring decode requires C == 1 (pure decode steps)")
-    if ring_k is not None and ring_k.shape[2] != KVD:
-        raise ValueError(f"ring rows must be flat [S, R, {KVD}]")
-    if ring_full is not None:
-        if ring_full.ndim != 5 or ring_full.shape[2] != 2 \
-                or ring_full.shape[4] != KVD:
+    has_ring = ring is not None
+    if has_ring:
+        if C != 1:
             raise ValueError(
-                f"ring_full must be [R, L, 2, S, {KVD}], got "
-                f"{ring_full.shape}")
-        if not 0 <= int(ring_layer) < ring_full.shape[1]:
+                "ring decode requires C == 1 (pure decode steps)")
+        if ring.ndim != 5 or ring.shape[1:3] != (L, 2) \
+                or ring.shape[4] != KVD:
             raise ValueError(
-                f"ring_layer {ring_layer} out of range for L = "
-                f"{ring_full.shape[1]}")
-    R = (ring_k.shape[1] if ring_k is not None
-         else ring_full.shape[0] if ring_full is not None else None)
+                f"ring must be [R, {L}, 2, S, {KVD}], got "
+                f"{list(ring.shape)}")
+        # over an int8 pool the ring stays in the COMPUTE dtype; otherwise
+        # it shares the pool's
+        if ring.dtype != compute_dt:
+            raise ValueError(
+                f"ring dtype {ring.dtype} != expected {compute_dt} (the "
+                f"kernels do not cast the whole ring)")
+    R = ring.shape[0] if has_ring else None
 
     windowed = C == 1
     if windowed:
@@ -817,42 +772,27 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         # out of the HBM pool, which Mosaic only takes at 128-lane rows:
         # narrower rows (one 64-wide kv head a chip under tp) go through
         # the BlockSpec kernel below, whose blocks span the whole row.
-        if ring_full is not None:
-            # over an int8 pool the ring stays in the COMPUTE dtype;
-            # otherwise it shares the pool's (never cast)
-            if ring_full.dtype != compute_dt:
-                raise ValueError(
-                    f"ring_full dtype {ring_full.dtype} != expected "
-                    f"{compute_dt} (the decode kernel does not cast the "
-                    f"whole ring)")
-            ring, rl = ring_full, int(ring_layer)
-        elif ring_k is not None:
-            ring = jnp.stack([ring_k, ring_v]).astype(compute_dt)
-            ring, rl = ring.transpose(2, 0, 1, 3)[:, None], 0
-        else:
-            ring, rl = None, 0
-        if not quant:
-            scales, sl = None, 0
-        elif scales_full is not None and use_pool_full:
-            scales, sl = scales_full.astype(jnp.float32), int(pool_layer)
-        else:
-            scales, sl = jnp.stack([k_scales, v_scales])[None].astype(
-                jnp.float32), 0
+        if planes == 1:
+            raise ValueError(
+                "a one-plane (latent) pool decodes through "
+                "mla_decode_attention; this kernel reads plane 1 for V")
         Hp = -(-H // 16) * 16          # whole sublane tiles a sequence
         qp = qw.reshape(S, H, KVD)
         if Hp != H:
             qp = jnp.pad(qp, ((0, 0), (0, Hp - H), (0, 0)))
+        # the layer as the kernel's three scalars (pool, ring, scales); one
+        # it does not read stays 0, so that every compiled program keeps
+        # the constants it was measured with
+        layers = jnp.asarray(
+            [layer, layer if has_ring else 0, layer if quant else 0],
+            jnp.int32)
         out = _decode_call(
-            qp, *((pool_full, pool_full) if use_pool_full
-                  else (k_pool, v_pool)),
-            ring, scales, block_tables.astype(jnp.int32),
+            qp, pool, pool, ring, scales, block_tables.astype(jnp.int32),
             start_pos.astype(jnp.int32), seq_lens.astype(jnp.int32),
             (jnp.reshape(ring_count, (1,)).astype(jnp.int32)
              if has_ring else jnp.zeros((1,), jnp.int32)),
-            jnp.asarray([int(pool_layer) if use_pool_full else 0, rl, sl],
-                        jnp.int32),
-            slopes, bs=bs, H=H, KV=KV, D=D, sm_scale=float(sm_scale),
-            use_alibi=use_alibi, window=window, use_pool_full=use_pool_full,
+            layers, slopes, bs=bs, H=H, KV=KV, D=D,
+            sm_scale=float(sm_scale), use_alibi=use_alibi, window=window,
             out_dtype=jnp.dtype(q.dtype), interpret=bool(interpret))
         return jnp.moveaxis(head_windows(out[:, :, None]), 1, 2)
 
@@ -861,7 +801,7 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     # K/V tile stays ~<=2 MB of VMEM; prefill processes blocks in sub-tiles
     # so KV tiles + the H*Cb softmax scratch fit VMEM.
     if C == 1:
-        pbs = decode_tile_rows(bs, KVD, k_pool.dtype.itemsize)
+        pbs = decode_tile_rows(bs, KVD, pool.dtype.itemsize)
     else:
         pbs = next(d for d in range(min(bs, 256), 0, -1) if bs % d == 0)
     factor = bs // pbs
@@ -934,53 +874,37 @@ def flash_paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     o_spec = pl.BlockSpec((1, H * Cb, row_lanes),
                           lambda s, qc, j, *_: (s, qc, 0))
 
-    if use_pool_full:
-        # the WHOLE pool is the K and the V operand, viewed
-        # [L, 2, nb, pbs, KVD] (the same split of the slots axis, no data
-        # moves); the index map picks (layer, k/v, block) and the squeezed
-        # leading axes leave the kernel its [1, pbs, KVD] refs. A Pallas
-        # operand is a whole buffer, so handing it pool[layer, x] made XLA
-        # copy that plane out of the pool first: 2 L planes = the whole
-        # pool read and written once per step
-        li = int(pool_layer)
-        planes = pool_full.shape[1]
-        pool5 = pool_full.reshape(pool_full.shape[0], planes, nb_pool, pbs,
-                                  KVD)
-        in_specs = [q_spec] + [
-            pl.BlockSpec(
-                (None, None, 1, pbs, KVD),
-                lambda s, qc, j, *pref, x=x:
-                    (li, x, _kv_block(s, qc, j, *pref), 0, 0))
-            for x in (0, planes - 1)]
-        operands = [qw, pool5, pool5]
-    else:
-        # direct callers that hold one layer's planes only
-        in_specs = [q_spec] + [pl.BlockSpec((1, pbs, KVD), block_index)] * 2
-        operands = [qw, k_pool.reshape(nb_pool, pbs, KVD),
-                    v_pool.reshape(nb_pool, pbs, KVD)]
+    # the WHOLE pool is the K and the V operand, viewed
+    # [L, planes, nb, pbs, KVD] (the same split of the slots axis, no data
+    # moves); the index map picks (layer, k/v, block) and the squeezed
+    # leading axes leave the kernel its [1, pbs, KVD] refs. A Pallas
+    # operand is a whole buffer, so handing it pool[layer, x] made XLA
+    # copy that plane out of the pool first: 2 L planes = the whole
+    # pool read and written once per step
+    pool5 = pool.reshape(L, planes, nb_pool, pbs, KVD)
+    in_specs = [q_spec] + [
+        pl.BlockSpec(
+            (None, None, 1, pbs, KVD),
+            lambda s, qc, j, *pref, x=x:
+                (layer, x, _kv_block(s, qc, j, *pref), 0, 0))
+        for x in (0, planes - 1)]
+    operands = [qw, pool5, pool5]
     if quant:
-        # per-layer [KV, slots] scales re-laid [nb, KV, pbs] so a block's
+        # this layer's [KV, slots] scales re-laid [nb, KV, pbs] so a block's
         # minor dims are (KV, pbs) proper tiles; the same clamped block
         # index feeds both the KV tile and its scale window
-        ksb = k_scales.astype(jnp.float32).reshape(
-            KV, nb_pool, pbs).swapaxes(0, 1)
-        vsb = v_scales.astype(jnp.float32).reshape(
-            KV, nb_pool, pbs).swapaxes(0, 1)
         in_specs += [pl.BlockSpec((1, KV, pbs), block_index)] * 2
-        operands += [ksb, vsb]
+        operands += [scales[layer, x].reshape(KV, nb_pool, pbs).swapaxes(0, 1)
+                     for x in (0, 1)]
     grid = (S, nCb, maxb_v + 1 if has_ring else maxb_v)
     if has_ring:
-        if ring_k is None:
-            # rows narrower than 128 lanes (a tp runner's fused loop):
-            # a [R, 1, KVD] block of the 5-D carry is no legal tile, so
-            # this layer's planes are built here, R x S narrow rows
-            ring_k = jnp.moveaxis(ring_full[:, ring_layer, 0], 0, 1)
-            ring_v = jnp.moveaxis(ring_full[:, ring_layer, 1], 0, 1)
+        # rows narrower than 128 lanes (a tp runner's fused loop): a
+        # [R, 1, KVD] block of the 5-D carry is no legal tile, so this
+        # layer's [S, R, KVD] planes are built here, R x S narrow rows
         ring_spec = pl.BlockSpec((1, R, KVD),
                                  lambda s, qc, j, *_: (s, 0, 0))
         in_specs += [ring_spec, ring_spec]
-        operands += [ring_k.astype(compute_dt),
-                     ring_v.astype(compute_dt)]
+        operands += [jnp.moveaxis(ring[:, layer, x], 0, 1) for x in (0, 1)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pref,

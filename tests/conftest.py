@@ -95,14 +95,14 @@ _FULL_TESTS = frozenset([
     "test_inference_v2.py::TestFalconPhiRaggedRunners::test_phi_decode_matches_full_forward",
     "test_inference_v2.py::TestKVInt8::test_engine_int8_decode_loop_linear_layout",
     "test_inference_v2.py::TestKVInt8::test_engine_int8_pause_resume",
-    "test_inference_v2.py::TestKVInt8::test_kernel_direct_int8_parity",
+    "test_paged_attention.py::TestKVInt8Kernel::test_kernel_direct_int8_parity",
     "test_inference_v2.py::TestKVOffloadRestore::test_pause_evict_resume_token_exact",
     "test_inference_v2.py::TestOPTRaggedRunner::test_decode_matches_full_forward",
     "test_inference_v2.py::TestOnDeviceSampling::test_decode_batch_eos_freeze_accounting",
     "test_inference_v2.py::TestOnDeviceSampling::test_sampled_topk1_equals_greedy",
-    "test_inference_v2.py::TestPagedFlashKernel::test_engine_tokens_identical_dense_vs_kernel",
-    "test_inference_v2.py::TestPagedFlashKernel::test_gqa_and_chunk_parity",
-    "test_inference_v2.py::TestPagedFlashKernel::test_long_context_8k",
+    "test_paged_attention.py::TestPagedFlashKernel::test_engine_tokens_identical_dense_vs_kernel",
+    "test_paged_attention.py::TestPagedFlashKernel::test_gqa_and_chunk_parity",
+    "test_paged_attention.py::TestPagedFlashKernel::test_long_context_8k",
     "test_inference_v2.py::TestRaggedEngineParity::test_decode_greedy_eos_truncates",
     "test_inference_v2.py::TestRaggedEngineParity::test_decode_matches_full_forward",
     "test_inference_v2.py::TestRaggedEngineParity::test_fused_decode_loop_linear_layout",

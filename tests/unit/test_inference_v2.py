@@ -561,161 +561,6 @@ class TestFalconPhiRaggedRunners:
         assert gen == toks[len(prompt):]
 
 
-class TestPagedFlashKernel:
-    """The Pallas paged-decode kernel vs the dense-gather fallback — and the
-    long-context capability the dense path's max_context wall precluded."""
-
-    def test_engine_tokens_identical_dense_vs_kernel(self):
-        rng = np.random.default_rng(3)
-        prompt = list(rng.integers(1, 96, 13))
-        gens = []
-        for impl in ("dense", "paged_flash"):
-            cfg, mcfg, model, params = _tiny_setup(chunk=8, block_size=4)
-            cfg.attention_impl = impl
-            eng = InferenceEngineV2(mcfg, params, cfg)
-            gens.append(eng.generate([prompt], max_new_tokens=8)[0])
-        assert gens[0] == gens[1]
-
-    def test_long_context_8k(self):
-        """Flash through block tables at 8k+ context: per-step work scales
-        with LIVE blocks; here the pool itself is smaller than max_context
-        would require for the dense path ((128+1)*64 slots vs S*8192)."""
-        from deepspeed_tpu.ops.kernels import flash_paged_attention
-        bs, nb = 64, 129                     # 8256 poolable tokens
-        KV = H = 2
-        D = 16
-        S, C = 1, 1
-        ks = jax.random.split(jax.random.PRNGKey(5), 3)
-        pool_k = jax.random.normal(ks[0], ((nb + 1) * bs, KV, D), jnp.float32)
-        pool_v = jax.random.normal(ks[1], ((nb + 1) * bs, KV, D), jnp.float32)
-        maxb = 129
-        tables = jnp.asarray(
-            np.random.default_rng(0).permutation(nb)[None, :maxb], jnp.int32)
-        seq_len = 8192 + 17                  # > 8k live tokens
-        start = jnp.asarray([seq_len - 1], jnp.int32)
-        q = jax.random.normal(ks[2], (S, C, H, D), jnp.float32)
-
-        out = flash_paged_attention(q, pool_k, pool_v, tables, start,
-                                    jnp.asarray([seq_len], jnp.int32),
-                                    block_size=bs, interpret=True)
-
-        # jnp reference over the gathered live context
-        j = np.arange(seq_len)
-        idx = np.asarray(tables)[0, j // bs] * bs + j % bs
-        kc = np.asarray(pool_k)[idx]         # [seq_len, KV, D]
-        vc = np.asarray(pool_v)[idx]
-        s_att = np.einsum("chd,khd->hck", np.asarray(q)[0], kc) / np.sqrt(D)
-        p = jax.nn.softmax(jnp.asarray(s_att), axis=-1)
-        ref = jnp.einsum("hck,khd->chd", p, jnp.asarray(vc))[None]
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=1e-4)
-
-    def test_gqa_and_chunk_parity(self):
-        """Chunked prefill (C>1) + GQA kv heads vs dense reference."""
-        from deepspeed_tpu.ops.kernels import flash_paged_attention
-        bs, nb, KV, H, D, S, C = 8, 16, 2, 4, 8, 3, 4
-        ks = jax.random.split(jax.random.PRNGKey(7), 3)
-        pool_k = jax.random.normal(ks[0], ((nb + 1) * bs, KV, D), jnp.float32)
-        pool_v = jax.random.normal(ks[1], ((nb + 1) * bs, KV, D), jnp.float32)
-        perm = np.random.default_rng(1).permutation(nb)
-        tables = np.zeros((S, 8), np.int32)   # <=5 live blocks per seq
-        for s in range(S):
-            tables[s, :5] = perm[s * 5:s * 5 + 5]
-        tables = jnp.asarray(tables)
-        start = jnp.asarray([0, 5, 29], jnp.int32)
-        lens = start + C
-        q = jax.random.normal(ks[2], (S, C, H, D), jnp.float32)
-        out = flash_paged_attention(q, pool_k, pool_v, tables, start, lens,
-                                    block_size=bs, interpret=True)
-        for s in range(S):
-            L = int(lens[s])
-            j = np.arange(L)
-            idx = np.asarray(tables)[s, j // bs] * bs + j % bs
-            kc = np.repeat(np.asarray(pool_k)[idx], H // KV, 1)
-            vc = np.repeat(np.asarray(pool_v)[idx], H // KV, 1)
-            s_att = np.einsum("chd,khd->hck", np.asarray(q)[s], kc) / np.sqrt(D)
-            pos_q = int(start[s]) + np.arange(C)
-            mask = j[None, None, :] <= pos_q[None, :, None]
-            s_att = np.where(mask, s_att, -np.inf)
-            p = jax.nn.softmax(jnp.asarray(s_att), axis=-1)
-            ref = jnp.einsum("hck,khd->chd", p, jnp.asarray(vc))
-            np.testing.assert_allclose(np.asarray(out)[s], np.asarray(ref),
-                                       atol=2e-5, rtol=1e-4)
-
-    @pytest.mark.parametrize("mode,kv_dtype,window,ring", [
-        (mode, kv_dtype, window, ring)
-        for mode in ("decode", "prefill")
-        for kv_dtype in ("bf16", "int8")
-        for window in (None, 12)
-        for ring in ((False, True) if mode == "decode" else (False,))])
-    def test_whole_pool_operand_parity(self, mode, kv_dtype, window, ring):
-        """BlockSpec path (maxb > 1): handing the whole [L, 2, slots, KVD]
-        pool + pool_layer must equal the per-plane call BIT FOR BIT. Every
-        plane holds different rows and pool_layer is the last of three, so
-        a wrong layer or K/V index in the index map cannot pass."""
-        from deepspeed_tpu.ops.kernels import flash_paged_attention
-        rng = np.random.default_rng(26)
-        L, li = 3, 2
-        bs, nb, maxb, KV, H, D, S = 8, 16, 4, 2, 4, 8, 4
-        KVD, slots = KV * D, (nb + 1) * bs
-        C = 1 if mode == "decode" else 4
-        quant = kv_dtype == "int8"
-        if quant:
-            pool = jnp.asarray(
-                rng.integers(-127, 128, (L, 2, slots, KVD)), jnp.int8)
-            scales = jnp.asarray(
-                rng.uniform(0.005, 0.02, (L, 2, KV, slots)), jnp.float32)
-            plane_kw = dict(k_scales=scales[li, 0], v_scales=scales[li, 1])
-            full_kw = dict(scales_full=scales)
-        else:
-            pool = jnp.asarray(
-                rng.normal(size=(L, 2, slots, KVD)), jnp.bfloat16)
-            plane_kw, full_kw = {}, {}
-        tables = jnp.asarray(
-            rng.permutation(nb)[:S * maxb].reshape(S, maxb), jnp.int32)
-        lens = jnp.asarray([29, 9, 17, 0], jnp.int32)   # slot 3 idle
-        q = jnp.asarray(rng.normal(size=(S, C, H, D)), jnp.bfloat16)
-        common = dict(block_size=bs, num_kv_heads=KV, sliding_window=window,
-                      interpret=True)
-        if ring:
-            # the fused loop's form: the pool holds the settled rows, the
-            # loop's own tokens sit in the (never quantized) ring
-            rcount = jnp.asarray(3, jnp.int32)
-            common.update(
-                ring_full=jnp.asarray(
-                    rng.normal(size=(4, L, 2, S, KVD)), jnp.bfloat16),
-                ring_layer=li, ring_count=rcount)
-            start, settled = lens + rcount - 1, lens
-        else:
-            start, settled = jnp.maximum(lens - C, 0), lens
-        per_plane = flash_paged_attention(
-            q, pool[li, 0], pool[li, 1], tables, start, settled,
-            **common, **plane_kw)
-        whole = flash_paged_attention(
-            q, pool[li, 0], pool[li, 1], tables, start, settled,
-            pool_full=pool, pool_layer=li, **common, **full_kw)
-        assert bool(jnp.any(per_plane[:3] != 0))
-        assert bool(jnp.all(per_plane[3] == 0))         # idle slot
-        np.testing.assert_array_equal(
-            np.asarray(whole, np.float32), np.asarray(per_plane, np.float32))
-
-    def test_whole_pool_operand_is_checked(self):
-        # a pool of another geometry or dtype than the planes, or a layer
-        # outside it, is refused before any kernel is built
-        from deepspeed_tpu.ops.kernels import flash_paged_attention
-        bs, slots, KV, D = 8, 24, 2, 8
-        pool = jnp.zeros((3, 2, slots, KV * D), jnp.bfloat16)
-        args = (jnp.zeros((2, 1, 4, D), jnp.bfloat16), pool[0, 0],
-                pool[0, 1], jnp.zeros((2, 2), jnp.int32),
-                jnp.zeros((2,), jnp.int32), jnp.ones((2,), jnp.int32))
-        kw = dict(block_size=bs, num_kv_heads=KV, interpret=True)
-        for bad, layer in ((pool[:, :, :16], 0),
-                           (pool.astype(jnp.float32), 0), (pool, 3)):
-            with pytest.raises(ValueError, match="pool_full|pool_layer"):
-                flash_paged_attention(*args, pool_full=bad,
-                                      pool_layer=layer, **kw)
-
-
 class TestKVOffloadRestore:
     """engine.pause/resume — reference BlockedKVCache.offload/restore
     (inference/v2/ragged/kv_cache.py:166,176): a sequence's KV moves to host
@@ -998,88 +843,6 @@ class TestKVInt8:
         assert i8.data.size == fp.data.size
         assert i8.memory_bytes() < 0.6 * fp.memory_bytes()
 
-    def test_kernel_direct_int8_parity(self):
-        # direct kernel call: quantized pool + per-layer scales vs the fp
-        # pool, prefill (multi-block BlockSpec path) and grouped decode
-        # (linear layout) both
-        from deepspeed_tpu.inference.v2.kv_quant import quantize_rows
-        from deepspeed_tpu.ops.kernels import flash_paged_attention
-        rng = np.random.default_rng(7)
-        S, H, KV, D = 4, 8, 2, 16
-        KVD = KV * D
-
-        # prefill: blocked layout
-        bs, nb, maxb = 16, 12, 3
-        slots = (nb + 1) * bs
-        kf = jnp.asarray(rng.normal(size=(slots, KVD)), jnp.float32)
-        vf = jnp.asarray(rng.normal(size=(slots, KVD)), jnp.float32)
-        qk, sk = quantize_rows(kf, KV)
-        qv, sv = quantize_rows(vf, KV)
-        tables = jnp.asarray(
-            rng.permutation(nb)[:S * maxb].reshape(S, maxb), jnp.int32)
-        lens = jnp.asarray([40, 33, 17, 0], jnp.int32)
-        C = 8
-        q = jnp.asarray(rng.normal(size=(S, C, H, D)), jnp.float32)
-        start = jnp.maximum(lens - C, 0)
-        o_fp = flash_paged_attention(q, kf, vf, tables, start, lens,
-                                     block_size=bs, num_kv_heads=KV,
-                                     interpret=True)
-        o_i8 = flash_paged_attention(q, qk, qv, tables, start, lens,
-                                     block_size=bs, num_kv_heads=KV,
-                                     k_scales=sk, v_scales=sv,
-                                     interpret=True)
-        rel = float(jnp.max(jnp.abs(o_fp - o_i8))) / float(
-            jnp.max(jnp.abs(o_fp)))
-        assert rel < 0.05
-
-        # grouped decode: linear layout, full pool + scales_full + ring
-        bs2 = 64
-        slots2 = (S + 1) * bs2
-        kf2 = jnp.asarray(rng.normal(size=(slots2, KVD)), jnp.float32)
-        vf2 = jnp.asarray(rng.normal(size=(slots2, KVD)), jnp.float32)
-        qk2, sk2 = quantize_rows(kf2, KV)
-        qv2, sv2 = quantize_rows(vf2, KV)
-        L, li = 3, 1
-        pool = jnp.zeros((L, 2, slots2, KVD), jnp.int8)
-        pool = pool.at[li, 0].set(qk2).at[li, 1].set(qv2)
-        scales = jnp.ones((L, 2, KV, slots2), jnp.float32)
-        scales = scales.at[li, 0].set(sk2).at[li, 1].set(sv2)
-        tables2 = jnp.arange(S, dtype=jnp.int32)[:, None]
-        lens2 = jnp.asarray([40, 20, 64, 0], jnp.int32)
-        q2 = jnp.asarray(rng.normal(size=(S, 1, H, D)), jnp.float32)
-        R = 4
-        ring = jnp.asarray(rng.normal(size=(R, L, 2, S, KVD)), jnp.float32)
-        rcount = jnp.asarray(2, jnp.int32)
-        o_full = flash_paged_attention(
-            q2, pool[li, 0], pool[li, 1], tables2, lens2 + rcount, lens2,
-            block_size=bs2, num_kv_heads=KV,
-            pool_full=pool, pool_layer=li, scales_full=scales,
-            ring_full=ring, ring_layer=li, ring_count=rcount,
-            interpret=True)
-        # dense reference over the dequantized pool + ring tokens
-        from deepspeed_tpu.inference.v2.kv_quant import dequantize_rows
-        kd = dequantize_rows(qk2, sk2, jnp.float32)
-        vd = dequantize_rows(qv2, sv2, jnp.float32)
-        g = H // KV
-        for s_i in range(S):
-            if int(lens2[s_i]) == 0:
-                continue
-            base = int(tables2[s_i, 0]) * bs2
-            T = int(lens2[s_i])
-            kk = jnp.concatenate(
-                [kd[base:base + T], ring[:int(rcount), li, 0, s_i]], 0)
-            vv = jnp.concatenate(
-                [vd[base:base + T], ring[:int(rcount), li, 1, s_i]], 0)
-            for h in range(H):
-                kvh = h // g
-                kh = kk.reshape(-1, KV, D)[:, kvh]
-                vh = vv.reshape(-1, KV, D)[:, kvh]
-                sc = (q2[s_i, 0, h] @ kh.T) / np.sqrt(D)
-                want = jax.nn.softmax(sc) @ vh
-                np.testing.assert_allclose(
-                    np.asarray(o_full[s_i, 0, h]), np.asarray(want),
-                    atol=5e-5, rtol=5e-5)
-
     def test_int8_alignment_guard_on_tpu(self, monkeypatch):
         # the Mosaic DMA-tiling constraint must surface at engine
         # construction on TPU, not deep inside a kernel compile
@@ -1091,40 +854,6 @@ class TestKVInt8:
         cfg_dense = RaggedInferenceConfig(**{**cfg_i8.__dict__,
                                              "attention_impl": "dense"})
         InferenceEngineV2(mcfg, params, cfg_dense)
-
-    def test_kernel_int8_sliding_window(self):
-        # mistral-class sliding window over an int8 pool: the window mask
-        # must compose with score/prob scaling (scale applied pre-mask)
-        from deepspeed_tpu.inference.v2.kv_quant import quantize_rows
-        from deepspeed_tpu.ops.kernels import flash_paged_attention
-        rng = np.random.default_rng(8)
-        S, H, KV, D = 2, 4, 2, 16
-        KVD = KV * D
-        bs = 64
-        slots = (S + 1) * bs
-        kf = jnp.asarray(rng.normal(size=(slots, KVD)), jnp.float32)
-        vf = jnp.asarray(rng.normal(size=(slots, KVD)), jnp.float32)
-        qk, sk = quantize_rows(kf, KV)
-        qv, sv = quantize_rows(vf, KV)
-        tables = jnp.arange(S, dtype=jnp.int32)[:, None]
-        lens = jnp.asarray([60, 33], jnp.int32)
-        # kernel contract: start_pos is the query's own position and its
-        # K/V row is already in the pool — the engine always calls with
-        # start = seq_len - 1 at decode
-        start = lens - 1
-        q = jnp.asarray(rng.normal(size=(S, 1, H, D)), jnp.float32)
-        win = 16
-        o_fp = flash_paged_attention(q, kf, vf, tables, start, lens,
-                                     block_size=bs, num_kv_heads=KV,
-                                     sliding_window=win, interpret=True)
-        o_i8 = flash_paged_attention(q, qk, qv, tables, start, lens,
-                                     block_size=bs, num_kv_heads=KV,
-                                     k_scales=sk, v_scales=sv,
-                                     sliding_window=win, interpret=True)
-        rel = float(jnp.max(jnp.abs(o_fp - o_i8))) / float(
-            jnp.max(jnp.abs(o_fp)))
-        assert rel < 0.05
-
 
 def _tp_setup(num_heads=4, hidden=64, vocab=96, **cfg_kw):
     """GPT-2 geometry whose heads divide by 4 (TP over the virtual 8-device
@@ -1423,310 +1152,6 @@ class TestPrefillChunkCap:
         sm.put_tokens(1, range(20))
         items = sched.schedule()
         assert max(len(it.tokens) for it in items) == 4
-
-
-class TestSeqLenBoundedGroupedReads:
-    """Satellite: the grouped decode kernel's per-sequence context copy is
-    tiled and stops at each sequence's settled length instead of streaming
-    the whole (linear-layout) block; dead tiles are zero-filled."""
-
-    def test_partial_lengths_match_reference(self):
-        from deepspeed_tpu.ops.kernels import flash_paged_attention
-        rng = np.random.default_rng(41)
-        S, H, KV, D = 4, 4, 2, 16
-        KVD = KV * D
-        bs = 512                          # ts=256 -> 2 copy tiles per seq
-        slots = (S + 1) * bs
-        kf = jnp.asarray(rng.normal(size=(slots, KVD)), jnp.float32)
-        vf = jnp.asarray(rng.normal(size=(slots, KVD)), jnp.float32)
-        tables = jnp.arange(S, dtype=jnp.int32)[:, None]
-        lens = jnp.asarray([130, 512, 1, 0], jnp.int32)  # partial/full/idle
-        start = jnp.maximum(lens - 1, 0)
-        q = jnp.asarray(rng.normal(size=(S, 1, H, D)), jnp.float32)
-        out = flash_paged_attention(q, kf, vf, tables, start, lens,
-                                    block_size=bs, num_kv_heads=KV,
-                                    interpret=True)
-        g = H // KV
-        for s in range(S):
-            L = int(lens[s])
-            if L == 0:
-                assert np.allclose(np.asarray(out[s]), 0)
-                continue
-            base = int(tables[s, 0]) * bs
-            kc = np.repeat(np.asarray(kf)[base:base + L]
-                           .reshape(L, KV, D), g, 1)
-            vc = np.repeat(np.asarray(vf)[base:base + L]
-                           .reshape(L, KV, D), g, 1)
-            sc = np.einsum("chd,khd->hck", np.asarray(q)[s], kc) \
-                / np.sqrt(D)
-            mask = np.arange(L)[None, None, :] <= int(start[s])
-            p = jax.nn.softmax(jnp.asarray(np.where(mask, sc, -np.inf)),
-                               -1)
-            ref = jnp.einsum("hck,khd->chd", p, jnp.asarray(vc))
-            np.testing.assert_allclose(np.asarray(out[s]),
-                                       np.asarray(ref),
-                                       atol=2e-5, rtol=1e-4)
-
-
-def _decode_case(*, geom="gqa", maxb=2, S=8, bs=256, kv_dtype="bf16",
-                 rcount=None, R=4, window=None, alibi=False, poison=False,
-                 whole=True, lens=None, seed=0):
-    """One pure-decode call of the paged kernel (interpret mode) against
-    a dense float32 reference over the same rows. Block tables are a
-    random permutation: a sequence's blocks are never adjacent by
-    construction. Returns (out, ref), both [S, H, D] float32."""
-    from deepspeed_tpu.inference.v2.kv_quant import (dequantize_rows,
-                                                     quantize_rows)
-    from deepspeed_tpu.ops.kernels import (decode_tile_rows,
-                                           flash_paged_attention)
-    rng = np.random.default_rng(seed)
-    H, KV, D = {"gqa": (12, 2, 128), "mha": (16, 16, 128)}[geom]
-    KVD, g = KV * D, H // KV
-    L, li = 2, 1
-    nb = S * maxb + 3
-    slots = (nb + 1) * bs
-    ts = decode_tile_rows(bs, KVD, 1 if kv_dtype == "int8" else 2)
-    assert ts == 128
-    if lens is None:
-        # idle, one row, around a tile edge, around a block edge, full
-        want = [0, 1, ts - 1, ts, ts + 1, bs, bs + 1, maxb * bs]
-        lens = [min(want[s % len(want)], maxb * bs) for s in range(S)]
-    lens = np.asarray(lens, np.int64)
-    tables = rng.permutation(nb)[:S * maxb].reshape(S, maxb)
-    live = np.zeros((slots,), bool)
-    for s in range(S):
-        j = np.arange(lens[s])
-        live[tables[s, j // bs] * bs + j % bs] = True
-    dt = {"bf16": jnp.bfloat16, "f32": jnp.float32, "int8": jnp.bfloat16}[
-        kv_dtype]
-    kf = jnp.asarray(rng.normal(size=(slots, KVD)), dt)
-    vf = jnp.asarray(rng.normal(size=(slots, KVD)), dt)
-    kw = {}
-    if kv_dtype == "int8":
-        pk, sk = quantize_rows(kf.astype(jnp.float32), KV)
-        pv, sv = quantize_rows(vf.astype(jnp.float32), KV)
-        k_ref = np.asarray(dequantize_rows(pk, sk, jnp.float32))
-        v_ref = np.asarray(dequantize_rows(pv, sv, jnp.float32))
-        if poison:       # a dead row's scale is whatever was left there
-            dead = jnp.asarray(~live)[None, :]
-            sk = jnp.where(dead, jnp.nan, sk)
-            sv = jnp.where(dead, jnp.nan, sv)
-        scales = jnp.ones((L, 2, KV, slots), jnp.float32)
-        scales = scales.at[li, 0].set(sk).at[li, 1].set(sv)
-        kw.update(scales_full=scales) if whole else kw.update(
-            k_scales=sk, v_scales=sv)
-    else:
-        k_ref = np.asarray(kf, np.float32)
-        v_ref = np.asarray(vf, np.float32)
-        if poison:       # every row above a live length holds NaN
-            dead = jnp.asarray(~live)[:, None]
-            pk = jnp.where(dead, jnp.nan, kf)
-            pv = jnp.where(dead, jnp.nan, vf)
-        else:
-            pk, pv = kf, vf
-    pool = jnp.zeros((L, 2, slots, KVD), pk.dtype)
-    pool = pool.at[li, 0].set(pk).at[li, 1].set(pv)
-    if whole:
-        kw.update(pool_full=pool, pool_layer=li)
-    qdt = jnp.float32 if kv_dtype == "f32" else jnp.bfloat16
-    q = jnp.asarray(rng.normal(size=(S, 1, H, D)), qdt)
-    slopes = np.asarray([2.0 ** -(h + 1) for h in range(H)], np.float32)
-    if alibi:
-        kw.update(alibi_slopes=jnp.asarray(slopes))
-    lens_j = jnp.asarray(lens, jnp.int32)
-    if rcount is None:
-        start = jnp.maximum(lens_j - 1, 0)
-        ring = None
-    else:
-        ring = jnp.asarray(rng.normal(size=(R, L, 2, S, KVD)), qdt)
-        start = lens_j + rcount - 1
-        kw.update(ring_full=ring, ring_layer=li,
-                  ring_count=jnp.asarray(rcount, jnp.int32))
-    out = flash_paged_attention(
-        q, pool[li, 0], pool[li, 1], jnp.asarray(tables, jnp.int32), start,
-        lens_j, block_size=bs, num_kv_heads=KV, sliding_window=window,
-        interpret=True, **kw)
-    ref = np.zeros((S, H, D), np.float32)
-    qn = np.asarray(q, np.float32)
-    for s in range(S):
-        n = int(lens[s])
-        if n == 0:
-            continue
-        j = np.arange(n)
-        idx = tables[s, j // bs] * bs + j % bs
-        kc, vc, pos = k_ref[idx], v_ref[idx], j
-        if ring is not None:
-            rg = np.asarray(ring[:rcount, li, :, s], np.float32)
-            kc = np.concatenate([kc, rg[:, 0]])
-            vc = np.concatenate([vc, rg[:, 1]])
-            pos = np.concatenate(
-                [pos, int(start[s]) - (rcount - 1) + np.arange(rcount)])
-        kc = np.repeat(kc.reshape(-1, KV, D), g, 1)
-        vc = np.repeat(vc.reshape(-1, KV, D), g, 1)
-        sc = np.einsum("hd,khd->hk", qn[s, 0], kc) / np.sqrt(D)
-        dist = int(start[s]) - pos
-        mask = dist >= 0
-        if window is not None:
-            mask &= dist < window
-        if alibi:
-            sc = sc - slopes[:, None] * dist[None]
-        sc = np.where(mask[None], sc, -np.inf)
-        p = np.exp(sc - sc.max(-1, keepdims=True))
-        ref[s] = np.einsum("hk,khd->hd", p / p.sum(-1, keepdims=True), vc)
-    return np.asarray(out, np.float32)[:, 0], ref, lens
-
-
-class TestPagedDecodeKernel:
-    """The decode kernel of ``C == 1`` calls at 128-lane rows (several
-    sequences a grid step, live tiles only, through the block table) vs a
-    dense reference: one case a row of ISSUE 31's list."""
-
-    @pytest.mark.parametrize("case", [
-        # blocks a sequence 1 / 2 / 6, tables permuted, every length class
-        dict(maxb=1), dict(maxb=2), dict(maxb=6),
-        # the fused loop's ring: empty, one token, full
-        dict(rcount=0), dict(rcount=1), dict(rcount=4, maxb=1),
-        # MHA 16 / 16 at 2048-lane rows (chunked: a context does not fit)
-        dict(geom="mha", S=4, lens=[0, 129, 257, 512]),
-        dict(geom="mha", S=4, lens=[1, 128, 256, 511], rcount=2),
-        # slot counts the group size does not divide, or under one group
-        dict(S=20), dict(S=3, rcount=2), dict(S=16, maxb=1),
-        # int8 pool: whole-pool and per-layer scales, ring over int8
-        dict(kv_dtype="int8"), dict(kv_dtype="int8", whole=False),
-        dict(kv_dtype="int8", rcount=3, maxb=1),
-        # float32 pool, per-plane operands
-        dict(kv_dtype="f32", whole=False, S=4, lens=[0, 130, 256, 300]),
-        # sliding window (tiles wholly below it are not copied), ALiBi
-        dict(window=100), dict(window=200, rcount=4, maxb=6),
-        dict(window=100, kv_dtype="int8"), dict(alibi=True),
-        dict(alibi=True, rcount=2, window=300),
-        # poison: NaN in every pool row (or scale) above a live length
-        dict(poison=True, S=16), dict(poison=True, rcount=2, maxb=6),
-        dict(poison=True, geom="mha", S=4, lens=[0, 129, 257, 500]),
-        dict(poison=True, kv_dtype="int8"),
-    ], ids=lambda c: "-".join(f"{k}={v}" for k, v in c.items()
-                              if k != "lens"))
-    def test_matches_dense_reference(self, case):
-        out, ref, lens = _decode_case(**case)
-        assert np.isfinite(out).all()
-        assert not out[lens == 0].any()              # idle slots emit zeros
-        tol = {"f32": 2e-5, "int8": 0.03}.get(case.get("kv_dtype"), 0.02)
-        np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
-
-    def test_plan_follows_the_shapes(self):
-        # G, chunk rows and chunks from slot count, context and row bytes:
-        # Qwen's 512-byte rows hold a group's context in one or two
-        # chunks, OLMoE's 4,096-byte rows stream it in tiles
-        from deepspeed_tpu.ops.kernels.paged_attention import (
-            _DECODE_KV_VMEM, _decode_plan, decode_rows_fetched,
-            decode_tile_rows)
-        assert decode_tile_rows(640, 256, 2) == 128
-        assert decode_tile_rows(256, 2048, 1) == 128
-        assert decode_tile_rows(64, 256, 2) == 64          # block < tile
-        assert decode_tile_rows(640, 64, 2) == 640         # narrow rows
-        for S, ctx, row in ((128, 1280, 512), (16, 1536, 512),
-                            (32, 1280, 4096), (20, 512, 512), (3, 256, 512)):
-            G, cr, nch = _decode_plan(S, ctx, 128, row)
-            assert S % G == 0 and cr % 128 == 0 and cr * nch >= ctx
-            assert 4 * G * cr * row <= _DECODE_KV_VMEM
-        assert _decode_plan(32, 1280, 128, 4096)[2] > 1
-        assert decode_rows_fetched(0, 128) == 0
-        assert decode_rows_fetched(129, 128) == 256
-        assert decode_rows_fetched(673, 128) == 768
-        assert decode_rows_fetched(673, 128, window=200) == 384
-
-
-def _wide_gpt2(layers=4):
-    """A GPT-2 whose KV row is 256 lanes (2 heads of 128): the decode
-    kernel's shape class, small enough for interpret mode."""
-    mcfg = GPT2Config(vocab_size=96, max_seq_len=512, num_layers=layers,
-                      num_heads=2, hidden_size=256, dtype=jnp.float32)
-    params = GPT2(mcfg).init(jax.random.PRNGKey(0),
-                             jnp.zeros((1, 8), jnp.int32))["params"]
-    cfg = RaggedInferenceConfig(
-        max_seqs=4, chunk_size=16, block_size=128, num_blocks=12,
-        max_blocks_per_seq=2, dtype="float32", decode_loop_steps=4,
-        attention_impl="paged_flash")
-    return mcfg, params, cfg
-
-
-class TestDecodeKernelSetupCost:
-    """``setup_s`` is programs times their tracing: the decode kernel's
-    body is traced once a program family, not once a layer, and its
-    copies are loops, not G x tiles unrolled regions."""
-
-    def test_body_built_once_for_all_layers(self, monkeypatch):
-        from deepspeed_tpu.ops.kernels import paged_attention as pa
-        built = []
-        body = pa._decode_kernel
-
-        def counted(*a, **k):
-            built.append(k["R"])
-            return body(*a, **k)
-        monkeypatch.setattr(pa, "_decode_kernel", counted)
-        pa._decode_call.clear_cache()
-        mcfg, params, cfg = _wide_gpt2(layers=4)
-        eng = InferenceEngineV2(mcfg, params, cfg)
-        first = eng.put([1, 2], [[5, 6, 7], [9, 8, 7, 6, 5]], _greedy=True)
-        assert built == []                       # prefill: BlockSpec kernel
-        # the unfed and the fed step program, 4 layers each: one body
-        eng.decode_pipelined([1, 2], [first[1], first[2]], 3)
-        assert built == [None]
-        # the fused loop, 4 layers x 4 steps: one more (it has the ring)
-        eng.decode_batch([1, 2], [3, 4], 4)
-        assert built == [None, 4]
-
-    def test_copies_are_loops(self):
-        from deepspeed_tpu.ops.kernels import flash_paged_attention
-        S, H, KV, D, bs, maxb = 16, 2, 2, 128, 256, 6
-        pool = jnp.zeros((2, 2, (S * maxb + 1) * bs, KV * D), jnp.bfloat16)
-
-        def call(q, pool, tables, lens):
-            return flash_paged_attention(
-                q, pool[0, 0], pool[0, 1], tables, lens - 1, lens,
-                block_size=bs, num_kv_heads=KV, pool_full=pool,
-                pool_layer=1, interpret=True)
-        text = str(jax.make_jaxpr(call)(
-            jnp.zeros((S, 1, H, D), jnp.bfloat16), pool,
-            jnp.zeros((S, maxb), jnp.int32), jnp.ones((S,), jnp.int32)))
-        # G = 8 sequences x 12 tiles x (K, V) would be 192 starts a step
-        # if unrolled: the start appears once each for K and V in the
-        # first-step and next-step loops, and so does the wait
-        assert text.count("pallas_call") == 1
-        assert 0 < text.count("dma_start") <= 4
-        assert 0 < text.count("dma_wait") <= 2
-        assert "while" in text
-
-
-def test_decode_kv_rows_counted_per_step_and_per_fused_loop():
-    """pipeline_stats' live / fetched K/V rows against a hand count on a
-    three-sequence engine, over decode_pipelined and decode_batch."""
-    from deepspeed_tpu.ops.kernels import decode_rows_fetched
-    mcfg, params, cfg = _wide_gpt2(layers=1)
-    eng = InferenceEngineV2(mcfg, params, cfg)
-    prompts = {1: 3, 2: 127, 3: 130}
-    first = eng.put(list(prompts), [list(range(1, n + 1))
-                                    for n in prompts.values()], _greedy=True)
-    stats = eng.pipeline_stats
-    assert stats["decode_kv_rows_live"] == stats["decode_kv_rows_fetched"] == 0
-    n = 3
-    eng.decode_pipelined(list(prompts), [first[u] for u in prompts], n)
-    # step t attends its own token too: lengths p+1 .. p+n, tiles of 128
-    live = sum(p + t for p in prompts.values() for t in range(1, n + 1))
-    fetched = sum(decode_rows_fetched(p + t, 128)
-                  for p in prompts.values() for t in range(1, n + 1))
-    assert fetched == 3 * 128 + (128 + 256 * 2) + 3 * 256
-    assert (stats["decode_kv_rows_live"], stats["decode_kv_rows_fetched"]) \
-        == (live, fetched)
-    # the fused loop reads, every step, the rows settled at its entry
-    # (its own tokens ride the ring): 4 steps at lengths p + n
-    eng.decode_batch(list(prompts), [7, 8, 9], 4)
-    live += 4 * sum(p + n for p in prompts.values())
-    fetched += 4 * sum(decode_rows_fetched(p + n, 128)
-                       for p in prompts.values())
-    assert (stats["decode_kv_rows_live"], stats["decode_kv_rows_fetched"]) \
-        == (live, fetched)
-    assert 0 < live / fetched < 1
 
 
 class TestServePipeline:

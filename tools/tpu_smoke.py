@@ -96,8 +96,13 @@ def kernel_cases() -> List[KernelCase]:
 
     # paged attention over a multi-block bf16 pool (BlockSpec path)
     bs, nb, KV, D, H, C, S = 64, 32, 4, 64, 8, 4, 4
-    pool_k = jax.random.normal(ks[0], ((nb + 1) * bs, KV, D), jnp.bfloat16)
-    pool_v = jax.random.normal(ks[1], ((nb + 1) * bs, KV, D), jnp.bfloat16)
+    def as_pool(k, v):
+        """One layer's K and V planes [slots, KVD] (or scales [KV, slots])
+        as the [1, 2, ...] array the paged kernels take."""
+        return jnp.stack([k, v])[None]
+
+    pool = as_pool(*(jax.random.normal(x, ((nb + 1) * bs, KV * D),
+                                       jnp.bfloat16) for x in ks[:2]))
     tables = jnp.asarray(np.random.RandomState(0).permutation(nb)[:S * 8]
                          .reshape(S, 8), jnp.int32)
     start = jnp.asarray([0, 37, 130, 400], jnp.int32)
@@ -105,8 +110,8 @@ def kernel_cases() -> List[KernelCase]:
 
     def paged(interpret, **kw):
         return lambda a: flash_paged_attention(
-            a, pool_k, pool_v, tables, start, start + C, block_size=bs,
-            interpret=interpret, **kw)
+            a, pool, 0, tables, start, start + C, block_size=bs,
+            num_kv_heads=KV, interpret=interpret, **kw)
 
     cases += [
         KernelCase("paged_decode", paged(False), (qd,), paged(True)),
@@ -128,20 +133,21 @@ def kernel_cases() -> List[KernelCase]:
     t8 = jnp.arange(S8, dtype=jnp.int32)[:, None]
     l8 = jnp.asarray([256, 100, 17, 256, 64, 0, 128, 200], jnp.int32)
     q8 = jax.random.normal(ks[2], (S8, 1, H8, D8), jnp.bfloat16)
-    kbf, vbf = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16)
+    pool_bf = as_pool(kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16))
+    pool_i8, scales8 = as_pool(qk8, qv8), as_pool(sk8, sv8)
 
     def linear_ref(a):
-        return flash_paged_attention(a, kbf, vbf, t8, l8, l8, block_size=bs8,
-                                     num_kv_heads=KV8, interpret=True)
+        return flash_paged_attention(a, pool_bf, 0, t8, l8, l8,
+                                     block_size=bs8, num_kv_heads=KV8,
+                                     interpret=True)
 
     cases += [
         KernelCase("paged_decode_tiles_bf16", lambda a: flash_paged_attention(
-            a, kbf, vbf, t8, l8, l8, block_size=bs8, num_kv_heads=KV8,
+            a, pool_bf, 0, t8, l8, l8, block_size=bs8, num_kv_heads=KV8,
             interpret=False), (q8,), linear_ref),
         KernelCase("paged_decode_tiles_int8", lambda a: flash_paged_attention(
-            a, qk8, qv8, t8, l8, l8, block_size=bs8, num_kv_heads=KV8,
-            k_scales=sk8, v_scales=sv8, interpret=False), (q8,), linear_ref,
-            atol=6e-2),
+            a, pool_i8, 0, t8, l8, l8, block_size=bs8, num_kv_heads=KV8,
+            scales=scales8, interpret=False), (q8,), linear_ref, atol=6e-2),
     ]
     # the same pool as 16 half blocks, two a sequence, never adjacent
     tb2 = jnp.asarray(np.random.RandomState(2).permutation(S8 * 2)
@@ -150,19 +156,18 @@ def kernel_cases() -> List[KernelCase]:
 
     def paged_ring(interpret):
         return lambda a: flash_paged_attention(
-            a, kbf, vbf, tb2, l8 + 2, l8, block_size=bs8 // 2,
-            num_kv_heads=KV8, ring_full=ring8, ring_layer=0,
+            a, pool_bf, 0, tb2, l8 + 2, l8, block_size=bs8 // 2,
+            num_kv_heads=KV8, ring=ring8,
             ring_count=jnp.asarray(3, jnp.int32), interpret=interpret)
 
     cases.append(KernelCase("paged_decode_tiles_ring", paged_ring(False),
                             (q8,), paged_ring(True)))
-    kn, vn = kbf[:, :64], vbf[:, :64]
     qn = jax.random.normal(ks[2], (S8, 1, 8, 64), jnp.bfloat16)
 
     def narrow(interpret):
         return lambda a: flash_paged_attention(
-            a, kn, vn, t8, l8, l8, block_size=bs8, num_kv_heads=1,
-            interpret=interpret)
+            a, pool_bf[..., :64], 0, t8, l8, l8, block_size=bs8,
+            num_kv_heads=1, interpret=interpret)
 
     cases.append(KernelCase("paged_decode_linear_64wide", narrow(False),
                             (qn,), narrow(True)))
@@ -175,12 +180,11 @@ def kernel_cases() -> List[KernelCase]:
     st = jnp.maximum(l8 - 8, 0)
     cases.append(KernelCase(
         "paged_prefill_int8", lambda a: flash_paged_attention(
-            a, qk8[:slots_p], qv8[:slots_p], tb, st, l8,
+            a, pool_i8[:, :, :slots_p], 0, tb, st, l8,
             block_size=bs8 // 2, num_kv_heads=KV8,
-            k_scales=sk8[:, :slots_p], v_scales=sv8[:, :slots_p],
-            interpret=False), (qp,),
+            scales=scales8[..., :slots_p], interpret=False), (qp,),
         lambda a: flash_paged_attention(
-            a, kbf[:slots_p], vbf[:slots_p], tb, st, l8,
+            a, pool_bf[:, :, :slots_p], 0, tb, st, l8,
             block_size=bs8 // 2, num_kv_heads=KV8, interpret=True),
         atol=6e-2))
 
