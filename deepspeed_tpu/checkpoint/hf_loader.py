@@ -559,6 +559,7 @@ SPECIAL_HANDLERS = {
     "mixtral": _mixtral_experts,
     "qwen2_moe": _qwen2_moe_experts,
     "olmoe": _qwen2_moe_experts,     # the same per-expert names
+    "mellum": _qwen2_moe_experts,    # assumed: Qwen3MoE's (as its config keys)
 }
 
 _MOE_STACKED_RULES = [
@@ -691,6 +692,9 @@ ARCH_MAPS["kimi_linear"] = _KIMI_LINEAR_MAP
 ARCH_MAPS["mixtral"] = _MIXTRAL_MAP
 ARCH_MAPS["qwen2_moe"] = _QWEN2_MOE_MAP
 ARCH_MAPS["olmoe"] = _OLMOE_MAP
+# assumed names (Qwen3MoE's, whose config keys the family shares letter
+# for letter): OLMoE's map; the q / k norm scales are then a head wide
+ARCH_MAPS["mellum"] = _OLMOE_MAP
 
 
 def _fw_path(template: str, groups: Tuple[str, ...]) -> str:

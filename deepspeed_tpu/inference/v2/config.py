@@ -361,12 +361,17 @@ class RaggedInferenceConfig(ConfigModel):
         # either refuses here, by name; a model with both kinds of layer
         # gives both reasons
         why = []
-        recurrent = [k for k in kinds if k not in ("attn", "mla", None)]
+        recurrent = [k for k in kinds
+                     if k not in ("attn", "mla", "swa", None)]
         if recurrent:
             why.append(functools.partial(stateful_refusal,
                                          kind=recurrent[0]))
         if "mla" in kinds:
             why.append(latent_refusal)
+        if "swa" in kinds:
+            # a sliding-window layer's rows live in a slot of the window
+            # pool, which no block, manifest, shard or scale covers
+            why.append(windowed_refusal)
         for on, feature in (
                 (self.prefix_cache, "prefix_cache"),
                 (self.spec_decode != "off", "spec_decode"),
@@ -459,3 +464,13 @@ def latent_refusal(feature: str) -> str:
             f"('mla') attention layers: its cache keeps one plane a layer "
             f"(the latent row is key and value at once), and this path "
             f"has not been carried over that plane yet")
+
+
+def windowed_refusal(feature: str) -> str:
+    """The one wording of every refusal a model with sliding-window
+    ('swa') layers makes: the feature that cannot run over the rows its
+    window layers keep in a sequence slot of the window pool."""
+    return (f"{feature} is not supported for a model with sliding-window "
+            f"('swa') layers: their rows live in a sequence slot of the "
+            f"window pool (the last window's rows, overwritten in place), "
+            f"which no block, manifest, shard or scale carries yet")

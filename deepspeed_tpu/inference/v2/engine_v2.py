@@ -44,7 +44,7 @@ from ..config import InferenceConfig
 from .config import RaggedInferenceConfig
 from .drain import (EngineDrainingError, ReplayJournal, ServeDrainError,
                     ServeStepError, build_manifest, write_manifest)
-from .kv_cache import BlockedKVCache
+from .kv_cache import BlockedKVCache, window_step_rows
 from .kv_write import runs_issued
 from .model_runner import GPT2RaggedRunner, RaggedBatch
 from .sampling import SamplingParams, stage_slot
@@ -246,13 +246,20 @@ class InferenceEngineV2:
             self.config, self.runner.kv_layers, self.runner.kv_heads,
             self.runner.head_dim, dtype=resolve_dtype(self.config.dtype),
             state_spec=self.runner.state_spec,
-            planes=self.runner.kv_planes)
+            planes=self.runner.kv_planes,
+            window_spec=self.runner.window_spec)
         #: layer kind of the model's recurrent layers (None: it has none);
         #: what needs a state snapshot refuses by this name
         self._stateful = (self.runner.state_spec or {}).get("kind")
         #: a latent-attention model's cache has one plane a layer; what
         #: has not been carried over it refuses by name
         self._latent = self.runner.kv_planes == 1
+        #: a model with sliding-window layers keeps their rows in a slot
+        #: of the window pool; what would need those rows in a block, a
+        #: manifest or a shard refuses by name
+        self._windowed = self.kv_cache.window is not None
+        #: sequences name a slot (a state row, a window-pool row)
+        self._slotted = bool(self._stateful) or self._windowed
         if self.config.ep_size > 1:
             if self.runner.tp is not None:
                 # composed ep×tp: the pool head-shards over 'model' on
@@ -380,7 +387,17 @@ class InferenceEngineV2:
             # model has no K/V rows and never runs that kernel (beside
             # recurrent layers the state_* counters fill in the same run)
             "latent_rows_live": 0, "latent_rows_fetched": 0,
-            "latent_bytes_live": 0, "mla_prefill_tokens": 0}
+            "latent_bytes_live": 0, "mla_prefill_tokens": 0,
+            # models with sliding-window layers, a layer's worth each:
+            # the settled rows of its window ONE window layer must read
+            # for the live sequences per pure-decode step (and per step
+            # of a fused loop, whose own rows ride the ring), at most the
+            # window; the rows the decode kernel streams for them in
+            # whole copy tiles; the bytes the live rows are over all
+            # window layers. decode_kv_rows_* and kv_bytes_live keep
+            # their meaning over such a model's FULL layers
+            "window_rows_live": 0, "window_rows_fetched": 0,
+            "window_bytes_live": 0}
         #: rows the decode kernel this model runs streams for a sequence
         #: of so many settled tokens (each kernel module's own arithmetic)
         if self._latent:
@@ -389,14 +406,19 @@ class InferenceEngineV2:
                 decode_rows_fetched, block_size=self.config.block_size)
         else:
             from ...ops.kernels import decode_rows_fetched, decode_tile_rows
+            #: the tile the decode kernel copies by, over either pool
+            self._window_tile = decode_tile_rows(
+                self.config.block_size,
+                self.runner.local_kv_heads * self.runner.head_dim,
+                1 if self.config.kv_cache_dtype == "int8"
+                else np.dtype(resolve_dtype(self.config.dtype)).itemsize)
             self._kv_rows_fetched = functools.partial(
-                decode_rows_fetched,
-                tile_rows=decode_tile_rows(
-                    self.config.block_size,
-                    self.runner.local_kv_heads * self.runner.head_dim,
-                    1 if self.config.kv_cache_dtype == "int8"
-                    else np.dtype(resolve_dtype(self.config.dtype)).itemsize),
-                window=getattr(model_cfg, "sliding_window", None))
+                decode_rows_fetched, tile_rows=self._window_tile,
+                # one window for every layer, kept whole in the paged pool;
+                # a model that LISTS its window layers keeps those in the
+                # window pool and these counters over its full layers
+                window=None if self._windowed
+                else getattr(model_cfg, "sliding_window", None))
         #: bytes of one token's latent rows over all layers, as the model
         #: states them (the stored row's zero tail left out)
         self._latent_token_bytes = self.runner.kv_layers \
@@ -1168,30 +1190,58 @@ class InferenceEngineV2:
         also what has not been carried over a latent-attention model's
         one-plane cache (config.latent_refusal); a model with both kinds
         of layer gives both reasons."""
-        from .config import latent_refusal, stateful_refusal
+        from .config import (latent_refusal, stateful_refusal,
+                             windowed_refusal)
         why = []
         if self._stateful:
             why.append(stateful_refusal(feature, self._stateful))
         if latent_too and self._latent:
             why.append(latent_refusal(feature))
+        if self._windowed:
+            why.append(windowed_refusal(feature))
         if why:
             raise NotImplementedError("; ".join(why))
 
-    def _decode_row_counts(self, runs) -> Dict[str, int]:
+    def _decode_row_counts(self, runs, in_ring: bool = False
+                           ) -> Dict[str, int]:
         """The decode kernel's row counters for ``runs``, (steps a
         sequence ran, its settled rows) pairs: ``decode_kv_rows_*`` and
         their bytes over K/V planes, ``latent_rows_*`` and theirs over a latent
-        plane (a layer's worth each; one pair a model, never both)."""
+        plane (a layer's worth each; one pair a model, never both), and
+        ``window_rows_*`` beside the first pair for a model with
+        sliding-window layers. ``in_ring``: the runs are a fused loop's,
+        whose own tokens ride its ring (a step's query then stands ``t``
+        rows past the settled ones; a single step's own row is settled
+        before the kernel runs)."""
         live = sum(ran * rows for ran, rows in runs)
         fetched = sum(ran * self._kv_rows_fetched(rows)
                       for ran, rows in runs)
-        if not self._latent:
-            return {"decode_kv_rows_live": live,
-                    "decode_kv_rows_fetched": fetched,
-                    "kv_bytes_live": live
-                    * self.kv_cache.kv_bytes_per_token()}
-        return {"latent_rows_live": live, "latent_rows_fetched": fetched,
-                "latent_bytes_live": live * self._latent_token_bytes}
+        if self._latent:
+            return {"latent_rows_live": live, "latent_rows_fetched": fetched,
+                    "latent_bytes_live": live * self._latent_token_bytes}
+        out = {"decode_kv_rows_live": live,
+               "decode_kv_rows_fetched": fetched,
+               "kv_bytes_live": live * self.kv_cache.kv_bytes_per_token()}
+        if self._windowed and runs:
+            # step t of a run reads the settled rows its window still
+            # reaches, rows - max(rows + t - window + 1, 0), in whole
+            # tiles from the tile that holds the first of them (the
+            # kernel's own start tile, paged_attention.decode_rows_fetched)
+            spec, ts = self.runner.window_spec, self._window_tile
+            ran = np.asarray([r for r, _ in runs], np.int64)[:, None]
+            rows = np.asarray([n for _, n in runs], np.int64)[:, None]
+            t = np.arange(int(ran.max()), dtype=np.int64)[None, :]
+            alive = t < ran
+            first = np.maximum(rows + t - (0 if in_ring else 1)
+                               - spec["window"] + 1, 0)
+            wlive = int((np.maximum(rows - first, 0) * alive).sum())
+            out.update(
+                window_rows_live=wlive,
+                window_rows_fetched=int((np.maximum(
+                    -(-rows // ts) - first // ts, 0) * ts * alive).sum()),
+                window_bytes_live=wlive
+                * self.kv_cache.window_bytes_per_row())
+        return out
 
     def _kv_write_counts(self, stores, n: int) -> Dict[str, int]:
         """The pool writer's counters for ``stores``, (first position, real
@@ -1528,6 +1578,13 @@ class InferenceEngineV2:
                 raise ValueError(
                     f"{len(batch_uids)} uids but {len(first_tokens)} "
                     f"first_tokens")
+            if self._windowed and n > window_step_rows(cfg):
+                # a longer flush would overwrite rows the next step's
+                # window still reaches
+                raise ValueError(
+                    f"decode_batch of {n} steps over a window pool sized "
+                    f"for steps of at most {window_step_rows(cfg)} rows "
+                    f"(chunk_size / decode_loop_steps)")
             seqs = []
             for uid in batch_uids:
                 seq = self.state.get(uid)
@@ -1563,7 +1620,7 @@ class InferenceEngineV2:
             active = np.zeros((S,), np.int32)
             tables = np.zeros((S, MAXB), np.int32)
             # idle rows point at the state pool's idle row
-            sslots = np.full((S,), S, np.int32) if self._stateful else None
+            sslots = np.full((S,), S, np.int32) if self._slotted else None
             for i, (seq, t0) in enumerate(zip(seqs, first_tokens)):
                 tok0[i] = t0
                 start[i] = seq.seen_tokens
@@ -1605,7 +1662,8 @@ class InferenceEngineV2:
             stats = self.pipeline_stats
             for key, val in self._decode_row_counts([
                     (int(consumed[i]) if consumed is not None else n,
-                     seq.seen_tokens) for i, seq in enumerate(seqs)]).items():
+                     seq.seen_tokens) for i, seq in enumerate(seqs)],
+                    in_ring=True).items():
                 stats[key] += val
             if self._stateful:
                 ran = n * len(seqs) if consumed is None \
@@ -1779,7 +1837,7 @@ class InferenceEngineV2:
                         for item in sched)
             has_feed = False
             sslots = np.full((S,), cfg.max_seqs, np.int32) \
-                if self._stateful else None
+                if self._slotted else None
             for i, item in enumerate(sched):
                 seq = item.seq
                 if sslots is not None:
@@ -1814,7 +1872,7 @@ class InferenceEngineV2:
                            prefill_tokens_planned=S * C, prefill_steps=1,
                            prefill_rows=sum(len(item.tokens) > 1
                                             for item in sched))
-                if sslots is not None:
+                if self._stateful:
                     spec = self.runner.state_spec
                     span.count(
                         linear_attn_prefill_tokens=real,
@@ -1833,7 +1891,7 @@ class InferenceEngineV2:
                 lens = [item.start_pos + 1 for item in sched]
                 span.count(decode_slots_live=real, decode_slots_planned=S,
                            **self._decode_row_counts([(1, n) for n in lens]))
-                if sslots is not None:
+                if self._stateful:
                     span.count(state_slots_live=real,
                                state_bytes_live=real
                                * self.kv_cache.state_bytes_per_slot(),

@@ -119,17 +119,45 @@ class _HostRef:
         self.batch.drop(self.index)
 
 
+def window_blocks(window: int, cfg: RaggedInferenceConfig) -> int:
+    """R: the blocks a sequence's slot owns in the window pool, the ONE
+    place it is derived. A window layer keeps logical block ``b`` in the
+    slot's block ``b % R``, so row ``j`` is overwritten by row ``j + R x
+    block_size``. Every program stores its rows BEFORE it reads (a
+    prefill chunk, a decode step) or after its last read (the fused
+    loop's flush), so the oldest row a query at position ``i`` may need,
+    ``i - window + 1``, must outlive the newest row of its own step: ``R
+    x block_size >= window - 1 + n`` for the most rows ``n`` a step
+    stores, a prefill chunk's or a flush's. Exact for every start
+    position, aligned to a block or not: the kernels fetch whole tiles
+    through the table and mask by POSITION, so a fetched row that has
+    been overwritten (it lies below every query's window) never reaches
+    a score."""
+    return -(-(window - 1 + window_step_rows(cfg)) // cfg.block_size)
+
+
+def window_step_rows(cfg: RaggedInferenceConfig) -> int:
+    """The most rows one program stores into a slot of the window pool: a
+    prefill chunk's or a fused loop's flush (what ``window_blocks`` sizes
+    a slot for, and what ``decode_batch`` holds a caller's ``n`` to)."""
+    return max(cfg.effective_chunk, int(cfg.decode_loop_steps), 1)
+
+
 class BlockedKVCache:
     def __init__(self, cfg: RaggedInferenceConfig, num_layers: int,
                  kv_heads: int, head_dim: int, dtype: Any = None,
-                 state_spec: Optional[dict] = None, planes: int = 2):
+                 state_spec: Optional[dict] = None, planes: int = 2,
+                 window_spec: Optional[dict] = None):
         """``num_layers`` counts the layers that keep K/V (the softmax
         layers of a hybrid model, every layer otherwise). ``state_spec``
         (``RaggedRunnerBase.state_spec``) asks for the per-sequence state
         pool of a model with recurrent layers beside the paged planes.
         ``planes`` is how many planes a layer keeps: K and V, or the ONE
         plane of a latent-attention layer, whose row (``kv_heads`` 1,
-        ``head_dim`` the stored row) is key and value at once."""
+        ``head_dim`` the stored row) is key and value at once.
+        ``window_spec`` (``RaggedRunnerBase.window_spec``: ``layers`` and
+        ``window``) asks for the window pool of a model with
+        sliding-window layers, which ``num_layers`` then leaves out."""
         self.cfg = cfg
         self.planes = planes
         self.num_layers = num_layers
@@ -196,6 +224,20 @@ class BlockedKVCache:
             self.conv = jnp.zeros(
                 pool_shape(state_spec["layers"], rows, state_spec["taps"],
                            state_spec["conv_width"]), self.dtype)
+        # the window pool: the paged pool's own form over the
+        # sliding-window layers, R blocks a sequence slot + the idle
+        # slot's (padding rows of a batch point there; its last block is
+        # this pool's trash block). A slot's table is a function of the
+        # slot (model_runner.window_tables): nothing is allocated, freed
+        # or fragmented, and the allocator never sees this pool
+        self.window = None
+        self.window_blocks = 0
+        if window_spec is not None:
+            self.window_blocks = window_blocks(window_spec["window"], cfg)
+            self.window = jnp.zeros(
+                (window_spec["layers"], 2,
+                 (cfg.max_seqs + 1) * self.window_blocks * cfg.block_size,
+                 kv_heads * head_dim), self.dtype)
 
     def pin(self, device) -> None:
         """COMMIT the pool to ``device`` (a one-device engine pins itself
@@ -210,6 +252,8 @@ class BlockedKVCache:
         if self.state is not None:
             self.state = jax.device_put(self.state, device)
             self.conv = jax.device_put(self.conv, device)
+        if self.window is not None:
+            self.window = jax.device_put(self.window, device)
 
     @property
     def pool(self):
@@ -217,10 +261,13 @@ class BlockedKVCache:
         scales travel together through the jitted steps) or when the
         model has recurrent layers (the state pool travels with the
         planes), else the raw data array (byte-identical to the pre-int8
-        path)."""
-        if self.quantized or self.state is not None:
+        path). The window pool of a model with sliding-window layers
+        travels in it too."""
+        if self.quantized or self.state is not None \
+                or self.window is not None:
             from .kv_quant import KVPool
-            return KVPool(self.data, self.scales, self.state, self.conv)
+            return KVPool(self.data, self.scales, self.state, self.conv,
+                          self.window)
         return self.data
 
     def attach_prefix_cache(self, prefix: PrefixCache) -> None:
@@ -559,11 +606,13 @@ class BlockedKVCache:
         n = self.data.size * self.data.dtype.itemsize
         if self.scales is not None:
             n += self.scales.size * self.scales.dtype.itemsize
-        return n + self.state_bytes_per_slot() * (self.cfg.max_seqs + 1)
+        return n + (self.state_bytes_per_slot()
+                    + self.window_bytes_per_slot()) * (self.cfg.max_seqs + 1)
 
     def kv_bytes_per_token(self) -> int:
         """Bytes one token holds in the paged planes as stored, over all
-        layers (a latent row's zero tail included; scales left out)."""
+        layers that keep their whole chain there (a latent row's zero
+        tail included; scales and the window pool left out)."""
         L, planes, _, row = self.data.shape
         return L * planes * row * self.data.dtype.itemsize
 
@@ -575,6 +624,20 @@ class BlockedKVCache:
         return sum(a.size * a.dtype.itemsize // a.shape[0]
                    for a in self.state) \
             + self.conv.size * self.conv.dtype.itemsize // self.conv.shape[1]
+
+    def window_bytes_per_row(self) -> int:
+        """Bytes one position holds in the window pool over all
+        sliding-window layers, K and V (0 without any)."""
+        if self.window is None:
+            return 0
+        layers, planes, _, row = self.window.shape
+        return layers * planes * row * self.window.dtype.itemsize
+
+    def window_bytes_per_slot(self) -> int:
+        """Bytes of the window pool one sequence slot owns over all
+        sliding-window layers (0 without any)."""
+        return self.window_bytes_per_row() * self.window_blocks \
+            * self.cfg.block_size
 
     def memory_bytes_per_chip(self) -> int:
         """Bytes one chip actually holds, read from the device sharding
@@ -591,8 +654,10 @@ class BlockedKVCache:
         n = per_chip(self.data)
         if self.scales is not None:
             n += per_chip(self.scales)
-        # the state pool is never sharded (recurrent models refuse meshes)
-        return n + self.state_bytes_per_slot() * (self.cfg.max_seqs + 1)
+        # the state and window pools are never sharded (models with
+        # recurrent or sliding-window layers refuse meshes)
+        return n + (self.state_bytes_per_slot()
+                    + self.window_bytes_per_slot()) * (self.cfg.max_seqs + 1)
 
     # ------------------- host offload / restore ----------------------- #
     # Reference parity: BlockedKVCache.offload/restore

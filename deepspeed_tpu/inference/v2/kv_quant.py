@@ -42,11 +42,18 @@ class KVPool(NamedTuple):
     array a recurrent layer (a transposed delta-rule state a sequence
     slot), and ``conv`` [Ls, max_seqs + 1, K - 1, width], the short
     convolution's last inputs; the last row of both is the idle row that
-    padding rows of a batch point at."""
+    padding rows of a batch point at. A model with sliding-window layers
+    (``layer_kinds`` with "swa") carries ``window``, a second pool of
+    ``data``'s own form [Lw, 2, (max_seqs + 1) * R * block_size, KV*D]
+    over those layers alone: a sequence's slot (the one the state rows
+    go by) owns R whole blocks of it for its life, logical block ``b``
+    of the sequence lives in the slot's block ``b % R``, and the last
+    slot is the idle one (kv_cache.py has R)."""
     data: Any
     scales: Optional[Any] = None
     state: Optional[Any] = None
     conv: Optional[Any] = None
+    window: Optional[Any] = None
 
 
 class RingKV(NamedTuple):
@@ -67,15 +74,20 @@ class RingKV(NamedTuple):
     lin: Any = None
 
 
-def pool_parts(kv) -> Tuple[Any, Optional[Any]]:
-    """(data, scales) view of a pool that may be a KVPool or a raw array."""
+def pool_parts(kv, window: bool = False) -> Tuple[Any, Optional[Any]]:
+    """(data, scales) view of a pool that may be a KVPool or a raw array;
+    with ``window`` the window pool (never quantized) in the same form."""
+    if window:
+        return kv.window, None
     if isinstance(kv, KVPool):
         return kv.data, kv.scales
     return kv, None
 
 
-def repack(kv, data, scales):
+def repack(kv, data, scales, window: bool = False):
     """Rebuild the caller's pool type from updated parts."""
+    if window:
+        return kv._replace(window=data)
     if isinstance(kv, KVPool):
         return kv._replace(data=data, scales=scales)
     return data

@@ -2,7 +2,8 @@
 
 Every path that stores rows (a step's K/V or latent rows, the fused
 loop's ring at flush, the ``seq``-sharded exchange) calls
-:func:`store_rows`. A sequence's rows are consecutive positions, so
+:func:`store_rows`, over the paged pool or, for a sliding-window layer,
+over the window pool (the same form, the same writer, another table). A sequence's rows are consecutive positions, so
 inside a block they are consecutive pool slots: with the static tile
 ``t = gcd(n, block_size)`` the positions are cut at multiples of ``t``
 and every piece lies in ONE block, one contiguous window of ``t`` rows.
@@ -123,11 +124,16 @@ def _put(x, index, windows):
         mode=_IN_BOUNDS)
 
 
-def store_rows(kv, li, rows, plan: WritePlan, kv_heads=1):
+def store_rows(kv, li, rows, plan: WritePlan, kv_heads=1,
+               window: bool = False):
     """``kv`` with ``rows`` [P, S, n, W] stored in layer ``li`` at
     ``plan``'s windows. Over an int8 pool the rows are quantized a (row,
-    kv head) (``quantize_rows``) and their scales stored beside them."""
-    data, scales = pool_parts(kv)
+    kv head) (``quantize_rows``) and their scales stored beside them.
+    ``window``: into the window pool of a model with sliding-window
+    layers, at a plan made over that pool's shape and the slots' tables
+    (a run may wrap to the slot's first block: a window of ``t`` rows
+    lies in one logical block, so it never straddles the wrap)."""
+    data, scales = pool_parts(kv, window)
     P, S, n, W = rows.shape
     K, t = plan.real.shape[1:]
     windows = data.shape[2] // t
@@ -159,4 +165,5 @@ def store_rows(kv, li, rows, plan: WritePlan, kv_heads=1):
     if t > 1:
         # rows that are not the step's keep what the window holds
         src = jnp.where(plan.real[None, ..., None], src, _take(tiled, index))
-    return repack(kv, _put(tiled, index, src).reshape(data.shape), scales)
+    return repack(kv, _put(tiled, index, src).reshape(data.shape), scales,
+                  window)

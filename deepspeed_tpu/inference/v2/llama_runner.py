@@ -2,8 +2,10 @@
 hybrid Solar-Open2 family whose layers follow a per-layer list of mixers,
 the latent-attention openPangu-Ultra-MoE family, whose feed-forward
 kind follows a per-layer list too, Kimi-Linear, whose layer list holds
-recurrent AND latent layers, and Nemotron-H, whose layers are a mixer ALONE
-or a feed-forward ALONE and whose recurrent layers are state-space ones).
+recurrent AND latent layers, Nemotron-H, whose layers are a mixer ALONE
+or a feed-forward ALONE and whose recurrent layers are state-space ones,
+and Mellum, whose softmax layers are sliding-window or full by the list,
+each kind with its own position code and its own pool).
 
 Analogue of the reference's llama_v2 / mistral / mixtral v2 containers
 (``inference/v2/model_implementations/{llama_v2,mistral,mixtral}/``): RoPE
@@ -350,12 +352,18 @@ def _mla_mixer(p, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
 
 
 def _attn_mixer(pa, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
-                pos, valid_q, dtype):
+                pos, valid_q, dtype, kind: str = "attn", ring_layer=None):
     """One softmax-attention layer over plane ``plane`` of the paged
-    cache: projections (qkv bias, whole-projection QK-norm), rotary
-    positions unless the family has none (``use_rope`` false: no position
-    code at all), paged attention, the family's elementwise sigmoid
-    output gate if it has one, the output projection. Returns (kv, y)."""
+    cache: projections (qkv bias, QK-norm over the whole projection or a
+    head by ``qk_norm``'s value), rotary positions unless the family has
+    none (``use_rope`` false: no position code at all), paged attention,
+    the family's elementwise sigmoid output gate if it has one, the
+    output projection. What the layer's ``kind`` says of it: a ``"swa"``
+    layer of a model that lists such layers attends inside the model's
+    ``sliding_window``, over plane ``plane`` of the WINDOW pool, in its
+    own region ``attn_window``; and a model that gives its kinds their
+    own rotary code (``rope_of``) has the code as a table here. Returns
+    (kv, y)."""
     S, C, _ = h.shape
     H, KV, D = model_cfg.num_heads, model_cfg.num_kv_heads, model_cfg.head_dim
     q = woq_mm(h, pa["q_proj"]["kernel"], dtype)
@@ -365,20 +373,33 @@ def _attn_mixer(pa, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
         q = q + pa["q_proj"]["bias"].astype(dtype)
         k = k + pa["k_proj"]["bias"].astype(dtype)
         v = v + pa["v_proj"]["bias"].astype(dtype)
-    if model_cfg.qk_norm:
+    per_head = model_cfg.qk_norm == "head"
+    if model_cfg.qk_norm and not per_head:
         q = _rms(q, pa["q_norm"]["scale"], model_cfg.rms_eps).astype(dtype)
         k = _rms(k, pa["k_norm"]["scale"], model_cfg.rms_eps).astype(dtype)
     q = q.reshape(S, C, H, D)
     k = k.reshape(S, C, KV, D)
     v = v.reshape(S, C, KV, D)
+    if per_head:
+        q = _rms(q, pa["q_norm"]["scale"], model_cfg.rms_eps).astype(dtype)
+        k = _rms(k, pa["k_norm"]["scale"], model_cfg.rms_eps).astype(dtype)
     if getattr(model_cfg, "use_rope", True):
-        q = apply_rope(q, pos, model_cfg.rope_theta)
-        k = apply_rope(k, pos, model_cfg.rope_theta)
+        code = model_cfg.rope_of(kind) if hasattr(model_cfg, "rope_of") \
+            else ()
+        q = apply_rope(q, pos, model_cfg.rope_theta, *code)
+        k = apply_rope(k, pos, model_cfg.rope_theta, *code)
 
-    with region("attn_core"):
-        kv, y = paged_attention(kv, plane, q, k, v, batch, cfg, pos, valid_q,
-                                1.0 / (D ** 0.5), dtype,
-                                sliding_window=model_cfg.sliding_window)
+    # a model WITHOUT "swa" layers may still have one window for every
+    # layer (``LlamaConfig.sliding_window``, Mistral's), kept whole in the
+    # paged pool; one WITH them has it on those layers alone
+    windowed = kind == "swa"
+    listed = "swa" in (getattr(model_cfg, "layer_kinds", None) or ())
+    window = model_cfg.sliding_window if windowed or not listed else None
+    with region("attn_window" if windowed else "attn_core"):
+        kv, y = paged_attention(
+            kv, plane, q, k, v, batch, cfg, pos, valid_q, 1.0 / (D ** 0.5),
+            dtype, sliding_window=window, window_pool=windowed,
+            ring_layer=ring_layer)
     if getattr(model_cfg, "attn_gate", False):
         gate = woq_mm(h, pa["g_proj"]["kernel"], dtype)
         y = (y.astype(jnp.float32)
@@ -412,8 +433,8 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
     # one step function for every family: the layer lists say which mixer
     # and which feed-forward a layer runs, or that it has none (a layer of
     # one branch has that branch's norm and one residual add); softmax and
-    # latent layers take the cache's planes in order and recurrent layers
-    # the state pool's
+    # latent layers take the cache's planes in order, recurrent layers
+    # the state pool's and sliding-window layers the window pool's
     kinds = getattr(model_cfg, "layer_kinds", None) \
         or ("attn",) * model_cfg.num_layers
     ffn_kinds = getattr(model_cfg, "ffn_kinds", None) \
@@ -421,7 +442,7 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
     # a norm on each branch's OUTPUT too, before the residual add
     sandwich = getattr(model_cfg, "sandwich_norm", False)
     act = _mlp_act(model_cfg)
-    plane = si = 0
+    plane = si = wplane = 0
     for li, (kind, ffn) in enumerate(zip(kinds, ffn_kinds)):
         p = params[f"layer_{li}"]
         if kind is not None:
@@ -444,10 +465,17 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
                                        model_cfg, cfg, pos, valid_q, dtype)
                 plane += 1
             else:
+                # "attn" or "swa": one mixer, the kind says which pool's
+                # plane; in a model that lists "swa" layers the fused
+                # loop's ring holds both kinds' rows in the model's order
+                swa = kind == "swa"
                 with region("attn_proj"):
-                    kv, y = _attn_mixer(p["attn"], h, kv, plane, batch,
-                                        model_cfg, cfg, pos, valid_q, dtype)
-                plane += 1
+                    kv, y = _attn_mixer(
+                        p["attn"], h, kv, wplane if swa else plane, batch,
+                        model_cfg, cfg, pos, valid_q, dtype, kind,
+                        ring_layer=plane + wplane if "swa" in kinds
+                        else None)
+                wplane, plane = wplane + swa, plane + (not swa)
             if sandwich:
                 with region("norm"):
                     y = _rms(y, p["attn_branch_norm"]["scale"],

@@ -100,17 +100,48 @@ class RaggedBatch(NamedTuple):
     # programs make it once for all layers; None (a mixer called with a
     # bare batch, as the parity tests do) = made at each store
     write_plan: Any = None
+    # a model with sliding-window layers: the table [S, MAXB] of a window
+    # layer, a FUNCTION of ``state_slots`` (``window_tables``), and the
+    # step's plan over the window pool; both made once a program, or at
+    # each use from a bare batch
+    window_tables: Any = None
+    window_plan: Any = None
 
 
-def _store_step_rows(kv, li, rows, batch, cfg, kv_heads=1, shard=None):
+def window_tables(state_slots, kv, cfg: RaggedInferenceConfig):
+    """The block table [S, MAXB] every sliding-window layer reads and
+    writes through: the sequence in slot ``s`` keeps logical block ``b``
+    in block ``s * R + b % R`` of the window pool ``kv.window``, R the
+    blocks a slot owns (``kv_cache.window_blocks``, read back from the
+    pool's shape). Nothing is allocated: the table is arithmetic on the
+    slot, so it is built where the step's other tables arrive."""
+    R = kv.window.shape[2] // (cfg.block_size * (cfg.max_seqs + 1))
+    b = jnp.arange(cfg.max_blocks_per_seq, dtype=jnp.int32)
+    return state_slots[:, None].astype(jnp.int32) * R + (b % R)[None, :]
+
+
+def _tables(batch, kv, cfg, window: bool):
+    """The table a layer goes by: the allocator's, or the slot's."""
+    if not window:
+        return batch.block_tables
+    if batch.window_tables is not None:
+        return batch.window_tables
+    return window_tables(batch.state_slots,
+                         kv.pool if isinstance(kv, RingKV) else kv, cfg)
+
+
+def _store_step_rows(kv, li, rows, batch, cfg, kv_heads=1, shard=None,
+                     window: bool = False):
     """This step's fresh ``rows`` [P, S, C, W] into layer ``li`` of the
-    pool: the one writer (kv_write.py), at the batch's plan."""
-    plan = batch.write_plan
+    pool (of the window pool with ``window``): the one writer
+    (kv_write.py), at the batch's plan."""
+    plan = batch.window_plan if window else batch.write_plan
     if plan is None:
         plan = write_plan(batch.start_pos, batch.n_tokens,
-                          batch.block_tables, rows.shape[2], cfg.block_size,
-                          pool_parts(kv)[0].shape, shard)
-    return store_rows(kv, li, rows, plan, kv_heads)
+                          _tables(batch, kv, cfg, window), rows.shape[2],
+                          cfg.block_size, pool_parts(kv, window)[0].shape,
+                          shard)
+    return store_rows(kv, li, rows, plan, kv_heads, window)
 
 
 # --------------------------------------------------------------------- #
@@ -204,14 +235,17 @@ def _layer_norm(x, p, eps=1e-5):   # GPT2Config.layer_norm_eps default
     return (x - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
 
 
-def _gather_ctx(pool, li, batch, cfg, S, KV, D, dtype):
-    """[S, max_context, KV, D] context gathered through the block tables.
-    A quantized KVPool is dequantized per gathered row (dense/debug path
-    only — the Pallas kernel scales scores/probabilities instead)."""
-    data, scales = pool_parts(pool)
+def _gather_ctx(pool, li, batch, cfg, S, KV, D, dtype, window=False):
+    """[S, max_context, KV, D] context gathered through the block tables
+    (``window``: a sliding-window layer's, through the slots' tables out
+    of the window pool; a position whose row has been overwritten lies
+    below the window and is masked by the caller). A quantized KVPool is
+    dequantized per gathered row (dense/debug path only — the Pallas
+    kernel scales scores/probabilities instead)."""
+    data, scales = pool_parts(pool, window)
     bs = cfg.block_size
     j = jnp.arange(cfg.max_context, dtype=jnp.int32)
-    ctx_idx = batch.block_tables[:, j // bs] * bs + j % bs
+    ctx_idx = _tables(batch, pool, cfg, window)[:, j // bs] * bs + j % bs
     k_ctx = data[li, 0][ctx_idx].reshape(S, -1, KV, D)
     v_ctx = data[li, 1][ctx_idx].reshape(S, -1, KV, D)
     if scales is None:
@@ -249,17 +283,20 @@ def _grouped_dense_attention(q, k_ctx, v_ctx, mask, dist, scale, dtype,
 
 def _dense_ring_attention(pool, ring, li, q, batch, cfg, settled_lens,
                           rcount, scale, dtype, alibi_slopes,
-                          sliding_window):
+                          sliding_window, window, ring_layer):
     """Ring-mode attention without the Pallas kernel (off-TPU path): the
     gathered settled context and the ring concatenate along the context
-    axis, with the settled part masked column-exactly at settled_lens."""
+    axis, with the settled part masked column-exactly at settled_lens.
+    ``window``: layer ``li`` of the window pool; ``ring_layer``: its row
+    of the ring."""
     S, C, H, D = q.shape
     KV = ring.shape[4] // D
     T = cfg.max_context
-    k_ctx, v_ctx = _gather_ctx(pool, li, batch, cfg, S, KV, D, dtype)
+    k_ctx, v_ctx = _gather_ctx(pool, li, batch, cfg, S, KV, D, dtype,
+                               window)
     R = ring.shape[0]
-    ring_k = jnp.moveaxis(ring[:, li, 0], 0, 1).reshape(S, R, KV, D)
-    ring_v = jnp.moveaxis(ring[:, li, 1], 0, 1).reshape(S, R, KV, D)
+    ring_k = jnp.moveaxis(ring[:, ring_layer, 0], 0, 1).reshape(S, R, KV, D)
+    ring_v = jnp.moveaxis(ring[:, ring_layer, 1], 0, 1).reshape(S, R, KV, D)
     k_full = jnp.concatenate([k_ctx, ring_k.astype(dtype)], axis=1)
     v_full = jnp.concatenate([v_ctx, ring_v.astype(dtype)], axis=1)
     # columns: [0, T) settled (valid below settled_lens), [T, T+R) ring
@@ -489,7 +526,8 @@ def _attention_impl(cfg: RaggedInferenceConfig) -> str:
 
 def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
                     cfg: RaggedInferenceConfig, pos, valid_q, scale, dtype,
-                    alibi_slopes=None, sliding_window=None):
+                    alibi_slopes=None, sliding_window=None,
+                    window_pool: bool = False, ring_layer=None):
     """Append this step's K/V through the block tables, then attend.
 
     Shared by every ragged runner. q: [S, C, H, D]; k/v: [S, C, KV, D]
@@ -510,6 +548,15 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
     the TPU scatter slow path), attended by the kernel's ring round. The
     runners thread ``kv`` opaquely, so every family gets the fast path.
 
+    ``window_pool``: layer ``li`` is a sliding-window layer of a model
+    that bounds such layers' cache: its rows live in the WINDOW pool
+    (``KVPool.window``, the same form) and nowhere else, written and read
+    through the slots' tables (``window_tables``), ``li`` its index among
+    those layers; the kernels, the writer and the masks are the paged
+    pool's. The fused loop's ring holds every attention layer's rows in
+    the model's order, so such a model names the layer's row of the ring
+    apart (``ring_layer``; ``li`` where None).
+
     Returns (kv, y[S, C, H*D] in ``dtype``).
     """
     S, C, H, D = q.shape
@@ -526,16 +573,17 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
     ring_mode = isinstance(kv, RingKV)
     if ring_mode:
         pool, ring, t, rcount = kv[:4]
-        data, scales = pool_parts(pool)
-        # ring[t, li, 0/1] <- this step's K/V: the ring is R-LEADING so the
+        data, scales = pool_parts(pool, window_pool)
+        rl = li if ring_layer is None else ring_layer
+        # ring[t, rl, 0/1] <- this step's K/V: the ring is R-LEADING so the
         # per-step write is a leading-index dynamic-update-slice (in-place
         # in the scan carry; a trailing index forced a ring copy per layer).
         # The ring stays UNQUANTIZED (compute dtype) even over an int8
         # pool — its rows are rewritten every loop and quantized at flush.
         with region("kv_write"):
-            ring = ring.at[t, li, 0].set(
+            ring = ring.at[t, rl, 0].set(
                 k.reshape(S, KV * D).astype(ring.dtype))
-            ring = ring.at[t, li, 1].set(
+            ring = ring.at[t, rl, 1].set(
                 v.reshape(S, KV * D).astype(ring.dtype))
         kv = kv._replace(ring=ring)
         settled_lens = jnp.where(batch.n_tokens > 0,
@@ -550,10 +598,12 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
             # 17 MB transposes
             y = flash_paged_attention(
                 q.astype(data.dtype if scales is None else dtype),
-                data, li, batch.block_tables, batch.start_pos, settled_lens,
+                data, li, _tables(batch, kv, cfg, window_pool),
+                batch.start_pos, settled_lens,
                 block_size=bs, num_kv_heads=KV, sm_scale=scale,
                 alibi_slopes=alibi_slopes, sliding_window=sliding_window,
-                scales=scales, ring=ring, ring_count=rcount)
+                scales=scales, ring=ring, ring_count=rcount,
+                ring_layer=ring_layer)
         elif seq_on:
             y = _seq_dense_ring_attention(
                 pool, ring, li, q, batch, cfg, settled_lens, rcount,
@@ -561,7 +611,7 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
         else:
             y = _dense_ring_attention(
                 pool, ring, li, q, batch, cfg, settled_lens, rcount,
-                scale, dtype, alibi_slopes, sliding_window)
+                scale, dtype, alibi_slopes, sliding_window, window_pool, rl)
         return kv, y.reshape(S, C, H * D).astype(dtype)
 
     if seq_on:
@@ -573,8 +623,8 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
         kv = _store_step_rows(
             kv, li,
             jnp.stack([k.reshape(S, C, KV * D), v.reshape(S, C, KV * D)]),
-            batch, cfg, KV)
-    data, scales = pool_parts(kv)
+            batch, cfg, KV, window=window_pool)
+    data, scales = pool_parts(kv, window_pool)
 
     if impl == "paged_flash":
         from ...ops.kernels import flash_paged_attention
@@ -589,13 +639,14 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
         # scales scores/probabilities by the side-array scales.
         y = flash_paged_attention(
             q.astype(data.dtype if scales is None else dtype),
-            data, li, batch.block_tables, batch.start_pos, seq_lens,
-            block_size=bs, num_kv_heads=KV, sm_scale=scale,
+            data, li, _tables(batch, kv, cfg, window_pool), batch.start_pos,
+            seq_lens, block_size=bs, num_kv_heads=KV, sm_scale=scale,
             alibi_slopes=alibi_slopes, sliding_window=sliding_window,
             scales=scales)
         return kv, y.reshape(S, C, H * D).astype(dtype)
 
-    k_ctx, v_ctx = _gather_ctx(kv, li, batch, cfg, S, KV, D, dtype)
+    k_ctx, v_ctx = _gather_ctx(kv, li, batch, cfg, S, KV, D, dtype,
+                               window_pool)
     j = jnp.arange(cfg.max_context, dtype=jnp.int32)
     dist = (pos[:, :, None] - j[None, None, :]).astype(jnp.float32)
     mask = j[None, None, :] <= pos[:, :, None]          # [S, C, T]
@@ -733,6 +784,31 @@ class RaggedRunnerBase:
         kinds = getattr(model_cfg, "layer_kinds", None) \
             or ("attn",) * self.num_layers
         self.kv_layers = sum(k in ("attn", "mla") for k in kinds)
+        #: what the window pool must hold (None: no sliding-window layer):
+        #: the layers of kind "swa" keep their rows THERE and not in the
+        #: paged pool, which serves the model's full layers alone.
+        #: ``ring_of`` says which rows of the fused loop's ring (one a
+        #: softmax layer of either kind, in the model's order) flush into
+        #: which pool
+        self.window_spec = None
+        #: rows of the fused loop's ring: one a layer that keeps K/V or a
+        #: latent row, in either pool
+        self.ring_layers = self.kv_layers + kinds.count("swa")
+        if "swa" in kinds:
+            other = sorted({k for k in kinds
+                            if k not in ("attn", "swa", None)})
+            if other:
+                raise ValueError(
+                    f"sliding-window ('swa') layers do not mix with "
+                    f"{other} layers in one model: a sequence's slot "
+                    f"names its window-pool row alone")
+            softmax = [k for k in kinds if k in ("attn", "swa")]
+            self.window_spec = {
+                "layers": kinds.count("swa"),
+                "window": int(model_cfg.sliding_window),
+                "ring_of": {
+                    w: tuple(i for i, k in enumerate(softmax)
+                             if (k == "swa") == w) for w in (False, True)}}
         #: planes a layer keeps in the paged cache: K and V, or the ONE
         #: plane of a latent-attention model, whose stored row
         #: (``latent_row`` lanes; one "kv head") is key and value at once
@@ -897,6 +973,14 @@ class RaggedRunnerBase:
                     batch.start_pos, batch.n_tokens, batch.block_tables,
                     batch.tokens.shape[1], cfg.block_size,
                     pool_parts(kv_data)[0].shape, shard))
+                if self.window_spec is not None:
+                    # and in the window pool, through the slots' tables
+                    wt = window_tables(batch.state_slots, kv_data, cfg)
+                    batch = batch._replace(
+                        window_tables=wt, window_plan=write_plan(
+                            batch.start_pos, batch.n_tokens, wt,
+                            batch.tokens.shape[1], cfg.block_size,
+                            kv_data.window.shape))
             if seqc is not None:
                 batch = batch._replace(
                     tokens=jax.lax.dynamic_slice_in_dim(
@@ -1021,8 +1105,11 @@ class RaggedRunnerBase:
             # rows are the loop's freshest tokens, rewritten every step,
             # and are quantized once at flush time. Under TP the ring —
             # like the pool — is head-sharded: local_kv_heads rows.
-            ring_shape = (n, self.kv_layers, 2, S,
+            ring_shape = (n, self.ring_layers, 2, S,
                           self.local_kv_heads * self.head_dim)
+            # a sliding-window layer's table is loop-invariant too
+            wtables = None if self.window_spec is None \
+                else window_tables(sslots, kv_data, cfg)
             if self.kv_planes == 1:
                 # a latent cache's ring: one plane, sequence-major
                 # (latent_attention), over the latent layers alone where
@@ -1062,7 +1149,8 @@ class RaggedRunnerBase:
                     tok = drafts[:, t]
                 batch = RaggedBatch(tokens=tok[:, None], start_pos=pos,
                                     n_tokens=alive, block_tables=tables,
-                                    state_slots=sslots)
+                                    state_slots=sslots,
+                                    window_tables=wtables)
                 logits, kv_out = type(self).step_fn(
                     params, RingKV(kv_data, ring, t, t + 1, moe, lin), batch,
                     model_cfg=mcfg_l, cfg=cfg, dtype=dtype)
@@ -1135,7 +1223,7 @@ class RaggedRunnerBase:
             _decode_loop_ring, donate_argnames=("lin",),
             static_argnames=("n", "mode", "cand", "eos_id", "feed"))
 
-        def _flush_ring(kv_data, ring, tables, start0, active):
+        def _flush_ring(kv_data, ring, tables, start0, active, sslots=None):
             """The loop's ring rows into the pool, through the one writer
             (kv_write.py): a sequence's R rows are R consecutive positions
             from ``start0``, whole windows whatever the layout (one block a
@@ -1143,25 +1231,47 @@ class RaggedRunnerBase:
             int8 pool they are quantized here, once (the ring itself runs
             unquantized). Under ``seq`` every chip holds the SAME ring rows
             (the loop is replicated) and stores the windows whose block it
-            owns: zero collectives, pool bytes as at seq=1."""
+            owns: zero collectives, pool bytes as at seq=1. A model with
+            sliding-window layers flushes those layers' rows of the ring
+            into the window pool, through the slots' tables (``sslots``),
+            and the others' into the paged pool: the same rows, the same
+            writer, a second plan."""
             data, scales = pool_parts(kv_data)
             latent = self.kv_planes == 1       # ring [L, 1, S, R, W]
             R = ring.shape[3 if latent else 0]     # else [R, L, 2, S, W]
             kv_heads = 1 if scales is None else scales.shape[2]
 
-            def layer(l, kv):
-                rows = jax.lax.dynamic_index_in_dim(
-                    ring, l, 0 if latent else 1, keepdims=False)
-                if not latent:
-                    rows = jnp.transpose(rows, (1, 2, 0, 3))   # [2, S, R, W]
-                return store_rows(kv, l, rows, plan, kv_heads)
+            def flush(kv, plan, layers, window=False, ring_of=None):
+                def layer(l, kv):
+                    rows = jax.lax.dynamic_index_in_dim(
+                        ring, l if ring_of is None else ring_of[l],
+                        0 if latent else 1, keepdims=False)
+                    if not latent:
+                        rows = jnp.transpose(rows, (1, 2, 0, 3))  # [2,S,R,W]
+                    return store_rows(kv, l, rows, plan, kv_heads, window)
+                return jax.lax.fori_loop(0, layers, layer, kv)
 
             with region("kv_write"):
+                count = jnp.where(active > 0, R, 0)
                 plan = write_plan(
-                    start0, jnp.where(active > 0, R, 0), tables, R,
+                    start0, count, tables, R,
                     cfg.block_size, data.shape, None if seqc is None else
                     (seqc.seq_size, jax.lax.axis_index(SEQ_AXIS)))
-                return jax.lax.fori_loop(0, data.shape[0], layer, kv_data)
+                if self.window_spec is None:
+                    return flush(kv_data, plan, data.shape[0])
+                # two loops over the one ring: XLA re-lays the carry once
+                # for them (0.54 GB at the 256-client cell, ~1 ms a round
+                # by the v5e compile's own sizes; unrolling the layers or
+                # grouping the ring's rows by pool did not remove it)
+                ring_of = {w: jnp.asarray(rows, jnp.int32) for w, rows
+                           in self.window_spec["ring_of"].items()}
+                kv_data = flush(kv_data, plan, data.shape[0],
+                                ring_of=ring_of[False])
+                wplan = write_plan(
+                    start0, count, window_tables(sslots, kv_data, cfg), R,
+                    cfg.block_size, kv_data.window.shape)
+                return flush(kv_data, wplan, kv_data.window.shape[0],
+                             window=True, ring_of=ring_of[True])
 
         if mapped:
             # all flush work is chip-local (quantize_rows is per-kv-head,
@@ -1260,8 +1370,9 @@ class RaggedRunnerBase:
             params, kv_data, lin, state_slots, tok0, start_pos, active,
             block_tables, seeds, temps, top_ks, top_ps, draft_toks,
             n=n, mode=mode, cand=int(cand), eos_id=int(eos_id), feed=feed)
-        kv_data = self._flush_ring(kv_data, ring, block_tables, start_pos,
-                                   active)
+        kv_data = self._flush_ring(
+            kv_data, ring, block_tables, start_pos, active,
+            *(() if self.window_spec is None else (state_slots,)))
         if lin is not None:
             kv_data = kv_data._replace(state=lin[0], conv=lin[1])
         return toks, (lps if mode == "sample" else None), kv_data, \

@@ -57,10 +57,15 @@ class StateManager:
         #: free rows of the recurrent state pool (None: the model has no
         #: recurrent layer). A sequence takes one with its first blocks
         #: and returns it at flush; the row's last tenant's state is
-        #: wiped by the program that runs the new tenant's position 0
+        #: wiped by the program that runs the new tenant's position 0.
+        #: A model with sliding-window layers hands out the same slots:
+        #: a slot's row of the window pool is its R blocks, whose last
+        #: tenant's rows lie past the new tenant's length until it
+        #: overwrites them, and no mask lets a row past the length in
         self.state_slots_free: Optional[List[int]] = \
             list(range(cfg.max_seqs - 1, -1, -1)) \
-            if kv_cache.state is not None else None
+            if kv_cache.state is not None or kv_cache.window is not None \
+            else None
         # scheduler clock: ONE tick per scheduler invocation (bumped by
         # the engine's plan phase — deliberately NOT the engine step
         # counter, which decode_batch advances by n per fused call and
@@ -387,8 +392,9 @@ class StateManager:
         if self.state_slots_free is not None and seq.state_slot is None:
             if not self.state_slots_free:
                 raise OutOfBlocksError(
-                    f"sequence {seq.uid}: all {self.cfg.max_seqs} recurrent "
-                    f"state rows are taken")
+                    f"sequence {seq.uid}: all {self.cfg.max_seqs} sequence "
+                    f"slots (recurrent state rows, window-pool rows) are "
+                    f"taken")
             seq.state_slot = self.state_slots_free.pop()
         need = seq.blocks_needed(n_tokens, self.cfg.block_size)
         if need:

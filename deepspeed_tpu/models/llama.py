@@ -35,10 +35,12 @@ class LlamaConfig:
     rms_eps: float = 1e-5
     sliding_window: Optional[int] = None   # mistral local attention
     qkv_bias: bool = False                 # qwen2
-    # olmoe: RMSNorm over the WHOLE q and k projections (one learned scale
-    # of the projection's width, not per head), before the split into
-    # heads and before RoPE
-    qk_norm: bool = False
+    # True (olmoe): RMSNorm over the WHOLE q and k projections (one learned
+    # scale of the projection's width, not per head), before the split
+    # into heads and before RoPE. "head" (mellum; the qwen3 convention):
+    # one RMSNorm over each head's own lanes, one learned scale of the
+    # head's width shared by the heads, after the split and before RoPE
+    qk_norm: Any = False
     tie_embeddings: bool = False
     # LM-head cross-entropy knobs (models/_lm_utils.lm_head_xent):
     # "chunked" scan or the streaming "fused" Pallas kernel
@@ -94,14 +96,46 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """Rotary position embedding. x: [..., T, H, D]; positions: [..., T]."""
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original_max: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0):
+    """YaRN's inverse frequencies [head_dim // 2], float32, as
+    ``transformers`` computes them (``truncate`` at its default): a
+    frequency that turns more than ``beta_fast`` times over the
+    ``original_max`` positions is kept, one that turns less than
+    ``beta_slow`` times is divided by ``factor``, and a linear ramp over
+    the frequency's INDEX blends the two between. Built with numpy, once,
+    at trace time: a table of the program, not a branch of the step."""
+    import math
+    import numpy as np
+
+    def dim_of(turns):
+        return head_dim * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), head_dim - 1)
+    i = np.arange(head_dim // 2, dtype=np.float32)
+    ramp = np.clip((i - low) / max(high - low, 0.001), 0.0, 1.0)
+    plain = (1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                             / head_dim)).astype(np.float32)
+    return ((1.0 - ramp) * plain + ramp * plain / factor).astype(np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               inv_freq=None, scale=None) -> jnp.ndarray:
+    """Rotary position embedding. x: [..., T, H, D]; positions: [..., T].
+    ``inv_freq`` [D/2] stands in for ``theta``'s own frequencies (a scaled
+    code's table, ``yarn_frequencies``) and ``scale`` multiplies cos and
+    sin both (YaRN's ``attention_factor``)."""
     d = x.shape[-1]
-    freqs = rope_frequencies(d, theta)                     # [D/2]
+    freqs = rope_frequencies(d, theta) if inv_freq is None \
+        else jnp.asarray(inv_freq, jnp.float32)            # [D/2]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [..., T, D/2]
     cos = jnp.cos(ang)[..., None, :]                       # [..., T, 1, D/2]
     sin = jnp.sin(ang)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -38,6 +38,8 @@ from .pangu_ultra_moe import PanguUltraMoE, PanguUltraMoEConfig
 from .pangu_ultra_moe import make_model as make_pangu_ultra_moe
 from .kimi_linear import KimiLinear, KimiLinearConfig
 from .kimi_linear import make_model as make_kimi_linear
+from .mellum import LAYER_TYPES, Mellum, MellumConfig, YarnRope
+from .mellum import make_model as make_mellum
 from .nemotron_h import NemotronH, NemotronHConfig, kinds_from_pattern
 from .nemotron_h import make_model as make_nemotron_h
 
@@ -489,6 +491,76 @@ def _entry_kimi_linear(d):
         router_aux_loss_coef=0.0)
 
 
+def _entry_mellum(d):
+    """Mellum 2 (JetBrains/Mellum2-12B-A2.5B): ``layer_types`` says which
+    layers attend inside ``sliding_window`` and which in full,
+    ``rope_parameters`` gives each kind its own rotary code (plain for
+    the sliding layers, YaRN for the full ones); every layer sparse
+    (``mlp_layer_types``), softmax router renormalised over its top-k, no
+    shared expert; q and k normed a head. What the served path has no
+    form for is refused by name: a dense feed-forward layer, attention
+    bias, a ``rope_parameters`` section this entry does not know."""
+    n = d.get("num_hidden_layers", 28)
+    types = d.get("layer_types") or ["full_attention"] * n
+    bad = sorted(set(types) - set(LAYER_TYPES))
+    if bad or len(types) != n:
+        raise ValueError(
+            f"mellum layer_types must name num_hidden_layers ({n}) layers "
+            f"of {sorted(LAYER_TYPES)}; got {len(types)} with {bad}")
+    if set(d.get("mlp_layer_types") or ["sparse"]) != {"sparse"}:
+        raise ValueError("mellum configs with a dense feed-forward layer "
+                         "(mlp_layer_types other than 'sparse') are not "
+                         "supported")
+    if d.get("attention_bias", False):
+        raise ValueError("mellum configs with attention_bias set are not "
+                         "supported (the published one has none)")
+    rope = d.get("rope_parameters") or {}
+    unknown = sorted(set(rope) - set(LAYER_TYPES))
+    if unknown:
+        raise ValueError(
+            f"mellum rope_parameters sections {unknown} are not known "
+            f"(a section a layer type: {sorted(LAYER_TYPES)})")
+    full = rope.get("full_attention") or {}
+    slide = rope.get("sliding_attention") or {}
+    theta = float(slide.get("rope_theta", full.get(
+        "rope_theta", d.get("rope_theta", 10000.0))))
+    if slide.get("rope_type", "default") != "default" \
+            or float(full.get("rope_theta", theta)) != theta:
+        raise ValueError(
+            "mellum rope_parameters: the sliding layers' code must be "
+            "'default' and both sections share one rope_theta; got "
+            f"{rope!r}")
+    kind = full.get("rope_type", "default")
+    if kind not in ("default", "yarn"):
+        raise ValueError(f"mellum rope_parameters full_attention "
+                         f"rope_type {kind!r} is not supported "
+                         f"('default' or 'yarn')")
+    yarn = None
+    if kind == "yarn":
+        import math
+        factor = float(full["factor"])
+        yarn = YarnRope(
+            factor=factor,
+            original_max=int(full["original_max_position_embeddings"]),
+            beta_fast=float(full.get("beta_fast", 32.0)),
+            beta_slow=float(full.get("beta_slow", 1.0)),
+            attention_factor=float(full.get(
+                "attention_factor", 0.1 * math.log(factor) + 1.0)))
+    base = _hf_llama(d, intermediate_size=d.get("moe_intermediate_size",
+                                                896), rope_theta=theta)
+    return MellumConfig(
+        **base,
+        attn_head_dim=d.get("head_dim",
+                            base["hidden_size"] // base["num_heads"]),
+        layer_kinds=tuple(LAYER_TYPES[t] for t in types),
+        sliding_window=int(d.get("sliding_window", 1024)),
+        full_rope=yarn,
+        num_experts=d.get("num_experts", 64),
+        experts_top_k=d.get("num_experts_per_tok", 8),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        router_aux_loss_coef=0.0)
+
+
 def _entry_nemotron_h(d):
     """Nemotron-H (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16):
     ``hybrid_override_pattern`` read letter by letter, a layer a mixer
@@ -566,6 +638,7 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
                              make_kimi_linear, _entry_kimi_linear),
     "nemotron_h": ArchEntry(NemotronHConfig, NemotronH, make_nemotron_h,
                             _entry_nemotron_h),
+    "mellum": ArchEntry(MellumConfig, Mellum, make_mellum, _entry_mellum),
     "gpt_neo": ArchEntry(GPTNeoConfig, GPTNeo, make_gpt_neo,
                          _entry_gpt_neo),
     "internlm": ArchEntry(LlamaConfig, Llama, make_llama, _entry_internlm),

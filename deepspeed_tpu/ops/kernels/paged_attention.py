@@ -473,11 +473,22 @@ def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
     pos_rows = rows_of(starts_ref)
     len_rows = rows_of(lens_ref)
 
-    grp_len = lens_ref[i * G]
-    for g in range(1, G):
-        grp_len = jnp.maximum(grp_len, lens_ref[i * G + g])
+    if window is None:
+        grp_len = lens_ref[i * G]
+        for g in range(1, G):
+            grp_len = jnp.maximum(grp_len, lens_ref[i * G + g])
+        chunk_live = grp_len > c * CR
+    else:
+        # a chunk wholly below every sequence's window copied nothing
+        # (t_lo above) and its scores would all be masked: skip the round
+        chunk_live = False
+        for g in range(G):
+            s_ = i * G + g
+            chunk_live = jnp.logical_or(chunk_live, jnp.logical_and(
+                lens_ref[s_] > c * CR,
+                starts_ref[s_] - window + 1 < (c + 1) * CR))
 
-    @pl.when(grp_len > c * CR)
+    @pl.when(chunk_live)
     def _pool_round():
         parts = []
         for g in range(G):
@@ -641,6 +652,7 @@ def flash_paged_attention(q: jnp.ndarray, pool: jnp.ndarray, layer: int,
                           scales: Optional[jnp.ndarray] = None,
                           ring: Optional[jnp.ndarray] = None,
                           ring_count: Optional[jnp.ndarray] = None,
+                          ring_layer: Optional[int] = None,
                           interpret: Optional[bool] = None) -> jnp.ndarray:
     """Flash attention over paged KV.
 
@@ -676,6 +688,15 @@ def flash_paged_attention(q: jnp.ndarray, pool: jnp.ndarray, layer: int,
         and the tokens valid in it (pure decode only). It shares the
         pool's dtype, or q's over an int8 pool (the ring is never
         quantized), and is never cast.
+      ring_layer: the ring's row for this call where the ring holds more
+        layers than this pool (a model that keeps its sliding-window
+        layers in a second pool of the same form, one ring over both);
+        None: ``layer``, and the ring then has the pool's L layers.
+      sliding_window: keys below ``pos - window + 1`` reach no score, by
+        the first tile fetched and by the mask both: through a table that
+        reuses a sequence's blocks (the window pool's ``b % R``) a fetched
+        tile may hold NEWER rows at positions the mask reads as below the
+        window, and the mask goes by position.
 
     Returns [S, C, H, D] attention outputs in q.dtype. HBM traffic per
     step is O(sum of live blocks) of UNPADDED rows.
@@ -733,11 +754,14 @@ def flash_paged_attention(q: jnp.ndarray, pool: jnp.ndarray, layer: int,
         if C != 1:
             raise ValueError(
                 "ring decode requires C == 1 (pure decode steps)")
-        if ring.ndim != 5 or ring.shape[1:3] != (L, 2) \
-                or ring.shape[4] != KVD:
+        want = L if ring_layer is None else int(ring_layer) + 1
+        if ring.ndim != 5 or ring.shape[2] != 2 or ring.shape[4] != KVD \
+                or (ring.shape[1] != L if ring_layer is None
+                    else not 0 < want <= ring.shape[1]):
             raise ValueError(
-                f"ring must be [R, {L}, 2, S, {KVD}], got "
-                f"{list(ring.shape)}")
+                f"ring must be [R, {want}{'' if ring_layer is None else '+'}"
+                f", 2, S, {KVD}], got {list(ring.shape)}")
+        ring_layer = layer if ring_layer is None else int(ring_layer)
         # over an int8 pool the ring stays in the COMPUTE dtype; otherwise
         # it shares the pool's
         if ring.dtype != compute_dt:
@@ -784,7 +808,7 @@ def flash_paged_attention(q: jnp.ndarray, pool: jnp.ndarray, layer: int,
         # it does not read stays 0, so that every compiled program keeps
         # the constants it was measured with
         layers = jnp.asarray(
-            [layer, layer if has_ring else 0, layer if quant else 0],
+            [layer, ring_layer if has_ring else 0, layer if quant else 0],
             jnp.int32)
         out = _decode_call(
             qp, pool, pool, ring, scales, block_tables.astype(jnp.int32),
@@ -904,7 +928,8 @@ def flash_paged_attention(q: jnp.ndarray, pool: jnp.ndarray, layer: int,
         ring_spec = pl.BlockSpec((1, R, KVD),
                                  lambda s, qc, j, *_: (s, 0, 0))
         in_specs += [ring_spec, ring_spec]
-        operands += [jnp.moveaxis(ring[:, layer, x], 0, 1) for x in (0, 1)]
+        operands += [jnp.moveaxis(ring[:, ring_layer, x], 0, 1)
+                     for x in (0, 1)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pref,

@@ -103,6 +103,7 @@ REGIONS = (
     "loss",         # the (chunked) cross-entropy
     "grad_clip",    # mean over micro-batches, unscale, global norm, clip
     "optimizer",    # the update, the overflow gate, the new state
+    "attn_window",  # attn_core's twin around a sliding-window layer's call
 )
 
 

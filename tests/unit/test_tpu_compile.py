@@ -808,3 +808,107 @@ def test_nemotron_loop_and_refill_compile_at_256_clients(one_chip,
     assert names["grouped_ffn_decode"] == 5 and "ragged-dot" not in hlo
     assert not any(n.startswith(("mamba2", "short_conv")) for n in names), \
         names
+
+
+def test_mellum_loop_flush_and_refill_compile_at_256_clients(one_chip,
+                                                            monkeypatch):
+    """The fused 128-step decode loop, its flush and the [4, 512] refill
+    step of ``serve-mellum2-rollout-long`` at the published widths and the
+    cell's 256-client pools, from shapes alone: all eight attention layers
+    (two over the paged pool, six over the window pool of R = 6 blocks a
+    slot) run the ONE decode kernel at 8 queries a kv head over a 512-lane
+    row (the name and shape ``paged_attn_roofline.mellum2`` matches), the
+    experts of 7 lane groups run in the grouped kernel at the shape
+    ``grouped_moe_roofline.mellum2`` matches, the flush updates BOTH
+    donated pools in place, and the refill step's attention calls trace
+    under the two regions."""
+    import json
+    import os
+    import re
+    from collections import Counter
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from benchmark.model_types import mellum as mt
+    from deepspeed_tpu.inference.v2.kv_cache import window_blocks
+    from deepspeed_tpu.inference.v2.kv_quant import KVPool
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2-12b-a2.5b.json")) as f:
+        mcfg = mt.model_config(json.load(f))
+    with open(os.path.join(root, "benchmark", "cells",
+                           "serve-mellum2-rollout-long.json")) as f:
+        cell = json.load(f)
+    eng = cell["engine"]
+    icfg = RaggedInferenceConfig(**eng)
+    runner = LlamaRaggedRunner(mcfg, icfg)
+    slots, block, blocks, maxb = (eng["max_seqs"], eng["block_size"],
+                                  eng["num_blocks"],
+                                  eng["max_blocks_per_seq"])
+    assert (slots, blocks) == (256, 3840)
+    assert runner.window_spec == {
+        "layers": 6, "window": 1024,
+        "ring_of": {False: (3, 7), True: (0, 1, 2, 4, 5, 6)}}
+    assert (runner.kv_layers, runner.kv_heads, runner.head_dim) \
+        == (2, 4, 128)
+    R = window_blocks(1024, icfg)
+    assert R == cell["pool"]["window_blocks_per_slot"] == 6
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: mt.init_params(mcfg, 0)))
+    assert params["layer_1"]["moe"]["wi_gate"].shape == (32, 2304, 896)
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert abs(weights / 3.966e9 - 1) < 1e-3
+    planes = spec((2, 2, (blocks + 1) * block, 512), jnp.bfloat16)
+    window = spec((6, 2, (slots + 1) * R * block, 512), jnp.bfloat16)
+    pools = 2 * (planes.size + window.size)
+    assert window.size * 2 == cell["pool"]["window_pool_bytes"]
+    kv = KVPool(planes, None, None, None, window)
+    f32 = functools.partial(spec, dtype=jnp.float32)
+    exe = runner._decode_loop_ring.trace(
+        params, kv, None, spec((slots,)), spec((slots,)), spec((slots,)),
+        spec((slots,)), spec((slots, maxb)), spec((1,)), f32((1,)),
+        spec((1,)), f32((1,)), spec((1, 1)), n=128, mode="greedy", cand=1,
+        eos_id=-1, feed="self").lower(lowering_platforms=("tpu",)).compile()
+    hlo = exe.as_text()
+    assert Counter(_mosaic_call_names(hlo)) == {
+        "closed_call": 8, "grouped_ffn_decode": 8}
+    assert "ragged-dot" not in hlo
+    assert len(re.findall(
+        r"%closed_call[\w\-.]* = bf16\[256,32,512\]", hlo)) == 8
+    assert len(re.findall(
+        r"%grouped_ffn_decode[\w\-.]* = bf16\[3040,2304\]", hlo)) == 8
+    mem = exe.memory_analysis()
+    ring = 128 * 8 * 2 * slots * 512 * 2
+    assert mem.temp_size_in_bytes < ring // 2
+    # weights + both pools + the ring + temporaries under the chip's 15.75
+    assert weights + pools + ring + mem.temp_size_in_bytes < 14.0e9
+    # the flush: both donated pools aliased; beside them the ring re-laid
+    # once for its two loops and a layer's rows
+    flush = runner._flush_ring.trace(
+        kv, spec((128, 8, 2, slots, 512), jnp.bfloat16),
+        spec((slots, maxb)), spec((slots,)), spec((slots,)),
+        spec((slots,))).lower(lowering_platforms=("tpu",)).compile()
+    mem = flush.memory_analysis()
+    assert mem.alias_size_in_bytes == pools
+    assert mem.temp_size_in_bytes < 2 * ring
+    assert weights + pools + ring + mem.temp_size_in_bytes < 14.6e9
+    # the refill step: the BlockSpec kernel a layer, six under the window
+    # region; the experts at the ridge take ragged_dot
+    step = runner._step_greedy.trace(
+        params, kv, RaggedBatch(spec((4, 512)), spec((4,)), spec((4,)),
+                                spec((4, maxb)), spec((4,)))).lower(
+                                    lowering_platforms=("tpu",)).compile()
+    names = Counter(n for n in _mosaic_call_names(step.as_text())
+                    if n.startswith("rg."))
+    assert names == {"rg.attn_window": 6, "rg.attn_core": 2}
+    assert step.memory_analysis().alias_size_in_bytes == pools
