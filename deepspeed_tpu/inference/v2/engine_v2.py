@@ -393,11 +393,13 @@ class InferenceEngineV2:
             # for the live sequences per pure-decode step (and per step
             # of a fused loop, whose own rows ride the ring), at most the
             # window; the rows the decode kernel streams for them in
-            # whole copy tiles; the bytes the live rows are over all
-            # window layers. decode_kv_rows_* and kv_bytes_live keep
-            # their meaning over such a model's FULL layers
+            # whole copy tiles; the score columns its plan holds for them
+            # (every chunk of the call at its rows); the bytes the live
+            # rows are over all window layers. decode_kv_rows_* and
+            # kv_bytes_live keep their meaning over such a model's FULL
+            # layers
             "window_rows_live": 0, "window_rows_fetched": 0,
-            "window_bytes_live": 0}
+            "window_rows_scored": 0, "window_bytes_live": 0}
         #: rows the decode kernel this model runs streams for a sequence
         #: of so many settled tokens (each kernel module's own arithmetic)
         if self._latent:
@@ -405,13 +407,24 @@ class InferenceEngineV2:
             self._kv_rows_fetched = functools.partial(
                 decode_rows_fetched, block_size=self.config.block_size)
         else:
-            from ...ops.kernels import decode_rows_fetched, decode_tile_rows
+            from ...ops.kernels import (decode_rows_fetched,
+                                        decode_rows_scored, decode_tile_rows)
+            kv_row = self.runner.local_kv_heads * self.runner.head_dim
+            itemsize = 1 if self.config.kv_cache_dtype == "int8" \
+                else np.dtype(resolve_dtype(self.config.dtype)).itemsize
             #: the tile the decode kernel copies by, over either pool
             self._window_tile = decode_tile_rows(
-                self.config.block_size,
-                self.runner.local_kv_heads * self.runner.head_dim,
-                1 if self.config.kv_cache_dtype == "int8"
-                else np.dtype(resolve_dtype(self.config.dtype)).itemsize)
+                self.config.block_size, kv_row, itemsize)
+            if self._windowed:
+                #: score columns a window layer's decode call of so many
+                #: slots holds a sequence (the kernel's own plan)
+                self._window_scored = functools.partial(
+                    decode_rows_scored,
+                    ctx_rows=self.config.max_blocks_per_seq
+                    * self.config.block_size,
+                    tile_rows=self._window_tile,
+                    row_bytes=kv_row * itemsize,
+                    window=self.runner.window_spec["window"])
             self._kv_rows_fetched = functools.partial(
                 decode_rows_fetched, tile_rows=self._window_tile,
                 # one window for every layer, kept whole in the paged pool;
@@ -1202,10 +1215,11 @@ class InferenceEngineV2:
         if why:
             raise NotImplementedError("; ".join(why))
 
-    def _decode_row_counts(self, runs, in_ring: bool = False
+    def _decode_row_counts(self, runs, S: int, in_ring: bool = False
                            ) -> Dict[str, int]:
         """The decode kernel's row counters for ``runs``, (steps a
-        sequence ran, its settled rows) pairs: ``decode_kv_rows_*`` and
+        sequence ran, its settled rows) pairs of a program of ``S``
+        slots: ``decode_kv_rows_*`` and
         their bytes over K/V planes, ``latent_rows_*`` and theirs over a latent
         plane (a layer's worth each; one pair a model, never both), and
         ``window_rows_*`` beside the first pair for a model with
@@ -1239,6 +1253,8 @@ class InferenceEngineV2:
                 window_rows_live=wlive,
                 window_rows_fetched=int((np.maximum(
                     -(-rows // ts) - first // ts, 0) * ts * alive).sum()),
+                # the call's plan, live chunk or not: the kernel's own
+                window_rows_scored=int(ran.sum()) * self._window_scored(S),
                 window_bytes_live=wlive
                 * self.kv_cache.window_bytes_per_row())
         return out
@@ -1663,7 +1679,7 @@ class InferenceEngineV2:
             for key, val in self._decode_row_counts([
                     (int(consumed[i]) if consumed is not None else n,
                      seq.seen_tokens) for i, seq in enumerate(seqs)],
-                    in_ring=True).items():
+                    S, in_ring=True).items():
                 stats[key] += val
             if self._stateful:
                 ran = n * len(seqs) if consumed is None \
@@ -1890,7 +1906,8 @@ class InferenceEngineV2:
                 # this step's token is in the pool before the kernel runs
                 lens = [item.start_pos + 1 for item in sched]
                 span.count(decode_slots_live=real, decode_slots_planned=S,
-                           **self._decode_row_counts([(1, n) for n in lens]))
+                           **self._decode_row_counts(
+                               [(1, n) for n in lens], S))
                 if self._stateful:
                     span.count(state_slots_live=real,
                                state_bytes_live=real

@@ -22,7 +22,8 @@ from .flash_attention import (  # noqa: E402,F401
     sharded_flash_attention,
 )
 from .paged_attention import (  # noqa: E402,F401
-    decode_rows_fetched, decode_tile_rows, flash_paged_attention)
+    decode_rows_fetched, decode_rows_scored, decode_tile_rows,
+    flash_paged_attention)
 from .normalization import fused_layer_norm, fused_rms_norm  # noqa: E402,F401
 from .quantization import (  # noqa: E402,F401
     dequantize_blockwise,

@@ -258,10 +258,17 @@ def decode_rows_fetched(seq_len: int, tile_rows: int,
     return (-(-seq_len // tile_rows) - lo) * tile_rows
 
 
-def _decode_plan(S: int, ctx_rows: int, ts: int, row_bytes: int):
+def _decode_plan(S: int, ctx_rows: int, ts: int, row_bytes: int,
+                 window: Optional[int] = None):
     """(G, CR, NCH): sequences a grid step, context rows a sequence and
     step (whole tiles, even chunks), chunks a context — from the slot
-    count, the rows a sequence may hold, the tile and the bytes of a row."""
+    count, the rows a sequence may hold, the tile and the bytes of a row.
+    Under a sliding window the chunks count from each sequence's first
+    live tile, so they cover the tiles a window's rows can touch
+    (``window`` rows from a tile's last row on: one tile more than they
+    fill) where that is less than the context."""
+    if window is not None:
+        ctx_rows = min(ctx_rows, (-(-(window - 1) // ts) + 1) * ts)
     if S % 8 == 0:
         G = 8        # a whole sublane tile of the ring's [.., S, KVD] planes
     elif S <= 16:
@@ -275,12 +282,24 @@ def _decode_plan(S: int, ctx_rows: int, ts: int, row_bytes: int):
     return G, cr, nch
 
 
+def decode_rows_scored(slots: int, ctx_rows: int, tile_rows: int,
+                       row_bytes: int, window: Optional[int] = None) -> int:
+    """Score columns one call of the decode kernel holds for each of its
+    ``slots`` sequences: every chunk of the call's plan at its rows,
+    whether the sequence has live rows there or not."""
+    _, cr, nch = _decode_plan(slots, ctx_rows, tile_rows, row_bytes, window)
+    return cr * nch
+
+
 def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
                    slopes_ref, q_ref, kp_hbm, vp_hbm, *rest, G, CR, NCH, ts,
                    bs, maxb, H, Hp, KV, D, sm_scale, use_alibi, window, R,
                    quant):
     """Pure-decode attention over paged KV: grid step (i, c) serves the G
-    sequences of group i over context rows [c*CR, (c+1)*CR).
+    sequences of group i over context rows [c*CR, (c+1)*CR); under a
+    sliding window, over that range counted from each sequence's own
+    first live tile (``first_tile``), so the NCH chunks cover a window
+    wherever it stands in the context.
 
     K and V arrive by manual DMA in tiles of ``ts`` rows, each from
     ``pool[layer, x, table[s, b] * bs + t * ts]``: only the tiles below a
@@ -330,22 +349,34 @@ def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
     def kv_src(x, off):
         return kp_hbm.at[layer_ref[0], x, pl.ds(off, ts)]
 
+    def first_tile(s):
+        """The tile that holds the first row sequence s's window reaches:
+        where its chunk 0 starts."""
+        return jnp.maximum(starts_ref[s] - window + 1, 0) // ts
+
     def copies(gi, ci, sl, wait):
         """Start (or wait for) the live tiles of group gi, chunk ci."""
         def per_seq(g, carry):
             s = gi * G + g
-            t_hi = jnp.minimum((lens_ref[s] + ts - 1) // ts,
-                               jnp.minimum((ci + 1) * TPC, maxb * tpb))
-            t_lo = ci * TPC
-            if window is not None:
-                t_lo = jnp.maximum(
-                    t_lo, jnp.maximum(starts_ref[s] - window + 1, 0) // ts)
+            if window is None:
+                # apart, so that the unwindowed body stays the one every
+                # cell but Mellum2's was measured with, operation for
+                # operation
+                t_hi = jnp.minimum((lens_ref[s] + ts - 1) // ts,
+                                   jnp.minimum((ci + 1) * TPC, maxb * tpb))
+                t_lo = ci * TPC
+            else:
+                t_lo = first_tile(s) + ci * TPC
+                t_hi = jnp.minimum((lens_ref[s] + ts - 1) // ts,
+                                   jnp.minimum(t_lo + TPC, maxb * tpb))
 
             def per_tile(t, carry):
                 b = t // tpb
                 src = pl.multiple_of(
                     tables_ref[s * maxb + b] * bs + (t - b * tpb) * ts, ts)
-                dst = pl.multiple_of(g * CR + (t - ci * TPC) * ts, ts)
+                dst = pl.multiple_of(
+                    g * CR + (t - (ci * TPC if window is None else t_lo))
+                    * ts, ts)
                 cps = [pltpu.make_async_copy(
                     kv_src(x, src), scr.at[sl, pl.ds(dst, ts)],
                     sems.at[sl, x])
@@ -388,6 +419,8 @@ def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
                 n = lens_ref[i * G + g]
                 tb = n // ts
                 lb = n - tb * ts
+                if window is not None:
+                    tb = tb - first_tile(i * G + g)
 
                 @pl.when(jnp.logical_and(
                     lb > 0, jnp.logical_and(tb >= c * TPC,
@@ -431,10 +464,11 @@ def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
         l_scr[...] = jnp.zeros(l_scr.shape, l_scr.dtype)
         acc_scr[...] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
 
-    def rows_of(ref):
-        """Per-sequence scalars -> [M, 1] rows (Hp rows a sequence)."""
+    def rows_of(of):
+        """Per-sequence scalars ``of(s)`` -> [M, 1] rows (Hp rows a
+        sequence)."""
         return jnp.concatenate(
-            [jnp.full((Hp, 1), ref[i * G + g], jnp.int32)
+            [jnp.full((Hp, 1), of(i * G + g), jnp.int32)
              for g in range(G)], axis=0)
 
     def heads_of(sc):
@@ -470,8 +504,11 @@ def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
         m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
         acc_scr[...] = acc_scr[...] * alpha + pv_of(p)
 
-    pos_rows = rows_of(starts_ref)
-    len_rows = rows_of(lens_ref)
+    pos_rows = rows_of(lambda s: starts_ref[s])
+    len_rows = rows_of(lambda s: lens_ref[s])
+    if window is not None:
+        # the position of each sequence's chunk 0, column 0
+        first_rows = rows_of(lambda s: first_tile(s) * ts)
 
     if window is None:
         grp_len = lens_ref[i * G]
@@ -479,14 +516,13 @@ def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
             grp_len = jnp.maximum(grp_len, lens_ref[i * G + g])
         chunk_live = grp_len > c * CR
     else:
-        # a chunk wholly below every sequence's window copied nothing
-        # (t_lo above) and its scores would all be masked: skip the round
+        # a chunk past every sequence's settled rows copied nothing and
+        # its scores would all be masked: skip the round
         chunk_live = False
         for g in range(G):
             s_ = i * G + g
-            chunk_live = jnp.logical_or(chunk_live, jnp.logical_and(
-                lens_ref[s_] > c * CR,
-                starts_ref[s_] - window + 1 < (c + 1) * CR))
+            chunk_live = jnp.logical_or(
+                chunk_live, lens_ref[s_] > first_tile(s_) * ts + c * CR)
 
     @pl.when(chunk_live)
     def _pool_round():
@@ -505,6 +541,8 @@ def _decode_kernel(starts_ref, tables_ref, lens_ref, rcount_ref, layer_ref,
             parts.append(sc_g)
         sc = jnp.concatenate(parts, axis=0) * sm_scale     # [M, CR]
         col = c * CR + jax.lax.broadcasted_iota(jnp.int32, (M, CR), 1)
+        if window is not None:
+            col = col + first_rows
         # live rows only: below the settled length (in ring mode rows
         # lens..pos live in the ring and the pool's are stale) and causal
         mask = jnp.logical_and(col < len_rows, col <= pos_rows)
@@ -578,7 +616,7 @@ def closed_call(qw, kp, vp, ring, scales, tables, start_pos, seq_lens,
     pool_dt = kp.dtype
     ts = decode_tile_rows(bs, KVD, pool_dt.itemsize)
     row_bytes = KVD * pool_dt.itemsize + (4 * KV if quant else 0)
-    G, CR, NCH = _decode_plan(S, maxb * bs, ts, row_bytes)
+    G, CR, NCH = _decode_plan(S, maxb * bs, ts, row_bytes, window)
     R = None if ring is None else ring.shape[0]
     kernel = functools.partial(
         _decode_kernel, G=G, CR=CR, NCH=NCH, ts=ts, bs=bs, maxb=maxb, H=H,

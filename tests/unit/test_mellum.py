@@ -139,7 +139,14 @@ def test_engine_logits_match_the_reference(model, chunk, decode):
         else 2 * sum(WINDOW - 1 - t for t in range(LOOP)) + WINDOW
     assert stats["window_rows_live"] == wlive
     assert stats["window_bytes_live"] == wlive * 3 * 2 * 2 * 16 * 4
-    assert stats["window_rows_fetched"] >= wlive
+    # ... and the columns the decode kernel's plan holds for them: every
+    # chunk of every call, from the helper the kernel plans with
+    from deepspeed_tpu.ops.kernels import decode_rows_scored
+    calls = 9 if decode == "pipelined" else 2 * LOOP + 1
+    assert stats["window_rows_scored"] == calls * decode_rows_scored(
+        4, 24 * BLOCK, eng._window_tile, 2 * 16 * 4, WINDOW)
+    assert stats["window_rows_scored"] >= stats["window_rows_fetched"] \
+        >= wlive
 
 
 @pytest.mark.parametrize("length", range(21, 21 + 16))
@@ -306,7 +313,7 @@ def test_the_region_and_the_counters_are_in_the_vocabulary(model):
     assert REGIONS[-1] == "attn_window" and len(REGIONS) == 21
     cfg, params = model
     eng = engine(cfg, params)
-    assert {"window_rows_live", "window_rows_fetched",
+    assert {"window_rows_live", "window_rows_fetched", "window_rows_scored",
             "window_bytes_live"} <= set(eng.pipeline_stats)
     text = eng.runner._step.trace(
         params, eng._kv_data, RaggedBatch(
@@ -320,20 +327,29 @@ def test_the_region_and_the_counters_are_in_the_vocabulary(model):
 def test_the_window_counters_are_the_kernels_own_arithmetic(model):
     """``window_rows_live`` / ``_fetched`` in closed form against a walk
     over the steps with the kernel's start tile (``decode_rows_fetched``
-    at a window shortened by the rows the ring holds)."""
+    at a window shortened by the rows the ring holds), ``_scored`` against
+    the kernel's plan: ``NCH x CR`` a live sequence and step."""
     from deepspeed_tpu.ops.kernels import decode_rows_fetched
+    from deepspeed_tpu.ops.kernels.paged_attention import _decode_plan
     cfg, params = model
     eng = engine(cfg, params)
     ts = eng._window_tile
     runs = [(4, 3), (4, 9), (2, 40), (0, 7)]
-    got = eng._decode_row_counts(runs, in_ring=True)
+    got = eng._decode_row_counts(runs, 4, in_ring=True)
+    # the window's three tiles of 4 rows in one chunk, where the context
+    # of 24 blocks would be 96 columns a sequence
+    _, cr, nch = _decode_plan(4, 24 * BLOCK, ts, 2 * 16 * 4, WINDOW)
+    assert (cr, nch) == (12, 1)
+    assert got["window_rows_scored"] == 10 * nch * cr \
+        >= got["window_rows_fetched"] >= got["window_rows_live"]
     live = sum(min(rows, max(WINDOW - 1 - t, 0))
                for ran, rows in runs for t in range(ran))
     fetched = sum(decode_rows_fetched(rows, ts, window=WINDOW - 1 - t)
                   for ran, rows in runs for t in range(ran))
     assert (got["window_rows_live"], got["window_rows_fetched"]) \
         == (live, fetched)
-    step = eng._decode_row_counts([(1, 3), (1, 40)])
+    step = eng._decode_row_counts([(1, 3), (1, 40)], 4)
+    assert step["window_rows_scored"] == 2 * 12
     assert step["window_rows_live"] == 3 + WINDOW
     assert step["window_rows_fetched"] == sum(
         decode_rows_fetched(n, ts, window=WINDOW) for n in (3, 40))

@@ -356,20 +356,25 @@ class TestSeqLenBoundedGroupedReads:
 
 def _decode_case(*, geom="gqa", maxb=2, S=8, bs=256, kv_dtype="bf16",
                  rcount=None, R=4, window=None, alibi=False, poison=False,
-                 lens=None, seed=0):
+                 lens=None, wrap=None, seed=0):
     """One pure-decode call of the paged kernel (interpret mode) against
     a dense float32 reference over the same rows. Block tables are a
     random permutation: a sequence's blocks are never adjacent by
-    construction. Returns (out, ref), both [S, H, D] float32."""
+    construction. ``wrap``: the window pool's table instead, logical block
+    ``b`` of slot ``s`` in block ``s * wrap + b % wrap``, so a position
+    below the window reads the NEWER row that took its place and only a
+    mask by position keeps it out. Returns (out, ref), both [S, H, D]
+    float32."""
     from deepspeed_tpu.inference.v2.kv_quant import (dequantize_rows,
                                                      quantize_rows)
     from deepspeed_tpu.ops.kernels import (decode_tile_rows,
                                            flash_paged_attention)
     rng = np.random.default_rng(seed)
-    H, KV, D = {"gqa": (12, 2, 128), "mha": (16, 16, 128)}[geom]
+    H, KV, D = {"gqa": (12, 2, 128), "mha": (16, 16, 128),
+                "gqa32": (32, 4, 128)}[geom]
     KVD = KV * D
     L, li = 2, 1
-    nb = S * maxb + 3
+    nb = S * (wrap or maxb) + 3
     slots = (nb + 1) * bs
     ts = decode_tile_rows(bs, KVD, 1 if kv_dtype == "int8" else 2)
     assert ts == 128
@@ -378,7 +383,10 @@ def _decode_case(*, geom="gqa", maxb=2, S=8, bs=256, kv_dtype="bf16",
         want = [0, 1, ts - 1, ts, ts + 1, bs, bs + 1, maxb * bs]
         lens = [min(want[s % len(want)], maxb * bs) for s in range(S)]
     lens = np.asarray(lens, np.int64)
-    tables = rng.permutation(nb)[:S * maxb].reshape(S, maxb)
+    if wrap:
+        tables = np.arange(S)[:, None] * wrap + np.arange(maxb)[None] % wrap
+    else:
+        tables = rng.permutation(nb)[:S * maxb].reshape(S, maxb)
     live = np.zeros((slots,), bool)
     for s in range(S):
         j = np.arange(lens[s])
@@ -430,6 +438,13 @@ def _decode_case(*, geom="gqa", maxb=2, S=8, bs=256, kv_dtype="bf16",
     return np.asarray(out, np.float32)[:, 0], ref[:, 0], lens
 
 
+# idle, under the window, one row over it, a window that starts on a
+# tile's last row (nine tiles), windows around a chunk's edge, the mean
+# context of the rollout cell, a full context
+_LONG = dict(geom="gqa32", maxb=24, window=1024,
+             lens=[0, 700, 1025, 1407, 1664, 1665, 3243, 6144])
+
+
 class TestPagedDecodeKernel:
     """The decode kernel of ``C == 1`` calls at 128-lane rows (several
     sequences a grid step, live tiles only, through the block table) vs a
@@ -458,6 +473,13 @@ class TestPagedDecodeKernel:
         dict(poison=True, S=16), dict(poison=True, rcount=2, maxb=6),
         dict(poison=True, geom="mha", S=4, lens=[0, 129, 257, 500]),
         dict(poison=True, kv_dtype="int8"),
+        # a window of 1,024 over a context of 24 blocks (Mellum2's shape):
+        # the chunks count from each sequence's own first live tile, and
+        # the eight sequences of the one group stand all over the context
+        *[dict(_LONG, **table, **form)
+          for table in (dict(), dict(wrap=6))
+          for form in (dict(), dict(rcount=4), dict(kv_dtype="int8"),
+                       dict(poison=True))],
     ], ids=lambda c: "-".join(f"{k}={v}" for k, v in c.items()
                               if k != "lens"))
     def test_matches_dense_reference(self, case):
@@ -473,7 +495,7 @@ class TestPagedDecodeKernel:
         # chunks, OLMoE's 4,096-byte rows stream it in tiles
         from deepspeed_tpu.ops.kernels.paged_attention import (
             _DECODE_KV_VMEM, _decode_plan, decode_rows_fetched,
-            decode_tile_rows)
+            decode_rows_scored, decode_tile_rows)
         assert decode_tile_rows(640, 256, 2) == 128
         assert decode_tile_rows(256, 2048, 1) == 128
         assert decode_tile_rows(64, 256, 2) == 64          # block < tile
@@ -488,6 +510,26 @@ class TestPagedDecodeKernel:
         assert decode_rows_fetched(129, 128) == 256
         assert decode_rows_fetched(673, 128) == 768
         assert decode_rows_fetched(673, 128, window=200) == 384
+        # the serve cells' calls (slots, context rows, bytes a row): chat,
+        # rollout, OLMoE, Solar, Nemotron, Mellum2's full layers
+        for shape, plan in (((64, 1536, 512), (8, 768, 2)),
+                            ((128, 1280, 512), (8, 640, 2)),
+                            ((32, 1280, 4096), (8, 128, 10)),
+                            ((128, 1280, 2048), (8, 384, 4)),
+                            ((256, 6144, 512), (8, 1024, 6)),
+                            ((256, 6144, 1024), (8, 768, 8))):
+            S, ctx, row = shape
+            assert _decode_plan(S, ctx, 128, row) == plan
+            # a window the context fits in changes nothing
+            assert _decode_plan(S, ctx, 128, row, window=ctx) == plan
+        # under a window the chunks cover the tiles its rows can touch (a
+        # window that starts on a tile's last row: 1 + 7 x 128 + 127 rows
+        # in nine tiles), not the context: Mellum2's sliding layers
+        assert _decode_plan(256, 6144, 128, 1024, window=1024) == (8, 640, 2)
+        assert _decode_plan(8, 1536, 128, 512, window=130) == (8, 384, 1)
+        assert decode_rows_scored(256, 6144, 128, 1024) == 8 * 768
+        assert decode_rows_scored(256, 6144, 128, 1024, window=1024) \
+            == 2 * 640
 
 
 def _wide_gpt2(layers=4):

@@ -162,6 +162,53 @@ def kernel_cases() -> List[KernelCase]:
 
     cases.append(KernelCase("paged_decode_tiles_ring", paged_ring(False),
                             (q8,), paged_ring(True)))
+    # Mellum2's sliding layers: 32 / 4 x 128, a window of 1,024 over a
+    # 24-block context through the window pool's wrapped table (six
+    # blocks a slot), the eight sequences of one group all over the
+    # context, the fused loop's ring; against dense attention over the
+    # rows the table names, masked by position
+    Sw, Hw, KVw, Dw, bsw, maxbw, Rw, win = 8, 32, 4, 128, 256, 24, 6, 1024
+    pool_w = as_pool(*(jax.random.normal(x, ((Sw + 1) * Rw * bsw, KVw * Dw),
+                                         jnp.bfloat16) for x in ks[:2]))
+    tw = (jnp.arange(Sw, dtype=jnp.int32)[:, None] * Rw
+          + jnp.arange(maxbw, dtype=jnp.int32)[None] % Rw)
+    lw = jnp.asarray([0, 700, 1025, 1407, 1664, 1665, 3243, 6144], jnp.int32)
+    ring_w = jax.random.normal(ks[2], (4, 1, 2, Sw, KVw * Dw), jnp.bfloat16)
+    qw8 = jax.random.normal(ks[0], (Sw, 1, Hw, Dw), jnp.bfloat16)
+    rc = 3
+
+    def window_wrapped(a, interpret=False):
+        return flash_paged_attention(
+            a, pool_w, 0, tw, lw + rc - 1, lw, block_size=bsw,
+            num_kv_heads=KVw, sliding_window=win, ring=ring_w,
+            ring_count=jnp.asarray(rc, jnp.int32), interpret=interpret)
+
+    def window_wrapped_ref(a):
+        j = jnp.arange(maxbw * bsw)
+        rows = jnp.take_along_axis(tw, (j // bsw)[None], 1) * bsw + j % bsw
+        pos = jnp.concatenate([
+            jnp.broadcast_to(j, (Sw, j.size)),
+            lw[:, None] + jnp.arange(rc)[None]], 1)          # [S, n]
+        live = jnp.concatenate([j[None] < lw[:, None],
+                                jnp.broadcast_to(lw[:, None] > 0, (Sw, rc))],
+                               1)
+        qpos = (lw + rc - 1)[:, None]
+        live &= (pos <= qpos) & (pos > qpos - win)
+        kc, vc = (jnp.concatenate(
+            [pool_w[0, x][rows], jnp.moveaxis(ring_w[:rc, 0, x], 0, 1)], 1)
+            .astype(f32).reshape(Sw, -1, KVw, Dw) for x in (0, 1))
+        qg = a[:, 0].astype(f32).reshape(Sw, KVw, Hw // KVw, Dw)
+        sc = jnp.einsum("skgd,snkd->skgn", qg, kc) / np.sqrt(Dw)
+        live = live[:, None, None]
+        sc = jnp.where(live, sc, -jnp.inf)
+        p = jnp.exp(sc - jnp.max(jnp.where(live, sc, -1e30), -1,
+                                 keepdims=True))             # idle: zeros
+        out = jnp.einsum("skgn,snkd->skgd", p, vc) \
+            / jnp.maximum(p.sum(-1), 1e-30)[..., None]
+        return out.reshape(Sw, 1, Hw, Dw).astype(jnp.bfloat16)
+
+    cases.append(KernelCase("paged_decode_window_wrapped", window_wrapped,
+                            (qw8,), window_wrapped_ref))
     qn = jax.random.normal(ks[2], (S8, 1, 8, 64), jnp.bfloat16)
 
     def narrow(interpret):
