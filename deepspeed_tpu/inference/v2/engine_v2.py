@@ -27,13 +27,14 @@ import functools
 import os
 import time
 from collections import deque
+from collections.abc import Mapping
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
 from ...ops.kernels.delta_rule import kda_prefill_uses_kernel
-from ...ops.kernels import short_conv
+from ...ops.kernels import grouped_ffn, short_conv
 from ...resilience.fault_injection import get_fault_injector
 from ...telemetry.serve import serve_observer
 from ...telemetry.trace import SpanSet
@@ -141,6 +142,21 @@ def _runner_for(model_cfg: Any, cfg: RaggedInferenceConfig):
         from .bloom_gptj_neox_runner import GPTJRaggedRunner
         return GPTJRaggedRunner(model_cfg, cfg)
     return GPT2RaggedRunner(model_cfg, cfg)
+
+
+def _expert_stacks(params, local) -> Optional[tuple]:
+    """The stacked expert matrices of the model's first sparse layer, as
+    shapes and types behind ``local`` (the runner's in-jit view of the
+    parameters: its dequant pass), in ``llama_runner._moe_mlp``'s order;
+    None for a model with no routed experts."""
+    layers = params.values() if isinstance(params, Mapping) else ()
+    moe = next((p["moe"] for p in layers
+                if isinstance(p, Mapping) and "moe" in p), None)
+    if moe is None:
+        return None
+    names = [k for k in ("wi_gate", "wi_up", "wi", "wo") if k in moe]
+    seen = jax.eval_shape(local, {k: moe[k] for k in names})
+    return tuple(seen[k] for k in names)
 
 
 class InferenceEngineV2:
@@ -260,6 +276,11 @@ class InferenceEngineV2:
         self._windowed = self.kv_cache.window is not None
         #: sequences name a slot (a state row, a window-pool row)
         self._slotted = bool(self._stateful) or self._windowed
+        #: a sparse layer's expert stacks as the step programs see them
+        #: (None: no routed experts, or they travel the expert-parallel
+        #: path, which has no grouped kernel)
+        self._moe_stacks = None if self.config.ep_size > 1 \
+            else _expert_stacks(params, self.runner._local_params)
         if self.config.ep_size > 1:
             if self.runner.tp is not None:
                 # composed ep×tp: the pool head-shards over 'model' on
@@ -361,6 +382,12 @@ class InferenceEngineV2:
             # experts with at least one row, and visits to them (times an
             # expert's matrices were streamed), over layers and steps
             "moe_experts_hit": 0, "moe_expert_reads": 0,
+            # real positions of prefill steps through the sparse layers'
+            # routed experts, and those of them in steps whose shape took
+            # the grouped kernel (grouped_ffn.kernel_impl, as
+            # llama_runner._moe_mlp asks it: all of them on a TPU over
+            # plain floating stacks, none on a CPU)
+            "moe_prefill_tokens": 0, "moe_prefill_kernel_tokens": 0,
             # recurrent models: state rows with a live tenant and their
             # bytes, per decode step (sampled where decode_slots_live
             # is, and per step of a fused loop), and the real positions
@@ -1898,6 +1925,15 @@ class InferenceEngineV2:
                                C, spec["heads"], spec["d_k"], spec["d_v"])))
                 if self._latent:
                     span.count(mla_prefill_tokens=real)
+                if self._moe_stacks is not None:
+                    # the choice llama_runner._moe_mlp makes, of the same
+                    # operand types and widths
+                    span.count(
+                        moe_prefill_tokens=real,
+                        moe_prefill_kernel_tokens=real
+                        * (grouped_ffn.kernel_impl(
+                            self._moe_stacks,
+                            self.runner.compute_dtype) is not None))
                 # serve fault site: a replica dying with a freshly planned
                 # multi-token prefill chunk (tokens consumed host-side, step
                 # never dispatched)

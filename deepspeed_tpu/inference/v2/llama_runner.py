@@ -65,14 +65,15 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
 
     Which grouped matmul, from what this call can see
     (``grouped_ffn.kernel_impl``): on a TPU backend, over plain floating
-    expert stacks, a step whose routed rows are a weight stream (``S x C
-    x k <= 128 x E``, under the chip's ridge of ~240 rows an expert)
-    takes the Pallas grouped kernel, each hit expert's matrices streamed
-    once with gate, up and down in one pass, at the row tile that holds
-    an expert's expected rows: every decode step (2-4 rows an expert) at
-    16, Solar's [4, 512] refill step (51) at 64. A step at the ridge
-    (OLMoE's refill, 256 rows an expert), quantised stacks and every
-    other backend take three ``jax.lax.ragged_dot`` calls.
+    expert stacks, every step takes the Pallas grouped kernel, each hit
+    expert's matrices streamed once with gate, up and down in one pass,
+    at the row tile and the span cap its expected rows an expert ask for
+    (``grouped_ffn.row_tile`` / ``span_cap``): every decode step (2-4
+    rows an expert) at 16, Solar's [4, 512] refill step (51) at 64, both
+    under a 128-row span; OLMoE's and Mellum2's refill step (256 rows an
+    expert, the chip's ridge) at 128 under a 512-row span. Quantised
+    stacks and every other backend take three ``jax.lax.ragged_dot``
+    calls.
 
     Inside an ``expert``-axis shard_map (``cfg.ep_size > 1`` engines)
     the routed rows instead travel the dispatch→grouped-GEMM→combine
@@ -89,7 +90,7 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
     (padding positions are computed like the others and left out of the
     count only), then the held experts the kernel found with at least one
     row and the times it streamed an expert's matrices (one a visit: once
-    a hit expert unless its rows pass the 128 of a visit's span; both 0 on
+    a hit expert unless its rows pass a visit's span cap; both 0 on
     the ``ragged_dot`` path); None without it and on the expert-parallel
     path."""
     from ...moe.sharded_moe import grouped_moe_ffn, route_topk
@@ -133,8 +134,7 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
                 act, dtype, EP_AXIS, cfg.num_experts, cap,
                 normalize_weights=norm, chunks=chunks)
         return y.reshape(S, C, M), None
-    impl = grouped_ffn.kernel_impl(S * C * cfg.experts_top_k,
-                                   cfg.num_experts, weights, dtype)
+    impl = grouped_ffn.kernel_impl(weights, dtype)
     # routing, layout and the weighted sum; the grouped matmuls open
     # ``moe_experts`` inside
     with region("moe_route"):
@@ -160,9 +160,10 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
                 mine = jnp.zeros((E,), jnp.int32).at[top_idx].add(
                     1)[first:first + count]
                 hit = jnp.sum(mine > 0, dtype=jnp.int32)
-                tile = grouped_ffn.row_tile(S * C * cfg.experts_top_k, E)
-                reads = jnp.sum(grouped_ffn.streams(mine, tile),
-                                dtype=jnp.int32)
+                routed = S * C * cfg.experts_top_k
+                reads = jnp.sum(grouped_ffn.streams(
+                    mine, grouped_ffn.row_tile(routed, E),
+                    grouped_ffn.span_cap(routed, E)), dtype=jnp.int32)
             rows = jnp.concatenate([rows, jnp.stack([hit, reads])])
     return y.reshape(S, C, M), rows
 

@@ -262,16 +262,17 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     their groups alone) and add nothing to the output.
 
     ``impl``: None is the path above, three ``ragged_dot`` calls over all
-    S*k rows: what training takes (hundreds of rows an expert, and the
-    gradient flows through it) and what serving takes at the chip's ridge
-    (a prefill step at 256 rows an expert). "pallas" (or "interpret", the
-    same kernel interpreted) is the serving path where the work is a
-    weight stream (``S x k <= 128 x E``: a decode step's 2-4 rows an
-    expert at a 16-row tile, a refill step's 51 at a 64-row tile): one
+    S*k rows: what training takes (the gradient flows through it) and
+    what serving takes off the TPU or over packed stacks. "pallas" (or
+    "interpret", the same kernel interpreted) is the serving path: one
     grouped kernel of our own (``ops/kernels/grouped_ffn.py``) walks the
     held groups that have rows, reads each one's matrices once and does
-    gate, up and down in one pass; it has no gradient and returns no aux
-    loss. The caller chooses (``inference/v2/llama_runner._moe_mlp``, by
+    gate, up and down in one pass, at the row tile and the span cap the
+    expected rows an expert ask for (a decode step's 2-4 at a 16-row
+    tile, a refill step's 51 at a 64-row tile, both under a 128-row
+    span; a refill step's 256, at the chip's ridge, at a 128-row tile
+    under a 512-row span); it has no gradient and returns no aux loss.
+    The caller chooses (``inference/v2/llama_runner._moe_mlp``, by
     ``grouped_ffn.kernel_impl``).
     """
     S, E = logits.shape
@@ -287,12 +288,14 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
         eid = jnp.where(here, eid - first, count)          # elsewhere: last
         E = count
     if impl is not None:
-        from ..ops.kernels.grouped_ffn import layout_and_run, row_tile
+        from ..ops.kernels.grouped_ffn import (layout_and_run, row_tile,
+                                               span_cap)
         # row r of ys is token r // k's j-th choice: no sort to undo
         ys = layout_and_run(
             tokens, eid.astype(jnp.int32),
             tuple(w.astype(dtype) for w in weights), activation, dtype,
             tile=row_tile(S * k, logits.shape[1]),
+            cap=span_cap(S * k, logits.shape[1]),
             interpret=impl == "interpret")
         out = jnp.sum(ys.reshape(S, k, -1).astype(jnp.float32)
                       * w_sel[..., None], axis=1).astype(dtype)

@@ -1,20 +1,26 @@
-"""The routed experts' feed-forward of a decode step as one grouped kernel.
+"""The routed experts' feed-forward of a serve step as one grouped kernel.
 
-At a few rows an expert the work is a weight stream: every expert that
-is hit has its matrices read once, and the multiplies hide under the
-read. ``jax.lax.ragged_dot`` (three calls a layer, XLA's own grouped
-matmul) walks the ROWS; this kernel walks the GROUPS:
+At a few rows an expert (a decode step) the work is a weight stream:
+every expert that is hit has its matrices read once, and the multiplies
+hide under the read. At the chip's ridge (a refill step at 256 rows an
+expert) reading them once and multiplying cost about the same, and both
+still hide under each other as long as an expert is ONE visit.
+``jax.lax.ragged_dot`` (three calls a layer, XLA's own grouped matmul)
+walks the ROWS; this kernel walks the GROUPS:
 
 * rows arrive sorted by expert with every group laid out at a multiple
   of the row tile (:func:`group_layout`), so a group's row tiles lie one
   behind the other; a *visit* (one grid step) owns ONE expert and a span
   of its row tiles, every tile of the group while they are within the
-  span cap (the last of ``_ROW_TILES``, 128 rows);
+  span cap (:func:`span_cap`: 128 rows, the last of ``_ROW_TILES``, for a
+  call that expects no more an expert; 512, the last of
+  ``_TALL_HEIGHTS``, for one that does);
 * an expert's matrices come by manual DMA in contiguous row chunks, two
   scratch slots a matrix: the next chunk (and the next visit's first) is
   in flight while this one multiplies. A chunk, once in VMEM, multiplies
   every row of the span as ONE operand, of the smallest height of
-  ``_ROW_TILES`` that holds the span (the matrices are the MXU's
+  ``_ROW_TILES`` (and ``_TALL_HEIGHTS`` under the taller cap) that holds
+  the span (the matrices are the MXU's
   stationary operand: two products of 16 rows cost twice one of 32), so
   the matrices of a hit expert are streamed once however many row tiles
   it has;
@@ -47,9 +53,13 @@ from ...telemetry.trace import region
 #: rows a visit at decode shapes: one packed bfloat16 tile (two float32)
 ROW_TILE = 16
 #: the row tiles a layout may take and the heights of a visit's operand;
-#: past the last (the span cap) the work is no weight stream any more
-#: (the chip's ridge is ~240 rows an expert)
+#: the last is the span cap of a call that expects no more rows an expert
+#: (a weight stream: the chip's ridge is ~240 rows an expert)
 _ROW_TILES = (16, 32, 64, 128)
+#: the heights beyond, for a call that expects more (a refill step at the
+#: ridge, 256 rows an expert): a padded operand row is MXU time there, so
+#: they rise by a tile and not by doubling; the last is that call's cap
+_TALL_HEIGHTS = (256, 384, 512)
 #: bytes of one weight chunk in flight (a slot); two slots a matrix
 _CHUNK_BYTES = 1 << 20
 
@@ -60,23 +70,29 @@ def visits_bound(rows: int, groups: int, tile: int = ROW_TILE) -> int:
     return max(1, (rows + groups * (tile - 1)) // tile)
 
 
-def _span_tiles(tile: int) -> int:
-    """Row tiles a visit holds at most: the span cap over the tile (one
-    of ``_ROW_TILES``)."""
-    return _ROW_TILES[-1] // tile
+def span_cap(rows: int, experts: int) -> int:
+    """Rows a visit spans at most, for ``rows`` routed rows over
+    ``experts`` experts: 128 (the last row tile) while an expert expects
+    no more, 512 past it, so that an expert at the ridge is still ONE
+    visit and its matrices stream once. At 512 rows a visit the
+    multiplies outweigh the stream 2 : 1, and a group beyond takes a
+    second visit at little cost."""
+    return _ROW_TILES[-1] if rows <= _ROW_TILES[-1] * experts \
+        else _TALL_HEIGHTS[-1]
 
 
-def streams(sizes, tile: int = ROW_TILE):
+def streams(sizes, tile: int = ROW_TILE, cap: int = _ROW_TILES[-1]):
     """Times each group's matrices are streamed, [groups] int32 from the
     groups' row counts: once for a group with a row, once more for every
     span cap of rows (in whole tiles) beyond the first. The visits of
     :func:`group_layout`, and what ``moe_expert_reads`` sums."""
     tiles = (sizes + tile - 1) // tile
-    per = _span_tiles(tile)
+    per = cap // tile
     return ((tiles + per - 1) // per).astype(jnp.int32)
 
 
-def group_layout(eid, groups: int, tile: int = ROW_TILE):
+def group_layout(eid, groups: int, tile: int = ROW_TILE,
+                 cap: int = _ROW_TILES[-1]):
     """Where each routed row goes when every group starts at a multiple of
     ``tile``. ``eid`` [R] int32: the (local) group of each routed row,
     ``groups`` for a row in no held group. Returns (dest [R] int32: the
@@ -102,8 +118,8 @@ def group_layout(eid, groups: int, tile: int = ROW_TILE):
     dest = jnp.zeros((R,), jnp.int32).at[order].set(dest_sorted)
     # visit v serves the first group whose visits end beyond v, from the
     # tile its earlier visits of that group stopped at
-    per = _span_tiles(tile)
-    visits = streams(sizes, tile)
+    per = cap // tile
+    visits = streams(sizes, tile, cap)
     vis_end = jnp.cumsum(visits)
     nvis = vis_end[-1]
     v = jnp.minimum(jnp.arange(V, dtype=jnp.int32), jnp.maximum(nvis - 1, 0))
@@ -319,23 +335,24 @@ def _kernel(gid_ref, first_ref, ntile_ref, nvis_ref, x_hbm, *rest, K1, K2,
             each_tile(v, lambda w, j: rows_out(w, j).wait())
 
 
-def _heights(T: int, V: int):
+def _heights(T: int, V: int, cap: int = _ROW_TILES[-1]):
     """The operand heights a call's visits choose from: ``_ROW_TILES``
-    from the layout's tile up, no further than holds the whole layout."""
-    hs = [h for h in _ROW_TILES if h >= T]
+    (and ``_TALL_HEIGHTS`` under a taller cap) from the layout's tile up
+    to the span cap, no further than holds the whole layout."""
+    hs = [h for h in _ROW_TILES + _TALL_HEIGHTS if T <= h <= cap]
     enough = next((i for i, h in enumerate(hs) if h >= V * T), len(hs) - 1)
     return tuple(hs[:enough + 1])
 
 
 def vmem_need(T: int, V: int, M: int, F: int, itemsize: int,
-              gated: bool) -> int:
+              gated: bool, cap: int = _ROW_TILES[-1]) -> int:
     """Bytes of VMEM a call asks for (its ``vmem_limit_bytes``): the two
     chunk slots a matrix, the rows in and out of the tallest span, that
     span's float32 intermediates and 4 MB. What the shapes need and no
     round number: the rest of VMEM is where XLA prefetches the dense
     weights of the operations around the call."""
     tm, tf = _chunk_rows(M, F, itemsize), _chunk_rows(F, M, itemsize)
-    H = _heights(T, V)[-1]
+    H = _heights(T, V, cap)[-1]
     need = sum(2 * a * b * itemsize for a, b in
                [(tm, F)] * (2 if gated else 1) + [(tf, M)])
     return need + 2 * H * M * itemsize + H * (2 * F + 2 * M) * 4 + (4 << 20)
@@ -344,11 +361,13 @@ def vmem_need(T: int, V: int, M: int, F: int, itemsize: int,
 # jitted under its own name: the device trace names a Mosaic call after
 # the function that encloses it, and a program whose layers share shapes
 # traces this body once
-@functools.partial(jax.jit, static_argnames=("activation", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("activation", "cap", "interpret"))
 def grouped_ffn_decode(xs, visits, nvis, weights, *, activation,
-                       interpret=False):
-    """xs [V * T, M] rows in :func:`group_layout`'s order at row tile T;
-    visits (gid, first, ntile) [V] each, nvis [1]; weights (wi, wo) or
+                       cap=_ROW_TILES[-1], interpret=False):
+    """xs [V * T, M] rows in :func:`group_layout`'s order at row tile T
+    and span cap ``cap``; visits (gid, first, ntile) [V] each, nvis [1];
+    weights (wi, wo) or
     (wi_gate, wi_up, wo) stacked [G, ...] in xs's dtype. Returns ys
     [V * T, M]: every laid-out row through its group's feed-forward (rows
     of no visit's tiles are left as they were allocated)."""
@@ -361,7 +380,7 @@ def grouped_ffn_decode(xs, visits, nvis, weights, *, activation,
     isz = xs.dtype.itemsize
     tm, tf = _chunk_rows(M, F, isz), _chunk_rows(F, M, isz)
     K1, K2 = M // tm, F // tf
-    heights = _heights(T, V)
+    heights = _heights(T, V, cap)
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     H = heights[-1]
@@ -385,7 +404,7 @@ def grouped_ffn_decode(xs, visits, nvis, weights, *, activation,
         out_shape=jax.ShapeDtypeStruct((P, M), xs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=vmem_need(T, V, M, F, isz, gated)),
+            vmem_limit_bytes=vmem_need(T, V, M, F, isz, gated, cap)),
         interpret=interpret,
     )(*visits, nvis, xs, *weights)
 
@@ -395,39 +414,39 @@ def row_tile(rows: int, experts: int) -> int:
     experts: the smallest that holds the rows an expert expects, so that
     the padding a group brings stays under its rows (decode steps, 2-12
     rows an expert: 16; a refill step at 51: 64), the largest when none
-    does (:func:`fits` keeps such a step off the kernel). A visit spans
-    all of a group's tiles up to 128 rows, so a group over its tile
-    costs rows of padding and no second stream."""
+    does (a refill step at the ridge, 256 rows an expert). A visit spans
+    all of a group's tiles up to :func:`span_cap` rows, so a group over
+    its tile costs rows of padding and no second stream."""
     return next((t for t in _ROW_TILES if rows <= t * experts),
                 _ROW_TILES[-1])
 
 
-def kernel_impl(rows: int, experts: int, weights, dtype) -> Optional[str]:
-    """Which implementation a sparse layer of this shape takes in serving:
-    "pallas" on a TPU backend when the routed rows are a weight stream
-    (an expert's expected rows fit the largest row tile, under the chip's
-    ridge of ~240 rows an expert) over plain floating stacks whose widths
-    tile;
-    None (``ragged_dot``) otherwise, and anywhere but on a TPU."""
+def kernel_impl(weights, dtype) -> Optional[str]:
+    """Which implementation a sparse layer takes in serving: "pallas" on
+    a TPU backend over plain floating stacks whose widths tile, however
+    many rows an expert expects (:func:`row_tile` and :func:`span_cap`
+    follow them, from a decode step's 2-4 to a refill step's 256 at the
+    chip's ridge); None (``ragged_dot``) otherwise, and anywhere but on a
+    TPU."""
     if jax.default_backend() != "tpu":
         return None
-    return "pallas" if fits(rows, experts, weights, dtype) else None
+    return "pallas" if fits(weights, dtype) else None
 
 
-def fits(rows: int, experts: int, weights, dtype) -> bool:
-    """The shape rule of :func:`kernel_impl`, backend apart. A packed or
-    integer stack (no ``dtype`` of the compute type) does not fit."""
+def fits(weights, dtype) -> bool:
+    """The rule of :func:`kernel_impl`, backend apart: operand types and
+    widths. A packed or integer stack (no ``dtype`` of the compute type)
+    does not fit."""
     dtype = jnp.dtype(dtype)
     if not jnp.issubdtype(dtype, jnp.floating) \
             or any(getattr(w, "dtype", None) != dtype for w in weights):
         return False
     M, F = weights[-1].shape[2], weights[-1].shape[1]
-    return rows <= _ROW_TILES[-1] * experts \
-        and M % 128 == 0 and F % 128 == 0
+    return M % 128 == 0 and F % 128 == 0
 
 
 def layout_and_run(tokens, eid, weights, activation, dtype, *,
-                   tile: int = ROW_TILE,
+                   tile: int = ROW_TILE, cap: int = _ROW_TILES[-1],
                    interpret: bool) -> jnp.ndarray:
     """Every routed row through its held expert. tokens [S, M]; eid [R]
     int32 with R = S x k, row r belonging to token ``r // k``, its local
@@ -436,7 +455,7 @@ def layout_and_run(tokens, eid, weights, activation, dtype, *,
     R = eid.shape[0]
     k = R // tokens.shape[0]
     G = weights[0].shape[0]
-    dest, visits, nvis, _ = group_layout(eid, G, tile)
+    dest, visits, nvis, _ = group_layout(eid, G, tile, cap)
     P = visits[0].shape[0] * tile
     src = jnp.full((P,), tokens.shape[0], jnp.int32).at[dest].set(
         jnp.arange(R, dtype=jnp.int32) // k, mode="drop")
@@ -444,5 +463,6 @@ def layout_and_run(tokens, eid, weights, activation, dtype, *,
                   fill_value=0)
     with region("moe_experts"):
         ys = grouped_ffn_decode(xs, visits, nvis, tuple(weights),
-                                activation=activation, interpret=interpret)
+                                activation=activation, cap=cap,
+                                interpret=interpret)
     return jnp.take(ys, dest, axis=0, mode="fill", fill_value=0)

@@ -85,6 +85,42 @@ CASES = {
     "spans-at-the-64-row-tile": dict(
         k=1, held=None, router={}, normalize=False,
         rows=(65, 128, 129, 200, 0, 64, 3, 0), tile=64),
+    # a refill step at the chip's ridge: more than 128 rows an expert, so
+    # the layout takes the 128-row tile under the 512-row span and a hit
+    # expert is ONE visit (``streams``: the times its matrices stream).
+    # Every expert held (OLMoE's form), 256 rows an expert; one has none
+    "ridge-every-expert-held": dict(
+        k=1, held=None, router={}, normalize=False,
+        rows=(256, 241, 272, 0, 255, 257, 129, 384),
+        streams=(1, 1, 1, 0, 1, 1, 1, 1)),
+    # half the rows in no held group (Mellum2's form): never laid out
+    "ridge-half-in-no-held-group": dict(
+        k=1, held=(0, 4), router={}, normalize=False,
+        rows=(256, 260, 0, 250, 300, 280, 200, 254), streams=(1, 1, 0, 1)),
+    "ridge-second-half-held": dict(
+        k=1, held=(4, 4), router={}, normalize=True,
+        rows=(256, 260, 0, 250, 300, 280, 200, 254), streams=(1, 1, 1, 1)),
+    # ~400 rows an expert: operands of 384 and 512 rows
+    "ridge-400-rows-an-expert": dict(
+        k=1, held=None, router={}, normalize=False,
+        rows=(400, 385, 415, 512, 390, 0, 401, 397),
+        streams=(1, 1, 1, 1, 1, 0, 1, 1)),
+    # groups past the tall cap: 513 and 700 rows are two visits, 1,025 three
+    "ridge-groups-past-the-cap": dict(
+        k=1, held=None, router={}, normalize=False,
+        rows=(513, 256, 700, 0, 130, 1025, 384, 8),
+        streams=(2, 1, 2, 0, 1, 3, 1, 1)),
+    "ridge-ungated": dict(
+        k=1, held=None, router={}, normalize=False, gated=False,
+        rows=(256, 0, 400, 513, 255, 257, 128, 300),
+        streams=(1, 0, 1, 2, 1, 1, 1, 1)),
+    "ridge-ungated-in-a-share": dict(
+        k=1, held=(2, 4), router={}, normalize=False, gated=False,
+        rows=(256, 300, 400, 0, 513, 257, 128, 300), streams=(1, 0, 2, 1)),
+    # top-2 of real logits, no row steered: 1,200 tokens' 2,400 rows over
+    # 8 experts, ~300 each, the held half's laid out
+    "ridge-top-2-unsteered": dict(k=2, held=(0, 4), router={},
+                                  normalize=True, S=1200),
 }
 
 
@@ -125,9 +161,19 @@ def test_kernel_is_ragged_dot_and_the_dense_reference(name, dtype,
                                         c["tile"]))
         assert streams.max() == 2 and (streams[sizes[first:first + count]
                                                <= 128] <= 1).all()
+    elif name.startswith("ridge"):
+        R = tokens.shape[0] * c["k"]
+        assert R > 128 * E
+        assert (gf.row_tile(R, E), gf.span_cap(R, E)) == (128, 512)
+        if "streams" in c:
+            first, count = c["held"] or (0, E)
+            assert tuple(np.asarray(gf.streams(
+                np.asarray(c["rows"])[first:first + count], 128, 512))) \
+                == c["streams"]
     else:
         assert gf.row_tile(tokens.shape[0] * c["k"], E) \
             == (64 if name == "forty-rows-an-expert" else 16)
+        assert gf.span_cap(tokens.shape[0] * c["k"], E) == 128
     call = dict(normalize_weights=c["normalize"], held=c["held"], **router)
     want, _ = grouped_moe_ffn(tokens, logits, c["k"], weights, jax.nn.silu,
                               dtype, **call)
@@ -181,35 +227,38 @@ def test_the_shares_halves_sum_to_the_whole():
     assert float(jnp.abs(halves[0] + halves[1] - uncut).max()) < 1e-5
 
 
-@pytest.mark.parametrize("tile", [16, 64])
+@pytest.mark.parametrize("tile,cap", [(16, 128), (64, 128), (128, 512),
+                                      (64, 512)])
 @pytest.mark.parametrize("sizes", [(0, 0, 0, 0), (1, 0, 17, 0), (0, 64, 0, 0),
                                    (16, 16, 16, 16), (3, 5, 2, 7),
                                    (17, 32, 33, 64, 129, 0), (300, 0, 5, 0),
-                                   (128, 129, 0, 257)])
-def test_layout_puts_every_group_at_a_tile_and_within_its_bound(sizes, tile):
+                                   (128, 129, 0, 257), (512, 513, 256, 1025)])
+def test_layout_puts_every_group_at_a_tile_and_within_its_bound(sizes, tile,
+                                                                cap):
     G, T = len(sizes), tile
     elsewhere = 9
     eid = np.concatenate([np.full(n, g) for g, n in enumerate(sizes)]
                          + [np.full(elsewhere, G)]).astype(np.int32)
     np.random.default_rng(0).shuffle(eid)
     dest, (gid, first, ntile), nvis, got_sizes = jax.device_get(
-        gf.group_layout(jnp.asarray(eid), G, T))
+        gf.group_layout(jnp.asarray(eid), G, T, cap))
     V = gf.visits_bound(len(eid), G, T)
     tiles = [-(-n // T) for n in sizes]
     assert tuple(got_sizes) == sizes
     # the visit table: ONE visit a group with a row while its tiles are
-    # within the 128-row span, one more for every 128 rows beyond
-    per = 128 // T
+    # within the span cap (128 rows, or a ridge call's 512), one more for
+    # every cap of rows beyond
+    per = cap // T
     want = [(g, t0 + k, min(per, t - k))
             for g, (t, t0) in enumerate(zip(tiles, np.cumsum([0] + tiles)))
             for k in range(0, t, per)]
     assert gid.shape == first.shape == ntile.shape == (V,)
     assert int(nvis[0]) == len(want) <= sum(tiles) <= V
     assert list(zip(gid, first, ntile))[:len(want)] == want
-    streams = np.asarray(gf.streams(jnp.asarray(sizes, jnp.int32), T))
+    streams = np.asarray(gf.streams(jnp.asarray(sizes, jnp.int32), T, cap))
     assert list(streams) == [sum(g == w[0] for w in want) for g in range(G)]
     assert all(streams[g] == (n > 0) for g, n in enumerate(sizes)
-               if -(-n // T) * T <= 128)
+               if -(-n // T) * T <= cap)
     # behind the last visit it repeats (with no visit at all nothing
     # reads the lists)
     if want:
@@ -253,37 +302,109 @@ def test_a_visit_writes_back_its_own_tiles_alone():
 
 
 def test_who_takes_which_path(monkeypatch):
-    """The rule is shapes, operand types and the backend: on a TPU, steps
-    whose routed rows are a weight stream take the kernel at the row tile
-    that holds an expert's expected rows (every decode step 16, Solar's
-    refill step 64); a step at the chip's ridge (OLMoE's refill, 256 rows
-    an expert), quantised stacks and every other backend keep
-    ``ragged_dot``."""
+    """The rule is operand types, widths and the backend: on a TPU, over
+    plain floating stacks, every step takes the kernel at the row tile
+    that holds an expert's expected rows (every decode step 16 or 32,
+    Solar's refill step 64) under a 128-row span, and a step at the
+    chip's ridge (OLMoE's and Mellum2's [4, 512] refill, 256 rows an
+    expert) at the 128-row tile under a 512-row span; quantised stacks
+    and every other backend keep ``ragged_dot``."""
     bf = jnp.bfloat16
     solar = tuple(jax.ShapeDtypeStruct(s, bf) for s in
                   ((40, 4096, 1280), (40, 4096, 1280), (40, 1280, 4096)))
     olmoe = tuple(jax.ShapeDtypeStruct(s, bf) for s in
                   ((64, 2048, 1024), (64, 2048, 1024), (64, 1024, 2048)))
-    assert gf.fits(128 * 8, 320, solar, bf)          # 3.2 rows an expert
-    assert gf.fits(32 * 8, 64, olmoe, bf)            # 4
-    assert gf.fits(16 * 8, 64, olmoe, bf)            # a [16, 1] bucket: 2
-    assert gf.fits(4 * 512 * 8, 320, solar, bf)      # the refill: 51
-    assert not gf.fits(4 * 512 * 8, 64, olmoe, bf)   # 256, at the ridge
-    assert [gf.row_tile(r, e) for r, e in
-            ((1024, 320), (256, 64), (128, 64), (16384, 320), (4096, 64),
-             (16384, 64))] == [16, 16, 16, 64, 64, 128]
-    assert not gf.fits(32 * 8, 64, olmoe, jnp.float32)   # stacks to cast
+    mellum = tuple(jax.ShapeDtypeStruct(s, bf) for s in
+                   ((32, 2304, 896), (32, 2304, 896), (32, 896, 2304)))
+    assert gf.fits(solar, bf) and gf.fits(olmoe, bf) and gf.fits(mellum, bf)
+    # (routed rows, router outputs): decode steps at 3.2, 4 and 2 rows an
+    # expert, Solar's refill at 51, 64 and 32 exactly, then past 128
+    shapes = ((1024, 320), (256, 64), (128, 64), (16384, 320), (4096, 64),
+              (2048, 64), (16384, 64), (8193, 64), (8192, 64), (65536, 64))
+    assert [gf.row_tile(r, e) for r, e in shapes] \
+        == [16, 16, 16, 64, 64, 32, 128, 128, 128, 128]
+    assert [gf.span_cap(r, e) for r, e in shapes] \
+        == [128, 128, 128, 128, 128, 128, 512, 512, 128, 512]
+    assert not gf.fits(olmoe, jnp.float32)           # stacks to cast
     int8 = tuple(jax.ShapeDtypeStruct(w.shape, jnp.int8) for w in olmoe)
-    assert not gf.fits(32 * 8, 64, int8, jnp.int8)
+    assert not gf.fits(int8, jnp.int8)
     narrow = tuple(jax.ShapeDtypeStruct(s, bf) for s in
                    ((8, 64, 96), (8, 64, 96), (8, 96, 64)))
-    assert not gf.fits(16, 8, narrow, bf)            # lanes do not tile
-    assert not gf.fits(32 * 8, 64, (object(),) * 3, bf)  # a packed weight
+    assert not gf.fits(narrow, bf)                   # lanes do not tile
+    assert not gf.fits((object(),) * 3, bf)          # a packed weight
     # the CPU default stays XLA, whatever the shapes
-    assert gf.kernel_impl(32 * 8, 64, olmoe, bf) is None
+    assert gf.kernel_impl(olmoe, bf) is None
+    assert gf.kernel_impl(mellum, bf) is None
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert gf.kernel_impl(32 * 8, 64, olmoe, bf) == "pallas"
-    assert gf.kernel_impl(4 * 512 * 8, 64, olmoe, bf) is None
+    assert gf.kernel_impl(olmoe, bf) == gf.kernel_impl(mellum, bf) \
+        == gf.kernel_impl(solar, bf) == "pallas"
+    assert gf.kernel_impl(int8, jnp.int8) is None
+    assert gf.kernel_impl(olmoe, jnp.float32) is None
+
+
+#: (rows, router outputs, held experts, hidden, expert width, gated) of
+#: every call the ten cells made through the kernel before a ridge call
+#: took it too, and what the module said of each then (commit 451879e):
+#: (row tile, layout rows, heights, ``vmem_need``)
+PARENT_CALLS = {
+    "olmoe-decode": ((256, 64, 64, 2048, 1024, True),
+                     (16, 1216, (16, 32, 64, 128), 14680064)),
+    "solar2-decode": ((1024, 320, 40, 4096, 1280, True),
+                      (16, 1616, (16, 32, 64, 128), 16515072)),
+    "pangu-decode": ((1024, 256, 8, 7680, 2048, True),
+                     (16, 1136, (16, 32, 64, 128), 26214400)),
+    "kimi-decode": ((1024, 256, 64, 2304, 1024, True),
+                    (16, 1984, (16, 32, 64, 128), 13107200)),
+    "nemotron-decode": ((1536, 128, 64, 2688, 1920, False),
+                        (16, 2496, (16, 32, 64, 128), 12648448)),
+    "mellum2-decode": ((2048, 64, 32, 2304, 896, True),
+                       (32, 3040, (32, 64, 128), 12582912)),
+    "solar2-refill": ((16384, 320, 40, 4096, 1280, True),
+                      (64, 18880, (64, 128), 16515072)),
+    "pangu-refill": ((16384, 256, 8, 7680, 2048, True),
+                     (64, 16832, (64, 128), 26214400)),
+    "kimi-refill": ((16384, 256, 64, 2304, 1024, True),
+                    (64, 20416, (64, 128), 13107200)),
+    "nemotron-refill": ((12288, 128, 64, 2688, 1920, False),
+                        (128, 20352, (128,), 12648448)),
+}
+
+
+@pytest.mark.parametrize("call", sorted(PARENT_CALLS))
+def test_calls_under_the_ridge_keep_their_tile_heights_and_vmem(call):
+    """An edit to the ridge calls' cap or heights must not move a call
+    that expects at most 128 rows an expert: its row tile, its layout,
+    its heights (so its Mosaic body) and the VMEM it asks for are what
+    they were."""
+    (rows, experts, held, M, F, gated), want = PARENT_CALLS[call]
+    tile, cap = gf.row_tile(rows, experts), gf.span_cap(rows, experts)
+    V = gf.visits_bound(rows, held, tile)
+    assert cap == 128
+    assert (tile, V * tile, gf._heights(tile, V, cap),
+            gf.vmem_need(tile, V, M, F, 2, gated, cap)) == want
+    # and the defaults are that cap
+    assert gf._heights(tile, V) == want[2]
+    assert gf.vmem_need(tile, V, M, F, 2, gated) == want[3]
+
+
+@pytest.mark.parametrize("name,held,M,F,rows_of,need", [
+    ("mellum2", 32, 2304, 896, 20352, 25952256),
+    ("olmoe", 64, 2048, 1024, 24448, 27262976)])
+def test_a_ridge_call_asks_vmem_for_its_512_row_span(name, held, M, F,
+                                                     rows_of, need):
+    """The [4, 512] refill step of the two cells at 256 rows an expert:
+    a 128-row tile, heights rising by a tile to the 512-row cap, and a
+    ``vmem_limit_bytes`` that counts that span's rows and sums from the
+    shapes (22-25 MB where a 128-row span asks 12-15)."""
+    rows, experts = 4 * 512 * 8, 64
+    tile, cap = gf.row_tile(rows, experts), gf.span_cap(rows, experts)
+    V = gf.visits_bound(rows, held, tile)
+    assert (tile, cap, V * tile) == (128, 512, rows_of)
+    assert gf._heights(tile, V, cap) == (128, 256, 384, 512)
+    asked = gf.vmem_need(tile, V, M, F, 2, True, cap)
+    assert asked == need
+    assert asked - gf.vmem_need(tile, V, M, F, 2, True) \
+        == (512 - 128) * (2 * M * 2 + (2 * F + 2 * M) * 4)
 
 
 def test_training_layer_keeps_ragged_dot_and_its_gradient():
@@ -325,8 +446,8 @@ def _spy_on_layouts(monkeypatch):
     seen = []
     real = gf.group_layout
 
-    def spying(eid, groups, tile=gf.ROW_TILE):
-        out = real(eid, groups, tile)
+    def spying(eid, groups, *tiling):
+        out = real(eid, groups, *tiling)
         jax.debug.callback(lambda s: seen.append(np.asarray(s)), out[3])
         return out
     monkeypatch.setattr(gf, "group_layout", spying)
@@ -433,3 +554,47 @@ def test_fused_loop_counts_experts_hit_and_reads(family, monkeypatch):
     want = eng3.decode_batch(uids, [f3[u] for u in uids], 4)
     assert {u: list(map(int, t)) for u, t in toks.items()} \
         == {u: list(map(int, t)) for u, t in want.items()}
+
+
+@pytest.mark.parametrize("family", ["olmoe", "solar_open2", "dense"])
+def test_prefill_steps_count_tokens_through_the_kernel(family, monkeypatch):
+    """``moe_prefill_tokens`` counts the real positions of every prefill
+    step of a model with routed experts and ``moe_prefill_kernel_tokens``
+    those of them in steps whose shape took the grouped kernel, by the
+    choice the runner itself makes: none on the CPU, where the steps run
+    ``ragged_dot``; all of them once the choice says so, and the steps
+    then do run the kernel and serve the same first tokens. A dense
+    model counts neither."""
+    build = {"olmoe": _olmoe_engine, "solar_open2": _solar_engine,
+             "dense": _dense_engine}[family]
+    eng, vocab = build()
+    rng = np.random.default_rng(3)
+    uids = [0, 1, 2]
+    prompts = [rng.integers(1, vocab, 5 + i).tolist() for i in uids]
+    want = eng.put(uids, prompts, _greedy=True)
+    stats = eng.pipeline_stats
+    real = sum(map(len, prompts))
+    assert stats["prefill_tokens_real"] == real
+    assert stats["moe_prefill_kernel_tokens"] == 0
+    assert stats["moe_prefill_tokens"] == (0 if family == "dense" else real)
+    # a one-token step is a decode step: not a prefill token
+    eng.put(uids, [[want[u]] for u in uids], _greedy=True)
+    assert eng.pipeline_stats["moe_prefill_tokens"] \
+        == stats["moe_prefill_tokens"]
+    if family == "dense":
+        return
+    monkeypatch.setattr(gf, "kernel_impl", lambda *a: "interpret")
+    seen = _spy_on_layouts(monkeypatch)
+    jax.clear_caches()
+    eng2, _ = build()
+    got = eng2.put(uids, prompts, _greedy=True)
+    jax.effects_barrier()
+    stats = eng2.pipeline_stats
+    assert stats["moe_prefill_tokens"] \
+        == stats["moe_prefill_kernel_tokens"] == real
+    # the steps the counter spoke for went through the kernel's layout
+    assert len(seen) == stats["prefill_steps"] \
+        * eng2.runner.model_cfg.num_layers > 0
+    assert got == want
+    monkeypatch.undo()
+    jax.clear_caches()

@@ -452,8 +452,8 @@ def test_an_expert_width_of_half_a_lane_group_more_equals_the_plain_product(
     x, logits = f(S, M), f(S, E)
     wi_s, wo_s = pad_experts(wi, wo)
     assert wi_s.shape == (E, M, 256) and wo_s.shape == (E, 256, M)
-    assert not grouped_ffn.fits(S * k, E, (wi, wo), jnp.float32)
-    assert grouped_ffn.fits(S * k, E, (wi_s, wo_s), jnp.float32)
+    assert not grouped_ffn.fits((wi, wo), jnp.float32)
+    assert grouped_ffn.fits((wi_s, wo_s), jnp.float32)
     with jax.default_matmul_precision("highest"):
         got, _ = grouped_moe_ffn(x, logits, k, (wi_s, wo_s), relu2,
                                  jnp.float32, score="sigmoid",

@@ -273,6 +273,21 @@ def test_olmoe_decode_loop_compiles_without_an_expert_by_rows_temporary(
     # and the loop's temporaries stay far under one expert stack
     assert exe.memory_analysis().temp_size_in_bytes \
         < experts * 2048 * 1024 * 2
+    # the [4, 512] refill step, 256 rows an expert (the chip's ridge): the
+    # same kernel at a 128-row tile under the 512-row span, the VMEM that
+    # span's rows and sums need, and no ragged-dot
+    from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
+    from deepspeed_tpu.ops.kernels import grouped_ffn
+    hlo = runner._step_greedy.trace(
+        params, pool, RaggedBatch(i32((4, 512)), i32((4,)), i32((4,)),
+                                  i32((4, 2)))).lower(
+                                      lowering_platforms=("tpu",)
+                                  ).compile().as_text()
+    assert "ragged-dot" not in hlo
+    assert len(re.findall(
+        r"%grouped_ffn_decode[\w\-.]* = bf16\[24448,2048\]", hlo)) == layers
+    assert {a for a, _ in _scoped_vmem(hlo, "grouped_ffn_decode")} == {
+        grouped_ffn.vmem_need(128, 191, 2048, 1024, 2, True, 512)}
 
 
 def test_solar2_decode_loop_keeps_one_copy_of_the_state(one_chip,
@@ -903,12 +918,22 @@ def test_mellum_loop_flush_and_refill_compile_at_256_clients(one_chip,
     assert mem.temp_size_in_bytes < 2 * ring
     assert weights + pools + ring + mem.temp_size_in_bytes < 14.6e9
     # the refill step: the BlockSpec kernel a layer, six under the window
-    # region; the experts at the ridge take ragged_dot
+    # region; the experts at the ridge (256 rows an expert) in the grouped
+    # kernel too, at a 128-row tile under the 512-row span: another shape
+    # than the loop's call, which the cell's roofline reader matches
     step = runner._step_greedy.trace(
         params, kv, RaggedBatch(spec((4, 512)), spec((4,)), spec((4,)),
                                 spec((4, maxb)), spec((4,)))).lower(
                                     lowering_platforms=("tpu",)).compile()
-    names = Counter(n for n in _mosaic_call_names(step.as_text())
-                    if n.startswith("rg."))
-    assert names == {"rg.attn_window": 6, "rg.attn_core": 2}
+    hlo = step.as_text()
+    names = Counter(_mosaic_call_names(hlo))
+    assert names == {"rg.attn_window": 6, "rg.attn_core": 2,
+                     "grouped_ffn_decode": 8}
+    assert "ragged-dot" not in hlo
+    assert len(re.findall(
+        r"%grouped_ffn_decode[\w\-.]* = bf16\[20352,2304\]", hlo)) == 8
+    from deepspeed_tpu.ops.kernels import grouped_ffn
+    asked = grouped_ffn.vmem_need(128, 159, 2304, 896, 2, True, 512)
+    assert {a for a, _ in _scoped_vmem(hlo, "grouped_ffn_decode")} \
+        == {asked} and 20e6 < asked < 27e6
     assert step.memory_analysis().alias_size_in_bytes == pools

@@ -18,6 +18,7 @@ wider table behind it (``CHANGES.md`` records the last run).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import sys
 import time
@@ -601,9 +602,153 @@ def row_train_attribution():
          f"{max(TRAIN_STEP_WALL_COMPONENTS, key=lambda c: comps[c])}")
 
 
+#: (name, held experts, router outputs, hidden, expert width) of the two
+#: cells whose [4, 512] refill step routes 256 rows an expert
+_RIDGE_SHAPES = (("mellum2", 32, 64, 2304, 896), ("olmoe", 64, 64, 2048, 1024))
+#: (row tile, the heights past 128 rows): the first is what the module
+#: ships; the last (no height past 128) is the floor to beat, an expert at
+#: the ridge taking two or three visits and as many streams
+_RIDGE_CANDIDATES = ((128, (256, 384, 512)), (128, (256, 512)), (128, (512,)),
+                     (64, (256, 384, 512)), (128, (256,)), (128, ()))
+
+
+def _ms(fn, *args, n: int = 20) -> float:
+    """Milliseconds a call, host clock over ``n`` calls in flight behind
+    two warm ones (a call here is 0.5-5 ms of device time)."""
+    jax.block_until_ready([fn(*args) for _ in range(2)])
+    t0 = time.perf_counter()
+    jax.block_until_ready([fn(*args) for _ in range(n)])
+    return (time.perf_counter() - t0) / n * 1e3
+
+
+def row_refill_experts(shapes=_RIDGE_SHAPES, tokens: int = 2048, k: int = 8,
+                       interpret: bool = False):
+    """The routed experts of a refill step at the chip's ridge, the call
+    alone: XLA's three ``ragged_dot`` calls against the grouped kernel at
+    each candidate row tile and set of heights, at Mellum2's and OLMoE's
+    shapes, both checked against every held expert on every token; and
+    ``ragged_dot`` again with the rows behind the last group cut off and
+    at a width of whole 1,024 lanes, which says why XLA's call is slower
+    at Mellum2's shape. Prints its table; a time is the host's clock over
+    20 calls."""
+    from deepspeed_tpu.moe.sharded_moe import grouped_moe_ffn, route_topk
+    from deepspeed_tpu.ops.kernels import grouped_ffn as gf
+    bf, f32, act = jnp.bfloat16, jnp.float32, jax.nn.silu
+    hi = jax.lax.Precision.HIGHEST
+    R = tokens * k
+    shipped = gf._TALL_HEIGHTS
+    worst, ok, told = 0.0, True, []
+
+    impl = "interpret" if interpret else "pallas"
+
+    @jax.jit
+    def ragged3(xs, gs, wg, wu, wo):
+        h = act(jax.lax.ragged_dot(xs, wg, gs)) * jax.lax.ragged_dot(
+            xs, wu, gs)
+        return jax.lax.ragged_dot(h, wo, gs)
+
+    for seed, (name, G, E, M, F) in enumerate(shapes):
+        ks = _keys(50 + seed, 6)
+        x = jax.random.normal(ks[0], (tokens, M), bf)
+        logits = jax.random.normal(ks[1], (tokens, E), f32)
+        ws = tuple((jax.random.normal(kk, shape, f32) * 0.03).astype(bf)
+                   for kk, shape in zip(ks[2:], ((G, M, F), (G, M, F),
+                                                 (G, F, M))))
+        # every held expert on every token, masked by the router's choice
+        top, w_sel, _ = route_topk(logits, k, normalize=True)
+
+        @jax.jit
+        def dense(x, ws, top, w_sel):
+            def one(out, g):
+                wg, wu, wo = (w[g].astype(f32) for w in ws)
+                xf = x.astype(f32)
+                h = act(jnp.dot(xf, wg, precision=hi)) \
+                    * jnp.dot(xf, wu, precision=hi)
+                gate = jnp.sum(jnp.where(top == g, w_sel, 0.0), -1)
+                return out + gate[:, None] * jnp.dot(
+                    h.astype(bf).astype(f32), wo, precision=hi), None
+            return jax.lax.scan(one, jnp.zeros((tokens, M), f32),
+                                jnp.arange(G))[0]
+        want = dense(x, ws, top, w_sel)
+        scale = float(jnp.abs(want).max())
+
+        def err(got):
+            return float(jnp.abs(got.astype(f32) - want).max()) / scale
+
+        # the sorted rows the three calls see, and the sizes of the groups
+        eid = jnp.where(top < G, top, G).reshape(-1).astype(jnp.int32)
+        order = jnp.argsort(eid, stable=True)
+        xs = jnp.take(x, order // k, axis=0)
+        gs = jnp.bincount(eid, length=G).astype(jnp.int32)
+        in_groups = int(gs.sum())
+        whole = {i: jax.jit(functools.partial(
+            grouped_moe_ffn, k=k, weights=ws, activation=act, dtype=bf,
+            normalize_weights=True, held=(0, G), impl=i))
+            for i in (None, impl)}
+        t_r3 = _ms(ragged3, xs, gs, *ws)
+        t_gate = _ms(jax.jit(jax.lax.ragged_dot), xs, ws[0], gs)
+        e_r = err(whole[None](x, logits)[0])
+        print(f"  {name}: {R} rows, {in_groups} in {G} held groups of "
+              f"{int(gs.min())}-{int(gs.max())}, [{M} x {F}]; ragged_dot: "
+              f"three calls {t_r3:.3f} ms (gate alone {t_gate:.3f}), the "
+              f"whole layer's experts {_ms(whole[None], x, logits):.3f} ms, "
+              f"err {e_r:.2e}", flush=True)
+        if in_groups < R or F % 1024:
+            # why XLA's call is slower here: the rows past the last group,
+            # or a width that is no whole number of 1,024 lanes?
+            cut = xs[:-(-in_groups // 128) * 128]
+            wide = tuple(jnp.pad(w, [(0, 0)] + [
+                (0, -(-d // 1024) * 1024 - d if d == F else 0)
+                for d in w.shape[1:]]) for w in ws)
+            print(f"    ragged_dot, three calls: rows behind the last group "
+                  f"cut off ({cut.shape[0]} rows) "
+                  f"{_ms(ragged3, cut, gs, *ws):.3f} ms; width "
+                  f"{wide[0].shape[2]} {_ms(ragged3, xs, gs, *wide):.3f}"
+                  f" ms; both {_ms(ragged3, cut, gs, *wide):.3f} ms",
+                  flush=True)
+        for tile, tall in _RIDGE_CANDIDATES:
+            # the heights are the module's constant: a candidate set is
+            # tried by standing in for it, and the traced calls forgotten
+            gf._TALL_HEIGHTS = tall
+            jax.clear_caches()
+            cap = (tall or (128,))[-1]
+            run = jax.jit(functools.partial(
+                gf.grouped_ffn_decode, activation=act, cap=cap,
+                interpret=interpret))
+            dest, visits, nvis, sizes = gf.group_layout(eid, G, tile, cap)
+            P = visits[0].shape[0] * tile
+            src = jnp.full((P,), tokens, jnp.int32).at[dest].set(
+                jnp.arange(R, dtype=jnp.int32) // k, mode="drop")
+            laid = jnp.take(x, src, axis=0, mode="fill", fill_value=0)
+            t_k = _ms(run, laid, visits, nvis, ws)
+            ys = jnp.take(run(laid, visits, nvis, ws), dest, axis=0,
+                          mode="fill", fill_value=0)
+            e_k = err(jnp.sum(ys.reshape(tokens, k, M).astype(f32)
+                              * w_sel[..., None], axis=1))
+            streams = int(gf.streams(sizes, tile, cap).sum())
+            line = (f"    kernel at a {tile}-row tile, heights "
+                    f"{gf._heights(tile, P // tile, cap)}: {t_k:.3f} ms, "
+                    f"{P} layout rows, {streams} streams of "
+                    f"{int((sizes > 0).sum())} hit, err {e_k:.2e}")
+            if tall == shipped and tile == gf.row_tile(R, E):
+                gf._TALL_HEIGHTS = shipped
+                t_w = _ms(whole[impl], x, logits)
+                line += f"; SHIPPED, the whole layer's experts {t_w:.3f} ms"
+                told.append(f"{name} {t_k:.2f} ms against {t_r3:.2f}")
+                ok = ok and t_k < t_r3
+            print(line, flush=True)
+            worst = max(worst, e_k)
+            ok = ok and e_k <= max(1.5 * e_r, 2e-2)
+        gf._TALL_HEIGHTS = shipped
+        jax.clear_caches()
+    return ok, f"kernel alone against three ragged_dot calls: " \
+        f"{'; '.join(told)}; worst err {worst:.2e} of the dense scale"
+
+
 ENGINE_ROWS = (row_tp_paged_decode, row_async_parity, row_program_audit,
                row_prefix_cache, row_tp_overlap, row_hier_kv,
-               row_spec_decode, row_serve_attribution, row_train_attribution)
+               row_spec_decode, row_serve_attribution, row_train_attribution,
+               row_refill_experts)
 
 
 def main() -> int:
