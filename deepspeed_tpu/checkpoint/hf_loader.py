@@ -548,6 +548,21 @@ def _nemotron_h_names(state, hf_cfg):
     return out
 
 
+def _minicpm_sala_names(state, hf_cfg):
+    """minicpm_sala: both kinds of mixer sit under ``self_attn`` in the
+    checkpoint; ``mixer_types`` says which a layer's is, and a Lightning
+    layer's tensors move to ``lightning.`` so that the map can tell them
+    apart (its tree is ``layer_i/lin``)."""
+    mixers = hf_cfg["mixer_types"]
+    out = {}
+    for name, arr in state.items():
+        m = re.match(r"model\.layers\.(\d+)\.self_attn\.(.*)", name)
+        if m and mixers[int(m.group(1))] == "lightning-attn":
+            name = f"model.layers.{m.group(1)}.lightning.{m.group(2)}"
+        out[name] = arr
+    return out
+
+
 SPECIAL_HANDLERS = {
     "pangu_ultra_moe": _pangu_ultra_moe_names,
     "nemotron_h": _nemotron_h_names,
@@ -560,6 +575,7 @@ SPECIAL_HANDLERS = {
     "qwen2_moe": _qwen2_moe_experts,
     "olmoe": _qwen2_moe_experts,     # the same per-expert names
     "mellum": _qwen2_moe_experts,    # assumed: Qwen3MoE's (as its config keys)
+    "minicpm_sala": _minicpm_sala_names,
 }
 
 _MOE_STACKED_RULES = [
@@ -686,6 +702,24 @@ _NEMOTRON_H_MAP = _LLAMA_MAP[:6] + [
      "layer_{0}/shared_{1}_proj/kernel", "linear"),
 ]
 
+#: assumed names (the family's MiniCPM4 code for the sparse layers, with
+#: ``o_gate`` for its output gate; ``z_proj`` and ``o_norm`` for a Lightning
+#: layer's gate and output norm): the catalog row has the config's keys, not
+#: the checkpoint's
+_MINICPM_SALA_MAP = _LLAMA_MAP + [
+    (r"model\.layers\.(\d+)\.self_attn\.(q|k)_norm\.weight",
+     "layer_{0}/attn/{1}_norm/scale", "vector"),
+    (r"model\.layers\.(\d+)\.self_attn\.o_gate\.weight",
+     "layer_{0}/attn/g_proj/kernel", "linear"),
+    (r"model\.layers\.(\d+)\.lightning\.(q|k|v|o)_proj\.weight",
+     "layer_{0}/lin/{1}_proj/kernel", "linear"),
+    (r"model\.layers\.(\d+)\.lightning\.z_proj\.weight",
+     "layer_{0}/lin/g_proj/kernel", "linear"),
+    (r"model\.layers\.(\d+)\.lightning\.(q|k|o)_norm\.weight",
+     "layer_{0}/lin/{1}_norm/scale", "vector"),
+]
+
+ARCH_MAPS["minicpm_sala"] = _MINICPM_SALA_MAP
 ARCH_MAPS["pangu_ultra_moe"] = _PANGU_ULTRA_MOE_MAP
 ARCH_MAPS["nemotron_h"] = _NEMOTRON_H_MAP
 ARCH_MAPS["kimi_linear"] = _KIMI_LINEAR_MAP

@@ -362,7 +362,7 @@ class RaggedInferenceConfig(ConfigModel):
         # gives both reasons
         why = []
         recurrent = [k for k in kinds
-                     if k not in ("attn", "mla", "swa", None)]
+                     if k not in ("attn", "mla", "swa", "sparse", None)]
         if recurrent:
             why.append(functools.partial(stateful_refusal,
                                          kind=recurrent[0]))
@@ -372,6 +372,10 @@ class RaggedInferenceConfig(ConfigModel):
             # a sliding-window layer's rows live in a slot of the window
             # pool, which no block, manifest, shard or scale covers
             why.append(windowed_refusal)
+        if "sparse" in kinds:
+            # a block-selected layer's compressed keys live in a plane
+            # beside the pool, which no manifest, scale or shard covers
+            why.append(selecting_refusal)
         for on, feature in (
                 (self.prefix_cache, "prefix_cache"),
                 (self.spec_decode != "off", "spec_decode"),
@@ -381,6 +385,22 @@ class RaggedInferenceConfig(ConfigModel):
                 (self.ep_size > 1, "ep_size > 1")):
             if on and why:
                 raise ValueError("; ".join(r(feature) for r in why))
+        if "sparse" in kinds:
+            # the fused loop's sparse call attends over EVERY ring row
+            # without asking the selection: right only while the loop's
+            # own rows all lie in the blocks a query is forced to read
+            # (the window_size / block_size blocks ending at its own,
+            # which hold at least this many positions back)
+            sp = model_cfg.sparse
+            reach = sp.window_size - sp.block_size + 1
+            if self.decode_loop_steps > reach:
+                raise ValueError(
+                    f"decode_loop_steps={self.decode_loop_steps} is past "
+                    f"the {reach} positions a block-selected ('sparse') "
+                    f"layer's forced window always holds (window_size "
+                    f"{sp.window_size} - block_size {sp.block_size} + 1): "
+                    f"the fused loop's ring rows would be read whether "
+                    f"selected or not (set decode_loop_steps <= {reach})")
         if is_moe and self.tp_size > 1 and self.ep_size == 1:
             # tp alone would replicate the full expert set on every chip
             # AND trip the dense-branch all-reduce accounting — for MoE
@@ -474,3 +494,14 @@ def windowed_refusal(feature: str) -> str:
             f"('swa') layers: their rows live in a sequence slot of the "
             f"window pool (the last window's rows, overwritten in place), "
             f"which no block, manifest, shard or scale carries yet")
+
+
+def selecting_refusal(feature: str) -> str:
+    """The one wording of every refusal a model with block-selected
+    ('sparse') attention layers makes: the feature that cannot run over
+    the compressed-key plane its selection scores against."""
+    return (f"{feature} is not supported for a model with block-selected "
+            f"('sparse') attention layers: their compressed keys live in "
+            f"a plane beside the paged pool (a row a kernel_stride "
+            f"positions of every block), which no manifest, scale or "
+            f"shard carries yet")

@@ -263,7 +263,8 @@ class InferenceEngineV2:
             self.runner.head_dim, dtype=resolve_dtype(self.config.dtype),
             state_spec=self.runner.state_spec,
             planes=self.runner.kv_planes,
-            window_spec=self.runner.window_spec)
+            window_spec=self.runner.window_spec,
+            index_spec=self.runner.index_spec)
         #: layer kind of the model's recurrent layers (None: it has none);
         #: what needs a state snapshot refuses by this name
         self._stateful = (self.runner.state_spec or {}).get("kind")
@@ -274,6 +275,10 @@ class InferenceEngineV2:
         #: of the window pool; what would need those rows in a block, a
         #: manifest or a shard refuses by name
         self._windowed = self.kv_cache.window is not None
+        #: a model with block-selected layers keeps their compressed keys
+        #: in a plane beside the pool, which no manifest, scale or shard
+        #: covers: what would need one refuses by name
+        self._selecting = self.kv_cache.index is not None
         #: sequences name a slot (a state row, a window-pool row)
         self._slotted = bool(self._stateful) or self._windowed
         #: a sparse layer's expert stacks as the step programs see them
@@ -426,7 +431,29 @@ class InferenceEngineV2:
             # kv_bytes_live keep their meaning over such a model's FULL
             # layers
             "window_rows_live": 0, "window_rows_fetched": 0,
-            "window_rows_scored": 0, "window_bytes_live": 0}
+            "window_rows_scored": 0, "window_bytes_live": 0,
+            # models with block-selected layers, a layer's worth each, per
+            # pure-decode step (and per step of a fused loop) over the live
+            # sequences past ``dense_len``: the rows ONE sparse layer's
+            # attention must read (topk blocks a kv head, kv heads
+            # counted: 2 x 4,096 at the published sizes; the sparse
+            # decode kernel copies the listed blocks and no other) and
+            # the settled rows a dense call would have read (a kv head
+            # each, so that selected / live is the share of the context
+            # the selection's sizes imply a step reads: host arithmetic,
+            # the kernel's traced time is the evidence). Per prefill chunk
+            # (read back from the program: ``KVPool.sel_counts``) the
+            # selection blocks its real queries selected at or before
+            # their own and those the block-union kernel's tiles visited
+            # for them. ``sparse_dense_tokens``: positions (prefill and
+            # decode) whose context was below ``dense_len``, which attend
+            # over every key through the paged pool's own kernels
+            "sparse_rows_selected": 0, "sparse_rows_live": 0,
+            "sparse_prefill_blocks_selected": 0,
+            "sparse_prefill_blocks_visited": 0,
+            "sparse_dense_tokens": 0}
+        #: ``KVPool.sel_counts`` as last read back
+        self._sel_counts_seen = np.zeros((2,), np.int64)
         #: rows the decode kernel this model runs streams for a sequence
         #: of so many settled tokens (each kernel module's own arithmetic)
         if self._latent:
@@ -668,6 +695,9 @@ class InferenceEngineV2:
                 work_left, lambda: self._plan_step(greedy=_greedy), commit_one)
             if self._prefix is not None:
                 self._register_prefix(admitted)
+            # the last step's result has been read: the programs that
+            # counted are done
+            self._read_sel_counts()
             return done
 
     def _match_prefix(self, seq) -> None:
@@ -1219,6 +1249,8 @@ class InferenceEngineV2:
         """Recurrent layers whose short convolution takes the in-place
         kernel at a decode step of ``S`` rows (all of them or none)."""
         spec = self.runner.state_spec
+        if self.kv_cache.conv is None:          # no short convolution
+            return 0
         return spec["layers"] * short_conv.decode_uses_kernel(
             S, spec["conv_width"], self.kv_cache.conv.dtype)
 
@@ -1230,9 +1262,11 @@ class InferenceEngineV2:
         also what has not been carried over a latent-attention model's
         one-plane cache (config.latent_refusal); a model with both kinds
         of layer gives both reasons."""
-        from .config import (latent_refusal, stateful_refusal,
-                             windowed_refusal)
+        from .config import (latent_refusal, selecting_refusal,
+                             stateful_refusal, windowed_refusal)
         why = []
+        if self._selecting:
+            why.append(selecting_refusal(feature))
         if self._stateful:
             why.append(stateful_refusal(feature, self._stateful))
         if latent_too and self._latent:
@@ -1263,6 +1297,8 @@ class InferenceEngineV2:
         out = {"decode_kv_rows_live": live,
                "decode_kv_rows_fetched": fetched,
                "kv_bytes_live": live * self.kv_cache.kv_bytes_per_token()}
+        if self._selecting and runs:
+            out.update(self._sparse_decode_counts(runs, in_ring))
         if self._windowed and runs:
             # step t of a run reads the settled rows its window still
             # reaches, rows - max(rows + t - window + 1, 0), in whole
@@ -1285,6 +1321,41 @@ class InferenceEngineV2:
                 window_bytes_live=wlive
                 * self.kv_cache.window_bytes_per_row())
         return out
+
+    def _sparse_decode_counts(self, runs, in_ring: bool) -> Dict[str, int]:
+        """The block-selected layers' decode counters for ``runs`` (see
+        ``_decode_row_counts``): step t of a run stands at position
+        ``rows + t`` (a fused loop's) or ``rows - 1`` (a single step's own
+        row is settled), and past ``dense_len`` reads ``min(blocks at or
+        before it, topk)`` blocks a kv head. The decode counters
+        ``decode_kv_rows_*`` keep counting what a dense call would
+        read."""
+        sp = self.runner.model_cfg.sparse
+        kvh = self.runner.kv_heads
+        ran = np.asarray([r for r, _ in runs], np.int64)[:, None]
+        rows = np.asarray([n for _, n in runs], np.int64)[:, None]
+        t = np.arange(int(ran.max()), dtype=np.int64)[None, :]
+        pos = rows + t - (0 if in_ring else 1)
+        alive = t < ran
+        sparse = alive & (pos + 1 >= sp.dense_len)
+        blocks = np.minimum(pos // sp.block_size + 1, sp.topk)
+        sel = int((blocks * sp.block_size * sparse).sum()) * kvh
+        return {
+            "sparse_rows_selected": sel,
+            "sparse_rows_live": int(((pos + 1) * sparse).sum()) * kvh,
+            "sparse_dense_tokens": int((alive & ~sparse).sum())}
+
+    def _read_sel_counts(self) -> None:
+        """The prefill selection's two counts, read back from the cache
+        value (a sync: called where a prefill's result has been read)."""
+        if not self._selecting:
+            return
+        now = np.asarray(jax.device_get(self._kv_data.sel_counts), np.int64)
+        # int32 on the device: a wrap shows as a negative step
+        step = (now - self._sel_counts_seen) % (1 << 32)
+        self._sel_counts_seen = now
+        self.pipeline_stats["sparse_prefill_blocks_selected"] += int(step[0])
+        self.pipeline_stats["sparse_prefill_blocks_visited"] += int(step[1])
 
     def _kv_write_counts(self, stores, n: int) -> Dict[str, int]:
         """The pool writer's counters for ``stores``, (first position, real
@@ -1925,6 +1996,12 @@ class InferenceEngineV2:
                                C, spec["heads"], spec["d_k"], spec["d_v"])))
                 if self._latent:
                     span.count(mla_prefill_tokens=real)
+                if self._selecting:
+                    dl = self.runner.model_cfg.sparse.dense_len
+                    span.count(sparse_dense_tokens=sum(
+                        max(0, min(item.start_pos + len(item.tokens),
+                                   dl - 1) - item.start_pos)
+                        for item in sched))
                 if self._moe_stacks is not None:
                     # the choice llama_runner._moe_mlp makes, of the same
                     # operand types and widths

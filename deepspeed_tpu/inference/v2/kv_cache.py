@@ -147,7 +147,8 @@ class BlockedKVCache:
     def __init__(self, cfg: RaggedInferenceConfig, num_layers: int,
                  kv_heads: int, head_dim: int, dtype: Any = None,
                  state_spec: Optional[dict] = None, planes: int = 2,
-                 window_spec: Optional[dict] = None):
+                 window_spec: Optional[dict] = None,
+                 index_spec: Optional[dict] = None):
         """``num_layers`` counts the layers that keep K/V (the softmax
         layers of a hybrid model, every layer otherwise). ``state_spec``
         (``RaggedRunnerBase.state_spec``) asks for the per-sequence state
@@ -157,7 +158,13 @@ class BlockedKVCache:
         ``head_dim`` the stored row) is key and value at once.
         ``window_spec`` (``RaggedRunnerBase.window_spec``: ``layers`` and
         ``window``) asks for the window pool of a model with
-        sliding-window layers, which ``num_layers`` then leaves out."""
+        sliding-window layers, which ``num_layers`` then leaves out.
+        ``index_spec`` (``RaggedRunnerBase.index_spec``: ``layers`` and
+        ``stride``) asks for the compressed-key plane of a model with
+        block-selected layers (``index_plane.py``): one row a ``stride``
+        positions of every block, so the block table addresses it too. A
+        ``state_spec`` with ``taps`` 0 has no short convolution: no
+        ``conv`` array is made."""
         self.cfg = cfg
         self.planes = planes
         self.num_layers = num_layers
@@ -220,10 +227,12 @@ class BlockedKVCache:
                 for _ in range(state_spec["layers"]))
             # a slot's carried convolution inputs [taps - 1, width], laid
             # out in whole tiles as the decode step's kernel takes them
-            from ...ops.kernels.short_conv import pool_shape
-            self.conv = jnp.zeros(
-                pool_shape(state_spec["layers"], rows, state_spec["taps"],
-                           state_spec["conv_width"]), self.dtype)
+            if state_spec["taps"]:
+                from ...ops.kernels.short_conv import pool_shape
+                self.conv = jnp.zeros(
+                    pool_shape(state_spec["layers"], rows,
+                               state_spec["taps"],
+                               state_spec["conv_width"]), self.dtype)
         # the window pool: the paged pool's own form over the
         # sliding-window layers, R blocks a sequence slot + the idle
         # slot's (padding rows of a batch point there; its last block is
@@ -238,6 +247,19 @@ class BlockedKVCache:
                 (window_spec["layers"], 2,
                  (cfg.max_seqs + 1) * self.window_blocks * cfg.block_size,
                  kv_heads * head_dim), self.dtype)
+        # the compressed-key plane and the prefill selection's two counts
+        self.index = self.sel_counts = None
+        if index_spec is not None:
+            if cfg.block_size % index_spec["stride"]:
+                raise ValueError(
+                    f"block_size ({cfg.block_size}) must be a multiple of "
+                    f"the selection's kernel_stride "
+                    f"({index_spec['stride']}): a group of compressed "
+                    f"keys lies in one block")
+            self.index = jnp.zeros(
+                (index_spec["layers"], slots // index_spec["stride"],
+                 kv_heads * head_dim), self.dtype)
+            self.sel_counts = jnp.zeros((2,), jnp.int32)
 
     def pin(self, device) -> None:
         """COMMIT the pool to ``device`` (a one-device engine pins itself
@@ -251,9 +273,13 @@ class BlockedKVCache:
             self.scales = jax.device_put(self.scales, device)
         if self.state is not None:
             self.state = jax.device_put(self.state, device)
-            self.conv = jax.device_put(self.conv, device)
+            if self.conv is not None:
+                self.conv = jax.device_put(self.conv, device)
         if self.window is not None:
             self.window = jax.device_put(self.window, device)
+        if self.index is not None:
+            self.index = jax.device_put(self.index, device)
+            self.sel_counts = jax.device_put(self.sel_counts, device)
 
     @property
     def pool(self):
@@ -264,10 +290,10 @@ class BlockedKVCache:
         path). The window pool of a model with sliding-window layers
         travels in it too."""
         if self.quantized or self.state is not None \
-                or self.window is not None:
+                or self.window is not None or self.index is not None:
             from .kv_quant import KVPool
             return KVPool(self.data, self.scales, self.state, self.conv,
-                          self.window)
+                          self.window, self.index, self.sel_counts)
         return self.data
 
     def attach_prefix_cache(self, prefix: PrefixCache) -> None:
@@ -606,6 +632,8 @@ class BlockedKVCache:
         n = self.data.size * self.data.dtype.itemsize
         if self.scales is not None:
             n += self.scales.size * self.scales.dtype.itemsize
+        if self.index is not None:
+            n += self.index.size * self.index.dtype.itemsize
         return n + (self.state_bytes_per_slot()
                     + self.window_bytes_per_slot()) * (self.cfg.max_seqs + 1)
 
@@ -621,9 +649,10 @@ class BlockedKVCache:
         slot holds over all recurrent layers (0 without any)."""
         if self.state is None:
             return 0
+        conv = 0 if self.conv is None else \
+            self.conv.size * self.conv.dtype.itemsize // self.conv.shape[1]
         return sum(a.size * a.dtype.itemsize // a.shape[0]
-                   for a in self.state) \
-            + self.conv.size * self.conv.dtype.itemsize // self.conv.shape[1]
+                   for a in self.state) + conv
 
     def window_bytes_per_row(self) -> int:
         """Bytes one position holds in the window pool over all
@@ -654,6 +683,8 @@ class BlockedKVCache:
         n = per_chip(self.data)
         if self.scales is not None:
             n += per_chip(self.scales)
+        if self.index is not None:
+            n += per_chip(self.index)
         # the state and window pools are never sharded (models with
         # recurrent or sliding-window layers refuse meshes)
         return n + (self.state_bytes_per_slot()

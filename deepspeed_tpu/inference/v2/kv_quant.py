@@ -48,12 +48,23 @@ class KVPool(NamedTuple):
     over those layers alone: a sequence's slot (the one the state rows
     go by) owns R whole blocks of it for its life, logical block ``b``
     of the sequence lives in the slot's block ``b % R``, and the last
-    slot is the idle one (kv_cache.py has R)."""
+    slot is the idle one (kv_cache.py has R). A model with block-selected
+    ("sparse") attention layers carries ``index``, the compressed-key
+    plane [Ls, slots / stride, KV*D]: one row a ``stride`` consecutive
+    positions of a block (the mean of their K rows), a sparse layer and
+    all kv heads, addressed by the paged pool's own block table (block
+    ``b`` owns rows ``b x block_size / stride ..``), and ``sel_counts``
+    [2] int32, the selection blocks its prefill chunks selected and
+    visited so far (``index_plane.py``). A recurrent model without a
+    short convolution (``"lightning"``) has ``conv`` None beside its
+    ``state``."""
     data: Any
     scales: Optional[Any] = None
     state: Optional[Any] = None
     conv: Optional[Any] = None
     window: Optional[Any] = None
+    index: Optional[Any] = None
+    sel_counts: Optional[Any] = None
 
 
 class RingKV(NamedTuple):
@@ -65,13 +76,19 @@ class RingKV(NamedTuple):
     kernel found hit and the visits it made, which the sparse layers add to.
     ``lin`` is the ``(state, conv)`` pair of a model with recurrent
     layers: unlike the pool it cannot stay read-only, so it is a carry of
-    the loop and the recurrent layers update it in place."""
+    the loop and the recurrent layers update it in place. ``idx`` rides
+    along for models with block-selected layers: [Ls, S, NG, KV*D] float32,
+    the sums of the loop's own K rows over each ``stride``-group the loop
+    touches, group 0 the one that holds each sequence's first ring
+    position (``index_plane.py``): the selection sees windows that end
+    inside the ring, as attention sees the ring's rows."""
     pool: Any           # KVPool or raw pool array
     ring: Any
     t: Any
     rcount: Any
     moe_rows: Any = None
     lin: Any = None
+    idx: Any = None
 
 
 def pool_parts(kv, window: bool = False) -> Tuple[Any, Optional[Any]]:

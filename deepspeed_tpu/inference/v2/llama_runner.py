@@ -27,7 +27,8 @@ from ...telemetry.trace import region
 from .config import RaggedInferenceConfig
 from .kv_quant import lin_parts, with_lin
 from .model_runner import (RaggedBatch, RaggedRunnerBase, latent_attention,
-                           paged_attention, tp_all_reduce, woq_mm)
+                           paged_attention, sparse_paged_attention,
+                           tp_all_reduce, woq_mm)
 
 
 def _mlp_act(model_cfg):
@@ -303,6 +304,43 @@ def _mamba2_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
         mamba2_output(p, y, z, model_cfg, dtype)
 
 
+def _lightning_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, pos,
+                     valid_q, dtype):
+    """One Lightning linear-attention layer over the state pool: ``S_t =
+    lambda_h S_{t-1} + k_t v_t^T``, ``o_t = S_t^T q_t / sqrt(d)``, which
+    IS the state-space recurrence of :func:`_mamba2_mixer` at ``dt = 1``,
+    ``a = log lambda_h``, ``x = v``, ``B = k``, ``C = q / sqrt(d)``, ``D =
+    0`` (``models/minicpm_sala.py``), through the same three forms of
+    ``ops/kernels/ssd`` and the same rows and slots (:func:`_state_rows`);
+    the pool has no convolution part for it. Padded positions take a zero
+    step (``dt`` 0). Returns (kv, y [S, C, M])."""
+    from ...models.minicpm_sala import (lightning_inputs,
+                                        lightning_log_decay,
+                                        lightning_output)
+    from ...ops.kernels.ssd import mamba2_decode_update, mamba2_prefill
+    state, conv, st, slots, fresh, live = _state_rows(kv, si, batch)
+    S, C, _ = h.shape
+    x, Bm, Cm = lightning_inputs(p, h, pos, model_cfg, dtype)
+    H = model_cfg.lightning_heads
+    dt = jnp.broadcast_to(valid_q[..., None].astype(jnp.float32), (S, C, H))
+    a = lightning_log_decay(H)
+    if C == 1:
+        o, st = mamba2_decode_update(
+            st, slots, x[:, 0], dt[:, 0], a, Bm[:, 0], Cm[:, 0],
+            jnp.zeros((H,), jnp.float32), wipe=fresh & live)
+        o = o[:, None]
+    else:
+        St0 = st[slots]                                   # [S, H, dv, dk]
+        o, Sn = mamba2_prefill(
+            x, dt, a, Bm, Cm,
+            jnp.where(fresh[:, None, None, None], 0.0, St0),
+            chunk=model_cfg.lightning_chunk)
+        st = st.at[slots].set(
+            jnp.where(live[:, None, None, None], Sn, St0))
+    return _with_layer_state(kv, state, conv, si, st), \
+        lightning_output(p, o, h, model_cfg, dtype)
+
+
 def _mla_mixer(p, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
                pos, valid_q, dtype):
     """One latent-attention (MLA) layer over plane ``plane`` of the
@@ -353,7 +391,8 @@ def _mla_mixer(p, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
 
 
 def _attn_mixer(pa, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
-                pos, valid_q, dtype, kind: str = "attn", ring_layer=None):
+                pos, valid_q, dtype, kind: str = "attn", ring_layer=None,
+                index_layer=None):
     """One softmax-attention layer over plane ``plane`` of the paged
     cache: projections (qkv bias, QK-norm over the whole projection or a
     head by ``qk_norm``'s value), rotary positions unless the family has
@@ -363,8 +402,11 @@ def _attn_mixer(pa, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
     layer of a model that lists such layers attends inside the model's
     ``sliding_window``, over plane ``plane`` of the WINDOW pool, in its
     own region ``attn_window``; and a model that gives its kinds their
-    own rotary code (``rope_of``) has the code as a table here. Returns
-    (kv, y)."""
+    own rotary code (``rope_of``) has the code as a table here; a
+    ``"sparse"`` layer reads the blocks its selection names
+    (``sparse_paged_attention``: store, selection, attention over the
+    selected blocks), its compressed keys in plane ``index_layer``.
+    Returns (kv, y)."""
     S, C, _ = h.shape
     H, KV, D = model_cfg.num_heads, model_cfg.num_kv_heads, model_cfg.head_dim
     q = woq_mm(h, pa["q_proj"]["kernel"], dtype)
@@ -393,6 +435,11 @@ def _attn_mixer(pa, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
     # a model WITHOUT "swa" layers may still have one window for every
     # layer (``LlamaConfig.sliding_window``, Mistral's), kept whole in the
     # paged pool; one WITH them has it on those layers alone
+    if kind == "sparse":
+        kv, y = sparse_paged_attention(
+            kv, plane, index_layer, q, k, v, batch, cfg, pos, valid_q,
+            1.0 / (D ** 0.5), dtype, model_cfg.sparse)
+        return kv, _gated_out(pa, h, y, model_cfg, cfg, dtype)
     windowed = kind == "swa"
     listed = "swa" in (getattr(model_cfg, "layer_kinds", None) or ())
     window = model_cfg.sliding_window if windowed or not listed else None
@@ -401,12 +448,18 @@ def _attn_mixer(pa, h, kv, plane: int, batch: RaggedBatch, model_cfg, cfg,
             kv, plane, q, k, v, batch, cfg, pos, valid_q, 1.0 / (D ** 0.5),
             dtype, sliding_window=window, window_pool=windowed,
             ring_layer=ring_layer)
+    return kv, _gated_out(pa, h, y, model_cfg, cfg, dtype)
+
+
+def _gated_out(pa, h, y, model_cfg, cfg, dtype):
+    """The family's elementwise sigmoid output gate if it has one, then
+    the output projection."""
     if getattr(model_cfg, "attn_gate", False):
         gate = woq_mm(h, pa["g_proj"]["kernel"], dtype)
         y = (y.astype(jnp.float32)
              * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype)
     y = woq_mm(y, pa["o_proj"]["kernel"], dtype)
-    return kv, tp_all_reduce(y, cfg)        # TP collective 1 (row-parallel)
+    return tp_all_reduce(y, cfg)            # TP collective 1 (row-parallel)
 
 
 def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
@@ -430,6 +483,11 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
         # ``dtype``)
         rdtype = getattr(model_cfg, "residual_dtype", None) or dtype
         x = params["embed"]["embedding"][batch.tokens].astype(rdtype)
+        # the muP factors of a family that has them: on the embedding, on
+        # every branch before the residual add, under the head
+        if hasattr(model_cfg, "embed_scale"):
+            x = x * model_cfg.embed_scale
+    branch = getattr(model_cfg, "residual_scale", None)
 
     # one step function for every family: the layer lists say which mixer
     # and which feed-forward a layer runs, or that it has none (a layer of
@@ -443,7 +501,7 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
     # a norm on each branch's OUTPUT too, before the residual add
     sandwich = getattr(model_cfg, "sandwich_norm", False)
     act = _mlp_act(model_cfg)
-    plane = si = wplane = 0
+    plane = si = wplane = xi = 0
     for li, (kind, ffn) in enumerate(zip(kinds, ffn_kinds)):
         p = params[f"layer_{li}"]
         if kind is not None:
@@ -460,6 +518,17 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
                     kv, y = _mamba2_mixer(p["mamba"], h, kv, si, batch,
                                           model_cfg, valid_q, dtype)
                 si += 1
+            elif kind == "lightning":
+                with region("linear_attn"):
+                    kv, y = _lightning_mixer(p["lin"], h, kv, si, batch,
+                                             model_cfg, pos, valid_q, dtype)
+                si += 1
+            elif kind == "sparse":
+                with region("attn_proj"):
+                    kv, y = _attn_mixer(
+                        p["attn"], h, kv, plane, batch, model_cfg, cfg, pos,
+                        valid_q, dtype, kind, index_layer=xi)
+                plane, xi = plane + 1, xi + 1
             elif kind == "mla":
                 with region("mla_proj"):
                     kv, y = _mla_mixer(p["attn"], h, kv, plane, batch,
@@ -482,6 +551,8 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
                     y = _rms(y, p["attn_branch_norm"]["scale"],
                              model_cfg.rms_eps)
             with region("residual"):
+                if branch is not None:
+                    y = y.astype(rdtype) * branch
                 x = x + y.astype(rdtype)
         if ffn is None:
             continue
@@ -530,11 +601,15 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
             with region("norm"):
                 y = _rms(y, p["mlp_branch_norm"]["scale"], model_cfg.rms_eps)
         with region("residual"):
+            if branch is not None:
+                y = y.astype(rdtype) * branch
             x = x + y.astype(rdtype)
 
     from ...ops.kernels.fp6_gemm import Fp6GemmWeight
     with region("head"):
         x = _rms(x, params["final_norm"]["scale"], model_cfg.rms_eps)
+        if hasattr(model_cfg, "logit_divisor"):
+            x = x / model_cfg.logit_divisor
         last = jnp.maximum(batch.n_tokens - 1, 0)
         x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
         if model_cfg.tie_embeddings:

@@ -561,7 +561,6 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
     """
     S, C, H, D = q.shape
     KV = k.shape[2]
-    bs = cfg.block_size
     impl = _attention_impl(cfg)
     seq_on = seq_axis_active()
     if seq_on:
@@ -570,10 +569,8 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
         # (config validation already rejects an EXPLICIT paged_flash)
         impl = "dense"
 
-    ring_mode = isinstance(kv, RingKV)
-    if ring_mode:
-        pool, ring, t, rcount = kv[:4]
-        data, scales = pool_parts(pool, window_pool)
+    if isinstance(kv, RingKV):
+        ring, t = kv.ring, kv.t
         rl = li if ring_layer is None else ring_layer
         # ring[t, rl, 0/1] <- this step's K/V: the ring is R-LEADING so the
         # per-step write is a leading-index dynamic-update-slice (in-place
@@ -588,31 +585,9 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
         kv = kv._replace(ring=ring)
         settled_lens = jnp.where(batch.n_tokens > 0,
                                  batch.start_pos - t, 0)
-        if impl == "paged_flash":
-            from ...ops.kernels import flash_paged_attention
-            # the WHOLE pool and ring ride through: both kernels select
-            # (layer, k/v) themselves. As operands, data[li, x] slices
-            # made XLA copy every layer's K and V plane out of the pool
-            # each step (the device trace measured them at ~45% of the
-            # decode step), and ring[:, li, x].swapaxes added 44 strided
-            # 17 MB transposes
-            y = flash_paged_attention(
-                q.astype(data.dtype if scales is None else dtype),
-                data, li, _tables(batch, kv, cfg, window_pool),
-                batch.start_pos, settled_lens,
-                block_size=bs, num_kv_heads=KV, sm_scale=scale,
-                alibi_slopes=alibi_slopes, sliding_window=sliding_window,
-                scales=scales, ring=ring, ring_count=rcount,
-                ring_layer=ring_layer)
-        elif seq_on:
-            y = _seq_dense_ring_attention(
-                pool, ring, li, q, batch, cfg, settled_lens, rcount,
-                scale, dtype, alibi_slopes, sliding_window)
-        else:
-            y = _dense_ring_attention(
-                pool, ring, li, q, batch, cfg, settled_lens, rcount,
-                scale, dtype, alibi_slopes, sliding_window, window_pool, rl)
-        return kv, y.reshape(S, C, H * D).astype(dtype)
+        return kv, _attend(kv, li, q, KV, batch, cfg, pos, settled_lens,
+                           scale, dtype, impl, alibi_slopes, sliding_window,
+                           window_pool, ring_layer)
 
     if seq_on:
         return _seq_paged_attention(kv, li, q, k, v, batch, cfg, pos,
@@ -624,26 +599,65 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
             kv, li,
             jnp.stack([k.reshape(S, C, KV * D), v.reshape(S, C, KV * D)]),
             batch, cfg, KV, window=window_pool)
-    data, scales = pool_parts(kv, window_pool)
+    seq_lens = jnp.where(batch.n_tokens > 0,
+                         batch.start_pos + batch.n_tokens, 0) \
+        if impl == "paged_flash" else None
+    return kv, _attend(kv, li, q, KV, batch, cfg, pos, seq_lens, scale,
+                       dtype, impl, alibi_slopes, sliding_window,
+                       window_pool)
 
+
+def _attend(kv, li, q, KV, batch: "RaggedBatch",
+            cfg: RaggedInferenceConfig, pos, lens, scale, dtype, impl,
+            alibi_slopes=None, sliding_window=None,
+            window_pool: bool = False, ring_layer=None):
+    """The attend-only half of ``paged_attention``, its rows already
+    stored: in plane ``li`` of the pool ``kv`` (``lens`` the sequences'
+    whole lengths, read by the kernel alone), or, ``kv`` a ``RingKV``,
+    in the ring (``lens`` the SETTLED lengths). ``impl`` is
+    ``_attention_impl``'s answer after the caller's own overrides. A
+    sequence whose ``lens`` is 0 is attended over nothing, which is how
+    ``sparse_paged_attention`` sends the call only the sequences below
+    ``dense_len``. Returns y [S, C, H*D] in ``dtype``."""
+    S, C, H, D = q.shape
+    ring_mode = isinstance(kv, RingKV)
+    pool = kv.pool if ring_mode else kv
+    data, scales = pool_parts(pool, window_pool)
     if impl == "paged_flash":
         from ...ops.kernels import flash_paged_attention
-        seq_lens = jnp.where(batch.n_tokens > 0,
-                             batch.start_pos + batch.n_tokens, 0)
         # q joins the pool's storage dtype so the kernel's matmuls stay
         # single-dtype (f32 accumulation inside); the pool itself is NEVER
         # cast, copied or sliced — that would re-introduce the full-pool
-        # traffic this kernel exists to avoid, on the pool the store above
-        # just updated in place.
-        # Over an int8 pool q stays in the compute dtype; the kernel
-        # scales scores/probabilities by the side-array scales.
+        # traffic this kernel exists to avoid, on the pool the store
+        # just updated in place. Over an int8 pool q stays in the compute
+        # dtype; the kernel scales scores/probabilities by the side-array
+        # scales.
+        # In the fused loop the WHOLE pool and ring ride through: both
+        # kernels select (layer, k/v) themselves. As operands, data[li, x]
+        # slices made XLA copy every layer's K and V plane out of the pool
+        # each step (the device trace measured them at ~45% of the
+        # decode step), and ring[:, li, x].swapaxes added 44 strided
+        # 17 MB transposes
         y = flash_paged_attention(
             q.astype(data.dtype if scales is None else dtype),
             data, li, _tables(batch, kv, cfg, window_pool), batch.start_pos,
-            seq_lens, block_size=bs, num_kv_heads=KV, sm_scale=scale,
-            alibi_slopes=alibi_slopes, sliding_window=sliding_window,
-            scales=scales)
-        return kv, y.reshape(S, C, H * D).astype(dtype)
+            lens, block_size=cfg.block_size, num_kv_heads=KV,
+            sm_scale=scale, alibi_slopes=alibi_slopes,
+            sliding_window=sliding_window, scales=scales,
+            **(dict(ring=kv.ring, ring_count=kv.rcount,
+                    ring_layer=ring_layer) if ring_mode else {}))
+        return y.reshape(S, C, H * D).astype(dtype)
+    if ring_mode:
+        rl = li if ring_layer is None else ring_layer
+        if seq_axis_active():
+            y = _seq_dense_ring_attention(
+                pool, kv.ring, li, q, batch, cfg, lens, kv.rcount, scale,
+                dtype, alibi_slopes, sliding_window)
+        else:
+            y = _dense_ring_attention(
+                pool, kv.ring, li, q, batch, cfg, lens, kv.rcount, scale,
+                dtype, alibi_slopes, sliding_window, window_pool, rl)
+        return y.reshape(S, C, H * D).astype(dtype)
 
     k_ctx, v_ctx = _gather_ctx(kv, li, batch, cfg, S, KV, D, dtype,
                                window_pool)
@@ -652,8 +666,157 @@ def paged_attention(kv, li, q, k, v, batch: "RaggedBatch",
     mask = j[None, None, :] <= pos[:, :, None]          # [S, C, T]
     if sliding_window is not None:
         mask = jnp.logical_and(mask, dist < sliding_window)
-    y = _grouped_dense_attention(q, k_ctx, v_ctx, mask, dist, scale, dtype,
-                                 alibi_slopes)
+    return _grouped_dense_attention(q, k_ctx, v_ctx, mask, dist, scale,
+                                    dtype, alibi_slopes)
+
+
+def _select_in_tiles(scores_of, pos, sp, num_blocks: int, tile: int):
+    """``select_blocks`` over the chunk's queries a tile at a time (the
+    scores of a whole [4, 512] chunk against 2,560 groups would be 0.7
+    GB): ``scores_of(lo)`` gives the scaled compressed scores [S, tile,
+    KV, G, J] of queries ``lo .. lo + tile``. Returns chosen [S, C, KV,
+    NB] bool."""
+    from ...models.minicpm_sala import block_scores, topk_mask
+    S, C = pos.shape
+
+    def one(lo):
+        p = jax.lax.dynamic_slice_in_dim(pos, lo, tile, axis=1)
+        return topk_mask(
+            block_scores(scores_of(lo), p[:, :, None], sp, num_blocks),
+            sp.topk)                                     # [S, tile, KV, NB]
+    out = jax.lax.map(one, jnp.arange(0, C, tile, dtype=jnp.int32))
+    return jnp.moveaxis(out, 0, 1).reshape(S, C, *out.shape[3:])
+
+
+def sparse_paged_attention(kv, li, xi, q, k, v, batch: "RaggedBatch",
+                           cfg: RaggedInferenceConfig, pos, valid_q, scale,
+                           dtype, sp):
+    """``paged_attention``'s sibling for a block-selected layer (``sp``
+    its ``SparseConfig``): append this step's K/V to plane ``li`` of the
+    paged pool (or the fused loop's ring), bring the compressed-key plane
+    ``xi`` up to date with them, SELECT each query's blocks (region
+    ``attn_select``) and attend over them alone (``attn_sparse``).
+
+    A query whose context is below ``sp.dense_len`` attends over every
+    key: a decode step sends such sequences through the paged decode
+    kernel and the others through the sparse one, each under a
+    ``lax.cond`` that skips the call no sequence needs; a prefill chunk
+    takes the BlockSpec paged kernel unchanged while every query is below
+    it, else the block-union kernel, where such a query selects every
+    block. The kernels run on a TPU at whole-tile shapes
+    (``sparse_attention.decode_uses_kernel``; platform and shape decide),
+    their ``jax.numpy`` twins elsewhere. Returns (kv, y [S, C, H*D])."""
+    from ...models.minicpm_sala import select_blocks
+    from ...ops.kernels import default_interpret, sparse_attention as sa
+    from . import index_plane
+    S, C, H, D = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    bs, stride, sb = cfg.block_size, sp.kernel_stride, sp.block_size
+    NB = cfg.max_context // sb
+    impl = _attention_impl(cfg)
+    use_kernel = impl == "paged_flash" and (
+        sa.decode_uses_kernel(D, sb) or default_interpret())
+    ring_mode = isinstance(kv, RingKV)
+    live = batch.n_tokens > 0
+    tables = batch.block_tables
+    qg = q.reshape(S, C, KV, G, D)
+    idx = settled = None
+    if ring_mode:
+        pool, ring, t, rcount = kv[:4]
+        settled = batch.start_pos - t
+        with region("kv_write"):
+            k_row = k.reshape(S, KV * D).astype(ring.dtype)
+            ring = ring.at[t, li, 0].set(k_row)
+            ring = ring.at[t, li, 1].set(
+                v.reshape(S, KV * D).astype(ring.dtype))
+            idx = index_plane.ring_add(kv.idx, xi, k_row, batch.start_pos,
+                                       settled, live, stride)
+        kv = kv._replace(ring=ring, idx=idx)
+        lens = jnp.where(live, settled, 0)
+    else:
+        with region("kv_write"):
+            kv = _store_step_rows(
+                kv, li, jnp.stack([k.reshape(S, C, KV * D),
+                                   v.reshape(S, C, KV * D)]),
+                batch, cfg, KV)
+            kv = index_plane.refresh(kv, li, xi, batch.start_pos,
+                                     batch.n_tokens, tables, C, bs, stride)
+        pool = kv
+        lens = jnp.where(live, batch.start_pos + batch.n_tokens, 0)
+    data = pool_parts(pool)[0]
+    is_dense = pos + 1 < sp.dense_len                        # [S, C]
+
+    def scores(qq):
+        """Scaled compressed scores: a window is two group means."""
+        gs = index_plane.group_scores(pool, xi, qq, tables, bs, stride,
+                                      idx, settled)
+        nxt = jnp.concatenate([gs[..., 1:], gs[..., :1]], axis=-1)
+        return (gs + nxt) * (0.5 * scale)
+
+    def dense(lens_d):
+        """Every key at or before the query: the paged pool's own call."""
+        return _attend(kv, li, q, KV, batch, cfg, pos, lens_d, scale,
+                       dtype, impl).astype(dtype)
+
+    zeros = lambda: jnp.zeros((S, C, H * D), dtype)         # noqa: E731
+    if C == 1:
+        dense_row, sparse_row = is_dense[:, 0], ~is_dense[:, 0]
+
+        def sparse():
+            with region("attn_select"):
+                blocks = select_blocks(scores(qg)[:, 0], pos[:, :1], sp, NB)
+                rows, col = sa.selection_rows(
+                    blocks, tables, bs, sb, data.shape[2] - bs)
+            lens_s = jnp.where(sparse_row, lens, 0)
+            kw = dict(sel_block=sb, sm_scale=float(scale))
+            if ring_mode:
+                kw.update(ring=ring, ring_count=rcount)
+            with region("attn_sparse"):
+                if use_kernel:
+                    y = sa.sparse_decode_attention(
+                        q[:, 0], data, li, rows, col, batch.start_pos,
+                        lens_s, interpret=default_interpret(), **kw)
+                else:
+                    y = sa.sparse_decode_reference(
+                        q[:, 0], data, li, rows, col, batch.start_pos,
+                        lens_s, **kw)
+            return y.reshape(S, 1, H * D).astype(dtype)
+        y_s = jax.lax.cond(jnp.any(live & sparse_row), sparse, zeros)
+        with region("attn_core"):
+            y_d = jax.lax.cond(
+                jnp.any(live & dense_row),
+                lambda: dense(jnp.where(dense_row, lens, 0)), zeros)
+        return kv, jnp.where(dense_row[:, None, None], y_d, y_s)
+
+    def sparse_chunk():
+        tile = next(d for d in range(min(C, 128), 0, -1) if C % d == 0)
+        with region("attn_select"):
+            chosen = _select_in_tiles(
+                lambda lo: scores(jax.lax.dynamic_slice_in_dim(
+                    qg, lo, tile, axis=1)), pos, sp, NB, tile)
+            chosen = chosen | is_dense[:, :, None, None]
+        fn = sa.sparse_prefill_attention if use_kernel \
+            else sa.sparse_prefill_reference
+        with region("attn_sparse"):
+            y, counts = fn(
+                q.astype(data.dtype), data, li, tables, batch.start_pos,
+                lens, chosen, block_size=bs, sel_block=sb,
+                sm_scale=float(scale),
+                **(dict(interpret=default_interpret()) if use_kernel
+                   else {}))
+        return y.reshape(S, C, H * D).astype(dtype), counts
+
+    def dense_chunk():
+        # every real query selects every block at or before its own
+        n = jnp.sum(jnp.where(valid_q, pos // sb + 1, 0),
+                    dtype=jnp.int32) * KV
+        with region("attn_core"):
+            return dense(lens), jnp.stack([n, n])
+    y, counts = jax.lax.cond(jnp.any(valid_q & ~is_dense), sparse_chunk,
+                             dense_chunk)
+    with region("loop_carry"):
+        kv = kv._replace(sel_counts=kv.sel_counts + counts)
     return kv, y
 
 
@@ -783,7 +946,25 @@ class RaggedRunnerBase:
         # or latent layer, a state row a sequence for each recurrent one
         kinds = getattr(model_cfg, "layer_kinds", None) \
             or ("attn",) * self.num_layers
-        self.kv_layers = sum(k in ("attn", "mla") for k in kinds)
+        self.kv_layers = sum(k in ("attn", "mla", "sparse") for k in kinds)
+        other = sorted({k for k in kinds
+                        if k in ("swa", "mla", "kda", "mamba2")})
+        if other and {"sparse", "lightning"} & set(kinds):
+            raise ValueError(
+                f"block-selected ('sparse') and Lightning ('lightning') "
+                f"layers do not mix with {other} layers in one model: no "
+                f"pool, ring or state row has been carried over both")
+        #: what the compressed-key plane must hold (None: no
+        #: block-selected layer): a row a ``stride`` positions for each
+        #: "sparse" layer, whose K rows are the pool's ``pool_layers``
+        self.index_spec = None
+        if "sparse" in kinds:
+            paged = [k for k in kinds if k in ("attn", "sparse")]
+            self.index_spec = {
+                "layers": kinds.count("sparse"),
+                "stride": int(model_cfg.sparse.kernel_stride),
+                "pool_layers": tuple(i for i, k in enumerate(paged)
+                                     if k == "sparse")}
         #: what the window pool must hold (None: no sliding-window layer):
         #: the layers of kind "swa" keep their rows THERE and not in the
         #: paged pool, which serves the model's full layers alone.
@@ -827,7 +1008,8 @@ class RaggedRunnerBase:
         #: state ``[heads, d_v, d_k]`` a recurrent layer and the last
         #: ``taps - 1`` inputs of its convolution, ``conv_width`` wide
         self.state_spec = None
-        recurrent = [k for k in kinds if k in ("kda", "mamba2")]
+        recurrent = [k for k in kinds
+                     if k in ("kda", "mamba2", "lightning")]
         if len(set(recurrent)) > 1:
             raise ValueError(
                 "recurrent layers of two kinds ('kda' and 'mamba2') in "
@@ -839,6 +1021,14 @@ class RaggedRunnerBase:
                 "heads": model_cfg.kda_heads, "d_v": d, "d_k": d,
                 "taps": model_cfg.kda_conv,
                 "conv_width": 3 * model_cfg.kda_heads * d}
+        elif recurrent and recurrent[0] == "lightning":
+            # a state [d_v, d_k] a head and NO short convolution (taps 0:
+            # the pool then has no convolution part)
+            d = model_cfg.head_dim
+            self.state_spec = {
+                "kind": "lightning", "layers": len(recurrent),
+                "heads": model_cfg.lightning_heads, "d_v": d, "d_k": d,
+                "taps": 0, "conv_width": 0}
         elif recurrent:
             self.state_spec = {
                 "kind": "mamba2", "layers": len(recurrent),
@@ -1126,9 +1316,17 @@ class RaggedRunnerBase:
             # it made to them
             moe0 = jnp.zeros((moe_experts + 2 if moe_experts else 0,),
                              jnp.int32)
+            # a model with block-selected layers: the sums of the loop's
+            # own keys a compressed-key group (index_plane.py)
+            idx0 = None
+            if self.index_spec is not None:
+                from .index_plane import ring_groups
+                idx0 = ring_groups(
+                    kv_data, self.index_spec["pool_layers"], start, active,
+                    tables, n, cfg.block_size, self.index_spec["stride"])
 
             def body(carry, t):
-                ring, tok, pos, done, moe, lin = carry
+                ring, tok, pos, done, moe, lin, idx = carry
                 if use_eos:
                     # per-slot EOS freeze: finished slots stop appending KV
                     # (n_tokens 0 -> trash writes) and keep emitting eos_id
@@ -1152,9 +1350,10 @@ class RaggedRunnerBase:
                                     state_slots=sslots,
                                     window_tables=wtables)
                 logits, kv_out = type(self).step_fn(
-                    params, RingKV(kv_data, ring, t, t + 1, moe, lin), batch,
-                    model_cfg=mcfg_l, cfg=cfg, dtype=dtype)
+                    params, RingKV(kv_data, ring, t, t + 1, moe, lin, idx),
+                    batch, model_cfg=mcfg_l, cfg=cfg, dtype=dtype)
                 ring, moe, lin = kv_out.ring, kv_out.moe_rows, kv_out.lin
+                idx = kv_out.idx
                 # the one pre-sampling collective: every chip then selects
                 # the SAME next token from identical full-width logits
                 with region("head"):
@@ -1178,10 +1377,10 @@ class RaggedRunnerBase:
                     done = jnp.logical_or(done, nxt == eos_id)
                 else:
                     new_pos = pos + 1
-                return (ring, nxt, new_pos, done, moe, lin), (nxt, lp)
+                return (ring, nxt, new_pos, done, moe, lin, idx), (nxt, lp)
 
-            (ring, _, pos_f, _, moe, lin), (toks, lps) = jax.lax.scan(
-                body, (ring, tok0, start, done0, moe0, lin),
+            (ring, _, pos_f, _, moe, lin, _), (toks, lps) = jax.lax.scan(
+                body, (ring, tok0, start, done0, moe0, lin, idx0),
                 jnp.arange(n, dtype=jnp.int32))
             # consumed is shard_map-shape-stable: always an array; the
             # decode_loop wrapper drops it when EOS is disabled
@@ -1257,6 +1456,17 @@ class RaggedRunnerBase:
                     start0, count, tables, R,
                     cfg.block_size, data.shape, None if seqc is None else
                     (seqc.seq_size, jax.lax.axis_index(SEQ_AXIS)))
+                if self.index_spec is not None:
+                    # the compressed keys of the groups the ring's rows
+                    # touch, from the rows just stored
+                    from .index_plane import refresh
+                    kv_data = flush(kv_data, plan, data.shape[0])
+                    spec = self.index_spec
+                    for xi, li in enumerate(spec["pool_layers"]):
+                        kv_data = refresh(
+                            kv_data, li, xi, start0, count, tables, R,
+                            cfg.block_size, spec["stride"])
+                    return kv_data
                 if self.window_spec is None:
                     return flush(kv_data, plan, data.shape[0])
                 # two loops over the one ring: XLA re-lays the carry once

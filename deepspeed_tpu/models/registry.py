@@ -9,6 +9,7 @@ module classes, so checkpoints and serving configs can be resolved by name.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable, Dict, NamedTuple
 
 from .bert import Bert, BertConfig
@@ -40,6 +41,9 @@ from .kimi_linear import KimiLinear, KimiLinearConfig
 from .kimi_linear import make_model as make_kimi_linear
 from .mellum import LAYER_TYPES, Mellum, MellumConfig, YarnRope
 from .mellum import make_model as make_mellum
+from .minicpm_sala import (MIXER_TYPES, MiniCPMSALA, MiniCPMSALAConfig,
+                           SparseConfig)
+from .minicpm_sala import make_model as make_minicpm_sala
 from .nemotron_h import NemotronH, NemotronHConfig, kinds_from_pattern
 from .nemotron_h import make_model as make_nemotron_h
 
@@ -608,6 +612,57 @@ def _entry_nemotron_h(d):
         router_aux_loss_coef=0.0)
 
 
+def _entry_minicpm_sala(d):
+    """MiniCPM-SALA (openbmb/MiniCPM-SALA): ``mixer_types`` read letter for
+    letter (``minicpm4`` a block-selected attention layer,
+    ``lightning-attn`` a Lightning linear-attention layer; the published
+    list has no period), the family's muP scalings, output gates on both
+    kinds, NoPE on the sparse layers and rotary on the linear ones. The
+    selection's sizes come from ``sparse_config`` where the config has
+    one, else MiniCPM4.1's published values (the published config gives
+    none). ``num_hidden_layers_published`` (a cut configuration's own
+    key) keeps the residual scale at the model's depth. What the
+    published config does not set is refused by the key's name."""
+    mixers = d["mixer_types"]
+    n = d.get("num_hidden_layers", len(mixers))
+    unknown = sorted(set(mixers) - set(MIXER_TYPES))
+    if unknown or len(mixers) != n:
+        raise ValueError(
+            f"minicpm_sala mixer_types must name num_hidden_layers ({n}) "
+            f"layers of {sorted(MIXER_TYPES)}, got {len(mixers)} with "
+            f"unknown {unknown}")
+    for key, want in (("attention_bias", False), ("qk_norm", True),
+                      ("hidden_act", "silu"),
+                      ("lightning_scale", "1/sqrt(d)"),
+                      ("use_output_gate", True), ("use_output_norm", True),
+                      ("attn_use_output_gate", True),
+                      ("lightning_head_dim", d.get("head_dim", 128)),
+                      ("lightning_nkv", d.get("lightning_nh", 32)),
+                      ("tie_word_embeddings", False)):
+        if d.get(key, want) != want:
+            raise ValueError(
+                f"minicpm_sala configs with {key}={d[key]!r} are not "
+                f"supported (the published one has {want!r})")
+    names = {f.name for f in dataclasses.fields(SparseConfig)}
+    sparse = SparseConfig(**{k: v for k, v in
+                             (d.get("sparse_config") or {}).items()
+                             if k in names})
+    return MiniCPMSALAConfig(
+        **_hf_llama(d, num_layers=n,
+                    rms_eps=d.get("rms_norm_eps", 1e-6),
+                    max_seq_len=d.get("max_position_embeddings", 524288)),
+        attn_head_dim=d.get("head_dim", 128),
+        layer_kinds=tuple(MIXER_TYPES[m] for m in mixers),
+        use_rope=bool(d.get("attn_use_rope", False)),
+        lightning_heads=d.get("lightning_nh", 32),
+        lightning_rope=bool(d.get("lightning_use_rope", True)),
+        sparse=sparse,
+        scale_emb=float(d.get("scale_emb", 12)),
+        scale_depth=float(d.get("scale_depth", 1.4)),
+        dim_model_base=int(d.get("dim_model_base", 256)),
+        depth_published=int(d.get("num_hidden_layers_published", n)))
+
+
 ARCHITECTURES: Dict[str, ArchEntry] = {
     "gpt2": ArchEntry(GPT2Config, GPT2, make_gpt2, _entry_gpt2),
     "llama": ArchEntry(LlamaConfig, Llama, make_llama, _entry_llama),
@@ -639,6 +694,8 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
     "nemotron_h": ArchEntry(NemotronHConfig, NemotronH, make_nemotron_h,
                             _entry_nemotron_h),
     "mellum": ArchEntry(MellumConfig, Mellum, make_mellum, _entry_mellum),
+    "minicpm_sala": ArchEntry(MiniCPMSALAConfig, MiniCPMSALA,
+                              make_minicpm_sala, _entry_minicpm_sala),
     "gpt_neo": ArchEntry(GPTNeoConfig, GPTNeo, make_gpt_neo,
                          _entry_gpt_neo),
     "internlm": ArchEntry(LlamaConfig, Llama, make_llama, _entry_internlm),

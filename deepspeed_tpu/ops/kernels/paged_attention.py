@@ -258,6 +258,15 @@ def decode_rows_fetched(seq_len: int, tile_rows: int,
     return (-(-seq_len // tile_rows) - lo) * tile_rows
 
 
+def decode_group(S: int) -> int:
+    """Sequences a grid step of a decode kernel serves, of ``S`` slots."""
+    if S % 8 == 0:
+        return 8     # a whole sublane tile of the ring's [.., S, KVD] planes
+    if S <= 16:
+        return S
+    return max(d for d in range(1, 9) if S % d == 0)
+
+
 def _decode_plan(S: int, ctx_rows: int, ts: int, row_bytes: int,
                  window: Optional[int] = None):
     """(G, CR, NCH): sequences a grid step, context rows a sequence and
@@ -269,12 +278,7 @@ def _decode_plan(S: int, ctx_rows: int, ts: int, row_bytes: int,
     fill) where that is less than the context."""
     if window is not None:
         ctx_rows = min(ctx_rows, (-(-(window - 1) // ts) + 1) * ts)
-    if S % 8 == 0:
-        G = 8        # a whole sublane tile of the ring's [.., S, KVD] planes
-    elif S <= 16:
-        G = S
-    else:
-        G = max(d for d in range(1, 9) if S % d == 0)
+    G = decode_group(S)
     cap = min(_DECODE_CHUNK_ROWS, _DECODE_KV_VMEM // (4 * G * row_bytes))
     cap = max(ts, cap // ts * ts)
     nch = -(-ctx_rows // cap)

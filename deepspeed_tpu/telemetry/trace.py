@@ -88,7 +88,8 @@ REGIONS = (
     "attn_proj",    # q/k/v/out projections, rope, QK-norm, output gate
     "attn_core",    # the paged / flash / dense attention call, its masks
     "kv_write",     # rows into the pool or the loop's ring; the flush
-    "linear_attn",  # a delta-rule layer: convolution, decay, state update
+    "linear_attn",  # a linear-attention layer (delta rule or Lightning):
+                    # projections, convolution if any, decay, state update
     "ssm",          # a state-space layer: projection, convolution, scan, gate
     "mla_proj",     # latent attention's low-rank projections, absorption
     "mla_core",     # the latent attention call over the one-plane pool
@@ -104,6 +105,10 @@ REGIONS = (
     "grad_clip",    # mean over micro-batches, unscale, global norm, clip
     "optimizer",    # the update, the overflow gate, the new state
     "attn_window",  # attn_core's twin around a sliding-window layer's call
+    "attn_select",  # a block-selected layer's selection: compressed scores,
+                    # group sum, window maximum, forced blocks, top-k, rows
+    "attn_sparse",  # attn_core's twin around the call that reads the
+                    # selected blocks alone
 )
 
 

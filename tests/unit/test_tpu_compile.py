@@ -937,3 +937,112 @@ def test_mellum_loop_flush_and_refill_compile_at_256_clients(one_chip,
     assert {a for a, _ in _scoped_vmem(hlo, "grouped_ffn_decode")} \
         == {asked} and 20e6 < asked < 27e6
     assert step.memory_analysis().alias_size_in_bytes == pools
+
+
+def test_sala_loop_flush_and_refill_compile_at_96_clients(one_chip,
+                                                          monkeypatch):
+    """The fused 256-step decode loop, its flush and the [4, 512] refill
+    step of ``serve-minicpm-sala-rollout-32k`` at the published widths and
+    the cell's pool (10,300 blocks, contexts to 40,960), from shapes alone:
+    every block-selected layer holds BOTH decode kernels under a
+    ``lax.cond`` each (the sparse one, named ``sparse_decode`` as
+    ``sparse_attn_roofline.sala`` matches it, and the paged pool's own
+    for sequences below ``dense_len``), every Lightning layer updates its
+    state through the in-place Mosaic call at [97, 32, 128, 128], the
+    state enters donated and comes back aliased, no program copies a
+    plane of the pool or of the compressed keys out (the temporaries stay
+    under a plane's bytes), and the refill step holds the block-union
+    kernel beside the BlockSpec paged kernel."""
+    import json
+    import os
+    import re
+    from collections import Counter
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from benchmark.model_types import minicpm_sala as mt
+    from deepspeed_tpu.inference.v2.kv_quant import KVPool
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "minicpm-sala-9b.json")) as f:
+        mcfg = mt.model_config(json.load(f))
+    with open(os.path.join(root, "benchmark", "cells",
+                           "serve-minicpm-sala-rollout-32k.json")) as f:
+        eng = json.load(f)["engine"]
+    runner = LlamaRaggedRunner(mcfg, RaggedInferenceConfig(**eng))
+    slots, block, blocks, maxb = (eng["max_seqs"], eng["block_size"],
+                                  eng["num_blocks"],
+                                  eng["max_blocks_per_seq"])
+    assert (slots, blocks, maxb) == (96, 10300, 160)
+    assert runner.state_spec == {
+        "kind": "lightning", "layers": 6, "heads": 32, "d_v": 128,
+        "d_k": 128, "taps": 0, "conv_width": 0}
+    assert runner.index_spec == {"layers": 2, "stride": 16,
+                                 "pool_layers": (0, 1)}
+    assert (runner.kv_layers, runner.kv_heads, runner.head_dim) \
+        == (2, 2, 128)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: mt.init_params(mcfg, 0)))
+    assert params["lm_head"]["kernel"].shape == (4096, 73448)
+    rows = (blocks + 1) * block
+    state = tuple(spec((slots + 1, 32, 128, 128), jnp.float32)
+                  for _ in range(6))
+    planes = spec((2, 2, rows, 256), jnp.bfloat16)
+    index = spec((2, rows // 16, 256), jnp.bfloat16)
+    counts = spec((2,))
+    plane_bytes = rows * 256 * 2
+    f32 = functools.partial(spec, dtype=jnp.float32)
+    exe = runner._decode_loop_ring.trace(
+        params, KVPool(planes, None, None, None, None, index, counts),
+        (state, None), spec((slots,)), spec((slots,)), spec((slots,)),
+        spec((slots,)), spec((slots, maxb)), spec((1,)), f32((1,)),
+        spec((1,)), f32((1,)), spec((1, 1)), n=256, mode="greedy", cand=1,
+        eos_id=-1, feed="self").lower(lowering_platforms=("tpu",)).compile()
+    hlo = exe.as_text()
+    assert Counter(_mosaic_call_names(hlo)) == {
+        "mamba2_decode_state_update": 6, "sparse_decode": 2,
+        "closed_call": 2}
+    # the names and shapes the .sala readers match
+    assert len(re.findall(
+        r"%mamba2_decode_state_update[\w\-.]* = \(f32\[97,32,128,128\]",
+        hlo)) == 6
+    assert len(re.findall(
+        r"%sparse_decode[\w\-.]* = bf16\[96,32,256\]", hlo)) == 2
+    mem = exe.memory_analysis()
+    state_bytes = 6 * (slots + 1) * 32 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < plane_bytes
+    made = re.findall(r"= f32\[97,32,128,128\]\S* ([\w\-]+)\(", hlo)
+    assert set(made) <= {"parameter", "get-tuple-element"}, set(made)
+    # the flush: the pool and the compressed keys updated in place
+    ring = spec((256, 2, 2, slots, 256), jnp.bfloat16)
+    flush = runner._flush_ring.trace(
+        KVPool(planes, None, None, None, None, index, counts), ring,
+        spec((slots, maxb)), spec((slots,)), spec((slots,))).lower(
+            lowering_platforms=("tpu",)).compile()
+    mem = flush.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * plane_bytes + 2 * plane_bytes // 16
+    assert mem.temp_size_in_bytes < plane_bytes // 8
+    # the refill step: the block-union kernel a sparse layer, beside the
+    # paged pool's own for chunks below dense_len; the chunked recurrence
+    # without a kernel of its own
+    step = runner._step_greedy.trace(
+        params, KVPool(planes, None, state, None, None, index, counts),
+        RaggedBatch(spec((4, 512)), spec((4,)), spec((4,)), spec((4, maxb)),
+                    spec((4,)))).lower(
+                        lowering_platforms=("tpu",)).compile()
+    hlo = step.as_text()
+    names = Counter(_mosaic_call_names(hlo))
+    assert names["sparse_prefill"] == 2 and len(names) == 2, names
+    assert len(re.findall(
+        r"%sparse_prefill[\w\-.]* = bf16\[4,18432,128\]", hlo)) == 2
+    assert step.memory_analysis().temp_size_in_bytes < plane_bytes

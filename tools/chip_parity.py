@@ -6,6 +6,7 @@ catches.
     chiprun -- python tools/chip_parity.py --config kimi-linear-48b-a3b
     chiprun -- python tools/chip_parity.py --config nemotron-3-nano-30b-a3b
     chiprun -- python tools/chip_parity.py --config mellum2-12b-a2.5b
+    chiprun -- python tools/chip_parity.py --config minicpm-sala-9b [--prompt-blocks 128]
     python tools/chip_parity.py --config solar-open2-250b --rehearse   # CPU, toy
 
 Outside any timed window. The model type's hooks come from
@@ -101,7 +102,10 @@ SINGLE_BEFORE, FUSED = 22, 8
 #: by model type, where the default walk does not reach what the family
 #: adds: the prompt in whole blocks (default: the cell's shortest
 #: prompt), the steps of a fused loop and how many loops
-WALKS = {"mellum": {"prompt_blocks": 8, "fused": 128, "loops": 5}}
+WALKS = {"mellum": {"prompt_blocks": 8, "fused": 128, "loops": 5},
+         # two of the cell's 256-step loops: the second selects from
+         # compressed keys and reads rows the first loop's flush wrote
+         "minicpm_sala": {"fused": 256, "loops": 2}}
 
 #: the reference's own keyword for each wrong model, by model type
 VARIANTS = {
@@ -136,6 +140,15 @@ VARIANTS = {
         "plain_rotary_on_the_full_layers": {"yarn_on": False},
         "attention_factor_left_out": {"attention_factor_on": False},
         "head_norm_left_out": {"head_norm": False}},
+    "minicpm_sala": {
+        "dense_attention_in_place_of_the_selection": {"selection": "dense"},
+        "topk_32": {"topk": 32},
+        "forced_window_left_out": {"window_size": 0},
+        "block_0_left_out": {"init_blocks": 0},
+        "rotary_put_on_the_sparse_layers": {"sparse_rope": True},
+        "rotary_taken_off_the_lightning_layers": {"lightning_rope": False},
+        "lambda_1": {"decay_one": True},
+        "mup_factors_left_out": {"mup": False}},
 }
 
 
@@ -190,6 +203,10 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--hidden", action="store_true")
     ap.add_argument("--parts", default="engine,variants")
+    ap.add_argument("--prompt-blocks", type=int, default=None,
+                    help="whole blocks of context the prompts fill "
+                         "(default: the model type's walk, else the "
+                         "cell's shortest prompt)")
     args = ap.parse_args(argv)
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -228,8 +245,9 @@ def main(argv=None) -> int:
     spec = cell["correct"]
     block = cell["engine"]["block_size"]
     walk = {"fused": FUSED, "loops": 1, **WALKS.get(dims["model_type"], {})}
-    whole_blocks = walk.get("prompt_blocks") or max(1, min(load_json(
-        "traffic", entry["traffic"] + ".json")["prompt_lens"]) // block)
+    whole_blocks = args.prompt_blocks or walk.get("prompt_blocks") \
+        or max(1, min(load_json("traffic", entry["traffic"] + ".json")[
+            "prompt_lens"]) // block)
     if args.rehearse:
         block, walk["fused"] = 64, min(walk["fused"], FUSED)
     fused_total = walk["fused"] * walk["loops"]
@@ -248,12 +266,16 @@ def main(argv=None) -> int:
                for n in lens]
     # the model type's reference with its fixed dimensions, open to one
     # more keyword: a wrong model
-    inner = mt.reference_logits(cfg).__wrapped__
-    reference = importlib.import_module(inner.func.__module__)
+    if hasattr(mt, "reference_logits_with"):
+        # a model type whose reference runs a sequence at a time
+        logits_fn = functools.partial(mt.reference_logits_with, cfg)
+    else:
+        inner = mt.reference_logits(cfg).__wrapped__
+        reference = importlib.import_module(inner.func.__module__)
 
-    def logits_fn(**wrong):
-        return jax.jit(functools.partial(
-            inner.func, **{**inner.keywords, **wrong}))
+        def logits_fn(**wrong):
+            return jax.jit(functools.partial(
+                inner.func, **{**inner.keywords, **wrong}))
 
     result = {"config": args.config, "cell": cell_name, "seed": args.seed,
               "device": jax.devices()[0].device_kind, "prompt_lens": lens}
@@ -365,7 +387,8 @@ def main(argv=None) -> int:
                                    loops=walk["loops"])
         stats = {k: v for k, v in eng.pipeline_stats.items()
                  if k.startswith(("latent_", "mla_", "decode_kv_rows",
-                                  "state_", "linear_attn_", "window_"))}
+                                  "state_", "linear_attn_", "window_",
+                                  "sparse_"))}
         del eng
         toks, at = padded(streams)
         ref = np.asarray(logits_fn()(params, toks, at), np.float32)
@@ -404,6 +427,8 @@ def main(argv=None) -> int:
         if args.rehearse:
             n = min(n, POSITIONS)
         T = (edge if whole_blocks > 1 else 128) + n
+        if args.rehearse and dims["model_type"] == "minicpm_sala":
+            T = 384 + n         # past the toy dense_len, a CPU's size
         toks = jnp.asarray(rs.randint(0, cfg.vocab_size, (B, T)), jnp.int32)
         at = jnp.asarray(np.tile(np.arange(T - n, T), (B, 1)), jnp.int32)
         base = np.asarray(logits_fn()(params, toks, at), np.float32)
