@@ -34,7 +34,7 @@ import jax
 import numpy as np
 
 from ...ops.kernels.delta_rule import kda_prefill_uses_kernel
-from ...ops.kernels import grouped_ffn, short_conv
+from ...ops.kernels import grouped_ffn, short_conv, sparse_attention
 from ...resilience.fault_injection import get_fault_injector
 from ...telemetry.serve import serve_observer
 from ...telemetry.trace import SpanSet
@@ -47,7 +47,8 @@ from .drain import (EngineDrainingError, ReplayJournal, ServeDrainError,
                     ServeStepError, build_manifest, write_manifest)
 from .kv_cache import BlockedKVCache, window_step_rows
 from .kv_write import runs_issued
-from .model_runner import GPT2RaggedRunner, RaggedBatch
+from .model_runner import (GPT2RaggedRunner, RaggedBatch,
+                           _attention_impl)
 from .sampling import SamplingParams, stage_slot
 from .scheduler import SplitFuseScheduler
 from .sequence import SequenceStatus
@@ -448,7 +449,14 @@ class InferenceEngineV2:
             # for them. ``sparse_dense_tokens``: positions (prefill and
             # decode) whose context was below ``dense_len``, which attend
             # over every key through the paged pool's own kernels
+            # ``sparse_select_queries``: the real queries (prefill and
+            # decode) past ``dense_len``, each of which selected; of
+            # those, ``sparse_select_kernel_queries`` had their blocks'
+            # scores made by the selection kernel
+            # (sparse_attention.select_uses_kernel, as the layer asks it:
+            # all of a step's or none; 0 off the TPU)
             "sparse_rows_selected": 0, "sparse_rows_live": 0,
+            "sparse_select_queries": 0, "sparse_select_kernel_queries": 0,
             "sparse_prefill_blocks_selected": 0,
             "sparse_prefill_blocks_visited": 0,
             "sparse_dense_tokens": 0}
@@ -1341,9 +1349,21 @@ class InferenceEngineV2:
         blocks = np.minimum(pos // sp.block_size + 1, sp.topk)
         sel = int((blocks * sp.block_size * sparse).sum()) * kvh
         return {
+            **self._select_counts(int(sparse.sum()), 1),
             "sparse_rows_selected": sel,
             "sparse_rows_live": int(((pos + 1) * sparse).sum()) * kvh,
             "sparse_dense_tokens": int((alive & ~sparse).sum())}
+
+    def _select_counts(self, queries: int, C: int) -> Dict[str, int]:
+        """The selection's two counters for ``queries`` real queries past
+        ``dense_len`` of steps of ``C`` queries a row."""
+        r, sp = self.runner, self.runner.model_cfg.sparse
+        kernel = _attention_impl(self.config) == "paged_flash" \
+            and sparse_attention.select_uses_kernel(
+                r.head_dim, r.model_cfg.num_heads // r.kv_heads, r.kv_heads,
+                C, self.config.block_size, sp.kernel_stride, sp.block_size)
+        return {"sparse_select_queries": queries,
+                "sparse_select_kernel_queries": queries * kernel}
 
     def _read_sel_counts(self) -> None:
         """The prefill selection's two counts, read back from the cache
@@ -1998,10 +2018,12 @@ class InferenceEngineV2:
                     span.count(mla_prefill_tokens=real)
                 if self._selecting:
                     dl = self.runner.model_cfg.sparse.dense_len
-                    span.count(sparse_dense_tokens=sum(
+                    below = sum(
                         max(0, min(item.start_pos + len(item.tokens),
                                    dl - 1) - item.start_pos)
-                        for item in sched))
+                        for item in sched)
+                    span.count(sparse_dense_tokens=below,
+                               **self._select_counts(real - below, C))
                 if self._moe_stacks is not None:
                     # the choice llama_runner._moe_mlp makes, of the same
                     # operand types and widths

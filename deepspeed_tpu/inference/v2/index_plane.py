@@ -117,7 +117,9 @@ def group_scores(kv, li: int, q, tables, block_size: int, stride: int,
     of it a layer and step) and scored at their full width against
     queries that are zero outside their kv head's lanes. With ``idx`` (the
     fused loop's), the groups from ``settled // stride`` on read the
-    loop's sums."""
+    loop's sums. On a TPU at whole-tile shapes the layer does not call
+    this: ``sparse_attention.block_select_scores`` walks the live blocks
+    inside one kernel, and this is its twin."""
     index = kv.index
     per = block_size // stride
     S, C, KV, G, D = q.shape

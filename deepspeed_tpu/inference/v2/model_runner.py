@@ -670,20 +670,18 @@ def _attend(kv, li, q, KV, batch: "RaggedBatch",
                                     dtype, alibi_slopes)
 
 
-def _select_in_tiles(scores_of, pos, sp, num_blocks: int, tile: int):
-    """``select_blocks`` over the chunk's queries a tile at a time (the
+def _scores_in_tiles(scores_of, pos, sp, num_blocks: int, tile: int):
+    """``block_scores`` over the chunk's queries a tile at a time (the
     scores of a whole [4, 512] chunk against 2,560 groups would be 0.7
     GB): ``scores_of(lo)`` gives the scaled compressed scores [S, tile,
-    KV, G, J] of queries ``lo .. lo + tile``. Returns chosen [S, C, KV,
-    NB] bool."""
-    from ...models.minicpm_sala import block_scores, topk_mask
+    KV, G, J] of queries ``lo .. lo + tile``. Returns [S, C, KV, NB]
+    float32."""
+    from ...models.minicpm_sala import block_scores
     S, C = pos.shape
 
     def one(lo):
         p = jax.lax.dynamic_slice_in_dim(pos, lo, tile, axis=1)
-        return topk_mask(
-            block_scores(scores_of(lo), p[:, :, None], sp, num_blocks),
-            sp.topk)                                     # [S, tile, KV, NB]
+        return block_scores(scores_of(lo), p[:, :, None], sp, num_blocks)
     out = jax.lax.map(one, jnp.arange(0, C, tile, dtype=jnp.int32))
     return jnp.moveaxis(out, 0, 1).reshape(S, C, *out.shape[3:])
 
@@ -703,10 +701,15 @@ def sparse_paged_attention(kv, li, xi, q, k, v, batch: "RaggedBatch",
     ``lax.cond`` that skips the call no sequence needs; a prefill chunk
     takes the BlockSpec paged kernel unchanged while every query is below
     it, else the block-union kernel, where such a query selects every
-    block. The kernels run on a TPU at whole-tile shapes
-    (``sparse_attention.decode_uses_kernel``; platform and shape decide),
-    their ``jax.numpy`` twins elsewhere. Returns (kv, y [S, C, H*D])."""
-    from ...models.minicpm_sala import select_blocks
+    block. A block's SCORES (the plane's live rows through the block
+    table, window sums, softmax, group sum, window maximum, forced blocks)
+    are one kernel too, ``sparse_attention.block_select_scores``; the
+    top-k of them and the list's rows stay ``jax.numpy``. The kernels run
+    on a TPU at whole-tile shapes (``sparse_attention.decode_uses_kernel``
+    / ``select_uses_kernel``; platform and shape decide), their
+    ``jax.numpy`` twins elsewhere. Returns (kv, y [S, C, H*D])."""
+    from ...models.minicpm_sala import (block_scores, blocks_of_scores,
+                                        topk_mask)
     from ...ops.kernels import default_interpret, sparse_attention as sa
     from . import index_plane
     S, C, H, D = q.shape
@@ -717,6 +720,9 @@ def sparse_paged_attention(kv, li, xi, q, k, v, batch: "RaggedBatch",
     impl = _attention_impl(cfg)
     use_kernel = impl == "paged_flash" and (
         sa.decode_uses_kernel(D, sb) or default_interpret())
+    select_kernel = impl == "paged_flash" and (
+        sa.select_uses_kernel(D, G, KV, C, bs, stride, sb)
+        or (default_interpret() and sa.select_fits(G, KV, C, bs, stride)))
     ring_mode = isinstance(kv, RingKV)
     live = batch.n_tokens > 0
     tables = batch.block_tables
@@ -754,6 +760,13 @@ def sparse_paged_attention(kv, li, xi, q, k, v, batch: "RaggedBatch",
         nxt = jnp.concatenate([gs[..., 1:], gs[..., :1]], axis=-1)
         return (gs + nxt) * (0.5 * scale)
 
+    def kernel_scores():
+        """A block's scores [S, C, KV, NB] from the selection kernel."""
+        return sa.block_select_scores(
+            qg, pool.index, xi, tables, pos, batch.n_tokens, sp,
+            pool_block=bs, sm_scale=float(scale), num_blocks=NB,
+            ring_sums=idx, settled=settled, interpret=default_interpret())
+
     def dense(lens_d):
         """Every key at or before the query: the paged pool's own call."""
         return _attend(kv, li, q, KV, batch, cfg, pos, lens_d, scale,
@@ -765,9 +778,11 @@ def sparse_paged_attention(kv, li, xi, q, k, v, batch: "RaggedBatch",
 
         def sparse():
             with region("attn_select"):
-                blocks = select_blocks(scores(qg)[:, 0], pos[:, :1], sp, NB)
+                score = kernel_scores()[:, 0] if select_kernel \
+                    else block_scores(scores(qg)[:, 0], pos[:, :1], sp, NB)
                 rows, col = sa.selection_rows(
-                    blocks, tables, bs, sb, data.shape[2] - bs)
+                    blocks_of_scores(score, sp), tables, bs, sb,
+                    data.shape[2] - bs)
             lens_s = jnp.where(sparse_row, lens, 0)
             kw = dict(sel_block=sb, sm_scale=float(scale))
             if ring_mode:
@@ -792,10 +807,10 @@ def sparse_paged_attention(kv, li, xi, q, k, v, batch: "RaggedBatch",
     def sparse_chunk():
         tile = next(d for d in range(min(C, 128), 0, -1) if C % d == 0)
         with region("attn_select"):
-            chosen = _select_in_tiles(
+            score = kernel_scores() if select_kernel else _scores_in_tiles(
                 lambda lo: scores(jax.lax.dynamic_slice_in_dim(
                     qg, lo, tile, axis=1)), pos, sp, NB, tile)
-            chosen = chosen | is_dense[:, :, None, None]
+            chosen = topk_mask(score, sp.topk) | is_dense[:, :, None, None]
         fn = sa.sparse_prefill_attention if use_kernel \
             else sa.sparse_prefill_reference
         with region("attn_sparse"):

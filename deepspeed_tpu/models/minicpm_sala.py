@@ -239,7 +239,13 @@ def select_blocks(scores, pos, sp: SparseConfig, num_blocks: int):
     ``J = num_blocks x block_size / stride``; pos [...] int32 the query's
     position. Returns blocks [..., K] int32, ``K = min(topk, num_blocks)``,
     ascending, ``-1`` where fewer exist at or before ``pos``."""
-    score = block_scores(scores, pos, sp, num_blocks)
+    return blocks_of_scores(block_scores(scores, pos, sp, num_blocks), sp)
+
+
+def blocks_of_scores(score, sp: SparseConfig):
+    """The top-k of a block's scores [..., NB] (``block_scores``', or the
+    selection kernel's) as :func:`select_blocks` returns it."""
+    num_blocks = score.shape[-1]
     K = min(sp.topk, num_blocks)
     # the mask, then each chosen block to its rank's place (ascending):
     # no sort (``lax.top_k`` over [96, 2, 640] was a sort of 0.47 ms a

@@ -1,11 +1,17 @@
 """Block-selected attention over the paged pool (Pallas TPU): a query reads
 a LIST of selected key blocks, not every live tile.
 
-The selection itself (compressed scores, group sum, window maximum, forced
-blocks, top-k) is ``models/minicpm_sala.select_blocks``; this module holds
-what reads the pool under it, each form with its ``jax.numpy`` twin (what
-every CPU run takes, and what tier-1 holds the kernel to):
+The selection is ``models/minicpm_sala.select_blocks`` (compressed scores,
+group sum, window maximum, forced blocks, top-k); this module holds what
+reads the pool and the compressed-key plane under it, each form with its
+``jax.numpy`` twin (what every CPU run takes, and what tier-1 holds the
+kernel to):
 
+* :func:`block_select_scores` -- the selection's block scores of a tile
+  of queries: a sequence's live plane blocks walked once through the
+  block table, everything up to the forced blocks in VMEM (its twin is
+  ``minicpm_sala.block_scores`` over ``index_plane.group_scores``); the
+  top-k of them stays ``jax.numpy``.
 * :func:`sparse_decode_attention` -- a pure-decode call. Per (sequence, kv
   head) a sorted list of ``K`` selection blocks of ``sb`` rows; the kernel
   copies exactly those rows of K and of V (one kv head's ``D`` lanes of
@@ -562,3 +568,369 @@ def sparse_prefill_attention(q, pool, layer: int, tables, start_pos,
     return jnp.moveaxis(out, 1, 2), jnp.stack(
         [_selected_count(chosen[:, :C], pos[:, :C], seq_lens, sel_block),
          visited])
+
+
+# --------------------------------------------------------------------- #
+# the selection: a tile of queries against the compressed-key plane
+# --------------------------------------------------------------------- #
+
+#: a lane tile of the selection kernel: 16 query heads of a kv head x 8
+#: COLUMNS, lane ``h * 8 + column``; a column is one (query, kv head)
+_SEL_HEADS = 16
+_SEL_COLS = 8
+_SEL_LANES = _SEL_HEADS * _SEL_COLS
+#: groups a pass of the kernel's loops holds
+_SEL_CHUNK = 256
+#: output lanes: the columns of 16 consecutive tiles
+_SEL_SUPER = _LANES // _SEL_COLS
+
+
+def select_uses_kernel(head_dim: int, heads_per_kv: int, kv_heads: int,
+                       queries: int, pool_block: int, stride: int,
+                       sel_block: int) -> bool:
+    """Whether a selection of this shape scores through the Pallas kernel
+    (:func:`block_select_scores`) on this platform: the sparse kernels'
+    own rule, a kv head's query heads inside one lane tile, one query a
+    sequence or whole tiles of eight, plane blocks of whole bfloat16
+    tiles."""
+    return decode_uses_kernel(head_dim, sel_block) \
+        and select_fits(heads_per_kv, kv_heads, queries, pool_block, stride) \
+        and (pool_block // stride) % 16 == 0
+
+
+def select_fits(heads_per_kv: int, kv_heads: int, queries: int,
+                pool_block: int, stride: int) -> bool:
+    """The shapes the selection kernel's layout holds, on any platform
+    (interpret mode included): a chunk of its loops is whole plane
+    blocks."""
+    return heads_per_kv <= _SEL_HEADS \
+        and (queries % _SEL_COLS == 0
+             or (queries == 1 and _SEL_COLS % kv_heads == 0)) \
+        and _SEL_CHUNK % (pool_block // stride) == 0
+
+
+def _block_select_kernel(tables_ref, nblk_ref, nch_ref, off_ref, layer_ref,
+                         q_ref, pos_ref, plane_hbm, *rest, G, KV, D, Cq,
+                         MAXB, per, NB, NG, CH, WIN, PAD, heads, stride,
+                         ks, r, w, init_blocks, local_blocks, hs):
+    """Grid step (i, t): tile t of the queries of sequence group i (G
+    sequences x Cq queries x KV heads = ``nlt`` lane tiles of 8 columns)
+    against the group's plane rows, which step (i, 0) waits for and which
+    stay in VMEM for the group's other tiles."""
+    rest = list(rest)
+    ring_ref = rest.pop(0) if NG else None
+    o_ref, m_scr, sem, g_scr, p_scr = rest
+    i = pl.program_id(0)
+    t = pl.program_id(1)
+    ns, nqt = pl.num_programs(0), pl.num_programs(1)
+    u = i * nqt + t
+    slot = jax.lax.rem(i, 2)
+    nlt = G * KV * Cq // _SEL_COLS
+    ppl = _SEL_COLS // Cq              # (sequence, kv head) pairs a tile
+    W = KV * D
+    i32, f32 = jnp.int32, jnp.float32
+
+    def chunk(c):
+        return pl.ds(pl.multiple_of(c * CH, CH), CH)
+
+    def copies(gi, sl, wait):
+        for g in range(G):
+            s = gi * G + g
+            rows_of = lambda rows: m_scr.at[              # noqa: E731
+                sl, rows, pl.ds(g * W, W)]
+
+            def per_block(b, carry, s=s, rows_of=rows_of):
+                src = pl.multiple_of(tables_ref[s * MAXB + b] * per, per)
+                cp = pltpu.make_async_copy(
+                    plane_hbm.at[layer_ref[0], pl.ds(src, per), :],
+                    rows_of(pl.ds(pl.multiple_of(b * per, per), per)),
+                    sem.at[sl])
+                cp.wait() if wait else cp.start()
+                return carry
+
+            def per_chunk(c, carry, rows_of=rows_of):
+                # a DMA semaphore counts bytes: ONE wait for the copies
+                # of a whole chunk's blocks (0.06 ms of a 0.62 ms call)
+                pltpu.make_async_copy(rows_of(chunk(c)), rows_of(chunk(c)),
+                                      sem.at[sl]).wait()
+                return carry
+            n = nblk_ref[s]
+            whole = n // (CH // per) if wait else 0
+            jax.lax.fori_loop(0, whole, per_chunk, 0)
+            jax.lax.fori_loop(whole * (CH // per), n, per_block, 0)
+
+    @pl.when(u == 0)
+    def _first():
+        # a pair's lanes meet every other pair's rows under a zero weight:
+        # no row may ever hold a NaN
+        def clear(c, carry):
+            for sl in range(2):
+                m_scr[sl, chunk(c), :] = jnp.zeros((CH, G * W), m_scr.dtype)
+            return carry
+        jax.lax.fori_loop(0, m_scr.shape[1] // CH, clear, 0)
+        # the window before group 0 is never a score
+        p_scr[:, :PAD, :] = jnp.full((nlt, PAD, _SEL_LANES), _NEG_INF, f32)
+        copies(i, slot, wait=False)
+
+    @pl.when(t == 0)
+    def _turn():
+        @pl.when(i + 1 < ns)
+        def _next():
+            copies(i + 1, 1 - slot, wait=False)
+        copies(i, slot, wait=True)
+
+    nch = nch_ref[u]
+    lane = jax.lax.broadcasted_iota(i32, (1, _SEL_LANES), 1)
+    mine = (u % _SEL_SUPER) == (lane >> 3)              # this tile's lanes
+    rows = jax.lax.broadcasted_iota(i32, (CH, _SEL_LANES), 0)
+
+    @pl.when(nch == 0)
+    def _idle():
+        for lt in range(nlt):
+            o_ref[0, lt] = jnp.where(mine, _NEG_INF, o_ref[0, lt])
+
+    @pl.when(nch > 0)
+    def _tile():
+        for lt in range(nlt):
+            qr = q_ref[0, 0, lt]                              # [128, D]
+            if ppl > 1:
+                pair = (jax.lax.broadcasted_iota(
+                    i32, (_SEL_LANES, 1), 0) & (_SEL_COLS - 1)) // Cq
+                qr = jnp.concatenate(
+                    [jnp.where(pair == p, qr, jnp.zeros_like(qr))
+                     for p in range(ppl)], axis=1)         # [128, ppl * D]
+            k0 = lt * ppl * D
+            qm = qr.astype(m_scr.dtype)
+
+            def score(c, carry):
+                g_scr[lt, chunk(c), :] = jax.lax.dot_general(
+                    m_scr[slot, chunk(c), k0:k0 + ppl * D], qm,
+                    (((1,), (1,)), ((), ())), preferred_element_type=f32)
+                return carry
+            jax.lax.fori_loop(0, nch, score, 0)
+
+            if NG:
+                # the loop's own groups, float32, over the plane's from
+                # each sequence's first unsettled group on
+                sums = jnp.concatenate(
+                    [ring_ref[g] for g in range(G)], axis=1) / stride
+                rs = jax.lax.dot_general(
+                    sums, qr.astype(f32), (((1,), (1,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=f32)               # [NG, 128]
+                seq_of = (lane & (_SEL_COLS - 1)) // KV
+                for g in range(G):
+                    off = off_ref[i * G + g]
+                    base = pl.multiple_of((off // 8) * 8, 8)
+                    d = off - base
+                    place = (jax.lax.broadcasted_iota(i32, (WIN, NG), 0)
+                             == jax.lax.broadcasted_iota(i32, (WIN, NG), 1)
+                             + d).astype(f32)
+                    put = jax.lax.dot_general(
+                        place, rs, (((1,), (0,)), ((), ())),
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=f32)           # [WIN, 128]
+                    rel = jax.lax.broadcasted_iota(
+                        i32, (WIN, _SEL_LANES), 0) - d
+                    own = (rel >= 0) & (rel < NG) & (seq_of == g)
+                    g_scr[lt, pl.ds(base, WIN), :] = jnp.where(
+                        own, put, g_scr[lt, pl.ds(base, WIN), :])
+
+            pos = pos_ref[0, 0, lt, 0:1, :]                   # [1, 128]
+            tb = pos_ref[0, 0, lt, 1:2, :]
+
+            def valid_of(c):
+                return (c * CH + rows) * stride + (ks - 1) <= pos
+
+            def window(c, m):
+                # a window's score from two neighbouring groups
+                x = g_scr[lt, pl.ds(pl.multiple_of(c * CH, CH), CH + 8), :]
+                sc = ((x + pltpu.roll(x, CH + 7, 0)) * hs)[:CH]
+                sc = jnp.where(valid_of(c), sc, _NEG_INF)
+                g_scr[lt, chunk(c), :] = sc
+                return jnp.maximum(m, jnp.max(sc, axis=0, keepdims=True))
+            m = jax.lax.fori_loop(
+                0, nch, window, jnp.full((1, _SEL_LANES), _NEG_INF, f32))
+            m = jnp.where(jnp.isfinite(m), m, 0.0)
+
+            def expo(c, l):
+                e = jnp.exp(g_scr[lt, chunk(c), :] - m)
+                g_scr[lt, chunk(c), :] = e
+                return l + jnp.sum(e, axis=0, keepdims=True)
+            l = jax.lax.fori_loop(
+                0, nch, expo, jnp.zeros((1, _SEL_LANES), f32))
+            l = jnp.where(l == 0.0, 1.0, l)
+
+            def group_sum(c, carry):
+                p = g_scr[lt, chunk(c), :] / l
+                if heads < _SEL_HEADS:
+                    p = jnp.where((lane >> 3) < heads, p, 0.0)
+                for sh in (8, 16, 32, 64):                    # over h
+                    p = p + pltpu.roll(p, sh, 1)
+                p_scr[lt, pl.ds(pl.multiple_of(c * CH + PAD, 8), CH), :] = \
+                    jnp.where(valid_of(c), p, _NEG_INF)
+                return carry
+            jax.lax.fori_loop(0, nch, group_sum, 0)
+
+            sc = None
+            for k in range(-(w - 1), r):
+                v = p_scr[lt, pl.ds(PAD + k, NB, stride=r), :]
+                sc = v if sc is None else jnp.maximum(sc, v)
+            b = jax.lax.broadcasted_iota(i32, (NB, _SEL_LANES), 0)
+            forced = (b < init_blocks) | (b > tb - local_blocks)
+            sc = jnp.where(b <= tb, jnp.where(forced, jnp.inf, sc),
+                           _NEG_INF)
+            o_ref[0, lt] = jnp.where(mine, sc, o_ref[0, lt])
+
+
+def block_select_call(tables, nblk, nch, off, layer, qrows, posrows, index,
+                      ring, *, G, KV, Cq, per, NB, heads, stride, ks, r, w,
+                      init_blocks, local_blocks, hs, interpret):
+    """The selection kernel's one Mosaic call (``_block_select_call`` is
+    its jitted, named form)."""
+    NS, NQT, nlt, _, D = qrows.shape
+    MAXB = tables.shape[0] // (NS * G)
+    W = KV * D
+    CH = _SEL_CHUNK
+    Jp = -(-MAXB * per // CH) * CH
+    NG = 0 if ring is None else ring.shape[2]
+    WIN = -(-(NG + 7) // 8) * 8 if NG else 8
+    PAD = 8
+    assert CH % r == 0 and CH % per == 0 and w - 1 <= PAD
+    kernel = functools.partial(
+        _block_select_kernel, G=G, KV=KV, D=D, Cq=Cq, MAXB=MAXB, per=per,
+        NB=NB, NG=NG, CH=CH, WIN=WIN, PAD=PAD, heads=heads, stride=stride,
+        ks=ks, r=r, w=w, init_blocks=init_blocks,
+        local_blocks=local_blocks, hs=hs)
+    in_specs = [
+        pl.BlockSpec((1, 1, nlt, _SEL_LANES, D),
+                     lambda i, t, *_: (i, t, 0, 0, 0)),
+        pl.BlockSpec((1, 1, nlt, 8, _SEL_LANES),
+                     lambda i, t, *_: (i, t, 0, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [qrows, posrows, index]
+    if NG:
+        in_specs.append(pl.BlockSpec(
+            (None, G, NG, W), lambda i, t, *refs: (refs[4][1], i, 0, 0)))
+        operands.append(ring)
+    NU = NS * NQT
+    item = index.dtype.itemsize
+    vmem = 2 * Jp * G * W * item + 4 * nlt * (Jp + WIN + PAD) * _LANES * 4 \
+        + 4 * nlt * NB * _LANES * 4 + 8 * CH * max(_LANES, G * W) * 4 \
+        + _VMEM_MARGIN
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5, grid=(NS, NQT), in_specs=in_specs,
+        out_specs=pl.BlockSpec(
+            (1, nlt, NB, _LANES),
+            lambda i, t, *_: ((i * NQT + t) // _SEL_SUPER, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, Jp, G * W), index.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((nlt, Jp + WIN, _SEL_LANES), jnp.float32),
+            pltpu.VMEM((nlt, Jp + PAD, _SEL_LANES), jnp.float32)])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (-(-NU // _SEL_SUPER), nlt, NB, _LANES), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=interpret, name="block_select",
+    )(tables, nblk, nch, off, layer, *operands)
+
+
+# one traced, once-lowered function the sparse layers of a program share
+# (see ``_sparse_decode_call``); NO reader's pattern matches its name
+_block_select_call = jax.jit(block_select_call, static_argnames=(
+    "G", "KV", "Cq", "per", "NB", "heads", "stride", "ks", "r", "w",
+    "init_blocks", "local_blocks", "hs", "interpret"))
+
+
+def block_select_scores(q, index, layer: int, tables, pos, n_tokens, sp, *,
+                        pool_block: int, sm_scale: float, num_blocks: int,
+                        ring_sums=None, ring_layer=None, settled=None,
+                        interpret: bool = False):
+    """The selection's block scores as ONE kernel over the compressed-key
+    plane: what ``minicpm_sala.block_scores`` makes of
+    ``index_plane.group_scores``, without the gathered plane or a score a
+    query head in HBM. q [S, C, KV, Hg, D]; index [Ls, R, KV*D] the
+    plane, ``layer`` its sparse layer; tables [S, MAXB]; pos [S, C] the
+    queries' positions, of which a sequence's first ``n_tokens`` [S] are
+    real (0: the row does nothing). In a fused loop ``ring_sums`` is
+    ``RingKV.idx`` [Ls, S, NG, KV*D] float32 and ``settled`` [S] the
+    loop's first position a sequence: the groups from ``settled //
+    stride`` on read the loop's sums. Returns [S, C, KV, num_blocks]
+    float32: ``+inf`` forced, ``-inf`` past the query's own block and for
+    a query that is not real.
+
+    The kernel walks a sequence's plane blocks through the block table
+    itself (the next sequence group's copies in flight), only those at or
+    before the tile's last position, scores a kv head's query heads
+    against that head's lanes, and holds the window sums, the softmax
+    over the groups, the sum over the group's heads, the window maximum
+    and the forced blocks in VMEM."""
+    S, C, KV, Hg, D = q.shape
+    i32 = jnp.int32
+    stride, ks, sb = sp.kernel_stride, sp.kernel_size, sp.block_size
+    per = pool_block // stride
+    r, w = sb // stride, ks // stride
+    MAXB = tables.shape[1]
+    Cq = 1 if C == 1 else _SEL_COLS
+    G = _SEL_COLS // KV if C == 1 else 1
+    nlt = G * KV * Cq // _SEL_COLS
+    NS, NQT = -(-S // G), C // Cq
+    Sp = NS * G
+    real = jnp.arange(C, dtype=i32)[None, :] < n_tokens[:, None]
+    posq = jnp.where(real, pos.astype(i32), -1)                  # [S, C]
+    live = n_tokens > 0
+    if ring_sums is None:
+        nblk = jnp.where(live, jnp.max(posq, axis=1) // pool_block + 1, 0)
+        off = jnp.zeros((S,), i32)
+    else:
+        nblk = jnp.where(live, -(-settled // pool_block), 0)
+        off = jnp.clip(settled // stride, 0, MAXB * per - 1)
+    nblk = jnp.clip(nblk, 0, MAXB)
+
+    def rows(x, fill=0, axis=0):
+        """``x`` with its sequence axis padded to whole groups."""
+        if x is None or Sp == S:
+            return x
+        width = [(0, 0)] * x.ndim
+        width[axis] = (0, Sp - S)
+        return jnp.pad(x, width, constant_values=fill)
+    # columns of a grid step in the order (sequence, kv head, query),
+    # eight a lane tile; a tile's lane is h * 8 + column
+    qp = jnp.pad(rows(q), ((0, 0),) * 3 + ((0, _SEL_HEADS - Hg), (0, 0)))
+    qrows = qp.reshape(NS, G, NQT, Cq, KV, _SEL_HEADS, D).transpose(
+        0, 2, 1, 4, 3, 5, 6).reshape(NS, NQT, nlt, _SEL_COLS, _SEL_HEADS, D)
+    qrows = qrows.swapaxes(3, 4).reshape(NS, NQT, nlt, _SEL_LANES, D)
+    pc = jnp.broadcast_to(
+        rows(posq, -1).reshape(NS, G, NQT, Cq)[..., None],
+        (NS, G, NQT, Cq, KV)).transpose(0, 2, 1, 4, 3).reshape(
+            NS, NQT, nlt, _SEL_COLS)
+    nch = jnp.minimum(
+        -(-(jnp.max(pc, axis=(2, 3)) // stride + 1) // _SEL_CHUNK),
+        -(-MAXB * per // _SEL_CHUNK))
+    # a lane tile's rows: its columns' positions and their own blocks
+    posrows = jnp.tile(jnp.stack(
+        [pc, jnp.where(pc >= 0, pc // sb, -1)], axis=3),
+        (1, 1, 1, 1, _SEL_HEADS))
+    posrows = jnp.pad(posrows, ((0, 0),) * 3 + ((0, 6), (0, 0)))
+    rl = layer if ring_layer is None else ring_layer
+    out = _block_select_call(
+        rows(tables.astype(i32)).reshape(-1), rows(nblk.astype(i32)),
+        nch.reshape(-1).astype(i32), rows(off.astype(i32)),
+        jnp.asarray([layer, rl], i32), qrows, posrows, index,
+        rows(ring_sums, axis=1),
+        G=G, KV=KV, Cq=Cq, per=per, NB=num_blocks, heads=Hg, stride=stride,
+        ks=ks, r=r, w=w, init_blocks=sp.init_blocks,
+        local_blocks=sp.local_blocks, hs=0.5 * float(sm_scale),
+        interpret=bool(interpret))                    # [NUs, nlt, NB, 128]
+    NUs = out.shape[0]
+    out = out.transpose(1, 0, 3, 2).reshape(
+        nlt, NUs * _SEL_SUPER, _SEL_COLS, num_blocks)[:, :NS * NQT]
+    # (lane tile, column) back to (sequence, kv head, query)
+    out = out.reshape(nlt, NS, NQT, _SEL_COLS, num_blocks).transpose(
+        1, 2, 0, 3, 4).reshape(NS, NQT, G, KV, Cq, num_blocks)
+    return out.transpose(0, 2, 1, 4, 3, 5).reshape(
+        Sp, C, KV, num_blocks)[:S]

@@ -324,6 +324,10 @@ def test_the_regions_and_the_counters_are_in_the_vocabulary(model):
                                                 for t in range(LOOP))
     assert st["sparse_rows_live"] == live * kvh
     assert st["sparse_dense_tokens"] == sp.dense_len - 1
+    # every position from dense_len - 1 on selected: 120 prompt positions
+    # less the 63 below it, and every decode step; no kernel off the TPU
+    assert st["sparse_select_queries"] == 120 - (sp.dense_len - 1) + steps
+    assert st["sparse_select_kernel_queries"] == 0
     assert st["state_slots_live"] == steps
     assert st["state_bytes_live"] == steps * 3 * 4 * 16 * 16 * 4
     assert st["conv_steps_in_place"] == 0
@@ -493,3 +497,85 @@ def test_loader_names_reach_every_leaf(model):
     assert len(have) == len(want)
     for path, leaf in want:
         assert np.array_equal(np.asarray(have[path]), leaf), path
+
+
+def _select_case(case):
+    """One call of the selection at a toy size whose blocks still cross:
+    stride 2, windows of 4, selection blocks of 8 (4 groups), pool blocks
+    of 16 (8 plane rows), top-4, a local window of 16, ``dense_len`` 64.
+    Returns (q, plane, tables, pos, n_tokens, ring sums, settled)."""
+    from deepspeed_tpu.inference.v2 import index_plane
+    S, KV, G, D, bs, maxb, nb = 4, 2, 2, 16, 16, 16, 80
+    rng = np.random.default_rng(11)
+    plane = jnp.asarray(rng.normal(size=(2, (nb + 1) * bs // 2, KV * D)),
+                        jnp.float32)
+    tables = jnp.asarray(np.stack([rng.permutation(nb)[:maxb]
+                                   for _ in range(S)]), jnp.int32)
+    idx = settled = None
+    if case == "pool":
+        start, ntok, C = [200, 97, 64, 255], [1, 1, 1, 1], 1
+    elif case == "ring":
+        # the loop's groups (from settled // 2 on, 5 of them) cross the
+        # edge of a plane block (8 groups) in rows 0 and 3
+        start, ntok, C = [116, 97, 70, 241], [1, 1, 1, 1], 1
+        settled = jnp.asarray([110, 96, 64, 236], jnp.int32)
+        idx = jnp.asarray(rng.normal(size=(2, S, index_plane.ring_group_count(
+            8, 2), KV * D)), jnp.float32)
+    elif case == "straddle":
+        start, ntok, C = [48, 56, 40, 100], [32, 24, 32, 32], 32
+    elif case == "empty_row":
+        start, ntok, C = [96, 0, 130, 0], [32, 0, 32, 0], 32
+    else:
+        assert case == "part_block"
+        # the last pool block holds 3 and 9 of its 16 rows
+        start, ntok, C = [170, 121], [1, 1], 1
+        tables = tables[:2]
+    S = len(start)
+    q = jnp.asarray(rng.normal(size=(S, C, KV, G, D)) * 2.0, jnp.float32)
+    start = jnp.asarray(start, jnp.int32)
+    pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+    return (q, plane, tables, pos, jnp.asarray(ntok, jnp.int32), idx,
+            settled, bs)
+
+
+@pytest.mark.parametrize("case", ["pool", "ring", "straddle", "empty_row",
+                                  "part_block"])
+def test_the_selection_kernel_is_its_twin(case):
+    """Interpret mode against ``block_scores(group_scores(..))``: a real
+    query's block scores are the twin's to float32 rounding with the
+    infinities in the same places, and the selected SETS are the same; a
+    query that is not real (a row with no tokens, a chunk's tail) scores
+    nothing."""
+    from deepspeed_tpu.inference.v2 import index_plane
+    from deepspeed_tpu.inference.v2.kv_quant import KVPool
+    from deepspeed_tpu.models.minicpm_sala import (block_scores,
+                                                   blocks_of_scores)
+    from deepspeed_tpu.ops.kernels import sparse_attention as sa
+    sp = SparseConfig(4, 2, 8, 4, 1, 16, 64)
+    q, plane, tables, pos, ntok, idx, settled, bs = _select_case(case)
+    S, C, KV, G, D = q.shape
+    NB = tables.shape[1] * bs // sp.block_size
+    scale = D ** -0.5
+    assert sa.select_fits(G, KV, C, bs, sp.kernel_stride)
+    kv = KVPool(None, None, None, None, None, plane, None)
+    gs = index_plane.group_scores(kv, 1, q, tables, bs, sp.kernel_stride,
+                                  idx, settled)
+    cs = (gs + jnp.concatenate([gs[..., 1:], gs[..., :1]], -1)) \
+        * (0.5 * scale)
+    want = np.asarray(block_scores(cs, pos[:, :, None], sp, NB))
+    got = np.asarray(sa.block_select_scores(
+        q, plane, 1, tables, pos, ntok, sp, pool_block=bs, sm_scale=scale,
+        num_blocks=NB, ring_sums=idx, settled=settled, interpret=True))
+    real = np.arange(C)[None, :] < np.asarray(ntok)[:, None]
+    assert real.any() and got.shape == want.shape == (S, C, KV, NB)
+    assert (got[~real] == -np.inf).all()
+    got, want = got[real], want[real]
+    finite = np.isfinite(want)
+    assert (np.isfinite(got) == finite).all()
+    assert (got[~finite] == want[~finite]).all()
+    assert finite.sum() > 8 \
+        and np.abs(got[finite] - want[finite]).max() < 1e-5
+    assert (want[finite] > 1e-3).sum() > 4            # the plane was read
+    a, b = (np.asarray(blocks_of_scores(jnp.asarray(x), sp))
+            for x in (got, want))
+    assert (a == b).all() and (b >= 0).sum(-1).min() >= 1

@@ -946,13 +946,14 @@ def test_sala_loop_flush_and_refill_compile_at_96_clients(one_chip,
     the cell's pool (10,300 blocks, contexts to 40,960), from shapes alone:
     every block-selected layer holds BOTH decode kernels under a
     ``lax.cond`` each (the sparse one, named ``sparse_decode`` as
-    ``sparse_attn_roofline.sala`` matches it, and the paged pool's own
-    for sequences below ``dense_len``), every Lightning layer updates its
+    ``sparse_attn_roofline.sala`` matches it, behind the selection's
+    ``block_select``, and the paged pool's own for sequences below
+    ``dense_len``), every Lightning layer updates its
     state through the in-place Mosaic call at [97, 32, 128, 128], the
     state enters donated and comes back aliased, no program copies a
     plane of the pool or of the compressed keys out (the temporaries stay
     under a plane's bytes), and the refill step holds the block-union
-    kernel beside the BlockSpec paged kernel."""
+    kernel and the selection's beside the BlockSpec paged kernel."""
     import json
     import os
     import re
@@ -1010,13 +1011,18 @@ def test_sala_loop_flush_and_refill_compile_at_96_clients(one_chip,
     hlo = exe.as_text()
     assert Counter(_mosaic_call_names(hlo)) == {
         "mamba2_decode_state_update": 6, "sparse_decode": 2,
-        "closed_call": 2}
+        "block_select": 2, "closed_call": 2}
     # the names and shapes the .sala readers match
     assert len(re.findall(
         r"%mamba2_decode_state_update[\w\-.]* = \(f32\[97,32,128,128\]",
         hlo)) == 6
     assert len(re.findall(
         r"%sparse_decode[\w\-.]* = bf16\[96,32,256\]", hlo)) == 2
+    # the selection's scores are ``block_select``'s: no gathered plane and
+    # no score a query head is left in the program
+    big = (r"bf16\[(96,2560,256|15360,16,256)\]"
+           r"|f32\[[\d,]*(32,2560|2,16,25(60|59))\]")
+    assert not re.search(big, hlo), re.findall(big, hlo)[:4]
     mem = exe.memory_analysis()
     state_bytes = 6 * (slots + 1) * 32 * 128 * 128 * 4
     assert mem.alias_size_in_bytes >= state_bytes
@@ -1042,7 +1048,9 @@ def test_sala_loop_flush_and_refill_compile_at_96_clients(one_chip,
                         lowering_platforms=("tpu",)).compile()
     hlo = step.as_text()
     names = Counter(_mosaic_call_names(hlo))
-    assert names["sparse_prefill"] == 2 and len(names) == 2, names
+    assert names["sparse_prefill"] == 2 and names["block_select"] == 2 \
+        and len(names) == 3, names
     assert len(re.findall(
         r"%sparse_prefill[\w\-.]* = bf16\[4,18432,128\]", hlo)) == 2
+    assert not re.search(big, hlo), re.findall(big, hlo)[:4]
     assert step.memory_analysis().temp_size_in_bytes < plane_bytes
