@@ -92,6 +92,7 @@ class Ctx:
         self._t_phase = t_process
         self.setup_s: Optional[float] = None
         self.compiles: Optional[CompileClock] = None
+        self.setup_compiles: Dict[str, float] = {}
         self.trace_dir = os.path.join(ROOT, ".bench_trace", cell_name)
         self._tracing = False
         self.memory_peak_bytes = 0
@@ -123,9 +124,13 @@ class Ctx:
         self._t_phase = now
 
     def window_opens(self) -> None:
-        """The first measured step or request starts now: set-up ends."""
+        """The first measured step or request starts now: set-up ends
+        (and the compile clock's reading here is set-up's own: what a
+        serve cell's reference compiles after the window is not in it)."""
         self.mark("setup_tail")
         self.setup_s = time.perf_counter() - self.t_process
+        if self.compiles is not None:
+            self.setup_compiles = self.compiles.snapshot()
 
     @contextlib.contextmanager
     def span(self, name: str) -> Iterator[None]:

@@ -5,9 +5,10 @@
 
 ``layer_metrics/linear_attn_roofline.solar2.json`` names
 ``kda_decode_cost`` as ``linear_attn_cost.kda_decode_cost``
-(``readers.cost_function``); no reader names the prefill form yet;
-``roofline_share`` below is the same share for a builder's own reduction
-of a traced run.
+(``readers.cost_function``), and since PR 54
+``linear_attn_prefill_roofline.solar2.json`` / ``.kimi.json`` name
+``kda_prefill_cost`` the same way; ``roofline_share`` below is the same
+share for a builder's own reduction of a traced run.
 """
 
 from __future__ import annotations

@@ -50,11 +50,12 @@ device's, as ``busy_s`` is: summed over devices, over ``n_devices``; so
 Known limit: a fusion carries ONE ``op_name``, its root's, so a fusion
 XLA formed across a region's boundary counts whole on one side.
 
-``run.py`` does not call this yet (``obs["trace"].update(regions.
-from_trace(trace, path))`` in ``read_trace`` is a ``benchmark`` issue's
-edit, and so are the per-layer metrics over these keys: a metric listed
-for a cell has to be on its traced line). Until then, and for any profile
-taken with ``jax.profiler.trace`` around a live engine:
+``run.py::read_trace`` calls :func:`from_trace` on every traced run since
+PR 54 (``obs["trace"].update(...)``: the keys above are addressed as
+``trace.regions.<region>`` and ``trace.region_named_share`` by the
+``*_share`` readers over regions in ``layer_metrics/``); an untraced run
+never imports this module. For any profile taken with
+``jax.profiler.trace`` around a live engine, or a saved one again:
 
     python3 -m benchmark.regions .bench_trace/serve-chat-steady
 """

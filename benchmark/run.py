@@ -49,14 +49,19 @@ def read_trace(path: str):
     """One reading of a profile (``.xplane.pb``): ``reduce_trace``'s sums
     (window, busy, operations, collectives: computed as without the
     program's spans) with the idle gaps named by what the host was doing,
-    ``<bench span>/<innermost program span>`` (``program_spans``). Returns
-    ``obs["trace"]`` and the printed ``breakdown``."""
-    from benchmark import program_spans, reduce_trace
+    ``<bench span>/<innermost program span>`` (``program_spans``), and
+    the device's seconds by region of the step programs (``regions``:
+    nothing where the program has none). Returns ``obs["trace"]`` and the
+    printed ``breakdown``."""
+    from benchmark import program_spans, reduce_trace, regions
     trace = reduce_trace.load(path)
     reduced = reduce_trace.reduce(trace)
     reduced["idle_s"] = reduced["window_s"] - reduced["busy_s"]
     named = program_spans.name_gaps(program_spans.from_trace(trace))
     reduced.update(named["trace"])
+    t0 = time.perf_counter()
+    reduced.update(regions.from_trace(trace, path))
+    reduced["regions_read_s"] = time.perf_counter() - t0
     return reduced, {
         "device_ops": reduced["device_ops"],
         "idle_gaps": named["breakdown"]["idle_gaps"]}
@@ -115,7 +120,8 @@ def run_cell(argv=None):
     job = importlib.import_module(f"benchmark.jobs.{cell['kind']}")
     result = job.run(ctx)
     obs = result["obs"]
-    obs["setup"] = dict(ctx.compiles.snapshot(), phase_s=ctx.phase_s)
+    obs["setup"] = dict(ctx.compiles.snapshot(), phase_s=ctx.phase_s,
+                        at_window_open=ctx.setup_compiles)
     obs["memory_peak_bytes"] = ctx.memory_peak_bytes
     device["memory_peak_bytes"] = ctx.memory_peak_bytes
     line = {"correct": all(result["checks"].values()),
@@ -138,7 +144,8 @@ def run_cell(argv=None):
         device["window_s"] = reduced["window_s"]
         say("trace", {k: reduced.get(k) for k in (
             "idle_named_share", "clock_offset_s", "device_programs",
-            "idle_by_phase")})
+            "idle_by_phase", "region_named_share", "regions",
+            "regions_read_s")})
         if reduced["busy_s"] <= 0:
             line["correct"] = False
         for m in _metrics_of(manifest, "per_layer", entry["name"]):

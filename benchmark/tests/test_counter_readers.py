@@ -30,10 +30,17 @@ PIPELINE = {
     "decode_kv_rows_live": 950, "decode_kv_rows_fetched": 1000,
     "latent_rows_live": 800, "latent_rows_fetched": 1000,
     "moe_rows_routed": 1000, "moe_rows_hottest": 1300,
-    "moe_experts_hit": 5000, "moe_expert_reads": 5010}
+    "moe_experts_hit": 5000, "moe_expert_reads": 5010,
+    "moe_prefill_tokens": 4000, "moe_prefill_kernel_tokens": 3000,
+    "linear_attn_prefill_tokens": 4000,
+    "linear_attn_prefill_kernel_tokens": 4000,
+    "sparse_select_queries": 8000, "sparse_select_kernel_queries": 6000,
+    "window_rows_live": 900, "window_rows_scored": 1200}
 OBS = {"pipeline": PIPELINE, "rounds": 12,
        "step_stats": {"steps": 100, "stage_s": 0.1, "dispatch_s": 0.4,
-                      "commit_apply_s": 0.15},
+                      "commit_apply_s": 0.15,
+                      "flash_score_elems_computed": 1166,
+                      "flash_score_elems_needed": 1000},
        "door_wait_s": [0.001 * i for i in range(101)],
        "sched_wait_s": [0.0002] * 11, "prefill_s": [0.02 * i for i in
                                                     range(11)],
@@ -49,17 +56,20 @@ CASES = [
     ("prefill_rows_per_step.chat", 1.25),
     ("kv_write_rows_per_run.chat", 64.0),
     ("kv_write_rows_per_run.rollout", 64.0),
-    ("latent_live_rows_share.pangu", 80.0),
+    ("latent_live_rows_share.rollout", 80.0),
     ("host_launch_ms_per_step.train", 6.5),
-    ("host_launch_ms_per_step.zero3", 6.5),
-] + [(f"decode_live_rows_share.{c}", 95.0)
-     for c in ("chat", "rollout", "olmoe", "solar2")] \
-  + [(f"prefill_useful_share.{c}", 100 * 900 / 2048)
-     for c in ("rollout", "olmoe", "solar2", "pangu")] \
-  + [(f"fused_host_ms_per_round.{c}", 3.5)
-     for c in ("rollout", "olmoe", "solar2", "pangu")] \
-  + [(f"expert_imbalance.{c}", 1.3) for c in ("olmoe", "solar2", "pangu")] \
-  + [(f"moe_reads_per_hit.{c}", 1.002) for c in ("olmoe", "solar2", "pangu")]
+    ("decode_live_rows_share.chat", 95.0),
+    ("decode_live_rows_share.rollout", 95.0),
+    ("prefill_useful_share.rollout", 100 * 900 / 2048),
+    ("fused_host_ms_per_round.rollout", 3.5),
+    ("expert_imbalance.rollout", 1.3), ("moe_reads_per_hit.rollout", 1.002),
+    # the counters PR 39-53 left without a reader (PR 54)
+    ("flash_score_area_share.train", 1.166),
+    ("window_scored_share.mellum2", 75.0),
+    ("moe_prefill_kernel_share.rollout", 75.0),
+    ("linear_attn_prefill_kernel_share.rollout", 100.0),
+    ("sparse_select_kernel_share.sala", 75.0),
+]
 
 
 @pytest.mark.parametrize("name,value", CASES, ids=[c[0] for c in CASES])
@@ -80,7 +90,12 @@ def test_a_reader_of_the_programs_own_counts(name, value):
 def test_a_counter_that_stayed_at_nought_is_nothing_to_read():
     # a program on the ragged_dot path counts no visit and no hit
     obs = {"pipeline": dict(PIPELINE, moe_experts_hit=0, moe_expert_reads=0)}
-    assert readers.read(_spec("moe_reads_per_hit.olmoe"), obs) is None
+    assert readers.read(_spec("moe_reads_per_hit.rollout"), obs) is None
+    # and a model with no such layer reports no kernel share of 0 / 0
+    none = {"pipeline": dict(PIPELINE, moe_prefill_tokens=0,
+                             moe_prefill_kernel_tokens=0)}
+    assert readers.read(_spec("moe_prefill_kernel_share.rollout"),
+                        none) is None
 
 
 def test_the_delta_takes_every_number_and_nothing_else():
@@ -98,6 +113,7 @@ def test_the_delta_takes_every_number_and_nothing_else():
     ("flash_attention_cost", kernel_cost.flash_attention_cost),
     ("moe_cost.grouped_moe_ffn_cost", moe_cost.grouped_moe_ffn_cost),
     ("linear_attn_cost.kda_decode_cost", linear_attn_cost.kda_decode_cost),
+    ("linear_attn_cost.kda_prefill_cost", linear_attn_cost.kda_prefill_cost),
     ("mla_cost.mla_decode_attention_cost",
      mla_cost.mla_decode_attention_cost),
     ("kernel_cost.flash_attention_cost", kernel_cost.flash_attention_cost),
@@ -123,7 +139,7 @@ def test_every_roofline_reader_names_a_cost_that_exists():
             for c in k["costs"]:
                 assert callable(readers.cost_function(c["cost"])), f
                 seen += 1
-    assert seen >= 11
+    assert seen >= 13
 
 
 @pytest.mark.parametrize("cell,shape,hidden,width", [
@@ -180,6 +196,45 @@ def test_linear_attn_roofline_counts_one_cost_a_call():
                                    "custom-call-f32_4_64_1_64_64": 48}}}
     assert readers.read(_spec("linear_attn_roofline.solar2"), obs) \
         == pytest.approx(80.0)
+
+
+@pytest.mark.parametrize("cell,heads,layers", [("solar2", 64, 3),
+                                               ("kimi", 32, 6)])
+def test_linear_attn_prefill_roofline_is_one_evaluation_a_layer(
+        cell, heads, layers):
+    """A traced round of 40 [4, 512] refill steps whose three rows hold
+    61,437 real positions: the chunk kernel's work is those positions in
+    chunks of 64 and three states read and written, once a KDA layer; a
+    kernel that took five times that reads 20 %, and the padded quarter of
+    the steps is no work."""
+    tokens, rows = 61437.0, 3.0
+    least = kernel_cost.roofline_seconds(linear_attn_cost.kda_prefill_cost(
+        tokens, heads, 128, 128, sequences=rows), PEAK)
+    # q, k, decay, v, output in float32 outweigh the chunk's matmuls
+    assert least["bound"] == "memory"
+    name = f"kda_chunk_prefill-f32_4_512_{heads}_128"
+    obs = {"peak": PEAK,
+           "traced": {"pipeline": {
+               "linear_attn_prefill_kernel_tokens": tokens,
+               "prefill_rows": rows}},
+           "trace": {"n_devices": 1,
+                     "ops": {name: 5 * layers * least["seconds"],
+                             "kda_decode_state_update-f32_129_64_128_128":
+                                 1.0},
+                     "op_counts": {
+                         name: 40 * layers,
+                         "kda_decode_state_update-f32_129_64_128_128": 9}}}
+    spec = _spec(f"linear_attn_prefill_roofline.{cell}")
+    assert readers.read(spec, obs) == pytest.approx(20.0)
+    # the other model's kernel name is not matched, and a stretch whose
+    # refill admitted nothing counts no work: no 0 %
+    other = "kimi" if cell == "solar2" else "solar2"
+    assert readers.read(_spec(f"linear_attn_prefill_roofline.{other}"),
+                        obs) is None
+    assert readers.read(spec, dict(obs, traced={"pipeline": {
+        "linear_attn_prefill_kernel_tokens": 0.0,
+        "prefill_rows": 0.0}})) is None
+    assert readers.read(spec, dict(obs, traced={})) is None
 
 
 def test_per_may_be_a_number_or_a_key():
@@ -271,3 +326,32 @@ def test_the_serve_fixtures_gaps_carry_the_programs_span():
                                          "v5e_matmul_loop.xplane.pb"))
     assert readers.read(_spec("launch_bubble_share.chat"),
                         {"trace": old}) is None
+
+
+def test_the_regions_reach_the_traced_reading_and_no_untraced_run():
+    """``read_trace`` adds the regions' keys (PR 54), addressed as the
+    ``*_share`` readers over regions address them; the module is imported
+    inside ``read_trace`` alone, so an untraced run never loads it."""
+    import subprocess
+    import sys
+    trace, _ = run.read_trace(os.path.join(HERE, "fixtures",
+                                           "v5e_regions.xplane.pb"))
+    obs = {"trace": trace}
+    assert readers.read(_spec("region_named_share.train"), obs) \
+        == pytest.approx(100 * trace["region_named_share"])
+    want = 100 * (trace["regions"]["optimizer"]
+                  + trace["regions"]["grad_clip"]) / trace["busy_s"]
+    assert readers.read(_spec("optimizer_share.train"), obs) \
+        == pytest.approx(want) and want > 0
+    assert trace["regions_read_s"] > 0
+    # a profile of a program without regions: the readers stay silent
+    old, _ = run.read_trace(os.path.join(HERE, "fixtures",
+                                         "v5e_matmul_loop.xplane.pb"))
+    for name in ("region_named_share.rollout", "ffn_dense_share.chat",
+                 "attn_select_share.sala"):
+        assert readers.read(_spec(name), {"trace": old}) is None
+    code = ("import sys; import benchmark.run, benchmark.readers, "
+            "benchmark.jobs.closed_loop; "
+            "assert 'benchmark.regions' not in sys.modules")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   cwd=os.path.dirname(HERE))

@@ -68,6 +68,67 @@ def test_every_layer_metric_has_a_reader_and_moves_a_metric_of_its_cells(m):
     assert readers.read(spec, {}) is None
 
 
+FAMILIES = ("train", "chat", "rollout", "serve")
+
+
+def test_the_manifest_has_room_and_every_entry_names_its_cells():
+    assert len(MANIFEST["per_layer"]) <= 100 < 128
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    assert len(set(names)) == len(names)
+    for m in MANIFEST["per_layer"]:
+        assert "workloads" in m or m["moves"] == "setup_s", m["name"]
+        assert m.get("workloads", True), m["name"]
+    # a reader file for every entry and no file without one
+    files = {f[:-len(".json")] for f in os.listdir(
+        os.path.join(ROOT, "benchmark", "layer_metrics"))}
+    assert files == set(names)
+
+
+def test_no_two_reader_files_are_one_metric_under_two_names():
+    """A metric measured in eight cells is ONE entry with eight cells.
+    Reader files whose bodies are equal apart from ``reads`` may differ by
+    FAMILY only (``.train`` / ``.chat`` / ``.rollout`` / ``.serve``: the
+    cells that report the end-to-end metric each ``moves``), never by
+    cell: a cell joins the families' lists and brings files only for
+    readers whose body is its own (PERF.md section 7)."""
+    folder = os.path.join(ROOT, "benchmark", "layer_metrics")
+    by_body = {}
+    for f in sorted(os.listdir(folder)):
+        spec = _load("layer_metrics", f)
+        spec.pop("reads")
+        by_body.setdefault(json.dumps(spec, sort_keys=True), []).append(
+            f[:-len(".json")])
+    moves = {m["name"]: m["moves"] for m in MANIFEST["per_layer"]}
+    for names in (v for v in by_body.values() if len(v) > 1):
+        families = [n.rpartition(".")[2] for n in names]
+        assert all(f in FAMILIES for f in families), names
+        assert len(set(families)) == len(families), names
+        # twins of a family each move another metric, but for the one
+        # quantity every cell has under two families' lists: set-up
+        moved = {moves[n] for n in names}
+        assert len(moved) == len(names) or moved == {"setup_s"}, names
+
+
+@pytest.mark.parametrize("name", [
+    "queue_wait_p90_ms.chat", "host_ms_per_step.chat",
+    "decode_ms_per_step.rollout", "decode_ms_per_step.olmoe",
+    "decode_ms_per_step.solar2", "decode_ms_per_step.pangu",
+    "batch_occupancy.chat", "batch_occupancy.rollout"])
+def test_the_outside_twins_are_gone(name):
+    assert name not in [m["name"] for m in MANIFEST["per_layer"]]
+    assert not os.path.exists(os.path.join(
+        ROOT, "benchmark", "layer_metrics", name + ".json"))
+
+
+def test_a_family_lists_its_cells_once_and_in_the_manifests_order():
+    order = [w["name"] for w in MANIFEST["workloads"]]
+    for m in MANIFEST["per_layer"]:
+        cells = m.get("workloads")
+        if cells:
+            assert cells == [c for c in order if c in cells], m["name"]
+            assert len(set(cells)) == len(cells), m["name"]
+
+
 def test_bounds_are_within_the_contract():
     for m in MANIFEST["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.1

@@ -1,4 +1,5 @@
-"""The cell ``serve-mellum2-rollout-long`` and its ``.mellum2`` readers:
+"""The cell ``serve-mellum2-rollout-long``, its ``.mellum2`` readers and the
+families' readers that list it (``.rollout`` / ``.serve``, PR 54):
 the job exports every key they name (a ``--rehearse`` walk of the cell on
 the CPU, toy sizes), each counter reader on hand-made observations,
 ``window_attn_cost.mixed_decode_attention_cost`` by hand (with the case
@@ -18,6 +19,7 @@ CONFIG = "mellum2-12b-a2.5b"
 MANIFEST = load_manifest()
 PEAK = kernel_cost.peaks("TPU v5 lite")
 NAMES = [m["name"] for m in run._metrics_of(MANIFEST, "per_layer", CELL)]
+OWN = sorted(n for n in NAMES if n.endswith(".mellum2"))
 
 
 def _spec(name):
@@ -25,7 +27,13 @@ def _spec(name):
 
 
 def test_the_manifest_gives_the_cell_its_metrics():
-    assert len(NAMES) == 14 and all(n.endswith(".mellum2") for n in NAMES)
+    assert OWN == [
+        "grouped_ffn_share.mellum2", "grouped_moe_roofline.mellum2",
+        "paged_attn_roofline.mellum2", "paged_attn_share.mellum2",
+        "window_cache_share.mellum2", "window_live_rows_share.mellum2",
+        "window_scored_share.mellum2"]
+    assert all(n.rpartition(".")[2] in ("rollout", "serve") or "." not in n
+               for n in NAMES if n not in OWN)
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == (CONFIG, "rollout-long", 1)
@@ -103,9 +111,11 @@ PIPELINE = {
     "fused_dispatch_s": 0.030, "fused_apply_s": 0.012,
     "decode_kv_rows_live": 800, "decode_kv_rows_fetched": 1000,
     "kv_bytes_live": 3_000_000, "window_rows_live": 900,
-    "window_rows_fetched": 1200, "window_bytes_live": 1_000_000,
+    "window_rows_fetched": 1200, "window_rows_scored": 1200,
+    "window_bytes_live": 1_000_000,
     "moe_rows_routed": 1000, "moe_rows_hottest": 1300,
-    "moe_experts_hit": 5000, "moe_expert_reads": 5010}
+    "moe_experts_hit": 5000, "moe_expert_reads": 5010,
+    "moe_prefill_tokens": 4000, "moe_prefill_kernel_tokens": 4000}
 OBS = {"pipeline": PIPELINE, "rounds": 12, "window_s": 40.0,
        "refill_s": 8.0, "memory_peak_bytes": 14.2e9,
        "trace": {"window_s": 4.0, "idle_s": 0.1, "busy_s": 3.9}}
@@ -114,15 +124,18 @@ OBS = {"pipeline": PIPELINE, "rounds": 12, "window_s": 40.0,
 @pytest.mark.parametrize("name, want", [
     ("window_cache_share.mellum2", 25.0),
     ("window_live_rows_share.mellum2", 75.0),
-    ("decode_live_rows_share.mellum2", 80.0),
-    ("expert_imbalance.mellum2", 1.3),
-    ("moe_reads_per_hit.mellum2", 1.002),
-    ("prefill_useful_share.mellum2", 100 * 900 / 2048),
-    ("fused_host_ms_per_round.mellum2", 3.5),
-    ("refill_wall_share.mellum2", 20.0),
-    ("device_idle_share.mellum2", 2.5),
-    ("peak_hbm_gb.mellum2", 14.2)])
+    ("window_scored_share.mellum2", 75.0),
+    ("decode_live_rows_share.rollout", 80.0),
+    ("expert_imbalance.rollout", 1.3),
+    ("moe_reads_per_hit.rollout", 1.002),
+    ("prefill_useful_share.rollout", 100 * 900 / 2048),
+    ("fused_host_ms_per_round.rollout", 3.5),
+    ("refill_wall_share.rollout", 20.0),
+    ("device_idle_share.rollout", 2.5),
+    ("peak_hbm_gb.rollout", 14.2),
+    ("moe_prefill_kernel_share.rollout", 100.0)])
 def test_counter_readers(name, want):
+    assert name in NAMES            # the family's list holds this cell
     assert readers.read(_spec(name), OBS) == pytest.approx(want)
     assert readers.read(_spec(name), {}) is None
 

@@ -1,5 +1,6 @@
-"""The cell ``serve-nemotron3-nano-rollout-long`` and its ``.nemotron``
-readers: the job exports every key they name (a ``--rehearse`` walk of the
+"""The cell ``serve-nemotron3-nano-rollout-long``, its ``.nemotron``
+readers and the families' readers that list it (``.rollout`` / ``.serve``,
+PR 54): the job exports every key they name (a ``--rehearse`` walk of the
 cell on the CPU, toy sizes), each counter reader on hand-made observations,
 the two cost functions of ``ssm_cost.py`` by hand, and the roofline readers
 against a hand-made trace that carries the kernel names the v5e compile
@@ -17,6 +18,7 @@ CONFIG = "nemotron-3-nano-30b-a3b"
 MANIFEST = load_manifest()
 PEAK = kernel_cost.peaks("TPU v5 lite")
 NAMES = [m["name"] for m in run._metrics_of(MANIFEST, "per_layer", CELL)]
+OWN = sorted(n for n in NAMES if n.endswith(".nemotron"))
 
 
 def _spec(name):
@@ -24,7 +26,12 @@ def _spec(name):
 
 
 def test_the_manifest_gives_the_cell_its_metrics():
-    assert len(NAMES) == 14 and all(n.endswith(".nemotron") for n in NAMES)
+    assert OWN == [
+        "grouped_ffn_share.nemotron", "grouped_moe_roofline.nemotron",
+        "paged_attn_roofline.nemotron", "ssm_roofline.nemotron",
+        "ssm_share.nemotron"]
+    assert all(n.rpartition(".")[2] in ("rollout", "serve") or "." not in n
+               for n in NAMES if n not in OWN)
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == (CONFIG, "rollout-long", 1)
@@ -88,24 +95,28 @@ PIPELINE = {
     "fused_dispatch_s": 0.030, "fused_apply_s": 0.012,
     "decode_kv_rows_live": 800, "decode_kv_rows_fetched": 1000,
     "kv_bytes_live": 1_000_000, "state_bytes_live": 3_000_000,
+    "latent_bytes_live": 0,
     "moe_rows_routed": 1000, "moe_rows_hottest": 1300,
-    "moe_experts_hit": 5000, "moe_expert_reads": 5010}
+    "moe_experts_hit": 5000, "moe_expert_reads": 5010,
+    "moe_prefill_tokens": 4000, "moe_prefill_kernel_tokens": 4000}
 OBS = {"pipeline": PIPELINE, "rounds": 12, "window_s": 40.0,
        "refill_s": 8.0, "memory_peak_bytes": 13.6e9,
        "trace": {"window_s": 4.0, "idle_s": 0.1, "busy_s": 3.9}}
 
 
 @pytest.mark.parametrize("name, want", [
-    ("state_cache_share.nemotron", 75.0),
-    ("decode_live_rows_share.nemotron", 80.0),
-    ("expert_imbalance.nemotron", 1.3),
-    ("moe_reads_per_hit.nemotron", 1.002),
-    ("prefill_useful_share.nemotron", 100 * 900 / 2048),
-    ("fused_host_ms_per_round.nemotron", 3.5),
-    ("refill_wall_share.nemotron", 20.0),
-    ("device_idle_share.nemotron", 2.5),
-    ("peak_hbm_gb.nemotron", 13.6)])
+    ("state_cache_share.rollout", 75.0),
+    ("decode_live_rows_share.rollout", 80.0),
+    ("expert_imbalance.rollout", 1.3),
+    ("moe_reads_per_hit.rollout", 1.002),
+    ("prefill_useful_share.rollout", 100 * 900 / 2048),
+    ("fused_host_ms_per_round.rollout", 3.5),
+    ("refill_wall_share.rollout", 20.0),
+    ("device_idle_share.rollout", 2.5),
+    ("peak_hbm_gb.rollout", 13.6),
+    ("moe_prefill_kernel_share.rollout", 100.0)])
 def test_counter_readers(name, want):
+    assert name in NAMES            # the family's list holds this cell
     assert readers.read(_spec(name), OBS) == pytest.approx(want)
     assert readers.read(_spec(name), {}) is None
 

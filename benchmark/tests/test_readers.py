@@ -18,12 +18,19 @@ def _spec(name):
 def test_percentile_share_ratio_and_value():
     obs = {"gen_late_s": [0.001 * i for i in range(101)],
            "met_limits": [True, True, False, True],
-           "slot_steps_live": 30, "slot_steps": 40,
-           "setup": {"programs": 9}}
+           "pipeline": {"decode_slots_live": 30, "decode_slots_planned": 40},
+           "setup": {"programs": 9, "compile_s": 4.0, "trace_lower_s": 18.0,
+                     "phase_s": {"import": 12.0},
+                     "at_window_open": {"compile_s": 2.5}}}
     assert readers.read(_spec("gen_late_p99_ms"), obs) == pytest.approx(99.0)
     assert readers.read(_spec("goodput_share.chat"), obs) == 75.0
-    assert readers.read(_spec("batch_occupancy.chat"), obs) == 75.0
+    assert readers.read(_spec("bucket_occupancy.chat"), obs) == 75.0
     assert readers.read(_spec("setup_programs.serve"), obs) == 9.0
+    # set-up by phase (PR 54): the phase clock and JAX's own events
+    assert readers.read(_spec("setup_import_s"), obs) == 12.0
+    assert readers.read(_spec("setup_compile_s"), obs) == 2.5
+    assert readers.read(_spec("setup_trace_lower_s.serve"), obs) \
+        == readers.read(_spec("setup_trace_lower_s.train"), obs) == 18.0
 
 
 def test_paged_roofline_is_least_time_over_kernel_time():
@@ -63,4 +70,12 @@ def test_flash_roofline_counts_two_forwards_and_one_backward_per_four_calls():
                      "ops": {"attn-bf16_2_16_2048_128": 2 * least},
                      "op_counts": {"attn-bf16_2_16_2048_128": calls}}}
     assert readers.read(_spec("flash_attn_roofline.train"), obs) \
+        == pytest.approx(50.0)
+    # on four chips a device's share: the same calls and seconds on each
+    # of four devices read the same 50 % (a chip's batch is 2 there too)
+    four = {"peak": peak, "attention": att,
+            "trace": {"n_devices": 4,
+                      "ops": {"attn-bf16_2_16_2048_128": 4 * 2 * least},
+                      "op_counts": {"attn-bf16_2_16_2048_128": 4 * calls}}}
+    assert readers.read(_spec("flash_attn_roofline.train"), four) \
         == pytest.approx(50.0)

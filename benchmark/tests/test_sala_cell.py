@@ -1,12 +1,14 @@
-"""The cell ``serve-minicpm-sala-rollout-32k`` and its readers, the six
-``.sala`` files this cell brought (the manifest holds 128 per-layer
-metrics and had 122, so the family every other rollout cell has waits for a
-``benchmark`` issue to make room: ``PERF.md`` section 7). The engine counts every key the readers name (a toy engine, the
-job's own delta), each counter reader on hand-made observations, the cost
+"""The cell ``serve-minicpm-sala-rollout-32k`` and its readers: the nine
+``.sala`` files whose bodies are its own (six since PR 51, three since
+PR 54) and the families' readers that list it since PR 54 made room
+(``.rollout`` / ``.serve``). The engine counts every key the readers name
+(a toy engine, the job's own delta), each counter reader on hand-made
+observations, the mix's equal rounds under ten seeds, the cost
 functions of ``sparse_attn_cost.py`` by hand, the roofline readers against
 a hand-made trace that carries the kernel names the v5e compile gives at
-the published widths, and ``mix_stats`` of ``rollout-32k`` against what the
-mix file quotes (``tests/unit/test_minicpm_sala.py`` has the model; a time
+the published widths, a rehearsal of the cell (fill, one round, the traced
+stretch), and ``mix_stats`` of ``rollout-32k`` against what the mix file
+quotes (``tests/unit/test_minicpm_sala.py`` has the model; a time
 comes only from a chip run). Nothing here looks at where in
 ``BENCHMARK.json``'s lists the entries stand."""
 
@@ -14,7 +16,7 @@ import pytest
 
 from benchmark import kernel_cost, readers, run, sparse_attn_cost, ssm_cost
 from benchmark.common import load_json, load_manifest
-from benchmark.traffic import first_wave, mix_stats
+from benchmark.traffic import closed_loop_requests, first_wave, mix_stats
 
 CELL = "serve-minicpm-sala-rollout-32k"
 CONFIG = "minicpm-sala-9b"
@@ -30,13 +32,20 @@ def _spec(name):
 
 def test_the_manifest_gives_the_cell_its_metrics():
     assert sorted(SALA) == [
-        "linear_attn_roofline.sala", "sparse_attn_roofline.sala",
+        "attn_select_share.sala", "linear_attn_roofline.sala",
+        "linear_attn_share.sala", "sparse_attn_roofline.sala",
         "sparse_attn_share.sala", "sparse_prefill_roofline.sala",
-        "sparse_prefill_visit_ratio.sala", "sparse_read_share.sala"]
-    # no metric under another model's suffix: the cell joined no list but
-    # serve_tok_s's
-    assert NAMES == SALA
-    assert len(MANIFEST["per_layer"]) <= 128
+        "sparse_prefill_visit_ratio.sala", "sparse_read_share.sala",
+        "sparse_select_kernel_share.sala"]
+    # no metric under another model's suffix; the families that list it
+    family = [n for n in NAMES if n not in SALA]
+    assert all(n.rpartition(".")[2] in ("rollout", "serve") or "." not in n
+               for n in family)
+    assert {"device_idle_share.rollout", "peak_hbm_gb.rollout",
+            "prefill_useful_share.rollout", "refill_wall_share.rollout",
+            "fused_host_ms_per_round.rollout", "state_cache_share.rollout",
+            "region_named_share.rollout"} <= set(family)
+    assert len(cell_why()) <= 200
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == (CONFIG, "rollout-32k", 1)
@@ -60,27 +69,64 @@ def test_the_manifest_gives_the_cell_its_metrics():
     assert pool["state_pool_bytes"] == 97 * pool["state_bytes_per_sequence"]
 
 
+def cell_why():
+    return next(w for w in MANIFEST["workloads"] if w["name"] == CELL)["why"]
+
+
 def test_the_mix_is_what_the_issue_named_and_quotes_its_own_numbers():
     mix = load_json("traffic", "rollout-32k.json")
     assert (mix["prompt_lens"], mix["prompt_shares"], mix["gen_lens"]) \
-        == ([12288, 20480, 32768], [1, 1, 1], [8192])
-    # ISSUE 51's table, as the other rollout mixes: a round refills a
-    # quarter of a block, so its work goes by the seed (PERF.md section 6)
+        == ([20480], [1], [8192])
+    # ISSUE 54's table: ONE prompt class, the block of 12 left as it was
     assert mix["shuffle_block"] == load_json(
         "traffic", "rollout-long.json")["shuffle_block"] == 12
     stats = mix_stats(mix)
     for key, val in stats.items():
         assert mix["mix_stats"][key] == pytest.approx(val), key
-    assert mix["mix_stats"]["longest"] == 32768 + 8192
-    # 32 clients a class, one at each of the 32 phases of an 8,192-token
-    # output: every round 3 finish and 3 prompts refill
+    assert mix["mix_stats"]["mean_live_context"] == 24576
+    assert mix["mix_stats"]["longest"] == 20480 + 8192
+    # three clients at each of the 32 phases of an 8,192-token output:
+    # every round 3 finish and 3 prompts refill
     wave = first_wave(mix, 96, 256, seed=1, vocab=1000)
     remaining = sorted(r.gen_len for r in wave)
-    assert remaining == sorted(256 * (k + 1) for k in range(32)) * 1 * 3 \
-        or remaining == sorted([256 * (k + 1) for k in range(32)] * 3)
-    live = sum(len(r.prompt) for r in wave) / 96
-    assert live == pytest.approx(21845.33 + 256 * 15.5, rel=1e-4)
-    assert min(len(r.prompt) for r in wave) >= 12288 > 8192
+    assert remaining == sorted([256 * (k + 1) for k in range(32)] * 3)
+    live = sum(len(r.prompt) for r in wave)
+    assert live == 96 * 20480 + 3 * 256 * sum(range(32)) == 2347008
+    assert min(len(r.prompt) for r in wave) >= 20480 > 8192
+    # the first wave fits the pool with each sequence's last block partly
+    # filled, at its start and at the end of a loop (+ 256 a sequence)
+    eng = load_json("cells", CELL + ".json")["engine"]
+    blocks = sum(-(-(len(r.prompt) + 256) // eng["block_size"])
+                 for r in wave)
+    assert blocks == 9264 <= eng["num_blocks"]
+    assert max(len(r.prompt) + r.gen_len for r in wave) \
+        == eng["max_blocks_per_seq"] * eng["block_size"] - 12288
+
+
+@pytest.mark.parametrize("seed", [0, 1, 7, 2147483659, 2971000003,
+                                  3205000057, 3616000063, 4027000079,
+                                  4294967295, 5400000101])
+def test_every_round_refills_three_equal_prompts(seed):
+    """The closed loop hands the finished slots the next requests of the
+    seed's list, three a round: under every seed each is 20,480 tokens in
+    and 8,192 out, so a round is 40 [4, 512] steps with 3 rows of 4 real
+    (prefill_useful_share 75 %); only the token ids are the seed's."""
+    mix = load_json("traffic", "rollout-32k.json")
+    cell = load_json("cells", CELL + ".json")
+    reqs = closed_loop_requests(mix, cell["planned_requests"], seed, 1000)
+    assert len(reqs) == 288
+    chunk = cell["engine"]["chunk_size"]
+    for i in range(0, len(reqs), 3):
+        a_round = reqs[i:i + 3]
+        assert [(len(r.prompt), r.gen_len) for r in a_round] \
+            == [(20480, 8192)] * 3
+        # prefilled up to its last token, which the loop feeds
+        steps = max(-(-(len(r.prompt) - 1) // chunk) for r in a_round)
+        real = sum(len(r.prompt) - 1 for r in a_round)
+        assert steps == 40 and real / (steps * 4 * chunk) \
+            == pytest.approx(0.75, abs=1e-4)
+    other = closed_loop_requests(mix, 3, seed + 1, 1000)
+    assert other[0].prompt != reqs[0].prompt
 
 
 def test_an_engine_counts_every_key_the_readers_name():
@@ -105,7 +151,7 @@ def test_an_engine_counts_every_key_the_readers_name():
     for name in NAMES:
         for key in readers.keys_of(_spec(name)):
             head, _, rest = key.partition(".")
-            if head in ("trace", "peak") or key in job:
+            if head in ("trace", "peak", "setup") or key in job:
                 continue
             counter = key.replace("traced.", "").replace("pipeline.", "")
             if counter not in eng.pipeline_stats:
@@ -118,15 +164,31 @@ def test_an_engine_counts_every_key_the_readers_name():
 
 OBS = {"pipeline": {
     "sparse_rows_selected": 2 * 4096.0 * 96 * 256,
-    "sparse_rows_live": 2 * 25941.0 * 96 * 256,
+    "sparse_rows_live": 2 * 24576.0 * 96 * 256,
     "sparse_prefill_blocks_selected": 1000.0,
-    "sparse_prefill_blocks_visited": 3500.0}}
+    "sparse_prefill_blocks_visited": 3500.0,
+    "sparse_select_queries": 8000, "sparse_select_kernel_queries": 8000,
+    "prefill_tokens_real": 61437, "prefill_tokens_planned": 81920,
+    "fused_dispatch_s": 0.030, "fused_apply_s": 0.012,
+    "state_bytes_live": 1_000_000, "kv_bytes_live": 3_000_000,
+    "latent_bytes_live": 0},
+    "rounds": 6, "window_s": 40.0, "refill_s": 17.4,
+    "memory_peak_bytes": 12.53e9,
+    "trace": {"window_s": 6.7, "idle_s": 0.0335, "busy_s": 6.6665}}
 
 
 @pytest.mark.parametrize("name, want", [
-    ("sparse_read_share.sala", 100 * 4096 / 25941),
-    ("sparse_prefill_visit_ratio.sala", 3.5)])
+    ("sparse_read_share.sala", 100 * 4096 / 24576),
+    ("sparse_prefill_visit_ratio.sala", 3.5),
+    ("sparse_select_kernel_share.sala", 100.0),
+    ("state_cache_share.rollout", 25.0),
+    ("prefill_useful_share.rollout", 100 * 61437 / 81920),
+    ("fused_host_ms_per_round.rollout", 7.0),
+    ("refill_wall_share.rollout", 43.5),
+    ("device_idle_share.rollout", 0.5),
+    ("peak_hbm_gb.rollout", 12.53)])
 def test_counter_readers(name, want):
+    assert name in NAMES
     assert readers.read(_spec(name), OBS) == pytest.approx(want)
     assert readers.read(_spec(name), {}) is None
 
@@ -135,7 +197,7 @@ def test_the_sparse_decode_cost_by_hand():
     """96 sequences x 2 kv heads x 64 blocks of 64 rows, K and V at 128
     lanes of bfloat16: 4.19 MB a sequence and layer, 0.40 GB a layer and
     step, 0.81 GB over the two layers whatever the context; a dense call
-    at the mix's mean context would read 6.3 times that."""
+    at the mix's mean context would read 6 times that."""
     rows = 96 * 2 * 4096.0
     c = sparse_attn_cost.sparse_decode_attention_cost(rows, 2, 16, 128)
     assert c["bytes"] == 2 * rows * 2 * 128 * 2
@@ -145,8 +207,8 @@ def test_the_sparse_decode_cost_by_hand():
     assert least["bound"] == "memory"
     assert least["seconds"] == pytest.approx(0.983e-3, rel=1e-2)
     dense = kernel_cost.paged_decode_attention_cost(
-        96 * 25941.0, 32, 2, 128)
-    assert 2 * dense["bytes"] / c["bytes"] == pytest.approx(6.33, rel=1e-2)
+        96 * 24576.0, 32, 2, 128)
+    assert 2 * dense["bytes"] / c["bytes"] == pytest.approx(6.0, rel=1e-2)
     # linear in the rows and the layers
     one = sparse_attn_cost.sparse_decode_attention_cost(rows / 2, 1, 16, 128)
     assert one["bytes"] * 4 == c["bytes"]
@@ -212,9 +274,10 @@ def test_roofline_readers_match_the_compiled_names_and_stay_under_100(metric):
                      "op_counts": {name: calls, "fusion.1": 5}}}
     got = readers.read(_spec(metric + "_roofline.sala"), obs)
     assert got == pytest.approx(50.0, rel=1e-6)
-    if metric == "sparse_attn":
-        assert readers.read(_spec("sparse_attn_share.sala"), obs) \
-            == pytest.approx(20.0)
+    share = {"sparse_attn": "sparse_attn_share.sala",
+             "linear_attn": "linear_attn_share.sala"}.get(metric)
+    if share:
+        assert readers.read(_spec(share), obs) == pytest.approx(20.0)
     # another model's kernels are not matched: the dense decode kernel at
     # this geometry, Nemotron's state update
     other = dict(obs, trace=dict(obs["trace"], ops={

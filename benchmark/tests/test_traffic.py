@@ -113,3 +113,22 @@ def test_mix_stats_matches_the_cell_files_worked_figures():
         == pool["reserved_tokens"] * pool["bytes_per_token"]
     assert pool["mean_bytes_in_use"] \
         == 672 * cell["clients"] * pool["bytes_per_token"]
+
+
+def test_rollout_32k_is_one_class_and_its_stats_are_the_files_own():
+    """ISSUE 54's mix: one prompt length, so the seed draws token ids and
+    nothing else; mean live context 20,480 + 8,192 / 2."""
+    mix = _mix("rollout-32k")
+    s = traffic.mix_stats(mix)
+    assert (s["mean_prompt"], s["mean_gen"], s["mean_live_context"]) \
+        == (20480, 8192, 24576)
+    assert {k: mix["mix_stats"][k] for k in s} == s
+    a = traffic.closed_loop_requests(mix, 24, 1, 1000)
+    b = traffic.closed_loop_requests(mix, 24, 2 ** 31 + 11, 1000)
+    assert {(len(r.prompt), r.gen_len) for r in a + b} == {(20480, 8192)}
+    assert a[0].prompt != b[0].prompt
+    # the first wave: three clients at each phase of the output
+    wave = traffic.first_wave(mix, 96, 256, 7, 1000)
+    assert collections.Counter(r.gen_len for r in wave) \
+        == {256 * k: 3 for k in range(1, 33)}
+    assert {len(r.prompt) + r.gen_len for r in wave} == {28672}
