@@ -364,6 +364,13 @@ class InferenceEngineV2:
             "steps": 0, "fed_steps": 0, "retries": 0, "plan_s": 0.0,
             "dispatch_s": 0.0, "commit_block_s": 0.0, "commit_apply_s": 0.0,
             "fused_dispatch_s": 0.0, "fused_apply_s": 0.0,
+            # a call's own seconds (put, decode_pipelined, decode_batch)
+            # and the rest of what lies under it: put's admission loop,
+            # decode_batch's staging, the fused readback's wait, and the
+            # counters' own arithmetic (plan_count_s nested in plan_s)
+            "put_s": 0.0, "decode_pipelined_s": 0.0, "decode_batch_s": 0.0,
+            "admit_s": 0.0, "plan_count_s": 0.0, "fused_stage_s": 0.0,
+            "fused_readback_s": 0.0, "fused_count_s": 0.0,
             "prefill_tokens_real": 0,
             "prefill_tokens_planned": 0, "prefill_steps": 0,
             "prefill_rows": 0, "decode_slots_live": 0,
@@ -618,78 +625,82 @@ class InferenceEngineV2:
         survivor spans join the same logical track
         (docs/observability.md "Distributed tracing")."""
         with self._spans.span("serve/put", requests=len(batch_uids)):
-            admitted: List[int] = []
-            bs = self.config.block_size
-            for uid, toks in zip(batch_uids, batch_tokens):
-                seq0 = self.state.get(uid)
-                fresh = seq0 is None or (seq0.seen_tokens == 0
-                                         and not seq0.kv_blocks)
-                if self._draining():
-                    # a FRESH request is refused outright — the client must
-                    # retry on another replica. A continuation of a LIVE
-                    # sequence is simply not fed: that sequence rides the
-                    # drain manifest (a rejection record here would
-                    # double-route the same request — replayed by the
-                    # survivor AND retried by the client)
-                    if fresh:
-                        self._reject(uid, "draining",
-                                     detail="engine is draining for "
-                                     "preemption")
-                    continue
-                if fresh and self.serve_shed:
-                    # load shedding at the door: a prompt whose KV (plus one
-                    # generated token) exceeds the WHOLE pool can never be
-                    # served, eviction or not — shed it before it poisons
-                    # the scheduler (serve_shed=False keeps the legacy hard
-                    # starvation RuntimeError instead)
-                    need = -(-(len(toks) + 1) // bs)
-                    if need > self.config.num_blocks:
-                        self._reject(
-                            uid, "kv_pool_exhausted",
-                            needed_blocks=need,
-                            num_blocks=self.config.num_blocks,
-                            detail="prompt exceeds the whole KV pool")
+            # pure host work, nothing of this call queued on the device yet
+            with self._spans.span("serve/admit"):
+                admitted: List[int] = []
+                bs = self.config.block_size
+                for uid, toks in zip(batch_uids, batch_tokens):
+                    seq0 = self.state.get(uid)
+                    fresh = seq0 is None or (seq0.seen_tokens == 0
+                                             and not seq0.kv_blocks)
+                    if self._draining():
+                        # a FRESH request is refused outright — the client
+                        # must retry on another replica. A continuation of
+                        # a LIVE sequence is simply not fed: that sequence
+                        # rides the drain manifest (a rejection record here
+                        # would double-route the same request — replayed by
+                        # the survivor AND retried by the client)
+                        if fresh:
+                            self._reject(uid, "draining",
+                                         detail="engine is draining for "
+                                         "preemption")
                         continue
-                seq = self.state.put_tokens(uid, toks)
-                admitted.append(uid)
-                # a reused uid sheds its STALE rejection record — generate()
-                # and the serving layer treat a present record as "this
-                # request failed", which must only ever mean THIS admission
-                self.rejections.pop(uid, None)
-                if fresh:
-                    sp = sampling.get(uid) if sampling else None
-                    if sp is not None:
-                        seq.sampling = sp
-                    tid = traces.get(uid) if traces else None
-                    if tid is not None:
-                        # set BEFORE on_admit: the admit span must already
-                        # carry the trace context
-                        seq.trace_id = tid
-                    arrived = arrivals.get(uid) if arrivals else None
-                    seq.put_at = time.monotonic()
-                    if arrived is None:
-                        arrived = seq.put_at
-                    if self._obs is not None:
-                        self._obs.on_admit(seq, arrived)
-                    dl = deadlines.get(uid) if deadlines else None
-                    if dl is None and self.request_deadline_s > 0:
-                        dl = self.request_deadline_s
-                    if dl is not None and dl > 0 and seq.deadline_at is None:
-                        seq.deadline_at = dl + arrived
-                        seq.deadline_s = dl
-                        self._has_deadlines = True
-                    if self.journal is not None \
-                            and seq.seen_tokens == 0 and not seq.kv_blocks:
-                        # prompt still building: (re-)journal the full chain
-                        # (+ sampling identity, so a hard-crash replay keeps
-                        # the stream deterministic)
-                        self.journal.admit(uid, seq.prompt_log,
-                                           sampling=seq.sampling.to_dict()
-                                           if seq.sampling is not None
-                                           else None,
-                                           trace=seq.trace_id)
-                if self._prefix is not None:
-                    self._match_prefix(seq)
+                    if fresh and self.serve_shed:
+                        # load shedding at the door: a prompt whose KV (plus
+                        # one generated token) exceeds the WHOLE pool can
+                        # never be served, eviction or not — shed it before
+                        # it poisons the scheduler (serve_shed=False keeps
+                        # the legacy hard starvation RuntimeError instead)
+                        need = -(-(len(toks) + 1) // bs)
+                        if need > self.config.num_blocks:
+                            self._reject(
+                                uid, "kv_pool_exhausted",
+                                needed_blocks=need,
+                                num_blocks=self.config.num_blocks,
+                                detail="prompt exceeds the whole KV pool")
+                            continue
+                    seq = self.state.put_tokens(uid, toks)
+                    admitted.append(uid)
+                    # a reused uid sheds its STALE rejection record —
+                    # generate() and the serving layer treat a present record
+                    # as "this request failed", which must only ever mean
+                    # THIS admission
+                    self.rejections.pop(uid, None)
+                    if fresh:
+                        sp = sampling.get(uid) if sampling else None
+                        if sp is not None:
+                            seq.sampling = sp
+                        tid = traces.get(uid) if traces else None
+                        if tid is not None:
+                            # set BEFORE on_admit: the admit span must already
+                            # carry the trace context
+                            seq.trace_id = tid
+                        arrived = arrivals.get(uid) if arrivals else None
+                        seq.put_at = time.monotonic()
+                        if arrived is None:
+                            arrived = seq.put_at
+                        if self._obs is not None:
+                            self._obs.on_admit(seq, arrived)
+                        dl = deadlines.get(uid) if deadlines else None
+                        if dl is None and self.request_deadline_s > 0:
+                            dl = self.request_deadline_s
+                        if dl is not None and dl > 0 \
+                                and seq.deadline_at is None:
+                            seq.deadline_at = dl + arrived
+                            seq.deadline_s = dl
+                            self._has_deadlines = True
+                        if self.journal is not None \
+                                and seq.seen_tokens == 0 and not seq.kv_blocks:
+                            # prompt still building: (re-)journal the full
+                            # chain (+ sampling identity, so a hard-crash
+                            # replay keeps the stream deterministic)
+                            self.journal.admit(uid, seq.prompt_log,
+                                               sampling=seq.sampling.to_dict()
+                                               if seq.sampling is not None
+                                               else None,
+                                               trace=seq.trace_id)
+                    if self._prefix is not None:
+                        self._match_prefix(seq)
             done: Dict[int, np.ndarray] = {}
 
             def work_left():
@@ -1704,72 +1715,75 @@ class InferenceEngineV2:
                 f"{type(self.runner).__name__} has no decode_loop")
         with self._spans.span("serve/decode_batch", steps=n,
                               seqs=len(batch_uids)):
-            cfg = self.config
-            if len(batch_uids) > cfg.max_seqs:
-                raise ValueError(f"{len(batch_uids)} uids > max_seqs "
-                                 f"{cfg.max_seqs}")
-            if len(batch_uids) != len(first_tokens):
-                raise ValueError(
-                    f"{len(batch_uids)} uids but {len(first_tokens)} "
-                    f"first_tokens")
-            if self._windowed and n > window_step_rows(cfg):
-                # a longer flush would overwrite rows the next step's
-                # window still reaches
-                raise ValueError(
-                    f"decode_batch of {n} steps over a window pool sized "
-                    f"for steps of at most {window_step_rows(cfg)} rows "
-                    f"(chunk_size / decode_loop_steps)")
-            seqs = []
-            for uid in batch_uids:
-                seq = self.state.get(uid)
-                if seq is None or seq.status is SequenceStatus.PAUSED:
-                    raise ValueError(f"sequence {uid} missing or paused")
-                if seq.in_flight:
-                    raise ValueError(f"sequence {uid} has pending tokens; "
-                                     f"drain with put() first")
-                seqs.append(seq)
-            # reserve atomically: check the WHOLE batch's demand first so a
-            # mid-batch failure doesn't leave earlier sequences holding
-            # allocate-ahead blocks that deepen the pool pressure the caller is
-            # about to fall back from
-            bsz = self.config.block_size
-            need = 0
-            for s_ in seqs:
-                nb = s_.blocks_needed(n, bsz)
-                if len(s_.kv_blocks) + nb > cfg.max_blocks_per_seq:
+            spans, obs = self._spans, self._obs
+            with spans.span("serve/fused_stage", steps=n):
+                cfg = self.config
+                if len(batch_uids) > cfg.max_seqs:
+                    raise ValueError(f"{len(batch_uids)} uids > max_seqs "
+                                     f"{cfg.max_seqs}")
+                if len(batch_uids) != len(first_tokens):
+                    raise ValueError(
+                        f"{len(batch_uids)} uids but {len(first_tokens)} "
+                        f"first_tokens")
+                if self._windowed and n > window_step_rows(cfg):
+                    # a longer flush would overwrite rows the next step's
+                    # window still reaches
+                    raise ValueError(
+                        f"decode_batch of {n} steps over a window pool sized "
+                        f"for steps of at most {window_step_rows(cfg)} rows "
+                        f"(chunk_size / decode_loop_steps)")
+                seqs = []
+                for uid in batch_uids:
+                    seq = self.state.get(uid)
+                    if seq is None or seq.status is SequenceStatus.PAUSED:
+                        raise ValueError(f"sequence {uid} missing or paused")
+                    if seq.in_flight:
+                        raise ValueError(f"sequence {uid} has pending tokens; "
+                                         f"drain with put() first")
+                    seqs.append(seq)
+                # reserve atomically: check the WHOLE batch's demand first
+                # so a mid-batch failure doesn't leave earlier sequences
+                # holding allocate-ahead blocks that deepen the pool
+                # pressure the caller is about to fall back from
+                bsz = self.config.block_size
+                need = 0
+                for s_ in seqs:
+                    nb = s_.blocks_needed(n, bsz)
+                    if len(s_.kv_blocks) + nb > cfg.max_blocks_per_seq:
+                        raise OutOfBlocksError(
+                            f"sequence {s_.uid} would exceed "
+                            f"max_blocks_per_seq")
+                    need += nb
+                if need > self.kv_cache.free_blocks:
                     raise OutOfBlocksError(
-                        f"sequence {s_.uid} would exceed max_blocks_per_seq")
-                need += nb
-            if need > self.kv_cache.free_blocks:
-                raise OutOfBlocksError(
-                    f"decode_greedy needs {need} blocks, "
-                    f"{self.kv_cache.free_blocks} free")
-            for seq in seqs:
-                # covers positions seen .. seen + n - 1
-                self.state.ensure_blocks(seq, n)
+                        f"decode_greedy needs {need} blocks, "
+                        f"{self.kv_cache.free_blocks} free")
+                for seq in seqs:
+                    # covers positions seen .. seen + n - 1
+                    self.state.ensure_blocks(seq, n)
 
-            S, MAXB = cfg.max_seqs, cfg.max_blocks_per_seq
-            tok0 = np.zeros((S,), np.int32)
-            start = np.zeros((S,), np.int32)
-            active = np.zeros((S,), np.int32)
-            tables = np.zeros((S, MAXB), np.int32)
-            # idle rows point at the state pool's idle row
-            sslots = np.full((S,), S, np.int32) if self._slotted else None
-            for i, (seq, t0) in enumerate(zip(seqs, first_tokens)):
-                tok0[i] = t0
-                start[i] = seq.seen_tokens
-                active[i] = 1
-                tables[i, :len(seq.kv_blocks)] = seq.kv_blocks
-                if sslots is not None:
-                    sslots[i] = seq.state_slot
-            samp = self._stage_loop_sampling(seqs, S, sampling)
-            obs = self._obs
-            if obs is not None:
-                # attribution window for the fused path: one dispatch + one
-                # blocking readback cover n steps; the bookkeeping after is
-                # the commit apply, anything else in the window is host gap
-                obs.on_loop_enter()
-            spans, live = self._spans, len(seqs)
+                S, MAXB = cfg.max_seqs, cfg.max_blocks_per_seq
+                tok0 = np.zeros((S,), np.int32)
+                start = np.zeros((S,), np.int32)
+                active = np.zeros((S,), np.int32)
+                tables = np.zeros((S, MAXB), np.int32)
+                # idle rows point at the state pool's idle row
+                sslots = np.full((S,), S, np.int32) if self._slotted else None
+                for i, (seq, t0) in enumerate(zip(seqs, first_tokens)):
+                    tok0[i] = t0
+                    start[i] = seq.seen_tokens
+                    active[i] = 1
+                    tables[i, :len(seq.kv_blocks)] = seq.kv_blocks
+                    if sslots is not None:
+                        sslots[i] = seq.state_slot
+                samp = self._stage_loop_sampling(seqs, S, sampling)
+                if obs is not None:
+                    # attribution window for the fused path: one dispatch
+                    # + one blocking readback cover n steps; the bookkeeping
+                    # after is the commit apply, anything else in the window
+                    # is host gap
+                    obs.on_loop_enter()
+            live = len(seqs)
             with spans.span("serve/fused_dispatch", steps=n, seqs=live):
                 toks, lps, self._kv_data, consumed, moe_rows = \
                     self.runner.decode_loop(
@@ -1782,52 +1796,54 @@ class InferenceEngineV2:
                         else jax.numpy.asarray(sslots), **samp)
             # the flush stores all n ring rows of every live slot: counted
             # while the loop runs, not between its readback and the next call
-            for key, val in self._kv_write_counts(
-                    [(seq.seen_tokens, n) for seq in seqs], n).items():
-                self.pipeline_stats[key] += val
+            with spans.span("serve/fused_count", steps=n) as span:
+                span.count(**self._kv_write_counts(
+                    [(seq.seen_tokens, n) for seq in seqs], n))
             with spans.span("serve/fused_readback", steps=n, seqs=live):
                 # one wait for all of it. lps is None for a greedy loop,
                 # consumed when EOS is disabled (every slot fed all n),
                 # moe_rows for a model without experts
                 toks, lps, consumed, moe_rows = jax.device_get(
                     (toks, lps, consumed, moe_rows))
-            # the loop's own tokens ride its ring: every step a slot was
-            # alive the kernel read the rows settled at entry
-            stats = self.pipeline_stats
-            for key, val in self._decode_row_counts([
-                    (int(consumed[i]) if consumed is not None else n,
-                     seq.seen_tokens) for i, seq in enumerate(seqs)],
-                    S, in_ring=True).items():
-                stats[key] += val
-            if self._stateful:
-                ran = n * len(seqs) if consumed is None \
-                    else int(consumed[:len(seqs)].sum())
-                stats["state_slots_live"] += ran
-                stats["state_bytes_live"] += \
-                    ran * self.kv_cache.state_bytes_per_slot()
-                stats["conv_steps_in_place"] += n * self._conv_in_place(S)
-            if moe_rows is not None:
-                # per call: rows the experts took, and what they would
-                # have taken had every expert been as busy as the busiest
-                # (hottest / routed = the imbalance a straggling
-                # expert-parallel chip would feel; 1.0 = even). A model
-                # that holds a share of the experts counts its own here
-                # and the rows it sent to the others apart
-                mc = self.runner.model_cfg
-                # the grouped kernel's own two counts ride behind the
-                # experts': held experts with a row, and visits to them
-                # (how often an expert's matrices were streamed); both 0
-                # from a program on the ragged_dot path
-                moe_rows, (hit, reads) = moe_rows[:-2], moe_rows[-2:]
-                stats["moe_experts_hit"] += int(hit)
-                stats["moe_expert_reads"] += int(reads)
-                first = getattr(mc, "experts_first", 0)
-                mine = moe_rows[first:first + getattr(mc, "held",
-                                                      len(moe_rows))]
-                stats["moe_rows_routed"] += int(mine.sum())
-                stats["moe_rows_hottest"] += int(mine.max()) * len(mine)
-                stats["moe_rows_elsewhere"] += \
-                    int(moe_rows.sum()) - int(mine.sum())
+            # the counters' own arithmetic, on the host with the device idle
+            with spans.span("serve/fused_count", steps=n):
+                # the loop's own tokens ride its ring: every step a slot was
+                # alive the kernel read the rows settled at entry
+                stats = self.pipeline_stats
+                for key, val in self._decode_row_counts([
+                        (int(consumed[i]) if consumed is not None else n,
+                         seq.seen_tokens) for i, seq in enumerate(seqs)],
+                        S, in_ring=True).items():
+                    stats[key] += val
+                if self._stateful:
+                    ran = n * len(seqs) if consumed is None \
+                        else int(consumed[:len(seqs)].sum())
+                    stats["state_slots_live"] += ran
+                    stats["state_bytes_live"] += \
+                        ran * self.kv_cache.state_bytes_per_slot()
+                    stats["conv_steps_in_place"] += n * self._conv_in_place(S)
+                if moe_rows is not None:
+                    # per call: rows the experts took, and what they would
+                    # have taken had every expert been as busy as the busiest
+                    # (hottest / routed = the imbalance a straggling
+                    # expert-parallel chip would feel; 1.0 = even). A model
+                    # that holds a share of the experts counts its own here
+                    # and the rows it sent to the others apart
+                    mc = self.runner.model_cfg
+                    # the grouped kernel's own two counts ride behind the
+                    # experts': held experts with a row, and visits to them
+                    # (how often an expert's matrices were streamed); both 0
+                    # from a program on the ragged_dot path
+                    moe_rows, (hit, reads) = moe_rows[:-2], moe_rows[-2:]
+                    stats["moe_experts_hit"] += int(hit)
+                    stats["moe_expert_reads"] += int(reads)
+                    first = getattr(mc, "experts_first", 0)
+                    mine = moe_rows[first:first + getattr(mc, "held",
+                                                          len(moe_rows))]
+                    stats["moe_rows_routed"] += int(mine.sum())
+                    stats["moe_rows_hottest"] += int(mine.max()) * len(mine)
+                    stats["moe_rows_elsewhere"] += \
+                        int(moe_rows.sum()) - int(mine.sum())
             with spans.span("serve/fused_apply", steps=n, seqs=live):
                 out = self._apply_fused(batch_uids, seqs, first_tokens, n,
                                         toks, lps, consumed)
@@ -1845,7 +1861,7 @@ class InferenceEngineV2:
         self._step_counter += n
         out: Dict[int, List[int]] = {}
         journal_toks: Dict[int, List[int]] = {}
-        now = time.monotonic() if obs is not None else 0.0
+        now = time.monotonic()
         for i, (uid, seq) in enumerate(zip(batch_uids, seqs)):
             used = int(consumed[i]) if consumed is not None else n
             # replay history (drain.py): the fed first token joins
@@ -1871,11 +1887,13 @@ class InferenceEngineV2:
             seq.last_step = self._step_counter
             seq.status = SequenceStatus.WAITING
             out[uid] = toks[i].tolist()
-            if obs is not None and used > 0:
-                # one fused chunk commits `used` tokens at one host
-                # timestamp: TPOT is the inter-chunk interval split
-                # evenly (telemetry/serve.py)
-                obs.on_token_commit(seq, now, n=used)
+            if used > 0:
+                first = seq.stamp_first_token(now)
+                if obs is not None:
+                    # one fused chunk commits `used` tokens at one host
+                    # timestamp: TPOT is the inter-chunk interval split
+                    # evenly (telemetry/serve.py)
+                    obs.on_token_commit(seq, now, first, n=used)
         if self.journal is not None:
             self.journal.tokens(journal_toks)
         return out
@@ -1928,12 +1946,19 @@ class InferenceEngineV2:
                 return None
             self._step_counter += 1
             self.state.step += 1
+            # the first-schedule stamp is the engine's own (set with or
+            # without an observer, which files its histograms from it)
+            now = time.monotonic()
+            first = []
             for item in sched:
-                item.seq.last_step = self._step_counter
-                item.seq.last_sched = self.state.step
+                seq = item.seq
+                seq.last_step = self._step_counter
+                seq.last_sched = self.state.step
+                if seq.first_sched_at is None:
+                    seq.first_sched_at = now
+                    first.append(seq)
             if self._obs is not None:
-                # first-schedule stamps -> queue-wait histogram (pure host)
-                self._obs.on_sched(sched, time.monotonic())
+                self._obs.on_sched(sched, first, now)
             cfg = self.config
             # shape bucketing (jit caches by shape, so a handful of compiled
             # programs total; the reference flattens tokens into one ragged
@@ -1999,55 +2024,61 @@ class InferenceEngineV2:
             real = sum(len(item.tokens) for item in sched)
             span.set(step=self._step_counter, seqs=len(sched), S=S, T=C,
                      real=real)
-            span.count(**self._kv_write_counts(
-                [(item.start_pos, len(item.tokens)) for item in sched], C))
+            # the counters' own arithmetic, under its own bracket (a total
+            # alone, no annotation: a gap here keeps serve/plan's name):
+            # closed forms over the scheduled items, computed in every run
+            with self._spans.span("serve/plan_count"):
+                span.count(**self._kv_write_counts(
+                    [(item.start_pos, len(item.tokens)) for item in sched], C))
+                if C > 1:
+                    span.count(prefill_tokens_real=real,
+                               prefill_tokens_planned=S * C, prefill_steps=1,
+                               prefill_rows=sum(len(item.tokens) > 1
+                                                for item in sched))
+                    if self._stateful:
+                        spec = self.runner.state_spec
+                        span.count(
+                            linear_attn_prefill_tokens=real,
+                            linear_attn_prefill_kernel_tokens=real
+                            * (spec["kind"] == "kda"
+                               and kda_prefill_uses_kernel(
+                                   C, spec["heads"], spec["d_k"],
+                                   spec["d_v"])))
+                    if self._latent:
+                        span.count(mla_prefill_tokens=real)
+                    if self._selecting:
+                        dl = self.runner.model_cfg.sparse.dense_len
+                        below = sum(
+                            max(0, min(item.start_pos + len(item.tokens),
+                                       dl - 1) - item.start_pos)
+                            for item in sched)
+                        span.count(sparse_dense_tokens=below,
+                                   **self._select_counts(real - below, C))
+                    if self._moe_stacks is not None:
+                        # the choice llama_runner._moe_mlp makes, of the same
+                        # operand types and widths
+                        span.count(
+                            moe_prefill_tokens=real,
+                            moe_prefill_kernel_tokens=real
+                            * (grouped_ffn.kernel_impl(
+                                self._moe_stacks,
+                                self.runner.compute_dtype) is not None))
+                else:
+                    # this step's token is in the pool before the kernel runs
+                    lens = [item.start_pos + 1 for item in sched]
+                    span.count(decode_slots_live=real, decode_slots_planned=S,
+                               **self._decode_row_counts(
+                                   [(1, n) for n in lens], S))
+                    if self._stateful:
+                        span.count(state_slots_live=real,
+                                   state_bytes_live=real
+                                   * self.kv_cache.state_bytes_per_slot(),
+                                   conv_steps_in_place=self._conv_in_place(S))
             if C > 1:
-                span.count(prefill_tokens_real=real,
-                           prefill_tokens_planned=S * C, prefill_steps=1,
-                           prefill_rows=sum(len(item.tokens) > 1
-                                            for item in sched))
-                if self._stateful:
-                    spec = self.runner.state_spec
-                    span.count(
-                        linear_attn_prefill_tokens=real,
-                        linear_attn_prefill_kernel_tokens=real
-                        * (spec["kind"] == "kda"
-                           and kda_prefill_uses_kernel(
-                               C, spec["heads"], spec["d_k"], spec["d_v"])))
-                if self._latent:
-                    span.count(mla_prefill_tokens=real)
-                if self._selecting:
-                    dl = self.runner.model_cfg.sparse.dense_len
-                    below = sum(
-                        max(0, min(item.start_pos + len(item.tokens),
-                                   dl - 1) - item.start_pos)
-                        for item in sched)
-                    span.count(sparse_dense_tokens=below,
-                               **self._select_counts(real - below, C))
-                if self._moe_stacks is not None:
-                    # the choice llama_runner._moe_mlp makes, of the same
-                    # operand types and widths
-                    span.count(
-                        moe_prefill_tokens=real,
-                        moe_prefill_kernel_tokens=real
-                        * (grouped_ffn.kernel_impl(
-                            self._moe_stacks,
-                            self.runner.compute_dtype) is not None))
                 # serve fault site: a replica dying with a freshly planned
                 # multi-token prefill chunk (tokens consumed host-side, step
                 # never dispatched)
                 get_fault_injector().maybe_fire("during_prefill_chunk")
-            else:
-                # this step's token is in the pool before the kernel runs
-                lens = [item.start_pos + 1 for item in sched]
-                span.count(decode_slots_live=real, decode_slots_planned=S,
-                           **self._decode_row_counts(
-                               [(1, n) for n in lens], S))
-                if self._stateful:
-                    span.count(state_slots_live=real,
-                               state_bytes_live=real
-                               * self.kv_cache.state_bytes_per_slot(),
-                               conv_steps_in_place=self._conv_in_place(S))
             return _PlannedStep(sched, tokens, start, ntok, tables,
                                 feed_mask if has_feed else None, feed_idx,
                                 use_greedy,
@@ -2131,7 +2162,7 @@ class InferenceEngineV2:
             lps = np.asarray(fl.logprobs) if fl.logprobs is not None \
                 else None
         obs = self._obs
-        now = time.monotonic() if obs is not None else 0.0
+        now = time.monotonic()
         out: Dict[int, Any] = {}
         journal_toks: Dict[int, List[int]] = {}
         with self._spans.span("serve/commit_apply", step=step):
@@ -2151,11 +2182,11 @@ class InferenceEngineV2:
                             journal_toks[item.seq.uid] = [tok]
                     else:
                         out[item.seq.uid] = result[i]
+                    # the last chunk's output (token or logits) is this
+                    # request's first host-visible result -> TTFT/TPOT
+                    first = item.seq.stamp_first_token(now)
                     if obs is not None:
-                        # the last chunk's output (token or logits) is
-                        # this request's first host-visible result ->
-                        # TTFT/TPOT
-                        obs.on_token_commit(item.seq, now)
+                        obs.on_token_commit(item.seq, now, first)
                     item.seq.status = SequenceStatus.WAITING
             if self.journal is not None:
                 self.journal.tokens(journal_toks)
@@ -2221,35 +2252,38 @@ class InferenceEngineV2:
                                eos_token_id: Optional[int] = None,
                                ) -> Dict[int, List[int]]:
         cfg = self.config
-        if len(batch_uids) != len(first_tokens):
-            raise ValueError(
-                f"{len(batch_uids)} uids but {len(first_tokens)} "
-                f"first_tokens")
-        if isinstance(n, (list, tuple)):
-            budgets = {u: int(b) for u, b in zip(batch_uids, n)}
-        else:
-            budgets = {u: int(n) for u in batch_uids}
-        seqs: Dict[int, Any] = {}
-        for uid in batch_uids:
-            seq = self.state.get(uid)
-            if seq is None:
-                raise ValueError(f"unknown sequence {uid}")
-            if seq.in_flight:
-                raise ValueError(f"sequence {uid} has pending tokens; "
-                                 f"drain with put() first")
-            seqs[uid] = seq
-        for uid, seq in self.state.sequences.items():
-            if uid not in budgets and seq.in_flight:
+        # the batch's checks and its first tokens queued, before the first
+        # plan: the stretch put() brackets under the same name
+        with self._spans.span("serve/admit"):
+            if len(batch_uids) != len(first_tokens):
                 raise ValueError(
-                    f"sequence {uid} has pending tokens but is not in "
-                    f"this decode batch")
-        out: Dict[int, List[int]] = {u: [] for u in batch_uids}
-        finished = {u for u in batch_uids if budgets[u] <= 0}
-        inflight_n = {u: 0 for u in batch_uids}
-        spec_src: Dict[int, _InFlightStep] = {}   # uid -> producer step
-        for uid, t in zip(batch_uids, first_tokens):
-            if uid not in finished:
-                self.state.put_tokens(uid, [int(t)])
+                    f"{len(batch_uids)} uids but {len(first_tokens)} "
+                    f"first_tokens")
+            if isinstance(n, (list, tuple)):
+                budgets = {u: int(b) for u, b in zip(batch_uids, n)}
+            else:
+                budgets = {u: int(n) for u in batch_uids}
+            seqs: Dict[int, Any] = {}
+            for uid in batch_uids:
+                seq = self.state.get(uid)
+                if seq is None:
+                    raise ValueError(f"unknown sequence {uid}")
+                if seq.in_flight:
+                    raise ValueError(f"sequence {uid} has pending tokens; "
+                                     f"drain with put() first")
+                seqs[uid] = seq
+            for uid, seq in self.state.sequences.items():
+                if uid not in budgets and seq.in_flight:
+                    raise ValueError(
+                        f"sequence {uid} has pending tokens but is not in "
+                        f"this decode batch")
+            out: Dict[int, List[int]] = {u: [] for u in batch_uids}
+            finished = {u for u in batch_uids if budgets[u] <= 0}
+            inflight_n = {u: 0 for u in batch_uids}
+            spec_src: Dict[int, _InFlightStep] = {}   # uid -> producer step
+            for uid, t in zip(batch_uids, first_tokens):
+                if uid not in finished:
+                    self.state.put_tokens(uid, [int(t)])
         self._feed_src, self._feed_slot = None, {}
 
         def eligible(seq):
@@ -2275,7 +2309,7 @@ class InferenceEngineV2:
                 lps = np.asarray(fl.logprobs) \
                     if fl.logprobs is not None else None
             obs = self._obs
-            now = time.monotonic() if obs is not None else 0.0
+            now = time.monotonic()
             with self._spans.span("serve/commit_apply", step=step):
                 apply_commit(ring, fl, toks, lps, obs, now)
             if obs is not None:
@@ -2301,8 +2335,9 @@ class InferenceEngineV2:
                 if lps is not None and seq.sampling is not None \
                         and seq.sampling.logprobs:
                     seq.logprob_log.append(float(lps[i]))
+                first = seq.stamp_first_token(now)
                 if obs is not None:
-                    obs.on_token_commit(seq, now)
+                    obs.on_token_commit(seq, now, first)
                 if self.journal is not None:
                     journal_toks.setdefault(u, []).append(tok)
                 if patch and seq.spec_pending and seq.pending_tokens \
@@ -2583,7 +2618,7 @@ class InferenceEngineV2:
                     [(seqs[u].seen_tokens, L) for u in ready], L))
                 self.kv_cache.finalize_demotions()
                 self._step_counter += L
-                now = time.monotonic() if obs is not None else 0.0
+                now = time.monotonic()
                 journal_toks: Dict[int, List[int]] = {}
                 round_prop = 0
                 round_acc = 0
@@ -2637,11 +2672,14 @@ class InferenceEngineV2:
                     proposer.observe_commit(seq, seen0, acc, d_row)
                     if self.journal is not None:
                         journal_toks[u] = hist
-                    if obs is not None and a:
-                        obs.on_token_commit(seq, now, n=a)
-                        # traced requests get a spec-round mark on their
-                        # fleet track (no-op for untraced sequences)
-                        obs.on_spec_commit(seq, acc_drafts, prop_eff)
+                    if a:
+                        first = seq.stamp_first_token(now)
+                        if obs is not None:
+                            obs.on_token_commit(seq, now, first, n=a)
+                            # traced requests get a spec-round mark on
+                            # their fleet track (no-op for untraced
+                            # sequences)
+                            obs.on_spec_commit(seq, acc_drafts, prop_eff)
                     if len(out[u]) >= budgets[u] or (
                             eos_token_id is not None
                             and acc[-1] == eos_token_id):

@@ -104,15 +104,17 @@ class SequenceDescriptor:
     # request's actual budget, not the engine knob
     deadline_at: Optional[float] = None
     deadline_s: Optional[float] = None
-    # telemetry lifecycle stamps (time.monotonic; None until reached /
-    # when DSTPU_TELEMETRY=0): admission (the DUE instant when the
-    # caller passes ``arrivals``), first scheduled chunk, first and
-    # latest COMMITTED output token. ``put_at`` is when put() received
-    # the request (set by the engine itself), so the first-token time
-    # splits into door wait (put_at - admitted_at), scheduler wait
-    # (first_sched_at - put_at) and prefill (first_token_at -
-    # first_sched_at). The registry histograms aggregate them
-    # (telemetry/serve.py, docs/observability.md).
+    # lifecycle stamps (time.monotonic; None until reached): admission
+    # (the DUE instant when the caller passes ``arrivals``), first
+    # scheduled chunk, first and latest COMMITTED output token.
+    # ``put_at`` (when put() received the request), ``first_sched_at``
+    # and ``first_token_at`` are set by the engine itself, observer or
+    # not; ``admitted_at`` and ``last_token_at`` by the serve observer
+    # (None when DSTPU_TELEMETRY=0). The first-token time splits into
+    # door wait (put_at - admitted_at), scheduler wait (first_sched_at
+    # - put_at) and prefill (first_token_at - first_sched_at). The
+    # registry histograms aggregate them (telemetry/serve.py,
+    # docs/observability.md).
     admitted_at: Optional[float] = None
     put_at: Optional[float] = None
     first_sched_at: Optional[float] = None
@@ -130,6 +132,14 @@ class SequenceDescriptor:
     @property
     def in_flight(self) -> int:
         return len(self.pending_tokens)
+
+    def stamp_first_token(self, now: float) -> bool:
+        """A commit at ``now`` made output of this sequence host-visible:
+        stamp ``first_token_at`` if it is the first; True when it was."""
+        if self.first_token_at is not None:
+            return False
+        self.first_token_at = now
+        return True
 
     def blocks_needed(self, new_tokens: int, block_size: int) -> int:
         """KV blocks to allocate so `seen_tokens + new_tokens` fit."""

@@ -205,8 +205,9 @@ class Engine:
         #: train_batch's own totals (seconds by bracket, steps), readable
         #: without the registry; filled by the brackets of
         #: telemetry/trace.py
-        self.step_stats = {"steps": 0, "stage_s": 0.0, "dispatch_s": 0.0,
-                           "commit_apply_s": 0.0,
+        self.step_stats = {"steps": 0, "train_batch_s": 0.0, "stage_s": 0.0,
+                           "dispatch_s": 0.0, "device_wait_s": 0.0,
+                           "commit_apply_s": 0.0, "step_exit_s": 0.0,
                            "flash_score_elems_computed": 0,
                            "flash_score_elems_needed": 0}
         #: what ONE step's causal flash calls compute / need, from the
@@ -862,11 +863,13 @@ class Engine:
         """Run one full global step (micro_batch × GAS samples) and return the
         mean loss. The one-call equivalent of forward+backward+step.
 
-        Four brackets (``telemetry/trace.py``) split the call at its
-        host boundaries: ``train/stage``, ``train/dispatch``,
-        ``train/device_wait`` and ``train/commit_apply`` (and
-        ``train/step_exit`` around the observer closing its books). They
-        fill ``self.step_stats`` and, with the training observatory attached
+        ``train/batch`` (``telemetry/trace.py``) brackets the whole call
+        and four brackets split it at its host boundaries:
+        ``train/stage``, ``train/dispatch``, ``train/device_wait`` and
+        ``train/commit_apply`` (and ``train/step_exit`` around the observer
+        closing its books); each has a total, so the call's seconds add
+        up from its parts. They fill ``self.step_stats`` and, with the
+        training observatory attached
         (``self._train_obs``, DSTPU_TRAIN_OBS), its data_wait / stage /
         dispatch / device_execute / commit_apply / host_gap attribution
         (docs/observability.md "Training observatory"). The step just
@@ -875,102 +878,107 @@ class Engine:
         obs = self._train_obs
         spans = self._spans
         step = self.global_steps
-        if obs is not None:
-            obs.on_step_enter()
-        try:
-            with spans.span("train/stage", step=step):
-                self.tput_timer.start()
-                self.timers(TRAIN_BATCH_TIMER).start()
-                expected = self.config.train_batch_size
-                lead = jax.tree_util.tree_leaves(batch)[0].shape[0]
-                if lead != expected:
-                    raise ConfigError(
-                        f"train_batch expects leading dim == train_batch_size ({expected}), got {lead}")
-
-                from ..resilience.fault_injection import get_fault_injector
-                get_fault_injector().maybe_fire("step", step=step)
-                if self._watchdog is not None:
-                    self._watchdog.step_start(step)
-
-                if self.flops_profiler is not None:
-                    self.flops_profiler.maybe_start(step, batch)
-                self._ensure_opt_state_resident()
-                self._ensure_params_resident()
-                if self._watchdog is not None:
-                    self._watchdog.phase("compiled_step")
-            with spans.span("train/dispatch", step=step) as span:
-                _take_flash_plans()     # another program's, traced since
-                self.state, metrics = self._train_step(self.state, batch)
-                span.count(**self._flash_score_elems())
-            # the exposed device wait, with one step queued behind it:
-            # the PREVIOUS step's metrics, which the observer's sentinel
-            # then reads as ready values (nothing to wait for without
-            # an observer, or before the second step)
-            prev_loss = obs.previous_loss() if obs is not None else None
-            with spans.span("train/device_wait", step=step):
-                if prev_loss is not None:
-                    # dslint: allow(DSL001): the device_execute bracket
-                    # is the deliberate readback the attribution layer
-                    # measures; a deferred XLA error of the previous
-                    # step surfaces here
-                    jax.block_until_ready(prev_loss)
-            with spans.span("train/commit_apply", step=step) as span:
-                if self._stream_params:
-                    # re-park streamed leaves in pinned_host (inferred out
-                    # placements land them on device after the update)
-                    self.state = self._place_state(self.state)
-                self._evict_opt_state()
-                self._last_metrics = metrics
-
-                self.global_steps += 1
-                self.global_samples += expected
-                if self.compression_scheduler is not None and \
-                        self.compression_scheduler.pending():
-                    # state.step is the gate the compiled transform sees, but
-                    # reading it would block on the device every step (and a
-                    # technique whose offset is never reached would keep that
-                    # sync alive for the whole run). global_steps is its
-                    # host-side upper bound — they differ only by
-                    # overflow-skipped steps (rare, fp16 warmup), so the
-                    # announcement log may fire a few steps early; the
-                    # compiled gating itself is unaffected.
-                    self.compression_scheduler.check(self.global_steps)
-                self.timers(TRAIN_BATCH_TIMER).stop(barrier_value=metrics.loss)
-                self.tput_timer.stop(global_step=True, report_speed=True)
-                self._maybe_log(metrics)
-                if self.flops_profiler is not None:
-                    # before param eviction: the profiler counts param elements
-                    self.flops_profiler.maybe_stop(self.global_steps, metrics)
-                self._evict_params()
-                if self._watchdog is not None:
-                    # step_end blocks on the loss so the recorded duration is
-                    # the TRUE step time, not async dispatch time (and a hung
-                    # step parks us here — exactly where the watchdog is
-                    # watching)
-                    # dslint: allow(DSL001): the watchdog's sanctioned
-                    # blocking site
-                    jax.block_until_ready(metrics.loss)
-                    self._watchdog.step_end(self.global_steps)
-                span.count(steps=1)
-        except BaseException:
-            # a failure anywhere in the step — validation, injector fire,
-            # swap-in error, a dead dispatch, a deferred XLA error at a
-            # blocking read, monitor IO — must not read as an eternal
-            # stall (with action='abort' a stale in-flight marker would
-            # kill the process after the caller recovered), nor leak the
-            # observer's anchors: they would file the caller's whole
-            # recovery as the next step's data_wait
-            if self._watchdog is not None:
-                self._watchdog.step_abort()
+        with spans.span("train/batch"):
             if obs is not None:
-                obs.on_step_abort()
-            raise
-        if obs is not None:
-            # closes the books: the host_gap closure, and the anomaly
-            # sentinel's readbacks of the previous step's ready values
-            with spans.span("train/step_exit", step=step):
-                obs.on_step_exit(self.global_steps, metrics,
-                                 samples=expected)
+                obs.on_step_enter()
+            try:
+                with spans.span("train/stage", step=step):
+                    self.tput_timer.start()
+                    self.timers(TRAIN_BATCH_TIMER).start()
+                    expected = self.config.train_batch_size
+                    lead = jax.tree_util.tree_leaves(batch)[0].shape[0]
+                    if lead != expected:
+                        raise ConfigError(
+                            f"train_batch expects leading dim == train_batch_size ({expected}), got {lead}")
+
+                    from ..resilience.fault_injection import get_fault_injector
+                    get_fault_injector().maybe_fire("step", step=step)
+                    if self._watchdog is not None:
+                        self._watchdog.step_start(step)
+
+                    if self.flops_profiler is not None:
+                        self.flops_profiler.maybe_start(step, batch)
+                    self._ensure_opt_state_resident()
+                    self._ensure_params_resident()
+                    if self._watchdog is not None:
+                        self._watchdog.phase("compiled_step")
+                with spans.span("train/dispatch", step=step) as span:
+                    _take_flash_plans()     # another program's, traced since
+                    self.state, metrics = self._train_step(self.state, batch)
+                    span.count(**self._flash_score_elems())
+                # the exposed device wait, with one step queued behind it:
+                # the PREVIOUS step's metrics, which the observer's sentinel
+                # then reads as ready values (nothing to wait for without
+                # an observer, or before the second step)
+                prev_loss = obs.previous_loss() if obs is not None else None
+                with spans.span("train/device_wait", step=step):
+                    if prev_loss is not None:
+                        # dslint: allow(DSL001): the device_execute bracket
+                        # is the deliberate readback the attribution layer
+                        # measures; a deferred XLA error of the previous
+                        # step surfaces here
+                        jax.block_until_ready(prev_loss)
+                with spans.span("train/commit_apply", step=step) as span:
+                    if self._stream_params:
+                        # re-park streamed leaves in pinned_host (inferred out
+                        # placements land them on device after the update)
+                        self.state = self._place_state(self.state)
+                    self._evict_opt_state()
+                    self._last_metrics = metrics
+
+                    self.global_steps += 1
+                    self.global_samples += expected
+                    if self.compression_scheduler is not None and \
+                            self.compression_scheduler.pending():
+                        # state.step is the gate the compiled transform
+                        # sees, but reading it would block on the device
+                        # every step (and a technique whose offset is never
+                        # reached would keep that sync alive for the whole
+                        # run). global_steps is its host-side upper bound —
+                        # they differ only by overflow-skipped steps (rare,
+                        # fp16 warmup), so the announcement log may fire a
+                        # few steps early; the compiled gating itself is
+                        # unaffected.
+                        self.compression_scheduler.check(self.global_steps)
+                    self.timers(TRAIN_BATCH_TIMER).stop(
+                        barrier_value=metrics.loss)
+                    self.tput_timer.stop(global_step=True, report_speed=True)
+                    self._maybe_log(metrics)
+                    if self.flops_profiler is not None:
+                        # before param eviction: the profiler counts param
+                        # elements
+                        self.flops_profiler.maybe_stop(self.global_steps,
+                                                       metrics)
+                    self._evict_params()
+                    if self._watchdog is not None:
+                        # step_end blocks on the loss so the recorded
+                        # duration is the TRUE step time, not async dispatch
+                        # time (and a hung step parks us here — exactly where
+                        # the watchdog is watching)
+                        # dslint: allow(DSL001): the watchdog's sanctioned
+                        # blocking site
+                        jax.block_until_ready(metrics.loss)
+                        self._watchdog.step_end(self.global_steps)
+                    span.count(steps=1)
+            except BaseException:
+                # a failure anywhere in the step — validation, injector fire,
+                # swap-in error, a dead dispatch, a deferred XLA error at a
+                # blocking read, monitor IO — must not read as an eternal
+                # stall (with action='abort' a stale in-flight marker would
+                # kill the process after the caller recovered), nor leak the
+                # observer's anchors: they would file the caller's whole
+                # recovery as the next step's data_wait
+                if self._watchdog is not None:
+                    self._watchdog.step_abort()
+                if obs is not None:
+                    obs.on_step_abort()
+                raise
+            if obs is not None:
+                # closes the books: the host_gap closure, and the anomaly
+                # sentinel's readbacks of the previous step's ready values
+                with spans.span("train/step_exit", step=step):
+                    obs.on_step_exit(self.global_steps, metrics,
+                                     samples=expected)
         self._maybe_handle_preemption()
         return metrics.loss
 

@@ -24,10 +24,11 @@ access, no callbacks into traced programs; the audited serve programs
 are bit-identical with telemetry on or off (tier-1 asserts 0 host
 callbacks and 0 fresh compiles on the warm path either way). The
 per-request timestamps additionally live on the SequenceDescriptor
-(``admitted_at``/``first_sched_at``/``first_token_at``/
-``last_token_at``, and ``put_at`` set by the engine itself), so the
-first-token time splits per request into door wait, scheduler wait and
-prefill, not just in aggregate.
+(``admitted_at`` and ``last_token_at`` set here; ``put_at``,
+``first_sched_at`` and ``first_token_at`` by the engine itself, which
+hands them to ``on_sched`` / ``on_token_commit``), so the first-token
+time splits per request into door wait, scheduler wait and prefill, not
+just in aggregate, and with this observer off as well.
 
 Export: every ``DSTPU_TELEMETRY_EXPORT_EVERY`` committed steps the
 registry snapshot is atomically published to ``DSTPU_TELEMETRY_EXPORT``
@@ -188,37 +189,37 @@ class ServeObserver:
             self._req_span("req_admit", now, now, seq.uid,
                            trace=seq.trace_id)
 
-    def on_sched(self, sched, now):
-        """First-schedule stamps for this plan's sequences -> queue
-        wait. Continuations keep their original stamp (queue wait is an
-        admission-time property)."""
+    def on_sched(self, sched, first, now):
+        """The engine planned ``sched`` at ``now`` and stamped
+        ``first_sched_at`` on the sequences of ``first`` (scheduled for
+        the first time) -> queue wait. Continuations keep their
+        original stamp (queue wait is an admission-time property)."""
         req = self.req_spans
-        for item in sched:
-            seq = item.seq
-            if seq.first_sched_at is None:
-                seq.first_sched_at = now
-                if seq.put_at is not None:
-                    self.h_sched.observe(now - seq.put_at)
-                if seq.admitted_at is not None:
-                    self.h_queue.observe(now - seq.admitted_at)
-                    if req:
-                        self._req_span("req_queue_wait",
-                                       seq.admitted_at, now, seq.uid,
-                                       trace=seq.trace_id)
-            if req and len(item.tokens) > 1:
-                self._req_event("req_prefill_chunk", seq.uid,
-                                seq.trace_id, ntok=len(item.tokens))
+        for seq in first:
+            if seq.put_at is not None:
+                self.h_sched.observe(now - seq.put_at)
+            if seq.admitted_at is not None:
+                self.h_queue.observe(now - seq.admitted_at)
+                if req:
+                    self._req_span("req_queue_wait", seq.admitted_at, now,
+                                   seq.uid, trace=seq.trace_id)
+        if req:
+            for item in sched:
+                if len(item.tokens) > 1:
+                    self._req_event("req_prefill_chunk", item.seq.uid,
+                                    item.seq.trace_id,
+                                    ntok=len(item.tokens))
 
-    def on_token_commit(self, seq, now, n=1):
+    def on_token_commit(self, seq, now, first, n=1):
         """``n`` output tokens of ``seq`` became host-visible at ``now``
-        (one per pipelined commit; ``n`` per fused decode_batch chunk).
-        First commit -> TTFT; later commits -> per-token TPOT. A fused
-        chunk's follow-on tokens share one wall interval, so TPOT is the
-        interval split evenly (weight n) — the same quantity the bench's
-        per-chunk arithmetic reported."""
+        (one per pipelined commit; ``n`` per fused decode_batch chunk);
+        ``first`` when the engine stamped ``first_token_at`` with this
+        commit. First commit -> TTFT; later commits -> per-token TPOT.
+        A fused chunk's follow-on tokens share one wall interval, so
+        TPOT is the interval split evenly (weight n) — the same quantity
+        the bench's per-chunk arithmetic reported."""
         self.c_tokens.inc(n)
-        if seq.first_token_at is None:
-            seq.first_token_at = now
+        if first:
             if seq.first_sched_at is not None:
                 self.h_prefill.observe(now - seq.first_sched_at)
             if seq.admitted_at is not None:

@@ -9,10 +9,25 @@ consumer that used to time the boundary itself:
 
   * the engine's own totals (``InferenceEngineV2.pipeline_stats``,
     ``Engine.step_stats``), under the span's ``total`` key;
-  * the watchdog, which is told the span's ``phase`` on entry;
-  * the attached observer (``on_span``), which files it in the flight
-    ring under ``phase`` and in a registry histogram (``hist`` for the
-    serve engine; the train observer's own, by phase, at step exit).
+  * the watchdog, which is told the span's ``phase`` on entry (if any);
+  * the attached observer (``on_span``), which files a span that has a
+    ``phase`` in the flight ring under it and in a registry histogram
+    (``hist`` for the serve engine; the train observer's own, by phase,
+    at step exit).
+
+Every span has a ``total``, and an engine starts each key at 0.0: the
+seconds of a call into an engine (``serve/put``, ``serve/decode_batch``,
+``train/batch``, ..) equal the seconds of the brackets beneath it plus a
+remainder that is itself a number. A span without a ``phase`` (the
+calls, the stretches between their steps, the counters' own arithmetic)
+is a total and an annotation only: watchdog, ring and registry never
+see it. Two are a total alone (``annotated=False``): ``serve/plan_count``
+and ``train/batch`` open no ``TraceAnnotation``, so the profile's gaps
+keep the names they had (an idle gap during the counters' arithmetic
+reads ``serve/plan``, one between a train step's brackets reads as
+unnamed) and the benchmark's readers of those names read what they
+read. A wait has a total like any bracket; it is read as an addend of
+its call, never as a target.
 
 :data:`SPANS` is the whole vocabulary: a span that is not in the table
 cannot be opened. Names carry no dots (the benchmark's readers split
@@ -44,39 +59,55 @@ PREFIX = "dstpu:"
 
 
 class SpanSpec(NamedTuple):
-    total: Optional[str]   # key in the engine's totals dict
+    total: str             # key in the engine's totals dict
     phase: Optional[str]   # flight-ring / watchdog phase name
     hist: Optional[str]    # registry histogram the serve observer files it in
+    annotated: bool = True  # opens a TraceAnnotation (False: a total alone)
 
 
 SPANS: Dict[str, SpanSpec] = {
-    # the serve engine's public entry points
-    "serve/put": SpanSpec(None, None, None),
-    "serve/decode_pipelined": SpanSpec(None, None, None),
-    "serve/decode_batch": SpanSpec(None, None, None),
+    # the serve engine's public entry points: a call's own seconds, the
+    # sum its brackets below are held against (no phase, no histogram:
+    # the ring, the registry and the watchdog never see them)
+    "serve/put": SpanSpec("put_s", None, None),
+    "serve/decode_pipelined": SpanSpec("decode_pipelined_s", None, None),
+    "serve/decode_batch": SpanSpec("decode_batch_s", None, None),
+    # a call's stretch before its first plan: put's admission loop,
+    # decode_pipelined's checks of its batch and its first tokens queued
+    "serve/admit": SpanSpec("admit_s", None, None),
     # the pipelined step: plan -> dispatch -> commit (readback, apply)
     "serve/plan": SpanSpec("plan_s", "plan", "serve_plan_s"),
+    # the counters' own arithmetic at the end of a plan, nested in
+    # serve/plan (plan_s includes it)
+    "serve/plan_count": SpanSpec("plan_count_s", None, None,
+                                 annotated=False),
     "serve/dispatch": SpanSpec("dispatch_s", "dispatch", "serve_dispatch_s"),
     "serve/commit_block": SpanSpec("commit_block_s", "commit",
                                    "serve_commit_block_s"),
     "serve/commit_apply": SpanSpec("commit_apply_s", "commit_apply",
                                    "serve_commit_apply_s"),
-    # the fused decode loop: one dispatch and one readback cover n steps
+    # the fused decode loop: one dispatch and one readback cover n steps.
+    # decode_batch's validation, reservation and staging before the
+    # dispatch; the counters' arithmetic around the readback
+    "serve/fused_stage": SpanSpec("fused_stage_s", None, None),
     "serve/fused_dispatch": SpanSpec("fused_dispatch_s", "dispatch",
                                      "serve_dispatch_s"),
-    "serve/fused_readback": SpanSpec(None, "commit", "serve_commit_block_s"),
+    "serve/fused_readback": SpanSpec("fused_readback_s", "commit",
+                                     "serve_commit_block_s"),
+    "serve/fused_count": SpanSpec("fused_count_s", None, None),
     "serve/fused_apply": SpanSpec("fused_apply_s", "commit_apply",
                                   "serve_commit_apply_s"),
-    # the train step. Its observer files each phase's seconds itself, at
-    # step exit, under train_<phase>_s (the wait keeps the registry's and
-    # the ring's name, device_execute)
+    # the train step, and the whole call around it. Its observer files
+    # each phase's seconds itself, at step exit, under train_<phase>_s
+    # (the wait keeps the registry's and the ring's name, device_execute)
+    "train/batch": SpanSpec("train_batch_s", None, None, annotated=False),
     "train/stage": SpanSpec("stage_s", "stage", None),
     "train/dispatch": SpanSpec("dispatch_s", "dispatch", None),
-    "train/device_wait": SpanSpec(None, "device_execute", None),
+    "train/device_wait": SpanSpec("device_wait_s", "device_execute", None),
     "train/commit_apply": SpanSpec("commit_apply_s", "commit_apply", None),
     # the observer closing its books (the sentinel's scalar reads of the
-    # previous step, sampling, export): profiler only
-    "train/step_exit": SpanSpec(None, None, None),
+    # previous step, sampling, export)
+    "train/step_exit": SpanSpec("step_exit_s", None, None),
 }
 
 
@@ -155,7 +186,8 @@ class Span:
 
     def set(self, **args: int) -> None:
         self.args.update(args)
-        self._ann.set_metadata(**args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def count(self, **counts: int) -> None:
         totals = self._set.totals
@@ -172,21 +204,21 @@ class Span:
         wd = self._set.watchdog
         if wd is not None and self.spec.phase is not None:
             wd.phase(self.spec.phase)
-        self._ann = jax.profiler.TraceAnnotation(PREFIX + self.name,
-                                                 **self.args)
-        self._ann.__enter__()
+        self._ann = jax.profiler.TraceAnnotation(
+            PREFIX + self.name, **self.args) if self.spec.annotated else None
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter()
-        self._ann.__exit__(exc_type, exc, tb)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         if self._t0 is None:
             return
-        total = self.spec.total
-        if total is not None:
-            totals = self._set.totals
-            totals[total] = totals.get(total, 0.0) + (t1 - self._t0)
+        total, totals = self.spec.total, self._set.totals
+        totals[total] = totals.get(total, 0.0) + (t1 - self._t0)
         obs = self._set.observer()
         if obs is not None and self.spec.phase is not None:
             obs.on_span(self, self._t0, t1)

@@ -2,7 +2,10 @@
 per boundary reaches the engine's totals, the flight ring and the
 registry histogram; the request's four stamps split its first-token
 time; the counters of ``pipeline_stats`` are filled where the work
-happens; the train engine's ``step_stats`` likewise. One tiny GPT-2."""
+happens; the train engine's ``step_stats`` likewise. Since ISSUE 55 the
+books close: a call into an engine holds a total, every stretch beneath
+it a bracket with one, and three of the stamps are the engine's own.
+One tiny GPT-2."""
 
 import glob
 import time
@@ -95,19 +98,23 @@ class TestBracket:
         assert args == {"step": 7, "S": 16, "T": 8}
         assert spans.watchdog.phases == ["plan"]
 
-    def test_nested_spans_each_record_and_entry_points_hold_no_total(self):
+    def test_nested_spans_each_record_and_an_entry_point_holds_a_total(self):
         totals = {}
         sink = _Sink()
         spans = SpanSet(totals, lambda: sink)
+        spans.watchdog = _Dog()
         with spans.span("serve/put", requests=2):
             with spans.span("serve/plan"):
                 pass
             with spans.span("serve/dispatch", fed=0):
                 pass
-        # an entry point is on the profiler's clock only
+        # an entry point holds a total (the sum its brackets are held
+        # against) and still reaches neither ring nor histogram nor dog
         assert [n for n, _, _ in sink.seen] \
             == ["serve/plan", "serve/dispatch"]
-        assert set(totals) == {"plan_s", "dispatch_s"}
+        assert spans.watchdog.phases == ["plan", "dispatch"]
+        assert set(totals) == {"put_s", "plan_s", "dispatch_s"}
+        assert totals["put_s"] >= totals["plan_s"] + totals["dispatch_s"]
 
     def test_a_voided_bracket_reaches_nobody(self):
         """A plan that scheduled nothing is not a step: no seconds in
@@ -147,6 +154,38 @@ class TestBracket:
         from deepspeed_tpu.telemetry.registry import REGISTERED_METRICS
         assert hists <= set(REGISTERED_METRICS)
 
+    def test_every_span_that_brackets_host_seconds_has_a_total(self):
+        """A call's seconds add up from its brackets only if each has a
+        total; two spans may share a histogram, never a total."""
+        totals = [s.total for s in SPANS.values()]
+        assert all(t and t.endswith("_s") for t in totals)
+        by_engine = {}
+        for name, spec in SPANS.items():
+            by_engine.setdefault(name.split("/")[0], []).append(spec.total)
+        for keys in by_engine.values():
+            assert len(set(keys)) == len(keys)
+        # the calls, the stretches between their steps and the counters'
+        # own arithmetic are totals only: nothing new in the flight ring,
+        # the registry or the watchdog's phases
+        for name in ("serve/put", "serve/decode_pipelined",
+                     "serve/decode_batch", "serve/admit", "serve/plan_count",
+                     "serve/fused_stage", "serve/fused_count", "train/batch",
+                     "train/step_exit"):
+            assert SPANS[name].phase is None and SPANS[name].hist is None
+        # two of them are a total alone: no annotation, so the profile's
+        # gaps keep the names the benchmark's readers sum
+        assert {n for n, s in SPANS.items() if not s.annotated} == \
+            {"serve/plan_count", "train/batch"}
+
+    def test_every_total_starts_at_nought_on_a_fresh_engine(self):
+        """``counters_delta`` exports what both ends of a window hold: a
+        key first written by its bracket would miss the first window."""
+        eng = _engine()
+        for name, spec in SPANS.items():
+            if name.startswith("serve/"):
+                assert eng.pipeline_stats[spec.total] == 0.0, name
+        assert eng.pipeline_stats["steps"] == 0
+
 
 class TestServeBrackets:
     @pytest.fixture(scope="class")
@@ -177,10 +216,48 @@ class TestServeBrackets:
             st["dispatch_s"] + st["fused_dispatch_s"], rel=1e-9)
         assert hist["serve_commit_apply_s"]["sum"] == pytest.approx(
             st["commit_apply_s"] + st["fused_apply_s"], rel=1e-9)
-        # the fused readback is a wait, not work: ring and histogram
-        # hold it, the totals do not
-        assert hist["serve_commit_block_s"]["sum"] > st["commit_block_s"]
-        assert "fused_readback_s" not in st
+        # the fused readback is a wait with a total of its own: ring
+        # and histogram hold both waits under one name
+        assert ring["commit"] == pytest.approx(
+            st["commit_block_s"] + st["fused_readback_s"], rel=1e-9)
+        assert hist["serve_commit_block_s"]["sum"] == pytest.approx(
+            st["commit_block_s"] + st["fused_readback_s"], rel=1e-9)
+        assert st["fused_readback_s"] > 0.0
+
+    def test_a_calls_leaf_totals_never_exceed_the_calls(self, served):
+        """put, decode_pipelined and decode_batch each hold a total, and
+        what lies beneath them is bracketed leaf by leaf: the leaves sum
+        to at most the calls, and what is left is a number."""
+        _, _, after_put, after_decode, final = served
+        steps = ("plan_s", "dispatch_s", "commit_block_s", "commit_apply_s")
+        put = sum(after_put[k] for k in ("admit_s",) + steps)
+        assert 0.0 < put <= after_put["put_s"]
+        assert after_put["decode_pipelined_s"] == 0.0
+        piped = sum(after_decode[k] - after_put[k]
+                    for k in ("admit_s",) + steps)
+        assert 0.0 < piped <= after_decode["decode_pipelined_s"]
+        assert after_decode["put_s"] == after_put["put_s"]
+        fused = sum(final[k] for k in (
+            "fused_stage_s", "fused_dispatch_s", "fused_readback_s",
+            "fused_count_s", "fused_apply_s"))
+        assert 0.0 < fused <= final["decode_batch_s"]
+        # no step of the fused loop opens a pipelined bracket
+        assert all(final[k] == after_decode[k] for k in steps)
+
+    @pytest.mark.parametrize("key,where", [
+        ("admit_s", "put"), ("plan_count_s", "put"),
+        ("admit_s", "decode"), ("plan_count_s", "decode"),
+        ("fused_stage_s", "batch"),
+        ("fused_count_s", "batch")])
+    def test_the_stretches_between_the_steps_are_bracketed(self, served,
+                                                           key, where):
+        _, _, after_put, after_decode, final = served
+        then, now = {"put": ({key: 0.0}, after_put),
+                     "decode": (after_put, after_decode),
+                     "batch": (after_decode, final)}[where]
+        assert now[key] > then[key]
+        # the counters' arithmetic is nested in the plan that holds it
+        assert final["plan_count_s"] < final["plan_s"]
 
     def test_the_rings_phase_names_are_unchanged(self, served):
         names = {s[0] for s in served[0].flight.spans
@@ -283,6 +360,53 @@ class TestServeBrackets:
                     "commit_apply_s"):
             assert st[key] > 0.0
         assert eng.state.sequences[0].put_at is not None
+        for key in ("put_s", "admit_s", "plan_count_s",
+                    "decode_pipelined_s"):
+            assert st[key] > 0.0
+
+    @pytest.mark.parametrize("decode", ["pipelined", "fused"])
+    def test_the_stamps_are_the_engines_own(self, monkeypatch, decode):
+        """``put_at``, ``first_sched_at`` and ``first_token_at`` are set
+        with the observer off: the benchmark's per-request readers keep
+        reading when its cost is measured."""
+        monkeypatch.setenv("DSTPU_TELEMETRY", "0")
+        eng = _engine(decode_loop_steps=4)
+        assert eng._obs is None
+        before = time.monotonic()
+        first = eng.put([0, 1], _prompts(2), _greedy=True)
+        stamps = {}
+        for uid in (0, 1):
+            seq = eng.state.sequences[uid]
+            assert before <= seq.put_at <= seq.first_sched_at \
+                <= seq.first_token_at
+            # the observer's own two stay unset
+            assert seq.admitted_at is None and seq.last_token_at is None
+            stamps[uid] = (seq.put_at, seq.first_sched_at,
+                           seq.first_token_at)
+        if decode == "pipelined":
+            eng.decode_pipelined([0, 1], [int(first[0]), int(first[1])], 2)
+        else:
+            eng.decode_batch([0, 1], [int(first[0]), int(first[1])], 4)
+        # later schedules and commits leave the first stamps alone
+        for uid in (0, 1):
+            seq = eng.state.sequences[uid]
+            assert stamps[uid] == (seq.put_at, seq.first_sched_at,
+                                   seq.first_token_at)
+
+    def test_a_first_token_from_the_fused_loop_is_stamped(self,
+                                                          monkeypatch):
+        """A sequence whose first committed output comes from
+        ``decode_batch`` (its prompt fed without a greedy put) is stamped
+        by ``_apply_fused``."""
+        monkeypatch.setenv("DSTPU_TELEMETRY", "0")
+        eng = _engine(decode_loop_steps=4)
+        prompt = _prompts(1)[0]
+        eng.put([0], [prompt[:-1]])
+        seq = eng.state.sequences[0]
+        at_put = seq.first_token_at
+        seq.first_token_at = None
+        eng.decode_batch([0], [prompt[-1]], 4)
+        assert at_put is not None and seq.first_token_at > at_put
 
     def test_an_attached_watchdog_is_told_each_phase(self):
         eng = _engine()
@@ -314,9 +438,11 @@ class TestServeBrackets:
                 for e in line.events:
                     if e.name.startswith("dstpu:"):
                         found.setdefault(e.name, dict(e.stats))
-        assert {"dstpu:serve/put", "dstpu:serve/plan",
+        assert {"dstpu:serve/put", "dstpu:serve/admit", "dstpu:serve/plan",
                 "dstpu:serve/dispatch", "dstpu:serve/commit_block",
                 "dstpu:serve/commit_apply"} <= set(found)
+        # a total alone: the counters' arithmetic stays under serve/plan
+        assert "dstpu:serve/plan_count" not in found
         plan = found["dstpu:serve/plan"]
         assert plan["S"] == 2 and plan["T"] == 8 and plan["seqs"] == 2
         assert found["dstpu:serve/dispatch"]["program"] == "step_greedy"
@@ -331,8 +457,9 @@ class TestTrainBrackets:
         st = eng.step_stats
         assert st["steps"] == 3
         flash = {"flash_score_elems_computed", "flash_score_elems_needed"}
-        assert set(st) == {"steps", "stage_s", "dispatch_s",
-                           "commit_apply_s"} | flash
+        assert set(st) == {"steps", "train_batch_s", "stage_s",
+                           "dispatch_s", "device_wait_s", "commit_apply_s",
+                           "step_exit_s"} | flash
         assert all(st[k] > 0.0 for k in set(st) - flash)
         names = [s[0] for s in eng._train_obs.flight.spans]
         assert names == ["stage", "dispatch", "device_execute",
@@ -376,3 +503,26 @@ class TestTrainBrackets:
             eng.train_batch(b)
         assert eng.step_stats["steps"] == 2
         assert eng.step_stats["dispatch_s"] > 0.0
+        # no observer, no books to close: the bracket never opens
+        assert eng.step_stats["step_exit_s"] == 0.0
+        assert eng.step_stats["train_batch_s"] > 0.0
+
+    def test_a_two_step_train_batchs_leaves_never_exceed_the_call(self):
+        eng, batches = _train_engine()
+        fresh = dict(eng.step_stats)
+        assert {k: v for k, v in fresh.items() if k.endswith("_s")} \
+            == {SPANS[n].total: 0.0 for n in SPANS if n.startswith("train/")}
+        for b in batches[:2]:
+            eng.train_batch(b)
+        st = eng.step_stats
+        leaves = sum(st[k] for k in ("stage_s", "dispatch_s",
+                                     "device_wait_s", "commit_apply_s",
+                                     "step_exit_s"))
+        assert 0.0 < leaves <= st["train_batch_s"]
+        # the observer closing its books, and the wait for step N-1 (none
+        # before the second step), each under its own total
+        assert st["step_exit_s"] > 0.0 and st["device_wait_s"] > 0.0
+        # the enclosing call and the exit reach neither ring nor registry
+        names = [s[0] for s in eng._train_obs.flight.spans]
+        assert names == ["stage", "dispatch", "device_execute",
+                         "commit_apply"] * 2

@@ -376,7 +376,10 @@ class TestServeTelemetry:
         assert eng.metrics is None and eng.flight is None
         assert eng.slo_report() == {}
         seq = eng.state.sequences[0]
-        assert seq.admitted_at is None and seq.first_token_at is None
+        # the observer's own stamps stay unset; the first-token stamp
+        # is the engine's since ISSUE 55 (tests/unit/test_spans.py)
+        assert seq.admitted_at is None and seq.last_token_at is None
+        assert seq.first_token_at is not None
         assert all(len(t) == N_TOK for t in toks.values())
 
     def test_disabled_stream_identical_to_enabled(self, served,
