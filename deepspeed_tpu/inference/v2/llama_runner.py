@@ -56,6 +56,33 @@ class LlamaRaggedRunner(RaggedRunnerBase):
     supports_fused_woq = True
 
 
+def _moe_counts(top_idx, valid, E: int, held, kernel: bool):
+    """The fused loop's counters of one sparse layer, [E + 2] int32: the
+    routed rows of VALID rows per expert, then the held experts the
+    grouped kernel found with a row and the times it streamed an expert's
+    matrices (both over every row of the step, padding too, as the kernel
+    walks them; 0 without ``kernel``). top_idx [rows, k], valid [rows].
+    Counted by ONE compare of the choices against the experts and its
+    column sums: a scatter-add of rows x k integers is that many serial
+    updates on the TPU (45 us at 256 x 8 over 64 experts where this is 8:
+    PERF.md section 6, PR 57)."""
+    from ...ops.kernels import grouped_ffn
+    chose = top_idx[..., None] == jnp.arange(E, dtype=top_idx.dtype)
+    rows = jnp.sum(jnp.where(chose, valid[:, None, None].astype(jnp.int32),
+                             0), axis=(0, 1), dtype=jnp.int32)
+    hit = reads = jnp.int32(0)
+    if kernel:
+        first, count = held or (0, E)
+        mine = jnp.sum(chose, axis=(0, 1),
+                       dtype=jnp.int32)[first:first + count]
+        hit = jnp.sum(mine > 0, dtype=jnp.int32)
+        routed = top_idx.size
+        reads = jnp.sum(grouped_ffn.streams(
+            mine, grouped_ffn.row_tile(routed, E),
+            grouped_ffn.span_cap(routed, E)), dtype=jnp.int32)
+    return jnp.concatenate([rows, jnp.stack([hit, reads])])
+
+
 def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
              icfg: RaggedInferenceConfig = None, valid=None):
     """Grouped-GEMM MoE for the ragged path: tokens sort by their routed
@@ -151,21 +178,8 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
             top_idx = route_topk(logits, cfg.experts_top_k,
                                  score=router["score"],
                                  bias=router["select_bias"])[0]
-            E = cfg.num_experts
-            rows = jnp.zeros((E,), jnp.int32).at[top_idx].add(
-                valid.reshape(S * C, 1).astype(jnp.int32))
-            hit = reads = jnp.int32(0)
-            if impl is not None:
-                # what the kernel walked: every row of the step, padding too
-                first, count = held or (0, E)
-                mine = jnp.zeros((E,), jnp.int32).at[top_idx].add(
-                    1)[first:first + count]
-                hit = jnp.sum(mine > 0, dtype=jnp.int32)
-                routed = S * C * cfg.experts_top_k
-                reads = jnp.sum(grouped_ffn.streams(
-                    mine, grouped_ffn.row_tile(routed, E),
-                    grouped_ffn.span_cap(routed, E)), dtype=jnp.int32)
-            rows = jnp.concatenate([rows, jnp.stack([hit, reads])])
+            rows = _moe_counts(top_idx, valid.reshape(S * C),
+                               cfg.num_experts, held, impl is not None)
     return y.reshape(S, C, M), rows
 
 

@@ -91,6 +91,12 @@ def streams(sizes, tile: int = ROW_TILE, cap: int = _ROW_TILES[-1]):
     return ((tiles + per - 1) // per).astype(jnp.int32)
 
 
+#: rows a block of :func:`group_layout`'s running count: one 0 / 1
+#: triangle of this side sums a block's rows on the MXU, and a block's
+#: count of a group (at most this) is still a whole number in bfloat16
+_COUNT_BLOCK = 128
+
+
 def group_layout(eid, groups: int, tile: int = ROW_TILE,
                  cap: int = _ROW_TILES[-1]):
     """Where each routed row goes when every group starts at a multiple of
@@ -101,21 +107,46 @@ def group_layout(eid, groups: int, tile: int = ROW_TILE,
     [V] int32 lists: the group each visit serves, its first row tile and
     how many of the group's tiles it spans (:func:`streams` visits a
     group), the last real visit repeated behind it; nvis [1] int32; sizes
-    [groups] int32)."""
+    [groups] int32).
+
+    Counted, not sorted: a row's rank in its group (what a stable sort by
+    group would give it) is the number of rows of that group before it,
+    and a group's size the count at the last row. Both come from ONE
+    compare of ``eid`` against the groups and its running sum down the
+    rows, taken in blocks of ``_COUNT_BLOCK`` rows as two products with
+    0 / 1 triangles (inside a block, then over the blocks before it;
+    exact: 0 / 1 and counts up to 128 in bfloat16, sums in float32). A
+    scatter of R integers is R serial updates on the TPU, and the sort,
+    the gathers through its order and the scatter back were 96 us at
+    2,048 rows over 32 groups where this is 12 (PERF.md section 6,
+    PR 57); the visit lists read their group by a compare too."""
     R = eid.shape[0]
     V = visits_bound(R, groups, tile)
-    sizes = jnp.bincount(eid, length=groups + 1).astype(jnp.int32)[:groups]
+    ids = jnp.arange(groups, dtype=jnp.int32)
+    blocks = -(-R // _COUNT_BLOCK)
+    e = jnp.pad(eid, (0, blocks * _COUNT_BLOCK - R),
+                constant_values=groups).reshape(blocks, _COUNT_BLOCK)
+    onehot = e[..., None] == ids                    # [blocks, 128, groups]
+    # a product with a lower triangle of ones is a running sum
+    within = jnp.einsum("ij,bjg->big",
+                        jnp.tri(_COUNT_BLOCK, dtype=jnp.bfloat16),
+                        onehot.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    totals = within[:, -1]                          # [blocks, groups]
+    before = jnp.dot(jnp.tri(blocks, k=-1, dtype=jnp.bfloat16),
+                     totals.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32)
+    sizes = (before[-1] + totals[-1]).astype(jnp.int32)
     tiles = (sizes + tile - 1) // tile
     tile_end = jnp.cumsum(tiles)
-    first_row = jnp.cumsum(sizes) - sizes           # in the sorted order
-    order = jnp.argsort(eid, stable=True)
-    e_sorted = jnp.take(eid, order)
-    held = e_sorted < groups
-    e_safe = jnp.minimum(e_sorted, groups - 1)
-    rank = jnp.arange(R, dtype=jnp.int32) - jnp.take(first_row, e_safe)
-    dest_sorted = jnp.where(
-        held, (jnp.take(tile_end - tiles, e_safe)) * tile + rank, V * tile)
-    dest = jnp.zeros((R,), jnp.int32).at[order].set(dest_sorted)
+    # a row's place: its group's first row and its rank there, both read
+    # through the row's own one-hot
+    place = within + before[:, None] + (
+        (tile_end - tiles) * tile - 1).astype(jnp.float32)
+    dest = jnp.where(
+        e < groups,
+        jnp.sum(jnp.where(onehot, place, 0), axis=-1).astype(jnp.int32),
+        V * tile).reshape(-1)[:R]
     # visit v serves the first group whose visits end beyond v, from the
     # tile its earlier visits of that group stopped at
     per = cap // tile
@@ -123,11 +154,16 @@ def group_layout(eid, groups: int, tile: int = ROW_TILE,
     vis_end = jnp.cumsum(visits)
     nvis = vis_end[-1]
     v = jnp.minimum(jnp.arange(V, dtype=jnp.int32), jnp.maximum(nvis - 1, 0))
-    gid = jnp.minimum(jnp.searchsorted(vis_end, v, side="right"),
-                      groups - 1).astype(jnp.int32)
-    done = (v - jnp.take(vis_end - visits, gid)) * per
-    first = (jnp.take(tile_end - tiles, gid) + done).astype(jnp.int32)
-    ntile = jnp.clip(jnp.take(tiles, gid) - done, 0, per).astype(jnp.int32)
+    gid = jnp.minimum(jnp.sum(vis_end <= v[:, None], axis=1, dtype=jnp.int32),
+                      groups - 1)
+    of = gid[:, None] == ids                        # [V, groups]
+
+    def pick(per_group):
+        return jnp.sum(jnp.where(of, per_group, 0), axis=1, dtype=jnp.int32)
+
+    done = (v - pick(vis_end - visits)) * per
+    first = pick(tile_end - tiles) + done
+    ntile = jnp.clip(pick(tiles) - done, 0, per)
     return dest, (gid, first, ntile), nvis.reshape(1).astype(jnp.int32), sizes
 
 
@@ -456,6 +492,11 @@ def layout_and_run(tokens, eid, weights, activation, dtype, *,
     k = R // tokens.shape[0]
     G = weights[0].shape[0]
     dest, visits, nvis, _ = group_layout(eid, G, tile, cap)
+    # the rows' places as a plain array, as a scatter used to leave them:
+    # fused into their consumers, XLA plans the gather below otherwise
+    # and a refill step's runs three times as long (PERF.md section 6,
+    # PR 57)
+    dest = jax.lax.optimization_barrier(dest)
     P = visits[0].shape[0] * tile
     src = jnp.full((P,), tokens.shape[0], jnp.int32).at[dest].set(
         jnp.arange(R, dtype=jnp.int32) // k, mode="drop")

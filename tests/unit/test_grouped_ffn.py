@@ -407,6 +407,113 @@ def test_a_ridge_call_asks_vmem_for_its_512_row_span(name, held, M, F,
         == (512 - 128) * (2 * M * 2 + (2 * F + 2 * M) * 4)
 
 
+def _sorted_layout(eid, G, T, cap):
+    """:func:`group_layout` as a stable sort by group gives it (NumPy):
+    a row's place is its group's first row (groups at multiples of T, in
+    order) plus its rank among the group's rows; the visit lists as
+    :func:`streams` counts them, the last repeated behind them."""
+    R = len(eid)
+    V = gf.visits_bound(R, G, T)
+    order = np.argsort(eid, kind="stable")
+    sizes = np.bincount(eid, minlength=G + 1)[:G]
+    tiles = -(-sizes // T)
+    first_tile = np.cumsum(tiles) - tiles
+    first_row = np.cumsum(sizes) - sizes
+    dest = np.full(R, V * T, np.int64)
+    held = eid[order] < G
+    g = eid[order][held]
+    dest[order[held]] = first_tile[g] * T + np.arange(R)[held] - first_row[g]
+    per = cap // T
+    visits = [(g, first_tile[g] + k, min(per, tiles[g] - k))
+              for g in range(G) for k in range(0, tiles[g], per)]
+    last = visits[-1] if visits else (G - 1, tiles.sum(), 0)
+    gid, first, ntile = np.array(visits + [last] * (V - len(visits))).T
+    return dest, (gid, first, ntile), np.array([len(visits)]), sizes
+
+
+def _layout_cases():
+    """name -> (eid, groups, tile, cap): every call of the six MoE cells,
+    loop and refill, with rows drawn as the cell draws them (a uniform
+    choice of the router's outputs, the held first), and the edges."""
+    cells = dict(PARENT_CALLS)
+    cells["olmoe-refill"] = ((16384, 64, 64), None)
+    cells["mellum2-refill"] = ((16384, 64, 32), None)
+    cases = {}
+    for i, (name, (call, _)) in enumerate(sorted(cells.items())):
+        rows, experts, held = call[:3]
+        eid = np.minimum(np.random.default_rng(i).integers(
+            0, experts, rows), held)
+        cases[name] = (eid, held, gf.row_tile(rows, experts),
+                       gf.span_cap(rows, experts))
+    rng = np.random.default_rng(57)
+    cases.update({
+        "every-row-in-no-group": (np.full(200, 4), 4, 16, 128),
+        "one-group-holds-all": (np.full(700, 2), 4, 16, 128),
+        "one-group-past-the-ridge-cap": (np.full(1300, 0), 2, 128, 512),
+        "empty-groups-between": (rng.choice([1, 5, 8], 300), 8, 16, 128),
+        "one-row": (np.array([3]), 8, 16, 128),
+        "one-row-in-no-group": (np.array([8]), 8, 16, 128),
+        "one-group": (rng.integers(0, 2, 130), 1, 16, 128),
+        "a-block-and-a-row": (rng.integers(0, 9, 129), 8, 32, 128),
+        "a-row-short-of-a-block": (rng.integers(0, 9, 127), 8, 64, 128),
+        "sorted-already": (np.sort(rng.integers(0, 17, 1000)), 16, 16, 128),
+        "sorted-backwards": (np.sort(rng.integers(0, 17, 1000))[::-1],
+                             16, 16, 128),
+    })
+    return cases
+
+
+LAYOUT_CASES = _layout_cases()
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUT_CASES))
+def test_layout_is_the_stable_sorts_element_for_element(name):
+    """Counted, not sorted: a compare against the groups and its running
+    sum place every row where ``argsort(stable)`` + ``bincount`` + the
+    scatter back placed it, with the same sizes and visit lists."""
+    eid, G, T, cap = LAYOUT_CASES[name]
+    eid = eid.astype(np.int32)
+    got = jax.device_get(jax.jit(gf.group_layout, static_argnums=(1, 2, 3))(
+        jnp.asarray(eid), G, T, cap))
+    want = _sorted_layout(eid, G, T, cap)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert g.dtype == np.int32
+        np.testing.assert_array_equal(g, np.asarray(w))
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in ``jaxpr`` and the jaxprs inside it."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("what", ["group_layout", "loop_counters",
+                                  "loop_counters_off_the_kernel"])
+def test_index_arithmetic_holds_no_sort_and_no_scatter(what):
+    """A scatter of N integers is N serial updates on the TPU: the layout
+    and the fused loop's counters place nothing by scatter and sort
+    nothing (the ``src`` scatter of ``layout_and_run`` moves ROWS into the
+    kernel's operand and stays)."""
+    from deepspeed_tpu.inference.v2.llama_runner import _moe_counts
+    if what == "group_layout":
+        jaxpr = jax.make_jaxpr(lambda e: gf.group_layout(e, 32, 32, 128))(
+            jnp.zeros((2048,), jnp.int32))
+    else:
+        jaxpr = jax.make_jaxpr(lambda t, v: _moe_counts(
+            t, v, 64, (0, 32), what == "loop_counters"))(
+            jnp.zeros((256, 8), jnp.int32), jnp.ones((256,), bool))
+    names = _primitives(jaxpr.jaxpr)
+    assert "dot_general" in names or what != "group_layout"
+    bad = {n for n in names if "sort" in n or "scatter" in n
+           or n in ("gather", "while")}
+    assert not bad, bad
+
+
 def test_training_layer_keeps_ragged_dot_and_its_gradient():
     """``moe/layer.py`` is not the kernel's caller: its program still holds
     ``ragged_dot`` (no Pallas call) and a gradient flows through it."""

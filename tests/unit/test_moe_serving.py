@@ -384,3 +384,85 @@ class TestEPWarmPath:
             f"pipeline run")
         for u in uids:
             ep2.flush(u)
+
+
+# ------------------------------------------------------------------ #
+# the fused loop's counters of a sparse layer (single chip)
+# ------------------------------------------------------------------ #
+
+
+def _scattered_counts(top, valid, E, held, kernel):
+    """The counters as the scatter-add made them (NumPy ``add.at``)."""
+    from deepspeed_tpu.ops.kernels import grouped_ffn as gf
+    rows = np.zeros(E, np.int64)
+    np.add.at(rows, top, np.broadcast_to(valid[:, None], top.shape))
+    hit = reads = 0
+    if kernel:
+        first, count = held or (0, E)
+        mine = np.zeros(E, np.int64)
+        np.add.at(mine, top, 1)
+        mine = mine[first:first + count]
+        hit = int((mine > 0).sum())
+        reads = int(gf.streams(jnp.asarray(mine, jnp.int32),
+                               gf.row_tile(top.size, E),
+                               gf.span_cap(top.size, E)).sum())
+    return np.concatenate([rows, [hit, reads]])
+
+
+class TestLoopCounters:
+    """``rows`` (what ``expert_imbalance.rollout`` reads), ``hit`` and
+    ``reads`` (``moe_reads_per_hit.rollout``) hold the integers the
+    scatter-adds held: counted by a compare, under a ``valid`` mask with
+    dead rows and under a chip's ``held`` share of the experts."""
+
+    @pytest.mark.parametrize("kernel", [True, False],
+                             ids=["kernel", "ragged_dot"])
+    @pytest.mark.parametrize("held", [None, (0, 8), (8, 8), (4, 3)],
+                             ids=lambda h: "all" if h is None
+                             else f"held{h[0]}-{h[1]}")
+    @pytest.mark.parametrize("dead", [0.0, 0.4, 1.0],
+                             ids=["all-valid", "dead-rows", "none-valid"])
+    def test_counts_are_the_scatter_adds(self, dead, held, kernel):
+        from deepspeed_tpu.inference.v2.llama_runner import _moe_counts
+        E, k, rows = 16, 4, 96
+        rng = np.random.default_rng(int(dead * 10) + (held or (9, 9))[0])
+        # every row's k choices differ, as a top-k's do; a hot expert so
+        # that one held group passes a row tile
+        top = np.stack([rng.permutation(E)[:k] for _ in range(rows)])
+        top[: rows // 2, 0] = 5
+        valid = rng.random(rows) >= dead
+        got = jax.jit(_moe_counts, static_argnums=(2, 3, 4))(
+            jnp.asarray(top, jnp.int32), jnp.asarray(valid), E, held, kernel)
+        assert got.dtype == jnp.int32 and got.shape == (E + 2,)
+        np.testing.assert_array_equal(
+            np.asarray(got), _scattered_counts(top, valid, E, held, kernel))
+
+    def test_a_hot_expert_past_the_span_cap_is_read_twice(self):
+        from deepspeed_tpu.inference.v2.llama_runner import _moe_counts
+        top = np.zeros((300, 1), np.int64)            # 300 rows on expert 0
+        top[:3, 0] = [1, 2, 2]
+        got = np.asarray(_moe_counts(jnp.asarray(top, jnp.int32),
+                                     jnp.ones((300,), bool), 4, None, True))
+        want = _scattered_counts(top, np.ones(300, bool), 4, None, True)
+        np.testing.assert_array_equal(got, want)
+        assert list(got) == [297, 1, 2, 0, 3, 5]      # 297 rows: 3 streams
+
+    def test_moe_mlp_counts_valid_positions_alone(self, base_pair):
+        """Through ``_moe_mlp`` itself: the routed rows of valid positions
+        per expert, the choice ``route_topk`` makes of the layer's own
+        logits; padding is computed and left out of the count."""
+        from deepspeed_tpu.inference.v2.llama_runner import _moe_mlp
+        from deepspeed_tpu.moe.sharded_moe import route_topk
+        mcfg, params, _ = base_pair
+        p_moe = params["layer_0"]["moe"]
+        M = p_moe["gate"].shape[0]
+        h = jax.random.normal(jax.random.PRNGKey(3), (3, 8, M), jnp.float32)
+        valid = jnp.arange(8)[None, :] < jnp.asarray([8, 3, 0])[:, None]
+        y, rows = _moe_mlp(p_moe, h, mcfg, jnp.float32, valid=valid)
+        assert y.shape == h.shape
+        top = np.asarray(route_topk(
+            h.reshape(24, M) @ p_moe["gate"], mcfg.experts_top_k)[0])
+        want = _scattered_counts(top, np.asarray(valid).reshape(24),
+                                 mcfg.num_experts, None, False)
+        np.testing.assert_array_equal(np.asarray(rows), want)
+        assert int(rows[:-2].sum()) == 11 * mcfg.experts_top_k
