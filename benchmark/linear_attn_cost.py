@@ -3,12 +3,12 @@
 ``moe_cost.py`` the grouped matmuls'): what one KDA layer of
 ``ops/kernels/delta_rule.py`` must compute and move, in its two forms.
 
-``layer_metrics/linear_attn_roofline.solar2.json`` names
+``layer_metrics/linear_attn_roofline.rollout.json`` names
 ``kda_decode_cost`` as ``linear_attn_cost.kda_decode_cost``
-(``readers.cost_function``), and since PR 54
-``linear_attn_prefill_roofline.solar2.json`` / ``.kimi.json`` name
-``kda_prefill_cost`` the same way; ``roofline_share`` below is the same
-share for a builder's own reduction of a traced run.
+(``readers.cost_function``) and ``linear_attn_prefill_roofline.rollout.json``
+names ``kda_prefill_cost`` the same way, each with its sizes from the
+cell's own file; ``roofline_share`` below is the same share for a builder's
+own reduction of a traced run.
 """
 
 from __future__ import annotations

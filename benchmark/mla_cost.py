@@ -1,7 +1,7 @@
 """Operations and bytes latent attention (MLA) needs, from its shapes alone.
 
 The true counts of the ABSORBED form, which
-``layer_metrics/mla_attn_roofline.pangu.json`` names as
+``layer_metrics/mla_attn_roofline.rollout.json`` names as
 ``mla_cost.mla_decode_attention_cost`` (``kernel_cost.
 paged_decode_attention_cost``, its stand-in until PR 37, counts a row's
 bytes right at ``kv_heads`` 1, ``head_dim`` 288 and its FLOPs 1.9 x

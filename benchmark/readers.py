@@ -5,7 +5,11 @@ returns nothing, and the harness leaves the metric out of the line.
 
 Observations are addressed by dotted keys into the job's ``obs`` (with
 ``trace.*`` from ``reduce_trace.reduce``, ``setup.*`` from the compile
-clock, ``memory_peak_bytes`` and ``peak.*`` from ``peaks.json``).
+clock, ``memory_peak_bytes``, ``peak.*`` from ``peaks.json`` and ``cell.*``,
+the cell's own file). A key or a kernel's pattern may hold ``{dotted.key}``
+placeholders, resolved in the observations first: a family's reader names
+``trace.ops.{cell.kernels.grouped_ffn.op}`` and every cell of its list
+states its kernel's traced name and sizes in its file's ``kernels`` block.
 """
 
 from __future__ import annotations
@@ -18,17 +22,36 @@ from . import kernel_cost, reduce_trace
 from .common import percentile
 
 _COST_MODULE = re.compile(r"^[a-z0-9_]*_cost$")
-# a kernel's pattern may name sizes of the run: {attention.q_heads}
+# a key or a pattern may name what the run or the cell's file states:
+# {attention.q_heads}, {cell.kernels.grouped_ffn.op}
 _PLACEHOLDER = re.compile(r"\{([\w.]+)\}")
 
 
-def lookup(obs: Dict[str, Any], key: str) -> Any:
+def _walk(obs: Dict[str, Any], key: str) -> Any:
     cur: Any = obs
     for part in key.split("."):
         if not isinstance(cur, dict) or part not in cur:
             return None
         cur = cur[part]
     return cur
+
+
+def resolve(obs: Dict[str, Any], text: str) -> Optional[str]:
+    """``text`` with every ``{dotted.key}`` replaced by what the
+    observations hold under it; nothing where one of them holds nothing
+    (the reader then has nothing to read)."""
+    found = {key: _walk(obs, key) for key in _PLACEHOLDER.findall(text)}
+    if any(v is None for v in found.values()):
+        return None
+    return _PLACEHOLDER.sub(lambda m: str(found[m.group(1)]), text)
+
+
+def lookup(obs: Dict[str, Any], key: str) -> Any:
+    """What the observations hold under a dotted key, its placeholders
+    resolved first: wherever a reader names a key it may name the
+    cell's."""
+    key = resolve(obs, key)
+    return None if key is None else _walk(obs, key)
 
 
 def _sum(obs, keys) -> Optional[float]:
@@ -96,8 +119,9 @@ def r_roofline(spec, obs):
         return None
     least, took = 0.0, 0.0
     for k in spec["kernels"]:
-        pattern = _PLACEHOLDER.sub(lambda m: str(lookup(obs, m.group(1))),
-                                   k["pattern"])
+        pattern = resolve(obs, k["pattern"])
+        if pattern is None:
+            return None
         seconds, calls = reduce_trace.kernel_seconds(trace, pattern)
         if not calls:
             return None
@@ -122,13 +146,18 @@ def keys_of(spec: Dict[str, Any]) -> list:
     to export for the reader to find something to read."""
     keys = [spec[k] for k in ("key", "series") if k in spec]
     keys += list(spec.get("num", ())) + list(spec.get("den", ()))
+    named = []
     for k in spec.get("kernels", ()):
-        keys += _PLACEHOLDER.findall(k["pattern"])
+        named += _PLACEHOLDER.findall(k["pattern"])
         for c in k["costs"]:
             keys += list(c["args"].values())
             if isinstance(c.get("per"), str):
                 keys.append(c["per"])
-    return keys
+    # a key with placeholders is only known once they resolve: what the
+    # job has to export is the placeholders' own keys
+    for key in keys:
+        named += _PLACEHOLDER.findall(key) or [key]
+    return named
 
 
 REDUCERS = {"value": r_value, "percentile": r_percentile,

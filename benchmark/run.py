@@ -120,6 +120,7 @@ def run_cell(argv=None):
     job = importlib.import_module(f"benchmark.jobs.{cell['kind']}")
     result = job.run(ctx)
     obs = result["obs"]
+    obs["cell"] = cell   # its ``kernels`` block: names and sizes for readers
     obs["setup"] = dict(ctx.compiles.snapshot(), phase_s=ctx.phase_s,
                         at_window_open=ctx.setup_compiles)
     obs["memory_peak_bytes"] = ctx.memory_peak_bytes

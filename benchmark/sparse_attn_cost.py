@@ -9,7 +9,7 @@ compute.
 ``layer_metrics/sparse_prefill_roofline.sala.json``
 ``sparse_prefill_attention_cost`` (``readers.cost_function``); a Lightning
 layer's decode update is ``ssm_cost.mamba2_decode_cost`` at 96 sequences,
-32 heads, 128 x 128 (``linear_attn_roofline.sala``): the same recurrence at
+32 heads, 128 x 128 (``ssm_roofline.rollout``): the same recurrence at
 ``dt = 1``.
 """
 

@@ -1,5 +1,6 @@
-"""The cell ``serve-kimi-linear-rollout-long``, its ``.kimi`` readers and
-the families' readers that list it (``.rollout`` / ``.serve``, PR 54):
+"""The cell ``serve-kimi-linear-rollout-long``, its ``kernels`` block (PR
+58: the seven ``.kimi`` reader files became entries of the cell's own
+file) and the families' readers that list it (``.rollout`` / ``.serve``):
 the job exports every key they name (a ``--rehearse`` walk of the cell on
 the CPU, toy sizes), each counter reader on hand-made observations, the two
 cost functions at the configuration's 32 heads, and the roofline readers
@@ -18,7 +19,11 @@ CELL = "serve-kimi-linear-rollout-long"
 MANIFEST = load_manifest()
 PEAK = kernel_cost.peaks("TPU v5 lite")
 NAMES = [m["name"] for m in run._metrics_of(MANIFEST, "per_layer", CELL)]
-KIMI = sorted(n for n in NAMES if n.endswith(".kimi"))
+KERNEL_READERS = sorted(
+    n for n in NAMES
+    if any(k.startswith("cell.kernels.")
+           for k in readers.keys_of(load_json("layer_metrics",
+                                              n + ".json"))))
 
 
 def _spec(name):
@@ -26,15 +31,17 @@ def _spec(name):
 
 
 def test_the_manifest_gives_the_cell_its_metrics_and_nothing_else_moved():
-    # files of its own only for the readers whose body is its own (the
-    # kernels' names and sizes); the rest it shares with its family
-    assert KIMI == [
-        "grouped_ffn_share.kimi", "grouped_moe_roofline.kimi",
-        "linear_attn_prefill_roofline.kimi", "linear_attn_roofline.kimi",
-        "linear_attn_share.kimi", "mla_attn_roofline.kimi",
-        "mla_attn_share.kimi"]
+    # no reader file of its own: the kernels' names and sizes stand in
+    # the cell's file, and seven families' readers take them from there
+    assert KERNEL_READERS == [
+        "grouped_ffn_share.rollout", "grouped_moe_roofline.rollout",
+        "linear_attn_prefill_roofline.rollout",
+        "linear_attn_roofline.rollout", "mla_attn_roofline.rollout",
+        "mla_attn_share.rollout", "state_update_share.rollout"]
     assert all(n.rpartition(".")[2] in ("rollout", "serve") or "." not in n
-               for n in NAMES if n not in KIMI)
+               for n in NAMES)
+    assert sorted(load_json("cells", CELL + ".json")["kernels"]) == [
+        "grouped_ffn", "linear_attn_prefill", "mla", "state_update"]
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == ("kimi-linear-48b-a3b", "rollout-long", 1)
@@ -79,6 +86,7 @@ def test_a_rehearsal_fills_every_key_the_kimi_readers_name(capsys):
 PIPELINE = {
     "prefill_tokens_real": 900, "prefill_tokens_planned": 2048,
     "fused_dispatch_s": 0.030, "fused_apply_s": 0.012,
+    "put_s": 8.0, "decode_batch_s": 32.0,
     "latent_rows_live": 800, "latent_rows_fetched": 1000,
     "latent_bytes_live": 1_000_000, "state_bytes_live": 3_000_000,
     "kv_bytes_live": 0,
@@ -88,7 +96,7 @@ PIPELINE = {
     "linear_attn_prefill_tokens": 4000,
     "linear_attn_prefill_kernel_tokens": 3000}
 OBS = {"pipeline": PIPELINE, "rounds": 12, "window_s": 40.0,
-       "refill_s": 8.0, "memory_peak_bytes": 11.3e9,
+       "memory_peak_bytes": 11.3e9,
        "trace": {"window_s": 4.0, "idle_s": 0.1, "busy_s": 3.9}}
 
 
@@ -98,7 +106,7 @@ OBS = {"pipeline": PIPELINE, "rounds": 12, "window_s": 40.0,
     ("expert_imbalance.rollout", 1.3), ("moe_reads_per_hit.rollout", 1.002),
     ("prefill_useful_share.rollout", 100 * 900 / 2048),
     ("fused_host_ms_per_round.rollout", 3.5),
-    ("refill_wall_share.rollout", 20.0), ("device_idle_share.rollout", 2.5),
+    ("refill_call_share.rollout", 20.0), ("device_idle_share.rollout", 2.5),
     ("peak_hbm_gb.rollout", 11.3),
     ("moe_prefill_kernel_share.rollout", 100.0),
     ("linear_attn_prefill_kernel_share.rollout", 75.0)])
@@ -159,18 +167,20 @@ def test_roofline_readers_match_the_compiled_names_and_stay_under_100(metric):
             PEAK)["seconds"]}[metric]
     name = KERNELS[metric]
     obs = {"peak": PEAK, "attention": {"q_heads": 32},
+           "cell": load_json("cells", CELL + ".json"),
            "traced": {"decode_context_tokens": ctx,
                       "pipeline": {"moe_rows_routed": rows,
                                    "moe_experts_hit": hit}},
            "trace": {"n_devices": 1, "busy_s": 10 * cost,
                      "ops": {name: 2 * cost, "fusion.1": 8 * cost},
                      "op_counts": {name: calls, "fusion.1": 5}}}
-    got = readers.read(_spec(f"{metric}_roofline.kimi"), obs)
+    got = readers.read(_spec(f"{metric}_roofline.rollout"), obs)
     assert got == pytest.approx(50.0, rel=1e-6)
-    share = {"linear_attn": "linear_attn_share", "mla_attn": "mla_attn_share",
+    share = {"linear_attn": "state_update_share", "mla_attn": "mla_attn_share",
              "grouped_moe": "grouped_ffn_share"}[metric]
-    assert readers.read(_spec(share + ".kimi"), obs) == pytest.approx(20.0)
+    assert readers.read(_spec(share + ".rollout"), obs) \
+        == pytest.approx(20.0)
     # another model's kernel names are not matched
     other = dict(obs, trace=dict(obs["trace"], ops={"x": 1.0},
                                  op_counts={"x": 1}))
-    assert readers.read(_spec(f"{metric}_roofline.kimi"), other) is None
+    assert readers.read(_spec(f"{metric}_roofline.rollout"), other) is None

@@ -1,5 +1,7 @@
-"""The cell ``serve-mellum2-rollout-long``, its ``.mellum2`` readers and the
-families' readers that list it (``.rollout`` / ``.serve``, PR 54):
+"""The cell ``serve-mellum2-rollout-long``, its ``.mellum2`` readers (the
+window's, which one configuration has), its ``kernels`` block (PR 58: the
+grouped kernel's two ``.mellum2`` files became an entry of the cell's own
+file) and the families' readers that list it (``.rollout`` / ``.serve``):
 the job exports every key they name (a ``--rehearse`` walk of the cell on
 the CPU, toy sizes), each counter reader on hand-made observations,
 ``window_attn_cost.mixed_decode_attention_cost`` by hand (with the case
@@ -28,7 +30,6 @@ def _spec(name):
 
 def test_the_manifest_gives_the_cell_its_metrics():
     assert OWN == [
-        "grouped_ffn_share.mellum2", "grouped_moe_roofline.mellum2",
         "paged_attn_roofline.mellum2", "paged_attn_share.mellum2",
         "window_cache_share.mellum2", "window_live_rows_share.mellum2",
         "window_scored_share.mellum2"]
@@ -109,6 +110,7 @@ def test_a_rehearsal_fills_every_key_the_mellum2_readers_name(capsys):
 PIPELINE = {
     "prefill_tokens_real": 900, "prefill_tokens_planned": 2048,
     "fused_dispatch_s": 0.030, "fused_apply_s": 0.012,
+    "put_s": 8.0, "decode_batch_s": 32.0,
     "decode_kv_rows_live": 800, "decode_kv_rows_fetched": 1000,
     "kv_bytes_live": 3_000_000, "window_rows_live": 900,
     "window_rows_fetched": 1200, "window_rows_scored": 1200,
@@ -117,7 +119,7 @@ PIPELINE = {
     "moe_experts_hit": 5000, "moe_expert_reads": 5010,
     "moe_prefill_tokens": 4000, "moe_prefill_kernel_tokens": 4000}
 OBS = {"pipeline": PIPELINE, "rounds": 12, "window_s": 40.0,
-       "refill_s": 8.0, "memory_peak_bytes": 14.2e9,
+       "memory_peak_bytes": 14.2e9,
        "trace": {"window_s": 4.0, "idle_s": 0.1, "busy_s": 3.9}}
 
 
@@ -130,7 +132,7 @@ OBS = {"pipeline": PIPELINE, "rounds": 12, "window_s": 40.0,
     ("moe_reads_per_hit.rollout", 1.002),
     ("prefill_useful_share.rollout", 100 * 900 / 2048),
     ("fused_host_ms_per_round.rollout", 3.5),
-    ("refill_wall_share.rollout", 20.0),
+    ("refill_call_share.rollout", 20.0),
     ("device_idle_share.rollout", 2.5),
     ("peak_hbm_gb.rollout", 14.2),
     ("moe_prefill_kernel_share.rollout", 100.0)])
@@ -198,7 +200,7 @@ def test_roofline_readers_match_the_compiled_names_and_stay_under_100(metric):
                                           hidden=2304, width=896),
             PEAK)["seconds"]}[metric]
     name = KERNELS[metric]
-    obs = {"peak": PEAK,
+    obs = {"peak": PEAK, "cell": load_json("cells", CELL + ".json"),
            "traced": {"pipeline": {"decode_kv_rows_live": full,
                                    "window_rows_live": window,
                                    "moe_rows_routed": rows,
@@ -210,10 +212,13 @@ def test_roofline_readers_match_the_compiled_names_and_stay_under_100(metric):
                 "grouped_moe": "grouped_moe_roofline"}[metric]
     share = {"paged_attn": "paged_attn_share",
              "grouped_moe": "grouped_ffn_share"}[metric]
-    assert readers.read(_spec(roofline + ".mellum2"), obs) \
+    # the window's readers are this configuration's own; the grouped
+    # kernel's are the family's, with the cell's name and sizes
+    suffix = {"paged_attn": ".mellum2", "grouped_moe": ".rollout"}[metric]
+    roofline, share = roofline + suffix, share + suffix
+    assert readers.read(_spec(roofline), obs) \
         == pytest.approx(50.0, rel=1e-6)
-    assert readers.read(_spec(share + ".mellum2"), obs) \
-        == pytest.approx(20.0)
+    assert readers.read(_spec(share), obs) == pytest.approx(20.0)
     # another model's kernel names are not matched: Nemotron's decode
     # kernel over its 256-lane row, Kimi's grouped kernel's shape
     other = dict(obs, trace=dict(obs["trace"], ops={
@@ -221,9 +226,9 @@ def test_roofline_readers_match_the_compiled_names_and_stay_under_100(metric):
         "grouped_ffn_decode-bf16_1984_2304": 1.0},
         op_counts={"closed_call-bf16_256_32_256": 1,
                    "grouped_ffn_decode-bf16_1984_2304": 1}))
-    assert readers.read(_spec(roofline + ".mellum2"), other) is None
+    assert readers.read(_spec(roofline), other) is None
     # and a parent that has no such counter gives the reader nothing to
     # read: the line then leaves the metric out
     bare = dict(obs, traced={"pipeline": {"decode_kv_rows_live": full,
                                           "moe_rows_routed": rows}})
-    assert readers.read(_spec(roofline + ".mellum2"), bare) is None
+    assert readers.read(_spec(roofline), bare) is None

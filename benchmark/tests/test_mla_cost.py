@@ -62,22 +62,24 @@ def test_mla_roofline_reader_counts_one_layers_rows_a_call():
         1.005 * 1152 / PEAK["hbm_bytes_per_s"], rel=1e-3)
     least = 5 * ctx_tokens * row["seconds"]
     name = "mla_decode_attention-bf16_128_128_512"
-    obs = {"peak": PEAK, "attention": {"q_heads": 128, "kv_heads": 1,
+    obs = {"peak": PEAK,
+           "cell": _load("cells", "serve-pangu-rollout-long.json"),
+           "attention": {"q_heads": 128, "kv_heads": 1,
                                        "head_dim": 576, "kv_row": 576,
                                        "layers": 5},
            "traced": {"decode_context_tokens": ctx_tokens},
            "trace": {"n_devices": 1, "busy_s": 8 * least,
                      "ops": {name: 2 * least, "fusion-bf16_4_512": 1.0},
                      "op_counts": {name: 640, "fusion-bf16_4_512": 9}}}
-    spec = _load("layer_metrics", "mla_attn_roofline.pangu.json")
+    spec = _load("layer_metrics", "mla_attn_roofline.rollout.json")
     assert readers.read(spec, obs) == pytest.approx(50.0)
-    assert readers.read(_load("layer_metrics", "mla_attn_share.pangu.json"),
+    assert readers.read(_load("layer_metrics", "mla_attn_share.rollout.json"),
                         obs) == pytest.approx(25.0)
     # a program without the kernel: nothing to read, nothing reported
     obs["trace"]["ops"].pop(name)
     obs["trace"]["op_counts"].pop(name)
     assert readers.read(spec, obs) is None
-    assert readers.read(_load("layer_metrics", "mla_attn_share.pangu.json"),
+    assert readers.read(_load("layer_metrics", "mla_attn_share.rollout.json"),
                         obs) is None
 
 

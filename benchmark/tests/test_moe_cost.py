@@ -1,6 +1,8 @@
 """``moe_cost.grouped_moe_ffn_cost`` against hand-worked numbers at the
 shapes of ``serve-olmoe-rollout`` (hidden 2048, 64 experts of width 1024,
-8 experts a token, bfloat16)."""
+8 experts a token, bfloat16) and, at ``matrices=2``, of
+``serve-nemotron3-nano-rollout-long``'s ungated experts (hidden 2688,
+published width 1856; until PR 58 ``ssm_cost.ungated_ffn_cost``)."""
 
 import pytest
 
@@ -55,3 +57,50 @@ def test_roofline_share_of_a_measured_time():
         hidden=HIDDEN, width=WIDTH)
     assert got["share"] == pytest.approx(50.0)
     assert got["bound"] == "memory"
+
+
+NEMOTRON = dict(hidden=2688, width=1856)
+
+
+def test_the_ungated_experts_cost_by_hand():
+    """TWO matrices an expert hit at the published width 1856: 19.96 MB
+    an expert, 1.28 GB a layer and step when all 64 are hit; two thirds of
+    what the three-matrix count gives for the same shape."""
+    c = moe_cost.grouped_moe_ffn_cost(rows=768, experts_hit=64, matrices=2,
+                                      **NEMOTRON)
+    assert c["flops"] == 4.0 * 768 * 2688 * 1856
+    assert c["bytes"] == 2 * 64 * 2688 * 1856 * 2 + 2 * 768 * 2688 * 2
+    assert c["bytes"] == pytest.approx(1.2853e9, rel=1e-3)
+    assert kernel_cost.roofline_seconds(c, V5E)["bound"] == "memory"
+    three = moe_cost.grouped_moe_ffn_cost(rows=768, experts_hit=64,
+                                          **NEMOTRON)
+    assert three["flops"] == 1.5 * c["flops"]
+    assert three["bytes"] / c["bytes"] == pytest.approx(1.5, rel=5e-3)
+
+
+@pytest.mark.parametrize("rows, hit", [
+    (768, 64), (768.0, 63.2), (5 * 128 * 768.0, 5 * 128 * 64.0),
+    (196608.0, 40448.0), (1.0, 1.0)])
+def test_two_matrices_count_what_the_retired_ungated_cost_counted(rows, hit):
+    """``ssm_cost.ungated_ffn_cost`` as it stood until PR 58, to the
+    digit: the same floats in the same order, so the reading of
+    ``grouped_moe_roofline`` on Nemotron's cell did not move."""
+    was = {"flops": 4.0 * rows * 2688 * 1856,
+           "bytes": float(2 * hit * 2688 * 1856 * 2 + 2 * rows * 2688 * 2)}
+    assert moe_cost.grouped_moe_ffn_cost(rows, hit, matrices=2,
+                                         **NEMOTRON) == was
+    # and three matrices is the default every SwiGLU cell reads by
+    assert moe_cost.grouped_moe_ffn_cost(rows, hit, **NEMOTRON) \
+        == moe_cost.grouped_moe_ffn_cost(rows, hit, matrices=3, **NEMOTRON)
+
+
+def test_the_three_matrix_count_would_pass_100_on_two_matrix_experts():
+    """Why Nemotron's cell states ``matrices`` 2: a kernel at 92 % of the
+    two-matrix bound reads 138 % against the three-matrix one, which the
+    driver refuses as an impossible reading."""
+    two = kernel_cost.roofline_seconds(moe_cost.grouped_moe_ffn_cost(
+        rows=768, experts_hit=64, matrices=2, **NEMOTRON), V5E)["seconds"]
+    three = kernel_cost.roofline_seconds(moe_cost.grouped_moe_ffn_cost(
+        rows=768, experts_hit=64, **NEMOTRON), V5E)["seconds"]
+    took = two / 0.92
+    assert 100 * three / took == pytest.approx(138.0, abs=0.6)

@@ -1,8 +1,10 @@
-"""The cell ``serve-nemotron3-nano-rollout-long``, its ``.nemotron``
-readers and the families' readers that list it (``.rollout`` / ``.serve``,
-PR 54): the job exports every key they name (a ``--rehearse`` walk of the
-cell on the CPU, toy sizes), each counter reader on hand-made observations,
-the two cost functions of ``ssm_cost.py`` by hand, and the roofline readers
+"""The cell ``serve-nemotron3-nano-rollout-long``, its ``kernels`` block
+(PR 58: the five ``.nemotron`` reader files became entries of the cell's
+own file) and the families' readers that list it (``.rollout`` /
+``.serve``): the job exports every key they name (a ``--rehearse`` walk of
+the cell on the CPU, toy sizes), each counter reader on hand-made
+observations, the state update's cost by hand (the ungated experts' is
+``test_moe_cost.py``'s, ``matrices=2``), and the roofline readers
 against a hand-made trace that carries the kernel names the v5e compile
 gives at the published widths (``tests/unit/test_nemotron_h.py`` has the
 model; a time comes only from a chip run). Nothing here looks at where in
@@ -18,7 +20,11 @@ CONFIG = "nemotron-3-nano-30b-a3b"
 MANIFEST = load_manifest()
 PEAK = kernel_cost.peaks("TPU v5 lite")
 NAMES = [m["name"] for m in run._metrics_of(MANIFEST, "per_layer", CELL)]
-OWN = sorted(n for n in NAMES if n.endswith(".nemotron"))
+KERNEL_READERS = sorted(
+    n for n in NAMES
+    if any(k.startswith("cell.kernels.")
+           for k in readers.keys_of(load_json("layer_metrics",
+                                              n + ".json"))))
 
 
 def _spec(name):
@@ -26,12 +32,20 @@ def _spec(name):
 
 
 def test_the_manifest_gives_the_cell_its_metrics():
-    assert OWN == [
-        "grouped_ffn_share.nemotron", "grouped_moe_roofline.nemotron",
-        "paged_attn_roofline.nemotron", "ssm_roofline.nemotron",
-        "ssm_share.nemotron"]
+    # no reader file of its own: five families' readers take the
+    # kernels' names and sizes from the cell's file
+    assert KERNEL_READERS == [
+        "grouped_ffn_share.rollout", "grouped_moe_roofline.rollout",
+        "paged_attn_roofline.rollout", "ssm_roofline.rollout",
+        "state_update_share.rollout"]
     assert all(n.rpartition(".")[2] in ("rollout", "serve") or "." not in n
-               for n in NAMES if n not in OWN)
+               for n in NAMES)
+    kernels = load_json("cells", CELL + ".json")["kernels"]
+    assert sorted(kernels) == ["grouped_ffn", "paged_attn", "state_update"]
+    # two matrices an expert at the PUBLISHED width; 2 of 13 layers keep K/V
+    assert (kernels["grouped_ffn"]["matrices"],
+            kernels["grouped_ffn"]["width"]) == (2, 1856)
+    assert kernels["paged_attn"]["layers"] == 2
     cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == (CONFIG, "rollout-long", 1)
@@ -93,6 +107,7 @@ def test_a_rehearsal_fills_every_key_the_nemotron_readers_name(capsys):
 PIPELINE = {
     "prefill_tokens_real": 900, "prefill_tokens_planned": 2048,
     "fused_dispatch_s": 0.030, "fused_apply_s": 0.012,
+    "put_s": 8.0, "decode_batch_s": 32.0,
     "decode_kv_rows_live": 800, "decode_kv_rows_fetched": 1000,
     "kv_bytes_live": 1_000_000, "state_bytes_live": 3_000_000,
     "latent_bytes_live": 0,
@@ -100,7 +115,7 @@ PIPELINE = {
     "moe_experts_hit": 5000, "moe_expert_reads": 5010,
     "moe_prefill_tokens": 4000, "moe_prefill_kernel_tokens": 4000}
 OBS = {"pipeline": PIPELINE, "rounds": 12, "window_s": 40.0,
-       "refill_s": 8.0, "memory_peak_bytes": 13.6e9,
+       "memory_peak_bytes": 13.6e9,
        "trace": {"window_s": 4.0, "idle_s": 0.1, "busy_s": 3.9}}
 
 
@@ -111,7 +126,7 @@ OBS = {"pipeline": PIPELINE, "rounds": 12, "window_s": 40.0,
     ("moe_reads_per_hit.rollout", 1.002),
     ("prefill_useful_share.rollout", 100 * 900 / 2048),
     ("fused_host_ms_per_round.rollout", 3.5),
-    ("refill_wall_share.rollout", 20.0),
+    ("refill_call_share.rollout", 20.0),
     ("device_idle_share.rollout", 2.5),
     ("peak_hbm_gb.rollout", 13.6),
     ("moe_prefill_kernel_share.rollout", 100.0)])
@@ -140,22 +155,6 @@ def test_the_state_updates_cost_by_hand():
     assert half["bytes"] == c["bytes"] - state * 4
 
 
-def test_the_ungated_experts_cost_by_hand():
-    """TWO matrices an expert hit at the published width 1856: 19.96 MB
-    an expert, 1.28 GB a layer and step when all 64 are hit; two thirds of
-    what the three-matrix count gives for the same shape."""
-    c = ssm_cost.ungated_ffn_cost(rows=768, experts_hit=64, hidden=2688,
-                                  width=1856)
-    assert c["flops"] == 4.0 * 768 * 2688 * 1856
-    assert c["bytes"] == 2 * 64 * 2688 * 1856 * 2 + 2 * 768 * 2688 * 2
-    assert c["bytes"] == pytest.approx(1.2853e9, rel=1e-3)
-    assert kernel_cost.roofline_seconds(c, PEAK)["bound"] == "memory"
-    three = moe_cost.grouped_moe_ffn_cost(rows=768, experts_hit=64,
-                                          hidden=2688, width=1856)
-    assert three["flops"] == 1.5 * c["flops"]
-    assert three["bytes"] / c["bytes"] == pytest.approx(1.5, rel=5e-3)
-
-
 KERNELS = {
     "ssm": "mamba2_decode_state_update-f32_257_64_64_128",
     "paged_attn": "closed_call-bf16_256_32_256",
@@ -178,11 +177,12 @@ def test_roofline_readers_match_the_compiled_names_and_stay_under_100(metric):
             kernel_cost.paged_decode_attention_cost(ctx, 32, 2, 128),
             PEAK)["seconds"],
         "grouped_moe": kernel_cost.roofline_seconds(
-            ssm_cost.ungated_ffn_cost(rows=rows, experts_hit=hit,
-                                      hidden=2688, width=1856),
+            moe_cost.grouped_moe_ffn_cost(rows=rows, experts_hit=hit,
+                                          hidden=2688, width=1856,
+                                          matrices=2),
             PEAK)["seconds"]}[metric]
     name = KERNELS[metric]
-    obs = {"peak": PEAK,
+    obs = {"peak": PEAK, "cell": load_json("cells", CELL + ".json"),
            "attention": {"q_heads": 32, "kv_heads": 2, "head_dim": 128,
                          "kv_row": 256, "layers": 13},
            "traced": {"decode_context_tokens": ctx,
@@ -193,11 +193,11 @@ def test_roofline_readers_match_the_compiled_names_and_stay_under_100(metric):
                      "op_counts": {name: calls, "fusion.1": 5}}}
     roofline = {"ssm": "ssm_roofline", "paged_attn": "paged_attn_roofline",
                 "grouped_moe": "grouped_moe_roofline"}[metric]
-    got = readers.read(_spec(roofline + ".nemotron"), obs)
+    got = readers.read(_spec(roofline + ".rollout"), obs)
     assert got == pytest.approx(50.0, rel=1e-6)
-    share = {"ssm": "ssm_share", "grouped_moe": "grouped_ffn_share"}
+    share = {"ssm": "state_update_share", "grouped_moe": "grouped_ffn_share"}
     if metric in share:
-        assert readers.read(_spec(share[metric] + ".nemotron"), obs) \
+        assert readers.read(_spec(share[metric] + ".rollout"), obs) \
             == pytest.approx(20.0)
     # another model's kernel names are not matched: Kimi's state update,
     # its grouped kernel's shape
@@ -206,16 +206,4 @@ def test_roofline_readers_match_the_compiled_names_and_stay_under_100(metric):
         "grouped_ffn_decode-bf16_1984_2304": 1.0},
         op_counts={"kda_decode_state_update-f32_129_32_128_128": 1,
                    "grouped_ffn_decode-bf16_1984_2304": 1}))
-    assert readers.read(_spec(roofline + ".nemotron"), other) is None
-
-
-def test_the_three_matrix_count_would_pass_100_on_this_models_experts():
-    """Why ``moe_cost.grouped_moe_ffn_cost`` is not named: a kernel at
-    92 % of the two-matrix bound reads 138 % against the three-matrix
-    one, which the driver refuses as an impossible reading."""
-    two = kernel_cost.roofline_seconds(ssm_cost.ungated_ffn_cost(
-        rows=768, experts_hit=64, hidden=2688, width=1856), PEAK)["seconds"]
-    three = kernel_cost.roofline_seconds(moe_cost.grouped_moe_ffn_cost(
-        rows=768, experts_hit=64, hidden=2688, width=1856), PEAK)["seconds"]
-    took = two / 0.92
-    assert 100 * three / took == pytest.approx(138.0, abs=0.6)
+    assert readers.read(_spec(roofline + ".rollout"), other) is None

@@ -42,6 +42,8 @@ def test_paged_roofline_is_least_time_over_kernel_time():
     peak = kernel_cost.peaks("TPU v5 lite")
     least = 28 * 2.0 * ctx_tokens * 2 * 128 * 2 / peak["hbm_bytes_per_s"]
     obs = {"peak": peak, "attention": att,
+           # the layers that keep K/V are the cell's to state (PR 58)
+           "cell": {"kernels": {"paged_attn": {"layers": 28}}},
            "traced": {"decode_context_tokens": ctx_tokens},
            "trace": {"n_devices": 1,
                      "ops": {"closed_call-bf16_128_12_256": 4 * least,

@@ -70,8 +70,10 @@ def test_a_familys_reader_finds_its_keys_in_every_cell_of_its_list(
     job kind exports. The jobs export by kind and the engines' counters
     whole (``counters_delta``), so one rehearsed cell a kind stands for
     the kind: a key that resolves there resolves in every cell the kind
-    runs, at nought where the model has no such layer."""
-    kind = load_json("cells", cell + ".json")["kind"]
-    _line, obs = _rehearse(REHEARSED[kind])
+    runs, at nought where the model has no such layer. What a reader
+    takes from the cell's own file (``cell.*``: its ``kernels`` block, PR
+    58) is looked up in THAT cell's file, not in the stand-in's."""
+    own = load_json("cells", cell + ".json")
+    _line, obs = _rehearse(REHEARSED[own["kind"]])
     capsys.readouterr()
-    assert not _missing(name, obs)
+    assert not _missing(name, dict(obs, cell=own))

@@ -1,7 +1,10 @@
-"""The cell ``serve-minicpm-sala-rollout-32k`` and its readers: the nine
-``.sala`` files whose bodies are its own (six since PR 51, three since
-PR 54) and the families' readers that list it since PR 54 made room
-(``.rollout`` / ``.serve``). The engine counts every key the readers name
+"""The cell ``serve-minicpm-sala-rollout-32k`` and its readers: the seven
+``.sala`` files whose bodies are its own (the block selection and the
+sparse kernels, which one configuration has), its ``kernels`` block (PR
+58: the Lightning layers' state update is Nemotron's kernel, and its two
+``.sala`` files became an entry of the cell's own file) and the families'
+readers that list it (``.rollout`` / ``.serve``). The engine counts every
+key the readers name
 (a toy engine, the job's own delta), each counter reader on hand-made
 observations, the mix's equal rounds under ten seeds, the cost
 functions of ``sparse_attn_cost.py`` by hand, the roofline readers against
@@ -32,8 +35,7 @@ def _spec(name):
 
 def test_the_manifest_gives_the_cell_its_metrics():
     assert sorted(SALA) == [
-        "attn_select_share.sala", "linear_attn_roofline.sala",
-        "linear_attn_share.sala", "sparse_attn_roofline.sala",
+        "attn_select_share.sala", "sparse_attn_roofline.sala",
         "sparse_attn_share.sala", "sparse_prefill_roofline.sala",
         "sparse_prefill_visit_ratio.sala", "sparse_read_share.sala",
         "sparse_select_kernel_share.sala"]
@@ -42,7 +44,8 @@ def test_the_manifest_gives_the_cell_its_metrics():
     assert all(n.rpartition(".")[2] in ("rollout", "serve") or "." not in n
                for n in family)
     assert {"device_idle_share.rollout", "peak_hbm_gb.rollout",
-            "prefill_useful_share.rollout", "refill_wall_share.rollout",
+            "prefill_useful_share.rollout", "refill_call_share.rollout",
+            "ssm_roofline.rollout", "state_update_share.rollout",
             "fused_host_ms_per_round.rollout", "state_cache_share.rollout",
             "region_named_share.rollout"} <= set(family)
     assert len(cell_why()) <= 200
@@ -146,12 +149,16 @@ def test_an_engine_counts_every_key_the_readers_name():
         max_blocks_per_seq=20, decode_loop_steps=8, dtype="float32"))
     eng.put([0], [list(range(100))], _greedy=True)
     eng.decode_batch([0], [5], 8)
-    job = {"window_s", "refill_s", "rounds", "memory_peak_bytes"}
+    job = {"window_s", "rounds", "memory_peak_bytes"}
+    own = {"cell": load_json("cells", CELL + ".json")}
     missing = []
     for name in NAMES:
         for key in readers.keys_of(_spec(name)):
             head, _, rest = key.partition(".")
             if head in ("trace", "peak", "setup") or key in job:
+                continue
+            if head == "cell":          # the cell's own file states it
+                assert readers.lookup(own, key) is not None, (name, key)
                 continue
             counter = key.replace("traced.", "").replace("pipeline.", "")
             if counter not in eng.pipeline_stats:
@@ -170,9 +177,10 @@ OBS = {"pipeline": {
     "sparse_select_queries": 8000, "sparse_select_kernel_queries": 8000,
     "prefill_tokens_real": 61437, "prefill_tokens_planned": 81920,
     "fused_dispatch_s": 0.030, "fused_apply_s": 0.012,
+    "put_s": 17.4, "decode_batch_s": 22.6,
     "state_bytes_live": 1_000_000, "kv_bytes_live": 3_000_000,
     "latent_bytes_live": 0},
-    "rounds": 6, "window_s": 40.0, "refill_s": 17.4,
+    "rounds": 6, "window_s": 40.0,
     "memory_peak_bytes": 12.53e9,
     "trace": {"window_s": 6.7, "idle_s": 0.0335, "busy_s": 6.6665}}
 
@@ -184,7 +192,7 @@ OBS = {"pipeline": {
     ("state_cache_share.rollout", 25.0),
     ("prefill_useful_share.rollout", 100 * 61437 / 81920),
     ("fused_host_ms_per_round.rollout", 7.0),
-    ("refill_wall_share.rollout", 43.5),
+    ("refill_call_share.rollout", 43.5),
     ("device_idle_share.rollout", 0.5),
     ("peak_hbm_gb.rollout", 12.53)])
 def test_counter_readers(name, want):
@@ -265,17 +273,20 @@ def test_roofline_readers_match_the_compiled_names_and_stay_under_100(metric):
     calls = {"sparse_attn": 2 * 256, "sparse_prefill": 2 * 32,
              "linear_attn": 6 * 256}[metric]
     name = KERNELS[metric]
-    obs = {"peak": PEAK,
+    obs = {"peak": PEAK, "cell": load_json("cells", CELL + ".json"),
            "traced": {"pipeline": {
                "sparse_rows_selected": rows,
                "sparse_prefill_blocks_selected": blocks}},
            "trace": {"n_devices": 1, "busy_s": 10 * cost,
                      "ops": {name: 2 * cost, "fusion.1": 8 * cost},
                      "op_counts": {name: calls, "fusion.1": 5}}}
-    got = readers.read(_spec(metric + "_roofline.sala"), obs)
+    # the Lightning layers' update is the state-space family's kernel
+    roofline = {"linear_attn": "ssm_roofline.rollout"}.get(
+        metric, metric + "_roofline.sala")
+    got = readers.read(_spec(roofline), obs)
     assert got == pytest.approx(50.0, rel=1e-6)
     share = {"sparse_attn": "sparse_attn_share.sala",
-             "linear_attn": "linear_attn_share.sala"}.get(metric)
+             "linear_attn": "state_update_share.rollout"}.get(metric)
     if share:
         assert readers.read(_spec(share), obs) == pytest.approx(20.0)
     # another model's kernels are not matched: the dense decode kernel at
@@ -285,4 +296,4 @@ def test_roofline_readers_match_the_compiled_names_and_stay_under_100(metric):
         "mamba2_decode_state_update-f32_257_64_64_128": 1.0},
         op_counts={"closed_call-bf16_96_32_256": 1,
                    "mamba2_decode_state_update-f32_257_64_64_128": 1}))
-    assert readers.read(_spec(metric + "_roofline.sala"), other) is None
+    assert readers.read(_spec(roofline), other) is None
