@@ -412,11 +412,13 @@ class InferenceEngineV2:
             "state_slots_live": 0, "state_bytes_live": 0,
             "linear_attn_prefill_tokens": 0,
             "linear_attn_prefill_kernel_tokens": 0,
-            # layer-steps of decode whose short convolution ran the
+            # layer-steps of decode through a short convolution (recurrent
+            # layers that have one x decode steps, a fused loop's and a
+            # pipelined step's alike), and of those the ones that ran the
             # in-place kernel on the pool of carried inputs
-            # (short_conv.decode_uses_kernel, as the mixers ask it):
-            # recurrent layers x decode steps where it does, else 0
-            "conv_steps_in_place": 0,
+            # (short_conv.decode_uses_kernel, as the mixers ask it: all
+            # of a step's or none)
+            "conv_steps": 0, "conv_steps_in_place": 0,
             # latent-attention models, a layer's worth each: settled
             # latent rows of the live sequences per pure-decode step (and
             # per step of a fused loop), the rows the decode kernel
@@ -1264,14 +1266,17 @@ class InferenceEngineV2:
     def flush(self, uid: int) -> None:
         self._flush_uid(uid)
 
-    def _conv_in_place(self, S: int) -> int:
-        """Recurrent layers whose short convolution takes the in-place
-        kernel at a decode step of ``S`` rows (all of them or none)."""
+    def _conv_steps(self, S: int, n: int = 1) -> Dict[str, int]:
+        """The short convolution's two counters for ``n`` decode steps of
+        ``S`` rows: the recurrent layers that have one, a step, and of
+        those the ones that take the in-place kernel (all or none)."""
         spec = self.runner.state_spec
         if self.kv_cache.conv is None:          # no short convolution
-            return 0
-        return spec["layers"] * short_conv.decode_uses_kernel(
-            S, spec["conv_width"], self.kv_cache.conv.dtype)
+            return {"conv_steps": 0, "conv_steps_in_place": 0}
+        steps = n * spec["layers"]
+        return {"conv_steps": steps,
+                "conv_steps_in_place": steps * short_conv.decode_uses_kernel(
+                    S, spec["conv_width"], self.kv_cache.conv.dtype)}
 
     def _refuse_stateful(self, feature: str,
                          latent_too: bool = False) -> None:
@@ -1821,7 +1826,8 @@ class InferenceEngineV2:
                     stats["state_slots_live"] += ran
                     stats["state_bytes_live"] += \
                         ran * self.kv_cache.state_bytes_per_slot()
-                    stats["conv_steps_in_place"] += n * self._conv_in_place(S)
+                    for key, val in self._conv_steps(S, n).items():
+                        stats[key] += val
                 if moe_rows is not None:
                     # per call: rows the experts took, and what they would
                     # have taken had every expert been as busy as the busiest
@@ -2035,7 +2041,9 @@ class InferenceEngineV2:
                                prefill_tokens_planned=S * C, prefill_steps=1,
                                prefill_rows=sum(len(item.tokens) > 1
                                                 for item in sched))
-                    if self._stateful:
+                    if self.kv_cache.state is not None:
+                        # (layers that keep no state, a gated short
+                        # convolution's, have no recurrence to chunk)
                         spec = self.runner.state_spec
                         span.count(
                             linear_attn_prefill_tokens=real,
@@ -2073,7 +2081,7 @@ class InferenceEngineV2:
                         span.count(state_slots_live=real,
                                    state_bytes_live=real
                                    * self.kv_cache.state_bytes_per_slot(),
-                                   conv_steps_in_place=self._conv_in_place(S))
+                                   **self._conv_steps(S))
             if C > 1:
                 # serve fault site: a replica dying with a freshly planned
                 # multi-token prefill chunk (tokens consumed host-side, step

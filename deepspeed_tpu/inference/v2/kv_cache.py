@@ -164,7 +164,9 @@ class BlockedKVCache:
         block-selected layers (``index_plane.py``): one row a ``stride``
         positions of every block, so the block table addresses it too. A
         ``state_spec`` with ``taps`` 0 has no short convolution: no
-        ``conv`` array is made."""
+        ``conv`` array is made; one with ``heads`` 0 has no matrix state:
+        no ``state`` tuple is made (the convolution's carried inputs are
+        then all a sequence slot holds)."""
         self.cfg = cfg
         self.planes = planes
         self.num_layers = num_layers
@@ -221,10 +223,13 @@ class BlockedKVCache:
             # of 4 MB rows past 2^30 bytes of ONE array (PERF.md, PR 32).
             # A state is [d_v, d_k], the two sizes apart: square for the
             # delta rule, [head_dim, state] for a state-space layer
-            self.state = tuple(
-                jnp.zeros((rows, state_spec["heads"], state_spec["d_v"],
-                           state_spec["d_k"]), jnp.float32)
-                for _ in range(state_spec["layers"]))
+            # (``heads`` 0, a gated short convolution's layers, keeps no
+            # state: no ``state`` tuple is made, as no ``conv`` at taps 0)
+            if state_spec["heads"]:
+                self.state = tuple(
+                    jnp.zeros((rows, state_spec["heads"], state_spec["d_v"],
+                               state_spec["d_k"]), jnp.float32)
+                    for _ in range(state_spec["layers"]))
             # a slot's carried convolution inputs [taps - 1, width], laid
             # out in whole tiles as the decode step's kernel takes them
             if state_spec["taps"]:
@@ -273,13 +278,19 @@ class BlockedKVCache:
             self.scales = jax.device_put(self.scales, device)
         if self.state is not None:
             self.state = jax.device_put(self.state, device)
-            if self.conv is not None:
-                self.conv = jax.device_put(self.conv, device)
+        if self.conv is not None:
+            self.conv = jax.device_put(self.conv, device)
         if self.window is not None:
             self.window = jax.device_put(self.window, device)
         if self.index is not None:
             self.index = jax.device_put(self.index, device)
             self.sel_counts = jax.device_put(self.sel_counts, device)
+
+    @property
+    def stateful(self) -> bool:
+        """Whether a sequence holds a slot of the state pool: recurrent
+        state, carried convolution inputs, or both."""
+        return self.state is not None or self.conv is not None
 
     @property
     def pool(self):
@@ -289,7 +300,7 @@ class BlockedKVCache:
         planes), else the raw data array (byte-identical to the pre-int8
         path). The window pool of a model with sliding-window layers
         travels in it too."""
-        if self.quantized or self.state is not None \
+        if self.quantized or self.stateful \
                 or self.window is not None or self.index is not None:
             from .kv_quant import KVPool
             return KVPool(self.data, self.scales, self.state, self.conv,
@@ -647,12 +658,10 @@ class BlockedKVCache:
     def state_bytes_per_slot(self) -> int:
         """Bytes of recurrent state and convolution inputs one sequence
         slot holds over all recurrent layers (0 without any)."""
-        if self.state is None:
-            return 0
         conv = 0 if self.conv is None else \
             self.conv.size * self.conv.dtype.itemsize // self.conv.shape[1]
         return sum(a.size * a.dtype.itemsize // a.shape[0]
-                   for a in self.state) + conv
+                   for a in self.state or ()) + conv
 
     def window_bytes_per_row(self) -> int:
         """Bytes one position holds in the window pool over all

@@ -4,8 +4,10 @@ the latent-attention openPangu-Ultra-MoE family, whose feed-forward
 kind follows a per-layer list too, Kimi-Linear, whose layer list holds
 recurrent AND latent layers, Nemotron-H, whose layers are a mixer ALONE
 or a feed-forward ALONE and whose recurrent layers are state-space ones,
-and Mellum, whose softmax layers are sliding-window or full by the list,
-each kind with its own position code and its own pool).
+Mellum, whose softmax layers are sliding-window or full by the list,
+each kind with its own position code and its own pool, and LFM2, whose
+recurrent layers are gated short convolutions that carry their last
+inputs and no state).
 
 Analogue of the reference's llama_v2 / mistral / mixtral v2 containers
 (``inference/v2/model_implementations/{llama_v2,mistral,mixtral}/``): RoPE
@@ -145,7 +147,8 @@ def _moe_mlp(p_moe, h, cfg: MixtralConfig, dtype,
         score=getattr(cfg, "router_score", "softmax"),
         select_bias=p_moe["sel_bias"]
         if getattr(cfg, "router_bias", False) else None,
-        weight_scale=float(getattr(cfg, "routed_scaling", 1.0)))
+        weight_scale=float(getattr(cfg, "routed_scaling", 1.0)),
+        norm_eps=float(getattr(cfg, "router_norm_eps", 1e-20)))
     held = (cfg.experts_first, cfg.held) if hasattr(cfg, "held") else None
     if ep_axis_active():
         from ...moe.sharded_moe import (ep_serve_capacity,
@@ -204,33 +207,43 @@ def _state_rows(kv, si: int, batch: RaggedBatch):
     state, conv = lin_parts(kv)
     fresh = batch.start_pos == 0
     live = batch.n_tokens > 0
-    return state, conv, state[si], batch.state_slots, fresh, live
+    # a model whose recurrent layers carry convolution inputs alone
+    # ('conv') has no state part
+    st = None if state is None else state[si]
+    return state, conv, st, batch.state_slots, fresh, live
 
 
 def _short_conv(conv, si: int, batch: RaggedBatch, fresh, live, pre, w,
-                bias=None):
-    """The activated short convolution of recurrent layer ``si`` over its
-    rows' carried inputs, and the pool with the inputs the next call
-    carries: the last K-1 up to each row's last real position; an idle
-    row keeps what it had. pre [S, C, W] float32, w [K, W], bias [W] or
-    None. A decode step on the TPU is one in-place Pallas call
-    (``ops/kernels/short_conv``: platform and shape decide, nothing a
-    user sets); a prefill chunk and every other backend gather the
-    slots' rows, run ``conv_silu`` and scatter. Returns (conv, y
+                bias=None, activation="silu"):
+    """The short convolution of recurrent layer ``si`` over its rows'
+    carried inputs, and the pool with the inputs the next call carries:
+    the last K-1 up to each row's last real position; an idle row keeps
+    what it had. pre [S, C, W] float32, w [K, W] (the taps' count is
+    the weights'), bias [W] or None, ``activation`` a name of
+    ``short_conv.ACTIVATIONS`` or None (a family's own: SiLU for the
+    delta-rule and state-space layers, none for LFM2's). A decode step
+    on the TPU is one in-place Pallas call (``ops/kernels/short_conv``:
+    platform and shape decide, nothing a user sets); a prefill chunk and
+    every other backend gather the slots' rows, convolve
+    (``models.solar_open2.short_conv``) and scatter. Returns (conv, y
     [S, C, W] float32)."""
-    from ...models.solar_open2 import conv_silu
+    from ...models import solar_open2
     from ...ops.kernels import default_interpret, short_conv
     S, C, W = pre.shape
     slots = batch.state_slots
     if C == 1 and short_conv.decode_uses_kernel(S, W, conv.dtype):
         conv, y = short_conv.short_conv_decode_step(
             conv, si, slots, pre[:, 0], w, bias, fresh, live,
-            interpret=default_interpret())
+            activation=activation, interpret=default_interpret())
         return conv, y[:, None]
     taps = w.shape[0] - 1
     prev0 = conv[si, slots]                 # [S, (K-1) W / lanes, lanes]
     prev = jnp.where(fresh[:, None, None], 0, prev0).reshape(S, taps, W)
-    y, padded = conv_silu(pre, w, prev, bias)
+    y, padded = solar_open2.short_conv(pre, w, prev.astype(jnp.float32))
+    if bias is not None:
+        y = y + bias
+    if activation is not None:
+        y = short_conv.ACTIVATIONS[activation](y)
     rows = batch.n_tokens[:, None] + jnp.arange(taps, dtype=jnp.int32)
     nxt = jnp.take_along_axis(padded, rows[..., None], axis=1)
     return conv.at[si, slots].set(jnp.where(
@@ -316,6 +329,23 @@ def _mamba2_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
             jnp.where(live[:, None, None, None], Sn, St0))
     return _with_layer_state(kv, state, conv, si, st), \
         mamba2_output(p, y, z, model_cfg, dtype)
+
+
+def _gated_conv_mixer(p, h, kv, si: int, batch: RaggedBatch, dtype):
+    """One gated short-convolution (LFM2) layer over the convolution part
+    of the state pool, which is ALL a sequence carries for it: ``B | C |
+    u`` in one projection, ``v = B * u`` in float32, the causal
+    convolution of ``v`` with no bias and no activation
+    (:func:`_short_conv`: the same rows, slots, ``fresh`` and ``live`` as
+    the other recurrent kinds, :func:`_state_rows`), ``W_out (C * conv)``.
+    A padded position's ``v`` lies past the row's last real one and is
+    never carried. Returns (kv, y [S, C, M])."""
+    from ...models.lfm2 import gated_conv_inputs, gated_conv_output
+    state, conv, _, _, fresh, live = _state_rows(kv, si, batch)
+    v, c, w = gated_conv_inputs(p, h, dtype)
+    conv, y = _short_conv(conv, si, batch, fresh, live, v, w,
+                          activation=None)
+    return with_lin(kv, state, conv), gated_conv_output(p, c, y, dtype)
 
 
 def _lightning_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, pos,
@@ -531,6 +561,11 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
                 with region("ssm"):
                     kv, y = _mamba2_mixer(p["mamba"], h, kv, si, batch,
                                           model_cfg, valid_q, dtype)
+                si += 1
+            elif kind == "conv":
+                with region("conv_mixer"):
+                    kv, y = _gated_conv_mixer(p["conv"], h, kv, si, batch,
+                                              dtype)
                 si += 1
             elif kind == "lightning":
                 with region("linear_attn"):

@@ -1020,16 +1020,25 @@ class RaggedRunnerBase:
             self.kv_planes = 1
             self.kv_heads, self.head_dim = 1, model_cfg.latent_row
         #: what the state pool must hold (None: no recurrent layer): one
-        #: state ``[heads, d_v, d_k]`` a recurrent layer and the last
-        #: ``taps - 1`` inputs of its convolution, ``conv_width`` wide
+        #: state ``[heads, d_v, d_k]`` a recurrent layer (``heads`` 0: the
+        #: layers keep no state) and the last ``taps - 1`` inputs of its
+        #: convolution, ``conv_width`` wide (``taps`` 0: they have none)
         self.state_spec = None
         recurrent = [k for k in kinds
-                     if k in ("kda", "mamba2", "lightning")]
+                     if k in ("kda", "mamba2", "lightning", "conv")]
         if len(set(recurrent)) > 1:
             raise ValueError(
-                "recurrent layers of two kinds ('kda' and 'mamba2') in "
-                "one model: the state pool holds one shape of state")
-        if recurrent and recurrent[0] == "kda":
+                f"recurrent layers of more than one kind "
+                f"({sorted(set(recurrent))}) in one model: the state pool "
+                f"holds one shape of state and one width of convolution")
+        if recurrent and recurrent[0] == "conv":
+            # the carried inputs of a gated short convolution and NO
+            # matrix state (heads 0: the pool then has no state part)
+            self.state_spec = {
+                "kind": "conv", "layers": len(recurrent), "heads": 0,
+                "taps": model_cfg.conv_taps,
+                "conv_width": model_cfg.hidden_size}
+        elif recurrent and recurrent[0] == "kda":
             d = model_cfg.kda_head_dim
             self.state_spec = {
                 "kind": "kda", "layers": len(recurrent),

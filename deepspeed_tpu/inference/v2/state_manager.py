@@ -64,7 +64,7 @@ class StateManager:
         #: overwrites them, and no mask lets a row past the length in
         self.state_slots_free: Optional[List[int]] = \
             list(range(cfg.max_seqs - 1, -1, -1)) \
-            if kv_cache.state is not None or kv_cache.window is not None \
+            if kv_cache.stateful or kv_cache.window is not None \
             else None
         # scheduler clock: ONE tick per scheduler invocation (bumped by
         # the engine's plan phase — deliberately NOT the engine step

@@ -39,6 +39,9 @@ from .pangu_ultra_moe import PanguUltraMoE, PanguUltraMoEConfig
 from .pangu_ultra_moe import make_model as make_pangu_ultra_moe
 from .kimi_linear import KimiLinear, KimiLinearConfig
 from .kimi_linear import make_model as make_kimi_linear
+from .lfm2 import LAYER_TYPES as LFM2_LAYER_TYPES
+from .lfm2 import Lfm2, Lfm2Config
+from .lfm2 import make_model as make_lfm2
 from .mellum import LAYER_TYPES, Mellum, MellumConfig, YarnRope
 from .mellum import make_model as make_mellum
 from .minicpm_sala import (MIXER_TYPES, MiniCPMSALA, MiniCPMSALAConfig,
@@ -565,6 +568,73 @@ def _entry_mellum(d):
         router_aux_loss_coef=0.0)
 
 
+def _entry_lfm2(d):
+    """LFM2 (LiquidAI/LFM2-24B-A2B, ``lfm2_moe``; the dense ``lfm2`` is
+    the same entry with no sparse layer): ``layer_types`` says which
+    layers are gated short convolutions and which attend in full; the
+    first ``num_dense_layers`` layers have a dense feed-forward of
+    ``intermediate_size`` AS GIVEN and the others are sparse (sigmoid
+    router with a selection bias, renormalised over its top-k with the
+    family's ``1e-6``, no shared expert); q and k normed a head; the head
+    tied. What the served path has no form for is refused by name: a
+    convolution bias, the dense sibling's ``block_auto_adjust_ff_dim``
+    (it rewrites the width the config states), a rotary code other than
+    the plain one."""
+    moe = d.get("model_type") == "lfm2_moe"
+    n = d.get("num_hidden_layers", 40 if moe else 32)
+    types = d.get("layer_types")
+    if types is None:
+        full = d.get("full_attn_idxs")
+        full = set(range(n)) if full is None else set(full)
+        types = ["full_attention" if i in full else "conv"
+                 for i in range(n)]
+    bad = sorted(set(types) - set(LFM2_LAYER_TYPES))
+    if bad or len(types) != n:
+        raise ValueError(
+            f"lfm2 layer_types must name num_hidden_layers ({n}) layers "
+            f"of {sorted(LFM2_LAYER_TYPES)}; got {len(types)} with {bad}")
+    # the dense sibling's own default for block_auto_adjust_ff_dim is
+    # True: an ``lfm2`` file that leaves the key out is refused too
+    for key, have in (("conv_bias", d.get("conv_bias", False)),
+                      ("block_auto_adjust_ff_dim",
+                       d.get("block_auto_adjust_ff_dim", not moe))):
+        if have:
+            raise ValueError(
+                f"lfm2 configs with {key}={have!r} are not supported "
+                f"(the served path has False)")
+    rope = d.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default" \
+            or d.get("rope_scaling") is not None:
+        raise ValueError("lfm2 configs with a scaled rotary code "
+                         f"({rope or d.get('rope_scaling')!r}) are not "
+                         "supported ('default' alone)")
+    k_dense = int(d.get("num_dense_layers", 0)) if moe else n
+    base = _hf_llama(
+        d, num_layers=n,
+        intermediate_size=d.get("moe_intermediate_size", 1536),
+        rope_theta=float(rope.get("rope_theta",
+                                  d.get("rope_theta", 1000000.0))),
+        rms_eps=d.get("norm_eps", 1e-5),
+        max_seq_len=d.get("max_position_embeddings", 128000),
+        tie_embeddings=bool(d.get("tie_word_embeddings",
+                                  d.get("tie_embedding", True))))
+    return Lfm2Config(
+        **base,
+        attn_head_dim=d.get("head_dim",
+                            base["hidden_size"] // base["num_heads"]),
+        layer_kinds=tuple(LFM2_LAYER_TYPES[t] for t in types),
+        ffn_kinds=tuple("dense" if i < k_dense else "moe"
+                        for i in range(n)),
+        conv_taps=int(d.get("conv_L_cache", 3)),
+        dense_intermediate_size=d.get("intermediate_size", 11776),
+        num_experts=d.get("num_experts", 64),
+        experts_top_k=d.get("num_experts_per_tok", 4),
+        norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+        router_bias=bool(d.get("use_expert_bias", True)),
+        routed_scaling=float(d.get("routed_scaling_factor", 1.0)),
+        router_aux_loss_coef=0.0)
+
+
 def _entry_nemotron_h(d):
     """Nemotron-H (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16):
     ``hybrid_override_pattern`` read letter by letter, a layer a mixer
@@ -694,6 +764,8 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
     "nemotron_h": ArchEntry(NemotronHConfig, NemotronH, make_nemotron_h,
                             _entry_nemotron_h),
     "mellum": ArchEntry(MellumConfig, Mellum, make_mellum, _entry_mellum),
+    "lfm2": ArchEntry(Lfm2Config, Lfm2, make_lfm2, _entry_lfm2),
+    "lfm2_moe": ArchEntry(Lfm2Config, Lfm2, make_lfm2, _entry_lfm2),
     "minicpm_sala": ArchEntry(MiniCPMSALAConfig, MiniCPMSALA,
                               make_minicpm_sala, _entry_minicpm_sala),
     "gpt_neo": ArchEntry(GPTNeoConfig, GPTNeo, make_gpt_neo,

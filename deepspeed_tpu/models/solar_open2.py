@@ -291,7 +291,9 @@ class SolarSparseBlock(nn.Module):
         idx, wts, _ = route_topk(x.astype(jnp.float32) @ gate,
                               cfg.experts_top_k, score=cfg.router_score,
                               bias=bias, normalize=cfg.norm_topk_prob,
-                              scale=cfg.routed_scaling)
+                              scale=cfg.routed_scaling,
+                              norm_eps=getattr(cfg, "router_norm_eps",
+                                               1e-20))
         # [N, E]: each token's weight on every expert; this share's slice
         dense_w = jnp.zeros((B * T, E), jnp.float32).at[
             jnp.arange(B * T)[:, None], idx].add(wts)

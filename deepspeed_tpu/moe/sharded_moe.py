@@ -189,7 +189,8 @@ def gate(logits: jnp.ndarray, k: int = 1, **kwargs):
 
 
 def route_topk(logits: jnp.ndarray, k: int, *, score: str = "softmax",
-               bias=None, normalize: bool = True, scale: float = 1.0):
+               bias=None, normalize: bool = True, scale: float = 1.0,
+               norm_eps: float = 1e-20):
     """The router's choice for every row: (top_idx [S, k] int32,
     weights [S, k] float32, scores [S, E] float32).
 
@@ -200,7 +201,8 @@ def route_topk(logits: jnp.ndarray, k: int, *, score: str = "softmax",
     follow): scores ``sigmoid(logits)``; the k largest of ``score +
     bias`` are chosen (the bias takes part in the SELECTION only), and
     their weights are the scores themselves, renormalised over the chosen
-    (``normalize``) and multiplied by ``scale``."""
+    (``normalize``: ``w / (sum w + norm_eps)``; lfm2's ``1e-6`` is its
+    family's, the default the others') and multiplied by ``scale``."""
     lf = logits.astype(jnp.float32)
     if score == "softmax":
         gates = jax.nn.softmax(lf, axis=-1)
@@ -224,7 +226,7 @@ def route_topk(logits: jnp.ndarray, k: int, *, score: str = "softmax",
         gates if bias is None else gates + bias.astype(jnp.float32), k)
     w_sel = jnp.take_along_axis(gates, top_idx, axis=-1)
     if normalize:
-        w_sel = w_sel / (jnp.sum(w_sel, axis=-1, keepdims=True) + 1e-20)
+        w_sel = w_sel / (jnp.sum(w_sel, axis=-1, keepdims=True) + norm_eps)
     if scale != 1.0:
         w_sel = w_sel * scale
     return top_idx, w_sel, gates
@@ -235,7 +237,7 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
                     normalize_weights: bool = True, *,
                     score: str = "softmax", select_bias=None,
                     weight_scale: float = 1.0, held=None,
-                    impl: Optional[str] = None,
+                    impl: Optional[str] = None, norm_eps: float = 1e-20,
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dropless top-k MoE via grouped expert matmuls (``jax.lax.ragged_dot``).
 
@@ -253,8 +255,8 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     renormalizes over the selected experts (mixtral); False keeps
     full-softmax weights (qwen2-moe). Returns (out [S, M], l_aux).
 
-    ``score`` / ``select_bias`` / ``weight_scale``: the router's form
-    (:func:`route_topk`). ``held = (first, count)``: the stacked weights
+    ``score`` / ``select_bias`` / ``weight_scale`` / ``norm_eps``: the
+    router's form (:func:`route_topk`). ``held = (first, count)``: the stacked weights
     hold experts ``first .. first + count`` of the ``E`` the router scores
     (one chip's share of a layer divided over chips). Routing runs over
     all ``E``; rows whose expert is elsewhere sort behind the last held
@@ -278,7 +280,7 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     S, E = logits.shape
     top_idx, w_sel, gates = route_topk(
         logits, k, score=score, bias=select_bias,
-        normalize=normalize_weights, scale=weight_scale)
+        normalize=normalize_weights, scale=weight_scale, norm_eps=norm_eps)
 
     eid = top_idx.reshape(-1)                              # [S*k]
     here = None

@@ -1,20 +1,23 @@
 """The short causal convolution of a recurrent layer at a decode step, on
 the serving pool of carried inputs, in place.
 
-A recurrent layer (KDA's ``q | k | v``, Mamba-2's ``x | B | C``) passes
-its ``W`` projected channels through a depthwise causal convolution of
-``K`` taps and a SiLU before the recurrence. Across steps a sequence
-carries its last ``K - 1`` inputs; at a decode step (one new input a row)
-the layer's work on them is
+A recurrent layer (KDA's ``q | k | v``, Mamba-2's ``x | B | C``, LFM2's
+gated ``B * u``) passes its ``W`` projected channels through a depthwise
+causal convolution of ``K`` taps, with a bias or without, and through an
+activation or none (SiLU for KDA and Mamba-2 at ``K = 4``; none for LFM2
+at ``K = 3``), before what follows. Across steps a sequence carries its
+last ``K - 1`` inputs ``p_0 .. p_{K-2}``; at a decode step (one new input
+``x`` a row) the layer's work on them is
 
-    y      = silu(((w0 p0 + w1 p1) + w2 p2) + w3 x [+ bias])
-    p0, p1, p2 <- p1, p2, x rounded to the pool's dtype
+    y = act((..(w_0 p_0 + w_1 p_1) + ..) + w_{K-1} x [+ bias])
+    p_0, .., p_{K-2} <- p_1, .., p_{K-2}, x rounded to the pool's dtype
 
 ``models.solar_open2.short_conv`` with ``llama_runner``'s gather and
 scatter says the same in ``jax.numpy`` (every prefill chunk, every other
 backend, and what tier-1 holds this kernel to, bit for bit: float32
 elementwise arithmetic in the same order, the same one rounding).
-:func:`short_conv_decode_step` is the Pallas form: each row's carried
+:func:`short_conv_decode_step` is the Pallas form, ONE body with ``K``
+read from the taps' shape and the activation a static: each row's carried
 inputs are read once from its slot, applied, shifted and written back to
 the same slot.
 
@@ -84,8 +87,12 @@ def _chunk(wc: int) -> int:
     return 16 if wc % 16 == 0 else wc
 
 
+#: the activations a layer may ask for, by name (None: none)
+ACTIVATIONS = {"silu": jax.nn.silu}
+
+
 def _kernel(si_ref, slots_ref, fresh_ref, live_ref, x_ref, w_ref, *rest,
-            taps, has_bias):
+            taps, has_bias, activation):
     rest = list(rest)
     b_ref = rest.pop(0) if has_bias else None
     pool_in, pool_out, y_ref, buf, rsem, wsem = rest
@@ -165,7 +172,9 @@ def _kernel(si_ref, slots_ref, fresh_ref, live_ref, x_ref, w_ref, *rest,
             acc = acc + x * ws[taps - 1]
             if has_bias:
                 acc = acc + bias
-            y_ref[g, rows, :, :] = jnp.swapaxes(jax.nn.silu(acc), 0, 1)
+            if activation is not None:
+                acc = ACTIVATIONS[activation](acc)
+            y_ref[g, rows, :, :] = jnp.swapaxes(acc, 0, 1)
             for j in range(1, taps - 1):
                 mine[tile, at(j - 1), :] = prev[j]
             mine[tile, at(taps - 2), :] = x.astype(buf.dtype)
@@ -186,9 +195,9 @@ def _kernel(si_ref, slots_ref, fresh_ref, live_ref, x_ref, w_ref, *rest,
 # jitted under its own name: the device trace names a Mosaic call after
 # the function that encloses it, and a program traces and lowers this once
 # for all its recurrent layers (the layer is an operand)
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("activation", "interpret"))
 def short_conv_decode_step(conv_pool, si, slots, x, w, bias, fresh, live, *,
-                           interpret=False):
+                           activation="silu", interpret=False):
     """One decode token for every row, on the pool of carried inputs in
     place.
 
@@ -198,8 +207,8 @@ def short_conv_decode_step(conv_pool, si, slots, x, w, bias, fresh, live, *,
     inputs; w [K, W] float32 (tap K - 1 multiplies ``x``); bias [W]
     float32 or None; ``fresh`` [S] rows that start from zero inputs
     whatever the slot held; ``live`` [S]: a row that is not leaves its
-    slot as it was. Returns (conv_pool, y [S, W] float32 after the
-    SiLU)."""
+    slot as it was; ``activation`` a name of :data:`ACTIVATIONS` or None.
+    Returns (conv_pool, y [S, W] float32 after the activation)."""
     S, W = x.shape
     K = w.shape[0]
     _, _, prows, L = conv_pool.shape
@@ -232,7 +241,8 @@ def short_conv_decode_step(conv_pool, si, slots, x, w, bias, fresh, live, *,
     vmem = math.prod(slabs) * conv_pool.dtype.itemsize \
         + 2 * (2 * groups * R + K + 1) * W * 4 + (4 << 20)
     pool, y = pl.pallas_call(
-        functools.partial(_kernel, taps=K, has_bias=bias is not None),
+        functools.partial(_kernel, taps=K, has_bias=bias is not None,
+                          activation=activation),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(conv_pool.shape, conv_pool.dtype),
                    jax.ShapeDtypeStruct((G, wc, R, L), F32)],

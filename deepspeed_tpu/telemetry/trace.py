@@ -140,6 +140,8 @@ REGIONS = (
                     # group sum, window maximum, forced blocks, top-k, rows
     "attn_sparse",  # attn_core's twin around the call that reads the
                     # selected blocks alone
+    "conv_mixer",   # a gated short-convolution mixer: projection, gates,
+                    # convolution, output projection
 )
 
 

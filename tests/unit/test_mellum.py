@@ -310,7 +310,7 @@ def test_one_cache_value_holds_the_paged_pool_and_the_window_pool(model):
 
 def test_the_region_and_the_counters_are_in_the_vocabulary(model):
     from deepspeed_tpu.telemetry.trace import REGIONS
-    assert "attn_window" in REGIONS and len(REGIONS) == 23
+    assert "attn_window" in REGIONS and len(REGIONS) == 24
     cfg, params = model
     eng = engine(cfg, params)
     assert {"window_rows_live", "window_rows_fetched", "window_rows_scored",

@@ -564,7 +564,7 @@ def test_one_cache_value_holds_paged_planes_and_a_pool_of_oblong_states(
 
 def test_the_region_and_the_counter_are_in_the_vocabulary(model):
     from deepspeed_tpu.telemetry.trace import REGIONS
-    assert "ssm" in REGIONS and len(REGIONS) == 23
+    assert "ssm" in REGIONS and len(REGIONS) == 24
     cfg, params = model
     assert "kv_bytes_live" in engine(cfg, params).pipeline_stats
     # the state-space layers trace under the region, the kernel inside it

@@ -98,6 +98,15 @@ def _ssm():
         max_blocks_per_seq=6, prefill_chunk_cap=0)
 
 
+def _conv():
+    from benchmark.model_types import lfm2_moe as mt
+    from deepspeed_tpu.models.lfm2 import Lfm2Config
+    cfg = Lfm2Config.tiny(dtype=jnp.float32, param_dtype=jnp.float32)
+    return cfg, mt.init_params(cfg, 3), dict(
+        max_seqs=4, chunk_size=16, block_size=16, num_blocks=24,
+        max_blocks_per_seq=6, prefill_chunk_cap=0)
+
+
 #: layer kind -> (model, the regions its step adds to ``_STEP``)
 _KINDS = {
     "dense": (_dense, {"attn_proj", "attn_core", "ffn_dense"}),
@@ -108,6 +117,8 @@ _KINDS = {
                    "moe_experts", "moe_shared"}),
     "ssm": (_ssm, {"attn_proj", "attn_core", "ssm", "moe_route",
                    "moe_experts", "moe_shared"}),
+    "conv": (_conv, {"attn_proj", "attn_core", "conv_mixer", "ffn_dense",
+                     "moe_route", "moe_experts"}),
 }
 
 
@@ -198,7 +209,7 @@ def _strip(text):
 
 
 def test_the_vocabulary_is_closed():
-    assert len(trace.REGIONS) == len(set(trace.REGIONS)) <= 23
+    assert len(trace.REGIONS) == len(set(trace.REGIONS)) <= 24
     for name in trace.REGIONS:
         assert re.fullmatch(r"[a-z][a-z0-9_]*", name), name
     with pytest.raises(KeyError, match="REGIONS"):

@@ -711,7 +711,8 @@ def test_nemotron_loop_and_refill_compile_at_256_clients(one_chip,
     ``serve-nemotron3-nano-rollout-long`` at the published widths and the
     cell's 256-client pool, from shapes alone: every Mamba-2 layer updates
     its OBLONG state through the in-place Mosaic call (whose name and
-    output shape ``ssm_roofline.nemotron`` matches), the ungated experts
+    output shape ``ssm_roofline.rollout`` matches through the cell's
+    ``kernels.state_update.op``), the ungated experts
     of width 1856 (stored 1920) run in the grouped kernel and not in
     ``ragged-dot``, the softmax layers in the paged decode kernel at 16
     queries a kv head, the state enters donated and comes back aliased,
@@ -834,7 +835,8 @@ def test_mellum_loop_flush_and_refill_compile_at_256_clients(one_chip,
     slot) run the ONE decode kernel at 8 queries a kv head over a 512-lane
     row (the name and shape ``paged_attn_roofline.mellum2`` matches), the
     experts of 7 lane groups run in the grouped kernel at the shape
-    ``grouped_moe_roofline.mellum2`` matches, the flush updates BOTH
+    ``grouped_moe_roofline.rollout`` matches through the cell's
+    ``kernels.grouped_ffn.op``, the flush updates BOTH
     donated pools in place, and the refill step's attention calls trace
     under the two regions."""
     import json
@@ -1054,3 +1056,114 @@ def test_sala_loop_flush_and_refill_compile_at_96_clients(one_chip,
         r"%sparse_prefill[\w\-.]* = bf16\[4,18432,128\]", hlo)) == 2
     assert not re.search(big, hlo), re.findall(big, hlo)[:4]
     assert step.memory_analysis().temp_size_in_bytes < plane_bytes
+
+
+def test_lfm2_loop_flush_and_refill_compile_at_128_clients(one_chip,
+                                                          monkeypatch):
+    """The fused 128-step decode loop, its flush and the [4, 512] refill
+    step of ``serve-lfm2-rollout-long`` at the published widths and the
+    cell's 128-client pools, from shapes alone: the seven gated
+    short-convolution layers run the ONE in-place convolution call at
+    three taps over a pool that has NO state part (the name and shape the
+    cell's ``kernels.short_conv.op`` states), the two attention layers
+    run the decode kernel at heads of 64 lanes (four query heads a kv
+    head over a 512-lane row: ``_decode_kernel``'s, not the BlockSpec
+    kernel's), all 64 held experts of 12 lane groups run in the grouped
+    kernel, and the weights are the configuration file's count."""
+    import json
+    import os
+    import re
+    from collections import Counter
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from benchmark.model_types import lfm2_moe as mt
+    from deepspeed_tpu.inference.v2.kv_quant import KVPool
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
+    from deepspeed_tpu.models.lfm2 import param_counts
+    from deepspeed_tpu.ops.kernels.short_conv import pool_shape
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-24b-a2b.json")) as f:
+        config = json.load(f)
+    mcfg = mt.model_config(config)
+    with open(os.path.join(root, "benchmark", "cells",
+                           "serve-lfm2-rollout-long.json")) as f:
+        cell = json.load(f)
+    eng = cell["engine"]
+    runner = LlamaRaggedRunner(mcfg, RaggedInferenceConfig(**eng))
+    slots, block, blocks, maxb = (eng["max_seqs"], eng["block_size"],
+                                  eng["num_blocks"],
+                                  eng["max_blocks_per_seq"])
+    assert (slots, blocks) == (128, 1920)
+    assert runner.state_spec == {"kind": "conv", "layers": 7, "heads": 0,
+                                 "taps": 3, "conv_width": 2048}
+    assert (runner.kv_layers, runner.kv_heads, runner.head_dim) \
+        == (2, 8, 64)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: mt.init_params(mcfg, 0)))
+    assert params["layer_1"]["moe"]["wi_gate"].shape == (64, 2048, 1536)
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
+    assert n_params == param_counts(mcfg)[0] == config["parameters"]
+    assert abs(n_params / 5.178e9 - 1) < 5e-3          # ISSUE 59's count
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    planes = spec((2, 2, (blocks + 1) * block, 512), jnp.bfloat16)
+    conv = spec(pool_shape(7, slots + 1, 3, 2048), jnp.bfloat16)
+    assert conv.shape == (7, 129, 32, 128)
+    assert conv.size * 2 == cell["pool"]["state_pool_bytes"]
+    kv = KVPool(planes, None, None, conv)
+    f32 = functools.partial(spec, dtype=jnp.float32)
+    exe = runner._decode_loop_ring.trace(
+        params, kv._replace(conv=None), (None, conv), spec((slots,)),
+        spec((slots,)), spec((slots,)), spec((slots,)), spec((slots, maxb)),
+        spec((1,)), f32((1,)), spec((1,)), f32((1,)), spec((1, 1)), n=128,
+        mode="greedy", cand=1, eos_id=-1,
+        feed="self").lower(lowering_platforms=("tpu",)).compile()
+    hlo = exe.as_text()
+    assert Counter(_mosaic_call_names(hlo)) == {
+        "closed_call": 2, "grouped_ffn_decode": 8,
+        "short_conv_decode_step": 7}
+    assert "ragged-dot" not in hlo
+    # the decode kernel's output [slots, q heads, kv heads x head_dim]: 32
+    # heads of 64 lanes over a 512-lane K/V row
+    assert len(re.findall(
+        r"%closed_call[\w\-.]* = bf16\[128,32,512\]", hlo)) == 2
+    # the name the trace prints is the cell's own
+    op = cell["kernels"]["short_conv"]["op"]
+    assert op == "short_conv_decode_step-bf16_7_129_32_128"
+    assert len(re.findall(
+        r"%short_conv_decode_step[\w\-.]* = \(bf16\[7,129,32,128\]",
+        hlo)) == 7
+    assert not _conv_pool_moves(hlo, 129)
+    mem = exe.memory_analysis()
+    ring = 128 * 2 * 2 * slots * 512 * 2
+    pools = 2 * (planes.size + conv.size)
+    assert weights + pools + ring + mem.temp_size_in_bytes < 13.5e9
+    flush = runner._flush_ring.trace(
+        kv._replace(conv=None), spec((128, 2, 2, slots, 512), jnp.bfloat16),
+        spec((slots, maxb)), spec((slots,)),
+        spec((slots,))).lower(lowering_platforms=("tpu",)).compile()
+    assert flush.memory_analysis().alias_size_in_bytes == 2 * planes.size
+    # the refill step: the BlockSpec kernel an attention layer, the
+    # convolution as gather / convolve / scatter (no decode call), the
+    # experts in the grouped kernel at the 128-row tile
+    step = runner._step_greedy.trace(
+        params, kv, RaggedBatch(spec((4, 512)), spec((4,)), spec((4,)),
+                                spec((4, maxb)), spec((4,)))).lower(
+                                    lowering_platforms=("tpu",)).compile()
+    hlo = step.as_text()
+    assert Counter(_mosaic_call_names(hlo)) == {
+        "rg.attn_core": 2, "grouped_ffn_decode": 8}
+    assert "ragged-dot" not in hlo
+    assert step.memory_analysis().alias_size_in_bytes == pools
+    assert weights + pools + ring \
+        + step.memory_analysis().temp_size_in_bytes < 15.0e9

@@ -7,6 +7,7 @@ catches.
     chiprun -- python tools/chip_parity.py --config nemotron-3-nano-30b-a3b
     chiprun -- python tools/chip_parity.py --config mellum2-12b-a2.5b
     chiprun -- python tools/chip_parity.py --config minicpm-sala-9b [--prompt-blocks 128]
+    chiprun -- python tools/chip_parity.py --config lfm2-24b-a2b
     python tools/chip_parity.py --config solar-open2-250b --rehearse   # CPU, toy
 
 Outside any timed window. The model type's hooks come from
@@ -96,7 +97,16 @@ LOGIT_TOLS = {"kimi_linear": (0.15, 2.0),
               # 3.50, the float8 reference 2.34 in the median (my chip
               # runs, PR 48: the readings are in PERF.md); each limit lies
               # between its two readings
-              "mellum": (1.2, 2.0)}
+              "mellum": (1.2, 2.0),
+              # the same sharp attention draw over two softmax layers at
+              # 64-lane heads, and seven convolution mixers of deviation 1
+              # whose gates MULTIPLY a bfloat16 stream's rounding (B * u,
+              # C * conv), nine layers deep with every expert held: the
+              # engine reads 0.70 in the median and 2.22 at the worst of
+              # 128 positions, the float8 reference 3.05 in the median
+              # (my chip runs, PR 59); over a FLAT draw the same engine
+              # reads the first two families' figures (PERF.md section 6)
+              "lfm2_moe": (1.5, 4.0)}
 POSITIONS = 32
 SINGLE_BEFORE, FUSED = 22, 8
 #: by model type, where the default walk does not reach what the family
@@ -105,7 +115,10 @@ SINGLE_BEFORE, FUSED = 22, 8
 WALKS = {"mellum": {"prompt_blocks": 8, "fused": 128, "loops": 5},
          # two of the cell's 256-step loops: the second selects from
          # compressed keys and reads rows the first loop's flush wrote
-         "minicpm_sala": {"fused": 256, "loops": 2}}
+         "minicpm_sala": {"fused": 256, "loops": 2},
+         # two of the cell's 128-step loops: the rows after them read
+         # carried convolution inputs that passed two flushes
+         "lfm2_moe": {"fused": 128, "loops": 2}}
 
 #: the reference's own keyword for each wrong model, by model type
 VARIANTS = {
@@ -140,6 +153,16 @@ VARIANTS = {
         "plain_rotary_on_the_full_layers": {"yarn_on": False},
         "attention_factor_left_out": {"attention_factor_on": False},
         "head_norm_left_out": {"head_norm": False}},
+    "lfm2_moe": {
+        "silu_on_the_convolution": {"conv_silu": True},
+        "four_taps": {"conv_window": 4},
+        # the compared positions start at the prompt's last (a whole
+        # number of blocks less one); a loop of 128 steps later the first
+        # flush, and every 128 after it
+        "carried_inputs_zeroed_at_a_flush": {"conv_reset": "flush"},
+        "selection_by_the_unbiased_score": {"select_biased": False},
+        "renormalisation_left_out": {"renorm": False},
+        "rotary_paired_as_at_128_lanes": {"rope_pair_dim": 128}},
     "minicpm_sala": {
         "dense_attention_in_place_of_the_selection": {"selection": "dense"},
         "topk_32": {"topk": 32},
@@ -397,6 +420,9 @@ def main(argv=None) -> int:
         err = np.abs(served - ref).max(-1) / sigma
         params = float8_in_place(params)
         low = np.asarray(logits_fn()(params, toks, at), np.float32)
+        # the rounded tree goes before the weights are drawn again: two
+        # trees of 10.4 GB do not fit beside each other
+        jax.tree_util.tree_map(lambda x: x.delete(), params)
         params = mt.init_params(cfg, args.seed)
         tol, tol_worst = LOGIT_TOLS.get(dims["model_type"],
                                         (LOGIT_TOL, LOGIT_TOL_WORST))
@@ -442,6 +468,11 @@ def main(argv=None) -> int:
                 tree = params = float8_in_place(params)
             if "state_dtype" in wrong:
                 wrong["state_dtype"] = jnp.bfloat16
+            if wrong.get("conv_reset") == "flush":
+                # the compared positions are a stream's first n served:
+                # a loop of the cell's steps after the first, a flush
+                steps = cell["engine"]["decode_loop_steps"]
+                wrong["conv_reset"] = (T - n + steps, steps)
             lg = np.asarray(logits_fn(**wrong)(tree, toks, at), np.float32)
             result["variants"][name] = dict(
                 cell_rule(base, lg.argmax(-1)),
