@@ -229,3 +229,17 @@ def alibi_bias(num_heads: int, q_len: int, k_len: int) -> jnp.ndarray:
     pos_k = jnp.arange(k_len)[None, :]
     dist = (pos_q - pos_k).astype(jnp.float32)             # >=0 on causal side
     return (-slopes[None, :, None, None] * dist[None, None]).astype(jnp.float32)
+
+
+def layer_class(parent, block_cls, name: str, remat: bool, **remat_kw):
+    """The flax class ``parent`` instantiates for its child ``name``, ONE
+    layer: ``block_cls``, under ``nn.remat`` when the config asks. Under
+    the engine's ZeRO-3 seam the layer gathers its own sharded weights
+    INSIDE that remat boundary (``quantized_collectives.gathered_in_layer``),
+    so they are the block's temporaries in the forward and gathered again
+    by its recompute, not held from one to the other; anywhere else this
+    is ``nn.remat(block_cls)`` / ``block_cls`` as the models always wrote."""
+    import flax.linen as nn
+    from ..runtime.zero.quantized_collectives import gathered_in_layer
+    cls = gathered_in_layer(block_cls, parent, name)
+    return nn.remat(cls, **remat_kw) if remat else cls

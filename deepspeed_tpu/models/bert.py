@@ -98,9 +98,10 @@ class Bert(nn.Module):
             x = x + wtt(token_type_ids)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          name="embed_norm")(x)
-        layer_cls = nn.remat(BertLayer) if cfg.remat else BertLayer
+        from ._lm_utils import layer_class
         for i in range(cfg.num_layers):
-            x = layer_cls(cfg, name=f"layer_{i}")(x, attention_mask)
+            x = layer_class(self, BertLayer, f"layer_{i}",
+                            cfg.remat)(cfg, name=f"layer_{i}")(x, attention_mask)
         # MLM head: transform + tied decoder
         x = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
                      param_dtype=cfg.param_dtype, name="mlm_transform")(x)

@@ -102,9 +102,10 @@ class Bloom(nn.Module):
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype,
                          name="word_embeddings_layernorm")(x)
-        block_cls = nn.remat(BloomBlock) if cfg.remat else BloomBlock
+        from ._lm_utils import layer_class
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, name=f"layer_{i}")(x)
+            x = layer_class(self, BloomBlock, f"layer_{i}",
+                            cfg.remat)(cfg, name=f"layer_{i}")(x)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
                          param_dtype=cfg.param_dtype, name="ln_f")(x)
         if cfg.tie_embeddings:

@@ -129,9 +129,10 @@ class Falcon(nn.Module):
                          param_dtype=cfg.param_dtype, name="word_embeddings")
         from ._lm_utils import constrain_activations
         x = constrain_activations(embed(tokens))
-        block_cls = nn.remat(FalconBlock) if cfg.remat else FalconBlock
+        from ._lm_utils import layer_class
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, name=f"layer_{i}")(x)
+            x = layer_class(self, FalconBlock, f"layer_{i}",
+                            cfg.remat)(cfg, name=f"layer_{i}")(x)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
                          param_dtype=cfg.param_dtype, name="ln_f")(x)
         if cfg.tie_embeddings:

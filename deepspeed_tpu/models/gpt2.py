@@ -209,15 +209,14 @@ class GPT2(nn.Module):
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wte")
         wpe = nn.Embed(cfg.max_seq_len, cfg.hidden_size,
                        dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="wpe")
-        from ._lm_utils import constrain_activations
+        from ._lm_utils import constrain_activations, layer_class
         with region("embed"):
             x = wte(tokens) + wpe(jnp.arange(T)[None, :])
             # pin the embedding output to the natural activation layout
             # (shared helper — see _lm_utils.constrain_activations for why)
             x = constrain_activations(x)
-        block_cls = Block
+        policy = None
         if cfg.remat:
-            policy = None
             if cfg.remat_policy == "dots":
                 policy = jax.checkpoint_policies.checkpoint_dots
             elif cfg.remat_policy == "no_mlp":
@@ -249,9 +248,10 @@ class GPT2(nn.Module):
                 # near-zero repeated MXU work at ~2x the qkv_out residency
                 names = [n for n in cfg.remat_policy[5:].split(",") if n]
                 policy = jax.checkpoint_policies.save_only_these_names(*names)
-            block_cls = nn.remat(Block, static_argnums=(2,), policy=policy)
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, name=f"h_{i}")(x, deterministic)
+            x = layer_class(self, Block, f"h_{i}", cfg.remat,
+                            static_argnums=(2,), policy=policy)(
+                cfg, name=f"h_{i}")(x, deterministic)
         with region("head"):
             x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                              name="ln_f")(x)

@@ -129,9 +129,10 @@ class GPTNeoX(nn.Module):
                          param_dtype=cfg.param_dtype, name="embed_in")
         from ._lm_utils import constrain_activations
         x = constrain_activations(embed(tokens))
-        block_cls = nn.remat(GPTNeoXBlock) if cfg.remat else GPTNeoXBlock
+        from ._lm_utils import layer_class
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, name=f"layer_{i}")(x)
+            x = layer_class(self, GPTNeoXBlock, f"layer_{i}",
+                            cfg.remat)(cfg, name=f"layer_{i}")(x)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
                          param_dtype=cfg.param_dtype,
                          name="final_layer_norm")(x)
@@ -212,9 +213,10 @@ class GPTJ(nn.Module):
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="wte")
         x = embed(tokens)
-        block_cls = nn.remat(GPTJBlock) if cfg.remat else GPTJBlock
+        from ._lm_utils import layer_class
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, name=f"layer_{i}")(x)
+            x = layer_class(self, GPTJBlock, f"layer_{i}",
+                            cfg.remat)(cfg, name=f"layer_{i}")(x)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
                          param_dtype=cfg.param_dtype, name="ln_f")(x)
         if cfg.tie_embeddings:

@@ -244,9 +244,10 @@ class Llama(nn.Module):
         x = embed(tokens)
         from ._lm_utils import constrain_activations
         x = constrain_activations(x)
-        block_cls = nn.remat(LlamaBlock) if cfg.remat else LlamaBlock
+        from ._lm_utils import layer_class
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, name=f"layer_{i}")(x)
+            x = layer_class(self, LlamaBlock, f"layer_{i}",
+                            cfg.remat)(cfg, name=f"layer_{i}")(x)
         x = RMSNorm(cfg.rms_eps, jnp.float32, name="final_norm")(x)
         if return_hidden:
             # training loss path: the caller fuses the LM head into the

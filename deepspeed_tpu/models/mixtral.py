@@ -106,10 +106,11 @@ class Mixtral(nn.Module):
         x = embed(tokens)
         from ._lm_utils import constrain_activations
         x = constrain_activations(x)
-        block_cls = (nn.remat(MixtralBlock, static_argnums=(2,))
-                     if cfg.remat else MixtralBlock)
+        from ._lm_utils import layer_class
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, self.ep_mesh, name=f"layer_{i}")(x, train)
+            x = layer_class(self, MixtralBlock, f"layer_{i}",
+                            cfg.remat, static_argnums=(2,))(
+                cfg, self.ep_mesh, name=f"layer_{i}")(x, train)
         x = RMSNorm(cfg.rms_eps, jnp.float32, name="final_norm")(x)
         head = nn.Dense(cfg.vocab_size, dtype=jnp.float32,
                         param_dtype=cfg.param_dtype, use_bias=False,

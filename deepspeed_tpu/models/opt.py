@@ -114,9 +114,10 @@ class OPT(nn.Module):
         x = x + pos(jnp.arange(T) + cfg.POSITION_OFFSET)
         from ._lm_utils import constrain_activations
         x = constrain_activations(x)
-        block_cls = nn.remat(OPTBlock) if cfg.remat else OPTBlock
+        from ._lm_utils import layer_class
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, name=f"layer_{i}")(x)
+            x = layer_class(self, OPTBlock, f"layer_{i}",
+                            cfg.remat)(cfg, name=f"layer_{i}")(x)
         if cfg.do_layer_norm_before:                   # post-LN has no final
             x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
                              param_dtype=cfg.param_dtype,

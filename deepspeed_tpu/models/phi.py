@@ -109,9 +109,10 @@ class Phi(nn.Module):
                      param_dtype=cfg.param_dtype, name="embed_tokens")(tokens)
         from ._lm_utils import constrain_activations
         x = constrain_activations(x)
-        block_cls = nn.remat(PhiBlock) if cfg.remat else PhiBlock
+        from ._lm_utils import layer_class
         for i in range(cfg.num_layers):
-            x = block_cls(cfg, name=f"layer_{i}")(x)
+            x = layer_class(self, PhiBlock, f"layer_{i}",
+                            cfg.remat)(cfg, name=f"layer_{i}")(x)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
                          param_dtype=cfg.param_dtype,
                          name="final_layernorm")(x)

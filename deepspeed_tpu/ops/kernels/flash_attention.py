@@ -892,7 +892,7 @@ def sharded_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     Falls back to fewer sharded dims when sizes don't divide. q/k/v are
     (B, T, H, D) for layout="BTHD" (flax convention) or (B, H, T, D).
     """
-    from ...utils.jax_compat import shard_map
+    from ...utils.jax_compat import manual_axes, shard_map
     from jax.sharding import PartitionSpec as P
 
     if layout == "BTHD":
@@ -902,7 +902,12 @@ def sharded_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     else:
         raise ValueError(f"unknown layout {layout!r}")
 
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    # inside an engine's manual seam (a shard_map over ``data``) the batch
+    # is this rank's already: the kernel goes under a shard_map over the
+    # axes still automatic (a Mosaic call refuses a context with any)
+    manual = set(manual_axes())
+    sizes = {a: n for a, n in zip(mesh.axis_names, mesh.devices.shape)
+             if a not in manual}
     bat = tuple(a for a in batch_axes
                 if sizes.get(a, 1) > 1 and q.shape[b_dim] % sizes[a] == 0)
     bsz = int(np.prod([sizes[a] for a in bat])) if bat else 1
@@ -917,7 +922,7 @@ def sharded_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     spec[b_dim] = bat if bat else None
     spec[h_dim] = hd
     pspec = P(*spec)
-    if pspec == P(None, None, None, None):
+    if pspec == P(None, None, None, None) and not manual:
         return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                layout=layout, block_q=block_q,
                                block_k=block_k, interpret=interpret)
@@ -927,8 +932,10 @@ def sharded_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                                layout=layout, block_q=block_q,
                                block_k=block_k, interpret=interpret)
 
-    return shard_map(local, mesh=mesh, in_specs=(pspec, pspec, pspec),
-                     out_specs=pspec, check_vma=False)(q, k, v)
+    return shard_map(local, mesh=None if manual else mesh,
+                     in_specs=(pspec, pspec, pspec), out_specs=pspec,
+                     check_vma=False,
+                     axis_names=tuple(sizes) if manual else ())(q, k, v)
 
 
 def attention_reference(q, k, v, *, causal=True, sm_scale=None,

@@ -20,6 +20,7 @@ API parity (reference engine.py):
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 import time
@@ -209,11 +210,20 @@ class Engine:
                            "dispatch_s": 0.0, "device_wait_s": 0.0,
                            "commit_apply_s": 0.0, "step_exit_s": 0.0,
                            "flash_score_elems_computed": 0,
-                           "flash_score_elems_needed": 0}
+                           "flash_score_elems_needed": 0,
+                           "zero_manual_leaves": 0, "zero_held_leaves": 0,
+                           "zero_auto_leaves": 0}
         #: what ONE step's causal flash calls compute / need, from the
         #: plans noted while the step function was traced
         self._flash_elems = {"flash_score_elems_computed": 0,
                              "flash_score_elems_needed": 0}
+        #: what ONE step adds of these: sharded leaves the explicit seam
+        #: gathers inside their layer, those it gathers in front of the
+        #: model and holds through the step, and those left to the
+        #: partitioner (``_maybe_manual_micro_grads``, as the step's trace
+        #: showed them)
+        self._zero_leaves = {"zero_manual_leaves": 0, "zero_held_leaves": 0,
+                             "zero_auto_leaves": 0}
         self._spans = SpanSet(self.step_stats, lambda: self._train_obs)
         self._train_obs = train_observer(self)
 
@@ -670,30 +680,55 @@ class Engine:
         )
 
     def _maybe_manual_micro_grads(self, default_fn):
-        """ZeRO++ (qwZ/qgZ): swap the micro-grad computation for a manual
-        shard_map over the data axis with quantized gather / reduce-scatter
-        collectives (see runtime/zero/quantized_collectives.py). Under plain
-        pjit those collectives are XLA-placed and always full-precision, so
-        comm compression requires the manual seam."""
+        """Stage 3 over ``data``: swap the micro-grad computation for a
+        manual shard_map over the data axis whose collectives the program
+        writes (runtime/zero/quantized_collectives.py): a sharded leaf is
+        gathered by an ``all_gather`` and its gradient leaves the backward
+        through that gather's transpose, a ``psum_scatter``, already a
+        shard. Under plain pjit the partitioner places them, always at
+        full precision (so ZeRO++'s qwZ / qgZ need this seam) and, on the
+        v5e, three of a layer's four backward re-gathers synchronous
+        (zero/sharding.py says what the compiled step showed).
+        Stages 0-2, one device, hpZ / MiCS inner axes, a seq-fused zero
+        axis, ``pipe`` and streamed parameters keep the declarative path,
+        and so does a model none of whose layers gathers its own weights
+        (``models/_lm_utils.layer_class``), seen when the step is traced:
+        the seam would hold every gathered weight from its forward use to
+        its backward one, which is ZeRO-2's residency (qwZ / qgZ keep the
+        seam there, as they always had it)."""
         cfg = self.config
         zcfg = cfg.zero_optimization
-        if not (zcfg.zero_quantized_weights or zcfg.zero_quantized_gradients):
-            return default_fn
         plan = self.zero_plan
+        quantized = zcfg.zero_quantized_weights or \
+            zcfg.zero_quantized_gradients
+        pspecs = plan.param_specs(self.state.params)
+        sharded = sum(
+            any(a in plan.param_axes
+                for e in spec for a in (e if isinstance(e, tuple) else (e,)))
+            for spec in jax.tree_util.tree_leaves(
+                pspecs, is_leaf=lambda x: isinstance(x, P)))
+        declarative = {"zero_manual_leaves": 0, "zero_held_leaves": 0,
+                       "zero_auto_leaves": sharded}
+        self._zero_leaves = declarative
         if plan.stage < 3:
-            logger.warning("ZeRO++ quantized collectives require stage 3; "
-                           "ignoring zero_quantized_weights/gradients")
+            if quantized:
+                logger.warning(
+                    "ZeRO++ quantized collectives require stage 3; "
+                    "ignoring zero_quantized_weights/gradients")
             return default_fn
         if self.topology.axis_size("data") <= 1 or \
-                set(plan.param_axes) - {"data"}:
-            logger.warning(
-                "ZeRO++ quantized collectives need params sharded over the "
-                "'data' axis (dp>1, no seq-fused or hpZ/MiCS inner sharding); "
-                "falling back to automatic collectives")
+                set(plan.param_axes) - {"data"} or plan.pipe_axes or \
+                self._stream_params:
+            if quantized:
+                logger.warning(
+                    "ZeRO++ quantized collectives need params sharded over "
+                    "the 'data' axis (dp>1, no seq-fused or hpZ/MiCS inner "
+                    "sharding); falling back to automatic collectives")
             return default_fn
 
         from .zero.quantized_collectives import (
-            prep_params, shard_map, strip_to_manual)
+            LayerGathers, layers_gather_their_own, prep_params, shard_map,
+            strip_to_manual)
 
         mesh = self.topology.mesh
         manual_axes = ("data",)
@@ -704,21 +739,30 @@ class Engine:
         compute_dtype = self.compute_dtype
         accum_dtype = self._grad_accum_dtype
 
-        pspecs = plan.param_specs(self.state.params)
         in_pspecs = jax.tree_util.tree_map(
             lambda s, p: strip_to_manual(s, manual_axes, np.ndim(p)),
             pspecs, self.state.params, is_leaf=lambda x: isinstance(x, P))
+        same = jnp.dtype(compute_dtype)
 
-        def local_fn(p_local, mb_local, rng, scale_state, step):
+        def cast(x):
+            if x.dtype == same:             # no equation to differentiate
+                return x
+            with region("optimizer"):       # the compute-dtype copy
+                return cast_floating(x, compute_dtype)
+
+        def local_fn(book, p_local, mb_local, rng, scale_state, step):
             # distinct dropout/noise masks per DP rank (the automatic path
             # draws masks over the global batch; fold_in restores that)
             rng = jax.random.fold_in(rng, jax.lax.axis_index(manual_axes))
 
             def scaled_loss(pl):
-                pfull = prep_params(pl, pspecs, manual_axes, world,
-                                    wbits, gbits)
-                cp = cast_floating(pfull, compute_dtype)
-                loss, _aux = self._loss_and_aux(cp, mb_local, rng, step)
+                # the whole tree in front of the model; a layer built
+                # through ``gathered_in_layer`` gathers its own again
+                # inside itself, and its leaves here are dead code
+                cp = prep_params(pl, pspecs, manual_axes, world, wbits,
+                                 gbits, cast, book)
+                with layers_gather_their_own(book):
+                    loss, _aux = self._loss_and_aux(cp, mb_local, rng, step)
                 # each rank owns 1/world of the batch: sum over ranks of
                 # loss/world == the global-mean objective of automatic mode
                 obj = loss / world
@@ -728,19 +772,46 @@ class Engine:
             grad_fn = jax.value_and_grad(scaled_loss, has_aux=True)
             (_scaled, local_loss), grads = grad_fn(p_local)
             loss = jax.lax.pmean(local_loss, manual_axes)
-            grads = jax.tree_util.tree_map(
-                lambda g: g.astype(accum_dtype), grads)
+            with region("grad_clip"):
+                grads = jax.tree_util.tree_map(
+                    lambda g: g.astype(accum_dtype), grads)
             return loss, grads
 
-        sm = shard_map(
-            local_fn, mesh,
-            in_specs=(in_pspecs, P(manual_axes), P(), P(), P()),
-            out_specs=(P(), in_pspecs),
-            axis_names=manual_axes)
-        log_dist(
-            f"ZeRO++ manual collectives: qwZ={'int8' if wbits else 'off'}, "
-            f"qgZ={'int8' if gbits else 'off'} over data={world}")
-        return sm
+        declined = []       # once a trace has shown no layer taking its own
+
+        def micro_grads(*args):
+            if declined:
+                return default_fn(*args)
+            book = LayerGathers()
+            out = shard_map(
+                functools.partial(local_fn, book), mesh,
+                in_specs=(in_pspecs, P(manual_axes), P(), P(), P()),
+                out_specs=(P(), in_pspecs), axis_names=manual_axes)(*args)
+            (took, inside), (held, held_bytes) = book.tally()
+            if not took and not quantized:
+                logger.warning(
+                    f"ZeRO-3 over data={world}: no layer of this model "
+                    f"gathers its own weights (models/_lm_utils.layer_class)"
+                    f", so explicit collectives would hold all {held} "
+                    f"sharded leaves gathered ({held_bytes / 1e6:.0f} MB) "
+                    f"from their forward use to their backward one; "
+                    f"keeping the partitioner's collectives")
+                declined.append(True)
+                return default_fn(*args)      # the seam's trace is dead code
+            self._zero_leaves = {"zero_manual_leaves": took,
+                                 "zero_held_leaves": held,
+                                 "zero_auto_leaves": 0}
+            (logger.warning if held_bytes > inside else log_dist)(
+                f"ZeRO-3 manual collectives over data={world}: {took} "
+                f"sharded leaves gathered inside their layer "
+                f"({inside / 1e6:.0f} MB a pass, a layer's at a time), "
+                f"{held} in front of the model ({held_bytes / 1e6:.0f} MB "
+                f"held through the step), 0 left to the partitioner; "
+                f"qwZ={'int8' if wbits else 'off'}, "
+                f"qgZ={'int8' if gbits else 'off'}")
+            return out
+
+        return micro_grads
 
     def _maybe_onebit_grads(self, micro_grads):
         """1-bit optimizers: run the whole grad-accumulation loop in a manual
@@ -905,7 +976,8 @@ class Engine:
                 with spans.span("train/dispatch", step=step) as span:
                     _take_flash_plans()     # another program's, traced since
                     self.state, metrics = self._train_step(self.state, batch)
-                    span.count(**self._flash_score_elems())
+                    span.count(**self._flash_score_elems(),
+                               **self._zero_leaves)
                 # the exposed device wait, with one step queued behind it:
                 # the PREVIOUS step's metrics, which the observer's sentinel
                 # then reads as ready values (nothing to wait for without

@@ -1,4 +1,4 @@
-"""ZeRO++ quantized collectives — manual-mode qwZ / qgZ.
+"""ZeRO-3's explicit collectives, and ZeRO++'s quantized ones (qwZ / qgZ).
 
 Capability parity with the reference's ZeRO++ comm compression
 (``runtime/zero/partition_parameters.py`` CUDAQuantizer allgather path for
@@ -6,22 +6,40 @@ quantized weights, ``runtime/comm/coalesced_collectives.py:31``
 ``all_to_all_quant_reduce`` for quantized gradients, kernels in
 ``csrc/quantization/`` — SURVEY.md §2.3 "ZeRO++ features" row).
 
-Design. Under plain pjit, ZeRO's gather/reduce collectives are placed by XLA
-and always run at full precision — there is no seam to compress them. So
-ZeRO++ runs the micro-gradient computation in **manual mode**: a
+Design. Under plain pjit, ZeRO's gather/reduce collectives are placed by XLA:
+always at full precision, and on the v5e (the compiled step, PERF.md section
+5, PR 60) with most of a layer's backward gathers synchronous. So a stage-3
+step over ``data`` runs its micro-gradient computation in **manual mode**: a
 ``shard_map`` over the ``data`` axis (all other mesh axes stay automatic),
 inside which
 
-  - every data-sharded param shard goes through :func:`gather_param` — a
-    per-device custom-VJP whose forward is an int8/int4 ``all_gather``
-    (**qwZ**) and whose backward is a quantized all-to-all + local
-    dequant-sum reduce-scatter (**qgZ**, the reference's single-hop
-    dequant-reduce-requant schedule) or a plain ``psum_scatter``;
-  - replicated params go through :func:`replicate_param`, whose backward is
-    the DP-grad ``psum`` the automatic partitioner would have inserted.
+  - every data-sharded param shard goes through a per-device custom-VJP
+    (:func:`_make_param_gather`) whose forward is an ``all_gather`` (int8 /
+    int4 under **qwZ**) and whose backward is a ``psum_scatter``, so the
+    gradient leaves the backward already a shard (under **qgZ** a quantized
+    all-to-all + local dequant-sum, the reference's single-hop
+    dequant-reduce-requant schedule);
+  - replicated params go through :func:`_make_replicated_prep`, whose
+    backward is the DP-grad ``psum`` the automatic partitioner would have
+    inserted.
+
+Stage 3's residency (a layer's gathered weights do not outlive the layer)
+is the model's to keep, with the seam's help. :func:`prep_params` gathers
+the whole tree in front of the model: correct for any loss function, but a
+leaf gathered there lives from its forward use to its backward one (it is
+an input of the model's remat blocks), which is ZeRO-2's residency. A model
+that builds a layer through :func:`gathered_in_layer` has each leaf that
+ARRIVES at the layer as the seam's own gathered value (by identity: the
+seam's :class:`LayerGathers` knows what it made) gathered again from this
+rank's shard INSIDE the layer, inside its remat boundary, so the backward
+gathers once more, as the partitioner's program did; the gather in front
+then feeds nothing and is dropped as dead code. A leaf the loss function
+made something else of first (compression, ``stop_gradient``) is left as it
+arrives. The seam counts what the layers took, and the engine keeps the
+declarative step for a model whose layers took nothing.
 
 This is also the framework's manual-collective escape hatch (SURVEY.md §7
-hard part 1) — the same seam serves explicit comm scheduling at scale.
+hard part 1).
 
 Quantization granularity is a per-row (last-dim) symmetric scale; int4 packs
 two nibbles per byte when the row length is even.
@@ -29,6 +47,7 @@ two nibbles per byte when the row length is even.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional, Sequence, Tuple
 
@@ -36,9 +55,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-
-from ...ops.kernels.quantization import (
-    pack_int4, sym_quantize_rowwise, unpack_int4)
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=()):
@@ -53,7 +69,13 @@ def shard_map(f, mesh, in_specs, out_specs, axis_names=()):
 # --------------------------------------------------------------------------- #
 
 
+# the quantization kernels are imported where qwZ / qgZ use them: every
+# model's layer loop imports this module (``gathered_in_layer``), and the
+# kernels package costs a second of start-up
+
+
 def _quant_for_comm(x: jnp.ndarray, bits: int):
+    from ...ops.kernels.quantization import pack_int4, sym_quantize_rowwise
     q, scale = sym_quantize_rowwise(x, bits)
     packed = bits == 4 and x.shape[-1] % 2 == 0
     if packed:
@@ -63,6 +85,7 @@ def _quant_for_comm(x: jnp.ndarray, bits: int):
 
 def _dequant_from_comm(q, scale, packed, dtype):
     if packed:
+        from ...ops.kernels.quantization import unpack_int4
         q = unpack_int4(q)
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
@@ -70,6 +93,16 @@ def _dequant_from_comm(q, scale, packed, dtype):
 # --------------------------------------------------------------------------- #
 # per-device collectives (to be used INSIDE shard_map manual regions)
 # --------------------------------------------------------------------------- #
+
+
+def _summed(reduce, ct):
+    """``reduce(ct)``, a sum over ranks. XLA:CPU promotes a 16-bit
+    all-reduce to float32 itself and aborts on the reducer a partial-manual
+    shard_map lowers (its root is a sharding annotation, not the add), so
+    there the promotion is written here: the same arithmetic."""
+    if jax.default_backend() == "cpu" and ct.dtype.itemsize < 4:
+        return reduce(ct.astype(jnp.float32)).astype(ct.dtype)
+    return reduce(ct)
 
 
 @functools.lru_cache(maxsize=None)
@@ -98,8 +131,8 @@ def _make_param_gather(dim: int, axes: Tuple[str, ...], world: int,
 
     def _reduce_scatter(ct):
         if grad_bits is None:
-            return jax.lax.psum_scatter(ct, axes, scatter_dimension=dim,
-                                        tiled=True)
+            return _summed(lambda c: jax.lax.psum_scatter(
+                c, axes, scatter_dimension=dim, tiled=True), ct)
         shape = ct.shape
         chunk = shape[dim] // world
         parts = jnp.moveaxis(
@@ -130,7 +163,8 @@ def _make_replicated_prep(axes: Tuple[str, ...]):
         return x
 
     prep.defvjp(lambda x: (x, None),
-                lambda _, ct: (jax.lax.psum(ct, axes),))
+                lambda _, ct: (_summed(
+                    lambda c: jax.lax.psum(c, axes), ct),))
     return prep
 
 
@@ -176,10 +210,18 @@ def strip_to_manual(spec: Optional[P], manual_axes: Sequence[str],
 
 
 def prep_params(params_local, specs, manual_axes: Tuple[str, ...], world: int,
-                weight_bits: Optional[int], grad_bits: Optional[int]):
+                weight_bits: Optional[int], grad_bits: Optional[int],
+                cast=None, book: Optional["LayerGathers"] = None):
     """Inside the manual region: gather every sharded param (qwZ fwd / qgZ
     bwd) and attach the DP-psum backward to replicated ones. Returns the
-    full-parameter tree the model computes with."""
+    full-parameter tree the model computes with, each leaf through ``cast``
+    (the engine's cast to its compute dtype). Full-precision collectives
+    come AFTER the cast: the links carry the compute dtype's bytes, as in
+    the partitioner's program. qwZ / qgZ come BEFORE it, as they always
+    did: qwZ quantizes the parameter's own values and qgZ's reduction runs
+    in the parameter's dtype. ``book`` is told of every gathered leaf."""
+    cast = cast or (lambda x: x)
+    quantized = weight_bits is not None or grad_bits is not None
 
     def leaf(x, spec):
         entry = _manual_entry(spec if isinstance(spec, P) else None,
@@ -189,9 +231,107 @@ def prep_params(params_local, specs, manual_axes: Tuple[str, ...], world: int,
                 f"param dim sharded over manual+auto axes jointly ({spec}); "
                 "ZeRO++ manual mode requires zero axes on their own dim")
         if entry is None:
-            return _make_replicated_prep(manual_axes)(x)
-        dim, axes = entry
-        return _make_param_gather(dim, axes, world, weight_bits, grad_bits)(x)
+            collect = _make_replicated_prep(manual_axes)
+        else:
+            dim, axes = entry
+            collect = _make_param_gather(dim, axes, world, weight_bits,
+                                         grad_bits)
+
+        def full():
+            return cast(collect(x)) if quantized else collect(cast(x))
+
+        out = full()
+        if book is not None and entry is not None:
+            book.note(out, full)
+        return out
 
     return jax.tree_util.tree_map(
         leaf, params_local, specs, is_leaf=lambda s: isinstance(s, P))
+
+
+# --------------------------------------------------------------------------- #
+# a layer's own gathers (stage 3's residency under the seam)
+# --------------------------------------------------------------------------- #
+
+
+class LayerGathers:
+    """The seam's book of ONE trace of the loss: which leaf of the tree
+    the loss function was given is the result of which gather, so that a
+    layer can run that gather again inside itself
+    (:func:`gathered_in_layer`), and which leaves a layer did."""
+
+    def __init__(self):
+        self._made = {}         # id(leaf) -> (leaf, the gather that made it)
+        self.in_layers = set()  # ids of the leaves a layer gathered itself
+
+    def note(self, leaf, gather):
+        self._made[id(leaf)] = (leaf, gather)
+
+    def gather_of(self, leaf):
+        """The gather that made ``leaf``; None for anything else: a value
+        the loss function computed from one (compression's quantized
+        weights, a ``stop_gradient``) is not the engine's to gather."""
+        made = self._made.get(id(leaf))
+        return made[1] if made is not None and made[0] is leaf else None
+
+    def tally(self):
+        """(leaves, bytes) gathered inside a layer, and (leaves, bytes)
+        held from the forward to the backward: gathered in front of the
+        model and not again."""
+        size = {k: leaf.size * leaf.dtype.itemsize
+                for k, (leaf, _) in self._made.items()}
+        inside = sum(size[k] for k in self.in_layers)
+        return (len(self.in_layers), inside), \
+            (len(size) - len(self.in_layers), sum(size.values()) - inside)
+
+
+#: the book of the seam that is tracing its loss right now; None outside one
+_OPEN: Optional[LayerGathers] = None
+
+
+@contextlib.contextmanager
+def layers_gather_their_own(book: LayerGathers):
+    """Held open by the seam around its loss function, for
+    :func:`gathered_in_layer` to read: a flax module is constructed inside
+    the model, where no argument of the engine's reaches."""
+    global _OPEN
+    prev, _OPEN = _OPEN, book
+    try:
+        yield
+    finally:
+        _OPEN = prev
+
+
+def gathered_in_layer(module_cls, parent, name: str):
+    """For a model: the flax class to instantiate for the child ``name`` of
+    ``parent``, ONE layer. Under the seam, ``module_cls`` with each leaf
+    that arrives as the seam's own gathered value gathered AGAIN from this
+    rank's shard where the layer reads it (``nn.map_variables``: wrap
+    ``nn.remat`` AROUND this, so that the gathered leaf is the remat
+    block's temporary and not its input; the gather in front of the model
+    then feeds nothing and is dropped as dead code). Anywhere else, and
+    for every other leaf, ``module_cls`` itself and the values that
+    arrive."""
+    book = _OPEN
+    if book is None:
+        return module_cls
+    import flax.linen as nn
+    from flax.traverse_util import flatten_dict, unflatten_dict
+
+    arriving = parent.variables.get("params", {}).get(name)
+    again = {} if arriving is None else {
+        key: book.gather_of(leaf)
+        for key, leaf in flatten_dict(arriving).items()}
+    again = {key: g for key, g in again.items() if g is not None}
+    if not again:
+        return module_cls
+    book.in_layers.update(
+        id(leaf) for key, leaf in flatten_dict(arriving).items()
+        if key in again)
+
+    def gather(variables):
+        return {"params": unflatten_dict({
+            key: again[key]() if key in again else leaf
+            for key, leaf in flatten_dict(variables["params"]).items()})}
+
+    return nn.map_variables(module_cls, "params", trans_in_fn=gather)

@@ -1,18 +1,39 @@
-"""ZeRO as declarative sharding.
+"""ZeRO as declarative sharding, with stage 3's collectives written out.
 
 The reference implements ZeRO imperatively: flattened partitions, gradient
 hooks, bucketed reduce-scatter, a parameter coordinator with trace-driven
 prefetch (``runtime/zero/stage_1_and_2.py``, ``stage3.py``,
 ``partitioned_param_coordinator.py`` — ~11k LoC). On TPU the same memory
-states are *sharding declarations* over the ``data`` (× ``seq``) mesh axes,
-and XLA's SPMD partitioner schedules the all-gathers/reduce-scatters that the
-reference hand-manages on side streams:
+states are *sharding declarations* over the ``data`` (× ``seq``) mesh axes:
 
   stage 0 — params/grads/opt-state replicated; grad psum (plain DP)
   stage 1 — optimizer state sharded over data     (opt-state partitioning)
-  stage 2 — + gradients constrained to the same shards (reduce-scatter)
-  stage 3 — + parameters sharded; XLA inserts per-layer all-gathers
-            (the coordinator's prefetch/release becomes compiler scheduling)
+  stage 2 — + gradients constrained to the same shards
+  stage 3 — + parameters sharded; every layer's weights are gathered for
+            its forward and again for its backward
+
+WHO places the collectives. For stages 1-2 XLA's SPMD partitioner does, from
+the declarations alone. For stage 3 over ``data`` the engine's step computes
+its micro-gradients inside an explicit ``shard_map`` (``Engine.
+_maybe_manual_micro_grads``, ``quantized_collectives.py``): the program
+writes each leaf's ``all_gather`` and the gradient leaves the backward
+through its transpose, a ``psum_scatter``. What the partitioner made of the
+declarations alone on a v5e:2x2 (the compiled step of PR 60, PERF.md section
+5): a layer's gradients as the TPU's fused all-reduce-scatter kernels (a
+shard comes out, but the TensorCore waits for each), and THREE of a layer's
+four backward re-gathers as synchronous all-gathers, ~91 MB a layer with
+nothing else running; "the coordinator's prefetch/release becomes compiler
+scheduling" held for the forward alone. Written out, the backward's
+re-gathers ride asynchronous fusions but for the first one a layer's
+recompute needs. What STILL relies on the partitioner for stage 3: the
+meshes the seam refuses (hpZ / MiCS: parameters over ``data_inner``; a
+``seq``-fused zero axis; ``pipe`` > 1; streamed, pinned-host parameters),
+and any model none of whose layers gathers its own weights
+(``models/_lm_utils.layer_class``: GPT-Neo, CLIP, the diffusion models, a
+caller's own ``loss_fn``), because the seam alone would hold every gathered
+weight from its forward use to its backward one; the engine sees that when
+it traces the step, says so and keeps this module's step. The declarations
+below serve both.
 
 `stage3_param_persistence_threshold` keeps small params replicated, exactly
 like the reference's persistent-parameter set (stage3.py persistence logic).
@@ -284,7 +305,10 @@ class ZeroShardingPlan:
 
     def constrain_grads(self, grads: Any, params: Any) -> Any:
         """Apply with_sharding_constraint to gradients inside jit (stage>=2:
-        forces the DP reduction to materialize as reduce-scatter shards)."""
+        the DP reduction's result is a shard; on the v5e the partitioner
+        makes it the fused all-reduce-scatter kernel. Behind stage 3's
+        explicit seam the large leaves arrive as shards already and this
+        slices the replicated small ones)."""
         specs = self.grad_specs(params)
         return jax.tree_util.tree_map(
             lambda g, s: jax.lax.with_sharding_constraint(g, NamedSharding(self.topo.mesh, s)),

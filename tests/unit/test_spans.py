@@ -457,10 +457,13 @@ class TestTrainBrackets:
         st = eng.step_stats
         assert st["steps"] == 3
         flash = {"flash_score_elems_computed", "flash_score_elems_needed"}
+        # sharded leaves by who writes their collectives: none at stage 0
+        zero = {"zero_manual_leaves", "zero_held_leaves", "zero_auto_leaves"}
         assert set(st) == {"steps", "train_batch_s", "stage_s",
                            "dispatch_s", "device_wait_s", "commit_apply_s",
-                           "step_exit_s"} | flash
-        assert all(st[k] > 0.0 for k in set(st) - flash)
+                           "step_exit_s"} | flash | zero
+        assert all(st[k] > 0.0 for k in set(st) - flash - zero)
+        assert all(st[k] == 0 for k in zero)
         names = [s[0] for s in eng._train_obs.flight.spans]
         assert names == ["stage", "dispatch", "device_execute",
                          "commit_apply"] * 3

@@ -13,6 +13,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -1167,3 +1168,139 @@ def test_lfm2_loop_flush_and_refill_compile_at_128_clients(one_chip,
     assert step.memory_analysis().alias_size_in_bytes == pools
     assert weights + pools + ring \
         + step.memory_analysis().temp_size_in_bytes < 15.0e9
+
+
+# --------------------------------------------------------------------------- #
+# the train engine's ZeRO-3 step over the four chips of a v5e:2x2 (ISSUE 60)
+# --------------------------------------------------------------------------- #
+
+
+def _collectives(hlo):
+    """(kind, how, result elements) of every weight-sized (a million
+    elements and more) collective the ENTRY computation of a scheduled
+    TPU text runs. ``how``: ``sync`` an instruction of the entry
+    computation itself (the TensorCore waits for it), ``kernel`` the TPU's
+    fused all-reduce-scatter (a reduce-scatter: its result is a shard;
+    synchronous as well), ``async`` inside an asynchronous collective
+    fusion (counted once, at the fusion that starts it)."""
+    import re
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            cur = "ENTRY" if head.group(1) else head.group(2)
+            comps[cur] = []
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+
+    def elements(text):
+        return max((int(np.prod([int(d) for d in dims.split(",") if d]))
+                    for dims in re.findall(r"\w+\[([\d,]*)\]", text)),
+                   default=0)
+
+    op = re.compile(r"= (.*?) (all-reduce|all-gather|reduce-scatter)"
+                    r"(-start)?\(")
+    inner = {name: [m for m in map(op.search, lines) if m]
+             for name, lines in comps.items() if name != "ENTRY"}
+    out = []
+    for line in comps["ENTRY"]:
+        m = op.search(line)
+        if m:
+            out.append((m.group(2), "async" if m.group(3) else "sync",
+                        elements(m.group(1))))
+            continue
+        call = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+        for m in inner.get(call.group(1), []) if call else []:
+            callee = call.group(1)
+            if callee.startswith("all-reduce-scatter"):
+                out.append(("reduce-scatter", "kernel",
+                            elements(line.split(" fusion(")[0])))
+            elif callee.startswith("async_collective_fusion"):
+                out.append((m.group(2), "async", elements(m.group(1))))
+    return [c for c in out if c[2] >= 1_000_000]
+
+
+def test_the_zero3_step_compiles_with_its_collectives_written_out(
+        monkeypatch):
+    """Two layers of the cell's model (benchmark/configs/gpt-1p3b.json at
+    a cut vocabulary) through the ENGINE's own stage-3 step builder, for
+    the four chips of a v5e:2x2, micro-batch 2 x 2048 a chip: the flash
+    kernels compile inside the seam (a Mosaic call refuses a context with
+    an automatic axis), every sharded leaf's gradient is a reduce-scatter
+    (no weight-sized all-reduce in the entry computation), and the count
+    of SYNCHRONOUS weight-sized gathers and reduce-scatters is what
+    PERF.md section 5 (PR 60) reads: a later change that folds an
+    asynchronous one back fails here, with no chip."""
+    import functools
+
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding
+
+    import deepspeed_tpu as dstpu
+    import deepspeed_tpu.ops.kernels as kernels
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.models.gpt2 import GPT2Config, make_model
+    from deepspeed_tpu.runtime.engine import Engine
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda *a: 4)
+    # the state stays where it was made: nothing can be put on a chip
+    # that is only described
+    monkeypatch.setattr(Engine, "_place_state", lambda self, state: state)
+
+    layers = 2
+    cfg = GPT2Config(vocab_size=8192, max_seq_len=2049, num_layers=layers,
+                     num_heads=16, hidden_size=2048, mlp_ratio=4,
+                     param_dtype=jnp.bfloat16, remat=True,
+                     remat_policy="qkv_out", flash_block_q=1024,
+                     flash_block_k=1024)
+    _, init_fn, loss_fn = make_model(cfg)
+    params = jax.jit(functools.partial(init_fn, batch_size=1, seq_len=64))(
+        jax.random.PRNGKey(0))
+    mesh = {"data": 4}
+    engine, _, _, _ = dstpu.initialize(
+        loss_fn=loss_fn, params=params,
+        topology=dstpu.build_mesh(MeshConfig(**mesh), devices=topo.devices),
+        config={"train_micro_batch_size_per_gpu": 2,
+                "gradient_accumulation_steps": 1, "bf16": {"enabled": True},
+                "data_types": {"grad_accum_dtype": "bfloat16"},
+                "gradient_clipping": 1.0, "steps_per_print": 1000000,
+                "optimizer": {"type": "AdamW", "params": {
+                    "lr": 3e-4, "moment_dtype": "bfloat16"}},
+                "zero_optimization": {"stage": 3}, "mesh": mesh})
+    state = jax.tree_util.tree_map(
+        lambda x, s: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=s),
+        engine.state, engine._state_shardings)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (engine.config.train_batch_size, 2049), jnp.int32,
+        sharding=engine.topology.batch_sharding())}
+    hlo = engine._train_step.trace(state, batch).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+
+    assert set(_mosaic_call_names(hlo)) == {"attn"}
+    found = _collectives(hlo)
+    kinds = {}
+    for kind, how, _ in found:
+        kinds[kind, how] = kinds.get((kind, how), 0) + 1
+    # a gradient leaves as a shard: four kernels a layer and the token
+    # embedding, none as an all-reduce of the leaf. The one all-reduce is
+    # the position table's: 2049 rows are no whole number of sublane
+    # tiles, and the compiler legalizes that reduce-scatter into an
+    # all-reduce (8 MB a step, combined with the biases' and norms' psums)
+    assert [c for c in found if c[0] == "all-reduce"] \
+        == [("all-reduce", "sync", 2049 * 2048)], found
+    assert kinds.get(("reduce-scatter", "sync"), 0) \
+        + kinds.get(("reduce-scatter", "kernel"), 0) == 4 * layers + 1, kinds
+    # the backward's re-gathers ride asynchronous fusions but for the
+    # recompute's c_fc, the first weight a layer's backward needs (the
+    # parent: three a layer in the backward and one in the forward); in
+    # front of the model wte, wpe and the first layer's c_attn
+    assert kinds.get(("all-gather", "sync"), 0) == layers + 3, kinds
+    assert kinds.get(("all-gather", "async"), 0) >= 7 * layers - 1, kinds
