@@ -183,10 +183,8 @@ class LlamaAttention(nn.Module):
                     and jax.device_count() == 1 else "xla")
         if impl == "flash":
             from deepspeed_tpu.ops.kernels import flash_attention
-            y = flash_attention(q, k, v, causal=True, layout="BTHD")
-            if cfg.sliding_window is not None and T > cfg.sliding_window:
-                raise NotImplementedError(
-                    "sliding window not yet supported on the flash path")
+            y = flash_attention(q, k, v, causal=True,
+                                window=cfg.sliding_window, layout="BTHD")
         elif impl == "xla":
             if KV != H:
                 k = jnp.repeat(k, H // KV, axis=2)

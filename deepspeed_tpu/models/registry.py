@@ -39,6 +39,9 @@ from .pangu_ultra_moe import PanguUltraMoE, PanguUltraMoEConfig
 from .pangu_ultra_moe import make_model as make_pangu_ultra_moe
 from .kimi_linear import KimiLinear, KimiLinearConfig
 from .kimi_linear import make_model as make_kimi_linear
+from .afmoe import LAYER_TYPES as AFMOE_LAYER_TYPES
+from .afmoe import Afmoe, AfmoeConfig
+from .afmoe import make_model as make_afmoe
 from .lfm2 import LAYER_TYPES as LFM2_LAYER_TYPES
 from .lfm2 import Lfm2, Lfm2Config
 from .lfm2 import make_model as make_lfm2
@@ -568,6 +571,62 @@ def _entry_mellum(d):
         router_aux_loss_coef=0.0)
 
 
+def _entry_afmoe(d):
+    """AFMoE (arcee-ai/Trinity-Mini, -Nano): ``layer_types`` says which
+    layers attend inside ``sliding_window`` (rotary) and which in full (no
+    position code); the first ``num_dense_layers`` layers have a dense
+    feed-forward, the others ``num_experts`` experts top-k by a sigmoid
+    score plus a selection bias, renormalised (``route_norm``) and scaled
+    (``route_scale``), beside ``num_shared_experts``; the embedding times
+    ``sqrt(hidden_size)`` (``mup_enabled``); the head untied. What the
+    module has no form for is refused by name: expert groups, a router
+    score other than the sigmoid, a scaled rotary code, a tied head."""
+    n = d.get("num_hidden_layers", 32)
+    types = d.get("layer_types") or ["full_attention"] * n
+    bad = sorted(set(types) - set(AFMOE_LAYER_TYPES))
+    if bad or len(types) != n:
+        raise ValueError(
+            f"afmoe layer_types must name num_hidden_layers ({n}) layers "
+            f"of {sorted(AFMOE_LAYER_TYPES)}; got {len(types)} with {bad}")
+    groups = {k: d.get(k, 1) for k in ("n_group", "topk_group",
+                                        "num_expert_groups",
+                                        "num_limited_groups")}
+    if set(groups.values()) != {1}:
+        raise ValueError(f"afmoe configs with expert groups are not "
+                         f"supported (one group of all experts): {groups}")
+    if d.get("score_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"afmoe score_func {d['score_func']!r} is not "
+                         f"supported ('sigmoid')")
+    if d.get("rope_scaling"):
+        raise ValueError("afmoe configs with rope_scaling set are not "
+                         "supported (the published one has none)")
+    if d.get("tie_word_embeddings", False):
+        raise ValueError("afmoe configs with a tied head are not supported "
+                         "(the published one is untied)")
+    hidden = d.get("hidden_size", 2048)
+    heads = d.get("num_attention_heads", 32)
+    return AfmoeConfig(
+        vocab_size=d.get("vocab_size", 200192),
+        max_seq_len=d.get("max_position_embeddings", 131072) + 1,
+        hidden_size=hidden, num_heads=heads,
+        num_kv_heads=d.get("num_key_value_heads", heads),
+        attn_head_dim=d.get("head_dim", hidden // heads),
+        layer_kinds=tuple(AFMOE_LAYER_TYPES[t] for t in types),
+        num_dense_layers=d.get("num_dense_layers", 2),
+        sliding_window=int(d.get("sliding_window", 2048)),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_eps=d.get("rms_norm_eps", 1e-5),
+        intermediate_size=d.get("intermediate_size", 6144),
+        moe_intermediate_size=d.get("moe_intermediate_size", 1024),
+        num_experts=d.get("num_experts", 128),
+        experts_top_k=d.get("num_experts_per_tok", 8),
+        num_shared_experts=d.get("num_shared_experts", 1),
+        route_norm=bool(d.get("route_norm", True)),
+        route_scale=float(d.get("route_scale", 1.0)),
+        mup_enabled=bool(d.get("mup_enabled", False)),
+        load_balance_coeff=float(d.get("load_balance_coeff", 1e-3)))
+
+
 def _entry_lfm2(d):
     """LFM2 (LiquidAI/LFM2-24B-A2B, ``lfm2_moe``; the dense ``lfm2`` is
     the same entry with no sparse layer): ``layer_types`` says which
@@ -764,6 +823,7 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
     "nemotron_h": ArchEntry(NemotronHConfig, NemotronH, make_nemotron_h,
                             _entry_nemotron_h),
     "mellum": ArchEntry(MellumConfig, Mellum, make_mellum, _entry_mellum),
+    "afmoe": ArchEntry(AfmoeConfig, Afmoe, make_afmoe, _entry_afmoe),
     "lfm2": ArchEntry(Lfm2Config, Lfm2, make_lfm2, _entry_lfm2),
     "lfm2_moe": ArchEntry(Lfm2Config, Lfm2, make_lfm2, _entry_lfm2),
     "minicpm_sala": ArchEntry(MiniCPMSALAConfig, MiniCPMSALA,

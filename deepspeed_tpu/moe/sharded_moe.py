@@ -232,13 +232,30 @@ def route_topk(logits: jnp.ndarray, k: int, *, score: str = "softmax",
     return top_idx, w_sel, gates
 
 
+@jax.custom_vjp
+def _keep_cotangent_rows(x: jnp.ndarray, keep: jnp.ndarray) -> jnp.ndarray:
+    """``x`` itself, and no operation of the forward program; its
+    cotangent passes for the rows ``keep`` marks and is zero elsewhere. A
+    grouped matmul leaves the rows past its groups as it found the memory
+    (zeros on the CPU, whatever was there on the TPU), in the backward's
+    products as in the forward's: the gathered rows take this on their
+    way in, so that what the backward leaves in a row no group holds never
+    reaches the tokens' gradient."""
+    return x
+
+
+_keep_cotangent_rows.defvjp(
+    lambda x, keep: (x, keep),
+    lambda keep, ct: (jnp.where(keep[:, None], ct, 0), None))
+
+
 def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
                     weights, activation, dtype,
                     normalize_weights: bool = True, *,
                     score: str = "softmax", select_bias=None,
                     weight_scale: float = 1.0, held=None,
                     impl: Optional[str] = None, norm_eps: float = 1e-20,
-                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                    return_counts: bool = False):
     """Dropless top-k MoE via grouped expert matmuls (``jax.lax.ragged_dot``).
 
     TPU-native answer to the reference's CUTLASS grouped GEMM
@@ -276,6 +293,11 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     under a 512-row span); it has no gradient and returns no aux loss.
     The caller chooses (``inference/v2/llama_runner._moe_mlp``, by
     ``grouped_ffn.kernel_impl``).
+
+    ``return_counts``: also return the rows each of the ``E`` experts the
+    router scores was chosen for ([E] int32, before the held cut): what a
+    selection bias balanced without a loss moves by, and what a step's
+    ``moe_rows_*`` counters are made of. The ``ragged_dot`` path only.
     """
     S, E = logits.shape
     top_idx, w_sel, gates = route_topk(
@@ -283,6 +305,11 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
         normalize=normalize_weights, scale=weight_scale, norm_eps=norm_eps)
 
     eid = top_idx.reshape(-1)                              # [S*k]
+    if return_counts:
+        if impl is not None:
+            raise ValueError("return_counts is the ragged_dot path's "
+                             "(impl=None)")
+        counts = jnp.bincount(eid, length=E).astype(jnp.int32)
     here = None
     if held is not None and tuple(held) != (0, E):
         first, count = held
@@ -305,6 +332,8 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     order = jnp.argsort(eid, stable=True)
     tok_of = order // k                                    # source token
     xs = jnp.take(tokens, tok_of, axis=0).astype(dtype)    # sorted by expert
+    if here is not None:
+        xs = _keep_cotangent_rows(xs, jnp.take(here, order))
     group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
 
     with region("moe_experts"):
@@ -331,7 +360,9 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     # keeps the router regularizer identical when the dropless path
     # auto-replaces the capacity path in MoE.__call__.
     if here is not None:
-        return out, jnp.float32(0.0)       # serving share: no aux loss
+        # a share: no aux loss
+        l_aux = jnp.float32(0.0)
+        return (out, l_aux, counts) if return_counts else (out, l_aux)
     me = gates.mean(axis=0)
     if k <= 2:
         first = jnp.bincount(top_idx[:, 0], length=E).astype(jnp.float32)
@@ -339,7 +370,7 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     else:
         ce = group_sizes.astype(jnp.float32) / float(S * k)
     l_aux = (me * ce).sum() * E
-    return out, l_aux
+    return (out, l_aux, counts) if return_counts else (out, l_aux)
 
 
 def _grouped_aux_loss(gates: jnp.ndarray, top_idx: jnp.ndarray, k: int,

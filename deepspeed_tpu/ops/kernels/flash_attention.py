@@ -43,6 +43,21 @@ A block wholly above the diagonal runs nothing, and its grid step fetches
 nothing either: the index maps hold the last block the row (the column, in
 dk/dv) does need (``_last_key_block``, ``_first_query_block``).
 
+A sliding window (``window=``: key ``j`` is visible to query ``i`` iff ``0
+<= i + causal_offset - j < window``) adds the diagonal's mirror image. A
+block wholly LEFT of the window runs nothing and fetches nothing, by the
+same hold from the other side; the block the window's left edge enters at
+its top-left corner (square blocks, ``window`` a multiple of the block) is
+the fourth kind, ``edge``: local key ``c`` is visible to local query ``r``
+iff ``c > r``, the diagonal block's complement, and it is walked in the
+same strips from the other corner (query sub-tile ``i`` against the key
+columns FROM its own; dk/dv: key sub-tile ``i`` against the query rows UP
+TO its own). The block's last row sees nothing of it and it is the first
+block that row meets, so an edge update guards the running maximum against
+``-inf - -inf``. A window that meets no block corner is a general block
+under the whole-block mask. ``window=None`` traces the kernels it always
+traced; a windowed call's kernels are named ``attn_w<window>``.
+
 So a large tile no longer pays for the masked half of its diagonal blocks:
 at T = 2048 and 1024-blocks the forward computes 0.625 of the square and dq
 and dk/dv 0.5625, where a whole-block skip alone computes 0.75 (the mask
@@ -100,13 +115,14 @@ def _sub_tiles(block: int, kernel: str) -> int:
     return 1
 
 
-def _block_kinds(qi, ki, *, causal, block_q, block_k, kv_len, causal_offset):
-    """(interior, diagonal, general) for the block at grid position
+def _block_kinds(qi, ki, *, causal, block_q, block_k, kv_len, causal_offset,
+                 window=None):
+    """(interior, diagonal, general, edge) for the block at grid position
     ``(qi, ki)``: at most one holds, none for a causal block wholly above
-    the diagonal. Works on traced ``program_id``s and, for ``_grid_kinds``,
-    on plain ints."""
+    the diagonal or wholly left of the window. Works on traced
+    ``program_id``s and, for ``_grid_kinds``, on plain ints."""
     if not causal:
-        return False, False, True
+        return False, False, True, False
     row0 = qi * block_q + causal_offset       # diagonal column of row 0
     col0 = ki * block_k
     # blocks strictly above the (bottom-right-aligned) diagonal run nothing
@@ -114,18 +130,33 @@ def _block_kinds(qi, ki, *, causal, block_q, block_k, kv_len, causal_offset):
     crossed = col0 + (block_k - 1) > row0     # the diagonal cuts the block
     padded = col0 + block_k > kv_len          # it holds padded key columns
     interior = (col0 + (block_k - 1) <= row0) & (col0 + block_k <= kv_len)
-    if block_q != block_k or causal_offset % block_q:
-        return interior, False, run & (crossed | padded)
-    diagonal = (row0 == col0) & (col0 + block_k <= kv_len)
-    return interior, diagonal, run & ((crossed & (row0 != col0)) | padded)
+    aligned = block_q == block_k and causal_offset % block_q == 0
+    if window is None:
+        if not aligned:
+            return interior, False, run & (crossed | padded), False
+        diagonal = (row0 == col0) & (col0 + block_k <= kv_len)
+        return (interior, diagonal,
+                run & ((crossed & (row0 != col0)) | padded), False)
+    # the window's left edge: row r sees columns > r + causal_offset - window
+    run = run & (col0 + (block_k - 1) > row0 - window)
+    cut = col0 <= row0 + (block_q - 1) - window    # the edge cuts the block
+    interior = interior & (col0 > row0 + (block_q - 1) - window)
+    if not aligned or window % block_q:
+        return interior, False, run & (crossed | cut | padded), False
+    whole = col0 + block_k <= kv_len
+    diagonal = (row0 == col0) & whole
+    edge = (col0 == row0 - window) & whole
+    return (interior, diagonal,
+            run & ((crossed & (row0 != col0))
+                   | (cut & (col0 != row0 - window)) | padded), edge)
 
 
 def _grid_kinds(nq: int, nk: int, **geom):
     """How many blocks of an ``nq`` x ``nk`` grid are (interior, diagonal,
-    general). Static: a kernel traces no body for a kind its grid lacks
-    (the train cells' grid has no general block), and ``causal_plan``
+    general, edge). Static: a kernel traces no body for a kind its grid
+    lacks (the train cells' grid has no general block), and ``causal_plan``
     reports the counts."""
-    counts = [0, 0, 0]
+    counts = [0, 0, 0, 0]
     for qi in range(nq):
         for ki in range(nk):
             for i, kind in enumerate(_block_kinds(qi, ki, **geom)):
@@ -145,10 +176,11 @@ def _causal_mask(rows: int, cols: int, diag, transposed: bool):
 
 
 def _general_mask(qi, ki, lse=None, *, transposed=False, causal, block_q,
-                  block_k, kv_len, causal_offset):
+                  block_k, kv_len, causal_offset, window=None):
     """The whole-block mask of the general kind: key padding, the causal
-    diagonal wherever it runs and, in the backward, query rows no key is
-    visible to (``lse == -inf``; ``exp(s - lse)`` would be inf there)."""
+    diagonal and the window's left edge wherever they run and, in the
+    backward, query rows no key is visible to (``lse == -inf``; ``exp(s -
+    lse)`` would be inf there)."""
     shape = (block_k, block_q) if transposed else (block_q, block_k)
     col = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, shape, 0 if transposed else 1)
@@ -157,6 +189,11 @@ def _general_mask(qi, ki, lse=None, *, transposed=False, causal, block_q,
         mask = jnp.logical_and(mask, _causal_mask(
             block_q, block_k,
             qi * block_q + causal_offset - ki * block_k, transposed))
+    if window is not None:
+        mask = jnp.logical_and(mask, jnp.logical_not(_causal_mask(
+            block_q, block_k,
+            qi * block_q + causal_offset - window - ki * block_k,
+            transposed)))
     if lse is not None:
         mask = jnp.logical_and(mask, jnp.isfinite(lse))
     return mask
@@ -165,19 +202,22 @@ def _general_mask(qi, ki, lse=None, *, transposed=False, causal, block_q,
 def _update_by_kind(qi, ki, update, *, kernel, present, lse=None, **geom):
     """Run ``update(mask, rows, cols)`` over the block at ``(qi, ki)`` as
     its kind asks: once and unmasked (interior), once a sub-tile strip on
-    and below the diagonal (aligned diagonal), or once under the
-    whole-block mask (general). The strips follow the axis whose slices
-    are independent in ``kernel``: per-query-row state (``"fwd"``,
-    ``"dq"``) takes query sub-tile ``i`` against the key columns up to its
-    own; per-key-row state (``"dkv"``) takes key sub-tile ``i`` against
-    the query rows from its own down, masks laid out ``[cols, rows]`` as
-    that kernel holds its tiles. One strip is one update: a strip cut
-    again into its unmasked part and the sub-tile on the diagonal paid
-    more for the second update than the narrower mask saved. ``lse`` is a
-    thunk for the block's logsumexp where the general mask needs it.
-    ``present`` (``_grid_kinds``) drops the bodies of kinds this grid
-    lacks."""
-    interior, diagonal, general = _block_kinds(qi, ki, **geom)
+    and below the diagonal (aligned diagonal) or right of the window's
+    edge (aligned edge), or once under the whole-block mask (general). The
+    strips follow the axis whose slices are independent in ``kernel``:
+    per-query-row state (``"fwd"``, ``"dq"``) takes query sub-tile ``i``
+    against the key columns up to its own (edge: from its own);
+    per-key-row state (``"dkv"``) takes key sub-tile ``i`` against the
+    query rows from its own down (edge: up to its own), masks laid out
+    ``[cols, rows]`` as that kernel holds its tiles. One strip is one
+    update: a strip cut again into its unmasked part and the sub-tile on
+    the diagonal paid more for the second update than the narrower mask
+    saved. ``lse`` is a thunk for the block's logsumexp where the general
+    mask needs it. ``present`` (``_grid_kinds``) drops the bodies of kinds
+    this grid lacks. Under a window an update may meet a row with nothing
+    visible before anything was (the edge block's last row; any row of a
+    general block): ``update(..., guard=True)`` tells the forward."""
+    interior, diagonal, general, edge = _block_kinds(qi, ki, **geom)
     block_q, block_k = geom["block_q"], geom["block_k"]
     whole_q, whole_k = pl.ds(0, block_q), pl.ds(0, block_k)
     transposed = kernel == "dkv"
@@ -205,56 +245,88 @@ def _update_by_kind(qi, ki, update, *, kernel, present, lse=None, **geom):
         def _whole_block():
             update(_general_mask(qi, ki, None if lse is None else lse(),
                                  transposed=transposed, **geom),
-                   whole_q, whole_k)
+                   whole_q, whole_k, guard=geom.get("window") is not None)
+
+    if present[3]:
+        @pl.when(edge)
+        def _edge_tiled():
+            # local key c is visible to local query r iff c > r
+            n = _sub_tiles(block_q, kernel)
+            sub = block_q // n
+            for i in range(n):
+                if transposed:
+                    rows, cols, diag = ((0, (i + 1) * sub), (i * sub, sub),
+                                        -i * sub)
+                else:
+                    rows, cols, diag = ((i * sub, sub),
+                                        (i * sub, block_k - i * sub), 0)
+                update(jnp.logical_not(_causal_mask(rows[1], cols[1], diag,
+                                                    transposed)),
+                       pl.ds(*rows), pl.ds(*cols), guard=True)
 
 
 def _last_key_block(qi, ki, nk, *, causal, block_q, block_k, causal_offset,
-                    **_):
+                    window=None, **_):
     """The key block to hold at grid step ``(qi, ki)``: ``ki`` itself
-    while the query block sees it, else the last one it does see. A step
-    above the diagonal computes nothing, and a block index that repeats
-    the step before costs no DMA (nor the re-fetch of block 0 when the
-    next query block starts)."""
+    while the query block sees it, else the last one it does see (under a
+    window: or the first). A step above the diagonal or left of the window
+    computes nothing, and a block index that repeats the step before costs
+    no DMA (nor the re-fetch of block 0 when the next query block
+    starts)."""
     if not causal:
         return ki
     last = (qi * block_q + (block_q - 1) + causal_offset) // block_k
-    return jnp.minimum(ki, jnp.clip(last, 0, nk - 1))
+    if window is None:
+        return jnp.minimum(ki, jnp.clip(last, 0, nk - 1))
+    first = (qi * block_q + causal_offset - window + 1) // block_k
+    return jnp.clip(ki, jnp.clip(first, 0, nk - 1), jnp.clip(last, 0, nk - 1))
 
 
 def _first_query_block(qi, ki, nq, *, causal, block_q, block_k, causal_offset,
-                       **_):
+                       window=None, **_):
     """The dk/dv walk's counterpart: the query block to hold at step
     ``(ki, qi)`` is ``qi`` once it sees the key block, else the first one
-    that does."""
+    that does (under a window: or the last)."""
     if not causal:
         return qi
     first = (ki * block_k - causal_offset) // block_q
-    return jnp.maximum(qi, jnp.clip(first, 0, nq - 1))
+    if window is None:
+        return jnp.maximum(qi, jnp.clip(first, 0, nq - 1))
+    last = (ki * block_k + (block_k - 1) - causal_offset + window - 1) \
+        // block_q
+    return jnp.clip(qi, jnp.clip(first, 0, nq - 1), jnp.clip(last, 0, nq - 1))
 
 
 def causal_plan(tq: int, tk: int, block_q: int, block_k: int, kv_len: int,
-                causal_offset: int) -> dict:
+                causal_offset: int, window: Optional[int] = None) -> dict:
     """What the three kernels of ONE causal call over padded lengths ``tq``
     x ``tk`` compute for one (batch, head), from its static shapes alone:
     how many blocks of each kind the grid holds (the kernels share it),
     the side of a sub-tile in each kernel, the score elements the forward,
     dq and dk/dv compute together, and the elements the mask needs of the
-    three (``row + causal_offset >= col``, ``col < kv_len``).
+    three (``0 <= row + causal_offset - col < window``, ``col < kv_len``:
+    a row's ``min(i + 1, window)`` when the lengths are equal).
     ``score_area_share`` is computed / needed: 1.0 would be kernels that
     compute no masked score."""
     nq, nk = tq // block_q, tk // block_k
-    interior, diagonal, general = _grid_kinds(
+    interior, diagonal, general, edge = _grid_kinds(
         nq, nk, causal=True, block_q=block_q, block_k=block_k, kv_len=kv_len,
-        causal_offset=causal_offset)
+        causal_offset=causal_offset, window=window)
     plan = {"interior": interior, "sub_tiled": diagonal, "general": general,
-            "skipped": nq * nk - interior - diagonal - general, "sub": {}}
+            "edge": edge,
+            "skipped": nq * nk - interior - diagonal - general - edge,
+            "sub": {}}
     computed = 0
     for kernel in _MAX_SUB_TILES:
         n = _sub_tiles(block_q, kernel)
         plan["sub"][kernel] = sub = block_q // n
         computed += ((interior + general) * block_q * block_k
-                     + diagonal * (n * (n + 1) // 2) * sub * sub)
-    visible = np.clip(np.arange(tq) + causal_offset + 1, 0, kv_len)
+                     + (diagonal + edge) * (n * (n + 1) // 2) * sub * sub)
+    diag = np.arange(tq) + causal_offset
+    visible = np.clip(diag + 1, 0, kv_len)
+    if window is not None:
+        visible = np.clip(visible - np.clip(diag - window + 1, 0, kv_len),
+                          0, None)
     plan["score_elems_computed"] = computed
     plan["score_elems_needed"] = len(_MAX_SUB_TILES) * int(visible.sum())
     plan["score_area_share"] = computed / max(plan["score_elems_needed"], 1)
@@ -293,9 +365,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros(l_scr.shape, l_scr.dtype)
         acc_scr[:] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
 
-    def update(mask, rows, cols):
+    def update(mask, rows, cols, guard=False):
         _online_softmax_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                              mask, sm_scale, rows, cols)
+                              mask, sm_scale, rows, cols, guard)
 
     _update_by_kind(qi, ki, update, kernel="fwd", present=present, **geom)
 
@@ -319,14 +391,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def _online_softmax_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
-                          s_mask, sm_scale, rows=None, cols=None):
+                          s_mask, sm_scale, rows=None, cols=None,
+                          guard=False):
     """One flash block update (shared by the dense and sparse kernels):
     scores for the current (q, k) tile, ``s_mask`` applied (None: every
     score counts), online-softmax accumulators advanced. ``rows`` /
     ``cols`` (``pl.ds``) narrow the update to a sub-tile of the block:
     the accumulators are per query row, so a row slice is independent.
     Matmul operands stay in their storage dtype (bf16 runs the MXU at
-    full rate) with fp32 accumulation."""
+    full rate) with fp32 accumulation. ``guard``: a row may have nothing
+    visible here and nothing behind it (a window's edge), so its running
+    maximum is still ``-inf``: the exponents then take 0 for it, and the
+    row's sums stay 0 in place of ``exp(-inf - -inf)``."""
     rows = slice(None) if rows is None else rows
     cols = slice(None) if cols is None else cols
     q = q_ref[0, 0, rows, :]
@@ -339,8 +415,9 @@ def _online_softmax_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
     l_prev = l_scr[rows, :]
     m_cur = jnp.max(s, axis=1, keepdims=True)
     m_next = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_next)
-    p = jnp.exp(s - m_next[:, :1])
+    m_ref = jnp.where(m_next == _NEG_INF, 0.0, m_next) if guard else m_next
+    alpha = jnp.exp(m_prev - m_ref)
+    p = jnp.exp(s - m_ref[:, :1])
     l_scr[rows, :] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
     m_scr[rows, :] = m_next
     v = v_ref[0, 0, cols, :]
@@ -503,14 +580,20 @@ def flash_attention_sparse(q, k, v, block_mask, *, sm_scale=None,
 _KERNEL_NAME = "attn"
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _kernel_name(window) -> str:
+    """A windowed call's kernels say so (``attn_w2048``): a profile's
+    reader can tell them from a full call's only by name."""
+    return _KERNEL_NAME if window is None else f"{_KERNEL_NAME}_w{window}"
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
-         interpret, group=1):
+         interpret, group=1, window=None):
     b, h, tq, d = q.shape
     tk = k.shape[2]
     nq, nk = tq // block_q, tk // block_k
     geom = dict(causal=causal, block_q=block_q, block_k=block_k,
-                kv_len=kv_len, causal_offset=causal_offset)
+                kv_len=kv_len, causal_offset=causal_offset, window=window)
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
                                present=_grid_kinds(nq, nk, **geom), **geom)
     grid = (b, h, nq, nk)
@@ -545,7 +628,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-        name=_KERNEL_NAME,
+        name=_kernel_name(window),
     )(q, k, v)
     return o, lse
 
@@ -587,7 +670,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[:] = jnp.zeros(dq_scr.shape, dq_scr.dtype)
 
-    def update(mask, rows, cols):
+    def update(mask, rows, cols, guard=False):
+        del guard       # lse is finite wherever a key is visible
         k = k_ref[0, 0, cols, :]
         _, ds = _bwd_tile(q_ref[0, 0, rows, :], k, v_ref[0, 0, cols, :],
                           do_ref[0, 0, rows, :], lse_ref[0, 0, rows, :],
@@ -625,7 +709,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # tiles are held [cols, rows]: dv = p^T do and dk = ds^T q contract
     # over query rows, which in this layout is a plain matmul; lse and
     # delta come as lane-dense (1, block_q) rows
-    def update(mask, rows, cols):
+    def update(mask, rows, cols, guard=False):
+        del guard
         q = q_ref[0, 0, rows, :]
         do = do_ref[0, 0, rows, :]
         p, ds = _bwd_tile(q, k_ref[0, 0, cols, :], v_ref[0, 0, cols, :], do,
@@ -647,9 +732,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6),
-                   static_argnames=("group",))
+                   static_argnames=("group", "window"))
 def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
-         res, g, dlse=None, group=1):
+         res, g, dlse=None, group=1, window=None):
     q, k, v, o, lse = res
     do = g[0]
     b, h, tq, d = q.shape
@@ -657,7 +742,7 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
     tk = k.shape[2]
     nq, nk = tq // block_q, tk // block_k
     geom = dict(causal=causal, block_q=block_q, block_k=block_k,
-                kv_len=kv_len, causal_offset=causal_offset)
+                kv_len=kv_len, causal_offset=causal_offset, window=window)
     present = _grid_kinds(nq, nk, **geom)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -692,7 +777,7 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-        name=_KERNEL_NAME,
+        name=_kernel_name(window),
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid walks KV heads (hk = h // group); the innermost dim fuses
@@ -733,55 +818,58 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-        name=_KERNEL_NAME,
+        name=_kernel_name(window),
     )(q, k, v, do, jnp.swapaxes(lse, 2, 3), jnp.swapaxes(delta, 2, 3))
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+_STATIC = tuple(range(3, 12))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
 def _flash(q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
-           interpret, group):
+           interpret, group, window):
     o, _ = _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
-                causal_offset, interpret, group)
+                causal_offset, interpret, group, window)
     return o
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
-               causal_offset, interpret, group):
+               causal_offset, interpret, group, window):
     o, lse = _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
-                  causal_offset, interpret, group)
+                  causal_offset, interpret, group, window)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset,
-               interpret, group, res, g):
+               interpret, group, window, res, g):
     return _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset,
-                interpret, res, (g,), group=group)
+                interpret, res, (g,), group=group, window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
 def _flash_lse(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
-               causal_offset, interpret, group):
+               causal_offset, interpret, group, window):
     """(o, lse) with lse a differentiable output (used by ring attention)."""
     return _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
-                causal_offset, interpret, group)
+                causal_offset, interpret, group, window)
 
 
 def _flash_lse_fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
-                   causal_offset, interpret, group):
+                   causal_offset, interpret, group, window):
     o, lse = _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
-                  causal_offset, interpret, group)
+                  causal_offset, interpret, group, window)
     return (o, lse), (q, k, v, o, lse)
 
 
 def _flash_lse_bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset,
-                   interpret, group, res, cts):
+                   interpret, group, window, res, cts):
     do, dlse = cts
     return _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset,
-                interpret, res, (do,), dlse=dlse, group=group)
+                interpret, res, (do,), dlse=dlse, group=group, window=window)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -793,7 +881,8 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = True, sm_scale: Optional[float] = None,
+                    causal: bool = True, window: Optional[int] = None,
+                    sm_scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
                     layout: str = "BTHD",
                     interpret: Optional[bool] = None,
@@ -804,6 +893,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
       q: (B, T, H, D) [layout="BTHD", flax convention] or (B, H, T, D).
       k, v: same layout; KV head count may divide H (GQA — heads broadcast).
       causal: lower-triangular mask.
+      window: sliding window under the causal mask: key ``j`` is visible
+        to query ``i`` iff ``0 <= i + (Tk - Tq) - j < window`` (the
+        query's own key among the ``window``). Blocks the window hides are
+        neither computed nor fetched. A window no shorter than the keys is
+        no window: the call is the ``window=None`` one.
       sm_scale: softmax scale, default 1/sqrt(D).
       block_q/block_k: tile sizes (clamped to the padded sequence; block_q
         a multiple of 128 outside interpret mode). 512/512
@@ -835,6 +929,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     tk = k.shape[2]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"window={window!r} needs causal=True and a "
+                             f"window of at least one key")
+        window = None if window >= tk else int(window)
 
     block_q = min(block_q, _round_up(tq, _LANES))
     block_k = min(block_k, _round_up(tk, _LANES))
@@ -855,10 +954,10 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     # and jax.nn.dot_product_attention): decode-style tq < tk attends the
     # whole prefix.
     args = (q, k, v, causal, float(sm_scale), block_q, block_k, tk,
-            tk - tq, interpret, group)
+            tk - tq, interpret, group, window)
     if causal:
         _CAUSAL_PLANS.append((b, h, causal_plan(tq_p, tk_p, block_q, block_k,
-                                                tk, tk - tq)))
+                                                tk, tk - tq, window)))
     if return_lse:
         o, lse = _flash_lse(*args)
         lse = lse[..., 0]                                  # (b, h, tq_p)
@@ -938,7 +1037,7 @@ def sharded_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      axis_names=tuple(sizes) if manual else ())(q, k, v)
 
 
-def attention_reference(q, k, v, *, causal=True, sm_scale=None,
+def attention_reference(q, k, v, *, causal=True, window=None, sm_scale=None,
                         layout="BTHD"):
     """Pure-jnp reference used by the kernel parity tests."""
     if layout == "BTHD":
@@ -955,6 +1054,8 @@ def attention_reference(q, k, v, *, causal=True, sm_scale=None,
     if causal:
         tk = k.shape[2]
         mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
         s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))

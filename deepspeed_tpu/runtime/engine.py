@@ -75,6 +75,64 @@ class StepMetrics(NamedTuple):
     # anomaly sentinel (telemetry/train.py) reads a ready flag instead
     # of re-deriving it host-side; None on legacy metrics constructors
     nonfinite: Any = None
+    # {name: int32 scalar}: what this step's ``aux["counters"]`` counted
+    # (summed over its micro-batches); () for a loss without them
+    counters: Any = ()
+
+
+#: the keys of a loss function's aux dict that the step acts on
+_STEP_AUX = ("counters", "add")
+
+
+@jax.jit
+def _add_trees(acc, new):
+    return jax.tree_util.tree_map(jnp.add, acc, new)
+
+
+def _step_aux(aux) -> Dict[str, Any]:
+    """What of ``loss_fn``'s aux the step acts on: ``aux["counters"]``
+    (``{name: integer scalar}``, summed on the device across steps and
+    read into ``step_stats``) and ``aux["add"]`` (``{leaf path: delta}``,
+    ``/``-joined keys of the parameter tree: such a leaf becomes its
+    MASTER's old value plus ``delta`` and the optimizer's result for it is
+    dropped: a leaf moved by a rule, not by its gradient, takes no decay
+    and does not pass through the compute copy's rounding). {} for a loss
+    that returns no aux, or one that is no dict."""
+    if len(aux) != 1 or not isinstance(aux[0], dict):
+        return {}
+    return {k: aux[0][k] for k in _STEP_AUX if aux[0].get(k)}
+
+
+def _add_to_leaves(new_params, old_params, add: Dict[str, Any]):
+    """``new_params`` with each leaf ``add`` names (``/``-joined keys)
+    replaced by ``old leaf + delta``, summed in the leaf's own dtype. A
+    name that is no leaf, or a delta of another shape, raises when the
+    step is traced."""
+    left = dict(add)
+
+    def put(path, new, old):
+        name = "/".join(str(getattr(k, "key", getattr(k, "name", k)))
+                        for k in path)
+        if name not in left:
+            return new
+        d = jnp.asarray(left.pop(name))
+        if d.shape != old.shape:
+            raise ValueError(f"aux['add'][{name!r}] has shape {d.shape},"
+                             f" the leaf {old.shape}")
+        return old + d.astype(old.dtype)
+
+    out = jax.tree_util.tree_map_with_path(put, new_params, old_params)
+    if left:
+        raise KeyError(f"aux['add'] names no leaf of the parameters: "
+                       f"{sorted(left)}")
+    return out
+
+
+def _refuse_step_aux(aux, where: str) -> None:
+    if _step_aux(aux):
+        raise NotImplementedError(
+            f"loss_fn's aux['counters'] / aux['add'] are not carried "
+            f"through {where}: the declarative step alone takes them")
 
 
 LossFn = Callable[..., Any]    # (params, batch, rng) -> loss | (loss, aux)
@@ -206,13 +264,13 @@ class Engine:
         #: train_batch's own totals (seconds by bracket, steps), readable
         #: without the registry; filled by the brackets of
         #: telemetry/trace.py
-        self.step_stats = {"steps": 0, "train_batch_s": 0.0, "stage_s": 0.0,
-                           "dispatch_s": 0.0, "device_wait_s": 0.0,
-                           "commit_apply_s": 0.0, "step_exit_s": 0.0,
-                           "flash_score_elems_computed": 0,
-                           "flash_score_elems_needed": 0,
-                           "zero_manual_leaves": 0, "zero_held_leaves": 0,
-                           "zero_auto_leaves": 0}
+        self._step_stats = {"steps": 0, "train_batch_s": 0.0, "stage_s": 0.0,
+                            "dispatch_s": 0.0, "device_wait_s": 0.0,
+                            "commit_apply_s": 0.0, "step_exit_s": 0.0,
+                            "flash_score_elems_computed": 0,
+                            "flash_score_elems_needed": 0,
+                            "zero_manual_leaves": 0, "zero_held_leaves": 0,
+                            "zero_auto_leaves": 0}
         #: what ONE step's causal flash calls compute / need, from the
         #: plans noted while the step function was traced
         self._flash_elems = {"flash_score_elems_computed": 0,
@@ -224,7 +282,10 @@ class Engine:
         #: showed them)
         self._zero_leaves = {"zero_manual_leaves": 0, "zero_held_leaves": 0,
                              "zero_auto_leaves": 0}
-        self._spans = SpanSet(self.step_stats, lambda: self._train_obs)
+        #: the sum of ``StepMetrics.counters`` since ``step_stats`` was last
+        #: read, on the device ({name: int32}); None with nothing to fold
+        self._counters_dev = None
+        self._spans = SpanSet(self._step_stats, lambda: self._train_obs)
         self._train_obs = train_observer(self)
 
         # ZeRO-Offload mode: the optimizer STEP runs on the host CPU — fp32
@@ -509,11 +570,12 @@ class Engine:
                         host_mask)
 
             def scaled_loss(cp):
-                loss, _aux = self._loss_and_aux(cp, micro_batch, rng, step)
-                return ls.scale_loss(loss, scale_state) if fp16 else loss, loss
+                loss, aux = self._loss_and_aux(cp, micro_batch, rng, step)
+                return (ls.scale_loss(loss, scale_state) if fp16 else loss,
+                        (loss, _step_aux(aux)))
 
             grad_fn = jax.value_and_grad(scaled_loss, has_aux=True)
-            (_scaled, loss), grads = grad_fn(cparams)
+            (_scaled, (loss, aux)), grads = grad_fn(cparams)
             with region("grad_clip"):
                 grads = jax.tree_util.tree_map(
                     lambda g: g.astype(accum_dtype), grads)
@@ -523,7 +585,7 @@ class Engine:
                     grads = jax.tree_util.tree_map(
                         lambda g, is_host, s: jax.device_put(g, s)
                         if is_host else g, grads, host_mask, dev_twins)
-            return loss, grads
+            return loss, grads, aux
 
         micro_grads = self._maybe_manual_micro_grads(micro_grads)
         onebit_grads = self._maybe_onebit_grads(micro_grads)
@@ -560,17 +622,18 @@ class Engine:
             def scan_body(carry, xs):
                 grad_acc, loss_acc = carry
                 mb, r = xs
-                loss, grads = micro_grads(params_c, mb, r,
-                                          state.scale_state, state.step)
+                loss, grads, aux = micro_grads(params_c, mb, r,
+                                               state.scale_state, state.step)
                 with region("grad_clip"):
                     grad_acc = jax.tree_util.tree_map(jnp.add, grad_acc,
                                                       grads)
                     if plan.stage >= 2:
                         grad_acc = plan.constrain_grads(grad_acc, params_c)
                     loss_acc = loss_acc + loss
-                return (grad_acc, loss_acc), None
+                return (grad_acc, loss_acc), aux
 
             new_comm = state.comm_state
+            aux = {}
             if onebit_grads is not None:
                 loss_sum, grads, new_comm = onebit_grads(
                     params_c, micro_batches, micro_rngs,
@@ -578,14 +641,20 @@ class Engine:
             elif gas == 1:
                 # micro_batches IS the single micro batch (no leading gas
                 # axis — see the reshape-free branch above)
-                loss, grads = micro_grads(params_c, micro_batches,
-                                          micro_rngs[0],
-                                          state.scale_state, state.step)
+                loss, grads, aux = micro_grads(params_c, micro_batches,
+                                               micro_rngs[0],
+                                               state.scale_state, state.step)
                 loss_sum = loss
             else:
-                (grads, loss_sum), _ = jax.lax.scan(
+                (grads, loss_sum), aux = jax.lax.scan(
                     scan_body, (zeros, jnp.zeros((), jnp.float32)),
                     (micro_batches, micro_rngs))
+                # counters add up over the micro-batches; a leaf moves
+                # by what the last one made of its delta (a rule's step
+                # is one a step, not one a micro-batch)
+                aux = {k: jax.tree_util.tree_map(
+                    (lambda v: v.sum(0)) if k == "counters"
+                    else (lambda v: v[-1]), sub) for k, sub in aux.items()}
             with region("grad_clip"):
                 mean_loss = (loss_sum / gas).astype(jnp.float32)
 
@@ -625,6 +694,9 @@ class Engine:
                     grads, state.opt_state, params_u)
                 new_params = jax.tree_util.tree_map(
                     lambda p, u: p + u.astype(p.dtype), params_u, updates)
+                if aux.get("add"):
+                    new_params = _add_to_leaves(new_params, params_u,
+                                                aux["add"])
 
                 # overflow gate: keep old params/opt-state on non-finite
                 # grads (params_c == state.params numerically; with param
@@ -650,7 +722,10 @@ class Engine:
                     loss_scale=state.scale_state.scale,
                     skipped=jnp.logical_not(finite),
                     nonfinite=jnp.logical_not(
-                        jnp.isfinite(mean_loss) & jnp.isfinite(grad_norm)))
+                        jnp.isfinite(mean_loss) & jnp.isfinite(grad_norm)),
+                    counters=jax.tree_util.tree_map(
+                        lambda c: c.astype(jnp.int32),
+                        aux.get("counters", ())))
                 new_state = TrainState(step=new_step, params=new_params,
                                        opt_state=new_opt_state,
                                        scale_state=new_scale, rng=new_rng,
@@ -762,7 +837,8 @@ class Engine:
                 cp = prep_params(pl, pspecs, manual_axes, world, wbits,
                                  gbits, cast, book)
                 with layers_gather_their_own(book):
-                    loss, _aux = self._loss_and_aux(cp, mb_local, rng, step)
+                    loss, aux = self._loss_and_aux(cp, mb_local, rng, step)
+                _refuse_step_aux(aux, "ZeRO-3's explicit collectives")
                 # each rank owns 1/world of the batch: sum over ranks of
                 # loss/world == the global-mean objective of automatic mode
                 obj = loss / world
@@ -809,7 +885,7 @@ class Engine:
                 f"held through the step), 0 left to the partitioner; "
                 f"qwZ={'int8' if wbits else 'off'}, "
                 f"qgZ={'int8' if gbits else 'off'}")
-            return out
+            return (*out, {})
 
         return micro_grads
 
@@ -838,9 +914,12 @@ class Engine:
             ridx = jax.lax.axis_index(manual_axes)
 
             def mg(mb, r):
-                return micro_grads(params, mb,
-                                   jax.random.fold_in(r, ridx), scale_state,
-                                   step)
+                loss, grads, aux = micro_grads(
+                    params, mb, jax.random.fold_in(r, ridx), scale_state,
+                    step)
+                if aux:
+                    _refuse_step_aux((aux,), "the 1-bit optimizers' seam")
+                return loss, grads
 
             if gas == 1:
                 mb = jax.tree_util.tree_map(lambda x: x[0], micro_batches)
@@ -913,6 +992,37 @@ class Engine:
     @property
     def mesh(self):
         return self.topology.mesh
+
+    @property
+    def step_stats(self) -> Dict[str, Any]:
+        """``train_batch``'s own totals: ``steps``, the seconds of each
+        bracket (``telemetry/trace.py``), ``flash_score_elems_*``,
+        ``zero_*_leaves`` and, for a loss whose aux has ``counters``, each
+        of them summed over the steps so far (a sparse model's
+        ``moe_rows_routed`` / ``_elsewhere`` / ``_hottest``, as
+        ``pipeline_stats`` has them in serving). The counters are summed
+        on the device and read HERE: a read waits for the last step
+        dispatched, so read between stretches of steps, not inside one."""
+        self._read_counters()
+        return self._step_stats
+
+    def _read_counters(self) -> None:
+        if self._counters_dev is not None:
+            for name, value in jax.device_get(self._counters_dev).items():
+                self._step_stats[name] = \
+                    self._step_stats.get(name, 0) + int(value)
+            self._counters_dev = None
+
+    def _fold_counters(self, counters) -> None:
+        """Add one step's counters to the running sum, on the device: one
+        small dispatch and no read (every 1024th step reads, so that an
+        int32 sum of rows cannot wrap between a caller's own reads)."""
+        if self._counters_dev is None:
+            self._counters_dev = counters
+        else:
+            self._counters_dev = _add_trees(self._counters_dev, counters)
+        if self.global_steps % 1024 == 1023:
+            self._read_counters()
 
     def _flash_score_elems(self) -> Dict[str, int]:
         """One step's ``flash_score_elems_computed`` / ``_needed``: score
@@ -997,6 +1107,8 @@ class Engine:
                         self.state = self._place_state(self.state)
                     self._evict_opt_state()
                     self._last_metrics = metrics
+                    if metrics.counters:
+                        self._fold_counters(metrics.counters)
 
                     self.global_steps += 1
                     self.global_samples += expected

@@ -68,7 +68,9 @@ def build_cpu_optimizer_step(engine):
 
         def micro_grads(mb, r):
             def scaled_loss(cp):
-                loss, _aux = engine._loss_and_aux(cp, mb, r, step)
+                loss, aux = engine._loss_and_aux(cp, mb, r, step)
+                from ..engine import _refuse_step_aux
+                _refuse_step_aux(aux, "the CPU-offloaded optimizer's step")
                 return (ls.scale_loss(loss, scale_state) if fp16 else loss,
                         loss)
             (_s, loss), grads = jax.value_and_grad(

@@ -2,7 +2,8 @@
 driver's tier-1 command, ``pytest tests/``, never collects): a program PR
 that renames or drops a counter a per-layer reader names fails HERE, on
 the CPU, and not first as a metric gone silent on the ledger. One
-rehearsed cell a job kind; the test itself is the benchmark's, imported.
+rehearsed cell a job kind; the test itself is the benchmark's, imported
+(job kind ``train_sparse``'s from its own cell's test file, PR 61).
 
 And the regions' keys (``benchmark/regions.from_trace``, which
 ``run.py::read_trace`` calls on every traced run since PR 54; twelve
@@ -18,6 +19,9 @@ import os
 import pytest
 
 from benchmark import readers, regions
+from benchmark.tests.test_afmoe_cell import (  # noqa: F401
+    test_the_rehearsal_fills_every_key_the_cells_readers_name as
+    test_the_train_sparse_kinds_rehearsal_fills_every_key)
 from benchmark.tests.test_rehearse_exports import (  # noqa: F401
     test_a_rehearsal_fills_every_key_its_cells_readers_name)
 from deepspeed_tpu.telemetry.trace import REGIONS

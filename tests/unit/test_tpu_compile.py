@@ -1304,3 +1304,102 @@ def test_the_zero3_step_compiles_with_its_collectives_written_out(
     # front of the model wte, wpe and the first layer's c_attn
     assert kinds.get(("all-gather", "sync"), 0) == layers + 3, kinds
     assert kinds.get(("all-gather", "async"), 0) >= 7 * layers - 1, kinds
+
+
+def test_the_trinity_cells_train_step_compiles_under_the_chips_memory(
+        monkeypatch):
+    """The step of ``train-trinity-mini-8k-1chip`` as its job builds it
+    (benchmark/configs/trinity-mini-26b-a3b.json at published widths: 5
+    layers, 16 of 128 experts held, 1/8 of the vocabulary; micro-batch 2 x
+    8,192, float32 master / moments / gradients, bf16 compute, remat a
+    layer) through the ENGINE's own step builder, for one v5e chip: it fits
+    under 15.75 GB, its flash calls are the WINDOW kernel on the four
+    sliding layers and today's on the full one (four calls a layer:
+    forward, its recompute, dq, dk/dv: what ``flash_window_roofline.train``
+    divides by), and the experts' grouped products are there in both
+    passes. The engine is built over a toy tree of the same STRUCTURE (a
+    described chip holds no array) and its step traced at the real
+    shapes."""
+    import dataclasses
+    import json
+    import os
+
+    from jax.experimental import topologies
+
+    import deepspeed_tpu as dstpu
+    import deepspeed_tpu.ops.kernels as kernels
+    from benchmark.model_types import afmoe as mt
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.models.afmoe import make_model
+    from deepspeed_tpu.ops.kernels.flash_attention import take_causal_plans
+    from deepspeed_tpu.runtime.engine import Engine
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    one = SingleDeviceSharding(topo.devices[0])
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-mini-26b-a3b.json")) as f:
+        full = mt.model_config(json.load(f), "float32")
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "pretrain-8k-sparse.json")) as f:
+        job = json.load(f)
+    toy = dataclasses.replace(
+        full, vocab_size=64, hidden_size=16, num_heads=2, num_kv_heads=1,
+        attn_head_dim=8, intermediate_size=16, moe_intermediate_size=8,
+        num_experts=8, experts_held=2, attention_impl="xla")
+    params = make_model(toy)[1](jax.random.PRNGKey(0), 1, 8)
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+    monkeypatch.setattr(Engine, "_place_state", lambda self, state: state)
+    engine, _, _, _ = dstpu.initialize(
+        loss_fn=make_model(full)[2], params=params,
+        topology=dstpu.build_mesh(MeshConfig(**job["mesh"]),
+                                  devices=topo.devices[:1]),
+        config=dict(job["ds_config"], mesh=job["mesh"]))
+
+    # every params-shaped subtree of the state (the master, the moments)
+    # at the real shapes; whatever else it holds as it is
+    real = jax.tree_util.tree_leaves(mt.param_shapes(full))
+    toy_shapes = [p.shape for p in jax.tree_util.tree_leaves(params)]
+
+    def at_real_shapes(sub):
+        leaves, treedef = jax.tree_util.tree_flatten(sub)
+        if [np.shape(x) for x in leaves] == toy_shapes:
+            leaves = [jax.ShapeDtypeStruct(r.shape, x.dtype, sharding=one)
+                      for r, x in zip(real, leaves)]
+            return jax.tree_util.tree_unflatten(treedef, leaves)
+        if isinstance(sub, dict):
+            return {k: at_real_shapes(v) for k, v in sub.items()}
+        if isinstance(sub, (tuple, list)) and not hasattr(sub, "shape"):
+            vals = [at_real_shapes(v) for v in sub]
+            return type(sub)(*vals) if hasattr(sub, "_fields") \
+                else type(sub)(vals)
+        return jax.ShapeDtypeStruct(np.shape(sub), sub.dtype, sharding=one)
+
+    state = at_real_shapes(engine.state)
+    n = sum(int(np.prod(x.shape))
+            for x in jax.tree_util.tree_leaves(state.params))
+    assert 705e6 < n < 706e6
+    B = engine.config.train_batch_size
+    batch = {"tokens": jax.ShapeDtypeStruct((B, full.max_seq_len), jnp.int32,
+                                            sharding=one)}
+    take_causal_plans()
+    exe = engine._train_step.trace(state, batch).lower(
+        lowering_platforms=("tpu",)).compile()
+    mem = exe.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 4e9 < total < 15.75e9, total
+    names = _mosaic_call_names(exe.as_text())
+    window = f"attn_w{full.sliding_window}"
+    assert names.count(window) == 4 * 4 and names.count("attn") == 4 * 1
+    assert sum(n.startswith("ragged-dot") for n in names) >= 4 * 9
+    plans = take_causal_plans()             # one a layer's call
+    assert {(b, h) for b, h, _ in plans} == {(B, 32)}
+    assert sorted((plan["edge"], plan["skipped"]) for _, _, plan in plans) \
+        == [(0, 28)] + [(6, 43)] * 4
