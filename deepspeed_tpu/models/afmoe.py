@@ -32,11 +32,16 @@ with the flash kernels, remat a layer and the chunked cross-entropy.
   the copy, as every product reads its weights' copy), and
   ``aux["counters"]`` the step's
   ``moe_rows_routed`` / ``moe_rows_elsewhere`` / ``moe_rows_hottest`` as
-  ``InferenceEngineV2.pipeline_stats`` counts them in serving.
+  ``InferenceEngineV2.pipeline_stats`` counts them in serving, and
+  ``moe_rows_visited`` / ``moe_layers_full``: the rows of the sorted order
+  the layers' grouped products walked, and the layers whose held rows
+  exceeded ``sharded_moe.held_row_bound`` and walked every routed row.
 
 ``experts_held`` < ``num_experts`` is one chip's share of a layer divided
 over chips by expert (``experts_first`` on): routing runs over all
-``num_experts``, a row whose expert lies elsewhere adds nothing here. On
+``num_experts``, a row whose expert lies elsewhere adds nothing here (and
+is not visited: ``grouped_moe_ffn`` cuts the sorted order to a bound on the
+held rows). On
 one chip that is the whole program; the exchange that would bring other
 chips' rows here is not stood in for. Under an ``expert`` mesh axis the
 share would be computed on every member alike, so the sparse layer
@@ -333,17 +338,28 @@ def bias_step(counts: jnp.ndarray, coeff: float) -> jnp.ndarray:
     return coeff * jnp.sign(c.mean() - c)
 
 
-def step_counters(cfg: AfmoeConfig, counts) -> dict:
-    """``moe_rows_*`` of one step from its sparse layers' per-expert rows,
-    as ``pipeline_stats`` counts them in serving: rows routed to experts
-    held here, rows routed elsewhere, and the busiest held expert's rows
-    times the experts held, each summed over the layers."""
+def step_counters(cfg: AfmoeConfig, counts, tokens: int) -> dict:
+    """``moe_rows_*`` of one step of ``tokens`` tokens from its sparse
+    layers' per-expert rows, as ``pipeline_stats`` counts them in serving:
+    rows routed to experts held here, rows routed elsewhere, and the
+    busiest held expert's rows times the experts held, each summed over
+    the layers; and what ``grouped_moe_ffn`` did with them: the rows of
+    the sorted order each layer visited (``held_row_bound``, or every
+    routed row where the held rows exceeded it) and the layers that took
+    every row."""
+    from ..moe.sharded_moe import held_row_bound
     first, n = cfg.held
+    rows = tokens * cfg.experts_top_k
+    bound = held_row_bound(tokens, cfg.experts_top_k, cfg.num_experts,
+                           cfg.held)
     here = [c[first:first + n] for c in counts]
     routed = sum(h.sum() for h in here)
+    full = sum((h.sum() > bound).astype(jnp.int32) for h in here)
     return {"moe_rows_routed": routed,
             "moe_rows_elsewhere": sum(c.sum() for c in counts) - routed,
-            "moe_rows_hottest": sum(h.max() for h in here) * n}
+            "moe_rows_hottest": sum(h.max() for h in here) * n,
+            "moe_rows_visited": bound * len(here) + (rows - bound) * full,
+            "moe_layers_full": full}
 
 
 def make_model(cfg: AfmoeConfig):
@@ -372,7 +388,7 @@ def make_model(cfg: AfmoeConfig):
             aux = {"add": {f"layer_{i}/moe/select_bias":
                            bias_step(c, cfg.load_balance_coeff)
                            for i, c in zip(sparse, counts)},
-                   "counters": step_counters(cfg, counts)}
+                   "counters": step_counters(cfg, counts, inputs.size)}
         return loss, aux
 
     return model, init_fn, loss_fn

@@ -13,6 +13,7 @@ expert-parallel all-to-all.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -249,6 +250,22 @@ _keep_cotangent_rows.defvjp(
     lambda keep, ct: (jnp.where(keep[:, None], ct, 0), None))
 
 
+def held_row_bound(S: int, k: int, E: int, held) -> int:
+    """The rows of the sorted order :func:`grouped_moe_ffn`'s ``ragged_dot``
+    path visits for a held share: twice the rows an even router sends to
+    ``held = (first, count)`` of ``E`` experts, in whole 512-row tiles, and
+    never more than the ``S * k`` routed rows (``held`` None or all ``E``:
+    every row). A bound from shapes alone, so the program is static; the
+    step whose held rows exceed it takes all ``S * k`` (a ``cond`` on the
+    device's own count), and ``models/afmoe.py::step_counters`` counts
+    those steps."""
+    rows = S * k
+    if held is None or tuple(held) == (0, E):
+        return rows
+    expected = -(-rows * held[1] // E)
+    return min(rows, -(-2 * expected // 512) * 512)
+
+
 def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
                     weights, activation, dtype,
                     normalize_weights: bool = True, *,
@@ -277,11 +294,18 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     hold experts ``first .. first + count`` of the ``E`` the router scores
     (one chip's share of a layer divided over chips). Routing runs over
     all ``E``; rows whose expert is elsewhere sort behind the last held
-    group, lie in no group of the grouped matmuls (which leave rows past
-    their groups alone) and add nothing to the output.
+    group and most are never visited: the sorted order is cut to
+    :func:`held_row_bound` rows, twice the share's even part, and the few
+    of them past the held rows lie in no group of the grouped matmuls
+    (which leave rows past their groups alone) and add nothing to the
+    output. A step whose held rows exceed the bound (the count is on the
+    device) takes every row through the same body under a ``cond``: no row
+    is dropped for any routing. Both bodies add a token's held rows in the
+    order they were, so the cut changes no sum.
 
-    ``impl``: None is the path above, three ``ragged_dot`` calls over all
-    S*k rows: what training takes (the gradient flows through it) and
+    ``impl``: None is the path above, three ``ragged_dot`` calls over the
+    rows visited (all S*k of a whole layer): what training takes (the
+    gradient flows through it) and
     what serving takes off the TPU or over packed stacks. "pallas" (or
     "interpret", the same kernel interpreted) is the serving path: one
     grouped kernel of our own (``ops/kernels/grouped_ffn.py``) walks the
@@ -330,29 +354,55 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
                       * w_sel[..., None], axis=1).astype(dtype)
         return out, jnp.float32(0.0)
     order = jnp.argsort(eid, stable=True)
-    tok_of = order // k                                    # source token
-    xs = jnp.take(tokens, tok_of, axis=0).astype(dtype)    # sorted by expert
-    if here is not None:
-        xs = _keep_cotangent_rows(xs, jnp.take(here, order))
-    group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
+    if return_counts and here is not None:
+        # the held groups' sizes are a slice of what is already counted (a
+        # second count of 131,072 ids is 1.2 ms on a v5e)
+        group_sizes = counts[held[0]:held[0] + held[1]]
+    else:
+        group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
 
-    with region("moe_experts"):
-        if len(weights) == 3:
-            wi_gate, wi_up, wo = weights
-            g = jax.lax.ragged_dot(xs, wi_gate.astype(dtype), group_sizes)
-            u = jax.lax.ragged_dot(xs, wi_up.astype(dtype), group_sizes)
-            h = activation(g) * u
-        else:
-            wi, wo = weights
-            h = activation(
-                jax.lax.ragged_dot(xs, wi.astype(dtype), group_sizes))
-        ys = jax.lax.ragged_dot(h, wo.astype(dtype), group_sizes)  # [S*k, M]
+    def visit(rows, tokens, w_flat, weights):
+        """The first ``rows`` rows of the sorted order through the experts
+        and back into their tokens: all of them, or the bound that holds
+        every row of a held group."""
+        first = order if rows == S * k else order[:rows]
+        tok_of = first // k                                # source token
+        xs = jnp.take(tokens, tok_of, axis=0).astype(dtype)  # by expert
+        if here is not None:
+            keep = jnp.take(here, first)
+            xs = _keep_cotangent_rows(xs, keep)
+        with region("moe_experts"):
+            if len(weights) == 3:
+                wi_gate, wi_up, wo = weights
+                g = jax.lax.ragged_dot(xs, wi_gate.astype(dtype), group_sizes)
+                u = jax.lax.ragged_dot(xs, wi_up.astype(dtype), group_sizes)
+                h = activation(g) * u
+            else:
+                wi, wo = weights
+                h = activation(
+                    jax.lax.ragged_dot(xs, wi.astype(dtype), group_sizes))
+            ys = jax.lax.ragged_dot(h, wo.astype(dtype), group_sizes)
+        ws = jnp.take(w_flat, first).astype(dtype)
+        if here is not None:
+            # a row past the groups carries whatever the matmul left there
+            ys = jnp.where(keep[:, None], ys, 0)
+        return jnp.zeros_like(tokens, dtype).at[tok_of].add(ys * ws[:, None])
 
-    ws = jnp.take(w_sel.reshape(-1), order).astype(dtype)
-    if here is not None:
-        # a row past the groups carries whatever the matmul left there
-        ys = jnp.where(jnp.take(here, order)[:, None], ys, 0)
-    out = jnp.zeros_like(tokens, dtype).at[tok_of].add(ys * ws[:, None])
+    operands = (tokens, w_sel.reshape(-1), tuple(weights))
+    bound = held_row_bound(S, k, logits.shape[1], held)
+    if bound == S * k:
+        out = visit(S * k, *operands)
+    else:
+        # held rows sort first: ``bound`` rows hold them all unless the
+        # router sent this share more than twice its even part, and the
+        # step that does so takes every row as before. Each branch keeps
+        # its operands alone for the backward: left to itself a ``cond``
+        # writes the other branch's residuals as zeros, full-size, in
+        # every step (Trinity's 8k step then no longer fits a v5e)
+        out = jax.lax.cond(
+            group_sizes.sum() <= bound,
+            jax.checkpoint(functools.partial(visit, bound)),
+            jax.checkpoint(functools.partial(visit, S * k)), *operands)
 
     # load-balance loss — same statistic the capacity path this call
     # replaces would report: top1gating/top2gating use FIRST-choice counts

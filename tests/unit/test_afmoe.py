@@ -300,15 +300,187 @@ def test_config_from_hf_reads_the_catalog_rows_keys():
         config_from_hf(dict(d, n_group=2))
 
 
+def _every_row_ffn(tokens, logits, k, weights, held):
+    """``grouped_moe_ffn``'s ``ragged_dot`` path over a held share as the
+    parent of ISSUE 62 had it, kept here to compare with: every routed row
+    sorted, gathered, multiplied, masked and added back."""
+    from deepspeed_tpu.moe.sharded_moe import _keep_cotangent_rows, route_topk
+    top_idx, w_sel, _ = route_topk(logits, k, score="sigmoid")
+    eid = top_idx.reshape(-1)
+    first, count = held
+    here = (eid >= first) & (eid < first + count)
+    eid = jnp.where(here, eid - first, count)
+    order = jnp.argsort(eid, stable=True)
+    tok_of = order // k
+    xs = _keep_cotangent_rows(jnp.take(tokens, tok_of, axis=0),
+                              jnp.take(here, order))
+    sizes = jnp.bincount(eid, length=count).astype(jnp.int32)
+    wi_gate, wi_up, wo = weights
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, wi_gate, sizes)) \
+        * jax.lax.ragged_dot(xs, wi_up, sizes)
+    ys = jax.lax.ragged_dot(h, wo, sizes)
+    ys = jnp.where(jnp.take(here, order)[:, None], ys, 0)
+    ws = jnp.take(w_sel.reshape(-1), order)
+    return jnp.zeros_like(tokens).at[tok_of].add(ys * ws[:, None])
+
+
+@pytest.mark.parametrize("routing", ["even", "overflowing"])
+def test_a_share_cut_to_its_bound_is_the_share_over_every_row(routing):
+    """ISSUE 62: a held share visits ``held_row_bound`` rows of the sorted
+    order, and every routed row in the step whose held rows exceed that.
+    Either way nothing is dropped: the output is the parent's body's bit
+    for bit (a token's held rows are added in the order they were) and the
+    gradients of the tokens, the three stacks and the logits agree to
+    1e-6; the step's counters say which body ran."""
+    from deepspeed_tpu.models.afmoe import step_counters
+    from deepspeed_tpu.moe.sharded_moe import grouped_moe_ffn, held_row_bound
+    S, M, W, E, k, held = 512, 16, 8, 16, 2, (4, 2)
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    tokens = jax.random.normal(ks[0], (S, M))
+    logits = jax.random.normal(ks[1], (S, E))
+    weights = tuple(jax.random.normal(kk, shape) * 0.3 for kk, shape in zip(
+        ks[2:], ((2, M, W), (2, M, W), (2, W, M))))
+    if routing == "overflowing":    # every token's choices are held
+        logits = logits.at[:, 4:6].add(10.0)
+    bound = held_row_bound(S, k, E, held)
+    assert bound == 512 < S * k
+
+    def cut(tokens, weights, logits):
+        out, _, counts = grouped_moe_ffn(
+            tokens, logits, k, weights, jax.nn.silu, jnp.float32,
+            score="sigmoid", held=held, return_counts=True)
+        return jnp.sum(jnp.sin(out)), (out, counts)
+
+    def every(tokens, weights, logits):
+        out = _every_row_ffn(tokens, logits, k, weights, held)
+        return jnp.sum(jnp.sin(out)), out
+
+    (_, (out, counts)), g = jax.jit(jax.value_and_grad(
+        cut, (0, 1, 2), has_aux=True))(tokens, weights, logits)
+    (_, ref), g_ref = jax.jit(jax.value_and_grad(
+        every, (0, 1, 2), has_aux=True))(tokens, weights, logits)
+    n_here = int(counts[4:6].sum())
+    assert (n_here > bound) == (routing == "overflowing")
+    assert np.any(np.asarray(ref))
+    np.testing.assert_array_equal(out, ref)
+    for a, b in zip(jax.tree_util.tree_leaves(g),
+                    jax.tree_util.tree_leaves(g_ref)):
+        assert np.any(np.asarray(b))
+        assert _rel(a, b) < 1e-6
+    cfg = AfmoeConfig.tiny(num_experts=E, experts_top_k=k, experts_held=2,
+                           experts_first=4)
+    c = step_counters(cfg, [counts], S)
+    assert int(c["moe_rows_routed"]) == n_here
+    if routing == "overflowing":
+        assert n_here == S * k
+        assert (int(c["moe_layers_full"]), int(c["moe_rows_visited"])) == (
+            1, S * k)
+    else:
+        assert (int(c["moe_layers_full"]), int(c["moe_rows_visited"])) == (
+            0, bound)
+
+
+@pytest.mark.parametrize("S,k,E,held,bound", [
+    (16384, 8, 128, (0, 16), 32768),    # train-trinity-mini-8k-1chip
+    (16384, 8, 128, (0, 128), 131072),
+    (16384, 8, 128, None, 131072),
+    (16384, 8, 128, (64, 64), 131072),  # twice a half: every row
+    (512, 2, 16, (4, 2), 512),
+    (100, 3, 16, (0, 1), 300),          # a tile is more than the rows
+    (4096, 6, 64, (8, 3), 2560),        # 1,152 expected: 2,304 -> 5 tiles
+])
+def test_the_bound_on_a_shares_rows_is_twice_its_even_part(S, k, E, held,
+                                                           bound):
+    from deepspeed_tpu.moe.sharded_moe import held_row_bound
+    got = held_row_bound(S, k, E, held)
+    assert got == bound
+    assert got == S * k or (got % 512 == 0 and got < S * k)
+    if held is not None:
+        assert got >= min(S * k, 2 * S * k * held[1] / E)
+
+
+def test_the_steps_counters_say_which_layers_visited_every_row():
+    """``step_counters`` from the layers' per-expert rows alone: a layer
+    whose held rows exceed the bound counts every routed row as visited
+    and itself as full, a layer at or under it the bound; a tree that holds
+    every expert visits every row and no layer is "full"."""
+    from deepspeed_tpu.models.afmoe import step_counters
+    from deepspeed_tpu.moe.sharded_moe import held_row_bound
+    tokens, E, k = 1024, 16, 4
+    cfg = AfmoeConfig.tiny(num_experts=E, experts_top_k=k, experts_held=2,
+                           experts_first=6)
+    bound = held_row_bound(tokens, k, E, cfg.held)
+    assert bound == 1024
+
+    def layer(here):        # `here` rows on experts 6 and 7, the rest on 0
+        c = np.zeros(E, np.int32)
+        c[6], c[7] = here - here // 3, here // 3
+        c[0] = tokens * k - here
+        return jnp.asarray(c)
+
+    got = jax.jit(lambda cs: step_counters(cfg, cs, tokens))(
+        [layer(300), layer(bound), layer(bound + 1), layer(4096)])
+    assert {key: int(v) for key, v in got.items()} == {
+        "moe_rows_routed": 300 + 1024 + 1025 + 4096,
+        "moe_rows_elsewhere": 4 * 4096 - (300 + 1024 + 1025 + 4096),
+        "moe_rows_hottest": 2 * (200 + 683 + 684 + 2731),
+        "moe_rows_visited": 2 * 1024 + 2 * 4096, "moe_layers_full": 2}
+    whole = AfmoeConfig.tiny(num_experts=E, experts_top_k=k)
+    got = step_counters(whole, [layer(300), layer(4096)], tokens)
+    assert (int(got["moe_rows_visited"]), int(got["moe_layers_full"])) == (
+        2 * 4096, 0)
+
+
+def test_the_new_counters_reach_step_stats_and_add_up_over_steps():
+    """ISSUE 62's two counters through the engine: a tree whose first
+    sparse layer's selection bias sends every choice to the held experts
+    (its held rows exceed the bound: every row visited, in every step) and
+    whose second routes as drawn (the bound's rows visited). Over three
+    steps ``step_stats`` holds the host's recount, as for
+    ``moe_rows_routed``."""
+    from deepspeed_tpu.moe.sharded_moe import held_row_bound
+    cfg = dataclasses.replace(SMALL, experts_held=4, experts_first=4)
+    _, _, loss_fn = make_model(cfg)
+    params = _draw(cfg)
+    moe = params["layer_1"]["moe"]
+    moe["select_bias"] = moe["select_bias"].at[4:8].add(10.0)
+    dims = _dims(cfg)
+    rows = 4 * T * cfg.experts_top_k
+    bound = held_row_bound(4 * T, cfg.experts_top_k, cfg.num_experts,
+                           cfg.held)
+    assert bound == 512 < rows
+    engine = _engine(loss_fn, params, train_micro_batch_size_per_gpu=4)
+    recount = {"moe_rows_routed": 0, "moe_rows_visited": 0,
+               "moe_layers_full": 0}
+    for seed in (1, 2, 3):
+        batch = {"tokens": _tokens(cfg, batch=4, seed=seed)}
+        counts = reference.expert_counts(
+            jax.device_get(engine.state.params), batch["tokens"], **dims)
+        engine.train_batch(batch)
+        for c in counts:
+            here = int(np.asarray(c[4:8]).sum())
+            recount["moe_rows_routed"] += here
+            recount["moe_rows_visited"] += rows if here > bound else bound
+            recount["moe_layers_full"] += here > bound
+    stats = engine.step_stats
+    assert {key: stats[key] for key in recount} == recount
+    assert recount["moe_layers_full"] == 3
+    assert recount["moe_rows_visited"] == 3 * (rows + bound)
+
+
+@pytest.mark.parametrize("routing", ["even", "overflowing"])
 def test_what_a_grouped_matmul_leaves_past_its_groups_reaches_no_gradient(
-        monkeypatch):
+        monkeypatch, routing):
     """On the TPU ``ragged_dot`` leaves the rows past its groups as it
     found the memory, in the backward's products too (the first chip run of
     ISSUE 61 read token gradients 20,000 times the reference's). Planted
     here: a ``ragged_dot`` that writes NaN there, forward and backward. The
     share's output, its token gradient and its weight gradients stay what
-    the clean one gives."""
-    from deepspeed_tpu.moe.sharded_moe import grouped_moe_ffn
+    the clean one gives: over the rows of the bound (``even``: the rows
+    past the held ones and under ``held_row_bound``) and over every routed
+    row (``overflowing``: nine tokens in ten choose held experts alone, so
+    the held rows exceed the bound and a few rows still lie past them)."""
+    from deepspeed_tpu.moe.sharded_moe import grouped_moe_ffn, held_row_bound
     real = jax.lax.ragged_dot
 
     def dirt(x, sizes):
@@ -331,12 +503,21 @@ def test_what_a_grouped_matmul_leaves_past_its_groups_reaches_no_gradient(
         return dirt(dl, sizes)[0], dr, None
 
     dirty.defvjp(fwd, bwd)
-    S, M, W, E, k = 48, 16, 8, 8, 2
+    S, M, W, E, k = 640, 16, 8, 8, 2
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     tokens = jax.random.normal(ks[0], (S, M))
     logits = jax.random.normal(ks[1], (S, E))
     weights = tuple(jax.random.normal(kk, shape) * 0.3 for kk, shape in zip(
         ks[2:], ((2, M, W), (2, M, W), (2, W, M))))
+    if routing == "overflowing":    # nine tokens in ten choose held ones
+        logits = logits.at[:576, 2:4].add(10.0)
+    counts = grouped_moe_ffn(tokens, logits, k, weights, jax.nn.silu,
+                             jnp.float32, score="sigmoid", held=(2, 2),
+                             return_counts=True)[2]
+    bound = held_row_bound(S, k, E, (2, 2))
+    assert bound == 1024 < S * k
+    assert (bound < int(counts[2:4].sum()) < S * k) == (
+        routing == "overflowing")
 
     def f(tokens, weights):
         out, _ = grouped_moe_ffn(tokens, logits, k, weights, jax.nn.silu,
@@ -353,34 +534,52 @@ def test_what_a_grouped_matmul_leaves_past_its_groups_reaches_no_gradient(
         np.testing.assert_allclose(a, b, atol=1e-6)
 
 
-#: sha256 of the sorted instructions of a held share's FORWARD program
-#: (``grouped_moe_ffn(..., held=(4, 4), impl=None)`` as a serving step
-#: calls it, compiled for the CPU; metadata, instruction and region
-#: numbers cut) as the parent of ISSUE 61 compiled it, under jax
-#: ``_SERVE_PINNED_JAX``
-_SERVE_PINNED = \
-    "66d1526fd93b7b8bcb1887de3f38e8cda7fe1793b0b96721570d39cc8ad3c6a8"
+#: sha256 of the sorted instructions of a FORWARD program of
+#: ``grouped_moe_ffn(..., impl=None)`` as a serving step calls it, compiled
+#: for the CPU (metadata, instruction and region numbers cut), under jax
+#: ``_SERVE_PINNED_JAX``. ``share``: ``held=(4, 4)`` over 48 routed rows,
+#: where ``held_row_bound`` is all of them, as the parent of ISSUE 61
+#: compiled it; ``whole``: ``held=None``, what ``moe/layer.py`` and a
+#: whole-layer serve take, as the parent of ISSUE 62 compiled it. Neither
+#: may move. ``share_cut``: the same share over 2,048 routed rows, where
+#: the bound is 1,024: the program ISSUE 62 made (a ``conditional`` of the
+#: body over 1,024 rows and over 2,048), pinned on its own tree
+_SERVE_PINNED = {
+    "share": (24, (4, 4), "66d1526fd93b7b8bcb1887de3f38e8cda7fe1793b0b9672"
+                          "1570d39cc8ad3c6a8"),
+    "whole": (24, None, "a4ec9180fd69950d4fb29b9d16fc22f4eaaa2f7506550ef42"
+                        "975f8d3b3cda773"),
+    "share_cut": (1024, (4, 4), "d260936808ab2d11891e47a45d8946b9a9d1ab6b4"
+                                "7274d06e94cdd17ffdba24c"),
+}
 _SERVE_PINNED_JAX = "0.9.0"
 
 
-def test_a_serving_share_compiles_to_the_instructions_it_always_did():
+@pytest.mark.parametrize("case", sorted(_SERVE_PINNED))
+def test_a_serving_share_compiles_to_the_instructions_it_always_did(case):
     """``_keep_cotangent_rows`` and ``return_counts`` (off) add nothing to
-    a forward program: a held share served through the ``ragged_dot`` path
-    compiles to the parent's instructions, one for one."""
+    a forward program, and neither does ``held_row_bound`` where it cuts
+    nothing: a held share over few rows and a whole layer served through
+    the ``ragged_dot`` path compile to their parents' instructions, one for
+    one. A share whose bound cuts the sorted order is ISSUE 62's program by
+    design, re-pinned there: a later change to it has to say so here."""
     import hashlib
     import re
     from deepspeed_tpu.moe.sharded_moe import grouped_moe_ffn
+    S, held, pinned = _SERVE_PINNED[case]
 
     def step(x, logits, wg, wu, wo, bias):
         return grouped_moe_ffn(
             x, logits, 2, (wg, wu, wo), jax.nn.silu, jnp.float32, True,
             score="sigmoid", select_bias=bias, weight_scale=2.5,
-            held=(4, 4), impl=None)[0]
+            held=held, impl=None)[0]
 
-    S, M, W, E, n = 24, 16, 8, 16, 4
+    M, W, E = 16, 8, 16
+    n = E if held is None else held[1]
     args = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (
         (S, M), (S, E), (n, M, W), (n, M, W), (n, W, M), (E,))]
     text = jax.jit(step).lower(*args).compile().as_text()
+    assert ("conditional(" in text) == (case == "share_cut")
     text = re.sub(r", metadata=\{[^}]*\}", "", text)
     text = re.sub(r"\.\d+", "", text)
     text = "\n".join(
@@ -390,4 +589,4 @@ def test_a_serving_share_compiles_to_the_instructions_it_always_did():
     text = re.sub(r"region_\d+", "region", text)
     text = "\n".join(sorted(line.strip() for line in text.splitlines()))
     if jax.__version__ == _SERVE_PINNED_JAX:
-        assert hashlib.sha256(text.encode()).hexdigest() == _SERVE_PINNED
+        assert hashlib.sha256(text.encode()).hexdigest() == pinned
