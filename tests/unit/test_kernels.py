@@ -1,5 +1,7 @@
 """Pallas kernel parity tests (interpret mode on the CPU mesh) — the analogue
-of the reference's per-op numerical tests under ``tests/unit/ops/``."""
+of the reference's per-op numerical tests under ``tests/unit/ops/``. The
+flash-attention classes are in ``test_kernels_flash.py``, the FP6 ones in
+``test_kernels_fp6.py`` (a file is what tier-1 schedules)."""
 
 import jax
 import jax.numpy as jnp
@@ -8,236 +10,17 @@ import pytest
 
 from deepspeed_tpu.ops.kernels import (
     dequantize_blockwise,
-    flash_attention,
     fused_adamw_update,
     fused_layer_norm,
     fused_rms_norm,
     quant_dequant,
     quantize_blockwise,
 )
-from deepspeed_tpu.ops.kernels.flash_attention import attention_reference
 from deepspeed_tpu.ops.kernels.fused_optimizer import adamw_reference
 
 
 def _rand(key, shape, dtype=jnp.float32):
     return jax.random.normal(key, shape, dtype)
-
-
-class TestFlashAttention:
-    @pytest.mark.parametrize("causal", [True, False])
-    @pytest.mark.parametrize("t", [128, 80])  # 80 exercises padding+mask
-    def test_forward_parity(self, causal, t):
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
-        q = _rand(k1, (2, t, 2, 32))
-        k = _rand(k2, (2, t, 2, 32))
-        v = _rand(k3, (2, t, 2, 32))
-        out = flash_attention(q, k, v, causal=causal, interpret=True)
-        ref = attention_reference(q, k, v, causal=causal)
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-    def test_gqa_forward(self):
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(1), 3)
-        q = _rand(k1, (1, 128, 4, 16))
-        k = _rand(k2, (1, 128, 2, 16))
-        v = _rand(k3, (1, 128, 2, 16))
-        out = flash_attention(q, k, v, causal=True, interpret=True)
-        ref = attention_reference(q, k, v, causal=True)
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-    @pytest.mark.parametrize("causal", [True, False])
-    @pytest.mark.parametrize("t", [128, 80])  # 80 exercises padding+mask
-    def test_gqa_gradient_parity(self, causal, t):
-        """GQA backward: the grouped dk/dv accumulation grid must sum a KV
-        head's cotangent over its whole q-head group (4 q heads over 2 KV
-        heads here), matching autodiff through the repeated reference —
-        with multiple q/k blocks so the fused (q-head, q-block) inner grid
-        dim is exercised across block boundaries."""
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
-        q = _rand(k1, (1, t, 4, 16))
-        k = _rand(k2, (1, t, 2, 16))
-        v = _rand(k3, (1, t, 2, 16))
-
-        def loss_flash(q, k, v):
-            o = flash_attention(q, k, v, causal=causal, interpret=True,
-                                block_q=64, block_k=128)
-            return jnp.sum(jnp.sin(o))
-
-        def loss_ref(q, k, v):
-            return jnp.sum(jnp.sin(attention_reference(q, k, v, causal=causal)))
-
-        g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g1, g2):
-            assert a.shape == b.shape
-            np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
-
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_gradient_parity(self, causal):
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(2), 3)
-        q = _rand(k1, (1, 128, 2, 16))
-        k = _rand(k2, (1, 128, 2, 16))
-        v = _rand(k3, (1, 128, 2, 16))
-
-        def loss_flash(q, k, v):
-            o = flash_attention(q, k, v, causal=causal, interpret=True)
-            return jnp.sum(jnp.sin(o))
-
-        def loss_ref(q, k, v):
-            return jnp.sum(jnp.sin(attention_reference(q, k, v, causal=causal)))
-
-        g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g1, g2):
-            np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
-
-    def test_gradient_parity_padded(self):
-        """Padded (non-multiple-of-block) sequence: grads must match too."""
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3), 3)
-        q = _rand(k1, (1, 72, 2, 16))
-        k = _rand(k2, (1, 72, 2, 16))
-        v = _rand(k3, (1, 72, 2, 16))
-
-        def loss_flash(q, k, v):
-            return jnp.sum(flash_attention(q, k, v, causal=True,
-                                           interpret=True) ** 2)
-
-        def loss_ref(q, k, v):
-            return jnp.sum(attention_reference(q, k, v, causal=True) ** 2)
-
-        g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g1, g2):
-            np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
-
-    @pytest.mark.parametrize("tq,tk", [(1, 128), (64, 256), (96, 160)])
-    def test_causal_decode_alignment(self, tq, tk):
-        """q_len != kv_len: causal diagonal is bottom-right aligned (decode
-        over a prefix attends the whole prefix)."""
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
-        q = _rand(k1, (1, tq, 2, 16))
-        k = _rand(k2, (1, tk, 2, 16))
-        v = _rand(k3, (1, tk, 2, 16))
-        out = flash_attention(q, k, v, causal=True, interpret=True)
-        ref = attention_reference(q, k, v, causal=True)
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-    def test_attention_impl_validation(self):
-        from deepspeed_tpu.models.gpt2 import GPT2, GPT2Config
-        cfg = GPT2Config.tiny(attention_impl="typo", dtype=jnp.float32)
-        model = GPT2(cfg)
-        toks = jnp.zeros((1, 8), jnp.int32)
-        with pytest.raises(ValueError, match="attention_impl"):
-            model.init(jax.random.PRNGKey(0), toks)
-
-    # (tq, tk, q heads, kv heads, block, kv padding) -> blocks of each kind a
-    # (batch, head): interior / sub-tiled / general
-    BLOCK_KIND_CASES = {
-        "three_kinds": ((700, 700, 2, 2, 256), (3, 2, 1)),
-        "sub64_of_256": ((512, 512, 2, 2, 256), (1, 2, 0)),
-        "cell_blocks_1024": ((2048, 2048, 1, 1, 1024), (1, 2, 0)),
-        "gqa_dkv_walk": ((768, 768, 4, 2, 256), (3, 3, 0)),
-        "one_block_512": ((512, 512, 2, 1, 512), (0, 1, 0)),
-        "block_128_is_its_own_sub_tile": ((256, 256, 2, 2, 128), (1, 2, 0)),
-        "tq_lt_tk_aligned": ((256, 512, 2, 2, 256), (1, 1, 0)),
-        "tq_lt_tk_unaligned": ((200, 456, 2, 2, 256), (1, 0, 1)),
-        "padded_kv_len": ((200, 200, 2, 2, 256), (0, 0, 1)),
-    }
-
-    @pytest.mark.parametrize("case", sorted(BLOCK_KIND_CASES))
-    def test_block_kinds_parity(self, case):
-        """Forward and gradients against the reference for grids that hold
-        interior, sub-tiled (aligned diagonal) and general blocks: an
-        aligned diagonal block computes only the strips on and below the
-        diagonal, an interior block builds no mask, and everything else
-        (padding, an offset that meets no block corner) keeps the
-        whole-block mask and its old answer."""
-        from deepspeed_tpu.ops.kernels.flash_attention import \
-            take_causal_plans
-        (tq, tk, h, hk, block), kinds = self.BLOCK_KIND_CASES[case]
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(tq + tk + h), 3)
-        q = _rand(k1, (1, tq, h, 16))
-        k = _rand(k2, (1, tk, hk, 16))
-        v = _rand(k3, (1, tk, hk, 16))
-
-        def flash(q, k, v):
-            return flash_attention(q, k, v, causal=True, interpret=True,
-                                   block_q=block, block_k=block)
-
-        take_causal_plans()
-        out = flash(q, k, v)
-        (b_, h_, plan), = take_causal_plans()
-        assert (b_, h_) == (1, h)
-        assert (plan["interior"], plan["sub_tiled"], plan["general"]) == kinds
-        ref = attention_reference(q, k, v, causal=True)
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-        g1 = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))),
-                      argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(lambda *a: jnp.sum(jnp.sin(
-            attention_reference(*a, causal=True))), argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g1, g2):
-            assert a.shape == b.shape
-            np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
-
-    @pytest.mark.parametrize("geom,want", [
-        # the train cells: T 2048 at 1024-blocks, offset 0
-        ((2048, 2048, 1024, 1024, 2048, 0),
-         dict(interior=1, sub_tiled=2, general=0, skipped=1,
-              sub={"fwd": 512, "dq": 256, "dkv": 256},
-              computed=1024 * 1024 * 3 + 2 * (3 * 512 ** 2 + 2 * 10 * 256 ** 2),
-              needed=3 * (2048 * 2049 // 2))),
-        # the defaults over the same T: 4 x 4 blocks of 512
-        ((2048, 2048, 512, 512, 2048, 0),
-         dict(interior=6, sub_tiled=4, general=0, skipped=6,
-              sub={"fwd": 256, "dq": 128, "dkv": 128},
-              computed=6 * 512 ** 2 * 3 + 4 * (3 * 256 ** 2 + 2 * 10 * 128 ** 2),
-              needed=3 * (2048 * 2049 // 2))),
-        # ring attention's diagonal hop: a shard's T / sp against itself
-        ((512, 512, 512, 512, 512, 0),
-         dict(interior=0, sub_tiled=1, general=0, skipped=0,
-              sub={"fwd": 256, "dq": 128, "dkv": 128},
-              computed=3 * 256 ** 2 + 2 * 10 * 128 ** 2,
-              needed=3 * (512 * 513 // 2))),
-        # a query shard one whole block below its keys: offset = a block
-        ((512, 1024, 512, 512, 1024, 512),
-         dict(interior=1, sub_tiled=1, general=0, skipped=0,
-              sub={"fwd": 256, "dq": 128, "dkv": 128},
-              computed=3 * 512 ** 2 + 3 * 256 ** 2 + 2 * 10 * 128 ** 2,
-              needed=3 * (512 * 512 + 512 * 513 // 2))),
-        # an offset that meets no block corner: every crossed block general
-        ((512, 1024, 512, 512, 900, 388),
-         dict(interior=0, sub_tiled=0, general=2, skipped=0,
-              sub={"fwd": 256, "dq": 128, "dkv": 128},
-              computed=3 * 2 * 512 ** 2,
-              needed=3 * sum(min(r + 389, 900) for r in range(512)))),
-    ])
-    def test_causal_plan(self, geom, want):
-        from deepspeed_tpu.ops.kernels.flash_attention import causal_plan
-        plan = causal_plan(*geom)
-        for key in ("interior", "sub_tiled", "general", "skipped", "sub"):
-            assert plan[key] == want[key], key
-        assert plan["score_elems_computed"] == want["computed"]
-        assert plan["score_elems_needed"] == want["needed"]
-        assert plan["score_area_share"] == pytest.approx(
-            want["computed"] / want["needed"])
-        assert plan["score_area_share"] >= 1.0
-
-    def test_block_q_must_fill_lanes_on_the_chip(self):
-        """The dk/dv kernel reads lse and delta as (1, block_q) lane rows."""
-        x = jnp.zeros((1, 128, 1, 16))
-        with pytest.raises(ValueError, match="multiple of 128"):
-            flash_attention(x, x, x, block_q=64, interpret=False)
-
-    def test_multi_block(self):
-        """Sequence spanning several KV blocks (online-softmax accumulation)."""
-        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(4), 3)
-        q = _rand(k1, (1, 256, 1, 16))
-        k = _rand(k2, (1, 256, 1, 16))
-        v = _rand(k3, (1, 256, 1, 16))
-        out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
-                              interpret=True)
-        ref = attention_reference(q, k, v, causal=True)
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
 class TestNorms:
@@ -362,179 +145,6 @@ class TestFusedAdamW:
                 p_fused, g, m, v, t, lr=1e-3, weight_decay=0.01,
                 interpret=True)
         np.testing.assert_allclose(p_fused, p_opx, atol=1e-5, rtol=1e-5)
-
-
-class TestFlashAttentionSparse:
-    """Block-sparse flash path (splash-style grid skipping)."""
-
-    def _ref(self, q, k, v, bm, block=128):
-        mask = np.kron(np.asarray(bm, bool),
-                       np.ones((block, block), dtype=bool))[:, :q.shape[2],
-                                                            :k.shape[2]]
-        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                       k.astype(jnp.float32)) / np.sqrt(q.shape[-1])
-        s = jnp.where(jnp.asarray(mask)[None], s,
-                      float(np.finfo(np.float32).min))
-        p = jax.nn.softmax(s, axis=-1)
-        p = jnp.where(jnp.asarray(mask)[None].any(-1, keepdims=True), p, 0.0)
-        return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
-
-    def test_matches_masked_reference(self):
-        from deepspeed_tpu.ops.kernels import flash_attention_sparse
-        rng = jax.random.PRNGKey(0)
-        b, h, t, d = 2, 2, 384, 64            # 3x3 blocks of 128
-        q = jax.random.normal(rng, (b, h, t, d), jnp.float32)
-        k = jax.random.normal(jax.random.PRNGKey(1), (b, h, t, d))
-        v = jax.random.normal(jax.random.PRNGKey(2), (b, h, t, d))
-        bm = np.array([[[1, 0, 1], [0, 1, 0], [1, 1, 1]],
-                       [[1, 1, 0], [1, 0, 1], [0, 0, 1]]], np.int32)
-        out = flash_attention_sparse(q, k, v, bm, layout="BHTD",
-                                     interpret=True)
-        ref = self._ref(q, k, v, bm)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5)
-
-    def test_fully_masked_row_is_zero(self):
-        from deepspeed_tpu.ops.kernels import flash_attention_sparse
-        q = jax.random.normal(jax.random.PRNGKey(0), (1, 1, 256, 64))
-        bm = np.array([[[1, 1], [0, 0]]], np.int32)   # row block 1: nothing
-        out = flash_attention_sparse(q, q, q, bm, layout="BHTD",
-                                     interpret=True)
-        np.testing.assert_allclose(np.asarray(out[:, :, 128:]), 0.0)
-        assert float(jnp.abs(out[:, :, :128]).max()) > 0
-
-    def test_sparse_attention_flash_impl(self):
-        from deepspeed_tpu.ops.sparse_attention import (
-            BigBirdSparsityConfig, sparse_attention)
-        # 128-block layout re-tiles exactly — the flash path applies it
-        cfg = BigBirdSparsityConfig(num_heads=2, block=128,
-                                    num_sliding_window_blocks=1,
-                                    num_global_blocks=1)
-        q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 384, 32))
-        layout = cfg.make_layout(384)
-        out = sparse_attention(q, q, q, cfg, layout=layout, impl="flash")
-        ref = sparse_attention(q, q, q, cfg, layout=layout)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5)
-
-    def test_flash_impl_rejects_inexact_and_token_masks(self):
-        from deepspeed_tpu.ops.sparse_attention import (
-            FixedSparsityConfig, sparse_attention)
-        # fine causal layout: coarsening would add (future) attention
-        cfg = FixedSparsityConfig(num_heads=1, block=16,
-                                  attention="unidirectional")
-        q = jax.random.normal(jax.random.PRNGKey(0), (1, 1, 256, 32))
-        with pytest.raises(ValueError, match="128-block"):
-            sparse_attention(q, q, q, cfg, impl="flash")
-        with pytest.raises(ValueError, match="layout_mask"):
-            sparse_attention(q, q, q, cfg, impl="flash",
-                             layout_mask=jnp.ones((1, 256, 256), bool))
-
-    def test_coarsen_layout(self):
-        from deepspeed_tpu.ops.sparse_attention import (
-            coarsen_layout, coarsening_is_exact)
-        fine = np.zeros((1, 16, 16), bool)
-        fine[0, 3, 9] = True                  # one 16-block hit
-        coarse = coarsen_layout(fine, 16, 128)
-        assert coarse.shape == (1, 2, 2)
-        assert coarse[0, 0, 1] and coarse.sum() == 1
-        assert not coarsening_is_exact(fine, 16)   # partial block -> inexact
-        # fully-dense coarse blocks are exact
-        fine2 = np.zeros((1, 16, 16), bool)
-        fine2[0, :8, 8:] = True
-        assert coarsening_is_exact(fine2, 16)
-        # expansion (block > 128) is exact by repetition
-        big = np.asarray([[[1, 0], [0, 1]]], bool)
-        exp = coarsen_layout(big, 256, 128)
-        assert exp.shape == (1, 4, 4)
-        assert exp[0, 0, 0] and exp[0, 1, 1] and not exp[0, 0, 2]
-
-
-class TestShardedFlash:
-    """sharded_flash_attention: the DP/ZeRO/TP shard_map wrapping."""
-
-    def test_batch_and_head_sharded(self, devices8):
-        from deepspeed_tpu.config import MeshConfig
-        from deepspeed_tpu.ops.kernels import sharded_flash_attention
-        from deepspeed_tpu.parallel import build_mesh
-        topo = build_mesh(MeshConfig(data=4, model=2))
-        ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        q, k, v = (jax.random.normal(x, (8, 32, 4, 16), jnp.float32)
-                   for x in ks)
-        ref = attention_reference(q, k, v, causal=True)
-        out = sharded_flash_attention(q, k, v, topo.mesh, causal=True,
-                                      interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=1e-4)
-
-    def test_indivisible_falls_back(self, devices8):
-        from deepspeed_tpu.config import MeshConfig
-        from deepspeed_tpu.ops.kernels import sharded_flash_attention
-        from deepspeed_tpu.parallel import build_mesh
-        topo = build_mesh(MeshConfig(data=8))
-        ks = jax.random.split(jax.random.PRNGKey(1), 3)
-        # batch 3 not divisible by data=8 -> unsharded kernel fallback
-        q, k, v = (jax.random.normal(x, (3, 16, 2, 8), jnp.float32)
-                   for x in ks)
-        ref = attention_reference(q, k, v, causal=True)
-        out = sharded_flash_attention(q, k, v, topo.mesh, causal=True,
-                                      interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=1e-4)
-
-    def test_grad_matches_reference(self, devices8):
-        from deepspeed_tpu.config import MeshConfig
-        from deepspeed_tpu.ops.kernels import sharded_flash_attention
-        from deepspeed_tpu.parallel import build_mesh
-        topo = build_mesh(MeshConfig(data=2, model=2, seq=2))
-        ks = jax.random.split(jax.random.PRNGKey(2), 3)
-        q, k, v = (jax.random.normal(x, (4, 32, 4, 8), jnp.float32)
-                   for x in ks)
-
-        def loss_kernel(q, k, v):
-            o = sharded_flash_attention(q, k, v, topo.mesh, causal=True,
-                                        interpret=True)
-            return jnp.sum(o ** 2)
-
-        def loss_ref(q, k, v):
-            return jnp.sum(attention_reference(q, k, v, causal=True) ** 2)
-
-        gk = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gk, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=2e-4, rtol=1e-3)
-
-    def test_lse_output_grad(self):
-        """return_lse: the lse cotangent folds into the backward
-        (delta - dlse) — check against autodiff of a jnp logsumexp."""
-        from deepspeed_tpu.ops.kernels import flash_attention
-        ks = jax.random.split(jax.random.PRNGKey(3), 3)
-        q, k, v = (jax.random.normal(x, (1, 16, 2, 8), jnp.float32)
-                   for x in ks)
-        sm = 1.0 / np.sqrt(8)
-
-        def loss_kernel(q, k, v):
-            o, lse = flash_attention(q, k, v, causal=True, interpret=True,
-                                     return_lse=True)
-            return jnp.sum(o) + jnp.sum(jnp.sin(lse))
-
-        def loss_ref(q, k, v):
-            qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-            s = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * sm
-            mask = jnp.tril(jnp.ones((16, 16), bool))
-            s = jnp.where(mask, s, -jnp.inf)
-            o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), vt)
-            lse = jax.nn.logsumexp(s, axis=-1)
-            return jnp.sum(jnp.swapaxes(o, 1, 2)) + jnp.sum(jnp.sin(lse))
-
-        np.testing.assert_allclose(float(loss_kernel(q, k, v)),
-                                   float(loss_ref(q, k, v)), rtol=1e-5)
-        gk = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gk, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=2e-4, rtol=1e-3)
 
 
 class TestFusedXent:
@@ -795,180 +405,3 @@ class TestFusedXent:
             assert d < 1e-3
 
 
-class TestFp6Gemm:
-    """Fused FP6 weight-only GEMM (ops/kernels/fp6_gemm.py) — the
-    reference's FP6 serving path (inference/v2/kernels/core_ops/
-    cuda_linear/), TPU form."""
-
-    def _w(self, K=256, N=512, seed=0):
-        return jax.random.normal(jax.random.PRNGKey(seed), (K, N),
-                                 jnp.float32) * 0.1
-
-    def test_pack_unpack_quantization_error(self):
-        from deepspeed_tpu.ops.kernels import fp6_gemm_pack, fp6_gemm_unpack
-        w = self._w()
-        wq = fp6_gemm_unpack(fp6_gemm_pack(w))
-        assert wq.shape == w.shape
-        # e3m2 with per-column scaling: ~2 mantissa bits => relative
-        # error bounded by ~2^-3 of the column max
-        colmax = jnp.max(jnp.abs(w), axis=0)
-        err = jnp.max(jnp.abs(wq - w) / colmax[None, :])
-        assert float(err) < 0.14, float(err)
-
-    def test_matmul_matches_unpacked(self):
-        from deepspeed_tpu.ops.kernels import (fp6_gemm_pack,
-                                               fp6_gemm_unpack, fp6_matmul)
-        w = self._w()
-        fw = fp6_gemm_pack(w)
-        x = jax.random.normal(jax.random.PRNGKey(1), (24, 256), jnp.float32)
-        ref = x @ fp6_gemm_unpack(fw)
-        got = fp6_matmul(x, fw, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=3e-4, rtol=3e-4)
-
-    def test_batched_and_padded_rows(self):
-        from deepspeed_tpu.ops.kernels import (fp6_gemm_pack,
-                                               fp6_gemm_unpack, fp6_matmul)
-        fw = fp6_gemm_pack(self._w())
-        x = jax.random.normal(jax.random.PRNGKey(2), (3, 5, 256),
-                              jnp.float32)          # M=15: pads to tile
-        ref = x @ fp6_gemm_unpack(fw)
-        got = fp6_matmul(x, fw, interpret=True)
-        assert got.shape == (3, 5, 512)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=3e-4, rtol=3e-4)
-
-    def test_unaligned_falls_back(self):
-        from deepspeed_tpu.ops.kernels import (fp6_gemm_pack,
-                                               fp6_gemm_unpack, fp6_matmul)
-        w = self._w(K=100, N=40)                    # no 128-divisor tiles
-        fw = fp6_gemm_pack(w)
-        x = jax.random.normal(jax.random.PRNGKey(3), (4, 100), jnp.float32)
-        ref = x @ fp6_gemm_unpack(fw)
-        got = fp6_matmul(x, fw, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=1e-5)
-
-    def test_storage_is_6_bits(self):
-        from deepspeed_tpu.ops.kernels import fp6_gemm_pack
-        fw = fp6_gemm_pack(self._w(K=256, N=512))
-        assert fw.bytes3.dtype == jnp.uint8
-        # 3 bytes per 4 values = 6 bits/value
-        assert fw.bytes3.size == 256 * 512 * 6 // 8
-
-    def test_woq_fp6_serving_dtype(self):
-        # inference/quantization num_bits=6 path: FPQuantizedTensor leaves,
-        # dequantize_tree view, memory accounting
-        from deepspeed_tpu.inference.quantization import (
-            dequantize_tree, quantize_model_params, woq_memory_bytes)
-        from deepspeed_tpu.ops.fp_quantizer import FPQuantizedTensor
-        params = {"proj": {"kernel": self._w(K=128, N=256)},
-                  "norm": {"scale": jnp.ones((256,))}}
-        q = quantize_model_params(
-            params, {"quantized_weights": {"enabled": True, "num_bits": 6,
-                                           "group_size": 128}})
-        assert isinstance(q["proj"]["kernel"], FPQuantizedTensor)
-        deq = dequantize_tree(q)
-        colmax = float(jnp.max(jnp.abs(params["proj"]["kernel"])))
-        assert float(jnp.max(jnp.abs(
-            deq["proj"]["kernel"] - params["proj"]["kernel"]))) < 0.14 * colmax
-        assert woq_memory_bytes(q) < woq_memory_bytes(params) / 2
-
-
-class TestFusedFp6Serving:
-    """fused_gemm WOQ through the ragged engine: Fp6GemmWeight leaves
-    survive the in-jit dequant pass and llama_runner's woq_mm dispatch
-    streams them through the fused kernel (eligible shapes) or the
-    unpack fallback (small projections)."""
-
-    def _engine(self, fused):
-        from deepspeed_tpu.inference.quantization import (
-            quantize_model_params, woq_memory_bytes)
-        from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                                RaggedInferenceConfig)
-        from deepspeed_tpu.models.llama import Llama, LlamaConfig
-
-        mcfg = LlamaConfig.tiny(dtype=jnp.float32, max_seq_len=128,
-                                hidden_size=128, num_heads=4,
-                                num_kv_heads=2, intermediate_size=512)
-        model = Llama(mcfg)
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-        q = quantize_model_params(
-            params, {"quantized_weights": {
-                "dtype": "fp6", "group_size": 64, "fused_gemm": fused,
-                "excluded_modules": ["embed", "norm", "lm_head"]}})
-        cfg = RaggedInferenceConfig(max_seqs=2, chunk_size=8, block_size=64,
-                                    num_blocks=8, max_blocks_per_seq=1,
-                                    dtype="float32")
-        return InferenceEngineV2(mcfg, q, cfg), q, woq_memory_bytes
-
-    def test_fused_leaves_and_generate_parity(self):
-        from deepspeed_tpu.inference.quantization import dequantize_tree
-        from deepspeed_tpu.inference.v2 import InferenceEngineV2
-        from deepspeed_tpu.ops.kernels import Fp6GemmWeight
-        eng_f, qf, _ = self._engine(fused=True)
-        # the wide MLP kernels really are in the fused layout
-        mlp = qf["layer_0"]["mlp"]["gate_proj"]["kernel"]
-        assert isinstance(mlp, Fp6GemmWeight)
-
-        # parity against the SAME fused tree served dense (the generic
-        # fp6 engine quantizes with different scale groups, so its
-        # trajectory is a different model — not the comparison)
-        dense_same = dequantize_tree(qf)
-        eng_ref = InferenceEngineV2(eng_f.runner.model_cfg, dense_same,
-                                    eng_f.config)
-        prompt = list(np.random.default_rng(0).integers(1, 512, 12))
-        got_f = eng_f.generate([prompt], max_new_tokens=5)[0]
-        got_r = eng_ref.generate([prompt], max_new_tokens=5)[0]
-        # identical decoded values, different accumulation order: greedy
-        # trajectories must agree at least on the first tokens
-        assert got_f[:2] == got_r[:2], (got_f, got_r)
-
-    def test_fused_moe_router_survives(self):
-        # Mixtral's router weight [hidden, E] is fused-packable; the MoE
-        # path must unpack it rather than crash (review r5 finding)
-        from deepspeed_tpu.inference.quantization import (
-            quantize_model_params)
-        from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                                RaggedInferenceConfig)
-        from deepspeed_tpu.models.mixtral import Mixtral, MixtralConfig
-        mcfg = MixtralConfig.tiny(dtype=jnp.float32, max_seq_len=128,
-                                  hidden_size=128, num_heads=4,
-                                  num_kv_heads=2, intermediate_size=512,
-                                  num_experts=4)
-        model = Mixtral(mcfg)
-        k = jax.random.PRNGKey(0)
-        params = model.init({"params": k, "gating": k},
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-        q = quantize_model_params(
-            params, {"quantized_weights": {
-                "dtype": "fp6", "fused_gemm": True,
-                "excluded_modules": ["embed", "norm", "lm_head"]}})
-        eng = InferenceEngineV2(mcfg, q, RaggedInferenceConfig(
-            max_seqs=2, chunk_size=8, block_size=64, num_blocks=8,
-            max_blocks_per_seq=1, dtype="float32"))
-        out = eng.generate([[5, 6, 7, 8]], max_new_tokens=3)[0]
-        assert len(out) == 3
-
-    def test_fused_non_fp6_rejected(self):
-        from deepspeed_tpu.inference.quantization import (
-            quantize_model_params)
-        for bad in ({"dtype": "fp8", "fused_gemm": True},
-                    {"num_bits": 8, "fused_gemm": True}):
-            with pytest.raises(ValueError, match="fused_gemm"):
-                quantize_model_params(
-                    {"k": jnp.ones((8, 8))}, {"quantized_weights": bad})
-
-    def test_plain_consumers_get_dense(self):
-        # default dequantize_tree (no keep_fused) unpacks fused leaves
-        from deepspeed_tpu.inference.quantization import dequantize_tree
-        from deepspeed_tpu.ops.kernels import (Fp6GemmWeight,
-                                               fp6_gemm_pack)
-        w = jax.random.normal(jax.random.PRNGKey(0), (64, 128))
-        tree = {"k": fp6_gemm_pack(w)}
-        out = dequantize_tree(tree)
-        assert not isinstance(out["k"], Fp6GemmWeight)
-        assert out["k"].shape == (64, 128)
-        kept = dequantize_tree(tree, keep_fused=True)
-        assert isinstance(kept["k"], Fp6GemmWeight)

@@ -11,36 +11,26 @@ the loader's names, and the union of the refusals a recurrent and a latent
 model make."""
 
 import dataclasses
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmark.model_types import kimi_linear as mt
 from benchmark.reference import kimi_linear as reference
-from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                        RaggedInferenceConfig)
+from deepspeed_tpu.inference.v2 import RaggedInferenceConfig
 from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
 from deepspeed_tpu.models.kimi_linear import (KimiLinear, KimiLinearConfig,
                                               mla_param_count, param_counts)
 from deepspeed_tpu.models.registry import config_from_hf
 from deepspeed_tpu.models.solar_open2 import mixer_param_count
+from family_harness import prompt_of
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-CONFIG = os.path.join(ROOT, "benchmark", "configs",
-                      "kimi-linear-48b-a3b.json")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+CONFIG = "kimi-linear-48b-a3b.json"
 REDUCED = ("num_hidden_layers", "linear_attn_config", "num_experts",
            "vocab_size")
-#: float32 engine against a float32 reference at highest precision: what
-#: is left is the order of the sums (the chunked delta rule against the
-#: recurrence, the absorbed products against the expanded ones, the
-#: grouped matmul against the dense mask), a few 1e-6 on logits of size 4
-TOL = 2e-4
 
 
 def tiny(**kw):
@@ -50,35 +40,23 @@ def tiny(**kw):
                                  **kw)
 
 
+#: float32 engine against a float32 reference at highest precision: what
+#: is left is the order of the sums (the chunked delta rule against the
+#: recurrence, the absorbed products against the expanded ones, the
+#: grouped matmul against the dense mask), a few 1e-6 on logits of size 4
+FAMILY = H.Family(mt, tiny, tol=2e-4)
+engine = FAMILY.engine
+
+
 @pytest.fixture(scope="module")
 def model():
-    cfg = tiny()
-    return cfg, mt.init_params(cfg, 3)
-
-
-def engine(cfg, params, chunk=64, **kw):
-    kw.setdefault("max_seqs", 4)
-    return InferenceEngineV2(cfg, params, RaggedInferenceConfig(
-        chunk_size=chunk, block_size=16, num_blocks=24,
-        max_blocks_per_seq=6, decode_loop_steps=4, dtype="float32",
-        prefill_chunk_cap=0, **kw))
-
-
-def ref_logits(cfg, params, tokens, at):
-    out = mt.reference_logits(cfg)(params, jnp.asarray([tokens]),
-                                   jnp.asarray([at]))
-    return np.asarray(out)[0]
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 512, n).tolist()
+    return FAMILY.model()
 
 
 # ------------------------- (a) engine vs reference ------------------------ #
 
 
-@pytest.mark.parametrize("chunk", [64, 16], ids=["one-chunk", "three-chunks"])
-@pytest.mark.parametrize("decode", ["fused", "pipelined"])
+@H.chunk_and_decode
 def test_engine_logits_match_the_reference(model, chunk, decode):
     """A 37-token prompt (three 16-token latent blocks) prefilled in one
     chunk or in three (the chunked delta rule and the absorbed prefill side
@@ -87,27 +65,10 @@ def test_engine_logits_match_the_reference(model, chunk, decode):
     or step by step, then one more position's logits: each against the
     reference's forward pass over the whole sequence (token-by-token
     recurrence, expanded attention, no cache)."""
-    cfg, params = model
     prompt = prompt_of(37)
-    eng = engine(cfg, params, chunk)
-    lg = np.asarray(eng.put([7], [prompt])[7])
-    want = ref_logits(cfg, params, prompt, [len(prompt) - 1])[0]
-    assert np.abs(lg - want).max() < TOL
-    tok = int(np.argmax(lg))
-    if decode == "fused":
-        toks = eng.decode_batch([7], [tok], 8)[7]
-    else:
-        toks = eng.decode_pipelined([7], [tok], 8)[7]
-    seq = prompt + [tok] + list(toks)
-    at = list(range(len(prompt), len(seq)))
-    want = ref_logits(cfg, params, seq, at)
-    assert [int(t) for t in toks] == np.argmax(want[:-1], -1).tolist()
-    # the state and the rows the decode left in the cache: the next
-    # position's logits
-    lg = np.asarray(eng.put([7], [[int(toks[-1])]])[7])
-    assert np.abs(lg - want[-1]).max() < TOL
     # BOTH families of counters fill in the one run
-    stats = eng.pipeline_stats
+    stats = FAMILY.serve_against_reference(model, chunk,
+                                           decode).pipeline_stats
     assert stats["mla_prefill_tokens"] == len(prompt)
     assert stats["linear_attn_prefill_tokens"] == len(prompt)
     assert stats["linear_attn_prefill_kernel_tokens"] == 0
@@ -130,38 +91,14 @@ def test_engine_logits_match_the_reference(model, chunk, decode):
 
 
 def test_flax_model_and_runner_read_one_tree(model):
-    cfg, params = model
-    prompt = prompt_of(12, seed=8)
-    with jax.default_matmul_precision("highest"):
-        got = KimiLinear(cfg).apply({"params": params},
-                                    jnp.asarray([prompt]))[0]
-    want = ref_logits(cfg, params, prompt, list(range(len(prompt))))
-    assert float(np.abs(np.asarray(got) - want).max()) < TOL
+    FAMILY.flax_model_reads_the_runners_tree(KimiLinear, model)
 
 
 def test_two_sequences_decode_as_they_do_alone_and_a_slot_starts_fresh(model):
     """Two sequences of different lengths in one batch, and then a third in
     a slot the first one left: each decodes what it decodes alone (the
     state rows and the latent blocks of a flushed tenant reach nobody)."""
-    cfg, params = model
-    prompts = {1: prompt_of(21, seed=1), 2: prompt_of(33, seed=2),
-               3: prompt_of(18, seed=3)}
-
-    def alone(uid):
-        eng = engine(cfg, params, 16)
-        tok = int(np.argmax(np.asarray(eng.put([uid], [prompts[uid]])[uid])))
-        return [tok] + [int(t) for t in eng.decode_batch([uid], [tok], 4)[uid]]
-
-    eng = engine(cfg, params, 16, max_seqs=2)
-    out = eng.put([1, 2], [prompts[1], prompts[2]])
-    first = {u: int(np.argmax(np.asarray(out[u]))) for u in (1, 2)}
-    got = eng.decode_batch([1, 2], [first[1], first[2]], 4)
-    for u in (1, 2):
-        assert [first[u]] + [int(t) for t in got[u]] == alone(u)
-    eng.flush(1)
-    tok = int(np.argmax(np.asarray(eng.put([3], [prompts[3]])[3])))
-    assert [tok] + [int(t) for t in eng.decode_batch([3], [tok], 4)[3]] \
-        == alone(3)
+    FAMILY.two_sequences_decode_as_alone(model)
 
 
 # ----------------------- (b) absorbed == expanded ------------------------ #
@@ -277,59 +214,16 @@ def test_engine_through_the_kernels_matches_the_reference(model):
     plane as its K and its V operand, the decode steps through the latent
     decode kernel, per step and in the fused loop over its ring, beside
     the recurrent layers' state."""
-    cfg, params = model
-    prompt = prompt_of(21, seed=4)
-    eng = engine(cfg, params, 16, attention_impl="paged_flash")
-    lg = np.asarray(eng.put([3], [prompt])[3])
-    want = ref_logits(cfg, params, prompt, [len(prompt) - 1])[0]
-    assert np.abs(lg - want).max() < TOL
-    tok = int(np.argmax(lg))
-    toks = list(eng.decode_batch([3], [tok], 4)[3])
-    toks += list(eng.decode_pipelined([3], [int(toks[-1])], 2)[3])
-    seq = prompt + [tok] + toks
-    want = ref_logits(cfg, params, seq, list(range(len(prompt), len(seq))))
-    assert [int(t) for t in toks] == np.argmax(want[:-1], -1).tolist()
+    FAMILY.serve_through_the_kernels(model)
+
 
 def test_decode_through_the_conv_kernel_serves_the_jnp_paths_tokens(
         model, monkeypatch):
-    """The decode steps' short convolution through the in-place Pallas
-    call (forced and interpreted here; on the chip platform and shape
-    pick it) after a chunked prefill: 4 steps of the fused loop and 5
-    step by step give the jnp path's tokens and leave its pool, states
-    and carried inputs alike, and the engine counts the layer-steps.
-    (Alike to float32 rounding: inside a step program XLA's CPU backend
-    contracts the taps' multiply-adds where it fuses them and not in the
-    interpreted body; ``test_short_conv.py`` holds the call alone to the
-    jnp path bit for bit, and so did the chip, PERF.md PR 46.)"""
-    from deepspeed_tpu.ops.kernels import short_conv
-    cfg, params = model
-    prompts = {5: prompt_of(21, seed=4), 6: prompt_of(9, seed=5)}
-
-    def serve():
-        eng = engine(cfg, params, 16)
-        first = {u: int(np.argmax(np.asarray(lg)))
-                 for u, lg in eng.put(list(prompts),
-                                      list(prompts.values())).items()}
-        out = eng.decode_batch([5, 6], [first[5], first[6]], 4)
-        toks = {u: [first[u]] + [int(t) for t in out[u]] for u in prompts}
-        # one sequence alone: the other rows of its bucket are idle
-        toks[6] += [int(t) for t in
-                    eng.decode_pipelined([6], [toks[6][-1]], 5)[6]]
-        return toks, jax.device_get((eng._kv_data.state, eng._kv_data.conv)), \
-            eng.pipeline_stats["conv_steps_in_place"]
-
-    want_toks, want_pool, counted = serve()
-    assert counted == 0                  # the CPU path: gather and scatter
-    monkeypatch.setattr(short_conv, "decode_uses_kernel",
-                        lambda *a, **k: True)
-    toks, pool, counted = serve()
-    assert toks == want_toks
-    for got, want in zip(jax.tree_util.tree_leaves(pool),
-                         jax.tree_util.tree_leaves(want_pool)):
-        assert np.allclose(got, want, rtol=1e-4, atol=1e-5)
-    layers = sum(k in ("kda", "mamba2") for k in cfg.layer_kinds)
-    assert layers and counted == (4 + 5) * layers
-
+    """``Family.decode_through_the_conv_kernel``; the engine counts the
+    layer-steps the kernel took."""
+    plain, forced = FAMILY.decode_through_the_conv_kernel(model, monkeypatch)
+    assert plain["conv_steps_in_place"] == 0     # the CPU path: gather and scatter
+    assert forced["conv_steps_in_place"] == (4 + 5) * 3      # 3 KDA layers
 
 
 # ------------------------------ (d) shares ------------------------------- #
@@ -352,11 +246,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     assert whole_cfg.routed_scaling == 2.446
 
     def share(first, held):
-        cfg = dataclasses.replace(whole_cfg, experts_first=first,
-                                  experts_held=held)
-        p = dict(whole["moe"], **{n: whole["moe"][n][first:first + held]
-                                  for n in ("wi_gate", "wi_up", "wo")})
-        return cfg, p
+        return H.share_of(whole_cfg, whole["moe"], first, held)
 
     with jax.default_matmul_precision("highest"):
         once = reference._swiglu(whole, h, shared)
@@ -368,11 +258,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
             refs.append(reference._sparse_mlp(p, h, first=first, **kw))
         unbiased = reference._sparse_mlp(
             dict(whole["moe"], sel_bias=jnp.zeros((8,))), h, first=0, **kw)
-    for part, ref in zip(parts, refs):
-        assert float(jnp.abs(part).max()) > 1e-3      # each share does work
-        assert float(jnp.abs(part - ref).max()) < 1e-5
-    assert float(jnp.abs(sum(parts) + once - uncut).max()) < 1e-5
-    assert float(jnp.abs(sum(refs) + once - uncut).max()) < 1e-5
+    H.shares_add_up(parts, refs, uncut, once)
     # and the bias took part: without it other experts are chosen
     assert float(jnp.abs(unbiased + once - uncut).max()) > 1e-2
 
@@ -417,17 +303,7 @@ def test_one_cache_value_holds_a_latent_plane_and_a_state_pool(model):
 # ------------------------------ (f) refusals ----------------------------- #
 
 
-@pytest.mark.parametrize("feature, kw, call", [
-    ("prefix_cache", dict(prefix_cache=True), None),
-    ("spec_decode", dict(spec_decode="ngram"), None),
-    ("kv_cache_dtype='int8'", dict(kv_cache_dtype="int8"), None),
-    ("tp_size > 1", dict(tp_size=2, max_seqs=2), None),
-    ("seq_size > 1", dict(seq_size=2, max_seqs=2), None),
-    ("ep_size > 1", dict(ep_size=2, max_seqs=2), None),
-    ("handoff_out", {}, ([1],)), ("handoff_in", {}, ({},)),
-    ("drain", {}, ()), ("replay", {}, ({},)),
-    ("attach_draft", {}, "model"), ("decode_spec", {}, ([1], [3], 2)),
-    ("pause", {}, (1,)), ("resume", {}, (1,))])
+@pytest.mark.parametrize("feature, kw, call", H.REFUSALS)
 def test_the_union_of_the_two_families_refusals(model, feature, kw, call):
     """What a recurrent model refuses and what a latent one refuses, this
     model refuses, each by its existing wording: construction options by
@@ -436,17 +312,7 @@ def test_the_union_of_the_two_families_refusals(model, feature, kw, call):
     plane and need a state snapshot: the recurrent wording alone."""
     from deepspeed_tpu.inference.v2.config import (latent_refusal,
                                                    stateful_refusal)
-    cfg, params = model
-    if call is None:
-        with pytest.raises(ValueError) as err:
-            engine(cfg, params, **kw)
-    else:
-        eng = engine(cfg, params)
-        eng.put([1], [prompt_of(9)])
-        with pytest.raises(NotImplementedError) as err:
-            getattr(eng, feature)(*((cfg, params) if call == "model"
-                                    else call))
-    said = str(err.value)
+    said = FAMILY.refusal(model, feature, kw, call)
     assert stateful_refusal(feature) in said
     assert (latent_refusal(feature) in said) \
         == (feature not in ("pause", "resume"))
@@ -456,13 +322,7 @@ def test_the_union_of_the_two_families_refusals(model, feature, kw, call):
 
 
 def _published():
-    """The catalog's ``config`` as the configuration file carries it, the
-    reduced keys back at their published values."""
-    with open(CONFIG) as f:
-        d = json.load(f)
-    for key in REDUCED:
-        d[key] = d[key + "_published"]
-    return d
+    return H.published(CONFIG, REDUCED)
 
 
 def test_config_from_hf_layer_lists_and_parameter_counts():
@@ -499,8 +359,7 @@ def test_config_from_hf_layer_lists_and_parameter_counts():
 
 
 def test_the_benchmarks_cut_is_a_share_of_the_published_model():
-    with open(CONFIG) as f:
-        d = json.load(f)
+    d = H.benchmark_config(CONFIG)
     cfg = mt.model_config(d)
     assert cfg.layer_kinds == ("kda", "kda", "kda", "mla") * 2
     assert cfg.ffn_kinds == ("dense",) + ("moe",) * 7
@@ -510,9 +369,7 @@ def test_the_benchmarks_cut_is_a_share_of_the_published_model():
     assert mt.kv_bytes_per_token(cfg) == 2304      # 2 x 576 lanes x 2 B
     # every catalog key is carried; what differs is what ``reduced`` names,
     # and inside the one nested group only the two layer lists
-    with open(CATALOG) as f:
-        cat = next(json.loads(l) for l in f
-                   if "Kimi-Linear-48B-A3B" in l)["config"]
+    cat = H.catalog_row("Kimi-Linear-48B-A3B-Instruct")["config"]
     assert {k for k in cat if d.get(k) != cat[k]} == set(d["reduced"]) \
         == set(REDUCED)
     la, pub = d["linear_attn_config"], cat["linear_attn_config"]
@@ -530,8 +387,7 @@ def test_the_benchmarks_cut_is_a_share_of_the_published_model():
     ("hidden_act", "gelu"), ("moe_router_activation_func", "softmax"),
     ("linear_attn_config", {"kda_layers": [1, 2], "full_attn_layers": [2]})])
 def test_config_from_hf_refuses_what_it_does_not_implement(key, value):
-    with pytest.raises(ValueError, match=key):
-        config_from_hf(dict(_published(), **{key: value}))
+    H.hf_refuses(_published(), {key: value}, key)
 
 
 def test_loader_names_reach_every_leaf():
@@ -539,13 +395,9 @@ def test_loader_names_reach_every_leaf():
     ``self_attn``, per-expert ``w1`` / ``w3`` / ``w2``, convolutions
     ``[C, 1, K]``, ``A_log`` ``[1, 1, H, 1]``) converts to the tree the
     runner serves, leaf for leaf."""
-    from deepspeed_tpu.checkpoint.hf_loader import (SPECIAL_HANDLERS,
-                                                    convert_hf_state)
     cfg = tiny(experts_held=None, experts_first=0)
     params = jax.tree_util.tree_map(np.asarray, mt.init_params(cfg, 1))
-    state = {"model.embed_tokens.weight": params["embed"]["embedding"],
-             "model.norm.weight": params["final_norm"]["scale"],
-             "lm_head.weight": params["lm_head"]["kernel"].T}
+    state = H.hf_trunk(params)
     for i, kind in enumerate(cfg.layer_kinds):
         p, pre = params[f"layer_{i}"], f"model.layers.{i}"
         state[f"{pre}.input_layernorm.weight"] = p["input_norm"]["scale"]
@@ -571,9 +423,8 @@ def test_loader_names_reach_every_leaf():
                 k["kv_a_proj"]["kernel"].T
             state[f"{a}.kv_a_layernorm.weight"] = k["kv_a_norm"]["scale"]
         if "mlp" in p:
-            for n in ("gate", "up", "down"):
-                state[f"{pre}.mlp.{n}_proj.weight"] = \
-                    p["mlp"][f"{n}_proj"]["kernel"].T
+            H.hf_projections(state, f"{pre}.mlp", p["mlp"],
+                             ("gate", "up", "down"))
             continue
         m = f"{pre}.block_sparse_moe"
         state[f"{m}.gate.weight"] = p["moe"]["gate"].T
@@ -581,17 +432,8 @@ def test_loader_names_reach_every_leaf():
         for n in ("gate", "up", "down"):
             state[f"{m}.shared_experts.{n}_proj.weight"] = \
                 p[f"shared_{n}_proj"]["kernel"].T
-        for e in range(cfg.num_experts):
-            for ours, theirs in (("wi_gate", "w1"), ("wi_up", "w3"),
-                                 ("wo", "w2")):
-                state[f"{m}.experts.{e}.{theirs}.weight"] = \
-                    p["moe"][ours][e].T
-    hf_cfg = {"linear_attn_config": {"kda_layers": [1, 2, 3],
-                                     "full_attn_layers": [4]}}
-    got = convert_hf_state("kimi_linear",
-                           SPECIAL_HANDLERS["kimi_linear"](state, hf_cfg))
-    want = jax.tree_util.tree_leaves_with_path(params)
-    have = dict(jax.tree_util.tree_leaves_with_path(got))
-    assert len(have) == len(want)
-    for path, leaf in want:
-        assert np.array_equal(np.asarray(have[path]), leaf), path
+        H.hf_experts(state, f"{m}.experts", p["moe"],
+                     (("wi_gate", "w1"), ("wi_up", "w3"), ("wo", "w2")))
+    H.loader_reaches_every_leaf(
+        "kimi_linear", state, {"linear_attn_config": {
+            "kda_layers": [1, 2, 3], "full_attn_layers": [4]}}, params)

@@ -11,7 +11,6 @@ of the model-configs guide, the cache's one part, the refusals and the
 registry."""
 
 import dataclasses
-import json
 import os
 
 import jax
@@ -19,59 +18,39 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmark.model_types import lfm2_moe as mt
 from benchmark.reference import lfm2 as reference
-from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                        RaggedInferenceConfig)
+from deepspeed_tpu.inference.v2 import RaggedInferenceConfig
 from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
 from deepspeed_tpu.models.lfm2 import Lfm2Config, param_counts
 from deepspeed_tpu.models.registry import config_from_hf
+from family_harness import prompt_of
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-CONFIG = os.path.join(ROOT, "benchmark", "configs", "lfm2-24b-a2b.json")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = ("num_hidden_layers", "layer_types", "num_dense_layers")
-#: float32 engine against a float32 reference at highest precision: what
-#: is left is the order of the sums (a chunk's convolution against the
-#: whole sequence's, the grouped matmul against the dense mask), a few
-#: 1e-6 on logits of size 1
-TOL = 2e-4
 
 
 def tiny(**kw):
     return Lfm2Config.tiny(dtype=jnp.float32, param_dtype=jnp.float32, **kw)
 
 
+#: float32 engine against a float32 reference at highest precision: what
+#: is left is the order of the sums (a chunk's convolution against the
+#: whole sequence's, the grouped matmul against the dense mask), a few
+#: 1e-6 on logits of size 1
+FAMILY = H.Family(mt, tiny, tol=2e-4)
+engine = FAMILY.engine
+
+
 @pytest.fixture(scope="module")
 def model():
-    cfg = tiny()
-    return cfg, mt.init_params(cfg, 3)
-
-
-def engine(cfg, params, chunk=64, **kw):
-    kw.setdefault("max_seqs", 4)
-    return InferenceEngineV2(cfg, params, RaggedInferenceConfig(
-        chunk_size=chunk, block_size=16, num_blocks=24,
-        max_blocks_per_seq=6, decode_loop_steps=4, dtype="float32",
-        prefill_chunk_cap=0, **kw))
-
-
-def ref_logits(cfg, params, tokens, at):
-    out = mt.reference_logits(cfg)(params, jnp.asarray([tokens]),
-                                   jnp.asarray([at]))
-    return np.asarray(out)[0]
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 512, n).tolist()
+    return FAMILY.model()
 
 
 # ------------------------- (b) engine vs reference ------------------------ #
 
 
-@pytest.mark.parametrize("chunk", [64, 16], ids=["one-chunk", "three-chunks"])
-@pytest.mark.parametrize("decode", ["fused", "pipelined"])
+@H.chunk_and_decode
 def test_engine_logits_match_the_reference(model, chunk, decode):
     """A 37-token prompt prefilled in one chunk or in three of uneven
     real lengths (16, 16, 5: a chunk's convolution reads the inputs the
@@ -79,25 +58,8 @@ def test_engine_logits_match_the_reference(model, chunk, decode):
     loops of 4: the second reads carried inputs and K/V rows a flush
     lies behind) or step by step, then one more position's logits: each
     against the reference's forward pass over the whole sequence."""
-    cfg, params = model
-    prompt = prompt_of(37)
-    eng = engine(cfg, params, chunk)
-    lg = np.asarray(eng.put([7], [prompt])[7])
-    want = ref_logits(cfg, params, prompt, [len(prompt) - 1])[0]
-    assert np.abs(lg - want).max() < TOL
-    tok = int(np.argmax(lg))
-    if decode == "fused":
-        toks = list(eng.decode_batch([7], [tok], 4)[7])
-        toks += list(eng.decode_batch([7], [int(toks[-1])], 4)[7])
-    else:
-        toks = eng.decode_pipelined([7], [tok], 8)[7]
-    seq = prompt + [tok] + [int(t) for t in toks]
-    at = list(range(len(prompt), len(seq)))
-    want = ref_logits(cfg, params, seq, at)
-    assert [int(t) for t in toks] == np.argmax(want[:-1], -1).tolist()
-    lg = np.asarray(eng.put([7], [[int(toks[-1])]])[7])
-    assert np.abs(lg - want[-1]).max() < TOL
-    stats = eng.pipeline_stats
+    stats = FAMILY.serve_against_reference(model, chunk, decode,
+                                           loops=(4, 4)).pipeline_stats
     # 8 decode steps and the one-token step: a slot live in each, holding
     # 4 conv layers x 2 carried inputs x 64 lanes x 4 B and nothing else
     assert stats["state_slots_live"] == 9
@@ -121,37 +83,13 @@ def test_two_sequences_decode_as_they_do_alone_and_a_slot_starts_fresh(model):
     one of them has run out of prompt the other's row has ``n_tokens``
     0 and keeps its carried inputs), and then a third in a slot the
     first one left: each decodes what it decodes alone (a fresh row does
-    not see the last tenant's carried inputs)."""
-    cfg, params = model
-    prompts = {1: prompt_of(21, seed=1), 2: prompt_of(43, seed=2),
-               3: prompt_of(18, seed=3)}
+    not see the last tenant's carried inputs, which are still there)."""
+    def garbage(eng, slot):
+        assert float(jnp.abs(eng._kv_data.conv[:, slot]).max()) > 0
 
-    def alone(uid):
-        # one engine serves the three of them one after the other: its
-        # programs compile once (a fresh slot's state is this test's own
-        # claim, so the first of them is also served by a fresh engine)
-        tok = int(np.argmax(np.asarray(
-            solo.put([uid], [prompts[uid]])[uid])))
-        toks = [tok] + [int(t) for t in
-                        solo.decode_batch([uid], [tok], 4)[uid]]
-        solo.flush(uid)
-        return toks
-
-    solo = engine(cfg, params, 16, max_seqs=2)
-    want = {u: alone(u) for u in (1, 2, 3)}
-    eng = engine(cfg, params, 16, max_seqs=2)
-    out = eng.put([1, 2], [prompts[1], prompts[2]])
-    first = {u: int(np.argmax(np.asarray(out[u]))) for u in (1, 2)}
-    got = eng.decode_batch([1, 2], [first[1], first[2]], 4)
-    for u in (1, 2):
-        assert [first[u]] + [int(t) for t in got[u]] == want[u]
-    slot = eng.state.sequences[1].state_slot
-    eng.flush(1)
-    assert float(jnp.abs(eng._kv_data.conv[:, slot]).max()) > 0  # garbage
-    tok = int(np.argmax(np.asarray(eng.put([3], [prompts[3]])[3])))
+    eng, slot = FAMILY.two_sequences_decode_as_alone(
+        model, (21, 43, 18), after_flush=garbage)
     assert eng.state.sequences[3].state_slot == slot
-    assert [tok] + [int(t) for t in eng.decode_batch([3], [tok], 4)[3]] \
-        == want[3]
 
 
 def test_an_idle_row_of_a_step_keeps_its_carried_inputs(model):
@@ -256,38 +194,12 @@ def test_the_pool_and_the_dispatch_at_three_taps():
 
 def test_decode_through_the_conv_kernel_serves_the_jnp_paths_tokens(
         model, monkeypatch):
-    """The decode steps' convolution through the in-place Pallas call
-    (forced and interpreted here; on the chip platform and shape pick it)
-    after a chunked prefill: 4 steps of the fused loop and 5 step by step
-    give the jnp path's tokens and leave its pool alike (to float32
-    rounding: inside a step program XLA's CPU backend contracts the taps'
-    multiply-adds where it fuses them), and the engine counts the
-    layer-steps."""
-    from deepspeed_tpu.ops.kernels import short_conv
-    cfg, params = model
-    prompts = {5: prompt_of(21, seed=4), 6: prompt_of(9, seed=5)}
-
-    def serve():
-        eng = engine(cfg, params, 16)
-        first = {u: int(np.argmax(np.asarray(lg)))
-                 for u, lg in eng.put(list(prompts),
-                                      list(prompts.values())).items()}
-        out = eng.decode_batch([5, 6], [first[5], first[6]], 4)
-        toks = {u: [first[u]] + [int(t) for t in out[u]] for u in prompts}
-        toks[6] += [int(t) for t in
-                    eng.decode_pipelined([6], [toks[6][-1]], 5)[6]]
-        st = eng.pipeline_stats
-        return toks, np.asarray(eng._kv_data.conv), \
-            (st["conv_steps"], st["conv_steps_in_place"])
-
-    want_toks, want_pool, counted = serve()
-    assert counted == (9 * 4, 0)
-    monkeypatch.setattr(short_conv, "decode_uses_kernel",
-                        lambda *a, **k: True)
-    toks, pool, counted = serve()
-    assert toks == want_toks
-    assert np.allclose(pool, want_pool, rtol=1e-4, atol=1e-5)
-    assert counted == (9 * 4, 9 * 4)
+    """``Family.decode_through_the_conv_kernel``; the engine counts the
+    layer-steps, in place when the kernel took them."""
+    plain, forced = FAMILY.decode_through_the_conv_kernel(model, monkeypatch)
+    assert (plain["conv_steps"], plain["conv_steps_in_place"]) == (9 * 4, 0)
+    assert (forced["conv_steps"], forced["conv_steps_in_place"]) \
+        == (9 * 4, 9 * 4)
 
 
 # ------------------------------ (d) the router ---------------------------- #
@@ -422,53 +334,25 @@ def test_two_recurrent_kinds_in_one_model_are_refused_by_name():
 # ------------------------------ (g) refusals ------------------------------ #
 
 
-@pytest.mark.parametrize("feature, kw, call", [
-    ("prefix_cache", dict(prefix_cache=True), None),
-    ("spec_decode", dict(spec_decode="ngram"), None),
-    ("kv_cache_dtype='int8'", dict(kv_cache_dtype="int8"), None),
-    ("tp_size > 1", dict(tp_size=2, max_seqs=2), None),
-    ("seq_size > 1", dict(seq_size=2, max_seqs=2), None),
-    ("ep_size > 1", dict(ep_size=2, max_seqs=2), None),
-    ("handoff_out", {}, ([1],)), ("handoff_in", {}, ({},)),
-    ("drain", {}, ()), ("replay", {}, ({},)),
-    ("attach_draft", {}, "model"), ("decode_spec", {}, ([1], [3], 2)),
-    ("pause", {}, (1,)), ("resume", {}, (1,))])
+@pytest.mark.parametrize("feature, kw, call", H.REFUSALS)
 def test_what_a_conv_model_refuses(model, feature, kw, call):
     """What would need a snapshot, a rewind or a shard of the carried
     inputs refuses by the feature's name and the layer kind ``'conv'``,
     in the recurrent kinds' one wording: construction options by
     ``config.validate``, calls by the engine."""
     from deepspeed_tpu.inference.v2.config import stateful_refusal
-    cfg, params = model
-    if call is None:
-        with pytest.raises(ValueError) as err:
-            engine(cfg, params, **kw)
-    else:
-        eng = engine(cfg, params)
-        eng.put([1], [prompt_of(9)])
-        with pytest.raises(NotImplementedError) as err:
-            getattr(eng, feature)(*((cfg, params) if call == "model"
-                                    else call))
-    assert str(err.value) == stateful_refusal(feature, "conv")
-    assert "('conv')" in str(err.value)
+    said = FAMILY.refusal(model, feature, kw, call)
+    assert said == stateful_refusal(feature, "conv")
+    assert "('conv')" in said
 
 
 # ------------------------- (h) registry and the cut ----------------------- #
 
 
-def _published():
-    """The catalog's ``config`` as the configuration file carries it, the
-    reduced keys back at their published values."""
-    with open(CATALOG) as f:
-        row = next(r for r in map(json.loads, f)
-                   if r["name"] == "LFM2-24B-A2B")
-    return row
-
-
 def test_config_from_hf_layer_lists_and_parameter_counts():
-    if not os.path.exists(CATALOG):
+    if not os.path.exists(H.CATALOG):
         pytest.skip("no catalog on this machine")
-    row = _published()
+    row = H.catalog_row("LFM2-24B-A2B")
     name, cfg = config_from_hf(row["config"])
     assert name == "lfm2_moe" and isinstance(cfg, Lfm2Config)
     assert len(cfg.layer_kinds) == 40
@@ -491,11 +375,10 @@ def test_config_from_hf_layer_lists_and_parameter_counts():
 
 
 def test_the_benchmarks_cut_is_layers_one_to_nine_of_the_published_model():
-    if not os.path.exists(CATALOG):
+    if not os.path.exists(H.CATALOG):
         pytest.skip("no catalog on this machine")
-    row = _published()
-    with open(CONFIG) as f:
-        cut = json.load(f)
+    row = H.catalog_row("LFM2-24B-A2B")
+    cut = H.benchmark_config("lfm2-24b-a2b.json")
     assert cut["_source"] == row["source_url"]
     for key, value in row["config"].items():
         if key not in REDUCED:
@@ -525,10 +408,8 @@ def test_the_benchmarks_cut_is_layers_one_to_nine_of_the_published_model():
 def test_config_from_hf_refuses_what_it_does_not_implement(key, value):
     hf = dict(model_type="lfm2_moe", num_hidden_layers=2,
               layer_types=["conv", "full_attention"])
-    config_from_hf(hf)
-    with pytest.raises(ValueError, match=key if key != "rope_parameters"
-                       else "rotary"):
-        config_from_hf(dict(hf, **{key: value}))
+    H.hf_refuses(hf, {key: value},
+                 key if key != "rope_parameters" else "rotary")
 
 
 def test_the_dense_sibling_leaves_its_width_key_out_and_is_refused():

@@ -13,19 +13,17 @@ the cache's two pools, the refusals of the new kind, and that the seven
 other families build what they built."""
 
 import dataclasses
-import json
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmark.model_types import mellum as mt
 from benchmark.reference import mellum as reference
-from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                        RaggedInferenceConfig)
+from deepspeed_tpu.inference.v2 import RaggedInferenceConfig
 from deepspeed_tpu.inference.v2.kv_cache import window_blocks
 from deepspeed_tpu.inference.v2.model_runner import (RaggedBatch,
                                                      window_tables)
@@ -33,19 +31,11 @@ from deepspeed_tpu.models.llama import yarn_frequencies
 from deepspeed_tpu.models.mellum import (Mellum, MellumConfig, YarnRope,
                                          param_counts)
 from deepspeed_tpu.models.registry import config_from_hf
+from family_harness import prompt_of
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-CONFIG = os.path.join(ROOT, "benchmark", "configs", "mellum2-12b-a2.5b.json")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+CONFIG = "mellum2-12b-a2.5b.json"
 REDUCED = ("num_hidden_layers", "layer_types", "mlp_layer_types",
            "num_experts", "vocab_size")
-#: float32 engine against a float32 reference at highest precision: what
-#: is left is the order of the sums (the paged attention's online softmax
-#: over tiles against the dense one, the grouped matmul against the dense
-#: mask, the rotary table built by numpy against jax.numpy's), a few 1e-6
-#: on logits of size 4
-TOL = 2e-4
 WINDOW, BLOCK, CHUNK, LOOP = 8, 4, 8, 4
 
 
@@ -56,18 +46,22 @@ def tiny(**kw):
                              **kw)
 
 
+#: float32 engine against a float32 reference at highest precision: what
+#: is left is the order of the sums (the paged attention's online softmax
+#: over tiles against the dense one, the grouped matmul against the dense
+#: mask, the rotary table built by numpy against jax.numpy's), a few 1e-6
+#: on logits of size 4. Blocks of 4 rows under a window of 8, so that a
+#: slot of the window pool wraps inside every test
+FAMILY = H.Family(mt, tiny, tol=2e-4, chunk_size=CHUNK, block_size=BLOCK,
+                  num_blocks=64, max_blocks_per_seq=24,
+                  decode_loop_steps=LOOP)
+TOL = FAMILY.tol
+engine = FAMILY.engine
+
+
 @pytest.fixture(scope="module")
 def model():
-    cfg = tiny()
-    return cfg, mt.init_params(cfg, 3)
-
-
-def engine(cfg, params, chunk=CHUNK, **kw):
-    kw.setdefault("max_seqs", 4)
-    return InferenceEngineV2(cfg, params, RaggedInferenceConfig(
-        chunk_size=chunk, block_size=BLOCK, num_blocks=64,
-        max_blocks_per_seq=24, decode_loop_steps=LOOP, dtype="float32",
-        prefill_chunk_cap=0, **kw))
+    return FAMILY.model()
 
 
 @pytest.fixture(scope="module")
@@ -78,37 +72,20 @@ def shared_engine(model):
 
 
 def ref_logits(cfg, params, tokens, at, **variant):
+    """The reference's logits, or those of the reference with ``variant``
+    wrong."""
+    if not variant:
+        return FAMILY.ref_logits(cfg, params, tokens, at)
     fn = jax.jit(lambda p, t, a: reference.logits(
         p, t, a, **mt.reference_dims(cfg), **variant))
     return np.asarray(fn(params, jnp.asarray([tokens]),
                          jnp.asarray([at])))[0]
 
 
-def prompt_of(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 512, n).tolist()
-
-
-def serve(eng, cfg, params, uid, prompt, loops=2, decode="fused"):
-    """Prefill ``prompt``, decode ``loops`` fused loops (or as many single
-    steps), one more position; returns the worst logit error against the
-    reference and whether every served token was the reference's."""
-    lg = np.asarray(eng.put([uid], [prompt])[uid])
-    want = ref_logits(cfg, params, prompt, [len(prompt) - 1])[0]
-    worst = np.abs(lg - want).max()
-    tok = int(np.argmax(lg))
-    n = loops * LOOP
-    if decode == "fused":
-        toks = []
-        for _ in range(loops):
-            toks += [int(t) for t in eng.decode_batch(
-                [uid], [toks[-1] if toks else tok], LOOP)[uid]]
-    else:
-        toks = [int(t) for t in eng.decode_pipelined([uid], [tok], n)[uid]]
-    seq = prompt + [tok] + toks
-    want = ref_logits(cfg, params, seq, list(range(len(prompt), len(seq))))
-    same = toks == np.argmax(want[:-1], -1).tolist()
-    lg = np.asarray(eng.put([uid], [[toks[-1]]])[uid])
-    return max(worst, np.abs(lg - want[-1]).max()), same
+def serve(eng, model, uid, prompt, loops=2, decode="fused"):
+    """``Family.walk`` at ``loops`` fused loops of ``LOOP`` steps (or as
+    many single steps)."""
+    FAMILY.walk(eng, model, uid, prompt, decode, (LOOP,) * loops)
 
 
 # ------------------------- (a) engine vs reference ------------------------ #
@@ -123,11 +100,9 @@ def test_engine_logits_match_the_reference(model, chunk, decode):
     against the reference's forward pass over the whole sequence (dense
     attention under each layer's own mask, no cache). A window layer's
     slot holds 16 rows (72 at the single chunk), so 46 positions wrap it."""
-    cfg, params = model
-    eng = engine(cfg, params, chunk)
+    eng = engine(*model, chunk)
     assert eng.kv_cache.window_blocks == -(-(WINDOW - 1 + chunk) // BLOCK)
-    err, same = serve(eng, cfg, params, 7, prompt_of(37), decode=decode)
-    assert err < TOL and same
+    serve(eng, model, 7, prompt_of(37), decode=decode)
     stats = eng.pipeline_stats
     # the full layer's rows: one layer's worth, K and V, 2 heads x 16
     live = (sum(range(38, 46)) if decode == "pipelined"
@@ -155,12 +130,12 @@ def test_every_residue_of_a_start_position(model, shared_engine, length):
     slot's 16 rows, so that the last chunk, the two flushes and the single
     step after them start at every residue of the slot (and of a block),
     aligned or not; each tenant takes the slot its predecessor left full."""
-    cfg, params = model
     eng = shared_engine
     assert eng.kv_cache.window_blocks == 4
-    err, same = serve(eng, cfg, params, length, prompt_of(length, length))
-    eng.flush(length)
-    assert err < TOL and same
+    try:
+        serve(eng, model, length, prompt_of(length, length))
+    finally:
+        eng.flush(length)
 
 
 def test_engine_through_the_kernels_matches_the_reference():
@@ -170,13 +145,10 @@ def test_engine_through_the_kernels_matches_the_reference():
     its window reaches, and mask by position what a wrapped tile holds."""
     cfg = tiny(attn_head_dim=64)
     params = mt.init_params(cfg, 5)
-    eng = InferenceEngineV2(cfg, params, RaggedInferenceConfig(
-        max_seqs=4, chunk_size=8, block_size=8, num_blocks=32,
-        max_blocks_per_seq=12, decode_loop_steps=LOOP, dtype="float32",
-        prefill_chunk_cap=0, attention_impl="paged_flash"))
+    eng = engine(cfg, params, block_size=8, num_blocks=32,
+                 max_blocks_per_seq=12, attention_impl="paged_flash")
     assert eng.kv_cache.window_blocks == 2
-    err, same = serve(eng, cfg, params, 1, prompt_of(29))
-    assert err < TOL and same
+    serve(eng, (cfg, params), 1, prompt_of(29))
 
 
 def test_flax_model_and_runner_read_one_tree(model):
@@ -200,9 +172,8 @@ def test_a_slots_second_tenant_reads_none_of_the_firsts_rows(model):
     eng.put([1], [prompt_of(40)])
     slot = eng.state.sequences[1].state_slot
     eng.flush(1)
-    err, same = serve(eng, cfg, params, 2, prompt_of(5, 9), loops=1)
+    serve(eng, model, 2, prompt_of(5, 9), loops=1)
     assert eng.state.sequences[2].state_slot == slot
-    assert err < TOL and same
     rows = np.asarray(eng._kv_data.window)[:, :, slot * 16:(slot + 1) * 16]
     assert np.abs(rows).min(axis=-1).min() > 0     # every row was written
 
@@ -454,10 +425,7 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
     assert whole_cfg.norm_topk_prob and whole_cfg.router_score == "softmax"
 
     def share(first, held):
-        cfg = dataclasses.replace(whole_cfg, experts_first=first,
-                                  experts_held=held)
-        return cfg, dict(whole, **{n: whole[n][first:first + held]
-                                   for n in ("wi_gate", "wi_up", "wo")})
+        return H.share_of(whole_cfg, whole, first, held)
 
     with jax.default_matmul_precision("highest"):
         uncut = reference._sparse_mlp(whole, h, top_k=2, first=0)
@@ -466,11 +434,7 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
             cfg, p = share(first, 4)
             parts.append(_moe_mlp(p, h, cfg, jnp.float32)[0])
             refs.append(reference._sparse_mlp(p, h, top_k=2, first=first))
-    for part, ref in zip(parts, refs):
-        assert float(jnp.abs(part).max()) > 1e-3      # each share does work
-        assert float(jnp.abs(part - ref).max()) < 1e-5
-    assert float(jnp.abs(sum(parts) - uncut).max()) < 1e-5
-    assert float(jnp.abs(sum(refs) - uncut).max()) < 1e-5
+    H.shares_add_up(parts, refs, uncut)
     # the top-2 weights are renormalised: they sum to 1 a token over the
     # two shares together
     probs = jax.nn.softmax(h @ whole["gate"], axis=-1)
@@ -481,34 +445,15 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
 # ------------------------------ (f) refusals ------------------------------ #
 
 
-@pytest.mark.parametrize("feature, kw, call", [
-    ("prefix_cache", dict(prefix_cache=True), None),
-    ("spec_decode", dict(spec_decode="ngram"), None),
-    ("kv_cache_dtype='int8'", dict(kv_cache_dtype="int8"), None),
-    ("tp_size > 1", dict(tp_size=2, max_seqs=2), None),
-    ("seq_size > 1", dict(seq_size=2, max_seqs=2), None),
-    ("ep_size > 1", dict(ep_size=2, max_seqs=2), None),
-    ("handoff_out", {}, ([1],)), ("handoff_in", {}, ({},)),
-    ("drain", {}, ()), ("replay", {}, ({},)),
-    ("attach_draft", {}, "model"), ("decode_spec", {}, ([1], [3], 2)),
-    ("pause", {}, (1,)), ("resume", {}, (1,))])
+@pytest.mark.parametrize("feature, kw, call", H.REFUSALS)
 def test_what_needs_a_windows_rows_elsewhere_refuses_by_name(
         model, feature, kw, call):
     """One wording (``config.windowed_refusal``): construction options by
     ``config.validate``, calls by the engine."""
     from deepspeed_tpu.inference.v2.config import windowed_refusal
-    cfg, params = model
-    if call is None:
-        with pytest.raises(ValueError) as err:
-            engine(cfg, params, **kw)
-    else:
-        eng = engine(cfg, params)
-        eng.put([1], [prompt_of(9)])
-        with pytest.raises(NotImplementedError) as err:
-            getattr(eng, feature)(*((cfg, params) if call == "model"
-                                    else call))
-    assert str(err.value) == windowed_refusal(feature)
-    assert "'swa'" in str(err.value)
+    said = FAMILY.refusal(model, feature, kw, call)
+    assert said == windowed_refusal(feature)
+    assert "'swa'" in said
 
 
 @pytest.mark.parametrize("other", ["mla", "kda", "mamba2"])
@@ -611,10 +556,8 @@ def test_one_window_for_every_layer_stays_on_the_paged_pool():
 
 
 def _published():
-    with open(CONFIG) as f:
-        d = json.load(f)
-    for key in ("num_hidden_layers", "num_experts", "vocab_size"):
-        d[key] = d[key + "_published"]
+    d = H.published(CONFIG, ("num_hidden_layers", "num_experts",
+                             "vocab_size"))
     d["layer_types"] = (d["layer_types"][:4] * 7)
     d["mlp_layer_types"] = ["sparse"] * 28
     return d
@@ -644,17 +587,14 @@ def test_config_from_hf_layer_lists_and_parameter_counts():
 
 
 def test_the_benchmarks_cut_is_a_share_of_the_published_model():
-    with open(CONFIG) as f:
-        d = json.load(f)
+    d = H.benchmark_config(CONFIG)
     cfg = mt.model_config(d)
     assert cfg.layer_kinds == ("swa", "swa", "swa", "attn") * 2
     assert (cfg.num_experts, cfg.held, cfg.vocab_size) == (64, 32, 49152)
     total, _ = param_counts(cfg)
     assert abs(total / 1.983e9 - 1) < 0.001        # 3.97 GB in bfloat16
     assert mt.kv_bytes_per_token(cfg) == 4096      # 2 x K, V x 4 x 128 x 2 B
-    with open(CATALOG) as f:
-        cat = next(json.loads(line) for line in f
-                   if '"Mellum2-12B-A2.5B-Instruct"' in line)
+    cat = H.catalog_row("Mellum2-12B-A2.5B-Instruct")
     assert d["_source"] == cat["source_url"]
     cat = cat["config"]
     assert {k for k in cat if d.get(k) != cat[k]} == set(d["reduced"]) \
@@ -679,43 +619,28 @@ def test_the_benchmarks_cut_is_a_share_of_the_published_model():
     ({"rope_parameters": {"sliding_attention": {"rope_type": "yarn"}}},
      "rope_parameters")])
 def test_config_from_hf_refuses_what_it_does_not_implement(change, match):
-    with pytest.raises(ValueError, match=match):
-        config_from_hf(dict(_published(), **change))
+    H.hf_refuses(_published(), change, match)
 
 
 def test_loader_names_reach_every_leaf():
     """A checkpoint named as Qwen3MoE's are (assumed: the family's config
     keys are that one's) converts to the tree the runner serves, leaf for
     leaf."""
-    from deepspeed_tpu.checkpoint.hf_loader import (SPECIAL_HANDLERS,
-                                                    convert_hf_state)
     cfg = tiny(experts_held=None, experts_first=0, num_layers=2,
                layer_kinds=("swa", "attn"))
     params = jax.tree_util.tree_map(np.asarray, mt.init_params(cfg, 1))
-    state = {"model.embed_tokens.weight": params["embed"]["embedding"],
-             "model.norm.weight": params["final_norm"]["scale"],
-             "lm_head.weight": params["lm_head"]["kernel"].T}
+    state = H.hf_trunk(params)
     for i in range(2):
         p, pre = params[f"layer_{i}"], f"model.layers.{i}."
         state[pre + "input_layernorm.weight"] = p["input_norm"]["scale"]
         state[pre + "post_attention_layernorm.weight"] = \
             p["post_attn_norm"]["scale"]
-        for n in "qkvo":
-            state[pre + f"self_attn.{n}_proj.weight"] = \
-                p["attn"][f"{n}_proj"]["kernel"].T
+        H.hf_projections(state, pre + "self_attn", p["attn"], "qkvo")
         for n in "qk":
             state[pre + f"self_attn.{n}_norm.weight"] = \
                 p["attn"][f"{n}_norm"]["scale"]
         state[pre + "mlp.gate.weight"] = p["moe"]["gate"].T
-        for e in range(8):
-            for hf, fw in (("gate_proj", "wi_gate"), ("up_proj", "wi_up"),
-                           ("down_proj", "wo")):
-                state[pre + f"mlp.experts.{e}.{hf}.weight"] = \
-                    p["moe"][fw][e].T
-    got = convert_hf_state("mellum", SPECIAL_HANDLERS["mellum"](
-        state, {"num_experts": 8}))
-    want = jax.tree_util.tree_leaves_with_path(params)
-    have = dict(jax.tree_util.tree_leaves_with_path(got))
-    assert len(have) == len(want)
-    for path, leaf in want:
-        assert np.array_equal(np.asarray(have[path]), leaf), path
+        H.hf_experts(state, pre + "mlp.experts", p["moe"], (
+            ("wi_gate", "gate_proj"), ("wi_up", "up_proj"),
+            ("wo", "down_proj")))
+    H.loader_reaches_every_leaf("mellum", state, {"num_experts": 8}, params)

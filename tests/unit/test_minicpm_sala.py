@@ -9,7 +9,6 @@ choices, forced blocks and the ``dense_len`` crossing all occur.
 """
 
 import dataclasses
-import json
 import os
 
 import jax
@@ -17,20 +16,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmark.model_types import minicpm_sala as mt
 from benchmark.reference import minicpm_sala as reference
-from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                        RaggedInferenceConfig)
+from deepspeed_tpu.inference.v2 import RaggedInferenceConfig
 from deepspeed_tpu.models.minicpm_sala import (MiniCPMSALA,
                                                MiniCPMSALAConfig,
                                                SparseConfig,
                                                lightning_log_decay,
                                                param_count, select_blocks)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-#: what the sibling families reach in float32 (the issue's figure)
-TOL = 2e-4
 BLOCK, CHUNK, LOOP = 16, 32, 8
 
 
@@ -40,18 +35,19 @@ def tiny(**kw):
     return MiniCPMSALAConfig.tiny(**kw)
 
 
+#: what the sibling families reach in float32 (the issue's figure); 80
+#: blocks for contexts of ~200, a fused loop of 8, prefill chunks capped at
+#: the default
+FAMILY = H.Family(mt, tiny, tol=2e-4, chunk_size=CHUNK, num_blocks=80,
+                  max_blocks_per_seq=20, decode_loop_steps=LOOP,
+                  prefill_chunk_cap=256, attention_impl="auto")
+TOL = FAMILY.tol
+engine = FAMILY.engine
+
+
 @pytest.fixture(scope="module")
 def model():
-    cfg = tiny()
-    return cfg, mt.init_params(cfg, 7)
-
-
-def engine(cfg, params, chunk=CHUNK, **kw):
-    kw.setdefault("attention_impl", "auto")
-    return InferenceEngineV2(cfg, params, RaggedInferenceConfig(
-        max_seqs=4, chunk_size=chunk, block_size=BLOCK, num_blocks=80,
-        max_blocks_per_seq=20, dtype="float32",
-        **dict({"decode_loop_steps": LOOP}, **kw)))
+    return FAMILY.model(seed=7)
 
 
 def ref_logits(cfg, params, tokens, at, **variant):
@@ -359,11 +355,9 @@ def test_what_needs_the_plane_or_the_state_elsewhere_refuses_at_construction(
         model, feature, kw):
     from deepspeed_tpu.inference.v2.config import (selecting_refusal,
                                                    stateful_refusal)
-    cfg, params = model
-    with pytest.raises(ValueError) as err:
-        engine(cfg, params, **kw)
-    assert stateful_refusal(feature, "lightning") in str(err.value)
-    assert selecting_refusal(feature) in str(err.value)
+    said = FAMILY.refusal(model, feature, kw, None)
+    assert stateful_refusal(feature, "lightning") in said
+    assert selecting_refusal(feature) in said
 
 
 def test_a_loop_longer_than_the_forced_window_refuses_at_construction(model):
@@ -411,10 +405,7 @@ def test_beside_another_familys_kind_refuses_at_construction(new, other):
 
 
 def _published():
-    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
-        row = next(r for r in map(json.loads, f)
-                   if r["name"] == "MiniCPM-SALA")
-    return row["config"]
+    return H.catalog_row("MiniCPM-SALA")["config"]
 
 
 def test_config_from_hf_reads_the_published_keys():
@@ -438,9 +429,7 @@ def test_config_from_hf_reads_the_published_keys():
     assert cfg.rms_eps == 1e-6 and not cfg.tie_embeddings
     assert round(param_count(cfg) / 1e6) == 9477
     # the benchmark's cut: layers 9-16, the published depth kept
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "minicpm-sala-9b.json")) as f:
-        cut = mt.model_config(json.load(f))
+    cut = mt.model_config(H.benchmark_config("minicpm-sala-9b.json"))
     assert cut.layer_kinds == cfg.layer_kinds[9:17] \
         == ("sparse",) + ("lightning",) * 6 + ("sparse",)
     assert cut.residual_scale == cfg.residual_scale
@@ -454,35 +443,25 @@ def test_config_from_hf_reads_the_published_keys():
     ({"lightning_scale": "1"}, "lightning_scale"),
     ({"sparse_config": {"kernel_size": 48}}, "kernel_size")])
 def test_config_from_hf_refuses_what_it_does_not_implement(change, match):
-    from deepspeed_tpu.models.registry import config_from_hf
     base = {"model_type": "minicpm_sala", "num_hidden_layers": 2,
             "mixer_types": ["minicpm4", "lightning-attn"]}
-    with pytest.raises(ValueError, match=match):
-        config_from_hf(dict(base, **change))
+    H.hf_refuses(base, change, match)
 
 
 def test_loader_names_reach_every_leaf(model):
-    from deepspeed_tpu.checkpoint.hf_loader import (SPECIAL_HANDLERS,
-                                                    convert_hf_state)
     cfg, params = model
     params = jax.tree_util.tree_map(np.asarray, params)
     mixers = ["minicpm4" if k == "sparse" else "lightning-attn"
               for k in cfg.layer_kinds]
-    state = {"model.embed_tokens.weight": params["embed"]["embedding"],
-             "model.norm.weight": params["final_norm"]["scale"],
-             "lm_head.weight": params["lm_head"]["kernel"].T}
+    state = H.hf_trunk(params)
     for i, kind in enumerate(cfg.layer_kinds):
         p, pre = params[f"layer_{i}"], f"model.layers.{i}."
         state[pre + "input_layernorm.weight"] = p["input_norm"]["scale"]
         state[pre + "post_attention_layernorm.weight"] = \
             p["post_attn_norm"]["scale"]
-        for n in ("gate", "up", "down"):
-            state[pre + f"mlp.{n}_proj.weight"] = \
-                p["mlp"][f"{n}_proj"]["kernel"].T
+        H.hf_projections(state, pre + "mlp", p["mlp"], ("gate", "up", "down"))
         mix = p["attn" if kind == "sparse" else "lin"]
-        for n in "qkvo":
-            state[pre + f"self_attn.{n}_proj.weight"] = \
-                mix[f"{n}_proj"]["kernel"].T
+        H.hf_projections(state, pre + "self_attn", mix, "qkvo")
         for n in "qk":
             state[pre + f"self_attn.{n}_norm.weight"] = \
                 mix[f"{n}_norm"]["scale"]
@@ -490,13 +469,8 @@ def test_loader_names_reach_every_leaf(model):
         state[pre + f"self_attn.{gate}.weight"] = mix["g_proj"]["kernel"].T
         if kind == "lightning":
             state[pre + "self_attn.o_norm.weight"] = mix["o_norm"]["scale"]
-    got = convert_hf_state("minicpm_sala", SPECIAL_HANDLERS["minicpm_sala"](
-        state, {"mixer_types": mixers}))
-    want = jax.tree_util.tree_leaves_with_path(params)
-    have = dict(jax.tree_util.tree_leaves_with_path(got))
-    assert len(have) == len(want)
-    for path, leaf in want:
-        assert np.array_equal(np.asarray(have[path]), leaf), path
+    H.loader_reaches_every_leaf("minicpm_sala", state,
+                                {"mixer_types": mixers}, params)
 
 
 def _select_case(case):

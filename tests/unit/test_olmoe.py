@@ -10,15 +10,16 @@ widths is ``tools/chip_parity.py --config olmoe-1b-7b``.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmark.reference import olmoe as reference
-from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                        RaggedInferenceConfig)
+from deepspeed_tpu.inference.v2 import RaggedInferenceConfig
 from deepspeed_tpu.models.mixtral import Mixtral, MixtralConfig
 
 #: same precision on both sides (float32 inputs, float32 accumulation,
@@ -88,11 +89,11 @@ def tokens_of(seed, shape):
     return np.random.RandomState(seed).randint(1, 500, shape).astype(np.int32)
 
 
-def make_engine(cfg, params, **kw):
-    kw.setdefault("dtype", "float32")
-    return InferenceEngineV2(cfg, params, RaggedInferenceConfig(
-        max_seqs=4, chunk_size=8, block_size=8, num_blocks=40,
-        max_blocks_per_seq=8, decode_loop_steps=4, **kw))
+#: chunks and blocks of 8 rows, so that two short prompts take several of
+#: each; prefill chunks capped at the default
+make_engine = functools.partial(
+    H.toy_engine, chunk_size=8, block_size=8, num_blocks=40,
+    max_blocks_per_seq=8, prefill_chunk_cap=256)
 
 
 # ------------------------- (i) the flax model ------------------------- #
@@ -277,29 +278,21 @@ def test_qk_norm_under_tensor_parallel_is_refused_at_construction():
 
 def _hf_state(cfg, params):
     """The tiny tree under the names an ``olmoe`` checkpoint uses."""
-    t = lambda a: np.asarray(a).T                     # noqa: E731
-    state = {"model.embed_tokens.weight": np.asarray(
-                 params["embed"]["embedding"]),
-             "model.norm.weight": np.asarray(params["final_norm"]["scale"]),
-             "lm_head.weight": t(params["lm_head"]["kernel"])}
+    params = jax.tree_util.tree_map(np.asarray, params)
+    state = H.hf_trunk(params)
     for i in range(cfg.num_layers):
         p, pre = params[f"layer_{i}"], f"model.layers.{i}."
-        state[pre + "input_layernorm.weight"] = np.asarray(
-            p["input_norm"]["scale"])
-        state[pre + "post_attention_layernorm.weight"] = np.asarray(
-            p["post_attn_norm"]["scale"])
-        for n in "qkvo":
-            state[pre + f"self_attn.{n}_proj.weight"] = t(
-                p["attn"][f"{n}_proj"]["kernel"])
+        state[pre + "input_layernorm.weight"] = p["input_norm"]["scale"]
+        state[pre + "post_attention_layernorm.weight"] = \
+            p["post_attn_norm"]["scale"]
+        H.hf_projections(state, pre + "self_attn", p["attn"], "qkvo")
         for n in "qk":
-            state[pre + f"self_attn.{n}_norm.weight"] = np.asarray(
-                p["attn"][f"{n}_norm"]["scale"])
-        state[pre + "mlp.gate.weight"] = t(p["moe"]["gate"])
-        for e in range(cfg.num_experts):
-            for hf, ours in (("gate_proj", "wi_gate"), ("up_proj", "wi_up"),
-                             ("down_proj", "wo")):
-                state[pre + f"mlp.experts.{e}.{hf}.weight"] = t(
-                    p["moe"][ours][e])
+            state[pre + f"self_attn.{n}_norm.weight"] = \
+                p["attn"][f"{n}_norm"]["scale"]
+        state[pre + "mlp.gate.weight"] = p["moe"]["gate"].T
+        H.hf_experts(state, pre + "mlp.experts", p["moe"], (
+            ("wi_gate", "gate_proj"), ("wi_up", "up_proj"),
+            ("wo", "down_proj")))
     return state
 
 

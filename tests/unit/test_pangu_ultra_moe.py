@@ -9,33 +9,24 @@ rule of the model-configs guide, the latent pool's shape and bytes, the MTP
 module, the loader's names, and every refusal a latent cache makes."""
 
 import dataclasses
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmark.model_types import pangu_ultra_moe as mt
 from benchmark.reference import pangu_ultra_moe as reference
-from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                        RaggedInferenceConfig)
+from deepspeed_tpu.inference.v2 import RaggedInferenceConfig
 from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
 from deepspeed_tpu.models.pangu_ultra_moe import (PanguUltraMoE,
                                                   PanguUltraMoEConfig,
                                                   param_counts)
 from deepspeed_tpu.models.registry import config_from_hf
+from family_harness import prompt_of
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-CONFIG = os.path.join(ROOT, "benchmark", "configs",
-                      "openpangu-ultra-moe-718b.json")
-#: float32 engine against a float32 reference at highest precision: what
-#: is left is the order of the sums (the absorbed products against the
-#: expanded ones, the grouped matmul against the dense mask), a few 1e-6
-#: on logits of size 4
-TOL = 2e-4
+CONFIG = "openpangu-ultra-moe-718b.json"
 
 
 def tiny(**kw):
@@ -45,60 +36,33 @@ def tiny(**kw):
                                     param_dtype=jnp.float32, **kw)
 
 
+#: float32 engine against a float32 reference at highest precision: what
+#: is left is the order of the sums (the absorbed products against the
+#: expanded ones, the grouped matmul against the dense mask), a few 1e-6
+#: on logits of size 4
+FAMILY = H.Family(mt, tiny, tol=2e-4)
+TOL = FAMILY.tol
+engine = FAMILY.engine
+
+
 @pytest.fixture(scope="module")
 def model():
-    cfg = tiny()
-    return cfg, mt.init_params(cfg, 3)
-
-
-def engine(cfg, params, chunk=64, **kw):
-    kw.setdefault("max_seqs", 4)
-    return InferenceEngineV2(cfg, params, RaggedInferenceConfig(
-        chunk_size=chunk, block_size=16, num_blocks=24,
-        max_blocks_per_seq=6, decode_loop_steps=4, dtype="float32",
-        prefill_chunk_cap=0, **kw))
-
-
-def ref_logits(cfg, params, tokens, at):
-    out = mt.reference_logits(cfg)(params, jnp.asarray([tokens]),
-                                   jnp.asarray([at]))
-    return np.asarray(out)[0]
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 512, n).tolist()
+    return FAMILY.model()
 
 
 # ------------------------- (a) engine vs reference ------------------------ #
 
 
-@pytest.mark.parametrize("chunk", [64, 16], ids=["one-chunk", "three-chunks"])
-@pytest.mark.parametrize("decode", ["fused", "pipelined"])
+@H.chunk_and_decode
 def test_engine_logits_match_the_reference(model, chunk, decode):
     """A 37-token prompt (three 16-token latent blocks) prefilled in one
     chunk or in three, 8 tokens decoded through the fused loop (its ring,
     then the flush into the pool) or step by step, then one more position's
     logits: each against the reference's forward pass over the whole
     sequence (expanded attention, no cache)."""
-    cfg, params = model
     prompt = prompt_of(37)
-    eng = engine(cfg, params, chunk)
-    lg = np.asarray(eng.put([7], [prompt])[7])
-    want = ref_logits(cfg, params, prompt, [len(prompt) - 1])[0]
-    assert np.abs(lg - want).max() < TOL
-    tok = int(np.argmax(lg))
-    if decode == "fused":
-        toks = eng.decode_batch([7], [tok], 8)[7]
-    else:
-        toks = eng.decode_pipelined([7], [tok], 8)[7]
-    seq = prompt + [tok] + list(toks)
-    at = list(range(len(prompt), len(seq)))
-    want = ref_logits(cfg, params, seq, at)
-    assert [int(t) for t in toks] == np.argmax(want[:-1], -1).tolist()
-    # the rows the decode left in the pool: the next position's logits
-    lg = np.asarray(eng.put([7], [[int(toks[-1])]])[7])
-    assert np.abs(lg - want[-1]).max() < TOL
-    stats = eng.pipeline_stats
+    stats = FAMILY.serve_against_reference(model, chunk,
+                                           decode).pipeline_stats
     assert stats["mla_prefill_tokens"] == len(prompt)
     # 8 decode steps (the fused loop counts the 37 rows settled at its
     # entry: its own ride the ring; a step alone its own row too), then
@@ -117,13 +81,7 @@ def test_engine_logits_match_the_reference(model, chunk, decode):
 
 
 def test_flax_model_and_runner_read_one_tree(model):
-    cfg, params = model
-    prompt = prompt_of(12, seed=8)
-    with jax.default_matmul_precision("highest"):
-        got = PanguUltraMoE(cfg).apply({"params": params},
-                                       jnp.asarray([prompt]))[0]
-    want = ref_logits(cfg, params, prompt, list(range(len(prompt))))
-    assert float(np.abs(np.asarray(got) - want).max()) < TOL
+    FAMILY.flax_model_reads_the_runners_tree(PanguUltraMoE, model)
 
 
 # ----------------------- (b) absorbed == expanded ------------------------ #
@@ -207,18 +165,7 @@ def test_engine_through_the_kernels_matches_the_reference(model):
     the prefill chunks through the paged kernel with the ONE plane as its
     K and its V operand, the decode steps through the latent decode
     kernel, per step and in the fused loop over its ring."""
-    cfg, params = model
-    prompt = prompt_of(21, seed=4)
-    eng = engine(cfg, params, 16, attention_impl="paged_flash")
-    lg = np.asarray(eng.put([3], [prompt])[3])
-    want = ref_logits(cfg, params, prompt, [len(prompt) - 1])[0]
-    assert np.abs(lg - want).max() < TOL
-    tok = int(np.argmax(lg))
-    toks = list(eng.decode_batch([3], [tok], 4)[3])
-    toks += list(eng.decode_pipelined([3], [int(toks[-1])], 2)[3])
-    seq = prompt + [tok] + toks
-    want = ref_logits(cfg, params, seq, list(range(len(prompt), len(seq))))
-    assert [int(t) for t in toks] == np.argmax(want[:-1], -1).tolist()
+    FAMILY.serve_through_the_kernels(model)
 
 
 # ------------------------------ (c) shares ------------------------------- #
@@ -235,11 +182,7 @@ def test_shares_add_up_to_the_uncut_layer():
     shared = ("shared_gate_proj", "shared_up_proj", "shared_down_proj")
 
     def share(first, held):
-        cfg = dataclasses.replace(whole_cfg, experts_first=first,
-                                  experts_held=held)
-        p = dict(whole["moe"], **{n: whole["moe"][n][first:first + held]
-                                  for n in ("wi_gate", "wi_up", "wo")})
-        return cfg, p
+        return H.share_of(whole_cfg, whole["moe"], first, held)
 
     with jax.default_matmul_precision("highest"):
         uncut = reference._sparse_mlp(whole["moe"], h, top_k=2, first=0,
@@ -252,11 +195,7 @@ def test_shares_add_up_to_the_uncut_layer():
             refs.append(reference._sparse_mlp(p, h, top_k=2, first=first,
                                               scaling=2.5))
         once = reference._swiglu(whole, h, shared)
-    for part, ref in zip(parts, refs):
-        assert float(jnp.abs(part).max()) > 1e-3      # each share does work
-        assert float(jnp.abs(part - ref).max()) < 1e-5
-    assert float(jnp.abs(sum(parts) + once - uncut).max()) < 1e-5
-    assert float(jnp.abs(sum(refs) + once - uncut).max()) < 1e-5
+    H.shares_add_up(parts, refs, uncut, once)
 
 
 # ----------------------------- (d) latent pool --------------------------- #
@@ -315,20 +254,11 @@ def test_pause_and_resume_carry_the_latent_rows(model):
 # ------------------------------ (e) refusals ----------------------------- #
 
 
-@pytest.mark.parametrize("feature, kw", [
-    ("prefix_cache", dict(prefix_cache=True)),
-    ("spec_decode", dict(spec_decode="ngram")),
-    ("kv_cache_dtype='int8'", dict(kv_cache_dtype="int8")),
-    ("tp_size > 1", dict(tp_size=2, max_seqs=2)),
-    ("seq_size > 1", dict(seq_size=2, max_seqs=2)),
-    ("ep_size > 1", dict(ep_size=2, max_seqs=2)),
-])
+@pytest.mark.parametrize("feature, kw", H.CONSTRUCTION_REFUSALS)
 def test_construction_refuses_what_the_latent_plane_cannot_do(model, feature,
                                                               kw):
-    cfg, params = model
-    with pytest.raises(ValueError) as err:
-        engine(cfg, params, **kw)
-    assert feature in str(err.value) and "'mla'" in str(err.value)
+    said = FAMILY.refusal(model, feature, kw, None)
+    assert feature in said and "'mla'" in said
 
 
 @pytest.mark.parametrize("call", [
@@ -338,15 +268,8 @@ def test_calls_refuse_what_the_latent_plane_cannot_do(model, call):
     """``attach_draft`` is also how an MTP draft would arrive: speculation
     with one refuses by name until the loop hands back its last hidden
     state (PERF.md section 7)."""
-    cfg, params = model
-    eng = engine(cfg, params)
-    eng.put([1], [prompt_of(9)])
-    args = {"handoff_out": ([1],), "handoff_in": ({},), "drain": (),
-            "replay": ({},), "attach_draft": (cfg, params),
-            "decode_spec": ([1], [3], 2)}
-    with pytest.raises(NotImplementedError) as err:
-        getattr(eng, call)(*args[call])
-    assert call in str(err.value) and "'mla'" in str(err.value)
+    said = FAMILY.refusal(model, call, {}, H.CALL_ARGS[call])
+    assert call in said and "'mla'" in said
 
 
 def test_latent_layers_do_not_mix_with_other_kinds(model):
@@ -462,15 +385,9 @@ def test_mtp_logits_match_the_reference():
 
 
 def _published():
-    """The catalog's ``config`` as the configuration file carries it, the
-    reduced keys back at their published values."""
-    with open(CONFIG) as f:
-        d = json.load(f)
-    for key in ("num_hidden_layers", "first_k_dense_replace",
-                "n_routed_experts", "vocab_size",
-                "num_nextn_predict_layers"):
-        d[key] = d[key + "_published"]
-    return d
+    return H.published(CONFIG, (
+        "num_hidden_layers", "first_k_dense_replace", "n_routed_experts",
+        "vocab_size", "num_nextn_predict_layers"))
 
 
 def test_config_from_hf_layer_lists_and_parameter_counts():
@@ -493,8 +410,7 @@ def test_config_from_hf_layer_lists_and_parameter_counts():
 
 
 def test_the_benchmarks_cut_is_a_share_of_the_published_model():
-    with open(CONFIG) as f:
-        d = json.load(f)
+    d = H.benchmark_config(CONFIG)
     cfg = mt.model_config(d)
     assert cfg.ffn_kinds == ("dense", "moe", "moe", "moe", "moe")
     assert (cfg.num_experts, cfg.held, cfg.vocab_size) == (256, 8, 19200)
@@ -502,9 +418,7 @@ def test_the_benchmarks_cut_is_a_share_of_the_published_model():
     assert abs(total / 3.41e9 - 1) < 0.005         # 6.82 GB in bfloat16
     assert mt.kv_bytes_per_token(cfg) == 5760      # 5 x 576 lanes x 2 B
     # every catalog key is carried, the widths unchanged
-    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
-        cat = next(json.loads(l) for l in f
-                   if "openPangu-Ultra-MoE-718B" in l)["config"]
+    cat = H.catalog_row("openPangu-Ultra-MoE-718B")["config"]
     reduced = set(d["reduced"])
     assert {k for k in cat if d.get(k) != cat[k]} == reduced
 
@@ -512,25 +426,20 @@ def test_the_benchmarks_cut_is_a_share_of_the_published_model():
 @pytest.mark.parametrize("key, value", [
     ("attention_bias", True), ("rope_scaling", {"type": "yarn"})])
 def test_config_from_hf_refuses_what_it_does_not_implement(key, value):
-    with pytest.raises(ValueError, match=key):
-        config_from_hf(dict(_published(), **{key: value}))
+    H.hf_refuses(_published(), {key: value}, key)
 
 
 def test_loader_names_reach_every_leaf():
     """A checkpoint named as the family's are (per-expert matrices, the
     MTP module as layer ``num_hidden_layers``) converts to the tree the
     runner serves, leaf for leaf."""
-    from deepspeed_tpu.checkpoint.hf_loader import (SPECIAL_HANDLERS,
-                                                    convert_hf_state)
     cfg = tiny(experts_held=None, experts_first=0, nextn_layers=1)
     params = jax.tree_util.tree_map(np.asarray, mt.init_params(cfg, 1))
     norms = {"input_norm": "input_layernorm",
              "attn_branch_norm": "post_attention_layernorm",
              "post_attn_norm": "pre_mlp_layernorm",
              "mlp_branch_norm": "post_mlp_layernorm"}
-    state = {"model.embed_tokens.weight": params["embed"]["embedding"],
-             "model.norm.weight": params["final_norm"]["scale"],
-             "lm_head.weight": params["lm_head"]["kernel"].T}
+    state = H.hf_trunk(params)
 
     def block(p, pre):
         for ours, theirs in norms.items():
@@ -545,19 +454,16 @@ def test_loader_names_reach_every_leaf():
             state[f"{pre}.self_attn.{n}_layernorm.weight"] = \
                 a[f"{n}_norm"]["scale"]
         if "mlp" in p:
-            for n in ("gate", "up", "down"):
-                state[f"{pre}.mlp.{n}_proj.weight"] = \
-                    p["mlp"][f"{n}_proj"]["kernel"].T
+            H.hf_projections(state, f"{pre}.mlp", p["mlp"],
+                             ("gate", "up", "down"))
             return
         state[f"{pre}.mlp.gate.weight"] = p["moe"]["gate"].T
         for n in ("gate", "up", "down"):
             state[f"{pre}.mlp.shared_experts.{n}_proj.weight"] = \
                 p[f"shared_{n}_proj"]["kernel"].T
-        for e in range(cfg.num_experts):
-            for ours, theirs in (("wi_gate", "gate_proj"),
-                                 ("wi_up", "up_proj"), ("wo", "down_proj")):
-                state[f"{pre}.mlp.experts.{e}.{theirs}.weight"] = \
-                    p["moe"][ours][e].T
+        H.hf_experts(state, f"{pre}.mlp.experts", p["moe"], (
+            ("wi_gate", "gate_proj"), ("wi_up", "up_proj"),
+            ("wo", "down_proj")))
 
     for i in range(3):
         block(params[f"layer_{i}"], f"model.layers.{i}")
@@ -567,11 +473,5 @@ def test_loader_names_reach_every_leaf():
         state[f"model.layers.3.{n}.weight"] = m[n]["scale"]
     state["model.layers.3.eh_proj.weight"] = m["eh_proj"]["kernel"].T
     state["model.layers.3.shared_head.norm.weight"] = m["final_norm"]["scale"]
-    hf_cfg = {"num_hidden_layers": 3}
-    got = convert_hf_state("pangu_ultra_moe",
-                           SPECIAL_HANDLERS["pangu_ultra_moe"](state, hf_cfg))
-    want = jax.tree_util.tree_leaves_with_path(params)
-    have = dict(jax.tree_util.tree_leaves_with_path(got))
-    assert len(have) == len(want)
-    for path, leaf in want:
-        assert np.array_equal(np.asarray(have[path]), leaf), path
+    H.loader_reaches_every_leaf("pangu_ultra_moe", state,
+                                {"num_hidden_layers": 3}, params)

@@ -6,29 +6,21 @@ top-2, 4 layers in the 1 : 3 pattern. Logits against the plain reference
 the token-by-token recurrence, the share rule of the model-configs guide,
 the state pool's hygiene, and every refusal a stateful model makes."""
 
-import dataclasses
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_harness as H
 from benchmark.model_types import solar_open2 as mt
 from benchmark.reference import solar_open2 as reference
-from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
-                                        RaggedInferenceConfig)
 from deepspeed_tpu.models.registry import config_from_hf
 from deepspeed_tpu.models.solar_open2 import SolarOpen2Config, param_counts
 from deepspeed_tpu.ops.kernels import delta_rule as dr
+from family_harness import prompt_of
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-#: float32 engine against a float32 reference at highest precision: what
-#: is left is the order of the sums (chunked against token by token, the
-#: grouped matmul against the dense mask), a few 1e-6 on logits of size 3
-TOL = 2e-4
+CONFIG = "solar-open2-250b.json"
 
 
 def tiny(**kw):
@@ -36,58 +28,31 @@ def tiny(**kw):
                                  param_dtype=jnp.float32, **kw)
 
 
+#: float32 engine against a float32 reference at highest precision: what
+#: is left is the order of the sums (chunked against token by token, the
+#: grouped matmul against the dense mask), a few 1e-6 on logits of size 3.
+#: Eight slots over 40 blocks, prefill chunks capped at the default
+FAMILY = H.Family(mt, tiny, tol=2e-4, max_seqs=8, num_blocks=40,
+                  max_blocks_per_seq=8, prefill_chunk_cap=256)
+engine, ref_logits = FAMILY.engine, FAMILY.ref_logits
+
+
 @pytest.fixture(scope="module")
 def model():
-    cfg = tiny()
-    return cfg, mt.init_params(cfg, 3)
-
-
-def engine(cfg, params, chunk=64, **kw):
-    kw.setdefault("max_seqs", 8)
-    return InferenceEngineV2(cfg, params, RaggedInferenceConfig(
-        chunk_size=chunk, block_size=16, num_blocks=40,
-        max_blocks_per_seq=8, decode_loop_steps=4, dtype="float32", **kw))
-
-
-def ref_logits(cfg, params, tokens, at):
-    out = mt.reference_logits(cfg)(params, jnp.asarray([tokens]),
-                                   jnp.asarray([at]))
-    return np.asarray(out)[0]
-
-
-def prompt_of(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 512, n).tolist()
+    return FAMILY.model()
 
 
 # ------------------------- (a) engine vs reference ------------------------ #
 
 
-@pytest.mark.parametrize("chunk", [64, 16], ids=["one-chunk", "three-chunks"])
-@pytest.mark.parametrize("decode", ["fused", "pipelined"])
+@H.chunk_and_decode
 def test_engine_logits_match_the_reference(model, chunk, decode):
     """A 37-token prompt prefilled in one chunk or in three (the state
     rides from chunk to chunk), 8 tokens decoded through the fused loop
     or step by step, then one more position's logits: each against the
     reference's forward pass over the whole sequence."""
-    cfg, params = model
-    prompt = prompt_of(37)
-    eng = engine(cfg, params, chunk)
-    lg = np.asarray(eng.put([7], [prompt])[7])
-    want = ref_logits(cfg, params, prompt, [len(prompt) - 1])[0]
-    assert np.abs(lg - want).max() < TOL
-    tok = int(np.argmax(lg))
-    if decode == "fused":
-        toks = eng.decode_batch([7], [tok], 8)[7]
-    else:
-        toks = eng.decode_pipelined([7], [tok], 8)[7]
-    seq = prompt + [tok] + list(toks)
-    at = list(range(len(prompt), len(seq)))
-    want = ref_logits(cfg, params, seq, at)
-    assert [int(t) for t in toks] == np.argmax(want[:-1], -1).tolist()
-    # the state the decode left behind: the next position's logits
-    lg = np.asarray(eng.put([7], [[int(toks[-1])]])[7])
-    assert np.abs(lg - want[-1]).max() < TOL
-    stats = eng.pipeline_stats
+    eng = FAMILY.serve_against_reference(model, chunk, decode)
+    prompt, stats = prompt_of(37), eng.pipeline_stats
     assert stats["linear_attn_prefill_tokens"] == len(prompt)
     # the chunk kernel is the chip's: none of them went through it here
     assert stats["linear_attn_prefill_kernel_tokens"] == 0
@@ -112,43 +77,11 @@ def test_generate_serves_the_model(model):
 
 def test_decode_through_the_conv_kernel_serves_the_jnp_paths_tokens(
         model, monkeypatch):
-    """The decode steps' short convolution through the in-place Pallas
-    call (forced and interpreted here; on the chip platform and shape
-    pick it) after a chunked prefill: 4 steps of the fused loop and 5
-    step by step give the jnp path's tokens and leave its pool, states
-    and carried inputs alike, and the engine counts the layer-steps.
-    (Alike to float32 rounding: inside a step program XLA's CPU backend
-    contracts the taps' multiply-adds where it fuses them and not in the
-    interpreted body; ``test_short_conv.py`` holds the call alone to the
-    jnp path bit for bit, and so did the chip, PERF.md PR 46.)"""
-    from deepspeed_tpu.ops.kernels import short_conv
-    cfg, params = model
-    prompts = {5: prompt_of(21, seed=4), 6: prompt_of(9, seed=5)}
-
-    def serve():
-        eng = engine(cfg, params, 16)
-        first = {u: int(np.argmax(np.asarray(lg)))
-                 for u, lg in eng.put(list(prompts),
-                                      list(prompts.values())).items()}
-        out = eng.decode_batch([5, 6], [first[5], first[6]], 4)
-        toks = {u: [first[u]] + [int(t) for t in out[u]] for u in prompts}
-        # one sequence alone: the other rows of its bucket are idle
-        toks[6] += [int(t) for t in
-                    eng.decode_pipelined([6], [toks[6][-1]], 5)[6]]
-        return toks, jax.device_get((eng._kv_data.state, eng._kv_data.conv)), \
-            eng.pipeline_stats["conv_steps_in_place"]
-
-    want_toks, want_pool, counted = serve()
-    assert counted == 0                  # the CPU path: gather and scatter
-    monkeypatch.setattr(short_conv, "decode_uses_kernel",
-                        lambda *a, **k: True)
-    toks, pool, counted = serve()
-    assert toks == want_toks
-    for got, want in zip(jax.tree_util.tree_leaves(pool),
-                         jax.tree_util.tree_leaves(want_pool)):
-        assert np.allclose(got, want, rtol=1e-4, atol=1e-5)
-    layers = sum(k in ("kda", "mamba2") for k in cfg.layer_kinds)
-    assert layers and counted == (4 + 5) * layers
+    """``Family.decode_through_the_conv_kernel``; the engine counts the
+    layer-steps the kernel took."""
+    plain, forced = FAMILY.decode_through_the_conv_kernel(model, monkeypatch)
+    assert plain["conv_steps_in_place"] == 0     # the CPU path: gather and scatter
+    assert forced["conv_steps_in_place"] == (4 + 5) * 3      # 3 KDA layers
 
 
 # ------------------- (b) chunked against token by token ------------------- #
@@ -305,11 +238,7 @@ def test_shares_routed_parts_add_up_to_the_uncut_layer():
     h = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 64))
 
     def share(first, held):
-        cfg = dataclasses.replace(whole_cfg, experts_first=first,
-                                  experts_held=held)
-        p = dict(whole["moe"], **{n: whole["moe"][n][first:first + held]
-                                  for n in ("wi_gate", "wi_up", "wo")})
-        return cfg, p
+        return H.share_of(whole_cfg, whole["moe"], first, held)
 
     with jax.default_matmul_precision("highest"):
         uncut, _ = _moe_mlp(whole["moe"], h, whole_cfg, jnp.float32)
@@ -406,48 +335,26 @@ def test_two_sequences_in_eight_slots_decode_as_they_do_alone(model):
 # ------------------------------ (e) refusals ----------------------------- #
 
 
-@pytest.mark.parametrize("feature, kw", [
-    ("prefix_cache", dict(prefix_cache=True)),
-    ("spec_decode", dict(spec_decode="ngram")),
-    ("kv_cache_dtype='int8'", dict(kv_cache_dtype="int8")),
-    ("tp_size > 1", dict(tp_size=2, max_seqs=2)),
-    ("seq_size > 1", dict(seq_size=2, max_seqs=2)),
-    ("ep_size > 1", dict(ep_size=2, max_seqs=2)),
-])
+@pytest.mark.parametrize("feature, kw", H.CONSTRUCTION_REFUSALS)
 def test_construction_refuses_what_needs_a_state_snapshot(model, feature, kw):
-    cfg, params = model
-    with pytest.raises(ValueError) as err:
-        engine(cfg, params, **kw)
-    assert feature in str(err.value) and "'kda'" in str(err.value)
+    said = FAMILY.refusal(model, feature, kw, None)
+    assert feature in said and "'kda'" in said
 
 
 @pytest.mark.parametrize("call", [
     "pause", "resume", "handoff_out", "handoff_in", "drain", "replay",
     "attach_draft", "decode_spec"])
 def test_calls_refuse_what_needs_a_state_snapshot(model, call):
-    cfg, params = model
-    eng = engine(cfg, params)
-    eng.put([1], [prompt_of(9)])
-    args = {"pause": (1,), "resume": (1,), "handoff_out": ([1],),
-            "handoff_in": ({},), "drain": (), "replay": ({},),
-            "attach_draft": (cfg, params), "decode_spec": ([1], [3], 2)}
-    with pytest.raises(NotImplementedError) as err:
-        getattr(eng, call)(*args[call])
-    assert call in str(err.value) and "'kda'" in str(err.value)
+    said = FAMILY.refusal(model, call, {}, H.CALL_ARGS[call])
+    assert call in said and "'kda'" in said
 
 
 # ------------------------------ (f) registry ----------------------------- #
 
 
 def _published():
-    """The catalog's ``config`` as the configuration file carries it, the
-    three reduced keys back at their published values."""
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "solar-open2-250b.json")) as f:
-        d = json.load(f)
-    for key in ("num_hidden_layers", "n_routed_experts", "vocab_size"):
-        d[key] = d[key + "_published"]
-    return d
+    return H.published(CONFIG, ("num_hidden_layers", "n_routed_experts",
+                                "vocab_size"))
 
 
 def test_config_from_hf_layer_list_and_parameter_counts():
@@ -467,9 +374,7 @@ def test_config_from_hf_layer_list_and_parameter_counts():
 
 
 def test_the_benchmarks_cut_is_a_share_of_the_published_model():
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "solar-open2-250b.json")) as f:
-        d = json.load(f)
+    d = H.benchmark_config(CONFIG)
     cfg = mt.model_config(d)
     assert cfg.layer_kinds == ("attn", "kda", "kda", "kda")
     assert (cfg.num_experts, cfg.held, cfg.vocab_size) == (320, 40, 24576)
@@ -481,18 +386,11 @@ def test_the_benchmarks_cut_is_a_share_of_the_published_model():
 @pytest.mark.parametrize("key, value", [
     ("first_k_dense_replace", 1), ("kda_use_full_proj", True)])
 def test_config_from_hf_refuses_what_it_does_not_implement(key, value):
-    with pytest.raises(ValueError, match=key):
-        config_from_hf(dict(_published(), **{key: value}))
+    H.hf_refuses(_published(), {key: value}, key)
 
 
 def test_flax_model_and_runner_read_one_tree(model):
     """The flax module's forward (token-by-token recurrence, every held
     expert densely) gives the reference's logits on the served tree."""
     from deepspeed_tpu.models.solar_open2 import SolarOpen2
-    cfg, params = model
-    prompt = prompt_of(12, seed=8)
-    with jax.default_matmul_precision("highest"):
-        got = SolarOpen2(cfg).apply({"params": params},
-                                    jnp.asarray([prompt]))[0]
-    want = ref_logits(cfg, params, prompt, list(range(len(prompt))))
-    assert float(np.abs(np.asarray(got) - want).max()) < TOL
+    FAMILY.flax_model_reads_the_runners_tree(SolarOpen2, model)

@@ -1,0 +1,244 @@
+"""The TRAIN engine's step programs compiled for the TPU v5e with no chip
+attached (see ``test_tpu_compile.py``): ZeRO-3's step over the four chips
+of a v5e:2x2 (ISSUE 60) and the sparse train cell's step on one (ISSUE 61)."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tpu_compile_common import (
+    _mosaic_call_names, described_chips_programs_stay_out_of_the_cache)
+
+
+def _collectives(hlo):
+    """(kind, how, result elements) of every weight-sized (a million
+    elements and more) collective the ENTRY computation of a scheduled
+    TPU text runs. ``how``: ``sync`` an instruction of the entry
+    computation itself (the TensorCore waits for it), ``kernel`` the TPU's
+    fused all-reduce-scatter (a reduce-scatter: its result is a shard;
+    synchronous as well), ``async`` inside an asynchronous collective
+    fusion (counted once, at the fusion that starts it)."""
+    import re
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            cur = "ENTRY" if head.group(1) else head.group(2)
+            comps[cur] = []
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+
+    def elements(text):
+        return max((int(np.prod([int(d) for d in dims.split(",") if d]))
+                    for dims in re.findall(r"\w+\[([\d,]*)\]", text)),
+                   default=0)
+
+    op = re.compile(r"= (.*?) (all-reduce|all-gather|reduce-scatter)"
+                    r"(-start)?\(")
+    inner = {name: [m for m in map(op.search, lines) if m]
+             for name, lines in comps.items() if name != "ENTRY"}
+    out = []
+    for line in comps["ENTRY"]:
+        m = op.search(line)
+        if m:
+            out.append((m.group(2), "async" if m.group(3) else "sync",
+                        elements(m.group(1))))
+            continue
+        call = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+        for m in inner.get(call.group(1), []) if call else []:
+            callee = call.group(1)
+            if callee.startswith("all-reduce-scatter"):
+                out.append(("reduce-scatter", "kernel",
+                            elements(line.split(" fusion(")[0])))
+            elif callee.startswith("async_collective_fusion"):
+                out.append((m.group(2), "async", elements(m.group(1))))
+    return [c for c in out if c[2] >= 1_000_000]
+
+
+def test_the_zero3_step_compiles_with_its_collectives_written_out(
+        monkeypatch):
+    """Two layers of the cell's model (benchmark/configs/gpt-1p3b.json at
+    a cut vocabulary) through the ENGINE's own stage-3 step builder, for
+    the four chips of a v5e:2x2, micro-batch 2 x 2048 a chip: the flash
+    kernels compile inside the seam (a Mosaic call refuses a context with
+    an automatic axis), every sharded leaf's gradient is a reduce-scatter
+    (no weight-sized all-reduce in the entry computation), and the count
+    of SYNCHRONOUS weight-sized gathers and reduce-scatters is what
+    PERF.md section 5 (PR 60) reads: a later change that folds an
+    asynchronous one back fails here, with no chip."""
+    import functools
+
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding
+
+    import deepspeed_tpu as dstpu
+    import deepspeed_tpu.ops.kernels as kernels
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.models.gpt2 import GPT2Config, make_model
+    from deepspeed_tpu.runtime.engine import Engine
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda *a: 4)
+    # the state stays where it was made: nothing can be put on a chip
+    # that is only described
+    monkeypatch.setattr(Engine, "_place_state", lambda self, state: state)
+
+    layers = 2
+    cfg = GPT2Config(vocab_size=8192, max_seq_len=2049, num_layers=layers,
+                     num_heads=16, hidden_size=2048, mlp_ratio=4,
+                     param_dtype=jnp.bfloat16, remat=True,
+                     remat_policy="qkv_out", flash_block_q=1024,
+                     flash_block_k=1024)
+    _, init_fn, loss_fn = make_model(cfg)
+    params = jax.jit(functools.partial(init_fn, batch_size=1, seq_len=64))(
+        jax.random.PRNGKey(0))
+    mesh = {"data": 4}
+    engine, _, _, _ = dstpu.initialize(
+        loss_fn=loss_fn, params=params,
+        topology=dstpu.build_mesh(MeshConfig(**mesh), devices=topo.devices),
+        config={"train_micro_batch_size_per_gpu": 2,
+                "gradient_accumulation_steps": 1, "bf16": {"enabled": True},
+                "data_types": {"grad_accum_dtype": "bfloat16"},
+                "gradient_clipping": 1.0, "steps_per_print": 1000000,
+                "optimizer": {"type": "AdamW", "params": {
+                    "lr": 3e-4, "moment_dtype": "bfloat16"}},
+                "zero_optimization": {"stage": 3}, "mesh": mesh})
+    state = jax.tree_util.tree_map(
+        lambda x, s: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=s),
+        engine.state, engine._state_shardings)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (engine.config.train_batch_size, 2049), jnp.int32,
+        sharding=engine.topology.batch_sharding())}
+    hlo = engine._train_step.trace(state, batch).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+
+    assert set(_mosaic_call_names(hlo)) == {"attn"}
+    found = _collectives(hlo)
+    kinds = {}
+    for kind, how, _ in found:
+        kinds[kind, how] = kinds.get((kind, how), 0) + 1
+    # a gradient leaves as a shard: four kernels a layer and the token
+    # embedding, none as an all-reduce of the leaf. The one all-reduce is
+    # the position table's: 2049 rows are no whole number of sublane
+    # tiles, and the compiler legalizes that reduce-scatter into an
+    # all-reduce (8 MB a step, combined with the biases' and norms' psums)
+    assert [c for c in found if c[0] == "all-reduce"] \
+        == [("all-reduce", "sync", 2049 * 2048)], found
+    assert kinds.get(("reduce-scatter", "sync"), 0) \
+        + kinds.get(("reduce-scatter", "kernel"), 0) == 4 * layers + 1, kinds
+    # the backward's re-gathers ride asynchronous fusions but for the
+    # recompute's c_fc, the first weight a layer's backward needs (the
+    # parent: three a layer in the backward and one in the forward); in
+    # front of the model wte, wpe and the first layer's c_attn
+    assert kinds.get(("all-gather", "sync"), 0) == layers + 3, kinds
+    assert kinds.get(("all-gather", "async"), 0) >= 7 * layers - 1, kinds
+
+
+def test_the_trinity_cells_train_step_compiles_under_the_chips_memory(
+        monkeypatch):
+    """The step of ``train-trinity-mini-8k-1chip`` as its job builds it
+    (benchmark/configs/trinity-mini-26b-a3b.json at published widths: 5
+    layers, 16 of 128 experts held, 1/8 of the vocabulary; micro-batch 2 x
+    8,192, float32 master / moments / gradients, bf16 compute, remat a
+    layer) through the ENGINE's own step builder, for one v5e chip: it fits
+    under 15.75 GB, its flash calls are the WINDOW kernel on the four
+    sliding layers and today's on the full one (four calls a layer:
+    forward, its recompute, dq, dk/dv: what ``flash_window_roofline.train``
+    divides by), and the experts' grouped products are there in both
+    passes. The engine is built over a toy tree of the same STRUCTURE (a
+    described chip holds no array) and its step traced at the real
+    shapes."""
+    import dataclasses
+    import json
+    import os
+
+    from jax.experimental import topologies
+
+    import deepspeed_tpu as dstpu
+    import deepspeed_tpu.ops.kernels as kernels
+    from benchmark.model_types import afmoe as mt
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.models.afmoe import make_model
+    from deepspeed_tpu.ops.kernels.flash_attention import take_causal_plans
+    from deepspeed_tpu.runtime.engine import Engine
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    one = SingleDeviceSharding(topo.devices[0])
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-mini-26b-a3b.json")) as f:
+        full = mt.model_config(json.load(f), "float32")
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "pretrain-8k-sparse.json")) as f:
+        job = json.load(f)
+    toy = dataclasses.replace(
+        full, vocab_size=64, hidden_size=16, num_heads=2, num_kv_heads=1,
+        attn_head_dim=8, intermediate_size=16, moe_intermediate_size=8,
+        num_experts=8, experts_held=2, attention_impl="xla")
+    params = make_model(toy)[1](jax.random.PRNGKey(0), 1, 8)
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+    monkeypatch.setattr(Engine, "_place_state", lambda self, state: state)
+    engine, _, _, _ = dstpu.initialize(
+        loss_fn=make_model(full)[2], params=params,
+        topology=dstpu.build_mesh(MeshConfig(**job["mesh"]),
+                                  devices=topo.devices[:1]),
+        config=dict(job["ds_config"], mesh=job["mesh"]))
+
+    # every params-shaped subtree of the state (the master, the moments)
+    # at the real shapes; whatever else it holds as it is
+    real = jax.tree_util.tree_leaves(mt.param_shapes(full))
+    toy_shapes = [p.shape for p in jax.tree_util.tree_leaves(params)]
+
+    def at_real_shapes(sub):
+        leaves, treedef = jax.tree_util.tree_flatten(sub)
+        if [np.shape(x) for x in leaves] == toy_shapes:
+            leaves = [jax.ShapeDtypeStruct(r.shape, x.dtype, sharding=one)
+                      for r, x in zip(real, leaves)]
+            return jax.tree_util.tree_unflatten(treedef, leaves)
+        if isinstance(sub, dict):
+            return {k: at_real_shapes(v) for k, v in sub.items()}
+        if isinstance(sub, (tuple, list)) and not hasattr(sub, "shape"):
+            vals = [at_real_shapes(v) for v in sub]
+            return type(sub)(*vals) if hasattr(sub, "_fields") \
+                else type(sub)(vals)
+        return jax.ShapeDtypeStruct(np.shape(sub), sub.dtype, sharding=one)
+
+    state = at_real_shapes(engine.state)
+    n = sum(int(np.prod(x.shape))
+            for x in jax.tree_util.tree_leaves(state.params))
+    assert 705e6 < n < 706e6
+    B = engine.config.train_batch_size
+    batch = {"tokens": jax.ShapeDtypeStruct((B, full.max_seq_len), jnp.int32,
+                                            sharding=one)}
+    take_causal_plans()
+    exe = engine._train_step.trace(state, batch).lower(
+        lowering_platforms=("tpu",)).compile()
+    mem = exe.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 4e9 < total < 15.75e9, total
+    names = _mosaic_call_names(exe.as_text())
+    window = f"attn_w{full.sliding_window}"
+    assert names.count(window) == 4 * 4 and names.count("attn") == 4 * 1
+    assert sum(n.startswith("ragged-dot") for n in names) >= 4 * 9
+    plans = take_causal_plans()             # one a layer's call
+    assert {(b, h) for b, h, _ in plans} == {(B, 32)}
+    assert sorted((plan["edge"], plan["skipped"]) for _, _, plan in plans) \
+        == [(0, 28)] + [(6, 43)] * 4
