@@ -345,13 +345,16 @@ def step_counters(cfg: AfmoeConfig, counts, tokens: int) -> dict:
     busiest held expert's rows times the experts held, each summed over
     the layers; and what ``grouped_moe_ffn`` did with them: the rows of
     the sorted order each layer visited (``held_row_bound``, or every
-    routed row where the held rows exceeded it) and the layers that took
-    every row."""
-    from ..moe.sharded_moe import held_row_bound
+    routed row where the held rows exceeded it), the layers that took
+    every row, and the layers whose rows rejoined their tokens through the
+    combine kernel (``combine_impl``: 0 wherever ``.at[].add`` ran)."""
+    from ..moe.sharded_moe import combine_impl, held_row_bound
     first, n = cfg.held
     rows = tokens * cfg.experts_top_k
     bound = held_row_bound(tokens, cfg.experts_top_k, cfg.num_experts,
                            cfg.held)
+    kernel = combine_impl(tokens, n, cfg.hidden_size, (bound, rows),
+                          cfg.dtype)
     here = [c[first:first + n] for c in counts]
     routed = sum(h.sum() for h in here)
     full = sum((h.sum() > bound).astype(jnp.int32) for h in here)
@@ -359,7 +362,9 @@ def step_counters(cfg: AfmoeConfig, counts, tokens: int) -> dict:
             "moe_rows_elsewhere": sum(c.sum() for c in counts) - routed,
             "moe_rows_hottest": sum(h.max() for h in here) * n,
             "moe_rows_visited": bound * len(here) + (rows - bound) * full,
-            "moe_layers_full": full}
+            "moe_layers_full": full,
+            "moe_combine_layers": jnp.int32(
+                len(here) if kernel is not None else 0)}
 
 
 def make_model(cfg: AfmoeConfig):

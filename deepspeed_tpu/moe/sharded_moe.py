@@ -189,9 +189,29 @@ def gate(logits: jnp.ndarray, k: int = 1, **kwargs):
     return topkgating(logits, k, **kwargs)
 
 
+def _chosen_by_compare(gates: jnp.ndarray, top_idx: jnp.ndarray):
+    """``take_along_axis(gates, top_idx, -1)`` without a gather: a sum that
+    picks ONE value and zeros, so exact; its transpose is a masked
+    broadcast where the gather's is a scatter-add. XLA runs a scalar gather
+    of 131,072 elements at ~8 ns an element on a v5e (1.04 ms). The
+    experts stand on the second-minor axis, so the sum over them adds
+    whole registers (over the lanes it is 0.3 ms slower a pass)."""
+    experts = jnp.arange(gates.shape[-1], dtype=top_idx.dtype)
+    hit = top_idx.T[:, None, :] == experts[None, :, None]    # [k, E, S]
+    return jnp.sum(jnp.where(hit, gates.T[None], 0), axis=1).T
+
+
+def _rows_chosen(top_idx: jnp.ndarray, E: int) -> jnp.ndarray:
+    """``bincount(top_idx.reshape(-1), length=E)`` as a sum over a one-hot
+    compare ([E] int32): the scatter-add of 131,072 ones into 128 bins is
+    1.14 ms on a v5e, one serial update each."""
+    hit = top_idx[:, :, None] == jnp.arange(E, dtype=top_idx.dtype)
+    return jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+
+
 def route_topk(logits: jnp.ndarray, k: int, *, score: str = "softmax",
                bias=None, normalize: bool = True, scale: float = 1.0,
-               norm_eps: float = 1e-20):
+               norm_eps: float = 1e-20, chosen=None):
     """The router's choice for every row: (top_idx [S, k] int32,
     weights [S, k] float32, scores [S, E] float32).
 
@@ -203,7 +223,12 @@ def route_topk(logits: jnp.ndarray, k: int, *, score: str = "softmax",
     bias`` are chosen (the bias takes part in the SELECTION only), and
     their weights are the scores themselves, renormalised over the chosen
     (``normalize``: ``w / (sum w + norm_eps)``; lfm2's ``1e-6`` is its
-    family's, the default the others') and multiplied by ``scale``."""
+    family's, the default the others') and multiplied by ``scale``.
+
+    ``chosen(gates, top_idx)``: how the chosen experts' scores are read;
+    None is ``take_along_axis``, what every serve step's program holds."""
+    if chosen is None:
+        chosen = functools.partial(jnp.take_along_axis, axis=-1)
     lf = logits.astype(jnp.float32)
     if score == "softmax":
         gates = jax.nn.softmax(lf, axis=-1)
@@ -217,7 +242,7 @@ def route_topk(logits: jnp.ndarray, k: int, *, score: str = "softmax",
             # normalize_weights=False for k == 1.
             w_sel = jax.nn.softmax(top_vals, axis=-1)      # [S, k]
         else:
-            w_sel = jnp.take_along_axis(gates, top_idx, axis=-1)
+            w_sel = chosen(gates, top_idx)
         return top_idx, w_sel, gates
     if score != "sigmoid":
         raise ValueError(f"router score must be 'softmax' or 'sigmoid', "
@@ -225,7 +250,7 @@ def route_topk(logits: jnp.ndarray, k: int, *, score: str = "softmax",
     gates = jax.nn.sigmoid(lf)
     _, top_idx = jax.lax.top_k(
         gates if bias is None else gates + bias.astype(jnp.float32), k)
-    w_sel = jnp.take_along_axis(gates, top_idx, axis=-1)
+    w_sel = chosen(gates, top_idx)
     if normalize:
         w_sel = w_sel / (jnp.sum(w_sel, axis=-1, keepdims=True) + norm_eps)
     if scale != 1.0:
@@ -266,6 +291,96 @@ def held_row_bound(S: int, k: int, E: int, held) -> int:
     return min(rows, -(-2 * expected // 512) * 512)
 
 
+def combine_impl(S: int, n: int, M: int, rows, dtype) -> Optional[str]:
+    """How the ``ragged_dot`` path moves its rows: "pallas" on a TPU
+    backend where ``ops/kernels/moe_combine.py`` takes every row count of
+    ``rows`` (a call's bound and its ``S * k``), None (XLA's gather and
+    ``.at[].add``, the kernel's oracle) otherwise and anywhere else: the
+    kernel interpreted would cost the CPU minutes."""
+    from ..ops.kernels import moe_combine
+    if jax.default_backend() != "tpu":
+        return None
+    return "pallas" if all(moe_combine.fits(S, n, M, r, dtype)
+                           for r in rows) else None
+
+
+def _in_order(x, index):
+    """``x[index]`` along the first axis for an ``index`` out of a sort
+    (every entry in bounds): ``take``'s own bounds check is a second pass
+    over the gathered rows (0.41 ms over [32,768, 2,048] on a v5e)."""
+    return x.at[index].get(mode="promise_in_bounds")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _rows_by_expert(impl, dtype, tokens_dtype, tokens, tok_of, chose, row,
+                    sizes):
+    """The tokens' rows in the sorted order, ``tokens[tok_of]``; its
+    transposition is the combine kernel at unit weights (``chose`` [S, n]:
+    token ``t`` chose expert ``e``) where XLA's is a scatter-add of as many
+    rows. The kernel reads a row in no group as zeros (what
+    :func:`_keep_cotangent_rows` is for elsewhere)."""
+    return _in_order(tokens, tok_of).astype(dtype)
+
+
+def _rows_by_expert_bwd(impl, dtype, tokens_dtype, res, ct):
+    from ..ops.kernels.moe_combine import moe_combine
+    chose, row, sizes = res
+    return (moe_combine(ct, chose.astype(jnp.float32), row, sizes,
+                        tokens_dtype, interpret=impl == "interpret"),
+            None, None, None, None)
+
+
+_rows_by_expert.defvjp(
+    lambda impl, dtype, tokens_dtype, tokens, tok_of, chose, row, sizes: (
+        _rows_by_expert(impl, dtype, tokens_dtype, tokens, tok_of, chose,
+                        row, sizes), (chose, row, sizes)),
+    _rows_by_expert_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _rows_to_tokens(impl, dtype, ys, weight, w_sel, first, chose, row,
+                    sizes):
+    """The experts' rows weighted (``weight`` [S, n], token-major) and
+    added into their tokens [S, M] through the combine kernel: float32
+    sums in expert order, rounded once. Backward: the rows' cotangent is
+    XLA's row gather times the rows' own weights (``w_sel`` [S, k] read at
+    ``first``: it takes no cotangent here, ``weight`` does), as the
+    scatter-add's; a weight's is its row's product with the token's
+    cotangent, brought token-major by the kernel again (one lane an
+    expert) where a scatter of as many scalars would take them one by
+    one. The residuals are the scatter-add's (rows, weights, order)."""
+    from ..ops.kernels.moe_combine import moe_combine
+    return moe_combine(ys, weight, row, sizes, dtype,
+                       interpret=impl == "interpret")
+
+
+def _rows_to_tokens_bwd(impl, dtype, res, ct):
+    from ..ops.kernels.moe_combine import moe_combine
+    ys, w_sel, first, chose, row, sizes = res
+    n, rows = sizes.shape[0], ys.shape[0]
+    at = jnp.arange(rows)
+    got = _in_order(ct, first // w_sel.shape[1]).astype(ys.dtype)
+    ws = _in_order(w_sel.reshape(-1), first).astype(ys.dtype)
+    dys = jnp.where((at < sizes.sum())[:, None], got * ws[:, None], 0)
+    dws = jnp.sum(ys.astype(jnp.float32) * got.astype(jnp.float32), axis=-1)
+    # a row's expert by compare with the groups' ends; one past the held
+    # ones (no lane) for a row in no group
+    e_of = jnp.sum(at[:, None] >= jnp.cumsum(sizes)[None, :], axis=1)
+    lanes = jnp.arange(-(-n // 128) * 128)
+    by_lane = jnp.where((e_of[:, None] == lanes[None, :])
+                        & (lanes[None, :] < n), dws[:, None], 0)
+    dweight = moe_combine(by_lane, chose.astype(jnp.float32), row, sizes,
+                          jnp.float32, interpret=impl == "interpret")[:, :n]
+    return dys, dweight, None, None, None, None, None
+
+
+_rows_to_tokens.defvjp(
+    lambda impl, dtype, ys, weight, w_sel, first, chose, row, sizes: (
+        _rows_to_tokens(impl, dtype, ys, weight, w_sel, first, chose, row,
+                        sizes), (ys, w_sel, first, chose, row, sizes)),
+    _rows_to_tokens_bwd)
+
+
 def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
                     weights, activation, dtype,
                     normalize_weights: bool = True, *,
@@ -279,8 +394,8 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     (``inference/v2/kernels/cutlass_ops/moe_gemm/``) and the
     megablocks-style dropless dispatch: tokens sort by their routed expert,
     each expert multiplies ONLY its contiguous run of rows, and the outputs
-    scatter-add back weighted by the router. Computes S*k expert rows
-    instead of the capacity path's S*E (or the serving dense path's
+    add back into their tokens weighted by the router. Computes S*k expert
+    rows instead of the capacity path's S*E (or the serving dense path's
     every-expert-on-every-token) — E/k x fewer FLOPs — with no capacity
     drop and no [S, E, C] one-hot tensors.
 
@@ -302,6 +417,28 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     device) takes every row through the same body under a ``cond``: no row
     is dropped for any routing. Both bodies add a token's held rows in the
     order they were, so the cut changes no sum.
+
+    How the rows move (``impl=None``). The sort is STABLE over the flat
+    slot index ``t * k + j`` and top-k picks distinct experts, so inside
+    one expert's group the rows are in token order and a token sends an
+    expert at most one row: the rows a tile of consecutive tokens sends
+    one expert are ONE contiguous run of the sorted order, and where a
+    token's row lies is its expert's group start plus the tokens before it
+    that chose the expert, a cumulative sum over a one-hot compare. On
+    that rests ``ops/kernels/moe_combine.py``, which a TPU backend takes
+    where the shapes fit (:func:`combine_impl`): the rows rejoin their
+    tokens through a kernel that walks the runs, in float32, rounded once;
+    the same kernel at unit weights is the transposition of the row
+    gather, and once more (a lane an expert) it brings the weights'
+    cotangent token-major. No scatter of routed-row size is left (XLA's
+    walks its rows one by one on a TPU: 2.93 ms for 32,768 rows of 2,048
+    where the kernel takes 0.58). Everywhere else the weighted rows are
+    added back with ``.at[].add`` (a token's held rows in the order they
+    were, in the compute type): the CPU's path, and the kernel's oracle in
+    the tests. On every backend the groups' sizes are a sum over a one-hot
+    compare and the chosen scores a masked sum (``route_topk(chosen=)``):
+    ``bincount`` and ``take_along_axis`` of 131,072 elements are 1.1 ms
+    each on a v5e, bit for bit the same values.
 
     ``impl``: None is the path above, three ``ragged_dot`` calls over the
     rows visited (all S*k of a whole layer): what training takes (the
@@ -326,14 +463,13 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
     S, E = logits.shape
     top_idx, w_sel, gates = route_topk(
         logits, k, score=score, bias=select_bias,
-        normalize=normalize_weights, scale=weight_scale, norm_eps=norm_eps)
+        normalize=normalize_weights, scale=weight_scale, norm_eps=norm_eps,
+        chosen=_chosen_by_compare if impl is None else None)
 
     eid = top_idx.reshape(-1)                              # [S*k]
-    if return_counts:
-        if impl is not None:
-            raise ValueError("return_counts is the ragged_dot path's "
-                             "(impl=None)")
-        counts = jnp.bincount(eid, length=E).astype(jnp.int32)
+    if return_counts and impl is not None:
+        raise ValueError("return_counts is the ragged_dot path's "
+                         "(impl=None)")
     here = None
     if held is not None and tuple(held) != (0, E):
         first, count = held
@@ -354,23 +490,38 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
                       * w_sel[..., None], axis=1).astype(dtype)
         return out, jnp.float32(0.0)
     order = jnp.argsort(eid, stable=True)
-    if return_counts and here is not None:
-        # the held groups' sizes are a slice of what is already counted (a
-        # second count of 131,072 ids is 1.2 ms on a v5e)
-        group_sizes = counts[held[0]:held[0] + held[1]]
-    else:
-        group_sizes = jnp.bincount(eid, length=E).astype(jnp.int32)
+    # one count of the choices serves the caller and, as a slice, the held
+    # groups' sizes
+    counts = _rows_chosen(top_idx, logits.shape[1])
+    group_sizes = counts if here is None \
+        else counts[held[0]:held[0] + held[1]]
+    held_rows = group_sizes.sum()
+    bound = held_row_bound(S, k, logits.shape[1], held)
+    kernel = combine_impl(S, E, tokens.shape[1], (bound, S * k), dtype)
+    if kernel is not None:
+        from ..ops.kernels import moe_combine
+        # [S, k, E]: choice j of token t is held expert e; [S, E]: token t
+        # chose it, and the row of the sorted order it sent it
+        hit = top_idx[:, :, None] == (0 if here is None else held[0]) \
+            + jnp.arange(E, dtype=top_idx.dtype)
+        chose = jnp.any(hit, axis=1)
+        row = moe_combine.rows_of(chose, group_sizes)
 
-    def visit(rows, tokens, w_flat, weights):
+    def visit(rows, tokens, w_sel, weights):
         """The first ``rows`` rows of the sorted order through the experts
         and back into their tokens: all of them, or the bound that holds
         every row of a held group."""
         first = order if rows == S * k else order[:rows]
         tok_of = first // k                                # source token
-        xs = jnp.take(tokens, tok_of, axis=0).astype(dtype)  # by expert
-        if here is not None:
-            keep = jnp.take(here, first)
-            xs = _keep_cotangent_rows(xs, keep)
+        if kernel is not None:
+            xs = _rows_by_expert(kernel, dtype, tokens.dtype, tokens, tok_of,
+                                 chose, row, group_sizes)
+        else:
+            xs = _in_order(tokens, tok_of).astype(dtype)    # by expert
+            if here is not None:
+                # held rows sort first: a row past them lies in no group
+                keep = jnp.arange(rows) < held_rows
+                xs = _keep_cotangent_rows(xs, keep)
         with region("moe_experts"):
             if len(weights) == 3:
                 wi_gate, wi_up, wo = weights
@@ -382,14 +533,21 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
                 h = activation(
                     jax.lax.ragged_dot(xs, wi.astype(dtype), group_sizes))
             ys = jax.lax.ragged_dot(h, wo.astype(dtype), group_sizes)
-        ws = jnp.take(w_flat, first).astype(dtype)
+        if kernel is not None:
+            # a sum that picks one value and zeros, so exact, and its
+            # transpose a masked broadcast; rounded as the rows' type
+            # holds a weight, like ``ws`` below
+            weight = jnp.sum(jnp.where(
+                hit, w_sel[:, :, None].astype(dtype), 0), axis=1)
+            return _rows_to_tokens(kernel, dtype, ys, weight, w_sel, first,
+                                   chose, row, group_sizes)
+        ws = _in_order(w_sel.reshape(-1), first).astype(dtype)
         if here is not None:
             # a row past the groups carries whatever the matmul left there
             ys = jnp.where(keep[:, None], ys, 0)
         return jnp.zeros_like(tokens, dtype).at[tok_of].add(ys * ws[:, None])
 
-    operands = (tokens, w_sel.reshape(-1), tuple(weights))
-    bound = held_row_bound(S, k, logits.shape[1], held)
+    operands = (tokens, w_sel, tuple(weights))
     if bound == S * k:
         out = visit(S * k, *operands)
     else:
@@ -400,7 +558,7 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
         # writes the other branch's residuals as zeros, full-size, in
         # every step (Trinity's 8k step then no longer fits a v5e)
         out = jax.lax.cond(
-            group_sizes.sum() <= bound,
+            held_rows <= bound,
             jax.checkpoint(functools.partial(visit, bound)),
             jax.checkpoint(functools.partial(visit, S * k)), *operands)
 
@@ -415,8 +573,7 @@ def grouped_moe_ffn(tokens: jnp.ndarray, logits: jnp.ndarray, k: int,
         return (out, l_aux, counts) if return_counts else (out, l_aux)
     me = gates.mean(axis=0)
     if k <= 2:
-        first = jnp.bincount(top_idx[:, 0], length=E).astype(jnp.float32)
-        ce = first / float(S)
+        ce = _rows_chosen(top_idx[:, :1], E).astype(jnp.float32) / float(S)
     else:
         ce = group_sizes.astype(jnp.float32) / float(S * k)
     l_aux = (me * ce).sum() * E

@@ -300,14 +300,16 @@ def test_config_from_hf_reads_the_catalog_rows_keys():
         config_from_hf(dict(d, n_group=2))
 
 
-def _every_row_ffn(tokens, logits, k, weights, held):
-    """``grouped_moe_ffn``'s ``ragged_dot`` path over a held share as the
-    parent of ISSUE 62 had it, kept here to compare with: every routed row
-    sorted, gathered, multiplied, masked and added back."""
+def _every_row_ffn(tokens, logits, k, weights, held, **router):
+    """``grouped_moe_ffn``'s ``ragged_dot`` path as the parents of ISSUE 62
+    and ISSUE 64 had it, kept here to compare with: the chosen scores by
+    ``take_along_axis`` (``route_topk`` as every serve step calls it), the
+    groups' sizes by ``bincount``, every routed row sorted, gathered,
+    multiplied, masked and added back. ``held`` None: a whole layer."""
     from deepspeed_tpu.moe.sharded_moe import _keep_cotangent_rows, route_topk
-    top_idx, w_sel, _ = route_topk(logits, k, score="sigmoid")
+    top_idx, w_sel, _ = route_topk(logits, k, score="sigmoid", **router)
     eid = top_idx.reshape(-1)
-    first, count = held
+    first, count = held or (0, logits.shape[1])
     here = (eid >= first) & (eid < first + count)
     eid = jnp.where(here, eid - first, count)
     order = jnp.argsort(eid, stable=True)
@@ -424,7 +426,9 @@ def test_the_steps_counters_say_which_layers_visited_every_row():
         "moe_rows_routed": 300 + 1024 + 1025 + 4096,
         "moe_rows_elsewhere": 4 * 4096 - (300 + 1024 + 1025 + 4096),
         "moe_rows_hottest": 2 * (200 + 683 + 684 + 2731),
-        "moe_rows_visited": 2 * 1024 + 2 * 4096, "moe_layers_full": 2}
+        "moe_rows_visited": 2 * 1024 + 2 * 4096, "moe_layers_full": 2,
+        # off the TPU ``.at[].add`` moves the rows (ISSUE 64)
+        "moe_combine_layers": 0}
     whole = AfmoeConfig.tiny(num_experts=E, experts_top_k=k)
     got = step_counters(whole, [layer(300), layer(4096)], tokens)
     assert (int(got["moe_rows_visited"]), int(got["moe_layers_full"])) == (
@@ -464,6 +468,7 @@ def test_the_new_counters_reach_step_stats_and_add_up_over_steps():
             recount["moe_layers_full"] += here > bound
     stats = engine.step_stats
     assert {key: stats[key] for key in recount} == recount
+    assert stats["moe_combine_layers"] == 0      # the CPU: ``.at[].add``
     assert recount["moe_layers_full"] == 3
     assert recount["moe_rows_visited"] == 3 * (rows + bound)
 
@@ -535,22 +540,27 @@ def test_what_a_grouped_matmul_leaves_past_its_groups_reaches_no_gradient(
 
 
 #: sha256 of the sorted instructions of a FORWARD program of
-#: ``grouped_moe_ffn(..., impl=None)`` as a serving step calls it, compiled
-#: for the CPU (metadata, instruction and region numbers cut), under jax
-#: ``_SERVE_PINNED_JAX``. ``share``: ``held=(4, 4)`` over 48 routed rows,
-#: where ``held_row_bound`` is all of them, as the parent of ISSUE 61
-#: compiled it; ``whole``: ``held=None``, what ``moe/layer.py`` and a
-#: whole-layer serve take, as the parent of ISSUE 62 compiled it. Neither
-#: may move. ``share_cut``: the same share over 2,048 routed rows, where
-#: the bound is 1,024: the program ISSUE 62 made (a ``conditional`` of the
-#: body over 1,024 rows and over 2,048), pinned on its own tree
+#: ``grouped_moe_ffn(..., impl=None)`` as a serving step off the TPU calls
+#: it, compiled for the CPU (metadata, instruction and region numbers cut),
+#: under jax ``_SERVE_PINNED_JAX``. ``share``: ``held=(4, 4)`` over 48
+#: routed rows, where ``held_row_bound`` is all of them; ``whole``:
+#: ``held=None``, what ``moe/layer.py`` and a whole-layer serve take;
+#: ``share_cut``: the same share over 2,048 routed rows, where the bound is
+#: 1,024 (a ``conditional`` of the body over 1,024 rows and over 2,048).
+#: ALL THREE WERE RE-PINNED BY ISSUE 64, BY DESIGN: the body counts its
+#: groups by a one-hot compare (it was ``bincount``), reads the chosen
+#: scores by a masked sum (it was ``take_along_axis``), marks the held
+#: rows by their place in the sorted order (it was a gather of ``here``)
+#: and gathers by the sort's order without ``take``'s bounds check, on
+#: every backend. What the serve CELLS run (``impl="pallas"``) did not
+#: move: ``test_moe_combine.py`` pins it to ISSUE 64's parent
 _SERVE_PINNED = {
-    "share": (24, (4, 4), "66d1526fd93b7b8bcb1887de3f38e8cda7fe1793b0b9672"
-                          "1570d39cc8ad3c6a8"),
-    "whole": (24, None, "a4ec9180fd69950d4fb29b9d16fc22f4eaaa2f7506550ef42"
-                        "975f8d3b3cda773"),
-    "share_cut": (1024, (4, 4), "d260936808ab2d11891e47a45d8946b9a9d1ab6b4"
-                                "7274d06e94cdd17ffdba24c"),
+    "share": (24, (4, 4), "9456e94045811dc40db1beab7261f420"
+                          "6b8a18b508e2e176e3284f26a8fdd76b"),
+    "whole": (24, None, "653a2fa22d198420025db17e394dac7e"
+                        "c7c891292a87d6fc34bd4f428160e9e9"),
+    "share_cut": (1024, (4, 4), "64d5e134cb353b24a52e903ab8f8e0b4"
+                                "a2636e087e2389f8eeae9256c8b9aeaa"),
 }
 _SERVE_PINNED_JAX = "0.9.0"
 
@@ -559,10 +569,14 @@ _SERVE_PINNED_JAX = "0.9.0"
 def test_a_serving_share_compiles_to_the_instructions_it_always_did(case):
     """``_keep_cotangent_rows`` and ``return_counts`` (off) add nothing to
     a forward program, and neither does ``held_row_bound`` where it cuts
-    nothing: a held share over few rows and a whole layer served through
-    the ``ragged_dot`` path compile to their parents' instructions, one for
-    one. A share whose bound cuts the sorted order is ISSUE 62's program by
-    design, re-pinned there: a later change to it has to say so here."""
+    nothing: a held share over few rows, a whole layer and a share whose
+    bound cuts the sorted order, served through the ``ragged_dot`` path,
+    compile to the instructions pinned here. ISSUE 64 changed how that
+    body counts and picks (the comment above) and re-pinned all three on
+    its own tree; a later change to it has to say so here. So that the pin
+    still guards something, the program's OUTPUT is held to the parents'
+    arithmetic written out (``_every_row_ffn``: ``bincount``,
+    ``take_along_axis``, every row): bit for bit."""
     import hashlib
     import re
     from deepspeed_tpu.moe.sharded_moe import grouped_moe_ffn
@@ -576,9 +590,18 @@ def test_a_serving_share_compiles_to_the_instructions_it_always_did(case):
 
     M, W, E = 16, 8, 16
     n = E if held is None else held[1]
-    args = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (
-        (S, M), (S, E), (n, M, W), (n, M, W), (n, W, M), (E,))]
-    text = jax.jit(step).lower(*args).compile().as_text()
+    shapes = ((S, M), (S, E), (n, M, W), (n, M, W), (n, W, M), (E,))
+    args = [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
+    exe = jax.jit(step).lower(*args).compile()
+    x, logits, wg, wu, wo, bias = (
+        jax.random.normal(kk, s) for kk, s in zip(
+            jax.random.split(jax.random.PRNGKey(3), 6), shapes))
+    bias = 0.1 * bias               # the choice stays the scores' mostly
+    want = _every_row_ffn(x, logits, 2, (wg, wu, wo), held, bias=bias,
+                          scale=2.5)
+    assert np.any(np.asarray(want))
+    np.testing.assert_array_equal(exe(x, logits, wg, wu, wo, bias), want)
+    text = exe.as_text()
     assert ("conditional(" in text) == (case == "share_cut")
     text = re.sub(r", metadata=\{[^}]*\}", "", text)
     text = re.sub(r"\.\d+", "", text)

@@ -3,6 +3,7 @@ attached (see ``test_tpu_compile.py``): ZeRO-3's step over the four chips
 of a v5e:2x2 (ISSUE 60) and the sparse train cell's step on one (ISSUE 61)."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +12,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from tpu_compile_common import (
-    _mosaic_call_names, described_chips_programs_stay_out_of_the_cache)
+    _mosaic_call_names, described_chips_programs_stay_out_of_the_cache,
+    one_chip)
 
 
 def _collectives(hlo):
@@ -238,7 +240,47 @@ def test_the_trinity_cells_train_step_compiles_under_the_chips_memory(
     window = f"attn_w{full.sliding_window}"
     assert names.count(window) == 4 * 4 and names.count("attn") == 4 * 1
     assert sum(n.startswith("ragged-dot") for n in names) >= 4 * 9
+    # ISSUE 64: a sparse layer's rows rejoin their tokens through the
+    # combine kernel, in each branch of its ``cond``: forward, the layer's
+    # recompute, and in the backward for the tokens' and the weights'
+    # cotangents; the third forward the checkpointed branch runs inside the
+    # backward keeps none (nobody reads its output)
+    assert names.count("moe_combine") == 4 * 2 * 4
     plans = take_causal_plans()             # one a layer's call
     assert {(b, h) for b, h, _ in plans} == {(B, 32)}
     assert sorted((plan["edge"], plan["skipped"]) for _, _, plan in plans) \
         == [(0, 28)] + [(6, 43)] * 4
+
+
+@pytest.mark.parametrize("S,n,M,rows,dtype", [
+    (16384, 16, 2048, 32768, jnp.bfloat16),     # the cell's bound branch
+    (16384, 16, 2048, 131072, jnp.bfloat16),    # its full branch
+    (16384, 16, 128, 32768, jnp.float32),       # the weights' cotangent
+    (16384, 128, 2048, 131072, jnp.bfloat16),   # a whole layer (moe/layer)
+    (4096, 8, 4096, 8192, jnp.float32),         # mixtral's widths, float32
+])
+def test_the_combine_kernel_compiles_at_the_calls_it_takes(
+        one_chip, S, n, M, rows, dtype):
+    """``moe_combine`` (ISSUE 64) through Mosaic at the sparse train
+    cell's sizes and at the other callers' widths: unaligned copies,
+    float32 products at HIGHEST and the tiles' VMEM are refused here, not
+    on the chip. The call keeps the default scoped VMEM (16 MB): its tiles
+    follow the width."""
+    from deepspeed_tpu.ops.kernels import moe_combine as mc
+    assert mc.fits(S, n, M, rows, dtype)
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    exe = jax.jit(lambda ys, w, row, sizes: mc.moe_combine(
+        ys, w, row, sizes, dtype)).trace(
+            spec((rows, M), dtype), spec((S, n), jnp.float32),
+            spec((S, n), jnp.int32), spec((n,), jnp.int32)).lower(
+                lowering_platforms=("tpu",)).compile()
+    text = exe.as_text()
+    assert _mosaic_call_names(text) == ["moe_combine"]
+    call, = (line for line in text.splitlines()
+             if '"tpu_custom_call"' in line)
+    used = re.search(
+        r'"used_scoped_memory_configs":\[\{[^\]]*?"size":"(\d+)"', call)
+    assert 1 << 20 < int(used.group(1)) <= 16 << 20
