@@ -33,7 +33,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from ...ops.kernels.delta_rule import kda_prefill_uses_kernel
+from ...ops.kernels.delta_rule import (gdn_prefill_uses_kernel,
+                                       kda_prefill_uses_kernel)
 from ...ops.kernels import grouped_ffn, short_conv, sparse_attention
 from ...resilience.fault_injection import get_fault_injector
 from ...telemetry.serve import serve_observer
@@ -406,10 +407,17 @@ class InferenceEngineV2:
             # is, and per step of a fused loop), and the real positions
             # that went through the chunked delta rule; of those, the
             # ones of steps whose shape took the Pallas chunk kernel
-            # (delta_rule.kda_prefill_uses_kernel, as the mixer asks it;
-            # none of a state-space layer's, whose chunked form has no
-            # kernel yet)
+            # (delta_rule.kda_prefill_uses_kernel / gdn_.., as the mixer
+            # asks it; none of a state-space layer's, whose chunked form
+            # has no kernel yet). ``state_bytes_resident`` counts the
+            # same slots by what the device STORES for one, its arrays'
+            # last two dimensions in whole tiles
+            # (kv_cache.state_bytes_per_slot(resident=True)): over
+            # ``state_bytes_live`` it says what a layout pads
+            # (``state_bytes_padding``, their difference, is counted too:
+            # the benchmark's readers sum and divide)
             "state_slots_live": 0, "state_bytes_live": 0,
+            "state_bytes_resident": 0, "state_bytes_padding": 0,
             "linear_attn_prefill_tokens": 0,
             "linear_attn_prefill_kernel_tokens": 0,
             # layer-steps of decode through a short convolution (recurrent
@@ -1278,6 +1286,14 @@ class InferenceEngineV2:
                 "conv_steps_in_place": steps * short_conv.decode_uses_kernel(
                     S, spec["conv_width"], self.kv_cache.conv.dtype)}
 
+    def _state_bytes_stored(self, slot_steps: int) -> Dict[str, int]:
+        """``slot_steps`` live slots of the state pool by what the device
+        stores for one, and what of that is padding."""
+        stored = self.kv_cache.state_bytes_per_slot(resident=True)
+        return {"state_bytes_resident": slot_steps * stored,
+                "state_bytes_padding": slot_steps * (
+                    stored - self.kv_cache.state_bytes_per_slot())}
+
     def _refuse_stateful(self, feature: str,
                          latent_too: bool = False) -> None:
         """What would need a snapshot of the recurrent state refuses, by
@@ -1398,7 +1414,8 @@ class InferenceEngineV2:
         rows) pairs of a program that holds ``n`` positions a sequence."""
         return {"kv_write_rows": sum(count for _, count in stores),
                 "kv_write_runs": sum(
-                    runs_issued(start, count, n, self.config.block_size)
+                    runs_issued(start, count, n, self.config.block_size,
+                                self.kv_cache.data.shape[3])
                     for start, count in stores)}
 
     def pause(self, uid: int) -> None:
@@ -1826,6 +1843,8 @@ class InferenceEngineV2:
                     stats["state_slots_live"] += ran
                     stats["state_bytes_live"] += \
                         ran * self.kv_cache.state_bytes_per_slot()
+                    for key, val in self._state_bytes_stored(ran).items():
+                        stats[key] += val
                     for key, val in self._conv_steps(S, n).items():
                         stats[key] += val
                 if moe_rows is not None:
@@ -2045,13 +2064,15 @@ class InferenceEngineV2:
                         # (layers that keep no state, a gated short
                         # convolution's, have no recurrence to chunk)
                         spec = self.runner.state_spec
+                        uses_kernel = {
+                            "kda": kda_prefill_uses_kernel,
+                            "gdn": gdn_prefill_uses_kernel}.get(spec["kind"])
                         span.count(
                             linear_attn_prefill_tokens=real,
-                            linear_attn_prefill_kernel_tokens=real
-                            * (spec["kind"] == "kda"
-                               and kda_prefill_uses_kernel(
-                                   C, spec["heads"], spec["d_k"],
-                                   spec["d_v"])))
+                            linear_attn_prefill_kernel_tokens=real * bool(
+                                uses_kernel and uses_kernel(
+                                    C, spec["heads"], spec["d_k"],
+                                    spec["d_v"])))
                     if self._latent:
                         span.count(mla_prefill_tokens=real)
                     if self._selecting:
@@ -2081,6 +2102,7 @@ class InferenceEngineV2:
                         span.count(state_slots_live=real,
                                    state_bytes_live=real
                                    * self.kv_cache.state_bytes_per_slot(),
+                                   **self._state_bytes_stored(real),
                                    **self._conv_steps(S))
             if C > 1:
                 # serve fault site: a replica dying with a freshly planned

@@ -21,6 +21,7 @@ sequence grows. ``seq = 1`` reproduces the layout above bit-for-bit.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, List, Optional
 
 import jax
@@ -225,15 +226,22 @@ class BlockedKVCache:
             # delta rule, [head_dim, state] for a state-space layer
             # (``heads`` 0, a gated short convolution's layers, keeps no
             # state: no ``state`` tuple is made, as no ``conv`` at taps 0)
+            # (a kind whose [d_v, d_k] would not tile whole gives the
+            # layout of a slot's numbers itself: ``state_shape``)
             if state_spec["heads"]:
+                shape = state_spec.get("state_shape") or (
+                    state_spec["heads"], state_spec["d_v"],
+                    state_spec["d_k"])
                 self.state = tuple(
-                    jnp.zeros((rows, state_spec["heads"], state_spec["d_v"],
-                               state_spec["d_k"]), jnp.float32)
+                    jnp.zeros((rows,) + tuple(shape), jnp.float32)
                     for _ in range(state_spec["layers"]))
             # a slot's carried convolution inputs [taps - 1, width], laid
             # out in whole tiles as the decode step's kernel takes them
             if state_spec["taps"]:
                 from ...ops.kernels.short_conv import pool_shape
+                self._conv_width = state_spec["conv_width"]
+                self._conv_channels = state_spec.get(
+                    "conv_channels", self._conv_width)
                 self.conv = jnp.zeros(
                     pool_shape(state_spec["layers"], rows,
                                state_spec["taps"],
@@ -655,13 +663,25 @@ class BlockedKVCache:
         L, planes, _, row = self.data.shape
         return L * planes * row * self.data.dtype.itemsize
 
-    def state_bytes_per_slot(self) -> int:
+    def state_bytes_per_slot(self, resident: bool = False) -> int:
         """Bytes of recurrent state and convolution inputs one sequence
-        slot holds over all recurrent layers (0 without any)."""
-        conv = 0 if self.conv is None else \
-            self.conv.size * self.conv.dtype.itemsize // self.conv.shape[1]
-        return sum(a.size * a.dtype.itemsize // a.shape[0]
-                   for a in self.state or ()) + conv
+        slot holds over all recurrent layers (0 without any): the
+        model's (a pool wider than the layers' channels,
+        ``short_conv.whole_width``, counts the channels), or with
+        ``resident`` what the device stores for them, an array's last two
+        dimensions in whole tiles (8 sublanes of 4 bytes, 16 of 2, by 128
+        lanes: 96 lanes of float32 are stored as 128)."""
+        def of(a, rows):
+            shape = list(a.shape)
+            if resident:
+                sub = 32 // a.dtype.itemsize
+                shape[-1] = -(-shape[-1] // 128) * 128
+                shape[-2] = -(-shape[-2] // sub) * sub
+            return math.prod(shape) * a.dtype.itemsize // rows
+        conv = 0 if self.conv is None else of(self.conv, self.conv.shape[1])
+        if not resident and self.conv is not None:
+            conv = conv * self._conv_channels // self._conv_width
+        return sum(of(a, a.shape[0]) for a in self.state or ()) + conv
 
     def window_bytes_per_row(self) -> int:
         """Bytes one position holds in the window pool over all

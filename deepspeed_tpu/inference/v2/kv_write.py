@@ -5,7 +5,8 @@ loop's ring at flush, the ``seq``-sharded exchange) calls
 :func:`store_rows`, over the paged pool or, for a sliding-window layer,
 over the window pool (the same form, the same writer, another table). A sequence's rows are consecutive positions, so
 inside a block they are consecutive pool slots: with the static tile
-``t = gcd(n, block_size)`` the positions are cut at multiples of ``t``
+``t = gcd(n, block_size)`` (:func:`tile_rows`: halved for very wide
+rows) the positions are cut at multiples of ``t``
 and every piece lies in ONE block, one contiguous window of ``t`` rows.
 The pool is addressed as whole windows, ``[L * P * slots / t, t, W]``
 (``t`` divides ``block_size``, so also the slots), under ONE flat index,
@@ -45,18 +46,33 @@ _WINDOWS = jax.lax.GatherDimensionNumbers(
     start_index_map=(0, 1, 2))
 
 
-def tile_rows(n: int, block_size: int) -> int:
-    """Rows a window carries for steps of ``n`` positions a sequence."""
-    return math.gcd(n, block_size)
+#: the most elements of one window (512 KiB of bfloat16): the TPU
+#: compiler's gather takes a larger one in lane pieces of a RE-LAID pool,
+#: a whole-pool copy a piece (5.6 GB at a 7.56 GB pool of 3,840-lane rows
+#: under 256-row windows, which no longer compiled; 128 rows of OLMoE's
+#: 2,048 lanes, the widest until then, sit on the limit; PERF.md, PR 65)
+_WINDOW_ELEMENTS = 1 << 18
 
 
-def runs_issued(start: int, count: int, n: int, block_size: int) -> int:
+def tile_rows(n: int, block_size: int, row: int = 0) -> int:
+    """Rows a window carries for steps of ``n`` positions a sequence, in
+    a pool whose rows are ``row`` lanes: their greatest common divisor
+    with the block, halved while a window passes
+    :data:`_WINDOW_ELEMENTS`."""
+    t = math.gcd(n, block_size)
+    while t % 2 == 0 and t * row > _WINDOW_ELEMENTS:
+        t //= 2
+    return t
+
+
+def runs_issued(start: int, count: int, n: int, block_size: int,
+                row: int = 0) -> int:
     """Windows :func:`store_rows` writes for one sequence's ``count`` real
     rows from position ``start`` (trash windows not counted): the
     engine's ``kv_write_runs``, the writer's own arithmetic."""
     if count <= 0:
         return 0
-    t = tile_rows(n, block_size)
+    t = tile_rows(n, block_size, row)
     return (start + count - 1) // t - start // t + 1
 
 
@@ -79,7 +95,7 @@ def write_plan(start, count, tables, n, block_size, pool_shape, shard=None
     block goes to the local trash block."""
     P, slots = pool_shape[1], pool_shape[2]
     S, bs, i32 = start.shape[0], block_size, jnp.int32
-    t = tile_rows(n, bs)
+    t = tile_rows(n, bs, pool_shape[3])
     K = n // t + (t > 1)
     # window k of sequence s holds positions [wpos, wpos + t), wpos a
     # multiple of t; its row i is the step's row first + i

@@ -231,6 +231,16 @@ def _short_conv(conv, si: int, batch: RaggedBatch, fresh, live, pre, w,
     from ...ops.kernels import default_interpret, short_conv
     S, C, W = pre.shape
     slots = batch.state_slots
+    wide = conv.shape[2] * conv.shape[3] // (w.shape[0] - 1)
+    if wide > W:
+        # the pool is wider than the layer's channels
+        # (``short_conv.whole_width``): zeros ride in the rest
+        room = lambda t: jnp.pad(                        # noqa: E731
+            t, [(0, 0)] * (t.ndim - 1) + [(0, wide - W)])
+        conv, y = _short_conv(conv, si, batch, fresh, live, room(pre),
+                              room(w), None if bias is None else room(bias),
+                              activation)
+        return conv, y[..., :W]
     if C == 1 and short_conv.decode_uses_kernel(S, W, conv.dtype):
         conv, y = short_conv.short_conv_decode_step(
             conv, si, slots, pre[:, 0], w, bias, fresh, live,
@@ -290,6 +300,49 @@ def _kda_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
             jnp.where(live[:, None, None, None], Sn, St0))
     return _with_layer_state(kv, state, conv, si, st), \
         kda_output(p, o, h, model_cfg, dtype)
+
+
+@jax.jit
+def gdn_chunked_prefill(q, k, v, g, beta, St0, n_tokens):
+    """:func:`kda_chunked_prefill` for the delta rule with ONE decay a
+    head, on states laid out as its pool holds them."""
+    from ...ops.kernels.delta_rule import gdn_prefill
+    return gdn_prefill(q, k, v, g, beta, St0, n_tokens)
+
+
+def _gdn_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
+               dtype):
+    """One gated delta-rule layer with ONE decay a head over the state
+    pool, as :func:`_kda_mixer` runs the channel form: the same rows,
+    slots and short convolution (q | k | v of two widths), the head's
+    decay and the family's own output (``models/olmo_hybrid.py``), a
+    state ``[d_k, heads x d_v]`` a row (``delta_rule.gdn_state_shape``).
+    Padded positions leave the state as it was (beta 0, g 0). Returns
+    (kv, y [S, C, M])."""
+    from ...models.olmo_hybrid import gdn_output, gdn_recurrence_inputs
+    from ...models.solar_open2 import kda_conv_inputs
+    from ...ops.kernels.delta_rule import gdn_decode_update
+    state, conv, st, slots, fresh, live = _state_rows(kv, si, batch)
+    S, C, _ = h.shape
+    pre, w = kda_conv_inputs(p, h, dtype)
+    conv, y = _short_conv(conv, si, batch, fresh, live, pre, w)
+    q, k, v, g, beta = gdn_recurrence_inputs(p, h, y, model_cfg, dtype)
+    g = jnp.where(valid_q[..., None], g, 0.0)
+    beta = jnp.where(valid_q[..., None], beta, 0.0)
+    if C == 1:
+        # exp(-inf) = 0 wipes what the slot held: a fresh row's zero state
+        g1 = jnp.where((fresh & live)[:, None], -jnp.inf, g[:, 0])
+        o, st = gdn_decode_update(st, slots, q[:, 0], k[:, 0], v[:, 0], g1,
+                                  beta[:, 0])
+        o = o[:, None]
+    else:
+        St0 = st[slots]                                   # [S, dk, H dv]
+        o, Sn = gdn_chunked_prefill(
+            q, k, v, g, beta, jnp.where(fresh[:, None, None], 0.0, St0),
+            batch.n_tokens)
+        st = st.at[slots].set(jnp.where(live[:, None, None], Sn, St0))
+    return _with_layer_state(kv, state, conv, si, st), \
+        gdn_output(p, o, h, model_cfg, dtype)
 
 
 def _mamba2_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
@@ -542,19 +595,27 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
         or ("attn",) * model_cfg.num_layers
     ffn_kinds = getattr(model_cfg, "ffn_kinds", None) \
         or ("moe" if is_moe else "dense",) * len(kinds)
-    # a norm on each branch's OUTPUT too, before the residual add
-    sandwich = getattr(model_cfg, "sandwich_norm", False)
+    # where a block's norms stand (``block_norms``): in front of each
+    # branch ("pre", the default), on its OUTPUT too, before the residual
+    # add ("sandwich"), or on the output alone ("post")
+    norms = getattr(model_cfg, "block_norms", "pre")
+    pre_norm, out_norm = norms != "post", norms != "pre"
     act = _mlp_act(model_cfg)
     plane = si = wplane = xi = 0
     for li, (kind, ffn) in enumerate(zip(kinds, ffn_kinds)):
         p = params[f"layer_{li}"]
         if kind is not None:
             with region("norm"):
-                h = _rms(x, p["input_norm"]["scale"],
-                         model_cfg.rms_eps).astype(dtype)
+                h = _rms(x, p["input_norm"]["scale"], model_cfg.rms_eps
+                         ).astype(dtype) if pre_norm else x.astype(dtype)
             if kind == "kda":
                 with region("linear_attn"):
                     kv, y = _kda_mixer(p["kda"], h, kv, si, batch,
+                                       model_cfg, valid_q, dtype)
+                si += 1
+            elif kind == "gdn":
+                with region("linear_attn"):
+                    kv, y = _gdn_mixer(p["gdn"], h, kv, si, batch,
                                        model_cfg, valid_q, dtype)
                 si += 1
             elif kind == "mamba2":
@@ -595,7 +656,7 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
                         ring_layer=plane + wplane if "swa" in kinds
                         else None)
                 wplane, plane = wplane + swa, plane + (not swa)
-            if sandwich:
+            if out_norm:
                 with region("norm"):
                     y = _rms(y, p["attn_branch_norm"]["scale"],
                              model_cfg.rms_eps)
@@ -607,8 +668,8 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
             continue
 
         with region("norm"):
-            h = _rms(x, p["post_attn_norm"]["scale"],
-                     model_cfg.rms_eps).astype(dtype)
+            h = _rms(x, p["post_attn_norm"]["scale"], model_cfg.rms_eps
+                     ).astype(dtype) if pre_norm else x.astype(dtype)
         if ffn == "moe":
             # the fused decode loop's kv carries a count of routed rows
             counted = getattr(kv, "moe_rows", None) is not None
@@ -646,7 +707,7 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
                 m = jax.nn.silu(gate) * up
                 m = woq_mm(m, pm["down_proj"]["kernel"], dtype)
                 y = tp_all_reduce(m, cfg)                 # TP collective 2
-        if sandwich:
+        if out_norm:
             with region("norm"):
                 y = _rms(y, p["mlp_branch_norm"]["scale"], model_cfg.rms_eps)
         with region("residual"):

@@ -963,7 +963,7 @@ class RaggedRunnerBase:
             or ("attn",) * self.num_layers
         self.kv_layers = sum(k in ("attn", "mla", "sparse") for k in kinds)
         other = sorted({k for k in kinds
-                        if k in ("swa", "mla", "kda", "mamba2")})
+                        if k in ("swa", "mla", "kda", "gdn", "mamba2")})
         if other and {"sparse", "lightning"} & set(kinds):
             raise ValueError(
                 f"block-selected ('sparse') and Lightning ('lightning') "
@@ -1021,11 +1021,13 @@ class RaggedRunnerBase:
             self.kv_heads, self.head_dim = 1, model_cfg.latent_row
         #: what the state pool must hold (None: no recurrent layer): one
         #: state ``[heads, d_v, d_k]`` a recurrent layer (``heads`` 0: the
-        #: layers keep no state) and the last ``taps - 1`` inputs of its
+        #: layers keep no state; ``state_shape``, where a kind gives one,
+        #: is how the pool lays a slot's ``heads x d_v x d_k`` numbers
+        #: out instead) and the last ``taps - 1`` inputs of its
         #: convolution, ``conv_width`` wide (``taps`` 0: they have none)
         self.state_spec = None
         recurrent = [k for k in kinds
-                     if k in ("kda", "mamba2", "lightning", "conv")]
+                     if k in ("kda", "gdn", "mamba2", "lightning", "conv")]
         if len(set(recurrent)) > 1:
             raise ValueError(
                 f"recurrent layers of more than one kind "
@@ -1045,6 +1047,21 @@ class RaggedRunnerBase:
                 "heads": model_cfg.kda_heads, "d_v": d, "d_k": d,
                 "taps": model_cfg.kda_conv,
                 "conv_width": 3 * model_cfg.kda_heads * d}
+        elif recurrent and recurrent[0] == "gdn":
+            # ONE decay a head: keys and values of two widths, q | k | v
+            # through the convolution, and the layout that tiles whole
+            from ...ops.kernels.delta_rule import gdn_state_shape
+            from ...ops.kernels.short_conv import whole_width
+            from ...utils.dtypes import resolve_dtype
+            H, dk, dv = (model_cfg.gdn_heads, model_cfg.gdn_key_dim,
+                         model_cfg.gdn_value_dim)
+            self.state_spec = {
+                "kind": "gdn", "layers": len(recurrent), "heads": H,
+                "d_v": dv, "d_k": dk, "taps": model_cfg.gdn_conv,
+                "conv_width": whole_width(model_cfg.gdn_conv_width,
+                                          resolve_dtype(cfg.dtype)),
+                "conv_channels": model_cfg.gdn_conv_width,
+                "state_shape": gdn_state_shape(H, dk, dv)}
         elif recurrent and recurrent[0] == "lightning":
             # a state [d_v, d_k] a head and NO short convolution (taps 0:
             # the pool then has no convolution part)

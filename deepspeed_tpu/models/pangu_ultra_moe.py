@@ -73,6 +73,13 @@ class PanguUltraMoEConfig(MixtralConfig):
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
+    def block_norms(self) -> str:
+        """Where a block's norms stand, as the runner reads it ("pre",
+        "sandwich" or "post"): the family's published key is the
+        boolean."""
+        return "sandwich" if self.sandwich_norm else "pre"
+
+    @property
     def latent_row(self) -> int:
         """The row as stored: whole 128-lane groups, the tail zero."""
         return -(-self.head_dim // 128) * 128

@@ -47,6 +47,9 @@ from .lfm2 import Lfm2, Lfm2Config
 from .lfm2 import make_model as make_lfm2
 from .mellum import LAYER_TYPES, Mellum, MellumConfig, YarnRope
 from .mellum import make_model as make_mellum
+from .olmo_hybrid import LAYER_TYPES as OLMO_HYBRID_LAYER_TYPES
+from .olmo_hybrid import OlmoHybrid, OlmoHybridConfig
+from .olmo_hybrid import make_model as make_olmo_hybrid
 from .minicpm_sala import (MIXER_TYPES, MiniCPMSALA, MiniCPMSALAConfig,
                            SparseConfig)
 from .minicpm_sala import make_model as make_minicpm_sala
@@ -694,6 +697,61 @@ def _entry_lfm2(d):
         router_aux_loss_coef=0.0)
 
 
+def _entry_olmo_hybrid(d):
+    """Olmo-Hybrid (allenai/Olmo-Hybrid-7B): ``layer_types`` says which
+    layers are gated delta-rule layers with ONE decay a head
+    (``linear_attention``: the ``linear_*`` keys give their heads, the two
+    widths and the taps) and which attend in full (multi-head, RMSNorm
+    over the whole q and k projections); a dense SwiGLU in every layer,
+    the norms on the branches' outputs, the head untied. A null
+    ``rope_parameters.rope_theta`` is NO position code; a number is the
+    plain rotary code. What the served path has no form for is refused by
+    name: grouped key heads in the linear layers, a bias, another
+    activation, a window, a scaled rotary code."""
+    n = d.get("num_hidden_layers", 32)
+    types = d.get("layer_types") or []
+    bad = sorted(set(types) - set(OLMO_HYBRID_LAYER_TYPES))
+    if bad or len(types) != n:
+        raise ValueError(
+            f"olmo_hybrid layer_types must name num_hidden_layers ({n}) "
+            f"layers of {sorted(OLMO_HYBRID_LAYER_TYPES)}; got "
+            f"{len(types)} with {bad}")
+    heads = d.get("linear_num_value_heads", 30)
+    for key, have, served in (
+            ("linear_num_key_heads", d.get("linear_num_key_heads", heads),
+             heads),
+            ("attention_bias", bool(d.get("attention_bias", False)), False),
+            ("hidden_act", d.get("hidden_act", "silu"), "silu"),
+            ("sliding_window", d.get("sliding_window"), None)):
+        if have != served:
+            raise ValueError(
+                f"olmo_hybrid configs with {key}={have!r} are not "
+                f"supported (the served path has {served!r})")
+    rope = d.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default" \
+            or d.get("rope_scaling") is not None:
+        raise ValueError("olmo_hybrid configs with a scaled rotary code "
+                         f"({rope or d.get('rope_scaling')!r}) are not "
+                         "supported (none, or 'default')")
+    theta = rope.get("rope_theta", d.get("rope_theta"))
+    base = _hf_llama(d, num_layers=n, rms_eps=d.get("rms_norm_eps", 1e-6),
+                     rope_theta=float(theta or 10000.0))
+    head_dim = d.get("head_dim") or base["hidden_size"] // base["num_heads"]
+    if head_dim * base["num_heads"] != base["hidden_size"]:
+        raise ValueError(
+            f"olmo_hybrid configs with head_dim={head_dim!r} x "
+            f"{base['num_heads']} heads != hidden_size are not supported")
+    return OlmoHybridConfig(
+        **base,
+        layer_kinds=tuple(OLMO_HYBRID_LAYER_TYPES[t] for t in types),
+        use_rope=theta is not None,
+        gdn_heads=heads,
+        gdn_key_dim=d.get("linear_key_head_dim", 96),
+        gdn_value_dim=d.get("linear_value_head_dim", 192),
+        gdn_conv=d.get("linear_conv_kernel_dim", 4),
+        gdn_neg_eigval=bool(d.get("linear_allow_neg_eigval", True)))
+
+
 def _entry_nemotron_h(d):
     """Nemotron-H (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16):
     ``hybrid_override_pattern`` read letter by letter, a layer a mixer
@@ -826,6 +884,8 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
     "afmoe": ArchEntry(AfmoeConfig, Afmoe, make_afmoe, _entry_afmoe),
     "lfm2": ArchEntry(Lfm2Config, Lfm2, make_lfm2, _entry_lfm2),
     "lfm2_moe": ArchEntry(Lfm2Config, Lfm2, make_lfm2, _entry_lfm2),
+    "olmo_hybrid": ArchEntry(OlmoHybridConfig, OlmoHybrid,
+                             make_olmo_hybrid, _entry_olmo_hybrid),
     "minicpm_sala": ArchEntry(MiniCPMSALAConfig, MiniCPMSALA,
                               make_minicpm_sala, _entry_minicpm_sala),
     "gpt_neo": ArchEntry(GPTNeoConfig, GPTNeo, make_gpt_neo,

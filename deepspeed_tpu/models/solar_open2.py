@@ -164,6 +164,20 @@ def kda_conv_inputs(p, h, dtype):
     return pre, w.astype(f32)
 
 
+def l2_normed(t):
+    """t / |t|_2 over the last axis (a head's lanes), 1e-6 under the
+    root."""
+    return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+
+
+def delta_beta(p, h, neg_eigval: bool, dtype):
+    """The delta rule's step size a head, float32: ``sigmoid(W_b h)``,
+    doubled where the family allows negative eigenvalues."""
+    beta = jax.nn.sigmoid(jnp.matmul(h, p["b_proj"].astype(dtype),
+                                     preferred_element_type=jnp.float32))
+    return 2.0 * beta if neg_eigval else beta
+
+
 def kda_recurrence_inputs(p, h, y, cfg: SolarOpen2Config, dtype):
     """From the activated convolution y [B, T, 3*H*dk] float32 and the
     normed residual h to the recurrence's inputs: (q, k [B, T, H, dk],
@@ -174,18 +188,13 @@ def kda_recurrence_inputs(p, h, y, cfg: SolarOpen2Config, dtype):
     mm = lambda x, w: jnp.matmul(x, w.astype(dtype),     # noqa: E731
                                  preferred_element_type=f32)
     q, k, v = (t.reshape(B, T, H, d) for t in jnp.split(y, 3, axis=-1))
-    l2 = lambda t: t * jax.lax.rsqrt(                    # noqa: E731
-        jnp.sum(t * t, -1, keepdims=True) + 1e-6)
-    q, k = l2(q) * d ** -0.5, l2(k)
+    q, k = l2_normed(q) * d ** -0.5, l2_normed(k)
     f = jnp.matmul(mm(h, p["f_a"]), p["f_b"].astype(f32),
                    precision=jax.lax.Precision.HIGHEST)
     g = -jnp.exp(p["A_log"].astype(f32))[:, None] \
         * jax.nn.softplus(f.reshape(B, T, H, d)
                           + p["dt_bias"].astype(f32).reshape(H, d))
-    beta = jax.nn.sigmoid(mm(h, p["b_proj"]))
-    if cfg.kda_neg_eigval:
-        beta = 2.0 * beta
-    return q, k, v, g, beta
+    return q, k, v, g, delta_beta(p, h, cfg.kda_neg_eigval, dtype)
 
 
 def kda_inputs(p, h, cfg: SolarOpen2Config, conv_prev, dtype):

@@ -47,6 +47,7 @@ broadcasts over its sublanes for free; only ``v`` needs turning.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -451,3 +452,321 @@ def kda_decode_update(state, slots, q, k, v, g, beta, *,
         state, slots.astype(jnp.int32), q, k, beta[..., None] * k,
         jnp.exp(g), v, interpret=impl == "interpret")
     return o, state
+
+
+# --------------------------------------------------------------------- #
+# ONE decay a head (the gated delta rule of the state-space lineage):
+# the recurrence above with a_t the same in every channel of a head's
+# keys, keys and values of two widths. g [.., H] is the head's log decay.
+# The definition and the chunked twin are the channel forms on a
+# broadcast decay; the two Pallas kernels below are the scalar forms.
+# --------------------------------------------------------------------- #
+
+def _over_keys(g, q):
+    """The head's log decay g [.., H] in every channel of q [.., H, dk]."""
+    return jnp.broadcast_to(g[..., None].astype(F32), q.shape)
+
+
+def gdn_state_shape(H: int, dk: int, dv: int) -> Tuple[int, int]:
+    """A sequence's states of one layer as the serving pool holds them:
+    ``[dk, H * dv]``, keys along the sublanes and (head, value) along the
+    lanes. Where ``dk`` is a whole number of sublane tiles and ``H * dv``
+    of lane tiles the array tiles whole (96 = 12 x 8, 30 x 192 = 45 x
+    128), so the decode step moves the state's bytes and no more;
+    ``[H, dv, dk]``, KDA's layout, would store 96 lanes as 128."""
+    return (dk, H * dv)
+
+
+def _from_pool(St, H: int):
+    """[B, dk, H * dv] (the pool's rows) -> [B, H, dk, dv]."""
+    B, dk, W = St.shape
+    return jnp.moveaxis(St.reshape(B, dk, H, W // H), 2, 1)
+
+
+def _to_pool(S):
+    """[B, H, dk, dv] -> [B, dk, H * dv]."""
+    B, H, dk, dv = S.shape
+    return jnp.moveaxis(S, 1, 2).reshape(B, dk, H * dv)
+
+
+def _lane_group(dv: int) -> int:
+    """Heads whose values fill whole 128-lane tiles side by side."""
+    return 128 // math.gcd(dv, 128)
+
+
+def gdn_decode_uses_kernel(H: int, dk: int, dv: int,
+                           backend: Optional[str] = None) -> bool:
+    """Whether :func:`gdn_decode_update` runs the Pallas update: on the
+    TPU, keys a whole number of sublane tiles and the heads a whole
+    number of lane groups (two heads of 192 values are three tiles)."""
+    backend = backend or jax.default_backend()
+    return backend == "tpu" and dk % 8 == 0 and H % _lane_group(dv) == 0
+
+
+_DECODE_BLOCK_BYTES = 1 << 20      # of state a grid step reads, and writes
+
+
+def _decode_heads(H: int, dk: int, dv: int) -> int:
+    """Heads a grid step of the decode update: the most whole lane groups
+    that divide the heads and keep a step's state under a megabyte (10 of
+    30 at 96 x 192: 737 KB in, as much out, twice for the pipeline)."""
+    group = _lane_group(dv)
+    fit = [n for n in range(group, H + 1, group)
+           if H % n == 0 and n * dk * dv * 4 <= _DECODE_BLOCK_BYTES]
+    return max(fit) if fit else group
+
+
+def _gdn_decode_kernel(slots_ref, qk_ref, row_ref, s_ref, so_ref, o_ref, *,
+                       dv: int, group: int):
+    del slots_ref                            # used by the index maps only
+    dk, W = s_ref.shape                      # W = heads of the step x dv
+    gw = group * dv                          # a group's lanes: whole tiles
+    lane = jax.lax.broadcasted_iota(jnp.int32, (dk, gw), 1)
+    q_t, k_t = qk_ref[0], qk_ref[1]          # [dk, heads]: a head a column
+
+    def over_lanes(x, first):
+        """The columns of heads ``first ..`` over their own values'
+        lanes: [dk, gw]."""
+        out = jnp.broadcast_to(x[:, first:first + 1], (dk, gw))
+        for i in range(1, group):
+            out = jnp.where(lane >= i * dv, jnp.broadcast_to(
+                x[:, first + i:first + i + 1], (dk, gw)), out)
+        return out
+
+    for n in range(W // gw):
+        at = slice(n * gw, (n + 1) * gw)
+        kc, qc = over_lanes(k_t, n * group), over_lanes(q_t, n * group)
+        v, a, beta = (row_ref[i:i + 1, at] for i in range(3))
+        Sd = s_ref[:, at] * a
+        kS = jnp.sum(Sd * kc, axis=0, keepdims=True)
+        Sn = Sd + kc * ((v - kS) * beta)
+        so_ref[:, at] = Sn
+        o_ref[:, at] = jnp.sum(Sn * qc, axis=0, keepdims=True)
+
+
+# jitted under its own name: the device trace names the Mosaic call after
+# it, and the benchmark's reader finds the update by this one
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gdn_decode_state_update(state, slots, q, k, v, a, beta, *,
+                            interpret=False):
+    """state [rows, dk, H * dv] float32 (:func:`gdn_state_shape`), in
+    place; q, k [S, H, dk], v [S, H, dv], a (the decay, exp g) and beta
+    [S, H], all float32. The grid is (row, block of heads): a step reads
+    and writes ``[dk, heads x dv]`` of one slot's state, whole tiles. What
+    lies along the lanes (v, the decay, the step size, the output) is a
+    row of ``heads x dv`` lanes; what lies along the sublanes (q, k) a
+    column a head, spread over the head's lanes inside. Returns (state,
+    o [S, H, dv])."""
+    S, H, dk = q.shape
+    dv = v.shape[-1]
+    hb = _decode_heads(H, dk, dv)
+    # [S, blocks, 2, dk, hb]: q and k of a block's heads as columns
+    qk = jnp.moveaxis(jnp.stack([q, k], 1).reshape(S, 2, H // hb, hb, dk),
+                      (2, 3), (1, 4))
+    rows = jnp.stack([v.reshape(S, H * dv), jnp.repeat(a, dv, axis=-1),
+                      jnp.repeat(beta, dv, axis=-1)], 1)   # [S, 3, H dv]
+    st = pl.BlockSpec((None, dk, hb * dv),
+                      lambda i, j, slots: (slots[i], 0, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(S, H // hb),
+        in_specs=[pl.BlockSpec((None, None, 2, dk, hb),
+                               lambda i, j, *_: (i, j, 0, 0, 0)),
+                  pl.BlockSpec((None, 3, hb * dv),
+                               lambda i, j, *_: (i, 0, j)), st],
+        out_specs=[st, pl.BlockSpec((None, 1, hb * dv),
+                                    lambda i, j, *_: (i, 0, j))])
+    state, o = pl.pallas_call(
+        functools.partial(_gdn_decode_kernel, dv=dv,
+                          group=_lane_group(dv)),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((S, 1, H * dv), F32)],
+        # operand 3 (after the prefetched slots) is the pool
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(slots, qk, rows, state)
+    return state, o.reshape(S, H, dv)
+
+
+def gdn_decode_update(state, slots, q, k, v, g, beta, *,
+                      impl: Optional[str] = None):
+    """One decode token for every row on one layer's state pool
+    ``[rows, dk, H * dv]`` in place, as :func:`kda_decode_update` with the
+    head's log decay g [S, H] (``-inf`` wipes what the slot held). Returns
+    (o [S, H, dv] float32, state). ``impl``: "pallas" where
+    :func:`gdn_decode_uses_kernel` says so, "xla" elsewhere (gather,
+    :func:`kda_step`, scatter), "interpret" for the tests."""
+    H, dk, dv = q.shape[1], q.shape[2], v.shape[-1]
+    if impl is None:
+        impl = "pallas" if gdn_decode_uses_kernel(H, dk, dv) else "xla"
+    q, k, v, g, beta = (x.astype(F32) for x in (q, k, v, g, beta))
+    if impl == "xla":
+        o, Sn = kda_step(q, k, v, _over_keys(g, q), beta,
+                         _from_pool(state[slots], H))
+        return o, state.at[slots].set(_to_pool(Sn))
+    state, o = gdn_decode_state_update(
+        state, slots.astype(jnp.int32), q, k, v, jnp.exp(g), beta,
+        interpret=impl == "interpret")
+    return o, state
+
+
+def gdn_prefill_uses_kernel(T: int, H: int, dk: int, dv: int,
+                            backend: Optional[str] = None) -> bool:
+    """Whether :func:`gdn_prefill` runs the Pallas chunk kernel: on the
+    TPU, at ``T`` a whole number of 64-position chunks and widths of
+    whole sublane tiles (a block's last two dimensions are a chunk's
+    positions and a head's whole width, whatever it is; the heads are
+    blocked by any number that divides them). The mixer dispatches on it
+    and the engine counts by it."""
+    backend = backend or jax.default_backend()
+    return (backend == "tpu" and T >= _CHUNK and T % _CHUNK == 0
+            and dk % 8 == 0 and dv % 8 == 0)
+
+
+def _prefill_heads(H: int) -> int:
+    """Heads a grid step of the chunk kernel: the most that divide the
+    heads up to a sublane tile's eight (6 of 30)."""
+    return max(n for n in range(1, _PREFILL_HEADS + 1) if H % n == 0)
+
+
+def _gdn_consts():
+    """What the heads of a grid step share: the chunk's triangles and the
+    sub-blocks' own columns."""
+    L, sub = _CHUNK, _SUB
+    t = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    t0 = t // sub * sub
+    return {"incl": (j <= t).astype(F32), "strict": (j < t).astype(F32),
+            "after": (j > t).astype(F32), "eye": (j == t).astype(F32),
+            "cross": (j < t0).astype(F32),
+            "col": j - t0, "row": (t - t0)[:, :1]}
+
+
+def _gdn_chunk_head(q, k, v, gb, S, c):
+    """One chunk of one head with ONE decay a position: q, k [L, dk];
+    v [L, dv]; gb [2, L] the log decay and the step size as ROWS; S
+    [dk, dv]; ``c`` of :func:`_gdn_consts`. With a scalar decay the two
+    [L, L] tables are plain matmuls under one mask of ``exp(G_t - G_i)``,
+    where the channel form needs its sub-blocks' reference points. Every
+    exponent is a sum of the ``g`` it spans (a matmul against a 0 / 1
+    triangle: terms of one sign, no difference of running sums). The
+    diagonal sub-blocks of ``I + Diag(beta) A`` are inverted by forward
+    substitution, the rest block by block, as :func:`_chunk_head` does.
+    Returns (o [L, dv], S)."""
+    L, dv = v.shape
+    sub = _SUB
+    g_in = c["incl"] * gb[0:1]                # g_j where j <= t
+    G = jnp.sum(g_in, axis=1, keepdims=True)               # [L, 1]
+    Gaft = jnp.sum(c["after"] * gb[0:1], axis=1, keepdims=True)
+    beta = jnp.sum(c["eye"] * gb[1:2], axis=1, keepdims=True)
+    # sum of g over i < j <= t (0 above the diagonal, masked below)
+    D = jnp.exp(_dot(g_in, c["strict"]))
+    eG = jnp.exp(G)
+    X = _dot(jnp.concatenate([k * beta, q]), k, _NT)       # [2L, L]
+    N = X[:L] * D * c["strict"]               # Diag(beta) A
+    QK = X[L:] * D * c["incl"]
+    Ti = c["eye"]
+    for i in range(sub):
+        n_i = jnp.sum(jnp.where(c["col"] == i, N, 0.0), axis=1,
+                      keepdims=True)
+        Ti = Ti - jnp.where(c["row"] > i, n_i, 0.0) * _own(Ti, i)
+    xs = _dot(jnp.concatenate([k * eG, q * eG]), S)        # [2L, dv]
+    Y = _dot(Ti, beta * v - beta * xs[:L])
+    Nb = _dot(Ti, N * c["cross"])
+    U = [Y[:sub]]
+    for n in range(sub, L, sub):
+        done = jnp.concatenate(U + [jnp.zeros((L - n, dv), F32)])
+        U.append(Y[n:n + sub] - _dot(Nb[n:n + sub], done))
+    U = jnp.concatenate(U)
+    o = xs[L:] + _dot(QK, U)
+    S = S * eG[L - 1:] + _dot(k * jnp.exp(Gaft), U, _TN)
+    return o, S
+
+
+def _gdn_prefill_kernel(n_ref, q_ref, k_ref, v_ref, gb_ref, s0_ref, o_ref,
+                        s_ref):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    # a chunk wholly past the row's positions leaves the state alone
+    live = pl.program_id(2) * _CHUNK < n_ref[pl.program_id(0)]
+
+    @pl.when(live)
+    def _():
+        # the block's heads side by side, for :func:`_prefill_kernel`'s
+        # reason; the operands are head-major, so a block is the batch
+        c = _gdn_consts()
+        o, S = jax.vmap(lambda *x: _gdn_chunk_head(*x, c))(
+            q_ref[...], k_ref[...], v_ref[...], gb_ref[...], s_ref[...])
+        o_ref[...] = o
+        s_ref[...] = S
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gdn_chunk_prefill(q, k, v, g, beta, S0, lengths, *, interpret=False):
+    """The scalar-decay chunked form as one Pallas call, HEAD-MAJOR: q, k
+    [B, H, T, dk], v [B, H, T, dv], g, beta [B, H, T] (float32), S0
+    [B, H, dk, dv]; ``lengths`` as :func:`kda_chunk_prefill`'s. The grid
+    is (row, block of heads, chunk), the chunks innermost and in order,
+    the states in VMEM from a row's first chunk to its last. A block's
+    last two dimensions are a chunk's 64 positions and a head's WHOLE
+    width, so any width goes and any number of heads that divides them
+    (:func:`_prefill_heads`). Returns (o [B, H, T, dv], S)."""
+    B, H, T, dk = q.shape
+    dv = v.shape[-1]
+    hb, L = _prefill_heads(H), _CHUNK
+    at = lambda b, c, n: jnp.minimum(                     # noqa: E731
+        c, jnp.maximum((n[b] + L - 1) // L - 1, 0))
+    seq = lambda d: pl.BlockSpec(                         # noqa: E731
+        (None, hb, L, d), lambda b, j, c, n: (b, j, at(b, c, n), 0))
+    st = pl.BlockSpec((None, hb, dk, dv), lambda b, j, c, n: (b, j, 0, 0))
+    # the log decay and the step size of a chunk as two rows of 64
+    gb = jnp.stack([g, beta], 2).reshape(B, H, 2, T // L, L)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(B, H // hb, T // L),
+        in_specs=[seq(dk), seq(dk), seq(dv),
+                  pl.BlockSpec((None, hb, None, 2, L),
+                               lambda b, j, c, n: (b, j, at(b, c, n), 0, 0)),
+                  st],
+        out_specs=[pl.BlockSpec((None, hb, L, dv),
+                                lambda b, j, c, n: (b, j, c, 0)), st])
+    return pl.pallas_call(
+        _gdn_prefill_kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, dv), F32),
+                   jax.ShapeDtypeStruct(S0.shape, F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(lengths, q, k, v, jnp.moveaxis(gb, 2, 3), S0)
+
+
+def gdn_prefill(q, k, v, g, beta, St0, lengths=None, *,
+                impl: Optional[str] = None):
+    """A prefill chunk of every row on the pool's states St0
+    [B, dk, H * dv] (:func:`gdn_state_shape`): q, k [B, T, H, dk],
+    v [B, T, H, dv], g, beta [B, T, H]. The Pallas kernel where
+    :func:`gdn_prefill_uses_kernel` says so, :func:`kda_chunked` on the
+    broadcast decay elsewhere; ``lengths`` and ``impl`` as
+    :func:`kda_prefill`'s. Returns (o [B, T, H, dv] float32, St)."""
+    B, T, H, dk = q.shape
+    if impl is None:
+        impl = "pallas" if gdn_prefill_uses_kernel(
+            T, H, dk, v.shape[-1]) else "xla"
+    S0 = _from_pool(St0.astype(F32), H)
+    if impl == "xla":
+        o, S = kda_chunked(q, k, v, _over_keys(g, q), beta, S0)
+        return o, _to_pool(S)
+    if lengths is None:
+        lengths = jnp.full((B,), T, jnp.int32)
+    heads_first = lambda x: jnp.moveaxis(x.astype(F32), 2, 1)  # noqa: E731
+    o, S = gdn_chunk_prefill(
+        *(heads_first(x) for x in (q, k, v, g, beta)), S0,
+        lengths.astype(jnp.int32), interpret=impl == "interpret")
+    return jnp.moveaxis(o, 1, 2), _to_pool(S)

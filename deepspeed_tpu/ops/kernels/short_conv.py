@@ -69,6 +69,18 @@ def pool_shape(layers: int, rows: int, taps: int, width: int
     return (layers, rows, (taps - 1) * width // lanes, lanes)
 
 
+def whole_width(width: int, dtype) -> int:
+    """``width`` channels rounded up to the next width whose taps are whole
+    tiles of the pool's dtype (:func:`decode_uses_kernel`'s grain: 11,520
+    channels of bfloat16, 90 rows of 128 lanes a tap, become 12,288, 96
+    rows = 6 tiles). A layer whose channels fall short asks the pool for
+    this width and carries zeros in the rest (``llama_runner._short_conv``
+    pads the step's inputs and the taps). A width that is no whole number
+    of lane rows (the toy shapes) stays as it is."""
+    unit = _LANES * 8 * 4 // jnp.dtype(dtype).itemsize
+    return width if width % _LANES else -(-width // unit) * unit
+
+
 def decode_uses_kernel(S: int, width: int, dtype,
                        backend: Optional[str] = None) -> bool:
     """Whether a decode step of ``S`` rows runs

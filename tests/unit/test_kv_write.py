@@ -227,3 +227,44 @@ def test_engine_counts_rows_and_runs_per_step_and_per_flush():
     eng.decode_batch(list(prompts), [7, 8], 4)
     assert (stats["kv_write_rows"], stats["kv_write_runs"]) \
         == (86 + 8, 11 + 4)
+
+
+@pytest.mark.parametrize("n,bs,row,t", [
+    (512, 256, 3840, 64),      # 30 K/V heads of 128: 256 rows would be 1.9 MiB
+    (256, 256, 3840, 64), (512, 640, 2048, 128), (512, 256, 1024, 256),
+    (256, 256, 640, 256), (512, 256, 0, 256), (1, 256, 3840, 1),
+    (96, 96, 1 << 20, 3)])     # halved while even, never below an odd tile
+def test_a_window_is_halved_until_the_gather_takes_it_whole(n, bs, row, t):
+    """512 KiB of bfloat16 a window: the cells' windows until PR 65 (at
+    most 128 rows of 2,048 lanes) keep their size, 3,840-lane rows get 64."""
+    assert tile_rows(n, bs, row) == t
+    assert runs_issued(0, n, n, bs, row) == n // t
+
+
+@pytest.mark.parametrize("case", ["aligned", "unaligned", "crossing"])
+def test_a_halved_window_leaves_the_pool_as_the_row_scatter_does(
+        case, monkeypatch):
+    """The writer under a window limit that bites (16 rows of the toy
+    pool's 16 lanes where the step and the block give 256)."""
+    from deepspeed_tpu.inference.v2 import kv_write
+    monkeypatch.setattr(kv_write, "_WINDOW_ELEMENTS", 16 * W)
+    n = bs = 256
+    assert tile_rows(n, bs, W) == 16
+    rng = np.random.default_rng(11)
+    S = 5
+    slots = (S * MAXB + 1) * bs
+    tables = jnp.asarray(
+        rng.permutation(S * MAXB).reshape(S, MAXB), jnp.int32)
+    start, count = jnp.asarray(_starts(case, n, bs)), jnp.asarray(_counts(n))
+    rows = jnp.asarray(rng.standard_normal((2, S, n, W)), jnp.bfloat16)
+    kv = _pool(rng, False, 2, slots)
+    plan = write_plan(start, count, tables, n, bs, kv.data.shape)
+    assert plan.real.shape[1:] == (n // 16 + 1, 16)
+    got = store_rows(kv, LAYER, rows, plan, KV)
+    want = _row_scatter(kv, rows, start, count, tables, bs)
+    keep = slots - bs
+    assert np.array_equal(_bits(got.data[:, :, :keep]),
+                          _bits(want.data[:, :, :keep]))
+    live = int((np.asarray(plan.index[0]) != keep // 16).sum())
+    assert live == sum(runs_issued(int(s), int(c), n, bs, W)
+                       for s, c in zip(start, count))

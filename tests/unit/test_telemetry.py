@@ -456,9 +456,15 @@ class TestServeTelemetry:
                 _serve(eng, _workload())
         finally:
             set_fault_injector(None)
+        # EVERY live recorder of the process dumps (auto_dump), one file
+        # a recorder: an engine an earlier test of this worker left alive
+        # leaves a file too, and ``os.listdir`` names them in no order.
+        # The faulted engine's own file is the one named after its recorder
+        mine = f"_{id(eng.flight) & 0xffff:04x}.json"
         dumps = [f for f in os.listdir(tmp_path)
-                 if f.startswith("flight_fault_mid_commit")]
-        assert dumps
+                 if f.startswith("flight_fault_mid_commit")
+                 and f.endswith(mine)]
+        assert len(dumps) == 1
         trace = json.loads(open(tmp_path / dumps[0]).read())
         assert any(ev["name"] in ("plan", "dispatch", "commit")
                    for ev in trace["traceEvents"])

@@ -342,3 +342,100 @@ def test_refill_step_runs_the_chunk_kernel_of_the_delta_rule(
     tile = 64
     assert {a for a, _ in _scoped_vmem(hlo, "grouped_ffn_decode")} == {
         grouped_ffn.vmem_need(tile, padded // tile, width, inner, 2, True)}
+
+
+def test_olmo_hybrid_loop_and_refill_step_compile_at_the_published_widths(
+        one_chip, monkeypatch):
+    """The fused 256-step decode loop and the [4, 512] refill step of
+    ``serve-olmo-hybrid-rollout-long`` at the published widths, the
+    cell's cut (8 layers) and the cell's pool (7.56 GB of 3,840-lane K/V
+    rows beside 0.89 GB of state), from shapes alone. The loop: six
+    delta-rule layers with ONE decay a head, each its short convolution
+    (11,520 channels in a 12,288-wide pool) and its state update in place
+    on a pool ``[65, 96, 5760]`` that tiles whole, two 30-head MHA layers
+    in the paged decode kernel, nothing state-shaped copied. The refill
+    step: the scalar-decay chunk kernel six times from ONE lowering, the
+    BlockSpec attention kernel twice, and the pool written in windows the
+    compiler's gather takes whole: at 256-row windows of 3,840 lanes it
+    re-laid the pool (5.6 GB of temporaries, and no room for them)."""
+    import json
+    import os
+    import re
+    from collections import Counter
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from benchmark.model_types import olmo_hybrid as mt
+    from deepspeed_tpu.inference.v2.kv_quant import KVPool
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
+    from deepspeed_tpu.ops.kernels import short_conv
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b.json")) as f:
+        mcfg = mt.model_config(json.load(f))
+    with open(os.path.join(root, "benchmark", "cells",
+                           "serve-olmo-hybrid-rollout-long.json")) as f:
+        eng = json.load(f)["engine"]
+    runner = LlamaRaggedRunner(mcfg, RaggedInferenceConfig(
+        **eng, attention_impl="paged_flash"))
+    slots, block, blocks, maxb, steps = (
+        eng["max_seqs"], eng["block_size"], eng["num_blocks"],
+        eng["max_blocks_per_seq"], eng["decode_loop_steps"])
+    assert runner.state_spec == {
+        "kind": "gdn", "layers": 6, "heads": 30, "d_v": 192, "d_k": 96,
+        "taps": 4, "conv_width": 12288, "conv_channels": 11520,
+        "state_shape": (96, 5760)}
+    assert (runner.kv_layers, runner.kv_heads, runner.head_dim) \
+        == (2, 30, 128)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: mt.init_params(mcfg, 0)))
+    state = tuple(spec((slots + 1, 96, 5760), jnp.float32)
+                  for _ in range(6))
+    conv = spec(short_conv.pool_shape(6, slots + 1, 4, 12288), jnp.bfloat16)
+    assert conv.shape == (6, slots + 1, 288, 128)
+    planes = spec((2, 2, (blocks + 1) * block, 3840), jnp.bfloat16)
+    f32 = functools.partial(spec, dtype=jnp.float32)
+    exe = runner._decode_loop_ring.trace(
+        params, KVPool(planes, None, None, None), (state, conv),
+        spec((slots,)), spec((slots,)), spec((slots,)), spec((slots,)),
+        spec((slots, maxb)), spec((1,)), f32((1,)), spec((1,)), f32((1,)),
+        spec((1, 1)), n=steps, mode="greedy", cand=1, eos_id=-1,
+        feed="self").lower(lowering_platforms=("tpu",)).compile()
+    hlo = exe.as_text()
+    assert Counter(_mosaic_call_names(hlo)) == {
+        "short_conv_decode_step": 6, "gdn_decode_state_update": 6,
+        "closed_call": 2}
+    assert len(re.findall(
+        r"%%gdn_decode_state_update[\w\-.]* = \(f32\[%d,96,5760\]"
+        % (slots + 1), hlo)) == 6
+    assert not _conv_pool_moves(hlo, 288)
+    mem = exe.memory_analysis()
+    state_bytes = 6 * (slots + 1) * 96 * 5760 * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 6
+    made = re.findall(r"= f32\[%d,96,5760\]\S* ([\w\-]+)\(" % (slots + 1),
+                      hlo)
+    assert set(made) <= {"parameter", "get-tuple-element"}, set(made)
+
+    lowered = runner._step_greedy.trace(
+        params, KVPool(planes, None, state, conv),
+        RaggedBatch(spec((4, 512)), spec((4,)), spec((4,)), spec((4, maxb)),
+                    spec((4,)))).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert len(re.findall(r"func\.func private @gdn_chunk_prefill\b",
+                          text)) == 1
+    assert "triangular_solve" not in text
+    exe = lowered.compile()
+    names = Counter(_mosaic_call_names(exe.as_text()))
+    assert names["gdn_chunk_prefill"] == 6 and len(names) == 2, names
+    assert sum(names.values()) == 8          # and the two attention layers
+    # the pool is written in place: no temporary near the pool's size
+    assert exe.memory_analysis().temp_size_in_bytes < 1 << 30

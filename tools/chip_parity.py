@@ -8,6 +8,7 @@ catches.
     chiprun -- python tools/chip_parity.py --config mellum2-12b-a2.5b
     chiprun -- python tools/chip_parity.py --config minicpm-sala-9b [--prompt-blocks 128]
     chiprun -- python tools/chip_parity.py --config lfm2-24b-a2b
+    chiprun -- python tools/chip_parity.py --config olmo-hybrid-7b
     python tools/chip_parity.py --config solar-open2-250b --rehearse   # CPU, toy
 
 Outside any timed window. The model type's hooks come from
@@ -106,7 +107,16 @@ LOGIT_TOLS = {"kimi_linear": (0.15, 2.0),
               # 128 positions, the float8 reference 3.05 in the median
               # (my chip runs, PR 59); over a FLAT draw the same engine
               # reads the first two families' figures (PERF.md section 6)
-              "lfm2_moe": (1.5, 4.0)}
+              "lfm2_moe": (1.5, 4.0),
+              # a dense model, so no expert is ever swapped; but the
+              # attention draw is sharp and every branch reads an
+              # UNNORMED stream (the family norms a branch's output, not
+              # its input), eight layers deep in bfloat16: the engine
+              # reads 0.092 in the median and 0.132 at the worst of 128
+              # positions, the float8 reference 1.83 in the median and
+              # the mildest wrong model (beta without its 2) 2.02 (my chip
+              # run, PR 65); each limit lies between its two readings
+              "olmo_hybrid": (0.4, 0.8)}
 POSITIONS = 32
 SINGLE_BEFORE, FUSED = 22, 8
 #: by model type, where the default walk does not reach what the family
@@ -118,7 +128,11 @@ WALKS = {"mellum": {"prompt_blocks": 8, "fused": 128, "loops": 5},
          "minicpm_sala": {"fused": 256, "loops": 2},
          # two of the cell's 128-step loops: the rows after them read
          # carried convolution inputs that passed two flushes
-         "lfm2_moe": {"fused": 128, "loops": 2}}
+         "lfm2_moe": {"fused": 128, "loops": 2},
+         # one of the cell's 256-step loops: the rows after it read the
+         # K/V rows its flush wrote (64-row windows of 3,840 lanes) and
+         # the state 256 in-place updates left
+         "olmo_hybrid": {"fused": 256, "loops": 1}}
 
 #: the reference's own keyword for each wrong model, by model type
 VARIANTS = {
@@ -163,6 +177,11 @@ VARIANTS = {
         "selection_by_the_unbiased_score": {"select_biased": False},
         "renormalisation_left_out": {"renorm": False},
         "rotary_paired_as_at_128_lanes": {"rope_pair_dim": 128}},
+    "olmo_hybrid": {
+        "beta_without_its_2": {"beta_scale": 1.0},
+        "a_decay_a_channel_drawn_independently": {"channel_decay": True},
+        "norms_moved_to_the_branch_inputs": {"norm_at": "input"},
+        "rotary_switched_on": {"rope_theta": 10000.0}},
     "minicpm_sala": {
         "dense_attention_in_place_of_the_selection": {"selection": "dense"},
         "topk_32": {"topk": 32},
