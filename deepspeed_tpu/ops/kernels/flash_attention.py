@@ -58,6 +58,24 @@ block that row meets, so an edge update guards the running maximum against
 under the whole-block mask. ``window=None`` traces the kernels it always
 traced; a windowed call's kernels are named ``attn_w<window>``.
 
+A windowed call's grid is as wide as its window. The innermost grid
+dimension of forward and dq is not the ``nk`` key blocks but the most key
+blocks any query block sees (``_walk_steps``: a static number from the
+blocks, the window and the offset, ``window / block + 1`` for aligned
+square blocks: 3 of 8 at T = 8192, 1024-blocks and a window of 2048), and
+a row's steps are the run of blocks that ENDS at its diagonal block
+(``_key_step``), so ``_init`` / ``_finish`` stay on the first / last step.
+dk/dv walks ``group x`` the most query blocks any key block is seen from,
+counted from the first that sees it (``_query_step``). The one idle kind
+of step left is one that falls outside a run shorter than the grid is wide
+(the first rows of a sequence, the last key columns, a ``causal_offset``
+that starts a row inside the window): it runs no body and holds a block
+the row does need. The bodies take the step's block index where they took
+the grid's. ``causal_plan`` counts the grid steps the three kernels take
+a q head (``steps``: 72 at that shape where the square's walk took 192)
+and those that run a body (``steps_run``: 63 either way). Without a window
+the walk is the whole square's, the steps above the diagonal idle.
+
 So a large tile no longer pays for the masked half of its diagonal blocks:
 at T = 2048 and 1024-blocks the forward computes 0.625 of the square and dq
 and dk/dv 0.5625, where a whole-block skip alone computes 0.75 (the mask
@@ -265,36 +283,99 @@ def _update_by_kind(qi, ki, update, *, kernel, present, lse=None, **geom):
                        pl.ds(*rows), pl.ds(*cols), guard=True)
 
 
-def _last_key_block(qi, ki, nk, *, causal, block_q, block_k, causal_offset,
-                    window=None, **_):
-    """The key block to hold at grid step ``(qi, ki)``: ``ki`` itself
-    while the query block sees it, else the last one it does see (under a
-    window: or the first). A step above the diagonal or left of the window
-    computes nothing, and a block index that repeats the step before costs
-    no DMA (nor the re-fetch of block 0 when the next query block
-    starts)."""
-    if not causal:
-        return ki
+def _clamp(x, lo: int, hi: int):
+    """``min(max(x, lo), hi)`` of a plain int (the static reckoning of a
+    grid's width) or of a traced block index (an index map, a kernel)."""
+    if isinstance(x, int):
+        return min(max(x, lo), hi)
+    return jnp.clip(x, lo, hi)
+
+
+def _key_run(qi, nk, *, block_q, block_k, causal_offset, window, **_):
+    """(first, last) key block query block ``qi`` sees under a causal
+    mask (``window`` None: first is 0), both inside ``0 .. nk - 1``."""
     last = (qi * block_q + (block_q - 1) + causal_offset) // block_k
     if window is None:
-        return jnp.minimum(ki, jnp.clip(last, 0, nk - 1))
+        return 0, _clamp(last, 0, nk - 1)
     first = (qi * block_q + causal_offset - window + 1) // block_k
-    return jnp.clip(ki, jnp.clip(first, 0, nk - 1), jnp.clip(last, 0, nk - 1))
+    return _clamp(first, 0, nk - 1), _clamp(last, 0, nk - 1)
 
 
-def _first_query_block(qi, ki, nq, *, causal, block_q, block_k, causal_offset,
-                       window=None, **_):
-    """The dk/dv walk's counterpart: the query block to hold at step
-    ``(ki, qi)`` is ``qi`` once it sees the key block, else the first one
-    that does (under a window: or the last)."""
-    if not causal:
-        return qi
+def _query_run(ki, nq, *, block_q, block_k, causal_offset, window, **_):
+    """The dk/dv walk's counterpart: (first, last) query block that sees
+    key block ``ki`` (``window`` None: last is ``nq - 1``)."""
     first = (ki * block_k - causal_offset) // block_q
     if window is None:
-        return jnp.maximum(qi, jnp.clip(first, 0, nq - 1))
+        return _clamp(first, 0, nq - 1), nq - 1
     last = (ki * block_k + (block_k - 1) - causal_offset + window - 1) \
         // block_q
-    return jnp.clip(qi, jnp.clip(first, 0, nq - 1), jnp.clip(last, 0, nq - 1))
+    return _clamp(first, 0, nq - 1), _clamp(last, 0, nq - 1)
+
+
+def _walk_steps(nq: int, nk: int, **geom):
+    """Innermost grid steps of (forward and dq, dk/dv a q head). Without a
+    window a row walks every key block (a column every query block). Under
+    one the grid is as wide as the window: the most key blocks any query
+    block sees (the most query blocks any key block is seen from), exactly,
+    in plain ints over the ``nq`` rows (``nk`` columns): ``window / block +
+    1`` for aligned square blocks, 3 of 8 at the Trinity cell's shape."""
+    if geom["window"] is None:      # a window is under a causal mask
+        return nk, nq
+    runs = [_key_run(qi, nk, **geom) for qi in range(nq)]
+    seen = [_query_run(ki, nq, **geom) for ki in range(nk)]
+    return (max(last - first + 1 for first, last in runs),
+            max(last - first + 1 for first, last in seen))
+
+
+def _key_step(qi, j, nk, steps, **geom):
+    """(key block, whether the step may run a body) of forward / dq grid
+    step ``(qi, j)``. Without a window the step IS the block. Under one the
+    row's ``steps`` steps are the run of blocks that ENDS at the last block
+    it sees, so its last step is always its diagonal block; a step that
+    falls before the first block the row sees (the first rows of a
+    sequence, a ``causal_offset`` that starts a row inside the window) is
+    the one idle kind left."""
+    if geom["window"] is None:      # a window is under a causal mask
+        return j, None
+    first, last = _key_run(qi, nk, **geom)
+    ki = last - (steps - 1) + j
+    return ki, ki >= first
+
+
+def _query_step(ki, s, nq, **geom):
+    """The dk/dv walk's counterpart: key block ``ki``'s step ``s`` counts
+    query blocks from the first that sees it; one past the last idles."""
+    if geom["window"] is None:      # a window is under a causal mask
+        return s, None
+    first, last = _query_run(ki, nq, **geom)
+    return first + s, first + s <= last
+
+
+def _last_key_block(qi, j, nk, steps, **geom):
+    """The key block to hold at grid step ``(qi, j)``: the step's own
+    while the query block sees it, else the nearest one it does see (the
+    last for a step above the diagonal, the first for one before the
+    window). Such a step computes nothing, and a block index that repeats
+    the step before costs no DMA (nor the re-fetch of block 0 when the
+    next query block starts)."""
+    if not geom["causal"]:
+        return j
+    first, last = _key_run(qi, nk, **geom)
+    if geom["window"] is None:
+        return jnp.minimum(j, last)
+    return jnp.maximum(_key_step(qi, j, nk, steps, **geom)[0], first)
+
+
+def _first_query_block(s, ki, nq, **geom):
+    """The dk/dv walk's counterpart: the query block to hold at key block
+    ``ki``'s step ``s`` is the step's own while it sees the key block,
+    else the first one that does (under a window: the last)."""
+    if not geom["causal"]:
+        return s
+    first, last = _query_run(ki, nq, **geom)
+    if geom["window"] is None:
+        return jnp.maximum(s, first)
+    return jnp.minimum(first + s, last)
 
 
 def causal_plan(tq: int, tk: int, block_q: int, block_k: int, kv_len: int,
@@ -307,15 +388,21 @@ def causal_plan(tq: int, tk: int, block_q: int, block_k: int, kv_len: int,
     three (``0 <= row + causal_offset - col < window``, ``col < kv_len``:
     a row's ``min(i + 1, window)`` when the lengths are equal).
     ``score_area_share`` is computed / needed: 1.0 would be kernels that
-    compute no masked score."""
+    compute no masked score. ``steps`` are the grid steps the three take
+    together for one q head (``_walk_steps``; dk/dv's a q head of its KV
+    head's group) and ``steps_run`` those that run a body (a block of a
+    kind, in each kernel); ``skipped`` stays the blocks of the SQUARE not
+    computed, whether a step idles over them or the grid leaves them out."""
     nq, nk = tq // block_q, tk // block_k
-    interior, diagonal, general, edge = _grid_kinds(
-        nq, nk, causal=True, block_q=block_q, block_k=block_k, kv_len=kv_len,
-        causal_offset=causal_offset, window=window)
+    geom = dict(causal=True, block_q=block_q, block_k=block_k, kv_len=kv_len,
+                causal_offset=causal_offset, window=window)
+    interior, diagonal, general, edge = _grid_kinds(nq, nk, **geom)
+    run = interior + diagonal + general + edge
+    k_steps, q_steps = _walk_steps(nq, nk, **geom)
     plan = {"interior": interior, "sub_tiled": diagonal, "general": general,
-            "edge": edge,
-            "skipped": nq * nk - interior - diagonal - general - edge,
-            "sub": {}}
+            "edge": edge, "skipped": nq * nk - run,
+            "steps": 2 * nq * k_steps + nk * q_steps,
+            "steps_run": len(_MAX_SUB_TILES) * run, "sub": {}}
     computed = 0
     for kernel in _MAX_SUB_TILES:
         n = _sub_tiles(block_q, kernel)
@@ -353,13 +440,19 @@ def take_causal_plans() -> list:
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale, present, **geom):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+def _when(cond, body):
+    """``body()`` under ``pl.when(cond)``; ``cond`` None: always."""
+    body() if cond is None else pl.when(cond)(body)
 
-    @pl.when(ki == 0)
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                *, sm_scale, present, nk, **geom):
+    qi = pl.program_id(2)
+    j = pl.program_id(3)
+    steps = pl.num_programs(3)
+    ki, seen = _key_step(qi, j, nk, steps, **geom)
+
+    @pl.when(j == 0)
     def _init():
         m_scr[:] = jnp.full(m_scr.shape, _NEG_INF, m_scr.dtype)
         l_scr[:] = jnp.zeros(l_scr.shape, l_scr.dtype)
@@ -369,9 +462,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         _online_softmax_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr,
                               mask, sm_scale, rows, cols, guard)
 
-    _update_by_kind(qi, ki, update, kernel="fwd", present=present, **geom)
+    _when(seen, lambda: _update_by_kind(qi, ki, update, kernel="fwd",
+                                        present=present, **geom))
 
-    @pl.when(ki == nk - 1)
+    @pl.when(j == steps - 1)
     def _finish():
         l = l_scr[:, :1]
         # fully-masked padded rows have l == 0; emit zeros, lse = -inf
@@ -595,8 +689,10 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
     geom = dict(causal=causal, block_q=block_q, block_k=block_k,
                 kv_len=kv_len, causal_offset=causal_offset, window=window)
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
-                               present=_grid_kinds(nq, nk, **geom), **geom)
-    grid = (b, h, nq, nk)
+                               present=_grid_kinds(nq, nk, **geom), nk=nk,
+                               **geom)
+    steps, _ = _walk_steps(nq, nk, **geom)
+    grid = (b, h, nq, steps)
     out_shape = [
         jax.ShapeDtypeStruct((b, h, tq, d), q.dtype),
         jax.ShapeDtypeStruct((b, h, tq, 1), jnp.float32),
@@ -605,8 +701,8 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
     # head across its q-head group — no materialized repeat
     kv_spec = pl.BlockSpec(
         (1, 1, block_k, d),
-        lambda b, h, i, j: (b, h // group, _last_key_block(i, j, nk, **geom),
-                            0))
+        lambda b, h, i, j: (b, h // group,
+                            _last_key_block(i, j, nk, steps, **geom), 0))
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -661,12 +757,13 @@ def _bwd_tile(q, k, v, do, lse, delta, mask, sm_scale, transposed):
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, sm_scale, present, **geom):
+                   dq_scr, *, sm_scale, present, nk, **geom):
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    j = pl.program_id(3)
+    steps = pl.num_programs(3)
+    ki, seen = _key_step(qi, j, nk, steps, **geom)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         dq_scr[:] = jnp.zeros(dq_scr.shape, dq_scr.dtype)
 
@@ -680,26 +777,27 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _update_by_kind(qi, ki, update, kernel="dq", present=present,
-                    lse=lambda: lse_ref[0, 0], **geom)
+    _when(seen, lambda: _update_by_kind(
+        qi, ki, update, kernel="dq", present=present,
+        lse=lambda: lse_ref[0, 0], **geom))
 
-    @pl.when(ki == nk - 1)
+    @pl.when(j == steps - 1)
     def _finish():
         dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, sm_scale, present, nq, **geom):
+                    *, sm_scale, present, nq, steps, **geom):
     # GQA grouped accumulation: the grid's innermost dim fuses (q-head in
-    # group, q block) as gq = qh * nq + qi, so ONE kv head's dk/dv
-    # accumulates over every q head it serves before the block is written
-    # — K/V never get materialized per q-head and the cotangent comes out
-    # already (b, hk, t, d)
+    # group, step of the key block's walk over query blocks) as gq = qh *
+    # steps + s, so ONE kv head's dk/dv accumulates over every q head it
+    # serves before the block is written — K/V never get materialized per
+    # q-head and the cotangent comes out already (b, hk, t, d)
     ki = pl.program_id(2)
     gq = pl.program_id(3)
     ng = pl.num_programs(3)
-    qi = gq % nq
+    qi, seen = _query_step(ki, gq % steps, nq, **geom)
 
     @pl.when(gq == 0)
     def _init():
@@ -722,8 +820,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[cols, :] += jax.lax.dot_general(
             ds.astype(q.dtype), q, nn, preferred_element_type=jnp.float32)
 
-    _update_by_kind(qi, ki, update, kernel="dkv", present=present,
-                    lse=lambda: lse_ref[0, 0], **geom)
+    _when(seen, lambda: _update_by_kind(
+        qi, ki, update, kernel="dkv", present=present,
+        lse=lambda: lse_ref[0, 0], **geom))
 
     @pl.when(gq == ng - 1)
     def _finish():
@@ -744,6 +843,7 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
     geom = dict(causal=causal, block_q=block_q, block_k=block_k,
                 kv_len=kv_len, causal_offset=causal_offset, window=window)
     present = _grid_kinds(nq, nk, **geom)
+    k_steps, q_steps = _walk_steps(nq, nk, **geom)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)                    # (b, h, tq, 1)
@@ -756,12 +856,12 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
 
     kv_spec = pl.BlockSpec(
         (1, 1, block_k, d),
-        lambda b, h, i, j: (b, h // group, _last_key_block(i, j, nk, **geom),
-                            0))
+        lambda b, h, i, j: (b, h // group,
+                            _last_key_block(i, j, nk, k_steps, **geom), 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, present=present,
-                          **geom),
-        grid=(b, h, nq, nk),
+                          nk=nk, **geom),
+        grid=(b, h, nq, k_steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
             kv_spec, kv_spec,
@@ -781,26 +881,29 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid walks KV heads (hk = h // group); the innermost dim fuses
-    # (q-head in group, q block) so each kv head's cotangent sums its whole
-    # q-head group in-scratch — the index maps pick the q-side head as
-    # hh * group + gq // nq and the q block as gq % nq.
+    # (q-head in group, step over the q blocks) so each kv head's cotangent
+    # sums its whole q-head group in-scratch — the index maps pick the
+    # q-side head as hh * group + gq // q_steps and the q block from the
+    # step gq % q_steps.
     # lse and delta go in as lane-dense (b, h, 1, tq) rows: the kernel
     # holds its tiles [cols, rows].
     def q_block(i, gq):
-        return _first_query_block(gq % nq, i, nq, **geom)
+        return _first_query_block(gq % q_steps, i, nq, **geom)
 
     q_spec = pl.BlockSpec(
         (1, 1, block_q, d),
-        lambda b, hh, i, gq: (b, hh * group + gq // nq, q_block(i, gq), 0))
+        lambda b, hh, i, gq: (b, hh * group + gq // q_steps, q_block(i, gq),
+                              0))
     kv_spec = pl.BlockSpec((1, 1, block_k, d),
                            lambda b, hh, i, gq: (b, hh, i, 0))
     row_spec = pl.BlockSpec(
         (1, 1, 1, block_q),
-        lambda b, hh, i, gq: (b, hh * group + gq // nq, 0, q_block(i, gq)))
+        lambda b, hh, i, gq: (b, hh * group + gq // q_steps, 0,
+                              q_block(i, gq)))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, present=present,
-                          nq=nq, **geom),
-        grid=(b, hk, nk, group * nq),
+                          nq=nq, steps=q_steps, **geom),
+        grid=(b, hk, nk, group * q_steps),
         in_specs=[
             q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
         ],

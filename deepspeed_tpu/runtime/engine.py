@@ -269,12 +269,15 @@ class Engine:
                             "commit_apply_s": 0.0, "step_exit_s": 0.0,
                             "flash_score_elems_computed": 0,
                             "flash_score_elems_needed": 0,
+                            "flash_grid_steps": 0, "flash_grid_steps_run": 0,
                             "zero_manual_leaves": 0, "zero_held_leaves": 0,
                             "zero_auto_leaves": 0}
-        #: what ONE step's causal flash calls compute / need, from the
-        #: plans noted while the step function was traced
+        #: what ONE step's causal flash calls compute / need and the grid
+        #: steps they take / run a body in, from the plans noted while the
+        #: step function was traced
         self._flash_elems = {"flash_score_elems_computed": 0,
-                             "flash_score_elems_needed": 0}
+                             "flash_score_elems_needed": 0,
+                             "flash_grid_steps": 0, "flash_grid_steps_run": 0}
         #: what ONE step adds of these: sharded leaves the explicit seam
         #: gathers inside their layer, those it gathers in front of the
         #: model and holds through the step, and those left to the
@@ -997,6 +1000,7 @@ class Engine:
     def step_stats(self) -> Dict[str, Any]:
         """``train_batch``'s own totals: ``steps``, the seconds of each
         bracket (``telemetry/trace.py``), ``flash_score_elems_*``,
+        ``flash_grid_steps`` / ``_run``,
         ``zero_*_leaves`` and, for a loss whose aux has ``counters``, each
         of them summed over the steps so far (a sparse model's
         ``moe_rows_routed`` / ``_elsewhere`` / ``_hottest``, as
@@ -1030,14 +1034,21 @@ class Engine:
         needs, summed over the calls noted while this step's program was
         traced (``flash_attention.causal_plan``; a call in a scanned body
         is noted once, under ``shard_map`` with its shard's batch and
-        heads). Their ratio is the kernels' ``score_area_share``; 0 and 0
-        for a model without the kernel."""
+        heads). Their ratio is the kernels' ``score_area_share``. Beside
+        them ``flash_grid_steps`` / ``_run``: the grid steps a (batch, q
+        head) those kernels take, and the ones that run a body (the rest
+        idle above the diagonal; a windowed call's grid is as wide as its
+        window and idles only in front of a row's first block). All 0 for
+        a model without the kernel."""
         plans = _take_flash_plans()
         if plans:       # this dispatch traced the step function
             self._flash_elems = {
-                "flash_score_elems_" + key: sum(
-                    b * h * plan["score_elems_" + key] for b, h, plan in plans)
-                for key in ("computed", "needed")}
+                name: sum(b * h * plan[key] for b, h, plan in plans)
+                for name, key in (
+                    ("flash_score_elems_computed", "score_elems_computed"),
+                    ("flash_score_elems_needed", "score_elems_needed"),
+                    ("flash_grid_steps", "steps"),
+                    ("flash_grid_steps_run", "steps_run"))}
         return self._flash_elems
 
     def train_batch(self, batch: Any) -> jnp.ndarray:

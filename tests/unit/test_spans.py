@@ -456,7 +456,8 @@ class TestTrainBrackets:
             eng.train_batch(b)
         st = eng.step_stats
         assert st["steps"] == 3
-        flash = {"flash_score_elems_computed", "flash_score_elems_needed"}
+        flash = {"flash_score_elems_computed", "flash_score_elems_needed",
+                 "flash_grid_steps", "flash_grid_steps_run"}
         # sharded leaves by who writes their collectives: none at stage 0
         zero = {"zero_manual_leaves", "zero_held_leaves", "zero_auto_leaves"}
         assert set(st) == {"steps", "train_batch_s", "stage_s",
@@ -497,6 +498,13 @@ class TestTrainBrackets:
         assert computed * plan["score_elems_needed"] \
             == needed * plan["score_elems_computed"]
         assert seen == [(computed * n, needed * n) for n in (1, 2, 3)]
+        # one block a (batch, head): each of the three kernels takes one
+        # grid step for it and runs it, in each of the three train steps
+        assert (plan["steps"], plan["steps_run"]) == (3, 3)
+        calls = computed // plan["score_elems_computed"]
+        st = eng.step_stats
+        assert st["flash_grid_steps"] == st["flash_grid_steps_run"] \
+            == 3 * calls * 3
 
     def test_step_stats_fill_with_the_observer_off(self, monkeypatch):
         monkeypatch.setenv("DSTPU_TRAIN_OBS", "0")

@@ -12,8 +12,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from tpu_compile_common import (
-    _mosaic_call_names, described_chips_programs_stay_out_of_the_cache,
-    one_chip)
+    _mosaic_call_names, _mosaic_grids,
+    described_chips_programs_stay_out_of_the_cache, one_chip)
 
 
 def _collectives(hlo):
@@ -236,7 +236,8 @@ def test_the_trinity_cells_train_step_compiles_under_the_chips_memory(
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert 4e9 < total < 15.75e9, total
-    names = _mosaic_call_names(exe.as_text())
+    text = exe.as_text()
+    names = _mosaic_call_names(text)
     window = f"attn_w{full.sliding_window}"
     assert names.count(window) == 4 * 4 and names.count("attn") == 4 * 1
     assert sum(n.startswith("ragged-dot") for n in names) >= 4 * 9
@@ -250,6 +251,17 @@ def test_the_trinity_cells_train_step_compiles_under_the_chips_memory(
     assert {(b, h) for b, h, _ in plans} == {(B, 32)}
     assert sorted((plan["edge"], plan["skipped"]) for _, _, plan in plans) \
         == [(0, 28)] + [(6, 43)] * 4
+    # ISSUE 66: a sliding layer's three kernels walk 8 x 3 grid steps a q
+    # head where each walked 8 x 8 (21 of them run a body either way); the
+    # full layer's keep the square. And Mosaic took those grids: forward,
+    # its recompute and dq at 3 key blocks a query block, dk/dv at 3 query
+    # blocks a q head of a KV head's 8
+    assert sorted((plan["steps"], plan["steps_run"])
+                  for _, _, plan in plans) == [(72, 63)] * 4 + [(192, 108)]
+    assert sorted(_mosaic_grids(text, window)) \
+        == [(B, 4, 8, 8 * 3)] * 4 + [(B, 32, 8, 3)] * 4 * 3
+    assert sorted(_mosaic_grids(text, "attn")) \
+        == [(B, 4, 8, 8 * 8)] + [(B, 32, 8, 8)] * 3
 
 
 @pytest.mark.parametrize("S,n,M,rows,dtype", [

@@ -45,6 +45,30 @@ def _mosaic_call_names(hlo):
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)]
 
 
+def _mosaic_grids(hlo, name):
+    """The grid (Mosaic's ``iteration_bounds``) of every Mosaic call
+    ``name`` in the compiled text, read out of the call's own module: the
+    bytecode its ``backend_config`` carries."""
+    import base64
+    import json
+
+    from jax._src.lib.mlir import ir
+    grids = []
+    for line in hlo.splitlines():
+        if not re.match(r"\s*(ROOT )?%%%s(\.\d+)? = [^\n]*\"tpu_custom_call\""
+                        % re.escape(name), line):
+            continue
+        config = json.loads(re.search(r"backend_config=(\{.*\})",
+                                      line).group(1))
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            asm = ir.Module.parse(base64.b64decode(
+                config["custom_call_config"]["body"])).operation.get_asm()
+        bounds, = re.findall(r"iteration_bounds = array<i64: ([^>]*)>", asm)
+        grids.append(tuple(int(n) for n in bounds.split(",")))
+    return grids
+
+
 def _scoped_vmem(hlo, name):
     """(asked, used) bytes of scoped VMEM of every Mosaic call ``name`` in
     the compiled text: the call's ``vmem_limit_bytes`` and what Mosaic
