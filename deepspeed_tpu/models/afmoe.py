@@ -9,7 +9,9 @@ with the flash kernels, remat a layer and the chunked cross-entropy.
   head; RMSNorm everywhere, no bias anywhere.
 * attention, every layer: GQA, one RMSNorm over each head's own lanes of q
   and of k; rotate-half RoPE on ``"swa"`` (``sliding_attention``) layers
-  ALONE, no position code on ``"attn"`` (``full_attention``) layers; causal
+  ALONE, no position code on ``"attn"`` (``full_attention``) layers (norm
+  and code are ONE operation with its own backward,
+  ``ops/kernels/qk_norm_rope.py``: float32 inside, one rounding); causal
   softmax at ``head_dim ** -0.5``, on a ``"swa"`` layer over keys ``0 <= i
   - j < sliding_window``; ``y = W_o (attn * sigmoid(W_g z))``. The call is
   the flash kernel with the layer's window
@@ -57,8 +59,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.kernels import qk_norm_rope as _prep
 from ..telemetry.trace import region
-from .llama import RMSNorm, apply_rope
+from .llama import RMSNorm
 
 #: ``layer_types`` -> this module's layer kind
 LAYER_TYPES = {"sliding_attention": "swa", "full_attention": "attn"}
@@ -158,6 +161,21 @@ def _dense(cfg, feats: int, name: str):
                     param_dtype=cfg.param_dtype, name=name)
 
 
+class HeadScale(nn.Module):
+    """A per-head RMSNorm's one leaf, ``scale`` float32 [head_dim], under
+    the name the norm's module had."""
+
+    @nn.compact
+    def __call__(self, head_dim: int):
+        return self.param("scale", nn.initializers.ones, (head_dim,),
+                          jnp.float32)
+
+
+def _prep_interpreted(cfg: AfmoeConfig) -> bool:
+    """``"flash_interpret"`` interprets ``qk_norm_rope``'s kernel too."""
+    return cfg.attention_impl == "flash_interpret"
+
+
 class AfmoeAttention(nn.Module):
     cfg: AfmoeConfig
     kind: str
@@ -168,16 +186,19 @@ class AfmoeAttention(nn.Module):
         B, T, M = x.shape
         H, KV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         window = cfg.sliding_window if self.kind == "swa" else None
-        q = _dense(cfg, H * D, "q_proj")(x).reshape(B, T, H, D)
-        k = _dense(cfg, KV * D, "k_proj")(x).reshape(B, T, KV, D)
+        q = _dense(cfg, H * D, "q_proj")(x)
+        k = _dense(cfg, KV * D, "k_proj")(x)
         v = _dense(cfg, KV * D, "v_proj")(x).reshape(B, T, KV, D)
         gate = _dense(cfg, H * D, "gate_proj")(x)
-        q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
-        k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
-        if self.kind == "swa":         # a full layer carries no position
-            pos = jnp.arange(T)[None, :]
-            q = apply_rope(q, pos, cfg.rope_theta)
-            k = apply_rope(k, pos, cfg.rope_theta)
+        # head-major from here to the flash call's output: [B, H, T, D]
+        q, k = _prep.qk_norm_rope(
+            q, k, HeadScale(name="q_norm")(D), HeadScale(name="k_norm")(D),
+            cfg.rms_eps,
+            # a full layer carries no position
+            _prep.rotary_table(T, D, cfg.rope_theta)
+            if self.kind == "swa" else None,
+            interpret=_prep_interpreted(cfg))
+        v = jnp.swapaxes(v, 1, 2)
 
         impl = cfg.attention_impl
         if impl == "auto":
@@ -186,18 +207,20 @@ class AfmoeAttention(nn.Module):
         with region("attn_window" if window is not None else "attn_core"):
             if impl in ("flash", "flash_interpret"):
                 from ..ops.kernels import flash_attention
-                y = flash_attention(
-                    q, k, v, causal=True, window=window, layout="BTHD",
+                y = jnp.swapaxes(flash_attention(
+                    q, k, v, causal=True, window=window, layout="BHTD",
                     block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-                    interpret=True if impl == "flash_interpret" else None)
+                    interpret=True if impl == "flash_interpret" else None),
+                    1, 2)
             elif impl == "xla":
                 i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
                 mask = j <= i
                 if window is not None:
                     mask &= j > i - window
                 y = jax.nn.dot_product_attention(
-                    q, jnp.repeat(k, H // KV, axis=2),
-                    jnp.repeat(v, H // KV, axis=2), mask=mask[None, None])
+                    *(jnp.swapaxes(jnp.repeat(a, H // a.shape[1], axis=1),
+                                   1, 2) for a in (q, k, v)),
+                    mask=mask[None, None])
             else:
                 raise ValueError(
                     f"attention_impl must be 'auto', 'flash', "
@@ -338,7 +361,7 @@ def bias_step(counts: jnp.ndarray, coeff: float) -> jnp.ndarray:
     return coeff * jnp.sign(c.mean() - c)
 
 
-def step_counters(cfg: AfmoeConfig, counts, tokens: int) -> dict:
+def step_counters(cfg: AfmoeConfig, counts, tokens: int, seq: int) -> dict:
     """``moe_rows_*`` of one step of ``tokens`` tokens from its sparse
     layers' per-expert rows, as ``pipeline_stats`` counts them in serving:
     rows routed to experts held here, rows routed elsewhere, and the
@@ -347,7 +370,9 @@ def step_counters(cfg: AfmoeConfig, counts, tokens: int) -> dict:
     the sorted order each layer visited (``held_row_bound``, or every
     routed row where the held rows exceeded it), the layers that took
     every row, and the layers whose rows rejoined their tokens through the
-    combine kernel (``combine_impl``: 0 wherever ``.at[].add`` ran)."""
+    combine kernel (``combine_impl``: 0 wherever ``.at[].add`` ran); and
+    the layers whose ``q`` and ``k`` went through ``qk_norm_rope``'s Pallas
+    call (0 wherever its twin ran) at rows of ``seq`` tokens."""
     from ..moe.sharded_moe import combine_impl, held_row_bound
     first, n = cfg.held
     rows = tokens * cfg.experts_top_k
@@ -355,6 +380,8 @@ def step_counters(cfg: AfmoeConfig, counts, tokens: int) -> dict:
                            cfg.held)
     kernel = combine_impl(tokens, n, cfg.hidden_size, (bound, rows),
                           cfg.dtype)
+    prep = _prep.impl_of(seq, cfg.num_heads, cfg.head_dim, cfg.dtype,
+                         _prep_interpreted(cfg))
     here = [c[first:first + n] for c in counts]
     routed = sum(h.sum() for h in here)
     full = sum((h.sum() > bound).astype(jnp.int32) for h in here)
@@ -364,7 +391,9 @@ def step_counters(cfg: AfmoeConfig, counts, tokens: int) -> dict:
             "moe_rows_visited": bound * len(here) + (rows - bound) * full,
             "moe_layers_full": full,
             "moe_combine_layers": jnp.int32(
-                len(here) if kernel is not None else 0)}
+                len(here) if kernel is not None else 0),
+            "attn_prep_fused_layers": jnp.int32(
+                cfg.num_layers if prep is not None else 0)}
 
 
 def make_model(cfg: AfmoeConfig):
@@ -393,7 +422,8 @@ def make_model(cfg: AfmoeConfig):
             aux = {"add": {f"layer_{i}/moe/select_bias":
                            bias_step(c, cfg.load_balance_coeff)
                            for i, c in zip(sparse, counts)},
-                   "counters": step_counters(cfg, counts, inputs.size)}
+                   "counters": step_counters(cfg, counts, inputs.size,
+                                            inputs.shape[1])}
         return loss, aux
 
     return model, init_fn, loss_fn

@@ -76,9 +76,10 @@ def reference_side(params, tokens, **dims):
 
 
 @functools.lru_cache(maxsize=None)
-def _reference_side():
-    params, tokens = _draw(CFG), _tokens(CFG)
-    return (params, tokens) + reference_side(params, tokens, **_dims(CFG))
+def _reference_side(head_dim=CFG.head_dim):
+    cfg = dataclasses.replace(CFG, attn_head_dim=head_dim)
+    params, tokens = _draw(cfg), _tokens(cfg)
+    return (params, tokens) + reference_side(params, tokens, **_dims(cfg))
 
 
 def _rel(a, b):
@@ -86,11 +87,26 @@ def _rel(a, b):
     return float(np.abs(a - b).max() / max(np.linalg.norm(b), 1e-30))
 
 
-@pytest.mark.parametrize("impl", ["xla", "flash_interpret"])
-def test_loss_nll_and_every_leafs_gradient_match_the_reference(impl):
-    params, tokens, ref_loss, ref_nll, ref_grads = _reference_side()
+@pytest.mark.parametrize("impl,head_dim", [
+    ("xla", 16), ("flash_interpret", 16), ("flash_interpret", 128)])
+def test_loss_nll_and_every_leafs_gradient_match_the_reference(impl,
+                                                               head_dim):
+    """At 128-lane heads ``"flash_interpret"`` routes ``q`` and ``k``
+    through ``qk_norm_rope``'s kernel, interpreted (ISSUE 67); at the toy
+    16 lanes, and under ``"xla"``, through its ``jax.numpy`` twin. The
+    norms' scales stay leaves of the tree under the names they had."""
+    from deepspeed_tpu.ops.kernels import qk_norm_rope
+    params, tokens, ref_loss, ref_nll, ref_grads = _reference_side(head_dim)
     cfg = dataclasses.replace(CFG, attention_impl=impl, flash_block_q=32,
-                              flash_block_k=32, xent_chunks=2)
+                              flash_block_k=32, xent_chunks=2,
+                              attn_head_dim=head_dim)
+    assert qk_norm_rope.impl_of(
+        T, cfg.num_heads, head_dim, cfg.dtype, impl == "flash_interpret") \
+        == ("interpret" if head_dim == 128 else None)
+    for name in ("q_norm", "k_norm"):
+        scale, = params["layer_0"]["attn"][name].values()
+        assert list(params["layer_0"]["attn"][name]) == ["scale"]
+        assert (scale.shape, scale.dtype) == ((head_dim,), jnp.float32)
     model, _, loss_fn = make_model(cfg)
     (loss, aux), grads = jax.jit(jax.value_and_grad(
         lambda p: loss_fn(p, {"tokens": tokens}, None), has_aux=True))(params)
@@ -371,7 +387,7 @@ def test_a_share_cut_to_its_bound_is_the_share_over_every_row(routing):
         assert _rel(a, b) < 1e-6
     cfg = AfmoeConfig.tiny(num_experts=E, experts_top_k=k, experts_held=2,
                            experts_first=4)
-    c = step_counters(cfg, [counts], S)
+    c = step_counters(cfg, [counts], S, S)
     assert int(c["moe_rows_routed"]) == n_here
     if routing == "overflowing":
         assert n_here == S * k
@@ -420,30 +436,38 @@ def test_the_steps_counters_say_which_layers_visited_every_row():
         c[0] = tokens * k - here
         return jnp.asarray(c)
 
-    got = jax.jit(lambda cs: step_counters(cfg, cs, tokens))(
+    got = jax.jit(lambda cs: step_counters(cfg, cs, tokens, 64))(
         [layer(300), layer(bound), layer(bound + 1), layer(4096)])
     assert {key: int(v) for key, v in got.items()} == {
         "moe_rows_routed": 300 + 1024 + 1025 + 4096,
         "moe_rows_elsewhere": 4 * 4096 - (300 + 1024 + 1025 + 4096),
         "moe_rows_hottest": 2 * (200 + 683 + 684 + 2731),
         "moe_rows_visited": 2 * 1024 + 2 * 4096, "moe_layers_full": 2,
-        # off the TPU ``.at[].add`` moves the rows (ISSUE 64)
-        "moe_combine_layers": 0}
+        # off the TPU ``.at[].add`` moves the rows (ISSUE 64) and
+        # ``qk_norm_rope`` runs its twin (ISSUE 67)
+        "moe_combine_layers": 0, "attn_prep_fused_layers": 0}
     whole = AfmoeConfig.tiny(num_experts=E, experts_top_k=k)
-    got = step_counters(whole, [layer(300), layer(4096)], tokens)
+    got = step_counters(whole, [layer(300), layer(4096)], tokens, 64)
     assert (int(got["moe_rows_visited"]), int(got["moe_layers_full"])) == (
         2 * 4096, 0)
 
 
-def test_the_new_counters_reach_step_stats_and_add_up_over_steps():
+@pytest.mark.parametrize("prep", ["twin", "kernel"])
+def test_the_new_counters_reach_step_stats_and_add_up_over_steps(prep):
     """ISSUE 62's two counters through the engine: a tree whose first
     sparse layer's selection bias sends every choice to the held experts
     (its held rows exceed the bound: every row visited, in every step) and
     whose second routes as drawn (the bound's rows visited). Over three
     steps ``step_stats`` holds the host's recount, as for
-    ``moe_rows_routed``."""
+    ``moe_rows_routed``. And ISSUE 67's: every layer of a step whose ``q``
+    and ``k`` went through ``qk_norm_rope``'s Pallas call (128-lane heads,
+    interpreted here), none where its twin ran."""
     from deepspeed_tpu.moe.sharded_moe import held_row_bound
     cfg = dataclasses.replace(SMALL, experts_held=4, experts_first=4)
+    if prep == "kernel":
+        cfg = dataclasses.replace(cfg, attn_head_dim=128, flash_block_q=32,
+                                  flash_block_k=32,
+                                  attention_impl="flash_interpret")
     _, _, loss_fn = make_model(cfg)
     params = _draw(cfg)
     moe = params["layer_1"]["moe"]
@@ -469,6 +493,8 @@ def test_the_new_counters_reach_step_stats_and_add_up_over_steps():
     stats = engine.step_stats
     assert {key: stats[key] for key in recount} == recount
     assert stats["moe_combine_layers"] == 0      # the CPU: ``.at[].add``
+    assert stats["attn_prep_fused_layers"] == (
+        3 * cfg.num_layers if prep == "kernel" else 0)
     assert recount["moe_layers_full"] == 3
     assert recount["moe_rows_visited"] == 3 * (rows + bound)
 

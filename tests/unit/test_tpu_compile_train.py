@@ -247,6 +247,13 @@ def test_the_trinity_cells_train_step_compiles_under_the_chips_memory(
     # cotangents; the third forward the checkpointed branch runs inside the
     # backward keeps none (nobody reads its output)
     assert names.count("moe_combine") == 4 * 2 * 4
+    # ISSUE 67: a layer's QK-norm and rotary code are ONE call a pass
+    # (forward, the layer's recompute, backward), and nothing of float32
+    # at the size of ``q`` is left under the projections' region
+    assert names.count("qk_norm_rope") == 3 * 5
+    assert not [line for line in text.splitlines()
+                if "rg.attn_proj" in line
+                and re.search(r"= f32\[%d,8192,32,128\]" % B, line)]
     plans = take_causal_plans()             # one a layer's call
     assert {(b, h) for b, h, _ in plans} == {(B, 32)}
     assert sorted((plan["edge"], plan["skipped"]) for _, _, plan in plans) \
@@ -296,3 +303,43 @@ def test_the_combine_kernel_compiles_at_the_calls_it_takes(
     used = re.search(
         r'"used_scoped_memory_configs":\[\{[^\]]*?"size":"(\d+)"', call)
     assert 1 << 20 < int(used.group(1)) <= 16 << 20
+
+
+@pytest.mark.parametrize("rotate", [True, False], ids=["sliding", "full"])
+def test_the_qk_norm_rope_kernel_compiles_at_the_cells_calls(one_chip,
+                                                             rotate):
+    """``qk_norm_rope`` (ISSUE 67) through Mosaic at the sparse train
+    cell's two calls, a sliding layer's (with the table) and a full
+    layer's (without): forward and backward are one call each, named so,
+    rows ``[B, T, H * D]`` in and head-major ``[B, H, T, D]`` out (the
+    backward the other way), a grid step 256 rows of ``q``'s 4,096
+    bfloat16 lanes, batch innermost, under the default scoped VMEM (16
+    MB)."""
+    from deepspeed_tpu.ops.kernels import qk_norm_rope as qn
+    B, T, H, KV, D = 2, 8192, 32, 4, 128
+    assert qn.fits(T, H, D, jnp.bfloat16)
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def both(q, k, q_scale, k_scale, table, dq, dk):
+        out, vjp = jax.vjp(
+            lambda *a: qn._qk_norm_rope("pallas", 1e-5, *a, table),
+            q, k, q_scale, k_scale)
+        return out, vjp((dq, dk))
+
+    q, k = spec((B, T, H * D), jnp.bfloat16), spec((B, T, KV * D),
+                                                   jnp.bfloat16)
+    dq, dk = spec((B, H, T, D), jnp.bfloat16), spec((B, KV, T, D),
+                                                    jnp.bfloat16)
+    scale = spec((D,), jnp.float32)
+    table = (spec((T, D), jnp.float32),) * 2 if rotate else None
+    text = jax.jit(both).trace(q, k, scale, scale, table, dq, dk).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert _mosaic_call_names(text) == ["qk_norm_rope"] * 2
+    assert _mosaic_grids(text, "qk_norm_rope") == [(T // 256, B)] * 2
+    used = [int(re.search(
+        r'"used_scoped_memory_configs":\[\{[^\]]*?"size":"(\d+)"',
+        line).group(1)) for line in text.splitlines()
+        if '"tpu_custom_call"' in line]
+    assert len(used) == 2 and all(1 << 20 < n <= 16 << 20 for n in used)
