@@ -548,6 +548,21 @@ def _nemotron_h_names(state, hf_cfg):
     return out
 
 
+def _jamba_names(state, hf_cfg):
+    """jamba: a Mamba layer's convolution ``[C, 1, K]`` brought to
+    ``[K, C]`` and its ``A_log`` ``[channels, state]`` to ``[state,
+    channels]``, as the state pool lays a state out
+    (``models/jamba.py``)."""
+    out = {}
+    for name, arr in state.items():
+        if name.endswith(".mamba.conv1d.weight"):
+            arr = arr.reshape(arr.shape[0], arr.shape[-1]).T
+        elif name.endswith(".mamba.A_log"):
+            arr = arr.T
+        out[name] = arr
+    return out
+
+
 def _minicpm_sala_names(state, hf_cfg):
     """minicpm_sala: both kinds of mixer sit under ``self_attn`` in the
     checkpoint; ``mixer_types`` says which a layer's is, and a Lightning
@@ -566,6 +581,7 @@ def _minicpm_sala_names(state, hf_cfg):
 SPECIAL_HANDLERS = {
     "pangu_ultra_moe": _pangu_ultra_moe_names,
     "nemotron_h": _nemotron_h_names,
+    "jamba": _jamba_names,
     "kimi_linear": _kimi_linear_names,
     "phi3": _split_phi3_fused,
     "qwen": _split_qwen_fused,
@@ -702,6 +718,38 @@ _NEMOTRON_H_MAP = _LLAMA_MAP[:6] + [
      "layer_{0}/shared_{1}_proj/kernel", "linear"),
 ]
 
+#: assumed names (the family's modelling file, from memory: a layer's
+#: feed-forward under ``feed_forward``, its second norm ``pre_ff_layernorm``,
+#: the last norm ``final_layernorm``, the three inner norms
+#: ``{dt,b,c}_layernorm``); a Mamba layer's tensors are bare arrays in the
+#: tree (``models/jamba.py::Mamba1Mixer``'s names), brought to its shapes by
+#: :func:`_jamba_names`
+_JAMBA_MAP = [
+    (r"model\.embed_tokens\.weight", "embed/embedding", "embed"),
+    (r"model\.final_layernorm\.weight", "final_norm/scale", "vector"),
+    (r"lm_head\.weight", "lm_head/kernel", "linear"),
+    (r"model\.layers\.(\d+)\.input_layernorm\.weight",
+     "layer_{0}/input_norm/scale", "vector"),
+    (r"model\.layers\.(\d+)\.pre_ff_layernorm\.weight",
+     "layer_{0}/post_attn_norm/scale", "vector"),
+    (r"model\.layers\.(\d+)\.self_attn\.(q|k|v|o)_proj\.weight",
+     "layer_{0}/attn/{1}_proj/kernel", "linear"),
+    (r"model\.layers\.(\d+)\.feed_forward\.(gate|up|down)_proj\.weight",
+     "layer_{0}/mlp/{1}_proj/kernel", "linear"),
+    (r"model\.layers\.(\d+)\.mamba\.(in|x|dt|out)_proj\.weight",
+     "layer_{0}/mamba/{1}_proj", "linear"),
+    (r"model\.layers\.(\d+)\.mamba\.dt_proj\.bias",
+     "layer_{0}/mamba/dt_bias", "vector"),
+    (r"model\.layers\.(\d+)\.mamba\.conv1d\.weight",
+     "layer_{0}/mamba/conv_w", "vector"),
+    (r"model\.layers\.(\d+)\.mamba\.conv1d\.bias",
+     "layer_{0}/mamba/conv_b", "vector"),
+    (r"model\.layers\.(\d+)\.mamba\.(A_log|D)",
+     "layer_{0}/mamba/{1}", "vector"),
+    (r"model\.layers\.(\d+)\.mamba\.(dt|b|c)_layernorm\.weight",
+     "layer_{0}/mamba/{1}_norm", "vector"),
+]
+
 #: assumed names (the family's MiniCPM4 code for the sparse layers, with
 #: ``o_gate`` for its output gate; ``z_proj`` and ``o_norm`` for a Lightning
 #: layer's gate and output norm): the catalog row has the config's keys, not
@@ -722,6 +770,7 @@ _MINICPM_SALA_MAP = _LLAMA_MAP + [
 ARCH_MAPS["minicpm_sala"] = _MINICPM_SALA_MAP
 ARCH_MAPS["pangu_ultra_moe"] = _PANGU_ULTRA_MOE_MAP
 ARCH_MAPS["nemotron_h"] = _NEMOTRON_H_MAP
+ARCH_MAPS["jamba"] = _JAMBA_MAP
 ARCH_MAPS["kimi_linear"] = _KIMI_LINEAR_MAP
 ARCH_MAPS["mixtral"] = _MIXTRAL_MAP
 ARCH_MAPS["qwen2_moe"] = _QWEN2_MOE_MAP

@@ -22,7 +22,7 @@ from .engine_v2 import InferenceEngineV2
 #: arches whose HF weights map exactly AND that have a ragged runner
 _RAGGED_ARCHES = {"llama", "mistral", "qwen", "qwen2", "phi3", "phi", "gpt2",
                   "opt", "mixtral", "qwen2_moe", "olmoe", "bloom", "gpt_neox",
-                  "gptj"}
+                  "gptj", "jamba"}
 
 
 def build_hf_engine(model_dir: str,

@@ -36,6 +36,7 @@ import numpy as np
 from ...ops.kernels.delta_rule import (gdn_prefill_uses_kernel,
                                        kda_prefill_uses_kernel)
 from ...ops.kernels import grouped_ffn, short_conv, sparse_attention
+from ...ops.kernels.selective_scan import mamba1_prefill_uses_kernel
 from ...resilience.fault_injection import get_fault_injector
 from ...telemetry.serve import serve_observer
 from ...telemetry.trace import SpanSet
@@ -407,9 +408,10 @@ class InferenceEngineV2:
             # is, and per step of a fused loop), and the real positions
             # that went through the chunked delta rule; of those, the
             # ones of steps whose shape took the Pallas chunk kernel
-            # (delta_rule.kda_prefill_uses_kernel / gdn_.., as the mixer
-            # asks it; none of a state-space layer's, whose chunked form
-            # has no kernel yet). ``state_bytes_resident`` counts the
+            # (delta_rule.kda_prefill_uses_kernel / gdn_..,
+            # selective_scan.mamba1_.., as the mixer asks it; none of a
+            # Mamba-2 or Lightning layer's, whose chunked form has no
+            # kernel yet). ``state_bytes_resident`` counts the
             # same slots by what the device STORES for one, its arrays'
             # last two dimensions in whole tiles
             # (kv_cache.state_bytes_per_slot(resident=True)): over
@@ -2066,7 +2068,9 @@ class InferenceEngineV2:
                         spec = self.runner.state_spec
                         uses_kernel = {
                             "kda": kda_prefill_uses_kernel,
-                            "gdn": gdn_prefill_uses_kernel}.get(spec["kind"])
+                            "gdn": gdn_prefill_uses_kernel,
+                            "mamba1": mamba1_prefill_uses_kernel}.get(
+                                spec["kind"])
                         span.count(
                             linear_attn_prefill_tokens=real,
                             linear_attn_prefill_kernel_tokens=real * bool(

@@ -7,7 +7,8 @@ or a feed-forward ALONE and whose recurrent layers are state-space ones,
 Mellum, whose softmax layers are sliding-window or full by the list,
 each kind with its own position code and its own pool, and LFM2, whose
 recurrent layers are gated short convolutions that carry their last
-inputs and no state).
+inputs and no state, and Jamba, whose recurrent layers are Mamba-1
+mixers: a decay a channel AND state).
 
 Analogue of the reference's llama_v2 / mistral / mixtral v2 containers
 (``inference/v2/model_implementations/{llama_v2,mistral,mixtral}/``): RoPE
@@ -384,6 +385,42 @@ def _mamba2_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
         mamba2_output(p, y, z, model_cfg, dtype)
 
 
+def _mamba1_mixer(p, h, kv, si: int, batch: RaggedBatch, model_cfg, valid_q,
+                  dtype):
+    """One Mamba-1 layer (a decay a channel AND state) over the state
+    pool, as :func:`_mamba2_mixer` runs the scalar-decay form: the same
+    rows and slots (:func:`_state_rows`), a state ``[N, E]`` a row
+    (``selective_scan.mamba1_state_shape``), the short convolution over
+    ``x`` alone, B, C and the low-rank step size out of its OUTPUT
+    (``models/jamba.py``). Padded positions take a zero step (``dt`` 0:
+    decay 1, nothing added). One token a row goes through the decode
+    update in place, more through the chunk scan, in place too
+    (``ops/kernels/selective_scan``). Returns (kv, y [S, C, M])."""
+    from ...models.jamba import (mamba1_conv_inputs, mamba1_output,
+                                 mamba1_recurrence_inputs)
+    from ...ops.kernels.selective_scan import (mamba1_decode_update,
+                                               mamba1_prefill)
+    state, conv, st, slots, fresh, live = _state_rows(kv, si, batch)
+    S, C, _ = h.shape
+    f32 = jnp.float32
+    x, z = mamba1_conv_inputs(p, h, model_cfg, dtype)
+    conv, x = _short_conv(conv, si, batch, fresh, live, x,
+                          p["conv_w"].astype(f32), p["conv_b"].astype(f32))
+    dt, Bm, Cm = mamba1_recurrence_inputs(p, x, model_cfg, dtype)
+    dt = jnp.where(valid_q[..., None], dt, 0.0)
+    A = -jnp.exp(p["A_log"].astype(f32))
+    if C == 1:
+        y, st = mamba1_decode_update(
+            st, slots, x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], p["D"],
+            wipe=fresh & live)
+        y = y[:, None]
+    else:
+        y, st = mamba1_prefill(st, slots, x, dt, A, Bm, Cm, p["D"],
+                               wipe=fresh, live=live)
+    return _with_layer_state(kv, state, conv, si, st), \
+        mamba1_output(p, y, z, dtype)
+
+
 def _gated_conv_mixer(p, h, kv, si: int, batch: RaggedBatch, dtype):
     """One gated short-convolution (LFM2) layer over the convolution part
     of the state pool, which is ALL a sequence carries for it: ``B | C |
@@ -621,6 +658,11 @@ def _llama_ragged_step(params, kv, batch: RaggedBatch, *,
             elif kind == "mamba2":
                 with region("ssm"):
                     kv, y = _mamba2_mixer(p["mamba"], h, kv, si, batch,
+                                          model_cfg, valid_q, dtype)
+                si += 1
+            elif kind == "mamba1":
+                with region("ssm"):
+                    kv, y = _mamba1_mixer(p["mamba"], h, kv, si, batch,
                                           model_cfg, valid_q, dtype)
                 si += 1
             elif kind == "conv":

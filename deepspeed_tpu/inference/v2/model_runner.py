@@ -963,7 +963,8 @@ class RaggedRunnerBase:
             or ("attn",) * self.num_layers
         self.kv_layers = sum(k in ("attn", "mla", "sparse") for k in kinds)
         other = sorted({k for k in kinds
-                        if k in ("swa", "mla", "kda", "gdn", "mamba2")})
+                        if k in ("swa", "mla", "kda", "gdn", "mamba2",
+                                 "mamba1")})
         if other and {"sparse", "lightning"} & set(kinds):
             raise ValueError(
                 f"block-selected ('sparse') and Lightning ('lightning') "
@@ -1027,7 +1028,8 @@ class RaggedRunnerBase:
         #: convolution, ``conv_width`` wide (``taps`` 0: they have none)
         self.state_spec = None
         recurrent = [k for k in kinds
-                     if k in ("kda", "gdn", "mamba2", "lightning", "conv")]
+                     if k in ("kda", "gdn", "mamba2", "mamba1", "lightning",
+                              "conv")]
         if len(set(recurrent)) > 1:
             raise ValueError(
                 f"recurrent layers of more than one kind "
@@ -1070,6 +1072,20 @@ class RaggedRunnerBase:
                 "kind": "lightning", "layers": len(recurrent),
                 "heads": model_cfg.lightning_heads, "d_v": d, "d_k": d,
                 "taps": 0, "conv_width": 0}
+        elif recurrent and recurrent[0] == "mamba1":
+            # a decay a (channel, state) pair: ONE "head" whose state is
+            # [state, channels], the channels along the lanes; x ALONE
+            # through the convolution
+            from ...ops.kernels.selective_scan import mamba1_state_shape
+            from ...ops.kernels.short_conv import whole_width
+            from ...utils.dtypes import resolve_dtype
+            E, N = model_cfg.mamba_inner, model_cfg.mamba_state
+            self.state_spec = {
+                "kind": "mamba1", "layers": len(recurrent), "heads": 1,
+                "d_v": E, "d_k": N, "taps": model_cfg.mamba_conv,
+                "conv_width": whole_width(E, resolve_dtype(cfg.dtype)),
+                "conv_channels": E,
+                "state_shape": mamba1_state_shape(E, N)}
         elif recurrent:
             self.state_spec = {
                 "kind": "mamba2", "layers": len(recurrent),

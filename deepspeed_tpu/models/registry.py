@@ -53,6 +53,8 @@ from .olmo_hybrid import make_model as make_olmo_hybrid
 from .minicpm_sala import (MIXER_TYPES, MiniCPMSALA, MiniCPMSALAConfig,
                            SparseConfig)
 from .minicpm_sala import make_model as make_minicpm_sala
+from .jamba import Jamba, JambaConfig, kinds_from_periods
+from .jamba import make_model as make_jamba
 from .nemotron_h import NemotronH, NemotronHConfig, kinds_from_pattern
 from .nemotron_h import make_model as make_nemotron_h
 
@@ -752,6 +754,47 @@ def _entry_olmo_hybrid(d):
         gdn_neg_eigval=bool(d.get("linear_allow_neg_eigval", True)))
 
 
+def _entry_jamba(d):
+    """Jamba (ai21labs/AI21-Jamba2-3B): layer ``i`` attends where ``i %
+    attn_layer_period == attn_layer_offset`` and is a Mamba-1 mixer
+    otherwise (the ``mamba_*`` keys give its expansion, state width, taps
+    and step-size rank); a dense SwiGLU in every layer, no position code,
+    the head tied by ``tie_word_embeddings``. What the served path has no
+    form for is refused by name: sparse feed-forwards (``num_experts`` >
+    1, on the layers ``expert_layer_period / _offset`` name: inert at 1),
+    a bias on the Mamba projections, no bias on its convolution, another
+    activation, a window."""
+    for key, have, served in (
+            ("num_experts", d.get("num_experts", 1), 1),
+            ("num_experts_per_tok", d.get("num_experts_per_tok", 1), 1),
+            ("mamba_proj_bias", bool(d.get("mamba_proj_bias", False)), False),
+            ("mamba_conv_bias", bool(d.get("mamba_conv_bias", True)), True),
+            ("hidden_act", d.get("hidden_act", "silu"), "silu"),
+            ("sliding_window", d.get("sliding_window"), None)):
+        if have != served:
+            raise ValueError(
+                f"jamba configs with {key}={have!r} are not supported "
+                f"(the served path has {served!r})")
+    base = _hf_llama(d, rms_eps=d.get("rms_norm_eps", 1e-6),
+                     tie_embeddings=bool(d.get("tie_word_embeddings", False)))
+    period = int(d.get("attn_layer_period", 8))
+    offset = int(d.get("attn_layer_offset", 4))
+    if not 0 <= offset < period:
+        raise ValueError(
+            f"jamba configs with attn_layer_offset={offset!r} outside "
+            f"attn_layer_period={period!r} are not supported")
+    rank = d.get("mamba_dt_rank", "auto")
+    if rank == "auto":
+        rank = -(-base["hidden_size"] // 16)
+    return JambaConfig(
+        **base,
+        layer_kinds=kinds_from_periods(base["num_layers"], period, offset),
+        mamba_expand=int(d.get("mamba_expand", 2)),
+        mamba_state=int(d.get("mamba_d_state", 16)),
+        mamba_conv=int(d.get("mamba_d_conv", 4)),
+        mamba_dt_rank=int(rank))
+
+
 def _entry_nemotron_h(d):
     """Nemotron-H (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16):
     ``hybrid_override_pattern`` read letter by letter, a layer a mixer
@@ -880,6 +923,7 @@ ARCHITECTURES: Dict[str, ArchEntry] = {
                              make_kimi_linear, _entry_kimi_linear),
     "nemotron_h": ArchEntry(NemotronHConfig, NemotronH, make_nemotron_h,
                             _entry_nemotron_h),
+    "jamba": ArchEntry(JambaConfig, Jamba, make_jamba, _entry_jamba),
     "mellum": ArchEntry(MellumConfig, Mellum, make_mellum, _entry_mellum),
     "afmoe": ArchEntry(AfmoeConfig, Afmoe, make_afmoe, _entry_afmoe),
     "lfm2": ArchEntry(Lfm2Config, Lfm2, make_lfm2, _entry_lfm2),

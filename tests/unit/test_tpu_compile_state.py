@@ -439,3 +439,108 @@ def test_olmo_hybrid_loop_and_refill_step_compile_at_the_published_widths(
     assert sum(names.values()) == 8          # and the two attention layers
     # the pool is written in place: no temporary near the pool's size
     assert exe.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_jamba_loop_and_refill_step_compile_whole_at_the_published_widths(
+        one_chip, monkeypatch):
+    """The fused 128-step decode loop and the [4, 512] refill step of
+    ``serve-jamba2-rollout-long`` at the published widths, ALL 28 layers
+    and the cell's pools (2.4 GB of [16, 5120] float32 states and carried
+    inputs for 257 slots beside 1.0 GB of 128-lane K/V rows), from shapes
+    alone. The loop: 26 Mamba-1 layers, each its short convolution (5,120
+    channels in a 6,144-wide pool) and its selective-scan update in place
+    on a pool ``[257, 16, 5120]`` that tiles whole, two multi-query layers
+    (20 query heads on ONE K/V head: rows of exactly 128 lanes) in the
+    paged decode kernel, nothing state-shaped copied. The refill step:
+    the chunk scan 26 times from ONE lowering, in place over the same
+    pool, and the BlockSpec attention kernel twice at (20, 1, 128)."""
+    import json
+    import os
+    import re
+    from collections import Counter
+
+    import deepspeed_tpu.ops.kernels as kernels
+    from benchmark.model_types import jamba as mt
+    from deepspeed_tpu.inference.v2.kv_quant import KVPool
+    from deepspeed_tpu.inference.v2.llama_runner import LlamaRaggedRunner
+    from deepspeed_tpu.inference.v2.model_runner import RaggedBatch
+    from deepspeed_tpu.ops.kernels import short_conv
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "jamba2-3b.json")) as f:
+        mcfg = mt.model_config(json.load(f))
+    with open(os.path.join(root, "benchmark", "cells",
+                           "serve-jamba2-rollout-long.json")) as f:
+        cell = json.load(f)
+    eng = cell["engine"]
+    runner = LlamaRaggedRunner(mcfg, RaggedInferenceConfig(
+        **eng, attention_impl="paged_flash"))
+    slots, block, blocks, maxb, steps = (
+        eng["max_seqs"], eng["block_size"], eng["num_blocks"],
+        eng["max_blocks_per_seq"], eng["decode_loop_steps"])
+    assert runner.state_spec["state_shape"] == (16, 5120)
+    assert (runner.kv_layers, runner.kv_heads, runner.head_dim,
+            mcfg.num_heads) == (2, 1, 128, 20)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda: mt.init_params(mcfg, 0)))
+    pool = (slots + 1, 16, 5120)
+    state = tuple(spec(pool, jnp.float32) for _ in range(26))
+    conv = spec(short_conv.pool_shape(26, slots + 1, 4, 6144), jnp.bfloat16)
+    planes = spec((2, 2, (blocks + 1) * block, 128), jnp.bfloat16)
+    f32 = functools.partial(spec, dtype=jnp.float32)
+    exe = runner._decode_loop_ring.trace(
+        params, KVPool(planes, None, None, None), (state, conv),
+        spec((slots,)), spec((slots,)), spec((slots,)), spec((slots,)),
+        spec((slots, maxb)), spec((1,)), f32((1,)), spec((1,)), f32((1,)),
+        spec((1, 1)), n=steps, mode="greedy", cand=1, eos_id=-1,
+        feed="self").lower(lowering_platforms=("tpu",)).compile()
+    hlo = exe.as_text()
+    assert Counter(_mosaic_call_names(hlo)) == {
+        "short_conv_decode_step": 26, "mamba1_decode_state_update": 26,
+        "closed_call": 2}
+    # the names the cell's ``kernels`` block gives the benchmark's readers
+    shaped = "f32_%d_16_5120" % (slots + 1)
+    assert cell["kernels"]["selective_scan"]["op"] \
+        == "mamba1_decode_state_update-" + shaped
+    assert cell["kernels"]["selective_scan_prefill"]["op"] \
+        == "mamba1_chunk_scan-" + shaped
+    assert len(re.findall(
+        r"%%mamba1_decode_state_update[\w\-.]* = \(f32\[%d,16,5120\]"
+        % (slots + 1), hlo)) == 26
+    assert not _conv_pool_moves(hlo, 144)
+    mem = exe.memory_analysis()
+    state_bytes = 26 * (slots + 1) * 16 * 5120 * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 8
+    made = re.findall(r"= f32\[%d,16,5120\]\S* ([\w\-]+)\(" % (slots + 1),
+                      hlo)
+    assert set(made) <= {"parameter", "get-tuple-element"}, set(made)
+
+    lowered = runner._step_greedy.trace(
+        params, KVPool(planes, None, state, conv),
+        RaggedBatch(spec((4, 512)), spec((4,)), spec((4,)), spec((4, maxb)),
+                    spec((4,)))).lower(lowering_platforms=("tpu",))
+    assert len(re.findall(r"func\.func private @mamba1_chunk_scan\b",
+                          lowered.as_text())) == 1
+    exe = lowered.compile()
+    hlo = exe.as_text()
+    names = Counter(_mosaic_call_names(hlo))
+    assert names["mamba1_chunk_scan"] == 26 and len(names) == 2, names
+    assert sum(names.values()) == 28         # and the two attention layers
+    assert len(re.findall(
+        r"%%mamba1_chunk_scan[\w\-.]* = \(f32\[%d,16,5120\]" % (slots + 1),
+        hlo)) == 26
+    # every pool is written in place: weights 6.06 GB + pools 3.45 GB of
+    # arguments, and no temporary near a pool's size
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 1 << 29
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11 << 30

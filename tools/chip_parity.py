@@ -9,6 +9,7 @@ catches.
     chiprun -- python tools/chip_parity.py --config minicpm-sala-9b [--prompt-blocks 128]
     chiprun -- python tools/chip_parity.py --config lfm2-24b-a2b
     chiprun -- python tools/chip_parity.py --config olmo-hybrid-7b
+    chiprun -- python tools/chip_parity.py --config jamba2-3b
     python tools/chip_parity.py --config solar-open2-250b --rehearse   # CPU, toy
 
 Outside any timed window. The model type's hooks come from
@@ -116,7 +117,17 @@ LOGIT_TOLS = {"kimi_linear": (0.15, 2.0),
               # positions, the float8 reference 1.83 in the median and
               # the mildest wrong model (beta without its 2) 2.02 (my chip
               # run, PR 65); each limit lies between its two readings
-              "olmo_hybrid": (0.4, 0.8)}
+              "olmo_hybrid": (0.4, 0.8),
+              # a dense model again, 28 layers deep (every other served
+              # configuration is cut to 4-13), under the sharp attention
+              # draw, a head TIED to an embedding of deviation
+              # 1/sqrt(hidden) and a recurrence whose step size passes
+              # two bfloat16 matmuls: the engine reads 0.204 in the median
+              # and 0.267 at the worst of 128 positions; the float8
+              # reference 3.49 in the median and the mildest wrong model
+              # (a rotary code applied) 2.34 (my chip run, PR 68); each
+              # limit lies between its two readings
+              "jamba": (0.7, 1.4)}
 POSITIONS = 32
 SINGLE_BEFORE, FUSED = 22, 8
 #: by model type, where the default walk does not reach what the family
@@ -132,7 +143,10 @@ WALKS = {"mellum": {"prompt_blocks": 8, "fused": 128, "loops": 5},
          # one of the cell's 256-step loops: the rows after it read the
          # K/V rows its flush wrote (64-row windows of 3,840 lanes) and
          # the state 256 in-place updates left
-         "olmo_hybrid": {"fused": 256, "loops": 1}}
+         "olmo_hybrid": {"fused": 256, "loops": 1},
+         # two of the cell's 128-step loops: the rows after them read the
+         # state 256 in-place updates left and K/V rows two flushes wrote
+         "jamba": {"fused": 128, "loops": 2}}
 
 #: the reference's own keyword for each wrong model, by model type
 VARIANTS = {
@@ -182,6 +196,15 @@ VARIANTS = {
         "a_decay_a_channel_drawn_independently": {"channel_decay": True},
         "norms_moved_to_the_branch_inputs": {"norm_at": "input"},
         "rotary_switched_on": {"rope_theta": 10000.0}},
+    "jamba": {
+        "inner_norms_left_out": {"inner_norms": False},
+        "one_decay_a_channel_for_all_its_states": {"shared_decay": True},
+        "bf16_state": {"state_dtype": "bfloat16"},
+        # as lfm2_moe's lost carry: a loop of the cell's steps after the
+        # first compared position the first flush, and every loop after
+        "state_dropped_at_a_flush": {"state_reset": "flush"},
+        "conv_bias_left_out": {"conv_bias": False},
+        "rotary_applied": {"rope_theta": 10000.0}},
     "minicpm_sala": {
         "dense_attention_in_place_of_the_selection": {"selection": "dense"},
         "topk_32": {"topk": 32},
@@ -487,11 +510,13 @@ def main(argv=None) -> int:
                 tree = params = float8_in_place(params)
             if "state_dtype" in wrong:
                 wrong["state_dtype"] = jnp.bfloat16
-            if wrong.get("conv_reset") == "flush":
-                # the compared positions are a stream's first n served:
-                # a loop of the cell's steps after the first, a flush
-                steps = cell["engine"]["decode_loop_steps"]
-                wrong["conv_reset"] = (T - n + steps, steps)
+            for lost in ("conv_reset", "state_reset"):
+                if wrong.get(lost) == "flush":
+                    # the compared positions are a stream's first n
+                    # served: a loop of the cell's steps after the first,
+                    # a flush
+                    steps = cell["engine"]["decode_loop_steps"]
+                    wrong[lost] = (T - n + steps, steps)
             lg = np.asarray(logits_fn(**wrong)(tree, toks, at), np.float32)
             result["variants"][name] = dict(
                 cell_rule(base, lg.argmax(-1)),

@@ -323,6 +323,46 @@ def kernel_cases() -> List[KernelCase]:
             *a, jnp.int32(3), interpret=False, **hyp), (p0, g0, m0, v0),
         lambda *a: adamw_reference(*a, jnp.int32(3), **hyp), atol=1e-5))
 
+    # the Mamba-1 selective scan at Jamba2-3B's widths: the decode update
+    # in place over a pool (a wiped row, a row of dt = 0) and the chunk
+    # scan over two blocks of positions, each against its jnp twin
+    from deepspeed_tpu.ops.kernels import selective_scan as ss
+    E, N = 5120, 16
+    kk = _keys(17, 8)
+    A1 = -jnp.exp(jax.random.normal(kk[0], (N, E), f32))
+    D1 = jax.random.normal(kk[1], (E,), f32)
+    pool = jax.random.normal(kk[2], (33, N, E), f32)
+    slots1 = jnp.arange(32, dtype=jnp.int32)[::-1]
+    wipe1 = jnp.zeros((32,), bool).at[3].set(True)
+
+    def scan_inputs(key, *lead):
+        k1, k2, k3, k4 = jax.random.split(key, 4)
+        return (jax.random.normal(k1, lead + (E,), f32),
+                jax.nn.softplus(jax.random.normal(k2, lead + (E,), f32) - 1),
+                jax.random.normal(k3, lead + (N,), f32),
+                jax.random.normal(k4, lead + (N,), f32))
+
+    xd, dtd, Bd, Cd = scan_inputs(kk[3], 32)
+    dtd = dtd.at[5].set(0.0)
+    cases.append(KernelCase(
+        "selective_scan_decode",
+        lambda st, x, dt, B, C: ss.mamba1_decode_update(
+            st, slots1, x, dt, A1, B, C, D1, wipe=wipe1, impl="pallas"),
+        (pool, xd, dtd, Bd, Cd),
+        lambda st, x, dt, B, C: ss.mamba1_decode_update(
+            st, slots1, x, dt, A1, B, C, D1, wipe=wipe1, impl="xla"),
+        atol=1e-3))
+    xp, dtp, Bp, Cp = scan_inputs(kk[4], 4, 128)
+    slots4 = jnp.asarray([7, 2, 30, 32], jnp.int32)
+    cases.append(KernelCase(
+        "selective_scan_chunk",
+        lambda st, x, dt, B, C: ss.mamba1_prefill(
+            st, slots4, x, dt, A1, B, C, D1, wipe=wipe1[:4], impl="pallas"),
+        (pool, xp, dtp, Bp, Cp),
+        lambda st, x, dt, B, C: ss.mamba1_prefill(
+            st, slots4, x, dt, A1, B, C, D1, wipe=wipe1[:4], impl="xla"),
+        atol=5e-3))
+
     # block quantization: every value must come back within half a
     # quantization step of its group (int4 goes through the nibble packing)
     xq = jax.random.normal(ks[1], (512, 1024), f32)
