@@ -16,7 +16,10 @@ with the flash kernels, remat a layer and the chunked cross-entropy.
   - j < sliding_window``; ``y = W_o (attn * sigmoid(W_g z))``. The call is
   the flash kernel with the layer's window
   (``ops/kernels/flash_attention.py``), under ``region("attn_window")`` /
-  ``region("attn_core")``.
+  ``region("attn_core")``. Under ``remat`` a layer keeps that call's
+  output and row sums from its forward to its backward (``_REMAT_POLICY``:
+  the kernel file's two residual names), so the layer's recompute runs
+  everything else again and no flash kernel.
 * feed-forward: the first ``num_dense_layers`` layers a SwiGLU of width
   ``intermediate_size``; the rest ``sigmoid`` scores over ``num_experts``
   in float32, the top ``experts_top_k`` of ``score + select_bias``
@@ -60,6 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.kernels import qk_norm_rope as _prep
+from ..ops.kernels.flash_attention import RESIDUAL_NAMES, flash_attention
 from ..telemetry.trace import region
 from .llama import RMSNorm
 
@@ -176,6 +180,22 @@ def _prep_interpreted(cfg: AfmoeConfig) -> bool:
     return cfg.attention_impl == "flash_interpret"
 
 
+def _attention_impl(cfg: AfmoeConfig) -> str:
+    """``attention_impl`` with ``"auto"`` resolved where it is traced."""
+    if cfg.attention_impl == "auto":
+        return ("flash" if jax.default_backend() == "tpu"
+                and jax.device_count() == 1 else "xla")
+    return cfg.attention_impl
+
+
+#: what a layer's ``nn.remat`` keeps from its forward to its backward: the
+#: flash call's output and row sums (134 MB + 2 MB a layer at the cell's
+#: ``[2, 32, 8192, 128]``), so that the recompute runs the projections,
+#: ``qk_norm_rope`` and the feed-forward again and NO flash kernel
+_REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    *RESIDUAL_NAMES)
+
+
 class AfmoeAttention(nn.Module):
     cfg: AfmoeConfig
     kind: str
@@ -200,13 +220,9 @@ class AfmoeAttention(nn.Module):
             interpret=_prep_interpreted(cfg))
         v = jnp.swapaxes(v, 1, 2)
 
-        impl = cfg.attention_impl
-        if impl == "auto":
-            impl = ("flash" if jax.default_backend() == "tpu"
-                    and jax.device_count() == 1 else "xla")
+        impl = _attention_impl(cfg)
         with region("attn_window" if window is not None else "attn_core"):
             if impl in ("flash", "flash_interpret"):
-                from ..ops.kernels import flash_attention
                 y = jnp.swapaxes(flash_attention(
                     q, k, v, causal=True, window=window, layout="BHTD",
                     block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
@@ -339,7 +355,8 @@ class Afmoe(nn.Module):
             x = constrain_activations(x)
         counts = []
         for i, kind in enumerate(cfg.layer_kinds):
-            x, c = layer_class(self, AfmoeBlock, f"layer_{i}", cfg.remat)(
+            x, c = layer_class(self, AfmoeBlock, f"layer_{i}", cfg.remat,
+                               policy=_REMAT_POLICY)(
                 cfg, kind, cfg.sparse(i), name=f"layer_{i}")(x)
             if c is not None:
                 counts.append(c)
@@ -372,7 +389,9 @@ def step_counters(cfg: AfmoeConfig, counts, tokens: int, seq: int) -> dict:
     every row, and the layers whose rows rejoined their tokens through the
     combine kernel (``combine_impl``: 0 wherever ``.at[].add`` ran); and
     the layers whose ``q`` and ``k`` went through ``qk_norm_rope``'s Pallas
-    call (0 wherever its twin ran) at rows of ``seq`` tokens."""
+    call (0 wherever its twin ran) at rows of ``seq`` tokens; and the
+    layers whose recompute kept its flash call's output and row sums
+    (``_REMAT_POLICY``: 0 without ``remat``, and where no flash call ran)."""
     from ..moe.sharded_moe import combine_impl, held_row_bound
     first, n = cfg.held
     rows = tokens * cfg.experts_top_k
@@ -393,7 +412,10 @@ def step_counters(cfg: AfmoeConfig, counts, tokens: int, seq: int) -> dict:
             "moe_combine_layers": jnp.int32(
                 len(here) if kernel is not None else 0),
             "attn_prep_fused_layers": jnp.int32(
-                cfg.num_layers if prep is not None else 0)}
+                cfg.num_layers if prep is not None else 0),
+            "flash_residuals_kept_layers": jnp.int32(
+                cfg.num_layers if cfg.remat and _attention_impl(cfg)
+                in ("flash", "flash_interpret") else 0)}
 
 
 def make_model(cfg: AfmoeConfig):

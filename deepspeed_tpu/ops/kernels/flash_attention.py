@@ -84,6 +84,23 @@ call from its static shapes, and ``flash_attention`` notes it for every
 causal call it traces (``take_causal_plans``: the train engine's
 ``flash_score_elems_*``).
 
+Residuals under a caller's recompute. The forward rules keep ``(q, k, v,
+o, lse)`` for the backward. ``q``, ``k``, ``v`` are the caller's own; ``o``
+and ``lse`` are the kernel's, and the rules put a
+``jax.ad_checkpoint.checkpoint_name`` on each: ``RESIDUAL_OUT``
+(``"flash_out"``, ``[b, h, tq, d]`` in the inputs' dtype: ``b x h x t x d x
+2`` bytes a call in bfloat16) and ``RESIDUAL_LSE`` (``"flash_lse"``, named
+in the lane-dense form ``[b, h, tq]`` float32, ``b x h x t x 4`` bytes, and
+widened to the kernels' ``[b, h, tq, 1]`` column after the name). A name is
+the identity. A caller that wraps its layer in ``jax.checkpoint`` /
+``nn.remat`` with ``policy=jax.checkpoint_policies.save_only_these_names(
+*RESIDUAL_NAMES)`` keeps the two from its forward to its backward, and its
+recompute then runs NO forward kernel (dq and dk/dv read the kept arrays:
+the ones the recompute would have made, to the bit); one whose policy lists
+neither, or that has no policy, compiles to what it compiled to and runs
+the forward kernel again for them (``models/afmoe.py`` lists them;
+``models/gpt2.py``'s policies and ``models/llama.py`` do not).
+
 Three bodies a kernel cost three times the tracing and lowering, so the
 launchers ``_fwd`` / ``_bwd`` are inner jits: a model's unrolled layers
 share one traced and lowered copy of each call, named ``attn`` in a profile.
@@ -98,6 +115,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -926,6 +944,13 @@ def _bwd(causal, sm_scale, block_q, block_k, kv_len, causal_offset, interpret,
     return dq, dk, dv
 
 
+#: ``jax.ad_checkpoint.checkpoint_name`` of the two residuals the forward
+#: rules make themselves (module docstring): a caller's remat policy that
+#: lists them (``RESIDUAL_NAMES``) keeps the kernel out of its recompute
+RESIDUAL_OUT = "flash_out"
+RESIDUAL_LSE = "flash_lse"
+RESIDUAL_NAMES = (RESIDUAL_OUT, RESIDUAL_LSE)
+
 _STATIC = tuple(range(3, 12))
 
 
@@ -937,10 +962,21 @@ def _flash(q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
     return o
 
 
+def _named_residuals(o, lse):
+    """``o`` and ``lse`` under their checkpoint names (module docstring):
+    ``lse`` in the lane-dense form ``[b, h, tq]``, so that a policy that
+    keeps it keeps its numbers and not the ``[.., tq, 1]`` column's 128
+    lanes a row, widened again for the backward."""
+    o = checkpoint_name(o, RESIDUAL_OUT)
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_LSE)[..., None]
+    return o, lse
+
+
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
                causal_offset, interpret, group, window):
-    o, lse = _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
-                  causal_offset, interpret, group, window)
+    o, lse = _named_residuals(*_fwd(
+        q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
+        interpret, group, window))
     return o, (q, k, v, o, lse)
 
 
@@ -963,8 +999,9 @@ def _flash_lse(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
 
 def _flash_lse_fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
                    causal_offset, interpret, group, window):
-    o, lse = _fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_len,
-                  causal_offset, interpret, group, window)
+    o, lse = _named_residuals(*_fwd(
+        q, k, v, causal, sm_scale, block_q, block_k, kv_len, causal_offset,
+        interpret, group, window))
     return (o, lse), (q, k, v, o, lse)
 
 

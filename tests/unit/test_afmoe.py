@@ -137,6 +137,50 @@ def test_loss_nll_and_every_leafs_gradient_match_the_reference(impl,
             reference.bias_update(bias, c, CFG.load_balance_coeff))
 
 
+@pytest.mark.parametrize("head_dim", [16, 128])
+def test_a_layers_recompute_keeps_its_flash_calls_output_and_row_sums(
+        monkeypatch, head_dim):
+    """ISSUE 69: a layer's ``nn.remat`` keeps the flash call's ``o`` and
+    ``lse`` (``afmoe._REMAT_POLICY``), so the gradient's program holds one
+    forward kernel a layer where the policy-less remat holds two; the kept
+    arrays are the ones the recompute made, so loss and EVERY leaf's
+    gradient are the same to the bit, and ``remat`` off is both again."""
+    from deepspeed_tpu.models import afmoe
+    cfg = dataclasses.replace(
+        CFG, attention_impl="flash_interpret", flash_block_q=32,
+        flash_block_k=32, xent_chunks=2, attn_head_dim=head_dim, remat=True)
+    params, tokens = _draw(cfg), _tokens(cfg)
+
+    def forward_kernels(jaxpr):     # the flash call that reads q, k, v
+        return sum((eqn.primitive.name == "pallas_call"
+                    and eqn.params["name"].startswith("attn")
+                    and len(eqn.invars) == 3)
+                   + sum(map(forward_kernels,
+                             jax.core.jaxprs_in_params(eqn.params)))
+                   for eqn in jaxpr.eqns)
+
+    def side(c):
+        fn = jax.value_and_grad(
+            lambda p: make_model(c)[2](p, {"tokens": tokens}, None),
+            has_aux=True)
+        (loss, aux), grads = jax.jit(fn)(params)
+        return (forward_kernels(jax.make_jaxpr(fn)(params).jaxpr), loss,
+                grads, int(aux["counters"]["flash_residuals_kept_layers"]))
+
+    kept = side(cfg)
+    monkeypatch.setattr(afmoe, "_REMAT_POLICY", None)
+    plain = side(cfg)
+    off = side(dataclasses.replace(cfg, remat=False))
+    L = cfg.num_layers
+    assert (kept[0], plain[0], off[0]) == (L, 2 * L, L)
+    assert (kept[3], off[3]) == (L, 0)
+    for other in (plain, off):
+        assert float(kept[1]) == float(other[1])
+        for a, b in zip(jax.tree_util.tree_leaves(kept[2]),
+                        jax.tree_util.tree_leaves(other[2])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_the_eight_shares_add_up_to_the_uncut_layer():
     """The guide's section 4 test, forward AND gradient: with ``held`` each
     eighth of the experts in turn, the routed parts add up to the uncut
@@ -443,17 +487,21 @@ def test_the_steps_counters_say_which_layers_visited_every_row():
         "moe_rows_elsewhere": 4 * 4096 - (300 + 1024 + 1025 + 4096),
         "moe_rows_hottest": 2 * (200 + 683 + 684 + 2731),
         "moe_rows_visited": 2 * 1024 + 2 * 4096, "moe_layers_full": 2,
-        # off the TPU ``.at[].add`` moves the rows (ISSUE 64) and
-        # ``qk_norm_rope`` runs its twin (ISSUE 67)
-        "moe_combine_layers": 0, "attn_prep_fused_layers": 0}
+        # off the TPU ``.at[].add`` moves the rows (ISSUE 64),
+        # ``qk_norm_rope`` runs its twin (ISSUE 67) and no flash call
+        # leaves residuals to keep (ISSUE 69)
+        "moe_combine_layers": 0, "attn_prep_fused_layers": 0,
+        "flash_residuals_kept_layers": 0}
     whole = AfmoeConfig.tiny(num_experts=E, experts_top_k=k)
     got = step_counters(whole, [layer(300), layer(4096)], tokens, 64)
     assert (int(got["moe_rows_visited"]), int(got["moe_layers_full"])) == (
         2 * 4096, 0)
 
 
-@pytest.mark.parametrize("prep", ["twin", "kernel"])
-def test_the_new_counters_reach_step_stats_and_add_up_over_steps(prep):
+@pytest.mark.parametrize("prep,remat", [
+    ("twin", False), ("kernel", False), ("kernel", True), ("twin", True)])
+def test_the_new_counters_reach_step_stats_and_add_up_over_steps(prep,
+                                                                 remat):
     """ISSUE 62's two counters through the engine: a tree whose first
     sparse layer's selection bias sends every choice to the held experts
     (its held rows exceed the bound: every row visited, in every step) and
@@ -461,9 +509,12 @@ def test_the_new_counters_reach_step_stats_and_add_up_over_steps(prep):
     steps ``step_stats`` holds the host's recount, as for
     ``moe_rows_routed``. And ISSUE 67's: every layer of a step whose ``q``
     and ``k`` went through ``qk_norm_rope``'s Pallas call (128-lane heads,
-    interpreted here), none where its twin ran."""
+    interpreted here), none where its twin ran. And ISSUE 69's: every
+    layer of a step whose ``nn.remat`` kept its flash call's output and
+    row sums, none without ``remat`` and none where no flash call ran."""
     from deepspeed_tpu.moe.sharded_moe import held_row_bound
-    cfg = dataclasses.replace(SMALL, experts_held=4, experts_first=4)
+    cfg = dataclasses.replace(SMALL, experts_held=4, experts_first=4,
+                              remat=remat)
     if prep == "kernel":
         cfg = dataclasses.replace(cfg, attn_head_dim=128, flash_block_q=32,
                                   flash_block_k=32,
@@ -495,6 +546,8 @@ def test_the_new_counters_reach_step_stats_and_add_up_over_steps(prep):
     assert stats["moe_combine_layers"] == 0      # the CPU: ``.at[].add``
     assert stats["attn_prep_fused_layers"] == (
         3 * cfg.num_layers if prep == "kernel" else 0)
+    assert stats["flash_residuals_kept_layers"] == (
+        3 * cfg.num_layers if prep == "kernel" and remat else 0)
     assert recount["moe_layers_full"] == 3
     assert recount["moe_rows_visited"] == 3 * (rows + bound)
 

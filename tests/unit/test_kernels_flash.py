@@ -402,3 +402,97 @@ class TestShardedFlash:
         for a, b in zip(gk, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-4, rtol=1e-3)
+
+
+def _eqns(jaxpr):
+    """Every equation under ``jaxpr``, in order, nested ones after their
+    owner."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _forward_kernels(jaxpr) -> int:
+    """The forward ``pallas_call``s under ``jaxpr``: the one kernel of the
+    three that reads ``q``, ``k``, ``v`` and nothing else."""
+    return sum(e.primitive.name == "pallas_call" and len(e.invars) == 3
+               for e in _eqns(jaxpr))
+
+
+def _shapes(jaxpr, skip=()):
+    """``(primitive, output avals)`` of every equation under ``jaxpr``,
+    the primitives of ``skip`` left out."""
+    return [(e.primitive.name, tuple(str(v.aval) for v in e.outvars))
+            for e in _eqns(jaxpr) if e.primitive.name not in skip]
+
+
+@pytest.mark.parametrize("window", [None, 32])
+@pytest.mark.parametrize("return_lse", [False, True])
+class TestResidualNames:
+    """ISSUE 69: the forward rules name ``o`` and ``lse``
+    (``RESIDUAL_NAMES``), so a caller's ``jax.checkpoint`` decides whether
+    its recompute runs the forward kernel again."""
+
+    @staticmethod
+    def _loss(window, return_lse, policy=None, checkpoint=True):
+        def f(q, k, v):
+            # something to recompute on either side of the call
+            out = flash_attention(jnp.tanh(q), k, v, causal=True,
+                                  window=window, interpret=True, block_q=32,
+                                  block_k=32, return_lse=return_lse)
+            if return_lse:
+                return jnp.sum(jnp.sin(out[0])) + jnp.sum(jnp.cos(out[1]))
+            return jnp.sum(jnp.sin(out))
+        if checkpoint:
+            f = jax.checkpoint(f, policy=policy)
+        return jax.value_and_grad(f, argnums=(0, 1, 2))
+
+    @staticmethod
+    def _qkv():
+        ks = jax.random.split(jax.random.PRNGKey(7), 3)
+        return (_rand(ks[0], (1, 96, 4, 16)), _rand(ks[1], (1, 96, 2, 16)),
+                _rand(ks[2], (1, 96, 2, 16)))
+
+    def test_a_policy_that_lists_them_keeps_the_forward_out_of_the_recompute(
+            self, window, return_lse):
+        from deepspeed_tpu.ops.kernels.flash_attention import RESIDUAL_NAMES
+        qkv = self._qkv()
+        keep = jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)
+        plain = self._loss(window, return_lse)
+        kept = self._loss(window, return_lse, keep)
+        # the backward's two kernels are there either way
+        for fn, forwards in ((plain, 2), (kept, 1)):
+            jaxpr = jax.make_jaxpr(fn)(*qkv).jaxpr
+            assert _forward_kernels(jaxpr) == forwards
+            assert sum(e.primitive.name == "pallas_call"
+                       for e in _eqns(jaxpr)) == forwards + 2
+        (la, ga), (lb, gb) = jax.jit(plain)(*qkv), jax.jit(kept)(*qkv)
+        assert float(la) == float(lb)
+        for a, b in zip(ga, gb):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_a_policy_that_lists_another_name_recomputes_as_no_name_did(
+            self, window, return_lse, monkeypatch):
+        """A name is the identity: under a policy that keeps some OTHER
+        name (the GPT cells' ``qkv_out``) the gradient's jaxpr is the one
+        the unnamed rules trace, ``name`` equations apart, and so is the
+        un-checkpointed gradient's."""
+        import sys
+        # the package's ``flash_attention`` attribute is the function
+        fa = sys.modules["deepspeed_tpu.ops.kernels.flash_attention"]
+        qkv = self._qkv()
+        other = jax.checkpoint_policies.save_only_these_names("attn_out")
+        named = [jax.make_jaxpr(self._loss(window, return_lse, other))(*qkv),
+                 jax.make_jaxpr(self._loss(window, return_lse,
+                                           checkpoint=False))(*qkv)]
+        monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+        bare = [jax.make_jaxpr(self._loss(window, return_lse, other))(*qkv),
+                jax.make_jaxpr(self._loss(window, return_lse,
+                                          checkpoint=False))(*qkv)]
+        for a, b in zip(named, bare):
+            # the names ARE in the one and in the other nothing else is
+            assert len(_shapes(a.jaxpr)) > len(_shapes(b.jaxpr))
+            assert _shapes(a.jaxpr, skip=("name",)) == _shapes(b.jaxpr)
+            assert _forward_kernels(a.jaxpr) == _forward_kernels(b.jaxpr)
+        assert _forward_kernels(named[0].jaxpr) == 2

@@ -12,7 +12,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from tpu_compile_common import (
-    _mosaic_call_names, _mosaic_grids,
+    _mosaic_call_names, _mosaic_grids, _xla_remats,
     described_chips_programs_stay_out_of_the_cache, one_chip)
 
 
@@ -155,9 +155,10 @@ def test_the_trinity_cells_train_step_compiles_under_the_chips_memory(
     8,192, float32 master / moments / gradients, bf16 compute, remat a
     layer) through the ENGINE's own step builder, for one v5e chip: it fits
     under 15.75 GB, its flash calls are the WINDOW kernel on the four
-    sliding layers and today's on the full one (four calls a layer:
-    forward, its recompute, dq, dk/dv: what ``flash_window_roofline.train``
-    divides by), and the experts' grouped products are there in both
+    sliding layers and today's on the full one (THREE calls a layer:
+    forward, dq, dk/dv; the layer's recompute keeps the forward's output
+    and row sums, ISSUE 69, where ``flash_window_roofline.train`` still
+    divides by four), and the experts' grouped products are there in both
     passes. The engine is built over a toy tree of the same STRUCTURE (a
     described chip holds no array) and its step traced at the real
     shapes."""
@@ -237,9 +238,15 @@ def test_the_trinity_cells_train_step_compiles_under_the_chips_memory(
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert 4e9 < total < 15.75e9, total
     text = exe.as_text()
+    # what the memory costs in the compiler's OWN recomputation: 23
+    # instructions before ISSUE 69 at 14.35 GB, 33 with the five layers'
+    # ``o`` and ``lse`` kept at 14.55 GB (four layers' shared ``up_proj``,
+    # ``k_proj``, router product and ``lse`` column a second time). A
+    # change that spends memory sees here what XLA buys back with it
+    assert len(_xla_remats(text)) <= 33, _xla_remats(text)
     names = _mosaic_call_names(text)
     window = f"attn_w{full.sliding_window}"
-    assert names.count(window) == 4 * 4 and names.count("attn") == 4 * 1
+    assert names.count(window) == 4 * 3 and names.count("attn") == 3 * 1
     assert sum(n.startswith("ragged-dot") for n in names) >= 4 * 9
     # ISSUE 64: a sparse layer's rows rejoin their tokens through the
     # combine kernel, in each branch of its ``cond``: forward, the layer's
@@ -260,15 +267,15 @@ def test_the_trinity_cells_train_step_compiles_under_the_chips_memory(
         == [(0, 28)] + [(6, 43)] * 4
     # ISSUE 66: a sliding layer's three kernels walk 8 x 3 grid steps a q
     # head where each walked 8 x 8 (21 of them run a body either way); the
-    # full layer's keep the square. And Mosaic took those grids: forward,
-    # its recompute and dq at 3 key blocks a query block, dk/dv at 3 query
-    # blocks a q head of a KV head's 8
+    # full layer's keep the square. And Mosaic took those grids: forward
+    # and dq at 3 key blocks a query block, dk/dv at 3 query blocks a q
+    # head of a KV head's 8
     assert sorted((plan["steps"], plan["steps_run"])
                   for _, _, plan in plans) == [(72, 63)] * 4 + [(192, 108)]
     assert sorted(_mosaic_grids(text, window)) \
-        == [(B, 4, 8, 8 * 3)] * 4 + [(B, 32, 8, 3)] * 4 * 3
+        == [(B, 4, 8, 8 * 3)] * 4 + [(B, 32, 8, 3)] * 4 * 2
     assert sorted(_mosaic_grids(text, "attn")) \
-        == [(B, 4, 8, 8 * 8)] + [(B, 32, 8, 8)] * 3
+        == [(B, 4, 8, 8 * 8)] + [(B, 32, 8, 8)] * 2
 
 
 @pytest.mark.parametrize("S,n,M,rows,dtype", [

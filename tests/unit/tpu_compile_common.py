@@ -45,6 +45,14 @@ def _mosaic_call_names(hlo):
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)]
 
 
+def _xla_remats(hlo):
+    """Names of the instructions XLA's OWN rematerialisation added to the
+    compiled text (``%fusion.790.remat``, ``%gte.remat.3``): what the
+    compiler computes a second time to stay under the chip's memory, apart
+    from what the program's ``jax.checkpoint`` asked for."""
+    return re.findall(r"%([\w.\-]+\.remat(?:\.\d+)?) = ", hlo)
+
+
 def _mosaic_grids(hlo, name):
     """The grid (Mosaic's ``iteration_bounds``) of every Mosaic call
     ``name`` in the compiled text, read out of the call's own module: the
